@@ -27,8 +27,8 @@ Commands:
                        producer edge.
 - ``scheduler``      — autoscaler monitor loop over the load balancer
                        (cmd/scheduler/main.go:68-76).
-- ``check``          — load config, build everything, run one echo
-                       request end-to-end, exit. CI smoke.
+- ``check``          — load config, build everything on the configured
+                       backend, run one request end-to-end, exit.
 """
 
 from __future__ import annotations
@@ -345,13 +345,7 @@ class App:
             ResourceRequest, ResourceType)
         from llmq_tpu.scheduling.topology import TpuTopology
 
-        try:
-            topo = TpuTopology.discover()
-        except Exception:  # noqa: BLE001 — discovery must never block
-            # serving (e.g. jax import-time platform quirks).
-            log.exception("topology discovery failed; engine runs "
-                          "unaccounted")
-            return
+        topo = TpuTopology.discover()
         mesh = self.cfg.tpu.mesh_shape
         n_chips = 1
         for v in (mesh or {}).values():
@@ -739,9 +733,11 @@ def cmd_scheduler(args) -> int:
 
 
 def cmd_check(args) -> int:
-    """Build the full monolith, run one message end-to-end, exit 0/1."""
+    """Build the full monolith on the CONFIGURED backend, run one
+    message end-to-end, exit 0/1. The last line names the backend (and
+    the device a jax engine sits on), so an echo pass can never be
+    read as a model pass."""
     cfg = _load(args)
-    cfg.executor.backend = args.backend or "echo"
     app = App(cfg, with_api=True, with_workers=True, with_engine=True)
     # Ephemeral port so a parallel real instance doesn't collide.
     cfg.server.port = 0
@@ -751,8 +747,8 @@ def cmd_check(args) -> int:
         import json
         import urllib.request
         port = app.api._httpd.server_address[1]  # noqa: SLF001
-        body = json.dumps({"content": "smoke check", "user_id": "check"}
-                          ).encode()
+        body = json.dumps({"content": "smoke check", "user_id": "check",
+                           "metadata": {"max_new_tokens": 8}}).encode()
         req = urllib.request.Request(
             f"http://127.0.0.1:{port}/api/v1/messages", data=body,
             headers={"Content-Type": "application/json"}, method="POST")
@@ -765,12 +761,17 @@ def cmd_check(args) -> int:
                     timeout=10) as resp:
                 m = json.loads(resp.read())
             if m["status"] == "completed":
-                ok = bool(m["response"])
+                # Echo must answer with the content; a random-init
+                # model may legitimately decode to an empty string.
+                ok = bool(m["response"]) or cfg.executor.backend != "echo"
                 break
             time.sleep(0.05)
     finally:
         app.stop()
-    log.info("CHECK %s", "OK" if ok else "FAILED")
+    from llmq_tpu.observability.device import describe_device
+    log.info("CHECK %s backend=%s device=%s", "OK" if ok else "FAILED",
+             cfg.executor.backend,
+             describe_device(app.engine.device_identity()))
     return 0 if ok else 1
 
 

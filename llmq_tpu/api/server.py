@@ -432,6 +432,13 @@ class ApiServer:
                "time": time.time()}
         if self.engine is not None:
             out["engine"] = "running" if self.engine.running else "stopped"
+            ident = getattr(self.engine, "device_identity", None)
+            device = ident() if callable(ident) else None
+            if device is not None:
+                # What the engine actually sits on: a server that came
+                # up on the CPU must not read like one on a chip.
+                # Device-free backends (echo) omit the field.
+                out["device"] = device
             role = getattr(self.engine, "disagg_role", "unified")
             if role != "unified":
                 # Disagg role advertisement (docs/disaggregation.md):
@@ -820,6 +827,9 @@ class ApiServer:
             stats[name] = {qn: s.to_dict()
                            for qn, s in mgr.get_all_stats().items()}
             stats[name]["workers"] = self.factory.get_worker_stats(name)
+            # Which ordering core serves (NativeMLQ, or _PyBackend when
+            # the C++ build failed and the loader fell back).
+            stats[name]["core"] = mgr.queue.backend_name
             dlq = self.factory.get_dead_letter_queue(name)
             if dlq is not None:
                 stats[name]["dead_letter_size"] = dlq.size()

@@ -257,7 +257,35 @@ class SubprocessReplicaPool(ReplicaPool):
         self.provisioned = 0
         self.decommissioned = 0
 
+    def _child_backend(self) -> str:
+        """The executor backend a spawned replica will run: its
+        ``--backend`` argument, else what the configuration it inherits
+        (LLMQ_CONFIG + LLMQ_* environment) resolves to."""
+        args = [str(a) for a in (self.config.args or [])]
+        for i, a in enumerate(args):
+            if a == "--backend" and i + 1 < len(args):
+                return args[i + 1]
+            if a.startswith("--backend="):
+                return a.split("=", 1)[1]
+        from llmq_tpu.core.config import load_config
+        return load_config().executor.backend
+
     def provision(self, seq: int) -> Optional[Endpoint]:
+        from llmq_tpu.observability.device import held_accelerator
+        held = held_accelerator()
+        if held and self._child_backend() == "jax":
+            # One process per chip: this process's engine holds the
+            # device, and the child inherits this environment unchanged
+            # — it would wait out ready_timeout and never come up.
+            log.error(
+                "refusing to spawn a --backend jax replica: this process "
+                "holds the %s device(s) and a chip belongs to one process "
+                "at a time. Launch one serve process per chip from "
+                "outside with each pinned to its own chip "
+                "(docs/deployment.md \"One process per chip\") and list "
+                "them as cluster.peers, or use controlplane.pool.kind=exec",
+                held)
+            return None
         port = int(self.config.base_port) + int(seq)
         url = f"http://127.0.0.1:{port}"
         cmd = ([sys.executable, "-m", "llmq_tpu", "--host", "127.0.0.1",

@@ -111,9 +111,8 @@ def build_engine(cfg: Config, *, name: str = "engine0",
         from llmq_tpu.models.llama import get_config, init_params
         from llmq_tpu.models.checkpoint import import_hf_llama, load_checkpoint
 
-        if cfg.tpu.compilation_cache_dir:
-            from llmq_tpu.parallel import enable_compilation_cache
-            enable_compilation_cache(cfg.tpu.compilation_cache_dir)
+        from llmq_tpu.parallel import enable_compilation_cache
+        enable_compilation_cache(cfg.tpu.compilation_cache_dir)
 
         mcfg = get_config(cfg.model.name, max_seq_len=cfg.model.max_seq_len)
         if cfg.model.vocab_size:
@@ -239,10 +238,12 @@ def build_engine(cfg: Config, *, name: str = "engine0",
         kv_tiering=getattr(ex, "kv_tiering", None),
         speculation=spec)
     tier = getattr(ex, "kv_tiering", None)
-    log.info("built %s engine %s (slots=%d pages=%d page_size=%d "
+    from llmq_tpu.observability.device import describe_device
+    log.info("built %s engine %s on %s (slots=%d pages=%d page_size=%d "
              "mesh=%s prefix_cache=%s mixed_batch=%s ragged_attention=%s "
              "async_pipeline=%s kv_tiering=%s speculation=%s)",
-             ex.backend, name, ex.max_batch_size, ex.kv_pages, ex.page_size,
+             ex.backend, name, describe_device(engine.device_identity()),
+             ex.max_batch_size, ex.kv_pages, ex.page_size,
              (mesh_shape if (ex.backend == "jax" and mesh_shape)
               else "off"),
              "on" if getattr(ex.prefix_cache, "enabled", False) else "off",

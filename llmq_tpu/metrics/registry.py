@@ -44,7 +44,7 @@ _PROC_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10, 30)
 _STAGE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                   0.25, 0.5, 1, 2.5, 5, 10, 30)
 #: Per-chunk step-time components in MILLISECONDS: sub-0.1 ms host
-#: dispatches on echo, up to seconds through a tunneled runtime.
+#: dispatches on echo, up to seconds for a stalled transfer.
 _STEP_MS_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10,
                     25, 50, 100, 250, 500, 1000, 2500)
 #: Program compiles: sub-second export-cache loads up to multi-minute

@@ -290,7 +290,7 @@ def _logits(params: Params, h: jnp.ndarray) -> jnp.ndarray:
     return jnp.dot(h, embed.T).astype(jnp.float32)
 
 
-@partial(jax.jit, static_argnames=("cfg",))
+@partial(jax.jit, static_argnames=("cfg", "last_only"))
 def forward_prefill(
     params: Params,
     cfg: LlamaConfig,
@@ -299,9 +299,16 @@ def forward_prefill(
     lengths: jnp.ndarray,       # (B,) int32 — valid tokens per row
     kv_cache: KVCache,          # paged pools (written in place via .at)
     block_tables: jnp.ndarray,  # (B, max_pages) int32; pad with page 0
+    last_only: bool = False,
 ) -> Tuple[jnp.ndarray, KVCache]:
     """Prefill: run up to T tokens per sequence, writing their KV into the
     paged pool. Returns (logits (B, T, V) f32, updated cache).
+
+    ``last_only`` (serving): project only each row's LAST valid token —
+    logits (B, V). Serving samples nothing else, and the head is the
+    widest matmul of the pass: at B=4, T=2048, V=128k the full logits
+    are 3.9 GB of f32 plus gather temporaries, which is what kept
+    llama3-8b's batched-prefill program from fitting a 16 GB chip.
 
     Conventions (shared with the engine's KV allocator):
     - **page 0 of the pool is reserved** — never allocated to a sequence;
@@ -370,6 +377,8 @@ def forward_prefill(
         hn2 = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
         h = h + _mlp(hn2, layer_slice(lp["w_gate"], l),
                      layer_slice(lp["w_up"], l), layer_slice(lp["w_down"], l))
+    if last_only:
+        h = h[jnp.arange(B), lengths - 1]                  # (B, D)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     if quant_kv:
         out_cache = {"k": pools[0], "v": pools[1],
@@ -801,10 +810,8 @@ def forward_prefill_sp(params: Params, cfg: LlamaConfig,
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from llmq_tpu.ops.ring_attention import shard_map_compat
-
     spec_t = P(None, axis_name)
-    fn = jax.jit(shard_map_compat(
+    fn = jax.jit(jax.shard_map(
         _partial(_sp_forward_local, cfg=cfg, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(), spec_t),

@@ -7,6 +7,7 @@ is intentionally narrow: handles in, handles out.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,12 +20,16 @@ log = get_logger("native")
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "src", "mlq.cpp")
 _SO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_libmlq.so")
+#: sha256 of the source the .so was built from, kept beside it: a tree
+#: copy (git checkout, rsync, the chip tool) rewrites every mtime, so
+#: staleness is decided from the source's CONTENT.
+_STAMP = _SO + ".srchash"
 
 #: Absolute-path override for the loaded library. The sanitizer harness
 #: (scripts/analysis/run_sanitizers.py, docs/analysis.md) points this at
 #: an asan/ubsan-instrumented variant from native/build/ so the REAL
 #: Python queue suites drive the instrumented core; the override is
-#: loaded as-is (no rebuild, no mtime check) and a missing/unloadable
+#: loaded as-is (no rebuild, no staleness check) and a missing/unloadable
 #: path is a hard error, not a silent fallback to the production .so.
 _ENV_OVERRIDE = "LLMQ_NATIVE_LIB"
 
@@ -41,16 +46,28 @@ _load_failed = False
 def _build_if_needed() -> bool:
     if not os.path.exists(_SRC):
         return os.path.exists(_SO)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return True
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()
+    if os.path.exists(_SO) and os.path.exists(_STAMP):
+        with open(_STAMP) as f:
+            if f.read().strip() == digest:
+                return True
+    # Build beside the target and rename: concurrent first imports
+    # (worker processes, parallel tests) never load a half-written .so.
+    tmp = f"{_SO}.tmp.{os.getpid()}"
     try:
         subprocess.run(
             ["g++", "-O2", "-std=c++17", "-fPIC", "-Wall", "-Wextra",
-             "-Werror", "-shared", "-o", _SO, _SRC],
+             "-Werror", "-shared", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120,
         )
+        os.replace(tmp, _SO)
+        with open(_STAMP, "w") as f:
+            f.write(digest)
         return True
-    except Exception as e:  # noqa: BLE001 — any build failure → Python fallback
+    except (OSError, subprocess.SubprocessError) as e:
+        # No compiler (or a failed build) → the Python queue core
+        # serves; /api/v1/queues/stats "core" says which one did.
         log.warning("native queue core build failed; using Python fallback: %s", e)
         return False
 

@@ -17,7 +17,11 @@ Two implementations of the decode hot path:
 
 :func:`paged_decode_step` routes each decode layer (TPU → fused
 Pallas write+attention kernel, else scatter + pure JAX;
-``LLMQ_PALLAS=0`` forces the fallback).
+``LLMQ_PALLAS=0`` forces the fallback). Every routing decision is a
+pure function of geometry, backend and ``LLMQ_PALLAS``;
+:func:`kernel_routes` evaluates the same predicates outside a trace so
+the executor can log, per compiled program, which implementation each
+op took.
 :func:`blockwise_prefill_attention` is the memory-bounded prefill
 (online softmax over KV chunks — no (B, H, T, S) f32 logits tensor).
 """
@@ -228,25 +232,154 @@ def paged_pool_window(pool: jnp.ndarray, block_table: jnp.ndarray,
     return pool[:, page_of, slot_of]
 
 
-def _kernel_route(k_pool, *, extra_ok: bool = True, enabled: bool = True):
+def pallas_mode() -> str:
+    """``LLMQ_PALLAS``: ``auto`` (default — kernels on a TPU backend,
+    pure JAX elsewhere), ``0`` (pure JAX everywhere) or ``interpret``
+    (kernel bodies through the Pallas interpreter: CPU test coverage
+    only). ``interpret`` on a TPU backend is an error — it would serve
+    the interpreter's program on the chip the kernels were written
+    for."""
+    mode = os.environ.get("LLMQ_PALLAS", "auto")
+    if mode not in ("auto", "0", "interpret"):
+        raise ValueError(
+            f"LLMQ_PALLAS={mode!r}: expected auto, 0 or interpret")
+    if mode == "interpret" and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "LLMQ_PALLAS=interpret on a TPU backend: interpret mode is "
+            "for CPU test coverage; unset it (or set 0 for the pure-JAX "
+            "path)")
+    return mode
+
+
+def _kernel_route(gd: int, *, extra_ok: bool = True, enabled: bool = True):
     """Shared LLMQ_PALLAS routing policy for the paged-KV kernels.
 
     Returns (use_kernel, interpret). Kernel eligibility: not disabled
     (``LLMQ_PALLAS=0`` or ``enabled=False`` — the caller's static
     opt-out, e.g. mesh-sharded programs where GSPMD cannot partition a
-    single-chip Pallas call), ``extra_ok``, H_kv·D lane-aligned, and
-    either a TPU backend or ``LLMQ_PALLAS=interpret`` (CI coverage of
-    kernel bodies without a TPU)."""
-    mode = os.environ.get("LLMQ_PALLAS", "auto")
-    aligned = k_pool.shape[3] % 128 == 0
-    if mode == "0" or not enabled or not extra_ok or not aligned:
+    single-chip Pallas call), ``extra_ok``, ``gd`` (= H_kv·D, the
+    pool's flat trailing axis) lane-aligned, and either a TPU backend
+    or ``LLMQ_PALLAS=interpret`` (CI coverage of kernel bodies without
+    a TPU)."""
+    mode = pallas_mode()
+    if mode == "0" or not enabled or not extra_ok or gd % 128:
         return False, False
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
+    if jax.default_backend() == "tpu":
         return True, False
     if mode == "interpret":
         return True, True
     return False, False
+
+
+def _fused_decode_ok(B: int, page_size: int, max_pages: int, gd: int,
+                     itemsize: int) -> bool:
+    """bf16 fused decode kernel eligibility. page_size % 8: the kernel
+    writes back the 8-sublane tile holding the new row — sub-8 pages
+    can't. The tile plan must also be legal for this geometry
+    (large-GD models at big pages force an illegal sub-8 row tile —
+    the split write-kernel + pooled-attention path serves those)."""
+    from llmq_tpu.ops.pallas.fused_decode import fused_kernel_viable
+    return page_size % 8 == 0 and fused_kernel_viable(
+        B, page_size, max_pages, gd, itemsize)
+
+
+def _fused_decode_q8_ok(B: int, page_size: int, max_pages: int, gd: int,
+                        n_kv_heads: int, n_scale_heads: int) -> bool:
+    """int8-KV fused decode kernel eligibility. page_size % 128: a
+    scale page is a (H_kv, page_size) block whose LANE dim is page_size
+    — Mosaic rejects the page DMA slice when it isn't lane-tile aligned
+    (found by an on-chip A/B at ps=16). H_kv = 8 fills the minimum
+    sublane tile of the scale page. Serving configs for int8 KV want
+    128-token pages anyway (per-page DMA cost); smaller pages take the
+    pure path."""
+    from llmq_tpu.ops.pallas.fused_decode import fused_kernel_viable
+    return (page_size % 128 == 0 and n_kv_heads == n_scale_heads == 8
+            and fused_kernel_viable(B, page_size, max_pages, gd, 1))
+
+
+def _ragged_ok(B: int, page_size: int, max_pages: int, gd: int,
+               n_heads: int, n_tokens: int, itemsize: int,
+               multi_ok: bool) -> bool:
+    from llmq_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_kernel_viable)
+    return (multi_ok and n_tokens % RAGGED_Q_BLOCK == 0
+            and ragged_kernel_viable(B, page_size, max_pages, gd, n_heads,
+                                     q_block=RAGGED_Q_BLOCK,
+                                     itemsize=itemsize))
+
+
+def _ragged_q8_ok(B: int, page_size: int, max_pages: int, gd: int,
+                  n_heads: int, n_tokens: int, n_kv_heads: int,
+                  n_scale_heads: int, multi_ok: bool) -> bool:
+    """int8-KV ragged kernel: the fused q8 decode kernel's scale-page
+    lane constraints (128-token pages, H_kv = 8) on top of
+    :func:`_ragged_ok`."""
+    return (page_size % 128 == 0 and n_kv_heads == n_scale_heads == 8
+            and _ragged_ok(B, page_size, max_pages, gd, n_heads, n_tokens,
+                           1, multi_ok))
+
+
+def kernel_routes(*, batch: int, page_size: int, max_pages: int,
+                  n_heads: int, n_kv_heads: int, head_dim: int,
+                  kv_itemsize: int, quant_kv: bool, enabled: bool,
+                  multi_ok: bool, decode: bool = False,
+                  prefill_rows: int = 0,
+                  ragged_tokens: int = 0) -> dict:
+    """Which implementation each attention op of ONE serving program
+    takes at this geometry: ``{op: "pallas:<kernel function>" | "xla"}``.
+    Evaluates the very predicates the dispatchers below call at trace
+    time (nested-jit trace caching makes a trace-time recorder miss
+    programs), so the executor can log the decision where the program
+    is built and chip_smoke.py can hold the compiled text against it.
+
+    ``decode``: the program runs decode steps over ``batch`` rows;
+    ``prefill_rows``: it runs bucket prefill over that many rows (0 =
+    none); ``ragged_tokens``: it runs the ragged mixed step over a
+    packed buffer of that many tokens (0 = none)."""
+    gd = n_kv_heads * head_dim
+
+    def pick(ok: bool, kernel: str) -> str:
+        use, interp = _kernel_route(gd, extra_ok=ok, enabled=enabled)
+        if not use:
+            return "xla"
+        return f"pallas{'-interpret' if interp else ''}:{kernel}"
+
+    out = {}
+    ragged = "xla"
+    if ragged_tokens:
+        if quant_kv:
+            ragged = pick(_ragged_q8_ok(batch, page_size, max_pages, gd,
+                                        n_heads, ragged_tokens, n_kv_heads,
+                                        n_kv_heads, multi_ok),
+                          "_ragged_kernel_q8")
+        else:
+            ragged = pick(_ragged_ok(batch, page_size, max_pages, gd,
+                                     n_heads, ragged_tokens, kv_itemsize,
+                                     multi_ok), "_ragged_kernel")
+        out["ragged_attention"] = ragged
+    if prefill_rows or ragged_tokens:
+        rows_ok = prefill_rows == 1 or multi_ok
+        out["prefill_write"] = ("xla" if quant_kv
+                                else pick(rows_ok, "_kv_prefill_kernel"))
+        if ragged == "xla":
+            # Bucket prefill — or the ragged step's fallback, which
+            # runs the bucket ops over the dense per-slice view.
+            out["prefill_attention"] = (
+                "xla" if quant_kv
+                else pick(rows_ok, "_prefill_attn_kernel"))
+    if decode:
+        if quant_kv:
+            fused = pick(_fused_decode_q8_ok(batch, page_size, max_pages,
+                                             gd, n_kv_heads, n_kv_heads),
+                         "_fused_kernel_q8")
+            out["decode_write"] = out["decode_attention"] = fused
+        else:
+            fused = pick(_fused_decode_ok(batch, page_size, max_pages, gd,
+                                          kv_itemsize), "_fused_kernel")
+            out["decode_attention"] = fused
+            out["decode_write"] = (fused if fused != "xla"
+                                   else pick(True, "_kv_write_kernel"))
+    return out
 
 
 def paged_kv_write(k_pool, v_pool, k_new, v_new, page_of, slot_of, layer,
@@ -263,8 +396,8 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, page_of, slot_of, layer,
     N = k_new.shape[0]
     kn = k_new.reshape(N, -1)
     vn = v_new.reshape(N, -1)
-    use_kernel, interpret = _kernel_route(k_pool, extra_ok=distinct_pages,
-                                          enabled=enabled)
+    use_kernel, interpret = _kernel_route(
+        k_pool.shape[3], extra_ok=distinct_pages, enabled=enabled)
     if use_kernel:
         return _jit_kv_write()(k_pool, v_pool, kn, vn,
                                page_of, slot_of, layer,
@@ -297,7 +430,7 @@ def paged_kv_write_prefill(k_pool, v_pool, k, v, block_tables, positions,
     # (multi_ok): the kernels have no VJP, and the B > 1 training path
     # must keep the differentiable fallback.
     use_kernel, interpret = _kernel_route(
-        k_pool, extra_ok=(B == 1 or multi_ok), enabled=enabled)
+        k_pool.shape[3], extra_ok=(B == 1 or multi_ok), enabled=enabled)
     if use_kernel:
         # The write kernel is per-sequence; B > 1 (batched prefill)
         # chains one aliased call per row through the pool — the dense
@@ -361,7 +494,7 @@ def dispatch_prefill_attention(q, k_pool, v_pool, block_tables, positions,
     B, T = q.shape[0], q.shape[1]
     page_size = k_pool.shape[2]
     use_kernel, interpret = _kernel_route(
-        k_pool, extra_ok=(B == 1 or multi_ok), enabled=enabled)
+        k_pool.shape[3], extra_ok=(B == 1 or multi_ok), enabled=enabled)
     if use_kernel:
         # Per-sequence kernel, row-looped for batched prefill: pure
         # READS of the pool — B opaque kernel consumers don't make XLA
@@ -393,17 +526,11 @@ def paged_decode_step(q, k_new, v_new, k_pool, v_pool, block_tables,
     the row-RMW write kernel / scatter followed by pooled attention.
     Returns (attn, k_pool, v_pool).
     """
-    # page_size % 8: the fused kernel writes back the 8-sublane tile
-    # holding the new row (fused_decode.py) — sub-8 pages can't. The
-    # tile plan must also be legal for this geometry (large-GD models at
-    # big pages force an illegal sub-8 row tile — route to the split
-    # write-kernel + pooled-attention path instead).
-    from llmq_tpu.ops.pallas.fused_decode import fused_kernel_viable
-    fused_ok = (k_pool.shape[2] % 8 == 0 and fused_kernel_viable(
-        q.shape[0], k_pool.shape[2], block_tables.shape[1],
-        k_pool.shape[3], k_pool.dtype.itemsize))
     use_kernel, interpret = _kernel_route(
-        k_pool, extra_ok=fused_ok, enabled=enabled)
+        k_pool.shape[3], enabled=enabled,
+        extra_ok=_fused_decode_ok(q.shape[0], k_pool.shape[2],
+                                  block_tables.shape[1], k_pool.shape[3],
+                                  k_pool.dtype.itemsize))
     if use_kernel:
         attn, (k_pool, v_pool) = _jit_fused_decode()(
             q, k_new, v_new, k_pool, v_pool, block_tables, seq_lens,
@@ -431,7 +558,7 @@ def blockwise_prefill_attention(
     Same semantics as the full-logits version (mask: kv_pos <= q_pos and
     kv_pos < seq_len) but peak memory is O(B·H·T·block_size) f32 instead
     of O(B·H·T·S) — the difference between GBs-per-layer and MBs at 8k
-    context (VERDICT r1 weak #4). ``lax.scan`` over chunks keeps one
+    context. ``lax.scan`` over chunks keeps one
     compiled body; XLA fuses mask+softmax into the chunk matmuls.
     """
     B, T, H, D = q.shape
@@ -532,19 +659,12 @@ def paged_decode_step_q8(q, k_new, v_new, pools, block_tables, seq_lens,
     kq, kscale = quantize_kv_rows(k_new)    # (B, Hkv, D) i8, (B, Hkv)
     vq, vscale = quantize_kv_rows(v_new)
 
-    from llmq_tpu.ops.pallas.fused_decode import fused_kernel_viable
-    # page_size % 128: a scale page is a (H_kv, page_size) block whose
-    # LANE dim is page_size — Mosaic rejects the page DMA slice when it
-    # isn't lane-tile aligned (found by an on-chip A/B at ps=16).
-    # Serving configs for int8 KV want 128-token pages anyway
-    # (per-page DMA cost); smaller pages fall back to the pure path.
-    fused_ok = (k_pool.shape[2] % 128 == 0
-                and k_pool.shape[3] // D == ks_pool.shape[2] == 8
-                and fused_kernel_viable(
-                    B, k_pool.shape[2], block_tables.shape[1],
-                    k_pool.shape[3], k_pool.dtype.itemsize))
-    use_kernel, interpret = _kernel_route(k_pool, extra_ok=fused_ok,
-                                          enabled=enabled)
+    use_kernel, interpret = _kernel_route(
+        k_pool.shape[3], enabled=enabled,
+        extra_ok=_fused_decode_q8_ok(B, k_pool.shape[2],
+                                     block_tables.shape[1],
+                                     k_pool.shape[3], k_pool.shape[3] // D,
+                                     ks_pool.shape[2]))
     if use_kernel:
         attn, pools = _jit_fused_decode_q8()(
             q, kq, kscale, vq, vscale, pools, block_tables, seq_lens,
@@ -655,9 +775,6 @@ def ragged_mixed_step(q_dec, k_new_d, v_new_d, q_pf, k_pf, v_pf,
     fused/split decode step), preserving token-for-token equivalence.
     Returns ``(attn_dec (B, H, D), attn_pf (N, H, D), k_pool,
     v_pool)``."""
-    from llmq_tpu.ops.pallas.ragged_paged_attention import (
-        ragged_kernel_viable)
-
     B, H, D = q_dec.shape
     N = q_pf.shape[0]
     page_size = k_pool.shape[2]
@@ -679,14 +796,10 @@ def ragged_mixed_step(q_dec, k_new_d, v_new_d, q_pf, k_pf, v_pf,
         k_pool, v_pool, k_dense, v_dense, pf_block_tables, pos_dense,
         lengths, layer, enabled=enabled, multi_ok=multi_ok)
 
-    ragged_ok = (multi_ok
-                 and N % RAGGED_Q_BLOCK == 0
-                 and ragged_kernel_viable(
-                     B, page_size, MP, GD, H,
-                     q_block=RAGGED_Q_BLOCK,
-                     itemsize=k_pool.dtype.itemsize))
-    use_kernel, interpret = _kernel_route(k_pool, extra_ok=ragged_ok,
-                                          enabled=enabled)
+    use_kernel, interpret = _kernel_route(
+        GD, enabled=enabled,
+        extra_ok=_ragged_ok(B, page_size, MP, GD, H, N,
+                            k_pool.dtype.itemsize, multi_ok))
     if use_kernel:
         bt_all = jnp.concatenate(
             [dec_block_tables, pf_block_tables], axis=0)
@@ -720,8 +833,6 @@ def ragged_mixed_step_q8(q_dec, k_new_d, v_new_d, q_pf, k_pf, v_pf,
     (the bucket path's prefill attention gathered + dequantized the
     full bf16 window per slice per layer). Fallback mirrors the exact
     bucket-path q8 ops. Returns ``(attn_dec, attn_pf, pools)``."""
-    from llmq_tpu.ops.pallas.ragged_paged_attention import (
-        ragged_kernel_viable)
     from llmq_tpu.ops.quant import quantize_kv_rows
 
     k_pool = pools[0]
@@ -739,18 +850,10 @@ def ragged_mixed_step_q8(q_dec, k_new_d, v_new_d, q_pf, k_pf, v_pf,
         pools, k_dense, v_dense, pf_block_tables, pos_dense, lengths,
         layer)
 
-    # Same scale-page lane constraints as the fused q8 decode kernel
-    # (ops/pallas/fused_decode.py): 128-token pages, H_kv = 8.
-    ragged_ok = (multi_ok
-                 and N % RAGGED_Q_BLOCK == 0
-                 and page_size % 128 == 0
-                 and GD // D == ks_pool.shape[2] == 8
-                 and ragged_kernel_viable(
-                     B, page_size, MP, GD, H,
-                     q_block=RAGGED_Q_BLOCK,
-                     itemsize=k_pool.dtype.itemsize))
-    use_kernel, interpret = _kernel_route(k_pool, extra_ok=ragged_ok,
-                                          enabled=enabled)
+    use_kernel, interpret = _kernel_route(
+        GD, enabled=enabled,
+        extra_ok=_ragged_q8_ok(B, page_size, MP, GD, H, N, GD // D,
+                               ks_pool.shape[2], multi_ok))
     if use_kernel:
         kq, kscale = quantize_kv_rows(k_new_d)
         vq, vscale = quantize_kv_rows(v_new_d)

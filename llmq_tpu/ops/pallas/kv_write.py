@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llmq_tpu.ops.pallas._compat import CompilerParams
 
 
 def _kv_write_kernel(
@@ -175,7 +174,7 @@ def kv_cache_write_pallas(
         out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
         input_output_aliases={5: 0, 6: 1},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_of.astype(jnp.int32), slot_of.astype(jnp.int32),
@@ -346,7 +345,7 @@ def kv_prefill_write_pallas(
         out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
         input_output_aliases={4: 0, 5: 1},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_table.astype(jnp.int32), meta,

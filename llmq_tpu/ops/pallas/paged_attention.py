@@ -62,7 +62,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llmq_tpu.ops.pallas._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -264,7 +263,7 @@ def paged_decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, GD), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llmq_tpu.ops.pallas._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -259,7 +258,7 @@ def paged_prefill_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T * H, GD), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), meta,

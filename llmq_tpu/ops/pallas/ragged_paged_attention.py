@@ -50,7 +50,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llmq_tpu.ops.pallas._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -600,7 +599,7 @@ def ragged_mixed_attention_pallas(
                    jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
         input_output_aliases={11: 2, 12: 3},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
@@ -1185,7 +1184,7 @@ def ragged_mixed_attention_q8_pallas(
                    jax.ShapeDtypeStruct(ks_pool.shape, ks_pool.dtype),
                    jax.ShapeDtypeStruct(vs_pool.shape, vs_pool.dtype)],
         input_output_aliases={13: 2, 14: 3, 15: 4, 16: 5},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),

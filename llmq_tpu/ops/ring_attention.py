@@ -22,21 +22,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across the jax API rename: ≥0.6 exposes it at
-    top level with ``check_vma``; older releases (this image ships
-    0.4.x) only have ``jax.experimental.shard_map.shard_map`` with the
-    equivalent knob spelled ``check_rep``. Defaults to the library's
-    safe ``True`` — call sites that must skip replication checking
-    (the ring rotation's ppermute accumulation) opt out explicitly."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def _chunk_attention(q, k, v, q_offset, k_offset, causal):
     """Partial (unnormalised) attention of local q against one k/v chunk.
     Returns (chunk_max (B,H,Tq), exp-sum (B,H,Tq), acc (B,Tq,H,D))."""
@@ -106,7 +91,7 @@ def ring_attention_sharded(mesh: Mesh, q, k, v, causal: bool = True,
     spec = P(None, axis_name, None, None)
 
     fn = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             partial(ring_attention, axis_name=axis_name, causal=causal),
             mesh=mesh,
             in_specs=(spec, spec, spec),

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import os
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -42,29 +43,40 @@ def make_mesh(shape: Dict[str, int],
     return mesh
 
 
-def enable_compilation_cache(cache_dir: str,
-                             min_compile_secs: float = 0.5) -> None:
-    """Turn on JAX's persistent compilation cache at ``cache_dir``.
+#: Where the persistent caches live when ``JAX_COMPILATION_CACHE_DIR``
+#: is not set: a FIXED path under the checkout (the directory is part
+#: of the cache key on some backends, so a path made from a temp name,
+#: a pid or the time never hits). Listed in ``.gitignore``.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    Every program whose compile took ≥ ``min_compile_secs`` is serialized
-    to disk; later processes (serving restarts, the driver bench)
-    deserialize instead of recompiling — warmup drops from minutes to
-    seconds. Safe to call repeatedly; "" is a no-op. The cache is also
-    what makes the executor's PARALLEL warmup effective: AOT-compiled
-    programs land in the cache, and the real first call hits it.
-    """
-    if not cache_dir:
-        return
-    import os
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      min_compile_secs)
-    # Cache regardless of entry size (the decode programs are large
-    # anyway; small prefill buckets still cost full tracing+compile).
+def enable_compilation_cache(configured_dir: str = "") -> None:
+    """Turn on JAX's persistent compilation cache — the ONE place a
+    cache directory is decided, called by every jax-backend engine
+    build (and the bench).
+
+    Precedence: ``JAX_COMPILATION_CACHE_DIR`` in the environment wins —
+    JAX has already taken it, so this sets the thresholds only and no
+    directory in code; else ``configured_dir``
+    (``tpu.compilation_cache_dir``, for container deployments that
+    mount a volume); else :data:`DEFAULT_CACHE_DIR`. The executor's
+    ``jax.export`` artifact cache lives in ``<this dir>/export``.
+
+    Programs are kept whatever they weigh (the small prefill buckets
+    still pay full tracing + Mosaic lowering) once they took half a
+    second to compile: a warm start must load every warm-up program
+    (chip_smoke.py asserts it) without filing each eager one-liner.
+    Safe to call repeatedly."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cache_dir = configured_dir or DEFAULT_CACHE_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    log.info("XLA compilation cache at %s", cache_dir)
+    log.info("XLA compilation cache at %s",
+             jax.config.jax_compilation_cache_dir)
 
 
 def single_device_mesh(axis_names: Sequence[str] = ("dp", "tp")) -> Mesh:
@@ -88,7 +100,7 @@ def distributed_init(coordinator: Optional[str] = None,
     Exercised for real by tests/test_distributed.py: two OS processes
     rendezvous on a local coordinator and run a cross-process
     allgather over the CPU backend."""
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         log.info("jax.distributed already initialised")
         return
     kwargs = {}

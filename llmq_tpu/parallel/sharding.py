@@ -2,7 +2,7 @@
 
 The megatron-style TP layout, expressed as a **regex partition-rule
 table** (the ``match_partition_rules`` shape from the pjit serving
-stacks, SNIPPETS.md [2]) resolved into PartitionSpecs and left to XLA
+stacks) resolved into PartitionSpecs and left to XLA
 to lower into ICI collectives:
 
 - qkv projections shard the HEAD (output) dim → each chip computes its
@@ -71,8 +71,7 @@ def tree_path_str(path: Sequence) -> str:
 
 def match_partition_rules(rules: Sequence[Tuple[str, P]], tree):
     """PartitionSpec pytree for ``tree``: each leaf gets the spec of
-    the FIRST rule whose regex searches its '/'-joined path (SNIPPETS
-    [2] ``match_partition_rules`` shape). Scalar leaves replicate
+    the FIRST rule whose regex searches its '/'-joined path. Scalar leaves replicate
     unconditionally. Raises if no rule matches — a partition table
     must be total over the model it claims to cover."""
 

@@ -1,0 +1,449 @@
+#!/usr/bin/env python3
+"""Kernel phase of ``chip_smoke.py`` — the process that holds the chip
+after the server child has exited.
+
+At the geometry ``load_config()`` resolves (the same file and ``LLMQ_*``
+environment the serve phase ran with, so the two agree by construction):
+
+(a) builds the engine through ``build_engine(cfg, warmup=True)`` — the
+    programs the server compiled, loaded from the caches it left — and
+    holds each compiled program's text against the routes the executor
+    logged: a route that names a Pallas kernel must show Mosaic custom
+    calls (``_kernel_route`` did not hand back the reference), a program
+    whose routes are all ``xla`` must show none. On the shipped
+    single-chip bf16 path every attention op of ``prefill_b*``,
+    ``decode_chunk`` and ``mixed_chunk`` must be a Pallas kernel.
+(b) runs one teacher-forced schedule — a prefill through every bucket,
+    decode steps over the full batch, one mixed step — through up to
+    three paths and compares LOGITS (never sampled streams: random-init
+    weights put top-2 gaps inside bf16 rounding): the serving path, the
+    pure-JAX bf16 path on one device (``LLMQ_PALLAS=0``'s routing) and a
+    float32 ``jax.numpy`` run of the same weights. On a mesh the serving
+    path is the GSPMD-partitioned pure-JAX program.
+
+Writes a JSON report to ``--out`` and exits non-zero when a check does
+not hold. ``--tiny`` shrinks the model for the CPU unit test.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import sys
+import time
+from typing import Any, Dict, List, Tuple
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+#: Max |Δlogit| allowed between two paths, on logits of std ≈ 1
+#: (random-init). Two bf16 paths differ by reduction order, a bf16 path
+#: and float32 by the rounding of every activation: measured on the v5e
+#: at full-depth llama3-1b (PR 21) 0.078 kernel vs pure-JAX and 0.069 /
+#: 0.070 kernel / pure-JAX vs float32, so twice that. A routing, layout
+#: or sharding fault moves logits by O(1).
+TOL = 0.15
+#: int8 WEIGHTS (w8a8) quantize every activation dynamically, so a
+#: bf16-rounding difference in one attention output can move the next
+#: linear's input by a whole int8 step: measured at llama3-8b width
+#: (PR 21) 0.24 / 0.33 / 0.34 kernel vs pure-JAX at 2 / 8 / 32 layers
+#: with int8 weights, against 0.053 / 0.065 at 2 / 8 layers with bf16
+#: weights and the SAME int8-KV kernel — the kernel agrees, the network
+#: is not smooth. Twice the measured value again.
+TOL_W8A8 = 0.7
+#: float32 reference weights must fit beside the serving model.
+F32_MAX_PARAMS = 2_000_000_000
+
+MOSAIC_CALL = "tpu_custom_call"
+
+
+class CheckFailure(AssertionError):
+    pass
+
+
+def check(cond: Any, msg: str) -> None:
+    if not cond:
+        raise CheckFailure(msg)
+
+
+def say(msg: str) -> None:
+    sys.stdout.write(f"[kernel_check] {msg}\n")
+    sys.stdout.flush()
+
+
+def check_program_text(ex, on_tpu: bool, expect_all_pallas: bool) -> Dict:
+    """(a): compiled text vs logged routes, per warm-up program."""
+    out = {}
+    for name, compiled in sorted(ex._aot.items()):  # noqa: SLF001
+        routes = ex.program_routes[name]
+        kernels = sorted({impl.split(":", 1)[1]
+                          for impl in routes.values()
+                          if impl.startswith("pallas:")})
+        text = compiled.as_text()
+        n_calls = text.count(MOSAIC_CALL)
+        out[name] = {"routes": routes, "mosaic_calls": n_calls}
+        if on_tpu:
+            if kernels:
+                check(n_calls > 0,
+                      f"{name}: routes name {kernels} but the compiled "
+                      f"program has no {MOSAIC_CALL}")
+            else:
+                check(n_calls == 0,
+                      f"{name}: routes are all xla but the compiled "
+                      f"program has {n_calls} {MOSAIC_CALL}")
+        if expect_all_pallas and name.startswith(
+                ("prefill_b", "decode_chunk", "mixed_chunk")):
+            bad = {op: impl for op, impl in routes.items()
+                   if not impl.startswith("pallas:")}
+            check(not bad, f"{name}: expected every attention op on a "
+                           f"Pallas kernel, got {bad}")
+    return out
+
+
+class Path:
+    """One way of running the model: config + placed params + jitted
+    prefill / decode / mixed steps that return logits."""
+
+    def __init__(self, name: str, cfg, params, *, num_pages: int,
+                 page_size: int, cache_dtype, ragged: bool,
+                 kv_shardings=None) -> None:
+        import jax
+
+        from llmq_tpu.models.llama import (forward_decode, forward_mixed,
+                                           forward_mixed_ragged,
+                                           forward_prefill, init_kv_pages)
+
+        self.name = name
+        self.params = params
+        def make():
+            return init_kv_pages(cfg, num_pages, page_size,
+                                 dtype=cache_dtype)
+
+        def jit(f, n_out: int):
+            """Donated cache; on a mesh, the executor's layout: the
+            pool pinned on the way out of every program."""
+            if kv_shardings is None:
+                return jax.jit(f, donate_argnums=(1,))
+            return jax.jit(
+                f, donate_argnums=(1,),
+                out_shardings=(None,) * n_out + (dict(kv_shardings),))
+
+        # On a mesh the pool is created already sharded.
+        self.cache = (make() if kv_shardings is None else
+                      jax.jit(make, out_shardings=kv_shardings)())
+
+        def prefill(params, cache, tokens, positions, lengths, bts):
+            logits, cache = forward_prefill(params, cfg, tokens, positions,
+                                            lengths, cache, bts)
+            return logits[0, lengths[0] - 1], cache
+
+        def decode(params, cache, tokens, positions, bts, active):
+            return forward_decode(params, cfg, tokens, positions, cache,
+                                  bts, active=active)
+
+        def mixed(params, cache, tokens, positions, bts, active,
+                  pf_tokens, pf_positions, pf_lengths, pf_bts):
+            import jax.numpy as jnp
+            dec, pf, cache = forward_mixed(
+                params, cfg, tokens, positions, cache, bts, pf_tokens,
+                pf_positions, pf_lengths, pf_bts, dec_active=active)
+            idx = jnp.arange(pf_tokens.shape[0])
+            return dec, pf[idx, pf_lengths - 1], cache
+
+        def mixed_ragged(params, cache, tokens, positions, bts, active,
+                         pf_tokens, pf_positions, pf_qoff, pf_qlen,
+                         pf_bts):
+            return forward_mixed_ragged(
+                params, cfg, tokens, positions, cache, bts, pf_tokens,
+                pf_positions, pf_qoff, pf_qlen, pf_bts, dec_active=active)
+
+        self._prefill = jit(prefill, 1)
+        self._decode = jit(decode, 1)
+        self._mixed = jit(mixed_ragged if ragged else mixed, 2)
+
+    def prefill(self, *a):
+        out, self.cache = self._prefill(self.params, self.cache, *a)
+        return out
+
+    def decode(self, *a):
+        out, self.cache = self._decode(self.params, self.cache, *a)
+        return out
+
+    def mixed(self, *a):
+        dec, pf, self.cache = self._mixed(self.params, self.cache, *a)
+        return dec, pf
+
+
+def schedule(ex, seed: int = 0) -> Dict:
+    """The teacher-forced inputs, from the executor's geometry alone:
+    row r prefills a prompt that lands in bucket r (longest first, then
+    short prompts), all rows decode ``N_DECODE`` forced steps, then one
+    mixed step where the last S rows continue as prefill slices."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    spec = ex.spec
+    B, ps, MP = spec.batch_size, spec.page_size, spec.max_pages_per_seq
+    V = ex.model_cfg.vocab_size
+    S = max(1, ex.mixed_prefill_slices)
+    T = ex.mixed_slice_tokens or ex.prefill_buckets[0]
+    if ex.ragged_attention:
+        T //= S     # ragged: mixed_slice_tokens is the PACKED capacity
+    n_decode = 3
+    max_len = MP * ps
+    buckets = sorted(ex.prefill_buckets, reverse=True)
+    rows = []
+    next_page = 1
+    for r in range(B):
+        slice_row = r >= B - S
+        if r < len(buckets) and not slice_row:
+            length = min(buckets[r], max_len) - 5
+        else:
+            length = 11 + (r % 7)
+        # Room for the forced decode steps (+ the slice for slice rows).
+        total = length + n_decode + 1 + (T if slice_row else 0)
+        check(total <= max_len, f"row {r}: {total} tokens > {max_len}")
+        n_pages = -(-total // ps)
+        check(next_page + n_pages <= spec.num_pages,
+              f"schedule needs more than {spec.num_pages} pages")
+        bt = np.zeros(MP, np.int32)
+        bt[:n_pages] = np.arange(next_page, next_page + n_pages)
+        next_page += n_pages
+        rows.append({"length": length, "bt": bt,
+                     "prompt": rng.integers(3, V, length, dtype=np.int32)})
+    return {
+        "rows": rows, "S": S, "T": T, "n_decode": n_decode,
+        "forced": rng.integers(3, V, (n_decode + 1, B), dtype=np.int32),
+        "slices": rng.integers(3, V, (S, T), dtype=np.int32),
+    }
+
+
+def run_schedule(path: Path, ex, sch: Dict) -> Dict[str, Any]:
+    """Run the schedule through one path; returns host float32 logits
+    keyed by step name."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    spec = ex.spec
+    B, MP = spec.batch_size, spec.max_pages_per_seq
+    rows, S, T = sch["rows"], sch["S"], sch["T"]
+    out: Dict[str, Any] = {}
+    t0 = time.perf_counter()
+    for r, row in enumerate(rows):
+        L = row["length"]
+        Tb = next(b for b in sorted(ex.prefill_buckets) if L <= b)
+        toks = np.zeros((1, Tb), np.int32)
+        toks[0, :L] = row["prompt"]
+        pos = np.minimum(np.arange(Tb, dtype=np.int32), L - 1)[None]
+        logits = path.prefill(jnp.asarray(toks), jnp.asarray(pos),
+                              jnp.asarray([L], jnp.int32),
+                              jnp.asarray(row["bt"])[None])
+        out[f"prefill_b{Tb}.row{r}"] = np.asarray(logits, np.float32)
+    bts = jnp.asarray(np.stack([row["bt"] for row in rows]))
+    lens = np.asarray([row["length"] for row in rows], np.int32)
+    all_on = jnp.ones(B, bool)
+    for j in range(sch["n_decode"]):
+        logits = path.decode(jnp.asarray(sch["forced"][j]),
+                             jnp.asarray(lens + j), bts, all_on)
+        out[f"decode.step{j}"] = np.asarray(logits, np.float32)
+    # Mixed step: rows < B-S decode one more forced token; rows >= B-S
+    # continue as T-token prefill slices over their own pages.
+    j = sch["n_decode"]
+    pos_now = lens + j
+    active = np.arange(B) < B - S
+    sl = list(range(B - S, B))
+    pf_pos = np.stack([pos_now[r] + np.arange(T, dtype=np.int32)
+                       for r in sl])
+    pf_bts = jnp.asarray(np.stack([rows[r]["bt"] for r in sl]))
+    if ex.ragged_attention:
+        qblk = ex._ragged_qblk  # noqa: SLF001
+        N = ex._ragged_buf      # noqa: SLF001
+        step = -(-T // qblk) * qblk
+        check(S * step <= N, f"ragged buffer {N} < {S}x{step}")
+        pf_tok = np.zeros(N, np.int32)
+        pf_p = np.zeros(N, np.int32)
+        for i in range(S):
+            pf_tok[i * step:i * step + T] = sch["slices"][i]
+            pf_p[i * step:i * step + T] = pf_pos[i]
+        dec, pf = path.mixed(
+            jnp.asarray(sch["forced"][j]), jnp.asarray(pos_now), bts,
+            jnp.asarray(active), jnp.asarray(pf_tok), jnp.asarray(pf_p),
+            jnp.asarray(np.arange(S, dtype=np.int32) * step),
+            jnp.full((S,), T, jnp.int32), pf_bts)
+    else:
+        dec, pf = path.mixed(
+            jnp.asarray(sch["forced"][j]), jnp.asarray(pos_now), bts,
+            jnp.asarray(active), jnp.asarray(sch["slices"]),
+            jnp.asarray(pf_pos), jnp.full((S,), T, jnp.int32), pf_bts)
+    out["mixed.decode"] = np.asarray(dec, np.float32)[:B - S]
+    out["mixed.slices"] = np.asarray(pf, np.float32)
+    say(f"path {path.name}: schedule ran in "
+        f"{time.perf_counter() - t0:.1f}s")
+    return out
+
+
+def compare(a: Dict, b: Dict, tol: float, label: str) -> Dict:
+    import numpy as np
+
+    worst: Tuple[float, str] = (0.0, "")
+    per_step = {}
+    agree = total = 0
+    for key in a:
+        check(np.isfinite(a[key]).all() and np.isfinite(b[key]).all(),
+              f"{label}: non-finite logits at {key}")
+        check(a[key].shape == b[key].shape,
+              f"{label}: shape {a[key].shape} vs {b[key].shape} at {key}")
+        d = float(np.abs(a[key] - b[key]).max())
+        group = key.split(".")[0]
+        per_step[group] = max(per_step.get(group, 0.0), d)
+        if d > worst[0]:
+            worst = (d, key)
+        x = a[key].reshape(-1, a[key].shape[-1])
+        y = b[key].reshape(-1, b[key].shape[-1])
+        agree += int((x.argmax(-1) == y.argmax(-1)).sum())
+        total += x.shape[0]
+    res = {"max_abs_delta": round(worst[0], 4), "at": worst[1],
+           "tolerance": tol, "argmax_agree": f"{agree}/{total}",
+           "by_program": {k: round(v, 4) for k, v in per_step.items()}}
+    say(f"{label}: {json.dumps(res)}")
+    check(worst[0] <= tol,
+          f"{label}: max |dlogit| {worst[0]:.4f} at {worst[1]} exceeds "
+          f"tolerance {tol}")
+    return res
+
+
+def main(argv: List[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--out", default="")
+    ap.add_argument("--tiny", action="store_true",
+                    help="CPU unit test: keep the configured (tiny) "
+                         "model and skip the TPU-only assertions")
+    args = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmq_tpu.core.config import load_config
+    from llmq_tpu.engine import build_engine
+    from llmq_tpu.observability.device import device_identity
+
+    ident = device_identity()
+    on_tpu = ident["platform"] == "tpu"
+    check(on_tpu or args.tiny,
+          f"kernel phase came up on {ident}, not a TPU")
+    cfg = load_config()
+    cfg.executor.backend = "jax"
+    t0 = time.perf_counter()
+    engine = build_engine(cfg, warmup=True)
+    ex = engine.executor
+    mcfg = ex.model_cfg
+    from importlib import metadata
+
+    def version(dist: str) -> str:
+        try:
+            return metadata.version(dist)
+        except metadata.PackageNotFoundError:
+            return "not installed"
+
+    report: Dict[str, Any] = {
+        "device": ident,
+        "versions": {d: version(d) for d in ("jax", "jaxlib", "libtpu")},
+        "model": mcfg.name, "n_layers": mcfg.n_layers,
+        "geometry": {"batch": ex.spec.batch_size,
+                     "page_size": ex.spec.page_size,
+                     "num_pages": ex.spec.num_pages,
+                     "max_pages_per_seq": ex.spec.max_pages_per_seq,
+                     "prefill_buckets": ex.prefill_buckets,
+                     "chunk": ex.chunk_size,
+                     "mixed": [ex.mixed_prefill_slices,
+                               ex.mixed_slice_tokens],
+                     "ragged": ex.ragged_attention,
+                     "mesh": (dict(ex.mesh.shape) if ex.mesh is not None
+                              else None)},
+        "engine_build_s": round(time.perf_counter() - t0, 1),
+        "hbm_chips": ex.hbm_info(),
+    }
+    quant_w = cfg.model.quantization == "int8"
+    quant_kv = "k_scale" in ex.cache
+    shipped_path = (ex.mesh is None and not quant_kv
+                    and not ex.ragged_attention)
+    def write_report() -> None:
+        if args.out:
+            os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                        exist_ok=True)
+            with open(args.out, "w") as f:
+                json.dump(report, f, indent=1, sort_keys=True)
+
+    report["programs"] = check_program_text(
+        ex, on_tpu, expect_all_pallas=on_tpu and shipped_path)
+    write_report()
+    say(f"program text ok: " + json.dumps(
+        {n: p["mosaic_calls"] for n, p in report["programs"].items()}))
+
+    # (b) teacher-forced logits. Free the engine's pool first; its
+    # params are the serving path's params (same placement).
+    sch = schedule(ex)
+    cache_dtype = jnp.int8 if quant_kv else None
+    geom = dict(num_pages=ex.spec.num_pages, page_size=ex.spec.page_size,
+                ragged=ex.ragged_attention)
+    kv_shd = ex._kv_shardings  # noqa: SLF001
+    serving_params = ex.params
+    engine.stop()
+    ex.cache = None
+    ex._aot.clear()  # noqa: SLF001
+
+    serving = run_schedule(
+        Path("serving", mcfg, serving_params, cache_dtype=cache_dtype,
+             kv_shardings=kv_shd, **geom), ex, sch)
+    results = {}
+    pure_cfg = dataclasses.replace(mcfg, pallas=False)
+    one_dev = jax.devices()[0]
+    pure_params = (serving_params if ex.mesh is None else jax.tree.map(
+        lambda x: jax.device_put(np.asarray(x), one_dev), serving_params))
+    pure = run_schedule(
+        Path("pure_bf16", pure_cfg, pure_params, cache_dtype=cache_dtype,
+             **geom), ex, sch)
+    results["serving_vs_pure"] = compare(
+        serving, pure, TOL_W8A8 if quant_w else TOL, "serving vs pure-JAX")
+    del pure_params
+    n_params = sum(int(x.size) for x in jax.tree.leaves(serving_params))
+    if quant_w or quant_kv or n_params > F32_MAX_PARAMS:
+        # A float32 run of int8 weights would also measure activation
+        # quantization, and 8B float32 does not fit beside the model.
+        results["serving_vs_f32"] = "not run (int8 or too large for f32)"
+        say("float32 reference: " + results["serving_vs_f32"])
+    else:
+        f32_cfg = dataclasses.replace(mcfg, pallas=False,
+                                      dtype=jnp.float32)
+        f32_params = jax.tree.map(
+            lambda x: jax.device_put(np.asarray(x).astype(np.float32),
+                                     one_dev), serving_params)
+        del serving_params
+        ex.params = None
+        # TPU matmuls round float32 operands to bf16 passes unless
+        # asked not to: without this the "float32" run is not one.
+        with jax.default_matmul_precision("float32"):
+            f32 = run_schedule(
+                Path("f32", f32_cfg, f32_params, cache_dtype=jnp.float32,
+                     **geom), ex, sch)
+        results["serving_vs_f32"] = compare(serving, f32, TOL,
+                                            "serving vs float32")
+        results["pure_vs_f32"] = compare(pure, f32, TOL,
+                                         "pure-JAX vs float32")
+    report["logits"] = results
+    report["summary"] = {
+        "model": mcfg.name, "mesh": report["geometry"]["mesh"],
+        "versions": report["versions"],
+        "mosaic_calls": {n: p["mosaic_calls"]
+                         for n, p in report["programs"].items()},
+        "max_abs_delta": {k: (v["max_abs_delta"] if isinstance(v, dict)
+                              else v) for k, v in results.items()}}
+    write_report()
+    say("ok " + json.dumps(report["summary"]))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -251,7 +251,7 @@ def _real_redis():
                     reason="no real redis server/client available")
 class TestRedisStoreReal:
     """The SAME contract as TestRedisStore, against a REAL server
-    (VERDICT r3 #10): exercises actual RESP encoding, server-side TTLs
+   : exercises actual RESP encoding, server-side TTLs
     and set semantics the in-memory double can only approximate."""
 
     @pytest.fixture

@@ -17,7 +17,8 @@ from llmq_tpu.engine import ByteTokenizer, EchoExecutor, InferenceEngine
 from llmq_tpu.engine.engine import GenRequest
 from llmq_tpu.engine.kv_allocator import PageAllocator
 from llmq_tpu.metrics.registry import REGISTRY
-from llmq_tpu.observability.device import (DeviceTelemetry, decode_mfu,
+from llmq_tpu.observability.device import (DeviceTelemetry,
+                                           UnknownDeviceError, decode_mfu,
                                            get_device_telemetry,
                                            measure_rtt, peak_flops)
 from llmq_tpu.observability.slo import (SloTracker, configure_slo,
@@ -47,7 +48,35 @@ class TestSharedMath:
     def test_peak_flops_table(self):
         assert peak_flops("TPU v5e") == 197e12
         assert peak_flops("TPU v5p") == 459e12
-        assert peak_flops("unknown-device") == 197e12  # bench fallback
+        assert peak_flops("TPU v5 lite") == 197e12   # v5e's device_kind
+
+    def test_unknown_device_has_no_peak(self):
+        """A device the table does not list gets NO peak — never
+        v5e's: an error wherever a chip number is produced, no figure
+        in the live telemetry (CPU tests)."""
+        from llmq_tpu.observability.device import (decode_hbm_bw_util,
+                                                   peak_hbm_bandwidth)
+        for kind in ("cpu", "unknown-device", ""):
+            with pytest.raises(UnknownDeviceError):
+                peak_flops(kind)
+            with pytest.raises(UnknownDeviceError):
+                peak_hbm_bandwidth(kind)
+        with pytest.raises(UnknownDeviceError):
+            decode_mfu(1000, 10**9, "cpu")
+        with pytest.raises(UnknownDeviceError):
+            decode_hbm_bw_util(6400, 64, 1, 1, 1, "cpu")
+        t = DeviceTelemetry("unknown-peak", metrics=False)
+        t.configure_model(n_params=10**9, device_kind="cpu",
+                          platform="cpu", device_count=1)
+        t.note_step(0.001, 0.001, 0.001, tokens=64)
+        assert t.tokens_per_s() > 0
+        assert t.mfu() is None
+        snap = t.snapshot()
+        assert snap["mfu_pct"] is None
+        assert snap["model"]["platform"] == "cpu"
+        assert snap["model"]["device_count"] == 1
+        t.configure_model(n_params=10**9, device_kind="TPU v5 lite")
+        assert t.mfu() > 0
 
     def test_int8_doubles_peak(self):
         assert peak_flops("TPU v5e", quant="int8") == 2 * 197e12
@@ -67,7 +96,6 @@ class TestSharedMath:
         from llmq_tpu.observability.device import (decode_hbm_bw_util,
                                                    peak_hbm_bandwidth)
         assert peak_hbm_bandwidth("TPU v5e") == 819e9
-        assert peak_hbm_bandwidth("unknown") == 819e9
         # 64 rows at 6400 tok/s = 100 steps/s; 2 GB weights + 64 rows
         # × 100 KB/token × 512 tokens of live KV per step.
         got = decode_hbm_bw_util(6400, 64, 2 * 10**9, 100_000, 512,

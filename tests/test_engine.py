@@ -237,7 +237,7 @@ class TestConversationKV:
         assert eng.cached_conversations() == ["c1"]  # touch reset the clock
 
     def test_overdue_low_beats_fresh_normal(self):
-        """SLA-aware promotion (VERDICT r3 #9): a LOW request older than
+        """SLA-aware promotion: a LOW request older than
         its tier's max_wait_time is promoted and admitted ahead of a
         NORMAL request that arrived later — without promotion, strict
         (priority, arrival) order would admit the normal first."""

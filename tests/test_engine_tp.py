@@ -1,4 +1,4 @@
-"""Engine-level tensor-parallel serving (VERDICT r3 missing #1).
+"""Engine-level tensor-parallel serving.
 
 The full serving stack — InferenceEngine → JaxExecutor(mesh) → sharded
 model → sampled tokens — on the virtual 8-device CPU mesh: params and

@@ -4,13 +4,17 @@ The full serving stack on the virtual 8-device CPU mesh
 (``XLA_FLAGS=--xla_force_host_platform_device_count=8``, pinned by
 conftest): dp2×tp4 serves the mixed workload — prefill waves, decode,
 prefix continuation, preemption, tiering demote/promote, async
-pipeline depth 2 — token-for-token identical to the single-chip
-engine; the paged pool's page axis genuinely splits into per-replica
+pipeline depth 2 — and every token it commits is held, teacher-forced,
+to the float32 reference's logits within a stated tolerance (sampled
+streams of two bf16 partitionings are NOT compared: on random weights a
+reduction-order tie flips the argmax); the paged pool's page axis
+genuinely splits into per-replica
 universes mirrored by the host allocator; the warmup/export cache is
 keyed on the mesh geometry; and ``executor.mesh.enabled=false`` keeps
 the exact single-chip path.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -27,7 +31,12 @@ from llmq_tpu.engine.engine import GenRequest, InferenceEngine  # noqa: E402
 from llmq_tpu.engine.executor import JaxExecutor  # noqa: E402
 from llmq_tpu.engine.kv_allocator import PageAllocator  # noqa: E402
 from llmq_tpu.engine.tokenizer import ByteTokenizer  # noqa: E402
-from llmq_tpu.models.llama import init_params, llama3_tiny  # noqa: E402
+from llmq_tpu.models.llama import (  # noqa: E402
+    forward_prefill,
+    init_kv_pages,
+    init_params,
+    llama3_tiny,
+)
 from llmq_tpu.parallel import make_mesh  # noqa: E402
 from llmq_tpu.parallel.sharding import (  # noqa: E402
     LLAMA_PARTITION_RULES,
@@ -55,26 +64,74 @@ def tiny(request):
     return cfg, init_params(jax.random.PRNGKey(0), cfg)
 
 
+#: How far below the float32 reference's best logit a committed token's
+#: reference logit may sit. Logits here have std ≈ 1; a bf16 path is
+#: within ~0.03 of float32 per logit (measured: tp4 vs float32 0.021,
+#: single-device bf16 vs float32 0.023), so a near-tie can flip the
+#: argmax by a few hundredths — while a sharding, KV-placement or
+#: resume fault moves logits by O(1) and lands far outside.
+REF_GAP_TOL = 0.125
+
+
 @pytest.fixture(scope="module")
-def wave_reference(tiny):
-    """Single-chip reference run (one engine build for the module):
-    WAVE results plus both conversations' turn 2 — what every mesh
-    geometry must reproduce token-for-token."""
+def f32_reference(tiny):
+    """Teacher-forced float32 ``jax.numpy`` logits of the SAME weights:
+    ``ref(ids)[i]`` is the next-token distribution after ``ids[:i+1]``.
+    One pure-JAX prefill over a private pool, padded to the model's
+    whole context so every call reuses one compiled program."""
+    import jax.numpy as jnp
+
     cfg, params = tiny
-    eng = build_engine(cfg, params, None)
-    wave = run_requests(eng, WAVE)
-    assert all(r.finish_reason in ("eos", "length") for r in wave)
-    t2 = run_requests(eng, [dict(id="a2", prompt=" more",
-                                 conversation_id="c1"),
-                            dict(id="c2t", prompt=" again",
-                                 conversation_id="c2")])
-    out = {"wave": [r.tokens for r in wave],
-           "wave_text": [r.text for r in wave],
-           "turn2_tokens": [r.tokens for r in t2],
-           "turn2_cached": [r.cached_tokens for r in t2],
-           "preempt": run_preemption_phase(eng)}
-    eng.stop()
-    return out
+    cfg32 = dataclasses.replace(cfg, dtype=jnp.float32, pallas=False)
+    p32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+    T, ps = cfg.max_seq_len, 16
+    n_pages = T // ps
+    bt = jnp.arange(1, n_pages + 1, dtype=jnp.int32)[None]
+
+    def ref(ids):
+        assert 0 < len(ids) <= T, len(ids)
+        toks = np.zeros((1, T), np.int32)
+        toks[0, :len(ids)] = ids
+        pos = np.minimum(np.arange(T, dtype=np.int32), len(ids) - 1)[None]
+        logits, _ = forward_prefill(
+            p32, cfg32, jnp.asarray(toks), jnp.asarray(pos),
+            jnp.asarray([len(ids)], jnp.int32),
+            init_kv_pages(cfg32, n_pages + 1, ps), bt)
+        return np.asarray(logits[0, :len(ids)])
+
+    return ref
+
+
+def assert_follows_reference(ref, ctx_ids, gen_ids):
+    """Every committed token is, given the engine's OWN context
+    (teacher forcing), within REF_GAP_TOL of the float32 reference's
+    best next-token logit."""
+    assert gen_ids, "nothing generated"
+    ctx_ids, gen_ids = list(ctx_ids), list(gen_ids)
+    logits = ref(ctx_ids + gen_ids)
+    for j, t in enumerate(gen_ids):
+        row = logits[len(ctx_ids) + j - 1]
+        gap = float(row.max() - row[t])
+        assert gap <= REF_GAP_TOL, (
+            f"token {j} (id {t}): reference logit {float(row[t]):.3f} "
+            f"is {gap:.3f} below the reference best "
+            f"{float(row.max()):.3f} (id {int(row.argmax())})")
+
+
+def conversation_stream(engine, conv_id, result):
+    """(context, generated) of a conversation's latest turn, read from
+    the engine's own record of what it fed the model: the pinned
+    entry's written tokens plus the pending last sample."""
+    entry = engine._conv_cache[conv_id]
+    full = list(entry.tokens)
+    if entry.pending is not None:
+        full.append(entry.pending)
+    gen = list(result.tokens)
+    if full[-len(gen):] != gen:
+        # An EOS finish: the EOS sample is committed but never written.
+        full += gen[-1:]
+    assert full[-len(gen):] == gen, (full[-len(gen) - 2:], gen)
+    return full[:-len(gen)], gen
 
 
 def run_requests(engine, reqs):
@@ -86,18 +143,9 @@ def run_requests(engine, reqs):
 def run_preemption_phase(engine):
     """Deterministic preemption choreography: fill every slot with LOW
     decoders, let them run a step, then land REALTIME arrivals — the
-    late urgents must preempt. Final tokens are timing-independent
-    (slot preemption resumes exactly), so mesh and single-chip engines
-    compare even though their step cadence differs.
-
-    The prompt text matters: comparing DIFFERENT partitionings of the
-    same bf16 math (tp4 vs one chip) is exact only while no argmax
-    lands on a reduction-order near-tie — the same property every
-    mesh-equivalence pin in this repo (test_engine_tp.py included)
-    relies on. This workload is verified tie-free on dp2×tp4/tp4; a
-    flip here after a model change means re-picking prompts, not a
-    sharding bug (dp2×tp4 vs tp4-subset stays EXACTLY equal either
-    way — the dp machinery adds no arithmetic)."""
+    late urgents must preempt. Returns (prompt, tokens) per request:
+    a preempted-and-resumed stream must still follow the reference
+    given its own context (slot preemption resumes exactly)."""
     lows = [engine.submit(GenRequest(
         id=f"L{i}", prompt=f"steady background work {i}",
         priority=Priority.LOW, max_new_tokens=12)) for i in range(4)]
@@ -108,7 +156,7 @@ def run_preemption_phase(engine):
         id=f"R{i}", prompt=f"urgent {i}", priority=Priority.REALTIME,
         max_new_tokens=6)) for i in range(2)]
     engine.run_until_idle()
-    return [h.result.tokens for h in lows + rts]
+    return [(h.request.prompt, h.result.tokens) for h in lows + rts]
 
 
 def wait_until(fn, timeout=5.0, step=0.002):
@@ -284,17 +332,19 @@ def build_engine(cfg, params, mesh=None, *, pipeline=None, mixed=None,
 
 
 class TestMeshServing:
-    def test_dp2tp4_mixed_workload_token_identical(self, tiny,
-                                                   wave_reference):
+    def test_dp2tp4_mixed_workload_follows_reference(self, tiny,
+                                                     f32_reference):
         """The acceptance pin: waves + decode + prefix continuation +
-        preemption + 2-deep async pipeline + mixed batching, dp2×tp4
-        vs the single-chip reference, token-for-token. The mesh engine
-        runs with the pipeline AND mixed batching ON against a plain
-        reference — the whole composition must still be exact."""
+        preemption + 2-deep async pipeline + mixed batching on dp2×tp4,
+        every committed token held teacher-forced to the float32
+        reference (REF_GAP_TOL) — the whole composition, pipeline AND
+        mixed batching ON, must keep every row's KV and sampling
+        right."""
         from llmq_tpu.core.config import (AsyncPipelineConfig,
                                           MixedBatchConfig)
 
         cfg, params = tiny
+        tok = ByteTokenizer()
         mesh = make_mesh({"dp": 2, "tp": 4})
         pipe = AsyncPipelineConfig(enabled=True, depth=2)
         mixed = MixedBatchConfig(enabled=True, prefill_token_budget=32,
@@ -313,32 +363,37 @@ class TestMeshServing:
         assert shard_shape[3] == kv.shape[3] // 4
 
         res_m = run_requests(eng_m, WAVE)
-        for i, r_m in enumerate(res_m):
+        for w, r_m in zip(WAVE, res_m):
             assert r_m.finish_reason in ("eos", "length")
-            assert r_m.tokens == wave_reference["wave"][i]
-            assert r_m.text == wave_reference["wave_text"][i]
+            ctx = tok.encode(w["prompt"])
+            assert r_m.prompt_tokens == len(ctx)
+            assert_follows_reference(f32_reference, ctx, r_m.tokens)
 
         # Prefix continuation over the dp-sharded pool: turn 2 of both
-        # conversations adopts cached KV and still matches.
+        # conversations adopts cached KV and still follows.
         t2 = [dict(id="a2", prompt=" more", conversation_id="c1"),
               dict(id="c2t", prompt=" again", conversation_id="c2")]
         r2_m = run_requests(eng_m, t2)
-        for i, r_m in enumerate(r2_m):
+        for w, r_m in zip(t2, r2_m):
             assert r_m.cached_tokens > 0
-            assert r_m.cached_tokens == wave_reference["turn2_cached"][i]
-            assert r_m.tokens == wave_reference["turn2_tokens"][i]
+            ctx, gen = conversation_stream(eng_m, w["conversation_id"],
+                                           r_m)
+            assert len(ctx) > r_m.cached_tokens   # history + new turn
+            assert_follows_reference(f32_reference, ctx, gen)
 
         # Late-arriving REALTIME over a full batch: preemption REALLY
-        # fires on the mesh engine, and every stream still matches.
+        # fires on the mesh engine, and every stream still follows.
         preempts = []
         orig = eng_m._preempt
         eng_m._preempt = (  # type: ignore[method-assign]
             lambda victim, release_pages: (
                 preempts.append(victim.req.id),
                 orig(victim, release_pages))[-1])
-        toks = run_preemption_phase(eng_m)
+        streams = run_preemption_phase(eng_m)
         assert preempts, "no preemption occurred on the mesh engine"
-        assert toks == wave_reference["preempt"]
+        for prompt, toks in streams:
+            assert_follows_reference(f32_reference, tok.encode(prompt),
+                                     toks)
         eng_m.stop()
 
     def test_dp_page_locality(self, tiny):
@@ -411,19 +466,31 @@ class TestMeshServing:
             eng.stop()
         assert outs[0] == outs[1]
 
-    def test_tp4_subset_mesh_serves(self, tiny, wave_reference):
+    def test_tp4_subset_mesh_serves(self, tiny, f32_reference):
         """tp4 over a 4-device subset of the 8 — the second CI-lane
         geometry: a mesh need not span every visible device. (tp8
-        equivalence incl. continuation is test_engine_tp.py's pin.)"""
+        serving incl. continuation is test_engine_tp.py's pin.)"""
         cfg, params = tiny
+        tok = ByteTokenizer()
         mesh = make_mesh({"tp": 4}, devices=jax.devices()[:4])
         eng_m = build_engine(cfg, params, mesh)
         assert eng_m.executor.dp_shards == 1
         res_m = run_requests(eng_m, WAVE[:2])
-        for r_m, toks in zip(res_m, wave_reference["wave"][:2]):
-            assert r_m.tokens == toks
+        for w, r_m in zip(WAVE[:2], res_m):
+            assert_follows_reference(f32_reference,
+                                     tok.encode(w["prompt"]), r_m.tokens)
         assert len(eng_m.executor.hbm_info()) == 4
         eng_m.stop()
+
+    def test_reference_check_catches_a_wrong_stream(self, f32_reference):
+        """The oracle has teeth: a stream with one token swapped for an
+        arbitrary id is far outside the tolerance."""
+        tok = ByteTokenizer()
+        ctx = tok.encode("hello tensor parallel mesh")
+        good = [int(f32_reference(ctx)[-1].argmax())]
+        assert_follows_reference(f32_reference, ctx, good)
+        with pytest.raises(AssertionError, match="below the reference"):
+            assert_follows_reference(f32_reference, ctx, [good[0] ^ 1])
 
     def test_indivisible_dp_degrades_to_replication(self, tiny):
         """dp that doesn't divide the batch/pool builds with dp as pure
@@ -529,6 +596,41 @@ class TestMeshExportCacheKey:
         # Deterministic per geometry.
         again = self._executor(make_mesh({"dp": 2, "tp": 4}))
         assert again._export_cache_key() == dp2tp4._export_cache_key()
+
+    def test_key_changes_with_param_shardings(self):
+        """An edit to the partition rules changes the key: the params
+        enter with their partition specs, not just shapes — a mesh
+        artifact lowered under the old layout must MISS."""
+        from types import SimpleNamespace
+
+        from llmq_tpu.engine.executor import ExecutorSpec
+
+        cfg = tp_cfg()
+        mesh = make_mesh({"tp": 4}, devices=jax.devices()[:4])
+        avals = jax.eval_shape(
+            lambda: init_params(jax.random.PRNGKey(0), cfg))
+        cache = jax.eval_shape(lambda: init_kv_pages(cfg, 34, 16))
+
+        def key_for(rules):
+            placed = jax.tree.map(
+                lambda a, shd: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=shd),
+                avals, resolve_rules(rules, avals, mesh))
+            stub = SimpleNamespace(
+                model_cfg=cfg, spec=ExecutorSpec(2, 16, 34, 16, 2),
+                chunk_size=2, prefill_batch=1, prefill_buckets=[16],
+                _top_k=0, _top_p=1.0, mixed_prefill_slices=0,
+                mixed_slice_tokens=0, ragged_attention=False,
+                _ragged_buf=0, _ragged_qblk=0, verify_draft_k=0,
+                _spec_device_sampling=True, mesh=mesh, dp_shards=1,
+                params=placed, cache=dict(cache))
+            return JaxExecutor._export_cache_key(stub)
+
+        edited = ([(r"(^|/)wo(/|$)", P(None, None, "tp"))]
+                  + list(LLAMA_PARTITION_RULES))
+        assert key_for(LLAMA_PARTITION_RULES) == key_for(
+            list(LLAMA_PARTITION_RULES))
+        assert key_for(LLAMA_PARTITION_RULES) != key_for(edited)
 
     def test_mesh_keying_end_to_end(self, tmp_path, monkeypatch):
         """One flow over a real export dir: a cache primed single-chip
@@ -790,11 +892,6 @@ _AOT_8B_TP4 = r"""
 import sys
 sys.path.insert(0, {repo!r})
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass
 import jax.numpy as jnp
 from llmq_tpu.models.llama import (forward_decode, get_config,
                                    init_kv_pages, init_params)
@@ -876,8 +973,7 @@ def test_8b_tp4_aot_lowering_and_mesh_cache_key():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     script = _AOT_8B_TP4.format(repo=repo)
     env = {k: v for k, v in os.environ.items()
-           if not k.startswith(("JAX_", "XLA_"))
-           and k not in ("PYTHONPATH", "PYTHONSTARTUP")}
+           if not k.startswith(("JAX_", "XLA_"))}
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     p = subprocess.run([sys.executable, "-c", script], env=env,

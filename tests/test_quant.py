@@ -1,5 +1,5 @@
 """int8 w8a8 quantization: parity against the bf16 model, sharding
-congruence, and footprint math (VERDICT r2 #1 — the path that fits
+congruence, and footprint math (the path that fits
 llama3-8B on a 16 GB chip)."""
 
 import jax

@@ -1,4 +1,4 @@
-"""Multi-engine serving through the LoadBalancer (VERDICT r3 #8).
+"""Multi-engine serving through the LoadBalancer.
 
 End-to-end on the message path the reference never wires (SURVEY §3.5):
 QueueManager → Worker → EngineRouter.process_fn → LoadBalancer
