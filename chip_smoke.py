@@ -463,14 +463,16 @@ def serve_phase(srv: Server, report: Dict, expect_chips: int,
           f"compile_cache_misses_total {misses0} -> {misses1}")
     _, stats = http("GET", srv.base + "/api/v1/engine/stats")
     # Every XLA compilation in the process, eager one-liners included
-    # (the warm-up counters above cannot see those): reported, not
-    # asserted — see PERF.md "Open questions".
-    report["backend_compiles_after_ready"] = (
-        stats["device"]["compile"]["backend_compiles"] - compiles0)
+    # (the warm-up counters above cannot see those; the warm-up runs
+    # the serving loop's eager ops so that none is left for a request).
+    compiles1 = stats["device"]["compile"]["backend_compiles"]
+    check(compiles1 == compiles0,
+          f"{compiles1 - compiles0} XLA compilation(s) after ready: "
+          f"device.compile.backend_compiles {compiles0} -> {compiles1}")
+    report["backend_compiles_after_ready"] = compiles1 - compiles0
     report["sigterm_exit_s"] = round(srv.stop(), 1)
     say(f"clean exit {report['sigterm_exit_s']}s after SIGTERM; "
-        f"{report['backend_compiles_after_ready']} XLA compilation(s) "
-        f"after ready")
+        f"no XLA compilation after ready")
 
 
 def kernel_phase(srv: Server, report: Dict, deadline: float) -> None:

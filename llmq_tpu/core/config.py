@@ -1046,16 +1046,11 @@ class ExecutorConfig:
 @dataclass
 class TPUConfig:
     """Mesh/topology declaration (new scope; BASELINE config #5). The
-    platform is not a setting: ``JAX_PLATFORMS`` chooses it."""
+    platform is not a setting (``JAX_PLATFORMS`` chooses it) and
+    neither is the compile-cache directory
+    (``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``:
+    parallel/mesh.enable_compilation_cache)."""
     mesh_shape: Dict[str, int] = field(default_factory=dict)  # e.g. {"dp": 1, "tp": 8}
-    #: Persistent XLA compilation + export cache directory for
-    #: container deployments that mount a volume
-    #: (deployments/docker-compose.yml). ``JAX_COMPILATION_CACHE_DIR``
-    #: in the environment wins over this; "" (default) →
-    #: ``<checkout>/.jax_cache``. The cache is always on for the jax
-    #: backend (parallel/mesh.enable_compilation_cache): a restart
-    #: deserializes compiled executables instead of recompiling.
-    compilation_cache_dir: str = ""
 
 
 @dataclass

@@ -112,7 +112,7 @@ def build_engine(cfg: Config, *, name: str = "engine0",
         from llmq_tpu.models.checkpoint import import_hf_llama, load_checkpoint
 
         from llmq_tpu.parallel import enable_compilation_cache
-        enable_compilation_cache(cfg.tpu.compilation_cache_dir)
+        enable_compilation_cache()
 
         mcfg = get_config(cfg.model.name, max_seq_len=cfg.model.max_seq_len)
         if cfg.model.vocab_size:

@@ -1846,7 +1846,14 @@ class JaxExecutor:
         the artifacts were smoke-tested when first exported (same code
         identity, enforced by the cache key), and the big-bucket
         executions are what keeps a warm restart from hitting its <60 s
-        target."""
+        target.
+
+        The smoke pass also runs the serving loop's EAGER device ops —
+        the batched-prefill row split and the lane-join scatters — in
+        every array flavour the loop produces, so that nothing compiles
+        after ready (chip_smoke.py asserts a zero
+        ``device.compile.backend_compiles`` delta over its requests):
+        before, the first requests paid 10-13 small XLA compilations."""
         t_warm0 = time.perf_counter()
         self._warmup_parallel()
         # Boot decomposition: split the AOT wall between "artifact"
@@ -1882,7 +1889,12 @@ class JaxExecutor:
                 # One full-size prefill per bucket: lengths prev+1..b
                 # stream a chunk of exactly size-b through the bucket-b
                 # program.
-                self.prefill([1] * min(b, prev + 1), 0, bt[0], 0.0, 0)
+                n = min(b, prev + 1)
+                self.prefill([1] * n, 0, bt[0], 0.0, 0)
+                if self.prefill_batch > 1:
+                    # The admission-wave program of the same bucket,
+                    # and the eager per-row split of its result.
+                    self.prefill_multi_async([([1] * n, 0, bt[0], 0.0)])
                 prev = b
         # Reset pool: warmup wrote garbage KV into page 0 only (block
         # table all-zero), which is never read — nothing to clean.
@@ -1909,6 +1921,19 @@ class JaxExecutor:
         if self.chunk_size > 1:
             self.decode_chunk(zeros_b, zeros_b, zbt, ztemp,
                               np.ones(spec.batch_size, np.int32))
+            # Lane joins: a just-prefilled row's first token enters the
+            # batch device-to-device through eager scatters, and XLA
+            # compiles those once per flavour of the lane arrays —
+            # host-born, then a previous chunk's carry.
+            first = self.prefill_async([1], 0, bt[0], 0.0)
+            join = [(0, first, 0)]
+            h = self.decode_chunk_start(
+                zeros_b, zeros_b, zbt, ztemp,
+                np.ones(spec.batch_size, np.int32), overrides=join)
+            self.decode_chunk_start(
+                None, None, zbt, ztemp,
+                np.ones(spec.batch_size, np.int32), carry=h,
+                overrides=join).fetch()
             # Per-step cost estimate for the engine's tier-aware
             # admission cap: time (1-step, K-step) chunk PAIRS — both
             # pay one host round-trip, so the difference isolates

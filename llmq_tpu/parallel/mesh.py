@@ -52,17 +52,16 @@ DEFAULT_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_compilation_cache(configured_dir: str = "") -> None:
+def enable_compilation_cache() -> None:
     """Turn on JAX's persistent compilation cache — the ONE place a
     cache directory is decided, called by every jax-backend engine
     build (and the bench).
 
-    Precedence: ``JAX_COMPILATION_CACHE_DIR`` in the environment wins —
-    JAX has already taken it, so this sets the thresholds only and no
-    directory in code; else ``configured_dir``
-    (``tpu.compilation_cache_dir``, for container deployments that
-    mount a volume); else :data:`DEFAULT_CACHE_DIR`. The executor's
-    ``jax.export`` artifact cache lives in ``<this dir>/export``.
+    One rule: with ``JAX_COMPILATION_CACHE_DIR`` in the environment JAX
+    has already taken the directory, so this sets the thresholds only
+    and no directory in code; without it, :data:`DEFAULT_CACHE_DIR`.
+    The executor's ``jax.export`` artifact cache lives in
+    ``<this dir>/export``.
 
     Programs are kept whatever they weigh (the small prefill buckets
     still pay full tracing + Mosaic lowering) once they took half a
@@ -70,9 +69,8 @@ def enable_compilation_cache(configured_dir: str = "") -> None:
     (chip_smoke.py asserts it) without filing each eager one-liner.
     Safe to call repeatedly."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        cache_dir = configured_dir or DEFAULT_CACHE_DIR
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     log.info("XLA compilation cache at %s",

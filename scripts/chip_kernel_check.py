@@ -13,13 +13,16 @@ environment the serve phase ran with, so the two agree by construction):
     whose routes are all ``xla`` must show none. On the shipped
     single-chip bf16 path every attention op of ``prefill_b*``,
     ``decode_chunk`` and ``mixed_chunk`` must be a Pallas kernel.
-(b) runs one teacher-forced schedule — a prefill through every bucket,
-    decode steps over the full batch, one mixed step — through up to
-    three paths and compares LOGITS (never sampled streams: random-init
-    weights put top-2 gaps inside bf16 rounding): the serving path, the
-    pure-JAX bf16 path on one device (``LLMQ_PALLAS=0``'s routing) and a
-    float32 ``jax.numpy`` run of the same weights. On a mesh the serving
-    path is the GSPMD-partitioned pure-JAX program.
+(b) runs one teacher-forced schedule — a prefill through every bucket
+    (``last_only=True``, the branch every served prefill program
+    takes), decode steps over the full batch, one mixed step — through
+    up to three paths and compares LOGITS (never sampled streams:
+    random-init weights put top-2 gaps inside bf16 rounding): the
+    serving path, the pure-JAX bf16 path on one device
+    (``LLMQ_PALLAS=0``'s routing) and a float32 ``jax.numpy`` run of the
+    same weights. On a mesh the serving path is the GSPMD-partitioned
+    pure-JAX program. With int8 WEIGHTS the kernels are gated apart
+    from the network: see ``TOL_W8A8_RMS``.
 
 Writes a JSON report to ``--out`` and exits non-zero when a check does
 not hold. ``--tiny`` shrinks the model for the CPU unit test.
@@ -46,12 +49,20 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 TOL = 0.15
 #: int8 WEIGHTS (w8a8) quantize every activation dynamically, so a
 #: bf16-rounding difference in one attention output can move the next
-#: linear's input by a whole int8 step: measured at llama3-8b width
-#: (PR 21) 0.24 / 0.33 / 0.34 kernel vs pure-JAX at 2 / 8 / 32 layers
-#: with int8 weights, against 0.053 / 0.065 at 2 / 8 layers with bf16
-#: weights and the SAME int8-KV kernel — the kernel agrees, the network
-#: is not smooth. Twice the measured value again.
-TOL_W8A8 = 0.7
+#: linear's input by a whole int8 step, and the MAX over 128k logits
+#: is a heavy tail: measured at llama3-8b width (PR 21) max 0.24 / 0.33
+#: kernel vs pure-JAX at 2 / 8 layers (0.34 at 32) while the RMS of
+#: the same deltas is 0.042 / 0.047 on logits of RMS 1.0 — against max
+#: 0.053 / 0.065 with bf16 weights and the SAME int8-KV kernel. A max
+#: bound wide enough for that tail (0.7) sits next to the O(1) shift a
+#: real fault makes, so with int8 weights the check has two parts:
+#: the served w8a8 model is held to an RMS bound (twice the measured;
+#: a routing or layout fault gives RMS ≈ 1.4), its max only reported;
+#: and the KERNELS are gated at ``TOL`` on a model of the same width
+#: and KV dtype with bf16 weights, depth cut to ``KERNEL_GATE_LAYERS``
+#: so it fits beside nothing else (8B bf16 at full depth is 16 GB).
+TOL_W8A8_RMS = 0.1
+KERNEL_GATE_LAYERS = 8
 #: float32 reference weights must fit beside the serving model.
 F32_MAX_PARAMS = 2_000_000_000
 
@@ -134,9 +145,13 @@ class Path:
                       jax.jit(make, out_shardings=kv_shardings)())
 
         def prefill(params, cache, tokens, positions, lengths, bts):
+            # last_only: what every served prefill program runs (the
+            # row gather sits BEFORE the final norm and head), and no
+            # (1, T, 128k) float32 logits to hold.
             logits, cache = forward_prefill(params, cfg, tokens, positions,
-                                            lengths, cache, bts)
-            return logits[0, lengths[0] - 1], cache
+                                            lengths, cache, bts,
+                                            last_only=True)
+            return logits[0], cache
 
         def decode(params, cache, tokens, positions, bts, active):
             return forward_decode(params, cfg, tokens, positions, cache,
@@ -283,18 +298,25 @@ def run_schedule(path: Path, ex, sch: Dict) -> Dict[str, Any]:
     return out
 
 
-def compare(a: Dict, b: Dict, tol: float, label: str) -> Dict:
+def compare(a: Dict, b: Dict, tol: float | None, label: str,
+            rms_tol: float | None = None) -> Dict:
+    """Hold two paths' logits together: max |Δ| within ``tol`` and/or
+    RMS Δ within ``rms_tol`` (``None`` = reported, not gated)."""
     import numpy as np
 
     worst: Tuple[float, str] = (0.0, "")
     per_step = {}
     agree = total = 0
+    sq = n = 0.0
     for key in a:
         check(np.isfinite(a[key]).all() and np.isfinite(b[key]).all(),
               f"{label}: non-finite logits at {key}")
         check(a[key].shape == b[key].shape,
               f"{label}: shape {a[key].shape} vs {b[key].shape} at {key}")
-        d = float(np.abs(a[key] - b[key]).max())
+        delta = a[key] - b[key]
+        d = float(np.abs(delta).max())
+        sq += float(np.square(delta, dtype=np.float64).sum())
+        n += delta.size
         group = key.split(".")[0]
         per_step[group] = max(per_step.get(group, 0.0), d)
         if d > worst[0]:
@@ -303,13 +325,17 @@ def compare(a: Dict, b: Dict, tol: float, label: str) -> Dict:
         y = b[key].reshape(-1, b[key].shape[-1])
         agree += int((x.argmax(-1) == y.argmax(-1)).sum())
         total += x.shape[0]
+    rms = (sq / max(n, 1.0)) ** 0.5
     res = {"max_abs_delta": round(worst[0], 4), "at": worst[1],
-           "tolerance": tol, "argmax_agree": f"{agree}/{total}",
+           "tolerance": tol, "rms_delta": round(rms, 5),
+           "rms_tolerance": rms_tol, "argmax_agree": f"{agree}/{total}",
            "by_program": {k: round(v, 4) for k, v in per_step.items()}}
     say(f"{label}: {json.dumps(res)}")
-    check(worst[0] <= tol,
+    check(tol is None or worst[0] <= tol,
           f"{label}: max |dlogit| {worst[0]:.4f} at {worst[1]} exceeds "
           f"tolerance {tol}")
+    check(rms_tol is None or rms <= rms_tol,
+          f"{label}: RMS dlogit {rms:.4f} exceeds tolerance {rms_tol}")
     return res
 
 
@@ -405,10 +431,36 @@ def main(argv: List[str] | None = None) -> int:
     pure = run_schedule(
         Path("pure_bf16", pure_cfg, pure_params, cache_dtype=cache_dtype,
              **geom), ex, sch)
-    results["serving_vs_pure"] = compare(
-        serving, pure, TOL_W8A8 if quant_w else TOL, "serving vs pure-JAX")
+    if quant_w:
+        results["serving_vs_pure"] = compare(
+            serving, pure, None, "serving vs pure-JAX (w8a8)",
+            rms_tol=TOL_W8A8_RMS)
+    else:
+        results["serving_vs_pure"] = compare(serving, pure, TOL,
+                                             "serving vs pure-JAX")
     del pure_params
     n_params = sum(int(x.size) for x in jax.tree.leaves(serving_params))
+    if quant_w and ex.mesh is None:
+        # The kernel gate proper: same width, KV dtype and geometry,
+        # bf16 weights, depth cut to fit (see TOL_W8A8_RMS).
+        from llmq_tpu.models.llama import init_params
+
+        del serving_params
+        ex.params = None
+        gate_cfg = dataclasses.replace(
+            mcfg, n_layers=min(mcfg.n_layers, KERNEL_GATE_LAYERS))
+        gate_params = init_params(jax.random.PRNGKey(0), gate_cfg)
+        gate = [run_schedule(
+            Path(name, c, gate_params, cache_dtype=cache_dtype, **geom),
+            ex, sch) for name, c in (
+                ("kernels_bf16w", gate_cfg),
+                ("pure_bf16w", dataclasses.replace(gate_cfg,
+                                                   pallas=False)))]
+        results["kernels_vs_pure_bf16_weights"] = dict(
+            compare(gate[0], gate[1], TOL,
+                    "kernels vs pure-JAX (bf16 weights)"),
+            n_layers=gate_cfg.n_layers)
+        del gate_params
     if quant_w or quant_kv or n_params > F32_MAX_PARAMS:
         # A float32 run of int8 weights would also measure activation
         # quantization, and 8B float32 does not fit beside the model.

@@ -44,9 +44,8 @@ class TestCompilationCacheRule:
         from llmq_tpu.parallel.mesh import enable_compilation_cache
 
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-        enable_compilation_cache(str(tmp_path / "configured"))
+        enable_compilation_cache()
         assert "jax_compilation_cache_dir" not in updates
-        assert not (tmp_path / "configured").exists()
         # Thresholds only.
         assert updates["jax_persistent_cache_min_entry_size_bytes"] == 0
         assert "jax_persistent_cache_min_compile_time_secs" in updates
@@ -64,19 +63,10 @@ class TestCompilationCacheRule:
             tmp_path / ".jax_cache")
         assert (tmp_path / ".jax_cache").is_dir()
 
-    def test_configured_dir_yields_only_to_the_env(
-            self, updates, monkeypatch, tmp_path):
-        from llmq_tpu.parallel.mesh import enable_compilation_cache
-
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        enable_compilation_cache(str(tmp_path / "volume"))
-        assert updates["jax_compilation_cache_dir"] == str(
-            tmp_path / "volume")
-
     def test_builder_always_enables_it_on_the_jax_backend(
             self, monkeypatch):
-        """``tpu.compilation_cache_dir`` empty (the default) no longer
-        means "no cache": the jax backend always calls the rule."""
+        """No config key switches the cache on or places it: the jax
+        backend always calls the rule."""
         import llmq_tpu.parallel as parallel
         from llmq_tpu.engine import build_engine
 
@@ -85,17 +75,17 @@ class TestCompilationCacheRule:
         class Stop(Exception):
             pass
 
-        def spy(configured_dir=""):
-            seen.append(configured_dir)
+        def spy():
+            seen.append("called")
             raise Stop
 
         monkeypatch.setattr(parallel, "enable_compilation_cache", spy)
         cfg = default_config()
         cfg.executor.backend = "jax"
-        assert cfg.tpu.compilation_cache_dir == ""
+        assert not hasattr(cfg.tpu, "compilation_cache_dir")
         with pytest.raises(Stop):
             build_engine(cfg)
-        assert seen == [""]
+        assert seen == ["called"]
 
 
 # -- tpu.platform is gone: JAX_PLATFORMS chooses the platform ------------------

@@ -147,6 +147,16 @@ class TestKernelCheckLogic:
         bad = {"decode.step0": np.full((2, 8), np.nan, np.float32)}
         with pytest.raises(kc.CheckFailure, match="non-finite"):
             kc.compare(a, bad, kc.TOL, "nan")
+        # int8 weights: one logit's heavy-tail jump passes (the max is
+        # only reported), a shift of the whole row does not.
+        spike = {"decode.step0": a["decode.step0"].copy()}
+        spike["decode.step0"][0, 0] = 0.3
+        res = kc.compare(a, spike, None, "spike",
+                         rms_tol=kc.TOL_W8A8_RMS)
+        assert res["max_abs_delta"] == pytest.approx(0.3)
+        assert res["rms_delta"] == pytest.approx(0.075)
+        with pytest.raises(kc.CheckFailure, match="RMS"):
+            kc.compare(a, far, None, "shift", rms_tol=kc.TOL_W8A8_RMS)
 
     def test_schedule_fits_the_executor_geometry(self):
         from types import SimpleNamespace

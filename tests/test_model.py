@@ -96,6 +96,31 @@ class TestForward:
             fresh_cache(), tables([1, 2], [3, 4]))
         np.testing.assert_allclose(batched[0, :5], solo[0], rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("lengths", [[5], [3, 8, 1]])
+    def test_last_only_is_the_last_valid_row_of_the_full_logits(
+            self, params, lengths):
+        """``last_only=True`` — the branch every SERVED prefill program
+        takes — gathers each row's last valid token before the final
+        norm and head: same logits as slicing the full (B, T, V) run,
+        same cache, for one row and for ragged rows."""
+        B, T = len(lengths), 8
+        toks = jax.random.randint(jax.random.PRNGKey(7), (B, T), 0,
+                                  CFG.vocab_size)
+        pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+        lens = jnp.asarray(lengths, jnp.int32)
+        bt = tables(*[[1 + 2 * i, 2 + 2 * i] for i in range(B)])
+        full, cache_full = forward_prefill(params, CFG, toks, pos, lens,
+                                           fresh_cache(), bt)
+        last, cache_last = forward_prefill(params, CFG, toks, pos, lens,
+                                           fresh_cache(), bt,
+                                           last_only=True)
+        assert last.shape == (B, CFG.vocab_size)
+        np.testing.assert_allclose(
+            last, full[jnp.arange(B), lens - 1], rtol=1e-5, atol=1e-5)
+        for name in cache_full:
+            np.testing.assert_array_equal(cache_last[name],
+                                          cache_full[name])
+
     def test_conversation_continuation(self, params):
         """Turn 2 prefill over retained pages == one long prefill
         (BASELINE config #3: KV reuse across turns)."""

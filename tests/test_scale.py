@@ -153,8 +153,7 @@ print(f"AOT70B OK {{per_dev_gb:.2f}} GB/chip", flush=True)
 """
 
 
-@pytest.mark.slow      # ~50 s, the costliest tier-1 test (ROADMAP Design 10:
-@pytest.mark.skipif(   # "what needs minutes is slow"); 8B tp4 AOT stays tier-1
+@pytest.mark.skipif(
     os.environ.get("LLMQ_SKIP_MULTIPROC") == "1",
     reason="multi-process test disabled")
 def test_70b_dp2tp8_aot_lowering_compiles():

@@ -56,6 +56,43 @@ class TestWarmup:
         # warmed and an unwarmed executor — compare only the real row.
         assert (out_aot[0] == out_jit[0]).all()
 
+    def test_nothing_compiles_after_warmup(self):
+        """The serving loop's eager device ops — the batched-prefill
+        row split, the lane-join scatters over host-born and carried
+        lane arrays — all ran during warm-up: dispatching them again
+        compiles nothing (chip_smoke.py asserts the same over HTTP).
+        The batch and wave sizes are this test's alone, so no earlier
+        test in the session can have compiled these shapes for it."""
+        from llmq_tpu.observability.device import BACKEND_COMPILES
+
+        cfg = llama3_tiny(max_seq_len=128)
+        ex = JaxExecutor(cfg, init_params(jax.random.PRNGKey(0), cfg),
+                         batch_size=6, page_size=16, num_pages=33,
+                         chunk_size=4, prefill_buckets=[16, 32],
+                         prefill_batch=3, eos_id=-1)
+        BACKEND_COMPILES.watch()
+        ex.warmup()
+        before = BACKEND_COMPILES.count
+
+        B, MP = 6, ex.spec.max_pages_per_seq
+        bt = np.zeros((B, MP), np.int32)
+        bt[:3, 0] = [1, 2, 3]
+        firsts = ex.prefill_multi_async(
+            [([5, 6, 7], 0, bt[0], 0.0), ([8] * 20, 0, bt[1], 0.0)])
+        firsts.append(ex.prefill_async([9, 9], 0, bt[2], 0.0))
+        zeros, temps = np.zeros(B, np.int32), np.zeros(B, np.float32)
+        budgets = np.full(B, 4, np.int32)
+        h = ex.decode_chunk_start(
+            zeros, zeros, bt, temps, budgets,
+            overrides=[(0, firsts[0], 3), (1, firsts[1], 20)])
+        h2 = ex.decode_chunk_start(
+            None, None, bt, temps, budgets, carry=h,
+            overrides=[(2, firsts[2], 2)])
+        assert h2.fetch().shape == (B, 4)
+        ex.mixed_chunk_start(zeros, zeros, bt, temps, budgets,
+                             [(3, [1, 2, 3], 0, bt[3], 0.0)]).fetch()
+        assert BACKEND_COMPILES.count == before
+
     def test_warmup_on_mesh(self):
         """AOT specs carry the arrays' shardings — the mesh path must
         compile and serve through the executables too."""
