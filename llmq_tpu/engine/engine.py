@@ -65,7 +65,7 @@ from llmq_tpu.observability.usage import (DEFAULT_TENANT, RequestUsage,
                                           sanitize_tenant)
 from llmq_tpu.tenancy import get_tenant_registry, weighted_token_caps
 from llmq_tpu.utils.logging import get_logger
-from llmq_tpu.utils.profiling import SpanRecorder
+from llmq_tpu.utils.profiling import SpanRecorder, capture_held
 
 log = get_logger("engine")
 
@@ -434,6 +434,31 @@ class _CompletionPool:
             t.join(timeout=5.0)
 
 
+class _EngineMetrics:
+    """The engine's Prometheus children, each bound once.
+    ``family.labels(engine, ...)`` takes a lock, builds a tuple and
+    looks a dict up on every call (``metrics.py:labels`` stood among
+    the idle gaps of PR 22's traces), and a chunk touches a dozen of
+    them. ``m("decode_steps")`` / ``m("preemptions", tier)`` returns
+    the child, made at its first use — so a family this engine never
+    touches gains no series."""
+
+    __slots__ = ("_families", "_engine", "_kids")
+
+    def __init__(self, families, engine: str) -> None:
+        self._families = families
+        self._engine = engine
+        self._kids: Dict = {}
+
+    def __call__(self, family: str, *labels: str):
+        key = (family, labels) if labels else family
+        kid = self._kids.get(key)
+        if kid is None:
+            kid = self._kids[key] = getattr(
+                self._families, family).labels(self._engine, *labels)
+        return kid
+
+
 @dataclass
 class _ConvKV:
     """A conversation's KV kept resident in HBM between turns."""
@@ -489,7 +514,12 @@ class InferenceEngine:
         #: (deadline-aware admission; starvation bound for low tiers).
         self.tier_max_wait = dict(tier_max_wait or {})
         self._metrics = get_metrics() if enable_metrics else None
+        self._m = (_EngineMetrics(self._metrics, name)
+                   if self._metrics else None)
         # Per-engine recorder: stats must not mix spans across engines.
+        # The one span primitive (utils/profiling.py): the step's
+        # vocabulary below lands in this ring and, while a profiler
+        # capture is held, on the device trace's clock.
         self._prof = SpanRecorder()
         #: Device telemetry plane (observability/device.py): step-time
         #: decomposition, live tok/s + MFU, HBM accounting — shared by
@@ -672,7 +702,18 @@ class InferenceEngine:
         #: handle under _mu would invert the order). Engine-thread
         #: only; flushed right after the lock drops.
         self._pending_tier_notes: List = []
+        #: DISPATCHES of decode-capable programs (chunks, mixed
+        #: chunks, verify windows) — ``get_stats()["decode_steps"]``
+        #: and ``llm_queue_decode_steps_total``; a chunk runs up to
+        #: ``chunk_size`` device steps, counted in ``device_steps``.
         self.steps = 0
+        #: Counted at the dispatch (``_note_dispatch``): device steps
+        #: dispatched (a chunk's longest row budget), row-steps (the sum
+        #: of its row budgets: tokens it commits unless a row meets EOS
+        #: first), and preemptions by what the victim lost.
+        self.device_steps = 0
+        self.row_steps = 0
+        self.preemptions = {"slot": 0, "release": 0}
         #: Device stall accounting (bench satellite: BENCH rate
         #: points carry these as deltas so a poisoned latency point is
         #: attributable): a "stall" is a device transfer that exceeded
@@ -1220,6 +1261,31 @@ class InferenceEngine:
         # BaseException that sails past it and KILLS the engine thread
         # — the supervisor's restart path is the handler under test.
         chaos.fault("engine.step", engine=self.name)
+        if not self._has_step_work():
+            # Nothing submitted, pending, seated or in flight: the
+            # round is housekeeping, and a step that did no work opens
+            # no span.
+            self._expire_pins()
+            self._set_gauges()
+            return False
+        with self._prof.span("engine.step"):
+            return self._step()
+
+    def _has_step_work(self) -> bool:
+        with self._mu:
+            if self._inbox:
+                return True
+        if self._pending or self._inflight:
+            return True
+        return any(s is not None for s in self._slots)
+
+    def _step(self) -> bool:
+        """A round with work in it, under ``engine.step``. Each phase
+        opens its span of the fixed vocabulary (docs/observability.md
+        "The engine step") where it has something to do: ``ingest``,
+        ``admit``, ``prefill_advance``, ``fill``, ``resolve``,
+        ``reconcile`` (``fetch`` + ``commit``), ``assemble`` with
+        ``dispatch`` inside."""
         self._ingest()
         self._expire_pins()
         # Everything BEFORE the reconcile overlaps the in-flight chunk's
@@ -1244,17 +1310,15 @@ class InferenceEngine:
             # flight (depth 2 = the classic double buffer and the
             # pre-pipeline scheduling: at most ONE speculative dispatch
             # per step, since one chunk is always reconciled below).
-            while (len(self._inflight) < self._pipe_depth
-                   and not self._has_scheduling_work()
-                   and not self._geometry_changed(self._inflight[-1])
-                   and not self._mixed_work_waiting()):
-                # Mixed batching: pending prefill slices must ride the
-                # next host-assembled MIXED chunk — a speculative
-                # decode-only chunk would push them out a full cycle.
-                nxt = self._dispatch_speculative(self._inflight[-1])
-                if nxt is None:
-                    break
-                self._inflight.append(nxt)
+            if self._can_fill():
+                with self._prof.span("engine.fill"):
+                    while True:
+                        nxt = self._dispatch_speculative(self._inflight[-1])
+                        if nxt is None:
+                            break
+                        self._inflight.append(nxt)
+                        if not self._can_fill():
+                            break
             # Resolve AFTER dispatch, BEFORE processing: join rows'
             # first tokens must commit before any of their chunk rows
             # do (the chunk being processed may contain join rows from
@@ -1265,7 +1329,8 @@ class InferenceEngine:
             # _process_chunk consult ``self._inflight`` to defer
             # preemption/shedding, and its rows are still untouchable.
             infl = self._inflight[0]
-            self._process_chunk(infl)
+            with self._prof.span("engine.reconcile"):
+                self._process_chunk(infl)
             self._inflight.popleft()
             if not self._inflight:
                 # Reconciled: re-run admission NOW, when preemption and
@@ -1283,10 +1348,7 @@ class InferenceEngine:
                 # Then assemble the next chunk fresh from the
                 # just-reconciled state — fused with budgeted prefill
                 # slices when mixed batching has both kinds of work.
-                if self._mixed_applicable():
-                    self._mixed_once()
-                else:
-                    self._decode_once()
+                self._assemble()
             self._set_gauges()
             return True
         # No chunk in flight: DISPATCH before resolving — a final
@@ -1296,12 +1358,34 @@ class InferenceEngine:
         # the join). Sync executors never produce first_handles, so
         # the join-commit ordering (first token at resolve, rows at
         # the next reconcile) is preserved on every path.
-        if self._mixed_applicable():
-            stepped = self._mixed_once()
-        else:
-            stepped = self._decode_once()
+        stepped = self._assemble()
         resolved = self._resolve_prefills()
         return resolved or admitted or prefilled or stepped
+
+    def _can_fill(self) -> bool:
+        """The pipeline has room and nothing needs the host first: the
+        next chunk may be dispatched from the newest one's
+        device-carried end state. Mixed batching: pending prefill
+        slices must ride the next host-assembled MIXED chunk — a
+        speculative decode-only chunk would push them out a full
+        cycle."""
+        return (len(self._inflight) < self._pipe_depth
+                and not self._has_scheduling_work()
+                and not self._geometry_changed(self._inflight[-1])
+                and not self._mixed_work_waiting())
+
+    def _assemble(self) -> bool:
+        """Host assembly of the next chunk from reconciled state
+        (``engine.assemble``: eligibility, ``_budget_chunk_rows``, the
+        staging buffers) and its dispatch (``engine.dispatch`` inside,
+        the executor call alone). Nothing seated: no span."""
+        if not any(s is not None for s in self._slots):
+            self._set_gauges()
+            return False
+        with self._prof.span("engine.assemble"):
+            if self._mixed_applicable():
+                return self._mixed_once()
+            return self._decode_once()
 
     def run_until_idle(self, max_steps: int = 100000) -> None:
         for _ in range(max_steps):
@@ -1325,12 +1409,15 @@ class InferenceEngine:
     def _ingest(self) -> None:
         with self._mu:
             newly, self._inbox = self._inbox, []
-        now = self._clock.now()
-        for seq in newly:
-            seq.arrival = now
-            heapq.heappush(self._pending,
-                           (seq.eff_prio, seq.order, seq))
-        self._promote_overdue()
+        if not newly and not (self._pending and self.tier_max_wait):
+            return              # no arrival, nobody to promote
+        with self._prof.span("engine.ingest", arrivals=len(newly)):
+            now = self._clock.now()
+            for seq in newly:
+                seq.arrival = now
+                heapq.heappush(self._pending,
+                               (seq.eff_prio, seq.order, seq))
+            self._promote_overdue()
 
     def _promote_overdue(self) -> None:
         """SLA-aware tier promotion: a pending request that has waited
@@ -1403,6 +1490,12 @@ class InferenceEngine:
         return worst
 
     def _admit(self) -> bool:
+        if not self._pending:
+            return False
+        with self._prof.span("engine.admit", pending=len(self._pending)):
+            return self._admit_pending()
+
+    def _admit_pending(self) -> bool:
         admitted = False
         #: Entries popped because their conversation's previous turn is
         #: still live — re-queued after the loop. SKIPPED, not a
@@ -1494,13 +1587,14 @@ class InferenceEngine:
         self._slots[victim.slot] = None
         self.executor.release_slot(victim.slot)
         victim.slot = None
+        self.preemptions["release" if release_pages else "slot"] += 1
+        victim.handle.marks.setdefault("preempted", time.perf_counter())
         if release_pages:
             self._release_sequence_pages(victim)
         heapq.heappush(self._pending,
                        (victim.eff_prio, victim.order, victim))
         if self._metrics:
-            self._metrics.preemptions.labels(
-                self.name, victim.req.priority.tier_name).inc()
+            self._m("preemptions", victim.req.priority.tier_name).inc()
         # Engine-thread logs carry the request identity via explicit
         # fields (the contextvar binding lives on worker/API threads).
         log.info("preempted %s (%s)%s", victim.req.id,
@@ -1603,6 +1697,7 @@ class InferenceEngine:
                 worst = seq
         if worst is None or worst.sort_key() <= requester.sort_key():
             return False
+        worst.handle.marks.setdefault("preempted", time.perf_counter())
         self._release_sequence_pages(worst, waste_reason="shed")
         log.info("reclaimed pages of pending %s for %s",
                  worst.req.id, requester.req.id,
@@ -1981,13 +2076,11 @@ class InferenceEngine:
                 else:
                     self.prefix_misses += 1
                 if self._metrics:
-                    fam = (self._metrics.prefix_cache_hits
-                           if seq.cached_len > 0
-                           else self._metrics.prefix_cache_misses)
-                    fam.labels(self.name).inc()
+                    self._m("prefix_cache_hits" if seq.cached_len > 0
+                            else "prefix_cache_misses").inc()
                     if seq.cached_len > 0:
-                        self._metrics.cached_prefill_tokens.labels(
-                            self.name).inc(seq.cached_len)
+                        self._m("cached_prefill_tokens").inc(
+                            seq.cached_len)
             seq.slot = slot
             self._slots[slot] = seq        # slot held; prefilled=False
             seq.handle.marks.setdefault("admitted", time.perf_counter())
@@ -2034,6 +2127,14 @@ class InferenceEngine:
             # the decode program (budget-bounded) instead of dedicated
             # bucket programs that would stall it for the whole bucket.
             return reaped
+        with self._prof.span("engine.prefill_advance", seqs=len(cands)):
+            self._dispatch_prefill_buckets(cands, decode_active)
+        return True
+
+    def _dispatch_prefill_buckets(self, cands, decode_active: bool) -> None:
+        """``_advance_prefill``'s dispatch half: one bucket-chunk for
+        each of ``cands`` (the single most urgent one on a sync
+        executor), each program launch an ``engine.dispatch``."""
         buckets = getattr(self.executor, "prefill_buckets", None)
         t_dispatch0 = time.perf_counter()
         prefill_async = getattr(self.executor, "prefill_async", None)
@@ -2073,29 +2174,26 @@ class InferenceEngine:
                 grp = work[i0:i0 + npf]
                 if len(grp) == 1 and prefill_async is not None:
                     seq, chunk = grp[0]
-                    with self._prof.span("engine.prefill",
-                                         tokens=len(chunk)):
+                    with self._prefill_dispatch("prefill", [chunk]):
                         handles[i0] = prefill_async(
                             chunk, seq.todo_pos, seq.block_table,
                             seq.req.temperature)
                     continue
-                with self._prof.span("engine.prefill_multi",
-                                     seqs=len(grp),
-                                     tokens=sum(len(c) for _, c in grp)):
+                with self._prefill_dispatch("prefill_multi",
+                                            [c for _, c in grp]):
                     hs = prefill_multi(
                         [(chunk, seq.todo_pos, seq.block_table,
                           seq.req.temperature) for seq, chunk in grp])
                 handles[i0:i0 + len(grp)] = hs
         elif prefill_async is not None:
             for i, (seq, chunk) in enumerate(work):
-                with self._prof.span("engine.prefill",
-                                     tokens=len(chunk)):
+                with self._prefill_dispatch("prefill", [chunk]):
                     handles[i] = prefill_async(chunk, seq.todo_pos,
                                                seq.block_table,
                                                seq.req.temperature)
         else:
             seq, chunk = work[0]
-            with self._prof.span("engine.prefill", tokens=len(chunk)):
+            with self._prefill_dispatch("prefill", [chunk]):
                 first = self.executor.prefill(chunk, seq.todo_pos,
                                               seq.block_table,
                                               seq.req.temperature,
@@ -2118,7 +2216,6 @@ class InferenceEngine:
             else:
                 self._complete_prefill(seq, first)
                 self._flush_emits(seq)
-        return True
 
     def _resolve_prefills(self) -> bool:
         """Fetch the first tokens of async prefills dispatched on earlier
@@ -2135,27 +2232,28 @@ class InferenceEngine:
             fetch = lambda: gather(handles)              # noqa: E731
         else:
             fetch = lambda: [int(np.asarray(h)) for h in handles]  # noqa: E731
-        with self._prof.span("engine.resolve_fetch", n=len(pending)):
+        with self._prof.span("engine.resolve", seqs=len(pending)):
             # Offload the blocking transfer so arrivals keep being
             # admitted during the wait (same pattern as chunk fetches
             # — without this, resolve waits of ~chunk+RTT showed up as
             # 170-240 ms realtime queue_ms tails).
             box = self._offload_fetch(fetch, lane="resolve")
             self._service_while(box["ev"])
-        if box["err"] is not None:
-            raise box["err"]
-        vals = box["out"]
-        for seq, first, h in zip(pending, vals, handles):
-            if seq.first_handle is not h or seq.slot is None:
-                # Shed, cancelled, or re-admitted during the servicing
-                # wait (page-release preemption nulls first_handle and
-                # requeues the sequence): the fetched sample belongs to
-                # a prefill whose pages are gone — drop it; the rebuild
-                # path re-prefills and re-samples at the same position.
-                continue
-            seq.first_handle = None
-            self._complete_prefill(seq, int(first))
-            self._flush_emits(seq)   # first token must not wait a chunk
+            if box["err"] is not None:
+                raise box["err"]
+            vals = box["out"]
+            for seq, first, h in zip(pending, vals, handles):
+                if seq.first_handle is not h or seq.slot is None:
+                    # Shed, cancelled, or re-admitted during the
+                    # servicing wait (page-release preemption nulls
+                    # first_handle and requeues the sequence): the
+                    # fetched sample belongs to a prefill whose pages
+                    # are gone — drop it; the rebuild path re-prefills
+                    # and re-samples at the same position.
+                    continue
+                seq.first_handle = None
+                self._complete_prefill(seq, int(first))
+                self._flush_emits(seq)   # first token: no chunk's wait
         return True
 
     def _note_prefill_dispatch(self, tokens: int, host_seconds: float,
@@ -2180,9 +2278,8 @@ class InferenceEngine:
         self.prefill_stall_events += 1
         self.prefill_stall_ms_total += est_ms
         if self._metrics:
-            self._metrics.prefill_stall_ms.labels(
-                self.name, "mixed" if fused else "program").observe(
-                    est_ms)
+            self._m("prefill_stall_ms",
+                    "mixed" if fused else "program").observe(est_ms)
 
     def _observe_prefill_rate(self, seq: _Sequence) -> None:
         """Feed the learned prefill-rate EWMA (and the registered
@@ -2401,6 +2498,73 @@ class InferenceEngine:
                 return True
         return False
 
+    # -- counts at the dispatch (docs/observability.md "The engine step") -----
+
+    def _live_kv(self) -> "tuple[int, int]":
+        """(pages, tokens written in them) of the sequences that own a
+        row or wait with pages — KV reserved against KV in use. A walk
+        over rows and the pending heap: only while a capture is held."""
+        pages = tokens = 0
+        for s in self._slots:
+            if s is not None:
+                pages += len(s.pages)
+                tokens += s.pos
+        for _, _, s in self._pending:
+            if s.pages:
+                pages += len(s.pages)
+                tokens += s.pos
+        return pages, tokens
+
+    def _dispatch_span(self, entry: str, *, steps: int = 0, rows: int = 0,
+                       row_steps: int = 0, context_tokens: int = 0,
+                       prefill_tokens: int = 0, longest: int = 0,
+                       chunk: bool = True):
+        """``engine.dispatch``: the span around ONE executor call and
+        nothing else, with the counts taken where the work is handed
+        over. ``program`` is the executor's name for what runs (its
+        ``_aot`` key, ``jit_<program>`` on the device trace); ``steps``
+        the device steps dispatched (the longest row budget; 0 for a
+        dedicated prefill program), ``rows`` the rows with a budget,
+        ``row_steps`` the sum of the budgets, ``inflight`` the chunks
+        in flight once this one is, ``context_tokens`` the tokens the
+        rows attend to at the first step (host bookkeeping: a
+        speculative dispatch counts each unreconciled chunk's full
+        budget), ``prefill_tokens`` the prompt tokens riding along.
+        ``pages_live`` / ``tokens_live`` (``_live_kv``) only while a
+        capture is held. The same quantities accumulate for
+        ``get_stats()``."""
+        self.device_steps += steps
+        self.row_steps += row_steps
+        name_fn = getattr(self.executor, "program_name", None)
+        counts = {
+            "program": (entry if name_fn is None
+                        else name_fn(entry, longest)),
+            "steps": steps, "rows": rows, "row_steps": row_steps,
+            "inflight": len(self._inflight) + (1 if chunk else 0),
+            "context_tokens": context_tokens,
+            "prefill_tokens": prefill_tokens}
+        if capture_held():
+            counts["pages_live"], counts["tokens_live"] = self._live_kv()
+        return self._prof.span("engine.dispatch", **counts)
+
+    def _chunk_dispatch(self, entry: str, budgets: np.ndarray,
+                        context_tokens: int, prefill_tokens: int = 0):
+        """``_dispatch_span`` for a chunk whose row budgets are the
+        (B,) array handed to the device."""
+        return self._dispatch_span(
+            entry, steps=int(budgets.max()),
+            rows=int(np.count_nonzero(budgets)),
+            row_steps=int(budgets.sum()), context_tokens=context_tokens,
+            prefill_tokens=prefill_tokens)
+
+    def _prefill_dispatch(self, entry: str, chunks):
+        """``_dispatch_span`` for a dedicated prefill program over
+        ``chunks`` (one prompt chunk a row)."""
+        return self._dispatch_span(
+            entry, rows=len(chunks),
+            prefill_tokens=sum(len(c) for c in chunks),
+            longest=max(len(c) for c in chunks), chunk=False)
+
     def _dispatch_speculative(
             self, infl: _InflightChunk) -> Optional[_InflightChunk]:
         """Dispatch the next chunk from the in-flight chunk's
@@ -2433,6 +2597,7 @@ class InferenceEngine:
         chunk = min(chunk, self._admission_cap())
         capacity = self.spec.max_pages_per_seq * self.spec.page_size
         plan = []   # (seq, slot, budget, pages_needed)
+        ctx = 0     # context tokens the rows attend to (upper bound)
         for slot in range(B):
             seq = infl.seqs[slot]
             if seq is None or seq.slot != slot or not seq.prefilled:
@@ -2453,6 +2618,7 @@ class InferenceEngine:
             need = PageAllocator.pages_for(
                 pos_upper + b, self.spec.page_size) - len(seq.pages)
             plan.append((seq, slot, b, max(0, need)))
+            ctx += pos_upper
         # Joining rows: same eligibility as _decode_once's join path
         # (final prefill dispatched, not a rebuild/resume), minus rows
         # already snapshotted into ANY in-flight chunk.
@@ -2471,6 +2637,7 @@ class InferenceEngine:
             need = PageAllocator.pages_for(
                 seq.pos + b, self.spec.page_size) - len(seq.pages)
             join_plan.append((seq, slot, b, max(0, need)))
+            ctx += seq.pos
         if not plan and not join_plan:
             return None
         # Speculative growth must not shed: every universe the plan
@@ -2505,9 +2672,7 @@ class InferenceEngine:
         seqs = list(infl.seqs)
         for seq, slot, _, _ in join_plan:
             seqs[slot] = seq
-        with self._prof.span("engine.decode_chunk", active=len(plan),
-                             chunk=chunk, speculative=1,
-                             joined=len(join_plan)):
+        with self._chunk_dispatch("decode_chunk", budgets, ctx):
             handle = self.executor.decode_chunk_start(
                 None, None, block_tables, temps, budgets,
                 carry=infl.handle, overrides=overrides)
@@ -2518,7 +2683,7 @@ class InferenceEngine:
         self._note_dispatch_depth(len(self._inflight) + 1)
         # (caller appends the chunk after return)
         if self._metrics:
-            self._metrics.decode_steps.labels(self.name).inc()
+            self._m("decode_steps").inc()
         infl_next = _InflightChunk(handle, seqs, budgets,
                                    dispatch_s=dispatch_s,
                                    dispatched_at=now)
@@ -2663,14 +2828,21 @@ class InferenceEngine:
             cb = handle._on_token
             if cb is None:
                 return
-            for t in toks:
-                try:
-                    cb(t)
-                except Exception:  # noqa: BLE001 — broken stream consumer
-                    log.exception("on_token callback failed; detaching",
-                                  extra={"fields": {"request_id": req_id}})
-                    handle._on_token = None
-                    return
+            with self._prof.span("engine.deliver", tokens=len(toks)):
+                # The last leg of TTFT: ``first_token`` was stamped on
+                # the engine thread at commit; this is the instant the
+                # token is handed to its consumer.
+                handle.marks.setdefault("first_token_out",
+                                        time.perf_counter())
+                for t in toks:
+                    try:
+                        cb(t)
+                    except Exception:  # noqa: BLE001 — broken consumer
+                        log.exception(
+                            "on_token callback failed; detaching",
+                            extra={"fields": {"request_id": req_id}})
+                        handle._on_token = None
+                        return
 
         self._completion_pool().submit(req_id, emit)
 
@@ -2681,19 +2853,20 @@ class InferenceEngine:
         talks to the request, nothing that touches engine state. Runs
         AFTER the sequence's last token batch (same request key, FIFO
         worker), so streams always see tokens, then done."""
-        try:
-            self._record_trace(seq, reason)
-        except Exception:  # noqa: BLE001 — tracing must not block delivery
-            log.exception("trace record failed for %s", seq.req.id)
-        res = GenResult(
-            text=self.tokenizer.decode(seq.generated),
-            tokens=list(seq.generated),
-            prompt_tokens=len(seq.prompt_ids),
-            cached_tokens=seq.cached_len,
-            finish_reason=reason,
-            error=error,
-            kv_tier=seq.served_tier)
-        seq.handle._finish(res)
+        with self._prof.span("engine.deliver", finish=reason):
+            try:
+                self._record_trace(seq, reason)
+            except Exception:  # noqa: BLE001 — tracing must not block delivery
+                log.exception("trace record failed for %s", seq.req.id)
+            res = GenResult(
+                text=self.tokenizer.decode(seq.generated),
+                tokens=list(seq.generated),
+                prompt_tokens=len(seq.prompt_ids),
+                cached_tokens=seq.cached_len,
+                finish_reason=reason,
+                error=error,
+                kv_tier=seq.served_tier)
+            seq.handle._finish(res)
 
     # -- usage attribution (observability/usage.py) ---------------------------
 
@@ -2776,7 +2949,7 @@ class InferenceEngine:
         box = infl.fetch_box
         if box is None:
             t0 = time.perf_counter()
-            with self._prof.span("engine.chunk_fetch"):
+            with self._prof.span("engine.fetch"):
                 out, device_s, readback_s, overlapped_s = \
                     self._telemetry.timed_fetch(
                         infl.handle, dispatched_at=infl.dispatched_at)
@@ -2787,11 +2960,21 @@ class InferenceEngine:
                 self.stall_events += 1
                 self.stall_ms_total += dt * 1e3
         else:
-            with self._prof.span("engine.chunk_fetch"):
+            with self._prof.span("engine.fetch"):
                 self._service_while(box["ev"])
             if box["err"] is not None:
                 raise box["err"]
             out, device_s, readback_s, overlapped_s = box["out"]
+        with self._prof.span("engine.commit"):
+            self._commit_chunk(infl, out, device_s, readback_s,
+                               overlapped_s)
+
+    def _commit_chunk(self, infl: _InflightChunk, out, device_s: float,
+                      readback_s: float, overlapped_s: float) -> None:
+        """``_process_chunk`` once the tokens are on the host
+        (``engine.commit``): attribution, the rows' commits, the
+        flushes to the completion pool, a mixed chunk's finished
+        prefills."""
         pf_first = None
         if infl.pf is not None:
             out, pf_first = out      # mixed chunk: (decode, slice firsts)
@@ -2967,6 +3150,7 @@ class InferenceEngine:
         temps = st.take("chunk.temp", (B,), np.float32)
         budgets = np.zeros(B, np.int32)   # read again at process time
         overrides = []
+        ctx = 0
         for seq in active + joining:
             i = seq.slot
             # Joining rows' input token is a device scalar (their
@@ -2976,15 +3160,14 @@ class InferenceEngine:
             else:
                 overrides.append((i, seq.first_handle, seq.pos))
             positions[i] = seq.pos
+            ctx += seq.pos
             block_tables[i] = seq.block_table
             temps[i] = seq.req.temperature
             budgets[i] = budgets_by_order.get(seq.order, 1)
         if start_fn is not None:
             # Pipelined: dispatch only — tokens are fetched on the NEXT
             # step (possibly after the next chunk is already running).
-            with self._prof.span("engine.decode_dispatch",
-                                 active=len(active), chunk=chunk,
-                                 joined=len(joining)):
+            with self._chunk_dispatch("decode_chunk", budgets, ctx):
                 handle = start_fn(tokens, positions, block_tables, temps,
                                   budgets, overrides=overrides)
             now = time.perf_counter()
@@ -3001,16 +3184,18 @@ class InferenceEngine:
             self._start_fetch(infl)
             self.steps += 1
             if self._metrics:
-                self._metrics.decode_steps.labels(self.name).inc()
+                self._m("decode_steps").inc()
             return True
         t_call = time.perf_counter()
-        with self._prof.span("engine.decode_chunk",
-                             active=len(active), chunk=chunk):
-            if chunk > 1 and hasattr(self.executor, "decode_chunk"):
+        if chunk > 1 and hasattr(self.executor, "decode_chunk"):
+            with self._chunk_dispatch("decode_chunk", budgets, ctx):
                 out = self.executor.decode_chunk(tokens, positions,
                                                  block_tables, temps,
                                                  budgets)
-            else:
+        else:
+            with self._dispatch_span("decode", steps=1, rows=len(active),
+                                     row_steps=len(active),
+                                     context_tokens=ctx):
                 out = self.executor.decode(tokens, positions, block_tables,
                                            temps)[:, None]
         t_done = time.perf_counter()
@@ -3018,7 +3203,7 @@ class InferenceEngine:
         t_rb = time.perf_counter()
         self.steps += 1
         if self._metrics:
-            self._metrics.decode_steps.labels(self.name).inc()
+            self._m("decode_steps").inc()
         if self._usage.enabled or self._cp.enabled:
             parts = [(seq, max(1, int(budgets[seq.slot])), False)
                      for seq in active if seq.slot is not None]
@@ -3088,8 +3273,10 @@ class InferenceEngine:
         temps = st.take("spec.temp", (B,), np.float32)
         drafts = st.take("spec.draft", (B, K), np.int32)
         qlens = np.zeros(B, np.int32)   # read again at process time
+        ctx = 0
         for seq in active:
             i = seq.slot
+            ctx += seq.pos
             budget = budgets_by_order[seq.order]
             # Context = the committed stream: tokens whose KV is
             # written plus the pending last sample (next decode input).
@@ -3110,9 +3297,7 @@ class InferenceEngine:
         if start_fn is not None:
             # Pipelined: dispatch only — (out, n_commit) are fetched on
             # the NEXT step; the fetch overlaps arrival servicing.
-            with self._prof.span("engine.verify_dispatch",
-                                 active=len(active),
-                                 chunk=int(qlens.max())):
+            with self._verify_dispatch(qlens, ctx):
                 handle = start_fn(tokens, positions, block_tables, temps,
                                   drafts, qlens)
             now = time.perf_counter()
@@ -3129,11 +3314,10 @@ class InferenceEngine:
             self._start_fetch(infl)
             self.steps += 1
             if self._metrics:
-                self._metrics.decode_steps.labels(self.name).inc()
+                self._m("decode_steps").inc()
             return True
         t_call = time.perf_counter()
-        with self._prof.span("engine.verify_chunk", active=len(active),
-                             chunk=int(qlens.max())):
+        with self._verify_dispatch(qlens, ctx):
             out, ncommit = self.executor.verify_chunk(
                 tokens, positions, block_tables, temps, drafts, qlens)
         t_done = time.perf_counter()
@@ -3142,7 +3326,7 @@ class InferenceEngine:
         t_rb = time.perf_counter()
         self.steps += 1
         if self._metrics:
-            self._metrics.decode_steps.labels(self.name).inc()
+            self._m("decode_steps").inc()
         if self._usage.enabled or self._cp.enabled:
             # Satellite of the speculation plane: device-seconds charge
             # the ACCEPTED token counts, not the dispatched window
@@ -3170,6 +3354,14 @@ class InferenceEngine:
                                   self.tokens_generated_total - tok0)
         self._set_gauges()
         return True
+
+    def _verify_dispatch(self, qlens: np.ndarray, context_tokens: int):
+        """``_dispatch_span`` for a verify window: ONE device step over
+        up to ``draft_k + 1`` positions a row; ``row_steps`` is the
+        windows' sum, an upper bound of what the rows commit."""
+        return self._dispatch_span(
+            "verify_chunk", steps=1, rows=int(np.count_nonzero(qlens)),
+            row_steps=int(qlens.sum()), context_tokens=context_tokens)
 
     def _spec_trim(self, seq: _Sequence) -> None:
         """KV rollback for a reconciled verify window: pages past the
@@ -3210,8 +3402,7 @@ class InferenceEngine:
                 acc = max(0, n - 1)
                 accepted += acc
                 if self._metrics:
-                    self._metrics.spec_acceptance.labels(
-                        self.name).observe(acc / (w - 1))
+                    self._m("spec_acceptance").observe(acc / (w - 1))
         self.spec_windows += 1
         self.spec_tokens_proposed += proposed
         self.spec_tokens_accepted += accepted
@@ -3219,12 +3410,10 @@ class InferenceEngine:
         self.spec_fetches_total += 1
         if self._metrics:
             if proposed:
-                self._metrics.spec_tokens_proposed.labels(
-                    self.name).inc(proposed)
+                self._m("spec_tokens_proposed").inc(proposed)
             if accepted:
-                self._metrics.spec_tokens_accepted.labels(
-                    self.name).inc(accepted)
-            self._metrics.spec_readback_cadence.labels(self.name).set(
+                self._m("spec_tokens_accepted").inc(accepted)
+            self._m("spec_readback_cadence").set(
                 self.spec_commits_total / self.spec_fetches_total)
         self._telemetry.note_spec(proposed, accepted, committed)
 
@@ -3312,10 +3501,12 @@ class InferenceEngine:
                                (B, self.spec.max_pages_per_seq), np.int32)
         temps = st.take("chunk.temp", (B,), np.float32)
         budgets = np.zeros(B, np.int32)   # read again at process time
+        ctx = 0
         for seq in active:
             i = seq.slot
             tokens[i] = seq.last_token
             positions[i] = seq.pos
+            ctx += seq.pos
             block_tables[i] = seq.block_table
             temps[i] = seq.req.temperature
             budgets[i] = budgets_by_order.get(seq.order, 1)
@@ -3335,19 +3526,16 @@ class InferenceEngine:
             infl_pf.append((seq, len(sl), not seq.todo_ids))
 
         if self._metrics:
-            self._metrics.mixed_step_decode_rows.labels(self.name).set(
-                len(active))
-            self._metrics.mixed_step_prefill_tokens.labels(
-                self.name).set(packed)
-            self._metrics.mixed_budget_utilization.labels(
-                self.name).set(packed / budget if budget else 0.0)
+            self._m("mixed_step_decode_rows").set(len(active))
+            self._m("mixed_step_prefill_tokens").set(packed)
+            self._m("mixed_budget_utilization").set(
+                packed / budget if budget else 0.0)
 
         start_fn = getattr(self.executor, "mixed_chunk_start", None)
         t0 = time.perf_counter()
         if start_fn is not None:
-            with self._prof.span("engine.mixed_chunk",
-                                 active=len(active), chunk=chunk,
-                                 slices=len(pf), pf_tokens=packed):
+            with self._chunk_dispatch("mixed_chunk", budgets, ctx,
+                                      prefill_tokens=packed):
                 handle = start_fn(tokens, positions, block_tables,
                                   temps, budgets, pf)
             dispatch_s = time.perf_counter() - t_asm
@@ -3371,12 +3559,11 @@ class InferenceEngine:
             self.mixed_steps += 1
             self.mixed_prefill_tokens_total += packed
             if self._metrics:
-                self._metrics.decode_steps.labels(self.name).inc()
+                self._m("decode_steps").inc()
             return True
         # Sync executor (echo): one blocking call, commit inline.
-        with self._prof.span("engine.mixed_chunk", active=len(active),
-                             chunk=chunk, slices=len(pf),
-                             pf_tokens=packed):
+        with self._chunk_dispatch("mixed_chunk", budgets, ctx,
+                                  prefill_tokens=packed):
             out, pf_first = self.executor.mixed_chunk(
                 tokens, positions, block_tables, temps, budgets, pf)
         t_done = time.perf_counter()
@@ -3389,7 +3576,7 @@ class InferenceEngine:
         self.mixed_steps += 1
         self.mixed_prefill_tokens_total += packed
         if self._metrics:
-            self._metrics.decode_steps.labels(self.name).inc()
+            self._m("decode_steps").inc()
         if self._usage.enabled or self._cp.enabled:
             decode_parts = [(seq, max(1, int(budgets[seq.slot])), False)
                             for seq in active if seq.slot is not None]
@@ -3451,6 +3638,10 @@ class InferenceEngine:
                 # buffered here, flushed one batch job per chunk.
                 seq.pending_emit.append(nxt)
             else:
+                if len(seq.generated) == 1:
+                    # No completion workers: the hand-over is here.
+                    handle.marks.setdefault("first_token_out",
+                                            time.perf_counter())
                 try:
                     handle._on_token(nxt)
                 except Exception:  # noqa: BLE001 — broken stream consumer
@@ -3459,8 +3650,7 @@ class InferenceEngine:
                                       "request_id": seq.req.id}})
                     handle._on_token = None
         if self._metrics:
-            self._metrics.generated_tokens.labels(
-                self.name, seq.req.priority.tier_name).inc()
+            self._m("generated_tokens", seq.req.priority.tier_name).inc()
         limit = seq.req.max_new_tokens or self.max_decode_steps
         if len(seq.generated) >= limit:
             self._finish_active(seq, "length")
@@ -3602,6 +3792,7 @@ class InferenceEngine:
                                 "kv_promote_done", "handoff_claim_start",
                                 "handoff_claim_done", "prefill_start",
                                 "prefill_done", "first_token",
+                                "first_token_out", "preempted",
                                 "decode_done")
                   if stage in marks]
         store_wait_ms = marks.get("_store_wait_ms", 0.0)
@@ -3758,14 +3949,14 @@ class InferenceEngine:
     def _set_gauges(self) -> None:
         if not self._metrics:
             return
-        self._metrics.kv_pages_in_use.labels(self.name).set(
+        self._m("kv_pages_in_use").set(
             self.allocator.used())
-        self._metrics.kv_pinned_conversations.labels(self.name).set(
+        self._m("kv_pinned_conversations").set(
             len(self._conv_cache))
-        self._metrics.batch_occupancy.labels(self.name).set(
+        self._m("batch_occupancy").set(
             sum(1 for s in self._slots if s is not None))
         if self._prefix_cache is not None:
-            self._metrics.prefix_cache_pages.labels(self.name).set(
+            self._m("prefix_cache_pages").set(
                 self._prefix_cache.pages)
 
     # -- stats ---------------------------------------------------------------
@@ -3785,7 +3976,14 @@ class InferenceEngine:
             "slots": self.spec.batch_size,
             "active": sum(1 for s in self._slots if s is not None),
             "pending": pending,
+            # DISPATCHES of decode-capable programs, counted on the
+            # host (the benchmark reads it as such); the device steps
+            # and row-steps they asked for follow, counted at the
+            # dispatch too (docs/observability.md "The engine step").
             "decode_steps": self.steps,
+            "device_steps": self.device_steps,
+            "row_steps": self.row_steps,
+            "preemptions": dict(self.preemptions),
             "tokens_generated": self.tokens_generated_total,
             "kv_pages_used": self.allocator.used(),
             "kv_pages_total": self.allocator.total,

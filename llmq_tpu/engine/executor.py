@@ -37,7 +37,6 @@ from typing import Callable, Dict, List, Optional, Protocol, Tuple
 import numpy as np
 
 from llmq_tpu.utils.logging import get_logger
-from llmq_tpu.utils.profiling import annotate
 
 log = get_logger("executor")
 
@@ -717,6 +716,15 @@ class VerifyHandle:
         out = np.asarray(self.out)
         return out, verify_host_ncommit(out, self._drafts, self._qlens,
                                         self._eos)
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under another ``__name__``: ``jax.jit`` names the XLA
+    module it builds ``jit_<__name__>``."""
+    def call(*args):
+        return fn(*args)
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 class JaxExecutor:
@@ -1811,9 +1819,14 @@ class JaxExecutor:
                 exported = jexport.export(fn)(*args)
             # Re-jit the (de)serialized call with the SAME donation:
             # the exported module carries the aliasing attributes, so
-            # the pool stays in-place.
+            # the pool stays in-place. Through a function named after
+            # the program's key, so the device trace's ``XLA Modules``
+            # line reads ``jit_<program>`` and not ``jit_call`` for
+            # every one of them. The key holds nothing that differs
+            # between starts: the compile cache hits as before.
             self._aot[name] = jax.jit(
-                exported.call, donate_argnums=(1,)).lower(*args).compile()
+                _named(exported.call, name),
+                donate_argnums=(1,)).lower(*args).compile()
             if hit:
                 self._from_export_cache.add(name)
             else:
@@ -1992,6 +2005,20 @@ class JaxExecutor:
 
     # -- Executor API --------------------------------------------------------
 
+    def program_name(self, entry: str, tokens: int = 0) -> str:
+        """Key in ``_aot`` (and so ``jit_<key>`` on the device trace's
+        ``XLA Modules`` line) of the program that ``entry`` — a
+        dispatch method's name less ``_start`` / ``_async`` — runs;
+        ``tokens`` is the longest prompt chunk of a prefill dispatch.
+        What the engine puts on its ``engine.dispatch`` span."""
+        if entry in ("prefill", "prefill_multi"):
+            if self.ragged_attention:
+                return "ragged_chunk"
+            return f"{entry}_b{self._bucket_for(max(1, tokens))}"
+        if entry == "mixed_chunk" and self.ragged_attention:
+            return "ragged_chunk"
+        return entry
+
     def _prefill_chunk(self, chunk: List[int], start_pos: int, bt,
                        temperature: float):
         """Launch ONE bucketed prefill program (no host sync): pads the
@@ -2006,15 +2033,14 @@ class JaxExecutor:
         np.add(self._staging.arange(T), start_pos, out=positions)
         np.minimum(positions, start_pos + len(chunk) - 1, out=positions)
         fn = self._aot.get(f"prefill_b{T}", self._prefill_step)
-        with annotate(f"prefill_b{T}"):  # named region in xprof traces
-            tok, self.cache = fn(
-                self.params, self.cache,
-                jnp.asarray(padded)[None, :],
-                jnp.asarray(positions, jnp.int32)[None, :],
-                jnp.asarray([len(chunk)], jnp.int32),
-                bt,
-                jnp.asarray([temperature], jnp.float32),
-                self._next_key())
+        tok, self.cache = fn(
+            self.params, self.cache,
+            jnp.asarray(padded)[None, :],
+            jnp.asarray(positions, jnp.int32)[None, :],
+            jnp.asarray([len(chunk)], jnp.int32),
+            bt,
+            jnp.asarray([temperature], jnp.float32),
+            self._next_key())
         self._staging_fence(f"prefill{T}", tok)
         return tok
 
@@ -2076,11 +2102,10 @@ class JaxExecutor:
             bts[i] = bt
             temps[i] = temp
         fn = self._aot.get(f"prefill_multi_b{T}", self._prefill_multi)
-        with annotate(f"prefill_multi_b{T}"):
-            out, self.cache = fn(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(poss), jnp.asarray(lens), jnp.asarray(bts),
-                jnp.asarray(temps), self._next_key())
+        out, self.cache = fn(
+            self.params, self.cache, jnp.asarray(toks),
+            jnp.asarray(poss), jnp.asarray(lens), jnp.asarray(bts),
+            jnp.asarray(temps), self._next_key())
         self._staging_fence(f"pfm{T}", out)
         return [out[i] for i in range(len(reqs))]
 
@@ -2151,15 +2176,14 @@ class JaxExecutor:
             tok_in = tok_in.at[slot].set(tok_dev.astype(jnp.int32))
             pos_in = pos_in.at[slot].set(jnp.int32(pos))
             done_in = done_in.at[slot].set(False)
-        with annotate("decode_chunk"):
-            out, tok, pos, done, self.cache = fn(
-                self.params, self.cache,
-                tok_in, pos_in,
-                self._batch_arr(block_tables, jnp.int32),
-                self._batch_arr(temperatures, jnp.float32),
-                self._batch_arr(budgets, jnp.int32),
-                done_in,
-                self._next_key())
+        out, tok, pos, done, self.cache = fn(
+            self.params, self.cache,
+            tok_in, pos_in,
+            self._batch_arr(block_tables, jnp.int32),
+            self._batch_arr(temperatures, jnp.float32),
+            self._batch_arr(budgets, jnp.int32),
+            done_in,
+            self._next_key())
         return ChunkHandle(out, tok, pos, done)
 
     def decode_chunk(self, tokens: np.ndarray, positions: np.ndarray,
@@ -2185,31 +2209,29 @@ class JaxExecutor:
         jnp = self._jnp
         fn = self._aot.get("verify_chunk", self._verify_chunk)
         if self._spec_device_sampling:
-            with annotate("verify_chunk"):
-                out, ncommit, self.cache = fn(
-                    self.params, self.cache,
-                    self._batch_arr(tokens, jnp.int32),
-                    self._batch_arr(positions, jnp.int32),
-                    self._batch_arr(block_tables, jnp.int32),
-                    self._batch_arr(temperatures, jnp.float32),
-                    self._batch_arr(drafts, jnp.int32),
-                    self._batch_arr(qlens, jnp.int32),
-                    self._spec_key)
+            out, ncommit, self.cache = fn(
+                self.params, self.cache,
+                self._batch_arr(tokens, jnp.int32),
+                self._batch_arr(positions, jnp.int32),
+                self._batch_arr(block_tables, jnp.int32),
+                self._batch_arr(temperatures, jnp.float32),
+                self._batch_arr(drafts, jnp.int32),
+                self._batch_arr(qlens, jnp.int32),
+                self._spec_key)
             return VerifyHandle(out, ncommit)
         W = self.verify_draft_k + 1
         st = self._staging
         toks = st.take("verify.tok", (self.spec.batch_size, W), np.int32)
         toks[:, 0] = tokens
         toks[:, 1:] = drafts
-        with annotate("verify_chunk"):
-            out, self.cache = fn(
-                self.params, self.cache,
-                self._batch_arr(toks, jnp.int32),
-                self._batch_arr(positions, jnp.int32),
-                self._batch_arr(block_tables, jnp.int32),
-                self._batch_arr(temperatures, jnp.float32),
-                self._batch_arr(qlens, jnp.int32),
-                self._spec_key)
+        out, self.cache = fn(
+            self.params, self.cache,
+            self._batch_arr(toks, jnp.int32),
+            self._batch_arr(positions, jnp.int32),
+            self._batch_arr(block_tables, jnp.int32),
+            self._batch_arr(temperatures, jnp.float32),
+            self._batch_arr(qlens, jnp.int32),
+            self._spec_key)
         return VerifyHandle(out, None,
                             drafts=np.array(drafts, np.int32),
                             qlens=np.array(qlens, np.int32),
@@ -2260,19 +2282,18 @@ class JaxExecutor:
             pf_temps[i] = temp
         fn = self._aot.get("mixed_chunk", self._mixed_chunk)
         done0 = self._zeros_done()
-        with annotate("mixed_chunk"):
-            out, tok, pos, done, pf_first, self.cache = fn(
-                self.params, self.cache,
-                self._batch_arr(tokens, jnp.int32),
-                self._batch_arr(positions, jnp.int32),
-                self._batch_arr(block_tables, jnp.int32),
-                self._batch_arr(temperatures, jnp.float32),
-                self._batch_arr(budgets, jnp.int32),
-                done0,
-                jnp.asarray(pf_toks), jnp.asarray(pf_poss),
-                jnp.asarray(pf_lens), jnp.asarray(pf_bts),
-                jnp.asarray(pf_temps),
-                self._next_key())
+        out, tok, pos, done, pf_first, self.cache = fn(
+            self.params, self.cache,
+            self._batch_arr(tokens, jnp.int32),
+            self._batch_arr(positions, jnp.int32),
+            self._batch_arr(block_tables, jnp.int32),
+            self._batch_arr(temperatures, jnp.float32),
+            self._batch_arr(budgets, jnp.int32),
+            done0,
+            jnp.asarray(pf_toks), jnp.asarray(pf_poss),
+            jnp.asarray(pf_lens), jnp.asarray(pf_bts),
+            jnp.asarray(pf_temps),
+            self._next_key())
         return MixedChunkHandle(out, tok, pos, done, pf_first)
 
     def _ragged_chunk_start(self, tokens, positions, block_tables,
@@ -2321,19 +2342,18 @@ class JaxExecutor:
             off += -(-L // qblk) * qblk
         assert off <= N, (off, N)
         fn = self._aot.get("ragged_chunk", self._mixed_chunk)
-        with annotate("ragged_chunk"):
-            out, tok, pos, done, pf_first, self.cache = fn(
-                self.params, self.cache,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(temperatures, jnp.float32),
-                jnp.asarray(budgets, jnp.int32),
-                jnp.zeros(self.spec.batch_size, bool),
-                jnp.asarray(pf_toks), jnp.asarray(pf_poss),
-                jnp.asarray(pf_qoff), jnp.asarray(pf_qlen),
-                jnp.asarray(pf_bts), jnp.asarray(pf_temps),
-                self._next_key())
+        out, tok, pos, done, pf_first, self.cache = fn(
+            self.params, self.cache,
+            jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(positions, jnp.int32),
+            jnp.asarray(block_tables, jnp.int32),
+            jnp.asarray(temperatures, jnp.float32),
+            jnp.asarray(budgets, jnp.int32),
+            jnp.zeros(self.spec.batch_size, bool),
+            jnp.asarray(pf_toks), jnp.asarray(pf_poss),
+            jnp.asarray(pf_qoff), jnp.asarray(pf_qlen),
+            jnp.asarray(pf_bts), jnp.asarray(pf_temps),
+            self._next_key())
         return MixedChunkHandle(out, tok, pos, done, pf_first)
 
     def _ragged_prefill_start(self, reqs: List) -> List:

@@ -13,7 +13,8 @@ Perfetto. Mapping:
   ``i`` (instant) event so nothing is hidden by the pairing.
 - ``SpanRecorder`` spans (perf_counter-based) are shifted onto the
   wall clock with the caller-supplied anchor (``wall - perf`` sampled
-  in the process that owns the spans) and emitted on their own track.
+  in the process that owns the spans) and emitted on a track per
+  recording thread.
 """
 
 from __future__ import annotations
@@ -71,12 +72,17 @@ def chrome_trace(timelines: Iterable[Timeline], *,
     if spans:
         anchor = perf_anchor() if span_anchor is None else span_anchor
         pid = pid_for("executor-spans")
+        # One track per recording thread (the engine thread's nested
+        # ``engine.step`` spans; the completion pool's
+        # ``engine.deliver``), numbered from 2 in order of appearance.
+        tracks: Dict[int, int] = {}
         for s in spans:
+            tid = tracks.setdefault(getattr(s, "tid", 0), 2 + len(tracks))
             events.append({
                 "name": s.name, "ph": "X",
                 "ts": (s.start + anchor) * 1e6,
                 "dur": s.duration * 1e6,
-                "pid": pid, "tid": 2, "args": dict(s.meta or {})})
+                "pid": pid, "tid": tid, "args": dict(s.meta or {})})
 
     out: Dict[str, Any] = {"traceEvents": events, "displayTimeUnit": "ms"}
     if jax_trace_dir:

@@ -60,7 +60,7 @@ STAGE_ORDER = ("enqueued", "received", "scheduled", "dispatched",
                "admitted", "kv_promote_start", "handoff_claim_start",
                "kv_promote_done", "handoff_claim_done",
                "prefill_start", "prefill_done", "first_token",
-               "kv_publish", "decode_done",
+               "first_token_out", "preempted", "kv_publish", "decode_done",
                "failover", "retry_scheduled", "completed", "failed",
                "cancelled")
 _STAGE_RANK = {s: i for i, s in enumerate(STAGE_ORDER)}

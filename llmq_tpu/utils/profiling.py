@@ -9,16 +9,18 @@ Two layers:
   transfers and TPU utilization, viewable in XProf/perfetto/tensorboard.
   Enabled ambiently by setting ``LLMQ_TRACE_DIR`` (bench.py and the
   engine loop honor it).
-- **Host spans** — :class:`SpanRecorder`, a lightweight in-process
-  span log (name, start, duration) for control-plane paths (queue pop →
-  admission → decode chunk), exposed via ``GET /api/v1/engine/stats``
-  and dumpable to Chrome trace-event JSON for chrome://tracing.
+- **Host spans** — :class:`SpanRecorder`, the one span primitive:
+  ``span(name, **counts)`` writes an in-process ring (name, start,
+  duration, counts; ``GET /api/v1/engine/stats`` and the chrome export
+  of ``observability/chrome.py`` read it) and, while a capture is held,
+  the same interval as a ``TraceAnnotation`` on the device trace's
+  clock. The engine step's vocabulary: docs/observability.md.
 """
 
 from __future__ import annotations
 
-import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -57,53 +59,89 @@ def trace(label: str = "llmq", dir: Optional[str] = None) -> Iterator[None]:
     log.info("trace written to %s (view with xprof/tensorboard)", out)
 
 
-@contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named sub-region inside a device trace (TraceAnnotation).
-    Annotation setup is best-effort; body exceptions propagate
-    untouched (a blanket try around the yield would trip contextlib's
-    'generator didn't stop after throw()' and mask the real error)."""
-    try:
-        import jax
-        ann = jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — annotation is best-effort
-        ann = None
-    if ann is None:
-        yield
-        return
-    with ann:
-        yield
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation`` once JAX is in the process,
+    ``None`` before: a capture is held through ``jax.profiler``, so
+    without JAX there is none to land in — and a device-free backend
+    (echo) must not import JAX for the sake of its spans."""
+    jax = sys.modules.get("jax")
+    # (``profiler`` is missing only while ``import jax`` is under way
+    # on another thread)
+    profiler = getattr(jax, "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
 
 
-@dataclass
+def capture_held() -> bool:
+    """True while a profiler capture is held in this process (about
+    0.1 µs). Counts that cost more than reading a field are computed
+    only then."""
+    cls = _annotation_cls()
+    return cls is not None and cls.is_enabled()
+
+
+@dataclass(slots=True)
 class Span:
     name: str
     start: float      # perf_counter seconds
     duration: float
     meta: Optional[Dict] = None
+    tid: int = 0      # thread that ran the span
+
+
+class _OpenSpan:
+    """One ``SpanRecorder.span`` interval: a plain context manager (no
+    generator frame). Body exceptions propagate untouched."""
+
+    __slots__ = ("_rec", "_name", "_counts", "_t0", "_ann")
+
+    def __init__(self, rec: "SpanRecorder", name: str,
+                 counts: Optional[Dict]) -> None:
+        self._rec = rec
+        self._name = name
+        self._counts = counts
+        self._ann = None
+
+    def __enter__(self) -> "_OpenSpan":
+        cls = _annotation_cls()
+        if cls is not None and cls.is_enabled():
+            # On the profiler's clock, beside the device planes; the
+            # counts become the event's arguments.
+            self._ann = (cls(self._name, **self._counts) if self._counts
+                         else cls(self._name))
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self._rec.record(self._name, self._t0, dt, self._counts)
+        return False
 
 
 class SpanRecorder:
-    """Bounded in-memory span ring for control-plane profiling."""
+    """THE host-span primitive: ``span(name, **counts)`` writes a
+    bounded in-memory ring (``GET /api/v1/engine/stats`` ``profile``,
+    the chrome export) AND, while a profiler capture is held, opens a
+    ``jax.profiler.TraceAnnotation(name, **counts)`` for the same
+    interval, so the span lands on the device trace's clock with its
+    counts as event arguments. With no capture held a span costs two
+    clock reads, one ``is_enabled`` check and one ring append."""
 
     def __init__(self, capacity: int = 4096) -> None:
         self.capacity = capacity
         self._spans: deque = deque(maxlen=capacity)  # O(1) bounded append
         self._mu = threading.Lock()
 
-    @contextmanager
-    def span(self, name: str, **meta) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, t0, time.perf_counter() - t0,
-                        meta or None)
+    def span(self, name: str, **counts) -> _OpenSpan:
+        return _OpenSpan(self, name, counts or None)
 
     def record(self, name: str, start: float, duration: float,
                meta: Optional[Dict] = None) -> None:
+        span = Span(name, start, duration, meta, threading.get_ident())
         with self._mu:
-            self._spans.append(Span(name, start, duration, meta))
+            self._spans.append(span)
 
     def snapshot(self) -> List[Span]:
         with self._mu:
@@ -124,18 +162,6 @@ class SpanRecorder:
             d["mean_ms"] = round(d["mean_ms"], 3)
             d["max_ms"] = round(d["max_ms"], 3)
         return out
-
-    def dump_chrome_trace(self, path: str) -> None:
-        """Write chrome://tracing / perfetto-compatible trace events."""
-        events = [
-            {"name": s.name, "ph": "X", "ts": s.start * 1e6,
-             "dur": s.duration * 1e6, "pid": 0, "tid": 0,
-             "args": s.meta or {}}
-            for s in self.snapshot()
-        ]
-        with open(path, "w") as f:
-            json.dump({"traceEvents": events}, f)
-        log.info("wrote %d spans to %s", len(events), path)
 
     def clear(self) -> None:
         with self._mu:

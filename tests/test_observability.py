@@ -265,13 +265,13 @@ class TestChromeExport:
     def test_span_recorder_spans_stitch_in(self):
         from llmq_tpu.utils.profiling import SpanRecorder
         prof = SpanRecorder()
-        with prof.span("engine.decode_chunk", active=3):
+        with prof.span("engine.dispatch", rows=3):
             pass
         rec = FlightRecorder(emit_metrics=False)
         rec.record("r", "enqueued")
         doc = chrome_trace([rec.get("r")], spans=prof.snapshot(),
                            jax_trace_dir="/tmp/xprof")
-        assert any(e.get("name") == "engine.decode_chunk"
+        assert any(e.get("name") == "engine.dispatch"
                    for e in doc["traceEvents"])
         assert doc["otherData"]["jax_trace_dir"] == "/tmp/xprof"
 

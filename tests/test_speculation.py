@@ -535,7 +535,7 @@ class TestAttributionConservation:
         try:
             eng, _ = make_echo_engine(spec_cfg(), name="spec-cp")
             hs = [eng.submit(GenRequest(
-                      id=f"cp{i}", prompt="conserve conserve conserve ",
+                      id=f"spec-cp{i}", prompt="conserve conserve conserve ",
                       max_new_tokens=24))
                   for i in range(6)]
             eng.run_until_idle()
