@@ -283,6 +283,13 @@ def _fused_decode_ok(B: int, page_size: int, max_pages: int, gd: int,
         B, page_size, max_pages, gd, itemsize)
 
 
+def _prefill_attn_ok(rows_ok: bool, head_dim: int) -> bool:
+    """Prefill attention kernel eligibility: it cuts a page's lanes into
+    head windows of ``max(128, head_dim)``, so a head fills a divisor or
+    a multiple of 128 lanes (64 and 128 in every registered model)."""
+    return rows_ok and (128 % head_dim == 0 or head_dim % 128 == 0)
+
+
 def _fused_decode_q8_ok(B: int, page_size: int, max_pages: int, gd: int,
                         n_kv_heads: int, n_scale_heads: int) -> bool:
     """int8-KV fused decode kernel eligibility. page_size % 128: a
@@ -366,7 +373,8 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
             # runs the bucket ops over the dense per-slice view.
             out["prefill_attention"] = (
                 "xla" if quant_kv
-                else pick(rows_ok, "_prefill_attn_kernel"))
+                else pick(_prefill_attn_ok(rows_ok, head_dim),
+                          "_prefill_attn_kernel"))
     if decode:
         if quant_kv:
             fused = pick(_fused_decode_q8_ok(batch, page_size, max_pages,
@@ -494,7 +502,8 @@ def dispatch_prefill_attention(q, k_pool, v_pool, block_tables, positions,
     B, T = q.shape[0], q.shape[1]
     page_size = k_pool.shape[2]
     use_kernel, interpret = _kernel_route(
-        k_pool.shape[3], extra_ok=(B == 1 or multi_ok), enabled=enabled)
+        k_pool.shape[3], enabled=enabled,
+        extra_ok=_prefill_attn_ok(B == 1 or multi_ok, q.shape[3]))
     if use_kernel:
         # Per-sequence kernel, row-looped for batched prefill: pure
         # READS of the pool — B opaque kernel consumers don't make XLA

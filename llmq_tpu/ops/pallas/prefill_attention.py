@@ -11,23 +11,34 @@ copies (measured: it tripled prefill time). Reading through a Pallas
 kernel keeps the pool's only consumers opaque custom calls with clean
 buffer dependencies, mirroring the decode path.
 
-Shape strategy (same tricks as the decode kernel, see
-paged_attention.py): GQA via **block-diagonal Q** — q row (t, h) covers
-lanes [g(h)·D, (g(h)+1)·D) of the H_kv·D-wide flattened head dim, so
-every (q-block × kv-chunk) product is one 2D MXU matmul and per-head
-slicing (illegal lane granularity) never happens. Pages DMA HBM→VMEM
-per chunk; fully-masked chunks (beyond the q block's last visible
-position) are skipped entirely; online softmax accumulates across
-chunks in f32 scratch.
+Tile plan (:func:`prefill_tile_plan`, a pure function of the shapes):
 
-Grid: (n_q_blocks, n_kv_chunks), kv minor — accumulators carry across
-the kv loop of each q block, reset at chunk 0, flushed at the last
-chunk.
+- **Lanes hold heads.** The flat ``H_kv·D`` axis of a page is cut into
+  head windows of ``W = max(128, D)`` lanes: one KV head at D >= 128,
+  ``128 // D`` neighbours below. The query heads of a window's KV heads
+  are stacked on the ROWS of one ``(R·Tb, W)`` tile (R = heads per
+  window × n_rep; head h's D lanes sit under its KV head's, zero
+  elsewhere), built in VMEM from the ``(Tb, H·D)`` q block as it
+  arrives, so every (q block × K/V chunk × window) meets the MXU as
+  one 2D matmul over 128-aligned lane windows of the whole pages in
+  scratch. Wasted MXU lanes: W / D, never H_kv.
+- **The loop follows the context.** The grid runs over q blocks only;
+  inside, a ``fori_loop`` with a trip count computed from ``start_pos``
+  walks the K/V chunks up to the q block's last visible position, with
+  double-buffered whole-page DMAs (pages past that position inside the
+  last chunk are neither fetched nor waited for).
+- **Precision**: operands in the pool's dtype (bf16 in serving), f32
+  accumulation, f32 softmax statistics, ``p`` cast to the pool's dtype
+  before PV — what ``fused_decode.py`` and
+  ``blockwise_prefill_attention`` do.
+- q comes in and the result goes out as ``(T, H·D)``, a free reshape of
+  ``(T, H, D)``: nothing wider than that exists in HBM.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -37,130 +48,252 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+#: What the kernel asks Mosaic for (the default scoped limit is 16 MiB
+#: of the v5e's 128 MiB) and what the plan's own estimate must stay
+#: under: the rest is left to the compiler's temporaries.
+VMEM_LIMIT_BYTES = 32 * 2**20
+VMEM_BUDGET_BYTES = 20 * 2**20
+#: K/V chunk length the plan aims for: long enough to amortize a loop
+#: step, short enough that a chunk past the context costs little.
+CHUNK_TOKENS = 256
+#: Rows of the stacked q tile (one matmul's M) the plan aims for.
+MAX_TILE_ROWS = 512
+
+
+class PrefillPlan(NamedTuple):
+    """Tile sizes of one call, from its shapes alone."""
+    q_block: int            # Tb: query tokens per grid step
+    pages_per_chunk: int    # whole pages per K/V chunk
+    chunk_tokens: int       # S = pages_per_chunk · page_size
+    num_chunks: int         # chunks that cover the block table
+    lane_width: int         # W: lanes of one head window
+    num_windows: int        # H_kv·D / W
+    heads_per_tile: int     # R: query heads stacked on a tile's rows
+    lane_waste: float       # W / D: MXU lanes fed per useful lane
+    vmem_bytes: int         # estimate, see prefill_tile_plan
+
+    def steps(self, n_tokens: int, start_pos: int) -> int:
+        """Loop steps (q block × live K/V chunk) of a call whose first
+        query sits at ``start_pos``; each does ``num_windows`` pairs of
+        matmuls."""
+        total = 0
+        for qb in range(n_tokens // self.q_block):
+            last = start_pos + (qb + 1) * self.q_block - 1
+            total += min(last // self.chunk_tokens + 1, self.num_chunks)
+        return total
+
+
+def _largest_divisor(n: int, at_most: int) -> int:
+    d = max(1, min(n, at_most))
+    while n % d:
+        d -= 1
+    return d
+
+
+def prefill_tile_plan(n_tokens: int, n_heads: int, n_kv_heads: int,
+                      head_dim: int, page_size: int, max_pages: int,
+                      itemsize: int, *, q_block: int = 0,
+                      pages_per_chunk: int = 0) -> PrefillPlan:
+    """The kernel's tile sizes as a pure function of the call's shapes.
+
+    ``q_block`` / ``pages_per_chunk`` > 0 pin those two (tests); 0 lets
+    the plan choose: chunks of about ``CHUNK_TOKENS``, then the largest
+    q block whose stacked tile has at most ``MAX_TILE_ROWS`` rows and
+    whose VMEM estimate stays under ``VMEM_BUDGET_BYTES``.
+    """
+    T, H, Hkv, D = n_tokens, n_heads, n_kv_heads, head_dim
+    GD = Hkv * D
+    W = max(128, D)
+    if GD % W or W % D or W % 128:
+        raise ValueError(
+            f"H_kv*D = {GD} with D = {D} does not cut into 128-lane "
+            f"head windows")
+    R = (W // D) * (H // Hkv)
+    ppc = _largest_divisor(
+        max_pages, pages_per_chunk or max(1, CHUNK_TOKENS // page_size))
+    S = ppc * page_size
+
+    def vmem(tb: int) -> int:
+        rows = H * tb                          # all windows together
+        acc = rows * W * 4                     # f32 accumulator
+        stats = 2 * rows * 128 * 4             # m, l: (rows, 1) f32 pads
+                                               # to a full lane tile
+        stacked = rows * W * itemsize          # stacked q tiles
+        blocks = 2 * 2 * tb * H * D * itemsize  # q, out: double-buffered
+        kv = 2 * 2 * S * GD * itemsize         # K, V: two slots each
+        temps = 3 * R * tb * S * 4             # logits, p, mask of a tile
+        return acc + stats + stacked + blocks + kv + temps
+
+    if q_block:
+        tb = _largest_divisor(T, q_block)
+    else:
+        tb = T      # halved only while the half is a whole bf16 tile (16)
+        while tb % 32 == 0 and (
+                R * tb > MAX_TILE_ROWS or vmem(tb) > VMEM_BUDGET_BYTES):
+            tb //= 2
+    return PrefillPlan(
+        q_block=tb, pages_per_chunk=ppc, chunk_tokens=S,
+        num_chunks=max_pages // ppc, lane_width=W, num_windows=GD // W,
+        heads_per_tile=R, lane_waste=W / D, vmem_bytes=vmem(tb))
+
+
+def _lane_window(x, lo: int, width: int):
+    """``x`` with every lane outside [lo, lo + width) zeroed."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= lo) & (lane < lo + width), x, 0)
+
 
 def _prefill_attn_kernel(
     # scalar prefetch (SMEM)
     block_table_ref,   # (max_pages,) int32
     meta_ref,          # (2,) int32 — [start_pos, layer]
     # inputs
-    q_ref,             # (TbH, GD) VMEM — block-diagonal q rows
+    q_ref,             # (Tb, H·D) VMEM — this block's raw query heads
     k_hbm,             # (L, P, page_size, GD) ANY
     v_hbm,             # (L, P, page_size, GD) ANY
     # outputs
-    out_ref,           # (TbH, GD) VMEM
+    out_ref,           # (Tb, H·D) VMEM
     # scratch
-    m_ref,             # (TbH, 1) f32
-    l_ref,             # (TbH, 1) f32
-    acc_ref,           # (TbH, GD) f32
-    k_scratch,         # (2, ppc, page_size, GD) VMEM
-    v_scratch,         # (2, ppc, page_size, GD) VMEM
-    sem,               # DMA semaphores (2, 2, ppc)
+    qs_ref,            # (n_w, R·Tb, W) pool dtype — stacked q tiles
+    m_ref,             # (n_w, R·Tb, 1) f32
+    l_ref,             # (n_w, R·Tb, 1) f32
+    acc_ref,           # (n_w, R·Tb, W) f32
+    k_scratch,         # (2, S, GD) VMEM
+    v_scratch,         # (2, S, GD) VMEM
+    sem,               # DMA semaphores (2, 2) — [pool, slot]
     *,
-    pages_per_chunk: int,
+    plan: PrefillPlan,
     page_size: int,
-    num_chunks: int,
-    q_block: int,      # Tb — query tokens per grid row
-    n_heads: int,
+    head_dim: int,
+    n_rep: int,
     scale: float,
 ):
     qb = pl.program_id(0)
-    c = pl.program_id(1)
-    ppc = pages_per_chunk
+    Tb, ppc, S = plan.q_block, plan.pages_per_chunk, plan.chunk_tokens
+    W, n_w, R = plan.lane_width, plan.num_windows, plan.heads_per_tile
+    D = head_dim
     start = meta_ref[0]
     lyr = meta_ref[1]
-    # Last absolute position any q row of this block can see.
-    block_max_pos = start + (qb + 1) * q_block - 1
+    # Last absolute position any q row of this block can see, and the
+    # K/V chunks up to it: the only ones this block spends a step on.
+    block_max_pos = start + (qb + 1) * Tb - 1
+    n_live = jnp.minimum(block_max_pos // S + 1, plan.num_chunks)
 
-    def start_chunk(chunk, slot):
+    def chunk_dmas(chunk, slot, wait: bool):
+        """Start (or wait for) the live pages of ``chunk``. Liveness is
+        the same predicate both times, so starts and waits pair; every
+        copy on a semaphore moves one page, so waits drain in any
+        order."""
         base = chunk * ppc
         for j in range(ppc):  # static unroll
-            page_start = (base + j) * page_size
-            in_grid = chunk < num_chunks
-            live = jnp.logical_and(in_grid, page_start <= block_max_pos)
 
-            @pl.when(live)
+            @pl.when((base + j) * page_size <= block_max_pos)
             def _():
-                pid = block_table_ref[base + j]
-                pltpu.make_async_copy(
-                    k_hbm.at[lyr, pid], k_scratch.at[slot, j],
-                    sem.at[0, slot, j]).start()
-                pltpu.make_async_copy(
-                    v_hbm.at[lyr, pid], v_scratch.at[slot, j],
-                    sem.at[1, slot, j]).start()
+                pid = 0 if wait else block_table_ref[base + j]
+                rows = pl.ds(j * page_size, page_size)
+                for pool, (hbm, scratch) in enumerate(
+                        ((k_hbm, k_scratch), (v_hbm, v_scratch))):
+                    dma = pltpu.make_async_copy(
+                        hbm.at[lyr, pid], scratch.at[slot, rows],
+                        sem.at[pool, slot])
+                    dma.wait() if wait else dma.start()
 
-            @pl.when(jnp.logical_and(in_grid, jnp.logical_not(live)))
-            def _():
-                # Never-copied scratch could hold NaN; 0-weight × NaN
-                # would poison the p·V matmul.
-                v_scratch[slot, j] = jnp.zeros_like(v_scratch[slot, j])
-
-    def wait_chunk(chunk, slot):
-        base = chunk * ppc
-        for j in range(ppc):
-            page_start = (base + j) * page_size
-
-            @pl.when(page_start <= block_max_pos)
-            def _():
-                pltpu.make_async_copy(
-                    k_hbm.at[lyr, block_table_ref[base + j]],
-                    k_scratch.at[slot, j], sem.at[0, slot, j]).wait()
-                pltpu.make_async_copy(
-                    v_hbm.at[lyr, block_table_ref[base + j]],
-                    v_scratch.at[slot, j], sem.at[1, slot, j]).wait()
-
-    @pl.when(c == 0)
+    @pl.when(qb == 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        start_chunk(0, 0)
+        # A dead page inside a live chunk is never copied, and what the
+        # scratch holds there meets p = 0 in the PV matmul: it has to be
+        # finite (fresh VMEM can hold NaN; 0 × NaN = NaN). Later blocks
+        # find zeros or pages of this call's own context.
+        k_scratch[...] = jnp.zeros_like(k_scratch)
+        v_scratch[...] = jnp.zeros_like(v_scratch)
 
-    slot = jax.lax.rem(c, 2)
-    chunk_start = c * ppc * page_size
+    chunk_dmas(0, 0, wait=False)
 
-    @pl.when(chunk_start <= block_max_pos)
-    def _():
-        start_chunk(c + 1, 1 - slot)
-        wait_chunk(c, slot)
+    # Stack the query heads while chunk 0 is in flight. Window w holds
+    # heads [w·R, (w+1)·R): head h goes to rows [i·Tb, (i+1)·Tb) of the
+    # tile, i = h mod R, under the lanes of its KV head.
+    for h in range(n_w * R):
+        w, i = divmod(h, R)
+        if D >= 128:
+            x = q_ref[:, h * D:(h + 1) * D]
+        else:
+            at = (h * D) % 128             # where q_ref has the head
+            to = (i // n_rep) * D          # where its KV head sits
+            lo = (h * D) // 128 * 128
+            x = q_ref[:, lo:lo + 128].astype(jnp.float32)
+            if at != to:
+                x = pltpu.roll(x, (to - at) % 128, 1)
+            x = _lane_window(x, to, D)
+        qs_ref[w, i * Tb:(i + 1) * Tb, :] = x.astype(qs_ref.dtype)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        S = ppc * page_size
-        TbH = acc_ref.shape[0]
-        GD = acc_ref.shape[1]
-        q = q_ref[...]                                     # (TbH, GD)
-        k = k_scratch[slot].reshape(S, GD)
-        v = v_scratch[slot].reshape(S, GD)
-        dims = (((1,), (1,)), ((), ()))
-        logits = jax.lax.dot_general(
-            q.astype(jnp.float32), k.astype(jnp.float32), dims,
-            preferred_element_type=jnp.float32) * scale     # (TbH, S)
-        # Causal visibility by absolute position: q row r is token
-        # start + qb·Tb + r//H; kv column s is position chunk_start + s.
-        q_pos = (start + qb * q_block
-                 + jax.lax.broadcasted_iota(jnp.int32, (TbH, 1), 0)
-                 // n_heads)
-        kv_pos = chunk_start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, S), 1)
-        live = kv_pos <= q_pos                              # (TbH, S)
-        logits = jnp.where(live, logits, NEG_INF)
+    # Row r of a tile is token r mod Tb of the block, whatever its head.
+    q_pos = start + qb * Tb + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (R * Tb, 1), 0), Tb)
 
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(logits, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)                         # (TbH, S)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (TbH, GD)
-        acc_ref[...] = acc_ref[...] * alpha + pv
+    def chunk_step(c, carry):
+        slot = jax.lax.rem(c, 2)
 
-    @pl.when(c == num_chunks - 1)
-    def _():
-        out_ref[...] = (acc_ref[...]
-                        / jnp.maximum(l_ref[...], 1e-30)
-                        ).astype(out_ref.dtype)
+        @pl.when(c + 1 < n_live)
+        def _():
+            chunk_dmas(c + 1, 1 - slot, wait=False)
+
+        chunk_dmas(c, slot, wait=True)
+        # Causal visibility by absolute position, the same for every
+        # window. Chunk 0 shows every row position 0, so m is real from
+        # the first step on and a chunk a row sees nothing of gives it
+        # p = exp(-1e30 - m) = 0 exactly.
+        kv_pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+        live = kv_pos <= q_pos                              # (R·Tb, S)
+        for w in range(n_w):  # static, 128-aligned lane windows
+            k = k_scratch[slot, :, w * W:(w + 1) * W]       # (S, W)
+            v = v_scratch[slot, :, w * W:(w + 1) * W]
+            logits = jax.lax.dot_general(
+                qs_ref[w], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(live, logits, NEG_INF)
+            m_prev = m_ref[w]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)                     # (R·Tb, S)
+            l_ref[w] = alpha * l_ref[w] + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+            m_ref[w] = m_new
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (R·Tb, W)
+            acc_ref[w] = acc_ref[w] * alpha + pv
+        return carry
+
+    jax.lax.fori_loop(0, n_live, chunk_step, 0)
+
+    # Unstack: head h's result is rows [i·Tb, (i+1)·Tb) of its tile,
+    # lanes of its KV head; it goes back to where q_ref had the head.
+    def head_out(h):
+        w, i = divmod(h, R)
+        rows = slice(i * Tb, (i + 1) * Tb)
+        return acc_ref[w, rows, :] / jnp.maximum(l_ref[w, rows, :], 1e-30)
+
+    if D >= 128:
+        for h in range(n_w * R):
+            out_ref[:, h * D:(h + 1) * D] = head_out(h).astype(
+                out_ref.dtype)
+    else:
+        per = 128 // D
+        for g in range(n_w * R // per):    # one 128-lane store each
+            tile = None
+            for h in range(g * per, (g + 1) * per):
+                at = (h * D) % 128
+                to = (h % R // n_rep) * D
+                x = head_out(h)
+                if at != to:
+                    x = pltpu.roll(x, (at - to) % 128, 1)
+                x = _lane_window(x, at, D)
+                tile = x if tile is None else tile + x
+            out_ref[:, g * 128:(g + 1) * 128] = tile.astype(out_ref.dtype)
 
 
 def paged_prefill_attention_pallas(
@@ -171,85 +304,48 @@ def paged_prefill_attention_pallas(
     start_pos: jnp.ndarray,     # scalar int32 — absolute pos of q row 0
     layer: jnp.ndarray | int = 0,
     *,
-    pages_per_chunk: int = 8,
-    q_block: int = 64,
+    pages_per_chunk: int = 0,
+    q_block: int = 0,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Causal paged attention for a prefill chunk. Returns (T, H, D).
 
     Visibility: kv position <= q position (covers both in-chunk
-    causality and previously cached history). Requires H_kv·D % 128 == 0
-    and T % q_block == 0 (the executor's buckets are powers of two).
+    causality and previously cached history). Requires H_kv·D to cut
+    into 128-lane head windows (D a divisor or a multiple of 128).
+    ``pages_per_chunk`` / ``q_block`` = 0 (default) let
+    :func:`prefill_tile_plan` choose.
     """
     T, H, D = q.shape
     L, P, page_size, GD = k_pool.shape
     Hkv = GD // D
     max_pages = block_table.shape[0]
-    n_rep = H // Hkv
-    if GD % 128:
-        raise ValueError(f"H_kv*D = {GD} must be a multiple of 128")
-    qb = min(q_block, T)
-    while T % qb:
-        qb -= 1
-    ppc = min(pages_per_chunk, max_pages)
-    while max_pages % ppc:
-        ppc -= 1
-
-    def vmem_est(qb_, ppc_):
-        # f32 acc/m/l + double-buffered KV scratch + q/out BLOCKS —
-        # Mosaic DOUBLE-BUFFERS grid in/out blocks, so q and out each
-        # cost 2 buffers (undercounting this OOM'd scoped vmem for
-        # GD=1024 models: 16.94M vs the 16M limit).
-        acc = qb_ * H * (GD + 2) * 4
-        kv = 2 * 2 * ppc_ * page_size * GD * k_pool.dtype.itemsize
-        qo = 2 * 2 * qb_ * H * GD * q.dtype.itemsize
-        return acc + kv + qo
-
-    # Stay under the ~16 MB VMEM scoped limit with headroom: shrink the
-    # KV chunk first (large pages made the default 8-page chunk 2 MB+
-    # per buffer), then the q block.
-    while ppc > 1 and vmem_est(qb, ppc) > 10 * 2**20:
-        ppc = max(1, ppc // 2)
-        while max_pages % ppc:
-            ppc -= 1
-    while qb > 8 and vmem_est(qb, ppc) > 10 * 2**20:
-        qb //= 2
-        while T % qb:
-            qb -= 1
-    n_qb = T // qb
-    num_chunks = max_pages // ppc
-
-    # Block-diagonal q rows: row (t, h) carries q[t, h] in group block.
-    eye = jnp.eye(Hkv, dtype=q.dtype)
-    q_bd = jnp.einsum("tgrd,gh->tgrhd", q.reshape(T, Hkv, n_rep, D),
-                      eye).reshape(T * H, GD)
+    plan = prefill_tile_plan(
+        T, H, Hkv, D, page_size, max_pages, k_pool.dtype.itemsize,
+        q_block=q_block, pages_per_chunk=pages_per_chunk)
+    Tb, S, W = plan.q_block, plan.chunk_tokens, plan.lane_width
+    n_w, rows = plan.num_windows, plan.heads_per_tile * plan.q_block
 
     kernel = functools.partial(
-        _prefill_attn_kernel,
-        pages_per_chunk=ppc,
-        page_size=page_size,
-        num_chunks=num_chunks,
-        q_block=qb,
-        n_heads=H,
-        scale=D ** -0.5,
-    )
-    TbH = qb * H
+        _prefill_attn_kernel, plan=plan, page_size=page_size, head_dim=D,
+        n_rep=H // Hkv, scale=D ** -0.5)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_qb, num_chunks),
+        grid=(T // Tb,),
         in_specs=[
-            pl.BlockSpec((TbH, GD), lambda b, c, *_: (b, 0)),
+            pl.BlockSpec((Tb, H * D), lambda b, *_: (b, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((TbH, GD), lambda b, c, *_: (b, 0)),
+        out_specs=pl.BlockSpec((Tb, H * D), lambda b, *_: (b, 0)),
         scratch_shapes=[
-            pltpu.VMEM((TbH, 1), jnp.float32),
-            pltpu.VMEM((TbH, 1), jnp.float32),
-            pltpu.VMEM((TbH, GD), jnp.float32),
-            pltpu.VMEM((2, ppc, page_size, GD), k_pool.dtype),
-            pltpu.VMEM((2, ppc, page_size, GD), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2, ppc)),
+            pltpu.VMEM((n_w, rows, W), k_pool.dtype),
+            pltpu.VMEM((n_w, rows, 1), jnp.float32),
+            pltpu.VMEM((n_w, rows, 1), jnp.float32),
+            pltpu.VMEM((n_w, rows, W), jnp.float32),
+            pltpu.VMEM((2, S, GD), k_pool.dtype),
+            pltpu.VMEM((2, S, GD), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     meta = jnp.stack([jnp.asarray(start_pos, jnp.int32),
@@ -257,13 +353,11 @@ def paged_prefill_attention_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T * H, GD), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, H * D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(block_table.astype(jnp.int32), meta,
-      q_bd, k_pool, v_pool)
-    # Extract each row's diagonal block: (T·H, GD) → (T, H, D).
-    out5 = out.reshape(T, Hkv, n_rep, Hkv, D)
-    res = jnp.einsum("tgrhd,gh->tgrd", out5, jnp.eye(Hkv, dtype=out.dtype))
-    return res.reshape(T, H, D).astype(q.dtype)
+      q.reshape(T, H * D), k_pool, v_pool)
+    return out.reshape(T, H, D)

@@ -3,7 +3,13 @@
 after the server child has exited.
 
 At the geometry ``load_config()`` resolves (the same file and ``LLMQ_*``
-environment the serve phase ran with, so the two agree by construction):
+environment the serve phase ran with, so the two agree by construction)
+or, with ``--model-file``, at the geometry of a benchmark configuration
+(``benchmark/configs/*.json``: its Hugging Face model block registered
+as one more ``MODEL_CONFIGS`` entry, its ``server`` block as the
+configuration file, ``LLMQ_*`` still on top — the float32 run does not
+fit beside a deployment's pool and batch, so the SmolLM2 check runs
+with ``LLMQ_EXECUTOR_KV_PAGES=512 LLMQ_EXECUTOR_MAX_BATCH_SIZE=8``):
 
 (a) builds the engine through ``build_engine(cfg, warmup=True)`` — the
     programs the server compiled, loaded from the caches it left — and
@@ -339,9 +345,26 @@ def compare(a: Dict, b: Dict, tol: float | None, label: str,
     return res
 
 
+def register_model_file(path: str) -> Dict[str, Any]:
+    """Register the model of a benchmark configuration file, as the
+    benchmark's own child does, and return the file's ``server`` block,
+    which is a configuration file of the program."""
+    from benchmark.harness import contract
+    from benchmark.harness.child import register_model
+
+    with open(path, "r", encoding="utf-8") as f:
+        doc = json.load(f)
+    register_model(doc["server"]["model"]["name"],
+                   {k: doc[k] for k in contract.MODEL_KEYS if k in doc})
+    return doc["server"]
+
+
 def main(argv: List[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--out", default="")
+    ap.add_argument("--model-file", default="",
+                    help="a benchmark configuration (benchmark/configs/"
+                         "*.json): check its model at its server geometry")
     ap.add_argument("--tiny", action="store_true",
                     help="CPU unit test: keep the configured (tiny) "
                          "model and skip the TPU-only assertions")
@@ -359,7 +382,14 @@ def main(argv: List[str] | None = None) -> int:
     on_tpu = ident["platform"] == "tpu"
     check(on_tpu or args.tiny,
           f"kernel phase came up on {ident}, not a TPU")
-    cfg = load_config()
+    if args.model_file:
+        import tempfile
+        with tempfile.NamedTemporaryFile("w", suffix=".json") as f:
+            json.dump(register_model_file(args.model_file), f)  # is YAML
+            f.flush()
+            cfg = load_config(f.name)
+    else:
+        cfg = load_config()
     cfg.executor.backend = "jax"
     t0 = time.perf_counter()
     engine = build_engine(cfg, warmup=True)

@@ -190,6 +190,12 @@ class TestKernelRoutes:
         assert attention.kernel_routes(decode=True, **q8_128) == {
             "decode_attention": "pallas:_fused_kernel_q8",
             "decode_write": "pallas:_fused_kernel_q8"}
+        # A head that fills neither a divisor nor a multiple of 128
+        # lanes has no head window: prefill attention alone goes to XLA.
+        d96 = dict(self.GEOM, n_heads=16, n_kv_heads=4, head_dim=96)
+        assert attention.kernel_routes(prefill_rows=1, **d96) == {
+            "prefill_write": "pallas:_kv_prefill_kernel",
+            "prefill_attention": "xla"}
         # Mesh programs trace with pallas off.
         off = dict(self.GEOM, enabled=False)
         assert set(attention.kernel_routes(

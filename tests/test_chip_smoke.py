@@ -158,6 +158,26 @@ class TestKernelCheckLogic:
         with pytest.raises(kc.CheckFailure, match="RMS"):
             kc.compare(a, far, None, "shift", rms_tol=kc.TOL_W8A8_RMS)
 
+    def test_model_file_gives_the_benchmark_geometry(self, monkeypatch):
+        """``--model-file``: the benchmark's SmolLM2 configuration (read,
+        not edited) becomes a registered model, and its ``server`` block
+        loads as the program's configuration: the shape the cells run."""
+        from llmq_tpu.models import llama
+
+        kc = _load_kernel_check()
+        monkeypatch.setattr(llama, "MODEL_CONFIGS",
+                            dict(llama.MODEL_CONFIGS))
+        server = kc.register_model_file(os.path.join(
+            REPO, "benchmark", "configs", "smollm2-1.7b-bf16.json"))
+        mcfg = llama.get_config(server["model"]["name"])
+        assert (mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim) == (32, 32, 64)
+        assert (mcfg.n_layers, mcfg.dim, mcfg.tie_embeddings) == (
+            24, 2048, True)
+        assert llama.get_config(mcfg.name, n_layers=2).n_layers == 2
+        ex = server["executor"]
+        assert (ex["page_size"], ex["max_batch_size"]) == (16, 32)
+        assert server["model"]["max_seq_len"] // ex["page_size"] == 256
+
     def test_schedule_fits_the_executor_geometry(self):
         from types import SimpleNamespace
 

@@ -378,35 +378,144 @@ class TestFusedDecode:
                                       np.asarray(rv)[:, 1:])
 
 
+#: name -> (H, H_kv, D, page_size, max_pages, T, pages_per_chunk, q_block).
+#: ``gqa-2x64-ps8`` is the shape the kernel was first tested at; the
+#: others are the served head layouts scaled down: full multi-head D 64
+#: (SmolLM2: two heads share a 128-lane window), GQA D 64 (llama3-1b:
+#: half the query heads roll to their KV head's lanes), GQA D 128
+#: (llama3-8b: a window per KV head) and a 128-token page.
+_PREFILL_GEOMS = {
+    "gqa-2x64-ps8": (4, 2, 64, 8, 8, 16, 2, 8),
+    "mha-4x64": (4, 4, 64, 16, 8, 32, 2, 16),
+    "gqa-2x64-rep4": (8, 2, 64, 16, 8, 32, 2, 16),
+    "gqa-2x128": (4, 2, 128, 16, 8, 32, 2, 16),
+    "gqa-2x128-ps128": (4, 2, 128, 128, 2, 32, 1, 16),
+    "mha-4x64-plan": (4, 4, 64, 16, 32, 64, 0, 0),
+}
+
+
+def _prefill_case(geom, start, dtype):
+    """One call of the kernel and of gather + blockwise on the same
+    pool; returns (kernel output, reference), both (T, H, D)."""
+    from llmq_tpu.ops.pallas.prefill_attention import (
+        paged_prefill_attention_pallas)
+    H, Hkv, D, ps, mp, T, ppc, qb = _PREFILL_GEOMS[geom]
+    rng = np.random.default_rng(start)
+    L, P = 2, mp + 3
+    k_pool = jnp.asarray(rng.standard_normal((L, P, ps, Hkv * D)), dtype)
+    v_pool = jnp.asarray(rng.standard_normal((L, P, ps, Hkv * D)), dtype)
+    bt = jnp.asarray(rng.permutation(np.arange(1, P))[:mp], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((1, T, H, D)), dtype)
+    positions = (start + jnp.arange(T))[None, :].astype(jnp.int32)
+    seq_lens = jnp.asarray([start + T], jnp.int32)
+
+    k_hist = k_pool[1, bt[None]].reshape(1, mp * ps, Hkv, D)
+    v_hist = v_pool[1, bt[None]].reshape(1, mp * ps, Hkv, D)
+    # (gathered VALUES may be unflattened freely; the pool may not)
+    ref = blockwise_prefill_attention(q, k_hist, v_hist, positions,
+                                      seq_lens)
+    out = paged_prefill_attention_pallas(
+        q[0], k_pool, v_pool, bt, jnp.int32(start), 1,
+        pages_per_chunk=ppc, q_block=qb, interpret=True)
+    assert out.shape == (T, H, D) and out.dtype == q.dtype
+    return np.asarray(out, np.float32), np.asarray(ref[0], np.float32)
+
+
 class TestPrefillAttentionKernel:
     @pytest.mark.parametrize("start", [0, 24])
     def test_matches_blockwise(self, start):
         """Paged prefill attention kernel == gather + blockwise, for a
         fresh prompt (start=0) and a continuation chunk (start=24)."""
-        from llmq_tpu.ops.pallas.prefill_attention import (
-            paged_prefill_attention_pallas)
-        rng = np.random.default_rng(start)
-        L, P, ps, Hkv, D, H = 2, 24, 8, 2, 64, 4
-        T, mp = 16, 8
-        k_pool = jnp.asarray(rng.standard_normal((L, P, ps, Hkv * D)),
-                             jnp.float32)
-        v_pool = jnp.asarray(rng.standard_normal((L, P, ps, Hkv * D)),
-                             jnp.float32)
-        bt = jnp.asarray(rng.permutation(np.arange(1, P))[:mp], jnp.int32)
-        q = jnp.asarray(rng.standard_normal((1, T, H, D)), jnp.float32)
-        positions = (start + jnp.arange(T))[None, :].astype(jnp.int32)
-        seq_lens = jnp.asarray([start + T], jnp.int32)
+        out, ref = _prefill_case("gqa-2x64-ps8", start, jnp.float32)
+        np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
-        k_hist = k_pool[1, bt[None]].reshape(1, mp * ps, Hkv, D)
-        v_hist = v_pool[1, bt[None]].reshape(1, mp * ps, Hkv, D)
-        # (gathered VALUES may be unflattened freely; the pool may not)
-        ref = blockwise_prefill_attention(q, k_hist, v_hist, positions,
-                                          seq_lens)
-        out = paged_prefill_attention_pallas(
-            q[0], k_pool, v_pool, bt, jnp.int32(start), 1,
-            pages_per_chunk=2, q_block=8, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref[0]),
-                                   atol=3e-2, rtol=3e-2)
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("start", [0, 21, 70])
+    @pytest.mark.parametrize("geom", ["mha-4x64", "gqa-2x64-rep4",
+                                      "gqa-2x128"])
+    def test_head_layouts_and_offsets(self, geom, start, dtype):
+        """Every served head layout at a fresh prompt (chunks 1-3 dead),
+        an offset that is not page-aligned (the slice ends part way
+        into chunk 1) and one beyond two whole 32-token chunks (the
+        chunk boundary at 96 falls inside the slice), in float32 and in
+        the serving dtype (bf16 operands, f32 accumulation on both
+        sides, so they differ by one rounding of the output)."""
+        out, ref = _prefill_case(geom, start, dtype)
+        tol = 1e-4 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_128_token_pages(self, dtype):
+        """llama3-8b's serving page: one page a chunk, the slice
+        (positions 100-131) astride the page boundary."""
+        out, ref = _prefill_case("gqa-2x128-ps128", 100, dtype)
+        tol = 1e-4 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
+
+    def test_plan_chooses_tiles_when_not_pinned(self):
+        """No ``q_block`` / ``pages_per_chunk``: the plan's own tiles
+        (a 256-token chunk over 16-token pages) give the same result."""
+        out, ref = _prefill_case("mha-4x64-plan", 230, jnp.float32)
+        np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
+
+
+#: The bf16 serving geometries: name -> (H, H_kv, D, page_size,
+#: max_pages, grid steps of the block-diagonal plan this one replaced
+#: for a 256-token slice: (T / qb) x (max_pages / ppc) at the qb / ppc
+#: its VMEM estimate collapsed to).
+_SERVED = {
+    "smollm2-1.7b": (32, 32, 64, 16, 256, 8192),
+    "llama3-1b": (32, 8, 64, 16, 256, 2048),
+    "llama3-8b": (32, 8, 128, 16, 256, 4096),
+    "llama3-8b-ps128": (32, 8, 128, 128, 16, 2 * 16),
+}
+
+
+class TestPrefillTilePlan:
+    """The plan is a pure function of the shapes, so the regression
+    nobody saw (the kernel was only ever tested at H = 4, GD = 128 while
+    SmolLM2's H = 32, GD = 2048 collapsed it to 8-token q blocks and
+    one-page chunks) is held here, on the CPU, at the served widths."""
+
+    @pytest.mark.parametrize("T", [256, 1024])
+    @pytest.mark.parametrize("name", sorted(_SERVED))
+    def test_served_geometries(self, name, T):
+        from llmq_tpu.ops.pallas.prefill_attention import (
+            VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES, prefill_tile_plan)
+        H, Hkv, D, ps, mp, old_steps = _SERVED[name]
+        plan = prefill_tile_plan(T, H, Hkv, D, ps, mp, 2)
+        assert plan.vmem_bytes <= VMEM_BUDGET_BYTES < VMEM_LIMIT_BYTES
+        assert plan.lane_waste <= 128 / D
+        assert plan.lane_width % 128 == 0
+        assert plan.num_windows * plan.lane_width == Hkv * D
+        assert T % plan.q_block == 0 and plan.q_block % 16 == 0
+        assert mp % plan.pages_per_chunk == 0
+        assert plan.chunk_tokens >= min(256, mp * ps)
+        if T == 256 and ps == 16:
+            # A 256-token slice that ends at a 560-token context.
+            assert plan.steps(T, 304) * 50 <= old_steps
+            # The loop follows the context, not the block table.
+            assert plan.steps(T, 304) < plan.steps(T, 3000)
+        assert plan.steps(T, 0) <= (T // plan.q_block) * plan.num_chunks
+
+    def test_steps_count_live_chunks_only(self):
+        from llmq_tpu.ops.pallas.prefill_attention import prefill_tile_plan
+        plan = prefill_tile_plan(256, 32, 32, 64, 16, 256, 2)
+        # q blocks of 128 over 256-token chunks: the block that ends at
+        # position 431 sees chunks 0-1, the one that ends at 559 0-2.
+        assert (plan.q_block, plan.chunk_tokens) == (128, 256)
+        assert plan.steps(256, 304) == 2 + 3
+        # Past the block table's end every chunk is live, none more.
+        assert plan.steps(256, 4096) == 2 * plan.num_chunks
+
+    def test_rejects_heads_that_do_not_fill_lanes(self):
+        from llmq_tpu.ops.pallas.prefill_attention import prefill_tile_plan
+        with pytest.raises(ValueError, match="128-lane"):
+            prefill_tile_plan(16, 4, 1, 64, 16, 8, 2)     # GD = 64
+        with pytest.raises(ValueError, match="128-lane"):
+            prefill_tile_plan(16, 4, 4, 96, 16, 8, 2)     # D = 96
 
 
 class TestBlockwisePrefill:
