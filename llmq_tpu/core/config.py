@@ -816,7 +816,7 @@ class MetricsConfig:
 @dataclass
 class ModelConfig:
     """Execution-plane model selection (new scope; BASELINE configs #2/#5)."""
-    name: str = "llama3-tiny"          # llama3-tiny | llama3-8b | llama3-70b
+    name: str = "llama3-tiny"          # a key of models/llama.py MODEL_CONFIGS
     checkpoint_path: str = ""           # orbax checkpoint dir; empty → random init
     tokenizer_path: str = ""            # local HF tokenizer dir; empty → bytes
     # Safetensors re-exports of Meta-original interleaved-rotary
@@ -956,7 +956,7 @@ class RaggedAttentionConfig:
     prefill routes through the ragged program), the engine packs
     slices against the token budget instead of fixed slice widths, and
     the warmup/compile/export surface shrinks to {ragged_chunk,
-    decode, decode_chunk}. ``enabled: false`` (the DEFAULT) is a hard
+    decode_chunk}. ``enabled: false`` (the DEFAULT) is a hard
     off-switch: the bucket/fused path is byte-identical to
     pre-ragged behavior."""
     enabled: bool = False

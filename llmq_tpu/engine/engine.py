@@ -519,8 +519,12 @@ class InferenceEngine:
         # Per-engine recorder: stats must not mix spans across engines.
         # The one span primitive (utils/profiling.py): the step's
         # vocabulary below lands in this ring and, while a profiler
-        # capture is held, on the device trace's clock.
-        self._prof = SpanRecorder()
+        # capture is held, on the device trace's clock. A JAX
+        # executor brings the ring its warm-up wrote to
+        # (``engine.warmup.compile``, one span per program).
+        self._prof = getattr(executor, "spans", None)
+        if self._prof is None:
+            self._prof = SpanRecorder()
         #: Device telemetry plane (observability/device.py): step-time
         #: decomposition, live tok/s + MFU, HBM accounting — shared by
         #: name with the executor (compile-cache side) and read live by

@@ -37,6 +37,7 @@ from typing import Callable, Dict, List, Optional, Protocol, Tuple
 import numpy as np
 
 from llmq_tpu.utils.logging import get_logger
+from llmq_tpu.utils.profiling import SpanRecorder
 
 log = get_logger("executor")
 
@@ -856,7 +857,7 @@ class JaxExecutor:
         #: the same compiled geometry), per-bucket prefill programs
         #: are neither built nor compiled — ALL prefill routes through
         #: the ragged program — and the warmup/export surface shrinks
-        #: to {ragged_chunk, decode, decode_chunk}. OFF (default):
+        #: to {ragged_chunk, decode_chunk}. OFF (default):
         #: byte-identical bucket/fused behavior. Mesh path stays on
         #: buckets: the ragged kernel is a single-chip program.
         self.ragged_attention = bool(
@@ -1347,8 +1348,15 @@ class JaxExecutor:
         #: engine exists to set the flag — a metrics-off bench/engine
         #: must not have its warmup write prometheus families.
         from llmq_tpu.observability.device import (BACKEND_COMPILES,
+                                                   XLA_CACHE,
                                                    get_device_telemetry)
         BACKEND_COMPILES.watch()
+        XLA_CACHE.watch()
+        #: The host-span ring (utils/profiling.py). The warm-up opens
+        #: one ``engine.warmup.compile`` span per program in it; the
+        #: engine built over this executor takes it as its own, so the
+        #: spans show behind ``/api/v1/engine/stats`` ``profile``.
+        self.spans = SpanRecorder()
         self._telemetry = get_device_telemetry(telemetry_name,
                                                metrics=telemetry_metrics)
         self._telemetry.configure_model(**self.telemetry_info())
@@ -1673,6 +1681,8 @@ class JaxExecutor:
         from jax import export as jexport
         from concurrent.futures import ThreadPoolExecutor
 
+        from llmq_tpu.observability.device import XLA_CACHE
+
         jnp = self._jnp
         spec = self.spec
 
@@ -1722,15 +1732,20 @@ class JaxExecutor:
                                   key),
                                  self._routes(prefill_rows=NPF)))
         dec_routes = self._routes(decode=True)
-        jobs.append(("decode", self._decode_step,
-                     (p, c, bsds((B,), i32), bsds((B,), i32),
-                      bsds((B, MP), i32), bsds((B,), f32), key),
-                     dec_routes))
         if self.chunk_size > 1:
+            # The engine decodes through ``decode_chunk`` whenever the
+            # chunk is longer than one step (its admission cap never
+            # goes below 2), so the single-step program would be
+            # compiled, kept in the cache and never dispatched.
             jobs.append(("decode_chunk", self._decode_chunk,
                          (p, c, bsds((B,), i32), bsds((B,), i32),
                           bsds((B, MP), i32), bsds((B,), f32),
                           bsds((B,), i32), bsds((B,), jnp.bool_), key),
+                         dec_routes))
+        else:
+            jobs.append(("decode", self._decode_step,
+                         (p, c, bsds((B,), i32), bsds((B,), i32),
+                          bsds((B, MP), i32), bsds((B,), f32), key),
                          dec_routes))
         if self._verify_chunk is not None:
             Wv = self.verify_draft_k + 1
@@ -1773,14 +1788,32 @@ class JaxExecutor:
         if exp_dir:
             os.makedirs(exp_dir, exist_ok=True)
 
-        def note(name: str, t0: float, cache_hit: bool) -> None:
+        def note(name: str, t0: float, how: str,
+                 asked: Tuple[int, int]) -> None:
             # Compile-cache observability (docs/observability.md
             # "Device telemetry"): per-program compile seconds +
             # hit/miss counters + the warmup-progress gauge, so the
-            # geometry grid's compile cost is attributable per program.
+            # geometry grid's compile cost is attributable per program
+            # — and, apart from the export artifact, whether XLA's own
+            # cache still held the executable and what it weighs.
+            # Logged here, as each program ends, not in job order.
             dt = time.perf_counter() - t0
-            self._telemetry.note_compile(name, dt, cache_hit,
-                                         routes=self.program_routes[name])
+            cache_hit = how == "export cache"
+            xla = XLA_CACHE.outcome(asked)
+            # Sized only where XLA's cache served it: the runtime still
+            # holds those bytes (under 1 s for SmolLM2's six programs,
+            # 600 MB). A freshly COMPILED executable is not sized — a
+            # first start is when a size is least missed — and the
+            # next start reports it.
+            nbytes = (len(self._aot[name].runtime_executable().serialize())
+                      if xla == "hit" else None)
+            routes = self.program_routes[name]
+            self._telemetry.note_compile(name, dt, cache_hit, routes=routes,
+                                         xla_cache=xla,
+                                         executable_bytes=nbytes)
+            log.info("warmup compiled %s (%s, xla cache %s, %.1f s, "
+                     "%s bytes) routes: %s", name, how, xla, dt, nbytes,
+                     " ".join(f"{op}={impl}" for op, impl in routes.items()))
             with self._warm_mu:
                 self._warm_done += 1
                 done = self._warm_done
@@ -1794,7 +1827,7 @@ class JaxExecutor:
                     self._warm_miss_s += dt
             self._telemetry.note_warmup(done, len(jobs))
 
-        def compile_one(job):
+        def compile_one(job) -> None:
             # No fallback in here: a failed export-cache load, a failed
             # export or a Mosaic rejection fails the warm-up with the
             # compiler's own message — on the chip those are exactly
@@ -1802,10 +1835,11 @@ class JaxExecutor:
             name, fn, args, routes = job
             self.program_routes[name] = routes
             t0 = time.perf_counter()
+            asked = XLA_CACHE.mark()
             if not exp_dir:
                 self._aot[name] = fn.lower(*args).compile()
-                note(name, t0, cache_hit=False)
-                return name, "compiled"
+                note(name, t0, "compiled", asked)
+                return
             path = os.path.join(exp_dir, f"{exp_key}-{name}.jaxexp")
             hit = os.path.exists(path)
             if hit:
@@ -1834,8 +1868,11 @@ class JaxExecutor:
                 with open(tmp, "wb") as f:
                     f.write(exported.serialize())
                 os.replace(tmp, path)
-            note(name, t0, cache_hit=hit)
-            return name, "export cache" if hit else "exported"
+            note(name, t0, "export cache" if hit else "exported", asked)
+
+        def compile_in_span(job) -> None:
+            with self.spans.span("engine.warmup.compile", program=job[0]):
+                compile_one(job)
 
         with self._warm_mu:
             self._warm_done = 0
@@ -1843,10 +1880,7 @@ class JaxExecutor:
             self._warm_miss_s = 0.0
         self._telemetry.note_warmup(0, len(jobs))
         with ThreadPoolExecutor(max_workers=len(jobs)) as pool:
-            for name, how in pool.map(compile_one, jobs):
-                log.info("warmup compiled %s (%s) routes: %s", name, how,
-                         " ".join(f"{op}={impl}" for op, impl in
-                                  self.program_routes[name].items()))
+            list(pool.map(compile_in_span, jobs))    # raises a job's error
 
     def warmup(self) -> None:
         """Compile the decode step and every prefill bucket up front
@@ -1914,7 +1948,8 @@ class JaxExecutor:
         zeros_b = np.zeros(spec.batch_size, np.int32)
         zbt = np.zeros((spec.batch_size, spec.max_pages_per_seq), np.int32)
         ztemp = np.zeros(spec.batch_size, np.float32)
-        self.decode(zeros_b, zeros_b, zbt, ztemp)
+        if self.chunk_size == 1:
+            self.decode(zeros_b, zeros_b, zbt, ztemp)
         if self._mixed_chunk is not None:
             # Mixed-chunk smoke: one trash slice + 1-step decode
             # budgets, all writes land on reserved page 0.

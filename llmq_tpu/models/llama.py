@@ -1,4 +1,5 @@
-"""Llama-3 family in pure JAX (functional, scan-over-layers, paged KV).
+"""The Llama block in pure JAX (functional, stacked layers, paged KV):
+the Llama-3 family and Mistral-7B-v0.3, which is the same block.
 
 New scope: the reference serves models behind external HTTP endpoints and
 has no model code (SURVEY.md §2.2); this is the in-tree TPU model layer
@@ -6,9 +7,15 @@ for BASELINE configs #2/#3/#5 (8B single chip, KV reuse, 70B TP).
 
 Design notes (TPU-first):
 
-- **Stacked layer parameters + ``lax.scan``**: one trace/compile of the
-  layer body instead of n_layers copies — compile time stays flat from
-  tiny to 70B.
+- **Stacked layer parameters** (leading dim L), and a layer loop that
+  is UNROLLED wherever a layer calls a Pallas kernel that aliases the
+  KV pool — decode, mixed and verify steps, and prefill over bf16
+  pools: around such a call a pool carried through ``lax.scan`` was
+  copied whole once a layer (2-8x slower decode steps on the v5e), so
+  those programs pay for their depth at compile time instead. Prefill
+  over INT8 pools has no such call and is ROLLED (``lax.fori_loop``,
+  the pools as its carry): 6 s to compile against 220 s at 32 layers.
+  The table is in docs/performance.md, "Unrolled decode layers".
 - **Paged KV cache**: global page pools ``(L, P, page_size, H_kv, D)``
   indexed by per-sequence block tables. Static shapes everywhere: one
   compiled program per (batch, max_pages) bucket, regardless of actual
@@ -109,11 +116,28 @@ def llama3_70b(**kw) -> LlamaConfig:
         rope_theta=500000.0), **kw)
 
 
+def mistral_7b_v03(**kw) -> LlamaConfig:
+    """mistralai/Mistral-7B-v0.3 at its published sizes
+    (https://huggingface.co/mistralai/Mistral-7B-v0.3/blob/main/config.json):
+    hidden 4096, 32 layers, 32 query heads over 8 KV heads of 128,
+    FFN 14,336, vocabulary 32,768, RoPE theta 1e6, context 32,768,
+    untied head, no bias; v0.3 has no sliding window, so the block is
+    this file's Llama block unchanged. 7.25 B parameters: on one 16 GB
+    v5e chip it is served as ``quantization: int8`` over
+    ``kv_quantization: int8`` with a shorter ``max_seq_len``
+    (benchmark/configs/mistral-7b-v0.3-w8kv8.json)."""
+    return replace(LlamaConfig(
+        name="mistral-7b-v0.3", vocab_size=32768, dim=4096, n_layers=32,
+        n_heads=32, n_kv_heads=8, ffn_dim=14336, max_seq_len=32768,
+        rope_theta=1000000.0), **kw)
+
+
 MODEL_CONFIGS = {
     "llama3-tiny": llama3_tiny,
     "llama3-1b": llama3_1b,
     "llama3-8b": llama3_8b,
     "llama3-70b": llama3_70b,
+    "mistral-7b-v0.3": mistral_7b_v03,
 }
 
 
@@ -332,19 +356,9 @@ def forward_prefill(
     last_pos = jnp.max(jnp.where(valid, positions, -1), axis=1)
     seq_lens = last_pos + 1                                # (B,)
 
-    # Layers UNROLLED, one stacked pool threaded through per-layer
-    # aliased Pallas writes (B==1 serving prefill) — same structure and
-    # rationale as forward_decode below: any scan formulation makes XLA
-    # materialize pool copies (ys restack per call; carried pools
-    # degrade to per-layer full copies), and XLA scatter costs ~13µs
-    # per row. The pure-JAX fallback (general B / CPU) scatters into
-    # the threaded pool instead.
     lp = params["layers"]
-    quant_kv = "k_scale" in kv_cache
-    k_pool, v_pool = kv_cache["k"], kv_cache["v"]
-    if quant_kv:
-        pools = (k_pool, v_pool, kv_cache["k_scale"], kv_cache["v_scale"])
-    for l in range(cfg.n_layers):
+
+    def qkv(h, l):
         hn = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps)
         q = linear(hn, layer_slice(lp["wq"], l)).reshape(
             B, T, cfg.n_heads, cfg.head_dim)
@@ -352,17 +366,51 @@ def forward_prefill(
             B, T, cfg.n_kv_heads, cfg.head_dim)
         v = linear(hn, layer_slice(lp["wv"], l)).reshape(
             B, T, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if quant_kv:
-            # int8 pools: quantized write + dequantizing attention
-            # (ops/attention.py int8 section).
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+    def out_mlp(h, attn, l):
+        h = h + linear(attn.reshape(B, T, -1), layer_slice(lp["wo"], l))
+        hn2 = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
+        return h + _mlp(hn2, layer_slice(lp["w_gate"], l),
+                        layer_slice(lp["w_up"], l),
+                        layer_slice(lp["w_down"], l))
+
+    if "k_scale" in kv_cache:
+        # int8 pools: quantized write + dequantizing attention
+        # (ops/attention.py int8 section), layers ROLLED: one
+        # ``fori_loop`` body over the stacked parameters with the four
+        # pools as its carry. The one serving program with no aliased
+        # Pallas call in it (the writes are ``.at[].set`` scatters,
+        # which XLA updates in place inside a loop body), so the reason
+        # the others unroll does not hold — and unrolled it compiled
+        # longest of all (docs/performance.md "Unrolled decode layers").
+        def layer(l, carry):
+            h, pools = carry
+            q, k, v = qkv(h, l)
             pools = paged_kv_write_prefill_q8(
-                pools, k, v, block_tables, positions, lengths,
-                jnp.int32(l))
+                pools, k, v, block_tables, positions, lengths, l)
             attn = dispatch_prefill_attention_q8(
                 q, pools, block_tables, positions, seq_lens, l)
-        else:
+            return out_mlp(h, attn, l), pools
+
+        h, pools = lax.fori_loop(
+            0, cfg.n_layers, layer,
+            (h, (kv_cache["k"], kv_cache["v"], kv_cache["k_scale"],
+                 kv_cache["v_scale"])))
+        out_cache = {"k": pools[0], "v": pools[1],
+                     "k_scale": pools[2], "v_scale": pools[3]}
+    else:
+        # bf16 pools: layers UNROLLED, one stacked pool threaded
+        # through per-layer aliased Pallas writes (B==1 serving
+        # prefill) — same structure and rationale as forward_decode
+        # below: any scan formulation makes XLA materialize pool
+        # copies around an aliased kernel call (ys restack per call;
+        # carried pools degrade to per-layer full copies), and XLA
+        # scatter costs ~13µs per row. The pure-JAX fallback (general
+        # B / CPU) scatters into the threaded pool instead.
+        k_pool, v_pool = kv_cache["k"], kv_cache["v"]
+        for l in range(cfg.n_layers):
+            q, k, v = qkv(h, l)
             # Write this layer's KV into its slice of the pool.
             k_pool, v_pool = paged_kv_write_prefill(
                 k_pool, v_pool, k, v, block_tables, positions, lengths,
@@ -373,18 +421,11 @@ def forward_prefill(
             attn = dispatch_prefill_attention(
                 q, k_pool, v_pool, block_tables, positions, seq_lens, l,
                 enabled=cfg.pallas, multi_ok=cfg.pallas_batched_prefill)
-        h = h + linear(attn.reshape(B, T, -1), layer_slice(lp["wo"], l))
-        hn2 = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
-        h = h + _mlp(hn2, layer_slice(lp["w_gate"], l),
-                     layer_slice(lp["w_up"], l), layer_slice(lp["w_down"], l))
+            h = out_mlp(h, attn, l)
+        out_cache = {"k": k_pool, "v": v_pool}
     if last_only:
         h = h[jnp.arange(B), lengths - 1]                  # (B, D)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    if quant_kv:
-        out_cache = {"k": pools[0], "v": pools[1],
-                     "k_scale": pools[2], "v_scale": pools[3]}
-    else:
-        out_cache = {"k": k_pool, "v": v_pool}
     return _logits(params, h), out_cache
 
 

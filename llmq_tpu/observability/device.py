@@ -38,7 +38,7 @@ import tempfile
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from llmq_tpu.utils.logging import get_logger
 
@@ -187,6 +187,56 @@ class _BackendCompiles:
 
 
 BACKEND_COMPILES = _BackendCompiles()
+
+
+class _XlaCacheLookups:
+    """What XLA's persistent compilation cache answered, per THREAD,
+    from JAX's own monitoring events (one when a compile asks the
+    cache, one more when the cache serves it). JAX runs the listener
+    on the thread that compiles, and the warm-up compiles its programs
+    on a thread each, so a thread's two counts read before and after a
+    compile say which way THAT program went (``outcome``)."""
+
+    _ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+    _SERVED = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self) -> None:
+        self._mu = threading.Lock()
+        self._watching = False
+        self._local = threading.local()
+
+    def watch(self) -> None:
+        """Register the listener once (as ``_BackendCompiles.watch``)."""
+        with self._mu:
+            if self._watching:
+                return
+            self._watching = True
+        from jax import monitoring
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, name: str, **_kw) -> None:
+        if name == self._ASKED:
+            self._local.asked = getattr(self._local, "asked", 0) + 1
+        elif name == self._SERVED:
+            self._local.served = getattr(self._local, "served", 0) + 1
+
+    def mark(self) -> Tuple[int, int]:
+        """This thread's (asked, served) counts so far."""
+        return (getattr(self._local, "asked", 0),
+                getattr(self._local, "served", 0))
+
+    def outcome(self, since: Tuple[int, int]) -> str:
+        """``hit``: every compile of this thread since ``since`` was
+        served by the cache; ``miss``: one was compiled; ``off``: none
+        asked (no cache directory, or below its thresholds)."""
+        asked, served = self.mark()
+        asked, served = asked - since[0], served - since[1]
+        if asked == 0:
+            return "off"
+        return "hit" if served == asked else "miss"
+
+
+XLA_CACHE = _XlaCacheLookups()
 
 
 def measure_rtt(samples: int = 5) -> float:
@@ -477,16 +527,27 @@ class DeviceTelemetry:
 
     def note_compile(self, program: str, seconds: float,
                      cache_hit: bool,
-                     routes: Optional[Dict[str, str]] = None) -> None:
+                     routes: Optional[Dict[str, str]] = None,
+                     xla_cache: Optional[str] = None,
+                     executable_bytes: Optional[int] = None) -> None:
         """One program's warmup compile (or export-cache load).
-        ``program`` is a compiled-program name (decode, decode_chunk,
+        ``program`` is a compiled-program name (decode_chunk,
         mixed_chunk, prefill_b<N>…) — a config-bounded label set.
-        ``routes``: which implementation each attention op took
-        (ops/attention.kernel_routes)."""
+        ``cache_hit``: the EXPORT artifact existed (no tracing, no
+        lowering). ``xla_cache``: what XLA's persistent cache answered
+        for the executable itself (``_XlaCacheLookups.outcome``) — the
+        two miss apart: a cache that evicted the entry leaves the
+        artifact and compiles again. ``executable_bytes``: the
+        serialized executable, what a cache entry holds before
+        compression; ``None`` unless XLA's cache served it (a fresh
+        compilation is not sized). ``routes``: which implementation each attention
+        op took (ops/attention.kernel_routes)."""
         with self._mu:
             self._compile[program] = {
                 "seconds": round(seconds, 3),
                 "source": "export_cache" if cache_hit else "compiled",
+                "xla_cache": xla_cache,
+                "executable_bytes": executable_bytes,
                 "routes": dict(routes or {}),
             }
             if cache_hit:

@@ -222,6 +222,16 @@ class TestJaxTelemetry:
         assert comp["cache_hits"] == 0
         assert set(comp["programs"]) == set(ex._aot)
         assert all(p["seconds"] > 0 for p in comp["programs"].values())
+        # ... with what XLA's own cache answered (off here:
+        # tests/conftest.py disables it), apart from whether the
+        # export artifact existed (``source``), and the executable's
+        # serialized size — taken only on a hit, so none here.
+        assert {(p["xla_cache"], p["executable_bytes"])
+                for p in comp["programs"].values()} == {("off", None)}
+        # One warm-up span a program, in the ring the engine takes over.
+        spans = [sp for sp in ex.spans.snapshot()
+                 if sp.name == "engine.warmup.compile"]
+        assert sorted(sp.meta["program"] for sp in spans) == sorted(ex._aot)
         assert comp["warmup_done"] == comp["warmup_total"]
         assert snap["host_device_rtt_ms"] is not None
         # Model identity feeds the MFU estimator.
@@ -234,6 +244,30 @@ class TestJaxTelemetry:
         assert comp2["cache_hits"] > 0
         srcs = {p["source"] for p in comp2["programs"].values()}
         assert "export_cache" in srcs
+
+    @pytest.mark.parametrize("events, want", [
+        ((), "off"), (("asked",), "miss"), (("asked", "served"), "hit"),
+        (("asked", "served", "asked"), "miss")])
+    def test_xla_cache_outcome_is_per_thread(self, events, want):
+        """``XLA_CACHE`` reads JAX's own monitoring events on the
+        thread that compiled: the warm-up compiles a program a thread,
+        so another thread's lookups must not leak into this one's."""
+        import threading
+
+        from jax import monitoring
+
+        from llmq_tpu.observability.device import XLA_CACHE
+
+        names = {"asked": XLA_CACHE._ASKED, "served": XLA_CACHE._SERVED}
+        XLA_CACHE.watch()
+        since = XLA_CACHE.mark()
+        other = threading.Thread(target=lambda: [
+            monitoring.record_event(n) for n in names.values()])
+        other.start()
+        other.join()
+        for e in events:
+            monitoring.record_event(names[e])
+        assert XLA_CACHE.outcome(since) == want
 
     def test_ragged_warmup_compiles_strictly_fewer_programs(self):
         """Ragged attention collapses the bucket grid: warmup with

@@ -560,10 +560,20 @@ class TestJaxEngineUnderACapture:
                 assert any(a <= e[1] and e[1] + e[2] <= b
                            for a, b in steps)
 
+    def test_warmup_spans_show_in_the_engines_profile(self, captured):
+        """The warm-up opens ``engine.warmup.compile`` once a program in
+        the executor's ring, and the engine built over that executor
+        takes the ring as its own: the spans sit beside the step's in
+        ``get_stats()["profile"]`` (``/api/v1/engine/stats``)."""
+        ex, eng, _lines = captured
+        prof = eng.get_stats()["profile"]
+        assert prof["engine.warmup.compile"]["count"] == len(ex._aot)
+        assert "engine.step" in prof and eng._prof is ex.spans
+
     def test_programs_carry_their_names(self, captured):
         ex, _eng, _lines = captured
         assert {"decode_chunk", "mixed_chunk", "prefill_b16",
-                "prefill_b64", "decode"} <= set(ex._aot)
+                "prefill_b64"} <= set(ex._aot)
         for key, exe in ex._aot.items():
             assert f"jit_{key}" in exe.as_text()[:400], key
         assert ex.program_name("prefill", 9) == "prefill_b16"

@@ -174,12 +174,19 @@ def test_70b_dp2tp8_aot_lowering_compiles():
 
 
 class TestHfImport:
-    """import_hf_llama on a synthetic 2-layer safetensors checkpoint."""
+    """import_hf_llama on a synthetic 2-layer safetensors checkpoint:
+    at the tiny Llama shape, and at a Mistral-7B-v0.3 shape cut to size
+    (GQA 4:1, head 128) — ``MistralForCausalLM`` names its tensors as
+    ``LlamaForCausalLM`` does and has no bias, so one importer loads
+    both."""
 
-    @pytest.fixture
-    def hf_dir(self, tmp_path):
+    @pytest.fixture(params=["llama3-tiny", "mistral-7b-v0.3"])
+    def hf_dir(self, tmp_path, request):
         st = pytest.importorskip("safetensors.numpy")
-        cfg = get_config("llama3-tiny")
+        cut = ({} if request.param == "llama3-tiny" else
+               dict(vocab_size=512, dim=512, n_layers=2, n_heads=4,
+                    n_kv_heads=1, ffn_dim=256))
+        cfg = get_config(request.param, **cut)
         rng = np.random.default_rng(0)
 
         def w(o, i):

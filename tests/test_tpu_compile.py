@@ -1,5 +1,6 @@
-"""Mosaic's verdict without a chip: the serving path's prefill attention
-kernel compiled for a DESCRIBED v5e at the served widths.
+"""The TPU compiler's verdict without a chip: the serving path's prefill
+attention kernel, and the int8-KV prefill program at Mistral-7B-v0.3's
+widths, compiled for a DESCRIBED v5e at the served sizes.
 
 Interpret mode (tests/test_pallas.py) checks the kernel's arithmetic
 and cannot see what the TPU compiler refuses: a lane slice off the
@@ -59,3 +60,56 @@ def test_prefill_attention_compiles_for_v5e(one_chip, name):
         arg((T, H, D), jnp.bfloat16), pool, pool, arg((mp,), jnp.int32),
         arg((), jnp.int32), arg((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_int8_kv_prefill_program_stays_small_for_v5e(one_chip):
+    """The int8-KV prefill program (``forward_prefill(last_only=True)``,
+    what ``prefill_b512`` and the benchmark's logits check run) at
+    Mistral-7B-v0.3's widths and FULL depth, as ``mistral-7b-v0.3-w8kv8``
+    serves it: w8a8 weights, 832 int8 pages of 128 tokens, one 512-token
+    row. Its layers are one rolled loop body. Unrolled, the same program
+    took 220 s to compile here (126 s on the chip's host), 155 MB of
+    code and 568 MB of temporaries, and no run fitted its time limit
+    (PERF.md, PR 26): rolled it measured 6-21 s, 8.3 MB and 12 MB. The
+    limits below sit between the two, so the old graph cannot come back
+    unseen; and the temporaries stay under ONE pool, which is how a
+    carried pool that XLA had begun to copy would show."""
+    import time
+
+    from llmq_tpu.models import llama
+
+    cfg = llama.get_config("mistral-7b-v0.3", max_seq_len=2048,
+                           pallas_batched_prefill=True)
+    pages, page, bucket = 832, 128, 512
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: llama.init_params_quantized(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: llama.init_kv_pages(cfg, pages, page, dtype=jnp.int8)))
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(cache))
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def prefill(params, cache, tokens, positions, lengths, block_tables):
+        return llama.forward_prefill(params, cfg, tokens, positions,
+                                     lengths, cache, block_tables,
+                                     last_only=True)
+
+    t0 = time.perf_counter()
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, arg(1, bucket), arg(1, bucket), arg(1),
+        arg(1, cfg.max_seq_len // page)).compile()
+    seconds = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    assert seconds < 90.0, seconds
+    assert mem.generated_code_size_in_bytes < 40e6
+    assert mem.temp_size_in_bytes < pool_bytes / 8, (
+        mem.temp_size_in_bytes, pool_bytes)
+    # the four pools go in and come out in place
+    assert mem.alias_size_in_bytes >= pool_bytes

@@ -32,9 +32,12 @@ class TestWarmup:
         ex.warmup()
         # Loud failure if the AOT pass fell back: every program must be
         # present (a spec/signature drift would leave _aot empty).
+        # (No single-step ``decode``: with chunks longer than a step
+        # the engine never dispatches it —
+        # test_single_step_program_only_without_chunks.)
         assert set(ex._aot) == {"prefill_b16", "prefill_b32",
                                 "prefill_multi_b16", "prefill_multi_b32",
-                                "decode", "decode_chunk",
+                                "decode_chunk",
                                 "mixed_chunk"}, set(ex._aot)
 
         # Serving goes through the executables and matches the jit path.
@@ -55,6 +58,19 @@ class TestWarmup:
         # whose (never-read-in-production) contents differ between a
         # warmed and an unwarmed executor — compare only the real row.
         assert (out_aot[0] == out_jit[0]).all()
+
+    def test_single_step_program_only_without_chunks(self):
+        """``decode`` and ``decode_chunk`` exclude each other: the
+        warm-up compiles the one the engine can dispatch at this
+        ``chunk_size`` and not the other (at 7 B an executable of tens
+        of MB that no request would ever run)."""
+        ex = build(chunk=1)
+        ex.warmup()
+        assert "decode" in ex._aot and "decode_chunk" not in ex._aot
+        bt = np.zeros((4, ex.spec.max_pages_per_seq), np.int32)
+        out = ex.decode(np.ones(4, np.int32), np.zeros(4, np.int32), bt,
+                        np.zeros(4, np.float32))
+        assert out.shape == (4,)
 
     def test_nothing_compiles_after_warmup(self):
         """The serving loop's eager device ops — the batched-prefill
@@ -140,7 +156,7 @@ class TestWarmup:
 
         ex = build()
         ex.warmup()
-        assert len(list(tmp_path.glob("*.jaxexp"))) == 7   # all exported
+        assert len(list(tmp_path.glob("*.jaxexp"))) == 6   # all exported
 
         bt = np.zeros((4, ex.spec.max_pages_per_seq), np.int32)
         bt[0, :2] = [1, 2]
