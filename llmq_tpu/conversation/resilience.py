@@ -355,6 +355,10 @@ class ResilientStore:
             m.store_op_ms.labels(op=op, outcome=outcome).observe(ms)
         if retries:
             m.store_retries.inc(retries)
+        if self._closed:
+            # Closed but not collected yet: its last samples count, its
+            # breaker and health must not overwrite a live store's.
+            return
         if self._breaker is not None:
             m.store_breaker_state.set(
                 float(STATE_VALUE[self._breaker.state]))

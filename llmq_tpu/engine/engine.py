@@ -298,10 +298,12 @@ class _Sequence:
         #: Hit/miss counted for this REQUEST (first admission only —
         #: a shed-and-rebuilt sequence must not re-count its reuse).
         self.reuse_counted = False
-        #: A prefill slice of this sequence rides the in-flight MIXED
-        #: chunk: no further slice may dispatch until it reconciles
-        #: (positions would collide). Cleared at chunk processing.
-        self.mixed_pending = False
+        #: In-flight MIXED chunks that carry a prefill slice of this
+        #: sequence: one more at each such dispatch, one fewer when the
+        #: chunk is processed. Non-zero keeps the host-assembled paths
+        #: off the sequence (its slices are on the device queue); only a
+        #: carried dispatch may queue the next slice behind them.
+        self.mixed_pending = 0
         #: Prefill tokens actually run for this admission (all dispatch
         #: paths) — feeds the learned prefill-rate EWMA at completion.
         self.pf_tokens_run = 0
@@ -657,6 +659,29 @@ class InferenceEngine:
         #: and a first-seen-key insert could resize it mid-iteration.
         self.pipeline_depth_hist: Dict[int, int] = {
             d: 0 for d in range(1, 5)}
+        #: Why a pipeline fill stopped, one count each time it did
+        #: (``_fill_refusal`` / ``_dispatch_speculative``; keys
+        #: preallocated like the histogram's): ``depth`` the pipeline
+        #: is as deep as configured; ``free_slot`` a row is free and
+        #: something waits for the host (an arrival, a pending request,
+        #: prompt slices) — the batch is NOT full; ``urgent_pending``
+        #: every row is taken but an arrival is not ingested yet or the
+        #: head of the pending heap may preempt; ``cancelled`` a seated
+        #: request was cancelled; ``geometry`` a prefilled row is not in
+        #: the newest chunk's snapshot; ``pages`` the chunk's pages
+        #: would take shedding a sequence; ``row_ended`` a seated row
+        #: can take no further step and only the host ends it;
+        #: ``nothing_to_decode`` no row has budget left beyond the
+        #: chunks in flight; ``spec`` speculation windows never chain;
+        #: ``ragged`` the ragged program starts from host state;
+        #: ``tenancy`` tenant fairness caps budgets in the host's
+        #: assembly only.
+        self.fill_refusals: Dict[str, int] = {
+            k: 0 for k in ("depth", "free_slot", "urgent_pending",
+                           "cancelled", "geometry", "pages", "row_ended",
+                           "nothing_to_decode", "spec", "ragged",
+                           "tenancy")}
+        self._fill_stopped = ""
         #: Host staging buffers for chunk assembly (tokens/positions/
         #: block tables/temps) — per-dispatch np.zeros churn killer.
         #: Budgets stay freshly allocated: the _InflightChunk reads
@@ -1193,7 +1218,7 @@ class InferenceEngine:
                 self._slots[seq.slot] = None
                 seq.slot = None
             seq.first_handle = None
-            seq.mixed_pending = False
+            seq.mixed_pending = 0
             if seq.handle.done:
                 # Finished before the crash: dedup — do NOT re-fail or
                 # re-queue; the worker already owns the outcome.
@@ -1248,17 +1273,29 @@ class InferenceEngine:
         test/bench driving it synchronously.
 
         Pipelined decode (async-capable executors): the oldest
-        dispatched chunk is reconciled here — and when no scheduling
-        work is waiting, the pipeline is first FILLED to
-        ``async_pipeline.depth`` chunks dispatched from the
-        device-carried end state *before* fetching the oldest one's
-        tokens, so the fetch's host↔device round-trip overlaps the
-        in-flight chunks' compute and the device never idles between
-        chunks. Any scheduling work (arrivals, pending admissions,
-        prefills, cancellations) stops speculation and drains the
-        pipeline one chunk per step down to the
-        reconcile-then-fresh-dispatch path, which rebuilds the batch
-        from host state — so scheduling only ever acts on reconciled
+        dispatched chunk is reconciled here — and first the pipeline is
+        FILLED to ``async_pipeline.depth`` chunks dispatched from the
+        newest chunk's device-carried end state *before* the oldest
+        one's tokens are fetched, so the fetch's host↔device round-trip
+        and the host's assembly overlap the in-flight chunks' compute
+        and the device never idles between chunks. Two situations allow
+        a fill (``_can_fill``):
+
+        - nothing waits for the host (no arrival, nothing pending, no
+          prompt slice to run): the next chunk is the decode rows again;
+        - every row is taken — a FULL BATCH — and nobody waiting could
+          displace a row: nothing the fetch would tell the host can
+          seat a request sooner, so the next chunk is dispatched from
+          the carry whatever it has to hold: decode rows, the prompt
+          slices of seated sequences (a carried MIXED chunk), rows
+          joining behind their final slice, with pages found by
+          evicting cache that nobody holds.
+
+        A free row with anyone waiting, a cancellation, or a waiter
+        that may preempt stops the fill and drains the pipeline one
+        chunk per step down to the reconcile-then-fresh-dispatch path,
+        which rebuilds the batch from host state — so seating,
+        preemption and shedding only ever act on reconciled
         bookkeeping."""
         # Chaos seam (docs/robustness.md): kind "error" is absorbed by
         # the loop's except (one lost round); kind "crash" is a
@@ -1314,15 +1351,22 @@ class InferenceEngine:
             # flight (depth 2 = the classic double buffer and the
             # pre-pipeline scheduling: at most ONE speculative dispatch
             # per step, since one chunk is always reconciled below).
+            # The span opens once a fill may go ahead, and says how
+            # many chunks went out and why it stopped; a fill refused
+            # outright is counted in ``fill_refusals`` alone.
             if self._can_fill():
-                with self._prof.span("engine.fill"):
+                with self._prof.span("engine.fill") as fill:
+                    dispatched = 0
                     while True:
                         nxt = self._dispatch_speculative(self._inflight[-1])
                         if nxt is None:
                             break
                         self._inflight.append(nxt)
+                        dispatched += 1
                         if not self._can_fill():
                             break
+                    fill.note(dispatched=dispatched,
+                              stopped=self._fill_stopped)
             # Resolve AFTER dispatch, BEFORE processing: join rows'
             # first tokens must commit before any of their chunk rows
             # do (the chunk being processed may contain join rows from
@@ -1367,16 +1411,77 @@ class InferenceEngine:
         return resolved or admitted or prefilled or stepped
 
     def _can_fill(self) -> bool:
-        """The pipeline has room and nothing needs the host first: the
-        next chunk may be dispatched from the newest one's
-        device-carried end state. Mixed batching: pending prefill
-        slices must ride the next host-assembled MIXED chunk — a
-        speculative decode-only chunk would push them out a full
-        cycle."""
-        return (len(self._inflight) < self._pipe_depth
-                and not self._has_scheduling_work()
-                and not self._geometry_changed(self._inflight[-1])
-                and not self._mixed_work_waiting())
+        """The pipeline has room and the host could do nothing better
+        with the oldest chunk's tokens first: the next chunk may be
+        dispatched from the newest one's device-carried end state.
+        A refusal is counted under its reason."""
+        why = self._fill_refusal()
+        if why is None:
+            return True
+        self._refuse_fill(why)
+        return False
+
+    def _refuse_fill(self, why: str) -> None:
+        """Count one stopped fill (``fill_refusals``) and keep the
+        reason for the ``engine.fill`` span."""
+        self.fill_refusals[why] += 1
+        self._fill_stopped = why
+
+    def _fill_refusal(self) -> Optional[str]:
+        """Why the next chunk may NOT be dispatched from the carry
+        (a key of ``fill_refusals``), or None when it may.
+
+        With a row free the rule is the one the pipeline always had:
+        anything that needs host-side scheduling (``_has_scheduling_
+        work``) and any prompt slice left to run (``_mixed_work_
+        waiting``: it must ride the next host-assembled MIXED chunk)
+        stop the fill, so an arrival that can be seated is seated at
+        the next reconcile, within one chunk.
+
+        With every row taken (a FULL BATCH) waiting for the host helps
+        no request unless somebody waiting may displace a row: the
+        inbox must be ingested, and the head of the pending heap must
+        not be able to preempt (preemption off, or it is no more
+        urgent than the least urgent decoding row). Then the fill goes
+        ahead with requests pending and slices to run — the carried
+        chunk holds them (``_dispatch_speculative``). Tenant fairness
+        caps decode budgets on the host-assembled path only, so with
+        several tenants seated a full batch keeps the old rule."""
+        if len(self._inflight) >= self._pipe_depth:
+            return "depth"
+        free = False
+        for s in self._slots:
+            if s is None:
+                free = True
+            elif s.handle.cancelled:
+                return "cancelled"
+        if self._geometry_changed(self._inflight[-1]):
+            return "geometry"
+        if free:
+            if self._has_scheduling_work() or self._mixed_work_waiting():
+                return "free_slot"
+            return None
+        with self._mu:
+            if self._inbox:
+                return "urgent_pending"
+        if self._head_may_preempt():
+            return "urgent_pending"
+        slices = self._mixed_work_waiting()
+        if slices and getattr(self.executor, "ragged_attention", False):
+            return "ragged"     # that program starts from host state
+        if ((slices or self._pending) and self._tenancy.enabled
+                and len({s.req.tenant_id for s in self._slots}) > 1):
+            return "tenancy"
+        return None
+
+    def _head_may_preempt(self) -> bool:
+        """The most urgent pending request could take a decoding
+        sequence's row at a reconcile (``_admit_pending``'s test)."""
+        if not self.preemption_enabled or not self._pending:
+            return False
+        prio, order, _ = self._pending[0]
+        victim = self._least_urgent_active()
+        return victim is not None and victim.sort_key() > (prio, order)
 
     def _assemble(self) -> bool:
         """Host assembly of the next chunk from reconciled state
@@ -2450,8 +2555,10 @@ class InferenceEngine:
 
     def _mixed_work_waiting(self) -> bool:
         """Any mid-prefill slot with slices left to run (whether or not
-        one is already riding the in-flight chunk): blocks speculative
-        decode-only dispatch so the reconcile can fuse them."""
+        one is already riding the in-flight chunk). With a row free it
+        stops the fill so the reconcile can fuse them into a
+        host-assembled mixed chunk; under a full batch the carried
+        chunk takes them itself."""
         if not self._mixed_on():
             return False
         return any(s is not None and not s.prefilled and s.todo_ids
@@ -2478,11 +2585,15 @@ class InferenceEngine:
 
     def _has_scheduling_work(self) -> bool:
         """Anything that requires host-side scheduling before the next
-        chunk (and therefore forbids dispatching it speculatively from
-        device-carried state). Mid-prefill sequences do NOT block
-        speculation: their lanes are latched in the carry and their
-        bucket programs just queue behind the chunk — they join via a
-        fresh dispatch once resolved (_geometry_changed)."""
+        chunk WHILE A ROW IS FREE (and therefore forbids dispatching it
+        from device-carried state: ``_fill_refusal``): an arrival, a
+        pending request, a cancellation. With every row taken a pending
+        request that cannot displace anyone needs no scheduling, and
+        this is not consulted. Mid-prefill sequences do NOT block the
+        fill: their lanes are latched in the carry and their bucket
+        programs just queue behind the chunk — they join via a lane
+        override, or a fresh dispatch once resolved
+        (_geometry_changed)."""
         with self._mu:
             if self._inbox:
                 return True
@@ -2573,30 +2684,50 @@ class InferenceEngine:
             self, infl: _InflightChunk) -> Optional[_InflightChunk]:
         """Dispatch the next chunk from the in-flight chunk's
         device-carried end state, BEFORE its tokens are fetched.
+        Returns None, with the reason counted (``fill_refusals``), when
+        that isn't possible: the caller reconciles instead.
 
-        Budgets use conservative upper bounds (as if the in-flight chunk
-        consumes its full budget on every row): a row that cannot be
-        bounded safely gets budget 0 and enters latched (done_in), and
-        page allocation must succeed without shedding — any shedding
-        would mutate rows the in-flight chunk is still decoding.
-        Returns None when speculation isn't possible (reconcile
-        instead).
+        **Decode rows.** Budgets use conservative upper bounds (as if
+        every chunk in flight consumes its full budget on every row):
+        a row that cannot be bounded safely gets budget 0 and enters
+        latched (done_in).
 
-        Just-admitted sequences whose final prefill chunk is dispatched
-        but unresolved JOIN the speculative chunk as lane overrides
-        (first token device-to-device, position + done-latch overridden
-        — the lane may have belonged to a finished sequence). Without
-        this, an arrival during a chunk waits out BOTH that chunk and
-        the next speculative one before its same-step join on the fresh
-        path — a full chunk of avoidable admission latency, the single
-        largest term in realtime p99 under load."""
+        **Joining rows** enter as lane overrides (first token
+        device-to-device, position + done-latch overridden — the lane
+        may have belonged to a finished sequence): a just-admitted
+        sequence whose final prefill chunk is dispatched but
+        unresolved (``first_handle``) and, under a full batch, one
+        whose FINAL slice rides an in-flight mixed chunk (that chunk's
+        ``pf_first[i]``, still on the device). Their first token is
+        committed when the prefill is resolved or the mixed chunk
+        reconciled — always before this chunk's rows. Without the
+        join, an arrival during a chunk waits out BOTH that chunk and
+        the next one before its same-step join on the fresh path — a
+        full chunk of avoidable admission latency, the single largest
+        term in realtime p99 under load.
+
+        **Prefill slices**, under a full batch: the prompt slices of
+        seated mid-prefill sequences ride along as a carried MIXED
+        chunk, packed as ``_mixed_once`` packs them (their pages were
+        granted at admission). One sequence's consecutive slices may
+        ride consecutive chunks in flight: the device queue is FIFO.
+
+        **Pages.** A carried chunk never takes a page from a seated or
+        a pending sequence and never preempts — those mutate rows the
+        chunks in flight are still decoding. Under a full batch it may
+        evict what nobody holds: zero-reference radix leaves, then
+        idle conversation pins (``_alloc_pages``' first two rungs).
+        With a row free it does not: the fresh path, one chunk away,
+        decides what an admission is worth."""
         if self._spec_on:
             # Verify windows never chain device-to-device: the next
             # window's drafts are keyed off tokens the host has not
             # fetched yet — every window reconciles before the next
             # dispatch.
+            self._refuse_fill("spec")
             return None
         B = self.spec.batch_size
+        full = all(s is not None for s in self._slots)
         chunk = max(1, getattr(self.executor, "chunk_size", 1))
         chunk = min(chunk, self._admission_cap())
         capacity = self.spec.max_pages_per_seq * self.spec.page_size
@@ -2618,6 +2749,12 @@ class InferenceEngine:
             limit = seq.req.max_new_tokens or self.max_decode_steps
             b = min(chunk, limit - gen_upper, capacity - pos_upper)
             if b <= 0:
+                if not prev_b:
+                    # Nothing of this row is in flight and it can take
+                    # no step: only the host-assembled path ends it,
+                    # and a full batch would never get there.
+                    self._refuse_fill("row_ended")
+                    return None
                 continue
             need = PageAllocator.pages_for(
                 pos_upper + b, self.spec.page_size) - len(seq.pages)
@@ -2627,13 +2764,19 @@ class InferenceEngine:
         # (final prefill dispatched, not a rebuild/resume), minus rows
         # already snapshotted into ANY in-flight chunk.
         join_plan = []   # (seq, slot, budget, pages_needed)
+        first_of = {}    # slot → the override's device scalar
         for slot in range(B):
             seq = self._slots[slot]
             if (seq is None or seq.prefilled
                     or any(c.seqs[slot] is seq for c in self._inflight)
-                    or seq.first_handle is None or seq.todo_ids
+                    or seq.todo_ids
                     or seq.todo_resume is not None or seq.todo_rebuild
                     or seq.handle.cancelled):
+                continue
+            first = seq.first_handle
+            if first is None and full:
+                first = self._final_slice_first(seq)
+            if first is None:
                 continue
             b = self._budget_for(seq, chunk) - 1   # resolve commits one
             if b <= 0:
@@ -2641,20 +2784,30 @@ class InferenceEngine:
             need = PageAllocator.pages_for(
                 seq.pos + b, self.spec.page_size) - len(seq.pages)
             join_plan.append((seq, slot, b, max(0, need)))
+            first_of[slot] = first
             ctx += seq.pos
         if not plan and not join_plan:
+            self._refuse_fill("nothing_to_decode")
             return None
-        # Speculative growth must not shed: every universe the plan
-        # draws from needs headroom up front (a GLOBAL sum would pass
-        # while one dp universe is exhausted, breaking the no-shedding
-        # assert below).
+        pf_plan, pf_budget = [], 0
+        if full and self._mixed_on():
+            pf_plan, pf_budget = self._pack_slices(
+                [s for s in self._slots
+                 if not s.prefilled and s.todo_ids
+                 and s.first_handle is None and not s.handle.cancelled])
+        # Growth must not shed: every universe the plan draws from
+        # needs headroom up front (a GLOBAL sum would pass while one dp
+        # universe is exhausted, breaking the no-shedding assert
+        # below).
         need_by_shard: Dict[int, int] = {}
         for seq, slot, _, n in plan + join_plan:
             need_by_shard[self._slot_shard(slot)] = (
                 need_by_shard.get(self._slot_shard(slot), 0) + n)
-        if any(n > self.allocator.available(shard=d)
-               for d, n in need_by_shard.items()):
-            return None     # would require shedding → reconcile
+        for d, n in need_by_shard.items():
+            if n > self.allocator.available(shard=d) and not (
+                    full and self._evict_unheld(n, d)):
+                self._refuse_fill("pages")   # would shed → reconcile
+                return None
         t_asm = time.perf_counter()   # step decomposition: dispatch leg
         budgets = np.zeros(B, np.int32)   # read again at process time
         block_tables = self._staging.take(
@@ -2671,28 +2824,74 @@ class InferenceEngine:
             budgets[slot] = b
             block_tables[slot] = seq.block_table
             temps[slot] = seq.req.temperature
-        overrides = [(slot, seq.first_handle, seq.pos)
+        overrides = [(slot, first_of[slot], seq.pos)
                      for seq, slot, _, _ in join_plan]
         seqs = list(infl.seqs)
         for seq, slot, _, _ in join_plan:
             seqs[slot] = seq
-        with self._chunk_dispatch("decode_chunk", budgets, ctx):
-            handle = self.executor.decode_chunk_start(
-                None, None, block_tables, temps, budgets,
-                carry=infl.handle, overrides=overrides)
+        infl_pf = None
+        if pf_plan:
+            packed = sum(len(sl) for _, sl in pf_plan)
+            pf, infl_pf = self._take_slices(pf_plan, pf_budget, len(plan))
+            t0 = time.perf_counter()
+            with self._chunk_dispatch("mixed_chunk", budgets, ctx,
+                                      prefill_tokens=packed):
+                handle = self.executor.mixed_chunk_start(
+                    None, None, block_tables, temps, budgets, pf,
+                    carry=infl.handle, overrides=overrides)
+            self._mixed_dispatched(handle, infl_pf, packed,
+                                   time.perf_counter() - t0, bool(plan))
+        else:
+            with self._chunk_dispatch("decode_chunk", budgets, ctx):
+                handle = self.executor.decode_chunk_start(
+                    None, None, block_tables, temps, budgets,
+                    carry=infl.handle, overrides=overrides)
+            _prefetch(getattr(handle, "out", None))
         now = time.perf_counter()
         dispatch_s = now - t_asm
-        _prefetch(getattr(handle, "out", None))
         self.steps += 1
         self._note_dispatch_depth(len(self._inflight) + 1)
         # (caller appends the chunk after return)
         if self._metrics:
             self._m("decode_steps").inc()
-        infl_next = _InflightChunk(handle, seqs, budgets,
+        infl_next = _InflightChunk(handle, seqs, budgets, pf=infl_pf,
                                    dispatch_s=dispatch_s,
                                    dispatched_at=now)
         self._start_fetch(infl_next)
         return infl_next
+
+    def _final_slice_first(self, seq: _Sequence):
+        """For a sequence whose FINAL prefill slice rides a mixed chunk
+        in flight: that chunk's sampled first token as a lane
+        override's device scalar (``pf_first[i]``, not fetched for
+        this). None when no chunk in flight holds its final slice."""
+        if not seq.mixed_pending:
+            return None
+        for c in self._inflight:
+            for i, (s, _n, final) in enumerate(c.pf or ()):
+                if s is seq and final:
+                    return c.handle.pf_first_at(i)
+        return None
+
+    def _evict_unheld(self, n: int, shard: int) -> bool:
+        """Make ``n`` pages available in page universe ``shard`` out of
+        what nobody holds — zero-reference radix leaves, then idle
+        conversation pins, ``_alloc_pages``' first two rungs and no
+        further: no page a chunk in flight reads or writes is touched.
+        False when that is not enough."""
+        have = self.allocator.available(shard=shard)
+        while have < n:
+            if not ((self._prefix_cache is not None
+                     and self._prefix_cache.evict_pages(n - have) > 0)
+                    or self._reclaim_idle_conversation()):
+                return False
+            # Neither rung knows of universes (dp mesh): a pass that
+            # freed pages only elsewhere ends the attempt, rather than
+            # emptying the cache for a universe it cannot help.
+            was, have = have, self.allocator.available(shard=shard)
+            if have <= was:
+                return False
+        return True
 
     def _commit_row(self, seq: _Sequence, row: np.ndarray,
                     budget: int) -> None:
@@ -2705,7 +2904,14 @@ class InferenceEngine:
             seq.pos += 1
             self._commit_token(seq, nxt)
             if seq.slot is None:   # finished (eos/length/cancel)
-                break
+                return
+        if (seq.slot is not None and seq.pos
+                >= self.spec.max_pages_per_seq * self.spec.page_size):
+            # Block table exhausted: the row can take no further step,
+            # so it ends here, like a row at its token limit
+            # (``_commit_token``) — not at the next host-assembled
+            # chunk, which a full batch may never come to.
+            self._finish_active(seq, "length")
 
     def _offload_fetch(self, fn, lane: str = "chunk") -> Dict:
         """Run a blocking device→host fetch on a fetcher thread;
@@ -3437,19 +3643,6 @@ class InferenceEngine:
         B = self.spec.batch_size
         chunk = max(1, getattr(self.executor, "chunk_size", 1))
         chunk = min(chunk, self._admission_cap())
-        S = int(getattr(self.executor, "mixed_prefill_slices", 0))
-        T = int(getattr(self.executor, "mixed_slice_tokens", 0))
-        # The dispatch can never out-pack the compiled program. Bucket
-        # mode packs ≤ S·T by construction (T = budget//S), so the
-        # clamp is a no-op there. In RAGGED mode T is the packed
-        # buffer's TOTAL capacity and slices have no fixed width — a
-        # single slice may take the whole budget (token-budget packing
-        # with no bucket boundaries), so the total clamps to T.
-        budget = int(self._mixed_cfg.prefill_token_budget)
-        if getattr(self.executor, "ragged_attention", False):
-            budget = min(budget, T)
-        else:
-            budget = min(budget, S * T)
 
         # Decode rows: same eligibility/budgeting as _decode_once (no
         # join rows — mixed iterations reconcile every cycle, so there
@@ -3470,20 +3663,7 @@ class InferenceEngine:
             if s.handle.cancelled:
                 self._finish_active(s, "cancelled")
                 cands.remove(s)
-        cands.sort(key=lambda s: s.sort_key())
-        # Tenancy plane (docs/tenancy.md): under multi-tenant
-        # contention for the prefill budget, pack with per-tenant
-        # weight-proportional caps; with tenancy off (or one tenant)
-        # the single uncapped pass packs identically to the
-        # pre-tenancy loop.
-        tenant_caps = None
-        if self._tenancy.enabled:
-            cand_tenants = {s.req.tenant_id for s in cands}
-            if len(cand_tenants) > 1:
-                tenant_caps = weighted_token_caps(
-                    {t: self._tenancy.weight_for(t)
-                     for t in cand_tenants}, budget)
-        pf_plan = _pack_prefill_slices(cands, S, T, budget, tenant_caps)
+        pf_plan, budget = self._pack_slices(cands)
         packed = sum(len(sl) for _, sl in pf_plan)
         if not pf_plan:
             # Every candidate was shed/cancelled DURING decode
@@ -3515,25 +3695,7 @@ class InferenceEngine:
             temps[i] = seq.req.temperature
             budgets[i] = budgets_by_order.get(seq.order, 1)
 
-        pf = []
-        infl_pf = []
-        for seq, sl in pf_plan:
-            seq.handle.marks.setdefault("prefill_start",
-                                        time.perf_counter())
-            pf.append((seq.slot, sl, seq.todo_pos, seq.block_table,
-                       seq.req.temperature))
-            seq.todo_ids = seq.todo_ids[len(sl):]
-            seq.todo_pos += len(sl)
-            seq.pos = seq.todo_pos
-            seq.pf_tokens_run += len(sl)
-            seq.written_ids.extend(sl)
-            infl_pf.append((seq, len(sl), not seq.todo_ids))
-
-        if self._metrics:
-            self._m("mixed_step_decode_rows").set(len(active))
-            self._m("mixed_step_prefill_tokens").set(packed)
-            self._m("mixed_budget_utilization").set(
-                packed / budget if budget else 0.0)
+        pf, infl_pf = self._take_slices(pf_plan, budget, len(active))
 
         start_fn = getattr(self.executor, "mixed_chunk_start", None)
         t0 = time.perf_counter()
@@ -3543,16 +3705,11 @@ class InferenceEngine:
                 handle = start_fn(tokens, positions, block_tables,
                                   temps, budgets, pf)
             dispatch_s = time.perf_counter() - t_asm
-            self._note_prefill_dispatch(
-                packed, time.perf_counter() - t0,
-                decode_active=bool(active), fused=True)
-            _prefetch(getattr(handle, "out", None))
-            _prefetch(getattr(handle, "pf_first", None))
+            self._mixed_dispatched(handle, infl_pf, packed,
+                                   time.perf_counter() - t0, bool(active))
             seqs = [None] * B
             for seq in active:
                 seqs[seq.slot] = seq
-            for seq, _, _ in infl_pf:
-                seq.mixed_pending = True
             infl = _InflightChunk(handle, seqs, budgets, pf=infl_pf,
                                   dispatch_s=dispatch_s,
                                   dispatched_at=time.perf_counter())
@@ -3560,8 +3717,6 @@ class InferenceEngine:
             self._note_dispatch_depth(len(self._inflight))
             self._start_fetch(infl)
             self.steps += 1
-            self.mixed_steps += 1
-            self.mixed_prefill_tokens_total += packed
             if self._metrics:
                 self._m("decode_steps").inc()
             return True
@@ -3604,12 +3759,90 @@ class InferenceEngine:
         self._set_gauges()
         return True
 
+    def _pack_slices(self, cands) -> "tuple[list, int]":
+        """``([(seq, token_ids)], budget)``: the mid-prefill sequences
+        ``cands``, most urgent first, packed into the compiled mixed
+        program's slice grid under the token budget — the one packing
+        of a host-assembled (``_mixed_once``) and of a carried
+        (``_dispatch_speculative``) mixed chunk."""
+        S = int(getattr(self.executor, "mixed_prefill_slices", 0))
+        T = int(getattr(self.executor, "mixed_slice_tokens", 0))
+        # The dispatch can never out-pack the compiled program. Bucket
+        # mode packs ≤ S·T by construction (T = budget//S), so the
+        # clamp is a no-op there. In RAGGED mode T is the packed
+        # buffer's TOTAL capacity and slices have no fixed width — a
+        # single slice may take the whole budget (token-budget packing
+        # with no bucket boundaries), so the total clamps to T.
+        budget = int(self._mixed_cfg.prefill_token_budget)
+        if getattr(self.executor, "ragged_attention", False):
+            budget = min(budget, T)
+        else:
+            budget = min(budget, S * T)
+        cands.sort(key=lambda s: s.sort_key())
+        # Tenancy plane (docs/tenancy.md): under multi-tenant
+        # contention for the prefill budget, pack with per-tenant
+        # weight-proportional caps; with tenancy off (or one tenant)
+        # the single uncapped pass packs identically to the
+        # pre-tenancy loop.
+        tenant_caps = None
+        if self._tenancy.enabled:
+            cand_tenants = {s.req.tenant_id for s in cands}
+            if len(cand_tenants) > 1:
+                tenant_caps = weighted_token_caps(
+                    {t: self._tenancy.weight_for(t)
+                     for t in cand_tenants}, budget)
+        return _pack_prefill_slices(cands, S, T, budget,
+                                    tenant_caps), budget
+
+    def _take_slices(self, pf_plan, budget: int,
+                     decode_rows: int) -> "tuple[list, list]":
+        """Hand ``pf_plan``'s slices over to the chunk about to be
+        dispatched: the executor's ``pf`` tuples and the in-flight
+        snapshot ``[(seq, n_tokens, final)]``, each sequence's prefill
+        bookkeeping advanced past its slice."""
+        pf = []
+        infl_pf = []
+        for seq, sl in pf_plan:
+            seq.handle.marks.setdefault("prefill_start",
+                                        time.perf_counter())
+            pf.append((seq.slot, sl, seq.todo_pos, seq.block_table,
+                       seq.req.temperature))
+            seq.todo_ids = seq.todo_ids[len(sl):]
+            seq.todo_pos += len(sl)
+            seq.pos = seq.todo_pos
+            seq.pf_tokens_run += len(sl)
+            seq.written_ids.extend(sl)
+            infl_pf.append((seq, len(sl), not seq.todo_ids))
+        if self._metrics:
+            packed = sum(n for _, n, _ in infl_pf)
+            self._m("mixed_step_decode_rows").set(decode_rows)
+            self._m("mixed_step_prefill_tokens").set(packed)
+            self._m("mixed_budget_utilization").set(
+                packed / budget if budget else 0.0)
+        return pf, infl_pf
+
+    def _mixed_dispatched(self, handle, infl_pf, packed: int,
+                          host_seconds: float,
+                          decode_active: bool) -> None:
+        """Accounting of one mixed chunk handed to an async executor:
+        the stall estimate, the transfers queued behind the program,
+        the slices' in-flight latch, the counters."""
+        self._note_prefill_dispatch(packed, host_seconds,
+                                    decode_active=decode_active,
+                                    fused=True)
+        _prefetch(getattr(handle, "out", None))
+        _prefetch(getattr(handle, "pf_first", None))
+        for seq, _, _ in infl_pf:
+            seq.mixed_pending += 1
+        self.mixed_steps += 1
+        self.mixed_prefill_tokens_total += packed
+
     def _finish_mixed_prefills(self, pf, pf_first) -> None:
         """Reconcile the prefill slices of a processed mixed chunk:
         clear the in-flight latch and complete admissions whose FINAL
         slice ran (their sampled first token is ``pf_first[i]``)."""
         for i, (seq, _n, final) in enumerate(pf):
-            seq.mixed_pending = False
+            seq.mixed_pending = max(0, seq.mixed_pending - 1)
             if seq.slot is None or seq.prefilled:
                 continue   # shed or superseded while in flight
             if seq.handle.cancelled:
@@ -4016,6 +4249,10 @@ class InferenceEngine:
                 "depth_hist": {str(k): v for k, v in
                                sorted(self.pipeline_depth_hist.items())
                                if v},
+                # Why fills stopped (``fill_refusals``): "not full"
+                # (free_slot) against "could not" (pages, geometry…).
+                "fill_refusals": {k: v for k, v in
+                                  self.fill_refusals.items() if v},
                 "overlap_ratio": self._telemetry.overlap_ratio(),
             }
         if self._mixed_cfg is not None:

@@ -174,6 +174,22 @@ class _EchoOutProbe:
         self._ev.wait()
 
 
+class _EchoFirstToken:
+    """``pf_first[i]`` of an in-flight echo MIXED chunk as a lane
+    override's "device scalar": read only when the joining chunk's
+    program runs, by which time the FIFO device queue has run the
+    mixed chunk and set it."""
+
+    __slots__ = ("_handle", "_i")
+
+    def __init__(self, handle: "EchoChunkHandle", i: int) -> None:
+        self._handle = handle
+        self._i = i
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._handle.pf_first[self._i], dtype)
+
+
 class EchoChunkHandle:
     """In-flight echo chunk (``async_chunks`` mode): results materialize
     when the executor's device-queue thread runs the program. Carry
@@ -211,6 +227,11 @@ class EchoChunkHandle:
     def _fail(self, err: BaseException) -> None:
         self._err = err
         self._ev.set()
+
+    def pf_first_at(self, i: int) -> _EchoFirstToken:
+        """Slice ``i``'s sampled first token as a lane override's
+        scalar (parity with :meth:`MixedChunkHandle.pf_first_at`)."""
+        return _EchoFirstToken(self, i)
 
     def fetch(self):
         self._ev.wait()
@@ -515,29 +536,23 @@ class EchoExecutor:
             frozen = frozen | (active & (nxt == eos))
         return out, tok, pos, frozen
 
-    def decode_chunk_start(self, tokens, positions, block_tables,
-                           temperatures, budgets,
-                           carry: Optional["EchoChunkHandle"] = None,
-                           overrides: Optional[List] = None
-                           ) -> "EchoChunkHandle":
-        """Futures-returning decode chunk (parity with
-        JaxExecutor.decode_chunk_start): dispatch returns immediately;
-        with ``carry``, tok/pos/done come from the previous chunk's end
-        state; ``overrides`` re-seed a lane (slot, first-token, pos) for
-        a same-step join. Inputs are SNAPSHOTTED at dispatch — the
-        engine's staging buffers may be rewritten before the program
-        runs."""
+    def _lane_seed(self, tokens, positions, carry, overrides):
+        """Snapshot a chunk's lane inputs at dispatch and return the
+        closure the device-queue thread calls for ``(tok, pos, done)``:
+        the previous chunk's end state with ``carry`` (read when the
+        program RUNS — FIFO order has set it by then), else the host
+        arrays with no row latched; ``overrides`` re-seed a lane
+        (slot, first-token scalar, pos) for a join. The engine's
+        staging buffers may be rewritten before the program runs,
+        hence the copies."""
         B = self.spec.batch_size
         toks = (None if tokens is None
                 else np.asarray(tokens, np.int32).copy())
         poss = (None if positions is None
                 else np.asarray(positions, np.int32).copy())
-        buds = np.asarray(budgets, np.int32).copy()
         ovr = [(int(s), sc, int(p)) for s, sc, p in (overrides or ())]
 
-        def run(h: "EchoChunkHandle") -> None:
-            if self._step_delay_s:
-                time.sleep(self._step_delay_s)
+        def lanes():
             if carry is not None:
                 tok, pos, done = carry._tok, carry._pos, carry._done
             else:
@@ -550,20 +565,44 @@ class EchoExecutor:
                 tok[slot] = int(np.asarray(sc))
                 pos[slot] = p
                 done[slot] = False
-            h._set(*self._run_chunk_async(tok, pos, done, buds))
+            return tok, pos, done
+
+        return lanes
+
+    def decode_chunk_start(self, tokens, positions, block_tables,
+                           temperatures, budgets,
+                           carry: Optional["EchoChunkHandle"] = None,
+                           overrides: Optional[List] = None
+                           ) -> "EchoChunkHandle":
+        """Futures-returning decode chunk (parity with
+        JaxExecutor.decode_chunk_start): dispatch returns immediately;
+        with ``carry``, tok/pos/done come from the previous chunk's end
+        state; ``overrides`` re-seed a lane (slot, first-token, pos) for
+        a same-step join. Inputs are SNAPSHOTTED at dispatch — the
+        engine's staging buffers may be rewritten before the program
+        runs."""
+        buds = np.asarray(budgets, np.int32).copy()
+        lanes = self._lane_seed(tokens, positions, carry, overrides)
+
+        def run(h: "EchoChunkHandle") -> None:
+            if self._step_delay_s:
+                time.sleep(self._step_delay_s)
+            h._set(*self._run_chunk_async(*lanes(), buds))
 
         return self._device_submit(run)
 
     def mixed_chunk_start(self, tokens, positions, block_tables,
-                          temperatures, budgets,
-                          pf: List) -> "EchoChunkHandle":
+                          temperatures, budgets, pf: List,
+                          carry: Optional["EchoChunkHandle"] = None,
+                          overrides: Optional[List] = None
+                          ) -> "EchoChunkHandle":
         """Futures-returning mixed chunk: slice registration happens on
         the device-queue thread (FIFO — before any later chained
         chunk), mirroring the fused program writing slice KV inside the
-        same dispatch."""
-        toks = np.asarray(tokens, np.int32).copy()
-        poss = np.asarray(positions, np.int32).copy()
+        same dispatch. ``carry`` / ``overrides`` as in
+        ``decode_chunk_start``."""
         buds = np.asarray(budgets, np.int32).copy()
+        lanes = self._lane_seed(tokens, positions, carry, overrides)
         pf_snap = [(int(slot), list(t), int(sp))
                    for slot, t, sp, _bt, _temp in pf]
 
@@ -579,9 +618,7 @@ class EchoExecutor:
                     stream = self._register_prefill(slot, t, sp)
                     if stream:
                         pf_first[i] = stream[0]
-            done = np.zeros(self.spec.batch_size, bool)
-            out, tok, pos, done = self._run_chunk_async(
-                toks, poss, done, buds)
+            out, tok, pos, done = self._run_chunk_async(*lanes(), buds)
             h._set(out, tok, pos, done, pf_first=pf_first)
 
         return self._device_submit(run, mixed=True)
@@ -653,6 +690,13 @@ class MixedChunkHandle:
         self.pos = pos
         self.done = done
         self.pf_first = pf_first
+
+    def pf_first_at(self, i: int):
+        """Slice ``i``'s sampled first token, still on the device: the
+        scalar of a lane override ``(slot, scalar, pos)`` through which
+        a sequence whose FINAL slice rode this chunk joins the next one
+        before this one is fetched."""
+        return self.pf_first[i]
 
     def fetch(self) -> tuple:
         """Blocking host transfer: ``(decode tokens (B, K),
@@ -1950,13 +1994,15 @@ class JaxExecutor:
         ztemp = np.zeros(spec.batch_size, np.float32)
         if self.chunk_size == 1:
             self.decode(zeros_b, zeros_b, zbt, ztemp)
+        mixed = None
         if self._mixed_chunk is not None:
             # Mixed-chunk smoke: one trash slice + 1-step decode
             # budgets, all writes land on reserved page 0.
-            self.mixed_chunk_start(
+            mixed = self.mixed_chunk_start(
                 zeros_b, zeros_b, zbt, ztemp,
                 np.ones(spec.batch_size, np.int32),
-                [(0, [1], 0, zbt[0], 0.0)]).fetch()
+                [(0, [1], 0, zbt[0], 0.0)])
+            mixed.fetch()
         if self._verify_chunk is not None:
             # Verify-window smoke: window size 1 per row (a pure
             # correction step), trash drafts, every write landing on
@@ -1975,13 +2021,22 @@ class JaxExecutor:
             # host-born, then a previous chunk's carry.
             first = self.prefill_async([1], 0, bt[0], 0.0)
             join = [(0, first, 0)]
+            ones_b = np.ones(spec.batch_size, np.int32)
             h = self.decode_chunk_start(
-                zeros_b, zeros_b, zbt, ztemp,
-                np.ones(spec.batch_size, np.int32), overrides=join)
-            self.decode_chunk_start(
-                None, None, zbt, ztemp,
-                np.ones(spec.batch_size, np.int32), carry=h,
-                overrides=join).fetch()
+                zeros_b, zeros_b, zbt, ztemp, ones_b, overrides=join)
+            h = self.decode_chunk_start(
+                None, None, zbt, ztemp, ones_b, carry=h, overrides=join)
+            if mixed is not None and not self.ragged_attention:
+                # Under a full batch a row whose FINAL slice rode a
+                # mixed chunk joins the next chunk from that chunk's
+                # ``pf_first``, indexed on the device: every slice
+                # index once, scattered into the mixed chunk's carry
+                # (the lanes are set up the same for either program).
+                for i in range(self.mixed_prefill_slices):
+                    h = self.decode_chunk_start(
+                        None, None, zbt, ztemp, ones_b, carry=mixed,
+                        overrides=[(0, mixed.pf_first_at(i), 0)])
+            h.fetch()
             # Per-step cost estimate for the engine's tier-aware
             # admission cap: time (1-step, K-step) chunk PAIRS — both
             # pay one host round-trip, so the difference isolates
@@ -2173,6 +2228,27 @@ class JaxExecutor:
             self._next_key())
         return np.asarray(toks)
 
+    def _chunk_lanes(self, tokens, positions, carry, overrides):
+        """``(tok, pos, done)`` a chunk program starts from: the
+        previous chunk's device-resident end state with ``carry`` (a
+        :class:`ChunkHandle` or a :class:`MixedChunkHandle` — one
+        carry surface), else the host arrays with no row latched; then
+        the lane ``overrides`` (see ``decode_chunk_start``)."""
+        jnp = self._jnp
+        if carry is not None:
+            tok_in, pos_in, done_in = carry.tok, carry.pos, carry.done
+        else:
+            tok_in = self._batch_arr(tokens, jnp.int32)
+            pos_in = self._batch_arr(positions, jnp.int32)
+            done_in = self._zeros_done()
+        for slot, tok_dev, pos in (overrides or ()):
+            # Eager scatters preserve the carry's dp sharding (pinned
+            # by test), so the AOT program's input signature holds.
+            tok_in = tok_in.at[slot].set(tok_dev.astype(jnp.int32))
+            pos_in = pos_in.at[slot].set(jnp.int32(pos))
+            done_in = done_in.at[slot].set(False)
+        return tok_in, pos_in, done_in
+
     def decode_chunk_start(self, tokens, positions,
                            block_tables: np.ndarray,
                            temperatures: np.ndarray,
@@ -2199,18 +2275,8 @@ class JaxExecutor:
         """
         jnp = self._jnp
         fn = self._aot.get("decode_chunk", self._decode_chunk)
-        if carry is not None:
-            tok_in, pos_in, done_in = carry.tok, carry.pos, carry.done
-        else:
-            tok_in = self._batch_arr(tokens, jnp.int32)
-            pos_in = self._batch_arr(positions, jnp.int32)
-            done_in = self._zeros_done()
-        for slot, tok_dev, pos in (overrides or ()):
-            # Eager scatters preserve the carry's dp sharding (pinned
-            # by test), so the AOT program's input signature holds.
-            tok_in = tok_in.at[slot].set(tok_dev.astype(jnp.int32))
-            pos_in = pos_in.at[slot].set(jnp.int32(pos))
-            done_in = done_in.at[slot].set(False)
+        tok_in, pos_in, done_in = self._chunk_lanes(
+            tokens, positions, carry, overrides)
         out, tok, pos, done, self.cache = fn(
             self.params, self.cache,
             tok_in, pos_in,
@@ -2282,7 +2348,10 @@ class JaxExecutor:
                           block_tables: np.ndarray,
                           temperatures: np.ndarray,
                           budgets: np.ndarray,
-                          pf: List) -> "MixedChunkHandle":
+                          pf: List,
+                          carry=None,
+                          overrides: Optional[List] = None
+                          ) -> "MixedChunkHandle":
         """Dispatch one MIXED chunk (no host sync): the decode rows'
         chunk plus up to ``mixed_prefill_slices`` budgeted prefill
         slices in a single program. ``pf``: ``(slot, tokens, start_pos,
@@ -2290,11 +2359,21 @@ class JaxExecutor:
         ``mixed_slice_tokens`` tokens (``slot`` is engine bookkeeping —
         the program addresses slices by block table). Unused slice rows
         pad with one trash token against reserved page 0, exactly like
-        ``prefill_multi_async``."""
+        ``prefill_multi_async``.
+
+        ``carry`` and ``overrides`` mean what they mean to
+        ``decode_chunk_start``: the decode rows start from the previous
+        chunk's device-resident end state, and a lane is re-seeded for
+        a joining row. Same compiled program either way — it always
+        took ``tok / pos / done`` as device arrays. The ragged program
+        takes neither (the engine reconciles instead)."""
         if self._mixed_chunk is None:
             raise RuntimeError("mixed batching disabled for this executor")
         jnp = self._jnp
         if self.ragged_attention:
+            if carry is not None or overrides:
+                raise NotImplementedError(
+                    "the ragged mixed program starts from host state")
             return self._ragged_chunk_start(tokens, positions,
                                             block_tables, temperatures,
                                             budgets, pf)
@@ -2316,15 +2395,15 @@ class JaxExecutor:
             pf_bts[i] = bt
             pf_temps[i] = temp
         fn = self._aot.get("mixed_chunk", self._mixed_chunk)
-        done0 = self._zeros_done()
+        tok_in, pos_in, done_in = self._chunk_lanes(
+            tokens, positions, carry, overrides)
         out, tok, pos, done, pf_first, self.cache = fn(
             self.params, self.cache,
-            self._batch_arr(tokens, jnp.int32),
-            self._batch_arr(positions, jnp.int32),
+            tok_in, pos_in,
             self._batch_arr(block_tables, jnp.int32),
             self._batch_arr(temperatures, jnp.float32),
             self._batch_arr(budgets, jnp.int32),
-            done0,
+            done_in,
             jnp.asarray(pf_toks), jnp.asarray(pf_poss),
             jnp.asarray(pf_lens), jnp.asarray(pf_bts),
             jnp.asarray(pf_temps),

@@ -112,6 +112,14 @@ class _OpenSpan:
         self._t0 = time.perf_counter()
         return self
 
+    def note(self, **counts) -> None:
+        """Counts known only once the body ran (how many chunks a fill
+        dispatched, why it stopped): added to the ring's record and,
+        while a capture is held, to the annotation's arguments."""
+        self._counts = {**(self._counts or {}), **counts}
+        if self._ann is not None:
+            self._ann.set_metadata(**counts)
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         dt = time.perf_counter() - self._t0
         if self._ann is not None:

@@ -35,6 +35,9 @@ class _FakeAnnotation:
         type(self).log.append(("exit", self.name, exc[0]))
         return False
 
+    def set_metadata(self, **kwargs):
+        type(self).log.append(("metadata", self.name, kwargs))
+
 
 @pytest.fixture
 def fake_capture(monkeypatch):
@@ -83,6 +86,24 @@ class TestSpanRecorder:
             ("init", "engine.dispatch", counts),
             ("enter", "engine.dispatch"),
             ("exit", "engine.dispatch", None)]
+
+    @pytest.mark.parametrize("held", [True, False],
+                             ids=["capture_held", "no_capture"])
+    def test_note_adds_counts_known_after_the_body(self, fake_capture,
+                                                   held):
+        """``note`` inside the body: the counts land in the ring's
+        record beside those given at the call, and — a capture being
+        held — in the annotation's arguments."""
+        fake_capture.held = held
+        rec = SpanRecorder()
+        with rec.span("engine.fill", a=1) as sp:
+            sp.note(dispatched=2, stopped="depth")
+        (s,) = rec.snapshot()
+        assert s.meta == {"a": 1, "dispatched": 2, "stopped": "depth"}
+        meta = [e for e in fake_capture.log if e[0] == "metadata"]
+        assert meta == ([("metadata", "engine.fill",
+                          {"dispatched": 2, "stopped": "depth"})]
+                        if held else [])
 
     def test_no_annotation_while_no_capture_is_held(self, fake_capture):
         fake_capture.held = False

@@ -782,6 +782,29 @@ class TestHealthAndMetrics:
         assert store.totals["ops"] == 2
         store.close()
 
+    def test_a_closed_store_leaves_the_gauges_to_the_live_one(self):
+        """A store that was closed with its breaker open and is not
+        collected yet still flushes at the scrape (its last samples
+        count), but its breaker and health are no longer anybody's
+        state: the live store's gauges stand, whichever flushes last."""
+        from llmq_tpu.metrics.registry import get_metrics
+
+        dead = wrap_store(InMemoryStore(), _rcfg())
+        dead.register_consumer("exchange")
+        for _ in range(3):
+            dead._breaker.record_failure()          # noqa: SLF001
+        assert dead.resilience_stats()["breaker"]["state"] == "open"
+        dead.close()
+        live = wrap_store(InMemoryStore(), _rcfg())
+        live.register_consumer("exchange")
+        live.flush_metrics()
+        dead.flush_metrics()            # the weak set's order is free
+        m = get_metrics()
+        assert m.store_breaker_state._value.get() == 0.0  # noqa: SLF001
+        assert m.store_degraded.labels(
+            consumer="exchange")._value.get() == 0.0      # noqa: SLF001
+        live.close()
+
 
 # -- acceptance: blackout mid-workload -----------------------------------------
 

@@ -109,6 +109,48 @@ class TestWarmup:
                              [(3, [1, 2, 3], 0, bt[3], 0.0)]).fetch()
         assert BACKEND_COMPILES.count == before
 
+    def test_a_full_batchs_carried_chunks_compile_nothing(self):
+        """What a full batch adds to the serving loop ran during
+        warm-up too: a MIXED chunk started from a carry, and the join
+        of a row whose final slice rode it — ``pf_first`` indexed on
+        the device at either slice, scattered into a lane of a mixed
+        and of a decode chunk. First use after ready compiles nothing.
+        A batch size of this test's own, as above."""
+        from llmq_tpu.observability.device import BACKEND_COMPILES
+
+        cfg = llama3_tiny(max_seq_len=128)
+        ex = JaxExecutor(cfg, init_params(jax.random.PRNGKey(0), cfg),
+                         batch_size=5, page_size=16, num_pages=33,
+                         chunk_size=4, prefill_buckets=[16, 32],
+                         mixed_prefill_slices=2, mixed_slice_tokens=16,
+                         eos_id=-1)
+        BACKEND_COMPILES.watch()
+        ex.warmup()
+        before = BACKEND_COMPILES.count
+
+        B, MP = 5, ex.spec.max_pages_per_seq
+        bt = np.zeros((B, MP), np.int32)
+        bt[:4, 0] = [1, 2, 3, 4]
+        zeros, temps = np.zeros(B, np.int32), np.zeros(B, np.float32)
+        budgets = np.full(B, 4, np.int32)
+        h = ex.decode_chunk_start(zeros, zeros, bt, temps, budgets)
+        m = ex.mixed_chunk_start(
+            None, None, bt, temps, budgets,
+            [(2, [5, 6, 7], 0, bt[2], 0.0), (3, [8] * 16, 0, bt[3], 0.0)],
+            carry=h)
+        m2 = ex.mixed_chunk_start(
+            None, None, bt, temps, budgets,
+            [(4, [9, 9], 0, bt[4], 0.0)], carry=m,
+            overrides=[(2, m.pf_first_at(0), 3),
+                       (3, m.pf_first_at(1), 16)])
+        h2 = ex.decode_chunk_start(
+            None, None, bt, temps, budgets, carry=m2,
+            overrides=[(4, m2.pf_first_at(0), 2)])
+        assert h2.fetch().shape == (B, 4)
+        out, firsts = m2.fetch()
+        assert out.shape == (B, 4) and firsts.shape == (2,)
+        assert BACKEND_COMPILES.count == before
+
     def test_warmup_on_mesh(self):
         """AOT specs carry the arrays' shardings — the mesh path must
         compile and serve through the executables too."""
