@@ -51,10 +51,7 @@ a run that finds no TPU or whose chip section raises FAILS — the
 compile cache lives where parallel/mesh.enable_compilation_cache puts
 it: JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache),
 LLMQ_BENCH_PREFIX_CACHE (=0 disables the radix prefix KV cache in the
-SLA sweeps for A/B comparison), LLMQ_BENCH_RAGGED_ATTENTION (=1 routes
-the decode bench AND the SLA sweeps through the ragged paged-attention
-kernel — per-point kernel path + achieved HBM-bandwidth utilization
-are recorded for the A/B), LLMQ_BENCH_MIXED_BATCH (=0 disables
+SLA sweeps for A/B comparison), LLMQ_BENCH_MIXED_BATCH (=0 disables
 token-budget mixed prefill+decode batching for A/B) /
 LLMQ_BENCH_MIXED_BUDGET / LLMQ_BENCH_MIXED_SLICES,
 LLMQ_BENCH_TENANCY_RATE / LLMQ_BENCH_TENANCY_SECS (victim offered rate
@@ -1504,17 +1501,12 @@ def bench_tpu_decode(model_name: str, batch: int, steps: int,
     # chip (kernel: ops/pallas/fused_decode._fused_kernel_q8).
     kv_quant = os.environ.get("LLMQ_BENCH_KV_QUANT",
                               "int8" if quant == "int8" else "")
-    # Ragged paged-attention A/B (docs/performance.md "Ragged
-    # attention"): =1 routes the decode/mixed hot loop through the
-    # single ragged kernel, =0/unset keeps the bucket/fused baseline.
-    ragged_on = os.environ.get("LLMQ_BENCH_RAGGED_ATTENTION", "0") == "1"
     import jax.numpy as jnp
     ex = JaxExecutor(cfg, params, batch_size=batch, page_size=page_size,
                      num_pages=num_pages, chunk_size=chunk,
                      prefill_buckets=[128, 512], eos_id=-1,
                      cache_dtype=(jnp.int8 if kv_quant == "int8"
                                   else None),
-                     ragged_attention=ragged_on,
                      # Bench discipline: telemetry host-side only, no
                      # prometheus writes on the measured path.
                      telemetry_metrics=False)
@@ -1601,17 +1593,15 @@ def bench_tpu_decode(model_name: str, batch: int, steps: int,
     mean_ctx = prompt_len + (n_tok / 2.0)
     bw_util = decode_hbm_bw_util(tps, batch, wb, kvb, mean_ctx,
                                  dev.device_kind)
-    kernel_path = "ragged" if ragged_on else "bucket"
     log(f"[tpu] decode: {step_ms:.2f} ms/token-step, {tps:,.0f} tok/s "
         f"(B={batch}, chunk={chunk}), MFU={mfu*100:.2f}%, "
-        f"HBM-BW~{bw_util*100:.1f}% [{kernel_path}]  | "
+        f"HBM-BW~{bw_util*100:.1f}%  | "
         f"prefill {prefill_tps:,.0f} tok/s serialized, "
         f"{prefill_pipe_tps:,.0f} tok/s pipelined")
     return {
         "model": cfg.name, "params_b": round(n_params / 1e9, 3),
         "quant": quant or "bf16",
         "kv_quant": kv_quant or "bf16",
-        "kernel_path": kernel_path,
         "device": dev.device_kind, "batch": batch, "context": max_seq,
         "page_size": page_size,
         "host_device_rtt_ms": round(rtt_ms, 1),
@@ -1844,12 +1834,6 @@ def bench_poisson_tpu(model_name: str, rates, duration_s: float,
                 "LLMQ_BENCH_MIXED_BUDGET", "128")),
             max_slices=int(os.environ.get(
                 "LLMQ_BENCH_MIXED_SLICES", "2")))
-    # Ragged paged-attention A/B (docs/performance.md "Ragged
-    # attention"): =1 serves the sweep through the ragged program
-    # (token-budget slice packing, no bucket programs), =0/unset keeps
-    # the bucket/fused baseline — per-point kernel path is recorded so
-    # the headline delta is attributable.
-    ragged_on = os.environ.get("LLMQ_BENCH_RAGGED_ATTENTION", "0") == "1"
     # Mesh sweep (ISSUE 15, docs/multihost.md): LLMQ_BENCH_MESH (e.g.
     # "dp2xtp4") serves the whole SLA sweep through a dp×tp mesh —
     # params rule-table sharded, per-chip paged KV, MFU computed
@@ -1861,10 +1845,6 @@ def bench_poisson_tpu(model_name: str, rates, duration_s: float,
     if mesh_env:
         mesh_shape = parse_mesh_spec(mesh_env)
         from llmq_tpu.parallel import make_mesh
-        if ragged_on:
-            log("[poisson-tpu] ragged_attention is single-chip; "
-                "bucket path serves the mesh sweep")
-            ragged_on = False
         dp = int(mesh_shape.get("dp", 1))
         if dp > 1:
             # dp splits the page axis and the batch rows: keep both
@@ -1879,7 +1859,6 @@ def bench_poisson_tpu(model_name: str, rates, duration_s: float,
                                   else None),
                      mixed_prefill_slices=(mb.max_slices if mb else 0),
                      mixed_slice_tokens=(mb.slice_tokens if mb else 0),
-                     ragged_attention=ragged_on,
                      eos_id=tok.eos_id,
                      # Matches the engine's enable_metrics=False below:
                      # telemetry stays host-side (read per rate point),
@@ -2091,7 +2070,6 @@ def bench_poisson_tpu(model_name: str, rates, duration_s: float,
             dp=(int(mesh.shape.get("dp", 1)) if mesh is not None
                 else 1))
         point["device"] = {
-            "kernel_path": "ragged" if ragged_on else "bucket",
             # Per-rate-point mesh geometry: mfu_pct below is already
             # computed against n_chips × peak (device telemetry), and
             # "hbm" carries the truthful per-chip splits.
@@ -2367,7 +2345,6 @@ def bench_poisson_tpu(model_name: str, rates, duration_s: float,
     out["decode_step_ms_est"] = round(ex.step_ms or 0.0, 3)
     out["warmup_s"] = round(warmup_s, 1)
     out["decode_steps"] = engine.steps
-    out["kernel_path"] = "ragged" if ragged_on else "bucket"
     # Headline mesh geometry (None = single chip): sla_curve numbers
     # from different geometries are different machines — the artifact
     # must say which one produced the headline.
@@ -2615,7 +2592,6 @@ def main() -> None:
             # the bisection floor) — unreachable, not zero capacity.
             "gate_unreachable_8b":
                 (tpu_tiers_8b or {}).get("gate_unreachable", False),
-            "kernel_path": (tpu or {}).get("kernel_path"),
             # The serving mesh behind the SLA numbers (None = one
             # chip): dp×tp geometry + chip count, from LLMQ_BENCH_MESH.
             "mesh": (tpu_tiers or {}).get("mesh"),

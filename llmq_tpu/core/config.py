@@ -946,39 +946,6 @@ class MixedBatchConfig:
 
 
 @dataclass
-class RaggedAttentionConfig:
-    """Ragged paged-attention plane (docs/performance.md "Ragged
-    attention"; PAPERS.md arxiv 2604.15464). When enabled, the JAX
-    executor's mixed program takes prefill slices as ONE packed token
-    buffer with per-slice (q_offset, q_len) descriptors — a single
-    Pallas launch per layer serves the whole mixed batch on TPU, the
-    per-bucket prefill programs are neither built nor compiled (ALL
-    prefill routes through the ragged program), the engine packs
-    slices against the token budget instead of fixed slice widths, and
-    the warmup/compile/export surface shrinks to {ragged_chunk,
-    decode_chunk}. ``enabled: false`` (the DEFAULT) is a hard
-    off-switch: the bucket/fused path is byte-identical to
-    pre-ragged behavior."""
-    enabled: bool = False
-    #: Packed prefill-token capacity of the compiled ragged program
-    #: (one slice may take the whole capacity). 0 → derive from
-    #: ``mixed_batch.prefill_token_budget``.
-    prefill_token_capacity: int = 0
-    #: Max slices per ragged dispatch. 0 → derive from
-    #: ``mixed_batch.max_slices``.
-    max_slices: int = 0
-
-    def __post_init__(self) -> None:
-        if self.prefill_token_capacity < 0:
-            raise ValueError(
-                "ragged_attention.prefill_token_capacity must be >= 0")
-        if not 0 <= self.max_slices <= 16:
-            raise ValueError(
-                f"ragged_attention.max_slices must be in [0, 16] "
-                f"(got {self.max_slices})")
-
-
-@dataclass
 class MeshConfig:
     """Mesh-native serving executor (docs/multihost.md "Mesh-native
     executor"). When enabled, the JAX executor builds a named
@@ -1033,8 +1000,6 @@ class ExecutorConfig:
     prefix_cache: PrefixCacheConfig = field(default_factory=PrefixCacheConfig)
     kv_tiering: KVTieringConfig = field(default_factory=KVTieringConfig)
     mixed_batch: MixedBatchConfig = field(default_factory=MixedBatchConfig)
-    ragged_attention: RaggedAttentionConfig = field(
-        default_factory=RaggedAttentionConfig)
     async_pipeline: AsyncPipelineConfig = field(
         default_factory=AsyncPipelineConfig)
     speculation: SpeculationConfig = field(

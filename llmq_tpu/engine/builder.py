@@ -47,8 +47,6 @@ def build_engine(cfg: Config, *, name: str = "engine0",
     pipe_on = bool(getattr(pipe, "enabled", False))
     spec = getattr(ex, "speculation", None)
     spec_on = bool(getattr(spec, "enabled", False))
-    ragged = getattr(ex, "ragged_attention", None)
-    ragged_on = bool(getattr(ragged, "enabled", False))
     mesh_cfg = getattr(ex, "mesh", None)
     # Mesh-native serving (docs/multihost.md): executor.mesh is the
     # first-class knob (hard off-switch); the legacy tpu.mesh_shape
@@ -58,31 +56,11 @@ def build_engine(cfg: Config, *, name: str = "engine0",
         mesh_shape = dict(mesh_cfg.shape)
     elif getattr(cfg.tpu, "mesh_shape", None):
         mesh_shape = dict(cfg.tpu.mesh_shape)
-    if ragged_on and mesh_shape:
-        # The ragged kernel is a single-chip program; JaxExecutor would
-        # silently disable it on the mesh path — disable it HERE so the
-        # engine geometry and the boot log agree with what actually
-        # serves (the bucket path at its bucket widths).
-        log.warning("ragged_attention requested but mesh sharding is "
-                    "configured; keeping the bucket path (the ragged "
-                    "kernel is single-chip)")
-        ragged_on = False
     # Executor-side mixed geometry: S slice rows × T tokens (the
     # compiled program's shapes). Disabled → S = 0 → no mixed program
     # is built, and the engine keeps the exact unfused scheduling.
     mixed_slices = int(getattr(mixed, "max_slices", 0)) if mixed_on else 0
     mixed_slice_tokens = (int(mixed.slice_tokens) if mixed_on else 0)
-    if ragged_on:
-        # Ragged packing has no fixed slice width: a slice may take
-        # the whole token capacity, so the ENGINE-visible geometry is
-        # (max_slices × capacity) — _pack_prefill_slices then packs
-        # against the token budget alone.
-        mixed_slices = int(getattr(ragged, "max_slices", 0)) or (
-            mixed_slices or 2)
-        mixed_slice_tokens = (
-            int(getattr(ragged, "prefill_token_capacity", 0))
-            or int(getattr(mixed, "prefill_token_budget", 0) or 0)
-            or 128)
 
     if ex.backend == "echo":
         executor = EchoExecutor(
@@ -99,11 +77,6 @@ def build_engine(cfg: Config, *, name: str = "engine0",
             # pipeline"): only exposed when the pipeline is on, so the
             # off-switch keeps the exact synchronous echo scheduling.
             async_chunks=pipe_on)
-        # The engine's ragged budget clamp keys on this attribute: the
-        # echo engine must pack the SAME dispatch shapes (total ≤
-        # capacity) the JAX executor asserts on, or echo-validated
-        # packing diverges from what the real path accepts.
-        executor.ragged_attention = ragged_on
     elif ex.backend == "jax":
         import jax
         import jax.numpy as jnp
@@ -189,15 +162,6 @@ def build_engine(cfg: Config, *, name: str = "engine0",
             cache_dtype=(jnp.int8 if kv_quant == "int8" else None),
             mixed_prefill_slices=mixed_slices,
             mixed_slice_tokens=mixed_slice_tokens,
-            ragged_attention=ragged_on,
-            # Pass the RESOLVED geometry (mixed_slice_tokens above is
-            # already the capacity in ragged mode): leaving these 0
-            # would make the executor's S×T derivation — meant for
-            # direct construction with bucket-style slice widths —
-            # multiply the capacity by max_slices again.
-            ragged_token_capacity=(mixed_slice_tokens if ragged_on
-                                   else 0),
-            ragged_max_slices=(mixed_slices if ragged_on else 0),
             mesh=mesh,
             # Speculative decoding (docs/performance.md "Speculative
             # decoding"): draft_k > 0 builds the jitted verify program;
@@ -240,7 +204,7 @@ def build_engine(cfg: Config, *, name: str = "engine0",
     tier = getattr(ex, "kv_tiering", None)
     from llmq_tpu.observability.device import describe_device
     log.info("built %s engine %s on %s (slots=%d pages=%d page_size=%d "
-             "mesh=%s prefix_cache=%s mixed_batch=%s ragged_attention=%s "
+             "mesh=%s prefix_cache=%s mixed_batch=%s "
              "async_pipeline=%s kv_tiering=%s speculation=%s)",
              ex.backend, name, describe_device(engine.device_identity()),
              ex.max_batch_size, ex.kv_pages, ex.page_size,
@@ -249,8 +213,6 @@ def build_engine(cfg: Config, *, name: str = "engine0",
              "on" if getattr(ex.prefix_cache, "enabled", False) else "off",
              (f"on(budget={mixed.prefill_token_budget}"
               f"x{mixed_slices})" if mixed_on else "off"),
-             (f"on(cap={mixed_slice_tokens}x{mixed_slices})"
-              if ragged_on else "off"),
              (f"on(depth={pipe.depth})" if pipe_on else "off"),
              (f"on(host={tier.host_capacity_mb}MiB)"
               if getattr(tier, "enabled", False) else "off"),

@@ -673,14 +673,12 @@ class InferenceEngine:
         #: can take no further step and only the host ends it;
         #: ``nothing_to_decode`` no row has budget left beyond the
         #: chunks in flight; ``spec`` speculation windows never chain;
-        #: ``ragged`` the ragged program starts from host state;
         #: ``tenancy`` tenant fairness caps budgets in the host's
         #: assembly only.
         self.fill_refusals: Dict[str, int] = {
             k: 0 for k in ("depth", "free_slot", "urgent_pending",
                            "cancelled", "geometry", "pages", "row_ended",
-                           "nothing_to_decode", "spec", "ragged",
-                           "tenancy")}
+                           "nothing_to_decode", "spec", "tenancy")}
         self._fill_stopped = ""
         #: Host staging buffers for chunk assembly (tokens/positions/
         #: block tables/temps) — per-dispatch np.zeros churn killer.
@@ -1467,8 +1465,6 @@ class InferenceEngine:
         if self._head_may_preempt():
             return "urgent_pending"
         slices = self._mixed_work_waiting()
-        if slices and getattr(self.executor, "ragged_attention", False):
-            return "ragged"     # that program starts from host state
         if ((slices or self._pending) and self._tenancy.enabled
                 and len({s.req.tenant_id for s in self._slots}) > 1):
             return "tenancy"
@@ -3767,17 +3763,10 @@ class InferenceEngine:
         (``_dispatch_speculative``) mixed chunk."""
         S = int(getattr(self.executor, "mixed_prefill_slices", 0))
         T = int(getattr(self.executor, "mixed_slice_tokens", 0))
-        # The dispatch can never out-pack the compiled program. Bucket
-        # mode packs ≤ S·T by construction (T = budget//S), so the
-        # clamp is a no-op there. In RAGGED mode T is the packed
-        # buffer's TOTAL capacity and slices have no fixed width — a
-        # single slice may take the whole budget (token-budget packing
-        # with no bucket boundaries), so the total clamps to T.
-        budget = int(self._mixed_cfg.prefill_token_budget)
-        if getattr(self.executor, "ragged_attention", False):
-            budget = min(budget, T)
-        else:
-            budget = min(budget, S * T)
+        # The dispatch can never out-pack the compiled program's S
+        # slices of T tokens (the builder sets T = budget // S, so the
+        # clamp only bites on an executor built by hand).
+        budget = min(int(self._mixed_cfg.prefill_token_budget), S * T)
         cands.sort(key=lambda s: s.sort_key())
         # Tenancy plane (docs/tenancy.md): under multi-tenant
         # contention for the prefill budget, pack with per-tenant

@@ -806,9 +806,6 @@ class JaxExecutor:
                  chunk_size: int = 16, prefill_batch: int = 4,
                  mixed_prefill_slices: int = 2,
                  mixed_slice_tokens: int = 64,
-                 ragged_attention: bool = False,
-                 ragged_token_capacity: int = 0,
-                 ragged_max_slices: int = 0,
                  speculation_draft_k: int = 0,
                  speculation_device_sampling: bool = True,
                  mesh=None, telemetry_name: str = "engine0",
@@ -818,9 +815,8 @@ class JaxExecutor:
         from functools import partial
 
         from llmq_tpu.models.llama import (
-            forward_decode, forward_mixed, forward_mixed_ragged,
-            forward_prefill, forward_verify, init_kv_pages)
-        from llmq_tpu.ops.attention import RAGGED_Q_BLOCK
+            forward_decode, forward_mixed, forward_prefill,
+            forward_verify, init_kv_pages)
         from llmq_tpu.ops.sampling import (
             position_keys, sample_token, sample_token_keyed)
 
@@ -886,49 +882,15 @@ class JaxExecutor:
         #: per-sequence KV-write/attention kernels row-loop inside).
         self.prefill_batch = max(1, min(prefill_batch, batch_size))
         self.prefill_buckets = sorted(prefill_buckets or [32, 128, 512])
-        #: Mixed-batch program geometry: S slice rows × T tokens per
-        #: row fused into the decode chunk (0 disables — no mixed
-        #: program is built or compiled). See ``_mixed_chunk`` below.
+        #: Mixed-batch program geometry: S slice rows fused into the
+        #: decode chunk, each ``mixed_slice_tokens`` wide — a slice's
+        #: width, nothing else (0 disables — no mixed program is built
+        #: or compiled). See ``_mixed_chunk`` below.
         self.mixed_prefill_slices = max(0, mixed_prefill_slices)
         self.mixed_slice_tokens = max(0, mixed_slice_tokens)
         if self.mixed_prefill_slices == 0 or self.mixed_slice_tokens == 0:
             self.mixed_prefill_slices = 0
             self.mixed_slice_tokens = 0
-        #: Ragged paged-attention plane (docs/performance.md "Ragged
-        #: attention"; PAPERS.md arxiv 2604.15464). ON: the mixed
-        #: program takes slices as ONE packed token buffer with
-        #: per-slice descriptors (any packing of the token budget runs
-        #: the same compiled geometry), per-bucket prefill programs
-        #: are neither built nor compiled — ALL prefill routes through
-        #: the ragged program — and the warmup/export surface shrinks
-        #: to {ragged_chunk, decode_chunk}. OFF (default):
-        #: byte-identical bucket/fused behavior. Mesh path stays on
-        #: buckets: the ragged kernel is a single-chip program.
-        self.ragged_attention = bool(
-            ragged_attention and mesh is None)
-        self._ragged_qblk = RAGGED_Q_BLOCK
-        if self.ragged_attention:
-            S = max(1, ragged_max_slices or self.mixed_prefill_slices
-                    or 2)
-            cap = max(self._ragged_qblk,
-                      ragged_token_capacity
-                      or (self.mixed_prefill_slices
-                          * self.mixed_slice_tokens)
-                      or 128)
-            # The engine packs against (S slices × ≤cap tokens each,
-            # ≤ budget total): report the ragged geometry through the
-            # mixed attrs so _pack_prefill_slices becomes pure
-            # token-budget packing (no bucket boundaries).
-            self.mixed_prefill_slices = S
-            self.mixed_slice_tokens = cap
-            # Packed-buffer capacity: every slice segment pads to the
-            # kernel q-block, so the worst case is cap live tokens
-            # plus one partial granule per slice.
-            need = cap + S * (self._ragged_qblk - 1)
-            self._ragged_buf = -(-need // self._ragged_qblk
-                                 ) * self._ragged_qblk
-        else:
-            self._ragged_buf = 0
         if self._kv_shardings is not None:
             # Create the pool ALREADY sharded (out_shardings) — a 70B
             # pool materialized on one chip before resharding would OOM
@@ -1107,69 +1069,7 @@ class JaxExecutor:
 
         S, T = self.mixed_prefill_slices, self.mixed_slice_tokens
         _mixed_chunk = None
-        if self.ragged_attention:
-
-            @jit_mixed
-            def _mixed_chunk(params, cache, tokens, positions,
-                             block_tables, temperatures, budgets, done_in,
-                             pf_tokens, pf_positions, pf_qoff, pf_qlen,
-                             pf_block_tables, pf_temps, key):
-                """RAGGED mixed chunk: identical contract to the bucket
-                ``_mixed_chunk`` below (same carry, same pf_first
-                semantics, same EOS/budget latching) but the prefill
-                slices arrive as ONE packed (NBUF,) token buffer with
-                per-slice (q_offset, q_len) descriptors — step 0 runs
-                forward_mixed_ragged, so any packing of the token
-                budget (one long slice, many tails) is one program and,
-                on TPU, one attention launch per layer."""
-                B = tokens.shape[0]
-                keys = jax.random.split(key, K + 1)
-                out = jnp.full((B, K), eos, jnp.int32)
-                frozen = done_in
-                active0 = (~frozen) & (budgets > 0)
-                dec_logits, pf_logits, cache = forward_mixed_ragged(
-                    params, cfg, tokens, positions, cache, block_tables,
-                    pf_tokens, pf_positions, pf_qoff, pf_qlen,
-                    pf_block_tables, dec_active=active0)
-                pf_first = sample_token(
-                    pf_logits, keys[K], temperature=pf_temps,
-                    top_k=top_k, top_p=top_p)
-                nxt = sample_token(dec_logits, keys[0],
-                                   temperature=temperatures,
-                                   top_k=top_k, top_p=top_p)
-                emit = jnp.where(active0, nxt, eos).astype(jnp.int32)
-                out = out.at[:, 0].set(emit)
-                tok = jnp.where(active0, nxt.astype(jnp.int32), tokens)
-                pos = positions + active0.astype(jnp.int32)
-                frozen = frozen | (active0 & (nxt == eos))
-
-                def cond(st):
-                    j, _, _, _, fr, _ = st
-                    return (j < K) & jnp.any(~fr & (j < budgets))
-
-                def body(st):
-                    j, cache, tok, pos, fr, out = st
-                    active = (~fr) & (j < budgets)
-                    logits, cache = forward_decode(
-                        params, cfg, tok, pos, cache, block_tables,
-                        active=active)
-                    nxt = sample_token(logits, keys[j],
-                                       temperature=temperatures,
-                                       top_k=top_k, top_p=top_p)
-                    emit = jnp.where(active, nxt, eos).astype(jnp.int32)
-                    out = jax.lax.dynamic_update_slice(
-                        out, emit[:, None], (0, j))
-                    tok = jnp.where(active, nxt.astype(jnp.int32), tok)
-                    pos = pos + active.astype(jnp.int32)
-                    fr = fr | (active & (nxt == eos))
-                    return (j + 1, cache, tok, pos, fr, out)
-
-                _, cache, tok, pos, frozen, out = jax.lax.while_loop(
-                    cond, body,
-                    (jnp.int32(1), cache, tok, pos, frozen, out))
-                return out, tok, pos, frozen, pf_first, cache
-
-        elif S > 0:
+        if S > 0:
 
             @jit_mixed
             def _mixed_chunk(params, cache, tokens, positions,
@@ -1579,8 +1479,8 @@ class JaxExecutor:
         return self._batch_arr(
             np.zeros(self.spec.batch_size, np.bool_), np.bool_)
 
-    def _routes(self, *, decode: bool = False, prefill_rows: int = 0,
-                ragged: bool = False) -> Dict[str, str]:
+    def _routes(self, *, decode: bool = False,
+                prefill_rows: int = 0) -> Dict[str, str]:
         """Attention-op routes of one program at this executor's
         geometry (see :func:`llmq_tpu.ops.attention.kernel_routes`)."""
         from llmq_tpu.ops.attention import kernel_routes
@@ -1589,13 +1489,12 @@ class JaxExecutor:
         kv = self.cache["k"]
         return kernel_routes(
             batch=self.spec.batch_size, page_size=self.spec.page_size,
-            max_pages=self.spec.max_pages_per_seq, n_heads=cfg.n_heads,
+            max_pages=self.spec.max_pages_per_seq,
             n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
             kv_itemsize=kv.dtype.itemsize,
             quant_kv="k_scale" in self.cache, enabled=cfg.pallas,
             multi_ok=cfg.pallas_batched_prefill, decode=decode,
-            prefill_rows=prefill_rows,
-            ragged_tokens=self._ragged_buf if ragged else 0)
+            prefill_rows=prefill_rows)
 
     def _export_cache_dir(self) -> Optional[str]:
         """Directory for serialized post-lowering program artifacts
@@ -1681,12 +1580,6 @@ class JaxExecutor:
                       # across budget/slice reconfigurations.
                       (self.mixed_prefill_slices,
                        self.mixed_slice_tokens),
-                      # Ragged geometry: the ragged program's packed
-                      # buffer replaces the (S, T) grid entirely, so a
-                      # stale bucket-grid export must MISS when the
-                      # plane toggles (and vice versa).
-                      ("ragged", self.ragged_attention,
-                       self._ragged_buf, self._ragged_qblk),
                       # Speculation geometry: W = draft_k + 1 sets the
                       # verify program's shapes, and device- vs
                       # host-accept lower DIFFERENT programs under the
@@ -1756,25 +1649,19 @@ class JaxExecutor:
 
         jobs = []
         NPF = self.prefill_batch
-        if not self.ragged_attention:
-            # Ragged mode compiles NO per-bucket prefill programs: the
-            # (S, T) geometry grid collapses into the single ragged
-            # program below — the compile/warmup surface shrink is the
-            # telemetry-visible half of ROADMAP item 2.
-            for T in self.prefill_buckets:
-                jobs.append((f"prefill_b{T}", self._prefill_step,
-                             (p, c, sds((1, T), i32), sds((1, T), i32),
-                              sds((1,), i32), sds((1, MP), i32),
-                              sds((1,), f32), key),
-                             self._routes(prefill_rows=1)))
-                if NPF > 1:
-                    jobs.append((f"prefill_multi_b{T}",
-                                 self._prefill_multi,
-                                 (p, c, sds((NPF, T), i32),
-                                  sds((NPF, T), i32), sds((NPF,), i32),
-                                  sds((NPF, MP), i32), sds((NPF,), f32),
-                                  key),
-                                 self._routes(prefill_rows=NPF)))
+        for T in self.prefill_buckets:
+            jobs.append((f"prefill_b{T}", self._prefill_step,
+                         (p, c, sds((1, T), i32), sds((1, T), i32),
+                          sds((1,), i32), sds((1, MP), i32),
+                          sds((1,), f32), key),
+                         self._routes(prefill_rows=1)))
+            if NPF > 1:
+                jobs.append((f"prefill_multi_b{T}", self._prefill_multi,
+                             (p, c, sds((NPF, T), i32),
+                              sds((NPF, T), i32), sds((NPF,), i32),
+                              sds((NPF, MP), i32), sds((NPF,), f32),
+                              key),
+                             self._routes(prefill_rows=NPF)))
         dec_routes = self._routes(decode=True)
         if self.chunk_size > 1:
             # The engine decodes through ``decode_chunk`` whenever the
@@ -1804,19 +1691,7 @@ class JaxExecutor:
                              (p, c, bsds((B, Wv), i32), bsds((B,), i32),
                               bsds((B, MP), i32), bsds((B,), f32),
                               bsds((B,), i32), key), dec_routes))
-        if self._mixed_chunk is not None and self.ragged_attention:
-            S = self.mixed_prefill_slices
-            N = self._ragged_buf
-            jobs.append(("ragged_chunk", self._mixed_chunk,
-                         (p, c, sds((B,), i32), sds((B,), i32),
-                          sds((B, MP), i32), sds((B,), f32),
-                          sds((B,), i32), sds((B,), jnp.bool_),
-                          sds((N,), i32), sds((N,), i32),
-                          sds((S,), i32), sds((S,), i32),
-                          sds((S, MP), i32), sds((S,), f32), key),
-                         self._routes(decode=True, prefill_rows=S,
-                                      ragged=True)))
-        elif self._mixed_chunk is not None:
+        if self._mixed_chunk is not None:
             S, T = self.mixed_prefill_slices, self.mixed_slice_tokens
             jobs.append(("mixed_chunk", self._mixed_chunk,
                          (p, c, bsds((B,), i32), bsds((B,), i32),
@@ -1967,26 +1842,19 @@ class JaxExecutor:
         cache_warm = bool(self._aot) and all(
             name in self._from_export_cache for name in self._aot)
         bt = np.zeros((1, spec.max_pages_per_seq), np.int32)
-        if self.ragged_attention:
-            # No bucket programs exist: one small prefill smokes the
-            # ragged program end-to-end (all writes land on reserved
-            # page 0 through the all-zero block table).
-            self.prefill([1] * min(8, self.mixed_slice_tokens), 0,
-                         bt[0], 0.0, 0)
-        else:
-            prev = 0
-            for b in (self.prefill_buckets[:1] if cache_warm
-                      else self.prefill_buckets):
-                # One full-size prefill per bucket: lengths prev+1..b
-                # stream a chunk of exactly size-b through the bucket-b
-                # program.
-                n = min(b, prev + 1)
-                self.prefill([1] * n, 0, bt[0], 0.0, 0)
-                if self.prefill_batch > 1:
-                    # The admission-wave program of the same bucket,
-                    # and the eager per-row split of its result.
-                    self.prefill_multi_async([([1] * n, 0, bt[0], 0.0)])
-                prev = b
+        prev = 0
+        for b in (self.prefill_buckets[:1] if cache_warm
+                  else self.prefill_buckets):
+            # One full-size prefill per bucket: lengths prev+1..b
+            # stream a chunk of exactly size-b through the bucket-b
+            # program.
+            n = min(b, prev + 1)
+            self.prefill([1] * n, 0, bt[0], 0.0, 0)
+            if self.prefill_batch > 1:
+                # The admission-wave program of the same bucket, and
+                # the eager per-row split of its result.
+                self.prefill_multi_async([([1] * n, 0, bt[0], 0.0)])
+            prev = b
         # Reset pool: warmup wrote garbage KV into page 0 only (block
         # table all-zero), which is never read — nothing to clean.
         zeros_b = np.zeros(spec.batch_size, np.int32)
@@ -2026,7 +1894,7 @@ class JaxExecutor:
                 zeros_b, zeros_b, zbt, ztemp, ones_b, overrides=join)
             h = self.decode_chunk_start(
                 None, None, zbt, ztemp, ones_b, carry=h, overrides=join)
-            if mixed is not None and not self.ragged_attention:
+            if mixed is not None:
                 # Under a full batch a row whose FINAL slice rode a
                 # mixed chunk joins the next chunk from that chunk's
                 # ``pf_first``, indexed on the device: every slice
@@ -2102,11 +1970,7 @@ class JaxExecutor:
         ``tokens`` is the longest prompt chunk of a prefill dispatch.
         What the engine puts on its ``engine.dispatch`` span."""
         if entry in ("prefill", "prefill_multi"):
-            if self.ragged_attention:
-                return "ragged_chunk"
             return f"{entry}_b{self._bucket_for(max(1, tokens))}"
-        if entry == "mixed_chunk" and self.ragged_attention:
-            return "ragged_chunk"
         return entry
 
     def _prefill_chunk(self, chunk: List[int], start_pos: int, bt,
@@ -2139,13 +2003,6 @@ class JaxExecutor:
                 slot: int) -> int:
         jnp = self._jnp
         spec = self.spec
-        if self.ragged_attention:
-            if not tokens:
-                return spec.eos_id
-            res = self._ragged_prefill_start(
-                [(list(tokens), start_pos,
-                  np.asarray(block_table, np.int32), temperature)])
-            return int(np.asarray(res[0]))
         bt = jnp.asarray(block_table, jnp.int32)[None, :]
         pos = start_pos
         remaining = list(tokens)
@@ -2173,9 +2030,6 @@ class JaxExecutor:
         jnp = self._jnp
         N = self.prefill_batch
         assert 0 < len(reqs) <= N, len(reqs)
-        if self.ragged_attention:
-            return self._ragged_prefill_start(
-                [(list(t), sp, bt, temp) for t, sp, bt, temp in reqs])
         T = self._bucket_for(max(len(t) for t, _, _, _ in reqs))
         st = self._staging
         toks = st.take(f"pfm{T}.tok", (N, T), np.int32)
@@ -2205,10 +2059,6 @@ class JaxExecutor:
         sampled first token as a device array (fetch it when needed).
         Steady-state admission throughput — benchmarks and future
         sync-free engine paths; tokens must fit the largest bucket."""
-        if self.ragged_attention:
-            return self._ragged_prefill_start(
-                [(list(tokens), start_pos,
-                  np.asarray(block_table, np.int32), temperature)])[0]
         if len(tokens) > self.prefill_buckets[-1]:
             raise ValueError("prefill_async requires a single-bucket chunk")
         bt = self._jnp.asarray(block_table, self._jnp.int32)[None, :]
@@ -2365,18 +2215,10 @@ class JaxExecutor:
         ``decode_chunk_start``: the decode rows start from the previous
         chunk's device-resident end state, and a lane is re-seeded for
         a joining row. Same compiled program either way — it always
-        took ``tok / pos / done`` as device arrays. The ragged program
-        takes neither (the engine reconciles instead)."""
+        took ``tok / pos / done`` as device arrays."""
         if self._mixed_chunk is None:
             raise RuntimeError("mixed batching disabled for this executor")
         jnp = self._jnp
-        if self.ragged_attention:
-            if carry is not None or overrides:
-                raise NotImplementedError(
-                    "the ragged mixed program starts from host state")
-            return self._ragged_chunk_start(tokens, positions,
-                                            block_tables, temperatures,
-                                            budgets, pf)
         S, T = self.mixed_prefill_slices, self.mixed_slice_tokens
         assert 0 < len(pf) <= S, len(pf)
         st = self._staging
@@ -2409,121 +2251,6 @@ class JaxExecutor:
             jnp.asarray(pf_temps),
             self._next_key())
         return MixedChunkHandle(out, tok, pos, done, pf_first)
-
-    def _ragged_chunk_start(self, tokens, positions, block_tables,
-                            temperatures, budgets, pf: List,
-                            tag: str = "ragged") -> "MixedChunkHandle":
-        """Ragged mixed dispatch (docs/performance.md "Ragged
-        attention"): the slices pack into ONE (NBUF,) token buffer —
-        each segment q-block-aligned so every kernel q-block has one
-        owner — with per-slice (q_offset, q_len) descriptors, instead
-        of the (S, T) dense grid. A 100-token slice and three 8-token
-        tails are the same compiled program. Same handle contract as
-        the bucket ``mixed_chunk_start``.
-
-        ``tag`` keeps the two dispatch families' staging buffers
-        DISJOINT (same discipline as the bucket path's "mixed.*" vs
-        "pfm*.*" tags): engine mixed dispatches are bounded by the
-        pipeline depth, prefill waves by their own ring fence — shared
-        tags would let the combined outstanding count exceed the ring
-        and rewrite a buffer a queued program still aliases."""
-        jnp = self._jnp
-        S = self.mixed_prefill_slices
-        N = self._ragged_buf
-        qblk = self._ragged_qblk
-        cap = self.mixed_slice_tokens
-        assert 0 < len(pf) <= S, len(pf)
-        assert sum(len(t) for _s, t, *_ in pf) <= cap, \
-            "ragged pack exceeds the token capacity"
-        st = self._staging
-        pf_toks = st.take(f"{tag}.tok", (N,), np.int32)
-        pf_poss = st.take(f"{tag}.pos", (N,), np.int32)
-        pf_qoff = st.take(f"{tag}.qoff", (S,), np.int32)
-        pf_qlen = st.take(f"{tag}.qlen", (S,), np.int32)
-        pf_bts = st.take(f"{tag}.bt", (S, self.spec.max_pages_per_seq),
-                         np.int32)
-        pf_temps = st.take(f"{tag}.temp", (S,), np.float32)
-        off = 0
-        for i, (_slot, t, sp, bt, temp) in enumerate(pf):
-            L = len(t)
-            assert 0 < L <= cap, L
-            pf_toks[off:off + L] = t
-            np.add(st.arange(L), sp, out=pf_poss[off:off + L])
-            pf_qoff[i] = off
-            pf_qlen[i] = L
-            pf_bts[i] = bt
-            pf_temps[i] = temp
-            off += -(-L // qblk) * qblk
-        assert off <= N, (off, N)
-        fn = self._aot.get("ragged_chunk", self._mixed_chunk)
-        out, tok, pos, done, pf_first, self.cache = fn(
-            self.params, self.cache,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(block_tables, jnp.int32),
-            jnp.asarray(temperatures, jnp.float32),
-            jnp.asarray(budgets, jnp.int32),
-            jnp.zeros(self.spec.batch_size, bool),
-            jnp.asarray(pf_toks), jnp.asarray(pf_poss),
-            jnp.asarray(pf_qoff), jnp.asarray(pf_qlen),
-            jnp.asarray(pf_bts), jnp.asarray(pf_temps),
-            self._next_key())
-        return MixedChunkHandle(out, tok, pos, done, pf_first)
-
-    def _ragged_prefill_start(self, reqs: List) -> List:
-        """Route prefill work through the ragged program — the bucket
-        programs do not exist in ragged mode. ``reqs``: (tokens,
-        start_pos, block_table, temperature) per sequence. Prompts
-        chunk into ≤capacity pieces packed ≥1 per dispatch (pieces of
-        one request stay in order — the device stream is FIFO, and two
-        pieces of one request may even share a dispatch: the ragged
-        step writes every slice's KV before any slice attends).
-        Decode rows ride frozen (budgets 0 → every write redirects to
-        reserved page 0). Returns one device scalar per request — the
-        sampled next token as of the request's final piece."""
-        cap = self.mixed_slice_tokens
-        S = self.mixed_prefill_slices
-        qblk = self._ragged_qblk
-        st = self._staging
-        B, MP = self.spec.batch_size, self.spec.max_pages_per_seq
-        zeros_b = st.take("raggedpf.tok", (B,), np.int32)
-        zbt = st.take("raggedpf.bt", (B, MP), np.int32)
-        ztemp = st.take("raggedpf.temp", (B,), np.float32)
-        zbud = st.take("raggedpf.bud", (B,), np.int32)
-        results: List = [None] * len(reqs)
-        pieces = []
-        for ri, (toks, sp, bt, temp) in enumerate(reqs):
-            toks = list(toks)
-            off = 0
-            while off < len(toks):
-                chunk = toks[off:off + cap]
-                pieces.append((ri, chunk, sp + off, bt, temp,
-                               off + len(chunk) >= len(toks)))
-                off += len(chunk)
-        i = 0
-        while i < len(pieces):
-            group = []
-            live = padded = 0
-            while i < len(pieces) and len(group) < S:
-                _ri, chunk, _sp, _bt, _temp, _fin = pieces[i]
-                pad = -(-len(chunk) // qblk) * qblk
-                if group and (live + len(chunk) > cap
-                              or padded + pad > self._ragged_buf):
-                    break
-                group.append(pieces[i])
-                live += len(chunk)
-                padded += pad
-                i += 1
-            pf = [(0, chunk, sp, bt, temp)
-                  for (_ri, chunk, sp, bt, temp, _fin) in group]
-            handle = self._ragged_chunk_start(zeros_b, zeros_b, zbt,
-                                              ztemp, zbud, pf,
-                                              tag="raggedpf")
-            self._staging_fence("raggedpf", handle.out)
-            for j, (ri, _c, _sp, _bt, _t, fin) in enumerate(group):
-                if fin:
-                    results[ri] = handle.pf_first[j]
-        return results
 
     # -- tiered KV page transport (llmq_tpu/tiering/, docs/tiering.md) --------
 

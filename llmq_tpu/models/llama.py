@@ -42,9 +42,7 @@ from llmq_tpu.ops.attention import (dispatch_prefill_attention,
                                     paged_decode_step,
                                     paged_decode_step_q8,
                                     paged_kv_write_prefill,
-                                    paged_kv_write_prefill_q8,
-                                    ragged_mixed_step,
-                                    ragged_mixed_step_q8)
+                                    paged_kv_write_prefill_q8)
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import (embed_lookup, is_quantized, layer_slice,
                                 linear, tied_head_logits)
@@ -673,126 +671,6 @@ def forward_mixed(
     else:
         out_cache = {"k": k_pool, "v": v_pool}
     return _logits(params, h_d), _logits(params, h_p), out_cache
-
-
-@partial(jax.jit, static_argnames=("cfg",))
-def forward_mixed_ragged(
-    params: Params,
-    cfg: LlamaConfig,
-    dec_tokens: jnp.ndarray,        # (B,) int32
-    dec_positions: jnp.ndarray,     # (B,) int32
-    kv_cache: KVCache,
-    dec_block_tables: jnp.ndarray,  # (B, max_pages)
-    pf_tokens: jnp.ndarray,         # (N,) int32 — PACKED slice tokens
-    pf_positions: jnp.ndarray,      # (N,) int32 absolute, contiguous
-    pf_qoff: jnp.ndarray,           # (S,) int32 — qblk-aligned offsets
-    pf_qlen: jnp.ndarray,           # (S,) int32 — live tokens per slice
-    pf_block_tables: jnp.ndarray,   # (S, max_pages)
-    dec_active: Optional[jnp.ndarray] = None,  # (B,) bool
-) -> Tuple[jnp.ndarray, jnp.ndarray, KVCache]:
-    """:func:`forward_mixed`'s RAGGED path (ROADMAP item 2; PAPERS.md
-    arxiv 2604.15464): the prefill work arrives as ONE packed token
-    buffer with per-slice (q_offset, q_len) descriptors instead of the
-    (S, T) dense slice grid, and every layer's attention — decode rows
-    AND all packed slice tokens — runs through
-    :func:`llmq_tpu.ops.attention.ragged_mixed_step` (one Pallas launch
-    on TPU; the exact bucket-path ops elsewhere). One program serves
-    every packing of the token budget: a 100-token slice and a handful
-    of 8-token tails cost the same compiled geometry.
-
-    Slice conventions: segment ``i`` occupies packed rows
-    ``[pf_qoff[i], pf_qoff[i] + pf_qlen[i])`` (offsets multiples of the
-    kernel q-block, rows between segments are discarded padding);
-    positions are contiguous per segment with padding clamped to the
-    last valid position, exactly like :func:`forward_prefill` rows.
-    Returns ``(dec_logits (B, V), pf_last_logits (S, V), cache)`` —
-    the slice logits are sampled at each slice's LAST valid token (the
-    admission first-token when the slice is a sequence's final one).
-    """
-    B = dec_tokens.shape[0]
-    N = pf_tokens.shape[0]
-    page_sz = kv_cache["k"].shape[2]
-
-    h_d = embed_lookup(params["embed"], dec_tokens, cfg.dtype)   # (B, D)
-    cos_d, sin_d = rope_cos_sin(dec_positions[:, None], cfg.head_dim,
-                                cfg.rope_theta)
-    page_of = dec_block_tables[jnp.arange(B), dec_positions // page_sz]
-    if dec_active is not None:
-        page_of = jnp.where(dec_active, page_of, 0)
-    slot_of = dec_positions % page_sz
-    dec_seq_lens = dec_positions + 1
-
-    # Packed slice rows ride as ONE (1, N) "sequence" through the dense
-    # math (norms/QKV/MLP batch over tokens regardless of ownership);
-    # only the attention dispatch consumes the ragged descriptors.
-    h_p = embed_lookup(params["embed"], pf_tokens[None, :], cfg.dtype)
-    cos_p, sin_p = rope_cos_sin(pf_positions[None, :], cfg.head_dim,
-                                cfg.rope_theta)
-
-    lp = params["layers"]
-    quant_kv = "k_scale" in kv_cache
-    k_pool, v_pool = kv_cache["k"], kv_cache["v"]
-    if quant_kv:
-        pools = (k_pool, v_pool, kv_cache["k_scale"], kv_cache["v_scale"])
-    for l in range(cfg.n_layers):
-        wq, wk, wv = (layer_slice(lp["wq"], l), layer_slice(lp["wk"], l),
-                      layer_slice(lp["wv"], l))
-        hn_p = rms_norm(h_p, lp["attn_norm"][l], cfg.norm_eps)
-        q_p = linear(hn_p, wq).reshape(1, N, cfg.n_heads, cfg.head_dim)
-        k_p = linear(hn_p, wk).reshape(1, N, cfg.n_kv_heads, cfg.head_dim)
-        v_p = linear(hn_p, wv).reshape(1, N, cfg.n_kv_heads, cfg.head_dim)
-        q_p = apply_rope(q_p, cos_p, sin_p)[0]             # (N, H, D)
-        k_p = apply_rope(k_p, cos_p, sin_p)[0]
-        v_p = v_p[0]
-
-        hn_d = rms_norm(h_d, lp["attn_norm"][l], cfg.norm_eps)
-        q_d = linear(hn_d, wq).reshape(B, 1, cfg.n_heads, cfg.head_dim)
-        k_d = linear(hn_d, wk).reshape(B, 1, cfg.n_kv_heads, cfg.head_dim)
-        v_d = linear(hn_d, wv).reshape(B, 1, cfg.n_kv_heads, cfg.head_dim)
-        q_d = apply_rope(q_d, cos_d, sin_d)[:, 0]
-        k_d = apply_rope(k_d, cos_d, sin_d)[:, 0]
-        v_d = v_d[:, 0]
-
-        if quant_kv:
-            attn_d, attn_p, pools = ragged_mixed_step_q8(
-                q_d, k_d, v_d, q_p, k_p, v_p, pools, dec_block_tables,
-                dec_seq_lens, page_of, slot_of, pf_block_tables,
-                pf_positions, pf_qoff, pf_qlen, jnp.int32(l),
-                enabled=cfg.pallas,
-                multi_ok=cfg.pallas_batched_prefill)
-        else:
-            attn_d, attn_p, k_pool, v_pool = ragged_mixed_step(
-                q_d, k_d, v_d, q_p, k_p, v_p, k_pool, v_pool,
-                dec_block_tables, dec_seq_lens, page_of, slot_of,
-                pf_block_tables, pf_positions, pf_qoff, pf_qlen,
-                jnp.int32(l), enabled=cfg.pallas,
-                multi_ok=cfg.pallas_batched_prefill)
-
-        h_p = h_p + linear(attn_p.reshape(1, N, -1),
-                           layer_slice(lp["wo"], l))
-        hn2_p = rms_norm(h_p, lp["mlp_norm"][l], cfg.norm_eps)
-        h_p = h_p + _mlp(hn2_p, layer_slice(lp["w_gate"], l),
-                         layer_slice(lp["w_up"], l),
-                         layer_slice(lp["w_down"], l))
-
-        h_d = h_d + linear(attn_d.reshape(B, -1), layer_slice(lp["wo"], l))
-        hn2_d = rms_norm(h_d, lp["mlp_norm"][l], cfg.norm_eps)
-        h_d = h_d + _mlp(hn2_d, layer_slice(lp["w_gate"], l),
-                         layer_slice(lp["w_up"], l),
-                         layer_slice(lp["w_down"], l))
-
-    h_d = rms_norm(h_d, params["final_norm"], cfg.norm_eps)
-    h_p = rms_norm(h_p, params["final_norm"], cfg.norm_eps)
-    # Per-slice LAST valid token → (S, V) logits (what the bucket path
-    # samples at pf_logits[i, lengths[i]-1]).
-    idx_last = jnp.clip(pf_qoff + jnp.maximum(pf_qlen, 1) - 1, 0, N - 1)
-    h_last = h_p[0, idx_last]                              # (S, D)
-    if quant_kv:
-        out_cache = {"k": pools[0], "v": pools[1],
-                     "k_scale": pools[2], "v_scale": pools[3]}
-    else:
-        out_cache = {"k": k_pool, "v": v_pool}
-    return _logits(params, h_d), _logits(params, h_last), out_cache
 
 
 def _sp_forward_local(params: Params, tokens_local: jnp.ndarray,

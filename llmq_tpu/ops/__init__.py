@@ -1,6 +1,7 @@
 """TPU compute ops: norms, rotary embeddings, attention (prefill + paged
 decode), sampling. Pure-JAX reference implementations with Pallas TPU
-kernels for the hot decode path (``ops/pallas/``).
+kernels for the decode and prefill attention steps (``ops/pallas/``;
+``ops/attention.py`` routes between them).
 
 New scope — the reference delegates all model execution to external HTTP
 endpoints (SURVEY.md §2.2); these ops are the in-tree TPU inference

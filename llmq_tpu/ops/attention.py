@@ -1,29 +1,33 @@
 """Attention ops: causal prefill + paged decode.
 
-The paged layout (BASELINE north star; PAPERS.md ragged paged attention)
-stores KV in fixed-size pages indexed by per-sequence block tables, so
-conversations of different lengths share one HBM pool with no per-request
-reallocation and no recompilation (static shapes throughout — XLA traces
-once per batch geometry bucket).
+The paged layout (BASELINE north star) stores KV in fixed-size pages
+indexed by per-sequence block tables, so conversations of different
+lengths share one HBM pool with no per-request reallocation and no
+recompilation (static shapes throughout — XLA traces once per batch
+geometry bucket).
 
-Two implementations of the decode hot path:
+One implementation per step kind, chosen here and nowhere else:
 
-- :func:`paged_decode_attention` (this module) — pure JAX, the semantics
-  reference and the fallback on non-TPU backends. Gathers the full
-  padded window per step (correct, bandwidth-naive).
-- ``ops/pallas/paged_attention.py`` — the Pallas TPU kernel: streams
-  only live pages HBM→VMEM with double-buffered DMA and an online
-  softmax; tested against this module in tests/test_pallas.py.
+- pure JAX (this module): :func:`paged_decode_attention` and its
+  ``_pooled`` / ``_q8`` forms, the scatter writes and
+  :func:`blockwise_prefill_attention` (online softmax over KV chunks —
+  no (B, H, T, S) f32 logits tensor). The semantics reference the
+  kernels are tested against, and what serves off the TPU, under a
+  mesh and at geometries a kernel's predicate refuses.
+- ``ops/pallas/fused_decode.py`` — decode: the new token's KV write
+  fused with attention over the row's live pages (bf16 and int8 pools).
+- ``ops/pallas/prefill_attention.py`` — prefill attention of a slice
+  over its paged context (bf16 pools).
+- ``ops/pallas/kv_write.py`` — the decode and prefill page writes where
+  the fused decode kernel does not serve.
 
-:func:`paged_decode_step` routes each decode layer (TPU → fused
-Pallas write+attention kernel, else scatter + pure JAX;
-``LLMQ_PALLAS=0`` forces the fallback). Every routing decision is a
-pure function of geometry, backend and ``LLMQ_PALLAS``;
-:func:`kernel_routes` evaluates the same predicates outside a trace so
-the executor can log, per compiled program, which implementation each
-op took.
-:func:`blockwise_prefill_attention` is the memory-bounded prefill
-(online softmax over KV chunks — no (B, H, T, S) f32 logits tensor).
+:func:`_kernel_route` and the ``_ok`` predicates decide between them
+as pure functions of geometry, backend and ``LLMQ_PALLAS`` (``0``
+forces pure JAX); the dispatchers (:func:`paged_decode_step`,
+:func:`paged_kv_write_prefill`, :func:`dispatch_prefill_attention` and
+the ``_q8`` forms) call them at trace time, and :func:`kernel_routes`
+evaluates the same predicates outside a trace so the executor can log,
+per compiled program, which implementation each op took.
 """
 
 from __future__ import annotations
@@ -304,34 +308,11 @@ def _fused_decode_q8_ok(B: int, page_size: int, max_pages: int, gd: int,
             and fused_kernel_viable(B, page_size, max_pages, gd, 1))
 
 
-def _ragged_ok(B: int, page_size: int, max_pages: int, gd: int,
-               n_heads: int, n_tokens: int, itemsize: int,
-               multi_ok: bool) -> bool:
-    from llmq_tpu.ops.pallas.ragged_paged_attention import (
-        ragged_kernel_viable)
-    return (multi_ok and n_tokens % RAGGED_Q_BLOCK == 0
-            and ragged_kernel_viable(B, page_size, max_pages, gd, n_heads,
-                                     q_block=RAGGED_Q_BLOCK,
-                                     itemsize=itemsize))
-
-
-def _ragged_q8_ok(B: int, page_size: int, max_pages: int, gd: int,
-                  n_heads: int, n_tokens: int, n_kv_heads: int,
-                  n_scale_heads: int, multi_ok: bool) -> bool:
-    """int8-KV ragged kernel: the fused q8 decode kernel's scale-page
-    lane constraints (128-token pages, H_kv = 8) on top of
-    :func:`_ragged_ok`."""
-    return (page_size % 128 == 0 and n_kv_heads == n_scale_heads == 8
-            and _ragged_ok(B, page_size, max_pages, gd, n_heads, n_tokens,
-                           1, multi_ok))
-
-
 def kernel_routes(*, batch: int, page_size: int, max_pages: int,
-                  n_heads: int, n_kv_heads: int, head_dim: int,
+                  n_kv_heads: int, head_dim: int,
                   kv_itemsize: int, quant_kv: bool, enabled: bool,
                   multi_ok: bool, decode: bool = False,
-                  prefill_rows: int = 0,
-                  ragged_tokens: int = 0) -> dict:
+                  prefill_rows: int = 0) -> dict:
     """Which implementation each attention op of ONE serving program
     takes at this geometry: ``{op: "pallas:<kernel function>" | "xla"}``.
     Evaluates the very predicates the dispatchers below call at trace
@@ -340,9 +321,7 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
     is built and chip_smoke.py can hold the compiled text against it.
 
     ``decode``: the program runs decode steps over ``batch`` rows;
-    ``prefill_rows``: it runs bucket prefill over that many rows (0 =
-    none); ``ragged_tokens``: it runs the ragged mixed step over a
-    packed buffer of that many tokens (0 = none)."""
+    ``prefill_rows``: it runs prefill over that many rows (0 = none)."""
     gd = n_kv_heads * head_dim
 
     def pick(ok: bool, kernel: str) -> str:
@@ -352,29 +331,14 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
         return f"pallas{'-interpret' if interp else ''}:{kernel}"
 
     out = {}
-    ragged = "xla"
-    if ragged_tokens:
-        if quant_kv:
-            ragged = pick(_ragged_q8_ok(batch, page_size, max_pages, gd,
-                                        n_heads, ragged_tokens, n_kv_heads,
-                                        n_kv_heads, multi_ok),
-                          "_ragged_kernel_q8")
-        else:
-            ragged = pick(_ragged_ok(batch, page_size, max_pages, gd,
-                                     n_heads, ragged_tokens, kv_itemsize,
-                                     multi_ok), "_ragged_kernel")
-        out["ragged_attention"] = ragged
-    if prefill_rows or ragged_tokens:
+    if prefill_rows:
         rows_ok = prefill_rows == 1 or multi_ok
         out["prefill_write"] = ("xla" if quant_kv
                                 else pick(rows_ok, "_kv_prefill_kernel"))
-        if ragged == "xla":
-            # Bucket prefill — or the ragged step's fallback, which
-            # runs the bucket ops over the dense per-slice view.
-            out["prefill_attention"] = (
-                "xla" if quant_kv
-                else pick(_prefill_attn_ok(rows_ok, head_dim),
-                          "_prefill_attn_kernel"))
+        out["prefill_attention"] = (
+            "xla" if quant_kv
+            else pick(_prefill_attn_ok(rows_ok, head_dim),
+                      "_prefill_attn_kernel"))
     if decode:
         if quant_kv:
             fused = pick(_fused_decode_q8_ok(batch, page_size, max_pages,
@@ -697,193 +661,6 @@ def _jit_fused_decode_q8():
         return jax.jit(fused_decode_attention_q8_pallas,
                        static_argnames=("pages_per_chunk", "interpret"))
     return _kernel_jit("fused_decode_q8", make)
-
-
-# -- ragged mixed prefill+decode (PAPERS.md arxiv 2604.15464) ------------------
-#
-# One launch per layer for the whole mixed batch: B decode rows (fused
-# KV write + attention) and up to S prefill slices of VARIABLE length
-# packed into one qblk-aligned token buffer — replacing the per-slice
-# prefill kernels + fused decode kernel of the bucket path. The pure
-# fallback reconstructs the dense per-slice view and runs the EXACT
-# bucket-path ops, so ragged on/off is token-for-token identical on
-# CPU (the engine-level equivalence contract).
-
-#: Slice q tokens per kernel grid row; packed segments are padded to
-#: this granularity so every q-block belongs to exactly one slice.
-RAGGED_Q_BLOCK = 8
-
-
-def _jit_ragged():
-    def make():
-        from llmq_tpu.ops.pallas.ragged_paged_attention import (
-            ragged_mixed_attention_pallas)
-        return jax.jit(ragged_mixed_attention_pallas,
-                       static_argnames=("q_block", "pages_per_chunk",
-                                        "interpret"))
-    return _kernel_jit("ragged_mixed", make)
-
-
-def _jit_ragged_q8():
-    def make():
-        from llmq_tpu.ops.pallas.ragged_paged_attention import (
-            ragged_mixed_attention_q8_pallas)
-        return jax.jit(ragged_mixed_attention_q8_pallas,
-                       static_argnames=("q_block", "pages_per_chunk",
-                                        "interpret"))
-    return _kernel_jit("ragged_mixed_q8", make)
-
-
-def _ragged_dense_view(q_pf, k_pf, v_pf, pf_positions, pf_qoff, pf_qlen):
-    """Reconstruct the dense per-slice (S, Tcap, ...) view of the
-    packed ragged buffers for the pure fallback / the shared prefill
-    KV write. Rows past a slice's length gather arbitrary (finite)
-    packed rows — discarded by the write's validity mask and the
-    pack-back gather, exactly like bucket padding."""
-    N = q_pf.shape[0]
-    Tcap = N
-    t = jnp.arange(Tcap, dtype=jnp.int32)[None, :]
-    idx = jnp.clip(pf_qoff[:, None] + t, 0, N - 1)          # (S, Tcap)
-    q_dense = q_pf[idx]
-    k_dense = k_pf[idx]
-    v_dense = v_pf[idx]
-    qstart = pf_positions[jnp.clip(pf_qoff, 0, N - 1)]      # (S,)
-    # Contiguous positions clamped at the last valid token — the same
-    # convention the bucketed executor paths use for padding rows.
-    pos_dense = qstart[:, None] + jnp.minimum(
-        t, jnp.maximum(pf_qlen[:, None], 1) - 1)
-    return q_dense, k_dense, v_dense, pos_dense, qstart
-
-
-def _ragged_pack_back(attn_dense, pf_qoff, pf_qlen, n_tokens: int):
-    """(S, Tcap, H, D) dense attention → packed (N, H, D): token n of
-    the packed buffer reads its owner's dense row. Padding tokens gather
-    a clamped (finite, discarded) row."""
-    n = jnp.arange(n_tokens, dtype=jnp.int32)
-    inside = jnp.logical_and(n[:, None] >= pf_qoff[None, :],
-                             n[:, None] < (pf_qoff + pf_qlen)[None, :])
-    own = jnp.where(jnp.any(inside, axis=1),
-                    jnp.argmax(inside, axis=1), 0).astype(jnp.int32)
-    off = jnp.clip(n - pf_qoff[own], 0, attn_dense.shape[1] - 1)
-    return attn_dense[own, off]
-
-
-def ragged_mixed_step(q_dec, k_new_d, v_new_d, q_pf, k_pf, v_pf,
-                      k_pool, v_pool, dec_block_tables, dec_seq_lens,
-                      page_of, slot_of, pf_block_tables, pf_positions,
-                      pf_qoff, pf_qlen, layer, *, enabled: bool = True,
-                      multi_ok: bool = False):
-    """One mixed layer over the shared paged pool, ragged: write the
-    packed slices' KV, then attention for decode rows (+ fused decode
-    KV write) AND every packed slice token.
-
-    TPU path: the prefill write kernels followed by ONE ragged kernel
-    (ops/pallas/ragged_paged_attention.py) — per layer, 1 + S launches
-    instead of the bucket path's 1 + 2S. Fallback: the dense view runs
-    the exact bucket-path ops (write → per-slice prefill attention →
-    fused/split decode step), preserving token-for-token equivalence.
-    Returns ``(attn_dec (B, H, D), attn_pf (N, H, D), k_pool,
-    v_pool)``."""
-    B, H, D = q_dec.shape
-    N = q_pf.shape[0]
-    page_size = k_pool.shape[2]
-    MP = dec_block_tables.shape[1]
-    GD = k_pool.shape[3]
-
-    # The KV WRITE consumes the dense (S, N) per-slice view on both
-    # routes: the write kernels are per-sequence page-extent programs
-    # and the scatter fallback wants rectangular coordinates. The
-    # worst-case width is the full capacity (one slice may take it
-    # all), so the gather duplicates the packed buffer up to S× — at
-    # serving capacities that is KBs per layer, noise next to the page
-    # traffic; a packed-aware write kernel is the follow-up if a
-    # profile ever says otherwise.
-    q_dense, k_dense, v_dense, pos_dense, qstart = _ragged_dense_view(
-        q_pf, k_pf, v_pf, pf_positions, pf_qoff, pf_qlen)
-    lengths = jnp.maximum(pf_qlen, 1)
-    k_pool, v_pool = paged_kv_write_prefill(
-        k_pool, v_pool, k_dense, v_dense, pf_block_tables, pos_dense,
-        lengths, layer, enabled=enabled, multi_ok=multi_ok)
-
-    use_kernel, interpret = _kernel_route(
-        GD, enabled=enabled,
-        extra_ok=_ragged_ok(B, page_size, MP, GD, H, N,
-                            k_pool.dtype.itemsize, multi_ok))
-    if use_kernel:
-        bt_all = jnp.concatenate(
-            [dec_block_tables, pf_block_tables], axis=0)
-        seq_all = jnp.concatenate(
-            [dec_seq_lens, qstart + pf_qlen]).astype(jnp.int32)
-        attn_d, attn_p, (k_pool, v_pool) = _jit_ragged()(
-            q_dec, k_new_d, v_new_d, q_pf, k_pool, v_pool, bt_all,
-            seq_all, page_of, pf_qoff, pf_qlen, qstart, layer,
-            q_block=RAGGED_Q_BLOCK, interpret=interpret)
-        return attn_d, attn_p, k_pool, v_pool
-
-    pf_seq_lens = qstart + jnp.maximum(pf_qlen, 1)
-    attn_dense = dispatch_prefill_attention(
-        q_dense, k_pool, v_pool, pf_block_tables, pos_dense,
-        pf_seq_lens, layer, enabled=enabled, multi_ok=multi_ok)
-    attn_p = _ragged_pack_back(attn_dense, pf_qoff, pf_qlen, N)
-    attn_d, k_pool, v_pool = paged_decode_step(
-        q_dec, k_new_d, v_new_d, k_pool, v_pool, dec_block_tables,
-        dec_seq_lens, page_of, slot_of, layer, enabled=enabled)
-    return attn_d, attn_p, k_pool, v_pool
-
-
-def ragged_mixed_step_q8(q_dec, k_new_d, v_new_d, q_pf, k_pf, v_pf,
-                         pools, dec_block_tables, dec_seq_lens,
-                         page_of, slot_of, pf_block_tables, pf_positions,
-                         pf_qoff, pf_qlen, layer, *,
-                         enabled: bool = True, multi_ok: bool = False):
-    """int8-KV ragged mixed layer: quantized slice write, then ONE
-    ragged kernel with IN-KERNEL dequant at the VMEM edge — the int8
-    serving path stops round-tripping dequantized pages through HBM
-    (the bucket path's prefill attention gathered + dequantized the
-    full bf16 window per slice per layer). Fallback mirrors the exact
-    bucket-path q8 ops. Returns ``(attn_dec, attn_pf, pools)``."""
-    from llmq_tpu.ops.quant import quantize_kv_rows
-
-    k_pool = pools[0]
-    ks_pool = pools[2]
-    B, H, D = q_dec.shape
-    N = q_pf.shape[0]
-    page_size = k_pool.shape[2]
-    MP = dec_block_tables.shape[1]
-    GD = k_pool.shape[3]
-
-    q_dense, k_dense, v_dense, pos_dense, qstart = _ragged_dense_view(
-        q_pf, k_pf, v_pf, pf_positions, pf_qoff, pf_qlen)
-    lengths = jnp.maximum(pf_qlen, 1)
-    pools = paged_kv_write_prefill_q8(
-        pools, k_dense, v_dense, pf_block_tables, pos_dense, lengths,
-        layer)
-
-    use_kernel, interpret = _kernel_route(
-        GD, enabled=enabled,
-        extra_ok=_ragged_q8_ok(B, page_size, MP, GD, H, N, GD // D,
-                               ks_pool.shape[2], multi_ok))
-    if use_kernel:
-        kq, kscale = quantize_kv_rows(k_new_d)
-        vq, vscale = quantize_kv_rows(v_new_d)
-        bt_all = jnp.concatenate(
-            [dec_block_tables, pf_block_tables], axis=0)
-        seq_all = jnp.concatenate(
-            [dec_seq_lens, qstart + pf_qlen]).astype(jnp.int32)
-        attn_d, attn_p, pools = _jit_ragged_q8()(
-            q_dec, kq, kscale, vq, vscale, q_pf, pools, bt_all,
-            seq_all, page_of, pf_qoff, pf_qlen, qstart, layer,
-            q_block=RAGGED_Q_BLOCK, interpret=interpret)
-        return attn_d, attn_p, pools
-
-    pf_seq_lens = qstart + jnp.maximum(pf_qlen, 1)
-    attn_dense = dispatch_prefill_attention_q8(
-        q_dense, pools, pf_block_tables, pos_dense, pf_seq_lens, layer)
-    attn_p = _ragged_pack_back(attn_dense, pf_qoff, pf_qlen, N)
-    attn_d, pools = paged_decode_step_q8(
-        q_dec, k_new_d, v_new_d, pools, dec_block_tables, dec_seq_lens,
-        page_of, slot_of, layer, enabled=enabled)
-    return attn_d, attn_p, pools
 
 
 def paged_kv_write_prefill_q8(pools, k, v, block_tables, positions,

@@ -1,8 +1,9 @@
 """Pallas TPU kernel: FUSED decode attention + KV-cache write.
 
 One kernel per layer does both the current tokens' cache write and the
-paged attention read — vs two kernels (kv_write + paged_attention) with
-their doubled launch overhead and a separate page round-trip.
+paged attention read — vs a write kernel (kv_write.py) followed by a
+separate attention pass, with their doubled launch overhead and a
+second page round-trip.
 
 Design (v3 — third shape of this kernel; the numbers that drove it):
 

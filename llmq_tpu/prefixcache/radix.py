@@ -1,8 +1,8 @@
 """Radix-tree prefix KV cache over the paged allocator.
 
 Conversation-level reuse (PAPERS.md: "Observation, Not Prediction",
-arXiv 2606.01839) on top of the ragged paged-KV substrate (arXiv
-2604.15464) the engine already runs: finished sequences publish their
+arXiv 2606.01839) on top of the paged-KV substrate the engine
+already runs: finished sequences publish their
 page-aligned KV prefix into a radix tree keyed on token-ID blocks, and
 new admissions that share a prefix — the next turn of the same
 conversation, or an unrelated request with the same system prompt —

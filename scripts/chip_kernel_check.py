@@ -123,12 +123,11 @@ class Path:
     prefill / decode / mixed steps that return logits."""
 
     def __init__(self, name: str, cfg, params, *, num_pages: int,
-                 page_size: int, cache_dtype, ragged: bool,
+                 page_size: int, cache_dtype,
                  kv_shardings=None) -> None:
         import jax
 
         from llmq_tpu.models.llama import (forward_decode, forward_mixed,
-                                           forward_mixed_ragged,
                                            forward_prefill, init_kv_pages)
 
         self.name = name
@@ -172,16 +171,9 @@ class Path:
             idx = jnp.arange(pf_tokens.shape[0])
             return dec, pf[idx, pf_lengths - 1], cache
 
-        def mixed_ragged(params, cache, tokens, positions, bts, active,
-                         pf_tokens, pf_positions, pf_qoff, pf_qlen,
-                         pf_bts):
-            return forward_mixed_ragged(
-                params, cfg, tokens, positions, cache, bts, pf_tokens,
-                pf_positions, pf_qoff, pf_qlen, pf_bts, dec_active=active)
-
         self._prefill = jit(prefill, 1)
         self._decode = jit(decode, 1)
-        self._mixed = jit(mixed_ragged if ragged else mixed, 2)
+        self._mixed = jit(mixed, 2)
 
     def prefill(self, *a):
         out, self.cache = self._prefill(self.params, self.cache, *a)
@@ -209,8 +201,6 @@ def schedule(ex, seed: int = 0) -> Dict:
     V = ex.model_cfg.vocab_size
     S = max(1, ex.mixed_prefill_slices)
     T = ex.mixed_slice_tokens or ex.prefill_buckets[0]
-    if ex.ragged_attention:
-        T //= S     # ragged: mixed_slice_tokens is the PACKED capacity
     n_decode = 3
     max_len = MP * ps
     buckets = sorted(ex.prefill_buckets, reverse=True)
@@ -277,26 +267,10 @@ def run_schedule(path: Path, ex, sch: Dict) -> Dict[str, Any]:
     pf_pos = np.stack([pos_now[r] + np.arange(T, dtype=np.int32)
                        for r in sl])
     pf_bts = jnp.asarray(np.stack([rows[r]["bt"] for r in sl]))
-    if ex.ragged_attention:
-        qblk = ex._ragged_qblk  # noqa: SLF001
-        N = ex._ragged_buf      # noqa: SLF001
-        step = -(-T // qblk) * qblk
-        check(S * step <= N, f"ragged buffer {N} < {S}x{step}")
-        pf_tok = np.zeros(N, np.int32)
-        pf_p = np.zeros(N, np.int32)
-        for i in range(S):
-            pf_tok[i * step:i * step + T] = sch["slices"][i]
-            pf_p[i * step:i * step + T] = pf_pos[i]
-        dec, pf = path.mixed(
-            jnp.asarray(sch["forced"][j]), jnp.asarray(pos_now), bts,
-            jnp.asarray(active), jnp.asarray(pf_tok), jnp.asarray(pf_p),
-            jnp.asarray(np.arange(S, dtype=np.int32) * step),
-            jnp.full((S,), T, jnp.int32), pf_bts)
-    else:
-        dec, pf = path.mixed(
-            jnp.asarray(sch["forced"][j]), jnp.asarray(pos_now), bts,
-            jnp.asarray(active), jnp.asarray(sch["slices"]),
-            jnp.asarray(pf_pos), jnp.full((S,), T, jnp.int32), pf_bts)
+    dec, pf = path.mixed(
+        jnp.asarray(sch["forced"][j]), jnp.asarray(pos_now), bts,
+        jnp.asarray(active), jnp.asarray(sch["slices"]),
+        jnp.asarray(pf_pos), jnp.full((S,), T, jnp.int32), pf_bts)
     out["mixed.decode"] = np.asarray(dec, np.float32)[:B - S]
     out["mixed.slices"] = np.asarray(pf, np.float32)
     say(f"path {path.name}: schedule ran in "
@@ -415,7 +389,6 @@ def main(argv: List[str] | None = None) -> int:
                      "chunk": ex.chunk_size,
                      "mixed": [ex.mixed_prefill_slices,
                                ex.mixed_slice_tokens],
-                     "ragged": ex.ragged_attention,
                      "mesh": (dict(ex.mesh.shape) if ex.mesh is not None
                               else None)},
         "engine_build_s": round(time.perf_counter() - t0, 1),
@@ -423,8 +396,7 @@ def main(argv: List[str] | None = None) -> int:
     }
     quant_w = cfg.model.quantization == "int8"
     quant_kv = "k_scale" in ex.cache
-    shipped_path = (ex.mesh is None and not quant_kv
-                    and not ex.ragged_attention)
+    shipped_path = ex.mesh is None and not quant_kv
     def write_report() -> None:
         if args.out:
             os.makedirs(os.path.dirname(os.path.abspath(args.out)),
@@ -442,8 +414,7 @@ def main(argv: List[str] | None = None) -> int:
     # params are the serving path's params (same placement).
     sch = schedule(ex)
     cache_dtype = jnp.int8 if quant_kv else None
-    geom = dict(num_pages=ex.spec.num_pages, page_size=ex.spec.page_size,
-                ragged=ex.ragged_attention)
+    geom = dict(num_pages=ex.spec.num_pages, page_size=ex.spec.page_size)
     kv_shd = ex._kv_shardings  # noqa: SLF001
     serving_params = ex.params
     engine.stop()

@@ -97,3 +97,33 @@ def queue_backend(request) -> str:
         if not native_available():
             pytest.skip("native queue core not buildable here")
     return request.param
+
+
+@pytest.fixture(scope="session")
+def served_geometry():
+    """``served_geometry(name) -> (model config, executor block, int8
+    KV?)`` of a benchmark configuration: its model registered as the
+    benchmark's own child registers it and read back through
+    ``get_config`` at the file's context, beside the file's
+    ``server.executor`` block — so a test at "the served geometry"
+    follows the file, not a copy of its numbers."""
+    import json
+
+    from benchmark.harness import contract
+    from benchmark.harness.child import register_model
+    from llmq_tpu.models.llama import get_config
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def read(name: str):
+        with open(os.path.join(repo, "benchmark", "configs",
+                               f"{name}.json"), encoding="utf-8") as f:
+            doc = json.load(f)
+        model = doc["server"]["model"]
+        register_model(model["name"],
+                       {k: doc[k] for k in contract.MODEL_KEYS if k in doc})
+        cfg = get_config(model["name"], max_seq_len=model["max_seq_len"])
+        return (cfg, doc["server"]["executor"],
+                model.get("kv_quantization") == "int8")
+
+    return read

@@ -75,7 +75,7 @@ WAVE = [
 
 REFUSAL_KEYS = {"depth", "free_slot", "urgent_pending", "cancelled",
                 "geometry", "pages", "row_ended", "nothing_to_decode",
-                "spec", "ragged", "tenancy"}
+                "spec", "tenancy"}
 
 
 def drive_wave(eng, wave=WAVE, conv=None, steps_between=2, max_new=40):

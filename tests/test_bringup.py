@@ -154,9 +154,9 @@ def test_check_runs_the_configured_backend(monkeypatch, tmp_path):
 
 class TestKernelRoutes:
     #: llama3-1b at the shipped serving geometry.
-    GEOM = dict(batch=8, page_size=16, max_pages=128, n_heads=32,
-                n_kv_heads=8, head_dim=64, kv_itemsize=2, quant_kv=False,
-                enabled=True, multi_ok=True)
+    GEOM = dict(batch=8, page_size=16, max_pages=128, n_kv_heads=8,
+                head_dim=64, kv_itemsize=2, quant_kv=False, enabled=True,
+                multi_ok=True)
 
     def test_cpu_auto_routes_everything_to_xla(self, monkeypatch):
         from llmq_tpu.ops.attention import kernel_routes
@@ -192,7 +192,7 @@ class TestKernelRoutes:
             "decode_write": "pallas:_fused_kernel_q8"}
         # A head that fills neither a divisor nor a multiple of 128
         # lanes has no head window: prefill attention alone goes to XLA.
-        d96 = dict(self.GEOM, n_heads=16, n_kv_heads=4, head_dim=96)
+        d96 = dict(self.GEOM, n_kv_heads=4, head_dim=96)
         assert attention.kernel_routes(prefill_rows=1, **d96) == {
             "prefill_write": "pallas:_kv_prefill_kernel",
             "prefill_attention": "xla"}
@@ -200,6 +200,42 @@ class TestKernelRoutes:
         off = dict(self.GEOM, enabled=False)
         assert set(attention.kernel_routes(
             decode=True, prefill_rows=1, **off).values()) == {"xla"}
+
+    @pytest.mark.parametrize("config, want", [
+        ("smollm2-1.7b-bf16", {
+            "prefill_write": "pallas:_kv_prefill_kernel",
+            "prefill_attention": "pallas:_prefill_attn_kernel",
+            "decode_attention": "pallas:_fused_kernel",
+            "decode_write": "pallas:_fused_kernel"}),
+        # int8-KV prefill has no kernel yet (PERF.md §7, row 1).
+        ("mistral-7b-v0.3-w8kv8", {
+            "prefill_write": "xla", "prefill_attention": "xla",
+            "decode_attention": "pallas:_fused_kernel_q8",
+            "decode_write": "pallas:_fused_kernel_q8"})])
+    def test_kernel_routes_of_the_served_configurations(
+            self, monkeypatch, served_geometry, config, want):
+        """What each benchmark configuration's ``mixed_chunk`` (decode
+        rows and prompt slices: every op) takes on the chip, at the
+        file's own geometry. A geometry edit that drops a cell onto XLA
+        fails here, on the CPU, not as a slow cell."""
+        from llmq_tpu.ops import attention
+
+        monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+        monkeypatch.setattr(attention.jax, "default_backend",
+                            lambda: "tpu")
+        cfg, ex, q8 = served_geometry(config)
+        geom = dict(
+            batch=ex["max_batch_size"], page_size=ex["page_size"],
+            max_pages=cfg.max_seq_len // ex["page_size"],
+            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+            kv_itemsize=1 if q8 else 2, quant_kv=q8, enabled=True,
+            multi_ok=True)
+        assert attention.kernel_routes(
+            decode=True, prefill_rows=ex["mixed_batch"]["max_slices"],
+            **geom) == want
+        # The bucket programs run one row, or an admission wave.
+        assert attention.kernel_routes(prefill_rows=1, **geom) == {
+            k: v for k, v in want.items() if k.startswith("prefill")}
 
     def test_interpret_is_an_error_on_a_tpu_backend(self, monkeypatch):
         from llmq_tpu.ops import attention

@@ -182,9 +182,7 @@ class TestKernelCheckLogic:
         from types import SimpleNamespace
 
         kc = _load_kernel_check()
-        for B, ps, pages, ragged in ((8, 16, 512, False),
-                                     (64, 128, 264, False),
-                                     (8, 16, 512, True)):
+        for B, ps, pages in ((8, 16, 512), (64, 128, 264)):
             mp = 2048 // ps
             ex = SimpleNamespace(
                 spec=SimpleNamespace(batch_size=B, page_size=ps,
@@ -192,8 +190,7 @@ class TestKernelCheckLogic:
                                      max_pages_per_seq=mp),
                 model_cfg=SimpleNamespace(vocab_size=128256),
                 mixed_prefill_slices=2,
-                mixed_slice_tokens=128 if ragged else 64,
-                ragged_attention=ragged,
+                mixed_slice_tokens=64,
                 prefill_buckets=[128, 512, 2048])
             sch = kc.schedule(ex)
             assert len(sch["rows"]) == B and sch["T"] == 64

@@ -269,44 +269,22 @@ class TestJaxTelemetry:
             monitoring.record_event(names[e])
         assert XLA_CACHE.outcome(since) == want
 
-    def test_ragged_warmup_compiles_strictly_fewer_programs(self):
-        """Ragged attention collapses the bucket grid: warmup with
-        ragged ON must report strictly fewer compile_seconds{program}
-        entries than the bucket-grid warmup of the same geometry, with
-        the ragged program present and no per-bucket entries."""
-        ex_b = _tiny_executor("dev-jax-bucket", mixed_prefill_slices=2,
-                              mixed_slice_tokens=8)
-        ex_b.warmup()
-        ex_r = _tiny_executor("dev-jax-ragged", mixed_prefill_slices=2,
-                              mixed_slice_tokens=8, ragged_attention=True,
-                              ragged_token_capacity=16)
-        ex_r.warmup()
-        progs_b = get_device_telemetry(
-            "dev-jax-bucket").snapshot()["compile"]["programs"]
-        progs_r = get_device_telemetry(
-            "dev-jax-ragged").snapshot()["compile"]["programs"]
-        assert len(progs_r) < len(progs_b), (progs_r, progs_b)
-        assert "ragged_chunk" in progs_r
-        assert not any(p.startswith("prefill") for p in progs_r)
-        assert any(p.startswith("prefill") for p in progs_b)
-
-    def test_stale_bucket_export_misses_ragged_key(self, tmp_path,
-                                                   monkeypatch):
-        """The export-cache key includes the ragged geometry: a disk
-        cache populated by the bucket grid must MISS for the ragged
-        executor (every ragged program re-lowered, zero hits)."""
+    def test_stale_export_misses_another_slice_geometry(self, tmp_path,
+                                                        monkeypatch):
+        """The export-cache key includes the mixed slice geometry: a
+        disk cache written at one slice width must MISS for an
+        executor of another (``mixed_chunk`` has other shapes under the
+        same name, so every program is lowered again, zero hits)."""
         monkeypatch.setenv("LLMQ_EXPORT_CACHE_DIR", str(tmp_path))
-        ex_b = _tiny_executor("dev-jax-exp-bucket")
+        ex_a = _tiny_executor("dev-jax-exp-s64")
+        ex_a.warmup()
+        ex_b = _tiny_executor("dev-jax-exp-s32", mixed_slice_tokens=32)
+        assert ex_a._export_cache_key() != ex_b._export_cache_key()
         ex_b.warmup()
-        assert ex_b._export_cache_key() != _tiny_executor(
-            "dev-jax-exp-key", ragged_attention=True)._export_cache_key()
-        ex_r = _tiny_executor("dev-jax-exp-ragged",
-                              ragged_attention=True)
-        ex_r.warmup()
         comp = get_device_telemetry(
-            "dev-jax-exp-ragged").snapshot()["compile"]
+            "dev-jax-exp-s32").snapshot()["compile"]
         assert comp["cache_hits"] == 0
-        assert not ex_r._from_export_cache
+        assert not ex_b._from_export_cache
 
     def test_hbm_info_reports_resident_bytes(self):
         ex = _tiny_executor("dev-jax-hbm")

@@ -7,6 +7,7 @@ attribution metrics. ``mixed_batch.enabled: false`` is a hard
 off-switch: the executor must never see a mixed dispatch."""
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from llmq_tpu.core.config import MixedBatchConfig, PrefixCacheConfig
@@ -231,25 +232,37 @@ def tiny_model():
 
 
 def make_jax_engine(tiny_model, mixed, *, slots=3, prefix_cache=None,
-                    max_decode_steps=16):
+                    max_decode_steps=16, cache_dtype=None):
     cfg, params = tiny_model
     tok = ByteTokenizer()
     ex = JaxExecutor(cfg, params, batch_size=slots, page_size=8,
                      num_pages=96, prefill_buckets=[16, 64],
                      eos_id=tok.eos_id, chunk_size=4,
+                     cache_dtype=cache_dtype,
                      mixed_prefill_slices=2, mixed_slice_tokens=8)
+    assert ("k_scale" in ex.cache) == (cache_dtype is not None)
     return InferenceEngine(ex, tok, enable_metrics=False,
                            max_decode_steps=max_decode_steps,
                            prefix_cache=prefix_cache, mixed_batch=mixed)
 
 
+#: The KV pool's type: the model's own (bf16, the SmolLM2 cells) and
+#: int8 with bf16 scale pools (the Mistral cell, whose prompts ride
+#: mixed steps through the int8-KV prefill). Either way a prompt's K/V
+#: is written to the pool before anything attends to it and every read
+#: is of the pool, so a prompt cut into 8-token slices sees the values
+#: a whole-bucket prefill sees: the streams are held token for token.
+@pytest.mark.parametrize("cache_dtype", [None, jnp.int8],
+                         ids=["bf16", "int8"])
 class TestJaxEquivalence:
-    def test_wave_with_preemption_streams_identical(self, tiny_model):
+    def test_wave_with_preemption_streams_identical(self, tiny_model,
+                                                    cache_dtype):
         """Greedy CPU-mode JAX: admission waves (slices spanning
         iterations) + a realtime arrival that preempts — identical
         per-request token streams with mixed batching on vs off."""
         def run(mixed):
-            eng = make_jax_engine(tiny_model, mixed, slots=2)
+            eng = make_jax_engine(tiny_model, mixed, slots=2,
+                                  cache_dtype=cache_dtype)
             handles = []
             wave = [("a long prompt that needs slicing into chunks",
                      Priority.LOW),
@@ -272,13 +285,14 @@ class TestJaxEquivalence:
         assert s_on["mixed_batch"]["steps"] > 0, "fused path never ran"
         assert on == off
 
-    def test_prefix_cache_continuation_equivalence(self, tiny_model):
+    def test_prefix_cache_continuation_equivalence(self, tiny_model,
+                                                   cache_dtype):
         """Multi-turn conversations over the radix prefix cache:
         continuation prefill (cached KV + tail slices) must decode
         identically through the mixed path."""
         def run(mixed):
             eng = make_jax_engine(
-                tiny_model, mixed,
+                tiny_model, mixed, cache_dtype=cache_dtype,
                 prefix_cache=PrefixCacheConfig(enabled=True))
             out = []
             for turn in range(2):
@@ -299,11 +313,13 @@ class TestJaxEquivalence:
 
         assert run(mixed_cfg()) == run(None)
 
-    def test_multi_chunk_generation_through_mixed(self, tiny_model):
+    def test_multi_chunk_generation_through_mixed(self, tiny_model,
+                                                  cache_dtype):
         """A generation spanning several chunks while later arrivals
         prefill through the fused program runs to full length."""
         eng = make_jax_engine(tiny_model, mixed_cfg(),
-                              max_decode_steps=24)
+                              max_decode_steps=24,
+                              cache_dtype=cache_dtype)
         first = eng.submit(GenRequest(id="first", prompt="go",
                                       max_new_tokens=24))
         for _ in range(4):

@@ -1,9 +1,11 @@
-"""Pallas paged-attention kernel vs the pure-JAX semantics reference.
+"""The serving Pallas kernels (ops/pallas/: kv_write, fused_decode,
+prefill_attention) vs the pure-JAX semantics reference.
 
-The kernel (ops/pallas/paged_attention.py) runs in interpret mode here —
-CPU CI covers the kernel body (DMA schedule, online softmax, masking)
-without TPU hardware; on-device numerics are exercised by bench.py on
-the real chip.
+The kernels run in interpret mode here — CPU CI covers the kernel
+bodies (DMA schedule, online softmax, masking) without TPU hardware; the
+decode kernels at the served geometries are in
+tests/test_decode_kernels.py, on-device numerics in
+scripts/chip_kernel_check.py on the real chip.
 """
 
 import numpy as np
@@ -15,119 +17,12 @@ import jax.numpy as jnp  # noqa: E402
 from llmq_tpu.ops.attention import (  # noqa: E402
     blockwise_prefill_attention,
     causal_prefill_attention,
-    paged_decode_attention,
 )
-from llmq_tpu.ops.pallas.paged_attention import (  # noqa: E402
-    paged_decode_attention_pallas)
-
-
-def _paged_setup(rng, *, B=4, H=8, Hkv=2, D=64, ps=16, P=32, mp=6,
-                 dtype=jnp.float32):
-    q = jnp.asarray(rng.standard_normal((B, H, D)), dtype)
-    k = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), dtype)
-    v = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), dtype)
-    # Distinct non-zero pages per sequence (page 0 reserved).
-    ids = rng.permutation(np.arange(1, P))[: B * mp].reshape(B, mp)
-    bt = jnp.asarray(ids, jnp.int32)
-    return q, k, v, bt
-
-
-def _flat(pool):
-    """Kernel-layout view: (P, ps, H_kv, D) → flat (P, ps, H_kv·D)."""
-    return pool.reshape(pool.shape[0], pool.shape[1], -1)
 
 
 def _flat2(pool):
     """Stacked-pool view: (L, P, ps, H_kv, D) → (L, P, ps, H_kv·D)."""
     return pool.reshape(*pool.shape[:3], -1)
-
-
-class TestPagedDecodeKernel:
-    def test_matches_reference(self):
-        rng = np.random.default_rng(0)
-        q, k, v, bt = _paged_setup(rng)
-        # Lengths hit: single token, mid-page, page boundary, full window.
-        sl = jnp.asarray([1, 17, 32, 96], jnp.int32)
-        ref = paged_decode_attention(q, k, v, bt, sl)
-        out = paged_decode_attention_pallas(q, _flat(k), _flat(v), bt, sl,
-                                            pages_per_chunk=2,
-                                            interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=3e-2, rtol=3e-2)
-
-    def test_bf16_cache(self):
-        rng = np.random.default_rng(1)
-        q, k, v, bt = _paged_setup(rng, dtype=jnp.bfloat16)
-        sl = jnp.asarray([5, 40, 96, 64], jnp.int32)
-        ref = paged_decode_attention(q, k, v, bt, sl).astype(jnp.float32)
-        out = paged_decode_attention_pallas(q, _flat(k), _flat(v), bt, sl, pages_per_chunk=4,
-            interpret=True).astype(jnp.float32)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=5e-2, rtol=5e-2)
-
-    def test_chunk_width_irrelevant(self):
-        rng = np.random.default_rng(2)
-        q, k, v, bt = _paged_setup(rng)
-        sl = jnp.asarray([9, 25, 50, 80], jnp.int32)
-        a = paged_decode_attention_pallas(q, _flat(k), _flat(v), bt, sl,
-                                          pages_per_chunk=1, interpret=True)
-        b = paged_decode_attention_pallas(q, _flat(k), _flat(v), bt, sl,
-                                          pages_per_chunk=3, interpret=True)
-        c = paged_decode_attention_pallas(q, _flat(k), _flat(v), bt, sl,
-                                          pages_per_chunk=6, interpret=True)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-2, rtol=2e-2)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
-                                   atol=2e-2, rtol=2e-2)
-
-    def test_dead_pages_never_read(self):
-        """Garbage (NaN) in pages beyond seq_len must not leak: dead
-        pages are skipped by the DMA schedule and masked in compute."""
-        rng = np.random.default_rng(3)
-        q, k, v, bt = _paged_setup(rng)
-        sl = jnp.asarray([1, 16, 33, 90], jnp.int32)
-        k_np, v_np = np.asarray(k).copy(), np.asarray(v).copy()
-        ps = k_np.shape[1]
-        mp = bt.shape[1]
-        for b in range(bt.shape[0]):
-            n_live = -(-int(sl[b]) // ps)
-            for dead in np.asarray(bt)[b, n_live:mp]:
-                k_np[dead] = np.nan
-                v_np[dead] = np.nan
-        out = paged_decode_attention_pallas(
-            jnp.asarray(q), _flat(jnp.asarray(k_np)),
-            _flat(jnp.asarray(v_np)), bt, sl,
-            pages_per_chunk=2, interpret=True)
-        assert np.isfinite(np.asarray(out)).all()
-
-    def test_model_dispatch_under_interpret(self, monkeypatch):
-        """forward_decode routes through the kernel when
-        LLMQ_PALLAS=interpret and produces the same logits as pure JAX."""
-        monkeypatch.setenv("LLMQ_PALLAS", "0")
-        from llmq_tpu.models.llama import (forward_decode, get_config,
-                                           init_kv_pages, init_params)
-        # H_kv·head_dim must be 128-aligned for the kernel path: 2·64.
-        cfg = get_config("llama3-tiny", max_seq_len=64, dim=256,
-                         n_heads=4, n_kv_heads=2)
-        params = init_params(jax.random.PRNGKey(0), cfg)
-        cache = init_kv_pages(cfg, 16, 8)
-        bt = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32)
-        toks = jnp.asarray([7], jnp.int32)
-        pos = jnp.asarray([3], jnp.int32)
-        ref, _ = forward_decode(params, cfg, toks, pos, cache, bt)
-        monkeypatch.setenv("LLMQ_PALLAS", "interpret")
-        # The env var is read at trace time; equal configs share a jit
-        # cache entry, so force a retrace to route through the kernel.
-        jax.clear_caches()
-        out, _ = forward_decode(params, cfg, toks, pos, cache, bt)
-        # bf16 compute: kernel and pure-JAX paths accumulate in
-        # different orders, so logits at ~2.5 magnitude legitimately
-        # differ by a few bf16 ulps (~0.016 each) — 5e-2 covers that
-        # without masking a real indexing/masking bug (those show up
-        # as O(1) divergence on many elements, not 0.03 on one).
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=5e-2, rtol=5e-2)
-        jax.clear_caches()  # don't leak interpret-mode traces to others
 
 
 class TestKvWriteKernels:
@@ -376,6 +271,35 @@ class TestFusedDecode:
                                       np.asarray(rk)[:, 1:])
         np.testing.assert_array_equal(np.asarray(ov)[:, 1:],
                                       np.asarray(rv)[:, 1:])
+
+    def test_model_dispatch_under_interpret(self, monkeypatch):
+        """forward_decode routes through the fused kernel when
+        LLMQ_PALLAS=interpret and produces the same logits as pure JAX."""
+        monkeypatch.setenv("LLMQ_PALLAS", "0")
+        from llmq_tpu.models.llama import (forward_decode, get_config,
+                                           init_kv_pages, init_params)
+        # H_kv·head_dim must be 128-aligned for the kernel path: 2·64.
+        cfg = get_config("llama3-tiny", max_seq_len=64, dim=256,
+                         n_heads=4, n_kv_heads=2)
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        cache = init_kv_pages(cfg, 16, 8)
+        bt = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32)
+        toks = jnp.asarray([7], jnp.int32)
+        pos = jnp.asarray([3], jnp.int32)
+        ref, _ = forward_decode(params, cfg, toks, pos, cache, bt)
+        monkeypatch.setenv("LLMQ_PALLAS", "interpret")
+        # The env var is read at trace time; equal configs share a jit
+        # cache entry, so force a retrace to route through the kernel.
+        jax.clear_caches()
+        out, _ = forward_decode(params, cfg, toks, pos, cache, bt)
+        # bf16 compute: kernel and pure-JAX paths accumulate in
+        # different orders, so logits at ~2.5 magnitude legitimately
+        # differ by a few bf16 ulps (~0.016 each) — 5e-2 covers that
+        # without masking a real indexing/masking bug (those show up
+        # as O(1) divergence on many elements, not 0.03 on one).
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=5e-2, rtol=5e-2)
+        jax.clear_caches()  # don't leak interpret-mode traces to others
 
 
 #: name -> (H, H_kv, D, page_size, max_pages, T, pages_per_chunk, q_block).
