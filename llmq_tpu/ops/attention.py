@@ -314,7 +314,9 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
                   multi_ok: bool, decode: bool = False,
                   prefill_rows: int = 0) -> dict:
     """Which implementation each attention op of ONE serving program
-    takes at this geometry: ``{op: "pallas:<kernel function>" | "xla"}``.
+    takes at this geometry: ``{op: "pallas:<kernel function>" | "xla"}``;
+    a fused decode kernel is followed by the plan it was built with,
+    ``(rows=<a tile>,chunk_tokens=<a chunk>)``.
     Evaluates the very predicates the dispatchers below call at trace
     time (nested-jit trace caching makes a trace-time recorder miss
     programs), so the executor can log the decision where the program
@@ -330,6 +332,14 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
             return "xla"
         return f"pallas{'-interpret' if interp else ''}:{kernel}"
 
+    def fused(ok: bool, kernel: str, itemsize: int):
+        route = pick(ok, kernel)
+        if route == "xla":
+            return route
+        from llmq_tpu.ops.pallas.fused_decode import _tile_plan
+        plan = _tile_plan(batch, page_size, max_pages, gd, itemsize)
+        return f"{route}(rows={plan.rows},chunk_tokens={plan.chunk_tokens})"
+
     out = {}
     if prefill_rows:
         rows_ok = prefill_rows == 1 or multi_ok
@@ -341,15 +351,16 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
                       "_prefill_attn_kernel"))
     if decode:
         if quant_kv:
-            fused = pick(_fused_decode_q8_ok(batch, page_size, max_pages,
-                                             gd, n_kv_heads, n_kv_heads),
-                         "_fused_kernel_q8")
-            out["decode_write"] = out["decode_attention"] = fused
+            route = fused(_fused_decode_q8_ok(batch, page_size, max_pages,
+                                              gd, n_kv_heads, n_kv_heads),
+                          "_fused_kernel_q8", 1)
+            out["decode_write"] = out["decode_attention"] = route
         else:
-            fused = pick(_fused_decode_ok(batch, page_size, max_pages, gd,
-                                          kv_itemsize), "_fused_kernel")
-            out["decode_attention"] = fused
-            out["decode_write"] = (fused if fused != "xla"
+            route = fused(_fused_decode_ok(batch, page_size, max_pages, gd,
+                                           kv_itemsize),
+                          "_fused_kernel", kv_itemsize)
+            out["decode_attention"] = route
+            out["decode_write"] = (route if route != "xla"
                                    else pick(True, "_kv_write_kernel"))
     return out
 

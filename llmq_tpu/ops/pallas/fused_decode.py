@@ -3,368 +3,628 @@
 One kernel per layer does both the current tokens' cache write and the
 paged attention read — vs a write kernel (kv_write.py) followed by a
 separate attention pass, with their doubled launch overhead and a
-second page round-trip.
+second page round-trip. Two entry points share every line of the
+schedule: ``fused_decode_attention_pallas`` (bf16 pools) and
+``fused_decode_attention_q8_pallas`` (int8 pools with bf16 scale
+pools).
 
-Design (v3 — third shape of this kernel; the numbers that drove it):
+Design (v4 — fourth shape of this kernel; the numbers that drove it):
 
 - r2 kernel: per-row grid, per-row page-merge writeback, within-row
   double buffering → ~34µs/row at B=64 (≈16ms of a 21ms decode step),
   flat in seq_len. The merge (full-batch masked row extraction,
   page-wide selects, staging copies) and the per-row cold DMA stall
   dominated; actual page bandwidth was noise.
-- **Row tiles**: the grid is (B/R tiles, chunks); each step fetches R
-  rows' pages and runs ONE batched dot_general over the tile —
-  amortizing per-step scalar/dispatch overhead R× vs per-row grids.
-- **Cross-pair prefetch chain**: each live (tile, chunk) pair starts
-  the next live pair's DMAs (crossing tile boundaries) into the
-  alternate scratch slot; slot parity is a consumed-fetch counter in
-  SMEM, not ``chunk % 2``, because dead chunks are skipped.
-- **Tile-sliced merge**: the current token's K/V row is selected into
-  its (already fetched) page in scratch and the merged page is written
-  back as ONE full-page DMA per pool. The tile's k_new/v_new rows
-  arrive as a BlockSpec slice (free), so the r2 kernel's masked
-  extraction disappears; sub-page DMAs are impossible anyway (Mosaic
-  requires 2nd-minor slices tile-aligned — a (1, GD) row write doesn't
-  compile). Writeback waits land AFTER the attention math, so the DMA
-  overlaps compute but is guaranteed done before this scratch slot can
-  be refetched (the next pair's prefetch targets the other slot; the
-  pair after that reuses this one only after this step ends).
-- Fetch/wait liveness is keyed on ``eff_len = max(seq_len, 1)`` so a
-  ``seq_len == 0`` row still pairs starts with waits exactly.
-- Scratch is zeroed ONCE per call: dead positions inside a live chunk
-  contribute exactly 0 through the masked softmax, which is safe only
-  if stale scratch is finite (uninitialized VMEM can hold NaN bit
-  patterns; NaN + -1e30 = NaN and 0·NaN = NaN).
-- The mask rides an additive bf16 bias INPUT (0 / -1e30, broadcast
-  over H so the block's last-two dims are tile-aligned): Mosaic can't
-  stack SMEM scalars into vectors inside the kernel.
-- The online-softmax max floor is -1e29, not -inf: a fully-masked
-  chunk then yields p = exp(-1e30 + 1e29) = 0 exactly instead of
-  exp(0) = 1 pulling stale V into the accumulator.
-- DMA semaphores are shared per (pool, slot): TPU sflag space is ~2KB
-  (≈500 semaphores) — a per-(row, page) array doesn't fit. All sharers
-  copy identical byte counts, so per-copy waits drain in any order.
+- v3: a grid of (B/R row tiles, max_pages/ppc chunks), every step one
+  dot_general batched over the tile's R rows, the mask an additive
+  bias INPUT. Far from its K/V-bytes roofline in every cell (PERF.md
+  §6, PR 29; ledger, PR 28): 63 % at 31 rows, 14-20 % at the 2-4 rows
+  the open-loop cells hold. The grid was the block table's WIDTH
+  (SmolLM2: 4 tiles x 64 chunks of 64 tokens = 256 steps a call, of
+  which a 660-token context is live in 11 a row; a dead step still
+  paid the pipeline's step and a bias block fetch, ≈0.24 µs), a tile
+  computed all eight rows up to its LONGEST row (DMAs were skipped for
+  a row's dead pages, its products were not), and a 64-token chunk
+  half-filled the MXU's tiles on the token axis.
+- **v4: the unit of work is a live chunk of a live row.** The grid is
+  the row tiles alone; inside a tile a ``fori_loop`` runs over the
+  chunks up to the tile's last live one, and inside a chunk the rows
+  that hold a position there (:func:`_live_pages`, the ONE predicate a
+  row's DMA starts, its DMA waits and its products share) are listed
+  and visited; nothing else is. The steps a call runs follow the
+  batch's ``seq_lens``, not ``max_pages``; a dead row (``seq_len`` 0)
+  costs a few scalar reads and nothing else. :func:`decode_work` is the
+  same schedule counted on the host. On the chip (PERF.md §6, PR 29;
+  one call, v3 → v4): 4 rows of 360 tokens 85 → 27 µs, 2 rows of 2,000
+  180 → 54, 31 rows mixed over 300-1,500 417 → 326, Mistral's 60 rows
+  over 300-1,500 291 → 241; a block table twice as wide changes none.
+- **Live rows go two at a time** (``_GROUP``): a row's visit is a
+  dependent chain — QK^T on the MXU, the softmax on the VPU, PV on the
+  MXU — whose latencies nothing fills, ≈0.37 µs a visit; the products
+  of two rows in ONE straight line of code let the scheduler fill one
+  row's bubbles with the other's work (Mistral, 60 rows of 660: 197 →
+  183 µs; groups of 4 or 8: 180, 179 — the rest is v3's eight-row
+  batch, 165, which also multiplied for rows that were not there).
+- **The mask is made in the kernel**: per-row code compares an iota
+  with the row's ``seq_len`` scalar, so nothing has to stack SMEM
+  scalars into a vector — the bias array (2 MiB a call at SmolLM2's
+  geometry), its XLA producer and its per-step block fetch are gone.
+- **Chunks of ≥128 tokens** (:func:`_tile_plan`): as wide as 16 MiB of
+  K/V scratch allows, up to 256 tokens — 128 at SmolLM2's geometry, 256
+  at Mistral's, each the best of the widths tried on the chip. The
+  scratch passes the compiler's default scoped VMEM, and the plan says
+  so through ``vmem_limit_bytes``.
+- **Cross-pair prefetch chain** (kept): each (tile, chunk) step starts
+  the next step's DMAs (crossing tile boundaries) into the alternate
+  scratch slot before it waits for its own; slot parity is a
+  consumed-step counter in SMEM, which persists across grid steps.
+- **Per-row fetch semaphores**: a row's pages signal ``sem[pool, slot,
+  r]``, so a group's products start when ITS rows' pages have landed
+  while the later rows' are still in flight (TPU sflag space is ~2KB,
+  ≈500 semaphores: per-(row, page) does not fit, per-(pool, slot, row)
+  does). All sharers of a semaphore copy identical byte counts, so
+  per-page waits drain in any order; ONE wait for a whole chunk's bytes
+  works too (a DMA semaphore counts bytes) and bought 1.5 %: not kept.
+- **Tile-sliced merge** (kept): the current token's K/V row is selected
+  into its (already fetched) page in scratch, and only the 8-sublane
+  tile holding it is written back (sub-tile DMAs are impossible:
+  Mosaic requires 2nd-minor slices tile-aligned). The writeback is
+  waited for AFTER the row's attention math, so the DMA overlaps
+  compute but is done before this scratch slot can be refetched (the
+  next step's prefetch targets the other slot).
+- **Finite scratch under the mask** (kept, narrowed to where it
+  matters): masked logits are SELECTED to -1e30, so stale K scratch
+  never reaches the softmax; the probabilities of masked positions are
+  exactly 0, but 0·NaN = NaN in the PV product, so what multiplies them
+  must be finite: the dead tail pages of a row's last chunk are zeroed
+  in the V scratch (bf16 pools) or the V-scale scratch (int8 pools:
+  int8 data has no NaN) before that chunk's products. Fresh VMEM can
+  hold NaN bit patterns.
+- The online-softmax max floor is -1e29, not the mask value: were a
+  chunk ever fully masked, p = exp(-1e30 + 1e29) = 0 exactly instead
+  of exp(0) = 1 pulling stale V into the accumulator.
+- Per-row state: a row's running max / sum / accumulator and its
+  block-diagonal q are initialised at ITS first chunk and its output is
+  written at ITS last, so a tile's fixed cost follows its live rows
+  too; dead rows emit the zeros the tile's output block starts as.
 
-Chunk sizing: per-DMA issue cost is per PAGE, so serving configs want
-large pages (128-256 tokens); chunks default to ~256 tokens so chunks
-beyond a row's length skip both their DMAs and their masked matmuls.
+The rolled loops (rows, pages) keep the kernel's code — and the Mosaic
+payload each layer's call carries — a few rows' worth, whatever R and
+the chunk width are: one call compiles to 0.9-1.2 MB against v3's
+3.3-5.2.
 
-Same shape strategy as the other kernels: block-diagonal Q (one
-batched MXU matmul for all heads), pages flattened to (ps, H_kv·D),
-online softmax in f32 scratch. Constraints: all live rows target
-distinct pages (decode invariant), H_kv·D % 128 == 0.
+Same shape strategy as the other kernels: block-diagonal Q (one MXU
+matmul for all heads of a row), pages flattened to (ps, H_kv·D), online
+softmax in f32 scratch, bf16 MXU operands with f32 accumulation.
+Constraints: all live rows target distinct pages (decode invariant),
+H_kv·D % 128 == 0.
+
+int8 KV: pool pages are int8 (HALF the fetch DMA bytes — decode is
+bandwidth-bound, so this is the point); per-(token, kv-head) bf16 scale
+pools (L, P, H_kv, page_size) ride along, their pages fetched / merged /
+written back next to their data pages on semaphores of their own (scale
+pages are 2·H_kv·ps bytes vs GD·ps); dequantization happens in-register
+at the two products: K scales multiply LOGITS groupwise (the (head,
+position) scale layout IS the logits layout — no transpose), V scales
+fold into the probabilities before the PV matmul.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
 NEG_INF = -1e30
 
-_CONSUMED = 0   # SMEM state: fetches consumed so far (slot parity)
+_CONSUMED = 0   # SMEM state: steps consumed so far (slot parity)
+#: Live rows whose products run as one straight line of code.
+_GROUP = 2
+
+#: Tokens a chunk holds where the scratch budget allows.
+CHUNK_TOKENS = 256
+#: Most K/V scratch a plan may take: two slots of K and of V
+#: for a tile's rows. A v5e core has 128 MiB of VMEM; on the chip 16 MiB
+#: (128-token chunks at SmolLM2's 4 KiB a token and pool) beat 32 (256)
+#: at every occupancy, and Mistral's 256-token chunks (8 MiB) beat both
+#: 128 and 512 (PERF.md §6, PR 29).
+SCRATCH_BUDGET_BYTES = 16 * 2**20
+#: What a call needs beside that scratch: the f32 accumulator and the
+#: block-diagonal q of a tile, the pipelined q / new-row / output
+#: blocks, one row's operands in flight and the compiler's own scratch.
+_VMEM_HEADROOM_BYTES = 12 * 2**20
 
 
-def _fused_kernel(
-    # scalar prefetch (SMEM)
-    block_tables_ref,   # (B, max_pages) int32
-    seq_lens_ref,       # (B,) int32 — pos+1 (current token included)
-    write_page_ref,     # (B,) int32 — pool page id for the current token
-    layer_ref,          # (1,) int32
-    # inputs
-    q_ref,              # (R, H, D) VMEM — RAW query heads; the
-                        # block-diagonal GQA layout is built in VMEM
-                        # scratch once per tile (an H×GD q in HBM cost
-                        # ~0.3 ms/step of pure traffic at B=64)
-    k_new_ref,          # (R, GD) VMEM — this tile's current K rows
-    v_new_ref,          # (R, GD) VMEM
-    bias_ref,           # (R, 1, 8, S) bf16 — 0 live, -1e30 masked; 8
-                        # identical sublane rows (min tile), broadcast
-                        # to H in-register (ADVICE r3: an H-wide bias
-                        # was 4x the HBM traffic for H=32)
-    k_hbm,              # (L, P, ps, GD) ANY — aliased to output 1
-    v_hbm,              # (L, P, ps, GD) ANY — aliased to output 2
-    # outputs
-    out_ref,            # (R, H, D) VMEM — attention output, this tile
-    k_out,              # aliased pools (all DMAs target these)
-    v_out,
-    # scratch
-    m_ref, l_ref, acc_ref,          # (R,H,1),(R,H,1),(R,H,GD) f32
-    qbd_ref,                        # (R, H, GD) VMEM — block-diag q
-    k_scratch, v_scratch,           # (2, R, ppc, ps, GD) VMEM
-    state,                          # SMEM (1,) int32
-    sem,                            # DMA (2, 2) — [pool, slot] fetches
-    wsem,                           # DMA (2, R) — [pool, row] writebacks
-    *,
-    rows_per_tile: int,
-    pages_per_chunk: int,
-    page_size: int,
-    num_chunks: int,
-    batch: int,
-    n_rep: int,
-    scale: float,
-):
+class DecodePlan(NamedTuple):
+    """How one call is cut (:func:`_tile_plan`)."""
+    rows: int               # rows a tile (R)
+    pages_per_chunk: int
+    chunk_tokens: int       # pages_per_chunk * page_size
+    scratch_bytes: int      # K and V scratch, two slots
+    vmem_limit_bytes: int   # what the call asks of the compiler
+
+
+def _live_pages(seq_len, chunk, pages_per_chunk: int, page_size: int,
+                xp=jnp):
+    """How many of ``chunk``'s pages hold a position below ``seq_len``:
+    0 where the row is dead in the chunk. The ONE liveness predicate —
+    a row's DMA starts, DMA waits and products all follow it (starts
+    and waits that disagree corrupt the semaphores)."""
+    pages = (seq_len + (page_size - 1)) // page_size
+    return xp.clip(pages - chunk * pages_per_chunk, 0, pages_per_chunk)
+
+
+def _tile_chunks(seq_lens, chunk_tokens: int, xp=jnp):
+    """Steps a tile runs: up to its longest row's last live chunk, and
+    one for a tile with no live row (it hands the prefetch chain on)."""
+    longest = seq_lens[0]
+    for s in seq_lens[1:]:
+        longest = xp.maximum(longest, s)
+    return xp.maximum((longest + (chunk_tokens - 1)) // chunk_tokens, 1)
+
+
+def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
+                   pages_per_chunk: int, page_size: int, n_rep: int,
+                   scale: float):
+    """Both kernels' body. ``refs`` (scalar prefetch, inputs, outputs,
+    scratch), the int8 form's extras in brackets:
+
+    block_tables (B, max_pages), seq_lens (B,) — pos+1, current token
+    included —, write_page (B,), layer (1,): int32 SMEM;
+    q (R, H, D) RAW query heads (the block-diagonal GQA layout is built
+    in VMEM: an H×GD q in HBM cost ~0.3 ms/step of pure traffic at
+    B=64); k_new, v_new (R, GD) this tile's current rows [int8,
+    pre-quantized]; [kns, vns (R, Hkv, ps) bf16 new scales, pre-
+    broadcast along the page]; the pools (L, P, ps, GD) [and scale pools
+    (L, P, Hkv, ps)] in ANY, aliased to the outputs after ``out``
+    (R, H, D) — all DMAs target the outputs;
+    m, l (R, H, 1), acc (R, H, GD) f32; qbd (R, H, GD); k_buf, v_buf
+    (2, R, ppc, ps, GD) [ks_buf, vs_buf (2, R, ppc, Hkv, ps)]; state
+    SMEM (1,); live_rows, live_pages_of SMEM (R,) — the rows live in
+    the step's chunk and their page counts; sem DMA (pools, 2, R)
+    fetches; wsem DMA (pools, R) writebacks.
+    """
+    n_pools = 4 if quantized else 2
+    refs = iter(refs)
+
+    def take(n):
+        return [next(refs) for _ in range(n)]
+
+    bt_ref, seq_lens_ref, write_page_ref, layer_ref = take(4)
+    q_ref, k_new_ref, v_new_ref = take(3)
+    new_scale_refs = take(2 if quantized else 0)
+    take(n_pools)           # the pools as inputs: aliased to the outputs
+    out_ref, *pools = take(1 + n_pools)
+    m_ref, l_ref, acc_ref, qbd_ref = take(4)
+    bufs = take(n_pools)
+    state, live_rows, live_pages_of, sem, wsem = take(5)
+    k_buf, v_buf = bufs[:2]
+
     t = pl.program_id(0)
-    c = pl.program_id(1)
+    num_tiles = pl.num_programs(0)
     R = rows_per_tile
     ppc = pages_per_chunk
-    chunk_tokens = ppc * page_size
-    num_tiles = pl.num_programs(0)
+    S = ppc * page_size
+    H, GD = acc_ref.shape[1], acc_ref.shape[2]
+    D = q_ref.shape[2]
+    Hkv = H // n_rep
     lyr = layer_ref[0]
 
-    def row_c_last(row):
-        eff = jnp.maximum(seq_lens_ref[row], 1)
-        return (eff - 1) // chunk_tokens
-
-    def tile_c_last(tile):
-        m = row_c_last(tile * R)
-        for r in range(1, R):
-            m = jnp.maximum(m, row_c_last(tile * R + r))
-        return m
+    def live_pages(row, chunk):
+        return _live_pages(seq_lens_ref[row], chunk, ppc, page_size)
 
     def start_fetch(tile, chunk, slot):
-        """Start DMAs for every live (row, page) of (tile, chunk).
-        Liveness uses the TARGET rows' eff_len — must match wait_fetch
-        exactly or semaphores corrupt."""
-        base = chunk * ppc
-        for r in range(R):
+        """Start the DMAs of every live (row, page) of (tile, chunk)."""
+        def row_body(r, _):
             row = tile * R + r
-            eff = jnp.maximum(seq_lens_ref[row], 1)
-            for j in range(ppc):
-                live = (base + j) * page_size < eff
 
-                @pl.when(live)
-                def _():
-                    pid = block_tables_ref[row, base + j]
+            def page_body(j, _):
+                pid = bt_ref[row, chunk * ppc + j]
+                for i in range(n_pools):
                     pltpu.make_async_copy(
-                        k_out.at[lyr, pid], k_scratch.at[slot, r, j],
-                        sem.at[0, slot]).start()
-                    pltpu.make_async_copy(
-                        v_out.at[lyr, pid], v_scratch.at[slot, r, j],
-                        sem.at[1, slot]).start()
+                        pools[i].at[lyr, pid], bufs[i].at[slot, r, j],
+                        sem.at[i, slot, r]).start()
+                return 0
 
-    def wait_fetch(tile, chunk, slot):
-        base = chunk * ppc
-        for r in range(R):
-            row = tile * R + r
-            eff = jnp.maximum(seq_lens_ref[row], 1)
-            for j in range(ppc):
-                live = (base + j) * page_size < eff
+            jax.lax.fori_loop(0, live_pages(row, chunk), page_body, 0)
+            return 0
 
-                @pl.when(live)
-                def _():
-                    pid = block_tables_ref[row, base + j]
-                    pltpu.make_async_copy(
-                        k_out.at[lyr, pid], k_scratch.at[slot, r, j],
-                        sem.at[0, slot]).wait()
-                    pltpu.make_async_copy(
-                        v_out.at[lyr, pid], v_scratch.at[slot, r, j],
-                        sem.at[1, slot]).wait()
+        jax.lax.fori_loop(0, R, row_body, 0)
 
-    @pl.when(jnp.logical_and(t == 0, c == 0))
-    def _():
-        state[_CONSUMED] = 0
-        # BOTH pools: dead positions contribute through q·k_stale +
-        # bias and p·v_stale — the additive mask only yields exactly-0
-        # contributions if stale scratch is finite (fresh VMEM can hold
-        # NaN, and NaN + -1e30 = NaN straight through the softmax).
-        k_scratch[...] = jnp.zeros_like(k_scratch)
-        v_scratch[...] = jnp.zeros_like(v_scratch)
-        start_fetch(0, 0, 0)
+    def wait_row(r, n_live, slot):
+        """Wait for the row's ``n_live`` pages. A wait descriptor's
+        addresses are irrelevant — only its byte count and the
+        semaphore matter."""
+        def page_body(j, _):
+            for i in range(n_pools):
+                landed = bufs[i].at[slot, r, j]
+                pltpu.make_async_copy(landed, landed,
+                                      sem.at[i, slot, r]).wait()
+            return 0
 
-    @pl.when(c == 0)
-    def _():
-        # Floor at -1e29 (not -1e30): if every position of a chunk is
-        # masked, m stays at the floor and p = exp(-1e30 - (-1e29))
-        # underflows to exactly 0 — with the floor at the mask value
-        # itself, p would be exp(0) = 1 and stale V would leak.
-        m_ref[...] = jnp.full_like(m_ref, -1e29)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        # Build the block-diagonal GQA q for this tile: group g's
-        # queries live in GD columns [g·D, (g+1)·D) so ONE batched
-        # matmul serves all heads against the (S, GD) page layout.
-        qbd_ref[...] = jnp.zeros_like(qbd_ref)
-        D = q_ref.shape[2]
-        Hkv = q_ref.shape[1] // n_rep
-        for g in range(Hkv):
-            qbd_ref[:, g * n_rep:(g + 1) * n_rep, g * D:(g + 1) * D] = (
-                q_ref[:, g * n_rep:(g + 1) * n_rep, :])
+        jax.lax.fori_loop(0, n_live, page_body, 0)
 
-    c_last = tile_c_last(t)
-    fetched = c <= c_last
+    def writeback(r, slot, j, tile_lo, wp):
+        """The merged row's copies back to the pools: the 8-sublane tile
+        holding it (at page_size 256 a full-page write is 256x write
+        amplification; the offset is a multiple of 8 by construction,
+        which is Mosaic's sublane alignment), and the whole scale page
+        (tiny: Hkv·ps bf16)."""
+        copies = [pltpu.make_async_copy(
+            bufs[i].at[slot, r, j, pl.ds(tile_lo, 8)],
+            pools[i].at[lyr, wp, pl.ds(tile_lo, 8)],
+            wsem.at[i, r]) for i in range(2)]
+        copies += [pltpu.make_async_copy(
+            bufs[i].at[slot, r, j], pools[i].at[lyr, wp],
+            wsem.at[i, r]) for i in range(2, n_pools)]
+        return copies
 
-    @pl.when(fetched)
-    def _():
-        consumed = state[_CONSUMED]
-        slot = jax.lax.rem(consumed, 2)
-        nslot = 1 - slot
+    def new_row(ref, r):
+        """Row ``r`` of the tile's (R, GD) block as (1, GD): a masked
+        sum over the block's few sublanes, exact (one term is not 0) —
+        a dynamic one-row slice of a packed dtype is not something to
+        ask of Mosaic."""
+        x = ref[...]
+        mine = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) == r
+        return jnp.sum(jnp.where(mine, x.astype(jnp.float32), 0.0),
+                       axis=0, keepdims=True).astype(x.dtype)
 
-        # Prefetch the next live pair (possibly the next tile) while
-        # this pair computes — kills the per-tile cold stall.
-        @pl.when(c < c_last)
+    def head_rows(g):
+        """Where KV head ``g``'s query heads sit: (block-diagonal row,
+        query head) pairs. Rows are REP-major — row j·Hkv + g holds
+        query head g·n_rep + j — so the rows of one repetition are the
+        KV heads in order, which is the layout of a scale page."""
+        return [(j * Hkv + g, g * n_rep + j) for j in range(n_rep)]
+
+    def head_scales(pages):
+        """(ppc, Hkv, ps) scale pages → (H, S) f32 multiplier: pages
+        lane-concatenated into the chunk's S axis, and that (Hkv, S)
+        block repeated once a repetition (whole sublane tiles at the
+        eight KV heads the int8 kernel serves: no shuffle). Slices the
+        VALUE read once from the scratch — a mixed ref-slice
+        (``[slot, :, j]``) mis-lowered on real Mosaic (caught by an
+        on-chip A/B; interpret mode masked it)."""
+        hs = (pages[0] if ppc == 1 else jnp.concatenate(
+            [pages[j] for j in range(ppc)], axis=1))       # (Hkv, S)
+        hs = hs.astype(jnp.float32)
+        return hs if n_rep == 1 else jnp.concatenate([hs] * n_rep, axis=0)
+
+    def last_chunk(row, c):
+        """Whether ``c`` is the chunk the row's current token lives in
+        (its last live chunk), and the token's position."""
+        cur = seq_lens_ref[row] - 1
+        cur_page = cur // page_size
+        return cur_page // ppc == c, cur, cur_page
+
+    def prepare(r, row, c, slot, n_live):
+        """Before row ``r``'s products in its live chunk ``c``: its state
+        set up at its first chunk, its pages waited for, and in its last
+        chunk the current token merged and its writeback started."""
+        last, cur, cur_page = last_chunk(row, c)
+
+        @pl.when(c == 0)
         def _():
-            start_fetch(t, c + 1, nslot)
+            # Floor at -1e29 (not -1e30): see the module docstring.
+            m_ref[r] = jnp.full(m_ref.shape[1:], -1e29, m_ref.dtype)
+            l_ref[r] = jnp.zeros(l_ref.shape[1:], l_ref.dtype)
+            acc_ref[r] = jnp.zeros(acc_ref.shape[1:], acc_ref.dtype)
+            # Block-diagonal GQA q: group g's queries live in GD columns
+            # [g·D, (g+1)·D) so ONE matmul serves all heads against the
+            # (S, GD) page layout.
+            qbd_ref[r] = jnp.zeros(qbd_ref.shape[1:], qbd_ref.dtype)
+            for g in range(Hkv):
+                for at, h in head_rows(g):
+                    qbd_ref[r, at:at + 1, g * D:(g + 1) * D] = (
+                        q_ref[r, h:h + 1, :])
 
-        @pl.when(jnp.logical_and(c == c_last, t + 1 < num_tiles))
+        wait_row(r, n_live, slot)
+
+        @pl.when(last)
         def _():
-            start_fetch(t + 1, 0, nslot)
+            # What multiplies the probabilities must be finite beyond
+            # the row's last page too (0·NaN = NaN): zero the dead tail.
+            tail = bufs[3] if quantized else v_buf
 
-        wait_fetch(t, c, slot)
+            def zero_page(j, _):
+                tail[slot, r, j] = jnp.zeros(tail.shape[3:], tail.dtype)
+                return 0
 
-        # Merge each row whose current position lives in this chunk
-        # into its fetched page, and start the full-page writeback —
-        # this IS the cache write. The new rows arrive pre-sliced for
-        # the tile, so the select is one (ps, GD) where per row.
-        kn_all = k_new_ref[...]                          # (R, GD)
-        vn_all = v_new_ref[...]
-        for r in range(R):
-            row = t * R + r
-            cur = seq_lens_ref[row] - 1
-            cur_page_j = cur // page_size
-            cur_chunk = cur_page_j // ppc                # -1 if seq==0
-            jj = cur_page_j - cur_chunk * ppc
-            s = cur - cur_page_j * page_size
-            do_merge = c == cur_chunk
-            # Write back only the 8-sublane tile holding the new row,
-            # not the whole page: at page_size 256 a full-page RMW write
-            # is 256x write amplification (~33 MB/call at B=64 — half
-            # the kernel's traffic). The tile offset is a multiple of 8
-            # by construction, satisfying Mosaic's sublane alignment.
-            tile_lo = (s // 8) * 8
-            for j in range(ppc):
-                @pl.when(jnp.logical_and(do_merge, j == jj))
-                def _():
-                    sl = jax.lax.broadcasted_iota(
-                        jnp.int32, (page_size, 1), 0)
-                    keep = sl != s
-                    k_scratch[slot, r, j] = jnp.where(
-                        keep, k_scratch[slot, r, j],
-                        kn_all[r:r + 1].astype(k_scratch.dtype))
-                    v_scratch[slot, r, j] = jnp.where(
-                        keep, v_scratch[slot, r, j],
-                        vn_all[r:r + 1].astype(v_scratch.dtype))
-                    wp = write_page_ref[row]
-                    pltpu.make_async_copy(
-                        k_scratch.at[slot, r, j, pl.ds(tile_lo, 8)],
-                        k_out.at[lyr, wp, pl.ds(tile_lo, 8)],
-                        wsem.at[0, r]).start()
-                    pltpu.make_async_copy(
-                        v_scratch.at[slot, r, j, pl.ds(tile_lo, 8)],
-                        v_out.at[lyr, wp, pl.ds(tile_lo, 8)],
-                        wsem.at[1, r]).start()
+            jax.lax.fori_loop(n_live, ppc, zero_page, 0)
 
-        S = chunk_tokens
-        GD = acc_ref.shape[2]
-        q = qbd_ref[...]                                # (R, H, GD)
-        k = k_scratch[slot].reshape(R, S, GD)
-        v = v_scratch[slot].reshape(R, S, GD)
-        # Batched over the tile: contract GD, batch dim R. Operands stay
-        # bf16 — the MXU consumes bf16 natively with f32 accumulation;
-        # f32 inputs run emulated at a fraction of the rate.
-        dims = (((2,), (2,)), ((0,), (0,)))
+            # Merge the current token into its fetched page and start
+            # the writeback — this IS the cache write. The new rows
+            # arrive pre-sliced for the tile, so the select is one
+            # (ps, GD) where.
+            j = cur_page - c * ppc
+            s = cur - cur_page * page_size
+            keep = jax.lax.broadcasted_iota(
+                jnp.int32, (page_size, 1), 0) != s
+            k_buf[slot, r, j] = jnp.where(
+                keep, k_buf[slot, r, j], new_row(k_new_ref, r))
+            v_buf[slot, r, j] = jnp.where(
+                keep, v_buf[slot, r, j], new_row(v_new_ref, r))
+            if quantized:
+                # Scale column s ← this row's per-head scales (the
+                # input arrives pre-broadcast along ps, so the merge is
+                # one lane-select).
+                skeep = jax.lax.broadcasted_iota(
+                    jnp.int32, (Hkv, page_size), 1) != s
+                for buf, new in zip(bufs[2:], new_scale_refs):
+                    buf[slot, r, j] = jnp.where(
+                        skeep, buf[slot, r, j], new[r])
+            for copy in writeback(r, slot, j, (s // 8) * 8,
+                                  write_page_ref[row]):
+                copy.start()
+
+    def attend(r, row, c, slot):
+        """Row ``r``'s two products over its live chunk ``c`` and the
+        online-softmax update into ``m/l/acc[r]``: straight-line code,
+        so the rows of one group interleave on the MXU and the VPU."""
+        q = qbd_ref[r]                                       # (H, GD)
+        k = k_buf[slot, r].reshape(S, GD)
+        v = v_buf[slot, r].reshape(S, GD)
+        if quantized:
+            k = k.astype(jnp.bfloat16)
+            v = v.astype(jnp.bfloat16)
+        # Operands stay bf16 — the MXU consumes bf16 natively with f32
+        # accumulation; f32 inputs run emulated at a fraction of the
+        # rate.
         logits = jax.lax.dot_general(
-            q, k, dims,
-            preferred_element_type=jnp.float32) * scale   # (R, H, S)
-        H = acc_ref.shape[1]
-        # The bias carries 8 identical sublane rows; take one and let
-        # the VPU broadcast it across the H query heads (same values —
-        # liveness varies only per (row, position)).
-        bias = bias_ref[...].reshape(R, 8, S)[:, :1, :]
-        logits = logits + jnp.broadcast_to(
-            bias.astype(jnp.float32), (R, H, S))
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # (H, S)
+        if quantized:
+            # Dequantize K: the (head, position) scale layout IS the
+            # logits layout — one elementwise multiply, no transpose.
+            logits = logits * head_scales(bufs[2][slot, r])
+        pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
+        logits = jnp.where(pos < seq_lens_ref[row], logits, NEG_INF)
 
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(logits, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        m_prev = m_ref[r]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(logits - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
+        l_ref[r] = alpha * l_ref[r] + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[r] = m_new
+        if quantized:
+            # Dequantize V by folding its scales into the probabilities
+            # BEFORE the PV matmul: out = Σ_s (p·vscale)[s] · v_int8[s].
+            p = p * head_scales(bufs[3][slot, r])
         pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # (R, H, GD)
-        acc_ref[...] = acc_ref[...] * alpha + pv
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (H, GD)
+        acc_ref[r] = acc_ref[r] * alpha + pv
 
-        # Drain this pair's writebacks. Placed after the attention math
-        # so the page DMAs overlap it; completing before the step ends
-        # keeps the slot-reuse invariant (see module docstring). The
-        # wait descriptor's page index is irrelevant — only the byte
-        # count (one page) and the semaphore matter.
-        for r in range(R):
-            row = t * R + r
-            cur = seq_lens_ref[row] - 1
-            cur_chunk = (cur // page_size) // ppc
-
-            @pl.when(c == cur_chunk)
-            def _():
-                wp = write_page_ref[row]
-                pltpu.make_async_copy(
-                    k_scratch.at[slot, r, 0, pl.ds(0, 8)],
-                    k_out.at[lyr, wp, pl.ds(0, 8)],
-                    wsem.at[0, r]).wait()
-                pltpu.make_async_copy(
-                    v_scratch.at[slot, r, 0, pl.ds(0, 8)],
-                    v_out.at[lyr, wp, pl.ds(0, 8)],
-                    wsem.at[1, r]).wait()
-
-        state[_CONSUMED] = consumed + 1
-
-    @pl.when(c == num_chunks - 1)
-    def _():
-        # Zero guard: a seq_len == 0 row computes no chunk, leaving l at
-        # 0 — emit 0 (matching the other paged kernels) instead of 0/0.
-        res = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)  # (R,H,GD)
+    def finish(r, row, slot):
+        """After the products of the row's LAST chunk: drain its
+        writebacks — after the attention math, so the DMAs overlapped
+        it; before the step ends, which keeps the slot-reuse invariant
+        (see the module docstring) — and emit its output."""
+        for copy in writeback(r, slot, 0, 0, write_page_ref[row]):
+            copy.wait()
         # Un-blockdiagonal: group g's heads only populated columns
-        # [g·D, (g+1)·D) — emit the compact (R, H, D) directly (the
-        # old H×GD output cost another ~0.3 ms/step of HBM traffic).
-        D = out_ref.shape[2]
-        Hkv = out_ref.shape[1] // n_rep
+        # [g·D, (g+1)·D) — emit the compact (H, D) directly (an H×GD
+        # output cost another ~0.3 ms/step of HBM traffic).
+        res = acc_ref[r] / l_ref[r]                          # (H, GD)
         for g in range(Hkv):
-            out_ref[:, g * n_rep:(g + 1) * n_rep, :] = res[
-                :, g * n_rep:(g + 1) * n_rep,
-                g * D:(g + 1) * D].astype(out_ref.dtype)
+            for at, h in head_rows(g):
+                out_ref[r, h:h + 1, :] = res[
+                    at:at + 1, g * D:(g + 1) * D].astype(out_ref.dtype)
+
+    @pl.when(t == 0)
+    def _():
+        state[_CONSUMED] = 0
+        start_fetch(0, 0, 0)
+
+    # A dead row (seq_len 0) computes nothing: it emits 0, like the
+    # other paged kernels.
+    out_ref[...] = jnp.zeros_like(out_ref)
+    n_chunks = _tile_chunks([seq_lens_ref[t * R + r] for r in range(R)], S)
+
+    def chunk_body(c, _):
+        consumed = state[_CONSUMED]
+        slot = jax.lax.rem(consumed, 2)
+
+        # Prefetch the next step (possibly the next tile's first chunk)
+        # while this one computes — kills the per-tile cold stall.
+        more = c + 1 < n_chunks
+
+        @pl.when(jnp.logical_or(more, t + 1 < num_tiles))
+        def _():
+            start_fetch(jnp.where(more, t, t + 1),
+                        jnp.where(more, c + 1, 0), 1 - slot)
+
+        def note_live(r, n):
+            n_live = live_pages(t * R + r, c)
+            live_rows[n] = r
+            live_pages_of[n] = n_live
+            return n + (n_live > 0).astype(jnp.int32)
+
+        n_rows = jax.lax.fori_loop(0, R, note_live, 0)
+
+        def visit(g, _):
+            """One group of the live rows: each prepared, then their
+            products — a full group's as ONE straight line, so the rows'
+            MXU and VPU chains interleave — then the finished rows'
+            outputs."""
+            first = g * _GROUP
+            end = jnp.minimum(first + _GROUP, n_rows)
+
+            def each(fn):
+                def body(i, _):
+                    r = live_rows[i]
+                    fn(i, r, t * R + r)
+                    return 0
+                jax.lax.fori_loop(first, end, body, 0)
+
+            each(lambda i, r, row: prepare(r, row, c, slot,
+                                           live_pages_of[i]))
+
+            @pl.when(end - first == _GROUP)
+            def _():
+                for i in range(_GROUP):
+                    r = live_rows[first + i]
+                    attend(r, t * R + r, c, slot)
+
+            @pl.when(end - first < _GROUP)
+            def _():
+                each(lambda i, r, row: attend(r, row, c, slot))
+
+            def finish_if_last(i, r, row):
+                @pl.when(last_chunk(row, c)[0])
+                def _():
+                    finish(r, row, slot)
+
+            each(finish_if_last)
+            return 0
+
+        jax.lax.fori_loop(0, (n_rows + _GROUP - 1) // _GROUP, visit, 0)
+        state[_CONSUMED] = consumed + 1
+        return 0
+
+    jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+
+
+def _fused_kernel(*refs, **static):
+    """The bf16-pool kernel (its name is what ``kernel_routes`` logs)."""
+    _decode_kernel(*refs, quantized=False, **static)
+
+
+def _fused_kernel_q8(*refs, **static):
+    """The int8-pool kernel."""
+    _decode_kernel(*refs, quantized=True, **static)
 
 
 def _tile_plan(B: int, page_size: int, max_pages: int, GD: int,
                itemsize: int, pages_per_chunk: int = 0):
-    """Row-tile/chunk sizing under the ~12 MB scoped-VMEM budget.
-    Returns (R, ppc) or None when no LEGAL plan exists: Mosaic requires
-    the (R, GD) blocks' second-minor dim divisible by 8 OR equal to the
-    whole array dim — so the only legal row tiles are R=8 (when it
-    divides B) and R=B (whole-array block, covers B<8 and odd B)."""
-    def kv_scratch_bytes(r_, ppc_):
-        return 2 * 2 * r_ * ppc_ * page_size * GD * itemsize
-
-    if pages_per_chunk <= 0:
-        pages_per_chunk = max(1, 256 // page_size)
-    candidates = ([8] if B % 8 == 0 and B != 8 else []) + [B]
-    for R in candidates:
-        ppc = min(pages_per_chunk, max_pages)
-        while max_pages % ppc:
-            ppc -= 1
-        while ppc > 1 and kv_scratch_bytes(R, ppc) > 12 * 2**20:
-            ppc = max(1, ppc // 2)
-            while max_pages % ppc:
-                ppc -= 1
-        if kv_scratch_bytes(R, ppc) <= 12 * 2**20:
-            return R, ppc
+    """Row tile and chunk width: a pure function of the shapes. Returns
+    a :class:`DecodePlan`, or None when no LEGAL plan exists: Mosaic
+    requires the (R, GD) blocks' second-minor dim divisible by 8 OR
+    equal to the whole array dim — so the only legal row tiles are R=8
+    (when it divides B) and R=B (whole-array block, covers B<8 and odd
+    B). The chunk is the widest that divides the block table, holds at
+    most ``CHUNK_TOKENS`` (``pages_per_chunk``, if given, sets the limit
+    in pages instead) and whose scratch — two slots of K and of V for a
+    tile's rows — is within ``SCRATCH_BUDGET_BYTES`` (an int8 pool's
+    scale pages, 2 bytes a KV head beside a token's H_kv·D, ride in the
+    headroom)."""
+    limit = pages_per_chunk if pages_per_chunk > 0 else max(
+        1, CHUNK_TOKENS // page_size)
+    for R in ([8] if B % 8 == 0 and B != 8 else []) + [B]:
+        for ppc in range(min(limit, max_pages), 0, -1):
+            scratch = 2 * 2 * R * ppc * page_size * GD * itemsize
+            if max_pages % ppc == 0 and scratch <= SCRATCH_BUDGET_BYTES:
+                return DecodePlan(R, ppc, ppc * page_size, scratch,
+                                  scratch + _VMEM_HEADROOM_BYTES)
     return None
+
+
+def decode_work(seq_lens, plan: DecodePlan):
+    """What one call does for a batch of ``seq_lens`` under ``plan``,
+    counted on the host by the kernel's own schedule: ``(steps,
+    row_chunks_computed, row_chunks_live)`` — the (tile, chunk) steps
+    its loops run, the (row, chunk) pairs whose products run, and the
+    pairs in which a row holds a position at all. The last two are
+    equal when the kernel does the batch's work and no more; none
+    depends on the block table's width."""
+    seq_lens = np.asarray(seq_lens, np.int64)
+    page_size = plan.chunk_tokens // plan.pages_per_chunk
+    steps = computed = 0
+    for tile in seq_lens.reshape(-1, plan.rows):
+        n_chunks = int(_tile_chunks(list(tile), plan.chunk_tokens, np))
+        steps += n_chunks
+        for c in range(n_chunks):
+            computed += int((_live_pages(
+                tile, c, plan.pages_per_chunk, page_size, np) > 0).sum())
+    live = int((-(-seq_lens // plan.chunk_tokens)).sum())
+    return steps, computed, live
 
 
 def fused_kernel_viable(B: int, page_size: int, max_pages: int, GD: int,
                         itemsize: int = 2) -> bool:
     """Whether the fused kernel has a legal tile plan for this geometry
-    (large-GD models at big page sizes may not — e.g. llama3-8b's
-    GD=1024 at 256-token pages forces R=4, an illegal block). Callers
-    route to the split write+attention path when False."""
+    (large-GD models at big page sizes may not). Callers route to the
+    split write+attention path when False."""
     return _tile_plan(B, page_size, max_pages, GD, itemsize) is not None
+
+
+def _fused_call(q, new_rows, pools, block_tables, seq_lens, write_page,
+                layer, *, pages_per_chunk: int, interpret: bool):
+    """One ``pallas_call`` of :func:`_decode_kernel`. ``new_rows``: the
+    tile-sliced inputs after q — (k, v) rows, for int8 pools followed by
+    their pre-broadcast scales; ``pools``: (k, v) or (k, v, k_scale,
+    v_scale), FLAT (L, P, ps, GD) — any reshape here would break XLA's
+    aliasing and copy both pools every call (see init_kv_pages)."""
+    B, H, D = q.shape
+    k_pool = pools[0]
+    _, _, page_size, GD = k_pool.shape
+    quantized = len(pools) == 4
+    Hkv = GD // D
+    max_pages = block_tables.shape[1]
+    if GD % 128:
+        raise ValueError(f"H_kv*D = {GD} must be a multiple of 128")
+    plan = _tile_plan(B, page_size, max_pages, GD, k_pool.dtype.itemsize,
+                      pages_per_chunk)
+    if plan is None:
+        raise ValueError(
+            f"no legal fused-kernel tile plan for B={B} "
+            f"page_size={page_size} GD={GD} (route via "
+            f"fused_kernel_viable before calling)")
+    R, ppc = plan.rows, plan.pages_per_chunk
+
+    kernel = functools.partial(
+        _fused_kernel_q8 if quantized else _fused_kernel, rows_per_tile=R,
+        pages_per_chunk=ppc, page_size=page_size, n_rep=H // Hkv,
+        scale=D ** -0.5)
+
+    def tile(*block):
+        return pl.BlockSpec(block, lambda t, *_: (t,) + (0,) * (len(block) - 1))
+
+    any_space = [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B // R,),
+        in_specs=[tile(R, H, D)] + [tile(R, *x.shape[1:]) for x in new_rows]
+        + any_space,
+        out_specs=[tile(R, H, D)] + any_space,
+        scratch_shapes=[
+            pltpu.VMEM((R, H, 1), jnp.float32),
+            pltpu.VMEM((R, H, 1), jnp.float32),
+            pltpu.VMEM((R, H, GD), jnp.float32),
+            pltpu.VMEM((R, H, GD), q.dtype),
+        ] + [pltpu.VMEM((2, R, ppc) + p.shape[2:], p.dtype) for p in pools]
+        + [
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SMEM((R,), jnp.int32),
+            pltpu.SMEM((R,), jnp.int32),
+            pltpu.SemaphoreType.DMA((len(pools), 2, R)),
+            pltpu.SemaphoreType.DMA((len(pools), R)),
+        ],
+    )
+    # Operands: 4 scalar-prefetch, q, the new rows, then the pools,
+    # aliased to the outputs after ``out``.
+    first_pool = 5 + len(new_rows)
+    out, *pools_out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, D), q.dtype)] + [
+            jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        input_output_aliases={first_pool + i: 1 + i
+                              for i in range(len(pools))},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=plan.vmem_limit_bytes),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      write_page.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q, *new_rows, *pools)
+    return out, tuple(pools_out)
 
 
 def fused_decode_attention_pallas(
@@ -383,392 +643,23 @@ def fused_decode_attention_pallas(
 ):
     """Fused decode step: write the current tokens' KV into the pool
     (in place, aliased) AND return attention over the updated history.
-    Returns (attn (B, H, D), k_pool, v_pool).
+    Returns (attn (B, H, D), (k_pool, v_pool)).
 
     ``write_page`` must equal ``block_tables[b, (seq_lens[b]-1)//ps]``
     for live rows (the engine's invariant) or 0 for inactive rows.
     All live rows' write pages must be distinct.
 
-    ``pages_per_chunk=0`` (default) sizes chunks to ~256 tokens.
+    ``pages_per_chunk=0`` (default) lets :func:`_tile_plan` size the
+    chunk.
     """
-    B, H, D = q.shape
-    L, P, page_size, GD = k_pool.shape
-    Hkv = GD // D
-    max_pages = block_tables.shape[1]
-    n_rep = H // Hkv
-    if GD % 128:
-        raise ValueError(f"H_kv*D = {GD} must be a multiple of 128")
-    plan = _tile_plan(B, page_size, max_pages, GD, k_pool.dtype.itemsize,
-                      pages_per_chunk)
-    if plan is None:
-        raise ValueError(
-            f"no legal fused-kernel tile plan for B={B} "
-            f"page_size={page_size} GD={GD} (route via "
-            f"fused_kernel_viable before calling)")
-    R, ppc = plan
-    num_tiles = B // R
-    num_chunks = max_pages // ppc
-
-    # q goes in RAW (B, H, D); the kernel builds the block-diagonal GQA
-    # layout in VMEM (the old HBM-materialized H×GD q + H×GD output
-    # cost ~0.6 ms/step of pure traffic at B=64, H=32).
-    # Additive mask, chunk-blocked: (B, num_chunks, 8, S) with 0 on
-    # positions < seq_len and -1e30 beyond (built here because Mosaic
-    # can't stack SMEM scalars into vectors; 8 identical sublane rows —
-    # the MINIMUM tile-aligned height, broadcast to H inside the kernel
-    # — instead of H copies: at H=32 that is 4x less bias HBM traffic;
-    # bf16 because its exponent range covers -1e30 at half the bytes).
-    S = ppc * page_size
-    pos_all = (jnp.arange(num_chunks * S, dtype=jnp.int32)
-               .reshape(1, num_chunks, 1, S))
-    bias = jnp.where(pos_all < seq_lens.reshape(B, 1, 1, 1),
-                     0.0, NEG_INF).astype(jnp.bfloat16)
-    bias = jnp.broadcast_to(bias, (B, num_chunks, 8, S))
+    B = q.shape[0]
+    GD = k_pool.shape[3]
     kn = k_new.reshape(B, GD).astype(k_pool.dtype)
     vn = v_new.reshape(B, GD).astype(v_pool.dtype)
-
-    kernel = functools.partial(
-        _fused_kernel, rows_per_tile=R, pages_per_chunk=ppc,
-        page_size=page_size, num_chunks=num_chunks, batch=B,
-        n_rep=n_rep, scale=D ** -0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(num_tiles, num_chunks),
-        in_specs=[
-            pl.BlockSpec((R, H, D), lambda t, c, *_: (t, 0, 0)),
-            pl.BlockSpec((R, GD), lambda t, c, *_: (t, 0)),
-            pl.BlockSpec((R, GD), lambda t, c, *_: (t, 0)),
-            pl.BlockSpec((R, 1, 8, S), lambda t, c, *_: (t, c, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((R, H, D), lambda t, c, *_: (t, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((R, H, 1), jnp.float32),
-            pltpu.VMEM((R, H, 1), jnp.float32),
-            pltpu.VMEM((R, H, GD), jnp.float32),
-            pltpu.VMEM((R, H, GD), q.dtype),
-            pltpu.VMEM((2, R, ppc, page_size, GD), k_pool.dtype),
-            pltpu.VMEM((2, R, ppc, page_size, GD), v_pool.dtype),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2, R)),
-        ],
-    )
-    # Operands: 4 scalar-prefetch, then q, kn, vn, bias, pools →
-    # pool operands 8/9 alias outputs 1/2. Pools are ALREADY flat
-    # (L, P, ps, GD) — any reshape here would break XLA's aliasing and
-    # copy both pools every call (see init_kv_pages).
-    out, k_out, v_out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, D), q.dtype),
-                   jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
-        input_output_aliases={8: 1, 9: 2},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      write_page.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      q, kn, vn, bias, k_pool, v_pool)
-    return out.astype(q.dtype), (k_out, v_out)
-
-
-# -- int8 KV variant -----------------------------------------------------------
-#
-# Same structure as _fused_kernel with three deltas:
-# 1. pool pages are int8 (HALF the fetch/writeback DMA bytes — decode is
-#    bandwidth-bound, so this is the point);
-# 2. per-(token, kv-head) bf16 scale pools (L, P, H_kv, page_size) ride
-#    along: scale pages are fetched/merged/written back next to their
-#    data pages on separate semaphores (DMA semaphore sharers must copy
-#    identical byte counts; scale pages are 2·H_kv·ps bytes vs GD·ps);
-# 3. dequantization happens in-register at the matmuls: K scales
-#    multiply LOGITS groupwise (the (head, position) scale layout IS the
-#    logits layout — no transpose), V scales fold into the probabilities
-#    before the PV matmul.
-
-
-def _fused_kernel_q8(
-    # scalar prefetch (SMEM)
-    block_tables_ref, seq_lens_ref, write_page_ref, layer_ref,
-    # inputs
-    q_ref,              # (R, H, D) VMEM bf16
-    k_new_ref,          # (R, GD) VMEM int8 — pre-quantized current rows
-    v_new_ref,          # (R, GD) VMEM int8
-    kns_ref,            # (R, Hkv, ps) bf16 — new K scales, pre-broadcast
-    vns_ref,            # (R, Hkv, ps) bf16
-    bias_ref,           # (R, 1, 8, S) bf16
-    k_hbm, v_hbm,       # (L, P, ps, GD) int8 ANY — aliased
-    ks_hbm, vs_hbm,     # (L, P, Hkv, ps) bf16 ANY — aliased
-    # outputs
-    out_ref,            # (R, H, D)
-    k_out, v_out, ks_out, vs_out,
-    # scratch
-    m_ref, l_ref, acc_ref, qbd_ref,
-    k_scratch, v_scratch,           # (2, R, ppc, ps, GD) int8
-    ks_scratch, vs_scratch,         # (2, R, ppc, Hkv, ps) bf16
-    state, sem, ssem, wsem, swsem,
-    *,
-    rows_per_tile: int,
-    pages_per_chunk: int,
-    page_size: int,
-    num_chunks: int,
-    batch: int,
-    n_rep: int,
-    scale: float,
-):
-    t = pl.program_id(0)
-    c = pl.program_id(1)
-    R = rows_per_tile
-    ppc = pages_per_chunk
-    chunk_tokens = ppc * page_size
-    num_tiles = pl.num_programs(0)
-    lyr = layer_ref[0]
-
-    def row_c_last(row):
-        eff = jnp.maximum(seq_lens_ref[row], 1)
-        return (eff - 1) // chunk_tokens
-
-    def tile_c_last(tile):
-        m = row_c_last(tile * R)
-        for r in range(1, R):
-            m = jnp.maximum(m, row_c_last(tile * R + r))
-        return m
-
-    def start_fetch(tile, chunk, slot):
-        base = chunk * ppc
-        for r in range(R):
-            row = tile * R + r
-            eff = jnp.maximum(seq_lens_ref[row], 1)
-            for j in range(ppc):
-                live = (base + j) * page_size < eff
-
-                @pl.when(live)
-                def _():
-                    pid = block_tables_ref[row, base + j]
-                    pltpu.make_async_copy(
-                        k_out.at[lyr, pid], k_scratch.at[slot, r, j],
-                        sem.at[0, slot]).start()
-                    pltpu.make_async_copy(
-                        v_out.at[lyr, pid], v_scratch.at[slot, r, j],
-                        sem.at[1, slot]).start()
-                    pltpu.make_async_copy(
-                        ks_out.at[lyr, pid], ks_scratch.at[slot, r, j],
-                        ssem.at[0, slot]).start()
-                    pltpu.make_async_copy(
-                        vs_out.at[lyr, pid], vs_scratch.at[slot, r, j],
-                        ssem.at[1, slot]).start()
-
-    def wait_fetch(tile, chunk, slot):
-        base = chunk * ppc
-        for r in range(R):
-            row = tile * R + r
-            eff = jnp.maximum(seq_lens_ref[row], 1)
-            for j in range(ppc):
-                live = (base + j) * page_size < eff
-
-                @pl.when(live)
-                def _():
-                    pid = block_tables_ref[row, base + j]
-                    pltpu.make_async_copy(
-                        k_out.at[lyr, pid], k_scratch.at[slot, r, j],
-                        sem.at[0, slot]).wait()
-                    pltpu.make_async_copy(
-                        v_out.at[lyr, pid], v_scratch.at[slot, r, j],
-                        sem.at[1, slot]).wait()
-                    pltpu.make_async_copy(
-                        ks_out.at[lyr, pid], ks_scratch.at[slot, r, j],
-                        ssem.at[0, slot]).wait()
-                    pltpu.make_async_copy(
-                        vs_out.at[lyr, pid], vs_scratch.at[slot, r, j],
-                        ssem.at[1, slot]).wait()
-
-    @pl.when(jnp.logical_and(t == 0, c == 0))
-    def _():
-        state[_CONSUMED] = 0
-        k_scratch[...] = jnp.zeros_like(k_scratch)
-        v_scratch[...] = jnp.zeros_like(v_scratch)
-        # Scale scratch must be FINITE too: dead positions contribute
-        # k_stale·scale_stale through the masked softmax; a NaN scale
-        # would ride straight through the additive mask.
-        ks_scratch[...] = jnp.zeros_like(ks_scratch)
-        vs_scratch[...] = jnp.zeros_like(vs_scratch)
-        start_fetch(0, 0, 0)
-
-    @pl.when(c == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, -1e29)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        qbd_ref[...] = jnp.zeros_like(qbd_ref)
-        D = q_ref.shape[2]
-        Hkv = q_ref.shape[1] // n_rep
-        for g in range(Hkv):
-            qbd_ref[:, g * n_rep:(g + 1) * n_rep, g * D:(g + 1) * D] = (
-                q_ref[:, g * n_rep:(g + 1) * n_rep, :])
-
-    c_last = tile_c_last(t)
-    fetched = c <= c_last
-
-    @pl.when(fetched)
-    def _():
-        consumed = state[_CONSUMED]
-        slot = jax.lax.rem(consumed, 2)
-        nslot = 1 - slot
-
-        @pl.when(c < c_last)
-        def _():
-            start_fetch(t, c + 1, nslot)
-
-        @pl.when(jnp.logical_and(c == c_last, t + 1 < num_tiles))
-        def _():
-            start_fetch(t + 1, 0, nslot)
-
-        wait_fetch(t, c, slot)
-
-        kn_all = k_new_ref[...]                          # (R, GD) int8
-        vn_all = v_new_ref[...]
-        for r in range(R):
-            row = t * R + r
-            cur = seq_lens_ref[row] - 1
-            cur_page_j = cur // page_size
-            cur_chunk = cur_page_j // ppc
-            jj = cur_page_j - cur_chunk * ppc
-            s = cur - cur_page_j * page_size
-            do_merge = c == cur_chunk
-            tile_lo = (s // 8) * 8
-            for j in range(ppc):
-                @pl.when(jnp.logical_and(do_merge, j == jj))
-                def _():
-                    sl = jax.lax.broadcasted_iota(
-                        jnp.int32, (page_size, 1), 0)
-                    keep = sl != s
-                    k_scratch[slot, r, j] = jnp.where(
-                        keep, k_scratch[slot, r, j],
-                        kn_all[r:r + 1].astype(k_scratch.dtype))
-                    v_scratch[slot, r, j] = jnp.where(
-                        keep, v_scratch[slot, r, j],
-                        vn_all[r:r + 1].astype(v_scratch.dtype))
-                    # Scale column s ← this row's per-head scales (the
-                    # input arrives pre-broadcast along ps, so the
-                    # merge is one lane-select).
-                    li = jax.lax.broadcasted_iota(
-                        jnp.int32, (ks_scratch.shape[3], page_size), 1)
-                    skeep = li != s
-                    ks_scratch[slot, r, j] = jnp.where(
-                        skeep, ks_scratch[slot, r, j], kns_ref[r])
-                    vs_scratch[slot, r, j] = jnp.where(
-                        skeep, vs_scratch[slot, r, j], vns_ref[r])
-                    wp = write_page_ref[row]
-                    pltpu.make_async_copy(
-                        k_scratch.at[slot, r, j, pl.ds(tile_lo, 8)],
-                        k_out.at[lyr, wp, pl.ds(tile_lo, 8)],
-                        wsem.at[0, r]).start()
-                    pltpu.make_async_copy(
-                        v_scratch.at[slot, r, j, pl.ds(tile_lo, 8)],
-                        v_out.at[lyr, wp, pl.ds(tile_lo, 8)],
-                        wsem.at[1, r]).start()
-                    # Scale pages are tiny (Hkv·ps bf16): write whole.
-                    pltpu.make_async_copy(
-                        ks_scratch.at[slot, r, j],
-                        ks_out.at[lyr, wp], swsem.at[0, r]).start()
-                    pltpu.make_async_copy(
-                        vs_scratch.at[slot, r, j],
-                        vs_out.at[lyr, wp], swsem.at[1, r]).start()
-
-        S = chunk_tokens
-        GD = acc_ref.shape[2]
-        Hkv = ks_scratch.shape[3]
-        H = acc_ref.shape[1]
-        q = qbd_ref[...]                                # (R, H, GD)
-        k = k_scratch[slot].reshape(R, S, GD).astype(jnp.bfloat16)
-        v = v_scratch[slot].reshape(R, S, GD).astype(jnp.bfloat16)
-        dims = (((2,), (2,)), ((0,), (0,)))
-        logits = jax.lax.dot_general(
-            q, k, dims,
-            preferred_element_type=jnp.float32) * scale   # (R, H, S)
-
-        def head_scales(s_scratch):
-            """(2, R, ppc, Hkv, ps) scratch → (R, H, S) f32 multiplier:
-            pages lane-concatenated into the chunk's S axis, groups
-            expanded to their n_rep query heads (g-major head order —
-            matches the block-diagonal q layout). Reads the slot's
-            scratch ONCE and slices the VALUE — a mixed ref-slice
-            (``[slot, :, j]``) mis-lowered on real Mosaic (caught by an
-            on-chip A/B; interpret mode masked it)."""
-            full = s_scratch[slot]                   # (R, ppc, Hkv, ps)
-            pages = [full[:, j] for j in range(ppc)]
-            hs = (pages[0] if ppc == 1
-                  else jnp.concatenate(pages, axis=2))     # (R, Hkv, S)
-            rows = []
-            for g in range(Hkv):
-                rows.extend([hs[:, g:g + 1, :]] * n_rep)
-            return jnp.concatenate(rows, axis=1).astype(jnp.float32)
-
-        # Dequantize K: the (head, position) scale layout IS the logits
-        # layout — one elementwise multiply, no transpose.
-        logits = logits * head_scales(ks_scratch)
-        bias = bias_ref[...].reshape(R, 8, S)[:, :1, :]
-        logits = logits + jnp.broadcast_to(
-            bias.astype(jnp.float32), (R, H, S))
-
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(logits, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        # Dequantize V by folding its scales into the probabilities
-        # BEFORE the PV matmul: out = Σ_s (p·vscale)[s] · v_int8[s].
-        p = p * head_scales(vs_scratch)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # (R, H, GD)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-
-        for r in range(R):
-            row = t * R + r
-            cur = seq_lens_ref[row] - 1
-            cur_chunk = (cur // page_size) // ppc
-
-            @pl.when(c == cur_chunk)
-            def _():
-                wp = write_page_ref[row]
-                pltpu.make_async_copy(
-                    k_scratch.at[slot, r, 0, pl.ds(0, 8)],
-                    k_out.at[lyr, wp, pl.ds(0, 8)],
-                    wsem.at[0, r]).wait()
-                pltpu.make_async_copy(
-                    v_scratch.at[slot, r, 0, pl.ds(0, 8)],
-                    v_out.at[lyr, wp, pl.ds(0, 8)],
-                    wsem.at[1, r]).wait()
-                pltpu.make_async_copy(
-                    ks_scratch.at[slot, r, 0],
-                    ks_out.at[lyr, wp], swsem.at[0, r]).wait()
-                pltpu.make_async_copy(
-                    vs_scratch.at[slot, r, 0],
-                    vs_out.at[lyr, wp], swsem.at[1, r]).wait()
-
-        state[_CONSUMED] = consumed + 1
-
-    @pl.when(c == num_chunks - 1)
-    def _():
-        res = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)  # (R,H,GD)
-        D = out_ref.shape[2]
-        Hkv = out_ref.shape[1] // n_rep
-        for g in range(Hkv):
-            out_ref[:, g * n_rep:(g + 1) * n_rep, :] = res[
-                :, g * n_rep:(g + 1) * n_rep,
-                g * D:(g + 1) * D].astype(out_ref.dtype)
+    out, pools = _fused_call(
+        q, (kn, vn), (k_pool, v_pool), block_tables, seq_lens, write_page,
+        layer, pages_per_chunk=pages_per_chunk, interpret=interpret)
+    return out.astype(q.dtype), pools
 
 
 def fused_decode_attention_q8_pallas(
@@ -786,99 +677,18 @@ def fused_decode_attention_q8_pallas(
     pages_per_chunk: int = 0,
     interpret: bool = False,
 ):
-    """int8-KV fused decode step (see _fused_kernel_q8). Returns
-    (attn (B, H, D), pools)."""
-    k_pool, v_pool, ks_pool, vs_pool = pools
-    B, H, D = q.shape
-    L, P, page_size, GD = k_pool.shape
-    Hkv = GD // D
-    max_pages = block_tables.shape[1]
-    n_rep = H // Hkv
-    if GD % 128:
-        raise ValueError(f"H_kv*D = {GD} must be a multiple of 128")
-    plan = _tile_plan(B, page_size, max_pages, GD, k_pool.dtype.itemsize,
-                      pages_per_chunk)
-    if plan is None:
-        raise ValueError(
-            f"no legal q8 fused tile plan for B={B} "
-            f"page_size={page_size} GD={GD}")
-    R, ppc = plan
-    num_tiles = B // R
-    num_chunks = max_pages // ppc
-
-    S = ppc * page_size
-    pos_all = (jnp.arange(num_chunks * S, dtype=jnp.int32)
-               .reshape(1, num_chunks, 1, S))
-    bias = jnp.where(pos_all < seq_lens.reshape(B, 1, 1, 1),
-                     0.0, NEG_INF).astype(jnp.bfloat16)
-    bias = jnp.broadcast_to(bias, (B, num_chunks, 8, S))
-    kn = k_new_q.reshape(B, GD)
-    vn = v_new_q.reshape(B, GD)
+    """int8-KV fused decode step (the module docstring's last
+    paragraph). Returns (attn (B, H, D), pools)."""
+    B = q.shape[0]
+    _, _, page_size, GD = pools[0].shape
+    Hkv = pools[2].shape[2]
     # Scales pre-broadcast along the page dim: the kernel's merge is
     # then a single lane-select against the fetched scale page.
-    kns = jnp.broadcast_to(
-        k_new_scale.astype(jnp.bfloat16)[:, :, None], (B, Hkv, page_size))
-    vns = jnp.broadcast_to(
-        v_new_scale.astype(jnp.bfloat16)[:, :, None], (B, Hkv, page_size))
-
-    kernel = functools.partial(
-        _fused_kernel_q8, rows_per_tile=R, pages_per_chunk=ppc,
-        page_size=page_size, num_chunks=num_chunks, batch=B,
-        n_rep=n_rep, scale=D ** -0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(num_tiles, num_chunks),
-        in_specs=[
-            pl.BlockSpec((R, H, D), lambda t, c, *_: (t, 0, 0)),
-            pl.BlockSpec((R, GD), lambda t, c, *_: (t, 0)),
-            pl.BlockSpec((R, GD), lambda t, c, *_: (t, 0)),
-            pl.BlockSpec((R, Hkv, page_size), lambda t, c, *_: (t, 0, 0)),
-            pl.BlockSpec((R, Hkv, page_size), lambda t, c, *_: (t, 0, 0)),
-            pl.BlockSpec((R, 1, 8, S), lambda t, c, *_: (t, c, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((R, H, D), lambda t, c, *_: (t, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((R, H, 1), jnp.float32),
-            pltpu.VMEM((R, H, 1), jnp.float32),
-            pltpu.VMEM((R, H, GD), jnp.float32),
-            pltpu.VMEM((R, H, GD), q.dtype),
-            pltpu.VMEM((2, R, ppc, page_size, GD), k_pool.dtype),
-            pltpu.VMEM((2, R, ppc, page_size, GD), v_pool.dtype),
-            pltpu.VMEM((2, R, ppc, Hkv, page_size), ks_pool.dtype),
-            pltpu.VMEM((2, R, ppc, Hkv, page_size), vs_pool.dtype),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2, R)),
-            pltpu.SemaphoreType.DMA((2, R)),
-        ],
-    )
-    # Operand order: 4 scalar-prefetch, q, kn, vn, kns, vns, bias, then
-    # the four pools at operands 10-13 aliased to outputs 1-4.
-    out, k_out, v_out, ks_out, vs_out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, D), q.dtype),
-                   jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-                   jax.ShapeDtypeStruct(ks_pool.shape, ks_pool.dtype),
-                   jax.ShapeDtypeStruct(vs_pool.shape, vs_pool.dtype)],
-        input_output_aliases={10: 1, 11: 2, 12: 3, 13: 4},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      write_page.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      q, kn, vn, kns, vns, bias, k_pool, v_pool, ks_pool, vs_pool)
-    return out.astype(q.dtype), (k_out, v_out, ks_out, vs_out)
+    kns, vns = (jnp.broadcast_to(
+        s.astype(jnp.bfloat16)[:, :, None], (B, Hkv, page_size))
+        for s in (k_new_scale, v_new_scale))
+    out, pools = _fused_call(
+        q, (k_new_q.reshape(B, GD), v_new_q.reshape(B, GD), kns, vns),
+        tuple(pools), block_tables, seq_lens, write_page, layer,
+        pages_per_chunk=pages_per_chunk, interpret=interpret)
+    return out.astype(q.dtype), pools

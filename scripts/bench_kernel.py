@@ -1,68 +1,184 @@
 #!/usr/bin/env python
-"""Micro-bench the fused decode kernel alone on the chip (dev tool)."""
+"""Micro-bench the fused decode kernel alone on the chip (dev tool; no
+cell runs it).
+
+Heads, page size, block table width, batch, pool and the int8 kernel
+come from a served configuration (``--model-file
+benchmark/configs/<name>.json``); ``--lens`` gives the rows' contexts:
+
+    chiprun -- python3 scripts/bench_kernel.py \\
+        --model-file benchmark/configs/smollm2-1.7b-bf16.json \\
+        --lens 31x300-1500 --lens 4x360 --lens 2x2000
+
+One ``--lens`` is one occupancy: comma-separated ``N`` (one row of N
+tokens), ``KxN`` (K such rows) or ``KxLO-HI`` (K rows spread evenly
+over LO..HI); the rows left over are dead (``seq_len`` 0). Live rows
+take the lowest slots, as the engine seats them (``--spread``: evenly
+over the batch instead), in the order given (``--shuffle``: in a seeded
+random order, as a served batch mixes its lengths). Prints, an occupancy: µs a call, what
+``decode_work`` counts for it (steps, row-chunks computed, row-chunks
+live) and the share of the K/V bytes' time at 819 GB/s (the benchmark's
+``decode_attn_roofline`` for one call). ``--tree DIR`` imports
+``llmq_tpu`` from another checkout (a parent commit unpacked beside
+this one), ``--max-pages N`` widens or narrows the block table,
+``--pages-per-chunk N`` overrides the plan's chunk, ``--out F`` writes
+the numbers to F and each occupancy's attention output beside it.
+"""
+import argparse
+import json
 import os
 import sys
 import time
 from functools import partial
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from llmq_tpu.ops.pallas.fused_decode import fused_decode_attention_pallas
-
-B = int(sys.argv[1]) if len(sys.argv) > 1 else 64
-seq = int(sys.argv[2]) if len(sys.argv) > 2 else 160
-page_size = int(sys.argv[3]) if len(sys.argv) > 3 else 16
-max_seq = int(sys.argv[4]) if len(sys.argv) > 4 else 1024
-reps = 20  # kernel calls fused into one jit program
-
-L, Hkv, D, H = 16, 8, 64, 32  # llama3-1b shapes
-max_pages = max_seq // page_size
-P = B * max_pages + 1
-
-rng = np.random.default_rng(0)
-k_pool = jnp.asarray(rng.standard_normal((L, P, page_size, Hkv * D)),
-                     jnp.bfloat16)
-v_pool = jnp.asarray(rng.standard_normal((L, P, page_size, Hkv * D)),
-                     jnp.bfloat16)
-bt = np.zeros((B, max_pages), np.int32)
-pid = 1
-for b in range(B):
-    for j in range(max_pages):
-        bt[b, j] = pid
-        pid += 1
-bt = jnp.asarray(bt)
-seq_lens = jnp.full((B,), seq, jnp.int32)
-write_page = bt[jnp.arange(B), (seq - 1) // page_size]
-q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
-kn = jnp.asarray(rng.standard_normal((B, Hkv, D)), jnp.bfloat16)
-vn = jnp.asarray(rng.standard_normal((B, Hkv, D)), jnp.bfloat16)
+PEAK_BYTES_PER_S = 819e9  # TPU v5e (benchmark/harness/peaks.py)
+REPS = 24                 # kernel calls fused into one jit program
 
 
-@partial(jax.jit, donate_argnums=(1, 2))
-def many(q, k_pool, v_pool):
-    outs = []
-    for i in range(reps):
-        attn, (k_pool, v_pool) = fused_decode_attention_pallas(
-            q, kn, vn, k_pool, v_pool, bt, seq_lens, write_page,
-            jnp.int32(i % L))
-        outs.append(jnp.sum(attn))
-    return jnp.stack(outs), k_pool, v_pool
+def parse_lens(spec: str):
+    lens = []
+    for item in spec.split(","):
+        count, _, span = item.rpartition("x")
+        lo, _, hi = span.partition("-")
+        k, lo, hi = int(count or 1), int(lo), int(hi or lo)
+        lens += [lo + (hi - lo) * i // max(k - 1, 1) for i in range(k)]
+    return lens
 
 
-outs, k_pool, v_pool = many(q, k_pool, v_pool)
-jax.block_until_ready(outs)
-t0 = time.perf_counter()
-n = 3
-for _ in range(n):
-    outs, k_pool, v_pool = many(q, k_pool, v_pool)
-jax.block_until_ready(outs)
-dt = time.perf_counter() - t0
-per_call_us = dt / (n * reps) * 1e6
-print(f"B={B} seq={seq} ps={page_size} ctx={max_seq}: "
-      f"{per_call_us:,.0f} us/kernel-call  "
-      f"({per_call_us/B:,.2f} us/row)", flush=True)
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--model-file", required=True)
+    ap.add_argument("--lens", action="append", required=True)
+    ap.add_argument("--tree", default=os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    ap.add_argument("--max-pages", type=int, default=0)
+    ap.add_argument("--pages-per-chunk", type=int, default=0)
+    ap.add_argument("--spread", action="store_true")
+    ap.add_argument("--shuffle", action="store_true")
+    ap.add_argument("--out", default="")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="off the chip: interpret mode, 2 layers, 2 "
+                         "calls; the times mean nothing")
+    args = ap.parse_args()
+    sys.path.insert(0, os.path.abspath(args.tree))
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmq_tpu.ops.pallas import fused_decode
+
+    with open(args.model_file, encoding="utf-8") as f:
+        doc = json.load(f)
+    ex, model = doc["server"]["executor"], doc["server"]["model"]
+    H, Hkv = doc["num_attention_heads"], doc["num_key_value_heads"]
+    D = doc.get("head_dim") or doc["hidden_size"] // H
+    L, GD = doc["num_hidden_layers"], Hkv * D
+    reps = REPS
+    if args.rehearse:
+        L, reps = 2, 2
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path in interpret mode)")
+    B, ps, P = ex["max_batch_size"], ex["page_size"], ex["kv_pages"]
+    mp = args.max_pages or model["max_seq_len"] // ps
+    q8 = model.get("kv_quantization") == "int8"
+    itemsize = 1 if q8 else 2
+
+    # One layer's random pages, repeated: a whole pool's random bits
+    # would not fit beside it.
+    key = jax.random.key(0)
+    if q8:
+        data = [jnp.tile(jax.random.randint(k, (1, P, ps, GD), -127, 128,
+                                            jnp.int8), (L, 1, 1, 1))
+                for k in jax.random.split(key, 2)]
+        scales = [jnp.full((L, P, Hkv, ps), 0.01, jnp.bfloat16)
+                  for _ in range(2)]
+        pools = tuple(data) + tuple(scales)
+        new = [jnp.ones((B, Hkv, D), jnp.int8), jnp.ones((B, Hkv),
+                                                         jnp.bfloat16)] * 2
+        kernel = fused_decode.fused_decode_attention_q8_pallas
+    else:
+        pools = tuple(jnp.tile(jax.random.normal(k, (1, P, ps, GD),
+                                                 jnp.bfloat16), (L, 1, 1, 1))
+                      for k in jax.random.split(key, 2))
+        new = [jnp.ones((B, Hkv, D), jnp.bfloat16)] * 2
+        kernel = fused_decode.fused_decode_attention_pallas
+    q = jax.random.normal(key, (B, H, D), jnp.bfloat16)
+
+    @partial(jax.jit, donate_argnums=(0,))
+    def many(pools, bt, seq_lens, write_page):
+        outs = []
+        for i in range(reps):
+            # The int8 kernel takes its four pools as one argument.
+            attn, pools = kernel(
+                q, *new, *((pools,) if q8 else pools), bt, seq_lens,
+                write_page, jnp.int32(i % L),
+                pages_per_chunk=args.pages_per_chunk,
+                interpret=args.rehearse)
+            outs.append(jnp.sum(attn.astype(jnp.float32)))
+        return jnp.stack(outs), attn, pools
+
+    plan = None
+    if hasattr(fused_decode, "decode_work"):
+        plan = fused_decode._tile_plan(  # noqa: SLF001 — the dev tool
+            B, ps, mp, GD, itemsize, args.pages_per_chunk)
+    print(f"{doc['name']}: B={B} H={H} Hkv={Hkv} D={D} ps={ps} "
+          f"max_pages={mp} {'int8' if q8 else 'bf16'} plan={plan} "
+          f"device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    rng = np.random.default_rng(0)
+    results = []
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+    for spec in args.lens:
+        lens = parse_lens(spec)
+        assert len(lens) <= B and max(lens) <= mp * ps, spec
+        seq = np.zeros(B, np.int32)
+        at = (np.arange(len(lens)) * B // len(lens) if args.spread
+              else np.arange(len(lens)))
+        seq[at] = rng.permutation(lens) if args.shuffle else lens
+        n_pages = -(-seq // ps)
+        assert n_pages.sum() < P, "the pool is too small for these rows"
+        ids = 1 + rng.permutation(P - 1)[:n_pages.sum()]
+        bt = np.zeros((B, mp), np.int32)
+        write_page = np.zeros(B, np.int32)
+        for b, start in enumerate(np.cumsum(n_pages) - n_pages):
+            bt[b, :n_pages[b]] = ids[start:start + n_pages[b]]
+            if seq[b]:
+                write_page[b] = bt[b, (seq[b] - 1) // ps]
+        call = (jnp.asarray(bt), jnp.asarray(seq), jnp.asarray(write_page))
+        outs, attn, pools = many(pools, *call)
+        first = np.asarray(attn, np.float32)    # the last call's output
+        t0 = time.perf_counter()
+        n = 1 if args.rehearse else 10
+        for _ in range(n):
+            outs, attn, pools = many(pools, *call)
+        jax.block_until_ready(outs)
+        us = (time.perf_counter() - t0) / (n * reps) * 1e6
+        kv_bytes = int(seq.sum()) * (2 * GD * itemsize
+                                     + (4 * Hkv if q8 else 0))
+        least_us = kv_bytes / PEAK_BYTES_PER_S * 1e6
+        work = (fused_decode.decode_work(seq, plan) if plan else None)
+        results.append({"lens": spec, "rows": len(lens),
+                        "tokens": int(seq.sum()), "us_per_call": us,
+                        "decode_work": work, "kv_least_us": least_us,
+                        "kv_roofline_pct": 100 * least_us / us,
+                        "finite": bool(np.isfinite(first).all())})
+        if args.out:
+            np.save(f"{args.out}.{len(results) - 1}.npy", first)
+        print(f"  lens {spec}: {us:,.1f} us/call  decode_work (steps, "
+              f"computed, live)={work}  K/V bytes at peak {least_us:,.1f} "
+              f"us = {100 * least_us / us:.1f} %  finite="
+              f"{results[-1]['finite']}", flush=True)
+    if args.out:
+        with open(args.out, "w", encoding="utf-8") as f:
+            json.dump({"config": doc["name"], "tree": args.tree,
+                       "plan": plan and plan._asdict(), "max_pages": mp,
+                       "results": results}, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()

@@ -153,6 +153,9 @@ def test_check_runs_the_configured_backend(monkeypatch, tmp_path):
 
 
 class TestKernelRoutes:
+    #: The plan a fused decode route is logged with (rows a tile, tokens
+    #: a chunk): the widest chunk, but for SmolLM2's 32 KV heads.
+    PLAN = "(rows=8,chunk_tokens=256)"
     #: llama3-1b at the shipped serving geometry.
     GEOM = dict(batch=8, page_size=16, max_pages=128, n_kv_heads=8,
                 head_dim=64, kv_itemsize=2, quant_kv=False, enabled=True,
@@ -178,8 +181,8 @@ class TestKernelRoutes:
         assert routes == {
             "prefill_write": "pallas:_kv_prefill_kernel",
             "prefill_attention": "pallas:_prefill_attn_kernel",
-            "decode_attention": "pallas:_fused_kernel",
-            "decode_write": "pallas:_fused_kernel"}
+            "decode_attention": "pallas:_fused_kernel" + self.PLAN,
+            "decode_write": "pallas:_fused_kernel" + self.PLAN}
         # The same predicates the dispatchers use: int8 KV at 16-token
         # pages is NOT kernel-eligible (scale page lane alignment) and
         # the report says so instead of leaving it to be discovered.
@@ -188,8 +191,8 @@ class TestKernelRoutes:
             "decode_attention": "xla", "decode_write": "xla"}
         q8_128 = dict(q8, page_size=128, max_pages=16, batch=64)
         assert attention.kernel_routes(decode=True, **q8_128) == {
-            "decode_attention": "pallas:_fused_kernel_q8",
-            "decode_write": "pallas:_fused_kernel_q8"}
+            "decode_attention": "pallas:_fused_kernel_q8" + self.PLAN,
+            "decode_write": "pallas:_fused_kernel_q8" + self.PLAN}
         # A head that fills neither a divisor nor a multiple of 128
         # lanes has no head window: prefill attention alone goes to XLA.
         d96 = dict(self.GEOM, n_kv_heads=4, head_dim=96)
@@ -205,13 +208,14 @@ class TestKernelRoutes:
         ("smollm2-1.7b-bf16", {
             "prefill_write": "pallas:_kv_prefill_kernel",
             "prefill_attention": "pallas:_prefill_attn_kernel",
-            "decode_attention": "pallas:_fused_kernel",
-            "decode_write": "pallas:_fused_kernel"}),
+            # 128-token chunks: 16 MiB of scratch at 4 KiB a token.
+            "decode_attention": "pallas:_fused_kernel(rows=8,chunk_tokens=128)",
+            "decode_write": "pallas:_fused_kernel(rows=8,chunk_tokens=128)"}),
         # int8-KV prefill has no kernel yet (PERF.md §7, row 1).
         ("mistral-7b-v0.3-w8kv8", {
             "prefill_write": "xla", "prefill_attention": "xla",
-            "decode_attention": "pallas:_fused_kernel_q8",
-            "decode_write": "pallas:_fused_kernel_q8"})])
+            "decode_attention": "pallas:_fused_kernel_q8" + PLAN,
+            "decode_write": "pallas:_fused_kernel_q8" + PLAN})])
     def test_kernel_routes_of_the_served_configurations(
             self, monkeypatch, served_geometry, config, want):
         """What each benchmark configuration's ``mixed_chunk`` (decode
@@ -244,7 +248,7 @@ class TestKernelRoutes:
         assert attention.pallas_mode() == "interpret"      # CPU: fine
         routes = attention.kernel_routes(decode=True, **self.GEOM)
         assert routes["decode_attention"] == (
-            "pallas-interpret:_fused_kernel")
+            "pallas-interpret:_fused_kernel" + self.PLAN)
         monkeypatch.setattr(attention.jax, "default_backend",
                             lambda: "tpu")
         with pytest.raises(RuntimeError, match="interpret"):

@@ -9,8 +9,10 @@ held to the pure path at the one geometry it serves — SmolLM2: 32 heads
 over 32 KV heads of 64 in 16-token pages; Mistral: 32 over 8 of 128 in
 128-token pages — not at a toy one. Interpret mode: the kernel body
 (DMA schedule, merge and writeback, online softmax, masking) runs on
-the CPU; the batch is one 8-row tile and the block table is a few pages
-wide, at the plan's own pages-per-chunk.
+the CPU; the batch is one 8-row tile (four where a case says so) and
+the block table holds two of the plan's own chunks (wider where a case
+says so). ``decode_work`` — the same schedule counted on the host — is
+held to "computed = live, steps whatever the block table's width".
 """
 
 from types import SimpleNamespace
@@ -23,13 +25,12 @@ import jax.numpy as jnp  # noqa: E402
 
 from llmq_tpu.ops import attention  # noqa: E402
 from llmq_tpu.ops.pallas.fused_decode import (  # noqa: E402
-    _tile_plan, fused_kernel_viable)
+    CHUNK_TOKENS, SCRATCH_BUDGET_BYTES, _tile_plan, decode_work,
+    fused_kernel_viable)
 from llmq_tpu.ops.quant import quantize_kv_rows  # noqa: E402
 
 CONFIGS = ("smollm2-1.7b-bf16", "mistral-7b-v0.3-w8kv8")
 ROWS, LAYERS = 8, 2
-#: The K/V scratch ``_tile_plan`` budgets (two slots of K and of V).
-SCRATCH_BYTES = 12 * 2**20
 
 
 @pytest.fixture(autouse=True)
@@ -46,15 +47,22 @@ def _geom(served_geometry, name):
         H=cfg.n_heads, Hkv=cfg.n_kv_heads, D=cfg.head_dim,
         GD=cfg.n_kv_heads * cfg.head_dim, ps=ps, q8=q8,
         itemsize=1 if q8 else 2,
-        mp=max(4, 128 // ps),                # the test's block table
+        mp=2 * CHUNK_TOKENS // ps,           # the test's block table
         rows_full=ex["max_batch_size"], mp_full=cfg.max_seq_len // ps)
 
 
-def _inputs(g, seed, seq_lens):
-    """Pools full of history, one new token a row. Every page but the
-    reserved page 0 belongs to exactly one row."""
+def _inputs(g, seed, seq_lens, mp=None):
+    """Pools full of history, one new token a row. Every page a row
+    holds a position in belongs to that row alone; the block tables'
+    entries beyond (``dead``) point at spare pages no row reads, and
+    page 0 is the reserved one."""
     rng = np.random.default_rng(seed)
-    P = 1 + ROWS * g.mp
+    mp = mp or g.mp
+    seq_lens = np.asarray(seq_lens, np.int32)
+    rows = len(seq_lens)
+    assert rows % 8 == 0 and seq_lens.max() <= mp * g.ps
+    n_live = -(-seq_lens // g.ps)
+    P = 1 + int(n_live.sum()) + rows
     hist = [jnp.asarray(rng.standard_normal(
         (LAYERS, P, g.ps, g.Hkv, g.D), np.float32)) for _ in range(2)]
     if g.q8:
@@ -66,17 +74,19 @@ def _inputs(g, seed, seq_lens):
         pools = tuple(h.reshape(LAYERS, P, g.ps, g.GD)
                       .astype(jnp.bfloat16) for h in hist)
         new_dtype = jnp.bfloat16
-    bt = 1 + rng.permutation(ROWS * g.mp).reshape(ROWS, g.mp)
-    seq_lens = np.asarray(seq_lens, np.int32)
-    assert seq_lens.shape == (ROWS,) and seq_lens.max() <= g.mp * g.ps
+    ids = 1 + rng.permutation(P - 1)
+    dead, ids = ids[:rows], ids[rows:]
+    bt = rng.choice(dead, (rows, mp))
+    for b, start in enumerate(np.cumsum(n_live) - n_live):
+        bt[b, :n_live[b]] = ids[start:start + n_live[b]]
     live = seq_lens > 0
     pos = np.maximum(seq_lens - 1, 0)
-    page_of = np.where(live, bt[np.arange(ROWS), pos // g.ps], 0)
-    new = [jnp.asarray(rng.standard_normal((ROWS, g.Hkv, g.D))
+    page_of = np.where(live, bt[np.arange(rows), pos // g.ps], 0)
+    new = [jnp.asarray(rng.standard_normal((rows, g.Hkv, g.D))
                        * live[:, None, None], new_dtype) for _ in range(2)]
     return SimpleNamespace(
-        pools=pools, bt=jnp.asarray(bt, jnp.int32), np_bt=bt,
-        q=jnp.asarray(rng.standard_normal((ROWS, g.H, g.D)), jnp.bfloat16),
+        pools=pools, bt=jnp.asarray(bt, jnp.int32), np_bt=bt, dead=dead,
+        q=jnp.asarray(rng.standard_normal((rows, g.H, g.D)), jnp.bfloat16),
         kn=new[0], vn=new[1], seq_lens=jnp.asarray(seq_lens), live=live,
         page_of=jnp.asarray(page_of, jnp.int32), np_page_of=page_of,
         slot_of=jnp.asarray(pos % g.ps, jnp.int32), np_slot_of=pos % g.ps,
@@ -252,10 +262,84 @@ def _kv_head_groups(g):
     assert (np.abs(a[:, group] - b[:, group]).max(axis=-1) > 0.05).all()
 
 
+def _held_to_pure(g, x, ppc=0):
+    attn, out = _kernel(g, x, ppc=ppc)
+    ref, want = _pure(g, x)
+    _close(attn[x.live], ref[x.live])
+    assert np.all(attn[~x.live] == 0)
+    for got, exp in zip(out, want):
+        # The reference parks a dead row's zeros on reserved page 0.
+        np.testing.assert_array_equal(got[:, 1:], exp[:, 1:])
+    return attn
+
+
+def _long_row_among_short(g):
+    """One 1.5k-token row beside seven short rows in its tile: the tile
+    runs to the long row's last chunk, the short rows' products stop at
+    their own."""
+    x = _inputs(g, 8, [40, 1500, 17, 1, 300, 33, 129, 64],
+                mp=1536 // g.ps)
+    _held_to_pure(g, x)
+
+
+def _only_last_row_live(g):
+    """A tile whose only live row is its last."""
+    x = _inputs(g, 9, [0] * 7 + [CHUNK_TOKENS + g.ps + 5])
+    _held_to_pure(g, x)
+
+
+def _one_row_a_tile(g):
+    """Four tiles, one live row each, at a different place and of a
+    different length in each: every hand-over of the prefetch chain is
+    to a tile whose first fetch is one row's."""
+    lens = [0] * 32
+    for b, n in ((3, 300), (8, 17), (22, CHUNK_TOKENS), (31, 411)):
+        lens[b] = n
+    _held_to_pure(g, _inputs(g, 10, lens))
+
+
+def _dead_middle_tiles(g):
+    """Four tiles with live rows in the first and the last alone: the
+    prefetch chain crosses two tiles that fetch nothing."""
+    lens = [0] * 32
+    lens[1], lens[6], lens[29] = 260, 31, CHUNK_TOKENS + 1
+    _held_to_pure(g, _inputs(g, 11, lens))
+
+
+def _chunk_edges(g):
+    """Contexts exactly on a chunk's edge, one short of it and one past
+    it (the plan's own chunk at this geometry)."""
+    S = _tile_plan(ROWS, g.ps, g.mp, g.GD, g.itemsize).chunk_tokens
+    assert g.mp * g.ps >= 2 * S
+    _held_to_pure(g, _inputs(g, 12, [S, S + 1, S - 1, 2 * S, 2 * S - 1,
+                                     1, S, 2 * S]))
+
+
+def _wide_table(g):
+    """A block table four times wider with the same lengths: the same
+    output, bit for bit, and the same pools."""
+    x = _inputs(g, 13, _mixed_lens(g))
+    a, out_a = _kernel(g, x)
+    rng = np.random.default_rng(13)
+    wide = np.concatenate(
+        [x.np_bt, rng.choice(x.dead, (ROWS, 3 * g.mp))], axis=1)
+    x.bt = jnp.asarray(wide, jnp.int32)
+    b, out_b = _kernel(g, x)
+    np.testing.assert_array_equal(a, b)
+    for got, exp in zip(out_a, out_b):
+        np.testing.assert_array_equal(got, exp)
+    _close(a, _pure(g, x)[0])
+
+
 CASES = {"page-edges": _page_edges, "dead-rows": _dead_rows,
          "stacked-layer": _stacked_layer, "chunk-width": _chunk_width,
          "dead-pages": _dead_pages, "write-lands-once": _write_lands_once,
-         "kv-head-groups": _kv_head_groups}
+         "kv-head-groups": _kv_head_groups,
+         "long-row-among-short": _long_row_among_short,
+         "only-last-row-live": _only_last_row_live,
+         "one-row-a-tile": _one_row_a_tile,
+         "dead-middle-tiles": _dead_middle_tiles,
+         "chunk-edges": _chunk_edges, "wide-table": _wide_table}
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -275,19 +359,62 @@ def test_tile_plan_served_geometries(served_geometry, config, rows):
     """``_tile_plan`` is a pure function of the shapes: at each
     configuration's full geometry (32 rows x 256 pages of 16; 64 rows x
     16 pages of 128) and at one 8-row tile a plan exists, its row tile
-    is 8 or the batch, its chunk divides the block table and the K/V
-    scratch it implies is within what the function budgets."""
+    is 8 or the batch, its chunk divides the block table and fills the
+    MXU's token axis (>= 128 tokens), the scratch it implies is the
+    scratch it states, within the budget the module states, and the
+    VMEM it asks of the compiler covers that scratch inside what a v5e
+    core has."""
     g = _geom(served_geometry, config)
     B = g.rows_full if rows == "served" else ROWS
     assert fused_kernel_viable(B, g.ps, g.mp_full, g.GD, g.itemsize)
-    R, ppc = _tile_plan(B, g.ps, g.mp_full, g.GD, g.itemsize)
-    assert R in (8, B) and B % R == 0
-    assert g.mp_full % ppc == 0 and ppc >= 1
-    scratch = 2 * 2 * R * ppc * g.ps * g.GD * g.itemsize
-    assert scratch <= SCRATCH_BYTES
+    plan = _tile_plan(B, g.ps, g.mp_full, g.GD, g.itemsize)
+    assert plan.rows in (8, B) and B % plan.rows == 0
+    assert g.mp_full % plan.pages_per_chunk == 0
+    assert plan.chunk_tokens == plan.pages_per_chunk * g.ps
+    assert 128 <= plan.chunk_tokens <= CHUNK_TOKENS
+    assert plan.scratch_bytes == (2 * 2 * plan.rows * plan.chunk_tokens
+                                  * g.GD * g.itemsize)
+    assert plan.scratch_bytes <= SCRATCH_BUDGET_BYTES
+    assert plan.scratch_bytes < plan.vmem_limit_bytes <= 64 * 2**20
+    # A wider block table or a deeper batch changes no call's cut.
+    assert _tile_plan(B, g.ps, 2 * g.mp_full, g.GD, g.itemsize) == plan
     if config.startswith("smollm2"):
-        # The default 256-token chunk (16 pages) would want 33.5 MB.
-        assert (g.rows_full, g.mp_full, ppc) == (32, 256, 4)
-        assert 4 * scratch > SCRATCH_BYTES
+        assert (g.rows_full, g.mp_full) == (32, 256)
     else:
-        assert (g.rows_full, g.mp_full, ppc) == (64, 16, 2)
+        assert (g.rows_full, g.mp_full) == (64, 16)
+
+
+def _occupancy(name, rows):
+    """The three occupancies of the micro-bench (PERF.md §6, PR 29), at
+    a configuration's own batch: nearly full over 0.3-1.5k, four rows of
+    360, two rows of 2,000."""
+    if name == "full":
+        live = rows - 1
+        lens = [300 + 1200 * i // (live - 1) for i in range(live)]
+    else:
+        lens = {"four-of-360": [360] * 4, "two-of-2000": [2000] * 2}[name]
+    return lens + [0] * (rows - len(lens))
+
+
+@pytest.mark.parametrize("occupancy", ["full", "four-of-360", "two-of-2000"])
+@pytest.mark.parametrize("config", CONFIGS)
+def test_decode_work_follows_the_batch(served_geometry, config, occupancy):
+    """``decode_work`` counts the kernel's own schedule: products run
+    for exactly the (row, chunk) pairs in which the row holds a
+    position, and neither they nor the steps depend on the block
+    table's width."""
+    g = _geom(served_geometry, config)
+    lens = _occupancy(occupancy, g.rows_full)
+    width = max(g.mp_full, 2048 // g.ps)     # Mistral serves 2,048
+    plan = _tile_plan(g.rows_full, g.ps, width, g.GD, g.itemsize)
+    steps, computed, live = decode_work(lens, plan)
+    S = plan.chunk_tokens
+    assert computed == live == sum(-(-n // S) for n in lens)
+    tiles = np.asarray(lens).reshape(-1, plan.rows)
+    assert steps == sum(max(1, -(-int(t.max()) // S)) for t in tiles)
+    assert steps < g.rows_full // plan.rows * (width // plan.pages_per_chunk)
+    wider = _tile_plan(g.rows_full, g.ps, 2 * width, g.GD, g.itemsize)
+    assert decode_work(lens, wider) == (steps, computed, live)
+    if occupancy == "four-of-360":
+        # One live tile of ceil(360 / S) chunks; the others one step each.
+        assert steps == -(-360 // S) + g.rows_full // plan.rows - 1

@@ -1,6 +1,7 @@
 """The TPU compiler's verdict without a chip: the serving path's prefill
-attention kernel, and the int8-KV prefill program at Mistral-7B-v0.3's
-widths, compiled for a DESCRIBED v5e at the served sizes.
+attention kernel, its two fused decode kernels, and the int8-KV prefill
+program at Mistral-7B-v0.3's widths, compiled for a DESCRIBED v5e at
+the served sizes.
 
 Interpret mode (tests/test_pallas.py) checks the kernel's arithmetic
 and cannot see what the TPU compiler refuses: a lane slice off the
@@ -60,6 +61,52 @@ def test_prefill_attention_compiles_for_v5e(one_chip, name):
         arg((T, H, D), jnp.bfloat16), pool, pool, arg((mp,), jnp.int32),
         arg((), jnp.int32), arg((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: name -> (rows, H, H_kv, D, page_size, max_pages, layers, pool pages,
+#: int8 KV): the benchmark's two configurations as served, the smoke's
+#: llama3-1b, and a batch of one tile.
+_DECODE_GEOMETRIES = {
+    "smollm2-1.7b": (32, 32, 32, 64, 16, 256, 24, 3328, False),
+    "mistral-7b-w8kv8": (64, 32, 8, 128, 128, 16, 32, 832, True),
+    "llama3-1b": (8, 32, 8, 64, 16, 128, 16, 512, False),
+    "smollm2-1.7b-one-tile": (8, 32, 32, 64, 16, 256, 24, 3328, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_DECODE_GEOMETRIES))
+def test_fused_decode_compiles_for_v5e(one_chip, name):
+    """The plan's scratch passes the compiler's default scoped VMEM (16
+    MiB) at SmolLM2's geometry: Mosaic has to take the
+    ``vmem_limit_bytes`` the plan states, and the rolled row and page
+    loops with their DMAs. One call's program stays a fraction of the
+    v3 kernel's (3.3-5.2 MB serialized: PERF.md §6, PR 29) — every layer
+    of every decode program carries one."""
+    from llmq_tpu.ops.pallas.fused_decode import (
+        fused_decode_attention_pallas, fused_decode_attention_q8_pallas)
+
+    B, H, Hkv, D, ps, mp, L, P, q8 = _DECODE_GEOMETRIES[name]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tables = (arg((B, mp), jnp.int32), arg((B,), jnp.int32),
+              arg((B,), jnp.int32), arg((), jnp.int32))
+    q = arg((B, H, D), jnp.bfloat16)
+    if q8:
+        row, scale = arg((B, Hkv, D), jnp.int8), arg((B, Hkv), jnp.bfloat16)
+        pools = ((arg((L, P, ps, Hkv * D), jnp.int8),) * 2
+                 + (arg((L, P, Hkv, ps), jnp.bfloat16),) * 2)
+        lowered = jax.jit(fused_decode_attention_q8_pallas).lower(
+            q, row, scale, row, scale, pools, *tables)
+    else:
+        row = arg((B, Hkv, D), jnp.bfloat16)
+        pool = arg((L, P, ps, Hkv * D), jnp.bfloat16)
+        lowered = jax.jit(fused_decode_attention_pallas).lower(
+            q, row, row, pool, pool, *tables)
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(text) < 100_000       # the v3 kernel's was 154-427 kB
 
 
 def test_int8_kv_prefill_program_stays_small_for_v5e(one_chip):
