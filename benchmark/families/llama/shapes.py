@@ -1,11 +1,34 @@
-"""What a step or a kernel call MUST move and compute, from shapes
-alone. Kept with the benchmark so that no PR that claims a gain can
-change the yardstick. All functions take the configuration file's
-``model`` block (Hugging Face key names) and the served dtypes."""
+"""The Llama block's shapes: what a step or a kernel call MUST move and
+compute, from shapes alone, and what the harness has to know of the
+family to read a trace. Kept with the benchmark so that no PR that
+claims a gain can change the yardstick. All functions take the
+configuration file's ``model`` block (``MODEL_KEYS``, Hugging Face key
+names) and the served dtypes; the decode functions take the rows of the
+batch beside the tokens of context they attend to (this family's least
+bytes do not depend on the rows; a routed layer's do). Standard library
+only: the parent process and the metric readers import this and stay
+off JAX."""
 
 from __future__ import annotations
 
 from typing import Dict
+
+#: The keys of the public ``config.json`` that say what this family's
+#: shape is: ``contract.resolve_cell`` copies these into
+#: ``config["model"]``.
+MODEL_KEYS = ("vocab_size", "hidden_size", "intermediate_size",
+              "num_hidden_layers", "num_attention_heads",
+              "num_key_value_heads", "head_dim", "max_position_embeddings",
+              "rope_theta", "rms_norm_eps", "tie_word_embeddings")
+#: The program's kernels by their names in a trace (patterns): what the
+#: benchmark takes from the program besides spans and counters.
+DECODE_ATTN = r"fused_decode_attention"
+PREFILL_ATTN = r"paged_prefill_attention"
+
+
+def attn_calls_per_step(model: Dict) -> int:
+    """Decode attention calls of one decode step: one a layer."""
+    return model["num_hidden_layers"]
 
 
 def _dims(model: Dict) -> Dict[str, int]:
@@ -45,7 +68,7 @@ def kv_bytes_per_token(model: Dict, kv_itemsize: int) -> int:
 
 
 def decode_step_bytes(model: Dict, weight_itemsize: int, kv_itemsize: int,
-                      context_tokens: float) -> float:
+                      rows: float, context_tokens: float) -> float:
     """Bytes one decode step must read: every matrix once, plus the
     cached K and V of every token in the batch's contexts
     (``context_tokens`` = sum of the rows' context lengths)."""
@@ -60,7 +83,7 @@ def decode_step_flops(model: Dict, rows: float,
             + 4.0 * d["L"] * d["H"] * d["hd"] * context_tokens)
 
 
-def decode_attn_bytes(model: Dict, kv_itemsize: int,
+def decode_attn_bytes(model: Dict, kv_itemsize: int, rows: float,
                       context_tokens: float) -> float:
     """One decode step's attention over all layers: the cached K and V
     of every context token, read once (q, the new K/V row and the
@@ -68,7 +91,8 @@ def decode_attn_bytes(model: Dict, kv_itemsize: int,
     return kv_bytes_per_token(model, kv_itemsize) * context_tokens
 
 
-def decode_attn_flops(model: Dict, context_tokens: float) -> float:
+def decode_attn_flops(model: Dict, rows: float,
+                      context_tokens: float) -> float:
     d = _dims(model)
     return 4.0 * d["L"] * d["H"] * d["hd"] * context_tokens
 

@@ -4,15 +4,17 @@ Started by ``benchmark/run.py`` with a spec file. It
 
 1. fails unless JAX reports the platform and the number of chips the
    cell asks for (no fallback);
-2. registers the cell's model configuration (``configs/*.json``, Hugging
-   Face key names) into the program's ``MODEL_CONFIGS`` — the program is
-   not edited;
-3. makes the weights on the device from ``--seed`` in one jitted call,
-   in the type they are served in;
-4. holds logits of the serving path's own model functions (kernels,
-   paged cache) against the plain float32 reference (``reference.py``)
-   on a seeded sample — before the KV pool exists, so the float32 copy
-   of a layer has room;
+2. loads the model family the cell's configuration names
+   (``<path>/families/<family>/``, found by ``contract``) and has its
+   ``adapter.py`` register the configuration (``configs/*.json``) with
+   the program — the program is not edited, and this file names no
+   model;
+3. makes the weights on the device from ``--seed`` in one jitted call
+   of the adapter's builder, in the type they are served in;
+4. holds logits of the serving path's own model functions (the
+   adapter's: kernels, paged cache) against the family's plain float32
+   reference (``reference.py``) on a seeded sample — before the KV pool
+   exists, so the float32 copy of a layer has room;
 5. builds the program's own ``App`` (API server, queue plane, workers,
    engine) from the configuration's ``server`` block and serves on
    ``127.0.0.1``;
@@ -57,108 +59,25 @@ class Reply:
             self._f.flush()
 
 
-# -- model configuration and weights ------------------------------------------
+# -- weights -------------------------------------------------------------------
 
 
-def register_model(name: str, model: Dict[str, Any]):
-    """The configuration file's ``model`` block (Hugging Face keys) as
-    one more entry of the program's ``MODEL_CONFIGS``."""
-    from dataclasses import replace
-
-    import jax.numpy as jnp
-
-    from llmq_tpu.models import llama
-
-    hd = model.get("head_dim") or (model["hidden_size"]
-                                   // model["num_attention_heads"])
-    if hd * model["num_attention_heads"] != model["hidden_size"]:
-        raise ValueError("the program derives head_dim as hidden/heads; "
-                         f"{name} has head_dim {hd}")
-    base = llama.LlamaConfig(
-        name=name, vocab_size=model["vocab_size"], dim=model["hidden_size"],
-        n_layers=model["num_hidden_layers"],
-        n_heads=model["num_attention_heads"],
-        n_kv_heads=model["num_key_value_heads"],
-        ffn_dim=model["intermediate_size"],
-        max_seq_len=model["max_position_embeddings"],
-        rope_theta=float(model["rope_theta"]),
-        norm_eps=float(model["rms_norm_eps"]), dtype=jnp.bfloat16,
-        tie_embeddings=bool(model.get("tie_word_embeddings", False)))
-    llama.MODEL_CONFIGS[name] = lambda **kw: replace(base, **kw)
-    return base
-
-
-def param_builder(mcfg, quantized: bool):
-    """``build(key) -> params``: random weights from a key, in the
-    served type, for ONE jitted call on the device (``make_params``).
-    Uniform in (-a, a) with a = sqrt(3 / fan_in)
-    (the variance of the program's own normal init); the hardware
-    generator ("rbg"), because the default counter-based one costs
-    tens of seconds at 7 B. int8 leaves are made as int8: q uniform
-    bytes in [-127, 127] and one scale per output channel, in the
-    program's ``{"q", "s"}`` layout (ops/quant.py)."""
-    import jax
-    import jax.numpy as jnp
-
-    L, D, H, HKV, F, V = (mcfg.n_layers, mcfg.dim, mcfg.n_heads,
-                          mcfg.n_kv_heads, mcfg.ffn_dim, mcfg.vocab_size)
-    hd = mcfg.head_dim
-    shapes = {"wq": ((L, D, H * hd), D), "wk": ((L, D, HKV * hd), D),
-              "wv": ((L, D, HKV * hd), D), "wo": ((L, H * hd, D), H * hd),
-              "w_gate": ((L, D, F), D), "w_up": ((L, D, F), D),
-              "w_down": ((L, F, D), F)}
-
-    def dense(key, shape, fan_in):
-        a = (3.0 / fan_in) ** 0.5
-        return jax.random.uniform(key, shape, jnp.bfloat16, -a, a)
-
-    def quant(key, shape, fan_in, axis):
-        # value = q * s; q uniform int8, so std(q) = 127/sqrt(3) and
-        # s = a / 127 gives the same variance as ``dense``. Random
-        # BYTES, one layer at a time: a stacked 7 B leaf drawn at once
-        # as 32-bit integers does not fit beside the rest (14 GB).
-        a = (3.0 / fan_in) ** 0.5
-
-        def one(k, shp):
-            bits = jax.random.bits(k, shp, jnp.uint8)
-            return jnp.maximum(jax.lax.bitcast_convert_type(bits, jnp.int8),
-                               jnp.int8(-127))
-
-        if len(shape) == 3:
-            q = jax.lax.map(lambda k: one(k, shape[1:]),
-                            jax.random.split(key, shape[0]))
-        else:
-            q = one(key, shape)
-        sshape = list(shape)
-        sshape[axis] = 1
-        return {"q": q, "s": jnp.full(sshape, a / 127.0, jnp.float32)}
-
-    def build(key):
-        keys = jax.random.split(key, len(shapes) + 2)
-        mk = ((lambda k, s, f: quant(k, s, f, -2)) if quantized else dense)
-        layers = {n: mk(keys[i], s, f)
-                  for i, (n, (s, f)) in enumerate(shapes.items())}
-        layers["attn_norm"] = jnp.ones((L, D), jnp.bfloat16)
-        layers["mlp_norm"] = jnp.ones((L, D), jnp.bfloat16)
-        params = {"layers": layers,
-                  "final_norm": jnp.ones((D,), jnp.bfloat16)}
-        if quantized:
-            params["embed"] = quant(keys[-2], (V, D), D, -1)
-        else:
-            params["embed"] = dense(keys[-2], (V, D), D)
-        if not mcfg.tie_embeddings:
-            params["lm_head"] = mk(keys[-1], (D, V), D)
-        return params
-
-    return build
-
-
-def make_params(seed: int, mcfg, quantized: bool):
+def make_params(seed: int, build):
+    """The family's ``build(key)`` as ONE jitted call on the device."""
     import jax
     key = jax.random.key(seed % (2 ** 31), impl="rbg")
-    params = jax.jit(param_builder(mcfg, quantized))(key)
+    params = jax.jit(build)(key)
     jax.block_until_ready(params)
     return params
+
+
+def __getattr__(name: str) -> Any:
+    # The import path that is older than families: see
+    # ``contract.first_family``.
+    if name == "register_model":
+        from benchmark.harness import contract
+        return contract.first_family("adapter").register
+    raise AttributeError(name)
 
 
 # -- correctness ---------------------------------------------------------------
@@ -218,36 +137,27 @@ def exported(name: str, fn, args, ident: str):
 
 
 
-def check_logits(params, mcfg, spec: Dict[str, Any]) -> Dict[str, Any]:
-    """Teacher-forced logits of the serving path (the program's
-    ``forward_prefill(last_only=True)`` through the cell's smallest
-    bucket, then ``forward_decode`` steps through the paged cache, with
-    the kernels the served programs route to) against the float32
-    reference's full forward pass. A small pool of its own: the server's
-    pool does not exist yet."""
-    import dataclasses
-
+def check_logits(params, path, reference_logits,
+                 spec: Dict[str, Any]) -> Dict[str, Any]:
+    """Teacher-forced logits of the serving path (``path``: the
+    family's ``adapter.serving_path`` — the program's own prefill of the
+    last position through the cell's smallest bucket, then decode steps
+    through the paged cache, with the kernels the served programs route
+    to) against the family's float32 reference's full forward pass. A
+    small pool of its own: the server's pool does not exist yet."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark.harness.reference import reference_logits
-    from llmq_tpu.models.llama import (forward_decode, forward_prefill,
-                                       init_kv_pages)
-
-    srv = spec["config"]["server"]
-    ex = srv["executor"]
+    ex = spec["config"]["server"]["executor"]
     tol = spec["config"]["tolerance"]
     ps = int(ex["page_size"])
     bucket = int(min(ex["prefill_buckets"]))
-    kv_int8 = srv["model"].get("kv_quantization") == "int8"
-    cfg = dataclasses.replace(mcfg, pallas_batched_prefill=True)
     n_dec, rows_b = 3, 8
     rng = np.random.default_rng(spec["seed"] % (2 ** 31))
     lengths = [bucket - 5, max(8, bucket // 3)]
     max_pages = -(-(bucket + n_dec + 1) // ps)
-    cache = init_kv_pages(cfg, 1 + len(lengths) * max_pages, ps,
-                          dtype=jnp.int8 if kv_int8 else None)
+    cache = path.cache(1 + len(lengths) * max_pages)
 
     t_mark = [time.perf_counter()]
     phases: Dict[str, float] = {}
@@ -257,32 +167,32 @@ def check_logits(params, mcfg, spec: Dict[str, Any]) -> Dict[str, Any]:
         phases[name] = round(phases.get(name, 0.0) + now - t_mark[0], 3)
         t_mark[0] = now
 
+    # The traced functions keep these names whatever the family: they
+    # name the compiled programs, so XLA's cache finds them again.
     def prefill_fn(params, cache, tokens, positions, lens, bts):
-        return forward_prefill(params, cfg, tokens, positions, lens, cache,
-                               bts, last_only=True)
+        return path.prefill(params, cache, tokens, positions, lens, bts)
 
     def decode_fn(params, cache, tokens, positions, bts, active):
-        return forward_decode(params, cfg, tokens, positions, cache, bts,
-                              active=active)
+        return path.decode(params, cache, tokens, positions, bts, active)
 
     i32 = jnp.int32
     prefill = exported("check_prefill", prefill_fn, (
         params, cache, jax.ShapeDtypeStruct((1, bucket), i32),
         jax.ShapeDtypeStruct((1, bucket), i32),
         jax.ShapeDtypeStruct((1,), i32),
-        jax.ShapeDtypeStruct((1, max_pages), i32)), str(cfg))
+        jax.ShapeDtypeStruct((1, max_pages), i32)), path.ident)
     decode = exported("check_decode", decode_fn, (
         params, cache, jax.ShapeDtypeStruct((rows_b,), i32),
         jax.ShapeDtypeStruct((rows_b,), i32),
         jax.ShapeDtypeStruct((rows_b, max_pages), i32),
-        jax.ShapeDtypeStruct((rows_b,), jnp.bool_)), str(cfg))
+        jax.ShapeDtypeStruct((rows_b,), jnp.bool_)), path.ident)
 
     lap("programs")
     bts = np.zeros((rows_b, max_pages), np.int32)
     seqs, served = [], [[] for _ in lengths]
     for r, n in enumerate(lengths):
         bts[r] = 1 + r * max_pages + np.arange(max_pages)
-        seqs.append(rng.integers(3, cfg.vocab_size, n + n_dec,
+        seqs.append(rng.integers(3, path.vocab_size, n + n_dec,
                                  dtype=np.int32))
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :n] = seqs[r][:n]
@@ -310,9 +220,8 @@ def check_logits(params, mcfg, spec: Dict[str, Any]) -> Dict[str, Any]:
     worst_max, worst_rms, ref_rms = 0.0, 0.0, 0.0
     for r, n in enumerate(lengths):
         ref = np.asarray(reference_logits(
-            params, seqs[r], n_layers=cfg.n_layers, n_heads=cfg.n_heads,
-            n_kv_heads=cfg.n_kv_heads, eps=cfg.norm_eps,
-            theta=cfg.rope_theta, rows=list(range(n - 1, n + n_dec))))
+            params, seqs[r], spec["config"]["model"],
+            list(range(n - 1, n + n_dec))))
         d = np.stack(served[r]) - ref
         worst_max = max(worst_max, float(np.abs(d).max()))
         worst_rms = max(worst_rms, float(np.sqrt((d * d).mean(-1)).max()))
@@ -449,17 +358,22 @@ def main(argv: Optional[List[str]] = None) -> int:
     from llmq_tpu.parallel import enable_compilation_cache
     enable_compilation_cache()
 
+    from benchmark.harness import contract, tracered
     config = spec["config"]
     srv = config["server"]
-    name = srv["model"]["name"]
+    adapter = contract.load_family(spec["family_dir"], "adapter")
+    kernels = contract.load_family(spec["family_dir"], "shapes")
     t0 = time.perf_counter()
-    mcfg = register_model(name, config["model"])
-    quantized = srv["model"].get("quantization") == "int8"
-    params = make_params(spec["seed"], mcfg, quantized)
+    mcfg = adapter.register(srv["model"]["name"], config)
+    params = make_params(spec["seed"],
+                         adapter.param_builder(mcfg, srv["model"]))
     stages["weights"] = time.perf_counter() - t0
 
     t0 = time.perf_counter()
-    correctness = check_logits(params, mcfg, spec)
+    correctness = check_logits(
+        params, adapter.serving_path(mcfg, srv),
+        contract.load_family(spec["family_dir"],
+                             "reference").reference_logits, spec)
     stages["correctness"] = time.perf_counter() - t0
 
     # The program's own wiring, as ``python -m llmq_tpu serve`` does it.
@@ -582,10 +496,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                     if meta.get("terminal") != "failed":
                         meta["terminal"] = e.stage
             timelines[tl.request_id] = {"stages": stages_, "meta": meta}
-        from benchmark.harness import tracered
         for cap in captures:
             t_r = time.perf_counter()
-            cap["reduced"] = tracered.reduce_dir(cap["dir"])
+            cap["reduced"] = tracered.reduce_dir(
+                cap["dir"], kernels.DECODE_ATTN, kernels.PREFILL_ATTN)
             cap["reduce_s"] = time.perf_counter() - t_r
         out = {"taps": {rid: r for rid, r in tap.recs.items()},
                "captures": captures,

@@ -6,9 +6,10 @@ returns one number, or ``None`` where there is nothing to read."""
 from __future__ import annotations
 
 import re
+from types import ModuleType
 from typing import Any, Callable, Dict, Optional, Tuple
 
-from benchmark.harness import stats
+from benchmark.harness import contract, stats
 from benchmark.harness.peaks import peaks_for
 
 Run = Dict[str, Any]
@@ -58,6 +59,12 @@ def capture(run: Run, index: int = 0) -> Optional[Dict[str, Any]]:
     return caps[index] if len(caps) > index else None
 
 
+def family_shapes(run: Run) -> ModuleType:
+    """``shapes.py`` of the run's model family: its shape functions and
+    the names of its kernels in a trace."""
+    return contract.load_family(run["family_dir"], "shapes")
+
+
 def ops_time(cap: Dict, pattern: str) -> float:
     rx = re.compile(pattern)
     return sum(v[0] for k, v in cap["reduced"]["ops"].items()
@@ -66,11 +73,11 @@ def ops_time(cap: Dict, pattern: str) -> float:
 
 def decode_steps(run: Run, cap: Dict) -> Optional[float]:
     """Decode steps the device ran inside the capture: calls of the
-    decode attention kernel (one per layer and step) over the layers."""
+    decode attention kernel over the calls the family makes a step."""
     progs = cap["reduced"].get("programs", {})
     calls = sum(v[2] for v in progs.values())
-    layers = run["config"]["model"]["num_hidden_layers"]
-    return calls / layers if calls else None
+    per_step = family_shapes(run).attn_calls_per_step(run["config"]["model"])
+    return calls / per_step if calls else None
 
 
 def decode_module_time(cap: Dict) -> float:

@@ -35,22 +35,22 @@ WAITS = re.compile(r"(^|[ :.])(wait|sleep|select|poll|acquire|accept|recv|"
                    r"_bootstrap_inner|__call__|wrapper|inner)$")
 MIN_GAP_NS = 20_000
 LOOKBACK_NS = 200_000_000
-#: The program's kernels by their names in the trace: what the
-#: benchmark takes from the program besides spans and counters.
-DECODE_ATTN = re.compile(r"fused_decode_attention")
-PREFILL_ATTN = re.compile(r"paged_prefill_attention")
 
 
-def classify_programs(modules: List[List[Any]],
-                      ops: List[List[Any]]) -> Dict[str, List[float]]:
+def classify_programs(modules: List[List[Any]], ops: List[List[Any]],
+                      decode_attn: str,
+                      prefill_attn: str) -> Dict[str, List[float]]:
     """Program runs by what ran inside them, since an exported program
     is named ``jit_call(<fingerprint>)`` whatever it does: a run that
     holds decode attention calls is a ``decode`` chunk, one that holds
     prefill attention calls too a ``mixed`` chunk, one with prefill
-    attention alone a ``prefill`` program. kind -> [seconds, runs,
-    decode attention calls, prefill attention calls]."""
-    dec = sorted(e[1] for e in ops if DECODE_ATTN.search(op_name(e[0])))
-    pre = sorted(e[1] for e in ops if PREFILL_ATTN.search(op_name(e[0])))
+    attention alone a ``prefill`` program. The two kernels are known by
+    the patterns of the cell's model family (its ``shapes.py``
+    ``DECODE_ATTN`` / ``PREFILL_ATTN``). kind -> [seconds, runs, decode
+    attention calls, prefill attention calls]."""
+    dec_rx, pre_rx = re.compile(decode_attn), re.compile(prefill_attn)
+    dec = sorted(e[1] for e in ops if dec_rx.search(op_name(e[0])))
+    pre = sorted(e[1] for e in ops if pre_rx.search(op_name(e[0])))
     out: Dict[str, List[float]] = {}
     for _name, start, dur in modules:
         nd = bisect.bisect_left(dec, start + dur) - bisect.bisect_left(
@@ -141,7 +141,8 @@ def self_times(events: List[List[Any]]) -> Dict[str, List[float]]:
     return out
 
 
-def reduce_neutral(trace: Dict[str, Any]) -> Dict[str, Any]:
+def reduce_neutral(trace: Dict[str, Any], decode_attn: str,
+                   prefill_attn: str) -> Dict[str, Any]:
     dev_planes = [p for p in trace["planes"] if DEVICE_PLANE.match(p["name"])]
     host_events: List[List[Any]] = []
     for p in trace["planes"]:
@@ -177,8 +178,9 @@ def reduce_neutral(trace: Dict[str, Any]) -> Dict[str, Any]:
                     acc[0] += dur / 1e9
                     acc[1] += 1
         by_line = {ln["name"]: ln["events"] for ln in p["lines"]}
-        for k, v in classify_programs(by_line.get(MODULES_LINE, []),
-                                      by_line.get(OPS_LINE, [])).items():
+        for k, v in classify_programs(
+                by_line.get(MODULES_LINE, []), by_line.get(OPS_LINE, []),
+                decode_attn, prefill_attn).items():
             acc = programs.setdefault(k, [0.0, 0, 0, 0])
             for i in range(4):
                 acc[i] += v[i]
@@ -213,11 +215,13 @@ def reduce_neutral(trace: Dict[str, Any]) -> Dict[str, Any]:
     }
 
 
-def reduce_dir(trace_dir: str) -> Dict[str, Any]:
+def reduce_dir(trace_dir: str, decode_attn: str,
+               prefill_attn: str) -> Dict[str, Any]:
     path = find_xplane(trace_dir)
     if path is None:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    return reduce_neutral(xplane_to_neutral(path))
+    return reduce_neutral(xplane_to_neutral(path), decode_attn,
+                          prefill_attn)
 
 
 def top_ops(red: Dict[str, Any], k: int = 10) -> List[List[Any]]:

@@ -1,7 +1,8 @@
 """Batch rows a decode step kept busy: tokens delivered while the first
 capture was held (the tap's count) over the decode steps the device ran
-in it (decode attention calls / layers). ``engine/stats.decode_steps``
-counts dispatches, not steps, so it is not used."""
+in it (decode attention calls / the family's calls a step).
+``engine/stats.decode_steps`` counts dispatches, not steps, so it is not
+used."""
 from benchmark.harness.readers import capture, decode_steps
 
 

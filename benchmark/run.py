@@ -236,8 +236,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     workdir = work_dir(args.workload, args.trace)
     spec = {"workload": args.workload, "seed": args.seed,
             "platform": args.platform, "chips": cell["cell"]["chips"],
-            "config": cell["config"], "workdir": workdir,
-            "port": free_port()}
+            "config": cell["config"], "family_dir": cell["family_dir"],
+            "workdir": workdir, "port": free_port()}
     child = Child(spec, os.path.join(workdir, "server.log"))
     try:
         result = drive(args, bench, cell, child, spec)
@@ -371,8 +371,9 @@ def drive(args, bench, cell, child: Child, spec) -> Dict[str, Any]:
         "requests": requests, "attempted": attempted, "failed": failed,
         "opened": opened, "closed": closed, "setup_s": setup_s,
         "captures": dump.get("captures", []), "config": config,
-        "traffic": traffic, "device": ready["device"],
-        "ready": ready, "compiles_in_window": compiles,
+        "family_dir": cell["family_dir"], "traffic": traffic,
+        "device": ready["device"], "ready": ready,
+        "compiles_in_window": compiles,
     }
     metrics: Dict[str, Dict[str, Any]] = {}
     for m in cell["per_layer" if args.trace else "end_to_end"]:

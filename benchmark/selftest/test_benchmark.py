@@ -25,6 +25,14 @@ from benchmark.harness import contract, plan, stats, tracered  # noqa: E402
 
 DATA = os.path.join(ROOT, "benchmark", "selftest", "data")
 SEEDS = [0, 1, 17, 2 ** 31 + 5, 3000000001]
+#: The recorded traces are of the program serving a Llama block.
+LLAMA = os.path.join(ROOT, "benchmark", "families", "llama")
+
+
+def _reduce(trace):
+    shapes = contract.load_family(LLAMA, "shapes")
+    return tracered.reduce_neutral(trace, shapes.DECODE_ATTN,
+                                   shapes.PREFILL_ATTN)
 
 
 def _traffic(name):
@@ -145,7 +153,7 @@ def test_percentile_and_tpot_on_recorded_marks():
 
 def test_trace_reduction_on_a_small_recorded_trace():
     with open(os.path.join(DATA, "trace_small.json")) as f:
-        red = tracered.reduce_neutral(json.load(f))
+        red = _reduce(json.load(f))
     assert red["devices"] == 1
     assert red["busy_s"] == pytest.approx(15e-6)
     assert red["window_s"] == pytest.approx(65e-6)
@@ -170,7 +178,7 @@ def test_trace_reduction_on_a_small_recorded_trace():
 def test_trace_reduction_on_a_slice_of_a_chip_trace():
     """40 ms of a capture on the v5e: one mixed chunk under way."""
     with open(os.path.join(DATA, "trace_chip_sample.json")) as f:
-        red = tracered.reduce_neutral(json.load(f))
+        red = _reduce(json.load(f))
     assert red["devices"] == 1
     assert 0.9 < red["busy_s"] / red["window_s"] <= 1.0
     assert tracered.top_ops(red, 1)[0][0] == "paged_prefill_attention_pallas"
@@ -181,6 +189,13 @@ def test_trace_reduction_on_a_slice_of_a_chip_trace():
     mixed = red["programs"]["mixed"]
     assert mixed[1] == 1 and mixed[2] > 0 and mixed[3] > 0
     assert "decode" not in red["programs"]
+    # under a family whose kernels have other names the same trace
+    # holds no decode step: the patterns are the family's, not tracered's
+    with open(os.path.join(DATA, "trace_chip_sample.json")) as f:
+        other = tracered.reduce_neutral(json.load(f), "latent_decode",
+                                        "latent_prefill")
+    assert set(other["programs"]) == {"other"}
+    assert other["busy_s"] == red["busy_s"]
 
 
 # -- driven by data -------------------------------------------------------------
@@ -283,15 +298,20 @@ def test_peaks_table_refuses_an_unknown_device():
 
 
 def test_shape_functions_on_the_published_sizes(bench):
-    from benchmark.harness import shapes
-    smol = contract.resolve_cell(bench, "smollm2-chat-bursts")["config"]["model"]
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "mistral-7b-v0.3-w8kv8.json")) as f:
-        mis = json.load(f)      # kept for the cell in PERF.md's Open questions
+    smol = contract.resolve_cell(bench, "smollm2-chat-bursts")
+    mis = contract.resolve_cell(bench, "mistral7b-decode-saturated")
+    assert smol["family_dir"] == mis["family_dir"] == LLAMA
+    shapes = contract.load_family(LLAMA, "shapes")
+    smol, mis = smol["config"]["model"], mis["config"]["model"]
     assert shapes.kv_bytes_per_token(smol, 2) == 196608
     assert shapes.kv_bytes_per_token(mis, 1) == 65536 + 1024
     assert round(shapes.param_count(smol) / 1e9, 2) == 1.71
     assert round(shapes.param_count(mis) / 1e9, 2) == 7.25
+    assert shapes.attn_calls_per_step(smol) == 24
+    # this family's least bytes and operations of a step's attention do
+    # not depend on the rows of the batch
+    assert shapes.decode_attn_bytes(smol, 2, 4, 1000.0) == \
+        shapes.decode_attn_bytes(smol, 2, 31, 1000.0) == 196608 * 1000.0
 
 
 # -- rehearsal 1: the whole command on the CPU at a tiny size ------------------

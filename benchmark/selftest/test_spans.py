@@ -168,7 +168,8 @@ def test_a_program_without_the_spans_reads_as_nothing(tmp_path, bench):
 def test_the_new_entries_are_within_the_contract(bench):
     assert contract.check_names(bench) == []
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-9:] == list(NEW)
+    # by name: later PRs append their own entries after these
+    assert set(NEW) <= set(by_name)
     for n in NEW:
         assert set(by_name[n]) <= {"name", "unit", "better", "source",
                                    "layer", "moves", "workloads"}
