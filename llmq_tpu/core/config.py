@@ -816,7 +816,7 @@ class MetricsConfig:
 @dataclass
 class ModelConfig:
     """Execution-plane model selection (new scope; BASELINE configs #2/#5)."""
-    name: str = "llama3-tiny"          # a key of models/llama.py MODEL_CONFIGS
+    name: str = "llama3-tiny"          # a name of the models/ registry (models.model_names())
     checkpoint_path: str = ""           # orbax checkpoint dir; empty → random init
     tokenizer_path: str = ""            # local HF tokenizer dir; empty → bytes
     # Safetensors re-exports of Meta-original interleaved-rotary

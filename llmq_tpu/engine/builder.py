@@ -81,8 +81,8 @@ def build_engine(cfg: Config, *, name: str = "engine0",
         import jax
         import jax.numpy as jnp
 
-        from llmq_tpu.models.llama import get_config, init_params
-        from llmq_tpu.models.checkpoint import import_hf_llama, load_checkpoint
+        from llmq_tpu.models import family_of, get_config
+        from llmq_tpu.models.checkpoint import import_hf, load_checkpoint
 
         from llmq_tpu.parallel import enable_compilation_cache
         enable_compilation_cache()
@@ -92,6 +92,7 @@ def build_engine(cfg: Config, *, name: str = "engine0",
             mcfg = get_config(cfg.model.name,
                               max_seq_len=cfg.model.max_seq_len,
                               vocab_size=cfg.model.vocab_size)
+        fam = family_of(mcfg)
         if tokenizer.vocab_size > mcfg.vocab_size:
             raise ValueError(
                 f"tokenizer vocab ({tokenizer.vocab_size}) exceeds model "
@@ -106,12 +107,16 @@ def build_engine(cfg: Config, *, name: str = "engine0",
         if kv_quant not in ("", "int8"):
             raise ValueError(f"unknown model.kv_quantization {kv_quant!r} "
                              f"(supported: 'int8')")
+        fam.check_serving(
+            mcfg, quantization=quant, kv_quantization=kv_quant,
+            mesh=bool(mesh_shape),
+            speculation_draft_k=(spec.draft_k if spec_on else 0))
         import time as _time
         t_weights0 = _time.perf_counter()
         if params is None:
             path = cfg.model.checkpoint_path
             if path and path.endswith(".safetensors.d"):
-                params = import_hf_llama(
+                params = import_hf(
                     path, mcfg, meta_rope_layout=cfg.model.meta_rope_layout)
             elif path:
                 # An explicitly configured checkpoint that fails to load
@@ -123,11 +128,10 @@ def build_engine(cfg: Config, *, name: str = "engine0",
                     # Quantize leaf-by-leaf during init: materializing the
                     # full bf16 tree first would OOM the very chip int8
                     # exists to fit (llama3-8b bf16 = 16 GB = all of v5e).
-                    from llmq_tpu.models.llama import init_params_quantized
-                    params = init_params_quantized(jax.random.PRNGKey(0),
-                                                   mcfg)
+                    params = fam.init_params_quantized(
+                        jax.random.PRNGKey(0), mcfg)
                 else:
-                    params = init_params(jax.random.PRNGKey(0), mcfg)
+                    params = fam.init_params(jax.random.PRNGKey(0), mcfg)
         if quant == "int8":
             from llmq_tpu.ops.quant import quantize_params
             # Idempotent: a tree already quantized (init path above, or a

@@ -747,6 +747,12 @@ class InferenceEngine:
         #: the 5 s warning threshold in _service_while / chunk fetch.
         self.stall_events = 0
         self.stall_ms_total = 0.0
+        #: A routed model's counters, summed over every chunk fetched
+        #: (``ChunkHandle.stats``, models/deepseek_v3.py): tokens each
+        #: expert received, then the experts that received any summed
+        #: over the routed layers run, then those layer runs. ``None``
+        #: until a chunk brings some (a dense model never does).
+        self._moe: Optional[np.ndarray] = None
         #: Token-budget mixed prefill+decode batching
         #: (docs/architecture.md "Mixed step"). ``mixed_batch`` accepts
         #: a core.config.MixedBatchConfig or anything with the same
@@ -3171,9 +3177,26 @@ class InferenceEngine:
             if box["err"] is not None:
                 raise box["err"]
             out, device_s, readback_s, overlapped_s = box["out"]
-        with self._prof.span("engine.commit"):
+        with self._prof.span("engine.commit") as span:
             self._commit_chunk(infl, out, device_s, readback_s,
                                overlapped_s)
+            self._note_moe(getattr(infl.handle, "stats", None), span)
+
+    def _note_moe(self, stats, span) -> None:
+        """Fold a fetched chunk's routed-layer counters (they came over
+        with its tokens) into ``get_stats()["moe"]`` and onto the span
+        that covers the commit: what a capture's readers sum. The 128
+        loads ride as one string (``n3_0_5_...``: the profiler reads a
+        string that starts with a digit as a number), and only while a
+        capture is held."""
+        if stats is None:
+            return
+        st = np.asarray(stats, np.int64)
+        self._moe = st if self._moe is None else self._moe + st
+        if capture_held():
+            span.note(moe_pairs=int(st[:-2].sum()),
+                      moe_touched=int(st[-2]), moe_layer_runs=int(st[-1]),
+                      moe_load="n" + "_".join(map(str, st[:-2].tolist())))
 
     def _commit_chunk(self, infl: _InflightChunk, out, device_s: float,
                       readback_s: float, overlapped_s: float) -> None:
@@ -4250,6 +4273,20 @@ class InferenceEngine:
                 "prefill_tokens": self.mixed_prefill_tokens_total,
                 "prefill_token_budget":
                     int(self._mixed_cfg.prefill_token_budget),
+            }
+        if self._moe is not None:
+            load, touched, runs = (self._moe[:-2], int(self._moe[-2]),
+                                   int(self._moe[-1]))
+            out["moe"] = {
+                # (token, expert) pairs multiplied, routed-layer runs
+                # (steps x routed layers), distinct experts a run
+                # touched on average, and the busiest expert's tokens
+                # over the mean's.
+                "pairs": int(load.sum()), "layer_runs": runs,
+                "experts_touched_mean": touched / runs if runs else 0.0,
+                "load_max_over_mean": (float(load.max() / load.mean())
+                                       if load.sum() else 0.0),
+                "load": load.tolist(),
             }
         if self._tiering is not None:
             # Tiered KV plane (docs/tiering.md): residency per tier,

@@ -662,17 +662,27 @@ class ChunkHandle:
     fetch; ``tok``/``pos``/``done`` are the device-resident end state a
     speculative next chunk consumes directly (no host round-trip)."""
 
-    __slots__ = ("out", "tok", "pos", "done")
+    __slots__ = ("out", "tok", "pos", "done", "stats")
 
-    def __init__(self, out, tok, pos, done) -> None:
+    def __init__(self, out, tok, pos, done, stats=None) -> None:
         self.out = out
         self.tok = tok
         self.pos = pos
         self.done = done
+        #: The family's step counters summed over the chunk (a routed
+        #: model's expert loads), on the device until ``fetch`` brings
+        #: them over with the tokens; ``None`` for a family without.
+        self.stats = stats
 
     def fetch(self) -> np.ndarray:
-        """Blocking host transfer of the chunk's sampled tokens."""
-        return np.asarray(self.out)
+        """Blocking host transfer of the chunk's sampled tokens (and,
+        in the same ``device_get``, of its counters)."""
+        if self.stats is None:
+            return np.asarray(self.out)
+        import jax
+
+        out, self.stats = jax.device_get((self.out, self.stats))
+        return np.asarray(out)
 
 
 class MixedChunkHandle:
@@ -682,14 +692,15 @@ class MixedChunkHandle:
     ``pf_first`` — the per-slice sampled next tokens the engine commits
     for sequences whose FINAL slice rode this chunk."""
 
-    __slots__ = ("out", "tok", "pos", "done", "pf_first")
+    __slots__ = ("out", "tok", "pos", "done", "pf_first", "stats")
 
-    def __init__(self, out, tok, pos, done, pf_first) -> None:
+    def __init__(self, out, tok, pos, done, pf_first, stats=None) -> None:
         self.out = out
         self.tok = tok
         self.pos = pos
         self.done = done
         self.pf_first = pf_first
+        self.stats = stats     # as ChunkHandle.stats
 
     def pf_first_at(self, i: int):
         """Slice ``i``'s sampled first token, still on the device: the
@@ -705,7 +716,8 @@ class MixedChunkHandle:
         transfer pays the host↔device round-trip)."""
         import jax
 
-        out, pf = jax.device_get((self.out, self.pf_first))
+        out, pf, self.stats = jax.device_get(
+            (self.out, self.pf_first, self.stats))
         return np.asarray(out), np.asarray(pf)
 
 
@@ -763,6 +775,12 @@ class VerifyHandle:
                                         self._eos)
 
 
+def _is_quantized_tree(params) -> bool:
+    """int8 weights? Every family's tree has ``layers.wq``."""
+    from llmq_tpu.ops.quant import is_quantized
+    return is_quantized(params["layers"]["wq"])
+
+
 def _named(fn: Callable, name: str) -> Callable:
     """``fn`` under another ``__name__``: ``jax.jit`` names the XLA
     module it builds ``jit_<__name__>``."""
@@ -773,7 +791,8 @@ def _named(fn: Callable, name: str) -> Callable:
 
 
 class JaxExecutor:
-    """Paged continuous-batching executor over models/llama.py.
+    """Paged continuous-batching executor over a model family of
+    ``llmq_tpu/models/`` (the Llama block, the DeepSeek-V3 block).
 
     Compilation surface is bounded by design: one decode program for the
     fixed (B, max_pages) geometry, and one prefill program per length
@@ -814,21 +833,55 @@ class JaxExecutor:
         import jax.numpy as jnp
         from functools import partial
 
-        from llmq_tpu.models.llama import (
-            forward_decode, forward_mixed, forward_prefill,
-            forward_verify, init_kv_pages)
+        from llmq_tpu.models import family_of
         from llmq_tpu.ops.sampling import (
             position_keys, sample_token, sample_token_keyed)
-
-        import dataclasses as _dc
 
         self._jax = jax
         self._jnp = jnp
         self.mesh = mesh
-        # Serving context: forward-only programs, so the batched-prefill
-        # kernels are safe here (the flag keeps them away from the
-        # differentiated training path, which shares forward_prefill).
-        model_cfg = _dc.replace(model_cfg, pallas_batched_prefill=True)
+        #: The model family's module (models/__init__.py): the serving
+        #: programs below are built from ITS forward functions and pool,
+        #: at ITS config for forward-only programs.
+        fam = self._family = family_of(model_cfg)
+        model_cfg = fam.serving_config(model_cfg)
+        fam.check_serving(
+            model_cfg,
+            quantization=("int8" if _is_quantized_tree(params) else ""),
+            kv_quantization=("int8" if cache_dtype is not None
+                             and jnp.dtype(cache_dtype) == jnp.int8
+                             else ""),
+            mesh=mesh is not None and mesh.size > 1,
+            speculation_draft_k=int(speculation_draft_k))
+        init_kv_pages = fam.init_kv_pages
+        forward_prefill, forward_verify = (fam.forward_prefill,
+                                           fam.forward_verify)
+        #: Counters a forward pass of this family returns after the
+        #: cache (a routed model: tokens an expert, experts touched);
+        #: 0 for a family that counts nothing. The chunk programs sum
+        #: them over their steps and return the sum as their last
+        #: output (``None`` at 0: no output, the same program as
+        #: before there were any).
+        n_stats = fam.step_stats_size(model_cfg)
+
+        def forward_decode(params, cfg, tok, pos, cache, bts, active=None,
+                           acc=None):
+            if not n_stats:
+                return fam.forward_decode(params, cfg, tok, pos, cache, bts,
+                                          active=active) + (None,)
+            logits, cache, st = fam.forward_decode(
+                params, cfg, tok, pos, cache, bts, active=active, stats=True)
+            return logits, cache, (st if acc is None else acc + st)
+
+        def forward_mixed(*args, dec_active=None):
+            if not n_stats:
+                return fam.forward_mixed(*args, dec_active=dec_active) + (
+                    None,)
+            return fam.forward_mixed(*args, dec_active=dec_active,
+                                     stats=True)
+
+        def stats0():
+            return jnp.zeros((n_stats,), jnp.int32) if n_stats else None
         #: dp universes of the paged pool (docs/multihost.md): > 1 when
         #: the mesh has a dp axis that divides BOTH the batch and the
         #: page count — the batch dim then shards over dp, the pool's
@@ -946,12 +999,12 @@ class JaxExecutor:
             # mesh (sharded-array futures).
             jit_chunk = partial(jax.jit, donate_argnums=(1,),
                                 out_shardings=(_batch, _batch, _batch,
-                                               _batch, kvs))
+                                               _batch, kvs, None))
             # mixed_chunk returns (out, tok, pos, done, pf_first, cache);
             # pf_first is slice-indexed (not batch) → replicated.
             jit_mixed = partial(jax.jit, donate_argnums=(1,),
                                 out_shardings=(_batch, _batch, _batch,
-                                               _batch, _repl, kvs))
+                                               _batch, _repl, kvs, None))
             # verify (device accept) returns (out (B, W), n_commit (B,),
             # cache); verify (host accept) returns (out (B, W), cache).
             jit_verify = partial(jax.jit, donate_argnums=(1,),
@@ -993,7 +1046,7 @@ class JaxExecutor:
         @jit_decode
         def _decode_step(params, cache, tokens, positions, block_tables,
                          temperatures, key):
-            logits, cache = forward_decode(
+            logits, cache, _ = forward_decode(
                 params, cfg, tokens, positions, cache, block_tables)
             toks = sample_token(logits, key, temperature=temperatures,
                                 top_k=top_k, top_p=top_p)
@@ -1038,16 +1091,16 @@ class JaxExecutor:
             UNROLL = 2 if K % 2 == 0 else 1
 
             def cond(st):
-                j, _, _, _, frozen, _ = st
+                j, _, _, _, frozen, _, _ = st
                 return (j < K) & jnp.any(~frozen & (j < budgets))
 
             def body(st):
-                j, cache, tok, pos, frozen, out = st
+                j, cache, tok, pos, frozen, out, acc = st
                 for u in range(UNROLL):
                     active = (~frozen) & (j + u < budgets)
-                    logits, cache = forward_decode(
+                    logits, cache, acc = forward_decode(
                         params, cfg, tok, pos, cache, block_tables,
-                        active=active)
+                        active=active, acc=acc)
                     nxt = sample_token(logits, keys[j + u],
                                        temperature=temperatures,
                                        top_k=top_k, top_p=top_p)
@@ -1060,12 +1113,13 @@ class JaxExecutor:
                     tok = jnp.where(active, nxt.astype(jnp.int32), tok)
                     pos = pos + active.astype(jnp.int32)
                     frozen = frozen | (active & (nxt == eos))
-                return (j + UNROLL, cache, tok, pos, frozen, out)
+                return (j + UNROLL, cache, tok, pos, frozen, out, acc)
 
-            _, cache, tok, pos, frozen, out = jax.lax.while_loop(
+            _, cache, tok, pos, frozen, out, acc = jax.lax.while_loop(
                 cond, body,
-                (jnp.int32(0), cache, tokens, positions, frozen0, out0))
-            return out, tok, pos, frozen, cache
+                (jnp.int32(0), cache, tokens, positions, frozen0, out0,
+                 stats0()))
+            return out, tok, pos, frozen, cache, acc
 
         S, T = self.mixed_prefill_slices, self.mixed_slice_tokens
         _mixed_chunk = None
@@ -1100,13 +1154,12 @@ class JaxExecutor:
                 out = jnp.full((B, K), eos, jnp.int32)
                 frozen = done_in
                 active0 = (~frozen) & (budgets > 0)
-                dec_logits, pf_logits, cache = forward_mixed(
+                dec_logits, pf_logits, cache, acc = forward_mixed(
                     params, cfg, tokens, positions, cache, block_tables,
                     pf_tokens, pf_positions, pf_lengths, pf_block_tables,
                     dec_active=active0)
-                idx = jnp.arange(pf_tokens.shape[0])
                 pf_first = sample_token(
-                    pf_logits[idx, pf_lengths - 1], keys[K],
+                    pf_logits, keys[K],
                     temperature=pf_temps, top_k=top_k, top_p=top_p)
                 nxt = sample_token(dec_logits, keys[0],
                                    temperature=temperatures,
@@ -1118,15 +1171,15 @@ class JaxExecutor:
                 frozen = frozen | (active0 & (nxt == eos))
 
                 def cond(st):
-                    j, _, _, _, fr, _ = st
+                    j, _, _, _, fr, _, _ = st
                     return (j < K) & jnp.any(~fr & (j < budgets))
 
                 def body(st):
-                    j, cache, tok, pos, fr, out = st
+                    j, cache, tok, pos, fr, out, acc = st
                     active = (~fr) & (j < budgets)
-                    logits, cache = forward_decode(
+                    logits, cache, acc = forward_decode(
                         params, cfg, tok, pos, cache, block_tables,
-                        active=active)
+                        active=active, acc=acc)
                     nxt = sample_token(logits, keys[j],
                                        temperature=temperatures,
                                        top_k=top_k, top_p=top_p)
@@ -1136,12 +1189,12 @@ class JaxExecutor:
                     tok = jnp.where(active, nxt.astype(jnp.int32), tok)
                     pos = pos + active.astype(jnp.int32)
                     fr = fr | (active & (nxt == eos))
-                    return (j + 1, cache, tok, pos, fr, out)
+                    return (j + 1, cache, tok, pos, fr, out, acc)
 
-                _, cache, tok, pos, frozen, out = jax.lax.while_loop(
+                _, cache, tok, pos, frozen, out, acc = jax.lax.while_loop(
                     cond, body,
-                    (jnp.int32(1), cache, tok, pos, frozen, out))
-                return out, tok, pos, frozen, pf_first, cache
+                    (jnp.int32(1), cache, tok, pos, frozen, out, acc))
+                return out, tok, pos, frozen, pf_first, cache, acc
 
         _verify_chunk = None
         if self.verify_draft_k > 0 and self._spec_device_sampling:
@@ -1191,7 +1244,7 @@ class JaxExecutor:
                 def body(st):
                     j, cache, tok, pos, frozen, out, ncommit = st
                     active = (~frozen) & (j < qlens)
-                    logits, cache = forward_decode(
+                    logits, cache, _ = forward_decode(
                         params, cfg, tok, pos, cache, block_tables,
                         active=active)
                     ks = position_keys(key, rows, pos + 1)
@@ -1336,17 +1389,10 @@ class JaxExecutor:
     def telemetry_info(self) -> Dict:
         """Model identity for the MFU estimator — shared with the
         engine's telemetry registration (same math bench.py uses)."""
-        from llmq_tpu.models.llama import param_count
-        try:
-            from llmq_tpu.ops.quant import is_quantized
-            quant = ("int8"
-                     if is_quantized(self.params["layers"]["wq"]) else "")
-        except Exception:  # noqa: BLE001 — non-llama param trees
-            quant = ""
-        try:
-            n_params = param_count(self.params)
-        except Exception:  # noqa: BLE001
-            n_params = 0
+        quant = "int8" if _is_quantized_tree(self.params) else ""
+        # What one token multiplies with: every parameter of a dense
+        # block, the routed share of a sparse one.
+        n_params = self._family.active_param_count(self.model_cfg)
         from llmq_tpu.observability.device import device_identity
         ident = device_identity()
         return {"n_params": n_params,
@@ -1482,18 +1528,12 @@ class JaxExecutor:
     def _routes(self, *, decode: bool = False,
                 prefill_rows: int = 0) -> Dict[str, str]:
         """Attention-op routes of one program at this executor's
-        geometry (see :func:`llmq_tpu.ops.attention.kernel_routes`)."""
-        from llmq_tpu.ops.attention import kernel_routes
-
-        cfg = self.model_cfg
-        kv = self.cache["k"]
-        return kernel_routes(
-            batch=self.spec.batch_size, page_size=self.spec.page_size,
-            max_pages=self.spec.max_pages_per_seq,
-            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-            kv_itemsize=kv.dtype.itemsize,
-            quant_kv="k_scale" in self.cache, enabled=cfg.pallas,
-            multi_ok=cfg.pallas_batched_prefill, decode=decode,
+        geometry, by the model family's ``routes`` (for the Llama block
+        :func:`llmq_tpu.ops.attention.kernel_routes`)."""
+        return self._family.routes(
+            self.model_cfg, self.cache, batch=self.spec.batch_size,
+            page_size=self.spec.page_size,
+            max_pages=self.spec.max_pages_per_seq, decode=decode,
             prefill_rows=prefill_rows)
 
     def _export_cache_dir(self) -> Optional[str]:
@@ -2127,7 +2167,7 @@ class JaxExecutor:
         fn = self._aot.get("decode_chunk", self._decode_chunk)
         tok_in, pos_in, done_in = self._chunk_lanes(
             tokens, positions, carry, overrides)
-        out, tok, pos, done, self.cache = fn(
+        out, tok, pos, done, self.cache, stats = fn(
             self.params, self.cache,
             tok_in, pos_in,
             self._batch_arr(block_tables, jnp.int32),
@@ -2135,7 +2175,7 @@ class JaxExecutor:
             self._batch_arr(budgets, jnp.int32),
             done_in,
             self._next_key())
-        return ChunkHandle(out, tok, pos, done)
+        return ChunkHandle(out, tok, pos, done, stats)
 
     def decode_chunk(self, tokens: np.ndarray, positions: np.ndarray,
                      block_tables: np.ndarray, temperatures: np.ndarray,
@@ -2239,7 +2279,7 @@ class JaxExecutor:
         fn = self._aot.get("mixed_chunk", self._mixed_chunk)
         tok_in, pos_in, done_in = self._chunk_lanes(
             tokens, positions, carry, overrides)
-        out, tok, pos, done, pf_first, self.cache = fn(
+        out, tok, pos, done, pf_first, self.cache, stats = fn(
             self.params, self.cache,
             tok_in, pos_in,
             self._batch_arr(block_tables, jnp.int32),
@@ -2250,7 +2290,7 @@ class JaxExecutor:
             jnp.asarray(pf_lens), jnp.asarray(pf_bts),
             jnp.asarray(pf_temps),
             self._next_key())
-        return MixedChunkHandle(out, tok, pos, done, pf_first)
+        return MixedChunkHandle(out, tok, pos, done, pf_first, stats)
 
     # -- tiered KV page transport (llmq_tpu/tiering/, docs/tiering.md) --------
 

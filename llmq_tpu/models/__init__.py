@@ -1,9 +1,85 @@
+"""The model families behind ``model.name``, and the one place that
+knows which there are.
+
+A family is a module of this package that defines
+
+- ``MODEL_CONFIGS``: ``{name: factory(**overrides) -> config}``; a
+  config is a frozen dataclass with a class attribute ``FAMILY`` (the
+  key below) and at least ``name``, ``vocab_size``, ``n_layers``,
+  ``max_seq_len`` and ``dtype``;
+- parameters: ``init_params``, ``init_params_quantized``,
+  ``param_count``, ``param_count_analytic``, ``active_param_count``
+  (what one token multiplies with: the MFU estimate's count),
+  ``weight_bytes``;
+- the cache: ``init_kv_pages`` (a dict of ``(L, P, page_size, ...)``
+  leaves, page 0 reserved: the executor, the allocator, the prefix
+  cache, tiering and disaggregation treat it as a pytree of such
+  leaves and never by key) and ``kv_bytes_per_token``;
+- the serving programs' model functions: ``forward_prefill``,
+  ``forward_decode``, ``forward_mixed``, ``forward_verify``, with
+  ``models/llama.py``'s signatures and returns (``forward_mixed``
+  returns of each slice the logits of its last valid position, (S, V):
+  the one serving samples);
+- ``serving_config(cfg)``: ``cfg`` as the forward-only serving
+  programs take it, and ``import_hf(model_dir, cfg, **kw)``: a local
+  Hugging Face checkpoint directory into the family's tree;
+- ``step_stats_size(cfg)``: how many int32 counters a forward pass
+  returns after the cache when called with ``stats=True`` (0: the
+  family counts nothing and takes no such argument);
+- ``routes(cfg, cache, *, batch, page_size, max_pages, decode,
+  prefill_rows)``: which implementation each attention op of a program
+  takes (``ops/attention.kernel_routes``'s form);
+- ``check_serving(cfg, *, quantization, kv_quantization, mesh,
+  speculation_draft_k)``: raises ``ValueError`` naming the setting for
+  what the family does not support.
+"""
+
+from __future__ import annotations
+
+import importlib
+from types import ModuleType
+from typing import Dict
+
 from llmq_tpu.models.llama import (  # noqa: F401
     LlamaConfig,
     MODEL_CONFIGS,
-    get_config,
     init_params,
     forward_prefill,
     forward_decode,
 )
 from llmq_tpu.models.checkpoint import save_checkpoint, load_checkpoint  # noqa: F401
+
+#: family -> its module. A model of a new family is a new entry here
+#: and a module that defines the surface above.
+FAMILIES: Dict[str, str] = {
+    "llama": "llmq_tpu.models.llama",
+    "deepseek_v3": "llmq_tpu.models.deepseek_v3",
+}
+
+
+def family(name: str) -> ModuleType:
+    try:
+        return importlib.import_module(FAMILIES[name])
+    except KeyError:
+        raise ValueError(f"unknown model family {name!r}; known: "
+                         f"{sorted(FAMILIES)}") from None
+
+
+def family_of(cfg) -> ModuleType:
+    """The family module of a model config."""
+    return family(cfg.FAMILY)
+
+
+def model_names() -> Dict[str, str]:
+    """Every registered ``model.name`` -> its family."""
+    return {name: fam for fam in FAMILIES
+            for name in family(fam).MODEL_CONFIGS}
+
+
+def get_config(name: str, **kw):
+    """The config of ``model.name`` with ``kw`` overriding its fields,
+    whatever its family."""
+    names = model_names()
+    if name not in names:
+        raise ValueError(f"unknown model {name!r}; known: {sorted(names)}")
+    return family(names[name]).MODEL_CONFIGS[name](**kw)

@@ -71,17 +71,7 @@ def import_hf_llama(model_dir: str, cfg: LlamaConfig,
     split-half rotary layout that ``ops/rope.apply_rope`` implements.
     Pass ``meta_rope_layout=True`` only for safetensors re-exports of
     Meta-original interleaved checkpoints."""
-    from safetensors import safe_open  # type: ignore[import-not-found]
-
-    files = sorted(f for f in os.listdir(model_dir)
-                   if f.endswith(".safetensors"))
-    if not files:
-        raise FileNotFoundError(f"no safetensors in {model_dir}")
-    tensors: Dict[str, np.ndarray] = {}
-    for fname in files:
-        with safe_open(os.path.join(model_dir, fname), framework="np") as f:
-            for key in f.keys():
-                tensors[key] = f.get_tensor(key)
+    tensors = _read_safetensors(model_dir)
 
     def get(name: str) -> np.ndarray:
         return tensors[name]
@@ -125,3 +115,129 @@ def import_hf_llama(model_dir: str, cfg: LlamaConfig,
         params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dtype=dt)
     log.info("imported HF llama from %s (%d tensors)", model_dir, len(tensors))
     return params
+
+
+def _read_safetensors(model_dir: str) -> Dict[str, np.ndarray]:
+    from safetensors import safe_open  # type: ignore[import-not-found]
+
+    files = sorted(f for f in os.listdir(model_dir)
+                   if f.endswith(".safetensors"))
+    if not files:
+        raise FileNotFoundError(f"no safetensors in {model_dir}")
+    tensors: Dict[str, np.ndarray] = {}
+    for fname in files:
+        with safe_open(os.path.join(model_dir, fname), framework="np") as f:
+            for key in f.keys():
+                tensors[key] = f.get_tensor(key)
+    return tensors
+
+
+def _deinterleave_rope(w: np.ndarray, n_heads: int,
+                       rope_dim: int) -> np.ndarray:
+    """Interleaved -> split-half rotary layout of the LAST ``rope_dim``
+    output rows of each of ``n_heads`` heads. ``deepseek_v3``
+    checkpoints keep the rotary pairs side by side (``rope_interleave``:
+    the public ``apply_rotary_pos_emb_interleave`` regroups q and k as
+    evens-then-odds before it rotates the halves); doing that once to
+    the projections' rows leaves every score as it was and lets
+    ``ops/rope.apply_rope`` serve both families. w: (n_heads * head_dim,
+    dim_in), (out, in) orientation."""
+    head_dim = w.shape[0] // n_heads
+    w = w.reshape(n_heads, head_dim, -1)
+    rope = w[:, head_dim - rope_dim:].reshape(
+        n_heads, rope_dim // 2, 2, -1).transpose(0, 2, 1, 3).reshape(
+        n_heads, rope_dim, -1)
+    return np.concatenate([w[:, :head_dim - rope_dim], rope],
+                          axis=1).reshape(n_heads * head_dim, -1)
+
+
+def import_hf_deepseek_v3(model_dir: str, cfg) -> Params:
+    """A local Hugging Face ``deepseek_v3`` checkpoint directory
+    (safetensors) into ``models/deepseek_v3.py``'s tree
+    (``param_shapes``). The tensor names are from memory of the public
+    ``modeling_deepseek_v3.py`` (this sandbox has no network; the test
+    builds a synthetic checkpoint under the same names):
+    ``self_attn.{q_proj, kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj,
+    o_proj}``, ``mlp.{gate,up,down}_proj`` in the dense layers, and in
+    the routed ones ``mlp.gate.weight``,
+    ``mlp.gate.e_score_correction_bias``, ``mlp.experts.N.*`` and
+    ``mlp.shared_experts.*``. q_proj's and kv_a_proj_with_mqa's rotary
+    rows are de-interleaved (``_deinterleave_rope``); each expert's gate
+    and up matrices are laid side by side (``we_gate_up``), a leaf a
+    routed layer."""
+    t = _read_safetensors(model_dir)
+    dt, dr = cfg.dtype, cfg.qk_rope_head_dim
+    Ld, E = cfg.first_k_dense, cfg.n_routed_experts
+
+    def stack(layers, fmt: str, transform=None) -> jnp.ndarray:
+        mats = []
+        for i in layers:
+            w = t[fmt.format(i=i)]
+            mats.append((transform(w) if transform else w).T)
+        return jnp.asarray(np.stack(mats), dtype=dt)
+
+    def vec(layers, fmt: str, dtype=dt) -> jnp.ndarray:
+        return jnp.asarray(np.stack([t[fmt.format(i=i)] for i in layers]),
+                           dtype=dtype)
+
+    every = range(cfg.n_layers)
+    dense, routed = range(Ld), range(Ld, cfg.n_layers)
+    attn, mlp = "model.layers.{i}.self_attn.", "model.layers.{i}.mlp."
+
+    def experts(i: int) -> tuple:
+        pre = f"model.layers.{i}.mlp.experts."
+        gu = np.stack([np.concatenate(
+            [t[f"{pre}{e}.gate_proj.weight"].T,
+             t[f"{pre}{e}.up_proj.weight"].T], axis=1) for e in range(E)])
+        down = np.stack([t[f"{pre}{e}.down_proj.weight"].T
+                         for e in range(E)])
+        return gu, down
+
+    per_layer = [experts(i) for i in routed]
+    params: Params = {
+        "embed": jnp.asarray(t["model.embed_tokens.weight"], dtype=dt),
+        "lm_head": jnp.asarray(t["lm_head.weight"].T, dtype=dt),
+        "final_norm": jnp.asarray(t["model.norm.weight"], dtype=dt),
+        "layers": {
+            "wq": stack(every, attn + "q_proj.weight",
+                        lambda w: _deinterleave_rope(w, cfg.n_heads, dr)),
+            "wkv_a": stack(every, attn + "kv_a_proj_with_mqa.weight",
+                           lambda w: _deinterleave_rope(w, 1, dr)),
+            "kv_norm": vec(every, attn + "kv_a_layernorm.weight"),
+            "wkv_b": stack(every, attn + "kv_b_proj.weight"),
+            "wo": stack(every, attn + "o_proj.weight"),
+            "attn_norm": vec(every,
+                             "model.layers.{i}.input_layernorm.weight"),
+            "mlp_norm": vec(
+                every, "model.layers.{i}.post_attention_layernorm.weight"),
+        },
+        "dense": {"w_gate": stack(dense, mlp + "gate_proj.weight"),
+                  "w_up": stack(dense, mlp + "up_proj.weight"),
+                  "w_down": stack(dense, mlp + "down_proj.weight")},
+        "moe": {
+            "router": stack(routed, mlp + "gate.weight"),
+            "router_bias": vec(routed,
+                               mlp + "gate.e_score_correction_bias",
+                               jnp.float32),
+            "we_gate_up": tuple(jnp.asarray(g, dtype=dt)
+                                for g, _ in per_layer),
+            "we_down": tuple(jnp.asarray(d, dtype=dt)
+                             for _, d in per_layer),
+            "ws_gate": stack(routed, mlp + "shared_experts.gate_proj.weight"),
+            "ws_up": stack(routed, mlp + "shared_experts.up_proj.weight"),
+            "ws_down": stack(routed,
+                             mlp + "shared_experts.down_proj.weight"),
+        },
+    }
+    log.info("imported HF deepseek_v3 from %s (%d tensors)", model_dir,
+             len(t))
+    return params
+
+
+def import_hf(model_dir: str, cfg, **kw) -> Params:
+    """A local Hugging Face checkpoint directory into the tree of
+    ``cfg``'s model family, by that family's ``import_hf``
+    (``models/__init__.py``); ``kw`` is the family's own (the Llama
+    block's ``meta_rope_layout``)."""
+    from llmq_tpu.models import family_of
+    return family_of(cfg).import_hf(model_dir, cfg, **kw)

@@ -1,0 +1,679 @@
+"""The DeepSeek-V3 block in pure JAX (``model_type: deepseek_v3``):
+multi-head LATENT attention over a latent page pool, and a routed
+feed-forward with shared experts. Kanana-2-30B-A3B is this block at
+hidden 2,048 with 128 experts of 768, 6 a token, and 2 shared.
+
+The same conventions as ``models/llama.py`` (stacked layer parameters,
+unrolled layer loops around pool-aliasing kernels, a paged pool indexed
+by block tables, page 0 reserved, bf16 weights and matmul operands with
+float32 norms and softmax) and the same serving surface
+(``forward_prefill`` / ``forward_decode`` / ``forward_mixed``,
+``init_kv_pages``), so the executor's programs serve either family
+(``models/__init__.py`` has the registry and the surface).
+
+Layer equations (x: the layer's input after its RMSNorm; h: a head):
+
+- q_h = x W_q split [q_h^nope (128) ; q_h^rope (64)], RoPE on the rope
+  part. [c' ; k'] = x W_kva; c = RMSNorm(c') and k^rope = RoPE(k'), ONE
+  for all heads. **The cache holds (c, k^rope)**: ``kv_lora_rank +
+  qk_rope_head_dim`` = 576 values a token a layer, 1,152 B in bf16.
+  [k_h^nope ; v_h] = c W_kvb,h; score_h(t, s) = (q_h^nope . k_h^nope(s)
+  + q_h^rope . k^rope(s)) / sqrt(192); o_h = sum_s p_h(t, s) v_h(s).
+- **Decode is ABSORBED**: q~_h = q_h^nope (W_kvb,h^K)^T (512 wide),
+  score = (q~_h . c(s) + q_h^rope . k^rope(s)) / sqrt(192), o~_h =
+  sum_s p c(s), o_h = o~_h W_kvb,h^V — all heads read the same 576
+  values of a token and K/V are never expanded
+  (``ops/pallas/latent_decode.py``). Prefill EXPANDS K/V from the
+  cached latents and runs under XLA (a kernel for it is later work).
+- **The pool's one leaf** ``"ckv"`` is ``(L, P, page_size, W)`` with W
+  = 576 rounded up to 128 lanes = 640: ``[c | k^rope | zeros]``. One
+  leaf, because a score is then one contraction of a row with
+  ``[q~ | q^rope | 0]`` and a page one DMA; padded, because a 576-lane
+  row is tiled to 640 lanes in HBM and VMEM whatever its shape says,
+  so the 64 lanes cost what they cost and a 640-lane leaf says so. The
+  64 lanes of zeros are not counted in the published 1,152 B a token
+  a layer (``kv_bytes_per_token``); in HBM a row is ``latent_width``
+  values.
+- RoPE in the split-half layout (``ops/rope.py``), as everywhere in
+  this repo: the published checkpoints interleave the pairs
+  (``rope_interleave``), and the loader (``models/checkpoint.py``)
+  permutes W_q's and W_kva's rope columns once, which leaves every
+  score unchanged.
+- Routed layer (``ops/moe.py``): s = sigmoid(x W_r) in float32, the 6
+  experts the top 6 of s + b (b chooses only), g = 2.448 s_i / sum of
+  the chosen s, y = sum g_i SwiGLU_i(x) + SwiGLU_shared(x). The first
+  ``first_k_dense`` layers have a dense SwiGLU instead.
+
+The residual stream is float32 here, and the router reads the float32
+normalised activations: a routed layer's top-6 is a discontinuity, and
+each rounding of the stream to bf16 (0.2-0.4 % of it) is as wide as a
+tenth of the mean gap between a token's 6th and 7th score. The stream
+is a few kilobytes a token; the matmuls' operands stay bf16.
+
+``q_lora_rank`` (a low-rank query), int8 weights, an int8 cache, a mesh
+and speculation's verify window are not written for this family: each
+is refused with an error that names the setting (``check_serving``).
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, replace
+from functools import partial
+from typing import Any, ClassVar, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+
+from llmq_tpu.ops.moe import route, routed_ffn
+from llmq_tpu.ops.norms import rms_norm
+from llmq_tpu.ops.quant import embed_lookup
+from llmq_tpu.ops.rope import apply_rope, rope_cos_sin
+
+Params = Dict[str, Any]
+KVCache = Dict[str, jnp.ndarray]
+NEG = -1e30
+
+
+@dataclass(frozen=True)
+class DeepseekV3Config:
+    FAMILY: ClassVar[str] = "deepseek_v3"
+    name: str = "deepseek-v3-tiny"
+    vocab_size: int = 512
+    dim: int = 128
+    n_layers: int = 3
+    n_heads: int = 4
+    kv_lora_rank: int = 128
+    qk_nope_head_dim: int = 32
+    qk_rope_head_dim: int = 16
+    v_head_dim: int = 32
+    q_lora_rank: Optional[int] = None
+    ffn_dim: int = 256                 # the dense layers' SwiGLU
+    moe_ffn_dim: int = 64              # one expert's SwiGLU
+    n_routed_experts: int = 16
+    n_shared_experts: int = 1
+    n_experts_per_tok: int = 4
+    first_k_dense: int = 1
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    max_seq_len: int = 2048
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self) -> None:
+        if self.q_lora_rank is not None:
+            raise ValueError(
+                f"model {self.name!r}: q_lora_rank={self.q_lora_rank} (a "
+                f"low-rank query projection) is not supported; only "
+                f"q_lora_rank null is")
+        if not 0 <= self.first_k_dense <= self.n_layers:
+            raise ValueError(f"model {self.name!r}: first_k_dense "
+                             f"{self.first_k_dense} of {self.n_layers}")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def n_routed_layers(self) -> int:
+        return self.n_layers - self.first_k_dense
+
+    @property
+    def latent_width(self) -> int:
+        """Lanes of one cached row: the latent and the RoPE key, rounded
+        up to whole 128-lane tiles."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+
+def deepseek_v3_tiny(**kw) -> DeepseekV3Config:
+    return replace(DeepseekV3Config(), **kw)
+
+
+def kanana_2_30b_a3b(**kw) -> DeepseekV3Config:
+    """kakaocorp/kanana-2-30b-a3b-instruct-2601 at its published sizes
+    (https://huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601/
+    blob/main/config.json): 48 layers (the first dense, SwiGLU 6,144),
+    hidden 2,048, 32 heads of 128 + 64 over a latent of 512, values of
+    128, 128 routed experts of 768 with 6 a token and 2 shared, sigmoid
+    scores scaled 2.448, vocabulary 128,256 untied, RoPE theta 1e6,
+    context 32,768. 30.67 B parameters, 61 GB in bf16: one 16 GB chip
+    serves a cut in depth (benchmark/configs/kanana-2-30b-a3b-bf16.json
+    holds 8 layers of it with every width and all 128 experts)."""
+    return replace(DeepseekV3Config(
+        name="kanana-2-30b-a3b", vocab_size=128256, dim=2048, n_layers=48,
+        n_heads=32, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, ffn_dim=6144, moe_ffn_dim=768,
+        n_routed_experts=128, n_shared_experts=2, n_experts_per_tok=6,
+        first_k_dense=1, routed_scaling_factor=2.448, norm_topk_prob=True,
+        max_seq_len=32768, rope_theta=1000000.0, norm_eps=1e-6), **kw)
+
+
+MODEL_CONFIGS = {
+    "deepseek-v3-tiny": deepseek_v3_tiny,
+    "kanana-2-30b-a3b": kanana_2_30b_a3b,
+}
+
+
+def serving_config(cfg: DeepseekV3Config) -> DeepseekV3Config:
+    """``cfg`` for the forward-only serving programs: as it is."""
+    return cfg
+
+
+def import_hf(model_dir: str, cfg: DeepseekV3Config,
+              meta_rope_layout: bool = False) -> Params:
+    """A local Hugging Face checkpoint directory into this family's
+    tree (``models/checkpoint.import_hf_deepseek_v3``)."""
+    if meta_rope_layout:
+        raise ValueError("model.meta_rope_layout is the Llama block's "
+                         "(Meta's .pth layout); the family deepseek_v3 "
+                         "has its own rotary permutation")
+    from llmq_tpu.models.checkpoint import import_hf_deepseek_v3
+    return import_hf_deepseek_v3(model_dir, cfg)
+
+
+def step_stats_size(cfg: DeepseekV3Config) -> int:
+    """int32 counters a forward pass returns with ``stats=True``: the
+    tokens each expert received (E), the experts that received any
+    summed over the routed layers, and the routed layers run."""
+    return cfg.n_routed_experts + 2
+
+
+def check_serving(cfg: DeepseekV3Config, *, quantization: str = "",
+                  kv_quantization: str = "", mesh: bool = False,
+                  speculation_draft_k: int = 0) -> None:
+    """Refuse what is not written for this family, naming the setting."""
+    what = None
+    if quantization:
+        what = f"model.quantization={quantization!r} (int8 experts)"
+    elif kv_quantization:
+        what = f"model.kv_quantization={kv_quantization!r} (an int8 latent)"
+    elif mesh:
+        what = "executor.mesh (no partition rules for latents or experts)"
+    elif speculation_draft_k > 0:
+        what = (f"executor.speculation.draft_k={speculation_draft_k} "
+                f"(no verify window)")
+    if what:
+        raise ValueError(f"model {cfg.name!r} (family deepseek_v3) does "
+                         f"not support {what}; unset it")
+
+
+# -- parameters ---------------------------------------------------------------
+
+def param_shapes(cfg: DeepseekV3Config) -> Dict[str, Dict[str, tuple]]:
+    """Leaf name -> (shape, fan_in) by group: the tree's layout in one
+    place (init, the loader and the benchmark's builder follow it).
+    Every leaf is stacked over its layers, except the group
+    ``experts``: a routed layer's expert matrices are a leaf OF THEIR
+    OWN, one a routed layer (``params["moe"]["we_gate_up"]`` is a tuple
+    of them) — a slice of a stacked 5.6 GB leaf handed to the grouped
+    product was copied, 0.8 GB a layer of temporaries."""
+    L, D, H, V = cfg.n_layers, cfg.dim, cfg.n_heads, cfg.vocab_size
+    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    Ld, Lm = cfg.first_k_dense, cfg.n_routed_layers
+    E, Fe, F = cfg.n_routed_experts, cfg.moe_ffn_dim, cfg.ffn_dim
+    Fs = cfg.n_shared_experts * Fe
+    return {
+        "layers": {"wq": ((L, D, H * (dn + dr)), D),
+                   "wkv_a": ((L, D, r + dr), D),
+                   "wkv_b": ((L, r, H * (dn + dv)), r),
+                   "wo": ((L, H * dv, D), H * dv)},
+        "dense": {"w_gate": ((Ld, D, F), D), "w_up": ((Ld, D, F), D),
+                  "w_down": ((Ld, F, D), F)},
+        "moe": {"router": ((Lm, D, E), D),
+                "ws_gate": ((Lm, D, Fs), D), "ws_up": ((Lm, D, Fs), D),
+                "ws_down": ((Lm, Fs, D), Fs)},
+        "experts": {"we_gate_up": ((E, D, 2 * Fe), D),
+                    "we_down": ((E, Fe, D), Fe)},
+        "top": {"embed": ((V, D), D), "lm_head": ((D, V), D)},
+    }
+
+
+def norm_leaves(cfg: DeepseekV3Config) -> Params:
+    """The tree's RMSNorm weights (ones) and the router's selection
+    bias (zeros, float32): what a random init does not draw."""
+    L, D, Lm = cfg.n_layers, cfg.dim, cfg.n_routed_layers
+    return {"layers": {"attn_norm": jnp.ones((L, D), cfg.dtype),
+                       "mlp_norm": jnp.ones((L, D), cfg.dtype),
+                       "kv_norm": jnp.ones((L, cfg.kv_lora_rank),
+                                           cfg.dtype)},
+            "moe": {"router_bias": jnp.zeros((Lm, cfg.n_routed_experts),
+                                             jnp.float32)},
+            "final_norm": jnp.ones((D,), cfg.dtype)}
+
+
+def assemble(cfg: DeepseekV3Config, drawn: Dict[str, Dict[str, Any]]
+             ) -> Params:
+    """``param_shapes``-shaped groups of arrays (``experts``: a list
+    of one array a routed layer under each name) + ``norm_leaves`` ->
+    the parameter tree."""
+    fixed = norm_leaves(cfg)
+    return {"embed": drawn["top"]["embed"],
+            "lm_head": drawn["top"]["lm_head"],
+            "final_norm": fixed["final_norm"],
+            "layers": {**drawn["layers"], **fixed["layers"]},
+            "dense": dict(drawn["dense"]),
+            "moe": {**drawn["moe"], **fixed["moe"],
+                    **{k: tuple(v) for k, v in drawn["experts"].items()}}}
+
+
+def init_params(key: jax.Array, cfg: DeepseekV3Config) -> Params:
+    """Random-init parameter tree, N(0, 1 / fan_in) as the Llama
+    block's."""
+    shapes = param_shapes(cfg)
+    n = sum(len(g) for g in shapes.values())
+    keys = iter(jax.random.split(key, n))
+
+    def draw(k, shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * fan_in ** -0.5).astype(cfg.dtype)
+
+    drawn = {g: {name: draw(next(keys), shape, fan_in)
+                 for name, (shape, fan_in) in leaves.items()}
+             for g, leaves in shapes.items() if g != "experts"}
+    drawn["experts"] = {
+        name: [draw(k, shape, fan_in)
+               for k in jax.random.split(next(keys), cfg.n_routed_layers)]
+        for name, (shape, fan_in) in shapes["experts"].items()}
+    return assemble(cfg, drawn)
+
+
+def init_params_quantized(key: jax.Array, cfg: DeepseekV3Config) -> Params:
+    check_serving(cfg, quantization="int8")
+
+
+def param_count(params: Params) -> int:
+    return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
+
+
+def param_count_analytic(cfg: DeepseekV3Config) -> int:
+    """Parameters HELD, from the configuration alone."""
+    n = sum(_prod(shape) * (cfg.n_routed_layers if g == "experts" else 1)
+            for g, leaves in param_shapes(cfg).items()
+            for shape, _f in leaves.values())
+    fixed = (cfg.n_layers * (2 * cfg.dim + cfg.kv_lora_rank) + cfg.dim
+             + cfg.n_routed_layers * cfg.n_routed_experts)
+    return n + fixed
+
+
+def active_param_count(cfg: DeepseekV3Config) -> int:
+    """Parameters one token multiplies with: the held count less the
+    experts it is not routed to (the MFU estimate's numerator)."""
+    idle = cfg.n_routed_experts - cfg.n_experts_per_tok
+    return (param_count_analytic(cfg)
+            - cfg.n_routed_layers * idle * 3 * cfg.dim * cfg.moe_ffn_dim)
+
+
+def _prod(shape) -> int:
+    n = 1
+    for d in shape:
+        n *= d
+    return n
+
+
+def weight_bytes(cfg: DeepseekV3Config) -> int:
+    return param_count_analytic(cfg) * jnp.dtype(cfg.dtype).itemsize
+
+
+def kv_bytes_per_token(cfg: DeepseekV3Config,
+                       cache_dtype: Optional[Any] = None) -> int:
+    """The published cost of one cached token across the layers held:
+    the latent and the RoPE key, without the pool's lane padding."""
+    itemsize = jnp.dtype(cache_dtype or cfg.dtype).itemsize
+    return (cfg.n_layers * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+            * itemsize)
+
+
+def init_kv_pages(cfg: DeepseekV3Config, num_pages: int, page_size: int,
+                  dtype: Optional[Any] = None) -> KVCache:
+    """The latent page pool: ONE leaf ``"ckv"`` ``(L, P, page_size,
+    latent_width)`` (the module's docstring has the layout), page 0
+    reserved as in every pool of this repo."""
+    if dtype is not None and jnp.dtype(dtype) == jnp.int8:
+        check_serving(cfg, kv_quantization="int8")
+    return {"ckv": jnp.zeros((cfg.n_layers, num_pages, page_size,
+                              cfg.latent_width), dtype or cfg.dtype)}
+
+
+# -- kernel routes ------------------------------------------------------------
+
+def _route(cfg: DeepseekV3Config, page_size: int):
+    """(use the latent kernels, interpret) at this geometry: the
+    shared LLMQ_PALLAS policy, plus what the kernels need of the
+    shapes."""
+    from llmq_tpu.ops.attention import _kernel_route
+    ok = cfg.kv_lora_rank % 128 == 0 and page_size % 8 == 0
+    return _kernel_route(cfg.latent_width, extra_ok=ok)
+
+
+def routes(cfg: DeepseekV3Config, cache: KVCache, *, batch: int,
+           page_size: int, max_pages: int, decode: bool = False,
+           prefill_rows: int = 0) -> Dict[str, str]:
+    """Which implementation each attention op of one serving program
+    takes (``ops/attention.kernel_routes``'s form): the latent decode
+    kernel with its plan, and XLA for prefill."""
+    from llmq_tpu.ops.pallas.latent_decode import pages_per_chunk
+    out: Dict[str, str] = {}
+    if prefill_rows:
+        out["prefill_write"] = out["prefill_attention"] = "xla"
+    if decode:
+        use, interp = _route(cfg, page_size)
+        tag = f"pallas{'-interpret' if interp else ''}:"
+        chunk = pages_per_chunk(page_size, max_pages) * page_size
+        out["decode_write"] = (tag + "_latent_write_kernel" if use
+                               else "xla")
+        out["decode_attention"] = (
+            f"{tag}_latent_decode_kernel(rows=1,chunk_tokens={chunk})"
+            if use else "xla")
+    return out
+
+
+def _jit_latent(name: str):
+    from llmq_tpu.ops.attention import _kernel_jit
+
+    def make():
+        from llmq_tpu.ops.pallas import latent_decode
+        if name == "latent_write":
+            return jax.jit(latent_decode.latent_write_pallas,
+                           static_argnames=("interpret",))
+        return jax.jit(latent_decode.latent_decode_attention_pallas,
+                       static_argnames=("rank", "interpret"))
+    return _kernel_jit(name, make)
+
+
+# -- attention ----------------------------------------------------------------
+
+def _mlp(x, w_gate, w_up, w_down):
+    g = jnp.dot(x, w_gate)
+    return jnp.dot(jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)
+                   * jnp.dot(x, w_up), w_down)
+
+
+def _qkv(cfg: DeepseekV3Config, lp: Params, l: int, x, cos, sin):
+    """x (..., T, D) normed -> q_nope (..., T, H, dn), q_rope
+    (..., T, H, dr) rotated, row (..., T, W): the cache's row."""
+    r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = jnp.dot(x, lp["wq"][l]).reshape(x.shape[:-1] + (cfg.n_heads,
+                                                        dn + dr))
+    q_rope = apply_rope(q[..., dn:], cos, sin)
+    kva = jnp.dot(x, lp["wkv_a"][l])
+    c = rms_norm(kva[..., :r], lp["kv_norm"][l], cfg.norm_eps)
+    k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
+    pad = jnp.zeros(x.shape[:-1] + (cfg.latent_width - r - dr,), c.dtype)
+    return q[..., :dn], q_rope, jnp.concatenate([c, k_rope, pad], axis=-1)
+
+
+def _wkv_b(cfg: DeepseekV3Config, lp: Params, l: int):
+    w = lp["wkv_b"][l].reshape(cfg.kv_lora_rank, cfg.n_heads,
+                               cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def latent_decode_attention(cfg: DeepseekV3Config, lp: Params, l: int,
+                            q_nope, q_rope, row, pool, block_tables,
+                            seq_lens, page_of, slot_of):
+    """One decode step's attention of layer ``l`` in the ABSORBED form:
+    write each row's new cache row, then attend over the cached rows.
+    q_nope (B, H, dn), q_rope (B, H, dr), row (B, W); ``seq_lens`` 0
+    marks a row that is not live (its output is 0, its write went to
+    page 0). Returns (o (B, H * dv), pool)."""
+    B, r = q_nope.shape[0], cfg.kv_lora_rank
+    wk, wv = _wkv_b(cfg, lp, l)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wk)
+    pad = jnp.zeros((B, cfg.n_heads,
+                     cfg.latent_width - r - cfg.qk_rope_head_dim),
+                    q_lat.dtype)
+    scale = cfg.qk_head_dim ** -0.5
+    q_cat = (jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+             .astype(jnp.float32) * scale).astype(pool.dtype)
+    use, interp = _route(cfg, pool.shape[2])
+    if use:
+        pool = _jit_latent("latent_write")(pool, row, page_of, slot_of,
+                                           jnp.int32(l), interpret=interp)
+        o_lat = _jit_latent("latent_decode")(
+            q_cat, pool, block_tables, seq_lens, jnp.int32(l), rank=r,
+            interpret=interp)
+    else:
+        pool = pool.at[l, page_of, slot_of].set(row.astype(pool.dtype))
+        rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
+        s = jnp.einsum("bhw,bsw->bhs", q_cat, rows,
+                       preferred_element_type=jnp.float32)
+        live = (jnp.arange(rows.shape[1])[None, :]
+                < seq_lens[:, None])[:, None, :]
+        p = jnp.where(live, jax.nn.softmax(jnp.where(live, s, NEG), -1), 0.0)
+        o_lat = jnp.einsum("bhs,bsr->bhr", p.astype(rows.dtype),
+                           rows[..., :r],
+                           preferred_element_type=jnp.float32)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cfg.dtype), wv)
+    return o.reshape(B, -1), pool
+
+
+def latent_write_prefill(pool, rows, block_tables, positions, lengths,
+                         l: int):
+    """Write the rows of B contiguous slices (rows (B, T, W), the first
+    ``lengths`` of each valid, starting at ``positions[:, 0]``) into
+    layer ``l`` of the pool, a PAGE at a time: every page a slice
+    touches is read, merged and written once (an XLA scatter pays by
+    the index, so by the page here and not by the token). Pages no
+    valid token touches go to reserved page 0."""
+    B, T, W = rows.shape
+    ps, mp = pool.shape[2], block_tables.shape[1]
+    n_pages = -(-T // ps) + 1
+    p0 = positions[:, 0]
+    src = jnp.arange(n_pages * ps)[None, :] - (p0 % ps)[:, None]
+    valid = (src >= 0) & (src < lengths[:, None])          # (B, NP*ps)
+    buf = jnp.take_along_axis(rows, jnp.clip(src, 0, T - 1)[..., None],
+                              axis=1)
+    idx = (p0 // ps)[:, None] + jnp.arange(n_pages)[None, :]
+    pages = jnp.take_along_axis(block_tables, jnp.clip(idx, 0, mp - 1),
+                                axis=1)
+    valid = valid.reshape(B, n_pages, ps)
+    pages = jnp.where(valid.any(-1) & (idx < mp), pages, 0)
+    merged = jnp.where(valid[..., None],
+                       buf.reshape(B, n_pages, ps, W).astype(pool.dtype),
+                       pool[l, pages])
+    return pool.at[l, pages.reshape(-1)].set(
+        merged.reshape(B * n_pages, ps, W))
+
+
+def latent_prefill_attention(cfg: DeepseekV3Config, lp: Params, l: int,
+                             q_nope, q_rope, pool, block_tables, positions,
+                             seq_lens):
+    """Prefill attention of layer ``l``, UNABSORBED, under XLA: K and V
+    are expanded from each row's cached latents (its whole block-table
+    window, the new tokens already written), causal by absolute
+    position. q_* (B, T, H, .). Returns (B, T, H * dv)."""
+    B, T = q_nope.shape[:2]
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
+    wk, wv = _wkv_b(cfg, lp, l)
+    k_nope = jnp.einsum("bsr,rhn->bshn", rows[..., :r], wk)
+    v = jnp.einsum("bsr,rhv->bshv", rows[..., :r], wv)
+    s = (jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bthr,bsr->bhts", q_rope, rows[..., r:r + dr],
+                      preferred_element_type=jnp.float32))
+    key_pos = jnp.arange(rows.shape[1])
+    mask = ((key_pos[None, None, :] <= positions[:, :, None])
+            & (key_pos[None, None, :] < seq_lens[:, None, None]))
+    p = jax.nn.softmax(jnp.where(mask[:, None], s * cfg.qk_head_dim ** -0.5,
+                                 NEG), axis=-1)
+    o = jnp.einsum("bhts,bshv->bthv", p.astype(v.dtype), v)
+    return o.reshape(B, T, -1)
+
+
+# -- feed-forward -------------------------------------------------------------
+
+def _ffn(params: Params, cfg: DeepseekV3Config, l: int, x, live):
+    """Layer ``l``'s feed-forward over tokens x (N, D), normalised and
+    float32 (what the router reads; the products take it in
+    ``cfg.dtype``): dense for the first ``first_k_dense`` layers,
+    routed + shared after. Returns
+    (y, stats or None): ``ops/moe.routed_ffn``'s counts."""
+    xf, x = x, x.astype(cfg.dtype)
+    if l < cfg.first_k_dense:
+        d = params["dense"]
+        return _mlp(x, d["w_gate"][l], d["w_up"][l], d["w_down"][l]), None
+    m, i = params["moe"], l - cfg.first_k_dense
+    experts, gates = route(
+        xf, m["router"][i], m["router_bias"][i],
+        top_k=cfg.n_experts_per_tok, scale=cfg.routed_scaling_factor,
+        norm_topk=cfg.norm_topk_prob)
+    y, st = routed_ffn(x, experts, gates, m["we_gate_up"][i],
+                       m["we_down"][i], live)
+    return y + _mlp(x, m["ws_gate"][i], m["ws_up"][i], m["ws_down"][i]), st
+
+
+def _sum_stats(cfg: DeepseekV3Config, per_layer) -> jnp.ndarray:
+    """One forward pass's counters (``step_stats_size``): the routed
+    layers' counts summed, then how many routed layers ran."""
+    got = [st for st in per_layer if st is not None]
+    total = sum(got, jnp.zeros((cfg.n_routed_experts + 1,), jnp.int32))
+    return jnp.concatenate([total, jnp.full((1,), len(got), jnp.int32)])
+
+
+def _finish(params, h, cfg):
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
+    return jnp.dot(h, params["lm_head"]).astype(jnp.float32)
+
+
+# -- forward ------------------------------------------------------------------
+
+@partial(jax.jit, static_argnames=("cfg", "last_only", "stats"))
+def forward_prefill(params: Params, cfg: DeepseekV3Config, tokens,
+                    positions, lengths, kv_cache: KVCache, block_tables,
+                    last_only: bool = False, stats: bool = False):
+    """``models/llama.forward_prefill``'s contract (right-padded rows,
+    contiguous absolute ``positions``, continuation over cached pages
+    through the block tables) over the latent pool. Returns (logits,
+    cache), and the routed layers' counts after them with ``stats``."""
+    B, T = tokens.shape
+    h = embed_lookup(params["embed"], tokens, jnp.float32)
+    cos, sin = rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    valid = jnp.arange(T)[None, :] < lengths[:, None]
+    seq_lens = jnp.max(jnp.where(valid, positions, -1), axis=1) + 1
+    lp, pool, counts = params["layers"], kv_cache["ckv"], []
+    for l in range(cfg.n_layers):
+        x = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps).astype(cfg.dtype)
+        q_nope, q_rope, row = _qkv(cfg, lp, l, x, cos, sin)
+        pool = latent_write_prefill(pool, row, block_tables, positions,
+                                    lengths, l)
+        attn = latent_prefill_attention(cfg, lp, l, q_nope, q_rope, pool,
+                                        block_tables, positions, seq_lens)
+        h = h + jnp.dot(attn, lp["wo"][l])
+        x = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
+        y, st = _ffn(params, cfg, l, x.reshape(B * T, -1),
+                     valid.reshape(-1))
+        counts.append(st)
+        h = h + y.reshape(B, T, -1)
+    if last_only:
+        h = h[jnp.arange(B), lengths - 1]
+    out = (_finish(params, h, cfg), {"ckv": pool})
+    return out + (_sum_stats(cfg, counts),) if stats else out
+
+
+def _decode_geometry(positions, block_tables, page_size, active):
+    B = positions.shape[0]
+    page_of = block_tables[jnp.arange(B), positions // page_size]
+    seq_lens = positions + 1
+    if active is not None:
+        page_of = jnp.where(active, page_of, 0)
+        seq_lens = jnp.where(active, seq_lens, 0)
+    return page_of, positions % page_size, seq_lens
+
+
+@partial(jax.jit, static_argnames=("cfg", "stats"))
+def forward_decode(params: Params, cfg: DeepseekV3Config, tokens, positions,
+                   kv_cache: KVCache, block_tables, active=None,
+                   stats: bool = False):
+    """One decode step for every active row
+    (``models/llama.forward_decode``'s contract). A row that is not
+    active writes to page 0, attends to nothing and is routed to no
+    expert; its logits mean nothing."""
+    pool = kv_cache["ckv"]
+    h = embed_lookup(params["embed"], tokens, jnp.float32)  # (B, D)
+    cos, sin = rope_cos_sin(positions[:, None], cfg.qk_rope_head_dim,
+                            cfg.rope_theta)
+    page_of, slot_of, seq_lens = _decode_geometry(
+        positions, block_tables, pool.shape[2], active)
+    lp, counts = params["layers"], []
+    for l in range(cfg.n_layers):
+        x = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps).astype(cfg.dtype)
+        q_nope, q_rope, row = _qkv(cfg, lp, l, x[:, None], cos, sin)
+        attn, pool = latent_decode_attention(
+            cfg, lp, l, q_nope[:, 0], q_rope[:, 0], row[:, 0], pool,
+            block_tables, seq_lens, page_of, slot_of)
+        h = h + jnp.dot(attn, lp["wo"][l])
+        x = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
+        y, st = _ffn(params, cfg, l, x, active)
+        counts.append(st)
+        h = h + y
+    out = (_finish(params, h, cfg), {"ckv": pool})
+    return out + (_sum_stats(cfg, counts),) if stats else out
+
+
+def forward_verify(params, cfg: DeepseekV3Config, *args, **kw):
+    check_serving(cfg, speculation_draft_k=1)
+
+
+@partial(jax.jit, static_argnames=("cfg", "stats"))
+def forward_mixed(params: Params, cfg: DeepseekV3Config, dec_tokens,
+                  dec_positions, kv_cache: KVCache, dec_block_tables,
+                  pf_tokens, pf_positions, pf_lengths, pf_block_tables,
+                  dec_active=None, stats: bool = False):
+    """The fused mixed step (``models/llama.forward_mixed``'s
+    contract): B decode rows one token and S prefill slices of up to T
+    tokens in ONE traversal of the layers. Attention runs a layer's
+    slices and its decode rows apart (disjoint pages); the feed-forward
+    runs them TOGETHER, so a routed layer's experts are streamed once
+    for both. Returns (dec_logits (B, V), pf_logits (S, V), cache
+    [, counts]): of a slice only its LAST valid position is projected
+    — serving samples nothing else, and 1,024
+    slice tokens through a 128k-row head are 0.5 GB of float32 and
+    half a teraflop a mixed step."""
+    B = dec_tokens.shape[0]
+    S, T = pf_tokens.shape
+    pool = kv_cache["ckv"]
+    h_d = embed_lookup(params["embed"], dec_tokens, jnp.float32)
+    cos_d, sin_d = rope_cos_sin(dec_positions[:, None],
+                                cfg.qk_rope_head_dim, cfg.rope_theta)
+    page_of, slot_of, dec_seq_lens = _decode_geometry(
+        dec_positions, dec_block_tables, pool.shape[2], dec_active)
+    h_p = embed_lookup(params["embed"], pf_tokens, jnp.float32)
+    cos_p, sin_p = rope_cos_sin(pf_positions, cfg.qk_rope_head_dim,
+                                cfg.rope_theta)
+    pf_valid = jnp.arange(T)[None, :] < pf_lengths[:, None]
+    pf_seq_lens = jnp.max(jnp.where(pf_valid, pf_positions, -1), axis=1) + 1
+    live = jnp.concatenate(
+        [pf_valid.reshape(-1), (dec_active if dec_active is not None
+                                else jnp.ones((B,), jnp.bool_))])
+    lp, counts = params["layers"], []
+    for l in range(cfg.n_layers):
+        x_p = rms_norm(h_p, lp["attn_norm"][l],
+                       cfg.norm_eps).astype(cfg.dtype)
+        qn_p, qr_p, row_p = _qkv(cfg, lp, l, x_p, cos_p, sin_p)
+        pool = latent_write_prefill(pool, row_p, pf_block_tables,
+                                    pf_positions, pf_lengths, l)
+        attn_p = latent_prefill_attention(
+            cfg, lp, l, qn_p, qr_p, pool, pf_block_tables, pf_positions,
+            pf_seq_lens)
+        h_p = h_p + jnp.dot(attn_p, lp["wo"][l])
+        x_d = rms_norm(h_d, lp["attn_norm"][l],
+                       cfg.norm_eps).astype(cfg.dtype)
+        qn_d, qr_d, row_d = _qkv(cfg, lp, l, x_d[:, None], cos_d, sin_d)
+        attn_d, pool = latent_decode_attention(
+            cfg, lp, l, qn_d[:, 0], qr_d[:, 0], row_d[:, 0], pool,
+            dec_block_tables, dec_seq_lens, page_of, slot_of)
+        h_d = h_d + jnp.dot(attn_d, lp["wo"][l])
+        x = jnp.concatenate(
+            [rms_norm(h_p, lp["mlp_norm"][l], cfg.norm_eps).reshape(
+                S * T, -1),
+             rms_norm(h_d, lp["mlp_norm"][l], cfg.norm_eps)])
+        y, st = _ffn(params, cfg, l, x, live)
+        counts.append(st)
+        h_p = h_p + y[:S * T].reshape(S, T, -1)
+        h_d = h_d + y[S * T:]
+    h_p = h_p[jnp.arange(S), pf_lengths - 1]
+    out = (_finish(params, h_d, cfg), _finish(params, h_p, cfg),
+           {"ckv": pool})
+    return out + (_sum_stats(cfg, counts),) if stats else out

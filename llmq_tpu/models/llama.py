@@ -31,7 +31,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +54,7 @@ KVCache = Dict[str, jnp.ndarray]
 
 @dataclass(frozen=True)
 class LlamaConfig:
+    FAMILY: ClassVar[str] = "llama"        # models/__init__.py registry
     name: str = "llama3-tiny"
     vocab_size: int = 512
     dim: int = 128
@@ -246,6 +247,49 @@ def param_count_analytic(cfg: LlamaConfig) -> int:
     if not cfg.tie_embeddings:
         total += D * V
     return total
+
+
+#: A dense block: every parameter multiplies with every token.
+active_param_count = param_count_analytic
+
+
+def serving_config(cfg: LlamaConfig) -> LlamaConfig:
+    """``cfg`` for the forward-only serving programs: the batched-
+    prefill kernels are safe there (the flag keeps them away from the
+    differentiated training path, which shares ``forward_prefill``)."""
+    return replace(cfg, pallas_batched_prefill=True)
+
+
+def import_hf(model_dir: str, cfg: LlamaConfig, **kw) -> Params:
+    """A local Hugging Face checkpoint directory into this family's
+    tree (``models/checkpoint.import_hf_llama``)."""
+    from llmq_tpu.models.checkpoint import import_hf_llama
+    return import_hf_llama(model_dir, cfg, **kw)
+
+
+def step_stats_size(cfg: LlamaConfig) -> int:
+    """This family's forward passes count nothing
+    (``models/__init__.py``)."""
+    return 0
+
+
+def check_serving(cfg: LlamaConfig, **settings) -> None:
+    """Everything the serving settings can ask for is written for this
+    family."""
+
+
+def routes(cfg: LlamaConfig, cache: KVCache, *, batch: int, page_size: int,
+           max_pages: int, decode: bool = False,
+           prefill_rows: int = 0) -> Dict[str, str]:
+    """``ops/attention.kernel_routes`` at this config and pool."""
+    from llmq_tpu.ops.attention import kernel_routes
+    return kernel_routes(
+        batch=batch, page_size=page_size, max_pages=max_pages,
+        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        kv_itemsize=cache["k"].dtype.itemsize,
+        quant_kv="k_scale" in cache, enabled=cfg.pallas,
+        multi_ok=cfg.pallas_batched_prefill, decode=decode,
+        prefill_rows=prefill_rows)
 
 
 def weight_bytes(cfg: LlamaConfig) -> int:
@@ -578,7 +622,8 @@ def forward_mixed(
     padded rows point at reserved page 0) and
     :func:`forward_decode`'s (``dec_active`` redirects finished rows'
     writes to page 0). Returns
-    ``(dec_logits (B, V), pf_logits (S, T, V), cache)``.
+    ``(dec_logits (B, V), pf_logits (S, V), cache)``: of a slice the
+    logits of its last valid position, the one serving samples.
     """
     B = dec_tokens.shape[0]
     S, T = pf_tokens.shape
@@ -670,7 +715,8 @@ def forward_mixed(
                      "k_scale": pools[2], "v_scale": pools[3]}
     else:
         out_cache = {"k": k_pool, "v": v_pool}
-    return _logits(params, h_d), _logits(params, h_p), out_cache
+    pf_logits = _logits(params, h_p)[jnp.arange(S), pf_lengths - 1]
+    return _logits(params, h_d), pf_logits, out_cache
 
 
 def _sp_forward_local(params: Params, tokens_local: jnp.ndarray,

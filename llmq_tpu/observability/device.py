@@ -84,7 +84,11 @@ def decode_mfu(tokens_per_s: float, n_params: int, device_kind: str,
                quant: str = "", n_chips: int = 1) -> float:
     """Decode-phase model FLOPs utilization as a FRACTION: each token
     costs ~2·n_params FLOPs (the dense matmuls; attention is negligible
-    at serving context lengths). ``n_chips`` scales the denominator to
+    at serving context lengths). ``n_params`` is what one token
+    multiplies with: every parameter of a dense model, the ACTIVE count
+    of a routed one (the executor passes its family's
+    ``active_param_count``; a held count would overstate a sparse
+    model's utilization by its experts' ratio). ``n_chips`` scales the denominator to
     the serving mesh's aggregate peak — a dp2×tp4 engine is measured
     against 8 chips' FLOPs, not one (docs/multihost.md)."""
     if tokens_per_s <= 0 or n_params <= 0:

@@ -1,6 +1,9 @@
 #!/usr/bin/env python
-"""Micro-bench the fused decode kernel alone on the chip (dev tool; no
-cell runs it).
+"""Micro-bench the decode attention kernel alone on the chip (dev tool;
+no cell runs it): the fused decode kernel of the Llama block, or, for a
+configuration of the family ``deepseek_v3``, the latent decode kernel
+and the latent write (``ops/pallas/latent_decode.py``), each timed
+apart.
 
 Heads, page size, block table width, batch, pool and the int8 kernel
 come from a served configuration (``--model-file
@@ -45,31 +48,134 @@ def parse_lens(spec: str):
     return lens
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--model-file", required=True)
-    ap.add_argument("--lens", action="append", required=True)
-    ap.add_argument("--tree", default=os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    ap.add_argument("--max-pages", type=int, default=0)
-    ap.add_argument("--pages-per-chunk", type=int, default=0)
-    ap.add_argument("--spread", action="store_true")
-    ap.add_argument("--shuffle", action="store_true")
-    ap.add_argument("--out", default="")
-    ap.add_argument("--rehearse", action="store_true",
-                    help="off the chip: interpret mode, 2 layers, 2 "
-                         "calls; the times mean nothing")
-    args = ap.parse_args()
-    sys.path.insert(0, os.path.abspath(args.tree))
+def occupancy(spec: str, B: int, ps: int, mp: int, P: int, rng, args):
+    """(seq_lens (B,), block tables (B, mp), page of each row's last
+    token) of one ``--lens`` occupancy over a pool of ``P`` pages."""
+    import numpy as np
+    lens = parse_lens(spec)
+    assert len(lens) <= B and max(lens) <= mp * ps, spec
+    seq = np.zeros(B, np.int32)
+    at = (np.arange(len(lens)) * B // len(lens) if args.spread
+          else np.arange(len(lens)))
+    seq[at] = rng.permutation(lens) if args.shuffle else lens
+    n_pages = -(-seq // ps)
+    assert n_pages.sum() < P, "the pool is too small for these rows"
+    ids = 1 + rng.permutation(P - 1)[:n_pages.sum()]
+    bt = np.zeros((B, mp), np.int32)
+    write_page = np.zeros(B, np.int32)
+    for b, start in enumerate(np.cumsum(n_pages) - n_pages):
+        bt[b, :n_pages[b]] = ids[start:start + n_pages[b]]
+        if seq[b]:
+            write_page[b] = bt[b, (seq[b] - 1) // ps]
+    return len(lens), seq, bt, write_page
 
+
+def bench_latent(args, doc) -> None:
+    """The latent decode kernel and the latent write at the served
+    geometry: µs a call of each, and the share of the cached latents'
+    time at 819 GB/s — against the published bytes (rank + rope values
+    a token) and against the pool's (its lanes padded to 128)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmq_tpu.ops.pallas import latent_decode as ld
+
+    ex, model = doc["server"]["executor"], doc["server"]["model"]
+    H, L = doc["num_attention_heads"], doc["num_hidden_layers"]
+    rank, dr = doc["kv_lora_rank"], doc["qk_rope_head_dim"]
+    W = -(-(rank + dr) // 128) * 128
+    reps = REPS
+    if args.rehearse:
+        L, reps = 2, 2
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path in interpret mode)")
+    B, ps, P = ex["max_batch_size"], ex["page_size"], ex["kv_pages"]
+    mp = args.max_pages or model["max_seq_len"] // ps
+    key = jax.random.key(0)
+    pool = jnp.tile(jax.random.normal(key, (1, P, ps, W), jnp.bfloat16),
+                    (L, 1, 1, 1))
+    q = jax.random.normal(key, (B, H, W), jnp.bfloat16) * 0.05
+    new = jnp.ones((B, W), jnp.bfloat16)
+
+    @jax.jit
+    def attend(pool, bt, seq_lens):
+        outs = []
+        for i in range(reps):
+            # A query of its own a call: equal calls are one call to XLA.
+            o = ld.latent_decode_attention_pallas(
+                q * (1 + i / 64), pool, bt, seq_lens, jnp.int32(i % L),
+                rank=rank, interpret=args.rehearse)
+            outs.append(jnp.sum(o))
+        return jnp.stack(outs), o
+
+    @partial(jax.jit, donate_argnums=(0,))
+    def write(pool, page_of, slot_of):
+        for i in range(reps):
+            pool = ld.latent_write_pallas(pool, new, page_of, slot_of,
+                                          jnp.int32(i % L),
+                                          interpret=args.rehearse)
+        return pool
+
+    print(f"{doc['name']}: B={B} H={H} W={W} rank={rank} ps={ps} "
+          f"max_pages={mp} chunk_tokens="
+          f"{ld.pages_per_chunk(ps, mp) * ps} "
+          f"device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    rng = np.random.default_rng(0)
+    n = 1 if args.rehearse else 10
+    results = []
+    for spec in args.lens:
+        rows, seq, bt, write_page = occupancy(spec, B, ps, mp, P, rng, args)
+        call = (jnp.asarray(bt), jnp.asarray(seq))
+        outs, o = attend(pool, *call)
+        finite = bool(np.isfinite(np.asarray(o)).all())
+        t0 = time.perf_counter()
+        for _ in range(n):
+            outs, o = attend(pool, *call)
+        jax.block_until_ready(outs)
+        us = (time.perf_counter() - t0) / (n * reps) * 1e6
+        wargs = (jnp.asarray(write_page), jnp.asarray((seq - 1) % ps))
+        pool = write(pool, *wargs)
+        t0 = time.perf_counter()
+        for _ in range(n):
+            pool = write(pool, *wargs)
+        jax.block_until_ready(pool)
+        write_us = (time.perf_counter() - t0) / (n * reps) * 1e6
+        tokens = int(seq.sum())
+        least = tokens * (rank + dr) * 2 / PEAK_BYTES_PER_S * 1e6
+        pool_least = tokens * W * 2 / PEAK_BYTES_PER_S * 1e6
+        results.append({"lens": spec, "rows": rows, "tokens": tokens,
+                        "us_per_call": us, "write_us_per_call": write_us,
+                        "latent_least_us": least,
+                        "latent_roofline_pct": 100 * least / us,
+                        "pool_roofline_pct": 100 * pool_least / us,
+                        "finite": finite})
+        print(f"  lens {spec}: attention {us:,.1f} us/call, write "
+              f"{write_us:,.1f}; latents at peak {least:,.1f} us = "
+              f"{100 * least / us:.1f} % (the pool's padded rows: "
+              f"{100 * pool_least / us:.1f} %)  finite={finite}",
+              flush=True)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+        with open(args.out, "w", encoding="utf-8") as f:
+            json.dump({"config": doc["name"], "tree": args.tree,
+                       "max_pages": mp, "results": results}, f, indent=1)
+
+
+def bench_fused(args, doc) -> None:
+    """The fused decode kernel of the Llama block (bf16 or int8 by the
+    file) at the served geometry: µs a call, ``decode_work``'s
+    schedule, and the share of the K/V bytes' time at 819 GB/s."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from llmq_tpu.ops.pallas import fused_decode
 
-    with open(args.model_file, encoding="utf-8") as f:
-        doc = json.load(f)
     ex, model = doc["server"]["executor"], doc["server"]["model"]
     H, Hkv = doc["num_attention_heads"], doc["num_key_value_heads"]
     D = doc.get("head_dim") or doc["hidden_size"] // H
@@ -134,21 +240,7 @@ def main() -> None:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)),
                     exist_ok=True)
     for spec in args.lens:
-        lens = parse_lens(spec)
-        assert len(lens) <= B and max(lens) <= mp * ps, spec
-        seq = np.zeros(B, np.int32)
-        at = (np.arange(len(lens)) * B // len(lens) if args.spread
-              else np.arange(len(lens)))
-        seq[at] = rng.permutation(lens) if args.shuffle else lens
-        n_pages = -(-seq // ps)
-        assert n_pages.sum() < P, "the pool is too small for these rows"
-        ids = 1 + rng.permutation(P - 1)[:n_pages.sum()]
-        bt = np.zeros((B, mp), np.int32)
-        write_page = np.zeros(B, np.int32)
-        for b, start in enumerate(np.cumsum(n_pages) - n_pages):
-            bt[b, :n_pages[b]] = ids[start:start + n_pages[b]]
-            if seq[b]:
-                write_page[b] = bt[b, (seq[b] - 1) // ps]
+        rows, seq, bt, write_page = occupancy(spec, B, ps, mp, P, rng, args)
         call = (jnp.asarray(bt), jnp.asarray(seq), jnp.asarray(write_page))
         outs, attn, pools = many(pools, *call)
         first = np.asarray(attn, np.float32)    # the last call's output
@@ -162,7 +254,7 @@ def main() -> None:
                                      + (4 * Hkv if q8 else 0))
         least_us = kv_bytes / PEAK_BYTES_PER_S * 1e6
         work = (fused_decode.decode_work(seq, plan) if plan else None)
-        results.append({"lens": spec, "rows": len(lens),
+        results.append({"lens": spec, "rows": rows,
                         "tokens": int(seq.sum()), "us_per_call": us,
                         "decode_work": work, "kv_least_us": least_us,
                         "kv_roofline_pct": 100 * least_us / us,
@@ -178,6 +270,38 @@ def main() -> None:
             json.dump({"config": doc["name"], "tree": args.tree,
                        "plan": plan and plan._asdict(), "max_pages": mp,
                        "results": results}, f, indent=1)
+
+
+
+#: family (the configuration file's ``family``, ``llmq_tpu/models``
+#: ``FAMILIES``) -> the bench of its decode kernels. The kernels a
+#: family dispatches are what this tool is about, so a new family's
+#: bench is a function here and an entry in this table.
+BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent}
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--model-file", required=True)
+    ap.add_argument("--lens", action="append", required=True)
+    ap.add_argument("--tree", default=os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    ap.add_argument("--max-pages", type=int, default=0)
+    ap.add_argument("--pages-per-chunk", type=int, default=0)
+    ap.add_argument("--spread", action="store_true")
+    ap.add_argument("--shuffle", action="store_true")
+    ap.add_argument("--out", default="")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="off the chip: interpret mode, 2 layers, 2 "
+                         "calls; the times mean nothing")
+    args = ap.parse_args()
+    sys.path.insert(0, os.path.abspath(args.tree))
+    with open(args.model_file, encoding="utf-8") as f:
+        doc = json.load(f)
+    if doc.get("family") not in BENCHES:
+        sys.exit(f"{args.model_file}: no kernel bench for the family "
+                 f"{doc.get('family')!r}; known: {sorted(BENCHES)}")
+    BENCHES[doc["family"]](args, doc)
 
 
 if __name__ == "__main__":

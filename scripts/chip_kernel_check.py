@@ -164,12 +164,9 @@ class Path:
 
         def mixed(params, cache, tokens, positions, bts, active,
                   pf_tokens, pf_positions, pf_lengths, pf_bts):
-            import jax.numpy as jnp
-            dec, pf, cache = forward_mixed(
+            return forward_mixed(
                 params, cfg, tokens, positions, cache, bts, pf_tokens,
                 pf_positions, pf_lengths, pf_bts, dec_active=active)
-            idx = jnp.arange(pf_tokens.shape[0])
-            return dec, pf[idx, pf_lengths - 1], cache
 
         self._prefill = jit(prefill, 1)
         self._decode = jit(decode, 1)

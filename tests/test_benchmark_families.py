@@ -1,0 +1,209 @@
+"""Tier-1's guard of the seam between the benchmark's harness and a
+model family (``benchmark/families/<family>/``: ``shapes.py``,
+``reference.py``, ``adapter.py``; PERF.md §3): every configuration of
+``BENCHMARK.json`` names a family that loads with the whole surface,
+the shape functions give the published sizes, and the harness names no
+family. ``benchmark/selftest/test_families.py`` has the seam's own
+failure cases; tier-1 does not run it.
+
+The harness's logits check asks no family how to judge, and a routed
+model needs more than its worst of 8 positions under one limit: the
+family ``deepseek_v3`` judges many positions inside its
+``reference_logits`` (PERF.md §7 (f);
+``tests/test_deepseek_v3.py::test_the_benchmark_s_check_judges_many_positions``);
+here its configuration's ``tolerance`` is held to that judge's keys."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+from benchmark.harness import contract
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+
+
+@pytest.fixture(scope="module")
+def bench():
+    return contract.load_benchmark()
+
+
+def _configs(bench):
+    out = []
+    for entry in bench["configs"]:
+        with open(os.path.join(bench["_root"], entry["file"])) as f:
+            out.append((entry, json.load(f)))
+    return out
+
+
+def test_every_configuration_names_a_family_with_the_whole_surface(
+        bench):
+    families = set()
+    for entry, config in _configs(bench):
+        fdir = contract.family_dir(bench, config)
+        families.add(os.path.basename(fdir))
+        assert os.path.basename(fdir) == config["family"], entry["name"]
+        for part, surface in contract.FAMILY_SURFACE.items():
+            mod = contract.load_family(fdir, part)
+            assert all(hasattr(mod, n) for n in surface), (entry, part)
+        keys = contract.load_family(fdir, "shapes").MODEL_KEYS
+        assert all(k in config for k in keys), entry["name"]
+        assert set(config["reduced"]) == set(entry["reduced"])
+    assert families == {"llama", "deepseek_v3"}
+
+
+@pytest.mark.parametrize("cell", [
+    "smollm2-chat-bursts", "smollm2-decode-saturated",
+    "smollm2-sessions-prefix", "mistral7b-decode-saturated",
+    "kanana2-decode-saturated"])
+def test_every_cell_resolves_and_reports_what_the_contract_asks(bench, cell):
+    assert contract.check_names(bench) == []
+    assert cell in [w["name"] for w in bench["workloads"]]
+    got = contract.resolve_cell(bench, cell)
+    names = {m["name"] for m in got["end_to_end"]}
+    assert "setup_s" in names and len(names) >= 2
+    assert got["per_layer"]
+    for m in got["per_layer"]:
+        assert callable(contract.load_reader(bench, m["name"]))
+
+
+def test_no_cell_is_left_out_of_the_cases_above(bench):
+    mark = test_every_cell_resolves_and_reports_what_the_contract_asks \
+        .pytestmark[0]
+    assert sorted(mark.args[1]) == sorted(
+        w["name"] for w in bench["workloads"])
+
+
+@pytest.mark.parametrize("config,kv_itemsize,per_token", [
+    ("smollm2-1.7b-bf16", 2, 196_608),
+    ("mistral-7b-v0.3-w8kv8", 1, 66_560),
+    ("kanana-2-30b-a3b-bf16", 2, 9_216),
+])
+def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
+                                                    kv_itemsize, per_token):
+    entry, doc = next(x for x in _configs(bench) if x[0]["name"] == config)
+    shapes = contract.load_family(contract.family_dir(bench, doc), "shapes")
+    model = {k: doc[k] for k in shapes.MODEL_KEYS if k in doc}
+    assert shapes.kv_bytes_per_token(model, kv_itemsize) == per_token
+    assert shapes.attn_calls_per_step(model) == doc["num_hidden_layers"]
+
+
+def test_the_routed_family_s_shapes_on_the_published_sizes(bench):
+    cell = contract.resolve_cell(bench, "kanana2-decode-saturated")
+    shapes = contract.load_family(cell["family_dir"], "shapes")
+    held = cell["config"]["model"]
+    whole = dict(held, num_hidden_layers=48)
+    assert shapes.kv_bytes_per_token(dict(held, num_hidden_layers=1),
+                                     2) == 1_152
+    assert shapes.kv_bytes_per_token(whole, 2) == 55_296
+    assert round(shapes.param_count(whole) / 1e9, 2) == 30.67
+    assert shapes.param_count(held) == 5_069_642_624
+    assert shapes.active_param_count(held) < shapes.param_count(held) / 4
+    assert round(shapes.experts_touched(held, 64), 1) == 122.1
+    assert round(shapes.experts_touched(held, 8), 1) == 40.8
+    # a step's least bytes follow the rows (the experts they touch) ...
+    few = shapes.decode_step_bytes(held, 2, 2, 8, 8 * 900)
+    full = shapes.decode_step_bytes(held, 2, 2, 64, 64 * 900)
+    assert 0.35 < few / full < 0.5 and round(full / 1e9, 2) == 9.75
+    # ... its attention's only the context, read once for all 32 heads
+    assert shapes.decode_attn_bytes(held, 2, 8, 1e3) == \
+        shapes.decode_attn_bytes(held, 2, 64, 1e3) == 9_216e3
+    assert shapes.decode_attn_flops(held, 64, 1) == 8 * 32 * (576 + 512) * 2
+    # and no share of a roofline is measured against padded bytes
+    assert shapes.moe_ffn_bytes(held, 2, 122.1) == 122.1 * 3 * 2048 * 768 * 2
+
+
+def test_the_tolerance_is_what_the_family_s_judge_reads(bench):
+    """``tolerance`` holds the harness's two keys and the keys of
+    ``reference.judge``, no other; under its numbers a run whose clean
+    positions read 0.04 passes with most positions swapped, the
+    lower-precision control's 0.27 everywhere is refused, and so is one
+    position of unrelated logits (PERF.md §7 (f))."""
+    import numpy as np
+    cell = contract.resolve_cell(bench, "kanana2-decode-saturated")
+    judge = contract.load_family(cell["family_dir"], "reference").judge
+    tol = cell["config"]["tolerance"]
+    assert set(tol) == {"rms", "max", "clean_quantile", "rms_clean",
+                        "margin_eps", "min_positions", "why"}
+    # of the check's two prompts (bucket - 5 and bucket // 3, three
+    # decode steps each) the long one is judged, the short one is not
+    bucket = min(cell["config"]["server"]["executor"]["prefill_buckets"])
+    assert bucket // 3 + 3 < tol["min_positions"] <= bucket - 5 + 3
+    ref = np.zeros((40, 16), np.float32)
+    margins = np.full(40, 0.001)
+    sound = ref + np.where(np.arange(40) < 10, 0.04,
+                           np.linspace(0.11, 0.92, 40))[:, None]
+    got = judge(sound, ref, margins, tol)
+    assert got["ok"] and got["positions"] == 40, got
+    assert got["rms_clean"] == pytest.approx(0.04) and \
+        got["near_tie_share"] == 1.0, got
+    assert not judge(np.maximum(sound, 0.27), ref, margins, tol)["ok"]
+    unrelated = sound.copy()
+    unrelated[3] = 1.41
+    assert not judge(unrelated, ref, margins, tol)["ok"]
+
+
+def test_the_new_configuration_is_the_catalog_s_row(bench):
+    """Every number of the catalog's ``config`` under the same key;
+    only ``reduced``'s keys differ, and no width is among them."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    entry, doc = next(x for x in _configs(bench)
+                      if x[0]["name"] == "kanana-2-30b-a3b-bf16")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == entry["source"])
+    differs = {k for k, v in row["config"].items() if doc.get(k) != v}
+    assert differs == set(entry["reduced"]) == {"num_hidden_layers",
+                                                "max_position_embeddings"}
+    assert doc["n_routed_experts"] == 128 and doc["vocab_size"] == 128_256
+
+
+def test_the_harness_names_no_family():
+    named = re.compile(r"llama|deepseek|kanana|smollm|fused_decode|gmm|"
+                       r"latent_decode|moe_grouped|llmq_tpu\.models")
+    files = [os.path.join(REPO, "benchmark", "run.py")]
+    for sub in ("harness", "metrics"):
+        d = os.path.join(REPO, "benchmark", sub)
+        files += [os.path.join(d, n) for n in sorted(os.listdir(d))
+                  if n.endswith(".py")]
+    for path in files:
+        with open(path) as f:
+            hits = [ln for ln in f if named.search(ln)]
+        assert not hits, (path, hits)
+
+
+@pytest.mark.parametrize("family", ["llama", "deepseek_v3"])
+def test_who_imports_what_in_a_family(family):
+    """``shapes.py`` is standard library alone (the parent and the
+    readers import it); ``reference.py`` imports neither the program
+    nor the adapter; only ``adapter.py`` imports the program."""
+    fdir = os.path.join(REPO, "benchmark", "families", family)
+    imports = {}
+    for part in contract.FAMILY_SURFACE:
+        with open(os.path.join(fdir, part + ".py")) as f:
+            imports[part] = re.findall(
+                r"^\s*(?:from|import)\s+([\w.]+)", f.read(), re.M)
+    assert set(imports["shapes"]) <= {"__future__", "typing"}
+    assert not [m for m in imports["reference"]
+                if m.startswith(("llmq_tpu", "benchmark")) or "adapter" in m]
+    assert any(m.startswith("llmq_tpu") for m in imports["adapter"])
+
+
+def test_the_parent_process_stays_off_jax_for_the_new_cell(bench):
+    p = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; sys.path.insert(0, %r)\n"
+         "from benchmark.harness import contract, readers\n"
+         "b = contract.load_benchmark(%r)\n"
+         "c = contract.resolve_cell(b, 'kanana2-decode-saturated')\n"
+         "s = readers.family_shapes(c)\n"
+         "s.decode_step_bytes(c['config']['model'], 2, 2, 64, 6e4)\n"
+         "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n"
+         % (REPO, os.path.join(REPO, "BENCHMARK.json"))], capture_output=True, text=True,
+        timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]

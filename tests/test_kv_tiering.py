@@ -49,6 +49,16 @@ def wait_until(fn, timeout=5.0, step=0.002):
     return False
 
 
+def claim_ready(plane, conv_id):
+    """``plane.claim`` once the worker's extract has landed: an entry
+    counts as on the host before it is ready, and ``claim`` says "wait"
+    (and hands over nothing) until then."""
+    got = []
+    assert wait_until(lambda: got.append(plane.claim(conv_id)) or
+                      got[-1][0] == "ready")
+    return got[-1]
+
+
 # -- host pool -----------------------------------------------------------------
 
 
@@ -319,7 +329,7 @@ class TestPlaneStateMachine:
         plane = mk_plane(clock=clock)
         plane.demote("c", [3], list(range(8)), 8, None)
         assert wait_until(lambda: plane.counts()["host"] == 1)
-        status, entry = plane.claim("c")
+        status, entry = claim_ready(plane, "c")
         plane.note_promoted(entry, "host", 0.1)
         plane.release(entry)
         assert plane.stats()["round_trips"] == 1
@@ -327,7 +337,7 @@ class TestPlaneStateMachine:
         plane.demote("c", [4], list(range(8)), 8, None)
         assert wait_until(lambda: plane.counts()["host"] == 1)
         clock.advance(3600.0)
-        status, entry = plane.claim("c")
+        status, entry = claim_ready(plane, "c")
         plane.note_promoted(entry, "host", 0.1)
         plane.release(entry)
         assert plane.stats()["round_trips"] == 1

@@ -160,3 +160,62 @@ def test_int8_kv_prefill_program_stays_small_for_v5e(one_chip):
         mem.temp_size_in_bytes, pool_bytes)
     # the four pools go in and come out in place
     assert mem.alias_size_in_bytes >= pool_bytes
+
+
+#: kanana-2-30b-a3b-bf16 as served: 64 rows, 32 heads over one latent
+#: "head" of 640 lanes (512 + 64, padded), 128-token pages, 16 a row, 8
+#: layers, 832 pages.
+_LATENT = dict(B=64, H=32, W=640, rank=512, ps=128, mp=16, L=8, P=832)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "write"])
+def test_latent_kernels_compile_for_v5e(one_chip, kernel):
+    """The latent decode kernel (manual page DMAs into two 512-token
+    slots, a 32 x 640 by 640 x 512 product a chunk) and the latent
+    write at the served geometry: one Mosaic call each."""
+    from llmq_tpu.ops.pallas.latent_decode import (
+        latent_decode_attention_pallas, latent_write_pallas)
+
+    g = _LATENT
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((g["L"], g["P"], g["ps"], g["W"]), jnp.bfloat16)
+    if kernel == "decode":
+        lowered = jax.jit(latent_decode_attention_pallas,
+                          static_argnames=("rank",)).lower(
+            arg((g["B"], g["H"], g["W"]), jnp.bfloat16), pool,
+            arg((g["B"], g["mp"])), arg((g["B"],)), arg(()),
+            rank=g["rank"])
+    else:
+        lowered = jax.jit(latent_write_pallas).lower(
+            pool, arg((g["B"], g["W"]), jnp.bfloat16), arg((g["B"],)),
+            arg((g["B"],)), arg(()))
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(text) < 100_000
+
+
+@pytest.mark.parametrize("rows", [384, 6528],
+                         ids=["64-decode-rows", "1088-mixed-tokens"])
+def test_grouped_product_compiles_for_v5e(one_chip, rows):
+    """The routed layers' grouped product at this model's widths (128
+    experts, 2,048 -> 2 x 768 and 768 -> 2,048) for a decode step's
+    pairs and a mixed step's: two Mosaic calls, and the 0.8 GB expert
+    leaf is not copied (temporaries stay a few megabytes)."""
+    from llmq_tpu.ops.moe import moe_grouped_matmul_pallas
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def ffn(xs, w_gu, w_d, counts):
+        gu = moe_grouped_matmul_pallas(xs, w_gu, counts)
+        return moe_grouped_matmul_pallas(gu[:, :768] * gu[:, 768:], w_d,
+                                         counts)
+
+    compiled = jax.jit(ffn).lower(
+        arg((rows, 2048)), arg((128, 2048, 1536)), arg((128, 768, 2048)),
+        arg((128,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
