@@ -13,8 +13,10 @@ Design notes (TPU-first):
   pools: around such a call a pool carried through ``lax.scan`` was
   copied whole once a layer (2-8x slower decode steps on the v5e), so
   those programs pay for their depth at compile time instead. Prefill
-  over INT8 pools has no such call and is ROLLED (``lax.fori_loop``,
-  the pools as its carry): 6 s to compile against 220 s at 32 layers.
+  over INT8 pools has no such call — its write is XLA's, page by page,
+  and its attention kernel only READS the pools — and is ROLLED
+  (``lax.fori_loop``, the pools as its carry): 6 s to compile against
+  220 s at 32 layers.
   The table is in docs/performance.md, "Unrolled decode layers".
 - **Paged KV cache**: global page pools ``(L, P, page_size, H_kv, D)``
   indexed by per-sequence block tables. Static shapes everywhere: one
@@ -421,18 +423,22 @@ def forward_prefill(
         # int8 pools: quantized write + dequantizing attention
         # (ops/attention.py int8 section), layers ROLLED: one
         # ``fori_loop`` body over the stacked parameters with the four
-        # pools as its carry. The one serving program with no aliased
-        # Pallas call in it (the writes are ``.at[].set`` scatters,
-        # which XLA updates in place inside a loop body), so the reason
-        # the others unroll does not hold — and unrolled it compiled
-        # longest of all (docs/performance.md "Unrolled decode layers").
+        # pools as its carry. The one serving program with no ALIASED
+        # Pallas call in it (the writes are ``.at[].set`` of whole
+        # pages, which XLA updates in place inside a loop body; the
+        # attention kernel reads the carried pools and returns none of
+        # them: tests/test_tpu_compile.py holds the temporaries under
+        # one pool), so the reason the others unroll does not hold —
+        # and unrolled it compiled longest of all (docs/performance.md
+        # "Unrolled decode layers").
         def layer(l, carry):
             h, pools = carry
             q, k, v = qkv(h, l)
             pools = paged_kv_write_prefill_q8(
                 pools, k, v, block_tables, positions, lengths, l)
             attn = dispatch_prefill_attention_q8(
-                q, pools, block_tables, positions, seq_lens, l)
+                q, pools, block_tables, positions, seq_lens, l,
+                enabled=cfg.pallas, multi_ok=cfg.pallas_batched_prefill)
             return out_mlp(h, attn, l), pools
 
         h, pools = lax.fori_loop(
@@ -606,16 +612,21 @@ def forward_mixed(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, KVCache]:
     """Fused mixed step (token-budget mixed batching): advance B decode
     rows one token AND write S prefill slices (up to T tokens each) into
-    the shared paged pool in ONE traversal of the stacked layer weights.
+    the shared paged pool in ONE program, layer by layer.
 
     This is the device program behind ``executor.mixed_batch``: the
-    per-layer weight reads — where an HBM-bound decode step spends its
-    bandwidth — are paid once for both the decode rows and the prefill
-    slice tokens, and the decode rows' stall behind prefill work is
-    bounded by T·S (the engine's ``prefill_token_budget``) instead of
-    the longest admitted prompt. Layout is ragged by construction:
-    decode rows and slice rows are separate sequences over the same
-    pool, so their KV writes are disjoint and need no ordering.
+    decode rows' stall behind prefill work is bounded by T·S (the
+    engine's ``prefill_token_budget``) instead of the longest admitted
+    prompt. It is NOT one traversal of the weights: each layer calls
+    ``linear`` once for the slice rows and once for the decode rows —
+    two matmuls, so the device reads a layer's weights twice (the
+    slices' products are compute-bound and hide their read; the decode
+    rows' are the weight stream of a plain decode step again), and all
+    S·T slice positions are computed whatever ``pf_lengths`` says
+    (PERF.md §5: Mistral's mixed step costs 60-86 ms over a plain one).
+    Layout is ragged by construction: decode rows and slice rows are
+    separate sequences over the same pool, so their KV writes are
+    disjoint and need no ordering.
 
     Row conventions are exactly :func:`forward_prefill`'s (contiguous
     ``pf_positions`` per row, padding discarded past ``pf_lengths``,
@@ -667,7 +678,8 @@ def forward_mixed(
                 pools, k_p, v_p, pf_block_tables, pf_positions,
                 pf_lengths, jnp.int32(l))
             attn_p = dispatch_prefill_attention_q8(
-                q_p, pools, pf_block_tables, pf_positions, pf_seq_lens, l)
+                q_p, pools, pf_block_tables, pf_positions, pf_seq_lens, l,
+                enabled=cfg.pallas, multi_ok=cfg.pallas_batched_prefill)
         else:
             k_pool, v_pool = paged_kv_write_prefill(
                 k_pool, v_pool, k_p, v_p, pf_block_tables, pf_positions,
@@ -684,8 +696,8 @@ def forward_mixed(
                          layer_slice(lp["w_up"], l),
                          layer_slice(lp["w_down"], l))
 
-        # Decode rows, same layer — the weight tiles streamed for the
-        # slice rows above are what this half reuses.
+        # Decode rows, same layer: a matmul of their own, so the layer's
+        # weights are read a second time (see the docstring).
         hn_d = rms_norm(h_d, lp["attn_norm"][l], cfg.norm_eps)
         q_d = linear(hn_d, wq).reshape(B, 1, cfg.n_heads, cfg.head_dim)
         k_d = linear(hn_d, wk).reshape(B, 1, cfg.n_kv_heads, cfg.head_dim)

@@ -17,7 +17,7 @@ One implementation per step kind, chosen here and nowhere else:
 - ``ops/pallas/fused_decode.py`` — decode: the new token's KV write
   fused with attention over the row's live pages (bf16 and int8 pools).
 - ``ops/pallas/prefill_attention.py`` — prefill attention of a slice
-  over its paged context (bf16 pools).
+  over its paged context (bf16 and int8 pools).
 - ``ops/pallas/kv_write.py`` — the decode and prefill page writes where
   the fused decode kernel does not serve.
 
@@ -294,18 +294,32 @@ def _prefill_attn_ok(rows_ok: bool, head_dim: int) -> bool:
     return rows_ok and (128 % head_dim == 0 or head_dim % 128 == 0)
 
 
+def _scale_pages_ok(page_size: int, n_kv_heads: int,
+                    n_scale_heads: int) -> bool:
+    """What the int8-KV kernels ask of a scale page, a (H_kv, page_size)
+    block. page_size % 128: its LANE dim is page_size — Mosaic rejects
+    the page DMA slice when it isn't lane-tile aligned (found by an
+    on-chip A/B at ps=16). H_kv = 8 fills its minimum sublane tile.
+    Serving configs for int8 KV want 128-token pages anyway (per-page
+    DMA cost); smaller pages take the pure path."""
+    return page_size % 128 == 0 and n_kv_heads == n_scale_heads == 8
+
+
 def _fused_decode_q8_ok(B: int, page_size: int, max_pages: int, gd: int,
                         n_kv_heads: int, n_scale_heads: int) -> bool:
-    """int8-KV fused decode kernel eligibility. page_size % 128: a
-    scale page is a (H_kv, page_size) block whose LANE dim is page_size
-    — Mosaic rejects the page DMA slice when it isn't lane-tile aligned
-    (found by an on-chip A/B at ps=16). H_kv = 8 fills the minimum
-    sublane tile of the scale page. Serving configs for int8 KV want
-    128-token pages anyway (per-page DMA cost); smaller pages take the
-    pure path."""
+    """int8-KV fused decode kernel eligibility: the scale page's
+    condition and a legal tile plan."""
     from llmq_tpu.ops.pallas.fused_decode import fused_kernel_viable
-    return (page_size % 128 == 0 and n_kv_heads == n_scale_heads == 8
+    return (_scale_pages_ok(page_size, n_kv_heads, n_scale_heads)
             and fused_kernel_viable(B, page_size, max_pages, gd, 1))
+
+
+def _prefill_attn_q8_ok(rows_ok: bool, head_dim: int, page_size: int,
+                        n_kv_heads: int, n_scale_heads: int) -> bool:
+    """int8-KV prefill attention kernel eligibility: the bf16 kernel's
+    condition and the scale page's."""
+    return (_prefill_attn_ok(rows_ok, head_dim)
+            and _scale_pages_ok(page_size, n_kv_heads, n_scale_heads))
 
 
 def kernel_routes(*, batch: int, page_size: int, max_pages: int,
@@ -346,7 +360,9 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
         out["prefill_write"] = ("xla" if quant_kv
                                 else pick(rows_ok, "_kv_prefill_kernel"))
         out["prefill_attention"] = (
-            "xla" if quant_kv
+            pick(_prefill_attn_q8_ok(rows_ok, head_dim, page_size,
+                                     n_kv_heads, n_kv_heads),
+                 "_prefill_attn_kernel_q8") if quant_kv
             else pick(_prefill_attn_ok(rows_ok, head_dim),
                       "_prefill_attn_kernel"))
     if decode:
@@ -677,42 +693,98 @@ def _jit_fused_decode_q8():
 def paged_kv_write_prefill_q8(pools, k, v, block_tables, positions,
                               lengths, layer):
     """Prefill-chunk write into the int8 pools: quantize every (token,
-    head) row and scatter rows + scales (pure-JAX scatter — prefill is
-    compute-bound, and the scatter runs once per admission chunk, not
-    per decode step). k/v: (B, T, H_kv, D)."""
+    head) row and write rows + scales PAGE BY PAGE. k/v: (B, T, H_kv, D).
+
+    A chunk's tokens are contiguous (``positions[b, 0] + t``, the
+    convention of every prefill caller), so they cover ``T/page_size +
+    1`` consecutive pages of the row's block table at most: those pages
+    are read, the new rows and scale columns selected into them, and the
+    pages written back — a few page-sized updates a pool where a scatter
+    by token moved ``B·T`` rows a data pool and ``B·T·H_kv`` single
+    elements a scale pool (477 µs a layer for Mistral's two 512-token
+    slices, more than their attention: PERF.md §6, PR 33). Pure XLA and
+    nothing aliased, so it also serves inside ``forward_prefill``'s
+    rolled loop, on the CPU and under a mesh. A page slot past the
+    chunk's valid tokens rewrites reserved page 0 with itself."""
     from llmq_tpu.ops.quant import quantize_kv_rows
 
-    k_pool, v_pool, ks_pool, vs_pool = pools
     B, T = k.shape[0], k.shape[1]
-    page_size = k_pool.shape[2]
-    GD = k_pool.shape[3]
+    page_size, GD = pools[0].shape[2], pools[0].shape[3]
+    max_pages = block_tables.shape[1]
+    n_wp = -(-T // page_size) + 1          # pages a chunk can touch
     kq, kscale = quantize_kv_rows(k)       # (B, T, Hkv, D), (B, T, Hkv)
     vq, vscale = quantize_kv_rows(v)
-    valid = (jnp.arange(T)[None, :] < lengths[:, None])     # (B, T)
-    flat_valid = valid.reshape(-1)
-    flat_pos = positions.reshape(-1)
-    page_of = jnp.where(
-        flat_valid,
-        block_tables[jnp.repeat(jnp.arange(B), T), flat_pos // page_size],
-        0)
-    slot_of = jnp.where(flat_valid, flat_pos % page_size, 0)
-    k_pool = k_pool.at[layer, page_of, slot_of].set(kq.reshape(-1, GD))
-    v_pool = v_pool.at[layer, page_of, slot_of].set(vq.reshape(-1, GD))
-    ks_pool = _scale_scatter(ks_pool, layer, page_of, slot_of,
-                             kscale.reshape(B * T, -1))
-    vs_pool = _scale_scatter(vs_pool, layer, page_of, slot_of,
-                             vscale.reshape(B * T, -1))
-    return k_pool, v_pool, ks_pool, vs_pool
+    start = positions[:, 0]
+    off = start % page_size
+    # Which pages, and which of their slots the chunk fills.
+    page_idx = start[:, None] // page_size + jnp.arange(n_wp)[None]  # (B, n)
+    slot_pos = (page_idx[:, :, None] * page_size
+                + jnp.arange(page_size)[None, None])                # (B, n, ps)
+    fresh = ((slot_pos >= start[:, None, None])
+             & (slot_pos < (start + lengths)[:, None, None]))
+    live = fresh.any(axis=2) & (page_idx < max_pages)
+    pid = jnp.where(live, jnp.take_along_axis(
+        block_tables, jnp.clip(page_idx, 0, max_pages - 1), axis=1), 0)
+    fresh = fresh & live[:, :, None]
+
+    def paged(x):
+        """(B, T, ...) → page-aligned (B, n_wp, page_size, ...): token t
+        at slot ``off + t`` of the chunk's first page onwards."""
+        pad = jnp.zeros((B, n_wp * page_size) + x.shape[2:], x.dtype)
+        x = jax.vmap(lambda buf, rows, o: jax.lax.dynamic_update_slice_in_dim(
+            buf, rows, o, 0))(pad, x, off)
+        return x.reshape((B, n_wp, page_size) + x.shape[2:])
+
+    out = []
+    for i, new in enumerate((kq.reshape(B, T, GD), vq.reshape(B, T, GD),
+                             kscale, vscale)):
+        new = paged(new)                   # (B, n, ps, GD | Hkv)
+        mask = fresh[..., None]
+        if i >= 2:   # a scale page is (H_kv, page_size): slots on lanes
+            new, mask = jnp.moveaxis(new, 2, 3), fresh[:, :, None, :]
+        old = pools[i][layer, pid]         # (B, n) pages
+        out.append(pools[i].at[layer, pid].set(
+            jnp.where(mask, new.astype(old.dtype), old)))
+    return tuple(out)
+
+
+def _jit_prefill_attention_q8():
+    def make():
+        from llmq_tpu.ops.pallas.prefill_attention import (
+            paged_prefill_attention_q8_pallas)
+        return jax.jit(paged_prefill_attention_q8_pallas,
+                       static_argnames=("pages_per_chunk", "q_block",
+                                        "interpret"))
+    return _kernel_jit("prefill_attention_q8", make)
 
 
 def dispatch_prefill_attention_q8(q, pools, block_tables, positions,
-                                  seq_lens, layer) -> jnp.ndarray:
-    """Prefill-chunk attention over the int8 pools: gather + dequantize
-    the window, then the blockwise online-softmax (the gather between
-    scatter writes is the pure path's known cost; the decode hot loop is
-    where the kernel lives)."""
+                                  seq_lens, layer, *, enabled: bool = True,
+                                  multi_ok: bool = False) -> jnp.ndarray:
+    """Prefill-chunk attention over the int8 pools; q (B, T, H, D).
+
+    TPU kernel path (the rows :func:`dispatch_prefill_attention` takes,
+    at 128-token pages with eight KV heads): the int8 twin of the paged
+    prefill kernel, row-looped, reading pages and scale pages as they
+    lie and told each slice's valid length (``seq_lens`` less its first
+    position), so its work follows the context and the slice — pure
+    READS of the pools, as the bf16 kernel's. Same CONTIGUITY
+    REQUIREMENT on ``positions``. Otherwise: gather + dequantize the
+    block table's whole window, then the blockwise online-softmax."""
     k_pool, v_pool, ks_pool, vs_pool = pools
-    D = q.shape[3]
+    B, D = q.shape[0], q.shape[3]
+    use_kernel, interpret = _kernel_route(
+        k_pool.shape[3], enabled=enabled,
+        extra_ok=_prefill_attn_q8_ok(B == 1 or multi_ok, D,
+                                     k_pool.shape[2], k_pool.shape[3] // D,
+                                     ks_pool.shape[2]))
+    if use_kernel:
+        fn = _jit_prefill_attention_q8()
+        outs = [fn(q[b], pools, block_tables[b], positions[b, 0],
+                   seq_lens[b] - positions[b, 0], layer,
+                   interpret=interpret)
+                for b in range(B)]
+        return outs[0][None] if B == 1 else jnp.stack(outs)
     k_hist = _dequant_window(k_pool, ks_pool, layer, block_tables, D)
     v_hist = _dequant_window(v_pool, vs_pool, layer, block_tables, D)
     return blockwise_prefill_attention(q, k_hist, v_hist, positions,

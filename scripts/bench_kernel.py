@@ -1,9 +1,9 @@
 #!/usr/bin/env python
-"""Micro-bench the decode attention kernel alone on the chip (dev tool;
-no cell runs it): the fused decode kernel of the Llama block, or, for a
-configuration of the family ``deepseek_v3``, the latent decode kernel
-and the latent write (``ops/pallas/latent_decode.py``), each timed
-apart.
+"""Micro-bench the attention kernels alone on the chip (dev tool; no
+cell runs it): the fused decode kernel of the Llama block and, with
+``--prefill``, its paged prefill kernel; or, for a configuration of the
+family ``deepseek_v3``, the latent decode kernel and the latent write
+(``ops/pallas/latent_decode.py``), each timed apart.
 
 Heads, page size, block table width, batch, pool and the int8 kernel
 come from a served configuration (``--model-file
@@ -26,6 +26,18 @@ live) and the share of the K/V bytes' time at 819 GB/s (the benchmark's
 this one), ``--max-pages N`` widens or narrows the block table,
 ``--pages-per-chunk N`` overrides the plan's chunk, ``--out F`` writes
 the numbers to F and each occupancy's attention output beside it.
+
+``--prefill LENGTH@START`` (family ``llama``; repeatable, and then
+``--lens`` may be left out) times ONE slice of the mixed step through
+the prefill attention kernel the file's pools take (bf16 or int8): a
+slice of the mixed budget's width (``prefill_token_budget`` /
+``max_slices``) holding LENGTH valid tokens from position START on.
+Prints µs a call beside the plan's loop steps and the least time of
+the work that is there: the products of the valid queries against
+their visible context at 197 TFLOP/s, and the context's K/V (and
+scales), q and the output at 819 GB/s. ``--pure`` times what served
+an int8 pool before the kernel (``_dequant_window`` + the blockwise
+softmax under XLA) beside it.
 """
 import argparse
 import json
@@ -35,6 +47,7 @@ import time
 from functools import partial
 
 PEAK_BYTES_PER_S = 819e9  # TPU v5e (benchmark/harness/peaks.py)
+PEAK_FLOPS_PER_S = 197e12  # bf16
 REPS = 24                 # kernel calls fused into one jit program
 
 
@@ -68,6 +81,23 @@ def occupancy(spec: str, B: int, ps: int, mp: int, P: int, rng, args):
         if seq[b]:
             write_page[b] = bt[b, (seq[b] - 1) // ps]
     return len(lens), seq, bt, write_page
+
+
+def random_pools(L: int, P: int, ps: int, GD: int, Hkv: int, q8: bool):
+    """The Llama block's pools, (k, v) in bf16 or (k, v, k_scale,
+    v_scale) in int8: one layer's random pages, repeated — a whole
+    pool's random bits would not fit beside it."""
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.key(0), 2)
+    if not q8:
+        return tuple(jnp.tile(jax.random.normal(
+            k, (1, P, ps, GD), jnp.bfloat16), (L, 1, 1, 1)) for k in keys)
+    return tuple(jnp.tile(jax.random.randint(
+        k, (1, P, ps, GD), -127, 128, jnp.int8), (L, 1, 1, 1))
+        for k in keys) + tuple(
+        jnp.full((L, P, Hkv, ps), 0.01, jnp.bfloat16) for _ in range(2))
 
 
 def bench_latent(args, doc) -> None:
@@ -191,26 +221,15 @@ def bench_fused(args, doc) -> None:
     q8 = model.get("kv_quantization") == "int8"
     itemsize = 1 if q8 else 2
 
-    # One layer's random pages, repeated: a whole pool's random bits
-    # would not fit beside it.
-    key = jax.random.key(0)
+    pools = random_pools(L, P, ps, GD, Hkv, q8)
     if q8:
-        data = [jnp.tile(jax.random.randint(k, (1, P, ps, GD), -127, 128,
-                                            jnp.int8), (L, 1, 1, 1))
-                for k in jax.random.split(key, 2)]
-        scales = [jnp.full((L, P, Hkv, ps), 0.01, jnp.bfloat16)
-                  for _ in range(2)]
-        pools = tuple(data) + tuple(scales)
         new = [jnp.ones((B, Hkv, D), jnp.int8), jnp.ones((B, Hkv),
                                                          jnp.bfloat16)] * 2
         kernel = fused_decode.fused_decode_attention_q8_pallas
     else:
-        pools = tuple(jnp.tile(jax.random.normal(k, (1, P, ps, GD),
-                                                 jnp.bfloat16), (L, 1, 1, 1))
-                      for k in jax.random.split(key, 2))
         new = [jnp.ones((B, Hkv, D), jnp.bfloat16)] * 2
         kernel = fused_decode.fused_decode_attention_pallas
-    q = jax.random.normal(key, (B, H, D), jnp.bfloat16)
+    q = jax.random.normal(jax.random.key(0), (B, H, D), jnp.bfloat16)
 
     @partial(jax.jit, donate_argnums=(0,))
     def many(pools, bt, seq_lens, write_page):
@@ -272,6 +291,129 @@ def bench_fused(args, doc) -> None:
                        "results": results}, f, indent=1)
 
 
+def bench_prefill(args, doc) -> None:
+    """One slice of the mixed step through the paged prefill attention
+    kernel (``--prefill LENGTH@START``): µs a call, the plan's steps,
+    and the least time of the products and of the bytes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmq_tpu.ops import attention
+    from llmq_tpu.ops.pallas import prefill_attention as pa
+
+    ex, model = doc["server"]["executor"], doc["server"]["model"]
+    H, Hkv = doc["num_attention_heads"], doc["num_key_value_heads"]
+    D = doc.get("head_dim") or doc["hidden_size"] // H
+    L, GD = doc["num_hidden_layers"], Hkv * D
+    reps = REPS
+    if args.rehearse:
+        L, reps = 2, 2
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path in interpret mode)")
+    ps, P = ex["page_size"], ex["kv_pages"]
+    mp = args.max_pages or model["max_seq_len"] // ps
+    mixed = ex["mixed_batch"]
+    T = mixed["prefill_token_budget"] // mixed["max_slices"]
+    q8 = model.get("kv_quantization") == "int8"
+    itemsize = 1 if q8 else 2
+    pools = random_pools(L, P, ps, GD, Hkv, q8)
+    q = jax.random.normal(jax.random.key(0), (T, H, D), jnp.bfloat16)
+    plan = pa.prefill_tile_plan(T, H, Hkv, D, ps, mp, itemsize,
+                                q_itemsize=2 if q8 else 0)
+
+    def kernel(q, pools, bt, start, length, layer):
+        if q8:
+            return pa.paged_prefill_attention_q8_pallas(
+                q, pools, bt, start, length, layer, interpret=args.rehearse)
+        return pa.paged_prefill_attention_pallas(
+            q, *pools, bt, start, layer, interpret=args.rehearse)
+
+    def pure(q, pools, bt, start, length, layer):
+        positions = (start + jnp.arange(T, dtype=jnp.int32))[None]
+        hist = [attention._dequant_window(  # noqa: SLF001 — the dev tool
+            pools[i], pools[2 + i], layer, bt[None], D) for i in range(2)]
+        return attention.blockwise_prefill_attention(
+            q[None], *hist, positions, (start + length)[None])[0]
+
+    def many(fn):
+        @jax.jit
+        def run(pools, bt, start, length):
+            outs = []
+            for i in range(reps):
+                # A query of its own a call: equal calls are one to XLA.
+                o = fn(q * (1 + i / 64), pools, bt, start, length,
+                       jnp.int32(i % L))
+                outs.append(jnp.sum(o.astype(jnp.float32)))
+            return jnp.stack(outs), o
+        return run
+
+    paths = [("kernel", many(kernel))]
+    if args.pure and q8:
+        paths.append(("xla", many(pure)))
+    print(f"{doc['name']}: prefill slice T={T} H={H} Hkv={Hkv} D={D} "
+          f"ps={ps} max_pages={mp} {'int8' if q8 else 'bf16'} plan={plan} "
+          f"device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    rng = np.random.default_rng(0)
+    n = 1 if args.rehearse else 10
+    results = []
+    for spec in args.prefill:
+        length, _, start = spec.partition("@")
+        length, start = int(length), int(start or 0)
+        if length > T or start + length > mp * ps:
+            sys.exit(f"--prefill {spec}: a slice holds {T} tokens and a "
+                     f"block table {mp * ps}")
+        bt = np.zeros(mp, np.int32)
+        live = -(-(start + length) // ps)
+        bt[:live] = 1 + rng.permutation(P - 1)[:live]
+        call = (jnp.asarray(bt), jnp.int32(start), jnp.int32(length))
+        # each valid query against what it sees: QK^T and PV
+        pairs = sum(start + t + 1 for t in range(length))
+        flops = 2 * 2 * pairs * H * D
+        nbytes = ((start + length) * (2 * GD * itemsize
+                                      + (4 * Hkv if q8 else 0))
+                  + 2 * length * H * D * 2)
+        least = max(flops / PEAK_FLOPS_PER_S, nbytes / PEAK_BYTES_PER_S) * 1e6
+        rec = {"prefill": spec, "length": length, "start": start,
+               "steps": plan.steps(T, start, length if q8 else None),
+               "flops_least_us": flops / PEAK_FLOPS_PER_S * 1e6,
+               "bytes_least_us": nbytes / PEAK_BYTES_PER_S * 1e6}
+        for name, run in paths:
+            outs, o = run(pools, *call)
+            valid = np.asarray(o, np.float32)[:length]
+            t0 = time.perf_counter()
+            for _ in range(n):
+                outs, o = run(pools, *call)
+            jax.block_until_ready(outs)
+            rec[f"{name}_us_per_call"] = (
+                time.perf_counter() - t0) / (n * reps) * 1e6
+            rec[f"{name}_finite"] = bool(np.isfinite(valid).all())
+            if name == "kernel":
+                first = valid
+            else:
+                rec["max_abs_diff"] = (float(np.abs(first - valid).max())
+                                       if length else 0.0)
+        results.append(rec)
+        us = rec["kernel_us_per_call"]
+        print(f"  prefill {length} tokens at {start}: {us:,.1f} us/call  "
+              f"steps={rec['steps']}  products at peak "
+              f"{rec['flops_least_us']:,.1f} us, bytes at peak "
+              f"{rec['bytes_least_us']:,.1f} us = {100 * least / us:.1f} %  "
+              f"finite={rec['kernel_finite']}"
+              + (f"  xla {rec['xla_us_per_call']:,.1f} us/call, max |diff| "
+                 f"{rec['max_abs_diff']:.4f}" if "xla_us_per_call" in rec
+                 else ""), flush=True)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+        with open(f"{args.out}.prefill.json", "w", encoding="utf-8") as f:
+            json.dump({"config": doc["name"], "tree": args.tree,
+                       "plan": plan._asdict(), "max_pages": mp,
+                       "results": results}, f, indent=1)
+
 
 #: family (the configuration file's ``family``, ``llmq_tpu/models``
 #: ``FAMILIES``) -> the bench of its decode kernels. The kernels a
@@ -283,7 +425,12 @@ BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent}
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--model-file", required=True)
-    ap.add_argument("--lens", action="append", required=True)
+    ap.add_argument("--lens", action="append", default=[])
+    ap.add_argument("--prefill", action="append", default=[],
+                    metavar="LENGTH@START")
+    ap.add_argument("--pure", action="store_true",
+                    help="with --prefill over int8 pools: time the XLA "
+                         "path the kernel replaced beside it")
     ap.add_argument("--tree", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--max-pages", type=int, default=0)
@@ -301,7 +448,15 @@ def main() -> None:
     if doc.get("family") not in BENCHES:
         sys.exit(f"{args.model_file}: no kernel bench for the family "
                  f"{doc.get('family')!r}; known: {sorted(BENCHES)}")
-    BENCHES[doc["family"]](args, doc)
+    if args.prefill:
+        if doc["family"] != "llama":
+            sys.exit("--prefill: the paged prefill kernel is the family "
+                     "llama's")
+        bench_prefill(args, doc)
+    if args.lens:
+        BENCHES[doc["family"]](args, doc)
+    elif not args.prefill:
+        ap.error("give --lens or --prefill")
 
 
 if __name__ == "__main__":

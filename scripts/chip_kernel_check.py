@@ -15,8 +15,9 @@ with ``LLMQ_EXECUTOR_KV_PAGES=512 LLMQ_EXECUTOR_MAX_BATCH_SIZE=8``):
     programs the server compiled, loaded from the caches it left — and
     holds each compiled program's text against the routes the executor
     logged: a route that names a Pallas kernel must show Mosaic custom
-    calls (``_kernel_route`` did not hand back the reference), a program
-    whose routes are all ``xla`` must show none. On the shipped
+    calls, that kernel's among them (``_kernel_route`` did not hand
+    back the reference), a program whose routes are all ``xla`` must
+    show none. On the shipped
     single-chip bf16 path every attention op of ``prefill_b*``,
     ``decode_chunk`` and ``mixed_chunk`` must be a Pallas kernel.
 (b) runs one teacher-forced schedule — a prefill through every bucket
@@ -40,6 +41,7 @@ import argparse
 import dataclasses
 import json
 import os
+import re
 import sys
 import time
 from typing import Any, Dict, List, Tuple
@@ -73,6 +75,17 @@ KERNEL_GATE_LAYERS = 8
 F32_MAX_PARAMS = 2_000_000_000
 
 MOSAIC_CALL = "tpu_custom_call"
+#: Kernel function a route names (``ops/attention.kernel_routes``) ->
+#: the instruction its Mosaic call is in a compiled program's text and a
+#: device trace: the function ``ops/attention.py`` wraps in ``jax.jit``.
+KERNEL_OPS = {
+    "_kv_write_kernel": "kv_cache_write_pallas",
+    "_kv_prefill_kernel": "kv_prefill_write_pallas",
+    "_prefill_attn_kernel": "paged_prefill_attention_pallas",
+    "_prefill_attn_kernel_q8": "paged_prefill_attention_q8_pallas",
+    "_fused_kernel": "fused_decode_attention_pallas",
+    "_fused_kernel_q8": "fused_decode_attention_q8_pallas",
+}
 
 
 class CheckFailure(AssertionError):
@@ -105,6 +118,13 @@ def check_program_text(ex, on_tpu: bool, expect_all_pallas: bool) -> Dict:
                 check(n_calls > 0,
                       f"{name}: routes name {kernels} but the compiled "
                       f"program has no {MOSAIC_CALL}")
+                # ... and each named kernel's own call: a Mosaic call is
+                # named after the jitted function that makes it.
+                missing = [k for k in kernels if not re.search(
+                    rf"%{KERNEL_OPS[k.split('(')[0]]}[. ]", text)]
+                check(not missing,
+                      f"{name}: routes name {missing} but the compiled "
+                      f"program holds no call of theirs")
             else:
                 check(n_calls == 0,
                       f"{name}: routes are all xla but the compiled "

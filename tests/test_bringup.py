@@ -193,6 +193,16 @@ class TestKernelRoutes:
         assert attention.kernel_routes(decode=True, **q8_128) == {
             "decode_attention": "pallas:_fused_kernel_q8" + self.PLAN,
             "decode_write": "pallas:_fused_kernel_q8" + self.PLAN}
+        # int8-KV prefill attention follows the same page condition;
+        # its write is a scatter at any page.
+        assert attention.kernel_routes(prefill_rows=2, **q8) == {
+            "prefill_write": "xla", "prefill_attention": "xla"}
+        assert attention.kernel_routes(prefill_rows=2, **q8_128) == {
+            "prefill_write": "xla",
+            "prefill_attention": "pallas:_prefill_attn_kernel_q8"}
+        assert attention.kernel_routes(
+            prefill_rows=2, **dict(q8_128, multi_ok=False)) == {
+            "prefill_write": "xla", "prefill_attention": "xla"}
         # A head that fills neither a divisor nor a multiple of 128
         # lanes has no head window: prefill attention alone goes to XLA.
         d96 = dict(self.GEOM, n_kv_heads=4, head_dim=96)
@@ -211,9 +221,10 @@ class TestKernelRoutes:
             # 128-token chunks: 16 MiB of scratch at 4 KiB a token.
             "decode_attention": "pallas:_fused_kernel(rows=8,chunk_tokens=128)",
             "decode_write": "pallas:_fused_kernel(rows=8,chunk_tokens=128)"}),
-        # int8-KV prefill has no kernel yet (PERF.md §7, row 1).
+        # int8 pools: the write is a scatter, attention the _q8 twins.
         ("mistral-7b-v0.3-w8kv8", {
-            "prefill_write": "xla", "prefill_attention": "xla",
+            "prefill_write": "xla",
+            "prefill_attention": "pallas:_prefill_attn_kernel_q8",
             "decode_attention": "pallas:_fused_kernel_q8" + PLAN,
             "decode_write": "pallas:_fused_kernel_q8" + PLAN})])
     def test_kernel_routes_of_the_served_configurations(

@@ -385,6 +385,245 @@ class TestPrefillAttentionKernel:
         np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
 
+#: Mistral-7B-v0.3 as ``mistral-7b-v0.3-w8kv8`` serves it: 32 query heads
+#: over 8 KV heads of 128, 128-token pages, 16 a block table, 512-token
+#: slices. (H, H_kv, D, page_size, max_pages, T)
+_Q8_SERVED = (32, 8, 128, 128, 16, 512)
+
+#: case -> ((start_pos, valid length) of each slice of one call, geometry)
+_Q8_PREFILL_CASES = {
+    "fresh-150": ([(0, 150)], _Q8_SERVED),
+    "full-512": ([(0, 512)], _Q8_SERVED),
+    "continuation-512": ([(512, 300)], _Q8_SERVED),
+    "continuation-1408": ([(1408, 512)], _Q8_SERVED),
+    # the slice's last token in the last slot of a page (position 383)
+    "page-edge": ([(128, 256)], _Q8_SERVED),
+    "empty": ([(0, 0)], _Q8_SERVED),
+    "two-slices": ([(0, 301), (640, 37)], _Q8_SERVED),
+    # D = 64: two KV heads share a 128-lane window, so a tile's rows
+    # take two different rows of the scale pages
+    "two-heads-a-window": ([(100, 64)], (16, 8, 64, 128, 4, 64)),
+}
+
+
+def _q8_prefill_inputs(slices, geom, seed):
+    """int8 pools whose slices' contexts ``[0, start + length)`` went in
+    through the pure write, one block table a slice, and a query block
+    a slice: (q (B, T, H, D), pools, block tables, positions, seq_lens).
+    The pages of a block table that its context does not reach hold NaN
+    scales: what a kernel that fetched past the context would multiply
+    by."""
+    from llmq_tpu.ops.attention import paged_kv_write_prefill_q8
+    H, Hkv, D, ps, mp, T = geom
+    B = len(slices)
+    rng = np.random.default_rng(seed)
+    L, P = 2, B * mp + 1
+    pools = tuple(jnp.zeros((L, P, ps, Hkv * D), jnp.int8)
+                  for _ in range(2)) + tuple(
+        jnp.ones((L, P, Hkv, ps), jnp.bfloat16) for _ in range(2))
+    bts = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(B, mp),
+                      jnp.int32)
+    for b, (start, length) in enumerate(slices):
+        for at in range(0, start + length, T):
+            n = min(T, start + length - at)
+            k, v = (jnp.asarray(rng.standard_normal((1, T, Hkv, D)),
+                                jnp.bfloat16) for _ in range(2))
+            pos = (at + jnp.arange(T))[None].astype(jnp.int32)
+            pools = paged_kv_write_prefill_q8(
+                pools, k, v, bts[b:b + 1], pos, jnp.asarray([n], jnp.int32),
+                1)
+    dead = np.concatenate([np.asarray(bts[b, -(-(start + n) // ps):])
+                           for b, (start, n) in enumerate(slices)])
+    pools = pools[:2] + tuple(p.at[:, dead].set(jnp.nan)
+                              for p in pools[2:])
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.bfloat16)
+    starts = jnp.asarray([s for s, _ in slices], jnp.int32)
+    positions = starts[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    seq_lens = jnp.asarray([s + n for s, n in slices], jnp.int32)
+    return q, pools, bts, positions, seq_lens
+
+
+class TestPrefillWriteQ8:
+    """``paged_kv_write_prefill_q8`` writes page by page; what it leaves
+    is what a write token by token leaves."""
+
+    @pytest.mark.parametrize("slices", [
+        [(0, 150)], [(0, 512)], [(512, 300)], [(1408, 512)], [(128, 256)],
+        [(0, 0)], [(0, 301), (640, 37)], [(1, 1)], [(127, 2)],
+        [(1920, 128)]], ids=lambda s: "+".join(f"{n}at{a}" for a, n in s))
+    def test_equals_a_write_by_token(self, slices):
+        from llmq_tpu.ops.attention import paged_kv_write_prefill_q8
+        from llmq_tpu.ops.quant import quantize_kv_rows
+        Hkv, D, ps, mp, T = 8, 16, 128, 16, 512
+        B = len(slices)
+        rng = np.random.default_rng(B + slices[0][0])
+        L, P = 2, B * mp + 1
+        pools = tuple(jnp.asarray(rng.integers(-127, 128, (L, P, ps, Hkv * D)),
+                                  jnp.int8) for _ in range(2)) + tuple(
+            jnp.asarray(rng.uniform(0.01, 0.02, (L, P, Hkv, ps)),
+                        jnp.bfloat16) for _ in range(2))
+        bts = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(B, mp),
+                          jnp.int32)
+        k, v = (jnp.asarray(rng.standard_normal((B, T, Hkv, D)),
+                            jnp.bfloat16) for _ in range(2))
+        starts = jnp.asarray([a for a, _ in slices], jnp.int32)
+        positions = starts[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+        lengths = jnp.asarray([n for _, n in slices], jnp.int32)
+        got = paged_kv_write_prefill_q8(pools, k, v, bts, positions, lengths,
+                                        1)
+        want = [np.array(p) for p in pools]
+        (kq, ks), (vq, vs) = quantize_kv_rows(k), quantize_kv_rows(v)
+        for b, (start, n) in enumerate(slices):
+            for t in range(n):
+                page = int(bts[b, (start + t) // ps])
+                slot = (start + t) % ps
+                want[0][1, page, slot] = np.asarray(kq[b, t]).reshape(-1)
+                want[1][1, page, slot] = np.asarray(vq[b, t]).reshape(-1)
+                want[2][1, page, :, slot] = np.asarray(ks[b, t])
+                want[3][1, page, :, slot] = np.asarray(vs[b, t])
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+
+
+class TestPrefillAttentionKernelQ8:
+    """``paged_prefill_attention_q8_pallas`` against what served int8
+    prefill before it: ``_dequant_window`` + the blockwise softmax."""
+
+    @pytest.mark.parametrize("case", sorted(_Q8_PREFILL_CASES))
+    def test_matches_dequantised_blockwise(self, case, monkeypatch):
+        from llmq_tpu.ops.attention import (_dequant_window,
+                                            dispatch_prefill_attention_q8)
+        from llmq_tpu.ops.pallas.prefill_attention import prefill_tile_plan
+        slices, geom = _Q8_PREFILL_CASES[case]
+        q, pools, bts, positions, seq_lens = _q8_prefill_inputs(
+            slices, geom, seed=len(case))
+        H, Hkv, D, ps, mp, T = geom
+        tb = prefill_tile_plan(T, H, Hkv, D, ps, mp, 1, q_itemsize=2).q_block
+        # The reference gathers the whole block table, dead pages too:
+        # over pools whose NaN scales are made finite.
+        clean = pools[:2] + tuple(jnp.nan_to_num(p) for p in pools[2:])
+        ref = blockwise_prefill_attention(
+            q, _dequant_window(clean[0], clean[2], 1, bts, D),
+            _dequant_window(clean[1], clean[3], 1, bts, D), positions,
+            seq_lens)
+        monkeypatch.setenv("LLMQ_PALLAS", "interpret")
+        out = dispatch_prefill_attention_q8(
+            q, pools, bts, positions, seq_lens, 1, multi_ok=True)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+        assert np.isfinite(out).all()
+        for b, (_, n) in enumerate(slices):
+            np.testing.assert_allclose(out[b, :n], ref[b, :n],
+                                       atol=3e-2, rtol=3e-2)
+            # q blocks wholly past the valid rows are not computed
+            assert not out[b, -(-n // tb) * tb:].any()
+
+    def test_steps_follow_the_slice(self):
+        """The plan's count of the kernel's loop steps: a 150-token
+        prompt in a 512-token slice runs two q blocks of one chunk
+        each, an empty slice none, and a full slice at the table's end
+        all of its chunks."""
+        from llmq_tpu.ops.pallas.prefill_attention import prefill_tile_plan
+        H, Hkv, D, ps, mp, T = _Q8_SERVED
+        plan = prefill_tile_plan(T, H, Hkv, D, ps, mp, 1, q_itemsize=2)
+        assert (plan.q_block, plan.chunk_tokens) == (128, 256)
+        assert plan.steps(T, 0, 150) == 2
+        assert plan.steps(T, 0, 0) == 0
+        assert plan.steps(T, 0, 512) == 1 + 1 + 2 + 2
+        assert plan.steps(T, 1408, 512) == 6 + 7 + 7 + 8
+        assert plan.steps(T, 1408, 512) == plan.steps(T, 1408)
+
+    def test_forward_mixed_kernel_route_equals_pure(self, monkeypatch):
+        """One mixed step over int8 pools — eight decode rows and two
+        prompt slices of different lengths, one of them a continuation
+        — through the kernels (interpret mode) and through pure JAX:
+        the same logits and the same four pools."""
+        from llmq_tpu.models.llama import (forward_mixed, get_config,
+                                           init_kv_pages, init_params)
+        from llmq_tpu.ops.attention import kernel_routes
+        cfg = get_config("llama3-tiny", max_seq_len=512, dim=256,
+                         n_heads=16, n_kv_heads=8, n_layers=2,
+                         pallas_batched_prefill=True)
+        ps, mp, B, S, T = 128, 4, 8, 2, 128
+        params = init_params(jax.random.PRNGKey(33), cfg)
+        rng = np.random.default_rng(33)
+        bts = jnp.asarray(1 + np.arange((B + S) * mp).reshape(B + S, mp),
+                          jnp.int32)
+        dec_pos = jnp.asarray([1, 127, 128, 200, 255, 256, 300, 511],
+                              jnp.int32)
+        pf_start = np.asarray([0, 130])
+        pf_len = jnp.asarray([70, 128], jnp.int32)
+        args = (jnp.asarray(rng.integers(3, cfg.vocab_size, B), jnp.int32),
+                dec_pos)
+        pf_args = (
+            jnp.asarray(rng.integers(3, cfg.vocab_size, (S, T)), jnp.int32),
+            jnp.asarray(pf_start[:, None] + np.arange(T)[None], jnp.int32),
+            pf_len, bts[B:])
+
+        def cache():
+            # history under the decode rows and the continuing slice,
+            # through the pure write (unit-normal K/V, as a model's)
+            from llmq_tpu.ops.attention import paged_kv_write_prefill_q8
+            rng2 = np.random.default_rng(7)
+            c = init_kv_pages(cfg, 1 + (B + S) * mp, ps, dtype=jnp.int8)
+            pools = (c["k"], c["v"], c["k_scale"], c["v_scale"])
+            held = jnp.asarray(list(np.asarray(dec_pos)) + list(pf_start),
+                               jnp.int32)
+            pos = jnp.broadcast_to(jnp.arange(512, dtype=jnp.int32),
+                                   (B + S, 512))
+            for layer in range(cfg.n_layers):
+                k, v = (jnp.asarray(rng2.standard_normal(
+                    (B + S, 512, 8, cfg.head_dim)), jnp.bfloat16)
+                    for _ in range(2))
+                pools = paged_kv_write_prefill_q8(pools, k, v, bts, pos,
+                                                  held, layer)
+            return dict(zip(("k", "v", "k_scale", "v_scale"), pools))
+
+        got = {}
+        for mode in ("0", "interpret"):
+            monkeypatch.setenv("LLMQ_PALLAS", mode)
+            jax.clear_caches()      # the route is read at trace time
+            got[mode] = forward_mixed(params, cfg, *args, cache(),
+                                      bts[:B], *pf_args)
+        jax.clear_caches()
+        routes = kernel_routes(
+            batch=B, page_size=ps, max_pages=mp, n_kv_heads=8,
+            head_dim=cfg.head_dim, kv_itemsize=1, quant_kv=True,
+            enabled=True, multi_ok=True, decode=True, prefill_rows=S)
+        assert routes["prefill_attention"] == (
+            "pallas-interpret:_prefill_attn_kernel_q8")
+        pure, kern = got["0"], got["interpret"]
+        # The slices' logits, which the prefill kernel feeds, within the
+        # _q8 kernels' tolerance; the decode rows' (the fused decode
+        # kernel alone: they never see a slice) within what
+        # ``test_model_dispatch_under_interpret`` holds logits to.
+        for a, b, tol in zip(pure[:2], kern[:2], (5e-2, 3e-2)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=tol, rtol=tol)
+        # Layer 0's K/V go in before any attention: bit for bit. Layer
+        # 1's follow layer 0's attention output, which the two routes
+        # round differently: held as the values the pools stand for.
+        def values(cache, name):
+            # (page 0 is reserved: nothing a row owns lives there)
+            from llmq_tpu.ops.quant import dequantize_kv
+            x, sc = cache[name][:, 1:], cache[name + "_scale"][:, 1:]
+            deq = dequantize_kv(x.reshape(*x.shape[:3], 8, cfg.head_dim),
+                                jnp.moveaxis(sc, 2, 3), jnp.float32)
+            return [np.asarray(a, np.float32) for a in (x, sc, deq)]
+
+        for name in ("k", "v"):
+            for a, b in zip(values(pure[2], name)[:2],
+                            values(kern[2], name)[:2]):
+                np.testing.assert_array_equal(a[0], b[0])
+            # (a projection of the layer's output, as the logits are:
+            # 11 values of 1.3 million differ by 0.03-0.042)
+            np.testing.assert_allclose(
+                values(kern[2], name)[2], values(pure[2], name)[2],
+                atol=5e-2, rtol=5e-2)
+
+
 #: The bf16 serving geometries: name -> (H, H_kv, D, page_size,
 #: max_pages, grid steps of the block-diagonal plan this one replaced
 #: for a 256-token slice: (T / qb) x (max_pages / ppc) at the qb / ppc

@@ -1,7 +1,7 @@
-"""The TPU compiler's verdict without a chip: the serving path's prefill
-attention kernel, its two fused decode kernels, and the int8-KV prefill
-program at Mistral-7B-v0.3's widths, compiled for a DESCRIBED v5e at
-the served sizes.
+"""The TPU compiler's verdict without a chip: the serving path's two
+prefill attention kernels, its two fused decode kernels, and the int8-KV
+prefill program at Mistral-7B-v0.3's widths, compiled for a DESCRIBED
+v5e at the served sizes.
 
 Interpret mode (tests/test_pallas.py) checks the kernel's arithmetic
 and cannot see what the TPU compiler refuses: a lane slice off the
@@ -63,6 +63,40 @@ def test_prefill_attention_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+#: name -> (T, H, H_kv, D, page_size, max_pages, layers, pool pages):
+#: int8 pools as ``mistral-7b-v0.3-w8kv8`` serves them, and eight KV
+#: heads of 64 (llama3-1b's: two share a lane window, so a tile takes
+#: two rows of a scale page).
+_Q8_GEOMETRIES = {
+    "mistral-7b-w8kv8-b512": (512, 32, 8, 128, 128, 16, 32, 832),
+    "llama3-1b-kv8-b512": (512, 32, 8, 64, 128, 16, 16, 264),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_Q8_GEOMETRIES))
+def test_prefill_attention_q8_compiles_for_v5e(one_chip, name):
+    """The int8 twin: int8 page DMAs beside (8, 128) bf16 scale pages,
+    the int8 -> bf16 conversion of a 128-lane window, a scalar-prefetch
+    index map for the q block. One Mosaic call, named so that a device
+    trace and the benchmark's ``PREFILL_ATTN`` pattern find it."""
+    from llmq_tpu.ops.pallas.prefill_attention import (
+        paged_prefill_attention_q8_pallas)
+
+    T, H, Hkv, D, ps, mp, L, P = _Q8_GEOMETRIES[name]
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = ((arg((L, P, ps, Hkv * D), jnp.int8),) * 2
+             + (arg((L, P, Hkv, ps), jnp.bfloat16),) * 2)
+    text = jax.jit(paged_prefill_attention_q8_pallas).lower(
+        arg((T, H, D), jnp.bfloat16), pools, arg((mp,)), arg(()), arg(()),
+        arg(())).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%paged_prefill_attention_q8_pallas" in text
+    assert len(text) < 100_000
+
+
 #: name -> (rows, H, H_kv, D, page_size, max_pages, layers, pool pages,
 #: int8 KV): the benchmark's two configurations as served, the smoke's
 #: llama3-1b, and a batch of one tile.
@@ -109,7 +143,7 @@ def test_fused_decode_compiles_for_v5e(one_chip, name):
     assert len(text) < 100_000       # the v3 kernel's was 154-427 kB
 
 
-def test_int8_kv_prefill_program_stays_small_for_v5e(one_chip):
+def test_int8_kv_prefill_program_stays_small_for_v5e(one_chip, monkeypatch):
     """The int8-KV prefill program (``forward_prefill(last_only=True)``,
     what ``prefill_b512`` and the benchmark's logits check run) at
     Mistral-7B-v0.3's widths and FULL depth, as ``mistral-7b-v0.3-w8kv8``
@@ -120,10 +154,17 @@ def test_int8_kv_prefill_program_stays_small_for_v5e(one_chip):
     (PERF.md, PR 26): rolled it measured 6-21 s, 8.3 MB and 12 MB. The
     limits below sit between the two, so the old graph cannot come back
     unseen; and the temporaries stay under ONE pool, which is how a
-    carried pool that XLA had begun to copy would show."""
+    carried pool that XLA had begun to copy would show. Since PR 33 the
+    loop body holds the int8 prefill attention kernel, which only READS
+    the carried pools (routed here as on the chip: the backend this
+    process sees is the CPU): 7 s, 8.7 MB and 1.4 MB."""
     import time
 
     from llmq_tpu.models import llama
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
 
     cfg = llama.get_config("mistral-7b-v0.3", max_seq_len=2048,
                            pallas_batched_prefill=True)
@@ -154,6 +195,7 @@ def test_int8_kv_prefill_program_stays_small_for_v5e(one_chip):
         arg(1, cfg.max_seq_len // page)).compile()
     seconds = time.perf_counter() - t0
     mem = compiled.memory_analysis()
+    assert "%paged_prefill_attention_q8_pallas" in compiled.as_text()
     assert seconds < 90.0, seconds
     assert mem.generated_code_size_in_bytes < 40e6
     assert mem.temp_size_in_bytes < pool_bytes / 8, (
