@@ -46,7 +46,7 @@ import time
 import weakref
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -3185,18 +3185,33 @@ class InferenceEngine:
     def _note_moe(self, stats, span) -> None:
         """Fold a fetched chunk's routed-layer counters (they came over
         with its tokens) into ``get_stats()["moe"]`` and onto the span
-        that covers the commit: what a capture's readers sum. The 128
-        loads ride as one string (``n3_0_5_...``: the profiler reads a
-        string that starts with a digit as a number), and only while a
-        capture is held."""
+        that covers the commit: what a capture's readers sum. The
+        held experts' loads ride as one string (``n3_0_5_...``: the
+        profiler reads a string that starts with a digit as a number),
+        and only while a capture is held."""
         if stats is None:
             return
         st = np.asarray(stats, np.int64)
         self._moe = st if self._moe is None else self._moe + st
         if capture_held():
-            span.note(moe_pairs=int(st[:-2].sum()),
-                      moe_touched=int(st[-2]), moe_layer_runs=int(st[-1]),
-                      moe_load="n" + "_".join(map(str, st[:-2].tolist())))
+            c = self._moe_counts(st)
+            span.note(moe_pairs=int(c["load"].sum()),
+                      moe_touched=c["touched"], moe_layer_runs=c["runs"],
+                      moe_zero_slots=c["zero_slots"],
+                      moe_away_slots=c["away_slots"],
+                      moe_load="n" + "_".join(map(str, c["load"].tolist())))
+
+    def _moe_counts(self, st: np.ndarray) -> Dict[str, Any]:
+        """A family's step counters by name, by the layout the family
+        states (``models/__init__.py`` ``step_stats_layout``): ``load``
+        an array, the others ints, 0 for one the family does not
+        count."""
+        layout = getattr(self.executor, "step_stats_layout", None) or {}
+        first, end = layout["load"]
+        out: Dict[str, Any] = {"load": st[first:end]}
+        for name in ("touched", "runs", "zero_slots", "away_slots"):
+            out[name] = int(st[layout[name]]) if name in layout else 0
+        return out
 
     def _commit_chunk(self, infl: _InflightChunk, out, device_s: float,
                       readback_s: float, overlapped_s: float) -> None:
@@ -4275,18 +4290,23 @@ class InferenceEngine:
                     int(self._mixed_cfg.prefill_token_budget),
             }
         if self._moe is not None:
-            load, touched, runs = (self._moe[:-2], int(self._moe[-2]),
-                                   int(self._moe[-1]))
+            c = self._moe_counts(self._moe)
+            load, runs = c["load"], c["runs"]
             out["moe"] = {
-                # (token, expert) pairs multiplied, routed-layer runs
-                # (steps x routed layers), distinct experts a run
-                # touched on average, and the busiest expert's tokens
-                # over the mean's.
+                # (token, expert) pairs multiplied HERE, routed-layer
+                # runs (steps x routed layers), distinct held experts a
+                # run touched on average, the busiest held expert's
+                # tokens over the mean's, and the slots that went to
+                # zero-compute experts and to experts another chip
+                # holds (0 for a family with neither).
                 "pairs": int(load.sum()), "layer_runs": runs,
-                "experts_touched_mean": touched / runs if runs else 0.0,
+                "experts_touched_mean": (c["touched"] / runs if runs
+                                         else 0.0),
                 "load_max_over_mean": (float(load.max() / load.mean())
                                        if load.sum() else 0.0),
                 "load": load.tolist(),
+                "zero_slots": c["zero_slots"],
+                "away_slots": c["away_slots"],
             }
         if self._tiering is not None:
             # Tiered KV plane (docs/tiering.md): residency per tier,

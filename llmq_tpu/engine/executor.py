@@ -776,9 +776,10 @@ class VerifyHandle:
 
 
 def _is_quantized_tree(params) -> bool:
-    """int8 weights? Every family's tree has ``layers.wq``."""
+    """int8 weights? Every family's tree has the attention's output
+    projection at ``layers.wo``."""
     from llmq_tpu.ops.quant import is_quantized
-    return is_quantized(params["layers"]["wq"])
+    return is_quantized(params["layers"]["wo"])
 
 
 def _named(fn: Callable, name: str) -> Callable:
@@ -863,6 +864,9 @@ class JaxExecutor:
         #: output (``None`` at 0: no output, the same program as
         #: before there were any).
         n_stats = fam.step_stats_size(model_cfg)
+        #: ... and where each lies (``models/__init__.py``): what the
+        #: engine reads a fetched chunk's counters by.
+        self.step_stats_layout = fam.step_stats_layout(model_cfg)
 
         def forward_decode(params, cfg, tok, pos, cache, bts, active=None,
                            acc=None):

@@ -25,7 +25,16 @@ A family is a module of this package that defines
   Hugging Face checkpoint directory into the family's tree;
 - ``step_stats_size(cfg)``: how many int32 counters a forward pass
   returns after the cache when called with ``stats=True`` (0: the
-  family counts nothing and takes no such argument);
+  family counts nothing and takes no such argument), and
+  ``step_stats_layout(cfg)``: where each lies — ``{"load": (first,
+  end)}`` the tokens each held expert received, and the indices of
+  ``"touched"`` (experts that received any, summed over the routed
+  layers run), ``"runs"`` (routed layers run) and, for a family that
+  counts them, ``"zero_slots"`` (slots that chose a zero-compute
+  expert) and ``"away_slots"`` (slots whose expert another chip
+  holds); ``{}`` for a family that counts nothing. The engine reads
+  the counters by this and by nothing else
+  (``get_stats()["moe"]``);
 - ``routes(cfg, cache, *, batch, page_size, max_pages, decode,
   prefill_rows)``: which implementation each attention op of a program
   takes (``ops/attention.kernel_routes``'s form);
@@ -54,6 +63,7 @@ from llmq_tpu.models.checkpoint import save_checkpoint, load_checkpoint  # noqa:
 FAMILIES: Dict[str, str] = {
     "llama": "llmq_tpu.models.llama",
     "deepseek_v3": "llmq_tpu.models.deepseek_v3",
+    "longcat_flash": "llmq_tpu.models.longcat_flash",
 }
 
 

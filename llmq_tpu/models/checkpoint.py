@@ -157,8 +157,9 @@ def import_hf_deepseek_v3(model_dir: str, cfg) -> Params:
     (``param_shapes``). The tensor names are from memory of the public
     ``modeling_deepseek_v3.py`` (this sandbox has no network; the test
     builds a synthetic checkpoint under the same names):
-    ``self_attn.{q_proj, kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj,
-    o_proj}``, ``mlp.{gate,up,down}_proj`` in the dense layers, and in
+    ``self_attn.{q_proj (or, with ``q_lora_rank``, q_a_proj,
+    q_a_layernorm, q_b_proj), kv_a_proj_with_mqa, kv_a_layernorm,
+    kv_b_proj, o_proj}``, ``mlp.{gate,up,down}_proj`` in the dense layers, and in
     the routed ones ``mlp.gate.weight``,
     ``mlp.gate.e_score_correction_bias``, ``mlp.experts.N.*`` and
     ``mlp.shared_experts.*``. q_proj's and kv_a_proj_with_mqa's rotary
@@ -193,14 +194,20 @@ def import_hf_deepseek_v3(model_dir: str, cfg) -> Params:
                          for e in range(E)])
         return gu, down
 
+    def rope_q(w):
+        return _deinterleave_rope(w, cfg.n_heads, dr)
+
     per_layer = [experts(i) for i in routed]
     params: Params = {
         "embed": jnp.asarray(t["model.embed_tokens.weight"], dtype=dt),
         "lm_head": jnp.asarray(t["lm_head.weight"].T, dtype=dt),
         "final_norm": jnp.asarray(t["model.norm.weight"], dtype=dt),
         "layers": {
-            "wq": stack(every, attn + "q_proj.weight",
-                        lambda w: _deinterleave_rope(w, cfg.n_heads, dr)),
+            **({"wq": stack(every, attn + "q_proj.weight", rope_q)}
+               if not cfg.q_lora_rank else
+               {"wq_a": stack(every, attn + "q_a_proj.weight"),
+                "q_norm": vec(every, attn + "q_a_layernorm.weight"),
+                "wq_b": stack(every, attn + "q_b_proj.weight", rope_q)}),
             "wkv_a": stack(every, attn + "kv_a_proj_with_mqa.weight",
                            lambda w: _deinterleave_rope(w, 1, dr)),
             "kv_norm": vec(every, attn + "kv_a_layernorm.weight"),
@@ -230,6 +237,87 @@ def import_hf_deepseek_v3(model_dir: str, cfg) -> Params:
         },
     }
     log.info("imported HF deepseek_v3 from %s (%d tensors)", model_dir,
+             len(t))
+    return params
+
+
+def import_hf_longcat_flash(model_dir: str, cfg) -> Params:
+    """A local Hugging Face ``longcat_flash`` checkpoint directory
+    (safetensors) into ``models/longcat_flash.py``'s tree
+    (``param_shapes``): of each routed layer the HELD experts alone
+    (``cfg.held``), of the vocabulary the first ``cfg.vocab_size`` rows.
+    The tensor names are from memory of the public
+    ``modeling_longcat_flash.py`` (this sandbox has no network; the test
+    builds a synthetic checkpoint under the same names): a layer holds
+    ``self_attn.{0,1}.{q_a_proj, q_a_layernorm, q_b_proj,
+    kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj}``,
+    ``input_layernorm.{0,1}``, ``post_attention_layernorm.{0,1}``,
+    ``mlps.{0,1}.{gate,up,down}_proj`` and ``mlp.router.classifier``,
+    ``mlp.router.e_score_correction_bias``, ``mlp.experts.N.*``. The
+    rotary rows of q_b_proj and kv_a_proj_with_mqa are de-interleaved as
+    ``deepseek_v3``'s are."""
+    t = _read_safetensors(model_dir)
+    dt, dr, V = cfg.dtype, cfg.qk_rope_head_dim, cfg.vocab_size
+    pairs = [(l, j) for l in range(cfg.n_layers) for j in (0, 1)]
+
+    def stack(fmt: str, transform=None) -> jnp.ndarray:
+        mats = []
+        for l, j in pairs:
+            w = t[f"model.layers.{l}." + fmt.format(j=j)]
+            mats.append((transform(w) if transform else w).T)
+        return jnp.asarray(np.stack(mats), dtype=dt)
+
+    def vec(fmt: str) -> jnp.ndarray:
+        return jnp.asarray(np.stack(
+            [t[f"model.layers.{l}." + fmt.format(j=j)] for l, j in pairs]),
+            dtype=dt)
+
+    def experts(l: int) -> tuple:
+        pre = f"model.layers.{l}.mlp.experts."
+        held = range(*cfg.held)
+        gu = np.stack([np.concatenate(
+            [t[f"{pre}{e}.gate_proj.weight"].T,
+             t[f"{pre}{e}.up_proj.weight"].T], axis=1) for e in held])
+        return gu, np.stack([t[f"{pre}{e}.down_proj.weight"].T
+                             for e in held])
+
+    per_layer = [experts(l) for l in range(cfg.n_layers)]
+    router = "model.layers.{l}.mlp.router."
+    attn = "self_attn.{j}."
+    params: Params = {
+        "embed": jnp.asarray(t["model.embed_tokens.weight"][:V], dtype=dt),
+        "lm_head": jnp.asarray(t["lm_head.weight"][:V].T, dtype=dt),
+        "final_norm": jnp.asarray(t["model.norm.weight"], dtype=dt),
+        "layers": {
+            "wq_a": stack(attn + "q_a_proj.weight"),
+            "q_norm": vec(attn + "q_a_layernorm.weight"),
+            "wq_b": stack(attn + "q_b_proj.weight",
+                          lambda w: _deinterleave_rope(w, cfg.n_heads, dr)),
+            "wkv_a": stack(attn + "kv_a_proj_with_mqa.weight",
+                           lambda w: _deinterleave_rope(w, 1, dr)),
+            "kv_norm": vec(attn + "kv_a_layernorm.weight"),
+            "wkv_b": stack(attn + "kv_b_proj.weight"),
+            "wo": stack(attn + "o_proj.weight"),
+            "attn_norm": vec("input_layernorm.{j}.weight"),
+        },
+        "ffn": {"w_gate": stack("mlps.{j}.gate_proj.weight"),
+                "w_up": stack("mlps.{j}.up_proj.weight"),
+                "w_down": stack("mlps.{j}.down_proj.weight"),
+                "mlp_norm": vec("post_attention_layernorm.{j}.weight")},
+        "moe": {
+            "router": jnp.asarray(np.stack(
+                [t[router.format(l=l) + "classifier.weight"].T
+                 for l in range(cfg.n_layers)]), dtype=dt),
+            "router_bias": jnp.asarray(np.stack(
+                [t[router.format(l=l) + "e_score_correction_bias"]
+                 for l in range(cfg.n_layers)]), dtype=jnp.float32),
+            "we_gate_up": tuple(jnp.asarray(g, dtype=dt)
+                                for g, _ in per_layer),
+            "we_down": tuple(jnp.asarray(d, dtype=dt)
+                             for _, d in per_layer),
+        },
+    }
+    log.info("imported HF longcat_flash from %s (%d tensors)", model_dir,
              len(t))
     return params
 

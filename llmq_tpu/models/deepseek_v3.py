@@ -50,9 +50,12 @@ each rounding of the stream to bf16 (0.2-0.4 % of it) is as wide as a
 tenth of the mean gap between a token's 6th and 7th score. The stream
 is a few kilobytes a token; the matmuls' operands stay bf16.
 
-``q_lora_rank`` (a low-rank query), int8 weights, an int8 cache, a mesh
-and speculation's verify window are not written for this family: each
-is refused with an error that names the setting (``check_serving``).
+The attention itself — the cache's row, the absorbed decode, the
+expanded prefill, the kernels' routes, and ``q_lora_rank`` (a low-rank
+query) — is ``models/latent.py``'s, which ``models/longcat_flash.py``
+shares. Int8 weights, an int8 cache, a mesh and speculation's verify
+window are not written for this family: each is refused with an error
+that names the setting (``check_serving``).
 """
 
 from __future__ import annotations
@@ -64,18 +67,25 @@ from typing import Any, ClassVar, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from llmq_tpu.models.latent import (  # noqa: F401
+    LatentDims, attn_norm_count, attn_norm_leaves, attn_param_shapes,
+    draw_groups, init_latent_pool, latent_decode_attention,
+    latent_prefill_attention, latent_write_prefill, param_count, routes)
+from llmq_tpu.models.latent import prod as _prod
+from llmq_tpu.models.latent import swiglu as _mlp
+from llmq_tpu.models.latent import decode_geometry as _decode_geometry
+from llmq_tpu.models.latent import qkv as _qkv
 from llmq_tpu.ops.moe import route, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import embed_lookup
-from llmq_tpu.ops.rope import apply_rope, rope_cos_sin
+from llmq_tpu.ops.rope import rope_cos_sin
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jnp.ndarray]
-NEG = -1e30
 
 
 @dataclass(frozen=True)
-class DeepseekV3Config:
+class DeepseekV3Config(LatentDims):
     FAMILY: ClassVar[str] = "deepseek_v3"
     name: str = "deepseek-v3-tiny"
     vocab_size: int = 512
@@ -86,7 +96,9 @@ class DeepseekV3Config:
     qk_nope_head_dim: int = 32
     qk_rope_head_dim: int = 16
     v_head_dim: int = 32
-    q_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None   # a low-rank query (models/latent.py)
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     ffn_dim: int = 256                 # the dense layers' SwiGLU
     moe_ffn_dim: int = 64              # one expert's SwiGLU
     n_routed_experts: int = 16
@@ -101,28 +113,13 @@ class DeepseekV3Config:
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self) -> None:
-        if self.q_lora_rank is not None:
-            raise ValueError(
-                f"model {self.name!r}: q_lora_rank={self.q_lora_rank} (a "
-                f"low-rank query projection) is not supported; only "
-                f"q_lora_rank null is")
         if not 0 <= self.first_k_dense <= self.n_layers:
             raise ValueError(f"model {self.name!r}: first_k_dense "
                              f"{self.first_k_dense} of {self.n_layers}")
 
     @property
-    def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.qk_rope_head_dim
-
-    @property
     def n_routed_layers(self) -> int:
         return self.n_layers - self.first_k_dense
-
-    @property
-    def latent_width(self) -> int:
-        """Lanes of one cached row: the latent and the RoPE key, rounded
-        up to whole 128-lane tiles."""
-        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
 
 def deepseek_v3_tiny(**kw) -> DeepseekV3Config:
@@ -171,10 +168,16 @@ def import_hf(model_dir: str, cfg: DeepseekV3Config,
     return import_hf_deepseek_v3(model_dir, cfg)
 
 
+def step_stats_layout(cfg: DeepseekV3Config) -> Dict[str, Any]:
+    """Where each int32 counter of a forward pass with ``stats=True``
+    lies: the tokens each expert received (E), the experts that
+    received any summed over the routed layers, and the routed layers
+    run."""
+    E = cfg.n_routed_experts
+    return {"load": (0, E), "touched": E, "runs": E + 1}
+
+
 def step_stats_size(cfg: DeepseekV3Config) -> int:
-    """int32 counters a forward pass returns with ``stats=True``: the
-    tokens each expert received (E), the experts that received any
-    summed over the routed layers, and the routed layers run."""
     return cfg.n_routed_experts + 2
 
 
@@ -207,17 +210,12 @@ def param_shapes(cfg: DeepseekV3Config) -> Dict[str, Dict[str, tuple]]:
     OWN, one a routed layer (``params["moe"]["we_gate_up"]`` is a tuple
     of them) — a slice of a stacked 5.6 GB leaf handed to the grouped
     product was copied, 0.8 GB a layer of temporaries."""
-    L, D, H, V = cfg.n_layers, cfg.dim, cfg.n_heads, cfg.vocab_size
-    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    L, D, V = cfg.n_layers, cfg.dim, cfg.vocab_size
     Ld, Lm = cfg.first_k_dense, cfg.n_routed_layers
     E, Fe, F = cfg.n_routed_experts, cfg.moe_ffn_dim, cfg.ffn_dim
     Fs = cfg.n_shared_experts * Fe
     return {
-        "layers": {"wq": ((L, D, H * (dn + dr)), D),
-                   "wkv_a": ((L, D, r + dr), D),
-                   "wkv_b": ((L, r, H * (dn + dv)), r),
-                   "wo": ((L, H * dv, D), H * dv)},
+        "layers": attn_param_shapes(cfg, L),
         "dense": {"w_gate": ((Ld, D, F), D), "w_up": ((Ld, D, F), D),
                   "w_down": ((Ld, F, D), F)},
         "moe": {"router": ((Lm, D, E), D),
@@ -235,8 +233,7 @@ def norm_leaves(cfg: DeepseekV3Config) -> Params:
     L, D, Lm = cfg.n_layers, cfg.dim, cfg.n_routed_layers
     return {"layers": {"attn_norm": jnp.ones((L, D), cfg.dtype),
                        "mlp_norm": jnp.ones((L, D), cfg.dtype),
-                       "kv_norm": jnp.ones((L, cfg.kv_lora_rank),
-                                           cfg.dtype)},
+                       **attn_norm_leaves(cfg, L)},
             "moe": {"router_bias": jnp.zeros((Lm, cfg.n_routed_experts),
                                              jnp.float32)},
             "final_norm": jnp.ones((D,), cfg.dtype)}
@@ -260,21 +257,8 @@ def assemble(cfg: DeepseekV3Config, drawn: Dict[str, Dict[str, Any]]
 def init_params(key: jax.Array, cfg: DeepseekV3Config) -> Params:
     """Random-init parameter tree, N(0, 1 / fan_in) as the Llama
     block's."""
-    shapes = param_shapes(cfg)
-    n = sum(len(g) for g in shapes.values())
-    keys = iter(jax.random.split(key, n))
-
-    def draw(k, shape, fan_in):
-        return (jax.random.normal(k, shape, jnp.float32)
-                * fan_in ** -0.5).astype(cfg.dtype)
-
-    drawn = {g: {name: draw(next(keys), shape, fan_in)
-                 for name, (shape, fan_in) in leaves.items()}
-             for g, leaves in shapes.items() if g != "experts"}
-    drawn["experts"] = {
-        name: [draw(k, shape, fan_in)
-               for k in jax.random.split(next(keys), cfg.n_routed_layers)]
-        for name, (shape, fan_in) in shapes["experts"].items()}
+    drawn = draw_groups(key, param_shapes(cfg), cfg.dtype,
+                        cfg.n_routed_layers)
     return assemble(cfg, drawn)
 
 
@@ -282,16 +266,12 @@ def init_params_quantized(key: jax.Array, cfg: DeepseekV3Config) -> Params:
     check_serving(cfg, quantization="int8")
 
 
-def param_count(params: Params) -> int:
-    return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
-
-
 def param_count_analytic(cfg: DeepseekV3Config) -> int:
     """Parameters HELD, from the configuration alone."""
     n = sum(_prod(shape) * (cfg.n_routed_layers if g == "experts" else 1)
             for g, leaves in param_shapes(cfg).items()
             for shape, _f in leaves.values())
-    fixed = (cfg.n_layers * (2 * cfg.dim + cfg.kv_lora_rank) + cfg.dim
+    fixed = (cfg.n_layers * (2 * cfg.dim + attn_norm_count(cfg)) + cfg.dim
              + cfg.n_routed_layers * cfg.n_routed_experts)
     return n + fixed
 
@@ -302,13 +282,6 @@ def active_param_count(cfg: DeepseekV3Config) -> int:
     idle = cfg.n_routed_experts - cfg.n_experts_per_tok
     return (param_count_analytic(cfg)
             - cfg.n_routed_layers * idle * 3 * cfg.dim * cfg.moe_ffn_dim)
-
-
-def _prod(shape) -> int:
-    n = 1
-    for d in shape:
-        n *= d
-    return n
 
 
 def weight_bytes(cfg: DeepseekV3Config) -> int:
@@ -331,175 +304,7 @@ def init_kv_pages(cfg: DeepseekV3Config, num_pages: int, page_size: int,
     reserved as in every pool of this repo."""
     if dtype is not None and jnp.dtype(dtype) == jnp.int8:
         check_serving(cfg, kv_quantization="int8")
-    return {"ckv": jnp.zeros((cfg.n_layers, num_pages, page_size,
-                              cfg.latent_width), dtype or cfg.dtype)}
-
-
-# -- kernel routes ------------------------------------------------------------
-
-def _route(cfg: DeepseekV3Config, page_size: int):
-    """(use the latent kernels, interpret) at this geometry: the
-    shared LLMQ_PALLAS policy, plus what the kernels need of the
-    shapes."""
-    from llmq_tpu.ops.attention import _kernel_route
-    ok = cfg.kv_lora_rank % 128 == 0 and page_size % 8 == 0
-    return _kernel_route(cfg.latent_width, extra_ok=ok)
-
-
-def routes(cfg: DeepseekV3Config, cache: KVCache, *, batch: int,
-           page_size: int, max_pages: int, decode: bool = False,
-           prefill_rows: int = 0) -> Dict[str, str]:
-    """Which implementation each attention op of one serving program
-    takes (``ops/attention.kernel_routes``'s form): the latent decode
-    kernel with its plan, and XLA for prefill."""
-    from llmq_tpu.ops.pallas.latent_decode import pages_per_chunk
-    out: Dict[str, str] = {}
-    if prefill_rows:
-        out["prefill_write"] = out["prefill_attention"] = "xla"
-    if decode:
-        use, interp = _route(cfg, page_size)
-        tag = f"pallas{'-interpret' if interp else ''}:"
-        chunk = pages_per_chunk(page_size, max_pages) * page_size
-        out["decode_write"] = (tag + "_latent_write_kernel" if use
-                               else "xla")
-        out["decode_attention"] = (
-            f"{tag}_latent_decode_kernel(rows=1,chunk_tokens={chunk})"
-            if use else "xla")
-    return out
-
-
-def _jit_latent(name: str):
-    from llmq_tpu.ops.attention import _kernel_jit
-
-    def make():
-        from llmq_tpu.ops.pallas import latent_decode
-        if name == "latent_write":
-            return jax.jit(latent_decode.latent_write_pallas,
-                           static_argnames=("interpret",))
-        return jax.jit(latent_decode.latent_decode_attention_pallas,
-                       static_argnames=("rank", "interpret"))
-    return _kernel_jit(name, make)
-
-
-# -- attention ----------------------------------------------------------------
-
-def _mlp(x, w_gate, w_up, w_down):
-    g = jnp.dot(x, w_gate)
-    return jnp.dot(jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)
-                   * jnp.dot(x, w_up), w_down)
-
-
-def _qkv(cfg: DeepseekV3Config, lp: Params, l: int, x, cos, sin):
-    """x (..., T, D) normed -> q_nope (..., T, H, dn), q_rope
-    (..., T, H, dr) rotated, row (..., T, W): the cache's row."""
-    r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    q = jnp.dot(x, lp["wq"][l]).reshape(x.shape[:-1] + (cfg.n_heads,
-                                                        dn + dr))
-    q_rope = apply_rope(q[..., dn:], cos, sin)
-    kva = jnp.dot(x, lp["wkv_a"][l])
-    c = rms_norm(kva[..., :r], lp["kv_norm"][l], cfg.norm_eps)
-    k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
-    pad = jnp.zeros(x.shape[:-1] + (cfg.latent_width - r - dr,), c.dtype)
-    return q[..., :dn], q_rope, jnp.concatenate([c, k_rope, pad], axis=-1)
-
-
-def _wkv_b(cfg: DeepseekV3Config, lp: Params, l: int):
-    w = lp["wkv_b"][l].reshape(cfg.kv_lora_rank, cfg.n_heads,
-                               cfg.qk_nope_head_dim + cfg.v_head_dim)
-    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-
-
-def latent_decode_attention(cfg: DeepseekV3Config, lp: Params, l: int,
-                            q_nope, q_rope, row, pool, block_tables,
-                            seq_lens, page_of, slot_of):
-    """One decode step's attention of layer ``l`` in the ABSORBED form:
-    write each row's new cache row, then attend over the cached rows.
-    q_nope (B, H, dn), q_rope (B, H, dr), row (B, W); ``seq_lens`` 0
-    marks a row that is not live (its output is 0, its write went to
-    page 0). Returns (o (B, H * dv), pool)."""
-    B, r = q_nope.shape[0], cfg.kv_lora_rank
-    wk, wv = _wkv_b(cfg, lp, l)
-    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wk)
-    pad = jnp.zeros((B, cfg.n_heads,
-                     cfg.latent_width - r - cfg.qk_rope_head_dim),
-                    q_lat.dtype)
-    scale = cfg.qk_head_dim ** -0.5
-    q_cat = (jnp.concatenate([q_lat, q_rope, pad], axis=-1)
-             .astype(jnp.float32) * scale).astype(pool.dtype)
-    use, interp = _route(cfg, pool.shape[2])
-    if use:
-        pool = _jit_latent("latent_write")(pool, row, page_of, slot_of,
-                                           jnp.int32(l), interpret=interp)
-        o_lat = _jit_latent("latent_decode")(
-            q_cat, pool, block_tables, seq_lens, jnp.int32(l), rank=r,
-            interpret=interp)
-    else:
-        pool = pool.at[l, page_of, slot_of].set(row.astype(pool.dtype))
-        rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
-        s = jnp.einsum("bhw,bsw->bhs", q_cat, rows,
-                       preferred_element_type=jnp.float32)
-        live = (jnp.arange(rows.shape[1])[None, :]
-                < seq_lens[:, None])[:, None, :]
-        p = jnp.where(live, jax.nn.softmax(jnp.where(live, s, NEG), -1), 0.0)
-        o_lat = jnp.einsum("bhs,bsr->bhr", p.astype(rows.dtype),
-                           rows[..., :r],
-                           preferred_element_type=jnp.float32)
-    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cfg.dtype), wv)
-    return o.reshape(B, -1), pool
-
-
-def latent_write_prefill(pool, rows, block_tables, positions, lengths,
-                         l: int):
-    """Write the rows of B contiguous slices (rows (B, T, W), the first
-    ``lengths`` of each valid, starting at ``positions[:, 0]``) into
-    layer ``l`` of the pool, a PAGE at a time: every page a slice
-    touches is read, merged and written once (an XLA scatter pays by
-    the index, so by the page here and not by the token). Pages no
-    valid token touches go to reserved page 0."""
-    B, T, W = rows.shape
-    ps, mp = pool.shape[2], block_tables.shape[1]
-    n_pages = -(-T // ps) + 1
-    p0 = positions[:, 0]
-    src = jnp.arange(n_pages * ps)[None, :] - (p0 % ps)[:, None]
-    valid = (src >= 0) & (src < lengths[:, None])          # (B, NP*ps)
-    buf = jnp.take_along_axis(rows, jnp.clip(src, 0, T - 1)[..., None],
-                              axis=1)
-    idx = (p0 // ps)[:, None] + jnp.arange(n_pages)[None, :]
-    pages = jnp.take_along_axis(block_tables, jnp.clip(idx, 0, mp - 1),
-                                axis=1)
-    valid = valid.reshape(B, n_pages, ps)
-    pages = jnp.where(valid.any(-1) & (idx < mp), pages, 0)
-    merged = jnp.where(valid[..., None],
-                       buf.reshape(B, n_pages, ps, W).astype(pool.dtype),
-                       pool[l, pages])
-    return pool.at[l, pages.reshape(-1)].set(
-        merged.reshape(B * n_pages, ps, W))
-
-
-def latent_prefill_attention(cfg: DeepseekV3Config, lp: Params, l: int,
-                             q_nope, q_rope, pool, block_tables, positions,
-                             seq_lens):
-    """Prefill attention of layer ``l``, UNABSORBED, under XLA: K and V
-    are expanded from each row's cached latents (its whole block-table
-    window, the new tokens already written), causal by absolute
-    position. q_* (B, T, H, .). Returns (B, T, H * dv)."""
-    B, T = q_nope.shape[:2]
-    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
-    wk, wv = _wkv_b(cfg, lp, l)
-    k_nope = jnp.einsum("bsr,rhn->bshn", rows[..., :r], wk)
-    v = jnp.einsum("bsr,rhv->bshv", rows[..., :r], wv)
-    s = (jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bthr,bsr->bhts", q_rope, rows[..., r:r + dr],
-                      preferred_element_type=jnp.float32))
-    key_pos = jnp.arange(rows.shape[1])
-    mask = ((key_pos[None, None, :] <= positions[:, :, None])
-            & (key_pos[None, None, :] < seq_lens[:, None, None]))
-    p = jax.nn.softmax(jnp.where(mask[:, None], s * cfg.qk_head_dim ** -0.5,
-                                 NEG), axis=-1)
-    o = jnp.einsum("bhts,bshv->bthv", p.astype(v.dtype), v)
-    return o.reshape(B, T, -1)
+    return init_latent_pool(cfg, cfg.n_layers, num_pages, page_size, dtype)
 
 
 # -- feed-forward -------------------------------------------------------------
@@ -570,16 +375,6 @@ def forward_prefill(params: Params, cfg: DeepseekV3Config, tokens,
         h = h[jnp.arange(B), lengths - 1]
     out = (_finish(params, h, cfg), {"ckv": pool})
     return out + (_sum_stats(cfg, counts),) if stats else out
-
-
-def _decode_geometry(positions, block_tables, page_size, active):
-    B = positions.shape[0]
-    page_of = block_tables[jnp.arange(B), positions // page_size]
-    seq_lens = positions + 1
-    if active is not None:
-        page_of = jnp.where(active, page_of, 0)
-        seq_lens = jnp.where(active, seq_lens, 0)
-    return page_of, positions % page_size, seq_lens
 
 
 @partial(jax.jit, static_argnames=("cfg", "stats"))

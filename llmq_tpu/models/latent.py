@@ -1,0 +1,332 @@
+"""Multi-head LATENT attention over a latent page pool: what the
+families that have it share (``models/deepseek_v3.py``,
+``models/longcat_flash.py``). The equations and the pool's layout are
+in ``models/deepseek_v3.py``'s docstring; here they are written once.
+
+An attention is addressed by ONE index ``l``: its slice of the stacked
+attention leaves ``lp`` AND its layer of the pool. A family with one
+attention a layer passes the layer; one with two a layer stacks both
+and its pool's leading axis is twice its layers.
+
+Two things a configuration may add to DeepSeek-V3's attention, both
+written here and nowhere else:
+
+- ``q_lora_rank``: a low-rank query, c_q = RMSNorm(x W_qa), q = c_q
+  W_qb (leaves ``wq_a``, ``q_norm``, ``wq_b`` in place of ``wq``);
+- ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: q is multiplied by s_q
+  = sqrt(hidden / q_lora_rank) and [k^nope ; v] by s_kv = sqrt(hidden /
+  kv_lora_rank) (the RoPE key is not). s_kv never touches the cached
+  row: it is folded into the query's latent part and into the output,
+  in float32, so the pool holds the same ``[c | k^rope | 0]`` row
+  whatever the scales.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+
+from llmq_tpu.ops.norms import rms_norm
+from llmq_tpu.ops.rope import apply_rope
+
+Params = Dict[str, Any]
+NEG = -1e30
+
+
+class LatentDims:
+    """What the functions below read of a config beside its fields
+    (``dim``, ``n_heads``, ``kv_lora_rank``, ``q_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``mla_scale_q_lora``, ``mla_scale_kv_lora``, ``norm_eps``,
+    ``dtype``): a mixin for the families' frozen dataclasses."""
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Lanes of one cached row: the latent and the RoPE key, rounded
+        up to whole 128-lane tiles."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def q_scale(self) -> float:
+        if self.mla_scale_q_lora and self.q_lora_rank:
+            return (self.dim / self.q_lora_rank) ** 0.5
+        return 1.0
+
+    @property
+    def kv_scale(self) -> float:
+        if self.mla_scale_kv_lora:
+            return (self.dim / self.kv_lora_rank) ** 0.5
+        return 1.0
+
+
+def attn_param_shapes(cfg, n: int) -> Dict[str, tuple]:
+    """Leaf name -> (shape, fan_in) of ``n`` stacked attentions'
+    matrices."""
+    D, H, r = cfg.dim, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rq = cfg.q_lora_rank
+    q = ({"wq": ((n, D, H * (dn + dr)), D)} if not rq else
+         {"wq_a": ((n, D, rq), D), "wq_b": ((n, rq, H * (dn + dr)), rq)})
+    return {**q, "wkv_a": ((n, D, r + dr), D),
+            "wkv_b": ((n, r, H * (dn + dv)), r),
+            "wo": ((n, H * dv, D), H * dv)}
+
+
+def attn_norm_leaves(cfg, n: int) -> Params:
+    """The RMSNorm weights (ones) inside ``n`` stacked attentions."""
+    out = {"kv_norm": jnp.ones((n, cfg.kv_lora_rank), cfg.dtype)}
+    if cfg.q_lora_rank:
+        out["q_norm"] = jnp.ones((n, cfg.q_lora_rank), cfg.dtype)
+    return out
+
+
+def attn_norm_count(cfg) -> int:
+    """Parameters of ``attn_norm_leaves`` for one attention."""
+    return cfg.kv_lora_rank + (cfg.q_lora_rank or 0)
+
+
+def init_latent_pool(cfg, n_attn: int, num_pages: int, page_size: int,
+                     dtype: Optional[Any] = None) -> Dict[str, jnp.ndarray]:
+    """The latent page pool: ONE leaf ``"ckv"`` ``(n_attn, P,
+    page_size, latent_width)``, page 0 reserved as in every pool of
+    this repo."""
+    return {"ckv": jnp.zeros((n_attn, num_pages, page_size,
+                              cfg.latent_width), dtype or cfg.dtype)}
+
+
+# -- what the families' parameter trees share ---------------------------------
+
+def swiglu(x, w_gate, w_up, w_down):
+    g = jnp.dot(x, w_gate)
+    return jnp.dot(jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)
+                   * jnp.dot(x, w_up), w_down)
+
+
+def prod(shape) -> int:
+    n = 1
+    for d in shape:
+        n *= d
+    return n
+
+
+def param_count(params: Params) -> int:
+    return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
+
+
+def draw_groups(key: jax.Array, shapes: Dict[str, Dict[str, tuple]], dtype,
+                n_expert_leaves: int) -> Dict[str, Dict[str, Any]]:
+    """A family's ``param_shapes`` drawn N(0, 1 / fan_in) as the Llama
+    block's: group -> leaf -> array, and of the group ``experts`` a
+    LIST of ``n_expert_leaves`` arrays under each name (a leaf a routed
+    layer). What the family's ``assemble`` takes."""
+    keys = iter(jax.random.split(key, sum(len(g) for g in shapes.values())))
+
+    def draw(k, shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * fan_in ** -0.5).astype(dtype)
+
+    drawn = {g: {name: draw(next(keys), shape, fan_in)
+                 for name, (shape, fan_in) in leaves.items()}
+             for g, leaves in shapes.items() if g != "experts"}
+    drawn["experts"] = {
+        name: [draw(k, shape, fan_in)
+               for k in jax.random.split(next(keys), n_expert_leaves)]
+        for name, (shape, fan_in) in shapes["experts"].items()}
+    return drawn
+
+
+# -- kernel routes ------------------------------------------------------------
+
+def _route(cfg, page_size: int):
+    """(use the latent kernels, interpret) at this geometry: the
+    shared LLMQ_PALLAS policy, plus what the kernels need of the
+    shapes."""
+    from llmq_tpu.ops.attention import _kernel_route
+    ok = cfg.kv_lora_rank % 128 == 0 and page_size % 8 == 0
+    return _kernel_route(cfg.latent_width, extra_ok=ok)
+
+
+def routes(cfg, cache, *, batch: int, page_size: int, max_pages: int,
+           decode: bool = False, prefill_rows: int = 0) -> Dict[str, str]:
+    """Which implementation each attention op of one serving program
+    takes (``ops/attention.kernel_routes``'s form): the latent decode
+    kernel with its plan, and XLA for prefill."""
+    from llmq_tpu.ops.pallas.latent_decode import pages_per_chunk
+    out: Dict[str, str] = {}
+    if prefill_rows:
+        out["prefill_write"] = out["prefill_attention"] = "xla"
+    if decode:
+        use, interp = _route(cfg, page_size)
+        tag = f"pallas{'-interpret' if interp else ''}:"
+        chunk = pages_per_chunk(page_size, max_pages) * page_size
+        out["decode_write"] = (tag + "_latent_write_kernel" if use
+                               else "xla")
+        out["decode_attention"] = (
+            f"{tag}_latent_decode_kernel(rows=1,chunk_tokens={chunk})"
+            if use else "xla")
+    return out
+
+
+def _jit_latent(name: str):
+    from llmq_tpu.ops.attention import _kernel_jit
+
+    def make():
+        from llmq_tpu.ops.pallas import latent_decode
+        if name == "latent_write":
+            return jax.jit(latent_decode.latent_write_pallas,
+                           static_argnames=("interpret",))
+        return jax.jit(latent_decode.latent_decode_attention_pallas,
+                       static_argnames=("rank", "interpret"))
+    return _kernel_jit(name, make)
+
+
+# -- attention ----------------------------------------------------------------
+
+def qkv(cfg, lp: Params, l: int, x, cos, sin):
+    """x (..., T, D) normed -> q_nope (..., T, H, dn), q_rope
+    (..., T, H, dr) rotated, row (..., T, W): the cache's row. Both
+    halves of q carry s_q; the row carries no scale."""
+    r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    if cfg.q_lora_rank:
+        c_q = rms_norm(jnp.dot(x, lp["wq_a"][l]), lp["q_norm"][l],
+                       cfg.norm_eps)
+        q = jnp.dot(c_q, lp["wq_b"][l])
+    else:
+        q = jnp.dot(x, lp["wq"][l])
+    if cfg.q_scale != 1.0:
+        q = (q.astype(jnp.float32) * cfg.q_scale).astype(q.dtype)
+    q = q.reshape(x.shape[:-1] + (cfg.n_heads, dn + dr))
+    q_rope = apply_rope(q[..., dn:], cos, sin)
+    kva = jnp.dot(x, lp["wkv_a"][l])
+    c = rms_norm(kva[..., :r], lp["kv_norm"][l], cfg.norm_eps)
+    k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
+    pad = jnp.zeros(x.shape[:-1] + (cfg.latent_width - r - dr,), c.dtype)
+    return q[..., :dn], q_rope, jnp.concatenate([c, k_rope, pad], axis=-1)
+
+
+def wkv_b(cfg, lp: Params, l: int):
+    w = lp["wkv_b"][l].reshape(cfg.kv_lora_rank, cfg.n_heads,
+                               cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def decode_geometry(positions, block_tables, page_size, active):
+    """(page, slot, context length) of each decode row's new token; a
+    row that is not active writes to page 0 and attends to nothing."""
+    B = positions.shape[0]
+    page_of = block_tables[jnp.arange(B), positions // page_size]
+    seq_lens = positions + 1
+    if active is not None:
+        page_of = jnp.where(active, page_of, 0)
+        seq_lens = jnp.where(active, seq_lens, 0)
+    return page_of, positions % page_size, seq_lens
+
+
+def latent_decode_attention(cfg, lp: Params, l: int, q_nope, q_rope, row,
+                            pool, block_tables, seq_lens, page_of, slot_of):
+    """One decode step's attention ``l`` in the ABSORBED form: write
+    each row's new cache row, then attend over the cached rows.
+    q_nope (B, H, dn), q_rope (B, H, dr), row (B, W); ``seq_lens`` 0
+    marks a row that is not live (its output is 0, its write went to
+    page 0). Returns (o (B, H * dv), pool)."""
+    B, r = q_nope.shape[0], cfg.kv_lora_rank
+    wk, wv = wkv_b(cfg, lp, l)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wk)
+    pad = jnp.zeros((B, cfg.n_heads,
+                     cfg.latent_width - r - cfg.qk_rope_head_dim),
+                    q_lat.dtype)
+    scale, s_kv = cfg.qk_head_dim ** -0.5, cfg.kv_scale
+    if s_kv != 1.0:
+        q_lat = q_lat.astype(jnp.float32) * s_kv
+        q_rope, pad = q_rope.astype(jnp.float32), pad.astype(jnp.float32)
+    q_cat = (jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+             .astype(jnp.float32) * scale).astype(pool.dtype)
+    use, interp = _route(cfg, pool.shape[2])
+    if use:
+        pool = _jit_latent("latent_write")(pool, row, page_of, slot_of,
+                                           jnp.int32(l), interpret=interp)
+        o_lat = _jit_latent("latent_decode")(
+            q_cat, pool, block_tables, seq_lens, jnp.int32(l), rank=r,
+            interpret=interp)
+    else:
+        pool = pool.at[l, page_of, slot_of].set(row.astype(pool.dtype))
+        rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
+        s = jnp.einsum("bhw,bsw->bhs", q_cat, rows,
+                       preferred_element_type=jnp.float32)
+        live = (jnp.arange(rows.shape[1])[None, :]
+                < seq_lens[:, None])[:, None, :]
+        p = jnp.where(live, jax.nn.softmax(jnp.where(live, s, NEG), -1), 0.0)
+        o_lat = jnp.einsum("bhs,bsr->bhr", p.astype(rows.dtype),
+                           rows[..., :r],
+                           preferred_element_type=jnp.float32)
+    if s_kv != 1.0:
+        o_lat = o_lat.astype(jnp.float32) * s_kv
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cfg.dtype), wv)
+    return o.reshape(B, -1), pool
+
+
+def latent_write_prefill(pool, rows, block_tables, positions, lengths,
+                         l: int):
+    """Write the rows of B contiguous slices (rows (B, T, W), the first
+    ``lengths`` of each valid, starting at ``positions[:, 0]``) into
+    layer ``l`` of the pool, a PAGE at a time: every page a slice
+    touches is read, merged and written once (an XLA scatter pays by
+    the index, so by the page here and not by the token). Pages no
+    valid token touches go to reserved page 0."""
+    B, T, W = rows.shape
+    ps, mp = pool.shape[2], block_tables.shape[1]
+    n_pages = -(-T // ps) + 1
+    p0 = positions[:, 0]
+    src = jnp.arange(n_pages * ps)[None, :] - (p0 % ps)[:, None]
+    valid = (src >= 0) & (src < lengths[:, None])          # (B, NP*ps)
+    buf = jnp.take_along_axis(rows, jnp.clip(src, 0, T - 1)[..., None],
+                              axis=1)
+    idx = (p0 // ps)[:, None] + jnp.arange(n_pages)[None, :]
+    pages = jnp.take_along_axis(block_tables, jnp.clip(idx, 0, mp - 1),
+                                axis=1)
+    valid = valid.reshape(B, n_pages, ps)
+    pages = jnp.where(valid.any(-1) & (idx < mp), pages, 0)
+    merged = jnp.where(valid[..., None],
+                       buf.reshape(B, n_pages, ps, W).astype(pool.dtype),
+                       pool[l, pages])
+    return pool.at[l, pages.reshape(-1)].set(
+        merged.reshape(B * n_pages, ps, W))
+
+
+def latent_prefill_attention(cfg, lp: Params, l: int, q_nope, q_rope, pool,
+                             block_tables, positions, seq_lens):
+    """Prefill attention ``l``, UNABSORBED, under XLA: K and V are
+    expanded from each row's cached latents (its whole block-table
+    window, the new tokens already written), causal by absolute
+    position. q_* (B, T, H, .). Returns (B, T, H * dv)."""
+    B, T = q_nope.shape[:2]
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
+    wk, wv = wkv_b(cfg, lp, l)
+    k_nope = jnp.einsum("bsr,rhn->bshn", rows[..., :r], wk)
+    v = jnp.einsum("bsr,rhv->bshv", rows[..., :r], wv)
+    s_nope = jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
+                        preferred_element_type=jnp.float32)
+    s_kv = cfg.kv_scale
+    if s_kv != 1.0:
+        s_nope = s_nope * s_kv
+    s = s_nope + jnp.einsum("bthr,bsr->bhts", q_rope, rows[..., r:r + dr],
+                            preferred_element_type=jnp.float32)
+    key_pos = jnp.arange(rows.shape[1])
+    mask = ((key_pos[None, None, :] <= positions[:, :, None])
+            & (key_pos[None, None, :] < seq_lens[:, None, None]))
+    p = jax.nn.softmax(jnp.where(mask[:, None], s * cfg.qk_head_dim ** -0.5,
+                                 NEG), axis=-1)
+    if s_kv != 1.0:
+        o = (jnp.einsum("bhts,bshv->bthv", p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32)
+             * s_kv).astype(v.dtype)
+    else:
+        o = jnp.einsum("bhts,bshv->bthv", p.astype(v.dtype), v)
+    return o.reshape(B, T, -1)

@@ -269,6 +269,11 @@ def import_hf(model_dir: str, cfg: LlamaConfig, **kw) -> Params:
     return import_hf_llama(model_dir, cfg, **kw)
 
 
+def step_stats_layout(cfg: LlamaConfig) -> Dict[str, Any]:
+    """A dense block counts nothing (``models/__init__.py``)."""
+    return {}
+
+
 def step_stats_size(cfg: LlamaConfig) -> int:
     """This family's forward passes count nothing
     (``models/__init__.py``)."""

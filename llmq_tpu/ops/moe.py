@@ -1,4 +1,5 @@
-"""Routed feed-forward (DeepSeek-V3's): a float32 sigmoid router with a
+"""Routed feed-forward (DeepSeek-V3's, LongCat-Flash's): a float32
+router (sigmoid scores, or a softmax over all of them) with a
 selection-only correction bias, the top ``k`` of ``E`` experts a token,
 and a GROUPED product that multiplies each token with its own experts
 and no others.
@@ -20,9 +21,28 @@ elsewhere, by the policy every kernel of this repo follows
 widths (PERF.md §6, PR 31): both read only the experts a batch
 touches, and the kernel is the faster by a quarter at 64 rows.
 
-``stats`` of a call: tokens each expert received and how many experts
-received any, which the serving programs sum over their steps and
-layers (``engine.get_stats()["moe"]``).
+**A chip's share of the experts** (``routed_ffn(..., held=(lo, hi))``):
+the router still scores every expert and a token still chooses its
+``k`` among all of them, but this chip holds only the matrices of the
+experts ``lo .. hi - 1`` (``w_gate_up[0]`` is expert ``lo``'s). A pair
+whose expert is held elsewhere is multiplied with nothing here: the
+sum this chip returns is its PART of the routed layer's result, and the
+parts of all shares add up to the whole (``tests/test_moe_share.py``).
+Nothing here stands in for the other chips or for the exchange with
+them. **Zero-compute experts** (``n_routed``): a chosen index at or
+above it is an identity expert, which adds ``g_e x`` and enters no
+group (``identity_gate`` gives the token's summed weight of them; the
+token's home chip adds it). With a share, the held pairs are a small
+and varying part of the ``N k`` chosen ones, so they are multiplied a
+BLOCK of sorted pairs at a time, as many blocks as there are held pairs
+(``_held_blocks``), and no array of ``N k`` rows is ever made.
+
+``stats`` of a call: tokens each (held) expert received and how many
+experts received any — with a share or zero-compute experts also the
+slots that went to zero-compute experts and to experts held elsewhere
+— which the serving programs sum over their steps and layers
+(``engine.get_stats()["moe"]``; the family states the layout:
+``step_stats_layout``).
 """
 
 from __future__ import annotations
@@ -35,17 +55,21 @@ from jax import lax
 
 
 def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
-          top_k: int, scale: float, norm_topk: bool = True
-          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+          top_k: int, scale: float, norm_topk: bool = True,
+          scoring: str = "sigmoid") -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``x`` (N, D) -> (experts (N, k) int32, gates (N, k) float32).
 
-    s = sigmoid(x W_r) in float32 at the highest matmul precision (a
+    s = sigmoid(x W_r) — or, ``scoring="softmax"``, softmax over all E
+    of x W_r — in float32 at the highest matmul precision (a
     bf16 pass swaps near-tied experts); the ``k`` experts are the top
     ``k`` of ``s + bias``; the gates are the chosen ``s`` (WITHOUT the
     bias), normalised to sum 1 where ``norm_topk``, times ``scale``."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    s = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
     _, experts = lax.top_k(s + bias.astype(jnp.float32), top_k)
     g = jnp.take_along_axis(s, experts, axis=-1)
     if norm_topk:
@@ -83,9 +107,62 @@ def _grouped(xs, w, counts):
     return fn(xs, w, counts, interpret=interp)
 
 
+#: Sorted pairs a block of ``_held_blocks`` multiplies: two row tiles
+#: of the grouped product. A decode step of 128 rows brings 32 held
+#: pairs on average to 16 of 768 experts: one block.
+HELD_BLOCK = 256
+
+
+def identity_gate(experts: jnp.ndarray, gates: jnp.ndarray, n_routed: int,
+                  live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """(N,) float32: each token's summed weight of the zero-compute
+    (identity) experts it chose — the indices at or above ``n_routed``.
+    The routed layer adds this times the token itself. 0 for a row
+    that is not live."""
+    g = jnp.sum(jnp.where(experts >= n_routed, gates, 0.0), axis=-1)
+    return g if live is None else jnp.where(live, g, 0.0)
+
+
+def _held_blocks(x, key, gates, w_gate_up, w_down, counts, k):
+    """The held pairs' weighted SwiGLU results summed by token, (N, D)
+    float32. ``key`` (N k,): each pair's group among the held experts,
+    or ``E_held`` for a pair that is multiplied with nothing; ``counts``
+    (E_held,) the groups' sizes. The pairs are sorted by group and
+    taken ``HELD_BLOCK`` at a time while held pairs are left: a block's
+    groups are the groups' overlaps with its rows, its tokens' rows are
+    gathered, multiplied and added to their tokens."""
+    N, D = x.shape
+    F = w_gate_up.shape[-1] // 2
+    M, blk = key.shape[0], HELD_BLOCK
+    order = jnp.pad(jnp.argsort(key, stable=True), (0, -M % blk))
+    ends = jnp.cumsum(counts)
+    starts, n_held = ends - counts, ends[-1]
+    flat_gates = gates.reshape(-1)
+
+    def block(b, y):
+        lo = b * blk
+        pairs = lax.dynamic_slice(order, (lo,), (blk,))
+        tok = pairs // k
+        size = (jnp.clip(ends, lo, lo + blk)
+                - jnp.clip(starts, lo, lo + blk))
+        gu = _grouped(x[tok], w_gate_up, size)
+        a = (jax.nn.silu(gu[:, :F].astype(jnp.float32)).astype(x.dtype)
+             * gu[:, F:])
+        ys = _grouped(a, w_down, size)
+        w = jnp.where(lo + jnp.arange(blk) < n_held, flat_gates[pairs], 0.0)
+        ys = jnp.where(w[:, None] != 0, ys.astype(jnp.float32) * w[:, None],
+                       0.0)
+        return y.at[tok].add(ys)
+
+    return lax.fori_loop(0, (n_held + blk - 1) // blk, block,
+                         jnp.zeros((N, D), jnp.float32))
+
+
 def routed_ffn(x: jnp.ndarray, experts: jnp.ndarray, gates: jnp.ndarray,
                w_gate_up: jnp.ndarray, w_down: jnp.ndarray,
-               live: Optional[jnp.ndarray] = None
+               live: Optional[jnp.ndarray] = None, *,
+               held: Optional[Tuple[int, int]] = None,
+               n_routed: Optional[int] = None
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """sum_i gates_i SwiGLU_{experts_i}(x) for every token of ``x``
     (N, D). ``w_gate_up`` (E, D, 2F) holds each expert's gate and up
@@ -94,11 +171,27 @@ def routed_ffn(x: jnp.ndarray, experts: jnp.ndarray, gates: jnp.ndarray,
 
     Returns (y (N, D) in ``x.dtype``, stats (E + 1,) int32: the tokens
     each expert received, then the number of experts that received
-    any)."""
+    any).
+
+    ``held=(lo, hi)``: the matrices are those of the experts ``lo ..
+    hi - 1`` of the router's indices and the sum runs over the chosen
+    experts among them alone; ``n_routed``: the router's indices at or
+    above it are zero-compute experts, which are not multiplied here
+    (``identity_gate``). With either, stats is (E_held + 3,): the
+    tokens each held expert received, the held experts that received
+    any, the slots that chose a zero-compute expert, the slots whose
+    expert is held elsewhere. All experts held and none zero-compute
+    is the call without either, to the bit."""
     N, k = experts.shape
     E, _, F2 = w_gate_up.shape
     F = F2 // 2
     flat = experts.reshape(-1)
+    lo, hi = held if held is not None else (0, E)
+    if hi - lo != E:
+        raise ValueError(f"held {held}: {E} experts' matrices were given")
+    if (lo, hi) != (0, E) or n_routed not in (None, E):
+        return _routed_share(x, flat, gates, w_gate_up, w_down, live, k,
+                             lo, n_routed)
     if live is not None:
         # Sorted behind the last group: outside every group, so no
         # product touches those rows (they are zeroed below).
@@ -117,3 +210,23 @@ def routed_ffn(x: jnp.ndarray, experts: jnp.ndarray, gates: jnp.ndarray,
     stats = jnp.concatenate(
         [counts, jnp.sum(counts > 0, dtype=jnp.int32)[None]])
     return y, stats
+
+
+def _routed_share(x, flat, gates, w_gate_up, w_down, live, k, lo, n_routed):
+    """``routed_ffn`` for a share of the experts and / or zero-compute
+    experts: ``flat`` (N k,) the chosen router indices."""
+    E = w_gate_up.shape[0]
+    alive = (jnp.repeat(live, k) if live is not None
+             else jnp.ones(flat.shape, jnp.bool_))
+    here = alive & (flat >= lo) & (flat < lo + E)
+    zero = (alive & (flat >= n_routed) if n_routed is not None
+            else jnp.zeros(flat.shape, jnp.bool_))
+    key = jnp.where(here, flat - lo, E)
+    counts = jnp.zeros((E,), jnp.int32).at[key].add(1, mode="drop")
+    y = _held_blocks(x, key, gates, w_gate_up, w_down, counts, k)
+    n_zero = jnp.sum(zero, dtype=jnp.int32)
+    n_away = jnp.sum(alive, dtype=jnp.int32) - n_zero - jnp.sum(counts)
+    stats = jnp.concatenate(
+        [counts, jnp.stack([jnp.sum(counts > 0, dtype=jnp.int32),
+                            n_zero, n_away])])
+    return y.astype(x.dtype), stats

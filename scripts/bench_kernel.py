@@ -112,7 +112,9 @@ def bench_latent(args, doc) -> None:
     from llmq_tpu.ops.pallas import latent_decode as ld
 
     ex, model = doc["server"]["executor"], doc["server"]["model"]
-    H, L = doc["num_attention_heads"], doc["num_hidden_layers"]
+    H = doc["num_attention_heads"]
+    # cache layers: one a layer, or a double layer's two attentions
+    L = doc.get("num_hidden_layers") or 2 * doc["num_layers"]
     rank, dr = doc["kv_lora_rank"], doc["qk_rope_head_dim"]
     W = -(-(rank + dr) // 128) * 128
     reps = REPS
@@ -419,7 +421,8 @@ def bench_prefill(args, doc) -> None:
 #: ``FAMILIES``) -> the bench of its decode kernels. The kernels a
 #: family dispatches are what this tool is about, so a new family's
 #: bench is a function here and an entry in this table.
-BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent}
+BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent,
+           "longcat_flash": bench_latent}
 
 
 def main() -> None:

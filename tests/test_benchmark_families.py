@@ -53,13 +53,13 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
         keys = contract.load_family(fdir, "shapes").MODEL_KEYS
         assert all(k in config for k in keys), entry["name"]
         assert set(config["reduced"]) == set(entry["reduced"])
-    assert families == {"llama", "deepseek_v3"}
+    assert families == {"llama", "deepseek_v3", "longcat_flash"}
 
 
 @pytest.mark.parametrize("cell", [
     "smollm2-chat-bursts", "smollm2-decode-saturated",
     "smollm2-sessions-prefix", "mistral7b-decode-saturated",
-    "kanana2-decode-saturated"])
+    "kanana2-decode-saturated", "longcat-decode-saturated"])
 def test_every_cell_resolves_and_reports_what_the_contract_asks(bench, cell):
     assert contract.check_names(bench) == []
     assert cell in [w["name"] for w in bench["workloads"]]
@@ -82,6 +82,7 @@ def test_no_cell_is_left_out_of_the_cases_above(bench):
     ("smollm2-1.7b-bf16", 2, 196_608),
     ("mistral-7b-v0.3-w8kv8", 1, 66_560),
     ("kanana-2-30b-a3b-bf16", 2, 9_216),
+    ("longcat-flash-chat-bf16-ep32", 2, 9_216),
 ])
 def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
                                                     kv_itemsize, per_token):
@@ -89,7 +90,9 @@ def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
     shapes = contract.load_family(contract.family_dir(bench, doc), "shapes")
     model = {k: doc[k] for k in shapes.MODEL_KEYS if k in doc}
     assert shapes.kv_bytes_per_token(model, kv_itemsize) == per_token
-    assert shapes.attn_calls_per_step(model) == doc["num_hidden_layers"]
+    # one attention a layer, or a double layer's two
+    layers = doc.get("num_hidden_layers", 2 * doc.get("num_layers", 0))
+    assert shapes.attn_calls_per_step(model) == layers
 
 
 def test_the_routed_family_s_shapes_on_the_published_sizes(bench):
@@ -115,6 +118,44 @@ def test_the_routed_family_s_shapes_on_the_published_sizes(bench):
     assert shapes.decode_attn_flops(held, 64, 1) == 8 * 32 * (576 + 512) * 2
     # and no share of a roofline is measured against padded bytes
     assert shapes.moe_ffn_bytes(held, 2, 122.1) == 122.1 * 3 * 2048 * 768 * 2
+
+
+def test_the_shared_family_s_shapes_on_the_published_sizes(bench):
+    """``longcat_flash``: a chip's share. The arithmetic of the cut
+    (ISSUE 34): one attention 90,572,800 with its two inner norms, one
+    dense SwiGLU 226,492,416, an expert 37,748,736; 638,874,368 a layer
+    outside its experts; 560.7 B whole; 5,172,749,312 held."""
+    cell = contract.resolve_cell(bench, "longcat-decode-saturated")
+    shapes = contract.load_family(cell["family_dir"], "shapes")
+    held = cell["config"]["model"]
+    assert shapes.held_experts(held) == (0, 16)
+    assert shapes.attn_params(held) + 2_048 == 90_572_800
+    assert shapes.expert_params(held) == 37_748_736
+    assert shapes.param_count(held) == 5_172_749_312
+    layer = (shapes.param_count(dict(held, num_layers=2))
+             - shapes.param_count(dict(held, num_layers=1)))
+    assert layer == 638_874_368 + 16 * 37_748_736 == 1_242_854_144
+    whole = dict(held, num_layers=28, n_routed_experts=512,
+                 vocab_size=131_072, expert_share={"chips": 1, "index": 0})
+    assert shapes.param_count(whole) == 560_664_980_480
+    with pytest.raises(ValueError, match="not the router's 512"):
+        shapes.held_experts(dict(held, n_routed_experts=8))
+    # the cache: both attentions of a layer, 1,152 B each
+    assert shapes.kv_bytes_per_token(dict(held, num_layers=1), 2) == 2_304
+    assert shapes.attn_calls_per_step(held) == 8
+    # of a step's slots a 48th falls on a held expert: 32 pairs at 128
+    # rows, 2 an expert, and 13.9 of the 16 touched
+    assert shapes.held_slot_share(held) * 128 * 12 == 32
+    assert round(shapes.experts_touched(held, 128), 1) == 13.9
+    assert shapes.active_param_count(held) < shapes.param_count(held)
+    full = shapes.decode_step_bytes(held, 2, 2, 128, 128 * 900)
+    # attention and dense 5.07 GB, router 0.04, head 0.2, 13.9 experts a
+    # layer 4.2, latents 1.06: the issue's ~10.7 GB
+    assert round(full / 1e9, 1) == 10.6
+    assert shapes.decode_attn_bytes(held, 2, 128, 1e3) == 9_216e3
+    assert shapes.decode_attn_flops(held, 128, 1) == 8 * 64 * (576 + 512) * 2
+    assert shapes.moe_ffn_bytes(held, 2, 14) == 14 * 3 * 6144 * 2048 * 2
+    assert shapes.moe_ffn_flops(held, 32) == 2 * 32 * 3 * 6144 * 2048
 
 
 def test_the_tolerance_is_what_the_family_s_judge_reads(bench):
@@ -147,6 +188,36 @@ def test_the_tolerance_is_what_the_family_s_judge_reads(bench):
     assert not judge(unrelated, ref, margins, tol)["ok"]
 
 
+def test_the_shared_family_s_tolerance_sits_between_its_readings(bench):
+    """``longcat-flash-chat-bf16-ep32``: the judge's keys and no other;
+    under its numbers the served path's readings on the chip pass (a
+    median of 0.012 with a worst position of 0.081), the control one
+    precision down (0.059 everywhere) is refused by the MEDIAN — its
+    worst position, 0.107, is under ``rms`` — and so is one position of
+    unrelated logits."""
+    import numpy as np
+    cell = contract.resolve_cell(bench, "longcat-decode-saturated")
+    judge = contract.load_family(cell["family_dir"], "reference").judge
+    tol = cell["config"]["tolerance"]
+    assert set(tol) == {"rms", "max", "clean_quantile", "rms_clean",
+                        "margin_eps", "min_positions", "why"}
+    bucket = min(cell["config"]["server"]["executor"]["prefill_buckets"])
+    assert bucket // 3 + 3 < tol["min_positions"] <= bucket - 5 + 3
+    ref = np.zeros((128, 16), np.float32)
+    margins = np.full(128, 1e-3)
+    sound = ref + np.linspace(0.008, 0.021, 128)[:, None]
+    sound[5] = 0.081
+    got = judge(sound, ref, margins, tol)
+    assert got["ok"] and 0.011 < got["rms_clean"] < 0.016, got
+    assert tol["rms_clean"] ** 2 == pytest.approx(0.012 * 0.0595, rel=0.05)
+    control = ref + np.linspace(0.05, 0.107, 128)[:, None]
+    got = judge(control, ref, margins, tol)
+    assert not got["ok"] and got["rms"] < tol["rms"], got
+    unrelated = sound.copy()
+    unrelated[3] = 1.41
+    assert not judge(unrelated, ref, margins, tol)["ok"]
+
+
 def test_the_new_configuration_is_the_catalog_s_row(bench):
     """Every number of the catalog's ``config`` under the same key;
     only ``reduced``'s keys differ, and no width is among them."""
@@ -163,6 +234,39 @@ def test_the_new_configuration_is_the_catalog_s_row(bench):
     assert doc["n_routed_experts"] == 128 and doc["vocab_size"] == 128_256
 
 
+def test_the_shared_configuration_is_the_catalog_s_row(bench):
+    """``longcat-flash-chat-bf16-ep32``: every number of the catalog's
+    ``config`` under the same key but the four cuts, each with its
+    published value beside it; no width among them; the share and the
+    deployment stated; the server sized as the file reasons."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    entry, doc = next(x for x in _configs(bench)
+                      if x[0]["name"] == "longcat-flash-chat-bf16-ep32")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == entry["source"])
+    differs = {k for k, v in row["config"].items() if doc.get(k) != v}
+    assert differs == set(entry["reduced"]) == {
+        "num_layers", "n_routed_experts", "vocab_size",
+        "max_position_embeddings"}
+    assert doc["published"] == {k: row["config"][k] for k in differs}
+    assert not [k for k in differs if k.endswith(("_dim", "_rank", "_size"))
+                and k != "vocab_size"]
+    # the floors: four layers, at least 8 experts, an eighth of the
+    # vocabulary; the router keeps its width and its experts a token
+    assert doc["num_layers"] >= 4 and doc["n_routed_experts"] >= 8
+    assert doc["vocab_size"] * 8 >= row["config"]["vocab_size"]
+    assert doc["router_experts"] == 512 and doc["zero_expert_num"] == 256
+    assert doc["expert_share"] == {"chips": 32, "index": 0}
+    assert doc["moe_topk"] == 12 and "32 chips share" in doc["deployment"]
+    ex = doc["server"]["executor"]
+    assert ex["max_batch_size"] >= 64 and ex["prefill_buckets"] == [512]
+    assert ex["kv_pages"] > ex["max_batch_size"] * 12
+    assert set(doc["server_why"]) >= {"max_batch_size", "kv_pages",
+                                      "mixed_batch"}
+
+
 def test_the_harness_names_no_family():
     named = re.compile(r"llama|deepseek|kanana|smollm|fused_decode|gmm|"
                        r"latent_decode|moe_grouped|llmq_tpu\.models")
@@ -177,7 +281,7 @@ def test_the_harness_names_no_family():
         assert not hits, (path, hits)
 
 
-@pytest.mark.parametrize("family", ["llama", "deepseek_v3"])
+@pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash"])
 def test_who_imports_what_in_a_family(family):
     """``shapes.py`` is standard library alone (the parent and the
     readers import it); ``reference.py`` imports neither the program
@@ -191,6 +295,7 @@ def test_who_imports_what_in_a_family(family):
     assert set(imports["shapes"]) <= {"__future__", "typing"}
     assert not [m for m in imports["reference"]
                 if m.startswith(("llmq_tpu", "benchmark")) or "adapter" in m]
+    assert os.path.exists(os.path.join(fdir, "README.md")) or family == "llama"
     assert any(m.startswith("llmq_tpu") for m in imports["adapter"])
 
 

@@ -504,7 +504,7 @@ def test_registry_serves_both_families_by_name():
 
 @pytest.mark.parametrize("what,match", [
     ("unknown-name", "unknown model 'no-such-model'.*kanana-2-30b-a3b"),
-    ("q_lora_rank", "q_lora_rank=1536"),
+    ("first_k_dense", "first_k_dense 9 of 8"),
     ("int8-weights", "model.quantization='int8'"),
     ("int8-cache", "model.kv_quantization='int8'"),
     ("mesh", "executor.mesh"),
@@ -515,8 +515,8 @@ def test_registry_refuses_with_an_error_that_names_the_setting(what, match):
     with pytest.raises(ValueError, match=match):
         if what == "unknown-name":
             get_config("no-such-model")
-        elif what == "q_lora_rank":
-            get_config("kanana-2-30b-a3b", q_lora_rank=1536)
+        elif what == "first_k_dense":
+            get_config("kanana-2-30b-a3b", n_layers=8, first_k_dense=9)
         elif what == "int8-weights":
             ds.init_params_quantized(jax.random.PRNGKey(0), cfg)
         else:
@@ -528,6 +528,24 @@ def test_registry_refuses_with_an_error_that_names_the_setting(what, match):
                   "speculation": dict(speculation_draft_k=4)}[what]
             JaxExecutor(cfg, params, batch_size=2, page_size=8,
                         num_pages=16, **kw)
+
+
+def test_a_low_rank_query_is_served():
+    """``q_lora_rank`` (``models/latent.py``) in this family: decode
+    through the latent cache gives what one whole prefill gives, and
+    the tree holds the three query leaves in place of ``wq``."""
+    cfg = ds.deepseek_v3_tiny(dtype=jnp.float32, max_seq_len=128,
+                              q_lora_rank=48)
+    params = ds.init_params(jax.random.PRNGKey(7), cfg)
+    assert {"wq_a", "q_norm", "wq_b"} <= set(params["layers"])
+    assert "wq" not in params["layers"]
+    assert ds.param_count(params) == ds.param_count_analytic(cfg)
+    seq = np.random.default_rng(7).integers(3, cfg.vocab_size, 30,
+                                            dtype=np.int32)
+    through_cache, rows = serve(cfg, params, seq, (20,))
+    whole, _ = serve(cfg, params, seq, (30,))
+    np.testing.assert_allclose(through_cache[-1], whole[0], atol=2e-4)
+    assert rows[-1] == 29
 
 
 def test_builder_error_lists_the_registry(monkeypatch):

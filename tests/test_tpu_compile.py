@@ -207,18 +207,26 @@ def test_int8_kv_prefill_program_stays_small_for_v5e(one_chip, monkeypatch):
 #: kanana-2-30b-a3b-bf16 as served: 64 rows, 32 heads over one latent
 #: "head" of 640 lanes (512 + 64, padded), 128-token pages, 16 a row, 8
 #: layers, 832 pages.
-_LATENT = dict(B=64, H=32, W=640, rank=512, ps=128, mp=16, L=8, P=832)
+#: longcat-flash-chat-bf16-ep32 as served: 128 rows, 64 heads, the same
+#: row and pages, 8 cache layers (two attentions in each of 4 layers),
+#: 1,664 pages.
+_LATENT = {
+    "kanana2": dict(B=64, H=32, W=640, rank=512, ps=128, mp=16, L=8, P=832),
+    "longcat": dict(B=128, H=64, W=640, rank=512, ps=128, mp=16, L=8,
+                    P=1664),
+}
 
 
+@pytest.mark.parametrize("served", sorted(_LATENT))
 @pytest.mark.parametrize("kernel", ["decode", "write"])
-def test_latent_kernels_compile_for_v5e(one_chip, kernel):
+def test_latent_kernels_compile_for_v5e(one_chip, kernel, served):
     """The latent decode kernel (manual page DMAs into two 512-token
-    slots, a 32 x 640 by 640 x 512 product a chunk) and the latent
-    write at the served geometry: one Mosaic call each."""
+    slots, a heads x 640 by 640 x 512 product a chunk) and the latent
+    write at the served geometries: one Mosaic call each."""
     from llmq_tpu.ops.pallas.latent_decode import (
         latent_decode_attention_pallas, latent_write_pallas)
 
-    g = _LATENT
+    g = _LATENT[served]
 
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -261,3 +269,80 @@ def test_grouped_product_compiles_for_v5e(one_chip, rows):
         arg((128,), jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("tokens", [128, 2176],
+                         ids=["128-decode-rows", "2176-mixed-tokens"])
+def test_a_share_s_grouped_product_compiles_for_v5e(one_chip, monkeypatch,
+                                                    tokens):
+    """A chip's share of a routed layer at LongCat-Flash-Chat's widths
+    (16 of 512 experts held, 768 router outputs, 12 a token, 6,144 ->
+    2 x 2,048 -> 6,144): the held pairs go through the grouped product
+    a block of sorted pairs at a time inside ONE loop, so two Mosaic
+    calls whatever the tokens, and no array of tokens x 12 rows of the
+    hidden size is made (a mixed step's would be 0.3 GB in bf16 and
+    twice that in float32)."""
+    from llmq_tpu.ops import attention, moe
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def share(x, experts, gates, w_gu, w_d, live):
+        return moe.routed_ffn(x, experts, gates, w_gu, w_d, live,
+                              held=(0, 16), n_routed=512)
+
+    compiled = jax.jit(share).lower(
+        arg((tokens, 6144)), arg((tokens, 12), jnp.int32),
+        arg((tokens, 12), jnp.float32), arg((16, 6144, 4096)),
+        arg((16, 2048, 6144)), arg((tokens,), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 80e6 + (
+        tokens * 6144 * 4 * 2)
+
+
+def test_the_double_layer_s_decode_step_fits_v5e(one_chip, monkeypatch):
+    """One decode step of ``longcat-flash-chat-bf16-ep32`` as served
+    (4 double layers, 16 held experts, 16,384 of the vocabulary, 128
+    rows over a pool of 1,664 pages): 8 latent writes, 8 latent decode
+    calls and 4 routed layers' two grouped products; weights and pool
+    are 12.5 GB of arguments, the pool goes in and comes out in place,
+    and the step's temporaries stay a small part of what is left."""
+    from llmq_tpu.models import longcat_flash as lf
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    cfg = lf.longcat_flash_chat(n_layers=4, vocab_size=16384,
+                                held_experts=(0, 16), max_seq_len=2048)
+    B, pages, page = 128, 1664, 128
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: lf.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: lf.init_kv_pages(cfg, pages, page)))
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(cache))
+
+    def arg(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(params, cache, tokens, positions, block_tables, active):
+        return lf.forward_decode.__wrapped__(
+            params, cfg, tokens, positions, cache, block_tables,
+            active=active, stats=True)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, arg(B), arg(B), arg(B, cfg.max_seq_len // page),
+        arg(B, dtype=jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert compiled.as_text().count("tpu_custom_call") == 8 + 8 + 2 * 4
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.7e9
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
