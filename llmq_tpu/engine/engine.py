@@ -753,6 +753,9 @@ class InferenceEngine:
         #: over the routed layers run, then those layer runs. ``None``
         #: until a chunk brings some (a dense model never does).
         self._moe: Optional[np.ndarray] = None
+        #: Prefill key blocks (visited, the table held) of the mixed
+        #: chunks committed (``_note_key_blocks``).
+        self._key_blocks = np.zeros((2,), np.int64)
         #: Token-budget mixed prefill+decode batching
         #: (docs/architecture.md "Mixed step"). ``mixed_batch`` accepts
         #: a core.config.MixedBatchConfig or anything with the same
@@ -3181,6 +3184,8 @@ class InferenceEngine:
             self._commit_chunk(infl, out, device_s, readback_s,
                                overlapped_s)
             self._note_moe(getattr(infl.handle, "stats", None), span)
+            self._note_key_blocks(
+                getattr(infl.handle, "key_blocks", None), span)
 
     def _note_moe(self, stats, span) -> None:
         """Fold a fetched chunk's routed-layer counters (they came over
@@ -3200,6 +3205,20 @@ class InferenceEngine:
                       moe_zero_slots=c["zero_slots"],
                       moe_away_slots=c["away_slots"],
                       moe_load="n" + "_".join(map(str, c["load"].tolist())))
+
+    def _note_key_blocks(self, key_blocks, span) -> None:
+        """Fold a mixed chunk's prefill key blocks (``(visited, the
+        table holds)`` of one attention of its mixed step, reckoned by
+        the executor at the dispatch) into
+        ``get_stats()["mixed_key_blocks"]`` and, while a capture is
+        held, onto the span that covers the commit: how much of the
+        block tables' windows the prefill attention ran over."""
+        if key_blocks is None:
+            return
+        self._key_blocks += np.asarray(key_blocks, np.int64)
+        if capture_held():
+            span.note(pf_key_blocks=int(key_blocks[0]),
+                      pf_table_blocks=int(key_blocks[1]))
 
     def _moe_counts(self, st: np.ndarray) -> Dict[str, Any]:
         """A family's step counters by name, by the layout the family
@@ -4308,6 +4327,12 @@ class InferenceEngine:
                 "zero_slots": c["zero_slots"],
                 "away_slots": c["away_slots"],
             }
+        if self._key_blocks[1]:
+            # Key blocks the mixed steps' prefill attention ran over and
+            # those their slices' block tables held (one attention's,
+            # summed over the mixed chunks committed).
+            out["mixed_key_blocks"] = {"visited": int(self._key_blocks[0]),
+                                       "table": int(self._key_blocks[1])}
         if self._tiering is not None:
             # Tiered KV plane (docs/tiering.md): residency per tier,
             # hit breakdown incl. recompute, spill/round-trip counts.

@@ -692,15 +692,21 @@ class MixedChunkHandle:
     ``pf_first`` — the per-slice sampled next tokens the engine commits
     for sequences whose FINAL slice rode this chunk."""
 
-    __slots__ = ("out", "tok", "pos", "done", "pf_first", "stats")
+    __slots__ = ("out", "tok", "pos", "done", "pf_first", "stats",
+                 "key_blocks")
 
-    def __init__(self, out, tok, pos, done, pf_first, stats=None) -> None:
+    def __init__(self, out, tok, pos, done, pf_first, stats=None,
+                 key_blocks=None) -> None:
         self.out = out
         self.tok = tok
         self.pos = pos
         self.done = done
         self.pf_first = pf_first
         self.stats = stats     # as ChunkHandle.stats
+        #: (visited, the table holds): the key blocks one prefill
+        #: attention of this chunk's mixed step runs over its slices,
+        #: reckoned on the host (None: the family counts none).
+        self.key_blocks = key_blocks
 
     def pf_first_at(self, i: int):
         """Slice ``i``'s sampled first token, still on the device: the
@@ -867,6 +873,11 @@ class JaxExecutor:
         #: ... and where each lies (``models/__init__.py``): what the
         #: engine reads a fetched chunk's counters by.
         self.step_stats_layout = fam.step_stats_layout(model_cfg)
+        #: ``(seq_lens, T, page_size, max_pages) -> (visited, the table
+        #: holds)`` for a family whose prefill attention loops over key
+        #: blocks (``models/__init__.py``), else None: what a mixed
+        #: chunk's handle carries as ``key_blocks``.
+        self._mixed_key_blocks = getattr(fam, "mixed_key_blocks", None)
 
         def forward_decode(params, cfg, tok, pos, cache, bts, active=None,
                            acc=None):
@@ -2294,7 +2305,15 @@ class JaxExecutor:
             jnp.asarray(pf_lens), jnp.asarray(pf_bts),
             jnp.asarray(pf_temps),
             self._next_key())
-        return MixedChunkHandle(out, tok, pos, done, pf_first, stats)
+        key_blocks = None
+        if self._mixed_key_blocks is not None:
+            # the slices' contexts as the program reads them (an empty
+            # slot is one trash token at position 0: a context of 1)
+            key_blocks = self._mixed_key_blocks(
+                pf_poss[:, 0] + pf_lens, T, self.spec.page_size,
+                self.spec.max_pages_per_seq)
+        return MixedChunkHandle(out, tok, pos, done, pf_first, stats,
+                                key_blocks)
 
     # -- tiered KV page transport (llmq_tpu/tiering/, docs/tiering.md) --------
 

@@ -35,6 +35,13 @@ A family is a module of this package that defines
   holds); ``{}`` for a family that counts nothing. The engine reads
   the counters by this and by nothing else
   (``get_stats()["moe"]``);
+- optionally ``mixed_key_blocks(seq_lens, T, page_size, max_pages)``:
+  for a family whose prefill attention is a loop over key blocks under
+  XLA, ``(visited, the table holds)``: the blocks one attention of a
+  mixed step runs over slices of these contexts, reckoned on the host
+  by the rule the program runs by (``get_stats()["mixed_key_blocks"]``);
+  a family without it (a prefill kernel that follows the context by
+  itself) counts nothing;
 - ``routes(cfg, cache, *, batch, page_size, max_pages, decode,
   prefill_rows)``: which implementation each attention op of a program
   takes (``ops/attention.kernel_routes``'s form);

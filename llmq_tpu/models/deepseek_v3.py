@@ -70,7 +70,8 @@ import jax.numpy as jnp
 from llmq_tpu.models.latent import (  # noqa: F401
     LatentDims, attn_norm_count, attn_norm_leaves, attn_param_shapes,
     draw_groups, init_latent_pool, latent_decode_attention,
-    latent_prefill_attention, latent_write_prefill, param_count, routes)
+    latent_prefill_attention, latent_write_prefill, param_count,
+    prefill_key_blocks, routes)
 from llmq_tpu.models.latent import prod as _prod
 from llmq_tpu.models.latent import swiglu as _mlp
 from llmq_tpu.models.latent import decode_geometry as _decode_geometry
@@ -179,6 +180,16 @@ def step_stats_layout(cfg: DeepseekV3Config) -> Dict[str, Any]:
 
 def step_stats_size(cfg: DeepseekV3Config) -> int:
     return cfg.n_routed_experts + 2
+
+
+def mixed_key_blocks(seq_lens, T: int, page_size: int, max_pages: int):
+    """(visited, the table holds): the key blocks ONE prefill attention
+    of a mixed step runs over slices of these contexts (a NumPy array;
+    the executor's empty slot is one trash token: 1), and those their
+    block tables hold (``models/__init__.py``). The slices attend side
+    by side: every one runs the longest's blocks."""
+    _, visited, table = prefill_key_blocks(seq_lens, T, page_size, max_pages)
+    return len(seq_lens) * int(visited), len(seq_lens) * table
 
 
 def check_serving(cfg: DeepseekV3Config, *, quantization: str = "",

@@ -299,34 +299,87 @@ def latent_write_prefill(pool, rows, block_tables, positions, lengths,
         merged.reshape(B * n_pages, ps, W))
 
 
+def prefill_key_blocks(seq_lens, T: int, page_size: int, max_pages: int):
+    """The prefill attention's key blocks for rows attending SIDE BY
+    SIDE: (pages a block, blocks the loop visits, blocks the table
+    holds). A block is the whole pages that hold a slice of T tokens
+    (512 tokens = 4 pages of 128 where a slice is 512 wide), so a fresh
+    prompt's context is ONE block, and never more than the table; the
+    loop runs to the end of the longest row's context. ``seq_lens`` a
+    device array inside a program or a NumPy one on the host: the
+    executor counts by the rule the program runs by."""
+    pages = max(1, min(-(-T // page_size), max_pages))
+    tokens = pages * page_size
+    return (pages, (seq_lens.max() + tokens - 1) // tokens,
+            -(-max_pages // pages))
+
+
 def latent_prefill_attention(cfg, lp: Params, l: int, q_nope, q_rope, pool,
                              block_tables, positions, seq_lens):
-    """Prefill attention ``l``, UNABSORBED, under XLA: K and V are
-    expanded from each row's cached latents (its whole block-table
-    window, the new tokens already written), causal by absolute
-    position. q_* (B, T, H, .). Returns (B, T, H * dv)."""
-    B, T = q_nope.shape[:2]
-    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
+    """Prefill attention ``l``, UNABSORBED, under XLA, over the LIVE
+    key blocks of its rows' block-table windows (the new tokens already
+    written), causal by absolute position. q_* (B, T, H, .).
+    Returns (B, T, H * dv).
+
+    One loop body, its trip count read from ``seq_lens``
+    (``prefill_key_blocks``): block ``j`` gathers its pages of every
+    row, expands K and V from their latents, scores them in float32
+    (the nope part times s_kv, plus the RoPE part, times the head
+    scale), masks by position and ``seq_lens`` and folds them into a
+    running maximum, sum and float32 accumulator (the online softmax);
+    one division at the end. The scores that exist at a time are
+    (B, H, T, block); a block the loop does not visit is past every
+    context: all mask. Block 0 holds key 0, which every position of a
+    live row sees, so from there on the running maximum is a real score
+    and a wholly masked (row, block) adds exp(-1e30 - m) = 0. A row
+    with ``seq_lens`` 0 (an empty slot of a mixed step) returns zeros;
+    alone it runs no block."""
+    B, T, H = q_nope.shape[:3]
+    r, dr, dv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ps, mp = pool.shape[2], block_tables.shape[1]
+    bp, visited, _ = prefill_key_blocks(seq_lens, T, ps, mp)
+    kb = bp * ps
+    block_tables = jnp.pad(block_tables, ((0, 0), (0, -mp % bp)))
     wk, wv = wkv_b(cfg, lp, l)
-    k_nope = jnp.einsum("bsr,rhn->bshn", rows[..., :r], wk)
-    v = jnp.einsum("bsr,rhv->bshv", rows[..., :r], wv)
-    s_nope = jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
-                        preferred_element_type=jnp.float32)
-    s_kv = cfg.kv_scale
-    if s_kv != 1.0:
-        s_nope = s_nope * s_kv
-    s = s_nope + jnp.einsum("bthr,bsr->bhts", q_rope, rows[..., r:r + dr],
-                            preferred_element_type=jnp.float32)
-    key_pos = jnp.arange(rows.shape[1])
-    mask = ((key_pos[None, None, :] <= positions[:, :, None])
-            & (key_pos[None, None, :] < seq_lens[:, None, None]))
-    p = jax.nn.softmax(jnp.where(mask[:, None], s * cfg.qk_head_dim ** -0.5,
-                                 NEG), axis=-1)
-    if s_kv != 1.0:
-        o = (jnp.einsum("bhts,bshv->bthv", p.astype(v.dtype), v,
-                        preferred_element_type=jnp.float32)
-             * s_kv).astype(v.dtype)
-    else:
-        o = jnp.einsum("bhts,bshv->bthv", p.astype(v.dtype), v)
-    return o.reshape(B, T, -1)
+    scale, s_kv = cfg.qk_head_dim ** -0.5, cfg.kv_scale
+
+    def block(j, carry):
+        m, z, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(block_tables, j * bp, bp, 1)
+        rows = pool[l, pages].reshape(B, kb, pool.shape[-1])
+        k_nope = jnp.einsum("bsr,rhn->bshn", rows[..., :r], wk)
+        v = jnp.einsum("bsr,rhv->bshv", rows[..., :r], wv)
+        s = jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
+                       preferred_element_type=jnp.float32)
+        if s_kv != 1.0:
+            s = s * s_kv
+        s = s + jnp.einsum("bthr,bsr->bhts", q_rope, rows[..., r:r + dr],
+                           preferred_element_type=jnp.float32)
+        key_pos = j * kb + jnp.arange(kb)
+        mask = ((key_pos[None, None, :] <= positions[:, :, None])
+                & (key_pos[None, None, :] < seq_lens[:, None, None]))
+        s = jnp.where(mask[:, None], s * scale, NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        fade = jnp.exp(m - m_new)
+        z = z * fade + jnp.sum(p, axis=-1)
+        acc = acc * fade.transpose(0, 2, 1)[..., None] + jnp.einsum(
+            "bhts,bshv->bthv", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, z, acc
+
+    with jax.named_scope("latent_prefill_attention"):
+        _, z, acc = jax.lax.fori_loop(
+            0, visited, block,
+            (jnp.full((B, H, T), NEG, jnp.float32),
+             jnp.zeros((B, H, T), jnp.float32),
+             jnp.zeros((B, T, H, dv), jnp.float32)))
+        # A row that is not live: z is 0 (no block ran) or counts masked
+        # keys (a live row beside it ran some), and its output is 0.
+        live = (seq_lens > 0)[:, None, None]
+        z = jnp.where(live, z, 1.0).transpose(0, 2, 1)[..., None]
+        o = jnp.where(live[..., None], acc / z, 0.0)
+        if s_kv != 1.0:
+            o = o * s_kv
+        return o.astype(jnp.result_type(pool.dtype, wv.dtype)).reshape(
+            B, T, -1)

@@ -68,7 +68,7 @@ from llmq_tpu.models.latent import (  # noqa: F401
     LatentDims, attn_norm_count, attn_norm_leaves, attn_param_shapes,
     decode_geometry, draw_groups, init_latent_pool,
     latent_decode_attention, latent_prefill_attention, latent_write_prefill,
-    param_count, qkv, routes)
+    param_count, prefill_key_blocks, qkv, routes)
 from llmq_tpu.models.latent import prod as _prod
 from llmq_tpu.models.latent import swiglu as _mlp
 from llmq_tpu.ops.moe import identity_gate, route, routed_ffn
@@ -191,6 +191,17 @@ def step_stats_layout(cfg: LongcatFlashConfig) -> Dict[str, Any]:
 
 def step_stats_size(cfg: LongcatFlashConfig) -> int:
     return cfg.n_held + 4
+
+
+def mixed_key_blocks(seq_lens, T: int, page_size: int, max_pages: int):
+    """(visited, the table holds): the key blocks ONE prefill attention
+    of a mixed step runs over slices of these contexts (a NumPy array;
+    the executor's empty slot is one trash token: 1), and those their
+    block tables hold (``models/__init__.py``). The slices attend one
+    at a time (``_prefill_attend``): each runs its own blocks."""
+    each = [prefill_key_blocks(seq_lens[i:i + 1], T, page_size, max_pages)
+            for i in range(len(seq_lens))]
+    return sum(int(v) for _, v, _ in each), sum(t for _, _, t in each)
 
 
 def check_serving(cfg: LongcatFlashConfig, *, quantization: str = "",
@@ -396,11 +407,12 @@ def _run(params, cfg, h, positions, attend, live):
 def _prefill_attend(cfg, lp, pool, block_tables, positions, lengths,
                     seq_lens):
     """``attend`` of B slices of T tokens: write the rows, then the
-    expanded attention over each slice's block-table window, ONE SLICE
-    AT A TIME (at 64 heads a slice's float32 scores over a 2,048-token
-    window are 0.27 GB: four slices side by side were 2.6 GB of a
-    mixed step's temporaries). ``pool`` is a one-element list: the
-    pool as it stands."""
+    expanded attention over each slice's live key blocks
+    (``latent_prefill_attention``), ONE SLICE AT A TIME: each slice
+    then runs exactly its own blocks, and at 64 heads a slice's float32
+    scores over one 512-token block are 67 MB where four slices side by
+    side hold 268. ``pool`` is a
+    one-element list: the pool as it stands."""
     B, T = positions.shape
 
     def attend(a, q_nope, q_rope, row):

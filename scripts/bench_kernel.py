@@ -27,9 +27,11 @@ this one), ``--max-pages N`` widens or narrows the block table,
 ``--pages-per-chunk N`` overrides the plan's chunk, ``--out F`` writes
 the numbers to F and each occupancy's attention output beside it.
 
-``--prefill LENGTH@START`` (family ``llama``; repeatable, and then
-``--lens`` may be left out) times ONE slice of the mixed step through
-the prefill attention kernel the file's pools take (bf16 or int8): a
+``--prefill LENGTH@START`` (repeatable, and then ``--lens`` may be left
+out) times ONE slice of the mixed step through the prefill attention:
+for a latent family ``models/latent.latent_prefill_attention`` (XLA,
+a loop over the context's key blocks; ``--tree`` times a parent's), for
+the family ``llama`` the kernel the file's pools take (bf16 or int8): a
 slice of the mixed budget's width (``prefill_token_budget`` /
 ``max_slices``) holding LENGTH valid tokens from position START on.
 Prints µs a call beside the plan's loop steps and the least time of
@@ -417,6 +419,118 @@ def bench_prefill(args, doc) -> None:
                        "results": results}, f, indent=1)
 
 
+def bench_latent_prefill(args, doc) -> None:
+    """One slice of a latent family's mixed step through
+    ``models/latent.latent_prefill_attention`` (``--prefill
+    LENGTH@START``; XLA: expanded K and V from the cached latents): µs
+    a call beside the least time of the work that is there."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from types import SimpleNamespace
+
+    from llmq_tpu.models import latent
+
+    class Dims(latent.LatentDims, SimpleNamespace):
+        """What the attention reads of a configuration."""
+
+    ex, model = doc["server"]["executor"], doc["server"]["model"]
+    cfg = Dims(dim=doc["hidden_size"], n_heads=doc["num_attention_heads"],
+               kv_lora_rank=doc["kv_lora_rank"],
+               q_lora_rank=doc.get("q_lora_rank"),
+               qk_nope_head_dim=doc["qk_nope_head_dim"],
+               qk_rope_head_dim=doc["qk_rope_head_dim"],
+               v_head_dim=doc["v_head_dim"],
+               mla_scale_q_lora=bool(doc.get("mla_scale_q_lora")),
+               mla_scale_kv_lora=bool(doc.get("mla_scale_kv_lora")),
+               dtype=jnp.bfloat16)
+    H, r, dr = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    dn, dv, W = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.latent_width
+    L = doc.get("num_hidden_layers") or 2 * doc["num_layers"]
+    reps = 8
+    if args.rehearse:
+        L, reps = 2, 2
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path on this host)")
+    ps, P = ex["page_size"], ex["kv_pages"]
+    mp = args.max_pages or model["max_seq_len"] // ps
+    mixed = ex["mixed_batch"]
+    T = mixed["prefill_token_budget"] // mixed["max_slices"]
+    key = jax.random.key(0)
+    pool = jnp.tile(jax.random.normal(key, (1, P, ps, W), jnp.bfloat16),
+                    (L, 1, 1, 1)) * 0.5
+    lp = {"wkv_b": jax.random.normal(key, (L, r, H * (dn + dv)),
+                                     jnp.bfloat16) * r ** -0.5}
+    q_nope = jax.random.normal(key, (1, T, H, dn), jnp.bfloat16)
+    q_rope = jax.random.normal(key, (1, T, H, dr), jnp.bfloat16)
+
+    @jax.jit
+    def run(pool, bt, positions, seq_lens):
+        outs = []
+        for i in range(reps):
+            # A query of its own a call: equal calls are one to XLA.
+            o = latent.latent_prefill_attention(
+                cfg, lp, i % L, q_nope * (1 + i / 64), q_rope, pool, bt,
+                positions, seq_lens)
+            outs.append(jnp.sum(o.astype(jnp.float32)))
+        return jnp.stack(outs), o
+
+    print(f"{doc['name']}: latent prefill slice T={T} H={H} rank={r} "
+          f"ps={ps} max_pages={mp} tree={args.tree} "
+          f"device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    rng = np.random.default_rng(0)
+    n = 1 if args.rehearse else 10
+    results = []
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+    for spec in args.prefill:
+        length, _, start = spec.partition("@")
+        length, start = int(length), int(start or 0)
+        if not 0 < length <= T or start + length > mp * ps:
+            sys.exit(f"--prefill {spec}: a slice holds 1 to {T} tokens "
+                     f"and a block table {mp * ps}")
+        ctx = start + length
+        bt = np.zeros((1, mp), np.int32)
+        live = -(-ctx // ps)
+        bt[0, :live] = 1 + rng.permutation(P - 1)[:live]
+        positions = start + np.minimum(np.arange(T), length - 1)[None]
+        call = (jnp.asarray(bt), jnp.asarray(positions, jnp.int32),
+                jnp.asarray([ctx], jnp.int32))
+        outs, o = run(pool, *call)
+        finite = bool(np.isfinite(np.asarray(o, np.float32)).all())
+        t0 = time.perf_counter()
+        for _ in range(n):
+            outs, o = run(pool, *call)
+        jax.block_until_ready(outs)
+        us = (time.perf_counter() - t0) / (n * reps) * 1e6
+        # K and V expanded from the context's latents, then each valid
+        # query against what it sees: QK^T (nope and rope) and PV
+        pairs = sum(start + t + 1 for t in range(length))
+        flops = (2 * ctx * r * H * (dn + dv)
+                 + 2 * pairs * H * (dn + dr + dv))
+        nbytes = (ctx * W * 2 + r * H * (dn + dv) * 2
+                  + length * H * (dn + dr + dv) * 2)
+        flops_us = flops / PEAK_FLOPS_PER_S * 1e6
+        bytes_us = nbytes / PEAK_BYTES_PER_S * 1e6
+        results.append({"prefill": spec, "length": length, "start": start,
+                        "us_per_call": us, "finite": finite,
+                        "flops_least_us": flops_us,
+                        "bytes_least_us": bytes_us})
+        print(f"  prefill {length} tokens at {start}: {us:,.1f} us/call  "
+              f"products at peak {flops_us:,.1f} us, "
+              f"bytes at peak {bytes_us:,.1f} us = "
+              f"{100 * max(flops_us, bytes_us) / us:.1f} %  finite={finite}",
+              flush=True)
+    if args.out:
+        with open(f"{args.out}.prefill.json", "w", encoding="utf-8") as f:
+            json.dump({"config": doc["name"], "tree": args.tree,
+                       "max_pages": mp, "results": results}, f, indent=1)
+
+
 #: family (the configuration file's ``family``, ``llmq_tpu/models``
 #: ``FAMILIES``) -> the bench of its decode kernels. The kernels a
 #: family dispatches are what this tool is about, so a new family's
@@ -452,10 +566,8 @@ def main() -> None:
         sys.exit(f"{args.model_file}: no kernel bench for the family "
                  f"{doc.get('family')!r}; known: {sorted(BENCHES)}")
     if args.prefill:
-        if doc["family"] != "llama":
-            sys.exit("--prefill: the paged prefill kernel is the family "
-                     "llama's")
-        bench_prefill(args, doc)
+        (bench_prefill if doc["family"] == "llama"
+         else bench_latent_prefill)(args, doc)
     if args.lens:
         BENCHES[doc["family"]](args, doc)
     elif not args.prefill:
