@@ -767,6 +767,7 @@ class InferenceEngine:
                            else None)
         self.mixed_steps = 0
         self.mixed_prefill_tokens_total = 0
+        self.mixed_slice_tokens_total = 0
         #: Decode-stall attribution: estimated ms decode rows spent (or
         #: would spend) behind prefill work dispatched while they were
         #: active. Unfused prefill programs serialize with the decode
@@ -2649,20 +2650,29 @@ class InferenceEngine:
         in flight once this one is, ``context_tokens`` the tokens the
         rows attend to at the first step (host bookkeeping: a
         speculative dispatch counts each unreconciled chunk's full
-        budget), ``prefill_tokens`` the prompt tokens riding along.
+        budget), ``prefill_tokens`` the prompt tokens riding along and
+        ``slice_tokens`` the rows the program computes for them, padding
+        included (the executor's ``slice_tokens``: slices x width of a
+        mixed chunk, bucket x rows of a prefill program, 0 otherwise).
         ``pages_live`` / ``tokens_live`` (``_live_kv``) only while a
         capture is held. The same quantities accumulate for
         ``get_stats()``."""
         self.device_steps += steps
         self.row_steps += row_steps
         name_fn = getattr(self.executor, "program_name", None)
+        slice_fn = getattr(self.executor, "slice_tokens", None)
+        slice_tokens = (0 if slice_fn is None
+                        else slice_fn(entry, longest, rows))
+        if chunk:            # a mixed chunk: the dedicated programs'
+            self.mixed_slice_tokens_total += slice_tokens  # are apart
         counts = {
             "program": (entry if name_fn is None
                         else name_fn(entry, longest)),
             "steps": steps, "rows": rows, "row_steps": row_steps,
             "inflight": len(self._inflight) + (1 if chunk else 0),
             "context_tokens": context_tokens,
-            "prefill_tokens": prefill_tokens}
+            "prefill_tokens": prefill_tokens,
+            "slice_tokens": slice_tokens}
         if capture_held():
             counts["pages_live"], counts["tokens_live"] = self._live_kv()
         return self._prof.span("engine.dispatch", **counts)
@@ -4305,6 +4315,8 @@ class InferenceEngine:
             out["mixed_batch"] = {
                 "steps": self.mixed_steps,
                 "prefill_tokens": self.mixed_prefill_tokens_total,
+                # the rows the mixed programs computed for them
+                "slice_tokens": self.mixed_slice_tokens_total,
                 "prefill_token_budget":
                     int(self._mixed_cfg.prefill_token_budget),
             }

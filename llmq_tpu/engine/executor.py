@@ -37,7 +37,7 @@ from typing import Callable, Dict, List, Optional, Protocol, Tuple
 import numpy as np
 
 from llmq_tpu.utils.logging import get_logger
-from llmq_tpu.utils.profiling import SpanRecorder
+from llmq_tpu.utils.profiling import SpanRecorder, scope
 
 log = get_logger("executor")
 
@@ -315,6 +315,13 @@ class EchoExecutor:
             self.decode_chunk_start = None    # type: ignore[assignment]
             self.mixed_chunk_start = None     # type: ignore[assignment]
             self.verify_chunk_start = None    # type: ignore[assignment]
+
+    def slice_tokens(self, entry: str, tokens: int = 0, rows: int = 1) -> int:
+        """Parity with :meth:`JaxExecutor.slice_tokens`: a mixed chunk
+        counts its slices at full width; a prefill pads nothing here."""
+        if entry == "mixed_chunk":
+            return self.mixed_prefill_slices * self.mixed_slice_tokens
+        return tokens * rows if entry.startswith("prefill") else 0
 
     def _register_prefill(self, slot: int, tokens: List[int],
                           start_pos: int) -> List[int]:
@@ -1038,12 +1045,14 @@ class JaxExecutor:
         @jit_step
         def _prefill_step(params, cache, tokens, positions, lengths,
                           block_tables, temperature, key):
-            last, cache = forward_prefill(
-                params, cfg, tokens, positions, lengths, cache,
-                block_tables, last_only=True)          # (1, V) f32
-            tok = sample_token(last, key, temperature=temperature,
-                               top_k=top_k, top_p=top_p)
-            return tok[0], cache
+            with scope("prefill"):
+                last, cache = forward_prefill(
+                    params, cfg, tokens, positions, lengths, cache,
+                    block_tables, last_only=True)      # (1, V) f32
+            with scope("sample"):
+                tok = sample_token(last, key, temperature=temperature,
+                                   top_k=top_k, top_p=top_p)[0]
+            return tok, cache
 
         @jit_step
         def _prefill_multi(params, cache, tokens, positions, lengths,
@@ -1051,11 +1060,13 @@ class JaxExecutor:
             """Batched prefill: N prompts' chunks through one program —
             per-row last-token sampling; padded rows (length ≤ 1,
             all-zero block table) write only reserved page 0."""
-            last, cache = forward_prefill(
-                params, cfg, tokens, positions, lengths, cache,
-                block_tables, last_only=True)          # (N, V)
-            toks = sample_token(last, key, temperature=temperatures,
-                                top_k=top_k, top_p=top_p)
+            with scope("prefill"):
+                last, cache = forward_prefill(
+                    params, cfg, tokens, positions, lengths, cache,
+                    block_tables, last_only=True)      # (N, V)
+            with scope("sample"):
+                toks = sample_token(last, key, temperature=temperatures,
+                                    top_k=top_k, top_p=top_p)
             return toks, cache
 
         @jit_decode
@@ -1063,8 +1074,9 @@ class JaxExecutor:
                          temperatures, key):
             logits, cache, _ = forward_decode(
                 params, cfg, tokens, positions, cache, block_tables)
-            toks = sample_token(logits, key, temperature=temperatures,
-                                top_k=top_k, top_p=top_p)
+            with scope("sample"):
+                toks = sample_token(logits, key, temperature=temperatures,
+                                    top_k=top_k, top_p=top_p)
             return toks, cache
 
         K = self.chunk_size
@@ -1116,24 +1128,28 @@ class JaxExecutor:
                     logits, cache, acc = forward_decode(
                         params, cfg, tok, pos, cache, block_tables,
                         active=active, acc=acc)
-                    nxt = sample_token(logits, keys[j + u],
-                                       temperature=temperatures,
-                                       top_k=top_k, top_p=top_p)
-                    emit = jnp.where(active, nxt, eos).astype(jnp.int32)
-                    out = jax.lax.dynamic_update_slice(
-                        out, emit[:, None], (0, j + u))
-                    # Budget-paused rows keep their last REAL token —
-                    # it is the next chunk's input; only active rows
-                    # advance.
-                    tok = jnp.where(active, nxt.astype(jnp.int32), tok)
-                    pos = pos + active.astype(jnp.int32)
-                    frozen = frozen | (active & (nxt == eos))
+                    with scope("sample"):
+                        nxt = sample_token(logits, keys[j + u],
+                                           temperature=temperatures,
+                                           top_k=top_k, top_p=top_p)
+                        emit = jnp.where(active, nxt,
+                                         eos).astype(jnp.int32)
+                        out = jax.lax.dynamic_update_slice(
+                            out, emit[:, None], (0, j + u))
+                        # Budget-paused rows keep their last REAL token
+                        # — it is the next chunk's input; only active
+                        # rows advance.
+                        tok = jnp.where(active, nxt.astype(jnp.int32),
+                                        tok)
+                        pos = pos + active.astype(jnp.int32)
+                        frozen = frozen | (active & (nxt == eos))
                 return (j + UNROLL, cache, tok, pos, frozen, out, acc)
 
-            _, cache, tok, pos, frozen, out, acc = jax.lax.while_loop(
-                cond, body,
-                (jnp.int32(0), cache, tokens, positions, frozen0, out0,
-                 stats0()))
+            with scope("decode_loop"):
+                _, cache, tok, pos, frozen, out, acc = jax.lax.while_loop(
+                    cond, body,
+                    (jnp.int32(0), cache, tokens, positions, frozen0, out0,
+                     stats0()))
             return out, tok, pos, frozen, cache, acc
 
         S, T = self.mixed_prefill_slices, self.mixed_slice_tokens
@@ -1169,21 +1185,25 @@ class JaxExecutor:
                 out = jnp.full((B, K), eos, jnp.int32)
                 frozen = done_in
                 active0 = (~frozen) & (budgets > 0)
-                dec_logits, pf_logits, cache, acc = forward_mixed(
-                    params, cfg, tokens, positions, cache, block_tables,
-                    pf_tokens, pf_positions, pf_lengths, pf_block_tables,
-                    dec_active=active0)
-                pf_first = sample_token(
-                    pf_logits, keys[K],
-                    temperature=pf_temps, top_k=top_k, top_p=top_p)
-                nxt = sample_token(dec_logits, keys[0],
-                                   temperature=temperatures,
-                                   top_k=top_k, top_p=top_p)
-                emit = jnp.where(active0, nxt, eos).astype(jnp.int32)
-                out = out.at[:, 0].set(emit)
-                tok = jnp.where(active0, nxt.astype(jnp.int32), tokens)
-                pos = positions + active0.astype(jnp.int32)
-                frozen = frozen | (active0 & (nxt == eos))
+                with scope("mixed_step"):
+                    dec_logits, pf_logits, cache, acc = forward_mixed(
+                        params, cfg, tokens, positions, cache,
+                        block_tables, pf_tokens, pf_positions, pf_lengths,
+                        pf_block_tables, dec_active=active0)
+                    with scope("sample"):
+                        pf_first = sample_token(
+                            pf_logits, keys[K],
+                            temperature=pf_temps, top_k=top_k, top_p=top_p)
+                        nxt = sample_token(dec_logits, keys[0],
+                                           temperature=temperatures,
+                                           top_k=top_k, top_p=top_p)
+                        emit = jnp.where(active0, nxt,
+                                         eos).astype(jnp.int32)
+                        out = out.at[:, 0].set(emit)
+                        tok = jnp.where(active0, nxt.astype(jnp.int32),
+                                        tokens)
+                        pos = positions + active0.astype(jnp.int32)
+                        frozen = frozen | (active0 & (nxt == eos))
 
                 def cond(st):
                     j, _, _, _, fr, _, _ = st
@@ -1195,20 +1215,24 @@ class JaxExecutor:
                     logits, cache, acc = forward_decode(
                         params, cfg, tok, pos, cache, block_tables,
                         active=active, acc=acc)
-                    nxt = sample_token(logits, keys[j],
-                                       temperature=temperatures,
-                                       top_k=top_k, top_p=top_p)
-                    emit = jnp.where(active, nxt, eos).astype(jnp.int32)
-                    out = jax.lax.dynamic_update_slice(
-                        out, emit[:, None], (0, j))
-                    tok = jnp.where(active, nxt.astype(jnp.int32), tok)
-                    pos = pos + active.astype(jnp.int32)
-                    fr = fr | (active & (nxt == eos))
+                    with scope("sample"):
+                        nxt = sample_token(logits, keys[j],
+                                           temperature=temperatures,
+                                           top_k=top_k, top_p=top_p)
+                        emit = jnp.where(active, nxt,
+                                         eos).astype(jnp.int32)
+                        out = jax.lax.dynamic_update_slice(
+                            out, emit[:, None], (0, j))
+                        tok = jnp.where(active, nxt.astype(jnp.int32), tok)
+                        pos = pos + active.astype(jnp.int32)
+                        fr = fr | (active & (nxt == eos))
                     return (j + 1, cache, tok, pos, fr, out, acc)
 
-                _, cache, tok, pos, frozen, out, acc = jax.lax.while_loop(
-                    cond, body,
-                    (jnp.int32(1), cache, tok, pos, frozen, out, acc))
+                with scope("decode_loop"):
+                    (_, cache, tok, pos, frozen, out,
+                     acc) = jax.lax.while_loop(
+                        cond, body,
+                        (jnp.int32(1), cache, tok, pos, frozen, out, acc))
                 return out, tok, pos, frozen, pf_first, cache, acc
 
         _verify_chunk = None
@@ -1262,27 +1286,30 @@ class JaxExecutor:
                     logits, cache, _ = forward_decode(
                         params, cfg, tok, pos, cache, block_tables,
                         active=active)
-                    ks = position_keys(key, rows, pos + 1)
-                    nxt = sample_token_keyed(
-                        logits, ks, temperature=temperatures,
-                        top_k=top_k, top_p=top_p)
-                    emit = jnp.where(active, nxt, eos).astype(jnp.int32)
-                    out = jax.lax.dynamic_update_slice(
-                        out, emit[:, None], (0, j))
-                    ncommit = ncommit + active.astype(jnp.int32)
-                    nd = jax.lax.dynamic_slice_in_dim(
-                        drafts_pad, j, 1, axis=1)[:, 0]
-                    frozen = frozen | (active & ((nxt == eos)
-                                                 | (nxt != nd)))
-                    tok = jnp.where(active, nd, tok)
-                    pos = pos + active.astype(jnp.int32)
+                    with scope("sample"):
+                        ks = position_keys(key, rows, pos + 1)
+                        nxt = sample_token_keyed(
+                            logits, ks, temperature=temperatures,
+                            top_k=top_k, top_p=top_p)
+                        emit = jnp.where(active, nxt,
+                                         eos).astype(jnp.int32)
+                        out = jax.lax.dynamic_update_slice(
+                            out, emit[:, None], (0, j))
+                        ncommit = ncommit + active.astype(jnp.int32)
+                        nd = jax.lax.dynamic_slice_in_dim(
+                            drafts_pad, j, 1, axis=1)[:, 0]
+                        frozen = frozen | (active & ((nxt == eos)
+                                                     | (nxt != nd)))
+                        tok = jnp.where(active, nd, tok)
+                        pos = pos + active.astype(jnp.int32)
                     return (j + 1, cache, tok, pos, frozen, out, ncommit)
 
                 frozen0 = qlens <= 0
-                _, cache, _, _, _, out, ncommit = jax.lax.while_loop(
-                    cond, body,
-                    (jnp.int32(0), cache, tokens, positions, frozen0,
-                     out0, jnp.zeros(B, jnp.int32)))
+                with scope("decode_loop"):
+                    _, cache, _, _, _, out, ncommit = jax.lax.while_loop(
+                        cond, body,
+                        (jnp.int32(0), cache, tokens, positions, frozen0,
+                         out0, jnp.zeros(B, jnp.int32)))
                 return out, ncommit, cache
 
         elif self.verify_draft_k > 0:
@@ -1309,21 +1336,23 @@ class JaxExecutor:
                 device-accept program's.
                 """
                 B = tokens.shape[0]
-                logits, cache = forward_verify(
-                    params, cfg, tokens, positions, qlens, cache,
-                    block_tables)
-                V = logits.shape[-1]
-                pos_flat = (positions[:, None]
-                            + jnp.arange(W, dtype=jnp.int32)[None, :]
-                            + 1).reshape(-1)
-                rows_flat = jnp.repeat(
-                    jnp.arange(B, dtype=jnp.int32), W)
-                ks = position_keys(key, rows_flat, pos_flat)
-                toks = sample_token_keyed(
-                    logits.reshape(B * W, V), ks,
-                    temperature=jnp.repeat(temperatures, W),
-                    top_k=top_k, top_p=top_p)
-                return toks.reshape(B, W), cache
+                with scope("decode_loop"):  # the W steps, unrolled
+                    logits, cache = forward_verify(
+                        params, cfg, tokens, positions, qlens, cache,
+                        block_tables)
+                with scope("sample"):
+                    V = logits.shape[-1]
+                    pos_flat = (positions[:, None]
+                                + jnp.arange(W, dtype=jnp.int32)[None, :]
+                                + 1).reshape(-1)
+                    rows_flat = jnp.repeat(
+                        jnp.arange(B, dtype=jnp.int32), W)
+                    ks = position_keys(key, rows_flat, pos_flat)
+                    toks = sample_token_keyed(
+                        logits.reshape(B * W, V), ks,
+                        temperature=jnp.repeat(temperatures, W),
+                        top_k=top_k, top_p=top_p)
+                    return toks.reshape(B, W), cache
 
         self._prefill_step = _prefill_step
         self._prefill_multi = _prefill_multi
@@ -2027,6 +2056,21 @@ class JaxExecutor:
         if entry in ("prefill", "prefill_multi"):
             return f"{entry}_b{self._bucket_for(max(1, tokens))}"
         return entry
+
+    def slice_tokens(self, entry: str, tokens: int = 0, rows: int = 1) -> int:
+        """Prompt-token rows the program behind ``entry`` computes in
+        one dispatch, padding included: every slice of a mixed chunk at
+        its full width, a prefill program's bucket for each of its rows
+        (``prefill_multi`` always runs ``prefill_batch`` of them). 0 for
+        a program that takes no prompt tokens. What the engine puts on
+        its ``engine.dispatch`` span beside the live ``prefill_tokens``."""
+        if entry == "mixed_chunk":
+            return self.mixed_prefill_slices * self.mixed_slice_tokens
+        if entry == "prefill_multi":
+            return self._bucket_for(max(1, tokens)) * self.prefill_batch
+        if entry == "prefill":
+            return self._bucket_for(max(1, tokens))
+        return 0
 
     def _prefill_chunk(self, chunk: List[int], start_pos: int, bt,
                        temperature: float):

@@ -80,6 +80,7 @@ from llmq_tpu.ops.moe import route, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import embed_lookup
 from llmq_tpu.ops.rope import rope_cos_sin
+from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jnp.ndarray]
@@ -326,10 +327,12 @@ def _ffn(params: Params, cfg: DeepseekV3Config, l: int, x, live):
     ``cfg.dtype``): dense for the first ``first_k_dense`` layers,
     routed + shared after. Returns
     (y, stats or None): ``ops/moe.routed_ffn``'s counts."""
-    xf, x = x, x.astype(cfg.dtype)
-    if l < cfg.first_k_dense:
-        d = params["dense"]
-        return _mlp(x, d["w_gate"][l], d["w_up"][l], d["w_down"][l]), None
+    with scope("mlp"):
+        xf, x = x, x.astype(cfg.dtype)
+        if l < cfg.first_k_dense:
+            d = params["dense"]
+            return _mlp(x, d["w_gate"][l], d["w_up"][l],
+                        d["w_down"][l]), None
     m, i = params["moe"], l - cfg.first_k_dense
     experts, gates = route(
         xf, m["router"][i], m["router_bias"][i],
@@ -337,7 +340,9 @@ def _ffn(params: Params, cfg: DeepseekV3Config, l: int, x, live):
         norm_topk=cfg.norm_topk_prob)
     y, st = routed_ffn(x, experts, gates, m["we_gate_up"][i],
                        m["we_down"][i], live)
-    return y + _mlp(x, m["ws_gate"][i], m["ws_up"][i], m["ws_down"][i]), st
+    with scope("mlp"):       # the shared experts, beside the routed ones
+        return y + _mlp(x, m["ws_gate"][i], m["ws_up"][i],
+                        m["ws_down"][i]), st
 
 
 def _sum_stats(cfg: DeepseekV3Config, per_layer) -> jnp.ndarray:
@@ -349,8 +354,10 @@ def _sum_stats(cfg: DeepseekV3Config, per_layer) -> jnp.ndarray:
 
 
 def _finish(params, h, cfg):
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
-    return jnp.dot(h, params["lm_head"]).astype(jnp.float32)
+    with scope("head"):
+        h = rms_norm(h, params["final_norm"],
+                     cfg.norm_eps).astype(cfg.dtype)
+        return jnp.dot(h, params["lm_head"]).astype(jnp.float32)
 
 
 # -- forward ------------------------------------------------------------------
@@ -364,26 +371,35 @@ def forward_prefill(params: Params, cfg: DeepseekV3Config, tokens,
     through the block tables) over the latent pool. Returns (logits,
     cache), and the routed layers' counts after them with ``stats``."""
     B, T = tokens.shape
-    h = embed_lookup(params["embed"], tokens, jnp.float32)
-    cos, sin = rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    with scope("embed"):
+        h = embed_lookup(params["embed"], tokens, jnp.float32)
+    with scope("qkv"):
+        cos, sin = rope_cos_sin(positions, cfg.qk_rope_head_dim,
+                                cfg.rope_theta)
     valid = jnp.arange(T)[None, :] < lengths[:, None]
     seq_lens = jnp.max(jnp.where(valid, positions, -1), axis=1) + 1
     lp, pool, counts = params["layers"], kv_cache["ckv"], []
     for l in range(cfg.n_layers):
-        x = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps).astype(cfg.dtype)
+        with scope("qkv"):
+            x = rms_norm(h, lp["attn_norm"][l],
+                         cfg.norm_eps).astype(cfg.dtype)
         q_nope, q_rope, row = _qkv(cfg, lp, l, x, cos, sin)
         pool = latent_write_prefill(pool, row, block_tables, positions,
                                     lengths, l)
         attn = latent_prefill_attention(cfg, lp, l, q_nope, q_rope, pool,
                                         block_tables, positions, seq_lens)
-        h = h + jnp.dot(attn, lp["wo"][l])
-        x = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
+        with scope("attn_out"):
+            h = h + jnp.dot(attn, lp["wo"][l])
+        with scope("mlp"):
+            x = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
         y, st = _ffn(params, cfg, l, x.reshape(B * T, -1),
                      valid.reshape(-1))
         counts.append(st)
-        h = h + y.reshape(B, T, -1)
+        with scope("mlp"):
+            h = h + y.reshape(B, T, -1)
     if last_only:
-        h = h[jnp.arange(B), lengths - 1]
+        with scope("head"):
+            h = h[jnp.arange(B), lengths - 1]
     out = (_finish(params, h, cfg), {"ckv": pool})
     return out + (_sum_stats(cfg, counts),) if stats else out
 
@@ -397,23 +413,30 @@ def forward_decode(params: Params, cfg: DeepseekV3Config, tokens, positions,
     active writes to page 0, attends to nothing and is routed to no
     expert; its logits mean nothing."""
     pool = kv_cache["ckv"]
-    h = embed_lookup(params["embed"], tokens, jnp.float32)  # (B, D)
-    cos, sin = rope_cos_sin(positions[:, None], cfg.qk_rope_head_dim,
-                            cfg.rope_theta)
+    with scope("embed"):
+        h = embed_lookup(params["embed"], tokens, jnp.float32)  # (B, D)
+    with scope("qkv"):
+        cos, sin = rope_cos_sin(positions[:, None], cfg.qk_rope_head_dim,
+                                cfg.rope_theta)
     page_of, slot_of, seq_lens = _decode_geometry(
         positions, block_tables, pool.shape[2], active)
     lp, counts = params["layers"], []
     for l in range(cfg.n_layers):
-        x = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps).astype(cfg.dtype)
+        with scope("qkv"):
+            x = rms_norm(h, lp["attn_norm"][l],
+                         cfg.norm_eps).astype(cfg.dtype)
         q_nope, q_rope, row = _qkv(cfg, lp, l, x[:, None], cos, sin)
         attn, pool = latent_decode_attention(
             cfg, lp, l, q_nope[:, 0], q_rope[:, 0], row[:, 0], pool,
             block_tables, seq_lens, page_of, slot_of)
-        h = h + jnp.dot(attn, lp["wo"][l])
-        x = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
+        with scope("attn_out"):
+            h = h + jnp.dot(attn, lp["wo"][l])
+        with scope("mlp"):
+            x = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
         y, st = _ffn(params, cfg, l, x, active)
         counts.append(st)
-        h = h + y
+        with scope("mlp"):
+            h = h + y
     out = (_finish(params, h, cfg), {"ckv": pool})
     return out + (_sum_stats(cfg, counts),) if stats else out
 
@@ -440,46 +463,69 @@ def forward_mixed(params: Params, cfg: DeepseekV3Config, dec_tokens,
     B = dec_tokens.shape[0]
     S, T = pf_tokens.shape
     pool = kv_cache["ckv"]
-    h_d = embed_lookup(params["embed"], dec_tokens, jnp.float32)
-    cos_d, sin_d = rope_cos_sin(dec_positions[:, None],
-                                cfg.qk_rope_head_dim, cfg.rope_theta)
-    page_of, slot_of, dec_seq_lens = _decode_geometry(
-        dec_positions, dec_block_tables, pool.shape[2], dec_active)
-    h_p = embed_lookup(params["embed"], pf_tokens, jnp.float32)
-    cos_p, sin_p = rope_cos_sin(pf_positions, cfg.qk_rope_head_dim,
-                                cfg.rope_theta)
-    pf_valid = jnp.arange(T)[None, :] < pf_lengths[:, None]
-    pf_seq_lens = jnp.max(jnp.where(pf_valid, pf_positions, -1), axis=1) + 1
+    with scope("decode_rows"):
+        with scope("embed"):
+            h_d = embed_lookup(params["embed"], dec_tokens, jnp.float32)
+        with scope("qkv"):
+            cos_d, sin_d = rope_cos_sin(dec_positions[:, None],
+                                        cfg.qk_rope_head_dim,
+                                        cfg.rope_theta)
+        page_of, slot_of, dec_seq_lens = _decode_geometry(
+            dec_positions, dec_block_tables, pool.shape[2], dec_active)
+    with scope("slices"):
+        with scope("embed"):
+            h_p = embed_lookup(params["embed"], pf_tokens, jnp.float32)
+        with scope("qkv"):
+            cos_p, sin_p = rope_cos_sin(pf_positions, cfg.qk_rope_head_dim,
+                                        cfg.rope_theta)
+        pf_valid = jnp.arange(T)[None, :] < pf_lengths[:, None]
+        pf_seq_lens = jnp.max(jnp.where(pf_valid, pf_positions, -1),
+                              axis=1) + 1
     live = jnp.concatenate(
         [pf_valid.reshape(-1), (dec_active if dec_active is not None
                                 else jnp.ones((B,), jnp.bool_))])
     lp, counts = params["layers"], []
     for l in range(cfg.n_layers):
-        x_p = rms_norm(h_p, lp["attn_norm"][l],
-                       cfg.norm_eps).astype(cfg.dtype)
-        qn_p, qr_p, row_p = _qkv(cfg, lp, l, x_p, cos_p, sin_p)
-        pool = latent_write_prefill(pool, row_p, pf_block_tables,
-                                    pf_positions, pf_lengths, l)
-        attn_p = latent_prefill_attention(
-            cfg, lp, l, qn_p, qr_p, pool, pf_block_tables, pf_positions,
-            pf_seq_lens)
-        h_p = h_p + jnp.dot(attn_p, lp["wo"][l])
-        x_d = rms_norm(h_d, lp["attn_norm"][l],
-                       cfg.norm_eps).astype(cfg.dtype)
-        qn_d, qr_d, row_d = _qkv(cfg, lp, l, x_d[:, None], cos_d, sin_d)
-        attn_d, pool = latent_decode_attention(
-            cfg, lp, l, qn_d[:, 0], qr_d[:, 0], row_d[:, 0], pool,
-            dec_block_tables, dec_seq_lens, page_of, slot_of)
-        h_d = h_d + jnp.dot(attn_d, lp["wo"][l])
-        x = jnp.concatenate(
-            [rms_norm(h_p, lp["mlp_norm"][l], cfg.norm_eps).reshape(
-                S * T, -1),
-             rms_norm(h_d, lp["mlp_norm"][l], cfg.norm_eps)])
+        with scope("slices"):
+            with scope("qkv"):
+                x_p = rms_norm(h_p, lp["attn_norm"][l],
+                               cfg.norm_eps).astype(cfg.dtype)
+            qn_p, qr_p, row_p = _qkv(cfg, lp, l, x_p, cos_p, sin_p)
+            pool = latent_write_prefill(pool, row_p, pf_block_tables,
+                                        pf_positions, pf_lengths, l)
+            attn_p = latent_prefill_attention(
+                cfg, lp, l, qn_p, qr_p, pool, pf_block_tables,
+                pf_positions, pf_seq_lens)
+            with scope("attn_out"):
+                h_p = h_p + jnp.dot(attn_p, lp["wo"][l])
+        with scope("decode_rows"):
+            with scope("qkv"):
+                x_d = rms_norm(h_d, lp["attn_norm"][l],
+                               cfg.norm_eps).astype(cfg.dtype)
+            qn_d, qr_d, row_d = _qkv(cfg, lp, l, x_d[:, None], cos_d,
+                                     sin_d)
+            attn_d, pool = latent_decode_attention(
+                cfg, lp, l, qn_d[:, 0], qr_d[:, 0], row_d[:, 0], pool,
+                dec_block_tables, dec_seq_lens, page_of, slot_of)
+            with scope("attn_out"):
+                h_d = h_d + jnp.dot(attn_d, lp["wo"][l])
+        # The feed-forward takes both kinds of row side by side (its
+        # matrices are streamed once): no row kind on its scopes.
+        with scope("mlp"):
+            x = jnp.concatenate(
+                [rms_norm(h_p, lp["mlp_norm"][l], cfg.norm_eps).reshape(
+                    S * T, -1),
+                 rms_norm(h_d, lp["mlp_norm"][l], cfg.norm_eps)])
         y, st = _ffn(params, cfg, l, x, live)
         counts.append(st)
-        h_p = h_p + y[:S * T].reshape(S, T, -1)
-        h_d = h_d + y[S * T:]
-    h_p = h_p[jnp.arange(S), pf_lengths - 1]
-    out = (_finish(params, h_d, cfg), _finish(params, h_p, cfg),
-           {"ckv": pool})
+        with scope("mlp"):
+            h_p = h_p + y[:S * T].reshape(S, T, -1)
+            h_d = h_d + y[S * T:]
+    with scope("slices"), scope("head"):
+        h_p = h_p[jnp.arange(S), pf_lengths - 1]
+    with scope("decode_rows"):
+        dec_logits = _finish(params, h_d, cfg)
+    with scope("slices"):
+        pf_logits = _finish(params, h_p, cfg)
+    out = (dec_logits, pf_logits, {"ckv": pool})
     return out + (_sum_stats(cfg, counts),) if stats else out

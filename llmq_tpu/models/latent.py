@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rope import apply_rope
+from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
 NEG = -1e30
@@ -193,21 +194,24 @@ def qkv(cfg, lp: Params, l: int, x, cos, sin):
     (..., T, H, dr) rotated, row (..., T, W): the cache's row. Both
     halves of q carry s_q; the row carries no scale."""
     r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    if cfg.q_lora_rank:
-        c_q = rms_norm(jnp.dot(x, lp["wq_a"][l]), lp["q_norm"][l],
-                       cfg.norm_eps)
-        q = jnp.dot(c_q, lp["wq_b"][l])
-    else:
-        q = jnp.dot(x, lp["wq"][l])
-    if cfg.q_scale != 1.0:
-        q = (q.astype(jnp.float32) * cfg.q_scale).astype(q.dtype)
-    q = q.reshape(x.shape[:-1] + (cfg.n_heads, dn + dr))
-    q_rope = apply_rope(q[..., dn:], cos, sin)
-    kva = jnp.dot(x, lp["wkv_a"][l])
-    c = rms_norm(kva[..., :r], lp["kv_norm"][l], cfg.norm_eps)
-    k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
-    pad = jnp.zeros(x.shape[:-1] + (cfg.latent_width - r - dr,), c.dtype)
-    return q[..., :dn], q_rope, jnp.concatenate([c, k_rope, pad], axis=-1)
+    with scope("qkv"):
+        if cfg.q_lora_rank:
+            c_q = rms_norm(jnp.dot(x, lp["wq_a"][l]), lp["q_norm"][l],
+                           cfg.norm_eps)
+            q = jnp.dot(c_q, lp["wq_b"][l])
+        else:
+            q = jnp.dot(x, lp["wq"][l])
+        if cfg.q_scale != 1.0:
+            q = (q.astype(jnp.float32) * cfg.q_scale).astype(q.dtype)
+        q = q.reshape(x.shape[:-1] + (cfg.n_heads, dn + dr))
+        q_rope = apply_rope(q[..., dn:], cos, sin)
+        kva = jnp.dot(x, lp["wkv_a"][l])
+        c = rms_norm(kva[..., :r], lp["kv_norm"][l], cfg.norm_eps)
+        k_rope = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
+        pad = jnp.zeros(x.shape[:-1] + (cfg.latent_width - r - dr,),
+                        c.dtype)
+        return (q[..., :dn], q_rope,
+                jnp.concatenate([c, k_rope, pad], axis=-1))
 
 
 def wkv_b(cfg, lp: Params, l: int):
@@ -236,39 +240,48 @@ def latent_decode_attention(cfg, lp: Params, l: int, q_nope, q_rope, row,
     marks a row that is not live (its output is 0, its write went to
     page 0). Returns (o (B, H * dv), pool)."""
     B, r = q_nope.shape[0], cfg.kv_lora_rank
-    wk, wv = wkv_b(cfg, lp, l)
-    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wk)
-    pad = jnp.zeros((B, cfg.n_heads,
-                     cfg.latent_width - r - cfg.qk_rope_head_dim),
-                    q_lat.dtype)
-    scale, s_kv = cfg.qk_head_dim ** -0.5, cfg.kv_scale
-    if s_kv != 1.0:
-        q_lat = q_lat.astype(jnp.float32) * s_kv
-        q_rope, pad = q_rope.astype(jnp.float32), pad.astype(jnp.float32)
-    q_cat = (jnp.concatenate([q_lat, q_rope, pad], axis=-1)
-             .astype(jnp.float32) * scale).astype(pool.dtype)
+    with scope("qkv"):       # the query through wkv_b's key half
+        wk, wv = wkv_b(cfg, lp, l)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wk)
+        pad = jnp.zeros((B, cfg.n_heads,
+                         cfg.latent_width - r - cfg.qk_rope_head_dim),
+                        q_lat.dtype)
+        scale, s_kv = cfg.qk_head_dim ** -0.5, cfg.kv_scale
+        if s_kv != 1.0:
+            q_lat = q_lat.astype(jnp.float32) * s_kv
+            q_rope, pad = (q_rope.astype(jnp.float32),
+                           pad.astype(jnp.float32))
+        q_cat = (jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+                 .astype(jnp.float32) * scale).astype(pool.dtype)
     use, interp = _route(cfg, pool.shape[2])
     if use:
-        pool = _jit_latent("latent_write")(pool, row, page_of, slot_of,
-                                           jnp.int32(l), interpret=interp)
-        o_lat = _jit_latent("latent_decode")(
-            q_cat, pool, block_tables, seq_lens, jnp.int32(l), rank=r,
-            interpret=interp)
+        with scope("kv_write"):
+            pool = _jit_latent("latent_write")(
+                pool, row, page_of, slot_of, jnp.int32(l),
+                interpret=interp)
+        with scope("attn"):
+            o_lat = _jit_latent("latent_decode")(
+                q_cat, pool, block_tables, seq_lens, jnp.int32(l), rank=r,
+                interpret=interp)
     else:
-        pool = pool.at[l, page_of, slot_of].set(row.astype(pool.dtype))
-        rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
-        s = jnp.einsum("bhw,bsw->bhs", q_cat, rows,
-                       preferred_element_type=jnp.float32)
-        live = (jnp.arange(rows.shape[1])[None, :]
-                < seq_lens[:, None])[:, None, :]
-        p = jnp.where(live, jax.nn.softmax(jnp.where(live, s, NEG), -1), 0.0)
-        o_lat = jnp.einsum("bhs,bsr->bhr", p.astype(rows.dtype),
-                           rows[..., :r],
+        with scope("kv_write"):
+            pool = pool.at[l, page_of, slot_of].set(row.astype(pool.dtype))
+        with scope("attn"):
+            rows = pool[l][block_tables].reshape(B, -1, pool.shape[-1])
+            s = jnp.einsum("bhw,bsw->bhs", q_cat, rows,
                            preferred_element_type=jnp.float32)
-    if s_kv != 1.0:
-        o_lat = o_lat.astype(jnp.float32) * s_kv
-    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cfg.dtype), wv)
-    return o.reshape(B, -1), pool
+            live = (jnp.arange(rows.shape[1])[None, :]
+                    < seq_lens[:, None])[:, None, :]
+            p = jnp.where(live,
+                          jax.nn.softmax(jnp.where(live, s, NEG), -1), 0.0)
+            o_lat = jnp.einsum("bhs,bsr->bhr", p.astype(rows.dtype),
+                               rows[..., :r],
+                               preferred_element_type=jnp.float32)
+    with scope("attn_out"):  # the values out of wkv_b's value half
+        if s_kv != 1.0:
+            o_lat = o_lat.astype(jnp.float32) * s_kv
+        o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cfg.dtype), wv)
+        return o.reshape(B, -1), pool
 
 
 def latent_write_prefill(pool, rows, block_tables, positions, lengths,
@@ -279,24 +292,25 @@ def latent_write_prefill(pool, rows, block_tables, positions, lengths,
     touches is read, merged and written once (an XLA scatter pays by
     the index, so by the page here and not by the token). Pages no
     valid token touches go to reserved page 0."""
-    B, T, W = rows.shape
-    ps, mp = pool.shape[2], block_tables.shape[1]
-    n_pages = -(-T // ps) + 1
-    p0 = positions[:, 0]
-    src = jnp.arange(n_pages * ps)[None, :] - (p0 % ps)[:, None]
-    valid = (src >= 0) & (src < lengths[:, None])          # (B, NP*ps)
-    buf = jnp.take_along_axis(rows, jnp.clip(src, 0, T - 1)[..., None],
-                              axis=1)
-    idx = (p0 // ps)[:, None] + jnp.arange(n_pages)[None, :]
-    pages = jnp.take_along_axis(block_tables, jnp.clip(idx, 0, mp - 1),
-                                axis=1)
-    valid = valid.reshape(B, n_pages, ps)
-    pages = jnp.where(valid.any(-1) & (idx < mp), pages, 0)
-    merged = jnp.where(valid[..., None],
-                       buf.reshape(B, n_pages, ps, W).astype(pool.dtype),
-                       pool[l, pages])
-    return pool.at[l, pages.reshape(-1)].set(
-        merged.reshape(B * n_pages, ps, W))
+    with scope("kv_write"):
+        B, T, W = rows.shape
+        ps, mp = pool.shape[2], block_tables.shape[1]
+        n_pages = -(-T // ps) + 1
+        p0 = positions[:, 0]
+        src = jnp.arange(n_pages * ps)[None, :] - (p0 % ps)[:, None]
+        valid = (src >= 0) & (src < lengths[:, None])          # (B, NP*ps)
+        buf = jnp.take_along_axis(rows, jnp.clip(src, 0, T - 1)[..., None],
+                                  axis=1)
+        idx = (p0 // ps)[:, None] + jnp.arange(n_pages)[None, :]
+        pages = jnp.take_along_axis(block_tables, jnp.clip(idx, 0, mp - 1),
+                                    axis=1)
+        valid = valid.reshape(B, n_pages, ps)
+        pages = jnp.where(valid.any(-1) & (idx < mp), pages, 0)
+        merged = jnp.where(valid[..., None],
+                           buf.reshape(B, n_pages, ps, W).astype(pool.dtype),
+                           pool[l, pages])
+        return pool.at[l, pages.reshape(-1)].set(
+            merged.reshape(B * n_pages, ps, W))
 
 
 def prefill_key_blocks(seq_lens, T: int, page_size: int, max_pages: int):
@@ -368,7 +382,7 @@ def latent_prefill_attention(cfg, lp: Params, l: int, q_nope, q_rope, pool,
             preferred_element_type=jnp.float32)
         return m_new, z, acc
 
-    with jax.named_scope("latent_prefill_attention"):
+    with scope("attn"), scope("latent_prefill_attention"):
         _, z, acc = jax.lax.fori_loop(
             0, visited, block,
             (jnp.full((B, H, T), NEG, jnp.float32),

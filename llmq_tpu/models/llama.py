@@ -49,6 +49,7 @@ from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import (embed_lookup, is_quantized, layer_slice,
                                 linear, tied_head_logits)
 from llmq_tpu.ops.rope import apply_rope, rope_cos_sin
+from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jnp.ndarray]
@@ -397,8 +398,11 @@ def forward_prefill(
     """
     B, T = tokens.shape
 
-    h = embed_lookup(params["embed"], tokens, cfg.dtype)  # (B, T, D)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)  # (B,T,half)
+    with scope("embed"):
+        h = embed_lookup(params["embed"], tokens, cfg.dtype)  # (B, T, D)
+    with scope("qkv"):
+        cos, sin = rope_cos_sin(positions, cfg.head_dim,
+                                cfg.rope_theta)            # (B,T,half)
 
     # Absolute visible history per row: last valid position + 1.
     valid = (jnp.arange(T)[None, :] < lengths[:, None])    # (B, T)
@@ -408,21 +412,24 @@ def forward_prefill(
     lp = params["layers"]
 
     def qkv(h, l):
-        hn = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps)
-        q = linear(hn, layer_slice(lp["wq"], l)).reshape(
-            B, T, cfg.n_heads, cfg.head_dim)
-        k = linear(hn, layer_slice(lp["wk"], l)).reshape(
-            B, T, cfg.n_kv_heads, cfg.head_dim)
-        v = linear(hn, layer_slice(lp["wv"], l)).reshape(
-            B, T, cfg.n_kv_heads, cfg.head_dim)
-        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+        with scope("qkv"):
+            hn = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps)
+            q = linear(hn, layer_slice(lp["wq"], l)).reshape(
+                B, T, cfg.n_heads, cfg.head_dim)
+            k = linear(hn, layer_slice(lp["wk"], l)).reshape(
+                B, T, cfg.n_kv_heads, cfg.head_dim)
+            v = linear(hn, layer_slice(lp["wv"], l)).reshape(
+                B, T, cfg.n_kv_heads, cfg.head_dim)
+            return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
     def out_mlp(h, attn, l):
-        h = h + linear(attn.reshape(B, T, -1), layer_slice(lp["wo"], l))
-        hn2 = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
-        return h + _mlp(hn2, layer_slice(lp["w_gate"], l),
-                        layer_slice(lp["w_up"], l),
-                        layer_slice(lp["w_down"], l))
+        with scope("attn_out"):
+            h = h + linear(attn.reshape(B, T, -1), layer_slice(lp["wo"], l))
+        with scope("mlp"):
+            hn2 = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
+            return h + _mlp(hn2, layer_slice(lp["w_gate"], l),
+                            layer_slice(lp["w_up"], l),
+                            layer_slice(lp["w_down"], l))
 
     if "k_scale" in kv_cache:
         # int8 pools: quantized write + dequantizing attention
@@ -439,11 +446,14 @@ def forward_prefill(
         def layer(l, carry):
             h, pools = carry
             q, k, v = qkv(h, l)
-            pools = paged_kv_write_prefill_q8(
-                pools, k, v, block_tables, positions, lengths, l)
-            attn = dispatch_prefill_attention_q8(
-                q, pools, block_tables, positions, seq_lens, l,
-                enabled=cfg.pallas, multi_ok=cfg.pallas_batched_prefill)
+            with scope("kv_write"):
+                pools = paged_kv_write_prefill_q8(
+                    pools, k, v, block_tables, positions, lengths, l)
+            with scope("attn"):
+                attn = dispatch_prefill_attention_q8(
+                    q, pools, block_tables, positions, seq_lens, l,
+                    enabled=cfg.pallas,
+                    multi_ok=cfg.pallas_batched_prefill)
             return out_mlp(h, attn, l), pools
 
         h, pools = lax.fori_loop(
@@ -465,21 +475,25 @@ def forward_prefill(
         for l in range(cfg.n_layers):
             q, k, v = qkv(h, l)
             # Write this layer's KV into its slice of the pool.
-            k_pool, v_pool = paged_kv_write_prefill(
-                k_pool, v_pool, k, v, block_tables, positions, lengths,
-                jnp.int32(l), enabled=cfg.pallas,
-                multi_ok=cfg.pallas_batched_prefill)
+            with scope("kv_write"):
+                k_pool, v_pool = paged_kv_write_prefill(
+                    k_pool, v_pool, k, v, block_tables, positions,
+                    lengths, jnp.int32(l), enabled=cfg.pallas,
+                    multi_ok=cfg.pallas_batched_prefill)
             # Attend over the full paged history (covers continuation
             # turns); causality enforced via absolute positions.
-            attn = dispatch_prefill_attention(
-                q, k_pool, v_pool, block_tables, positions, seq_lens, l,
-                enabled=cfg.pallas, multi_ok=cfg.pallas_batched_prefill)
+            with scope("attn"):
+                attn = dispatch_prefill_attention(
+                    q, k_pool, v_pool, block_tables, positions, seq_lens,
+                    l, enabled=cfg.pallas,
+                    multi_ok=cfg.pallas_batched_prefill)
             h = out_mlp(h, attn, l)
         out_cache = {"k": k_pool, "v": v_pool}
-    if last_only:
-        h = h[jnp.arange(B), lengths - 1]                  # (B, D)
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return _logits(params, h), out_cache
+    with scope("head"):
+        if last_only:
+            h = h[jnp.arange(B), lengths - 1]              # (B, D)
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        return _logits(params, h), out_cache
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -502,9 +516,11 @@ def forward_decode(
     B = tokens.shape[0]
     page_sz = kv_cache["k"].shape[2]
 
-    h = embed_lookup(params["embed"], tokens, cfg.dtype)   # (B, D)
-    cos, sin = rope_cos_sin(positions[:, None], cfg.head_dim,
-                            cfg.rope_theta)                # (B,1,half)
+    with scope("embed"):
+        h = embed_lookup(params["embed"], tokens, cfg.dtype)   # (B, D)
+    with scope("qkv"):
+        cos, sin = rope_cos_sin(positions[:, None], cfg.head_dim,
+                                cfg.rope_theta)            # (B,1,half)
     page_of = block_tables[jnp.arange(B), positions // page_sz]
     if active is not None:
         page_of = jnp.where(active, page_of, 0)
@@ -525,38 +541,45 @@ def forward_decode(
     if quant_kv:
         pools = (k_pool, v_pool, kv_cache["k_scale"], kv_cache["v_scale"])
     for l in range(cfg.n_layers):
-        hn = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps)
-        q = linear(hn, layer_slice(lp["wq"], l)).reshape(
-            B, 1, cfg.n_heads, cfg.head_dim)
-        k = linear(hn, layer_slice(lp["wk"], l)).reshape(
-            B, 1, cfg.n_kv_heads, cfg.head_dim)
-        v = linear(hn, layer_slice(lp["wv"], l)).reshape(
-            B, 1, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin)[:, 0]                  # (B, H, D)
-        k = apply_rope(k, cos, sin)[:, 0]                  # (B, H_kv, D)
-        v = v[:, 0]
+        with scope("qkv"):
+            hn = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps)
+            q = linear(hn, layer_slice(lp["wq"], l)).reshape(
+                B, 1, cfg.n_heads, cfg.head_dim)
+            k = linear(hn, layer_slice(lp["wk"], l)).reshape(
+                B, 1, cfg.n_kv_heads, cfg.head_dim)
+            v = linear(hn, layer_slice(lp["wv"], l)).reshape(
+                B, 1, cfg.n_kv_heads, cfg.head_dim)
+            q = apply_rope(q, cos, sin)[:, 0]              # (B, H, D)
+            k = apply_rope(k, cos, sin)[:, 0]              # (B, H_kv, D)
+            v = v[:, 0]
         # Fused write + attention (every live sequence owns its page
-        # this step; inactive rows redirect to reserved page 0).
-        if quant_kv:
-            attn, pools = paged_decode_step_q8(
-                q, k, v, pools, block_tables, seq_lens,
-                page_of, slot_of, jnp.int32(l), enabled=cfg.pallas)
-        else:
-            attn, k_pool, v_pool = paged_decode_step(
-                q, k, v, k_pool, v_pool, block_tables, seq_lens,
-                page_of, slot_of, jnp.int32(l),
-                enabled=cfg.pallas)                        # (B, H, D)
-        h = h + linear(attn.reshape(B, -1), layer_slice(lp["wo"], l))
-        hn2 = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
-        h = h + _mlp(hn2, layer_slice(lp["w_gate"], l),
-                     layer_slice(lp["w_up"], l), layer_slice(lp["w_down"], l))
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        # this step; inactive rows redirect to reserved page 0): the
+        # write is the attention kernel's, so no ``kv_write`` here.
+        with scope("attn"):
+            if quant_kv:
+                attn, pools = paged_decode_step_q8(
+                    q, k, v, pools, block_tables, seq_lens,
+                    page_of, slot_of, jnp.int32(l), enabled=cfg.pallas)
+            else:
+                attn, k_pool, v_pool = paged_decode_step(
+                    q, k, v, k_pool, v_pool, block_tables, seq_lens,
+                    page_of, slot_of, jnp.int32(l),
+                    enabled=cfg.pallas)                    # (B, H, D)
+        with scope("attn_out"):
+            h = h + linear(attn.reshape(B, -1), layer_slice(lp["wo"], l))
+        with scope("mlp"):
+            hn2 = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
+            h = h + _mlp(hn2, layer_slice(lp["w_gate"], l),
+                         layer_slice(lp["w_up"], l),
+                         layer_slice(lp["w_down"], l))
     if quant_kv:
         out_cache = {"k": pools[0], "v": pools[1],
                      "k_scale": pools[2], "v_scale": pools[3]}
     else:
         out_cache = {"k": k_pool, "v": v_pool}
-    return _logits(params, h), out_cache
+    with scope("head"):
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        return _logits(params, h), out_cache
 
 
 def forward_verify(
@@ -646,21 +669,30 @@ def forward_mixed(
     page_sz = kv_cache["k"].shape[2]
 
     # Decode-row geometry (forward_decode).
-    h_d = embed_lookup(params["embed"], dec_tokens, cfg.dtype)   # (B, D)
-    cos_d, sin_d = rope_cos_sin(dec_positions[:, None], cfg.head_dim,
-                                cfg.rope_theta)
-    page_of = dec_block_tables[jnp.arange(B), dec_positions // page_sz]
-    if dec_active is not None:
-        page_of = jnp.where(dec_active, page_of, 0)
-    slot_of = dec_positions % page_sz
-    dec_seq_lens = dec_positions + 1
+    with scope("decode_rows"):
+        with scope("embed"):
+            h_d = embed_lookup(params["embed"], dec_tokens,
+                               cfg.dtype)                        # (B, D)
+        with scope("qkv"):
+            cos_d, sin_d = rope_cos_sin(dec_positions[:, None],
+                                        cfg.head_dim, cfg.rope_theta)
+        page_of = dec_block_tables[jnp.arange(B), dec_positions // page_sz]
+        if dec_active is not None:
+            page_of = jnp.where(dec_active, page_of, 0)
+        slot_of = dec_positions % page_sz
+        dec_seq_lens = dec_positions + 1
 
     # Slice-row geometry (forward_prefill).
-    h_p = embed_lookup(params["embed"], pf_tokens, cfg.dtype)    # (S, T, D)
-    cos_p, sin_p = rope_cos_sin(pf_positions, cfg.head_dim, cfg.rope_theta)
-    pf_valid = (jnp.arange(T)[None, :] < pf_lengths[:, None])
-    pf_last_pos = jnp.max(jnp.where(pf_valid, pf_positions, -1), axis=1)
-    pf_seq_lens = pf_last_pos + 1
+    with scope("slices"):
+        with scope("embed"):
+            h_p = embed_lookup(params["embed"], pf_tokens,
+                               cfg.dtype)                     # (S, T, D)
+        with scope("qkv"):
+            cos_p, sin_p = rope_cos_sin(pf_positions, cfg.head_dim,
+                                        cfg.rope_theta)
+        pf_valid = (jnp.arange(T)[None, :] < pf_lengths[:, None])
+        pf_last_pos = jnp.max(jnp.where(pf_valid, pf_positions, -1), axis=1)
+        pf_seq_lens = pf_last_pos + 1
 
     lp = params["layers"]
     quant_kv = "k_scale" in kv_cache
@@ -672,68 +704,97 @@ def forward_mixed(
                       layer_slice(lp["wv"], l))
         # Slice rows first (order is free — disjoint pages — but fixed
         # for determinism): write their KV, attend over their history.
-        hn_p = rms_norm(h_p, lp["attn_norm"][l], cfg.norm_eps)
-        q_p = linear(hn_p, wq).reshape(S, T, cfg.n_heads, cfg.head_dim)
-        k_p = linear(hn_p, wk).reshape(S, T, cfg.n_kv_heads, cfg.head_dim)
-        v_p = linear(hn_p, wv).reshape(S, T, cfg.n_kv_heads, cfg.head_dim)
-        q_p = apply_rope(q_p, cos_p, sin_p)
-        k_p = apply_rope(k_p, cos_p, sin_p)
-        if quant_kv:
-            pools = paged_kv_write_prefill_q8(
-                pools, k_p, v_p, pf_block_tables, pf_positions,
-                pf_lengths, jnp.int32(l))
-            attn_p = dispatch_prefill_attention_q8(
-                q_p, pools, pf_block_tables, pf_positions, pf_seq_lens, l,
-                enabled=cfg.pallas, multi_ok=cfg.pallas_batched_prefill)
-        else:
-            k_pool, v_pool = paged_kv_write_prefill(
-                k_pool, v_pool, k_p, v_p, pf_block_tables, pf_positions,
-                pf_lengths, jnp.int32(l), enabled=cfg.pallas,
-                multi_ok=cfg.pallas_batched_prefill)
-            attn_p = dispatch_prefill_attention(
-                q_p, k_pool, v_pool, pf_block_tables, pf_positions,
-                pf_seq_lens, l, enabled=cfg.pallas,
-                multi_ok=cfg.pallas_batched_prefill)
-        h_p = h_p + linear(attn_p.reshape(S, T, -1),
-                           layer_slice(lp["wo"], l))
-        hn2_p = rms_norm(h_p, lp["mlp_norm"][l], cfg.norm_eps)
-        h_p = h_p + _mlp(hn2_p, layer_slice(lp["w_gate"], l),
-                         layer_slice(lp["w_up"], l),
-                         layer_slice(lp["w_down"], l))
+        with scope("slices"):
+            with scope("qkv"):
+                hn_p = rms_norm(h_p, lp["attn_norm"][l], cfg.norm_eps)
+                q_p = linear(hn_p, wq).reshape(S, T, cfg.n_heads,
+                                               cfg.head_dim)
+                k_p = linear(hn_p, wk).reshape(S, T, cfg.n_kv_heads,
+                                               cfg.head_dim)
+                v_p = linear(hn_p, wv).reshape(S, T, cfg.n_kv_heads,
+                                               cfg.head_dim)
+                q_p = apply_rope(q_p, cos_p, sin_p)
+                k_p = apply_rope(k_p, cos_p, sin_p)
+            if quant_kv:
+                with scope("kv_write"):
+                    pools = paged_kv_write_prefill_q8(
+                        pools, k_p, v_p, pf_block_tables, pf_positions,
+                        pf_lengths, jnp.int32(l))
+                with scope("attn"):
+                    attn_p = dispatch_prefill_attention_q8(
+                        q_p, pools, pf_block_tables, pf_positions,
+                        pf_seq_lens, l, enabled=cfg.pallas,
+                        multi_ok=cfg.pallas_batched_prefill)
+            else:
+                with scope("kv_write"):
+                    k_pool, v_pool = paged_kv_write_prefill(
+                        k_pool, v_pool, k_p, v_p, pf_block_tables,
+                        pf_positions, pf_lengths, jnp.int32(l),
+                        enabled=cfg.pallas,
+                        multi_ok=cfg.pallas_batched_prefill)
+                with scope("attn"):
+                    attn_p = dispatch_prefill_attention(
+                        q_p, k_pool, v_pool, pf_block_tables, pf_positions,
+                        pf_seq_lens, l, enabled=cfg.pallas,
+                        multi_ok=cfg.pallas_batched_prefill)
+            with scope("attn_out"):
+                h_p = h_p + linear(attn_p.reshape(S, T, -1),
+                                   layer_slice(lp["wo"], l))
+            with scope("mlp"):
+                hn2_p = rms_norm(h_p, lp["mlp_norm"][l], cfg.norm_eps)
+                h_p = h_p + _mlp(hn2_p, layer_slice(lp["w_gate"], l),
+                                 layer_slice(lp["w_up"], l),
+                                 layer_slice(lp["w_down"], l))
 
         # Decode rows, same layer: a matmul of their own, so the layer's
         # weights are read a second time (see the docstring).
-        hn_d = rms_norm(h_d, lp["attn_norm"][l], cfg.norm_eps)
-        q_d = linear(hn_d, wq).reshape(B, 1, cfg.n_heads, cfg.head_dim)
-        k_d = linear(hn_d, wk).reshape(B, 1, cfg.n_kv_heads, cfg.head_dim)
-        v_d = linear(hn_d, wv).reshape(B, 1, cfg.n_kv_heads, cfg.head_dim)
-        q_d = apply_rope(q_d, cos_d, sin_d)[:, 0]
-        k_d = apply_rope(k_d, cos_d, sin_d)[:, 0]
-        v_d = v_d[:, 0]
-        if quant_kv:
-            attn_d, pools = paged_decode_step_q8(
-                q_d, k_d, v_d, pools, dec_block_tables, dec_seq_lens,
-                page_of, slot_of, jnp.int32(l), enabled=cfg.pallas)
-        else:
-            attn_d, k_pool, v_pool = paged_decode_step(
-                q_d, k_d, v_d, k_pool, v_pool, dec_block_tables,
-                dec_seq_lens, page_of, slot_of, jnp.int32(l),
-                enabled=cfg.pallas)
-        h_d = h_d + linear(attn_d.reshape(B, -1), layer_slice(lp["wo"], l))
-        hn2_d = rms_norm(h_d, lp["mlp_norm"][l], cfg.norm_eps)
-        h_d = h_d + _mlp(hn2_d, layer_slice(lp["w_gate"], l),
-                         layer_slice(lp["w_up"], l),
-                         layer_slice(lp["w_down"], l))
+        with scope("decode_rows"):
+            with scope("qkv"):
+                hn_d = rms_norm(h_d, lp["attn_norm"][l], cfg.norm_eps)
+                q_d = linear(hn_d, wq).reshape(B, 1, cfg.n_heads,
+                                               cfg.head_dim)
+                k_d = linear(hn_d, wk).reshape(B, 1, cfg.n_kv_heads,
+                                               cfg.head_dim)
+                v_d = linear(hn_d, wv).reshape(B, 1, cfg.n_kv_heads,
+                                               cfg.head_dim)
+                q_d = apply_rope(q_d, cos_d, sin_d)[:, 0]
+                k_d = apply_rope(k_d, cos_d, sin_d)[:, 0]
+                v_d = v_d[:, 0]
+            with scope("attn"):
+                if quant_kv:
+                    attn_d, pools = paged_decode_step_q8(
+                        q_d, k_d, v_d, pools, dec_block_tables,
+                        dec_seq_lens, page_of, slot_of, jnp.int32(l),
+                        enabled=cfg.pallas)
+                else:
+                    attn_d, k_pool, v_pool = paged_decode_step(
+                        q_d, k_d, v_d, k_pool, v_pool, dec_block_tables,
+                        dec_seq_lens, page_of, slot_of, jnp.int32(l),
+                        enabled=cfg.pallas)
+            with scope("attn_out"):
+                h_d = h_d + linear(attn_d.reshape(B, -1),
+                                   layer_slice(lp["wo"], l))
+            with scope("mlp"):
+                hn2_d = rms_norm(h_d, lp["mlp_norm"][l], cfg.norm_eps)
+                h_d = h_d + _mlp(hn2_d, layer_slice(lp["w_gate"], l),
+                                 layer_slice(lp["w_up"], l),
+                                 layer_slice(lp["w_down"], l))
 
-    h_d = rms_norm(h_d, params["final_norm"], cfg.norm_eps)
-    h_p = rms_norm(h_p, params["final_norm"], cfg.norm_eps)
+    with scope("decode_rows"), scope("head"):
+        h_d = rms_norm(h_d, params["final_norm"], cfg.norm_eps)
+    with scope("slices"), scope("head"):
+        h_p = rms_norm(h_p, params["final_norm"], cfg.norm_eps)
     if quant_kv:
         out_cache = {"k": pools[0], "v": pools[1],
                      "k_scale": pools[2], "v_scale": pools[3]}
     else:
         out_cache = {"k": k_pool, "v": v_pool}
-    pf_logits = _logits(params, h_p)[jnp.arange(S), pf_lengths - 1]
-    return _logits(params, h_d), pf_logits, out_cache
+    # Every position of every slice goes through the head; the last
+    # valid one is picked afterwards (PERF.md §5 has what that costs).
+    with scope("slices"), scope("head"):
+        pf_logits = _logits(params, h_p)[jnp.arange(S), pf_lengths - 1]
+    with scope("decode_rows"), scope("head"):
+        return _logits(params, h_d), pf_logits, out_cache
 
 
 def _sp_forward_local(params: Params, tokens_local: jnp.ndarray,

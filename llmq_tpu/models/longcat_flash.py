@@ -75,6 +75,7 @@ from llmq_tpu.ops.moe import identity_gate, route, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import embed_lookup
 from llmq_tpu.ops.rope import rope_cos_sin
+from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jnp.ndarray]
@@ -350,7 +351,8 @@ def _routed(params: Params, cfg: LongcatFlashConfig, l: int, u, live):
     if st.shape[0] == cfg.n_held + 1:      # all held, none zero-compute
         st = jnp.concatenate([st, jnp.zeros((2,), jnp.int32)])
     zero = identity_gate(experts, gates, cfg.n_routed_experts, live)
-    return y.astype(jnp.float32) + zero[:, None] * u, st
+    with scope("moe_combine"):
+        return y.astype(jnp.float32) + zero[:, None] * u, st
 
 
 def _layer(params: Params, cfg: LongcatFlashConfig, l: int, h, cos, sin,
@@ -364,20 +366,29 @@ def _layer(params: Params, cfg: LongcatFlashConfig, l: int, h, cos, sin,
     a0, a1 = 2 * l, 2 * l + 1
 
     def attention(a, h):
-        x = rms_norm(h, at["attn_norm"][a], cfg.norm_eps).astype(cfg.dtype)
+        with scope("qkv"):
+            x = rms_norm(h, at["attn_norm"][a],
+                         cfg.norm_eps).astype(cfg.dtype)
         q_nope, q_rope, row = qkv(cfg, at, a, x[None], cos, sin)
-        return h + jnp.dot(attend(a, q_nope[0], q_rope[0], row[0]),
-                           at["wo"][a])
+        o = attend(a, q_nope[0], q_rope[0], row[0])
+        with scope("attn_out"):
+            return h + jnp.dot(o, at["wo"][a])
 
-    def dense(a, x):
+    def dense(a, x):          # called under ``mlp``, with its norm
         return _mlp(x.astype(cfg.dtype), ff["w_gate"][a], ff["w_up"][a],
                     ff["w_down"][a])
 
     h = attention(a0, h)
-    u = rms_norm(h, ff["mlp_norm"][a0], cfg.norm_eps)
+    with scope("mlp"):
+        u = rms_norm(h, ff["mlp_norm"][a0], cfg.norm_eps)
     m, st = _routed(params, cfg, l, u, live)
-    h = attention(a1, h + dense(a0, u))
-    return h + dense(a1, rms_norm(h, ff["mlp_norm"][a1], cfg.norm_eps)) + m, st
+    with scope("mlp"):
+        h = h + dense(a0, u)
+    h = attention(a1, h)
+    with scope("mlp"):
+        y = dense(a1, rms_norm(h, ff["mlp_norm"][a1], cfg.norm_eps))
+    with scope("moe_combine"):
+        return h + y + m, st
 
 
 def _sum_stats(per_layer) -> jnp.ndarray:
@@ -388,15 +399,18 @@ def _sum_stats(per_layer) -> jnp.ndarray:
 
 
 def _finish(params, h, cfg):
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
-    return jnp.dot(h, params["lm_head"]).astype(jnp.float32)
+    with scope("head"):
+        h = rms_norm(h, params["final_norm"],
+                     cfg.norm_eps).astype(cfg.dtype)
+        return jnp.dot(h, params["lm_head"]).astype(jnp.float32)
 
 
 def _run(params, cfg, h, positions, attend, live):
     """All layers over the flat tokens h (N, D) at ``positions`` (N,).
     Returns (h', counters)."""
-    cos, sin = rope_cos_sin(positions[None], cfg.qk_rope_head_dim,
-                            cfg.rope_theta)
+    with scope("qkv"):
+        cos, sin = rope_cos_sin(positions[None], cfg.qk_rope_head_dim,
+                                cfg.rope_theta)
     counts = []
     for l in range(cfg.n_layers):
         h, st = _layer(params, cfg, l, h, cos, sin, attend, live)
@@ -456,16 +470,17 @@ def forward_prefill(params: Params, cfg: LongcatFlashConfig, tokens,
     valid = jnp.arange(T)[None, :] < lengths[:, None]
     seq_lens = jnp.max(jnp.where(valid, positions, -1), axis=1) + 1
     pool = [kv_cache["ckv"]]
+    with scope("embed"):
+        h = embed_lookup(params["embed"], tokens.reshape(-1), jnp.float32)
     h, counts = _run(
-        params, cfg,
-        embed_lookup(params["embed"], tokens.reshape(-1), jnp.float32),
-        positions.reshape(-1),
+        params, cfg, h, positions.reshape(-1),
         _prefill_attend(cfg, params["layers"], pool, block_tables,
                         positions, lengths, seq_lens),
         valid.reshape(-1))
     h = h.reshape(B, T, -1)
     if last_only:
-        h = h[jnp.arange(B), lengths - 1]
+        with scope("head"):
+            h = h[jnp.arange(B), lengths - 1]
     out = (_finish(params, h, cfg), {"ckv": pool[0]})
     return out + (counts,) if stats else out
 
@@ -481,9 +496,10 @@ def forward_decode(params: Params, cfg: LongcatFlashConfig, tokens,
     pool = [kv_cache["ckv"]]
     page_of, slot_of, seq_lens = decode_geometry(
         positions, block_tables, pool[0].shape[2], active)
+    with scope("embed"):
+        h = embed_lookup(params["embed"], tokens, jnp.float32)
     h, counts = _run(
-        params, cfg, embed_lookup(params["embed"], tokens, jnp.float32),
-        positions,
+        params, cfg, h, positions,
         _decode_attend(cfg, params["layers"], pool, block_tables, seq_lens,
                        page_of, slot_of),
         active)
@@ -523,21 +539,32 @@ def forward_mixed(params: Params, cfg: LongcatFlashConfig, dec_tokens,
                               page_of, slot_of)
 
     def attend(a, q_nope, q_rope, row):
-        return jnp.concatenate(
-            [attend_p(a, q_nope[:n], q_rope[:n], row[:n]),
-             attend_d(a, q_nope[n:], q_rope[n:], row[n:])])
+        # The one part of a layer that takes the two kinds of row
+        # apart: everything else runs them side by side, under the
+        # module's name alone.
+        with scope("slices"):
+            o_p = attend_p(a, q_nope[:n], q_rope[:n], row[:n])
+        with scope("decode_rows"):
+            o_d = attend_d(a, q_nope[n:], q_rope[n:], row[n:])
+        return jnp.concatenate([o_p, o_d])
 
     live = jnp.concatenate(
         [pf_valid.reshape(-1), (dec_active if dec_active is not None
                                 else jnp.ones((B,), jnp.bool_))])
+    with scope("embed"):
+        h = embed_lookup(
+            params["embed"],
+            jnp.concatenate([pf_tokens.reshape(-1), dec_tokens]),
+            jnp.float32)
     h, counts = _run(
-        params, cfg,
-        embed_lookup(params["embed"],
-                     jnp.concatenate([pf_tokens.reshape(-1), dec_tokens]),
-                     jnp.float32),
+        params, cfg, h,
         jnp.concatenate([pf_positions.reshape(-1), dec_positions]),
         attend, live)
-    h_p = h[:n].reshape(S, T, -1)[jnp.arange(S), pf_lengths - 1]
-    out = (_finish(params, h[n:], cfg), _finish(params, h_p, cfg),
-           {"ckv": pool[0]})
+    with scope("slices"), scope("head"):
+        h_p = h[:n].reshape(S, T, -1)[jnp.arange(S), pf_lengths - 1]
+    with scope("decode_rows"):
+        dec_logits = _finish(params, h[n:], cfg)
+    with scope("slices"):
+        pf_logits = _finish(params, h_p, cfg)
+    out = (dec_logits, pf_logits, {"ckv": pool[0]})
     return out + (counts,) if stats else out

@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from llmq_tpu.utils.profiling import scope
+
 
 def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
           top_k: int, scale: float, norm_topk: bool = True,
@@ -64,17 +66,19 @@ def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
     bf16 pass swaps near-tied experts); the ``k`` experts are the top
     ``k`` of ``s + bias``; the gates are the chosen ``s`` (WITHOUT the
     bias), normalised to sum 1 where ``norm_topk``, times ``scale``."""
-    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"unknown router scoring {scoring!r}")
-    s = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
-         else jax.nn.softmax(logits, axis=-1))
-    _, experts = lax.top_k(s + bias.astype(jnp.float32), top_k)
-    g = jnp.take_along_axis(s, experts, axis=-1)
-    if norm_topk:
-        g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
-    return experts.astype(jnp.int32), g * scale
+    with scope("moe_route"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         w_router.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        s = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+        _, experts = lax.top_k(s + bias.astype(jnp.float32), top_k)
+        g = jnp.take_along_axis(s, experts, axis=-1)
+        if norm_topk:
+            g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+        return experts.astype(jnp.int32), g * scale
 
 
 #: megablox tiling (rows, contraction, output): the best of those
@@ -119,8 +123,9 @@ def identity_gate(experts: jnp.ndarray, gates: jnp.ndarray, n_routed: int,
     (identity) experts it chose — the indices at or above ``n_routed``.
     The routed layer adds this times the token itself. 0 for a row
     that is not live."""
-    g = jnp.sum(jnp.where(experts >= n_routed, gates, 0.0), axis=-1)
-    return g if live is None else jnp.where(live, g, 0.0)
+    with scope("moe_combine"):
+        g = jnp.sum(jnp.where(experts >= n_routed, gates, 0.0), axis=-1)
+        return g if live is None else jnp.where(live, g, 0.0)
 
 
 def _held_blocks(x, key, gates, w_gate_up, w_down, counts, k):
@@ -134,25 +139,31 @@ def _held_blocks(x, key, gates, w_gate_up, w_down, counts, k):
     N, D = x.shape
     F = w_gate_up.shape[-1] // 2
     M, blk = key.shape[0], HELD_BLOCK
-    order = jnp.pad(jnp.argsort(key, stable=True), (0, -M % blk))
-    ends = jnp.cumsum(counts)
-    starts, n_held = ends - counts, ends[-1]
-    flat_gates = gates.reshape(-1)
+    with scope("moe_route"):
+        order = jnp.pad(jnp.argsort(key, stable=True), (0, -M % blk))
+        ends = jnp.cumsum(counts)
+        starts, n_held = ends - counts, ends[-1]
+        flat_gates = gates.reshape(-1)
 
     def block(b, y):
-        lo = b * blk
-        pairs = lax.dynamic_slice(order, (lo,), (blk,))
-        tok = pairs // k
-        size = (jnp.clip(ends, lo, lo + blk)
-                - jnp.clip(starts, lo, lo + blk))
-        gu = _grouped(x[tok], w_gate_up, size)
-        a = (jax.nn.silu(gu[:, :F].astype(jnp.float32)).astype(x.dtype)
-             * gu[:, F:])
-        ys = _grouped(a, w_down, size)
-        w = jnp.where(lo + jnp.arange(blk) < n_held, flat_gates[pairs], 0.0)
-        ys = jnp.where(w[:, None] != 0, ys.astype(jnp.float32) * w[:, None],
-                       0.0)
-        return y.at[tok].add(ys)
+        with scope("moe_route"):
+            lo = b * blk
+            pairs = lax.dynamic_slice(order, (lo,), (blk,))
+            tok = pairs // k
+            size = (jnp.clip(ends, lo, lo + blk)
+                    - jnp.clip(starts, lo, lo + blk))
+            xs = x[tok]
+        with scope("moe_experts"):
+            gu = _grouped(xs, w_gate_up, size)
+            a = (jax.nn.silu(gu[:, :F].astype(jnp.float32)).astype(x.dtype)
+                 * gu[:, F:])
+            ys = _grouped(a, w_down, size)
+        with scope("moe_combine"):
+            w = jnp.where(lo + jnp.arange(blk) < n_held, flat_gates[pairs],
+                          0.0)
+            ys = jnp.where(w[:, None] != 0,
+                           ys.astype(jnp.float32) * w[:, None], 0.0)
+            return y.at[tok].add(ys)
 
     return lax.fori_loop(0, (n_held + blk - 1) // blk, block,
                          jnp.zeros((N, D), jnp.float32))
@@ -192,41 +203,47 @@ def routed_ffn(x: jnp.ndarray, experts: jnp.ndarray, gates: jnp.ndarray,
     if (lo, hi) != (0, E) or n_routed not in (None, E):
         return _routed_share(x, flat, gates, w_gate_up, w_down, live, k,
                              lo, n_routed)
-    if live is not None:
-        # Sorted behind the last group: outside every group, so no
-        # product touches those rows (they are zeroed below).
-        flat = jnp.where(jnp.repeat(live, k), flat, E)
-    order = jnp.argsort(flat, stable=True)
-    counts = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
-    xs = x[order // k]                                     # (N*k, D)
-    gu = _grouped(xs, w_gate_up, counts)
-    a = (jax.nn.silu(gu[:, :F].astype(jnp.float32)).astype(x.dtype)
-         * gu[:, F:])
-    ys = _grouped(a, w_down, counts)                       # (N*k, D)
-    w = jnp.where(flat[order] < E, gates.reshape(-1)[order], 0.0)
-    ys = jnp.where(w[:, None] != 0, ys.astype(jnp.float32) * w[:, None], 0.0)
-    y = jnp.zeros((N * k, x.shape[-1]), jnp.float32).at[order].set(ys)
-    y = jnp.sum(y.reshape(N, k, -1), axis=1).astype(x.dtype)
-    stats = jnp.concatenate(
-        [counts, jnp.sum(counts > 0, dtype=jnp.int32)[None]])
-    return y, stats
+    with scope("moe_route"):
+        if live is not None:
+            # Sorted behind the last group: outside every group, so no
+            # product touches those rows (they are zeroed below).
+            flat = jnp.where(jnp.repeat(live, k), flat, E)
+        order = jnp.argsort(flat, stable=True)
+        counts = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+        xs = x[order // k]                                 # (N*k, D)
+    with scope("moe_experts"):
+        gu = _grouped(xs, w_gate_up, counts)
+        a = (jax.nn.silu(gu[:, :F].astype(jnp.float32)).astype(x.dtype)
+             * gu[:, F:])
+        ys = _grouped(a, w_down, counts)                   # (N*k, D)
+    with scope("moe_combine"):
+        w = jnp.where(flat[order] < E, gates.reshape(-1)[order], 0.0)
+        ys = jnp.where(w[:, None] != 0,
+                       ys.astype(jnp.float32) * w[:, None], 0.0)
+        y = jnp.zeros((N * k, x.shape[-1]), jnp.float32).at[order].set(ys)
+        y = jnp.sum(y.reshape(N, k, -1), axis=1).astype(x.dtype)
+        stats = jnp.concatenate(
+            [counts, jnp.sum(counts > 0, dtype=jnp.int32)[None]])
+        return y, stats
 
 
 def _routed_share(x, flat, gates, w_gate_up, w_down, live, k, lo, n_routed):
     """``routed_ffn`` for a share of the experts and / or zero-compute
     experts: ``flat`` (N k,) the chosen router indices."""
     E = w_gate_up.shape[0]
-    alive = (jnp.repeat(live, k) if live is not None
-             else jnp.ones(flat.shape, jnp.bool_))
-    here = alive & (flat >= lo) & (flat < lo + E)
-    zero = (alive & (flat >= n_routed) if n_routed is not None
-            else jnp.zeros(flat.shape, jnp.bool_))
-    key = jnp.where(here, flat - lo, E)
-    counts = jnp.zeros((E,), jnp.int32).at[key].add(1, mode="drop")
+    with scope("moe_route"):
+        alive = (jnp.repeat(live, k) if live is not None
+                 else jnp.ones(flat.shape, jnp.bool_))
+        here = alive & (flat >= lo) & (flat < lo + E)
+        zero = (alive & (flat >= n_routed) if n_routed is not None
+                else jnp.zeros(flat.shape, jnp.bool_))
+        key = jnp.where(here, flat - lo, E)
+        counts = jnp.zeros((E,), jnp.int32).at[key].add(1, mode="drop")
     y = _held_blocks(x, key, gates, w_gate_up, w_down, counts, k)
-    n_zero = jnp.sum(zero, dtype=jnp.int32)
-    n_away = jnp.sum(alive, dtype=jnp.int32) - n_zero - jnp.sum(counts)
-    stats = jnp.concatenate(
-        [counts, jnp.stack([jnp.sum(counts > 0, dtype=jnp.int32),
-                            n_zero, n_away])])
-    return y.astype(x.dtype), stats
+    with scope("moe_combine"):
+        n_zero = jnp.sum(zero, dtype=jnp.int32)
+        n_away = jnp.sum(alive, dtype=jnp.int32) - n_zero - jnp.sum(counts)
+        stats = jnp.concatenate(
+            [counts, jnp.stack([jnp.sum(counts > 0, dtype=jnp.int32),
+                                n_zero, n_away])])
+        return y.astype(x.dtype), stats

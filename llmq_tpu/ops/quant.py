@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from llmq_tpu.utils.profiling import scope
+
 Params = Dict[str, Any]
 
 #: Quantized-weight leaf: {"q": int8 weights, "s": f32 scales}.
@@ -91,7 +93,8 @@ def qdot(x: jnp.ndarray, w: QuantW) -> jnp.ndarray:
     (e.g. none here — layers are indexed before the call) must already
     be sliced away.
     """
-    xq, sx = quantize_act(x)
+    with scope("act_quant"):
+        xq, sx = quantize_act(x)
     wq, sw = w["q"], w["s"]
     # Contract the last axis of x with the first axis of wq.
     y = lax.dot_general(
@@ -142,7 +145,8 @@ def embed_lookup(embed: Union[jnp.ndarray, QuantW], tokens: jnp.ndarray,
 def tied_head_logits(embed: QuantW, h: jnp.ndarray) -> jnp.ndarray:
     """``h @ embed.T`` for a per-row-quantized embedding: the row scales
     become per-output-channel scales of the transposed head."""
-    xq, sx = quantize_act(h)
+    with scope("act_quant"):
+        xq, sx = quantize_act(h)
     y = lax.dot_general(
         xq, embed["q"],
         # contract h's last axis with embed's LAST axis (i.e. embed.T).

@@ -15,6 +15,11 @@ Two layers:
   of ``observability/chrome.py`` read it) and, while a capture is held,
   the same interval as a ``TraceAnnotation`` on the device trace's
   clock. The engine step's vocabulary: docs/observability.md.
+- **Device scopes** — :func:`scope`, ``jax.named_scope`` held to the
+  fixed vocabulary :data:`SCOPES`: the names the traced programs give
+  their own parts, which a capture's optimised HLO carries in every
+  instruction's ``op_name`` (``benchmark/harness/scopes.py`` reads
+  them). Metadata alone: trace time on the host, nothing on the device.
 """
 
 from __future__ import annotations
@@ -57,6 +62,30 @@ def trace(label: str = "llmq", dir: Optional[str] = None) -> Iterator[None]:
     with jax.profiler.trace(out):
         yield
     log.info("trace written to %s (view with xprof/tensorboard)", out)
+
+
+#: Every name a traced program may give a part of itself, at three
+#: levels (docs/observability.md "Device scopes"): the chunk programs'
+#: steps, the row kinds of a mixed step, the model's modules. Short:
+#: each is stored in every instruction's metadata of every executable.
+SCOPES = (
+    "mixed_step", "decode_loop", "prefill", "sample",
+    "slices", "decode_rows",
+    "embed", "qkv", "kv_write", "attn", "latent_prefill_attention",
+    "attn_out", "mlp", "moe_route", "moe_experts", "moe_combine", "head",
+    "act_quant",
+)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES` and no
+    other: the context under which a traced program's operations carry
+    ``name`` in their ``op_name``. Entered while a program is traced,
+    never while it runs."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not a device scope: {SCOPES}")
+    import jax
+    return jax.named_scope(name)
 
 
 def _annotation_cls():
