@@ -2651,9 +2651,10 @@ class InferenceEngine:
         rows attend to at the first step (host bookkeeping: a
         speculative dispatch counts each unreconciled chunk's full
         budget), ``prefill_tokens`` the prompt tokens riding along and
-        ``slice_tokens`` the rows the program computes for them, padding
-        included (the executor's ``slice_tokens``: slices x width of a
-        mixed chunk, bucket x rows of a prefill program, 0 otherwise).
+        ``slice_tokens`` the rows the program's products run for them,
+        padding included (the executor's ``slice_tokens``: the live row
+        tiles of a mixed chunk, bucket x rows of a prefill program, 0
+        otherwise).
         ``pages_live`` / ``tokens_live`` (``_live_kv``) only while a
         capture is held. The same quantities accumulate for
         ``get_stats()``."""
@@ -2661,8 +2662,8 @@ class InferenceEngine:
         self.row_steps += row_steps
         name_fn = getattr(self.executor, "program_name", None)
         slice_fn = getattr(self.executor, "slice_tokens", None)
-        slice_tokens = (0 if slice_fn is None
-                        else slice_fn(entry, longest, rows))
+        slice_tokens = (0 if slice_fn is None else slice_fn(
+            entry, prefill_tokens if chunk else longest, rows))
         if chunk:            # a mixed chunk: the dedicated programs'
             self.mixed_slice_tokens_total += slice_tokens  # are apart
         counts = {

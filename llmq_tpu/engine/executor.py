@@ -316,11 +316,21 @@ class EchoExecutor:
             self.mixed_chunk_start = None     # type: ignore[assignment]
             self.verify_chunk_start = None    # type: ignore[assignment]
 
+    #: ``ops/rows.ROW_TILE`` (this backend imports no JAX, and ``ops``
+    #: does; tests/test_profiling.py holds the two equal).
+    ROW_TILE = 256
+
     def slice_tokens(self, entry: str, tokens: int = 0, rows: int = 1) -> int:
         """Parity with :meth:`JaxExecutor.slice_tokens`: a mixed chunk
-        counts its slices at full width; a prefill pads nothing here."""
+        counts the row tiles its ``tokens`` fill, laid tight (a Llama
+        program's rule: no decode row shares them); a prefill pads
+        nothing here."""
         if entry == "mixed_chunk":
-            return self.mixed_prefill_slices * self.mixed_slice_tokens
+            tile = min(self.mixed_slice_tokens, self.ROW_TILE)
+            total = self.mixed_prefill_slices * self.mixed_slice_tokens
+            if total <= 2 * tile:          # ``ops/rows.worth_a_loop``
+                return total
+            return min(-(-tokens // tile) * tile, total)
         return tokens * rows if entry.startswith("prefill") else 0
 
     def _register_prefill(self, slot: int, tokens: List[int],
@@ -1159,7 +1169,7 @@ class JaxExecutor:
             @jit_mixed
             def _mixed_chunk(params, cache, tokens, positions,
                              block_tables, temperatures, budgets, done_in,
-                             pf_tokens, pf_positions, pf_lengths,
+                             pf_tokens, pf_positions, pf_lengths, pf_starts,
                              pf_block_tables, pf_temps, key):
                 """Token-budget MIXED chunk: one device program that
                 advances the decode rows up to K steps AND runs S
@@ -1189,7 +1199,7 @@ class JaxExecutor:
                     dec_logits, pf_logits, cache, acc = forward_mixed(
                         params, cfg, tokens, positions, cache,
                         block_tables, pf_tokens, pf_positions, pf_lengths,
-                        pf_block_tables, dec_active=active0)
+                        pf_starts, pf_block_tables, dec_active=active0)
                     with scope("sample"):
                         pf_first = sample_token(
                             pf_logits, keys[K],
@@ -1781,9 +1791,9 @@ class JaxExecutor:
                          (p, c, bsds((B,), i32), bsds((B,), i32),
                           bsds((B, MP), i32), bsds((B,), f32),
                           bsds((B,), i32), bsds((B,), jnp.bool_),
-                          sds((S, T), i32), sds((S, T), i32),
-                          sds((S,), i32), sds((S, MP), i32),
-                          sds((S,), f32), key),
+                          sds((S * T,), i32), sds((S * T,), i32),
+                          sds((S,), i32), sds((S + 1,), i32),
+                          sds((S, MP), i32), sds((S,), f32), key),
                          self._routes(decode=True, prefill_rows=S)))
 
         exp_dir = self._export_cache_dir()
@@ -2058,14 +2068,21 @@ class JaxExecutor:
         return entry
 
     def slice_tokens(self, entry: str, tokens: int = 0, rows: int = 1) -> int:
-        """Prompt-token rows the program behind ``entry`` computes in
-        one dispatch, padding included: every slice of a mixed chunk at
-        its full width, a prefill program's bucket for each of its rows
+        """Rows the program behind ``entry`` runs its row-wise products
+        over for the ``tokens`` prompt tokens of one dispatch, padding
+        included. A mixed chunk (``tokens`` all its slices' together,
+        laid tight): the row tiles that hold one, less the decode rows
+        that share them where the family runs both through one product
+        (its ``mixed_live_rows``), by the rule the program runs by
+        (``ops/rows.live_rows``). A prefill program (``tokens`` its
+        longest row's): its bucket for each of its rows
         (``prefill_multi`` always runs ``prefill_batch`` of them). 0 for
         a program that takes no prompt tokens. What the engine puts on
         its ``engine.dispatch`` span beside the live ``prefill_tokens``."""
         if entry == "mixed_chunk":
-            return self.mixed_prefill_slices * self.mixed_slice_tokens
+            return self._family.mixed_live_rows(
+                tokens, self.spec.batch_size, self.mixed_prefill_slices,
+                self.mixed_slice_tokens)
         if entry == "prefill_multi":
             return self._bucket_for(max(1, tokens)) * self.prefill_batch
         if entry == "prefill":
@@ -2306,9 +2323,16 @@ class JaxExecutor:
         slices in a single program. ``pf``: ``(slot, tokens, start_pos,
         block_table, temperature)`` per slice, each ≤
         ``mixed_slice_tokens`` tokens (``slot`` is engine bookkeeping —
-        the program addresses slices by block table). Unused slice rows
-        pad with one trash token against reserved page 0, exactly like
-        ``prefill_multi_async``.
+        the program addresses slices by block table).
+
+        The slices' tokens and absolute positions are laid TIGHT, back
+        to back in one buffer of S·T rows (``ops/rows.py``), and the
+        program is told where each slice starts and, last of
+        ``pf_starts``, how many rows hold a token: its row-wise
+        products run over those rows' tiles and no others. An unused
+        slice starts behind the last token, holds no row there and
+        keeps length 1: one trash token against reserved page 0,
+        exactly like ``prefill_multi_async``.
 
         ``carry`` and ``overrides`` mean what they mean to
         ``decode_chunk_start``: the decode rows start from the previous
@@ -2321,20 +2345,25 @@ class JaxExecutor:
         S, T = self.mixed_prefill_slices, self.mixed_slice_tokens
         assert 0 < len(pf) <= S, len(pf)
         st = self._staging
-        pf_toks = st.take("mixed.tok", (S, T), np.int32)
-        pf_poss = st.take("mixed.pos", (S, T), np.int32)
+        pf_toks = st.take("mixed.tok", (S * T,), np.int32)
+        pf_poss = st.take("mixed.pos", (S * T,), np.int32)
         pf_lens = st.take("mixed.len", (S,), np.int32, fill=1)
+        pf_starts = st.take("mixed.start", (S + 1,), np.int32, fill=None)
         pf_bts = st.take("mixed.bt", (S, self.spec.max_pages_per_seq),
                          np.int32)
         pf_temps = st.take("mixed.temp", (S,), np.float32)
+        at = 0
         for i, (_slot, t, sp, bt, temp) in enumerate(pf):
-            assert 0 < len(t) <= T, len(t)
-            pf_toks[i, :len(t)] = t
-            np.add(st.arange(T), sp, out=pf_poss[i])
-            np.minimum(pf_poss[i], sp + len(t) - 1, out=pf_poss[i])
-            pf_lens[i] = len(t)
+            n = len(t)
+            assert 0 < n <= T, n
+            pf_toks[at:at + n] = t
+            np.add(st.arange(T)[:n], sp, out=pf_poss[at:at + n])
+            pf_starts[i] = at
+            pf_lens[i] = n
             pf_bts[i] = bt
             pf_temps[i] = temp
+            at += n
+        pf_starts[len(pf):] = at
         fn = self._aot.get("mixed_chunk", self._mixed_chunk)
         tok_in, pos_in, done_in = self._chunk_lanes(
             tokens, positions, carry, overrides)
@@ -2346,15 +2375,16 @@ class JaxExecutor:
             self._batch_arr(budgets, jnp.int32),
             done_in,
             jnp.asarray(pf_toks), jnp.asarray(pf_poss),
-            jnp.asarray(pf_lens), jnp.asarray(pf_bts),
-            jnp.asarray(pf_temps),
+            jnp.asarray(pf_lens), jnp.asarray(pf_starts),
+            jnp.asarray(pf_bts), jnp.asarray(pf_temps),
             self._next_key())
         key_blocks = None
         if self._mixed_key_blocks is not None:
-            # the slices' contexts as the program reads them (an empty
-            # slot is one trash token at position 0: a context of 1)
+            # the slices' contexts as the program reads them
+            # (``ops/rows.grid_positions``; an empty slot is one trash
+            # token at the first dead row's position 0: a context of 1)
             key_blocks = self._mixed_key_blocks(
-                pf_poss[:, 0] + pf_lens, T, self.spec.page_size,
+                pf_poss[pf_starts[:S]] + pf_lens, T, self.spec.page_size,
                 self.spec.max_pages_per_seq)
         return MixedChunkHandle(out, tok, pos, done, pf_first, stats,
                                 key_blocks)

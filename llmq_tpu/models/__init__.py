@@ -18,8 +18,15 @@ A family is a module of this package that defines
 - the serving programs' model functions: ``forward_prefill``,
   ``forward_decode``, ``forward_mixed``, ``forward_verify``, with
   ``models/llama.py``'s signatures and returns (``forward_mixed``
-  returns of each slice the logits of its last valid position, (S, V):
-  the one serving samples);
+  takes its slices' tokens TIGHT with ``pf_starts`` — ``ops/rows.py`` —
+  and returns of each slice the logits of its last valid position,
+  (S, V): the one serving samples);
+- ``mixed_live_rows(tokens, batch, slices, width)``: the rows
+  ``forward_mixed``'s row-wise products run for that many prompt
+  tokens, reckoned on the host by the rule the program runs by
+  (``ops/rows.tile_rows`` where it runs row tiles, every row of the
+  grid where it does not; the executor's ``slice_tokens`` of a mixed
+  chunk);
 - ``serving_config(cfg)``: ``cfg`` as the forward-only serving
   programs take it, and ``import_hf(model_dir, cfg, **kw)``: a local
   Hugging Face checkpoint directory into the family's tree;

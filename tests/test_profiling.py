@@ -716,3 +716,47 @@ class TestOnDemandProfile:
                                       b"")
         assert status == 200
         assert out["active"] in (False, True)
+
+
+# -- ``slice_tokens`` of a mixed chunk: the rows the products run --------------
+
+#: (prompt tokens of the packed plan, decode rows, slices, their width)
+_PLANS = [(1, 4, 2, 8), (8, 4, 2, 8), (9, 4, 2, 8), (16, 4, 2, 8),
+          (300, 64, 2, 512), (630, 64, 2, 512), (1000, 128, 4, 512),
+          (1920, 128, 4, 512), (2048, 128, 4, 512), (200, 32, 2, 256)]
+
+
+@pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash"])
+@pytest.mark.parametrize("tokens,batch,slices,width", _PLANS)
+def test_a_mixed_chunk_counts_its_live_tiles_rows(family, tokens, batch,
+                                                  slices, width):
+    """``slice_tokens`` of a mixed chunk is the live tiles' rows less
+    the decode rows among them, by the family's rule — which is
+    ``ops/rows.live_rows``' rule: its trip count times the tile — never
+    under the prompt tokens, never over S x T; ``deepseek_v3``, whose
+    mixed step runs no row tiles, and any program whose rows are two
+    tiles or fewer (``rows.worth_a_loop``) count every row; and
+    the echo backend, which imports no JAX, counts as a Llama program
+    does."""
+    from llmq_tpu.engine.executor import EchoExecutor
+    from llmq_tpu.models import family as family_module
+    from llmq_tpu.ops import rows
+    fam = family_module(family)
+    lead = 0 if family == "llama" else batch
+    tile = rows.row_tile(width)
+    assert tile == min(width, 256)
+    got = fam.mixed_live_rows(tokens, batch, slices, width)
+    assert tokens <= got <= slices * width
+    if family == "deepseek_v3" or not rows.worth_a_loop(
+            lead + slices * width, tile):
+        assert got == slices * width   # no row tiles: every row runs
+    else:
+        trips = -(-(lead + tokens) // tile)      # live_rows' trip count
+        assert got == min(trips * tile, lead + slices * width) - lead
+        assert got < tokens + tile
+    echo = EchoExecutor(batch_size=batch, page_size=8, num_pages=64,
+                        max_pages_per_seq=8, mixed_prefill_slices=slices,
+                        mixed_slice_tokens=width)
+    assert EchoExecutor.ROW_TILE == rows.ROW_TILE
+    assert echo.slice_tokens("mixed_chunk", tokens) == (
+        family_module("llama").mixed_live_rows(tokens, batch, slices, width))

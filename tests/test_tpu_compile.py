@@ -346,3 +346,95 @@ def test_the_double_layer_s_decode_step_fits_v5e(one_chip, monkeypatch):
     assert 12.4e9 < mem.argument_size_in_bytes < 12.7e9
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+
+
+def _mixed_step(fam, cfg, one_chip, *, B, S, T, pages, page, quantized=False,
+                cache_dtype=None):
+    """``fam.forward_mixed`` compiled for the described chip at B rows
+    and S slices of T tokens laid tight (``ops/rows.py``)."""
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    init = fam.init_params_quantized if quantized else fam.init_params
+    params = on_chip(jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: fam.init_kv_pages(cfg, pages, page, dtype=cache_dtype)))
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, cache, tokens, positions, block_tables, *pf):
+        return fam.forward_mixed.__wrapped__(
+            params, cfg, tokens, positions, cache, block_tables, *pf,
+            dec_active=jnp.ones((B,), jnp.bool_))
+
+    mp = cfg.max_seq_len // page
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, arg(B), arg(B), arg(B, mp), arg(S * T), arg(S * T),
+        arg(S), arg(S + 1), arg(S, mp)).compile()
+    return compiled, params, cache
+
+
+def _whole_copies(compiled, tree):
+    """Copies, in the compiled program, of an array as large as a leaf
+    of ``tree`` (a stacked parameter, the pool)."""
+    import re
+    shapes = {"[" + ",".join(map(str, x.shape)) + "]"
+              for x in jax.tree.leaves(tree) if x.size > 1 << 20}
+    return [m for m in re.findall(
+        r"= \w+(\[[\d,]+\])\S* copy\(", compiled.as_text()) if m in shapes]
+
+
+def test_the_tight_mixed_step_copies_no_pool_for_v5e(one_chip, monkeypatch):
+    """``longcat-flash-chat-bf16-ep32``'s mixed step as served (128
+    rows, four 512-token slices laid tight, the row-wise blocks as
+    loops over 256-row tiles): the pool goes in and comes out in place
+    and is NEVER copied — without the barrier between the slices'
+    attention, which reads it inside a loop, and the decode rows'
+    aliased write, XLA kept both with two whole copies a step (2.2 GB
+    each, 2.9 GB of temporaries: PERF.md, PR 38) — and the temporaries
+    stay under the 0.72 GB of the un-looped step."""
+    from llmq_tpu.models import longcat_flash as lf
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    cfg = lf.longcat_flash_chat(n_layers=4, vocab_size=16384,
+                                held_experts=(0, 16), max_seq_len=2048)
+    compiled, _, cache = _mixed_step(lf, cfg, one_chip, B=128, S=4, T=512,
+                                     pages=1664, page=128)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(cache))
+    assert not _whole_copies(compiled, cache)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.72e9, mem.temp_size_in_bytes
+
+
+def test_the_tight_mixed_step_copies_no_stacked_matrix_for_v5e(
+        one_chip, monkeypatch):
+    """SmolLM2-1.7B's widths at 4 layers, 32 rows, two 512-token slices
+    (four row tiles, so ``attn_out`` + ``mlp`` loop; at its served two
+    slices of 256 nothing does): a stacked matrix carried into the
+    row-tile loop (``wo``, ``w_gate``, ``w_up``, ``w_down``: 4 x
+    201-805 MB at full depth) is read there a layer at a time and NEVER
+    copied whole — a product inside a loop whose result was split into
+    heads wanted its matrix transposed and got a copy of all its layers
+    a step (``wq``, ``wk``, ``wv``, while those ran a tile at a time:
+    PERF.md, PR 38) — and the loop is there: one ``while`` a layer."""
+    from llmq_tpu.models import llama
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    cfg = llama.LlamaConfig(
+        name="smollm2-4-layers", vocab_size=49152, dim=2048, n_layers=4,
+        n_heads=32, n_kv_heads=32, ffn_dim=8192, max_seq_len=4096,
+        rope_theta=130000.0, tie_embeddings=True,
+        pallas_batched_prefill=True)
+    compiled, params, _ = _mixed_step(llama, cfg, one_chip, B=32, S=2,
+                                      T=512, pages=512, page=16)
+    assert not _whole_copies(compiled, params)
+    assert compiled.as_text().count(" while(") >= cfg.n_layers

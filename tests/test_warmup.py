@@ -151,6 +151,48 @@ class TestWarmup:
         assert out.shape == (B, 4) and firsts.shape == (2,)
         assert BACKEND_COMPILES.count == before
 
+    @pytest.mark.parametrize("n_slices", [1, 2, 3])
+    def test_one_mixed_program_serves_every_packing(self, n_slices):
+        """After warm-up, mixed chunks with 1..S slices of every length
+        class — one token, under a tile, a tile, over one, a full slice
+        — compile nothing: how many row tiles hold a token is a trip
+        count the device reads, not a shape. And the program table is
+        the one there was: ONE ``mixed_chunk``. (Slices 16 wide, so a
+        tile is the whole 16 rows; S = 3 of this test's own.)"""
+        from llmq_tpu.observability.device import BACKEND_COMPILES
+
+        cfg = llama3_tiny(max_seq_len=128)
+        S, T = 3, 16
+        ex = JaxExecutor(cfg, init_params(jax.random.PRNGKey(0), cfg),
+                         batch_size=7, page_size=16, num_pages=33,
+                         chunk_size=4, prefill_buckets=[16, 32],
+                         mixed_prefill_slices=S, mixed_slice_tokens=T,
+                         eos_id=-1)
+        BACKEND_COMPILES.watch()
+        ex.warmup()
+        assert set(ex._aot) == {"prefill_b16", "prefill_b32",
+                                "prefill_multi_b16", "prefill_multi_b32",
+                                "decode_chunk", "mixed_chunk"}
+        before = BACKEND_COMPILES.count
+        B, MP = 7, ex.spec.max_pages_per_seq
+        bt = np.zeros((B, MP), np.int32)
+        bt[:, 0] = 1 + np.arange(B)
+        zeros, temps = np.zeros(B, np.int32), np.zeros(B, np.float32)
+        budgets = np.full(B, 4, np.int32)
+        carry = None
+        for n in (1, 7, 15, 16):
+            lens = [n, 16, 1][:n_slices]
+            carry = ex.mixed_chunk_start(
+                zeros if carry is None else None,
+                zeros if carry is None else None, bt, temps, budgets,
+                [(4 + i, [3 + i] * m, 0, bt[4 + i], 0.0)
+                 for i, m in enumerate(lens)], carry=carry)
+            assert ex.slice_tokens("mixed_chunk", sum(lens)) == (
+                -(-sum(lens) // T) * T)
+        out, firsts = carry.fetch()
+        assert out.shape == (B, 4) and firsts.shape == (S,)
+        assert BACKEND_COMPILES.count == before
+
     def test_warmup_on_mesh(self):
         """AOT specs carry the arrays' shardings — the mesh path must
         compile and serve through the executables too."""
