@@ -741,6 +741,23 @@ class InferenceEngine:
         self.device_steps = 0
         self.row_steps = 0
         self.preemptions = {"slot": 0, "release": 0}
+        #: A model family that keeps ROW STATE beside its pages (a
+        #: state-space layer's recurrent state: ``models/__init__.py``;
+        #: the executor says how many bytes a row) cannot be rebuilt
+        #: from pages: a prefix match, a pinned conversation, a tiering
+        #: promotion or a disaggregated hand-over gives K/V for its
+        #: attention layers and nothing for the others. So the engine
+        #: adopts none of them for such a family (cached length 0, the
+        #: whole context prefilled — token for token, through ``carry``
+        #: where the cache remembered the stream), counts each one it
+        #: declined, and preempts by release and rebuild only (the
+        #: victim's row goes to another sequence, and its state with
+        #: it). ``get_stats()["row_state"]``.
+        self._row_state_bytes = int(getattr(
+            executor, "row_state_bytes_per_row", 0) or 0)
+        self.row_state_rebuilds = 0
+        self.row_state_declined = {"prefix": 0, "conversation": 0,
+                                   "tiering": 0, "disagg": 0}
         #: Device stall accounting (bench satellite: BENCH rate
         #: points carry these as deltas so a poisoned latency point is
         #: attributable): a "stall" is a device transfer that exceeded
@@ -1699,6 +1716,9 @@ class InferenceEngine:
         re-prefilling its full written context (``written_ids`` — which
         includes any adopted conversation history)."""
         assert victim.slot is not None
+        # A row's state goes with the row: no cheap resume for a family
+        # that keeps one.
+        release_pages = release_pages or self._row_state_bytes > 0
         self._slots[victim.slot] = None
         self.executor.release_slot(victim.slot)
         victim.slot = None
@@ -1916,6 +1936,13 @@ class InferenceEngine:
                       and (entry.payload is not None
                            or (plane.content_free
                                and entry.tier == "host")))
+        if restorable and self._row_state_bytes:
+            # Pages without the rows' state: the remembered stream is
+            # recomputed instead (the fallback below).
+            restorable = False
+            self.row_state_declined[
+                "disagg" if getattr(entry, "from_exchange", False)
+                else "tiering"] += 1
         pages: Optional[List[int]] = None
         if restorable:
             need = PageAllocator.pages_for(entry.length,
@@ -2041,6 +2068,14 @@ class InferenceEngine:
                     # The pin's page-second meter ends here; the pages
                     # continue on THIS sequence's meter below.
                     self._usage.unpin_kv(conv)
+                if kv is not None and self._row_state_bytes:
+                    # The pin holds pages and no row state: give the
+                    # pages back and prefill the remembered stream.
+                    self.allocator.free(kv.pages)
+                    seq.carry = list(kv.tokens) + (
+                        [kv.pending] if kv.pending is not None else [])
+                    self.row_state_declined["conversation"] += 1
+                    kv = None
                 if kv is not None and self._tiering is not None \
                         and not promoted:
                     # Pin still resident — the hierarchy's top tier.
@@ -2062,6 +2097,8 @@ class InferenceEngine:
                 seq.prompt_ids = ids or [self.tokenizer.bos_id]
 
             resume_last: Optional[int] = None
+            if seq.rebuild and self._row_state_bytes:
+                self.row_state_rebuilds += 1
             if seq.rebuild:
                 # Pages were reclaimed mid-flight: re-prefill the exact
                 # written context (adopted history + prompt + generated
@@ -2128,7 +2165,10 @@ class InferenceEngine:
             if (self._prefix_cache is not None and start_pos == 0
                     and not seq.pages and len(ids) > 1):
                 m = self._prefix_cache.match(ids)
-                if m.nodes:
+                if m.nodes and self._row_state_bytes:
+                    self._prefix_cache.unlock(m)
+                    self.row_state_declined["prefix"] += 1
+                elif m.nodes:
                     n_m = len(m.pages)
                     seq.pages = list(m.pages)
                     seq.block_table[:n_m] = m.pages
@@ -2279,6 +2319,12 @@ class InferenceEngine:
             work.append((seq, chunk))
 
         handles: List = [None] * len(work)
+
+        def row_of(seq) -> tuple:
+            # the batch row whose state the chunk continues: an operand
+            # only of a family that keeps one
+            return (seq.slot,) if self._row_state_bytes else ()
+
         if use_multi:
             # Batched admission waves: npf prompts' chunks per program
             # (weights stream once per wave); ALL waves dispatch this
@@ -2292,20 +2338,22 @@ class InferenceEngine:
                     with self._prefill_dispatch("prefill", [chunk]):
                         handles[i0] = prefill_async(
                             chunk, seq.todo_pos, seq.block_table,
-                            seq.req.temperature)
+                            seq.req.temperature, *row_of(seq))
                     continue
                 with self._prefill_dispatch("prefill_multi",
                                             [c for _, c in grp]):
                     hs = prefill_multi(
                         [(chunk, seq.todo_pos, seq.block_table,
-                          seq.req.temperature) for seq, chunk in grp])
+                          seq.req.temperature) + row_of(seq)
+                         for seq, chunk in grp])
                 handles[i0:i0 + len(grp)] = hs
         elif prefill_async is not None:
             for i, (seq, chunk) in enumerate(work):
                 with self._prefill_dispatch("prefill", [chunk]):
                     handles[i] = prefill_async(chunk, seq.todo_pos,
                                                seq.block_table,
-                                               seq.req.temperature)
+                                               seq.req.temperature,
+                                               *row_of(seq))
         else:
             seq, chunk = work[0]
             with self._prefill_dispatch("prefill", [chunk]):
@@ -2639,7 +2687,7 @@ class InferenceEngine:
     def _dispatch_span(self, entry: str, *, steps: int = 0, rows: int = 0,
                        row_steps: int = 0, context_tokens: int = 0,
                        prefill_tokens: int = 0, longest: int = 0,
-                       chunk: bool = True):
+                       chunk: bool = True, state_rows: int = 0):
         """``engine.dispatch``: the span around ONE executor call and
         nothing else, with the counts taken where the work is handed
         over. ``program`` is the executor's name for what runs (its
@@ -2676,17 +2724,23 @@ class InferenceEngine:
             "slice_tokens": slice_tokens}
         if capture_held():
             counts["pages_live"], counts["tokens_live"] = self._live_kv()
+            if self._row_state_bytes:
+                # rows whose state the program updates: its decode rows
+                # and, of a prefill or a mixed chunk, its prompt chunks'
+                counts["state_rows"] = rows + state_rows
         return self._prof.span("engine.dispatch", **counts)
 
     def _chunk_dispatch(self, entry: str, budgets: np.ndarray,
-                        context_tokens: int, prefill_tokens: int = 0):
+                        context_tokens: int, prefill_tokens: int = 0,
+                        slices: int = 0):
         """``_dispatch_span`` for a chunk whose row budgets are the
-        (B,) array handed to the device."""
+        (B,) array handed to the device (``slices``: the prompt slices
+        a mixed chunk carries)."""
         return self._dispatch_span(
             entry, steps=int(budgets.max()),
             rows=int(np.count_nonzero(budgets)),
             row_steps=int(budgets.sum()), context_tokens=context_tokens,
-            prefill_tokens=prefill_tokens)
+            prefill_tokens=prefill_tokens, state_rows=slices)
 
     def _prefill_dispatch(self, entry: str, chunks):
         """``_dispatch_span`` for a dedicated prefill program over
@@ -2851,7 +2905,8 @@ class InferenceEngine:
             pf, infl_pf = self._take_slices(pf_plan, pf_budget, len(plan))
             t0 = time.perf_counter()
             with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                      prefill_tokens=packed):
+                                      prefill_tokens=packed,
+                                      slices=len(pf)):
                 handle = self.executor.mixed_chunk_start(
                     None, None, block_tables, temps, budgets, pf,
                     carry=infl.handle, overrides=overrides)
@@ -3765,7 +3820,8 @@ class InferenceEngine:
         t0 = time.perf_counter()
         if start_fn is not None:
             with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                      prefill_tokens=packed):
+                                      prefill_tokens=packed,
+                                      slices=len(pf)):
                 handle = start_fn(tokens, positions, block_tables,
                                   temps, budgets, pf)
             dispatch_s = time.perf_counter() - t_asm
@@ -3786,7 +3842,7 @@ class InferenceEngine:
             return True
         # Sync executor (echo): one blocking call, commit inline.
         with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                  prefill_tokens=packed):
+                                  prefill_tokens=packed, slices=len(pf)):
             out, pf_first = self.executor.mixed_chunk(
                 tokens, positions, block_tables, temps, budgets, pf)
         t_done = time.perf_counter()
@@ -4295,6 +4351,13 @@ class InferenceEngine:
             # compile-cache state.
             "device": self._telemetry.snapshot(),
         }
+        if self._row_state_bytes:
+            out["row_state"] = {
+                "rows": self.spec.batch_size,
+                "bytes_per_row": self._row_state_bytes,
+                "bytes": self._row_state_bytes * self.spec.batch_size,
+                "rebuilds": self.row_state_rebuilds,
+                "declined": dict(self.row_state_declined)}
         if self._pipe_cfg is not None:
             # Async pipeline (docs/performance.md): occupancy histogram
             # (chunks dispatched at each in-flight depth) + the

@@ -878,8 +878,7 @@ class JaxExecutor:
             mesh=mesh is not None and mesh.size > 1,
             speculation_draft_k=int(speculation_draft_k))
         init_kv_pages = fam.init_kv_pages
-        forward_prefill, forward_verify = (fam.forward_prefill,
-                                           fam.forward_verify)
+        forward_verify = fam.forward_verify
         #: Counters a forward pass of this family returns after the
         #: cache (a routed model: tokens an expert, experts touched);
         #: 0 for a family that counts nothing. The chunk programs sum
@@ -896,8 +895,36 @@ class JaxExecutor:
         #: chunk's handle carries as ``key_blocks``.
         self._mixed_key_blocks = getattr(fam, "mixed_key_blocks", None)
 
+        #: Row state beside the pages (``models/__init__.py``): leaves
+        #: indexed (layer, batch row, ...) for a family that has one —
+        #: its programs then carry and donate ``(pages, row state)``
+        #: where the others carry the pages, and take the batch rows
+        #: of their prompt chunks as one more operand — else ``None``,
+        #: and every program is what it was before there was any.
+        self.row_state = fam.init_row_state(model_cfg, batch_size)
+        self.row_state_bytes_per_row = (
+            fam.row_state_bytes_per_row(model_cfg)
+            if self.row_state is not None else 0)
+        has_rows = self.row_state is not None
+
+        def forward_prefill(params, cfg, tokens, positions, lengths, cache,
+                            bts, last_only, rows=()):
+            if not has_rows:
+                return fam.forward_prefill(params, cfg, tokens, positions,
+                                           lengths, cache, bts,
+                                           last_only=last_only)
+            last, pages, state = fam.forward_prefill(
+                params, cfg, tokens, positions, lengths, cache[0], bts,
+                last_only=last_only, row_state=cache[1], rows=rows[0])
+            return last, (pages, state)
+
         def forward_decode(params, cfg, tok, pos, cache, bts, active=None,
                            acc=None):
+            if has_rows:
+                logits, pages, state = fam.forward_decode(
+                    params, cfg, tok, pos, cache[0], bts, active=active,
+                    row_state=cache[1])
+                return logits, (pages, state), None
             if not n_stats:
                 return fam.forward_decode(params, cfg, tok, pos, cache, bts,
                                           active=active) + (None,)
@@ -905,7 +932,13 @@ class JaxExecutor:
                 params, cfg, tok, pos, cache, bts, active=active, stats=True)
             return logits, cache, (st if acc is None else acc + st)
 
-        def forward_mixed(*args, dec_active=None):
+        def forward_mixed(*args, dec_active=None, rows=()):
+            if has_rows:
+                cache = args[4]
+                dec, pf, pages, state = fam.forward_mixed(
+                    *args[:4], cache[0], *args[5:], dec_active=dec_active,
+                    row_state=cache[1], pf_rows=rows[0])
+                return dec, pf, (pages, state), None
             if not n_stats:
                 return fam.forward_mixed(*args, dec_active=dec_active) + (
                     None,)
@@ -1054,11 +1087,11 @@ class JaxExecutor:
 
         @jit_step
         def _prefill_step(params, cache, tokens, positions, lengths,
-                          block_tables, temperature, key):
+                          block_tables, temperature, key, *rows):
             with scope("prefill"):
                 last, cache = forward_prefill(
                     params, cfg, tokens, positions, lengths, cache,
-                    block_tables, last_only=True)      # (1, V) f32
+                    block_tables, True, rows)          # (1, V) f32
             with scope("sample"):
                 tok = sample_token(last, key, temperature=temperature,
                                    top_k=top_k, top_p=top_p)[0]
@@ -1066,14 +1099,14 @@ class JaxExecutor:
 
         @jit_step
         def _prefill_multi(params, cache, tokens, positions, lengths,
-                           block_tables, temperatures, key):
+                           block_tables, temperatures, key, *rows):
             """Batched prefill: N prompts' chunks through one program —
             per-row last-token sampling; padded rows (length ≤ 1,
             all-zero block table) write only reserved page 0."""
             with scope("prefill"):
                 last, cache = forward_prefill(
                     params, cfg, tokens, positions, lengths, cache,
-                    block_tables, last_only=True)      # (N, V)
+                    block_tables, True, rows)          # (N, V)
             with scope("sample"):
                 toks = sample_token(last, key, temperature=temperatures,
                                     top_k=top_k, top_p=top_p)
@@ -1170,7 +1203,7 @@ class JaxExecutor:
             def _mixed_chunk(params, cache, tokens, positions,
                              block_tables, temperatures, budgets, done_in,
                              pf_tokens, pf_positions, pf_lengths, pf_starts,
-                             pf_block_tables, pf_temps, key):
+                             pf_block_tables, pf_temps, key, *pf_rows):
                 """Token-budget MIXED chunk: one device program that
                 advances the decode rows up to K steps AND runs S
                 prefill slices of up to T tokens each over the shared
@@ -1199,7 +1232,8 @@ class JaxExecutor:
                     dec_logits, pf_logits, cache, acc = forward_mixed(
                         params, cfg, tokens, positions, cache,
                         block_tables, pf_tokens, pf_positions, pf_lengths,
-                        pf_starts, pf_block_tables, dec_active=active0)
+                        pf_starts, pf_block_tables, dec_active=active0,
+                        **({"rows": pf_rows} if pf_rows else {}))
                     with scope("sample"):
                         pf_first = sample_token(
                             pf_logits, keys[K],
@@ -1459,6 +1493,34 @@ class JaxExecutor:
                 "n_chips": (self.mesh.size
                             if self.mesh is not None else 1)}
 
+    @property
+    def _pool(self):
+        """What the serving programs donate and return at operand 1: the
+        page pool, and beside it the row state of a family that has
+        one."""
+        if self.row_state is None:
+            return self.cache
+        return self.cache, self.row_state
+
+    @_pool.setter
+    def _pool(self, pool) -> None:
+        if self.row_state is None:
+            self.cache = pool
+        else:
+            self.cache, self.row_state = pool
+
+    def _rows_arg(self, rows) -> tuple:
+        """The batch rows of a program's prompt chunks as its last
+        operand — ``()`` for a family without row state, whose programs
+        take none."""
+        if self.row_state is None:
+            return ()
+        if any(r is None for r in rows):
+            raise ValueError(
+                f"model {self.model_cfg.name!r} keeps row state: a "
+                f"prefill names its sequence's batch row (slot=)")
+        return (self._jnp.asarray(rows, self._jnp.int32),)
+
     def hbm_info(self) -> List[Dict]:
         """Per-chip HBM accounting: weights / KV-pool bytes resident on
         each local device (sharded trees split per device via sharding
@@ -1475,6 +1537,8 @@ class JaxExecutor:
         jax = self._jax
         if self._hbm_static is None:
             per: Dict[int, Dict[str, int]] = {}
+            zero = {"weights_bytes": 0, "kv_pool_bytes": 0,
+                    "row_state_bytes": 0}
 
             def add(tree, key: str) -> None:
                 for leaf in jax.tree.leaves(tree):
@@ -1495,17 +1559,15 @@ class JaxExecutor:
                             shard_bytes = (math.prod(shape) * itemsize
                                            // len(devs))
                         for dv in devs:
-                            d = per.setdefault(
-                                dv.id,
-                                {"weights_bytes": 0, "kv_pool_bytes": 0})
+                            d = per.setdefault(dv.id, dict(zero))
                             d[key] += int(shard_bytes)
                     else:
-                        d = per.setdefault(
-                            0, {"weights_bytes": 0, "kv_pool_bytes": 0})
+                        d = per.setdefault(0, dict(zero))
                         d[key] += int(math.prod(shape) * itemsize)
 
             add(self.params, "weights_bytes")
             add(self.cache, "kv_pool_bytes")
+            add(self.row_state, "row_state_bytes")
             self._hbm_static = per
         chips = []
         for dev in jax.local_devices():
@@ -1516,6 +1578,8 @@ class JaxExecutor:
                      "weights_bytes": d.get("weights_bytes", 0),
                      "kv_pool_bytes": d.get("kv_pool_bytes", 0),
                      "free_bytes": None, "limit_bytes": None}
+            if self.row_state is not None:
+                entry["row_state_bytes"] = d.get("row_state_bytes", 0)
             try:
                 stats = dev.memory_stats() or {}
                 limit = stats.get("bytes_limit")
@@ -1736,7 +1800,11 @@ class JaxExecutor:
                 x.shape, x.dtype, sharding=getattr(x, "sharding", None)),
             tree)
         p = abstract(self.params)
-        c = abstract(self.cache)
+        c = abstract(self._pool)
+        # the batch rows of a program's prompt chunks: an operand of a
+        # family that keeps row state, none of the others
+        rows_of = ((lambda n: (sds((n,), jnp.int32),))
+                   if self.row_state is not None else (lambda n: ()))
         key = sds((2,), jnp.uint32)
         B, MP = spec.batch_size, spec.max_pages_per_seq
         i32, f32 = jnp.int32, jnp.float32
@@ -1747,14 +1815,14 @@ class JaxExecutor:
             jobs.append((f"prefill_b{T}", self._prefill_step,
                          (p, c, sds((1, T), i32), sds((1, T), i32),
                           sds((1,), i32), sds((1, MP), i32),
-                          sds((1,), f32), key),
+                          sds((1,), f32), key) + rows_of(1),
                          self._routes(prefill_rows=1)))
             if NPF > 1:
                 jobs.append((f"prefill_multi_b{T}", self._prefill_multi,
                              (p, c, sds((NPF, T), i32),
                               sds((NPF, T), i32), sds((NPF,), i32),
                               sds((NPF, MP), i32), sds((NPF,), f32),
-                              key),
+                              key) + rows_of(NPF),
                              self._routes(prefill_rows=NPF)))
         dec_routes = self._routes(decode=True)
         if self.chunk_size > 1:
@@ -1793,7 +1861,8 @@ class JaxExecutor:
                           bsds((B,), i32), bsds((B,), jnp.bool_),
                           sds((S * T,), i32), sds((S * T,), i32),
                           sds((S,), i32), sds((S + 1,), i32),
-                          sds((S, MP), i32), sds((S,), f32), key),
+                          sds((S, MP), i32), sds((S,), f32), key)
+                         + rows_of(S),
                          self._routes(decode=True, prefill_rows=S)))
 
         exp_dir = self._export_cache_dir()
@@ -1947,7 +2016,7 @@ class JaxExecutor:
             if self.prefill_batch > 1:
                 # The admission-wave program of the same bucket, and
                 # the eager per-row split of its result.
-                self.prefill_multi_async([([1] * n, 0, bt[0], 0.0)])
+                self.prefill_multi_async([([1] * n, 0, bt[0], 0.0, 0)])
             prev = b
         # Reset pool: warmup wrote garbage KV into page 0 only (block
         # table all-zero), which is never read — nothing to clean.
@@ -1981,7 +2050,7 @@ class JaxExecutor:
             # batch device-to-device through eager scatters, and XLA
             # compiles those once per flavour of the lane arrays —
             # host-born, then a previous chunk's carry.
-            first = self.prefill_async([1], 0, bt[0], 0.0)
+            first = self.prefill_async([1], 0, bt[0], 0.0, 0)
             join = [(0, first, 0)]
             ones_b = np.ones(spec.batch_size, np.int32)
             h = self.decode_chunk_start(
@@ -2090,7 +2159,7 @@ class JaxExecutor:
         return 0
 
     def _prefill_chunk(self, chunk: List[int], start_pos: int, bt,
-                       temperature: float):
+                       temperature: float, slot: Optional[int] = None):
         """Launch ONE bucketed prefill program (no host sync): pads the
         chunk to its bucket, clamps padding positions, updates the
         donated cache. Returns the sampled-token device array."""
@@ -2103,14 +2172,14 @@ class JaxExecutor:
         np.add(self._staging.arange(T), start_pos, out=positions)
         np.minimum(positions, start_pos + len(chunk) - 1, out=positions)
         fn = self._aot.get(f"prefill_b{T}", self._prefill_step)
-        tok, self.cache = fn(
-            self.params, self.cache,
+        tok, self._pool = fn(
+            self.params, self._pool,
             jnp.asarray(padded)[None, :],
             jnp.asarray(positions, jnp.int32)[None, :],
             jnp.asarray([len(chunk)], jnp.int32),
             bt,
             jnp.asarray([temperature], jnp.float32),
-            self._next_key())
+            self._next_key(), *self._rows_arg([slot]))
         self._staging_fence(f"prefill{T}", tok)
         return tok
 
@@ -2129,7 +2198,7 @@ class JaxExecutor:
         while remaining:
             chunk = remaining[: self.prefill_buckets[-1]]
             remaining = remaining[len(chunk):]
-            tok = self._prefill_chunk(chunk, pos, bt, temperature)
+            tok = self._prefill_chunk(chunk, pos, bt, temperature, slot)
             pos += len(chunk)
         if tok is None:
             return spec.eos_id
@@ -2140,13 +2209,14 @@ class JaxExecutor:
         program dispatch (no host sync): the weight streaming of the
         dense path is paid once for the whole admission wave instead of
         per sequence. ``reqs``: (tokens, start_pos, block_table,
-        temperature) per sequence, each chunk ≤ the largest bucket.
+        temperature) per sequence — and its batch row, for a family
+        that keeps row state — each chunk ≤ the largest bucket.
         Returns one device scalar (sampled first token) per request.
         """
         jnp = self._jnp
         N = self.prefill_batch
         assert 0 < len(reqs) <= N, len(reqs)
-        T = self._bucket_for(max(len(t) for t, _, _, _ in reqs))
+        T = self._bucket_for(max(len(r[0]) for r in reqs))
         st = self._staging
         toks = st.take(f"pfm{T}.tok", (N, T), np.int32)
         poss = st.take(f"pfm{T}.pos", (N, T), np.int32)
@@ -2154,7 +2224,10 @@ class JaxExecutor:
         bts = st.take(f"pfm{T}.bt", (N, self.spec.max_pages_per_seq),
                       np.int32)
         temps = st.take(f"pfm{T}.temp", (N,), np.float32)
-        for i, (t, sp, bt, temp) in enumerate(reqs):
+        # a padded row names one past the last batch row: nobody's state
+        slots = [self.spec.batch_size] * N
+        for i, (t, sp, bt, temp, *slot) in enumerate(reqs):
+            slots[i] = slot[0] if slot else None
             toks[i, :len(t)] = t
             np.add(st.arange(T), sp, out=poss[i])
             np.minimum(poss[i], sp + len(t) - 1, out=poss[i])
@@ -2162,15 +2235,16 @@ class JaxExecutor:
             bts[i] = bt
             temps[i] = temp
         fn = self._aot.get(f"prefill_multi_b{T}", self._prefill_multi)
-        out, self.cache = fn(
-            self.params, self.cache, jnp.asarray(toks),
+        out, self._pool = fn(
+            self.params, self._pool, jnp.asarray(toks),
             jnp.asarray(poss), jnp.asarray(lens), jnp.asarray(bts),
-            jnp.asarray(temps), self._next_key())
+            jnp.asarray(temps), self._next_key(), *self._rows_arg(slots))
         self._staging_fence(f"pfm{T}", out)
         return [out[i] for i in range(len(reqs))]
 
     def prefill_async(self, tokens: List[int], start_pos: int,
-                      block_table: np.ndarray, temperature: float):
+                      block_table: np.ndarray, temperature: float,
+                      slot: Optional[int] = None):
         """Single-bucket prefill WITHOUT the host sync: returns the
         sampled first token as a device array (fetch it when needed).
         Steady-state admission throughput — benchmarks and future
@@ -2178,15 +2252,16 @@ class JaxExecutor:
         if len(tokens) > self.prefill_buckets[-1]:
             raise ValueError("prefill_async requires a single-bucket chunk")
         bt = self._jnp.asarray(block_table, self._jnp.int32)[None, :]
-        return self._prefill_chunk(list(tokens), start_pos, bt, temperature)
+        return self._prefill_chunk(list(tokens), start_pos, bt, temperature,
+                                   slot)
 
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
                block_tables: np.ndarray,
                temperatures: np.ndarray) -> np.ndarray:
         jnp = self._jnp
         fn = self._aot.get("decode", self._decode_step)
-        toks, self.cache = fn(
-            self.params, self.cache,
+        toks, self._pool = fn(
+            self.params, self._pool,
             self._batch_arr(tokens, jnp.int32),
             self._batch_arr(positions, jnp.int32),
             self._batch_arr(block_tables, jnp.int32),
@@ -2243,8 +2318,8 @@ class JaxExecutor:
         fn = self._aot.get("decode_chunk", self._decode_chunk)
         tok_in, pos_in, done_in = self._chunk_lanes(
             tokens, positions, carry, overrides)
-        out, tok, pos, done, self.cache, stats = fn(
-            self.params, self.cache,
+        out, tok, pos, done, self._pool, stats = fn(
+            self.params, self._pool,
             tok_in, pos_in,
             self._batch_arr(block_tables, jnp.int32),
             self._batch_arr(temperatures, jnp.float32),
@@ -2323,7 +2398,9 @@ class JaxExecutor:
         slices in a single program. ``pf``: ``(slot, tokens, start_pos,
         block_table, temperature)`` per slice, each ≤
         ``mixed_slice_tokens`` tokens (``slot`` is engine bookkeeping —
-        the program addresses slices by block table).
+        the program addresses slices by block table — except for a
+        family that keeps row state: there it is the row whose state
+        the slice continues).
 
         The slices' tokens and absolute positions are laid TIGHT, back
         to back in one buffer of S·T rows (``ops/rows.py``), and the
@@ -2353,7 +2430,10 @@ class JaxExecutor:
                          np.int32)
         pf_temps = st.take("mixed.temp", (S,), np.float32)
         at = 0
-        for i, (_slot, t, sp, bt, temp) in enumerate(pf):
+        # an unused slice names one past the last batch row: nobody's
+        pf_rows = [self.spec.batch_size] * S
+        for i, (slot, t, sp, bt, temp) in enumerate(pf):
+            pf_rows[i] = slot
             n = len(t)
             assert 0 < n <= T, n
             pf_toks[at:at + n] = t
@@ -2367,8 +2447,8 @@ class JaxExecutor:
         fn = self._aot.get("mixed_chunk", self._mixed_chunk)
         tok_in, pos_in, done_in = self._chunk_lanes(
             tokens, positions, carry, overrides)
-        out, tok, pos, done, pf_first, self.cache, stats = fn(
-            self.params, self.cache,
+        out, tok, pos, done, pf_first, self._pool, stats = fn(
+            self.params, self._pool,
             tok_in, pos_in,
             self._batch_arr(block_tables, jnp.int32),
             self._batch_arr(temperatures, jnp.float32),
@@ -2377,7 +2457,7 @@ class JaxExecutor:
             jnp.asarray(pf_toks), jnp.asarray(pf_poss),
             jnp.asarray(pf_lens), jnp.asarray(pf_starts),
             jnp.asarray(pf_bts), jnp.asarray(pf_temps),
-            self._next_key())
+            self._next_key(), *self._rows_arg(pf_rows))
         key_blocks = None
         if self._mixed_key_blocks is not None:
             # the slices' contexts as the program reads them

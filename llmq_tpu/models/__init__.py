@@ -11,10 +11,26 @@ A family is a module of this package that defines
   ``param_count``, ``param_count_analytic``, ``active_param_count``
   (what one token multiplies with: the MFU estimate's count),
   ``weight_bytes``;
-- the cache: ``init_kv_pages`` (a dict of ``(L, P, page_size, ...)``
+- the cache, which is PAGES and, for some families, ROW STATE beside
+  them. Pages: ``init_kv_pages`` (a dict of ``(L, P, page_size, ...)``
   leaves, page 0 reserved: the executor, the allocator, the prefix
   cache, tiering and disaggregation treat it as a pytree of such
-  leaves and never by key) and ``kv_bytes_per_token``;
+  leaves and never by key) and ``kv_bytes_per_token``. Row state:
+  ``init_row_state(cfg, batch)``, a dict of leaves indexed ``(layer,
+  batch row, ...)`` that a sequence carries from token to token
+  whatever its length (a state-space layer's recurrent state; the
+  leaves hold ``batch + 1`` rows, the last nobody's, as page 0 is: an
+  unused slice of a program names it), or
+  ``None`` for a family whose pages are its whole cache, and
+  ``row_state_bytes_per_row(cfg)`` (0 then). A family WITH row state
+  takes it in every forward function as ``row_state=`` and returns it
+  after the cache, takes the batch row of each prefill sequence
+  (``rows=``) and of each mixed slice (``pf_rows=``), zeroes a row's
+  state inside the program where its sequence starts (position 0) and
+  leaves the state of a decode row that is not active as it found it.
+  The executor carries and donates it beside the pool; the engine
+  adopts nothing that only pages could rebuild (``get_stats()
+  ["row_state"]``);
 - the serving programs' model functions: ``forward_prefill``,
   ``forward_decode``, ``forward_mixed``, ``forward_verify``, with
   ``models/llama.py``'s signatures and returns (``forward_mixed``
@@ -78,6 +94,7 @@ FAMILIES: Dict[str, str] = {
     "llama": "llmq_tpu.models.llama",
     "deepseek_v3": "llmq_tpu.models.deepseek_v3",
     "longcat_flash": "llmq_tpu.models.longcat_flash",
+    "granitemoehybrid": "llmq_tpu.models.granitemoehybrid",
 }
 
 

@@ -322,6 +322,69 @@ def import_hf_longcat_flash(model_dir: str, cfg) -> Params:
     return params
 
 
+def import_hf_granitemoehybrid(model_dir: str, cfg) -> Params:
+    """A local Hugging Face ``granitemoehybrid`` checkpoint directory
+    (safetensors) into ``models/granitemoehybrid.py``'s tree
+    (``param_shapes``). The tensor names are from memory of the public
+    ``modeling_granitemoehybrid.py`` (this sandbox has no network; the
+    test builds a synthetic checkpoint under the same names): in a
+    Mamba layer ``mamba.{in_proj, conv1d (weight (C, 1, K) and bias),
+    dt_bias, A_log, D, norm, out_proj}``, in an attention layer
+    ``self_attn.{q,k,v,o}_proj``, in every layer
+    ``shared_mlp.{input_linear, output_linear}`` and the two layer
+    norms. ``input_linear`` holds the gate's rows and then the up
+    projection's (the published code chunks its output in two): split
+    here into ``w_gate`` and ``w_up``. The head is tied to the
+    embedding."""
+    t = _read_safetensors(model_dir)
+    dt = cfg.dtype
+    kinds = list(cfg.layer_types)
+    every = range(len(kinds))
+    mamba = [l for l in every if kinds[l] == "mamba"]
+    attn = [l for l in every if kinds[l] == "attention"]
+
+    def stack(layers, name: str, transform=None, dtype=dt) -> jnp.ndarray:
+        mats = []
+        for l in layers:
+            w = t[f"model.layers.{l}.{name}"]
+            mats.append(transform(w) if transform else w)
+        return jnp.asarray(np.stack(mats), dtype=dtype)
+
+    F = cfg.ffn_dim
+    f32 = jnp.float32
+    params: Params = {
+        "embed": jnp.asarray(t["model.embed_tokens.weight"], dtype=dt),
+        "final_norm": jnp.asarray(t["model.norm.weight"], dtype=dt),
+        "layers": {
+            "attn_norm": stack(every, "input_layernorm.weight"),
+            "mlp_norm": stack(every, "post_attention_layernorm.weight"),
+            "w_gate": stack(every, "shared_mlp.input_linear.weight",
+                            lambda w: w[:F].T),
+            "w_up": stack(every, "shared_mlp.input_linear.weight",
+                          lambda w: w[F:].T),
+            "w_down": stack(every, "shared_mlp.output_linear.weight",
+                            lambda w: w.T),
+            "wq": stack(attn, "self_attn.q_proj.weight", lambda w: w.T),
+            "wk": stack(attn, "self_attn.k_proj.weight", lambda w: w.T),
+            "wv": stack(attn, "self_attn.v_proj.weight", lambda w: w.T),
+            "wo": stack(attn, "self_attn.o_proj.weight", lambda w: w.T),
+            "in_proj": stack(mamba, "mamba.in_proj.weight", lambda w: w.T),
+            "conv_w": stack(mamba, "mamba.conv1d.weight",
+                            lambda w: w[:, 0, :]),
+            "conv_b": stack(mamba, "mamba.conv1d.bias"),
+            "dt_bias": stack(mamba, "mamba.dt_bias", dtype=f32),
+            "a_log": stack(mamba, "mamba.A_log", dtype=f32),
+            "d_skip": stack(mamba, "mamba.D", dtype=f32),
+            "ssm_norm": stack(mamba, "mamba.norm.weight"),
+            "out_proj": stack(mamba, "mamba.out_proj.weight",
+                              lambda w: w.T),
+        },
+    }
+    log.info("imported HF granitemoehybrid from %s (%d tensors)", model_dir,
+             len(t))
+    return params
+
+
 def import_hf(model_dir: str, cfg, **kw) -> Params:
     """A local Hugging Face checkpoint directory into the tree of
     ``cfg``'s model family, by that family's ``import_hf``

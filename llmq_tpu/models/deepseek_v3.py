@@ -194,6 +194,16 @@ def mixed_key_blocks(seq_lens, T: int, page_size: int, max_pages: int):
     return len(seq_lens) * int(visited), len(seq_lens) * table
 
 
+def init_row_state(cfg, batch: int) -> None:
+    """No row state: the pages are this family's whole cache
+    (``models/__init__.py``)."""
+    return None
+
+
+def row_state_bytes_per_row(cfg) -> int:
+    return 0
+
+
 def check_serving(cfg: DeepseekV3Config, *, quantization: str = "",
                   kv_quantization: str = "", mesh: bool = False,
                   speculation_draft_k: int = 0) -> None:

@@ -291,6 +291,16 @@ def mixed_live_rows(tokens: int, batch: int, slices: int, width: int) -> int:
     return tile_rows(tokens, row_tile(width), slices * width)
 
 
+def init_row_state(cfg, batch: int) -> None:
+    """No row state: the pages are this family's whole cache
+    (``models/__init__.py``)."""
+    return None
+
+
+def row_state_bytes_per_row(cfg) -> int:
+    return 0
+
+
 def check_serving(cfg: LlamaConfig, **settings) -> None:
     """Everything the serving settings can ask for is written for this
     family."""

@@ -73,7 +73,7 @@ SCOPES = (
     "slices", "decode_rows",
     "embed", "qkv", "kv_write", "attn", "latent_prefill_attention",
     "attn_out", "mlp", "moe_route", "moe_experts", "moe_combine", "head",
-    "act_quant",
+    "act_quant", "ssm_conv", "ssm_update", "ssm_scan",
 )
 
 

@@ -3,7 +3,10 @@
 cell runs it): the fused decode kernel of the Llama block and, with
 ``--prefill``, its paged prefill kernel; or, for a configuration of the
 family ``deepseek_v3``, the latent decode kernel and the latent write
-(``ops/pallas/latent_decode.py``), each timed apart.
+(``ops/pallas/latent_decode.py``), each timed apart; or, for the family
+``granitemoehybrid``, the Mamba-2 decode state update in place
+(``ops/pallas/ssm_update.py``) beside XLA's fusion of the same
+(``--lens ROWS``: that many of the batch's rows decode).
 
 Heads, page size, block table width, batch, pool and the int8 kernel
 come from a served configuration (``--model-file
@@ -533,10 +536,108 @@ def bench_latent_prefill(args, doc) -> None:
 
 #: family (the configuration file's ``family``, ``llmq_tpu/models``
 #: ``FAMILIES``) -> the bench of its decode kernels. The kernels a
+def bench_ssm(args, doc) -> None:
+    """The Mamba-2 decode state update of one layer at the served
+    geometry (``--lens ROWS``: that many of the batch's rows decode, the
+    others keep their state): µs a call through the in-place kernel
+    (``ops/pallas/ssm_update.py``) and through XLA's fusion of
+    ``ops/ssm.ssm_update``, each over the stacked leaf with the pool
+    donated, and the share of the LIVE rows' state read once and written
+    once at 819 GB/s (the yardstick of ``ssm_update_roofline``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmq_tpu.ops import ssm
+
+    ex = doc["server"]["executor"]
+    B = ex["max_batch_size"]
+    H, P, N = doc["mamba_n_heads"], doc["mamba_d_head"], doc["mamba_d_state"]
+    L = doc["layer_types"].count("mamba")
+    reps = L
+    if args.rehearse:
+        os.environ["LLMQ_PALLAS"] = "interpret"
+        L = reps = 2
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path in interpret mode)")
+    key = jax.random.key(0)
+    ks = jax.random.split(key, 6)
+    x = jax.random.normal(ks[0], (B, H, P), jnp.float32)
+    dt = jnp.exp(jax.random.uniform(ks[1], (B, H), jnp.float32, -6.9, -2.3))
+    a = -jax.random.uniform(ks[2], (H,), jnp.float32, 1.0, 16.0)
+    bm = jax.random.normal(ks[3], (B, N), jnp.float32)
+    cm = jax.random.normal(ks[4], (B, N), jnp.float32)
+    d = jnp.ones((H,), jnp.float32)
+
+    def make(enabled):
+        # One rolled loop over the layers (the layer index traced: the
+        # kernel reads it from its scalar prefetch, XLA's path slices
+        # and updates the leaf dynamically): 36 unrolled XLA updates of
+        # one donated leaf made the compiler keep a copy of the leaf a
+        # call (141 GB asked of the chip's 15.75).
+        @partial(jax.jit, donate_argnums=(0,))
+        def run(pool, active, x, dt, a, bm, cm, d):
+            def body(i, carry):
+                pool, acc = carry
+                # inputs of its own a call
+                y, pool = ssm.ssm_update_layer(
+                    pool, i % L, x * (1 + i / 64), dt, a, bm, cm, d, active,
+                    enabled=enabled)
+                return pool, acc + y
+            return jax.lax.fori_loop(
+                0, reps, body, (pool, jnp.zeros((B, H, P), jnp.float32)))
+        return run
+
+    print(f"{doc['name']}: ssm update B={B} heads={H}x{P} state={N} "
+          f"layers={L} device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    n = 1 if args.rehearse else 10
+    results = []
+    for spec in args.lens:
+        rows = min(B, int(spec.split("x")[0]))
+        active = jnp.arange(B) < rows
+        rec = {"rows": rows}
+        for name, enabled in (("kernel", True), ("xla", False)):
+            run = make(enabled)
+            # (one layer's draw tiled: a draw of the whole 4.9 GB leaf
+            # needs as much again for its bits)
+            pool = jnp.tile(jax.random.normal(
+                ks[5], (1, B + 1, N, H * P), jnp.float32), (L, 1, 1, 1))
+            call = (active, x, dt, a, bm, cm, d)
+            pool, y = run(pool, *call)
+            rec[name + "_y"] = np.asarray(y)
+            t0 = time.perf_counter()
+            for _ in range(n):
+                pool, y = run(pool, *call)
+            jax.block_until_ready(y)
+            rec[name + "_us"] = (time.perf_counter() - t0) / (n * reps) * 1e6
+            del pool
+        least = rows * 2 * N * H * P * 4 / PEAK_BYTES_PER_S * 1e6
+        gap = float(np.abs(rec.pop("kernel_y")[:rows]
+                           - rec.pop("xla_y")[:rows]).max())
+        rec.update(least_us=least, max_abs_gap=gap,
+                   kernel_roofline_pct=100 * least / rec["kernel_us"],
+                   xla_roofline_pct=100 * least / rec["xla_us"])
+        results.append(rec)
+        print(f"  rows {rows:3d} of {B}: kernel {rec['kernel_us']:,.1f} "
+              f"us/call ({rec['kernel_roofline_pct']:.1f} % of the live "
+              f"rows' bytes at peak), xla {rec['xla_us']:,.1f} "
+              f"({rec['xla_roofline_pct']:.1f} %); least {least:,.1f}; "
+              f"outputs apart by {gap:.2e}", flush=True)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+        with open(args.out, "w", encoding="utf-8") as f:
+            json.dump({"config": doc["name"], "tree": args.tree,
+                       "results": results}, f, indent=1)
+
+
 #: family dispatches are what this tool is about, so a new family's
 #: bench is a function here and an entry in this table.
 BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent,
-           "longcat_flash": bench_latent}
+           "longcat_flash": bench_latent, "granitemoehybrid": bench_ssm}
 
 
 def main() -> None:
@@ -566,6 +667,10 @@ def main() -> None:
         sys.exit(f"{args.model_file}: no kernel bench for the family "
                  f"{doc.get('family')!r}; known: {sorted(BENCHES)}")
     if args.prefill:
+        if doc["family"] == "granitemoehybrid":
+            sys.exit("--prefill: no slice bench for granitemoehybrid (its "
+                     "attention layers run the llama kernels; its scan is "
+                     "XLA's)")
         (bench_prefill if doc["family"] == "llama"
          else bench_latent_prefill)(args, doc)
     if args.lens:

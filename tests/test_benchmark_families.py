@@ -53,13 +53,15 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
         keys = contract.load_family(fdir, "shapes").MODEL_KEYS
         assert all(k in config for k in keys), entry["name"]
         assert set(config["reduced"]) == set(entry["reduced"])
-    assert families == {"llama", "deepseek_v3", "longcat_flash"}
+    assert families == {"llama", "deepseek_v3", "longcat_flash",
+                        "granitemoehybrid"}
 
 
 @pytest.mark.parametrize("cell", [
     "smollm2-chat-bursts", "smollm2-decode-saturated",
     "smollm2-sessions-prefix", "mistral7b-decode-saturated",
-    "kanana2-decode-saturated", "longcat-decode-saturated"])
+    "kanana2-decode-saturated", "longcat-decode-saturated",
+    "granite4h-decode-saturated"])
 def test_every_cell_resolves_and_reports_what_the_contract_asks(bench, cell):
     assert contract.check_names(bench) == []
     assert cell in [w["name"] for w in bench["workloads"]]
@@ -83,6 +85,7 @@ def test_no_cell_is_left_out_of_the_cases_above(bench):
     ("mistral-7b-v0.3-w8kv8", 1, 66_560),
     ("kanana-2-30b-a3b-bf16", 2, 9_216),
     ("longcat-flash-chat-bf16-ep32", 2, 9_216),
+    ("granite-4.0-h-micro-bf16", 2, 8_192),
 ])
 def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
                                                     kv_itemsize, per_token):
@@ -90,8 +93,11 @@ def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
     shapes = contract.load_family(contract.family_dir(bench, doc), "shapes")
     model = {k: doc[k] for k in shapes.MODEL_KEYS if k in doc}
     assert shapes.kv_bytes_per_token(model, kv_itemsize) == per_token
-    # one attention a layer, or a double layer's two
+    # one attention a layer, or a double layer's two, or one an
+    # ATTENTION layer where the layers are of two kinds
     layers = doc.get("num_hidden_layers", 2 * doc.get("num_layers", 0))
+    if "layer_types" in doc:
+        layers = doc["layer_types"].count("attention")
     assert shapes.attn_calls_per_step(model) == layers
 
 
@@ -156,6 +162,128 @@ def test_the_shared_family_s_shapes_on_the_published_sizes(bench):
     assert shapes.decode_attn_flops(held, 128, 1) == 8 * 64 * (576 + 512) * 2
     assert shapes.moe_ffn_bytes(held, 2, 14) == 14 * 3 * 6144 * 2048 * 2
     assert shapes.moe_ffn_flops(held, 32) == 2 * 32 * 3 * 6144 * 2048
+
+
+def test_the_hybrid_family_s_shapes_on_the_published_sizes(bench):
+    """``granitemoehybrid``: two kinds of layer. The arithmetic of
+    ISSUE 39: a Mamba mixer 25,847,232, a SwiGLU 50,331,648, a Mamba
+    layer with its two norms 76,182,976, an attention layer 60,821,504,
+    the tied embedding 205,520,896; 3,191,396,096 whole, and nothing is
+    cut. The cache: 8,192 B a token over the 4 attention layers, and
+    76,437,504 B a ROW of state whatever its length."""
+    cell = contract.resolve_cell(bench, "granite4h-decode-saturated")
+    shapes = contract.load_family(cell["family_dir"], "shapes")
+    model = cell["config"]["model"]
+    assert shapes.param_count(model) == 3_191_396_096
+    one = {**model, "layer_types": ["mamba"], "num_hidden_layers": 1}
+    two = {**model, "layer_types": ["mamba"] * 2, "num_hidden_layers": 2}
+    assert shapes.param_count(two) - shapes.param_count(one) == 76_182_976
+    att = {**model, "layer_types": ["mamba", "attention"]}
+    assert shapes.param_count(att) - shapes.param_count(one) == 60_821_504
+    assert shapes.attn_calls_per_step(model) == 4
+    assert shapes.kv_bytes_per_token(model, 2) == 8_192
+    assert shapes.state_bytes_per_row(model) == 76_437_504
+    assert shapes.state_bytes_per_row(model) == 36 * (2_097_152 + 26_112)
+    # a decode step at 64 rows reads and writes every row's state once:
+    # 9.66 GB beside 6.38 GB of weights and 0.47 GB of K/V at 900-token
+    # contexts: 58 % of the step's bytes
+    state = shapes.ssm_update_bytes(model, 64)
+    assert state == 64 * 36 * 2 * 128 * 4096 * 4 and round(state / 1e9,
+                                                           2) == 9.66
+    full = shapes.decode_step_bytes(model, 2, 2, 64, 64 * 900)
+    assert round(full / 1e9, 1) == 16.5 and 0.57 < state / full < 0.6
+    assert full == (shapes.matmul_params(model) * 2 + 8_192 * 64 * 900
+                    + state)
+    assert shapes.decode_attn_bytes(model, 2, 64, 1e3) == 8_192e3
+    assert shapes.decode_attn_flops(model, 64, 1) == 4 * 4 * 32 * 64
+    assert shapes.DECODE_ATTN == contract.load_family(
+        os.path.join(REPO, "benchmark", "families", "llama"),
+        "shapes").DECODE_ATTN
+
+
+def test_the_hybrid_configuration_is_the_catalog_s_row(bench):
+    """``granite-4.0-h-micro-bf16``: every key of the catalog's
+    ``config`` under the same key but the context; nothing else is cut
+    (all 40 layers, every width, the whole vocabulary); what the file
+    assumes, the deployment and the server's sizes are stated, and the
+    program's own configuration of that name has the same sizes."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    entry, doc = next(x for x in _configs(bench)
+                      if x[0]["name"] == "granite-4.0-h-micro-bf16")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == entry["source"])
+    assert row["name"] == "granite-4.0-h-micro"
+    differs = {k for k, v in row["config"].items()
+               if k not in doc or doc[k] != v}
+    assert differs == set(entry["reduced"]) == {"max_position_embeddings"}
+    assert doc["published"] == {"max_position_embeddings": 131_072}
+    assert len(doc["layer_types"]) == doc["num_hidden_layers"] == 40
+    assert [i for i, k in enumerate(doc["layer_types"])
+            if k == "attention"] == [5, 15, 25, 35]
+    assert any("state_dtype float32" in a for a in doc["assumed"])
+    assert "float32 recurrent state" in doc["deployment"]
+    ex = doc["server"]["executor"]
+    assert ex["max_batch_size"] == 64 and ex["prefill_buckets"] == [512]
+    assert ex["prefill_batch"] == 1 and ex["decode_chunk"] == 16
+    assert ex["kv_pages"] * ex["page_size"] >= 64 * 1536
+    assert set(doc["server_why"]) >= {"max_batch_size", "page_size",
+                                      "kv_pages", "prefill_batch",
+                                      "mixed_batch"}
+    from llmq_tpu.models import get_config, granitemoehybrid as gm
+    cfg = get_config("granite-4.0-h-micro")
+    assert list(cfg.layer_types) == doc["layer_types"]
+    assert gm.param_count_analytic(cfg) == 3_191_396_096
+    assert (cfg.dim, cfg.ffn_dim, cfg.vocab_size) == (
+        doc["hidden_size"], doc["shared_intermediate_size"],
+        doc["vocab_size"])
+    assert (cfg.embedding_multiplier, cfg.residual_multiplier,
+            cfg.attention_multiplier, cfg.logits_scaling) == (
+        doc["embedding_multiplier"], doc["residual_multiplier"],
+        doc["attention_multiplier"], doc["logits_scaling"])
+
+
+def test_the_hybrid_family_s_tolerance_is_what_its_judge_reads(bench):
+    """``tolerance``: the harness's two keys, the family's two limits on
+    the decode positions it judges for itself — the worst position
+    (``decode_rms``) and the GROWTH of the error over the judged steps
+    (``decode_growth``: the last quarter's mean over the first's) —,
+    from how many tokens on it judges, and why. Under its numbers the
+    served path's readings on the chip pass (a level of 0.0005 at every
+    step, a worst position of 0.00066), the bfloat16-state control
+    (its error carried from token to token: 0.00052 rising to 0.00066,
+    a worst position of 0.0009) is refused by the GROWTH — its worst
+    position is under ``decode_rms`` — and so is a wrong program."""
+    import numpy as np
+    cell = contract.resolve_cell(bench, "granite4h-decode-saturated")
+    reference = contract.load_family(cell["family_dir"], "reference")
+    adapter_steps = 384
+    tol = cell["config"]["tolerance"]
+    assert set(tol) == {"rms", "max", "decode_rms", "decode_growth",
+                        "min_positions", "why"}
+    assert "PLACEHOLDER" not in tol["why"] and len(tol["why"]) > 200
+    bucket = min(cell["config"]["server"]["executor"]["prefill_buckets"])
+    assert bucket // 3 + 3 < tol["min_positions"] <= bucket - 5 + 3
+    # the judged steps leave a prompt of two slices before them
+    assert 2 <= bucket - 5 + 3 - adapter_steps <= bucket
+    ref = np.zeros((adapter_steps, 16), np.float32)
+    level = np.full(adapter_steps, 5.0e-4)
+    level[200] = 6.6e-4
+    got = reference.judge(ref + level[:, None], ref, tol)
+    assert got["ok"] and got["over"] == [] and got["positions"] == 384
+    assert got["growth"] == pytest.approx(1.0) and got["rms"] < tol[
+        "decode_rms"]
+    control = np.linspace(5.2e-4, 6.6e-4, adapter_steps)
+    control[300] = 9.0e-4
+    got = reference.judge(ref + control[:, None], ref, tol)
+    assert not got["ok"] and got["over"] == ["decode_growth"], got
+    assert 1.15 < got["growth"] < 1.3
+    wrong = level.copy()
+    wrong[17] = 0.2                       # one position of other logits
+    got = reference.judge(ref + wrong[:, None], ref, tol)
+    assert not got["ok"] and "decode_rms" in got["over"]
+    assert 1.0 < tol["decode_growth"] < 1.15 and tol["rms"] < 0.01
 
 
 def test_the_tolerance_is_what_the_family_s_judge_reads(bench):
@@ -281,7 +409,8 @@ def test_the_harness_names_no_family():
         assert not hits, (path, hits)
 
 
-@pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash"])
+@pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
+                                    "granitemoehybrid"])
 def test_who_imports_what_in_a_family(family):
     """``shapes.py`` is standard library alone (the parent and the
     readers import it); ``reference.py`` imports neither the program

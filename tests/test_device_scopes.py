@@ -26,7 +26,11 @@ from benchmark.harness import scopes as reader  # noqa: E402
 
 MODULES = {"embed", "qkv", "kv_write", "attn", "attn_out", "mlp", "head"}
 ROUTED = {"moe_route", "moe_experts", "moe_combine"}
+#: a state-space layer's: the convolution's window step, the decode
+#: update, the chunked scan of prompt tokens
+SSM = {"ssm_conv", "ssm_update", "ssm_scan"}
 FAMILIES = {
+    "granitemoehybrid": MODULES | SSM,
     "llama": MODULES,
     "llama-w8kv8": MODULES | {"act_quant"},
     "deepseek_v3": MODULES | ROUTED | {"latent_prefill_attention"},
@@ -50,6 +54,10 @@ def _tiny(family):
         from llmq_tpu.models import deepseek_v3 as ds
         cfg = ds.deepseek_v3_tiny(dtype=jnp.float32, max_seq_len=128)
         return cfg, ds.init_params(jax.random.PRNGKey(31), cfg), {}
+    if family == "granitemoehybrid":
+        from llmq_tpu.models import granitemoehybrid as gm
+        cfg = gm.granite4h_tiny(dtype=jnp.float32, max_seq_len=128)
+        return cfg, gm.init_params(jax.random.PRNGKey(39), cfg), {}
     from llmq_tpu.models import longcat_flash as lf
     cfg = lf.longcat_flash_tiny(dtype=jnp.float32, max_seq_len=128,
                                 held_experts=(8, 16))
@@ -73,7 +81,12 @@ def _jobs(ex):
 
     abstract = lambda tree: jax.tree.map(  # noqa: E731
         lambda x: sds(x.shape, x.dtype), tree)
-    p, c, key = abstract(ex.params), abstract(ex.cache), sds((2,), jnp.uint32)
+    # ``_pool``: the pages and, of a family that has one, the row state
+    # beside them; such a family's programs take their prompt chunks'
+    # batch rows last
+    p, c, key = abstract(ex.params), abstract(ex._pool), sds((2,), jnp.uint32)
+    rows = ((lambda n: (sds((n,), jnp.int32),))
+            if ex.row_state is not None else (lambda n: ()))
     B, MP = ex.spec.batch_size, ex.spec.max_pages_per_seq
     N, T = ex.prefill_batch, ex.prefill_buckets[0]
     S, TS = ex.mixed_prefill_slices, ex.mixed_slice_tokens
@@ -83,12 +96,12 @@ def _jobs(ex):
     return [
         (f"prefill_multi_b{T}", ex._prefill_multi,
          (p, c, sds((N, T), i32), sds((N, T), i32), sds((N,), i32),
-          sds((N, MP), i32), sds((N,), f32), key)),
+          sds((N, MP), i32), sds((N,), f32), key) + rows(N)),
         ("decode_chunk", ex._decode_chunk, chunk + (key,)),
         ("mixed_chunk", ex._mixed_chunk,
          chunk + (sds((S * TS,), i32), sds((S * TS,), i32), sds((S,), i32),
                   sds((S + 1,), i32), sds((S, MP), i32), sds((S,), f32),
-                  key)),
+                  key) + rows(S)),
     ]
 
 
@@ -136,7 +149,8 @@ def test_a_name_outside_the_vocabulary_is_refused():
     assert len(set(SCOPES)) == len(SCOPES)
     # short: they are stored in every instruction's metadata
     assert max(map(len, SCOPES)) <= len("latent_prefill_attention")
-    assert sum(map(len, SCOPES)) < 160
+    assert sum(map(len, SCOPES)) < 200
+    assert SSM <= set(SCOPES)
 
 
 def test_no_scope_string_outside_the_vocabulary():
@@ -199,9 +213,12 @@ def test_every_module_of_the_family_is_named(compiled):
         # here it is XLA's scatter, under ``kv_write`` or ``attn``
         lacks = want - got - {"kv_write"}
         if prog == "decode_chunk":
-            lacks -= {"latent_prefill_attention"}
+            lacks -= {"latent_prefill_attention", "ssm_scan"}
+        if prog.startswith("prefill"):
+            lacks -= {"ssm_update"}
         assert not lacks, (prog, sorted(lacks))
-        others = (ROUTED | {"act_quant", "latent_prefill_attention"}) - want
+        others = (ROUTED | SSM
+                  | {"act_quant", "latent_prefill_attention"}) - want
         assert not (got & others), (prog, sorted(got & others))
     assert "kv_write" in {c for p in _paths(text["mixed_chunk"])
                           for c in p.split("/")}

@@ -438,3 +438,108 @@ def test_the_tight_mixed_step_copies_no_stacked_matrix_for_v5e(
                                       T=512, pages=512, page=16)
     assert not _whole_copies(compiled, params)
     assert compiled.as_text().count(" while(") >= cfg.n_layers
+
+
+# -- the row state of a hybrid family (granite-4.0-h-micro's sizes) ------------
+
+
+def _state_leaf(one_chip, rows=65):
+    return jax.ShapeDtypeStruct((36, rows, 128, 4096), jnp.float32,
+                                sharding=one_chip)
+
+
+def test_the_state_update_kernel_compiles_for_v5e(one_chip):
+    """``ops/pallas/ssm_update.py`` at the served sizes: 64 rows of a
+    (128, 4,096) float32 state in a leaf of 36 layers and 65 rows (the
+    last nobody's), a (row, 1,024 lanes) a grid step, the leaf aliased
+    in and out: 4.9 GB of arguments, no temporary of a layer's size."""
+    from llmq_tpu.ops.pallas.ssm_update import ssm_update_pallas
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(pool, decay, dtx, bm, cm, active):
+        return ssm_update_pallas(pool, 7, decay, dtx, bm, cm, active)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        _state_leaf(one_chip), arg(64, 4096), arg(64, 4096), arg(64, 128),
+        arg(64, 128), arg(64, dtype=jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert mem.alias_size_in_bytes >= 36 * 65 * 128 * 4096 * 4
+    assert mem.temp_size_in_bytes < 16e6, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("which", ["read", "write"])
+def test_the_state_rows_copies_compile_for_v5e(one_chip, which):
+    """The two copies a prompt slice makes of its row's state, as
+    Mosaic calls (so that the leaf keeps its layout: XLA's gather and
+    update made the whole leaf follow the scan's): the write in
+    place."""
+    from llmq_tpu.ops.pallas import ssm_update as su
+
+    rows = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    new = jax.ShapeDtypeStruct((2, 128, 4096), jnp.float32,
+                               sharding=one_chip)
+    if which == "read":
+        compiled = jax.jit(lambda p, r: su.state_rows_read(p, 3, r)).lower(
+            _state_leaf(one_chip), rows).compile()
+    else:
+        compiled = jax.jit(lambda p, r, n: su.state_rows_write(p, 3, r, n),
+                           donate_argnums=(0,)).lower(
+            _state_leaf(one_chip), rows, new).compile()
+        assert compiled.memory_analysis().alias_size_in_bytes >= (
+            36 * 65 * 128 * 4096 * 4)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+
+
+def test_the_hybrid_prefill_copies_no_row_state_for_v5e(one_chip,
+                                                        monkeypatch):
+    """``granite-4.0-h-micro``'s prefill program at one period of its
+    layers (9 Mamba, 1 attention), every width as published, 64 rows and
+    a 512-token bucket: the page pool and both row-state leaves go in
+    and come out in place and are NEVER copied. The program has no
+    update kernel to hold the state's layout, and with XLA's gather and
+    ``dynamic_update_slice`` around the scan it copied the whole state
+    leaf in and out (4.9 GB each way at full depth, more than the chip
+    has left), and the convolution's window, kept (3, 4,352) a row, as
+    often as a layer touched it (PERF.md section 6, PR 39)."""
+    from llmq_tpu.models import granitemoehybrid as gm
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    types = tuple(gm.ATTENTION if i == 5 else gm.MAMBA for i in range(10))
+    cfg = gm.serving_config(gm.granite_4_0_h_micro(layer_types=types,
+                                                   max_seq_len=2048))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: gm.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: gm.init_kv_pages(cfg, 832, 128)))
+    state = on_chip(jax.eval_shape(lambda: gm.init_row_state(cfg, 64)))
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, cache, state, tokens, positions, lengths, bts, rows):
+        return gm.forward_prefill.__wrapped__(
+            params, cfg, tokens, positions, lengths, cache, bts,
+            last_only=True, row_state=state, rows=rows)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, cache, state, arg(1, 512), arg(1, 512), arg(1), arg(1, 16),
+        arg(1)).compile()
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((cache, state)))
+    assert not _whole_copies(compiled, (cache, state))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
+    # the scan's two copies of a row's state a Mamba layer, the prefill
+    # attention and its write
+    assert compiled.as_text().count("tpu_custom_call") == 2 * 9 + 2
