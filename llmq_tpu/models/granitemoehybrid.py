@@ -65,8 +65,9 @@ from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rows import (grid_positions, grid_to_rows, live_rows,
                                row_tile, rows_to_grid, tile_rows,
                                worth_a_loop)
-from llmq_tpu.ops.ssm import (conv_slices, conv_step, rows_read, rows_write,
-                              ssm_scan, ssm_update_layer, update_route)
+from llmq_tpu.ops.ssm import (conv_slices, conv_step, decode_walk, rows_read,
+                              rows_write, ssm_scan, ssm_update_layer,
+                              update_route)
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -493,10 +494,11 @@ def _split(xbc, dt, lp: Params, i, cfg: GraniteHybridConfig):
     return x, xbc[..., I:I + N], xbc[..., I + N:], dt, a
 
 
-def _mamba_decode(h, lp: Params, l, i, rs: RowState, active,
+def _mamba_decode(h, lp: Params, l, i, rs: RowState, active, walk,
                   cfg: GraniteHybridConfig):
     """One token a row through Mamba layer ``i``; rows that are not
-    ``active`` keep their window and their state."""
+    ``active`` keep their window and their state (``walk``: the step's
+    ``decode_walk`` of ``active``, one for all its layers)."""
     z, xbc, dt = _mamba_in(h, lp, l, i, cfg)
     ssm, conv = rs["ssm"], rs["conv"]
     with scope("ssm_conv"):
@@ -508,8 +510,7 @@ def _mamba_decode(h, lp: Params, l, i, rs: RowState, active,
     with scope("ssm_update"):
         x, bm, cm, dt, a = _split(xbc, dt, lp, i, cfg)
         y, ssm = ssm_update_layer(ssm, i, x, dt, a, bm, cm, lp["d_skip"][i],
-                                  active,
-                                  enabled=cfg.pallas)
+                                  active, walk=walk, enabled=cfg.pallas)
     h = _mamba_out(h, y.reshape(h.shape[0], -1), z, lp, i, cfg)
     return h, {"ssm": ssm, "conv": conv}
 
@@ -613,11 +614,11 @@ def _decode_geometry(positions, block_tables, page_sz, active):
 
 
 def _decode_layer(h, lp: Params, l, kind, i, k_pool, v_pool, rs, geom,
-                  block_tables, active, cfg: GraniteHybridConfig):
+                  block_tables, active, walk, cfg: GraniteHybridConfig):
     """One decode token a row through layer ``l`` (the decode program's
     layer and the decode rows' half of the mixed step's)."""
     if kind == MAMBA:
-        h, rs = _mamba_decode(h, lp, l, i, rs, active, cfg)
+        h, rs = _mamba_decode(h, lp, l, i, rs, active, walk, cfg)
     else:
         page_of, slot_of, seq_lens = geom
         q, k, v = _qkv(h, lp, l, i, cfg)
@@ -645,12 +646,13 @@ def forward_decode(params: Params, cfg: GraniteHybridConfig,
     h = _embed(params, cfg, tokens)
     geom = _decode_geometry(positions, block_tables,
                             kv_cache["k"].shape[2], active)
+    walk = decode_walk(live)
     lp = params["layers"]
 
     def layer(carry, l, kind, i):
         h, k_pool, v_pool, rs = carry
         return _decode_layer(h, lp, l, kind, i, k_pool, v_pool, rs, geom,
-                             block_tables, live, cfg)
+                             block_tables, live, walk, cfg)
 
     h, k_pool, v_pool, row_state = _run_layers(
         cfg, layer, (h, kv_cache["k"], kv_cache["v"], row_state), True)
@@ -720,6 +722,7 @@ def forward_mixed(params: Params, cfg: GraniteHybridConfig,
         h_d = _embed(params, cfg, dec_tokens)
         geom = _decode_geometry(dec_positions, dec_block_tables,
                                 kv_cache["k"].shape[2], dec_active)
+        walk = decode_walk(live)
     with scope("slices"):
         h_p = _embed(params, cfg, pf_tokens)
         pf_grid_pos, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
@@ -773,7 +776,7 @@ def forward_mixed(params: Params, cfg: GraniteHybridConfig,
         with scope("decode_rows"):
             h_d, k_pool, v_pool, rs = _decode_layer(
                 h_d, lp, l, kind, i, k_pool, v_pool, rs, geom,
-                dec_block_tables, live, cfg)
+                dec_block_tables, live, walk, cfg)
         return h_p, h_d, k_pool, v_pool, rs
 
     h_p, h_d, k_pool, v_pool, row_state = _run_layers(

@@ -184,16 +184,27 @@ def _leaf_route(pool: jnp.ndarray, enabled: bool) -> Tuple[bool, bool]:
                         enabled=enabled)
 
 
+def decode_walk(active: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The in-place kernel's walk of a decode step's rows: ``(rows (B,)
+    int32, n_live)`` — the rows that are ``active`` first, in ascending
+    order (what follows them is never read). The same for every layer
+    of a step, so a program makes it once."""
+    rows = jnp.nonzero(active, size=active.shape[0], fill_value=0)[0]
+    return rows.astype(jnp.int32), jnp.sum(active, dtype=jnp.int32)
+
+
 def ssm_update_layer(pool: jnp.ndarray, layer, x: jnp.ndarray,
                      dt: jnp.ndarray, a: jnp.ndarray, bm: jnp.ndarray,
                      cm: jnp.ndarray, d: jnp.ndarray, active: jnp.ndarray,
-                     *, enabled: bool = True
+                     *, walk=None, enabled: bool = True
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """:func:`ssm_update` on the first B rows of layer ``layer`` of the
     stacked leaf ``pool`` (L, R, N, H*P), R >= B: the in-place kernel
-    where :func:`update_route` takes it, else XLA's fusion of the same
-    and a ``dynamic_update_slice`` of the rows. Returns ``(y (B, H, P)
-    float32, pool)``."""
+    where :func:`update_route` takes it (over ``walk``, the step's
+    :func:`decode_walk`; made here if a caller has none), else XLA's
+    fusion of the same and a ``dynamic_update_slice`` of the rows.
+    Returns ``(y (B, H, P) float32, pool)``: a row that is not
+    ``active`` keeps its state, and its ``y`` is of no use."""
     B, H, P = x.shape
     use_kernel, interpret = _leaf_route(pool, enabled)
     if not use_kernel:
@@ -201,7 +212,9 @@ def ssm_update_layer(pool: jnp.ndarray, layer, x: jnp.ndarray,
         return y, pool.at[layer, :B].set(new)
     from llmq_tpu.ops.pallas.ssm_update import ssm_update_pallas
     xf, decay, dtx = _lane_inputs(x, dt, a)
-    y, pool = ssm_update_pallas(pool, layer, decay, dtx, bm, cm, active,
+    if walk is None:
+        walk = decode_walk(active)
+    y, pool = ssm_update_pallas(pool, layer, decay, dtx, bm, cm, *walk,
                                 interpret=interpret)
     return (y.reshape(B, H, P)
             + d.astype(jnp.float32)[None, :, None] * xf), pool

@@ -543,12 +543,17 @@ def bench_ssm(args, doc) -> None:
     (``ops/pallas/ssm_update.py``) and through XLA's fusion of
     ``ops/ssm.ssm_update``, each over the stacked leaf with the pool
     donated, and the share of the LIVE rows' state read once and written
-    once at 819 GB/s (the yardstick of ``ssm_update_roofline``)."""
+    once at 819 GB/s (the yardstick of ``ssm_update_roofline``), beside
+    the block a step of the kernel's walk holds and how many it takes.
+    ``--lanes N`` times the kernel again with its shape rule's VMEM
+    budget set so that it picks N-lane blocks (the sweep behind the
+    rule: PERF.md section 6, PR 40)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from llmq_tpu.ops import ssm
+    from llmq_tpu.ops.pallas import ssm_update as su
 
     ex = doc["server"]["executor"]
     B = ex["max_batch_size"]
@@ -594,34 +599,47 @@ def bench_ssm(args, doc) -> None:
           f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
           flush=True)
     n = 1 if args.rehearse else 10
+
+    def timed(enabled, active):
+        run = make(enabled)
+        # (one layer's draw tiled: a draw of the whole 4.9 GB leaf
+        # needs as much again for its bits)
+        pool = jnp.tile(jax.random.normal(
+            ks[5], (1, B + 1, N, H * P), jnp.float32), (L, 1, 1, 1))
+        call = (active, x, dt, a, bm, cm, d)
+        pool, y = run(pool, *call)
+        first = np.asarray(y)
+        t0 = time.perf_counter()
+        for _ in range(n):
+            pool, y = run(pool, *call)
+        jax.block_until_ready(y)
+        return (time.perf_counter() - t0) / (n * reps) * 1e6, first
+
     results = []
-    for spec in args.lens:
+    W = H * P
+    before_pr40 = not hasattr(su, "STATE_VMEM_BYTES")   # --tree <a parent>
+    xla = {}            # rows -> (us, y): XLA's knows nothing of the block
+    for lanes, spec in ((l, s) for l in (args.lanes or [0])
+                        for s in args.lens):
         rows = min(B, int(spec.split("x")[0]))
         active = jnp.arange(B) < rows
-        rec = {"rows": rows}
-        for name, enabled in (("kernel", True), ("xla", False)):
-            run = make(enabled)
-            # (one layer's draw tiled: a draw of the whole 4.9 GB leaf
-            # needs as much again for its bits)
-            pool = jnp.tile(jax.random.normal(
-                ks[5], (1, B + 1, N, H * P), jnp.float32), (L, 1, 1, 1))
-            call = (active, x, dt, a, bm, cm, d)
-            pool, y = run(pool, *call)
-            rec[name + "_y"] = np.asarray(y)
-            t0 = time.perf_counter()
-            for _ in range(n):
-                pool, y = run(pool, *call)
-            jax.block_until_ready(y)
-            rec[name + "_us"] = (time.perf_counter() - t0) / (n * reps) * 1e6
-            del pool
+        if lanes:
+            su.STATE_VMEM_BYTES = su.SLOTS * N * lanes * 4
+        block = su._lanes(W) if before_pr40 else su._lanes(N, W)
+        rec = {"rows": rows, "block": [N, block],
+               "steps": (B if before_pr40 else rows) * (W // block)}
+        rec["kernel_us"], kernel_y = timed(True, active)
+        if rows not in xla:
+            xla[rows] = timed(False, active)
+        rec["xla_us"], xla_y = xla[rows]
         least = rows * 2 * N * H * P * 4 / PEAK_BYTES_PER_S * 1e6
-        gap = float(np.abs(rec.pop("kernel_y")[:rows]
-                           - rec.pop("xla_y")[:rows]).max())
+        gap = float(np.abs(kernel_y[:rows] - xla_y[:rows]).max())
         rec.update(least_us=least, max_abs_gap=gap,
                    kernel_roofline_pct=100 * least / rec["kernel_us"],
                    xla_roofline_pct=100 * least / rec["xla_us"])
         results.append(rec)
-        print(f"  rows {rows:3d} of {B}: kernel {rec['kernel_us']:,.1f} "
+        print(f"  rows {rows:3d} of {B}: {rec['steps']} steps of a "
+              f"({N}, {block}) block: kernel {rec['kernel_us']:,.1f} "
               f"us/call ({rec['kernel_roofline_pct']:.1f} % of the live "
               f"rows' bytes at peak), xla {rec['xla_us']:,.1f} "
               f"({rec['xla_roofline_pct']:.1f} %); least {least:,.1f}; "
@@ -655,6 +673,9 @@ def main() -> None:
     ap.add_argument("--pages-per-chunk", type=int, default=0)
     ap.add_argument("--spread", action="store_true")
     ap.add_argument("--shuffle", action="store_true")
+    ap.add_argument("--lanes", action="append", type=int, default=[],
+                    help="granitemoehybrid: time the update kernel at "
+                         "this many lanes a step of its walk as well")
     ap.add_argument("--out", default="")
     ap.add_argument("--rehearse", action="store_true",
                     help="off the chip: interpret mode, 2 layers, 2 "

@@ -290,15 +290,57 @@ def test_the_convolution_s_window_ends_at_the_last_valid_input():
 # -- (c) the update kernel ----------------------------------------------------
 
 
-def test_the_update_kernel_is_the_plain_update_in_place(monkeypatch):
+#: which rows of the batch decode -> (``active``, the leaf's rows beyond
+#: the batch): eight rows and the row that is nobody's, and PR 39's
+#: case (three of five, a leaf of the batch's size).
+_LIVE = {
+    "all": ([True] * 8, 1),
+    "none": ([False] * 8, 1),
+    "the-first": ([True] + [False] * 7, 1),
+    "the-last": ([False] * 7 + [True], 1),
+    "alternating": ([False, True] * 4, 1),
+    "five-of-eight": ([True, True, False, True, False, False, True, True],
+                      1),
+    "three-of-five-no-spare-row": ([True, False, True, True, False], 0),
+}
+#: a step's block of the (16, 2,048) row -> the VMEM its three slots
+#: may take for ``_lanes`` to pick it
+_BLOCKS = {"whole-row": (2048, None), "1024-lanes": (1024, 3 * 16 * 1024 * 4),
+           "128-lanes": (128, 3 * 16 * 128 * 4)}
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.uint32)
+
+
+@pytest.mark.parametrize("block", sorted(_BLOCKS))
+@pytest.mark.parametrize("live", sorted(_LIVE))
+def test_the_update_kernel_is_the_plain_update_in_place(monkeypatch, live,
+                                                        block):
     """``ops/pallas/ssm_update.py`` in interpret mode against
-    ``ops/ssm.ssm_update`` on layer 1 of a stacked leaf of three: the
-    active rows' state and output are the plain update's, the rows that
-    do not decode and the other layers come back bit for bit."""
+    ``ops/ssm.ssm_update`` on layer 1 of a stacked leaf of three, over
+    which rows decode and every block the shape rule can pick: the live
+    rows' state and output are the plain update's (to an ulp here: XLA's
+    CPU backend contracts the update's multiply-add and the interpreter
+    does not; on the chip the two are bit-equal,
+    ``scripts/bench_kernel.py``); every row that does not decode, the
+    row that is nobody's and the other layers come back bit for bit;
+    ``y`` of a row that does not decode is the skip term alone (the
+    kernel's part exactly zero). The row that is nobody's holds NaNs,
+    so a live row that read it would show."""
+    from llmq_tpu.ops.pallas import ssm_update as su
+
     monkeypatch.setenv("LLMQ_PALLAS", "interpret")
-    L, B, N, H, P = 3, 5, 16, 4, 64
+    lanes, budget = _BLOCKS[block]
+    if budget is not None:
+        monkeypatch.setattr(su, "STATE_VMEM_BYTES", budget)
+    active, spare = _LIVE[live]
+    active = np.asarray(active)
+    L, B, N, H, P = 3, len(active), 16, 16, 128
+    assert su._lanes(N, H * P) == lanes
     rng = np.random.default_rng(5)
-    pool = rng.standard_normal((L, B, N, H * P)).astype(np.float32)
+    pool = rng.standard_normal((L, B + spare, N, H * P)).astype(np.float32)
+    pool[:, B:] = np.nan
     x = rng.standard_normal((B, H, P)).astype(np.float32)
     dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.5), (B, H))
                 ).astype(np.float32)
@@ -306,22 +348,41 @@ def test_the_update_kernel_is_the_plain_update_in_place(monkeypatch):
     bm, cm = (rng.standard_normal((B, N)).astype(np.float32)
               for _ in range(2))
     d = rng.standard_normal(H).astype(np.float32)
-    active = np.asarray([True, False, True, True, False])
     assert ssm.update_route(N, H * P, jnp.float32) == (True, True)
+    rows, n_live = ssm.decode_walk(jnp.asarray(active))
+    alive = np.flatnonzero(active)
+    assert rows.shape == (B,) and int(n_live) == len(alive)
+    assert list(np.asarray(rows)[:len(alive)]) == list(alive)
     args = [jnp.asarray(v) for v in (x, dt, a, bm, cm, d, active)]
     y, got = ssm.ssm_update_layer(jnp.asarray(pool), 1, *args)
-    want_y, want = ssm.ssm_update(jnp.asarray(pool[1]), *args)
-    got = np.asarray(got)
-    np.testing.assert_allclose(got[1], np.asarray(want), rtol=1e-6,
-                               atol=1e-6)
-    np.testing.assert_allclose(np.asarray(y)[active],
-                               np.asarray(want_y)[active], rtol=1e-5,
-                               atol=1e-5)
-    assert np.array_equal(got[1][~active], pool[1][~active])
-    assert np.array_equal(got[[0, 2]], pool[[0, 2]])
-    # a state that is not float32, or not of whole tiles, is XLA's
-    assert ssm.update_route(N, H * P, jnp.bfloat16) == (False, False)
-    assert ssm.update_route(N + 4, H * P, jnp.float32) == (False, False)
+    want_y, want = ssm.ssm_update(jnp.asarray(pool[1, :B]), *args)
+    y, got = np.asarray(y), np.asarray(got)
+    np.testing.assert_allclose(got[1, :B][active], np.asarray(want)[active],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(y[active], np.asarray(want_y)[active],
+                               rtol=1e-5, atol=1e-5)
+    assert np.array_equal(_bits(y[~active]),
+                          _bits((d[None, :, None] * x)[~active]))
+    assert np.array_equal(_bits(got[1, :B][~active]),
+                          _bits(pool[1, :B][~active]))
+    assert np.array_equal(_bits(got[1, B:]), _bits(pool[1, B:]))
+    assert np.array_equal(_bits(got[[0, 2]]), _bits(pool[[0, 2]]))
+
+
+def test_the_update_route_is_for_a_float32_state_of_whole_tiles():
+    """A state that is not float32, or not of whole (8, 128) tiles, is
+    XLA's; and the block of a grid step follows the row's shape alone:
+    whole rows at the served (128, 4,096), the widest block that fits
+    for a row too large for that."""
+    from llmq_tpu.ops.pallas import ssm_update as su
+
+    assert ssm.update_route(16, 256, jnp.bfloat16) == (False, False)
+    assert ssm.update_route(20, 256, jnp.float32) == (False, False)
+    assert su._lanes(128, 4096) == 4096
+    assert su._lanes(128, 8192) == 4096
+    assert su._lanes(1024, 31 * 128) == 128
+    assert (su.SLOTS * 128 * 4096 * 4 <= su.STATE_VMEM_BYTES
+            < su.VMEM_LIMIT_BYTES)
 
 
 def test_the_kernel_serves_the_decode_step(tiny, monkeypatch):

@@ -207,7 +207,7 @@ class TestPlaneStateMachine:
         plane = mk_plane()
         plane.demote("c", [3, 5], list(range(16)), 16, None)
         assert wait_until(lambda: plane.counts()["host"] == 1)
-        status, entry = plane.claim("c")
+        status, entry = claim_ready(plane, "c")
         assert status == "ready" and entry.tier == "host"
         leaves = plane.unpack(entry)
         # Content fidelity: page 3's payload is all-3.0, page 5 all-5.0.
@@ -296,7 +296,7 @@ class TestPlaneStateMachine:
         plane = mk_plane()
         plane.demote("c", [3], list(range(8)), 8, None)
         assert wait_until(lambda: plane.counts()["host"] == 1)
-        status, entry = plane.claim("c")
+        status, entry = claim_ready(plane, "c")
         assert status == "ready"
         plane.restash("c", entry)
         status2, entry2 = plane.claim("c")

@@ -448,25 +448,37 @@ def _state_leaf(one_chip, rows=65):
                                 sharding=one_chip)
 
 
-def test_the_state_update_kernel_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("width", [4096, 8192])
+def test_the_state_update_kernel_compiles_for_v5e(one_chip, width):
     """``ops/pallas/ssm_update.py`` at the served sizes: 64 rows of a
     (128, 4,096) float32 state in a leaf of 36 layers and 65 rows (the
-    last nobody's), a (row, 1,024 lanes) a grid step, the leaf aliased
-    in and out: 4.9 GB of arguments, no temporary of a layer's size."""
-    from llmq_tpu.ops.pallas.ssm_update import ssm_update_pallas
+    last nobody's), a WHOLE row a step of its walk (three slots of
+    2 MiB, the small operands whole beside them: inside the VMEM limit
+    the call states, which the compiler would refuse otherwise), the
+    live rows named by the scalar prefetch, the leaf aliased in and
+    out: 4.9 GB of arguments, no temporary of a layer's size. And at
+    twice the row, where the shape rule takes half a row a step (a
+    leaf of 4 layers)."""
+    from llmq_tpu.ops.pallas import ssm_update as su
 
     def arg(*shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def step(pool, decay, dtx, bm, cm, active):
-        return ssm_update_pallas(pool, 7, decay, dtx, bm, cm, active)
+    def step(pool, decay, dtx, bm, cm, rows, n_live):
+        return su.ssm_update_pallas(pool, 3, decay, dtx, bm, cm, rows,
+                                    n_live)
 
+    layers = 36 if width == 4096 else 4
+    assert su._lanes(128, width) == 4096
+    assert (su.SLOTS * 128 * 4096 * 4 <= su.STATE_VMEM_BYTES
+            < su.VMEM_LIMIT_BYTES)
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
-        _state_leaf(one_chip), arg(64, 4096), arg(64, 4096), arg(64, 128),
-        arg(64, 128), arg(64, dtype=jnp.bool_)).compile()
+        arg(layers, 65, 128, width), arg(64, width), arg(64, width),
+        arg(64, 128), arg(64, 128), arg(64, dtype=jnp.int32),
+        arg(dtype=jnp.int32)).compile()
     mem = compiled.memory_analysis()
     assert compiled.as_text().count("tpu_custom_call") == 1
-    assert mem.alias_size_in_bytes >= 36 * 65 * 128 * 4096 * 4
+    assert mem.alias_size_in_bytes >= layers * 65 * 128 * width * 4
     assert mem.temp_size_in_bytes < 16e6, mem.temp_size_in_bytes
 
 
@@ -543,3 +555,64 @@ def test_the_hybrid_prefill_copies_no_row_state_for_v5e(one_chip,
     # the scan's two copies of a row's state a Mamba layer, the prefill
     # attention and its write
     assert compiled.as_text().count("tpu_custom_call") == 2 * 9 + 2
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_the_hybrid_decode_rows_copy_no_state_for_v5e(one_chip, monkeypatch,
+                                                      program):
+    """``granite-4.0-h-micro``'s decode program and its mixed step (two
+    512-token slices beside the decode rows) at one period of its layers
+    (9 Mamba, 1 attention), every width as published, 64 rows: the page
+    pool and both row-state leaves go in and come out in place, NEITHER
+    the state leaf NOR a K/V pool is ever copied (the update kernel
+    takes the leaf as it lies, a whole row a grid step; in the mixed
+    step the barrier still orders the decode rows' aliased writes behind
+    the slices' reads), and the update is one Mosaic call a Mamba
+    layer."""
+    from llmq_tpu.models import granitemoehybrid as gm
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    types = tuple(gm.ATTENTION if i == 5 else gm.MAMBA for i in range(10))
+    cfg = gm.serving_config(gm.granite_4_0_h_micro(layer_types=types,
+                                                   max_seq_len=2048))
+    B, S, T, mp = 64, 2, 512, 16
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: gm.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: gm.init_kv_pages(cfg, 832, 128)))
+    state = on_chip(jax.eval_shape(lambda: gm.init_row_state(cfg, B)))
+
+    def arg(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, cache, state, tokens, positions, bts, active):
+        return gm.forward_decode.__wrapped__(
+            params, cfg, tokens, positions, cache, bts, active=active,
+            row_state=state)
+
+    def mixed(params, cache, state, tokens, positions, bts, active, *pf):
+        return gm.forward_mixed.__wrapped__(
+            params, cfg, tokens, positions, cache, bts, *pf[:-1],
+            dec_active=active, row_state=state, pf_rows=pf[-1])
+
+    args = [params, cache, state, arg(B), arg(B), arg(B, mp),
+            arg(B, dtype=jnp.bool_)]
+    if program == "mixed":
+        args += [arg(S * T), arg(S * T), arg(S), arg(S + 1), arg(S, mp),
+                 arg(S)]
+    compiled = jax.jit(decode if program == "decode" else mixed,
+                       donate_argnums=(1, 2)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((cache, state)))
+    assert not _whole_copies(compiled, (cache, state))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
+    assert sum("tpu_custom_call" in line and "/ssm_update/" in line
+               for line in compiled.as_text().splitlines()) == 9
