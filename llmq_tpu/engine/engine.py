@@ -755,6 +755,16 @@ class InferenceEngine:
         #: it). ``get_stats()["row_state"]``.
         self._row_state_bytes = int(getattr(
             executor, "row_state_bytes_per_row", 0) or 0)
+        #: A family whose window layers keep their keys in row state
+        #: (``executor.attention_window``): what a decode chunk's rows
+        #: attend to with and without the window, the key chunks the
+        #: window start skipped, and the slabs' tokens inside windows
+        #: against those reserved. ``get_stats()["window"]``.
+        self._window = getattr(executor, "attention_window", None)
+        self.window_counts = {"dispatches": 0, "context_tokens": 0,
+                              "window_tokens": 0, "chunks_visited": 0,
+                              "chunks_skipped": 0, "cache_live_tokens": 0,
+                              "cache_reserved_tokens": 0}
         self.row_state_rebuilds = 0
         self.row_state_declined = {"prefix": 0, "conversation": 0,
                                    "tiering": 0, "disagg": 0}
@@ -2722,6 +2732,8 @@ class InferenceEngine:
             "context_tokens": context_tokens,
             "prefill_tokens": prefill_tokens,
             "slice_tokens": slice_tokens}
+        if self._window and chunk and rows:
+            counts.update(self._window_counts())
         if capture_held():
             counts["pages_live"], counts["tokens_live"] = self._live_kv()
             if self._row_state_bytes:
@@ -2729,6 +2741,32 @@ class InferenceEngine:
                 # and, of a prefill or a mixed chunk, its prompt chunks'
                 counts["state_rows"] = rows + state_rows
         return self._prof.span("engine.dispatch", **counts)
+
+    def _window_counts(self) -> Dict[str, int]:
+        """One window layer's counts at a chunk's first step, by the
+        host's bookkeeping: the decoding rows' contexts bounded by the
+        window (``window_tokens``, beside ``context_tokens``), the key
+        chunks their attention visits and those the window start
+        skipped, and the tokens inside windows that the seated
+        sequences' slabs hold against the tokens all slabs reserve."""
+        w = self._window
+        seated = [s for s in self._slots if s is not None]
+        ctx = np.asarray([s.pos for s in seated if s.prefilled], np.int64)
+        visited, skipped = self.executor.window_chunks(ctx + 1)
+        out = {"window_tokens": int(np.minimum(ctx, w["tokens"]).sum()),
+               "window_chunks": visited, "window_chunks_skipped": skipped,
+               "window_live": int(sum(min(s.pos, w["tokens"])
+                                      for s in seated)),
+               "window_reserved": self.spec.batch_size * w["slab_tokens"]}
+        acc = self.window_counts
+        acc["dispatches"] += 1
+        acc["context_tokens"] += int(ctx.sum())
+        acc["window_tokens"] += out["window_tokens"]
+        acc["chunks_visited"] += visited
+        acc["chunks_skipped"] += skipped
+        acc["cache_live_tokens"] += out["window_live"]
+        acc["cache_reserved_tokens"] += out["window_reserved"]
+        return out
 
     def _chunk_dispatch(self, entry: str, budgets: np.ndarray,
                         context_tokens: int, prefill_tokens: int = 0,
@@ -4351,6 +4389,8 @@ class InferenceEngine:
             # compile-cache state.
             "device": self._telemetry.snapshot(),
         }
+        if self._window:
+            out["window"] = {**self._window, **self.window_counts}
         if self._row_state_bytes:
             out["row_state"] = {
                 "rows": self.spec.batch_size,

@@ -901,11 +901,30 @@ class JaxExecutor:
         #: where the others carry the pages, and take the batch rows
         #: of their prompt chunks as one more operand — else ``None``,
         #: and every program is what it was before there was any.
+        bind = getattr(fam, "bind_cache", None)
+        if bind is not None:
+            # A family whose row state is cut in the pool's pages (a
+            # window layer's slab) is told the page size and the most
+            # tokens one program writes for ONE sequence before it
+            # reads: a prefill bucket, or a mixed step's slice (the
+            # engine packs one slice a sequence a step:
+            # ``engine._pack_prefill_slices``).
+            model_cfg = bind(
+                model_cfg, page_size=page_size,
+                step_tokens=max(max(prefill_buckets or [32, 128, 512]),
+                                int(mixed_slice_tokens)))
         self.row_state = fam.init_row_state(model_cfg, batch_size)
         self.row_state_bytes_per_row = (
             fam.row_state_bytes_per_row(model_cfg)
             if self.row_state is not None else 0)
         has_rows = self.row_state is not None
+        #: ``{"tokens", "layers", "slab_tokens"}`` for a family whose
+        #: attention has window layers that keep their keys in row
+        #: state (``attention_window``), else None: what the engine
+        #: counts a window cache by (``get_stats()["window"]``).
+        window_fn = getattr(fam, "attention_window", None)
+        self.attention_window = (window_fn(model_cfg)
+                                 if window_fn is not None else None)
 
         def forward_prefill(params, cfg, tokens, positions, lengths, cache,
                             bts, last_only, rows=()):
@@ -918,13 +937,27 @@ class JaxExecutor:
                 last_only=last_only, row_state=cache[1], rows=rows[0])
             return last, (pages, state)
 
+        def with_state(fn, args, cache_at, kw, rows_kw):
+            """``fn`` of a family with row state: the cache operand is
+            ``(pages, state)`` and comes back so; the counters, for a
+            family that counts, after it."""
+            pages, state = args[cache_at]
+            if n_stats:
+                kw = dict(kw, stats=True)
+            out = fn(*args[:cache_at], pages, *args[cache_at + 1:],
+                     row_state=state, **kw, **rows_kw)
+            n = len(out) - (3 if n_stats else 2)
+            return (out[:n] + ((out[n], out[n + 1]),)
+                    + ((out[n + 2],) if n_stats else (None,)))
+
         def forward_decode(params, cfg, tok, pos, cache, bts, active=None,
                            acc=None):
             if has_rows:
-                logits, pages, state = fam.forward_decode(
-                    params, cfg, tok, pos, cache[0], bts, active=active,
-                    row_state=cache[1])
-                return logits, (pages, state), None
+                logits, cache, st = with_state(
+                    fam.forward_decode, (params, cfg, tok, pos, cache, bts),
+                    4, {"active": active}, {})
+                return logits, cache, (st if st is None or acc is None
+                                       else acc + st)
             if not n_stats:
                 return fam.forward_decode(params, cfg, tok, pos, cache, bts,
                                           active=active) + (None,)
@@ -934,11 +967,9 @@ class JaxExecutor:
 
         def forward_mixed(*args, dec_active=None, rows=()):
             if has_rows:
-                cache = args[4]
-                dec, pf, pages, state = fam.forward_mixed(
-                    *args[:4], cache[0], *args[5:], dec_active=dec_active,
-                    row_state=cache[1], pf_rows=rows[0])
-                return dec, pf, (pages, state), None
+                return with_state(fam.forward_mixed, args, 4,
+                                  {"dec_active": dec_active},
+                                  {"pf_rows": rows[0]})
             if not n_stats:
                 return fam.forward_mixed(*args, dec_active=dec_active) + (
                     None,)
@@ -991,6 +1022,14 @@ class JaxExecutor:
             1, model_cfg.max_seq_len // page_size)
         self.spec = ExecutorSpec(batch_size, page_size, num_pages,
                                  max_pages_per_seq, eos_id)
+        if self.attention_window is not None:
+            # the chunk the windowed decode kernel visits keys by: what
+            # ``window_chunks`` counts in
+            from llmq_tpu.ops.pallas.fused_decode import chunk_tokens
+            slab = next(iter(self.row_state.values()))
+            self._window_chunk_tokens = chunk_tokens(
+                batch_size, page_size, max_pages_per_seq, slab.shape[3],
+                slab.dtype.itemsize)
         self.chunk_size = max(1, chunk_size)
         self._top_k = top_k
         self._top_p = top_p
@@ -1508,6 +1547,14 @@ class JaxExecutor:
             self.cache = pool
         else:
             self.cache, self.row_state = pool
+
+    def window_chunks(self, seq_lens) -> tuple:
+        """``(visited, skipped)`` key chunks of ONE window layer's
+        decode attention over rows of ``seq_lens``
+        (``ops/pallas/fused_decode.window_chunks``)."""
+        from llmq_tpu.ops.pallas.fused_decode import window_chunks
+        return window_chunks(seq_lens, self._window_chunk_tokens,
+                             self.attention_window["tokens"])
 
     def _rows_arg(self, rows) -> tuple:
         """The batch rows of a program's prompt chunks as its last

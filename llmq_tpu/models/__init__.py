@@ -20,7 +20,11 @@ A family is a module of this package that defines
   batch row, ...)`` that a sequence carries from token to token
   whatever its length (a state-space layer's recurrent state; the
   leaves hold ``batch + 1`` rows, the last nobody's, as page 0 is: an
-  unused slice of a program names it), or
+  unused slice of a program names it; or a window layer's keys and
+  values, a SLAB of pool-shaped pages a batch row: ``models/afmoe.py``,
+  which also defines the optional ``bind_cache(cfg, *, page_size,
+  step_tokens)``, called by the executor before ``init_row_state``, and
+  ``attention_window(cfg)``, what the engine counts such a cache by), or
   ``None`` for a family whose pages are its whole cache, and
   ``row_state_bytes_per_row(cfg)`` (0 then). A family WITH row state
   takes it in every forward function as ``row_state=`` and returns it
@@ -95,6 +99,7 @@ FAMILIES: Dict[str, str] = {
     "deepseek_v3": "llmq_tpu.models.deepseek_v3",
     "longcat_flash": "llmq_tpu.models.longcat_flash",
     "granitemoehybrid": "llmq_tpu.models.granitemoehybrid",
+    "afmoe": "llmq_tpu.models.afmoe",
 }
 
 

@@ -385,6 +385,73 @@ def import_hf_granitemoehybrid(model_dir: str, cfg) -> Params:
     return params
 
 
+def import_hf_afmoe(model_dir: str, cfg) -> Params:
+    """A local Hugging Face ``afmoe`` checkpoint directory (safetensors)
+    into ``models/afmoe.py``'s tree (``param_shapes``): of each routed
+    layer the HELD experts alone (``cfg.held``), of the vocabulary the
+    first ``cfg.vocab_size`` rows, of the layers the first
+    ``cfg.n_layers``. The tensor names are from memory of the public
+    ``modeling_afmoe.py`` (this sandbox has no network; the test builds
+    a synthetic checkpoint under the same names): a layer holds
+    ``self_attn.{q,k,v,o,gate}_proj``, ``self_attn.{q,k}_norm``,
+    ``input_layernorm``, ``post_attention_layernorm``,
+    ``pre_mlp_layernorm``, ``post_mlp_layernorm`` and either
+    ``mlp.{gate,up,down}_proj`` (a dense layer) or ``mlp.router.gate``,
+    ``mlp.expert_bias``, ``mlp.shared_experts.*`` and
+    ``mlp.experts.N.*``. The rotary halves are as the program rotates
+    them (rotate-half): nothing is permuted."""
+    t = _read_safetensors(model_dir)
+    dt, V, Ld = cfg.dtype, cfg.vocab_size, cfg.n_dense_layers
+    every, routed = range(cfg.n_layers), range(Ld, cfg.n_layers)
+
+    def stack(layers, name: str, dtype=dt, matrix: bool = True):
+        return jnp.asarray(np.stack(
+            [t[f"model.layers.{l}.{name}"].T if matrix
+             else t[f"model.layers.{l}.{name}"] for l in layers]),
+            dtype=dtype)
+
+    def experts(l: int) -> tuple:
+        pre = f"model.layers.{l}.mlp.experts."
+        held = range(*cfg.held)
+        gu = np.stack([np.concatenate(
+            [t[f"{pre}{e}.gate_proj.weight"].T,
+             t[f"{pre}{e}.up_proj.weight"].T], axis=1) for e in held])
+        return gu, np.stack([t[f"{pre}{e}.down_proj.weight"].T
+                             for e in held])
+
+    per_layer = [experts(l) for l in routed]
+    norms = {"attn_norm": "input_layernorm",
+             "post_attn_norm": "post_attention_layernorm",
+             "mlp_norm": "pre_mlp_layernorm",
+             "post_mlp_norm": "post_mlp_layernorm",
+             "q_norm": "self_attn.q_norm", "k_norm": "self_attn.k_norm"}
+    mats = {"wq": "q_proj", "wk": "k_proj", "wv": "v_proj",
+            "wg": "gate_proj", "wo": "o_proj"}
+    return {
+        "embed": jnp.asarray(t["model.embed_tokens.weight"][:V], dtype=dt),
+        "lm_head": jnp.asarray(t["lm_head.weight"][:V].T, dtype=dt),
+        "final_norm": jnp.asarray(t["model.norm.weight"], dtype=dt),
+        "layers": {
+            **{k: stack(every, f"self_attn.{v}.weight")
+               for k, v in mats.items()},
+            **{k: stack(every, f"{v}.weight", matrix=False)
+               for k, v in norms.items()}},
+        "dense": {f"w_{k}": stack(range(Ld), f"mlp.{k}_proj.weight")
+                  for k in ("gate", "up", "down")},
+        "moe": {
+            "router": stack(routed, "mlp.router.gate.weight"),
+            "router_bias": stack(routed, "mlp.expert_bias", jnp.float32,
+                                 matrix=False),
+            **{f"ws_{k}": stack(routed,
+                                f"mlp.shared_experts.{k}_proj.weight")
+               for k in ("gate", "up", "down")},
+            "we_gate_up": tuple(jnp.asarray(g, dtype=dt)
+                                for g, _ in per_layer),
+            "we_down": tuple(jnp.asarray(d, dtype=dt)
+                             for _, d in per_layer)},
+    }
+
+
 def import_hf(model_dir: str, cfg, **kw) -> Params:
     """A local Hugging Face checkpoint directory into the tree of
     ``cfg``'s model family, by that family's ``import_hf``

@@ -75,7 +75,8 @@ def _jit_fused_decode():
         from llmq_tpu.ops.pallas.fused_decode import (
             fused_decode_attention_pallas)
         return jax.jit(fused_decode_attention_pallas,
-                       static_argnames=("pages_per_chunk", "interpret"))
+                       static_argnames=("pages_per_chunk", "interpret",
+                                        "window"))
     return _kernel_jit("fused_decode", make)
 
 
@@ -101,7 +102,7 @@ def _jit_prefill_attention():
             paged_prefill_attention_pallas)
         return jax.jit(paged_prefill_attention_pallas,
                        static_argnames=("pages_per_chunk", "q_block",
-                                        "interpret"))
+                                        "interpret", "window"))
     return _kernel_jit("prefill_attention", make)
 
 
@@ -140,10 +141,12 @@ def causal_prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def _gqa_attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                seq_lens: jnp.ndarray) -> jnp.ndarray:
+                seq_lens: jnp.ndarray, window=None) -> jnp.ndarray:
     """Shared decode-attention math: q (B, H, D) against gathered
-    history k/v (B, S, H_kv, D), masked beyond ``seq_lens``. GQA via
-    grouped einsum (no K/V repeat). Returns (B, H, D)."""
+    history k/v (B, S, H_kv, D), masked beyond ``seq_lens`` and, under
+    a ``window``, before ``seq_lens - window`` (the row's last
+    ``window`` keys, its current token counted). GQA via grouped einsum
+    (no K/V repeat). Returns (B, H, D)."""
     B, H, D = q.shape
     S = k.shape[1]
     Hkv = k.shape[2]
@@ -153,6 +156,8 @@ def _gqa_attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     logits = jnp.einsum("bgrd,bsgd->bgrs", qg, k,
                         preferred_element_type=jnp.float32) * scale
     mask = jnp.arange(S)[None, :] < seq_lens[:, None]  # (B, S)
+    if window is not None:
+        mask = mask & (jnp.arange(S)[None, :] >= seq_lens[:, None] - window)
     logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bgrs,bsgd->bgrd", probs.astype(v.dtype), v,
@@ -190,6 +195,7 @@ def paged_decode_attention_pooled(
     block_tables: jnp.ndarray,  # (B, max_pages) int32
     seq_lens: jnp.ndarray,     # (B,) int32
     layer: jnp.ndarray,        # scalar int32 — which layer's pages to read
+    window=None,               # static: see _gqa_attend
 ) -> jnp.ndarray:
     """Decode attention reading layer ``layer`` of the stacked FLAT pool
     (see models/llama.py:init_kv_pages for why the pool stores H_kv·D
@@ -208,7 +214,7 @@ def paged_decode_attention_pooled(
     Hkv = k_pool.shape[3] // D
     k = k_pool[layer, block_tables].reshape(B, S, Hkv, D)
     v = v_pool[layer, block_tables].reshape(B, S, Hkv, D)
-    return _gqa_attend(q, k, v, seq_lens)
+    return _gqa_attend(q, k, v, seq_lens, window)
 
 
 def paged_pool_window(pool: jnp.ndarray, block_table: jnp.ndarray,
@@ -470,8 +476,11 @@ def paged_kv_write_prefill(k_pool, v_pool, k, v, block_tables, positions,
 
 def dispatch_prefill_attention(q, k_pool, v_pool, block_tables, positions,
                                seq_lens, layer, *, enabled: bool = True,
-                               multi_ok: bool = False) -> jnp.ndarray:
+                               multi_ok: bool = False,
+                               window=None) -> jnp.ndarray:
     """Prefill-chunk attention over the paged pool; q (B, T, H, D).
+    ``window`` (static): a query sees its last ``window`` keys, itself
+    counted; ``None``: all of them.
 
     TPU kernel path (B == 1, or any B with ``multi_ok`` — per-row
     kernel reads don't break the pool aliasing): Pallas paged prefill
@@ -501,7 +510,8 @@ def dispatch_prefill_attention(q, k_pool, v_pool, block_tables, positions,
         # copy it (only a gather between aliased writes does).
         fn = _jit_prefill_attention()
         outs = [fn(q[b], k_pool, v_pool, block_tables[b],
-                   positions[b, 0], layer, interpret=interpret)
+                   positions[b, 0], layer, interpret=interpret,
+                   window=window)
                 for b in range(B)]
         return outs[0][None] if B == 1 else jnp.stack(outs)
     S = block_tables.shape[1] * page_size
@@ -510,13 +520,15 @@ def dispatch_prefill_attention(q, k_pool, v_pool, block_tables, positions,
     k_hist = k_pool[layer, block_tables].reshape(B, S, Hkv, D)
     v_hist = v_pool[layer, block_tables].reshape(B, S, Hkv, D)
     return blockwise_prefill_attention(q, k_hist, v_hist, positions,
-                                       seq_lens)
+                                       seq_lens, window=window)
 
 
 def paged_decode_step(q, k_new, v_new, k_pool, v_pool, block_tables,
                       seq_lens, page_of, slot_of, layer, *,
-                      enabled: bool = True):
+                      enabled: bool = True, window=None):
     """One decode layer's KV write + attention, fused where possible.
+    ``window`` (static): a row sees its last ``window`` keys, its
+    current token counted; ``None``: all of them.
 
     TPU: ONE Pallas kernel does both — the current token's K/V is
     merged into the attention's own page fetch (in-register self-
@@ -534,13 +546,13 @@ def paged_decode_step(q, k_new, v_new, k_pool, v_pool, block_tables,
     if use_kernel:
         attn, (k_pool, v_pool) = _jit_fused_decode()(
             q, k_new, v_new, k_pool, v_pool, block_tables, seq_lens,
-            page_of, layer, interpret=interpret)
+            page_of, layer, interpret=interpret, window=window)
         return attn, k_pool, v_pool
     k_pool, v_pool = paged_kv_write(k_pool, v_pool, k_new, v_new,
                                     page_of, slot_of, layer,
                                     distinct_pages=True, enabled=enabled)
     attn = paged_decode_attention_pooled(q, k_pool, v_pool, block_tables,
-                                         seq_lens, layer)
+                                         seq_lens, layer, window)
     return attn, k_pool, v_pool
 
 
@@ -552,11 +564,13 @@ def blockwise_prefill_attention(
     seq_lens: jnp.ndarray,   # (B,) visible history length
     *,
     block_size: int = 512,
+    window=None,
 ) -> jnp.ndarray:
     """Prefill attention with online softmax over KV chunks.
 
     Same semantics as the full-logits version (mask: kv_pos <= q_pos and
-    kv_pos < seq_len) but peak memory is O(B·H·T·block_size) f32 instead
+    kv_pos < seq_len; under a ``window`` also kv_pos > q_pos - window)
+    but peak memory is O(B·H·T·block_size) f32 instead
     of O(B·H·T·S) — the difference between GBs-per-layer and MBs at 8k
     context. ``lax.scan`` over chunks keeps one
     compiled body; XLA fuses mask+softmax into the chunk matmuls.
@@ -584,6 +598,9 @@ def blockwise_prefill_attention(
         kv_pos = i * Sb + jnp.arange(Sb)[None, :]           # (1, Sb)
         mask = ((kv_pos[:, None, :] <= positions[:, :, None])
                 & (kv_pos[:, None, :] < seq_lens[:, None, None]))  # (B,T,Sb)
+        if window is not None:
+            mask = mask & (kv_pos[:, None, :]
+                           > positions[:, :, None] - window)
         mask = mask[:, :, None, None, :]                    # (B,T,1,1,Sb)
         logits = jnp.where(mask, logits, NEG_INF)
         m_cur = jnp.max(logits, axis=-1, keepdims=True)

@@ -150,14 +150,31 @@ class DecodePlan(NamedTuple):
     vmem_limit_bytes: int   # what the call asks of the compiler
 
 
+def _first_chunk(seq_len, chunk_tokens: int, window, xp=jnp):
+    """The first chunk a row visits: 0, or under a ``window`` the chunk
+    that holds its oldest visible key, position ``seq_len - window``
+    (the current token is position ``seq_len - 1`` and counts among
+    the ``window`` keys it sees)."""
+    if window is None:
+        return 0
+    return xp.maximum(seq_len - window, 0) // chunk_tokens
+
+
 def _live_pages(seq_len, chunk, pages_per_chunk: int, page_size: int,
-                xp=jnp):
+                xp=jnp, window=None):
     """How many of ``chunk``'s pages hold a position below ``seq_len``:
-    0 where the row is dead in the chunk. The ONE liveness predicate —
+    0 where the row is dead in the chunk — past its last position or,
+    under a ``window``, before :func:`_first_chunk` (the visit starts
+    at a chunk's first page: the pages of that chunk that lie before
+    the window are fetched and masked). The ONE liveness predicate —
     a row's DMA starts, DMA waits and products all follow it (starts
     and waits that disagree corrupt the semaphores)."""
     pages = (seq_len + (page_size - 1)) // page_size
-    return xp.clip(pages - chunk * pages_per_chunk, 0, pages_per_chunk)
+    n = xp.clip(pages - chunk * pages_per_chunk, 0, pages_per_chunk)
+    if window is None:
+        return n
+    first = _first_chunk(seq_len, pages_per_chunk * page_size, window, xp)
+    return xp.where(chunk >= first, n, 0)
 
 
 def _tile_chunks(seq_lens, chunk_tokens: int, xp=jnp):
@@ -169,9 +186,23 @@ def _tile_chunks(seq_lens, chunk_tokens: int, xp=jnp):
     return xp.maximum((longest + (chunk_tokens - 1)) // chunk_tokens, 1)
 
 
+def _tile_first_chunk(seq_lens, n_chunks, chunk_tokens: int, window,
+                      xp=jnp):
+    """Where a tile's steps start: 0, or under a ``window`` the earliest
+    first chunk of its live rows (a dead row has none), at most the
+    tile's last step."""
+    if window is None:
+        return 0
+    first = n_chunks - 1
+    for s in seq_lens:
+        first = xp.minimum(first, xp.where(
+            s > 0, _first_chunk(s, chunk_tokens, window, xp), first))
+    return first
+
+
 def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
                    pages_per_chunk: int, page_size: int, n_rep: int,
-                   scale: float):
+                   scale: float, window=None):
     """Both kernels' body. ``refs`` (scalar prefetch, inputs, outputs,
     scratch), the int8 form's extras in brackets:
 
@@ -217,7 +248,20 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
     lyr = layer_ref[0]
 
     def live_pages(row, chunk):
-        return _live_pages(seq_lens_ref[row], chunk, ppc, page_size)
+        return _live_pages(seq_lens_ref[row], chunk, ppc, page_size,
+                           window=window)
+
+    def first_chunk(row):
+        if window is None:
+            return 0
+        return _first_chunk(seq_lens_ref[row], S, window)
+
+    def tile_first_chunk(tile):
+        """Where ``tile``'s steps start."""
+        if window is None:
+            return 0
+        lens = [seq_lens_ref[tile * R + r] for r in range(R)]
+        return _tile_first_chunk(lens, _tile_chunks(lens, S), S, window)
 
     def start_fetch(tile, chunk, slot):
         """Start the DMAs of every live (row, page) of (tile, chunk)."""
@@ -308,7 +352,7 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
         chunk the current token merged and its writeback started."""
         last, cur, cur_page = last_chunk(row, c)
 
-        @pl.when(c == 0)
+        @pl.when(c == first_chunk(row))
         def _():
             # Floor at -1e29 (not -1e30): see the module docstring.
             m_ref[r] = jnp.full(m_ref.shape[1:], -1e29, m_ref.dtype)
@@ -383,7 +427,10 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
             # logits layout — one elementwise multiply, no transpose.
             logits = logits * head_scales(bufs[2][slot, r])
         pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
-        logits = jnp.where(pos < seq_lens_ref[row], logits, NEG_INF)
+        seen = pos < seq_lens_ref[row]
+        if window is not None:
+            seen = seen & (pos >= seq_lens_ref[row] - window)
+        logits = jnp.where(seen, logits, NEG_INF)
 
         m_prev = m_ref[r]
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
@@ -416,15 +463,21 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
                 out_ref[r, h:h + 1, :] = res[
                     at:at + 1, g * D:(g + 1) * D].astype(out_ref.dtype)
 
+    first_c = tile_first_chunk(t)
+
     @pl.when(t == 0)
     def _():
         state[_CONSUMED] = 0
-        start_fetch(0, 0, 0)
+        start_fetch(0, first_c, 0)
 
     # A dead row (seq_len 0) computes nothing: it emits 0, like the
     # other paged kernels.
     out_ref[...] = jnp.zeros_like(out_ref)
     n_chunks = _tile_chunks([seq_lens_ref[t * R + r] for r in range(R)], S)
+    # The next tile's first chunk, for the prefetch across tiles (the
+    # last tile names itself: nothing is started for it).
+    next_c = (0 if window is None
+              else tile_first_chunk(jnp.minimum(t + 1, num_tiles - 1)))
 
     def chunk_body(c, _):
         consumed = state[_CONSUMED]
@@ -437,7 +490,7 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
         @pl.when(jnp.logical_or(more, t + 1 < num_tiles))
         def _():
             start_fetch(jnp.where(more, t, t + 1),
-                        jnp.where(more, c + 1, 0), 1 - slot)
+                        jnp.where(more, c + 1, next_c), 1 - slot)
 
         def note_live(r, n):
             n_live = live_pages(t * R + r, c)
@@ -487,7 +540,7 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
         state[_CONSUMED] = consumed + 1
         return 0
 
-    jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+    jax.lax.fori_loop(first_c, n_chunks, chunk_body, 0)
 
 
 def _fused_kernel(*refs, **static):
@@ -524,25 +577,49 @@ def _tile_plan(B: int, page_size: int, max_pages: int, GD: int,
     return None
 
 
-def decode_work(seq_lens, plan: DecodePlan):
+def chunk_tokens(B: int, page_size: int, max_pages: int, GD: int,
+                 itemsize: int = 2) -> int:
+    """The tokens in one key chunk of a call of this geometry: what
+    :func:`window_chunks` counts in (a page where no plan is legal and
+    the XLA form serves)."""
+    plan = _tile_plan(B, page_size, max_pages, GD, itemsize)
+    return plan.chunk_tokens if plan is not None else page_size
+
+
+def window_chunks(seq_lens, chunk_tokens: int, window=None):
+    """``(visited, skipped)``: the (row, chunk) pairs in which a row of
+    ``seq_lens`` holds a position it can see, and those that lie wholly
+    before its ``window`` (0 without one) — the kernel's schedule in
+    closed form, for a count at every dispatch."""
+    seq_lens = np.asarray(seq_lens, np.int64)
+    skipped = _first_chunk(seq_lens, chunk_tokens, window, np)
+    return (int((-(-seq_lens // chunk_tokens) - skipped).sum()),
+            int(np.sum(skipped)))
+
+
+def decode_work(seq_lens, plan: DecodePlan, window=None):
     """What one call does for a batch of ``seq_lens`` under ``plan``,
     counted on the host by the kernel's own schedule: ``(steps,
     row_chunks_computed, row_chunks_live)`` — the (tile, chunk) steps
     its loops run, the (row, chunk) pairs whose products run, and the
-    pairs in which a row holds a position at all. The last two are
-    equal when the kernel does the batch's work and no more; none
-    depends on the block table's width."""
+    pairs in which a row holds a position it can see (under a
+    ``window``: from the chunk of its oldest visible key on). The last
+    two are equal when the kernel does the batch's work and no more;
+    none depends on the block table's width."""
     seq_lens = np.asarray(seq_lens, np.int64)
     page_size = plan.chunk_tokens // plan.pages_per_chunk
     steps = computed = 0
     for tile in seq_lens.reshape(-1, plan.rows):
         n_chunks = int(_tile_chunks(list(tile), plan.chunk_tokens, np))
-        steps += n_chunks
-        for c in range(n_chunks):
+        first = int(_tile_first_chunk(list(tile), n_chunks,
+                                      plan.chunk_tokens, window, np))
+        steps += n_chunks - first
+        for c in range(first, n_chunks):
             computed += int((_live_pages(
-                tile, c, plan.pages_per_chunk, page_size, np) > 0).sum())
-    live = int((-(-seq_lens // plan.chunk_tokens)).sum())
-    return steps, computed, live
+                tile, c, plan.pages_per_chunk, page_size, np,
+                window) > 0).sum())
+    return steps, computed, window_chunks(seq_lens, plan.chunk_tokens,
+                                          window)[0]
 
 
 def fused_kernel_viable(B: int, page_size: int, max_pages: int, GD: int,
@@ -554,7 +631,8 @@ def fused_kernel_viable(B: int, page_size: int, max_pages: int, GD: int,
 
 
 def _fused_call(q, new_rows, pools, block_tables, seq_lens, write_page,
-                layer, *, pages_per_chunk: int, interpret: bool):
+                layer, *, pages_per_chunk: int, interpret: bool,
+                window=None):
     """One ``pallas_call`` of :func:`_decode_kernel`. ``new_rows``: the
     tile-sliced inputs after q — (k, v) rows, for int8 pools followed by
     their pre-broadcast scales; ``pools``: (k, v) or (k, v, k_scale,
@@ -580,7 +658,7 @@ def _fused_call(q, new_rows, pools, block_tables, seq_lens, write_page,
     kernel = functools.partial(
         _fused_kernel_q8 if quantized else _fused_kernel, rows_per_tile=R,
         pages_per_chunk=ppc, page_size=page_size, n_rep=H // Hkv,
-        scale=D ** -0.5)
+        scale=D ** -0.5, window=window)
 
     def tile(*block):
         return pl.BlockSpec(block, lambda t, *_: (t,) + (0,) * (len(block) - 1))
@@ -640,10 +718,16 @@ def fused_decode_attention_pallas(
     *,
     pages_per_chunk: int = 0,
     interpret: bool = False,
+    window: int | None = None,
 ):
     """Fused decode step: write the current tokens' KV into the pool
     (in place, aliased) AND return attention over the updated history.
     Returns (attn (B, H, D), (k_pool, v_pool)).
+
+    ``window``: a row sees its last ``window`` keys, itself counted
+    (positions ``seq_len - window .. seq_len - 1``), and visits no
+    chunk wholly before them; ``None``: every key, the program there
+    was before there was a window.
 
     ``write_page`` must equal ``block_tables[b, (seq_lens[b]-1)//ps]``
     for live rows (the engine's invariant) or 0 for inactive rows.
@@ -658,7 +742,8 @@ def fused_decode_attention_pallas(
     vn = v_new.reshape(B, GD).astype(v_pool.dtype)
     out, pools = _fused_call(
         q, (kn, vn), (k_pool, v_pool), block_tables, seq_lens, write_page,
-        layer, pages_per_chunk=pages_per_chunk, interpret=interpret)
+        layer, pages_per_chunk=pages_per_chunk, interpret=interpret,
+        window=window)
     return out.astype(q.dtype), pools
 
 

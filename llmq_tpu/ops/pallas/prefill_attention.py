@@ -90,20 +90,25 @@ class PrefillPlan(NamedTuple):
     vmem_bytes: int         # estimate, see prefill_tile_plan
 
     def steps(self, n_tokens: int, start_pos: int,
-              length: int | None = None) -> int:
+              length: int | None = None, window: int | None = None) -> int:
         """Loop steps (q block × live K/V chunk) of a call whose first
         query sits at ``start_pos``; each does ``num_windows`` pairs of
         matmuls. ``length``: the slice's valid rows, where the kernel
         is told (int8 pools) — a block past them runs no step and none
-        looks past the last of them."""
+        looks past the last of them. ``window``: a block starts at the
+        chunk of its FIRST query's oldest visible key."""
         total = 0
         for qb in range(n_tokens // self.q_block):
-            last = start_pos + (qb + 1) * self.q_block - 1
+            first_q = start_pos + qb * self.q_block
+            last = first_q + self.q_block - 1
             if length is not None:
                 if qb * self.q_block >= length:
                     break
                 last = min(last, start_pos + length - 1)
-            total += min(last // self.chunk_tokens + 1, self.num_chunks)
+            first = (0 if window is None
+                     else max(first_q - window + 1, 0) // self.chunk_tokens)
+            total += min(last // self.chunk_tokens + 1,
+                         self.num_chunks) - first
         return total
 
 
@@ -176,7 +181,8 @@ def _lane_window(x, lo: int, width: int):
 
 
 def _prefill_body(*refs, quantized: bool, plan: PrefillPlan,
-                  page_size: int, head_dim: int, n_rep: int, scale: float):
+                  page_size: int, head_dim: int, n_rep: int, scale: float,
+                  window=None):
     """Both kernels' body. ``refs`` (scalar prefetch, inputs, outputs,
     scratch), the int8 form's extras in brackets:
 
@@ -219,6 +225,11 @@ def _prefill_body(*refs, quantized: bool, plan: PrefillPlan,
         last_valid = start + length - 1
         block_max_pos = jnp.minimum(block_max_pos, last_valid)
     n_live = jnp.minimum(block_max_pos // S + 1, plan.num_chunks)
+    # Under a window the block's chunks start where its FIRST query's
+    # oldest visible key lies (position q - window + 1): the later
+    # queries' windows start no earlier.
+    first_c = (0 if window is None else
+               jnp.maximum(start + qb * Tb - (window - 1), 0) // S)
 
     def chunk_dmas(chunk, slot, wait: bool):
         """Start (or wait for) the live pages of ``chunk``. Liveness is
@@ -274,7 +285,8 @@ def _prefill_body(*refs, quantized: bool, plan: PrefillPlan,
             for buf in (bufs[2:] if quantized else bufs):
                 buf[...] = jnp.zeros_like(buf)
 
-        chunk_dmas(0, 0, wait=False)
+        chunk_dmas(first_c, 0 if window is None
+                   else jax.lax.rem(first_c, 2), wait=False)
 
         # Stack the query heads while chunk 0 is in flight. Window w
         # holds heads [w·R, (w+1)·R): head h goes to rows [i·Tb,
@@ -319,6 +331,8 @@ def _prefill_body(*refs, quantized: bool, plan: PrefillPlan,
             # of gives it p = exp(-1e30 - m) = 0 exactly.
             kv_pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
             live = kv_pos <= q_pos                          # (R·Tb, S)
+            if window is not None:
+                live = live & (kv_pos > q_pos - window)
             if quantized:
                 # K's scales go on the logits with the softmax scale,
                 # V's on p before PV (as ``fused_decode.py``'s twin).
@@ -341,6 +355,11 @@ def _prefill_body(*refs, quantized: bool, plan: PrefillPlan,
                     m_prev, jnp.max(logits, axis=-1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
                 p = jnp.exp(logits - m_new)                 # (R·Tb, S)
+                if window is not None:
+                    # a block's first chunk can lie wholly before a
+                    # LATER query's window: m is still -1e30 there and
+                    # exp(0) = 1 would count what the row cannot see
+                    p = jnp.where(live, p, 0.0)
                 l_ref[w] = alpha * l_ref[w] + jnp.sum(p, axis=-1,
                                                       keepdims=True)
                 m_ref[w] = m_new
@@ -352,7 +371,7 @@ def _prefill_body(*refs, quantized: bool, plan: PrefillPlan,
                 acc_ref[w] = acc_ref[w] * alpha + pv
             return carry
 
-        jax.lax.fori_loop(0, n_live, chunk_step, 0)
+        jax.lax.fori_loop(first_c, n_live, chunk_step, 0)
 
         # Unstack: head h's result is rows [i·Tb, (i+1)·Tb) of its
         # tile, lanes of its KV head; it goes back to where q_ref had
@@ -406,7 +425,7 @@ def _prefill_attn_kernel_q8(*refs, **static):
 
 
 def _prefill_call(q, pools, block_table, meta, *, pages_per_chunk: int,
-                  q_block: int, interpret: bool):
+                  q_block: int, interpret: bool, window=None):
     """One ``pallas_call`` of :func:`_prefill_body`. ``pools``: (k, v)
     or (k, v, k_scale, v_scale), FLAT (L, P, page_size, GD) and (L, P,
     H_kv, page_size); ``meta``: the scalars after the block table —
@@ -429,7 +448,7 @@ def _prefill_call(q, pools, block_table, meta, *, pages_per_chunk: int,
     kernel = functools.partial(
         _prefill_attn_kernel_q8 if quantized else _prefill_attn_kernel,
         plan=plan, page_size=page_size, head_dim=D, n_rep=H // Hkv,
-        scale=D ** -0.5)
+        scale=D ** -0.5, window=window)
     if quantized:
         # A block past the valid length asks for the last live block's
         # q again: the pipeline then fetches nothing for it.
@@ -482,11 +501,15 @@ def paged_prefill_attention_pallas(
     pages_per_chunk: int = 0,
     q_block: int = 0,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Causal paged attention for a prefill chunk. Returns (T, H, D).
 
     Visibility: kv position <= q position (covers both in-chunk
-    causality and previously cached history). Requires H_kv·D to cut
+    causality and previously cached history) and, under a ``window``,
+    kv position > q position - ``window`` (a query sees ``window``
+    keys, itself counted; a q block visits no chunk wholly before its
+    first query's); ``None`` is the program there was before. Requires H_kv·D to cut
     into 128-lane head windows (D a divisor or a multiple of 128).
     ``pages_per_chunk`` / ``q_block`` = 0 (default) let
     :func:`prefill_tile_plan` choose.
@@ -494,7 +517,7 @@ def paged_prefill_attention_pallas(
     return _prefill_call(
         q, (k_pool, v_pool), block_table, (start_pos, layer),
         pages_per_chunk=pages_per_chunk, q_block=q_block,
-        interpret=interpret)
+        interpret=interpret, window=window)
 
 
 def paged_prefill_attention_q8_pallas(

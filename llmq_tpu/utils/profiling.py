@@ -72,7 +72,8 @@ SCOPES = (
     "mixed_step", "decode_loop", "prefill", "sample",
     "slices", "decode_rows",
     "embed", "qkv", "kv_write", "attn", "latent_prefill_attention",
-    "attn_out", "mlp", "moe_route", "moe_experts", "moe_combine", "head",
+    "attn_window", "attn_full", "attn_gate", "attn_out", "mlp",
+    "moe_route", "moe_experts", "moe_combine", "head",
     "act_quant", "ssm_conv", "ssm_update", "ssm_scan",
 )
 

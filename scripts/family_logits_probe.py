@@ -18,11 +18,23 @@ differences and the worst position, served against the reference and
 the control against the reference at the SAME positions, and the RMS
 of the reference's logits. The family must have the routed families'
 surface (``reference.JUDGED``, ``reference_forward(..., lowp=)``,
-``judge``). One process for all seeds: the programs compile once."""
+``judge``). One process for all seeds: the programs compile once.
+
+``--check`` runs, for every seed, ``benchmark/harness/child.py``
+``check_logits`` itself, as a run of the cell does before it serves (the
+harness's prompts, the family's ``reference_logits`` with whatever it
+judges besides), and prints its verdict or the family's ``NotCorrect``.
+``--setattr module.attr=expression`` (repeatable) breaks the program on
+purpose first — the control that shows what the check refuses:
+
+    ... --check --setattr "llmq_tpu.models.afmoe._window=lambda cfg, kind: None"
+"""
 
 from __future__ import annotations
 
 import argparse
+import importlib
+import inspect
 import json
 import os
 import sys
@@ -37,6 +49,14 @@ def main() -> int:
     ap.add_argument("--config", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--out", default="")
+    ap.add_argument("--tokens", type=int, default=0,
+                    help="length of the judged sequence (default: the "
+                         "smallest prefill bucket less two); a family "
+                         "whose served_many prefills in slices takes more")
+    ap.add_argument("--check", action="store_true",
+                    help="run the harness's check_logits, not the readings")
+    ap.add_argument("--setattr", action="append", default=[],
+                    metavar="MODULE.ATTR=EXPRESSION")
     args = ap.parse_args()
 
     import jax
@@ -57,15 +77,46 @@ def main() -> int:
     srv = config["server"]
     tol = dict(config["tolerance"])
     mcfg = adapter.register(srv["model"]["name"], config)
-    adapter.serving_path(mcfg, srv)
+    path = adapter.serving_path(mcfg, srv)
     served_many, _ = reference.JUDGED
-    T = int(min(srv["executor"]["prefill_buckets"])) - 2
-    print(json.dumps({"device": str(jax.devices()[0]), "tokens": T}),
-          flush=True)
+    for patch in args.setattr:
+        target, expression = patch.split("=", 1)
+        module, attr = target.rsplit(".", 1)
+        setattr(importlib.import_module(module), attr, eval(expression))
+    # the harness keeps its check programs under this name: a broken
+    # program must not be found under the whole one's
+    path.ident += "".join(args.setattr)
+    T = args.tokens or int(min(srv["executor"]["prefill_buckets"])) - 2
+    print(json.dumps({"device": str(jax.devices()[0]), "tokens": T,
+                      "setattr": args.setattr}), flush=True)
+
+    def emit(line):
+        print(json.dumps(line), flush=True)
+        if args.out:
+            os.makedirs(os.path.dirname(os.path.join(ROOT, args.out)),
+                        exist_ok=True)
+            with open(os.path.join(ROOT, args.out), "a",
+                      encoding="utf-8") as f:
+                f.write(json.dumps(line) + "\n")
+
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         params = child.make_params(seed, adapter.param_builder(
             mcfg, srv["model"]))
+        if args.check:
+            line = {"seed": seed, "setattr": args.setattr}
+            try:
+                got = child.check_logits(
+                    params, path, reference.reference_logits,
+                    {"config": dict(config, model=model), "seed": seed})
+                line.update(correct=got["ok"], rms=got["rms"],
+                            phases_s=got["phases_s"])
+            except reference.NotCorrect as e:
+                line.update(correct=False, not_correct=str(e))
+            line["seconds"] = round(time.perf_counter() - t0, 1)
+            del params
+            emit(line)
+            continue
         tokens = np.random.default_rng(seed).integers(
             3, mcfg.vocab_size, T, dtype=np.int32)
         every = np.arange(T)
@@ -78,24 +129,22 @@ def main() -> int:
                 "reference_rms": float(np.sqrt((ref * ref).mean()))}
         for group, (at, served) in served_many(params, tokens).items():
             at = np.asarray(at)
+            # a family whose limits follow the context takes the positions
+            by = (at,) if "at" in inspect.signature(
+                reference.judge).parameters else ()
             for name, got in (("served", np.asarray(served)),
                               ("control", low[at])):
-                j = reference.judge(got, ref[at], margins[at], tol)
+                j = reference.judge(got, ref[at], margins[at], tol, *by)
                 rms = np.sqrt(((got - ref[at]) ** 2).mean(-1))
                 line[f"{group}.{name}"] = {
                     "rms_clean": j["rms_clean"], "rms": j["rms"],
                     "median": float(np.median(rms)),
                     "p90": float(np.quantile(rms, 0.9)), "ok": j["ok"],
-                    "near_tie_share": j["near_tie_share"]}
+                    "near_tie_share": j["near_tie_share"],
+                    "bands": j.get("bands", {})}
         line["seconds"] = round(time.perf_counter() - t0, 1)
         del params
-        print(json.dumps(line), flush=True)
-        if args.out:
-            os.makedirs(os.path.dirname(os.path.join(ROOT, args.out)),
-                        exist_ok=True)
-            with open(os.path.join(ROOT, args.out), "a",
-                      encoding="utf-8") as f:
-                f.write(json.dumps(line) + "\n")
+        emit(line)
     return 0
 
 

@@ -54,14 +54,14 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
         assert all(k in config for k in keys), entry["name"]
         assert set(config["reduced"]) == set(entry["reduced"])
     assert families == {"llama", "deepseek_v3", "longcat_flash",
-                        "granitemoehybrid"}
+                        "granitemoehybrid", "afmoe"}
 
 
 @pytest.mark.parametrize("cell", [
     "smollm2-chat-bursts", "smollm2-decode-saturated",
     "smollm2-sessions-prefix", "mistral7b-decode-saturated",
     "kanana2-decode-saturated", "longcat-decode-saturated",
-    "granite4h-decode-saturated"])
+    "granite4h-decode-saturated", "trinity-longshort-saturated"])
 def test_every_cell_resolves_and_reports_what_the_contract_asks(bench, cell):
     assert contract.check_names(bench) == []
     assert cell in [w["name"] for w in bench["workloads"]]
@@ -86,6 +86,7 @@ def test_no_cell_is_left_out_of_the_cases_above(bench):
     ("kanana-2-30b-a3b-bf16", 2, 9_216),
     ("longcat-flash-chat-bf16-ep32", 2, 9_216),
     ("granite-4.0-h-micro-bf16", 2, 8_192),
+    ("trinity-large-preview-bf16-ep16", 2, 4_096),
 ])
 def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
                                                     kv_itemsize, per_token):
@@ -96,7 +97,7 @@ def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
     # one attention a layer, or a double layer's two, or one an
     # ATTENTION layer where the layers are of two kinds
     layers = doc.get("num_hidden_layers", 2 * doc.get("num_layers", 0))
-    if "layer_types" in doc:
+    if "attention" in doc.get("layer_types", ()):
         layers = doc["layer_types"].count("attention")
     assert shapes.attn_calls_per_step(model) == layers
 
@@ -395,6 +396,214 @@ def test_the_shared_configuration_is_the_catalog_s_row(bench):
                                       "mixed_batch"}
 
 
+def test_the_window_family_s_shapes_on_the_published_sizes(bench):
+    """``afmoe``: a chip's share, two kinds of attention layer. The
+    arithmetic of the cut as the configuration's ``deployment`` states
+    it; the page pool holds the FULL layer's K and V alone; a decode
+    step's attention bytes are the full layer's exactly and the LEAST
+    the four sliding layers could read of a sum of contexts (the
+    harness samples no more), so no accepted roofline can pass 100 %
+    through this count; the exact bytes take the program's counter."""
+    cell = contract.resolve_cell(bench, "trinity-longshort-saturated")
+    shapes = contract.load_family(cell["family_dir"], "shapes")
+    held = cell["config"]["model"]
+    assert shapes.held_experts(held) == (0, 16)
+    assert shapes.layer_kinds(held) == (4, 1)
+    assert shapes.dense_layers(held) == 1
+    assert shapes.attn_params(held) == 62_914_560
+    assert shapes.expert_params(held) == 28_311_552
+    assert shapes.param_count(held) == 2_509_962_240 + 5 * 256 + 4 * 256
+    assert "2,509,962,240 parameters" in cell["config"]["deployment"]
+    whole = dict(held, num_hidden_layers=60, dense_layers_held=6,
+                 num_experts=256, vocab_size=200_192,
+                 expert_share={"chips": 1, "index": 0})
+    assert round(shapes.param_count(whole) / 1e9) == 399
+    assert round(shapes.active_param_count(whole) / 1e9, 1) == 13.4
+    assert shapes.kv_bytes_per_token(held, 2) == 4_096
+    assert round(shapes.experts_touched(held, 64), 1) == 10.2
+    assert shapes.held_slot_share(held) == 1 / 16
+    W, most = 4_096, held["max_position_embeddings"]
+    # 64 rows of 4,200 tokens: the full layer reads them all, a sliding
+    # layer at least the share W / max of their sum, really min(c, W)
+    ctx = 64 * 4_200
+    least = shapes.decode_attn_bytes(held, 2, 64, ctx)
+    assert least == 4_096 * ctx * (1 + 4 * W / most)
+    exact = 4_096 * ctx + shapes.attn_window_bytes(held, 2, 64 * W)
+    assert least < exact < 4_096 * ctx * 5
+    step = shapes.decode_step_bytes(held, 2, 2, 64, ctx)
+    assert 5.5e9 < step < 6.5e9 and least / step > 0.39
+    assert shapes.moe_ffn_bytes(held, 2, 10.2) == 10.2 * 28_311_552 * 2
+    assert shapes.prefill_attn_flops(held, 1e6) == (
+        4.0 * 48 * 128 * 1e6 * (1 + 4 * W / most))
+
+
+def test_the_window_configuration_is_the_catalog_s_row(bench):
+    """``trinity-large-preview-bf16-ep16``: every key of the catalog's
+    ``config`` under the same key with the same value but the four
+    cuts, each with its published value beside it; no width among them;
+    ``layer_types`` and ``num_dense_layers`` as published, the held
+    layers and the one dense layer among them stated beside; the share,
+    the deployment and the server as the file reasons."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    entry, doc = next(x for x in _configs(bench)
+                      if x[0]["name"] == "trinity-large-preview-bf16-ep16")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == entry["source"])
+    assert row["name"] == "Trinity-Large-Preview"
+    differs = {k for k, v in row["config"].items() if doc.get(k) != v}
+    assert differs == set(entry["reduced"]) == {
+        "num_hidden_layers", "num_experts", "vocab_size",
+        "max_position_embeddings"}
+    assert doc["published"] == {k: row["config"][k] for k in differs}
+    assert not [k for k in differs if k.endswith(("_dim", "_rank", "_size"))
+                and k != "vocab_size"]
+    # the floors: a leading dense layer and one whole period of routed
+    # layers, at least 8 experts, an eighth of the vocabulary; the
+    # router keeps its width and its experts a token
+    assert doc["num_hidden_layers"] == 5 and doc["dense_layers_held"] == 1
+    assert doc["layer_types"][:5].count("full_attention") == 1
+    assert doc["num_experts"] >= 8 and doc["router_experts"] == 256
+    assert doc["vocab_size"] * 8 >= row["config"]["vocab_size"]
+    assert doc["expert_share"] == {"chips": 16, "index": 0}
+    assert doc["num_experts_per_tok"] == 4
+    assert "16 chips share" in doc["deployment"]
+    assert len(doc["assumed"]) >= 7
+    ex = doc["server"]["executor"]
+    assert ex["max_batch_size"] >= 48 and ex["prefill_buckets"] == [512]
+    assert ex["page_size"] == 128 and ex["decode_chunk"] == 16
+    assert doc["server"]["model"]["max_seq_len"] == 14_336
+    # the program's registry holds the same model
+    from llmq_tpu.models import afmoe, get_config
+    cfg = get_config("trinity-large-preview")
+    assert (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (
+        row["config"]["hidden_size"], row["config"]["num_attention_heads"],
+        row["config"]["num_key_value_heads"], row["config"]["head_dim"])
+    assert list(cfg.layer_types) == row["config"]["layer_types"]
+    assert (cfg.ffn_dim, cfg.moe_ffn_dim, cfg.n_routed_experts,
+            cfg.n_experts_per_tok, cfg.n_dense_layers) == (
+        12_288, 3_072, 256, 4, 6)
+    assert cfg.sliding_window == row["config"]["sliding_window"]
+    assert afmoe.param_count_analytic(cfg) == 398_635_286_016
+
+
+def test_the_window_family_s_tolerance_sits_between_its_readings(bench):
+    """The judge's keys and no other; under its numbers the served
+    path's readings on the chip pass (a median of 0.0075 with swapped
+    positions up to 0.3), the control one precision down is refused by
+    the MEDIAN, and so is one position of unrelated logits. The limit
+    follows the context (both readings fall behind a longer one): what
+    passes behind 500 tokens is refused beyond the window, where the
+    control's smallest reading is 0.0165 and the served path's largest
+    0.0051. The family's own judged sequence passes the window by more
+    than a slice of the mixed step and the judged decode steps."""
+    import numpy as np
+    cell = contract.resolve_cell(bench, "trinity-longshort-saturated")
+    reference = contract.load_family(cell["family_dir"], "reference")
+    judge = reference.judge
+    tol = cell["config"]["tolerance"]
+    assert set(tol) == {"rms", "max", "clean_quantile", "rms_clean",
+                        "rms_clean_by_context", "margin_eps",
+                        "min_positions", "judged_tokens", "why"}
+    ex = cell["config"]["server"]["executor"]
+    bucket = min(ex["prefill_buckets"])
+    assert bucket // 3 + 3 < tol["min_positions"] <= bucket - 5 + 3
+    W = cell["config"]["model"]["sliding_window"]
+    mixed = ex["mixed_batch"]
+    one_slice = mixed["prefill_token_budget"] // mixed["max_slices"]
+    assert W + one_slice + 128 <= tol["judged_tokens"] <= \
+        cell["config"]["server"]["model"]["max_seq_len"]
+    bands = tol["rms_clean_by_context"]
+    assert [b[0] for b in bands] == [0, 1024, W]
+    assert bands[0][1] == tol["rms_clean"] > bands[1][1] > bands[2][1]
+    ref = np.zeros((128, 16), np.float32)
+    margins = np.full(128, 1e-3)
+    sound = ref + np.linspace(0.004, 0.011, 128)[:, None]
+    sound[5] = 0.3
+    got = judge(sound, ref, margins, tol)
+    assert got["ok"] and 0.007 < got["rms_clean"] < 0.008, got
+    assert judge(sound, ref, margins, tol, 300 + np.arange(128))["ok"]
+    control = ref + np.linspace(0.0165, 0.03, 128)[:, None]
+    got = judge(control, ref, margins, tol)
+    assert not got["ok"] and got["rms"] < tol["rms"], got
+    unrelated = sound.copy()
+    unrelated[3] = 1.5
+    assert not judge(unrelated, ref, margins, tol)["ok"]
+    # beyond the window the same differences are a lower precision's
+    between = ref + np.full((128, 1), 0.012, np.float32)
+    assert judge(between, ref, margins, tol, 300 + np.arange(128))["ok"]
+    beyond = judge(between, ref, margins, tol, 6272 + np.arange(128))
+    assert not beyond["ok"] and beyond["rms_clean"] < tol["rms_clean"]
+    assert beyond["bands"][str(W)]["limit"] == bands[2][1]
+    far = ref + np.linspace(0.0043, 0.0048, 128)[:, None]
+    assert judge(far, ref, margins, tol, 6272 + np.arange(128))["ok"]
+    least = ref + np.full((128, 1), 0.0165, np.float32)
+    assert not judge(least, ref, margins, tol, 6272 + np.arange(128))["ok"]
+    # a handful of positions in a band are no distribution: the six
+    # before the window's edge fall under the group's own limit
+    edge = judge(far, ref, margins, tol, W - 6 + np.arange(128))
+    assert list(edge["bands"]) == [str(W)]
+    # the sequence is the seed's: the harness's prompt decides it
+    a = reference.judged_sequence(np.arange(5, 515), 64, 25_024)
+    b = reference.judged_sequence(np.arange(6, 516), 64, 25_024)
+    assert (a == reference.judged_sequence(np.arange(5, 515), 64, 25_024)
+            ).all() and (a != b).any() and a.min() >= 3 and a.max() < 25_024
+
+
+@pytest.mark.parametrize("window,refused", [
+    ("whole", False), ("off", True), ("one-too-wide", True)])
+def test_the_harness_s_own_check_passes_the_window(monkeypatch, window,
+                                                    refused):
+    """``harness/child.py`` ``check_logits`` itself, as a run of the
+    cell calls it, on the rehearsal's toy (window 24): the family's
+    ``reference_logits`` judges the harness's prompt and then a
+    sequence of its own of ``tolerance.judged_tokens`` through prefill
+    in slices, the mixed step and decode from before, across and far
+    beyond the window's edge, so a served path whose window is off or
+    one key too wide is NOT correct by the comparison the benchmark
+    makes, not only by ``tests/test_afmoe.py``'s. (At the published
+    window of 4,096 the harness's prompts of 507 and 170 tokens end
+    below it: there the family's own sequence is the only one that
+    passes the edge; PERF.md §6, PR 41, has the control on the chip.)"""
+    import jax
+
+    import llmq_tpu.models.afmoe as am
+    from benchmark.harness import child
+    bench = contract.load_benchmark(os.path.join(
+        REPO, "benchmark", "selftest", "data", "rehearsal_afmoe.json"))
+    cell = contract.resolve_cell(bench, "tiny-afmoe-longshort")
+    config, srv = cell["config"], cell["config"]["server"]
+    adapter = contract.load_family(cell["family_dir"], "adapter")
+    reference = contract.load_family(cell["family_dir"], "reference")
+    assert config["tolerance"]["judged_tokens"] > \
+        4 * config["model"]["sliding_window"]
+    mcfg = adapter.register(srv["model"]["name"], config)
+    params = child.make_params(3400000123, adapter.param_builder(
+        mcfg, srv["model"]))
+    whole = am._window
+    if window == "off":
+        monkeypatch.setattr(am, "_window", lambda cfg, kind: None)
+    elif window == "one-too-wide":
+        monkeypatch.setattr(am, "_window", lambda cfg, kind: (
+            whole(cfg, kind) and whole(cfg, kind) + 1))
+    jax.clear_caches()          # the family's step functions are jitted
+    try:
+        path = adapter.serving_path(mcfg, srv)
+        path.ident += window    # the harness keeps its programs by name
+        spec = {"config": config, "seed": 3400000123}
+        if refused:
+            with pytest.raises(reference.NotCorrect, match="rms_clean"):
+                child.check_logits(params, path, reference.reference_logits,
+                                   spec)
+        else:
+            assert child.check_logits(
+                params, path, reference.reference_logits, spec)["ok"]
+    finally:
+        reference.JUDGED = None
+        jax.clear_caches()
+
+
 def test_the_harness_names_no_family():
     named = re.compile(r"llama|deepseek|kanana|smollm|fused_decode|gmm|"
                        r"latent_decode|moe_grouped|llmq_tpu\.models")
@@ -410,7 +619,7 @@ def test_the_harness_names_no_family():
 
 
 @pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
-                                    "granitemoehybrid"])
+                                    "granitemoehybrid", "afmoe"])
 def test_who_imports_what_in_a_family(family):
     """``shapes.py`` is standard library alone (the parent and the
     readers import it); ``reference.py`` imports neither the program

@@ -29,7 +29,11 @@ ROUTED = {"moe_route", "moe_experts", "moe_combine"}
 #: a state-space layer's: the convolution's window step, the decode
 #: update, the chunked scan of prompt tokens
 SSM = {"ssm_conv", "ssm_update", "ssm_scan"}
+#: a family with two kinds of attention layer: each kind's call, and a
+#: gated attention's gate
+WINDOW = {"attn_window", "attn_full", "attn_gate"}
 FAMILIES = {
+    "afmoe": MODULES | ROUTED | WINDOW,
     "granitemoehybrid": MODULES | SSM,
     "llama": MODULES,
     "llama-w8kv8": MODULES | {"act_quant"},
@@ -54,6 +58,11 @@ def _tiny(family):
         from llmq_tpu.models import deepseek_v3 as ds
         cfg = ds.deepseek_v3_tiny(dtype=jnp.float32, max_seq_len=128)
         return cfg, ds.init_params(jax.random.PRNGKey(31), cfg), {}
+    if family == "afmoe":
+        from llmq_tpu.models import afmoe as am
+        cfg = am.afmoe_tiny(dtype=jnp.float32, max_seq_len=128,
+                            held_experts=(8, 16))
+        return cfg, am.init_params(jax.random.PRNGKey(41), cfg), {}
     if family == "granitemoehybrid":
         from llmq_tpu.models import granitemoehybrid as gm
         cfg = gm.granite4h_tiny(dtype=jnp.float32, max_seq_len=128)
@@ -149,8 +158,10 @@ def test_a_name_outside_the_vocabulary_is_refused():
     assert len(set(SCOPES)) == len(SCOPES)
     # short: they are stored in every instruction's metadata
     assert max(map(len, SCOPES)) <= len("latent_prefill_attention")
-    assert sum(map(len, SCOPES)) < 200
-    assert SSM <= set(SCOPES)
+    # (176 characters until the family afmoe brought its three:
+    # attn_window, attn_full, attn_gate)
+    assert sum(map(len, SCOPES)) < 210
+    assert SSM | WINDOW <= set(SCOPES)
 
 
 def test_no_scope_string_outside_the_vocabulary():
@@ -217,7 +228,7 @@ def test_every_module_of_the_family_is_named(compiled):
         if prog.startswith("prefill"):
             lacks -= {"ssm_update"}
         assert not lacks, (prog, sorted(lacks))
-        others = (ROUTED | SSM
+        others = (ROUTED | SSM | WINDOW
                   | {"act_quant", "latent_prefill_attention"}) - want
         assert not (got & others), (prog, sorted(got & others))
     assert "kv_write" in {c for p in _paths(text["mixed_chunk"])

@@ -1,17 +1,21 @@
 """A chip's share of a routed layer (``ops/moe.routed_ffn(held=...)``,
-``models/longcat_flash.py``): the router scores every expert, a token
-chooses among all, this chip multiplies the pairs whose expert it
-holds, adds the zero-compute experts and leaves the rest out. The test
-that TIES THE SHARE TO THE MODEL: at a small size (32 experts + 16
-zero-compute ones, 4 shares of 8), one routed layer's partial results
-over all shares, with the zero-compute part and the dense path counted
-once, add up to what the family's plain reference
-(``benchmark/families/longcat_flash/reference.py``) gives for the
-UNCUT layer; and with everything held and no zero-compute expert
+``models/longcat_flash.py``, ``models/afmoe.py``): the router scores
+every expert, a token chooses among all, this chip multiplies the pairs
+whose expert it holds, adds what a token's home chip adds (LongCat's
+zero-compute experts, afmoe's shared expert) and leaves the rest out.
+The test that TIES THE SHARE TO THE MODEL, one case a family that
+serves a share (``FAMILIES``): at a small size (LongCat: 32 experts + 16
+zero-compute ones in 4 shares of 8; afmoe: 32 experts in 16 shares of 2
+beside a shared expert), one routed layer's partial results over all
+shares, with the home chip's part and the dense path counted once, add
+up to what the family's plain reference
+(``benchmark/families/<family>/reference.py``) gives for the UNCUT
+layer; and with everything held and no zero-compute expert
 ``routed_ffn`` is the call without a share, to the bit."""
 
 import dataclasses
 import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -20,19 +24,25 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.harness import contract
+from llmq_tpu.models import afmoe as am
 from llmq_tpu.models import longcat_flash as lf
 from llmq_tpu.ops import moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-reference = contract.load_family(
-    os.path.join(REPO, "benchmark", "families", "longcat_flash"), "reference")
 
 E, Z, SHARES, K = 32, 16, 4, 6
 HELD = E // SHARES
 PAGE, T = 8, 40
 
 
-def model_of(cfg):
+def _reference(family):
+    return contract.load_family(
+        os.path.join(REPO, "benchmark", "families", family), "reference")
+
+
+# -- LongCat-Flash: 4 shares of 8, zero-compute experts at home ----------------
+
+def _lf_model_of(cfg):
     """``cfg`` under the benchmark file's keys: what the reference
     reads, with the share as ``expert_share`` states it."""
     lo, hi = cfg.held
@@ -52,31 +62,13 @@ def model_of(cfg):
             "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta}
 
 
-@pytest.fixture(scope="module")
-def uncut():
-    cfg = lf.longcat_flash_tiny(
+def _lf_uncut():
+    return lf.longcat_flash_tiny(
         dtype=jnp.float32, max_seq_len=64, n_layers=1, n_routed_experts=E,
         zero_expert_num=Z, n_experts_per_tok=K)
-    params = lf.init_params(jax.random.PRNGKey(2), cfg)
-    params["moe"]["router_bias"] = 0.005 * jax.random.normal(
-        jax.random.PRNGKey(3), params["moe"]["router_bias"].shape)
-    seq = np.random.default_rng(2).integers(3, cfg.vocab_size, T,
-                                            dtype=np.int32)
-    return cfg, params, seq
 
 
-def share_of(cfg, params, s):
-    """(cfg, params) as chip ``s`` of ``SHARES`` holds them: its
-    experts' matrices alone, everything else whole."""
-    lo, hi = s * HELD, (s + 1) * HELD
-    m = dict(params["moe"])
-    m["we_gate_up"] = tuple(w[lo:hi] for w in m["we_gate_up"])
-    m["we_down"] = tuple(w[lo:hi] for w in m["we_down"])
-    return (dataclasses.replace(cfg, held_experts=(lo, hi)),
-            {**params, "moe": m})
-
-
-def all_positions(fns, cfg, params, seq):
+def _lf_all_positions(fns, cfg, params, seq):
     mp = cfg.max_seq_len // PAGE
     cache = lf.init_kv_pages(cfg, 1 + mp, PAGE)
     logits, _, st = fns.forward_prefill(
@@ -87,28 +79,18 @@ def all_positions(fns, cfg, params, seq):
     return np.asarray(logits)[0], np.asarray(st)
 
 
-class _Unjitted:
-    forward_prefill = staticmethod(lf.forward_prefill.__wrapped__)
-
-
-def test_the_shares_add_up_to_the_uncut_layer(uncut, monkeypatch):
-    """The model's layer with its routed part replaced by the SUM of
-    the four shares' partial results (each chip's held pairs; the
-    zero-compute experts, which every chip would add for its own rows,
-    once; both attentions and both dense SwiGLUs, data-parallel, once)
-    against the reference's uncut layer, which loops over all 32
-    experts."""
-    cfg, params, seq = uncut
-    slots = []
-
+def _lf_all_shares(fam, monkeypatch, slots):
+    """``lf._routed`` as the SUM of the shares' partial results: each
+    chip's held pairs; the zero-compute experts, which every chip would
+    add for its own rows, once."""
     def routed_by_all_shares(params, cfg, l, u, live):
         experts, gates = moe.route(
             u, params["moe"]["router"][l], params["moe"]["router_bias"][l],
             top_k=K, scale=cfg.routed_scaling_factor, norm_topk=False,
             scoring="softmax")
         total, stats = 0.0, []
-        for s in range(SHARES):
-            scfg, sp = share_of(cfg, params, s)
+        for s in range(fam.shares):
+            scfg, sp = share_of(fam, cfg, params, s)
             y, st = moe.routed_ffn(
                 u, experts, gates, sp["moe"]["we_gate_up"][l],
                 sp["moe"]["we_down"][l], live, held=scfg.held, n_routed=E)
@@ -119,18 +101,137 @@ def test_the_shares_add_up_to_the_uncut_layer(uncut, monkeypatch):
         return total + zero[:, None] * u, stats[0]
 
     monkeypatch.setattr(lf, "_routed", routed_by_all_shares)
-    served, _ = all_positions(_Unjitted, cfg, params, seq)
-    ref, _ = reference.reference_forward(params, seq, model_of(cfg),
-                                         np.arange(T))
+
+
+# -- afmoe: 16 shares of 2, the shared expert at home --------------------------
+
+def _am_model_of(cfg):
+    lo, hi = cfg.held
+    return {"num_hidden_layers": cfg.n_layers, "hidden_size": cfg.dim,
+            "num_attention_heads": cfg.n_heads,
+            "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+            "layer_types": list(cfg.layer_types),
+            "sliding_window": cfg.sliding_window,
+            "num_dense_layers": cfg.n_dense_layers,
+            "num_experts": hi - lo, "router_experts": E,
+            "expert_share": {"chips": E // (hi - lo),
+                             "index": lo // (hi - lo)},
+            "num_experts_per_tok": cfg.n_experts_per_tok,
+            "route_scale": cfg.route_scale, "route_norm": cfg.route_norm,
+            "mup_enabled": cfg.mup_enabled, "rms_norm_eps": cfg.norm_eps,
+            "rope_theta": cfg.rope_theta}
+
+
+def _am_uncut():
+    """One routed layer (a sliding one: the window of 24 is shorter
+    than the 40 tokens), 32 experts with 4 a token, all held."""
+    return am.bind_cache(
+        am.afmoe_tiny(dtype=jnp.float32, max_seq_len=64,
+                      layer_types=(am.SLIDING,), n_dense_layers=0,
+                      n_routed_experts=E, n_experts_per_tok=4),
+        page_size=PAGE, step_tokens=T)
+
+
+def _am_all_positions(fns, cfg, params, seq):
+    mp = cfg.max_seq_len // PAGE
+    cache = am.init_kv_pages(cfg, 1 + mp, PAGE)
+    logits, _, _, st = fns.forward_prefill(
+        params, cfg, jnp.asarray(seq[None]),
+        jnp.arange(len(seq), dtype=jnp.int32)[None],
+        jnp.asarray([len(seq)], jnp.int32), cache,
+        jnp.arange(1, 1 + mp, dtype=jnp.int32)[None], stats=True)
+    return np.asarray(logits)[0], np.asarray(st)
+
+
+def _am_all_shares(fam, monkeypatch, slots):
+    """``am.routed_ffn`` (called with every expert's matrices: the
+    uncut configuration) as the SUM of the sixteen shares' partial
+    results; the shared expert is ``am._ffn``'s own, computed once."""
+    def routed_by_all_shares(x, experts, gates, w_gate_up, w_down, live, *,
+                             held, n_routed):
+        assert held == (0, E) and n_routed == E
+        total, stats = 0.0, []
+        for s in range(fam.shares):
+            lo, hi = s * fam.held, (s + 1) * fam.held
+            y, st = moe.routed_ffn(x, experts, gates, w_gate_up[lo:hi],
+                                   w_down[lo:hi], live, held=(lo, hi),
+                                   n_routed=E)
+            total = total + y
+            stats.append(st)
+        slots.append(stats)
+        load = jnp.concatenate([st[:fam.held] for st in stats])
+        touched = sum(st[fam.held] for st in stats)
+        return total, jnp.concatenate([load, touched[None]])
+
+    monkeypatch.setattr(am, "routed_ffn", routed_by_all_shares)
+
+
+FAMILIES = {
+    "longcat_flash": SimpleNamespace(
+        name="longcat_flash", mod=lf, shares=SHARES, held=HELD, k=K,
+        uncut=_lf_uncut, model_of=_lf_model_of, bias=0.005,
+        all_positions=_lf_all_positions, all_shares=_lf_all_shares,
+        zero_at=HELD + 1, away_at=HELD + 2),
+    "afmoe": SimpleNamespace(
+        name="afmoe", mod=am, shares=16, held=E // 16, k=4,
+        uncut=_am_uncut, model_of=_am_model_of, bias=0.01,
+        all_positions=_am_all_positions, all_shares=_am_all_shares,
+        zero_at=None, away_at=E // 16 + 2),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def uncut(request):
+    fam = FAMILIES[request.param]
+    cfg = fam.uncut()
+    params = fam.mod.init_params(jax.random.PRNGKey(2), cfg)
+    params["moe"]["router_bias"] = fam.bias * jax.random.normal(
+        jax.random.PRNGKey(3), params["moe"]["router_bias"].shape)
+    seq = np.random.default_rng(2).integers(3, cfg.vocab_size, T,
+                                            dtype=np.int32)
+    return fam, cfg, params, seq
+
+
+def share_of(fam, cfg, params, s):
+    """(cfg, params) as chip ``s`` of the family's shares holds them:
+    its experts' matrices alone, everything else whole."""
+    lo, hi = s * fam.held, (s + 1) * fam.held
+    m = dict(params["moe"])
+    m["we_gate_up"] = tuple(w[lo:hi] for w in m["we_gate_up"])
+    m["we_down"] = tuple(w[lo:hi] for w in m["we_down"])
+    return (dataclasses.replace(cfg, held_experts=(lo, hi)),
+            {**params, "moe": m})
+
+
+def _unjitted(fam):
+    return SimpleNamespace(
+        forward_prefill=fam.mod.forward_prefill.__wrapped__)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(uncut, monkeypatch):
+    """The model's layer with its routed part replaced by the SUM of
+    the shares' partial results (each chip's held pairs; what a token's
+    home chip adds — the zero-compute experts, the shared expert —
+    once; the attention and the dense paths, data-parallel, once)
+    against the reference's uncut layer, which loops over all 32
+    experts."""
+    fam, cfg, params, seq = uncut
+    slots = []
+    fam.all_shares(fam, monkeypatch, slots)
+    served, _ = fam.all_positions(_unjitted(fam), cfg, params, seq)
+    ref, _ = _reference(fam.name).reference_forward(
+        params, seq, fam.model_of(cfg), np.arange(T))
     rms = np.sqrt(np.mean((served - np.asarray(ref)) ** 2, -1))
     assert rms.max() < 2e-5, rms.max()
     # every slot is held by exactly one share or is zero-compute
     stats = np.asarray(slots[0])
-    pairs, zero, away = (stats[:, :HELD].sum(-1), stats[:, HELD + 1],
-                         stats[:, HELD + 2])
-    assert np.all(zero == zero[0]) and zero[0] > 0
-    assert np.all(pairs + zero + away == T * K)
-    assert pairs.sum() + zero[0] == T * K and np.all(pairs > 0)
+    pairs, away = stats[:, :fam.held].sum(-1), stats[:, fam.away_at]
+    zero = (stats[:, fam.zero_at] if fam.zero_at is not None
+            else np.zeros_like(pairs))
+    assert np.all(zero == zero[0]) and (zero[0] > 0) == (
+        fam.zero_at is not None)
+    assert np.all(pairs + zero + away == T * fam.k)
+    assert pairs.sum() + zero[0] == T * fam.k and np.all(pairs > 0)
 
 
 @pytest.mark.parametrize("s", range(SHARES))
@@ -138,18 +239,19 @@ def test_a_share_alone_is_its_reference_s_partial_result(uncut, s):
     """Chip ``s``'s served logits against the reference given the same
     share: what the absent experts would have added is left out in
     both, and that partial result goes on to the head."""
-    cfg, params, seq = uncut
-    scfg, sp = share_of(cfg, params, s)
-    served, st = all_positions(lf, scfg, sp, seq)
-    ref, _ = reference.reference_forward(sp, seq, model_of(scfg),
+    fam, cfg, params, seq = uncut
+    reference = _reference(fam.name)
+    scfg, sp = share_of(fam, cfg, params, s)
+    served, st = fam.all_positions(fam.mod, scfg, sp, seq)
+    ref, _ = reference.reference_forward(sp, seq, fam.model_of(scfg),
                                          np.arange(T))
     rms = np.sqrt(np.mean((served - np.asarray(ref)) ** 2, -1))
     assert rms.max() < 2e-5, rms.max()
-    whole, _ = reference.reference_forward(params, seq, model_of(cfg),
+    whole, _ = reference.reference_forward(params, seq, fam.model_of(cfg),
                                            np.arange(T))
     # ... and it is NOT the uncut layer's: the share matters
     assert np.sqrt(np.mean((served - np.asarray(whole)) ** 2)) > 1e-3
-    layout = lf.step_stats_layout(scfg)
+    layout = fam.mod.step_stats_layout(scfg)
     assert st[layout["runs"]] == 1 and st[layout["away_slots"]] > 0
 
 

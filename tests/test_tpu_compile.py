@@ -14,6 +14,7 @@ module describes a topology while it is imported.
 """
 
 import os
+from functools import partial
 
 import pytest
 
@@ -616,3 +617,131 @@ def test_the_hybrid_decode_rows_copy_no_state_for_v5e(one_chip, monkeypatch,
     assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
     assert sum("tpu_custom_call" in line and "/ssm_update/" in line
                for line in compiled.as_text().splitlines()) == 9
+
+
+# -- afmoe: window and full attention over two caches ---------------------------
+
+
+def _trinity_share(one_chip, monkeypatch):
+    """``trinity-large-preview-bf16-ep16`` as served: the configuration
+    file's model through the family's adapter, the slabs bound as the
+    cell's executor binds them, parameters, pool and slabs as shapes on
+    the described chip."""
+    import json
+
+    from benchmark.harness import contract
+    from llmq_tpu.models import afmoe
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-large-preview-bf16-ep16.json")) as f:
+        doc = json.load(f)
+    adapter = contract.load_family(
+        os.path.join(root, "benchmark", "families", "afmoe"), "adapter")
+    name = "trinity-share-compile"
+    mcfg = adapter.register(name, doc)
+    monkeypatch.delitem(afmoe.MODEL_CONFIGS, name)
+    cfg = adapter._bound(mcfg, doc["server"])
+    ex = doc["server"]["executor"]
+    B, pool_pages = ex["max_batch_size"], ex["kv_pages"]
+    S = ex["mixed_batch"]["max_slices"]
+    T = ex["mixed_batch"]["prefill_token_budget"] // S
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: afmoe.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: afmoe.init_kv_pages(cfg, pool_pages, cfg.page_size)))
+    state = on_chip(jax.eval_shape(lambda: afmoe.init_row_state(cfg, B)))
+    return afmoe, cfg, params, cache, state, (B, S, T)
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_the_windowed_kernels_compile_for_v5e(one_chip, kernel, window):
+    """The two GQA kernels at Trinity's geometry (48 heads over 8 KV
+    heads of 128 in 128-token pages, a table of 112 pages, 64 rows):
+    over the full layer's pool without a window, over the sliding
+    layers' slabs (37 pages a row behind page 0) with it."""
+    from llmq_tpu.ops.pallas.fused_decode import fused_decode_attention_pallas
+    from llmq_tpu.ops.pallas.prefill_attention import (
+        paged_prefill_attention_pallas)
+
+    B, H, Hkv, D, ps, mp = 64, 48, 8, 128, 128, 112
+    L, P = (1, 4096) if window is None else (4, 1 + 64 * 37)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((L, P, ps, Hkv * D), jnp.bfloat16)
+    kw = {} if window is None else {"window": window}
+    if kernel == "decode":
+        row = arg((B, Hkv, D), jnp.bfloat16)
+        lowered = jax.jit(partial(fused_decode_attention_pallas, **kw)).lower(
+            arg((B, H, D), jnp.bfloat16), row, row, pool, pool,
+            arg((B, mp)), arg((B,)), arg((B,)), arg(()))
+    else:
+        lowered = jax.jit(partial(paged_prefill_attention_pallas,
+                                  **kw)).lower(
+            arg((512, H, D), jnp.bfloat16), pool, pool, arg((mp,)),
+            arg(()), arg(()))
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(text) < 100_000
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_the_share_s_step_fits_v5e_and_copies_no_cache(one_chip, monkeypatch,
+                                                       program):
+    """One step of ``trinity-large-preview-bf16-ep16`` as served (5
+    layers, 16 held experts, 25,024 of the vocabulary, 64 rows, the
+    file's pool beside 64 slabs of 37 pages in 4 layers; the mixed step
+    with the file's five 512-token slices): weights, pool and slabs
+    are 13.2 GB of arguments, BOTH caches go in and come out in place —
+    no copy of a pool or a slab leaf — and the step's temporaries stay
+    inside what is left of the chip's 16.9 GB."""
+    afmoe, cfg, params, cache, state, (B, S, T) = _trinity_share(
+        one_chip, monkeypatch)
+    mp = cfg.max_seq_len // cfg.page_size
+    assert (B, S, T) == (64, 5, 512)
+    assert cfg.slab_pages == 37 and state["wk"].shape == (4, 2369, 128, 1024)
+
+    def arg(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if program == "decode":
+        def step(params, cache, state, tokens, positions, tables, active):
+            return afmoe.forward_decode.__wrapped__(
+                params, cfg, tokens, positions, cache, tables, active=active,
+                stats=True, row_state=state)
+        args = (arg(B), arg(B), arg(B, mp), arg(B, dtype=jnp.bool_))
+        calls = 5 + 2 * 4
+    else:
+        def step(params, cache, state, tokens, positions, tables, active,
+                 *pf):
+            return afmoe.forward_mixed.__wrapped__(
+                params, cfg, tokens, positions, cache, tables, *pf[:5],
+                dec_active=active, stats=True, row_state=state,
+                pf_rows=pf[5])
+        args = (arg(B), arg(B), arg(B, mp), arg(B, dtype=jnp.bool_),
+                arg(S * T), arg(S * T), arg(S), arg(S + 1), arg(S, mp),
+                arg(S))
+        # a layer: its slices' write and attention, the rows' decode
+        calls = 5 * (2 * S + 1) + 2 * 4
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, cache, state, *args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((cache, state)))
+    assert compiled.as_text().count("tpu_custom_call") == calls
+    assert 13.1e9 < mem.argument_size_in_bytes < 13.4e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    assert mem.alias_size_in_bytes >= held
+    assert not _whole_copies(compiled, (cache, state))
+    assert mem.temp_size_in_bytes < (0.5e9 if program == "decode" else 2.0e9)
