@@ -322,15 +322,18 @@ class EchoExecutor:
 
     def slice_tokens(self, entry: str, tokens: int = 0, rows: int = 1) -> int:
         """Parity with :meth:`JaxExecutor.slice_tokens`: a mixed chunk
-        counts the row tiles its ``tokens`` fill, laid tight (a Llama
-        program's rule: no decode row shares them); a prefill pads
-        nothing here."""
+        counts the row tiles its ``tokens`` fill, laid tight behind
+        the decode rows that lead them, less those rows (a Llama
+        program's rule, ``ops/rows.tile_rows``); a prefill pads nothing
+        here."""
         if entry == "mixed_chunk":
             tile = min(self.mixed_slice_tokens, self.ROW_TILE)
             total = self.mixed_prefill_slices * self.mixed_slice_tokens
+            lead = self.spec.batch_size
             if total <= 2 * tile:          # ``ops/rows.worth_a_loop``
                 return total
-            return min(-(-tokens // tile) * tile, total)
+            return min(-(-(lead + tokens) // tile) * tile,
+                       lead + total) - lead
         return tokens * rows if entry.startswith("prefill") else 0
 
     def _register_prefill(self, slot: int, tokens: List[int],

@@ -286,9 +286,10 @@ def step_stats_size(cfg: LlamaConfig) -> int:
 
 def mixed_live_rows(tokens: int, batch: int, slices: int, width: int) -> int:
     """Rows ``forward_mixed``'s row-wise products run for ``tokens``
-    prompt tokens in ``slices`` slices ``width`` wide: the slice rows'
-    live tiles (the decode rows go through products of their own)."""
-    return tile_rows(tokens, row_tile(width), slices * width)
+    prompt tokens in ``slices`` slices ``width`` wide
+    (``models/__init__.py``): the live tiles' rows, less the ``batch``
+    decode rows that lead them."""
+    return tile_rows(tokens, row_tile(width), slices * width, lead=batch)
 
 
 def init_row_state(cfg, batch: int) -> None:
@@ -668,46 +669,55 @@ def forward_mixed(
     engine's ``prefill_token_budget``) instead of the longest admitted
     prompt.
 
-    **The slices' layout** (``ops/rows.py``). Their tokens lie TIGHT:
-    slice ``s`` is the ``pf_lengths[s]`` rows from ``pf_starts[s]`` of
-    one buffer of S·T rows, slice after slice with no gap;
-    ``pf_starts[S]`` is how many rows hold a token, the rest is zeros.
-    An unused slice starts there, holds no tight row and has length 1
-    (one trash token against reserved page 0, as in
-    :func:`forward_prefill`). The activations stay tight through the
-    layers. ``attn_out`` and the feed-forward (each row's norm,
-    residual and ``act_quant`` with them: nine tenths of the slices'
-    products) run over the live rows a tile at a time (``live_rows``:
-    as many tiles as hold a token, read on the device); the q, k, v
-    projections run all S·T rows. (Where S·T is two tiles or fewer
-    nothing loops, and the slices go back to T rows each at the door:
-    ``ops/rows.worth_a_loop``.) The KV write and the attention take
-    the (S, T) grid they always took, a slice a row: q, k, v are cut
-    out of the tight rows on the way in and the attention's output is
-    laid back on the way out. Of a slice only its last valid row goes
-    through the head.
+    **The rows' layout** (``ops/rows.py``). ONE activation buffer of
+    B + S·T rows: the B decode rows LEAD, and behind them the slices'
+    tokens lie TIGHT: slice ``s`` is the ``pf_lengths[s]`` rows from
+    ``B + pf_starts[s]``, slice after slice with no gap;
+    ``pf_starts[S]`` is how many slice rows hold a token, the rest is
+    zeros. An unused slice starts there, holds no tight row and has
+    length 1 (one trash token against reserved page 0, as in
+    :func:`forward_prefill`). What holds a token is so one prefix of
+    ``B + pf_starts[S]`` rows, and a layer's ``qkv``, ``attn_out`` and
+    feed-forward (each row's norm, residual and ``act_quant`` with
+    them) are each ONE product over both kinds of row: the layer's
+    matrices are read once a mixed step, not once for the slices and
+    once more for the decode rows. ``attn_out`` and the feed-forward
+    (nine tenths of the products) run over that prefix a tile at a
+    time (``live_rows``: as many tiles as hold a token, read on the
+    device, the matrices streamed once a tile); the q, k, v
+    projections run all B + S·T rows (a head split inside a loop makes
+    XLA transpose the stacked matrices). Where S·T is two tiles or
+    fewer nothing loops, all B + S·T rows run whole, and the slices go
+    back to T rows each at the door (``ops/rows.worth_a_loop``: the
+    slice rows alone decide, the rows that lead never tip it).
 
-    It is NOT one traversal of the weights: each layer's matrices are
-    read once a tile for the slice rows and once more for the decode
-    rows (the slices' products are compute-bound and hide their read;
-    the decode rows' are the weight stream of a plain decode step
-    again). Decode rows and slice rows are separate sequences over the
-    same pool, so their KV writes are disjoint and need no ordering.
+    Only the KV write and the attention take the two kinds of row
+    apart. The slices' q, k, v are cut out of the tight rows onto the
+    (S, T) grid they always took, a slice a row, written and attended
+    there; the decode rows' are the first B, written and attended by
+    the fused decode step; the two outputs are laid back into one
+    buffer. Decode rows and slice rows are separate sequences over the
+    same pool, so their KV writes are disjoint; the decode rows' write
+    takes the pool in place, and is held behind the slices' attention
+    so that XLA keeps one pool. Of a slice only its last valid row
+    goes through the head.
 
     On the grid, row conventions are exactly :func:`forward_prefill`'s
     (positions contiguous per slice and held at the last valid one past
     ``pf_lengths``, unused slices against reserved page 0), and the
     decode rows' are :func:`forward_decode`'s (``dec_active`` redirects
-    finished rows' writes to page 0). Returns
-    ``(dec_logits (B, V), pf_logits (S, V), cache)``: of a slice the
-    logits of its last valid position, the one serving samples.
+    finished rows' writes to page 0; such a row is computed all the
+    same). Returns ``(dec_logits (B, V), pf_logits (S, V), cache)``: of
+    a slice the logits of its last valid position, the one serving
+    samples.
     """
     B = dec_tokens.shape[0]
     S = pf_lengths.shape[0]
-    T = pf_tokens.shape[0] // S
+    N = pf_tokens.shape[0]
+    T = N // S
     page_sz = kv_cache["k"].shape[2]
     tile = row_tile(T)
-    if not worth_a_loop(S * T, tile):
+    if not worth_a_loop(N, tile):
         # Rows that run whole: every slice back at its own T rows, so
         # the moves to the grid and back are reshapes and the step is
         # the grid's program (``ops/rows.worth_a_loop`` has why).
@@ -717,30 +727,26 @@ def forward_mixed(
         pf_starts = jnp.arange(S + 1, dtype=jnp.int32) * T
     n_live = pf_starts[S]
 
-    # Decode-row geometry (forward_decode).
-    with scope("decode_rows"):
-        with scope("embed"):
-            h_d = embed_lookup(params["embed"], dec_tokens,
-                               cfg.dtype)                        # (B, D)
-        with scope("qkv"):
-            cos_d, sin_d = rope_cos_sin(dec_positions[:, None],
-                                        cfg.head_dim, cfg.rope_theta)
-        page_of = dec_block_tables[jnp.arange(B), dec_positions // page_sz]
-        if dec_active is not None:
-            page_of = jnp.where(dec_active, page_of, 0)
-        slot_of = dec_positions % page_sz
-        dec_seq_lens = dec_positions + 1
+    # Decode-row geometry (forward_decode) and the grid's
+    # (forward_prefill).
+    page_of = dec_block_tables[jnp.arange(B), dec_positions // page_sz]
+    if dec_active is not None:
+        page_of = jnp.where(dec_active, page_of, 0)
+    slot_of = dec_positions % page_sz
+    dec_seq_lens = dec_positions + 1
+    pf_grid_pos, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
+                                              pf_starts, T)
 
-    # Slice rows, tight; and the grid's geometry (forward_prefill).
-    with scope("slices"):
-        with scope("embed"):
-            h_p = embed_lookup(params["embed"], pf_tokens,
-                               cfg.dtype)                     # (S*T, D)
-        with scope("qkv"):
-            cos_p, sin_p = rope_cos_sin(pf_positions, cfg.head_dim,
-                                        cfg.rope_theta)
-        pf_grid_pos, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
-                                                  pf_starts, T)
+    # The decode rows lead the tight slice rows.
+    with scope("decode_rows"), scope("embed"):
+        h_d = embed_lookup(params["embed"], dec_tokens, cfg.dtype)  # (B, D)
+    with scope("slices"), scope("embed"):
+        h_p = embed_lookup(params["embed"], pf_tokens, cfg.dtype)   # (N, D)
+    h = jnp.concatenate([h_d, h_p])                             # (B + N, D)
+    with scope("qkv"):
+        cos, sin = rope_cos_sin(
+            jnp.concatenate([dec_positions, pf_positions]), cfg.head_dim,
+            cfg.rope_theta)
 
     lp = params["layers"]
     quant_kv = "k_scale" in kv_cache
@@ -748,15 +754,6 @@ def forward_mixed(
     if quant_kv:
         pools = (k_pool, v_pool, kv_cache["k_scale"], kv_cache["v_scale"])
     for l in range(cfg.n_layers):
-        def qkv(h, cos, sin, l=l):
-            with scope("qkv"):
-                hn = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps)
-                q, k, v = (
-                    linear(hn, layer_slice(lp[w], l)).reshape(
-                        h.shape[0], -1, cfg.head_dim)
-                    for w in ("wq", "wk", "wv"))
-                return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
-
         def out_mlp(h, attn, l=l):
             with scope("attn_out"):
                 h = h + linear(attn.reshape(h.shape[0], -1),
@@ -767,15 +764,21 @@ def forward_mixed(
                                 layer_slice(lp["w_up"], l),
                                 layer_slice(lp["w_down"], l))
 
-        # Slice rows first (order is free — disjoint pages — but fixed
-        # for determinism): write their KV, attend over their history.
+        # All B + S·T rows at once: the three projections are a tenth
+        # of the products and measured no faster a tile at a time
+        # (PERF.md, PR 38).
+        with scope("qkv"):
+            hn = rms_norm(h, lp["attn_norm"][l], cfg.norm_eps)
+            q, k, v = (linear(hn, layer_slice(lp[w], l)).reshape(
+                B + N, -1, cfg.head_dim) for w in ("wq", "wk", "wv"))
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+
+        # Slice rows first (disjoint pages, but the decode rows' write
+        # is the one that takes the pool in place): write their KV,
+        # attend over their history.
         with scope("slices"):
-            # All S·T rows at once: the three projections are a tenth
-            # of the slices' products and measured no faster a tile at
-            # a time (PERF.md, PR 38).
-            q_t, k_t, v_t = qkv(h_p, cos_p, sin_p)
-            q_p, k_p, v_p = (rows_to_grid(x, pf_starts, T)
-                             for x in (q_t, k_t, v_t))
+            q_p, k_p, v_p = (rows_to_grid(x, pf_starts, T, lead=B)
+                             for x in (q, k, v))
             if quant_kv:
                 with scope("kv_write"):
                     pools = paged_kv_write_prefill_q8(
@@ -786,6 +789,10 @@ def forward_mixed(
                         q_p, pools, pf_block_tables, pf_grid_pos,
                         pf_seq_lens, l, enabled=cfg.pallas,
                         multi_ok=cfg.pallas_batched_prefill)
+                # The decode rows' write takes the pool in place: only
+                # once the slices' attention has read it, or XLA copies
+                # the whole pool to keep both.
+                attn_p, pools = lax.optimization_barrier((attn_p, pools))
             else:
                 with scope("kv_write"):
                     k_pool, v_pool = paged_kv_write_prefill(
@@ -798,25 +805,27 @@ def forward_mixed(
                         q_p, k_pool, v_pool, pf_block_tables, pf_grid_pos,
                         pf_seq_lens, l, enabled=cfg.pallas,
                         multi_ok=cfg.pallas_batched_prefill)
-            attn_t = grid_to_rows(attn_p, pf_starts, jnp.zeros_like(q_t))
-            h_p = live_rows(out_mlp, n_live, tile, h_p, attn_t)
+                attn_p, k_pool, v_pool = lax.optimization_barrier(
+                    (attn_p, k_pool, v_pool))
 
-        # Decode rows, same layer: products of their own, so the
-        # layer's weights are read again (see the docstring).
-        with scope("decode_rows"):
-            q_d, k_d, v_d = qkv(h_d, cos_d[:, 0], sin_d[:, 0])
-            with scope("attn"):
-                if quant_kv:
-                    attn_d, pools = paged_decode_step_q8(
-                        q_d, k_d, v_d, pools, dec_block_tables,
-                        dec_seq_lens, page_of, slot_of, jnp.int32(l),
-                        enabled=cfg.pallas)
-                else:
-                    attn_d, k_pool, v_pool = paged_decode_step(
-                        q_d, k_d, v_d, k_pool, v_pool, dec_block_tables,
-                        dec_seq_lens, page_of, slot_of, jnp.int32(l),
-                        enabled=cfg.pallas)
-            h_d = out_mlp(h_d, attn_d)
+        with scope("decode_rows"), scope("attn"):
+            if quant_kv:
+                attn_d, pools = paged_decode_step_q8(
+                    q[:B], k[:B], v[:B], pools, dec_block_tables,
+                    dec_seq_lens, page_of, slot_of, jnp.int32(l),
+                    enabled=cfg.pallas)
+            else:
+                attn_d, k_pool, v_pool = paged_decode_step(
+                    q[:B], k[:B], v[:B], k_pool, v_pool, dec_block_tables,
+                    dec_seq_lens, page_of, slot_of, jnp.int32(l),
+                    enabled=cfg.pallas)
+
+        with scope("slices"):
+            attn = grid_to_rows(
+                attn_p, pf_starts,
+                jnp.concatenate([attn_d, jnp.zeros((N,) + attn_d.shape[1:],
+                                                   attn_d.dtype)]), lead=B)
+        h = live_rows(out_mlp, B + n_live, tile, h, attn, lead=B)
 
     if quant_kv:
         out_cache = {"k": pools[0], "v": pools[1],
@@ -824,11 +833,11 @@ def forward_mixed(
     else:
         out_cache = {"k": k_pool, "v": v_pool}
     with scope("slices"), scope("head"):
-        h_p = rms_norm(h_p[pf_starts[:S] + pf_lengths - 1],
+        h_p = rms_norm(h[B + pf_starts[:S] + pf_lengths - 1],
                        params["final_norm"], cfg.norm_eps)
         pf_logits = _logits(params, h_p)
     with scope("decode_rows"), scope("head"):
-        h_d = rms_norm(h_d, params["final_norm"], cfg.norm_eps)
+        h_d = rms_norm(h[:B], params["final_norm"], cfg.norm_eps)
         return _logits(params, h_d), pf_logits, out_cache
 
 

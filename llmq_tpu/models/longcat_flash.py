@@ -641,7 +641,8 @@ def forward_mixed(params: Params, cfg: LongcatFlashConfig, dec_tokens,
     for l in range(cfg.n_layers):
         h, st = _mixed_layer(
             params, cfg, l, h, cos, sin, attend, live,
-            lambda fn, *rows: live_rows(fn, B + n_live, tile, *rows))
+            lambda fn, *rows: live_rows(fn, B + n_live, tile, *rows,
+                                       lead=B))
         counts.append(st)
     counts = _sum_stats(counts)
     with scope("slices"), scope("head"):

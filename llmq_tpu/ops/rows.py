@@ -5,7 +5,9 @@ A mixed step's prompt tokens lie TIGHT: slice after slice with no gap,
 in one buffer of S x T rows of which the first ``n_live`` hold a token
 (``executor.mixed_chunk_start`` packs them so; ``pf_starts`` (S + 1,)
 says where each slice starts and, last, how many rows are live in
-all). What is a row's own — norms, projections, RoPE, the dense
+all); where a family runs its B decode rows through the same products
+they LEAD that buffer (``lead``), always live, and the live prefix is
+``lead + n_live`` rows. What is a row's own — norms, projections, RoPE, the dense
 feed-forwards, dynamic activation quantisation — can run over that
 prefix a TILE of rows at a time (:func:`live_rows`): the trip count is
 read on the device, so one compiled program multiplies as many tiles as
@@ -39,12 +41,18 @@ def row_tile(width: int) -> int:
 
 
 def worth_a_loop(total: int, tile: int) -> bool:
-    """Whether ``total`` rows are run a tile at a time: more than two
-    tiles. With two or fewer a loop can skip one at most, and a program
-    pays for holding it at every start: SmolLM2's ``mixed_chunk`` (two
-    256-row tiles, a loop a layer) took 13.8-15.1 s to load from XLA's
-    cache as a ``while`` and as a ``cond`` a tile, against 11.6-12.0
-    without (PERF.md, PR 38)."""
+    """THE rule for whether rows are run a tile at a time, which
+    :func:`live_rows` (the program) and :func:`tile_rows` (the host's
+    count) both ask: ``total``, the rows that MAY be dead, are more
+    than two tiles. Rows that lead them and are always live (a mixed
+    step's decode rows, ``lead``) are not counted: they cannot be
+    skipped, so they give a loop nothing to save (SmolLM2's 32 decode
+    rows before 512 slice rows run whole, 544 rows; Mistral's 64 before
+    1,024 loop). With two tiles or fewer a loop can skip one at most,
+    and a program pays for holding it at every start: SmolLM2's
+    ``mixed_chunk`` (two 256-row tiles, a loop a layer) took
+    13.8-15.1 s to load from XLA's cache as a ``while`` and as a
+    ``cond`` a tile, against 11.6-12.0 without (PERF.md, PR 38)."""
     return total > 2 * tile
 
 
@@ -52,19 +60,20 @@ def tile_rows(n_live: int, tile: int, total: int, lead: int = 0) -> int:
     """Host arithmetic, by the rule :func:`live_rows` runs by: the rows
     its tiles COVER when ``n_live`` of ``total`` rows are live behind
     ``lead`` rows that are always live, less those ``lead`` rows (all
-    of them where the rows are not :func:`worth_a_loop`). Where
+    ``total`` where they are not :func:`worth_a_loop`). Where
     ``tile`` does not divide ``lead + total`` and all of it is live,
     the last tile is moved back and runs some rows a second time: those
     are covered once and counted once, so this never passes
     ``total`` while the loop ran up to ``tile - 1`` rows more."""
-    if not worth_a_loop(lead + total, tile):
+    if not worth_a_loop(total, tile):
         return total
     return min(-(-(lead + n_live) // tile) * tile, lead + total) - lead
 
 
-def live_rows(fn: Callable, n_live, tile: int, *rows):
+def live_rows(fn: Callable, n_live, tile: int, *rows, lead: int = 0):
     """``fn`` over the first ``n_live`` rows of ``rows`` (arrays with
-    one leading axis M), ``tile`` rows at a time: ``fn(*tiles)`` takes
+    one leading axis M, the first ``lead`` of them always live and
+    counted in ``n_live``), ``tile`` rows at a time: ``fn(*tiles)`` takes
     the arrays cut to ``tile`` rows and returns an array or a tuple of
     arrays of ``tile`` rows, each row its own row's function. Returns
     the same with M rows: rows past the last live tile are ZERO and
@@ -73,13 +82,14 @@ def live_rows(fn: Callable, n_live, tile: int, *rows):
 
     ONE ``while`` whose trip count, ``ceil(n_live / tile)``, the device
     reads from ``n_live`` (a traced scalar): ``fn`` is traced once and
-    stands once in the program, whatever M. (M rows that are not
-    :func:`worth_a_loop` run whole: every row is computed.) Where
+    stands once in the program, whatever M. (Where the ``M - lead``
+    rows behind the lead are not :func:`worth_a_loop` all M run whole:
+    every row is computed and the program holds no loop.) Where
     ``tile`` does not divide M the last tile is moved back to end at M
     and computes some rows again, to the same values."""
     m = rows[0].shape[0]
     tile = min(tile, m)
-    if not worth_a_loop(m, tile):
+    if not worth_a_loop(m - lead, tile):
         return fn(*rows)
     closed, shapes = jax.make_jaxpr(fn, return_shape=True)(
         *(jax.ShapeDtypeStruct((tile,) + x.shape[1:], x.dtype)

@@ -9,6 +9,13 @@ share (``test_mixed_batch.py``, ``test_mistral_w8kv8.py``,
 The step is traced with ``TILE``-row tiles in slices ``WIDTH`` wide, so
 that a tile's edge falls inside a slice; its own ``jax.jit``, so that
 no other test meets a program traced with the small tile.
+
+``JOINED`` are the cases of a family whose decode rows LEAD the slice
+rows through one product (``models/llama.forward_mixed``), at the two
+served shapes in small: two slices a tile wide (SmolLM2's 2 x 256:
+nothing loops) and two slices two tiles wide (Mistral's 2 x 512: the
+rows loop, and the ``len(DECODE)`` rows that lead move the tiles'
+edges).
 """
 
 import dataclasses
@@ -35,8 +42,48 @@ CASES = {
 }
 #: the decode rows' contexts; the last row is not active
 DECODE = (5, 12, 3)
+#: case -> (slices, width, plan): the served shapes in small, each with
+#: a row that is not active (``DECODE``) and, where the plan is shorter
+#: than ``slices``, a slice that is not used
+JOINED = {
+    # S x T = 2 tiles: all 3 + 16 rows run whole, no loop
+    "two-tiles-whole": (2, TILE, [(5, 0)]),
+    "two-tiles-whole-full": (2, TILE, [(TILE, 0), (TILE, HISTORY)]),
+    # S x T = 4 tiles. 14 live rows are 2 tiles alone, 3 behind the
+    # decode rows that lead: B + n_live crosses an edge
+    "four-tiles-lead-crosses-an-edge": (2, 2 * TILE,
+                                        [(9, HISTORY), (5, 0)]),
+    # 12 live rows are 2 tiles with the lead and without
+    "four-tiles-lead-crosses-no-edge": (2, 2 * TILE, [(12, 0)]),
+    # all live: 35 rows, the fifth tile is moved back to end at the last
+    "four-tiles-full-last-tile-moved-back": (2, 2 * TILE,
+                                             [(16, 0), (16, 0)]),
+}
 
-_steps = {}
+_STATIC = {"forward_mixed": ("cfg",), "forward_decode": ("cfg",),
+           "forward_prefill": ("cfg", "last_only")}
+_jits = {}
+
+
+def _forward(fam, name, as_written=False):
+    """``fam``'s forward function ``name``: its mixed step under a jit
+    of this module's own and, ``as_written``, all three compiled to
+    round where their source rounds. XLA may otherwise keep a bfloat16
+    chain wide inside a fusion (``xla_allow_excess_precision``), and
+    does so differently in two programs of different shape; where
+    activations are quantised on the fly (int8 weights) one such
+    rounding moves a value by a quantum and a logit by 0.05, and the
+    two ways are then two choices of the compiler apart, not two
+    algorithms."""
+    if name != "forward_mixed" and not as_written:
+        return getattr(fam, name)            # the family's own jit
+    key = (fam, name, as_written)
+    if key not in _jits:
+        _jits[key] = jax.jit(
+            getattr(fam, name).__wrapped__, static_argnames=_STATIC[name],
+            compiler_options=({"xla_allow_excess_precision": False}
+                              if as_written else None))
+    return _jits[key]
 
 
 @pytest.fixture
@@ -45,39 +92,45 @@ def tight_step(monkeypatch):
     own, traced (at its first call, inside the test) with the small
     tile."""
     monkeypatch.setattr(rows, "ROW_TILE", TILE)
-
-    def step(fam):
-        if fam not in _steps:
-            _steps[fam] = jax.jit(fam.forward_mixed.__wrapped__,
-                                  static_argnames=("cfg",))
-        return _steps[fam]
-    return step
+    return lambda fam, as_written=False: _forward(fam, "forward_mixed",
+                                                  as_written)
 
 
-def _prefill(fam, cfg, params, cache, bt, toks, start):
+def shape_of(case):
+    """``(slices, width, plan)`` of a case of ``CASES`` or ``JOINED``."""
+    if case in CASES:
+        return SLICES, WIDTH, CASES[case]
+    return JOINED[case]
+
+
+def _prefill(forward_prefill, cfg, params, cache, bt, toks, start,
+             width=WIDTH):
     """One slice alone: ``toks`` at ``start``.. through
-    ``forward_prefill`` in a bucket ``WIDTH`` wide."""
+    ``forward_prefill`` in a bucket ``width`` wide (never under
+    ``WIDTH``: the history's rows are up to 12 tokens)."""
     n = len(toks)
-    padded = np.zeros((1, WIDTH), np.int32)
+    padded = np.zeros((1, width), np.int32)
     padded[0, :n] = toks
-    pos = start + np.minimum(np.arange(WIDTH, dtype=np.int32), n - 1)[None]
-    logits, cache = fam.forward_prefill(
+    pos = start + np.minimum(np.arange(width, dtype=np.int32), n - 1)[None]
+    logits, cache = forward_prefill(
         params, cfg, jnp.asarray(padded), jnp.asarray(pos),
         jnp.asarray([n], jnp.int32), cache, jnp.asarray(bt[None]),
         last_only=True)
     return np.asarray(logits)[0], cache
 
 
-def both_ways(step, fam, cfg, params, case, *, page, cache_dtype=None):
-    """``CASES[case]`` apart and together: ``(parts, mixed)``, each
+def both_ways(step, fam, cfg, params, case, *, page, cache_dtype=None,
+              as_written=False):
+    """The case's plan apart and together: ``(parts, mixed)``, each
     ``{"dec": the active rows' logits, "pf": the used slices' last
     logits, "pages": {pool: all but page 0}}``; ``step=None`` leaves
     the mixed step out."""
-    plan = CASES[case]
-    rng = np.random.default_rng(sorted(CASES).index(case))
-    B, S, T = len(DECODE), SLICES, WIDTH
+    S, T, plan = shape_of(case)
+    rng = np.random.default_rng([*sorted(CASES), *sorted(JOINED)].index(case))
+    B = len(DECODE)
     mp = cfg.max_seq_len // page
     bts = (1 + np.arange((B + S) * mp).reshape(B + S, mp)).astype(np.int32)
+    forward_prefill = _forward(fam, "forward_prefill", as_written)
     cache = fam.init_kv_pages(cfg, 1 + (B + S) * mp, page,
                               dtype=cache_dtype)
 
@@ -91,10 +144,10 @@ def both_ways(step, fam, cfg, params, case, *, page, cache_dtype=None):
                     pool[name][:, 1:].astype(jnp.float32)) for name in pool}}
 
     for b, n in enumerate(DECODE):      # what the decode rows attend to
-        _, cache = _prefill(fam, cfg, params, cache, bts[b], draw(n), 0)
+        _, cache = _prefill(forward_prefill, cfg, params, cache, bts[b], draw(n), 0)
     for s, (_, start) in enumerate(plan):
         if start:                       # what a continuing slice attends to
-            _, cache = _prefill(fam, cfg, params, cache, bts[B + s],
+            _, cache = _prefill(forward_prefill, cfg, params, cache, bts[B + s],
                                 draw(start), 0)
     slices = [draw(n) for n, _ in plan]
     dec_tok, dec_pos = draw(B), np.asarray(DECODE, np.int32)
@@ -104,10 +157,10 @@ def both_ways(step, fam, cfg, params, case, *, page, cache_dtype=None):
     ref = jax.tree.map(jnp.copy, cache)
     ref_pf = []
     for s, (toks, (_, start)) in enumerate(zip(slices, plan)):
-        logits, ref = _prefill(fam, cfg, params, ref, bts[B + s], toks,
-                               start)
+        logits, ref = _prefill(forward_prefill, cfg, params, ref, bts[B + s], toks,
+                               start, T)
         ref_pf.append(logits)
-    ref_dec, ref = fam.forward_decode(
+    ref_dec, ref = _forward(fam, "forward_decode", as_written)(
         params, cfg, jnp.asarray(dec_tok), jnp.asarray(dec_pos), ref,
         jnp.asarray(bts[:B]), active=jnp.asarray(active))
     parts = result(ref_dec, np.stack(ref_pf), ref)
@@ -124,7 +177,7 @@ def both_ways(step, fam, cfg, params, case, *, page, cache_dtype=None):
     tok, pos, starts = rows.pack_grid(grid[0], grid[1], lens,
                                       used=len(plan))
     assert starts[-1] == sum(n for n, _ in plan)
-    dec, pf, got = step(fam)(
+    dec, pf, got = step(fam, as_written)(
         params, cfg, jnp.asarray(dec_tok), jnp.asarray(dec_pos), cache,
         jnp.asarray(bts[:B]), jnp.asarray(tok), jnp.asarray(pos),
         jnp.asarray(lens), jnp.asarray(starts), jnp.asarray(pf_bts),
@@ -135,10 +188,10 @@ def both_ways(step, fam, cfg, params, case, *, page, cache_dtype=None):
 
 
 def check(step, fam, cfg, params, case, *, page, cache_dtype=None,
-          atol=1e-4):
-    """Run ``CASES[case]`` both ways and hold them together."""
+          atol=1e-4, as_written=False):
+    """Run the case both ways and hold them together."""
     parts, mixed = both_ways(step, fam, cfg, params, case, page=page,
-                             cache_dtype=cache_dtype)
+                             cache_dtype=cache_dtype, as_written=as_written)
     for name in ("dec", "pf"):
         np.testing.assert_allclose(mixed[name], parts[name], atol=atol,
                                    err_msg=name)
@@ -149,7 +202,7 @@ def check(step, fam, cfg, params, case, *, page, cache_dtype=None,
 
 
 def check_served(step, fam, cfg, params, case, *, page, cache_dtype=None,
-                 atol, pages_atol):
+                 atol, pages_atol, as_written=False):
     """The same in the precision that is SERVED (``cfg.dtype`` bfloat16),
     where the two ways round their sums in different orders and a bound
     on their distance alone would have to be loose: besides
@@ -160,7 +213,7 @@ def check_served(step, fam, cfg, params, case, *, page, cache_dtype=None,
     carry of the wrong type shows there."""
     assert cfg.dtype == jnp.bfloat16
     parts, mixed = both_ways(step, fam, cfg, params, case, page=page,
-                             cache_dtype=cache_dtype)
+                             cache_dtype=cache_dtype, as_written=as_written)
     wide = jax.tree.map(
         lambda x: x.astype(jnp.float32) if x.dtype == jnp.bfloat16 else x,
         params)

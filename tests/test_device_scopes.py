@@ -235,6 +235,24 @@ def test_every_module_of_the_family_is_named(compiled):
                           for c in p.split("/")}
 
 
+@pytest.mark.parametrize("name", ["llama", "llama-w8kv8"])
+def test_llamas_mixed_step_takes_its_rows_apart_for_attention_alone(name):
+    """``llama.forward_mixed`` runs a layer's ``qkv``, ``attn_out`` and
+    ``mlp`` once for both kinds of row, directly under ``mixed_step``;
+    what is left under ``decode_rows`` is what is the decode rows' own
+    (their embedding, their fused write + attention, their head), and
+    under ``slices`` the slices' own."""
+    _, fn, args = _jobs(_executor(name))[2]
+    step = {tuple(p.split("/")[1:3])
+            for p in _paths(fn.lower(*args).compile().as_text())
+            if p.split("/")[0] == "mixed_step"}
+    assert {("qkv",), ("attn_out",), ("mlp",)} <= {k[:1] for k in step}
+    assert {k[1] for k in step if k[:1] == ("decode_rows",) and k[1:]} == {
+        "embed", "attn", "head"}
+    assert {k[1] for k in step if k[:1] == ("slices",) and k[1:]} == {
+        "embed", "kv_write", "attn", "head"}
+
+
 def test_the_decode_loop_holds_the_modules_and_nothing_of_the_slices(compiled):
     _name, text = compiled
     loop = {p for p in _paths(text["mixed_chunk"])
@@ -264,8 +282,8 @@ def test_scopes_survive_the_export_cache(tmp_path, monkeypatch):
         got = _paths(warm._aot[name].as_text())
         assert got == _paths(cold._aot[name].as_text()), name
     got = _paths(warm._aot["mixed_chunk"].as_text())
-    assert {"mixed_step/slices/qkv", "mixed_step/decode_rows/attn",
-            "decode_loop/mlp"} <= got
+    assert {"mixed_step/qkv", "mixed_step/slices/attn",
+            "mixed_step/decode_rows/attn", "decode_loop/mlp"} <= got
 
 
 # -- (d) slice_tokens beside prefill_tokens ----------------------------------------
@@ -325,11 +343,13 @@ def test_slice_tokens_ride_the_dispatch_and_the_stats(backend):
     ex = eng.executor
     S, T = ex.mixed_prefill_slices, ex.mixed_slice_tokens
     for m in mixed:
-        # the tight rows' live tiles (8-token slices: a tile is 8 rows,
-        # three of them so that they are worth a loop; a Llama
-        # program's decode rows share none of them)
-        n = m["prefill_tokens"]
-        assert m["slice_tokens"] == -(-n // 8) * 8
+        # the live tiles' rows (8-token slices: a tile is 8 rows, three
+        # of them so that they are worth a loop) less the 4 decode rows
+        # that lead them through a Llama program's products
+        n, lead = m["prefill_tokens"], ex.spec.batch_size
+        assert lead == 4
+        assert m["slice_tokens"] == min(-(-(lead + n) // 8) * 8,
+                                        lead + S * T) - lead
         assert 0 < n <= m["slice_tokens"] <= S * T == 24
     assert all(m["slice_tokens"] == 0 == m["prefill_tokens"] for m in plain)
     for m in prefills:

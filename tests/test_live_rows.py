@@ -63,7 +63,43 @@ def test_two_tiles_or_fewer_run_whole(m):
     assert not re.search(r"stablehlo\.(while|case)", text)
     assert not rows.worth_a_loop(m, 8) and rows.worth_a_loop(17, 8)
     assert rows.tile_rows(1, 8, m) == m
-    assert rows.tile_rows(1, 8, m, lead=8) == (m if m == 8 else 8)
+    # rows that lead are always live and never tip the rule
+    assert rows.tile_rows(1, 8, m, lead=8) == m
+    assert rows.tile_rows(1, 8, 17, lead=8) == 8
+
+
+@pytest.mark.parametrize("slices,width,lead,tile,loops", [
+    (2, 256, 32, 256, False),    # SmolLM2: 2 x 256 behind 32 rows, 544 whole
+    (2, 512, 64, 256, True),     # Mistral: 2 x 512 behind 64 rows
+    (4, 512, 128, 256, True),    # LongCat: 4 x 512 behind 128 rows
+    (2, 8, 3, 8, False), (3, 8, 3, 8, True)])
+def test_the_rows_behind_the_lead_alone_decide_whether_rows_loop(
+        slices, width, lead, tile, loops):
+    """``worth_a_loop`` is the one rule, and it is asked of the rows
+    BEHIND the lead: the program (``live_rows``: a ``while`` in its
+    text or none) and the host's count (``tile_rows``, and
+    ``llama.mixed_live_rows`` at a served shape) agree, with the lead
+    and at every ``n_live``."""
+    from llmq_tpu.models import llama
+    slice_rows = slices * width
+    assert rows.worth_a_loop(slice_rows, tile) is loops
+    m = lead + slice_rows
+    x = jax.ShapeDtypeStruct((m, 2), jnp.float32)
+    run = jax.jit(lambda x, n: rows.live_rows(lambda t: t + 1, n, tile, x,
+                                              lead=lead))
+    text = run.lower(x, jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    assert bool(re.search(r"stablehlo\.(while|case)", text)) is loops
+    for n_live in sorted({0, 1, tile - lead, tile - lead + 1, slice_rows // 2,
+                          slice_rows - 1, slice_rows}):
+        out = np.asarray(run(jnp.zeros((m, 2), jnp.float32),
+                             jnp.int32(lead + n_live)))
+        ran = int(out[:, 0].sum())                 # rows that were computed
+        assert ran == lead + rows.tile_rows(n_live, tile, slice_rows,
+                                            lead=lead), n_live
+        assert out[:lead + n_live].all()
+        if tile == rows.row_tile(width):        # a served shape
+            assert llama.mixed_live_rows(n_live, lead, slices,
+                                         width) == ran - lead
 
 
 def test_each_result_keeps_the_type_fn_gives_it():

@@ -17,7 +17,7 @@ from llmq_tpu.engine.executor import EchoExecutor, JaxExecutor
 from llmq_tpu.engine.tokenizer import ByteTokenizer
 from llmq_tpu.models import llama
 from llmq_tpu.models.llama import get_config, init_params
-from mixed_tight import (CASES, check, check_served,  # noqa: F401
+from mixed_tight import (CASES, JOINED, check, check_served,  # noqa: F401
                          tight_step)
 
 
@@ -251,7 +251,7 @@ def make_jax_engine(tiny_model, mixed, *, slots=3, prefix_cache=None,
                            prefix_cache=prefix_cache, mixed_batch=mixed)
 
 
-@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("case", [*CASES, *JOINED])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bf16"])
 def test_the_tight_mixed_step_computes_what_the_parts_do(tight_step, dtype,

@@ -734,21 +734,22 @@ def test_a_mixed_chunk_counts_its_live_tiles_rows(family, tokens, batch,
     the decode rows among them, by the family's rule — which is
     ``ops/rows.live_rows``' rule: its trip count times the tile — never
     under the prompt tokens, never over S x T; ``deepseek_v3``, whose
-    mixed step runs no row tiles, and any program whose rows are two
-    tiles or fewer (``rows.worth_a_loop``) count every row; and
+    mixed step runs no row tiles, and any program whose slice rows are
+    two tiles or fewer (``rows.worth_a_loop``, asked of the rows behind
+    the lead: 512 behind SmolLM2's 32 run whole) count every row; and
     the echo backend, which imports no JAX, counts as a Llama program
     does."""
     from llmq_tpu.engine.executor import EchoExecutor
     from llmq_tpu.models import family as family_module
     from llmq_tpu.ops import rows
     fam = family_module(family)
-    lead = 0 if family == "llama" else batch
+    lead = batch            # both families' decode rows lead the slices'
     tile = rows.row_tile(width)
     assert tile == min(width, 256)
     got = fam.mixed_live_rows(tokens, batch, slices, width)
     assert tokens <= got <= slices * width
     if family == "deepseek_v3" or not rows.worth_a_loop(
-            lead + slices * width, tile):
+            slices * width, tile):
         assert got == slices * width   # no row tiles: every row runs
     else:
         trips = -(-(lead + tokens) // tile)      # live_rows' trip count

@@ -441,6 +441,61 @@ def test_the_tight_mixed_step_copies_no_stacked_matrix_for_v5e(
     assert compiled.as_text().count(" while(") >= cfg.n_layers
 
 
+def _control_flow(compiled):
+    """``while`` and ``conditional`` instructions of a compiled program."""
+    import re
+    return re.findall(r" (while|conditional)\(", compiled.as_text())
+
+
+@pytest.mark.parametrize("served", ["smollm2-1.7b-bf16",
+                                    "mistral-7b-v0.3-w8kv8"])
+def test_llamas_joined_mixed_step_copies_no_pool_and_no_stacked_matrix_for_v5e(
+        one_chip, monkeypatch, served):
+    """Both guards above for ``llama.forward_mixed`` since its decode
+    rows LEAD the tight slice rows through one product a module, at the
+    two served shapes and 4 layers. SmolLM2's (32 rows, two 256-token
+    slices, bf16): all 544 rows run whole and the program holds NO
+    ``while`` and no ``conditional`` — the slice rows alone decide
+    (``ops/rows.worth_a_loop``), and control flow a layer cost its
+    ``mixed_chunk`` 2-3 s at every start (PERF.md, PR 38). Mistral's
+    (64 rows, two 512-token slices, w8a8 over 832 int8 pages):
+    ``attn_out`` + ``mlp`` loop over the 256-row tiles of 1,088 rows,
+    one ``while`` a layer. In both the pools go in and come out in
+    place with no second pool beside the decode rows' aliased write
+    (the barrier behind the slices' attention), and no stacked matrix
+    is copied or transposed whole (q, k, v run un-looped)."""
+    from llmq_tpu.models import llama
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    if served == "smollm2-1.7b-bf16":
+        cfg = llama.LlamaConfig(
+            name="smollm2-4-layers", vocab_size=49152, dim=2048, n_layers=4,
+            n_heads=32, n_kv_heads=32, ffn_dim=8192, max_seq_len=4096,
+            rope_theta=130000.0, tie_embeddings=True,
+            pallas_batched_prefill=True)
+        compiled, params, cache = _mixed_step(
+            llama, cfg, one_chip, B=32, S=2, T=256, pages=3328, page=16)
+        assert not _control_flow(compiled)
+    else:
+        cfg = llama.get_config("mistral-7b-v0.3", n_layers=4,
+                               max_seq_len=2048, pallas_batched_prefill=True)
+        compiled, params, cache = _mixed_step(
+            llama, cfg, one_chip, B=64, S=2, T=512, pages=832, page=128,
+            quantized=True, cache_dtype=jnp.int8)
+        assert _control_flow(compiled).count("while") >= cfg.n_layers
+        assert "%paged_prefill_attention_q8_pallas" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(cache))
+    assert not _whole_copies(compiled, cache)
+    assert not _whole_copies(compiled, params)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 2, (
+        mem.temp_size_in_bytes, pool_bytes)
+
+
 # -- the row state of a hybrid family (granite-4.0-h-micro's sizes) ------------
 
 
