@@ -765,6 +765,11 @@ class InferenceEngine:
                               "window_tokens": 0, "chunks_visited": 0,
                               "chunks_skipped": 0, "cache_live_tokens": 0,
                               "cache_reserved_tokens": 0}
+        #: What one layer's fused decode attention does for a chunk's
+        #: rows at its first step (``executor.attn_work``; None where
+        #: the decode attention is not that kernel's): on the dispatch
+        #: span while a capture is held (``_attn_counts``).
+        self._attn_work = getattr(executor, "attn_work", None)
         self.row_state_rebuilds = 0
         self.row_state_declined = {"prefix": 0, "conversation": 0,
                                    "tiering": 0, "disagg": 0}
@@ -2713,7 +2718,8 @@ class InferenceEngine:
         padding included (the executor's ``slice_tokens``: the live row
         tiles of a mixed chunk, bucket x rows of a prefill program, 0
         otherwise).
-        ``pages_live`` / ``tokens_live`` (``_live_kv``) only while a
+        ``pages_live`` / ``tokens_live`` (``_live_kv``) and the
+        attention kernel's schedule (``_attn_counts``) only while a
         capture is held. The same quantities accumulate for
         ``get_stats()``."""
         self.device_steps += steps
@@ -2736,11 +2742,34 @@ class InferenceEngine:
             counts.update(self._window_counts())
         if capture_held():
             counts["pages_live"], counts["tokens_live"] = self._live_kv()
+            if chunk and rows and self._attn_work is not None:
+                counts.update(self._attn_counts())
             if self._row_state_bytes:
                 # rows whose state the program updates: its decode rows
                 # and, of a prefill or a mixed chunk, its prompt chunks'
                 counts["state_rows"] = rows + state_rows
         return self._prof.span("engine.dispatch", **counts)
+
+    def _attn_counts(self) -> Dict[str, int]:
+        """One attention layer's fused decode call at a chunk's first
+        step, by the host's bookkeeping of the decoding rows' contexts
+        (``executor.attn_work``; computed only while a capture is held:
+        it sorts and walks the batch): ``attn_steps``,
+        ``attn_row_chunks``, ``attn_row_chunks_full`` of a
+        full-attention layer and, for a family with window layers,
+        ``window_steps`` / ``window_chunks_full`` of one of those —
+        nothing where the decode attention is another kernel's."""
+        ctx = np.asarray([s.pos for s in self._slots
+                          if s is not None and s.prefilled], np.int64)
+        work = self._attn_work(ctx + 1)
+        if work is None:
+            return {}
+        out = dict(zip(("attn_steps", "attn_row_chunks",
+                        "attn_row_chunks_full"), work))
+        if self._window:
+            steps, _, full = self._attn_work(ctx + 1, window=True)
+            out["window_steps"], out["window_chunks_full"] = steps, full
+        return out
 
     def _window_counts(self) -> Dict[str, int]:
         """One window layer's counts at a chunk's first step, by the

@@ -1062,6 +1062,25 @@ class JaxExecutor:
         else:
             self.cache = init_kv_pages(model_cfg, num_pages, page_size,
                                        dtype=cache_dtype)
+        #: How the fused decode kernel cuts a call of this geometry
+        #: (``attn_work`` counts by it); None where the decode steps'
+        #: attention is not that kernel's — a family whose pool it does
+        #: not read (the latent ones), off the TPU, under a mesh, at a
+        #: geometry it refuses: the predicate the dispatchers and
+        #: ``decode_order`` go by.
+        self._decode_plan = None
+        pools = tuple(self.cache[name]
+                      for name in ("k", "v", "k_scale", "v_scale")
+                      if name in self.cache)
+        if pools and pools[0].ndim == 4:
+            from llmq_tpu.ops.attention import fused_decode_route
+            from llmq_tpu.ops.pallas.fused_decode import _tile_plan
+            if fused_decode_route(batch_size, pools, max_pages_per_seq,
+                                  model_cfg.head_dim,
+                                  getattr(model_cfg, "pallas", True))[0]:
+                self._decode_plan = _tile_plan(
+                    batch_size, page_size, max_pages_per_seq,
+                    pools[0].shape[3], pools[0].dtype.itemsize)
         self._key = jax.random.PRNGKey(seed)
         #: Speculation plane (docs/performance.md "Speculative
         #: decoding"): ``verify_draft_k`` > 0 builds the verify-window
@@ -1558,6 +1577,31 @@ class JaxExecutor:
         from llmq_tpu.ops.pallas.fused_decode import window_chunks
         return window_chunks(seq_lens, self._window_chunk_tokens,
                              self.attention_window["tokens"])
+
+    def attn_work(self, seq_lens, window: bool = False):
+        """``(steps, row_chunks, row_chunks_full)`` of ONE attention
+        layer's decode call over the decoding rows' ``seq_lens`` (the
+        rest of the batch counted dead): the kernel's (tile, chunk)
+        steps, the (row, chunk) pairs whose products run and those of
+        them in a step where the whole tile is live
+        (``ops/pallas/fused_decode.decode_work`` on the rows in the
+        order the step hands them over) — a full-attention layer's, or
+        with ``window`` a window layer's. A seat that holds no row
+        counts as the context the family's step hands the kernel for it
+        (its ``IDLE_ROW_CONTEXT``; 1, position 0's, where it names
+        none). None where the decode steps' attention is not that
+        kernel's."""
+        if self._decode_plan is None:
+            return None
+        from llmq_tpu.ops.pallas.fused_decode import decode_work
+        lens = np.full(self.spec.batch_size,
+                       getattr(self._family, "IDLE_ROW_CONTEXT", 1), np.int64)
+        lens[:len(seq_lens)] = seq_lens
+        steps, computed, _, full = decode_work(
+            lens, self._decode_plan,
+            self.attention_window["tokens"] if window else None,
+            ordered=True)
+        return steps, computed, full
 
     def _rows_arg(self, rows) -> tuple:
         """The batch rows of a program's prompt chunks as its last

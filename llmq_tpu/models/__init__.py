@@ -69,6 +69,10 @@ A family is a module of this package that defines
   by the rule the program runs by (``get_stats()["mixed_key_blocks"]``);
   a family without it (a prefill kernel that follows the context by
   itself) counts nothing;
+- optionally ``IDLE_ROW_CONTEXT``: the ``seq_len`` its decode step
+  hands the fused decode kernel for a row that is not active, where
+  that is not ``positions + 1`` of an empty seat's position 0 (the
+  executor's ``attn_work`` counts an empty seat by it);
 - ``routes(cfg, cache, *, batch, page_size, max_pages, decode,
   prefill_rows)``: which implementation each attention op of a program
   takes (``ops/attention.kernel_routes``'s form);

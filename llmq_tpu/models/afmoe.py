@@ -75,9 +75,10 @@ import jax.numpy as jnp
 from llmq_tpu.models.latent import draw_groups
 from llmq_tpu.models.latent import prod as _prod
 from llmq_tpu.models.llama import _mlp
-from llmq_tpu.ops.attention import (dispatch_prefill_attention,
+from llmq_tpu.ops.attention import (decode_order,
+                                    dispatch_prefill_attention,
                                     kernel_routes, paged_decode_step,
-                                    paged_kv_write_prefill)
+                                    paged_kv_write_prefill, rows_by_place)
 from llmq_tpu.ops.moe import route, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rope import apply_rope, rope_cos_sin
@@ -89,6 +90,11 @@ KVCache = Dict[str, jnp.ndarray]
 RowState = Dict[str, jnp.ndarray]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+
+#: The context a decode step hands the attention kernel for a row that
+#: is not active (``_decode_geometry``: it attends to nothing) — what
+#: the executor's ``attn_work`` counts an empty seat as.
+IDLE_ROW_CONTEXT = 0
 
 
 @dataclass(frozen=True)
@@ -620,33 +626,43 @@ def _prefill_attn(cfg: AfmoeConfig, l: int, q, k, v, kv_cache, rs, tables,
 
 
 def _decode_geometry(cfg: AfmoeConfig, positions, block_tables, active,
-                     rs: RowState):
+                     kv_cache: KVCache, rs: RowState):
     """What both kinds of layer need of a decode step's rows: ``tables``
     and ``page_of`` by kind (a row that is not active writes to page 0
-    of the pool and of the slabs), ``slot_of``, and ``seq_lens`` with 0
-    for a row that is not active: it attends to nothing."""
+    of the pool and of the slabs), ``slot_of``, ``seq_lens`` with 0 for
+    a row that is not active (it attends to nothing), ``live``, and the
+    ``order`` the attention kernel wants its rows in
+    (``ops/attention.decode_order``: made here ONCE for the step's
+    layers of both kinds — one geometry, so one kernel route, for the
+    pool and the slabs — with the tables, ``page_of`` and ``seq_lens``
+    laid out by it; None where the kernel does not serve)."""
     B = positions.shape[0]
     ps = cfg.page_size
     live = jnp.ones((B,), bool) if active is None else active
     rows = jnp.arange(B, dtype=jnp.int32)
-    tables = {FULL: block_tables,
-              SLIDING: _slab_table(rows, cfg, rs, block_tables.shape[1])}
-    idx = positions // ps
-    page_of = {kind: jnp.where(live, tables[kind][rows, idx], 0)
-               for kind in (FULL, SLIDING)}
-    return (tables, page_of, positions % ps,
-            jnp.where(live, positions + 1, 0), live)
+    kinds = (FULL, SLIDING)
+    tables = (block_tables,
+              _slab_table(rows, cfg, rs, block_tables.shape[1]))
+    page_of = tuple(jnp.where(live, table[rows, positions // ps], 0)
+                    for table in tables)
+    seq_lens = jnp.where(live, positions + 1, 0)
+    order = decode_order(seq_lens, (kv_cache["k"], kv_cache["v"]),
+                         block_tables.shape[1], cfg.head_dim,
+                         enabled=cfg.pallas)
+    seq_lens, *placed = rows_by_place(order, seq_lens, *tables, *page_of)
+    return (dict(zip(kinds, placed[:2])), dict(zip(kinds, placed[2:])),
+            positions % ps, seq_lens, live, order)
 
 
 def _decode_attn(cfg: AfmoeConfig, l: int, q, k, v, kv_cache, rs, geom):
     kind = cfg.layer_types[l]
-    tables, page_of, slot_of, seq_lens, _ = geom
+    tables, page_of, slot_of, seq_lens, _, order = geom
     k_pool, v_pool = _pools(kind, kv_cache, rs)
     with scope("attn"), _attn_scope(kind):
         attn, k_pool, v_pool = paged_decode_step(
             q, k, v, k_pool, v_pool, tables[kind], seq_lens, page_of[kind],
             slot_of, jnp.asarray(cfg.kind_index(l), jnp.int32),
-            enabled=cfg.pallas, window=_window(cfg, kind))
+            enabled=cfg.pallas, window=_window(cfg, kind), order=order)
     return (attn,) + _put(kind, kv_cache, rs, k_pool, v_pool)
 
 
@@ -713,7 +729,8 @@ def forward_decode(params: Params, cfg: AfmoeConfig, tokens: jnp.ndarray,
     row_state, _ = _own_rows(cfg, B, kv_cache, row_state, None)
     h = _embed(params, cfg, tokens)
     cos, sin = _rope_tables(cfg, positions)
-    geom = _decode_geometry(cfg, positions, block_tables, active, row_state)
+    geom = _decode_geometry(cfg, positions, block_tables, active, kv_cache,
+                            row_state)
     lp, counts = params["layers"], []
     for l in range(cfg.n_layers):
         with scope("qkv"):
@@ -767,7 +784,7 @@ def forward_mixed(params: Params, cfg: AfmoeConfig, dec_tokens: jnp.ndarray,
         h_d = _embed(params, cfg, dec_tokens)
         cos_d, sin_d = _rope_tables(cfg, dec_positions)
         geom = _decode_geometry(cfg, dec_positions, dec_block_tables,
-                                dec_active, row_state)
+                                dec_active, kv_cache, row_state)
     with scope("slices"):
         h_p = _embed(params, cfg, pf_tokens)
         cos_p, sin_p = _rope_tables(cfg, pf_positions)

@@ -58,9 +58,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from llmq_tpu.models.llama import _mlp
-from llmq_tpu.ops.attention import (dispatch_prefill_attention,
+from llmq_tpu.ops.attention import (decode_order,
+                                    dispatch_prefill_attention,
                                     kernel_routes, paged_decode_step,
-                                    paged_kv_write_prefill)
+                                    paged_kv_write_prefill, rows_by_place)
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rows import (grid_positions, grid_to_rows, live_rows,
                                row_tile, rows_to_grid, tile_rows,
@@ -605,27 +606,43 @@ def forward_prefill(params: Params, cfg: GraniteHybridConfig,
         return _head(params, cfg, h), {"k": k_pool, "v": v_pool}, row_state
 
 
-def _decode_geometry(positions, block_tables, page_sz, active):
+def _decode_geometry(positions, block_tables, kv_cache: KVCache, active,
+                     cfg: GraniteHybridConfig):
+    """What the attention layers need of a decode step's rows:
+    ``(block_tables, page_of, slot_of, seq_lens, order)``. The hidden
+    rows of this family stay by batch row (the Mamba layers' state is a
+    row's), so the ``order`` the attention kernel wants
+    (``ops/attention.decode_order``, made here once a step) is handed
+    to each attention call, and the three operands that are the same
+    for every layer are laid out by place here."""
     B = positions.shape[0]
-    page_of = block_tables[jnp.arange(B), positions // page_sz]
+    page_of = block_tables[jnp.arange(B), positions // kv_cache["k"].shape[2]]
     if active is not None:
         page_of = jnp.where(active, page_of, 0)
-    return page_of, positions % page_sz, positions + 1
+    seq_lens = positions + 1
+    order = decode_order(seq_lens, (kv_cache["k"], kv_cache["v"]),
+                         block_tables.shape[1], cfg.head_dim,
+                         enabled=cfg.pallas)
+    block_tables, page_of, seq_lens = rows_by_place(order, block_tables,
+                                                    page_of, seq_lens)
+    return (block_tables, page_of, positions % kv_cache["k"].shape[2],
+            seq_lens, order)
 
 
 def _decode_layer(h, lp: Params, l, kind, i, k_pool, v_pool, rs, geom,
-                  block_tables, active, walk, cfg: GraniteHybridConfig):
+                  active, walk, cfg: GraniteHybridConfig):
     """One decode token a row through layer ``l`` (the decode program's
     layer and the decode rows' half of the mixed step's)."""
     if kind == MAMBA:
         h, rs = _mamba_decode(h, lp, l, i, rs, active, walk, cfg)
     else:
-        page_of, slot_of, seq_lens = geom
+        block_tables, page_of, slot_of, seq_lens, order = geom
         q, k, v = _qkv(h, lp, l, i, cfg)
         with scope("attn"):
             attn, k_pool, v_pool = paged_decode_step(
                 q, k, v, k_pool, v_pool, block_tables, seq_lens, page_of,
-                slot_of, jnp.asarray(i, jnp.int32), enabled=cfg.pallas)
+                slot_of, jnp.asarray(i, jnp.int32), enabled=cfg.pallas,
+                order=order)
         h = _attn_out(h, attn, lp, i, cfg)
     return _mlp_block(h, lp, l, cfg), k_pool, v_pool, rs
 
@@ -644,15 +661,14 @@ def forward_decode(params: Params, cfg: GraniteHybridConfig,
     row_state, _ = _own_rows(cfg, B, row_state, None)
     live = jnp.ones((B,), bool) if active is None else active
     h = _embed(params, cfg, tokens)
-    geom = _decode_geometry(positions, block_tables,
-                            kv_cache["k"].shape[2], active)
+    geom = _decode_geometry(positions, block_tables, kv_cache, active, cfg)
     walk = decode_walk(live)
     lp = params["layers"]
 
     def layer(carry, l, kind, i):
         h, k_pool, v_pool, rs = carry
         return _decode_layer(h, lp, l, kind, i, k_pool, v_pool, rs, geom,
-                             block_tables, live, walk, cfg)
+                             live, walk, cfg)
 
     h, k_pool, v_pool, row_state = _run_layers(
         cfg, layer, (h, kv_cache["k"], kv_cache["v"], row_state), True)
@@ -720,8 +736,8 @@ def forward_mixed(params: Params, cfg: GraniteHybridConfig,
 
     with scope("decode_rows"):
         h_d = _embed(params, cfg, dec_tokens)
-        geom = _decode_geometry(dec_positions, dec_block_tables,
-                                kv_cache["k"].shape[2], dec_active)
+        geom = _decode_geometry(dec_positions, dec_block_tables, kv_cache,
+                                dec_active, cfg)
         walk = decode_walk(live)
     with scope("slices"):
         h_p = _embed(params, cfg, pf_tokens)
@@ -775,8 +791,8 @@ def forward_mixed(params: Params, cfg: GraniteHybridConfig,
                 h_p = live_rows(out, n_live, tile, h_p, attn)
         with scope("decode_rows"):
             h_d, k_pool, v_pool, rs = _decode_layer(
-                h_d, lp, l, kind, i, k_pool, v_pool, rs, geom,
-                dec_block_tables, live, walk, cfg)
+                h_d, lp, l, kind, i, k_pool, v_pool, rs, geom, live, walk,
+                cfg)
         return h_p, h_d, k_pool, v_pool, rs
 
     h_p, h_d, k_pool, v_pool, row_state = _run_layers(

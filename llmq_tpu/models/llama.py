@@ -39,12 +39,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from llmq_tpu.ops.attention import (dispatch_prefill_attention,
+from llmq_tpu.ops.attention import (decode_order,
+                                    dispatch_prefill_attention,
                                     dispatch_prefill_attention_q8,
                                     paged_decode_step,
                                     paged_decode_step_q8,
                                     paged_kv_write_prefill,
-                                    paged_kv_write_prefill_q8)
+                                    paged_kv_write_prefill_q8,
+                                    rows_by_place)
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.quant import (embed_lookup, is_quantized, layer_slice,
                                 linear, tied_head_logits)
@@ -517,6 +519,30 @@ def forward_prefill(
         return _logits(params, h), out_cache
 
 
+def _decode_geometry(cfg, kv_cache: KVCache, positions, block_tables,
+                     active):
+    """What the layers' attention needs of a decode step's rows:
+    ``(block_tables, seq_lens, page_of, slot_of, order)`` — a row that
+    is not active writes to page 0. The first three are laid out by the
+    ``order`` the attention kernel wants its rows in
+    (``ops/attention.decode_order``, made here ONCE for all the step's
+    layers; None, and nothing moved, where the kernel does not serve):
+    every layer's ``paged_decode_step*`` is handed them with it."""
+    page_sz = kv_cache["k"].shape[2]
+    page_of = block_tables[jnp.arange(positions.shape[0]),
+                           positions // page_sz]
+    if active is not None:
+        page_of = jnp.where(active, page_of, 0)
+    seq_lens = positions + 1
+    pools = (kv_cache["k"], kv_cache["v"])
+    if "k_scale" in kv_cache:
+        pools += (kv_cache["k_scale"], kv_cache["v_scale"])
+    order = decode_order(seq_lens, pools, block_tables.shape[1],
+                         cfg.head_dim, enabled=cfg.pallas)
+    return rows_by_place(order, block_tables, seq_lens, page_of) + (
+        positions % page_sz, order)
+
+
 @partial(jax.jit, static_argnames=("cfg",))
 def forward_decode(
     params: Params,
@@ -535,18 +561,14 @@ def forward_decode(
     chunk scatter their KV to reserved page 0 instead of the real pages.
     """
     B = tokens.shape[0]
-    page_sz = kv_cache["k"].shape[2]
 
     with scope("embed"):
         h = embed_lookup(params["embed"], tokens, cfg.dtype)   # (B, D)
     with scope("qkv"):
         cos, sin = rope_cos_sin(positions[:, None], cfg.head_dim,
                                 cfg.rope_theta)            # (B,1,half)
-    page_of = block_tables[jnp.arange(B), positions // page_sz]
-    if active is not None:
-        page_of = jnp.where(active, page_of, 0)
-    slot_of = positions % page_sz
-    seq_lens = positions + 1
+    block_tables, seq_lens, page_of, slot_of, order = _decode_geometry(
+        cfg, kv_cache, positions, block_tables, active)
 
     # Layers are UNROLLED (no scan) and the stacked pool threads through
     # one aliased Pallas write + one attention read per layer. This is
@@ -580,12 +602,13 @@ def forward_decode(
             if quant_kv:
                 attn, pools = paged_decode_step_q8(
                     q, k, v, pools, block_tables, seq_lens,
-                    page_of, slot_of, jnp.int32(l), enabled=cfg.pallas)
+                    page_of, slot_of, jnp.int32(l), enabled=cfg.pallas,
+                    order=order)
             else:
                 attn, k_pool, v_pool = paged_decode_step(
                     q, k, v, k_pool, v_pool, block_tables, seq_lens,
                     page_of, slot_of, jnp.int32(l),
-                    enabled=cfg.pallas)                    # (B, H, D)
+                    enabled=cfg.pallas, order=order)       # (B, H, D)
         with scope("attn_out"):
             h = h + linear(attn.reshape(B, -1), layer_slice(lp["wo"], l))
         with scope("mlp"):
@@ -715,7 +738,6 @@ def forward_mixed(
     S = pf_lengths.shape[0]
     N = pf_tokens.shape[0]
     T = N // S
-    page_sz = kv_cache["k"].shape[2]
     tile = row_tile(T)
     if not worth_a_loop(N, tile):
         # Rows that run whole: every slice back at its own T rows, so
@@ -729,11 +751,9 @@ def forward_mixed(
 
     # Decode-row geometry (forward_decode) and the grid's
     # (forward_prefill).
-    page_of = dec_block_tables[jnp.arange(B), dec_positions // page_sz]
-    if dec_active is not None:
-        page_of = jnp.where(dec_active, page_of, 0)
-    slot_of = dec_positions % page_sz
-    dec_seq_lens = dec_positions + 1
+    dec_block_tables, dec_seq_lens, page_of, slot_of, order = (
+        _decode_geometry(cfg, kv_cache, dec_positions, dec_block_tables,
+                         dec_active))
     pf_grid_pos, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
                                               pf_starts, T)
 
@@ -813,12 +833,12 @@ def forward_mixed(
                 attn_d, pools = paged_decode_step_q8(
                     q[:B], k[:B], v[:B], pools, dec_block_tables,
                     dec_seq_lens, page_of, slot_of, jnp.int32(l),
-                    enabled=cfg.pallas)
+                    enabled=cfg.pallas, order=order)
             else:
                 attn_d, k_pool, v_pool = paged_decode_step(
                     q[:B], k[:B], v[:B], k_pool, v_pool, dec_block_tables,
                     dec_seq_lens, page_of, slot_of, jnp.int32(l),
-                    enabled=cfg.pallas)
+                    enabled=cfg.pallas, order=order)
 
         with scope("slices"):
             attn = grid_to_rows(

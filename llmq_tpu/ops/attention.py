@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import os
 import threading
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -335,8 +336,10 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
                   prefill_rows: int = 0) -> dict:
     """Which implementation each attention op of ONE serving program
     takes at this geometry: ``{op: "pallas:<kernel function>" | "xla"}``;
-    a fused decode kernel is followed by the plan it was built with,
-    ``(rows=<a tile>,chunk_tokens=<a chunk>)``.
+    a fused decode kernel is followed by the plan it was built with
+    and by what it is handed,
+    ``(rows=<a tile>,chunk_tokens=<a chunk>,ordered)``: the step's rows
+    in :func:`decode_order`'s order.
     Evaluates the very predicates the dispatchers below call at trace
     time (nested-jit trace caching makes a trace-time recorder miss
     programs), so the executor can log the decision where the program
@@ -358,7 +361,8 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
             return route
         from llmq_tpu.ops.pallas.fused_decode import _tile_plan
         plan = _tile_plan(batch, page_size, max_pages, gd, itemsize)
-        return f"{route}(rows={plan.rows},chunk_tokens={plan.chunk_tokens})"
+        return (f"{route}(rows={plan.rows},"
+                f"chunk_tokens={plan.chunk_tokens},ordered)")
 
     out = {}
     if prefill_rows:
@@ -385,6 +389,71 @@ def kernel_routes(*, batch: int, page_size: int, max_pages: int,
             out["decode_write"] = (route if route != "xla"
                                    else pick(True, "_kv_write_kernel"))
     return out
+
+
+def fused_decode_route(B: int, pools, max_pages: int, head_dim: int,
+                       enabled: bool):
+    """``(use_kernel, interpret)`` of a decode step of ``B`` rows over
+    ``pools`` — (k, v) or the int8 (k, v, k_scale, v_scale): what
+    :func:`paged_decode_step` / :func:`paged_decode_step_q8` take and
+    :func:`decode_order` is made for."""
+    k_pool = pools[0]
+    _, _, page_size, gd = k_pool.shape
+    if len(pools) == 4:
+        ok = _fused_decode_q8_ok(B, page_size, max_pages, gd,
+                                 gd // head_dim, pools[2].shape[2])
+    else:
+        ok = _fused_decode_ok(B, page_size, max_pages, gd,
+                              k_pool.dtype.itemsize)
+    return _kernel_route(gd, enabled=enabled, extra_ok=ok)
+
+
+class DecodeOrder(NamedTuple):
+    """A decode step's rows in the order the fused kernel is handed
+    them (:func:`decode_order`)."""
+    rows: jnp.ndarray       # (B,) int32: the batch row at each place
+    places: jnp.ndarray     # (B,) int32: each batch row's place
+
+
+def rows_by_place(order: Optional[DecodeOrder], *per_row):
+    """Arrays of one entry a batch row, laid out by place — as they
+    came where no order was made (:func:`decode_order` gave None)."""
+    if order is None:
+        return per_row
+    return tuple(x[order.rows] for x in per_row)
+
+
+def _rows_back(order: Optional[DecodeOrder], by_place):
+    """What the kernel computed by place, a batch row at a time again."""
+    return by_place if order is None else by_place[order.places]
+
+
+def decode_order(seq_lens, pools, max_pages: int, head_dim: int, *,
+                 enabled: bool = True) -> Optional[DecodeOrder]:
+    """The order in which a decode step's rows reach the fused decode
+    kernel: longest context first, ``seq_len`` 0 last, ties by row — so
+    the eight rows of a kernel tile end in the same chunks, and a step
+    in which a tile's rows are all live is the rule (the kernel's
+    design note, v5). Made ONCE a step from the ``seq_lens`` the step
+    has, for all its layers (as ``ops/ssm.decode_walk`` is), and handed
+    to :func:`paged_decode_step` / :func:`paged_decode_step_q8` with the
+    step's ``block_tables``, ``seq_lens`` and ``page_of`` laid out by it
+    (:func:`rows_by_place`, once a step too: they are every layer's);
+    ``None`` where the step's attention is not the kernel's (off the
+    TPU, under a mesh, at a geometry its predicate refuses): nothing is
+    permuted there. A count of the rows that come before each row —
+    B x B comparisons in one fusion, no sort — gives every row its
+    place."""
+    B = seq_lens.shape[0]
+    if not fused_decode_route(B, pools, max_pages, head_dim, enabled)[0]:
+        return None
+    row = jnp.arange(B, dtype=jnp.int32)
+    mine, other = seq_lens[:, None], seq_lens[None, :]
+    before = (other > mine) | ((other == mine) & (row[None, :] < row[:, None]))
+    places = jnp.sum(before, axis=1, dtype=jnp.int32)
+    rows = jnp.sum(jnp.where(places[None, :] == row[:, None], row[None, :],
+                             0), axis=1, dtype=jnp.int32)
+    return DecodeOrder(rows, places)
 
 
 def paged_kv_write(k_pool, v_pool, k_new, v_new, page_of, slot_of, layer,
@@ -525,7 +594,7 @@ def dispatch_prefill_attention(q, k_pool, v_pool, block_tables, positions,
 
 def paged_decode_step(q, k_new, v_new, k_pool, v_pool, block_tables,
                       seq_lens, page_of, slot_of, layer, *,
-                      enabled: bool = True, window=None):
+                      enabled: bool = True, window=None, order=None):
     """One decode layer's KV write + attention, fused where possible.
     ``window`` (static): a row sees its last ``window`` keys, its
     current token counted; ``None``: all of them.
@@ -536,18 +605,24 @@ def paged_decode_step(q, k_new, v_new, k_pool, v_pool, block_tables,
     through the aliased pool, halving per-layer kernel launches and
     dropping the write kernel's separate page round-trip. Fallback:
     the row-RMW write kernel / scatter followed by pooled attention.
+
+    ``order`` (:func:`decode_order` of this step's ``seq_lens``, or
+    None): the kernel is handed the rows in that order. ``q``, the new
+    rows and the attention returned are by batch row whatever the order
+    (a gather each, here); ``block_tables``, ``seq_lens`` and
+    ``page_of``, every layer's, come ALREADY by place.
     Returns (attn, k_pool, v_pool).
     """
-    use_kernel, interpret = _kernel_route(
-        k_pool.shape[3], enabled=enabled,
-        extra_ok=_fused_decode_ok(q.shape[0], k_pool.shape[2],
-                                  block_tables.shape[1], k_pool.shape[3],
-                                  k_pool.dtype.itemsize))
+    use_kernel, interpret = fused_decode_route(
+        q.shape[0], (k_pool, v_pool), block_tables.shape[1], q.shape[2],
+        enabled)
+    assert order is None or use_kernel, "an order is the kernel's alone"
     if use_kernel:
+        q, k_new, v_new = rows_by_place(order, q, k_new, v_new)
         attn, (k_pool, v_pool) = _jit_fused_decode()(
             q, k_new, v_new, k_pool, v_pool, block_tables, seq_lens,
             page_of, layer, interpret=interpret, window=window)
-        return attn, k_pool, v_pool
+        return _rows_back(order, attn), k_pool, v_pool
     k_pool, v_pool = paged_kv_write(k_pool, v_pool, k_new, v_new,
                                     page_of, slot_of, layer,
                                     distinct_pages=True, enabled=enabled)
@@ -660,10 +735,12 @@ def _dequant_window(k_pool, scale_pool, layer, block_tables, D):
 
 
 def paged_decode_step_q8(q, k_new, v_new, pools, block_tables, seq_lens,
-                         page_of, slot_of, layer, *, enabled: bool = True):
+                         page_of, slot_of, layer, *, enabled: bool = True,
+                         order=None):
     """One decode layer against the int8 KV pools: quantize the current
     token's K/V per (row, head), write rows + scales, attend over the
-    dequantized paged history. Returns (attn, pools).
+    dequantized paged history. ``order``: :func:`paged_decode_step`'s.
+    Returns (attn, pools).
 
     TPU path: the int8 fused kernel (fused_decode.py) — same
     write+attend fusion as bf16, half the page DMA bytes. Fallback:
@@ -673,20 +750,24 @@ def paged_decode_step_q8(q, k_new, v_new, pools, block_tables, seq_lens,
 
     k_pool, v_pool, ks_pool, vs_pool = pools
     B, H, D = q.shape
+    use_kernel, interpret = fused_decode_route(
+        B, pools, block_tables.shape[1], D, enabled)
+    assert order is None or use_kernel, "an order is the kernel's alone"
     kq, kscale = quantize_kv_rows(k_new)    # (B, Hkv, D) i8, (B, Hkv)
     vq, vscale = quantize_kv_rows(v_new)
 
-    use_kernel, interpret = _kernel_route(
-        k_pool.shape[3], enabled=enabled,
-        extra_ok=_fused_decode_q8_ok(B, k_pool.shape[2],
-                                     block_tables.shape[1],
-                                     k_pool.shape[3], k_pool.shape[3] // D,
-                                     ks_pool.shape[2]))
     if use_kernel:
+        # By place AFTER the rows are quantised: a gather in front of
+        # it moves the fusion in which XLA rounds them (excess
+        # precision) and with that int8 steps of the whole model; so,
+        # the served 64-row step is the seated step's to the bit on the
+        # chip (PERF.md §6, PR 43).
+        q, kq, kscale, vq, vscale = rows_by_place(order, q, kq, kscale,
+                                                  vq, vscale)
         attn, pools = _jit_fused_decode_q8()(
             q, kq, kscale, vq, vscale, pools, block_tables, seq_lens,
             page_of, layer, interpret=interpret)
-        return attn, pools
+        return _rows_back(order, attn), pools
 
     k_pool = k_pool.at[layer, page_of, slot_of].set(kq.reshape(B, -1))
     v_pool = v_pool.at[layer, page_of, slot_of].set(vq.reshape(B, -1))

@@ -8,7 +8,7 @@ schedule: ``fused_decode_attention_pallas`` (bf16 pools) and
 ``fused_decode_attention_q8_pallas`` (int8 pools with bf16 scale
 pools).
 
-Design (v4 — fourth shape of this kernel; the numbers that drove it):
+Design (v5 — fifth shape of this kernel; the numbers that drove it):
 
 - r2 kernel: per-row grid, per-row page-merge writeback, within-row
   double buffering → ~34µs/row at B=64 (≈16ms of a 21ms decode step),
@@ -38,13 +38,60 @@ Design (v4 — fourth shape of this kernel; the numbers that drove it):
   one call, v3 → v4): 4 rows of 360 tokens 85 → 27 µs, 2 rows of 2,000
   180 → 54, 31 rows mixed over 300-1,500 417 → 326, Mistral's 60 rows
   over 300-1,500 291 → 241; a block table twice as wide changes none.
-- **Live rows go two at a time** (``_GROUP``): a row's visit is a
+- **Listed rows go two at a time** (``_GROUP``; a ragged step's rows
+  since v5): a row's visit is a
   dependent chain — QK^T on the MXU, the softmax on the VPU, PV on the
   MXU — whose latencies nothing fills, ≈0.37 µs a visit; the products
   of two rows in ONE straight line of code let the scheduler fill one
   row's bubbles with the other's work (Mistral, 60 rows of 660: 197 →
   183 µs; groups of 4 or 8: 180, 179 — the rest is v3's eight-row
   batch, 165, which also multiplied for rows that were not there).
+- **v5: the rows come ordered by context, and a step in which the
+  whole tile is live is one static block** (PR 43). v4 met its rows in
+  SEAT order, so a tile of eight held contexts drawn at random and was
+  ragged in every chunk but its first: at Mistral's served mix (59 of 64
+  rows over 130-1,536 tokens) 31 % of the row-chunks ran in a step where
+  all eight rows were live, and a tile ran to its one long row's last
+  chunk (42.7 steps a call). Handed the rows longest first
+  (``ops/attention.decode_order``: a count of the rows that come before
+  each row, made ONCE a decode step on the device from the ``seq_lens``
+  the step has; dead rows last, ties by row) the same kernel runs 30.7
+  steps and 91 % of its row-chunks in full steps (``decode_work``'s
+  fourth count, on the host). A full step is ONE group of the tile's R
+  rows whose list is 0 … R − 1: the rows are prepared and finished as
+  any group's (one trace of ``prepare`` / ``finish`` serves both paths:
+  the program grew by 10-14 kB of text a call, not by a second kernel),
+  and the products name their rows STATICALLY — every row's logits,
+  then every row's softmax update and second product, in one straight
+  line. What the one-row visit
+  could not hide (its QK^T → softmax → PV chain, the int8 → bf16
+  conversion before each product) the scheduler now fills with the
+  other rows' work: a listed visit reads its row from SMEM, so every
+  index of it is dynamic and two visits' stores to ``m/l/acc`` cannot be
+  told apart; a static block's can. On the chip (Mistral's geometry, 60
+  rows, µs a call; PERF.md §6, PR 43): the cell's mix over 300-1,500
+  shuffled 252.1 → 190.5 (the order alone 242.8, the static block alone
+  in seat order 226.9: neither half shows alone); 60 rows of 660 191.8 →
+  149.1, under v3's eight-row batch (164.5) without its waste. Outputs
+  are v4's to the bit in every order (an order changes no row's
+  arithmetic). How the order reaches the body: the dispatchers
+  (``ops/attention.paged_decode_step`` / ``_q8``, ``order=``) gather q
+  and the new rows by place and put the attention back by batch row at
+  every call, the block tables, ``seq_lens`` and write pages being laid
+  out once a step — ONE mechanism for every family (``granitemoehybrid``'s
+  Mamba layers keep state by batch row, so its rows could not move for
+  a step anyway), and the program around the call is the parent's.
+  Tried and not kept: blocks of 4 rows (190.2: level with 8), of 2
+  (198.6), eight static visits one after another in one straight line
+  (232.5; listed pairs: 242.8) — the gain is in the block's order, all
+  its rows' first products before any row's softmax, which the
+  compiler's scheduler keeps and does not find for itself, so the block
+  is the tile and there is no setting; and moving the step's hidden
+  rows once in ``llama`` / ``afmoe`` instead of the gathers a call
+  (3.3 µs a call at Mistral's geometry, 0.6 % of a step: a second
+  mechanism in two families for less than the cell resolves, and its
+  gather in front of the final norm cost the logits their bit-equality
+  with the parent's).
 - **The mask is made in the kernel**: per-row code compares an iota
   with the row's ``seq_len`` scalar, so nothing has to stack SMEM
   scalars into a vector — the bias array (2 MiB a call at SmolLM2's
@@ -406,16 +453,12 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
                                   write_page_ref[row]):
                 copy.start()
 
-    def attend(r, row, c, slot):
-        """Row ``r``'s two products over its live chunk ``c`` and the
-        online-softmax update into ``m/l/acc[r]``: straight-line code,
-        so the rows of one group interleave on the MXU and the VPU."""
+    def scores(r, row, c, slot):
+        """Row ``r``'s masked logits (H, S) over its live chunk ``c``."""
         q = qbd_ref[r]                                       # (H, GD)
         k = k_buf[slot, r].reshape(S, GD)
-        v = v_buf[slot, r].reshape(S, GD)
         if quantized:
             k = k.astype(jnp.bfloat16)
-            v = v.astype(jnp.bfloat16)
         # Operands stay bf16 — the MXU consumes bf16 natively with f32
         # accumulation; f32 inputs run emulated at a fraction of the
         # rate.
@@ -430,8 +473,14 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
         seen = pos < seq_lens_ref[row]
         if window is not None:
             seen = seen & (pos >= seq_lens_ref[row] - window)
-        logits = jnp.where(seen, logits, NEG_INF)
+        return jnp.where(seen, logits, NEG_INF)
 
+    def absorb(r, logits, slot):
+        """The online-softmax update of ``m/l/acc[r]`` by a chunk's
+        ``logits`` and the row's second product."""
+        v = v_buf[slot, r].reshape(S, GD)
+        if quantized:
+            v = v.astype(jnp.bfloat16)
         m_prev = m_ref[r]
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -446,6 +495,16 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (H, GD)
         acc_ref[r] = acc_ref[r] * alpha + pv
+
+    def attend(rs, c, slot):
+        """The two products of the rows ``rs`` (tile rows) over their
+        live chunk ``c``, ONE straight line of code: every row's logits,
+        then every row's update — so the rows' MXU and VPU chains
+        interleave. Over static ``rs`` (a full step's block) no index
+        of it is read from SMEM."""
+        logits = [scores(r, t * R + r, c, slot) for r in rs]
+        for r, x in zip(rs, logits):
+            absorb(r, x, slot)
 
     def finish(r, row, slot):
         """After the products of the row's LAST chunk: drain its
@@ -500,13 +559,18 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
 
         n_rows = jax.lax.fori_loop(0, R, note_live, 0)
 
+        # A step in which the whole tile is live is ONE group, and its
+        # list is 0 … R − 1: its products name their rows statically.
+        full = n_rows == R
+        group = jnp.where(full, R, _GROUP)
+
         def visit(g, _):
             """One group of the live rows: each prepared, then their
             products — a full group's as ONE straight line, so the rows'
             MXU and VPU chains interleave — then the finished rows'
             outputs."""
-            first = g * _GROUP
-            end = jnp.minimum(first + _GROUP, n_rows)
+            first = g * group
+            end = jnp.minimum(first + group, n_rows)
 
             def each(fn):
                 def body(i, _):
@@ -517,16 +581,20 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
 
             each(lambda i, r, row: prepare(r, row, c, slot,
                                            live_pages_of[i]))
+            listed = jnp.logical_not(full)
 
-            @pl.when(end - first == _GROUP)
+            @pl.when(listed & (end - first == _GROUP))
             def _():
                 for i in range(_GROUP):
-                    r = live_rows[first + i]
-                    attend(r, t * R + r, c, slot)
+                    attend([live_rows[first + i]], c, slot)
 
-            @pl.when(end - first < _GROUP)
+            @pl.when(listed & (end - first < _GROUP))
             def _():
-                each(lambda i, r, row: attend(r, row, c, slot))
+                each(lambda i, r, row: attend([r], c, slot))
+
+            @pl.when(full)
+            def _():
+                attend(list(range(R)), c, slot)
 
             def finish_if_last(i, r, row):
                 @pl.when(last_chunk(row, c)[0])
@@ -536,7 +604,7 @@ def _decode_kernel(*refs, quantized: bool, rows_per_tile: int,
             each(finish_if_last)
             return 0
 
-        jax.lax.fori_loop(0, (n_rows + _GROUP - 1) // _GROUP, visit, 0)
+        jax.lax.fori_loop(0, (n_rows + group - 1) // group, visit, 0)
         state[_CONSUMED] = consumed + 1
         return 0
 
@@ -565,15 +633,20 @@ def _tile_plan(B: int, page_size: int, max_pages: int, GD: int,
     in pages instead) and whose scratch — two slots of K and of V for a
     tile's rows — is within ``SCRATCH_BUDGET_BYTES`` (an int8 pool's
     scale pages, 2 bytes a KV head beside a token's H_kv·D, ride in the
-    headroom)."""
+    headroom). The limit asked of the compiler also holds what a full
+    step's block of an int8 pool stands up before its first product:
+    the K and V chunks of the tile's rows as bf16."""
     limit = pages_per_chunk if pages_per_chunk > 0 else max(
         1, CHUNK_TOKENS // page_size)
     for R in ([8] if B % 8 == 0 and B != 8 else []) + [B]:
         for ppc in range(min(limit, max_pages), 0, -1):
             scratch = 2 * 2 * R * ppc * page_size * GD * itemsize
             if max_pages % ppc == 0 and scratch <= SCRATCH_BUDGET_BYTES:
-                return DecodePlan(R, ppc, ppc * page_size, scratch,
-                                  scratch + _VMEM_HEADROOM_BYTES)
+                temporaries = (2 * R * ppc * page_size * GD * 2
+                               if itemsize == 1 else 0)
+                return DecodePlan(
+                    R, ppc, ppc * page_size, scratch,
+                    scratch + _VMEM_HEADROOM_BYTES + temporaries)
     return None
 
 
@@ -597,29 +670,37 @@ def window_chunks(seq_lens, chunk_tokens: int, window=None):
             int(np.sum(skipped)))
 
 
-def decode_work(seq_lens, plan: DecodePlan, window=None):
+def decode_work(seq_lens, plan: DecodePlan, window=None, *,
+                ordered: bool = False):
     """What one call does for a batch of ``seq_lens`` under ``plan``,
     counted on the host by the kernel's own schedule: ``(steps,
-    row_chunks_computed, row_chunks_live)`` — the (tile, chunk) steps
-    its loops run, the (row, chunk) pairs whose products run, and the
-    pairs in which a row holds a position it can see (under a
-    ``window``: from the chunk of its oldest visible key on). The last
-    two are equal when the kernel does the batch's work and no more;
-    none depends on the block table's width."""
+    row_chunks_computed, row_chunks_live, row_chunks_full)`` — the
+    (tile, chunk) steps its loops run, the (row, chunk) pairs whose
+    products run, the pairs in which a row holds a position it can see
+    (under a ``window``: from the chunk of its oldest visible key on),
+    and the pairs that ran in a step where the whole tile was live (the
+    static path, where the plan has one). The second and third are
+    equal when the kernel does the batch's work and no more; none
+    depends on the block table's width. ``seq_lens`` are in the order
+    the kernel is handed, or by batch row with ``ordered``: they are
+    then put in ``ops/attention.decode_order``'s order first (longest
+    first, ties by row), as the device does."""
     seq_lens = np.asarray(seq_lens, np.int64)
-    page_size = plan.chunk_tokens // plan.pages_per_chunk
-    steps = computed = 0
-    for tile in seq_lens.reshape(-1, plan.rows):
-        n_chunks = int(_tile_chunks(list(tile), plan.chunk_tokens, np))
-        first = int(_tile_first_chunk(list(tile), n_chunks,
-                                      plan.chunk_tokens, window, np))
-        steps += n_chunks - first
-        for c in range(first, n_chunks):
-            computed += int((_live_pages(
-                tile, c, plan.pages_per_chunk, page_size, np,
-                window) > 0).sum())
-    return steps, computed, window_chunks(seq_lens, plan.chunk_tokens,
-                                          window)[0]
+    if ordered:
+        seq_lens = seq_lens[np.argsort(-seq_lens, kind="stable")]
+    S, R = plan.chunk_tokens, plan.rows
+    tiles = seq_lens.reshape(-1, R)
+    by_row = list(tiles.T)
+    n_chunks = _tile_chunks(by_row, S, np)                      # (tiles,)
+    first = _tile_first_chunk(by_row, n_chunks, S, window, np)
+    chunk = np.arange(n_chunks.max())[None, :, None]
+    live = _live_pages(tiles[:, None, :], chunk, plan.pages_per_chunk,
+                       S // plan.pages_per_chunk, np, window) > 0
+    live &= ((chunk >= np.reshape(first, (-1, 1, 1)))
+             & (chunk < n_chunks[:, None, None]))       # the steps it runs
+    return (int(np.sum(n_chunks - first)), int(live.sum()),
+            window_chunks(seq_lens, S, window)[0],
+            R * int(live.all(axis=-1).sum()))
 
 
 def fused_kernel_viable(B: int, page_size: int, max_pages: int, GD: int,

@@ -29,6 +29,16 @@ live) and the share of the K/V bytes' time at 819 GB/s (the benchmark's
 this one), ``--max-pages N`` widens or narrows the block table,
 ``--pages-per-chunk N`` overrides the plan's chunk, ``--out F`` writes
 the numbers to F and each occupancy's attention output beside it.
+``--order seat|sorted|gathered`` (repeatable) says how the rows reach
+the kernel: as seated, in ``ops/attention.decode_order``'s order (the
+kernel alone), or in that order through a gather of q, the new rows and
+the output at every call, as serving has them
+(``paged_decode_step(order=...)``); the fourth count printed is the
+row-chunks that ran in full steps.
+``--fused`` runs this bench at ANY file's heads and pages (granite's
+four attention layers: ``--fused --layers 4``; Trinity's full layer:
+``--fused --layers 1``, its sliding ones with ``--window 4096``;
+``--kv-pages`` sizes the pool).
 
 ``--prefill LENGTH@START`` (repeatable, and then ``--lens`` may be left
 out) times ONE slice of the mixed step through the prefill attention:
@@ -223,10 +233,13 @@ def bench_fused(args, doc) -> None:
     elif jax.default_backend() != "tpu":
         sys.exit("no TPU here: a time from this host is no device "
                  "number (--rehearse runs the path in interpret mode)")
-    B, ps, P = ex["max_batch_size"], ex["page_size"], ex["kv_pages"]
+    B, ps = ex["max_batch_size"], ex["page_size"]
+    P = args.kv_pages or ex["kv_pages"]
+    L = args.layers or L
     mp = args.max_pages or model["max_seq_len"] // ps
     q8 = model.get("kv_quantization") == "int8"
     itemsize = 1 if q8 else 2
+    extra = {"window": args.window} if args.window else {}
 
     pools = random_pools(L, P, ps, GD, Hkv, q8)
     if q8:
@@ -238,25 +251,37 @@ def bench_fused(args, doc) -> None:
         kernel = fused_decode.fused_decode_attention_pallas
     q = jax.random.normal(jax.random.key(0), (B, H, D), jnp.bfloat16)
 
-    @partial(jax.jit, donate_argnums=(0,))
-    def many(pools, bt, seq_lens, write_page):
-        outs = []
-        for i in range(reps):
-            # The int8 kernel takes its four pools as one argument.
-            attn, pools = kernel(
-                q, *new, *((pools,) if q8 else pools), bt, seq_lens,
-                write_page, jnp.int32(i % L),
-                pages_per_chunk=args.pages_per_chunk,
-                interpret=args.rehearse)
-            outs.append(jnp.sum(attn.astype(jnp.float32)))
-        return jnp.stack(outs), attn, pools
+    def program(gathered):
+        """``reps`` calls in one program; ``gathered``: q, the new rows
+        and the output go through the order's gathers at every call, as
+        ``ops/attention.paged_decode_step(order=...)`` has them."""
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def many(pools, q, new, bt, seq_lens, write_page, rows, places):
+            outs = []
+            for i in range(reps):
+                qi, newi = q, new
+                if gathered:    # (a row of ``rows`` a call: no CSE)
+                    qi, newi = q[rows[i]], [x[rows[i]] for x in new]
+                # The int8 kernel takes its four pools as one argument.
+                attn, pools = kernel(
+                    qi, *newi, *((pools,) if q8 else pools), bt, seq_lens,
+                    write_page, jnp.int32(i % L),
+                    pages_per_chunk=args.pages_per_chunk,
+                    interpret=args.rehearse, **extra)
+                if gathered:
+                    attn = attn[places[i]]
+                outs.append(jnp.sum(attn.astype(jnp.float32)))
+            return jnp.stack(outs), attn, pools
+        return many
 
     plan = None
     if hasattr(fused_decode, "decode_work"):
         plan = fused_decode._tile_plan(  # noqa: SLF001 — the dev tool
             B, ps, mp, GD, itemsize, args.pages_per_chunk)
     print(f"{doc['name']}: B={B} H={H} Hkv={Hkv} D={D} ps={ps} "
-          f"max_pages={mp} {'int8' if q8 else 'bf16'} plan={plan} "
+          f"max_pages={mp} layers={L} {'int8' if q8 else 'bf16'} "
+          f"window={args.window or None} plan={plan} "
           f"device={jax.devices()[0].device_kind}"
           f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
           flush=True)
@@ -265,36 +290,68 @@ def bench_fused(args, doc) -> None:
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)),
                     exist_ok=True)
+    programs = {}
     for spec in args.lens:
-        rows, seq, bt, write_page = occupancy(spec, B, ps, mp, P, rng, args)
-        call = (jnp.asarray(bt), jnp.asarray(seq), jnp.asarray(write_page))
-        outs, attn, pools = many(pools, *call)
-        first = np.asarray(attn, np.float32)    # the last call's output
-        t0 = time.perf_counter()
-        n = 1 if args.rehearse else 10
-        for _ in range(n):
+        rows, seat_seq, seat_bt, seat_wp = occupancy(spec, B, ps, mp, P, rng,
+                                                     args)
+        for order in args.order or ["seat"]:
+            # ``sorted``: the rows handed over longest first, ties by
+            # row (ops/attention.decode_order), the kernel alone;
+            # ``gathered``: the same through the gathers a call.
+            by = (np.arange(B) if order == "seat"
+                  else np.argsort(-seat_seq.astype(np.int64), kind="stable"))
+            seq, bt, write_page = seat_seq[by], seat_bt[by], seat_wp[by]
+            places = np.argsort(by)
+            key = order == "gathered"
+            if key not in programs:
+                programs[key] = program(key)
+            many = programs[key]
+            call = tuple(jnp.asarray(x) for x in (
+                bt, seq, write_page,
+                np.tile(by.astype(np.int32), (reps, 1)),
+                np.tile(places.astype(np.int32), (reps, 1))))
+            if order == "sorted":   # the rows come in order
+                call = (q[by], [x[by] for x in new]) + call
+            else:
+                call = (q, new) + call
             outs, attn, pools = many(pools, *call)
-        jax.block_until_ready(outs)
-        us = (time.perf_counter() - t0) / (n * reps) * 1e6
-        kv_bytes = int(seq.sum()) * (2 * GD * itemsize
-                                     + (4 * Hkv if q8 else 0))
-        least_us = kv_bytes / PEAK_BYTES_PER_S * 1e6
-        work = (fused_decode.decode_work(seq, plan) if plan else None)
-        results.append({"lens": spec, "rows": rows,
-                        "tokens": int(seq.sum()), "us_per_call": us,
-                        "decode_work": work, "kv_least_us": least_us,
-                        "kv_roofline_pct": 100 * least_us / us,
-                        "finite": bool(np.isfinite(first).all())})
-        if args.out:
-            np.save(f"{args.out}.{len(results) - 1}.npy", first)
-        print(f"  lens {spec}: {us:,.1f} us/call  decode_work (steps, "
-              f"computed, live)={work}  K/V bytes at peak {least_us:,.1f} "
-              f"us = {100 * least_us / us:.1f} %  finite="
-              f"{results[-1]['finite']}", flush=True)
+            first = np.asarray(attn, np.float32)  # the last call's
+            if order == "sorted":                 # by batch row again
+                first = first[places]
+            t0 = time.perf_counter()
+            n = 1 if args.rehearse else 10
+            for _ in range(n):
+                outs, attn, pools = many(pools, *call)
+            jax.block_until_ready(outs)
+            us = (time.perf_counter() - t0) / (n * reps) * 1e6
+            seen = (np.minimum(seq, args.window) if args.window
+                    else seq)
+            kv_bytes = int(seen.sum()) * (2 * GD * itemsize
+                                          + (4 * Hkv if q8 else 0))
+            least_us = kv_bytes / PEAK_BYTES_PER_S * 1e6
+            work = (fused_decode.decode_work(
+                seq, plan, *([args.window] if args.window else []))
+                if plan else None)
+            results.append({
+                "lens": spec, "order": order, "rows": rows,
+                "tokens": int(seq.sum()),
+                "us_per_call": us, "decode_work": work,
+                "kv_least_us": least_us,
+                "kv_roofline_pct": 100 * least_us / us,
+                "finite": bool(np.isfinite(first).all())})
+            if args.out:
+                np.save(f"{args.out}.{len(results) - 1}.npy", first)
+            print(f"  lens {spec} order={order}: "
+                  f"{us:,.1f} us/call  decode_work "
+                  f"(steps, computed, live, in full steps)={work}  "
+                  f"K/V bytes at peak {least_us:,.1f} us = "
+                  f"{100 * least_us / us:.1f} %  finite="
+                  f"{results[-1]['finite']}", flush=True)
     if args.out:
         with open(args.out, "w", encoding="utf-8") as f:
             json.dump({"config": doc["name"], "tree": args.tree,
                        "plan": plan and plan._asdict(), "max_pages": mp,
+                       "window": args.window or None, "layers": L,
                        "results": results}, f, indent=1)
 
 
@@ -673,6 +730,20 @@ def main() -> None:
     ap.add_argument("--pages-per-chunk", type=int, default=0)
     ap.add_argument("--spread", action="store_true")
     ap.add_argument("--shuffle", action="store_true")
+    ap.add_argument("--order", action="append", default=[],
+                    choices=["seat", "sorted", "gathered"],
+                    help="how the rows reach the fused decode kernel "
+                         "(repeatable; default seat)")
+    ap.add_argument("--fused", action="store_true",
+                    help="the fused decode kernel at the file's heads and "
+                         "pages whatever its family (granite's attention "
+                         "layers, Trinity's)")
+    ap.add_argument("--window", type=int, default=0,
+                    help="with --lens: the kernel under this window")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="layers the bench's pools hold (default: the "
+                         "file's)")
+    ap.add_argument("--kv-pages", type=int, default=0)
     ap.add_argument("--lanes", action="append", type=int, default=[],
                     help="granitemoehybrid: time the update kernel at "
                          "this many lanes a step of its walk as well")
@@ -684,6 +755,8 @@ def main() -> None:
     sys.path.insert(0, os.path.abspath(args.tree))
     with open(args.model_file, encoding="utf-8") as f:
         doc = json.load(f)
+    if args.fused:
+        doc["family"] = "llama"
     if doc.get("family") not in BENCHES:
         sys.exit(f"{args.model_file}: no kernel bench for the family "
                  f"{doc.get('family')!r}; known: {sorted(BENCHES)}")

@@ -155,7 +155,7 @@ def test_check_runs_the_configured_backend(monkeypatch, tmp_path):
 class TestKernelRoutes:
     #: The plan a fused decode route is logged with (rows a tile, tokens
     #: a chunk): the widest chunk, but for SmolLM2's 32 KV heads.
-    PLAN = "(rows=8,chunk_tokens=256)"
+    PLAN = "(rows=8,chunk_tokens=256,ordered)"
     #: llama3-1b at the shipped serving geometry.
     GEOM = dict(batch=8, page_size=16, max_pages=128, n_kv_heads=8,
                 head_dim=64, kv_itemsize=2, quant_kv=False, enabled=True,
@@ -219,8 +219,8 @@ class TestKernelRoutes:
             "prefill_write": "pallas:_kv_prefill_kernel",
             "prefill_attention": "pallas:_prefill_attn_kernel",
             # 128-token chunks: 16 MiB of scratch at 4 KiB a token.
-            "decode_attention": "pallas:_fused_kernel(rows=8,chunk_tokens=128)",
-            "decode_write": "pallas:_fused_kernel(rows=8,chunk_tokens=128)"}),
+            "decode_attention": "pallas:_fused_kernel(rows=8,chunk_tokens=128,ordered)",
+            "decode_write": "pallas:_fused_kernel(rows=8,chunk_tokens=128,ordered)"}),
         # int8 pools: the write is a scatter, attention the _q8 twins.
         ("mistral-7b-v0.3-w8kv8", {
             "prefill_write": "xla",

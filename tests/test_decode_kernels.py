@@ -13,8 +13,17 @@ the CPU; the batch is one 8-row tile (four where a case says so) and
 the block table holds two of the plan's own chunks (wider where a case
 says so). ``decode_work`` — the same schedule counted on the host — is
 held to "computed = live, steps whatever the block table's width".
+
+Since PR 43 a step in which a whole tile is live runs its products over
+static rows, and the rows reach the kernel ordered by context
+(``ops/attention.decode_order``): the FULL_STEP cases hold that path —
+every step full, full then ragged, a merge and a zeroed tail inside a
+full step — and the ORDER cases hold "the order is invisible outside
+the call": each row's attention and cache write in seat order, in the
+order's and through the dispatcher's ``order=``.
 """
 
+import copy
 from types import SimpleNamespace
 
 import numpy as np
@@ -331,6 +340,59 @@ def _wide_table(g):
     _close(a, _pure(g, x)[0])
 
 
+def _plan_chunk(g):
+    return _tile_plan(ROWS, g.ps, g.mp, g.GD, g.itemsize).chunk_tokens
+
+
+def _every_step_full(g):
+    """Eight live rows of one length, two chunks and a page long: every
+    step of the tile is a full step, the last with every row's merge."""
+    S = _plan_chunk(g)
+    lens = [2 * S + g.ps] * ROWS
+    x = _inputs(g, 14, lens, mp=4 * S // g.ps)
+    plan = _tile_plan(ROWS, g.ps, 4 * S // g.ps, g.GD, g.itemsize)
+    assert decode_work(lens, plan) == (3, 24, 24, 24)
+    _held_to_pure(g, x)
+
+
+def _full_then_ragged(g):
+    """A tile full in its first chunk and ragged in its second: the
+    static path and the listed visits share a row's running softmax."""
+    S = _plan_chunk(g)
+    lens = [S + 5, S, 2 * S, S - 1, S + g.ps + 1, 7, 2 * S - 1, S + 1]
+    plan = _tile_plan(ROWS, g.ps, g.mp, g.GD, g.itemsize)
+    assert decode_work(lens, plan) == (2, 13, 13, 8)
+    _held_to_pure(g, _inputs(g, 15, lens))
+
+
+def _merge_and_tail_inside_a_full_step(g):
+    """Rows of a full step whose last chunks and live pages differ:
+    chunk 0 holds all eight rows, three of which end there — one in the
+    chunk's first page (its other pages a zeroed tail), one a page
+    further, one on the chunk's last position — beside rows that run on."""
+    S = _plan_chunk(g)
+    lens = [3, g.ps + 1, S, 2 * S, S + 1, 2 * S - g.ps, S + g.ps, 2 * S]
+    plan = _tile_plan(ROWS, g.ps, g.mp, g.GD, g.itemsize)
+    assert decode_work(lens, plan) == (2, 13, 13, 8)
+    _held_to_pure(g, _inputs(g, 16, lens))
+
+
+def _full_steps_of_every_chunk_width(g):
+    """A full step's block at both chunks the chunk-width case runs (the
+    plan's own and one page): eight live rows that end in different
+    chunks at each, held to the pure path, and one pool whatever the
+    chunk."""
+    S = _plan_chunk(g)
+    x = _inputs(g, 17, [S + 5, S, 2 * S, S - 1, S + g.ps + 1, S + 7,
+                        2 * S - 1, S + 1])
+    ref, want = _pure(g, x)
+    for ppc in (0, 1):
+        attn, out = _kernel(g, x, ppc=ppc)
+        _close(attn, ref)
+        for got, exp in zip(out, want):
+            np.testing.assert_array_equal(got, exp)
+
+
 CASES = {"page-edges": _page_edges, "dead-rows": _dead_rows,
          "stacked-layer": _stacked_layer, "chunk-width": _chunk_width,
          "dead-pages": _dead_pages, "write-lands-once": _write_lands_once,
@@ -339,7 +401,13 @@ CASES = {"page-edges": _page_edges, "dead-rows": _dead_rows,
          "only-last-row-live": _only_last_row_live,
          "one-row-a-tile": _one_row_a_tile,
          "dead-middle-tiles": _dead_middle_tiles,
-         "chunk-edges": _chunk_edges, "wide-table": _wide_table}
+         "chunk-edges": _chunk_edges, "wide-table": _wide_table,
+         "every-step-full": _every_step_full,
+         "full-then-ragged": _full_then_ragged,
+         "merge-and-tail-inside-a-full-step":
+             _merge_and_tail_inside_a_full_step,
+         "full-steps-of-every-chunk-width":
+             _full_steps_of_every_chunk_width}
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -351,6 +419,119 @@ def test_fused_decode_served_geometries(served_geometry, config, case):
     else:
         assert (g.H, g.Hkv, g.D, g.ps, g.q8) == (32, 8, 128, 128, True)
     CASES[case](g)
+
+
+# -- the order the kernel is handed its rows in --------------------------------
+
+
+def _host_order(seq_lens):
+    """``ops/attention.decode_order`` on the host: longest first, ties
+    by row."""
+    by = np.argsort(-np.asarray(seq_lens, np.int64), kind="stable")
+    return by, np.argsort(by)
+
+
+def _by_place(x, by):
+    """The same rows handed over in another order: everything that has
+    one entry a row, moved; the pools stay."""
+    y = copy.copy(x)
+    for name in ("q", "kn", "vn", "bt", "seq_lens", "page_of", "slot_of"):
+        setattr(y, name, jnp.asarray(np.asarray(getattr(x, name))[by]))
+    for name in ("live", "np_bt", "np_page_of", "np_slot_of"):
+        setattr(y, name, getattr(x, name)[by])
+    return y
+
+
+def _same_rows_in_any_order(g, x, monkeypatch):
+    """Each row's attention and its cache write in seat order, in the
+    order's, and through the dispatcher's ``order=`` as the models call
+    it: the pure path's, and the pools equal to the bit — the order is
+    invisible outside the call. Returns the order."""
+    lens = np.asarray(x.seq_lens)
+    by, places = _host_order(lens)
+    seat, seat_out = _kernel(g, x)
+    placed, placed_out = _kernel(g, _by_place(x, by))
+    ref, want = _pure(g, x)
+    for attn in (seat, placed[places]):
+        _close(attn[x.live], ref[x.live])
+        assert np.all(attn[~x.live] == 0)
+    for a, b, exp in zip(seat_out, placed_out, want):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a[:, 1:], exp[:, 1:])
+    # the device makes the order the host counts by
+    monkeypatch.setenv("LLMQ_PALLAS", "interpret")
+    order = attention.decode_order(x.seq_lens, x.pools, g.mp, g.D)
+    np.testing.assert_array_equal(order.rows, by)
+    np.testing.assert_array_equal(order.places, places)
+    bt, seq_lens, page_of = attention.rows_by_place(order, x.bt, x.seq_lens,
+                                                    x.page_of)
+    if g.q8:
+        attn, out = attention.paged_decode_step_q8(
+            x.q, x.kn, x.vn, x.pools, bt, seq_lens, page_of, x.slot_of,
+            x.layer, order=order)
+    else:
+        attn, *out = attention.paged_decode_step(
+            x.q, x.kn, x.vn, *x.pools, bt, seq_lens, page_of, x.slot_of,
+            x.layer, order=order)
+    np.testing.assert_array_equal(np.asarray(attn, np.float32),
+                                  placed[places])
+    for a, b in zip(out, placed_out):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), b)
+    monkeypatch.setenv("LLMQ_PALLAS", "0")
+    assert attention.decode_order(x.seq_lens, x.pools, g.mp, g.D) is None
+    return by
+
+
+def _shuffled_four_tiles(g, monkeypatch):
+    """32 rows of 29 lengths from one token to the block table's end and
+    three dead ones, shuffled over the seats: ordered, more row-chunks
+    run in full steps and fewer steps run."""
+    rng = np.random.default_rng(18)
+    top = g.mp * g.ps
+    lens = rng.permutation([1 + (top - 1) * i // 28 for i in range(29)]
+                           + [0] * 3)
+    by = _same_rows_in_any_order(g, _inputs(g, 18, lens), monkeypatch)
+    plan = _tile_plan(32, g.ps, g.mp, g.GD, g.itemsize)
+    seat, ordered = decode_work(lens, plan), decode_work(lens, plan,
+                                                         ordered=True)
+    assert ordered == decode_work(lens[by], plan)
+    assert ordered[1:3] == seat[1:3]            # the same work
+    assert ordered[0] < seat[0] and ordered[3] > seat[3]
+
+
+def _dead_rows_land_in_the_last_tile(g, monkeypatch):
+    """Five dead rows scattered among sixteen seats stand last in the
+    order, by row: the first tile is all live."""
+    S = _plan_chunk(g)
+    lens = np.asarray([S, 0, 2 * S, 9, 0, S + 1, S - 1, 0,
+                       g.ps, 0, 2 * S - 3, 40, S + g.ps, 0, 5, S])
+    by = _same_rows_in_any_order(g, _inputs(g, 19, lens), monkeypatch)
+    assert list(by[-5:]) == [1, 4, 7, 9, 13]
+    assert (lens[by[:8]] > 0).all()
+
+
+def _ties_stand_by_row(g, monkeypatch):
+    """Equal contexts keep their rows' order, whatever stands between
+    them: the order is a function of ``seq_lens`` alone."""
+    S = _plan_chunk(g)
+    lens = np.asarray([S, 7, S, 7, 2 * S, 7, S, 2 * S,
+                       7, S, 2 * S, S, 7, 7, 2 * S, S])
+    by = _same_rows_in_any_order(g, _inputs(g, 20, lens), monkeypatch)
+    assert list(by) == [4, 7, 10, 14, 0, 2, 6, 9, 11, 15, 1, 3, 5, 8, 12,
+                        13]
+
+
+ORDER_CASES = {"shuffled-four-tiles": _shuffled_four_tiles,
+               "dead-rows-land-in-the-last-tile":
+                   _dead_rows_land_in_the_last_tile,
+               "ties-stand-by-row": _ties_stand_by_row}
+
+
+@pytest.mark.parametrize("case", list(ORDER_CASES))
+@pytest.mark.parametrize("config", CONFIGS)
+def test_the_order_is_invisible_outside_the_call(served_geometry,
+                                                 monkeypatch, config, case):
+    ORDER_CASES[case](_geom(served_geometry, config), monkeypatch)
 
 
 @pytest.mark.parametrize("rows", ["served", "one-tile"])
@@ -376,6 +557,10 @@ def test_tile_plan_served_geometries(served_geometry, config, rows):
                                   * g.GD * g.itemsize)
     assert plan.scratch_bytes <= SCRATCH_BUDGET_BYTES
     assert plan.scratch_bytes < plan.vmem_limit_bytes <= 64 * 2**20
+    # a full step's products are one block of the tile's rows; over int8
+    # pools the plan asks room for their bf16 copies beside the scratch
+    assert plan.vmem_limit_bytes - plan.scratch_bytes >= (
+        4 * plan.rows * plan.chunk_tokens * g.GD if g.q8 else 0)
     # A wider block table or a deeper batch changes no call's cut.
     assert _tile_plan(B, g.ps, 2 * g.mp_full, g.GD, g.itemsize) == plan
     if config.startswith("smollm2"):
@@ -407,14 +592,49 @@ def test_decode_work_follows_the_batch(served_geometry, config, occupancy):
     lens = _occupancy(occupancy, g.rows_full)
     width = max(g.mp_full, 2048 // g.ps)     # Mistral serves 2,048
     plan = _tile_plan(g.rows_full, g.ps, width, g.GD, g.itemsize)
-    steps, computed, live = decode_work(lens, plan)
+    steps, computed, live, full = decode_work(lens, plan)
     S = plan.chunk_tokens
     assert computed == live == sum(-(-n // S) for n in lens)
     tiles = np.asarray(lens).reshape(-1, plan.rows)
     assert steps == sum(max(1, -(-int(t.max()) // S)) for t in tiles)
     assert steps < g.rows_full // plan.rows * (width // plan.pages_per_chunk)
     wider = _tile_plan(g.rows_full, g.ps, 2 * width, g.GD, g.itemsize)
-    assert decode_work(lens, wider) == (steps, computed, live)
+    assert decode_work(lens, wider) == (steps, computed, live, full)
     if occupancy == "four-of-360":
         # One live tile of ceil(360 / S) chunks; the others one step each.
         assert steps == -(-360 // S) + g.rows_full // plan.rows - 1
+
+
+@pytest.mark.parametrize("occupancy", ["full", "four-of-360", "two-of-2000"])
+@pytest.mark.parametrize("config", CONFIGS)
+def test_decode_work_counts_the_full_steps(served_geometry, config,
+                                           occupancy):
+    """The fourth count in closed form: a step is full while the tile's
+    SHORTEST row is live, so a tile gives its rows times its shortest
+    row's chunks — in the order handed over, and with ``ordered`` in
+    ``decode_order``'s, where the lengths' spread over tiles is gone."""
+    g = _geom(served_geometry, config)
+    lens = np.asarray(_occupancy(occupancy, g.rows_full))
+    width = max(g.mp_full, 2048 // g.ps)
+    plan = _tile_plan(g.rows_full, g.ps, width, g.GD, g.itemsize)
+    S, R = plan.chunk_tokens, plan.rows
+
+    def closed_form(by_place):
+        tiles = -(-by_place.reshape(-1, R) // S)        # chunks a row
+        return R * int(tiles.min(axis=1).sum())
+
+    rng = np.random.default_rng(21)
+    for handed in (lens, rng.permutation(lens)):
+        assert decode_work(handed, plan)[3] == closed_form(handed)
+        got = decode_work(handed, plan, ordered=True)
+        assert got[3] == closed_form(np.sort(lens)[::-1])
+        assert got[1:3] == decode_work(lens, plan)[1:3]
+        assert got[3] <= got[1]
+    if occupancy != "full":
+        # four or two live rows never fill a tile of eight
+        assert decode_work(lens, plan, ordered=True)[3] == 0
+    else:
+        # every tile but the last (its dead row) runs its shortest
+        # row's chunks full: nine row-chunks in ten
+        full, computed = got[3], got[1]
+        assert full / computed > (0.85 if g.rows_full == 64 else 0.7)

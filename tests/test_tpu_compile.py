@@ -99,11 +99,13 @@ def test_prefill_attention_q8_compiles_for_v5e(one_chip, name):
 
 
 #: name -> (rows, H, H_kv, D, page_size, max_pages, layers, pool pages,
-#: int8 KV): the benchmark's two configurations as served, the smoke's
-#: llama3-1b, and a batch of one tile.
+#: int8 KV): the benchmark's two Llama configurations and granite's four
+#: attention layers as served, the smoke's llama3-1b, and a batch of
+#: one tile (Trinity's two: ``test_the_windowed_kernels_compile_for_v5e``).
 _DECODE_GEOMETRIES = {
     "smollm2-1.7b": (32, 32, 32, 64, 16, 256, 24, 3328, False),
     "mistral-7b-w8kv8": (64, 32, 8, 128, 128, 16, 32, 832, True),
+    "granite-4.0-h-micro": (64, 32, 8, 64, 128, 16, 4, 832, False),
     "llama3-1b": (8, 32, 8, 64, 16, 128, 16, 512, False),
     "smollm2-1.7b-one-tile": (8, 32, 32, 64, 16, 256, 24, 3328, False),
 }
@@ -116,11 +118,18 @@ def test_fused_decode_compiles_for_v5e(one_chip, name):
     ``vmem_limit_bytes`` the plan states, and the rolled row and page
     loops with their DMAs. One call's program stays a fraction of the
     v3 kernel's (3.3-5.2 MB serialized: PERF.md §6, PR 29) — every layer
-    of every decode program carries one."""
+    of every decode program carries one. Since PR 43 the plan's static
+    block of a full step is in it (eight rows' products in one straight
+    line: + 10-14 kB of text a call; over int8 pools the stated limit
+    holds the block's bf16 copies too)."""
     from llmq_tpu.ops.pallas.fused_decode import (
         fused_decode_attention_pallas, fused_decode_attention_q8_pallas)
 
+    from llmq_tpu.ops.pallas.fused_decode import _tile_plan
+
     B, H, Hkv, D, ps, mp, L, P, q8 = _DECODE_GEOMETRIES[name]
+    plan = _tile_plan(B, ps, mp, Hkv * D, 1 if q8 else 2)
+    assert plan.rows == 8        # a full step's block is eight rows'
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -723,7 +732,8 @@ def test_the_windowed_kernels_compile_for_v5e(one_chip, kernel, window):
     """The two GQA kernels at Trinity's geometry (48 heads over 8 KV
     heads of 128 in 128-token pages, a table of 112 pages, 64 rows):
     over the full layer's pool without a window, over the sliding
-    layers' slabs (37 pages a row behind page 0) with it."""
+    layers' slabs (37 pages a row behind page 0) with it — the decode
+    kernel with its full-step block (PR 43) at both."""
     from llmq_tpu.ops.pallas.fused_decode import fused_decode_attention_pallas
     from llmq_tpu.ops.pallas.prefill_attention import (
         paged_prefill_attention_pallas)

@@ -55,6 +55,11 @@ DECODE_CASES = {
     "two-tiles-mostly-dead": ([5] + [0] * 7 + [200, 200] + [0] * 5 + [256],
                               64, 2),
     "window-of-one-page": ([16, 17, 31, 32, 33, 129, 255, 256], 16, 2),
+    # Chunk 1 (positions 32-63) holds all eight rows — a FULL step: four
+    # see their whole context and end there (merge and tail inside it),
+    # one passes the window inside chunk 0, three start at chunk 1.
+    "a-full-step-with-rows-on-both-sides-of-the-window": (
+        [33, 36, 40, 64, 70, 72, 90, 95], 40, 2),
 }
 
 
@@ -101,18 +106,21 @@ def test_windowed_decode_is_the_masked_form_and_visits_no_chunk_before_it(
     # the schedule counted on the host: products for the rows' visible
     # chunks and no more, none of them wholly before a window
     plan = _tile_plan(B, PS, mp, GD, 2, ppc)
-    steps, computed, visible = decode_work(seq, plan, W)
-    _, _, held = decode_work(seq, plan)
+    steps, computed, visible, _ = decode_work(seq, plan, W)
+    _, _, held, _ = decode_work(seq, plan)
     assert computed == visible == held - wholly_before
     assert window_chunks(seq, S, W) == (visible, wholly_before)
     assert steps <= decode_work(seq, plan)[0]
+    if case.startswith("a-full-step"):
+        assert decode_work(seq, plan, W)[3] == B    # chunk 1, and no other
 
 
 def test_decode_work_without_a_window_counts_what_it_counted():
     plan = _tile_plan(8, PS, 16, GD, 2, 2)
     seq = [1, 16, 40, 41, 100, 200, 256, 0]
-    assert decode_work(seq, plan) == decode_work(seq, plan, None) == (8, 25,
-                                                                      25)
+    # (no step of this tile is full: its last row is dead)
+    assert decode_work(seq, plan) == decode_work(seq, plan, None) == (
+        8, 25, 25, 0)
     assert window_chunks(seq, 32) == (25, 0)
 
 
@@ -204,9 +212,11 @@ def test_the_dispatchers_pass_the_window_to_kernel_and_fall_back_alike(
 #: source lines. A PR that changes a kernel on purpose records the new
 #: digest here and says so: the accepted families (SmolLM2, Mistral,
 #: Granite) call these kernels without a window, and their cells are
-#: held to what this program does.
-PARENT_DIGESTS = {"decode": "b20790914b36cc3a", "prefill": "2e999255c2e7b7de",
-                  "decode_q8": "14adb5e172ce48cc",
+#: held to what this program does. PR 43 changed the two DECODE kernels
+#: on purpose (a full step's static block of products; their digests
+#: are that tree's), and left the prefill kernels' as they were.
+PARENT_DIGESTS = {"decode": "8ba2a49188a5b544", "prefill": "2e999255c2e7b7de",
+                  "decode_q8": "80d6b928e02b8776",
                   "prefill_q8": "930a29e3d50cb305"}
 
 
