@@ -62,10 +62,7 @@ LLMQ_BENCH_KV_TIER_CONVS / LLMQ_BENCH_KV_TIER_SECS (conversation count
 and per-rate-point duration for the tiered-KV residency A/B),
 LLMQ_BENCH_DISAGG_LONG_RATE / LLMQ_BENCH_DISAGG_CHAT_RATE /
 LLMQ_BENCH_DISAGG_SECS (arrival rates and phase duration for the
-disaggregation A/B), LLMQ_BENCH_SPECULATION (=0 disables the
-speculative-decoding echo A/B: same Poisson schedule served spec-off
-vs spec-on, per-rate-point acceptance + readback-cadence deltas and
-the decode_tokens_per_s_speculative headline),
+disaggregation A/B),
 LLMQ_BENCH_MESH (e.g. "dp2xtp4": serve the SLA sweeps through a dp×tp
 mesh — rule-table-sharded params, per-chip paged KV, MFU against
 N-chip peak FLOPs; per-point and headline mesh geometry recorded),
@@ -789,142 +786,6 @@ def bench_controlplane_ramp(base_rate: float = 20.0,
         f"{static['realtime_p99_ms']:.1f} → "
         f"{managed['realtime_p99_ms']:.1f} ms")
     return out
-
-
-# -- 2d. speculative decoding A/B (docs/performance.md) -----------------------
-
-SPEC_PROMPTS = [
-    # Repetitive bodies: the echo stream replays the prompt, so the
-    # n-gram drafter's suffix matches land and the acceptance rate is
-    # high — the regime speculation is built for.
-    "the quick brown fox jumps. " * 4,
-    "alpha beta gamma alpha beta gamma alpha beta gamma alpha beta",
-    "status ok status ok status ok status ok status ok status ok",
-    # A low-repetition body keeps the aggregate acceptance honest.
-    "compute the partial trace of the density matrix now please",
-]
-
-
-def bench_speculation(n_reqs: int = 48, rates=(300.0, 600.0),
-                      step_delay_ms: float = 5.0, draft_k: int = 8,
-                      max_new: int = 64) -> Dict:
-    """Speculative-decoding A/B against the echo engine
-    (docs/performance.md "Speculative decoding"): the SAME Poisson
-    arrival schedule is served twice per rate point — speculation off
-    (chunked one-token-per-step decode) vs on (n-gram drafter + k-step
-    verify windows) — with ``step_delay_ms`` of simulated device
-    latency per dispatched program, so wall clock measures dispatch
-    count, exactly what speculation reduces.
-
-    Per rate point: decode tokens/s both sides + delta, the on-side
-    acceptance rate and readback cadence (batch tokens per host
-    fetch), the cadence delta vs the off side's chunk cadence, and a
-    per-request stream-equality flag (greedy echo speculation is
-    byte-identical by contract; the A/B asserts it stays that way
-    under arrival jitter)."""
-    from llmq_tpu.core.config import SpeculationConfig
-    from llmq_tpu.engine import EchoExecutor, InferenceEngine, ByteTokenizer
-    from llmq_tpu.engine.engine import GenRequest
-
-    delay_s = step_delay_ms / 1000.0
-
-    def run_side(rate: float, spec_cfg) -> Dict:
-        tok = ByteTokenizer()
-        ex = EchoExecutor(batch_size=8, page_size=8, num_pages=1024,
-                          max_pages_per_seq=16, eos_id=tok.eos_id,
-                          chunk_size=4, step_delay_s=delay_s)
-        side = "on" if spec_cfg is not None else "off"
-        eng = InferenceEngine(ex, tok, enable_metrics=False,
-                              name=f"spec-{side}", max_decode_steps=128,
-                              speculation=spec_cfg)
-        eng.start()
-        # Same seed per rate point on both sides: identical arrival
-        # schedule, so elapsed time differences are decode-plane only.
-        rng = bench_rng(int(rate) + 7)
-        handles = []
-        t0 = time.perf_counter()
-        next_arrival = t0
-        for i in range(n_reqs):
-            while True:
-                now = time.perf_counter()
-                if now >= next_arrival:
-                    break
-                time.sleep(min(0.0005, next_arrival - now))
-            handles.append(eng.submit(GenRequest(
-                id=f"s{i}", prompt=SPEC_PROMPTS[i % len(SPEC_PROMPTS)],
-                priority=sample_tier(rng), max_new_tokens=max_new)))
-            next_arrival += rng.expovariate(rate)
-        for h in handles:
-            if not h.wait(timeout=60.0):
-                raise RuntimeError(f"speculation bench: {h.request.id} "
-                                   f"did not finish ({side})")
-        elapsed = time.perf_counter() - t0
-        stats = eng.get_stats()
-        eng.stop()
-        streams = {h.request.id: list(h.result.tokens) for h in handles}
-        n_tokens = sum(len(s) for s in streams.values())
-        out = {
-            "decode_tokens_per_s": round(n_tokens / elapsed, 1),
-            "elapsed_s": round(elapsed, 3),
-            "tokens": n_tokens,
-            # Off side: one chunk fetch per decode step — its cadence
-            # baseline for the readback-cadence delta.
-            "chunk_cadence": round(
-                n_tokens / max(1, stats.get("decode_steps", 0)), 4),
-        }
-        spec_stats = stats.get("speculation")
-        if spec_stats:
-            out["acceptance_rate"] = spec_stats["acceptance_rate"]
-            out["readback_cadence"] = spec_stats["readback_cadence"]
-            out["spec_windows"] = spec_stats["windows"]
-        return out, streams
-
-    spec_cfg = SpeculationConfig(enabled=True, draft_k=draft_k,
-                                 ngram_max=3, device_sampling=True)
-    points = []
-    for rate in rates:
-        log(f"[speculation] A/B at {rate:g} req/s × {n_reqs} reqs "
-            f"(step delay {step_delay_ms:g} ms, k={draft_k}) ...")
-        off, off_streams = run_side(rate, None)
-        on, on_streams = run_side(rate, spec_cfg)
-        delta_pct = 0.0
-        if off["decode_tokens_per_s"] > 0:
-            delta_pct = 100.0 * (on["decode_tokens_per_s"]
-                                 / off["decode_tokens_per_s"] - 1.0)
-        point = {
-            "rate_per_s": rate,
-            "off": off,
-            "on": on,
-            "tokens_per_s_delta_pct": round(delta_pct, 1),
-            "readback_cadence_delta": round(
-                on.get("readback_cadence", 0.0) - off["chunk_cadence"],
-                4),
-            # Greedy echo speculation is byte-identical by contract —
-            # False here is a correctness regression, not a perf note.
-            "streams_identical": on_streams == off_streams,
-        }
-        points.append(point)
-        log(f"[speculation] {rate:g} req/s: off "
-            f"{off['decode_tokens_per_s']:.0f} tok/s → on "
-            f"{on['decode_tokens_per_s']:.0f} tok/s "
-            f"({delta_pct:+.1f}%), acceptance "
-            f"{on.get('acceptance_rate', 0.0):.3f}, cadence "
-            f"{on.get('readback_cadence', 0.0):.2f} tok/fetch "
-            f"(off chunk {off['chunk_cadence']:.2f}), identical="
-            f"{point['streams_identical']}")
-    best = max(points, key=lambda p: p["on"]["decode_tokens_per_s"])
-    return {
-        "n_reqs": n_reqs,
-        "step_delay_ms": step_delay_ms,
-        "draft_k": draft_k,
-        "points": points,
-        "decode_tokens_per_s_speculative":
-            best["on"]["decode_tokens_per_s"],
-        "decode_tokens_per_s_spec_off":
-            best["off"]["decode_tokens_per_s"],
-        "tokens_per_s_delta_pct": best["tokens_per_s_delta_pct"],
-        "streams_identical": all(p["streams_identical"] for p in points),
-    }
 
 
 # -- 3. single-chip decode (BASELINE config #2) -------------------------------
@@ -2460,13 +2321,6 @@ def main() -> None:
     except Exception as e:  # noqa: BLE001
         log(f"[controlplane] ramp bench failed: "
             f"{type(e).__name__}: {e}")
-    speculation_res = None
-    if os.environ.get("LLMQ_BENCH_SPECULATION", "1") != "0":
-        try:
-            speculation_res = bench_speculation()
-        except Exception as e:  # noqa: BLE001
-            log(f"[speculation] A/B bench failed: "
-                f"{type(e).__name__}: {e}")
     scenarios_res = None
     if not os.environ.get("LLMQ_BENCH_SKIP_SCENARIOS"):
         try:
@@ -2516,7 +2370,6 @@ def main() -> None:
         "kv_tiering": kv_tiering_res,
         "disagg": disagg_res,
         "controlplane": controlplane_res,
-        "speculation": speculation_res,
         "scenario_runs": scenarios_res,
         "store_chaos": store_chaos_res,
         "tpu": tpu,
@@ -2571,18 +2424,6 @@ def main() -> None:
             "store_chaos_wall_s_saved_pct":
                 (store_chaos_res or {}).get("wall_s_saved_pct"),
             "decode_tokens_per_s": (tpu or {}).get("decode_tokens_per_s"),
-            # Speculation A/B (docs/performance.md "Speculative
-            # decoding"): echo-engine decode throughput with the
-            # n-gram drafter + verify windows on, next to the SAME
-            # schedule served one-chunk-per-step, plus the on-side
-            # acceptance rate behind the win.
-            "decode_tokens_per_s_speculative":
-                (speculation_res or {})
-                .get("decode_tokens_per_s_speculative"),
-            "decode_tokens_per_s_spec_off":
-                (speculation_res or {}).get("decode_tokens_per_s_spec_off"),
-            "speculation_tokens_per_s_delta_pct":
-                (speculation_res or {}).get("tokens_per_s_delta_pct"),
             "max_rate_realtime_p99_ok":
                 (tpu_tiers or {}).get("max_rate_realtime_p99_ok"),
             "max_rate_realtime_p99_ok_8b":

@@ -602,14 +602,14 @@ class AsyncPipelineConfig:
     thread's only job between dispatches is packing the next chunk.
     ``enabled: false`` is a hard off-switch: the engine schedules
     exactly as it did before the subsystem existed (single in-flight
-    chunk + one speculative dispatch, all completions inline on the
+    chunk + one carried dispatch, all completions inline on the
     engine thread, the echo executor fully synchronous)."""
     enabled: bool = True
     #: Dispatched-but-unreconciled chunks the engine may keep in
     #: flight. 2 = classic double buffering (the next chunk's compute
-    #: hides the current chunk's readback); 1 disables speculation
-    #: entirely (reconcile every chunk — strictly tighter than the
-    #: off-switch, which keeps one speculative dispatch).
+    #: hides the current chunk's readback); 1 disables the carried
+    #: dispatch entirely (reconcile every chunk — strictly tighter than
+    #: the off-switch, which keeps one carried dispatch).
     depth: int = 2
     #: Threads on the completion executor. Jobs for one request always
     #: land on the same worker, so per-request token/finish order is
@@ -625,44 +625,6 @@ class AsyncPipelineConfig:
             raise ValueError(
                 f"async_pipeline.completion_workers must be in [1, 8] "
                 f"(got {self.completion_workers})")
-
-
-@dataclass
-class SpeculationConfig:
-    """Speculative decoding plane (docs/performance.md "Speculative
-    decoding"): an n-gram/prompt-lookup drafter (zero extra weights —
-    the draft model is the request's own prompt+generated suffix)
-    proposes up to ``draft_k`` tokens per row, the executor verifies
-    the whole window in ONE device program (teacher-forced decode
-    steps), and the engine commits the accepted run plus the correction
-    token per single batched readback — host fetches per token drop
-    below 1. ``enabled: false`` (the DEFAULT) is a hard off-switch: no
-    drafter runs, no verify program is built or compiled, and
-    scheduling/outputs are byte-identical to pre-speculation
-    behavior."""
-    enabled: bool = False
-    #: Max draft tokens proposed per row per window; the verify
-    #: program's static width is draft_k + 1 (drafts + correction).
-    draft_k: int = 4
-    #: Longest suffix n-gram the drafter matches (it backs off to
-    #: shorter n-grams down to 1 before giving up on a window).
-    ngram_max: int = 3
-    #: Device-resident accept: sampling, draft comparison, EOS freeze
-    #: and n_commit all stay inside the jitted window program. ``false``
-    #: runs the unconditional teacher-forced window on device and
-    #: recomputes the accept rule on host from the fetched tokens —
-    #: committed streams are byte-identical either way.
-    device_sampling: bool = True
-
-    def __post_init__(self) -> None:
-        if not 1 <= self.draft_k <= 16:
-            raise ValueError(
-                f"executor.speculation.draft_k must be in [1, 16] "
-                f"(got {self.draft_k})")
-        if self.ngram_max < 1:
-            raise ValueError(
-                f"executor.speculation.ngram_max must be >= 1 "
-                f"(got {self.ngram_max})")
 
 
 VALID_POOL_KINDS = ("none", "subprocess", "exec")
@@ -1002,8 +964,6 @@ class ExecutorConfig:
     mixed_batch: MixedBatchConfig = field(default_factory=MixedBatchConfig)
     async_pipeline: AsyncPipelineConfig = field(
         default_factory=AsyncPipelineConfig)
-    speculation: SpeculationConfig = field(
-        default_factory=SpeculationConfig)
     supervisor: SupervisorConfig = field(default_factory=SupervisorConfig)
     mesh: MeshConfig = field(default_factory=MeshConfig)
 

@@ -45,8 +45,6 @@ def build_engine(cfg: Config, *, name: str = "engine0",
     mixed_on = bool(getattr(mixed, "enabled", False))
     pipe = getattr(ex, "async_pipeline", None)
     pipe_on = bool(getattr(pipe, "enabled", False))
-    spec = getattr(ex, "speculation", None)
-    spec_on = bool(getattr(spec, "enabled", False))
     mesh_cfg = getattr(ex, "mesh", None)
     # Mesh-native serving (docs/multihost.md): executor.mesh is the
     # first-class knob (hard off-switch); the legacy tpu.mesh_shape
@@ -109,8 +107,7 @@ def build_engine(cfg: Config, *, name: str = "engine0",
                              f"(supported: 'int8')")
         fam.check_serving(
             mcfg, quantization=quant, kv_quantization=kv_quant,
-            mesh=bool(mesh_shape),
-            speculation_draft_k=(spec.draft_k if spec_on else 0))
+            mesh=bool(mesh_shape))
         import time as _time
         t_weights0 = _time.perf_counter()
         if params is None:
@@ -167,13 +164,6 @@ def build_engine(cfg: Config, *, name: str = "engine0",
             mixed_prefill_slices=mixed_slices,
             mixed_slice_tokens=mixed_slice_tokens,
             mesh=mesh,
-            # Speculative decoding (docs/performance.md "Speculative
-            # decoding"): draft_k > 0 builds the jitted verify program;
-            # 0 hides verify_chunk entirely so the off-switch keeps the
-            # exact one-token decode path.
-            speculation_draft_k=(spec.draft_k if spec_on else 0),
-            speculation_device_sampling=(spec.device_sampling
-                                         if spec_on else True),
             telemetry_name=name,
             # Warmup runs before InferenceEngine can set the flag.
             telemetry_metrics=metrics_on)
@@ -203,13 +193,12 @@ def build_engine(cfg: Config, *, name: str = "engine0",
         prefix_cache=getattr(ex, "prefix_cache", None),
         mixed_batch=mixed,
         async_pipeline=pipe,
-        kv_tiering=getattr(ex, "kv_tiering", None),
-        speculation=spec)
+        kv_tiering=getattr(ex, "kv_tiering", None))
     tier = getattr(ex, "kv_tiering", None)
     from llmq_tpu.observability.device import describe_device
     log.info("built %s engine %s on %s (slots=%d pages=%d page_size=%d "
              "mesh=%s prefix_cache=%s mixed_batch=%s "
-             "async_pipeline=%s kv_tiering=%s speculation=%s)",
+             "async_pipeline=%s kv_tiering=%s)",
              ex.backend, name, describe_device(engine.device_identity()),
              ex.max_batch_size, ex.kv_pages, ex.page_size,
              (mesh_shape if (ex.backend == "jax" and mesh_shape)
@@ -219,7 +208,5 @@ def build_engine(cfg: Config, *, name: str = "engine0",
               f"x{mixed_slices})" if mixed_on else "off"),
              (f"on(depth={pipe.depth})" if pipe_on else "off"),
              (f"on(host={tier.host_capacity_mb}MiB)"
-              if getattr(tier, "enabled", False) else "off"),
-             (f"on(k={spec.draft_k} device_sampling="
-              f"{spec.device_sampling})" if spec_on else "off"))
+              if getattr(tier, "enabled", False) else "off"))
     return engine

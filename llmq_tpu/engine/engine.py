@@ -343,10 +343,10 @@ class _InflightChunk:
     snapshot of the prefill slices fused into the program — their
     handle.fetch() returns (decode tokens, slice first-tokens)."""
 
-    __slots__ = ("handle", "seqs", "budgets", "fetch_box", "pf", "spec",
+    __slots__ = ("handle", "seqs", "budgets", "fetch_box", "pf",
                  "dispatch_s", "dispatched_at")
 
-    def __init__(self, handle, seqs, budgets, pf=None, spec=False,
+    def __init__(self, handle, seqs, budgets, pf=None,
                  dispatch_s: float = 0.0,
                  dispatched_at: float = 0.0) -> None:
         self.handle = handle
@@ -354,11 +354,6 @@ class _InflightChunk:
         self.budgets = budgets    # np.ndarray (B,) int32
         self.fetch_box = None
         self.pf = pf              # List[(seq, n_tokens, final)] | None
-        #: VERIFY window (speculation plane): ``budgets`` holds per-row
-        #: window sizes (upper bounds), ``handle.fetch()`` resolves to
-        #: (out, n_commit) and processing commits/charges only the
-        #: accepted run per row.
-        self.spec = spec
         #: Host-side assembly + dispatch seconds for this chunk — the
         #: "dispatch" leg of the step decomposition; the device/readback
         #: legs are measured at fetch (observability/device.py).
@@ -498,7 +493,6 @@ class InferenceEngine:
         mixed_batch=None,
         async_pipeline=None,
         kv_tiering=None,
-        speculation=None,
     ) -> None:
         self.executor = executor
         self.spec = executor.spec
@@ -630,7 +624,7 @@ class InferenceEngine:
         #: pipeline"). ``async_pipeline`` accepts a
         #: core.config.AsyncPipelineConfig or anything with its fields;
         #: None/disabled keeps the exact pre-pipeline scheduling (one
-        #: in-flight chunk + one speculative dispatch, completions
+        #: in-flight chunk + one carried dispatch, completions
         #: inline) — the config's hard off-switch.
         self._pipe_cfg = (async_pipeline
                           if async_pipeline is not None
@@ -638,7 +632,7 @@ class InferenceEngine:
                           else None)
         #: Bound on dispatched-but-unreconciled chunks. The off-switch
         #: value 2 IS today's scheduling: one in flight plus at most
-        #: one speculative dispatch per step.
+        #: one carried dispatch per step.
         self._pipe_depth = (max(1, min(4, int(getattr(
             self._pipe_cfg, "depth", 2))))
             if self._pipe_cfg is not None else 2)
@@ -649,7 +643,7 @@ class InferenceEngine:
             if self._pipe_cfg is not None else 0)
         self._completion: Optional[_CompletionPool] = None
         #: Dispatched-but-unfetched chunks, oldest first (pipelined
-        #: path). See _decode_once / _dispatch_speculative / step().
+        #: path). See _decode_once / _dispatch_carried / step().
         self._inflight: "deque[_InflightChunk]" = deque()
         #: Chunks dispatched at each pipeline occupancy (depth AFTER
         #: the dispatch) — the bench's depth histogram. Keys are
@@ -660,7 +654,7 @@ class InferenceEngine:
         self.pipeline_depth_hist: Dict[int, int] = {
             d: 0 for d in range(1, 5)}
         #: Why a pipeline fill stopped, one count each time it did
-        #: (``_fill_refusal`` / ``_dispatch_speculative``; keys
+        #: (``_fill_refusal`` / ``_dispatch_carried``; keys
         #: preallocated like the histogram's): ``depth`` the pipeline
         #: is as deep as configured; ``free_slot`` a row is free and
         #: something waits for the host (an arrival, a pending request,
@@ -672,13 +666,12 @@ class InferenceEngine:
         #: would take shedding a sequence; ``row_ended`` a seated row
         #: can take no further step and only the host ends it;
         #: ``nothing_to_decode`` no row has budget left beyond the
-        #: chunks in flight; ``spec`` speculation windows never chain;
-        #: ``tenancy`` tenant fairness caps budgets in the host's
-        #: assembly only.
+        #: chunks in flight; ``tenancy`` tenant fairness caps budgets in
+        #: the host's assembly only.
         self.fill_refusals: Dict[str, int] = {
             k: 0 for k in ("depth", "free_slot", "urgent_pending",
                            "cancelled", "geometry", "pages", "row_ended",
-                           "nothing_to_decode", "spec", "tenancy")}
+                           "nothing_to_decode", "tenancy")}
         self._fill_stopped = ""
         #: Host staging buffers for chunk assembly (tokens/positions/
         #: block tables/temps) — per-dispatch np.zeros churn killer.
@@ -730,7 +723,7 @@ class InferenceEngine:
         #: only; flushed right after the lock drops.
         self._pending_tier_notes: List = []
         #: DISPATCHES of decode-capable programs (chunks, mixed
-        #: chunks, verify windows) — ``get_stats()["decode_steps"]``
+        #: chunks) — ``get_stats()["decode_steps"]``
         #: and ``llm_queue_decode_steps_total``; a chunk runs up to
         #: ``chunk_size`` device steps, counted in ``device_steps``.
         self.steps = 0
@@ -824,43 +817,6 @@ class InferenceEngine:
         #: to the exchange from there. Both default to inert.
         self.disagg_role = "unified"
         self.on_conversation_cached = None
-        #: Speculative decoding plane (docs/performance.md "Speculative
-        #: decoding"): drafter + verify-window scheduling replacing the
-        #: one-step-per-token decode cadence. ``speculation`` accepts a
-        #: core.config.SpeculationConfig or anything with its fields;
-        #: None/disabled (the default) keeps the exact pre-speculation
-        #: scheduling — the config's hard off-switch. Also requires an
-        #: executor that carries a verify entry point (built only when
-        #: its speculation knobs are set).
-        self._spec_cfg = (speculation
-                          if speculation is not None
-                          and getattr(speculation, "enabled", False)
-                          else None)
-        self._spec_on = (self._spec_cfg is not None
-                         and callable(getattr(executor, "verify_chunk",
-                                              None)))
-        self._drafter = None
-        if self._spec_on:
-            from llmq_tpu.speculation import NgramDrafter
-            dk = int(getattr(self._spec_cfg, "draft_k", 4))
-            ex_k = getattr(executor, "verify_draft_k", None)
-            if ex_k:
-                # The executor's verify program has a STATIC width —
-                # the drafter must never out-propose it.
-                dk = min(dk, int(ex_k))
-            self._drafter = NgramDrafter(
-                dk, int(getattr(self._spec_cfg, "ngram_max", 3)))
-        #: Speculation counters (engine-local so metrics-off benches can
-        #: still read them): windows reconciled, draft tokens proposed/
-        #: accepted, tokens committed through verify windows, and the
-        #: host fetches that carried them — committed/fetches is the
-        #: readback cadence (tokens per host readback; > 1 means the
-        #: one-fetch-per-token floor is broken).
-        self.spec_windows = 0
-        self.spec_tokens_proposed = 0
-        self.spec_tokens_accepted = 0
-        self.spec_commits_total = 0
-        self.spec_fetches_total = 0
 
     # -- submission ----------------------------------------------------------
 
@@ -1377,19 +1333,19 @@ class InferenceEngine:
         admitted = self._admit()       # free slots only while in flight
         prefilled = self._advance_prefill()
         if self._inflight:
-            # Speculate BEFORE the blocking resolve: a just-admitted
+            # Carry BEFORE the blocking resolve: a just-admitted
             # sequence must still hold an UNRESOLVED first_handle at
-            # the speculation decision so it enters via the join plan
+            # the carry decision so it enters via the join plan
             # (device-side override). Resolving first would flip it to
             # prefilled-but-not-in-chunk → geometry_changed → no
-            # speculation → its tokens wait a whole extra reconcile
+            # carried dispatch → its tokens wait a whole extra reconcile
             # cycle (measured: realtime tail_ms p99 +190 ms when the
             # fetch-wait servicing made resolves early).
             #
             # Pipeline fill: keep dispatching from the newest chunk's
             # device-carried end state until ``depth`` chunks are in
             # flight (depth 2 = the classic double buffer and the
-            # pre-pipeline scheduling: at most ONE speculative dispatch
+            # pre-pipeline scheduling: at most ONE carried dispatch
             # per step, since one chunk is always reconciled below).
             # The span opens once a fill may go ahead, and says how
             # many chunks went out and why it stopped; a fill refused
@@ -1398,7 +1354,7 @@ class InferenceEngine:
                 with self._prof.span("engine.fill") as fill:
                     dispatched = 0
                     while True:
-                        nxt = self._dispatch_speculative(self._inflight[-1])
+                        nxt = self._dispatch_carried(self._inflight[-1])
                         if nxt is None:
                             break
                         self._inflight.append(nxt)
@@ -1484,7 +1440,7 @@ class InferenceEngine:
         not be able to preempt (preemption off, or it is no more
         urgent than the least urgent decoding row). Then the fill goes
         ahead with requests pending and slices to run — the carried
-        chunk holds them (``_dispatch_speculative``). Tenant fairness
+        chunk holds them (``_dispatch_carried``). Tenant fairness
         caps decode budgets on the host-assembled path only, so with
         several tenants seated a full batch keeps the old rule."""
         if len(self._inflight) >= self._pipe_depth:
@@ -1703,8 +1659,9 @@ class InferenceEngine:
                 # No preemption while a chunk is in flight: the victim's
                 # rows are still decoding on device and its host-side
                 # position bookkeeping would go stale. The pending
-                # request blocks speculation, so the next reconcile
-                # clears the chunk and preemption runs one cycle later.
+                # request blocks the carried dispatch, so the next
+                # reconcile clears the chunk and preemption runs one
+                # cycle later.
                 victim = self._least_urgent_active()
                 if victim is not None and victim.sort_key() > (prio, order):
                     self._preempt(victim, release_pages=False)
@@ -1906,7 +1863,8 @@ class InferenceEngine:
             if self._inflight:
                 # Page-shedding a decoding row would free pages the
                 # in-flight chunk is still writing; defer to the next
-                # reconcile (the unadmitted request blocks speculation).
+                # reconcile (the unadmitted request blocks the carried
+                # dispatch).
                 return None
             victim = self._least_urgent_active(exclude=requester,
                                                include_prefilling=True)
@@ -2639,11 +2597,6 @@ class InferenceEngine:
         prefill pipeline is strictly faster — full buckets, async
         waves), and at least one mid-prefill slot has a dispatchable
         slice."""
-        if self._spec_on:
-            # Speculation subsumes decode advancement: every decode
-            # token moves through a verify window, so prefill runs
-            # through the dedicated bucket pipeline instead of fusing.
-            return False
         if not self._mixed_on():
             return False
         if not any(s is not None and s.prefilled for s in self._slots):
@@ -2712,7 +2665,7 @@ class InferenceEngine:
         ``row_steps`` the sum of the budgets, ``inflight`` the chunks
         in flight once this one is, ``context_tokens`` the tokens the
         rows attend to at the first step (host bookkeeping: a
-        speculative dispatch counts each unreconciled chunk's full
+        carried dispatch counts each unreconciled chunk's full
         budget), ``prefill_tokens`` the prompt tokens riding along and
         ``slice_tokens`` the rows the program's products run for them,
         padding included (the executor's ``slice_tokens``: the live row
@@ -2817,7 +2770,7 @@ class InferenceEngine:
             prefill_tokens=sum(len(c) for c in chunks),
             longest=max(len(c) for c in chunks), chunk=False)
 
-    def _dispatch_speculative(
+    def _dispatch_carried(
             self, infl: _InflightChunk) -> Optional[_InflightChunk]:
         """Dispatch the next chunk from the in-flight chunk's
         device-carried end state, BEFORE its tokens are fetched.
@@ -2856,13 +2809,6 @@ class InferenceEngine:
         idle conversation pins (``_alloc_pages``' first two rungs).
         With a row free it does not: the fresh path, one chunk away,
         decides what an admission is worth."""
-        if self._spec_on:
-            # Verify windows never chain device-to-device: the next
-            # window's drafts are keyed off tokens the host has not
-            # fetched yet — every window reconciles before the next
-            # dispatch.
-            self._refuse_fill("spec")
-            return None
         B = self.spec.batch_size
         full = all(s is not None for s in self._slots)
         chunk = max(1, getattr(self.executor, "chunk_size", 1))
@@ -3283,7 +3229,7 @@ class InferenceEngine:
     def _process_chunk(self, infl: _InflightChunk) -> None:
         """Commit an in-flight chunk's tokens. Uses the dispatch-time
         snapshot; cancellations are deliberately NOT acted on here (the
-        reconcile/fresh path owns them — a speculative chunk may
+        reconcile/fresh path owns them — a carried chunk may
         already be running on rows a cancel would free).
 
         While the fetcher thread waits on the transfer, this thread
@@ -3374,22 +3320,17 @@ class InferenceEngine:
         pf_first = None
         if infl.pf is not None:
             out, pf_first = out      # mixed chunk: (decode, slice firsts)
-        ncommit = None
-        if infl.spec:
-            out, ncommit = out       # verify window: (tokens, n_commit)
         if self._usage.enabled or self._cp.enabled:
             # Attribute BEFORE committing: rows that finish during the
             # commit loop (EOS) finalize their ledger record there and
-            # must already carry this chunk's share. Verify windows
-            # weigh rows by the ACCEPTED token counts (speculation
-            # attribution satellite), plain chunks by dispatch budgets.
+            # must already carry this chunk's share, weighed by the
+            # rows' dispatch budgets.
             parts = []
             decode_rows = []
             for slot in range(self.spec.batch_size):
                 seq = infl.seqs[slot]
                 if seq is not None and seq.slot == slot:
-                    w = max(1, int(ncommit[slot] if ncommit is not None
-                                   else infl.budgets[slot]))
+                    w = max(1, int(infl.budgets[slot]))
                     parts.append((seq, w, False))
                     decode_rows.append((seq, w))
             if infl.pf is not None:
@@ -3403,21 +3344,12 @@ class InferenceEngine:
                 self._cp_decode_share(device_s + readback_s, parts,
                                       decode_rows)
         tok0 = self.tokens_generated_total
-        pairs = []
         for slot in range(self.spec.batch_size):
             seq = infl.seqs[slot]
             if seq is None or seq.slot != slot:
                 continue    # finished while the chunk was in flight
-            if infl.spec:
-                self._commit_row(seq, out[slot], int(ncommit[slot]))
-                pairs.append((int(infl.budgets[slot]),
-                              int(ncommit[slot])))
-                self._spec_trim(seq)
-            else:
-                self._commit_row(seq, out[slot], int(infl.budgets[slot]))
+            self._commit_row(seq, out[slot], int(infl.budgets[slot]))
             self._flush_emits(seq)
-        if infl.spec:
-            self._note_spec_window(pairs)
         if infl.pf is not None:
             self._finish_mixed_prefills(infl.pf, pf_first)
         self._telemetry.note_step(infl.dispatch_s, device_s, readback_s,
@@ -3496,8 +3428,6 @@ class InferenceEngine:
                     budgets_by_order[s.order] = max(1, int(b * scale))
 
     def _decode_once(self) -> bool:
-        if self._spec_on:
-            return self._spec_once()
         B = self.spec.batch_size
         chunk = max(1, getattr(self.executor, "chunk_size", 1))
         chunk = min(chunk, self._admission_cap())
@@ -3618,200 +3548,6 @@ class InferenceEngine:
                                   self.tokens_generated_total - tok0)
         self._set_gauges()
         return True
-
-    def _spec_once(self) -> bool:
-        """Dispatch ONE speculative VERIFY window (docs/performance.md
-        "Speculative decoding"): per prefilled row the n-gram drafter
-        proposes up to draft_k tokens out of the row's own committed
-        stream, the executor verifies the whole window in one device
-        program, and reconciliation commits the accepted run plus the
-        correction token — so one host readback advances a row by up to
-        draft_k + 1 tokens. Rows whose lookup comes up empty (or whose
-        budget is 1) ride the same program as plain single steps, so
-        every decode advancement flows through this path while the
-        plane is on. Joining rows (unresolved ``first_handle``) are NOT
-        fused here — their first token commits at the next
-        ``_resolve_prefills`` and they enter the following window.
-
-        Equivalence contract: the committed stream is byte-identical to
-        spec-off — greedy by the teacher-forced verify construction,
-        temperature by position-keyed sampling (a committed token is a
-        deterministic function of (row, absolute position, prefix))."""
-        B = self.spec.batch_size
-        drafter = self._drafter
-        K = drafter.draft_k
-        # Window length is the drafter's k plus the correction slot —
-        # NOT capped by the plain decode chunk size. A verify window is
-        # its own device program (the drafts/qlens shapes are keyed to
-        # draft_k, not chunk_size); clamping it to the chunk would
-        # forfeit the whole plane whenever draft_k + 1 > chunk_size.
-        # The admission cap still binds: an urgent waiter must not sit
-        # out a long window any more than a long chunk.
-        win = max(1, min(K + 1, self._admission_cap()))
-        active = [s for s in self._slots if s is not None and s.prefilled]
-        if not active:
-            self._set_gauges()
-            return False
-        budgets_by_order = self._budget_chunk_rows(win, active)
-        active = [s for s in self._slots
-                  if s is not None and s.prefilled
-                  and s.order in budgets_by_order]
-        if not active:
-            self._set_gauges()
-            return False
-
-        t_asm = time.perf_counter()   # step decomposition: dispatch leg
-        st = self._staging
-        tokens = st.take("spec.tok", (B,), np.int32)
-        positions = st.take("spec.pos", (B,), np.int32)
-        block_tables = st.take("spec.bt",
-                               (B, self.spec.max_pages_per_seq), np.int32)
-        temps = st.take("spec.temp", (B,), np.float32)
-        drafts = st.take("spec.draft", (B, K), np.int32)
-        qlens = np.zeros(B, np.int32)   # read again at process time
-        ctx = 0
-        for seq in active:
-            i = seq.slot
-            ctx += seq.pos
-            budget = budgets_by_order[seq.order]
-            # Context = the committed stream: tokens whose KV is
-            # written plus the pending last sample (next decode input).
-            d = (drafter.propose(seq.written_ids + [seq.last_token],
-                                 budget - 1)
-                 if budget > 1 else [])
-            if d:
-                drafts[i, :len(d)] = d
-            tokens[i] = seq.last_token
-            positions[i] = seq.pos
-            block_tables[i] = seq.block_table
-            temps[i] = seq.req.temperature
-            # Window writes KV at [pos, pos + w); pages for the full
-            # budget (≥ w) were ensured in _budget_chunk_rows — the
-            # rejected tail's pages are trimmed back at reconcile.
-            qlens[i] = 1 + len(d)
-        start_fn = getattr(self.executor, "verify_chunk_start", None)
-        if start_fn is not None:
-            # Pipelined: dispatch only — (out, n_commit) are fetched on
-            # the NEXT step; the fetch overlaps arrival servicing.
-            with self._verify_dispatch(qlens, ctx):
-                handle = start_fn(tokens, positions, block_tables, temps,
-                                  drafts, qlens)
-            now = time.perf_counter()
-            dispatch_s = now - t_asm
-            _prefetch(getattr(handle, "out", None))
-            seqs = [None] * B
-            for seq in active:
-                seqs[seq.slot] = seq
-            infl = _InflightChunk(handle, seqs, qlens, spec=True,
-                                  dispatch_s=dispatch_s,
-                                  dispatched_at=now)
-            self._inflight.append(infl)
-            self._note_dispatch_depth(len(self._inflight))
-            self._start_fetch(infl)
-            self.steps += 1
-            if self._metrics:
-                self._m("decode_steps").inc()
-            return True
-        t_call = time.perf_counter()
-        with self._verify_dispatch(qlens, ctx):
-            out, ncommit = self.executor.verify_chunk(
-                tokens, positions, block_tables, temps, drafts, qlens)
-        t_done = time.perf_counter()
-        out = np.asarray(out)
-        ncommit = np.asarray(ncommit)   # readback fence (no-op for echo)
-        t_rb = time.perf_counter()
-        self.steps += 1
-        if self._metrics:
-            self._m("decode_steps").inc()
-        if self._usage.enabled or self._cp.enabled:
-            # Satellite of the speculation plane: device-seconds charge
-            # the ACCEPTED token counts, not the dispatched window
-            # bounds — a row whose drafts all missed weighs 1, exactly
-            # like a plain step.
-            parts = [(seq, max(1, int(ncommit[seq.slot])), False)
-                     for seq in active if seq.slot is not None]
-            if self._usage.enabled:
-                self._charge_step(t_done - t_call, parts)
-            if self._cp.enabled:
-                self._cp_decode_share(
-                    (t_done - t_call) + (t_rb - t_done), parts,
-                    [(seq, w) for seq, w, _ in parts])
-        tok0 = self.tokens_generated_total
-        pairs = []
-        for seq in active:
-            slot = seq.slot
-            self._commit_row(seq, out[slot], int(ncommit[slot]))
-            pairs.append((int(qlens[slot]), int(ncommit[slot])))
-            self._spec_trim(seq)
-            self._flush_emits(seq)
-        self._note_spec_window(pairs)
-        self._telemetry.note_step(t_call - t_asm, t_done - t_call,
-                                  t_rb - t_done,
-                                  self.tokens_generated_total - tok0)
-        self._set_gauges()
-        return True
-
-    def _verify_dispatch(self, qlens: np.ndarray, context_tokens: int):
-        """``_dispatch_span`` for a verify window: ONE device step over
-        up to ``draft_k + 1`` positions a row; ``row_steps`` is the
-        windows' sum, an upper bound of what the rows commit."""
-        return self._dispatch_span(
-            "verify_chunk", steps=1, rows=int(np.count_nonzero(qlens)),
-            row_steps=int(qlens.sum()), context_tokens=context_tokens)
-
-    def _spec_trim(self, seq: _Sequence) -> None:
-        """KV rollback for a reconciled verify window: pages past the
-        committed position hold only the rejected tail's stale KV —
-        return them to the pool (the allocator resolves each page's dp
-        universe from its id, so a page allocated for this very window
-        goes back where it came from). Mirrors ``_finish_active``'s
-        pre-pin trim. No-op for a finished/shed sequence — its pages
-        were already released wholesale."""
-        if seq.slot is None:
-            return
-        keep = PageAllocator.pages_for(seq.pos, self.spec.page_size)
-        if len(seq.pages) <= keep:
-            return
-        extra = seq.pages[keep:]
-        seq.pages = seq.pages[:keep]
-        seq.block_table[keep:keep + len(extra)] = 0
-        self.allocator.free(extra)
-        self._usage_pages(seq)
-
-    def _note_spec_window(self, pairs) -> None:
-        """Speculation telemetry for one reconciled verify window.
-        ``pairs``: (window_size w, n_commit) per COMMITTED row — rows
-        skipped at reconcile (finished while in flight) are excluded so
-        the readback cadence stays truthful. Per drafted row (w > 1)
-        the acceptance rate observes (n-1)/(w-1); the cadence gauge is
-        cumulative committed tokens per host fetch."""
-        proposed = 0
-        accepted = 0
-        committed = 0
-        for w, n in pairs:
-            if w <= 0:
-                continue
-            n = max(0, n)
-            committed += n
-            if w > 1:
-                proposed += w - 1
-                acc = max(0, n - 1)
-                accepted += acc
-                if self._metrics:
-                    self._m("spec_acceptance").observe(acc / (w - 1))
-        self.spec_windows += 1
-        self.spec_tokens_proposed += proposed
-        self.spec_tokens_accepted += accepted
-        self.spec_commits_total += committed
-        self.spec_fetches_total += 1
-        if self._metrics:
-            if proposed:
-                self._m("spec_tokens_proposed").inc(proposed)
-            if accepted:
-                self._m("spec_tokens_accepted").inc(accepted)
-            self._m("spec_readback_cadence").set(
-                self.spec_commits_total / self.spec_fetches_total)
-        self._telemetry.note_spec(proposed, accepted, committed)
 
     def _mixed_once(self) -> bool:
         """Dispatch ONE mixed iteration: the active decode rows' chunk
@@ -3951,7 +3687,7 @@ class InferenceEngine:
         ``cands``, most urgent first, packed into the compiled mixed
         program's slice grid under the token budget — the one packing
         of a host-assembled (``_mixed_once``) and of a carried
-        (``_dispatch_speculative``) mixed chunk."""
+        (``_dispatch_carried``) mixed chunk."""
         S = int(getattr(self.executor, "mixed_prefill_slices", 0))
         T = int(getattr(self.executor, "mixed_slice_tokens", 0))
         # The dispatch can never out-pack the compiled program's S
@@ -4482,26 +4218,6 @@ class InferenceEngine:
             # Tiered KV plane (docs/tiering.md): residency per tier,
             # hit breakdown incl. recompute, spill/round-trip counts.
             out["kv_tiering"] = self._tiering.stats()
-        if self._spec_on:
-            # Speculation plane (docs/performance.md "Speculative
-            # decoding"): acceptance and readback cadence — what
-            # bench.py reports as the LLMQ_BENCH_SPECULATION deltas.
-            out["speculation"] = {
-                "draft_k": self._drafter.draft_k,
-                "windows": self.spec_windows,
-                "tokens_proposed": self.spec_tokens_proposed,
-                "tokens_accepted": self.spec_tokens_accepted,
-                "acceptance_rate": (
-                    round(self.spec_tokens_accepted
-                          / self.spec_tokens_proposed, 4)
-                    if self.spec_tokens_proposed else 0.0),
-                "tokens_committed": self.spec_commits_total,
-                "fetches": self.spec_fetches_total,
-                "readback_cadence": (
-                    round(self.spec_commits_total
-                          / self.spec_fetches_total, 4)
-                    if self.spec_fetches_total else 0.0),
-            }
         if self._prefix_cache is not None:
             pc = self._prefix_cache.get_stats()
             total = self.prefix_hits + self.prefix_misses

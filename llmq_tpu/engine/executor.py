@@ -199,9 +199,9 @@ class EchoChunkHandle:
     program N has completed and set them."""
 
     __slots__ = ("out", "_ev", "_out", "_tok", "_pos", "_done",
-                 "pf_first", "_err", "_mixed", "_ncommit", "_verify")
+                 "pf_first", "_err", "_mixed")
 
-    def __init__(self, mixed: bool = False, verify: bool = False) -> None:
+    def __init__(self, mixed: bool = False) -> None:
         self._ev = threading.Event()
         self.out = _EchoOutProbe(self._ev)
         self._out = None
@@ -211,17 +211,10 @@ class EchoChunkHandle:
         self.pf_first = None
         self._err: Optional[BaseException] = None
         self._mixed = mixed
-        #: Speculation verify chunk: fetch() returns (out, n_commit) —
-        #: the accepted-run length per row rides the same single
-        #: readback as the tokens (docs/performance.md "Speculative
-        #: decoding").
-        self._verify = verify
-        self._ncommit = None
 
-    def _set(self, out, tok, pos, done, pf_first=None, ncommit=None) -> None:
+    def _set(self, out, tok, pos, done, pf_first=None) -> None:
         self._out, self._tok, self._pos, self._done = out, tok, pos, done
         self.pf_first = pf_first
-        self._ncommit = ncommit
         self._ev.set()
 
     def _fail(self, err: BaseException) -> None:
@@ -237,8 +230,6 @@ class EchoChunkHandle:
         self._ev.wait()
         if self._err is not None:
             raise self._err
-        if self._verify:
-            return self._out, self._ncommit
         if self._mixed:
             return self._out, self.pf_first
         return self._out
@@ -295,26 +286,12 @@ class EchoExecutor:
             0.0, float(prefill_delay_per_token_s))
         self._devq: Optional[queue.Queue] = None
         self._dev_thread: Optional[threading.Thread] = None
-        #: Deterministic verify seam (speculation plane): when set, a
-        #: ``fn(slot, n_drafts) -> int`` capping how many drafts a
-        #: window may ACCEPT for that slot — the echo "device" then
-        #: rejects the (cap+1)-th draft even when it matches the true
-        #: stream. Because the echo correction token IS the true next
-        #: token, capping changes acceptance counts (and therefore
-        #: windows/pages/rollbacks) without ever changing the committed
-        #: stream — the full accept/rollback/EOS-mid-window state
-        #: machine becomes testable without hardware.
-        self.verify_accept_cap: Optional[Callable[[int, int], int]] = None
-        #: Compiled-width cap for the engine's drafter (None = any
-        #: width — the echo backend has no compiled geometry).
-        self.verify_draft_k: Optional[int] = None
         if not self._async_chunks:
             # Hide the futures API: the engine feature-detects
             # decode_chunk_start/mixed_chunk_start with getattr — a
             # None instance attribute keeps it on the sync path.
             self.decode_chunk_start = None    # type: ignore[assignment]
             self.mixed_chunk_start = None     # type: ignore[assignment]
-            self.verify_chunk_start = None    # type: ignore[assignment]
 
     #: ``ops/rows.ROW_TILE`` (this backend imports no JAX, and ``ops``
     #: does; tests/test_profiling.py holds the two equal).
@@ -428,69 +405,9 @@ class EchoExecutor:
                                 temperatures, budgets)
         return out, pf_first
 
-    # -- speculation verify seam (docs/performance.md) -----------------------
-
-    def _verify_rows(self, positions: np.ndarray, drafts: np.ndarray,
-                     qlens: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        """Verify one speculation window per row against the echo
-        stream. Window semantics mirror the JAX ``_verify_chunk``
-        program: step j emits the TRUE token at ``positions[slot]+j+1``
-        (the echo stream is the model), the row stops at the first
-        draft mismatch (that emission is the correction token), at EOS,
-        or at the window end — ``n_commit`` counts the steps run. The
-        ``verify_accept_cap`` seam injects deterministic rejections:
-        the echoed correction equals the rejected draft, so the
-        committed stream is unchanged while every rollback path runs.
-        """
-        B = self.spec.batch_size
-        n_drafts = int(drafts.shape[1]) if drafts.ndim == 2 else 0
-        eos = self.spec.eos_id
-        out = np.full((B, n_drafts + 1), eos, np.int32)
-        ncommit = np.zeros(B, np.int32)
-        with self._mu:
-            for slot in range(B):
-                w = int(qlens[slot])
-                if w <= 0:
-                    continue
-                prompt = self._slot_prompt.get(slot)
-                end = self._slot_end.get(slot, 0)
-                cap = w - 1
-                if self.verify_accept_cap is not None:
-                    cap = max(0, min(cap, int(self.verify_accept_cap(
-                        slot, w - 1))))
-                n = 0
-                for j in range(w):
-                    k = int(positions[slot]) + j - end
-                    nxt = eos
-                    if prompt is not None and 0 <= k + 1 < len(prompt):
-                        nxt = int(prompt[k + 1])
-                    out[slot, j] = nxt
-                    n += 1
-                    if nxt == eos or j >= w - 1:
-                        break
-                    if j >= cap or int(drafts[slot, j]) != nxt:
-                        break
-                ncommit[slot] = n
-        return out, ncommit
-
-    def verify_chunk(self, tokens: np.ndarray, positions: np.ndarray,
-                     block_tables: np.ndarray, temperatures: np.ndarray,
-                     drafts: np.ndarray, qlens: np.ndarray
-                     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Synchronous verify window: ONE simulated device step for the
-        whole window (the speculation win — a window of w teacher-forced
-        steps costs one chunk cadence, and commits up to w tokens per
-        readback). Returns ``(out (B, n_drafts+1), n_commit (B,))``."""
-        if self._step_delay_s:
-            time.sleep(self._step_delay_s)
-        return self._verify_rows(np.asarray(positions, np.int32),
-                                 np.asarray(drafts, np.int32),
-                                 np.asarray(qlens, np.int32))
-
     # -- async futures API (docs/performance.md "Async pipeline") ------------
 
-    def _device_submit(self, fn, mixed: bool = False,
-                       verify: bool = False) -> "EchoChunkHandle":
+    def _device_submit(self, fn, mixed: bool = False) -> "EchoChunkHandle":
         """Enqueue one simulated device program. The single FIFO worker
         thread mirrors a real accelerator's in-order execution stream —
         chained carries read the PREVIOUS handle's end state, which FIFO
@@ -501,7 +418,7 @@ class EchoExecutor:
                 target=self._device_loop, args=(self._devq,),
                 name="echo-device", daemon=True)
             self._dev_thread.start()
-        h = EchoChunkHandle(mixed=mixed, verify=verify)
+        h = EchoChunkHandle(mixed=mixed)
         self._devq.put((fn, h))
         return h
 
@@ -643,26 +560,6 @@ class EchoExecutor:
 
         return self._device_submit(run, mixed=True)
 
-    def verify_chunk_start(self, tokens, positions, block_tables,
-                           temperatures, drafts, qlens
-                           ) -> "EchoChunkHandle":
-        """Futures-returning verify window (parity with
-        JaxExecutor.verify_chunk_start): dispatch returns immediately;
-        the FIFO device queue runs the window and the handle's fetch
-        returns ``(out, n_commit)`` — the speculation plane's single
-        batched readback. Inputs are snapshotted at dispatch."""
-        poss = np.asarray(positions, np.int32).copy()
-        drfs = np.asarray(drafts, np.int32).copy()
-        qls = np.asarray(qlens, np.int32).copy()
-
-        def run(h: "EchoChunkHandle") -> None:
-            if self._step_delay_s:
-                time.sleep(self._step_delay_s)
-            out, ncommit = self._verify_rows(poss, drfs, qls)
-            h._set(out, None, None, None, ncommit=ncommit)
-
-        return self._device_submit(run, verify=True)
-
     def release_slot(self, slot: int) -> None:
         with self._mu:
             self._slot_prompt.pop(slot, None)
@@ -680,7 +577,7 @@ class EchoExecutor:
 class ChunkHandle:
     """In-flight decode chunk: ``out`` is the (B, K) token matrix to
     fetch; ``tok``/``pos``/``done`` are the device-resident end state a
-    speculative next chunk consumes directly (no host round-trip)."""
+    carried next chunk consumes directly (no host round-trip)."""
 
     __slots__ = ("out", "tok", "pos", "done", "stats")
 
@@ -747,60 +644,6 @@ class MixedChunkHandle:
         return np.asarray(out), np.asarray(pf)
 
 
-def verify_host_ncommit(out: np.ndarray, drafts: np.ndarray,
-                        qlens: np.ndarray, eos: int) -> np.ndarray:
-    """Host-side accept rule for a fetched verify window — the exact
-    mirror of the device-accept program's freeze logic, used when
-    ``speculation.device_sampling`` is off (and by tests as the
-    reference oracle). Per row: walk the window, count a commit per
-    step, stop AFTER the step whose sample is EOS, is the last window
-    position, or diverges from its draft (the divergent sample is the
-    correction and is itself committed)."""
-    B, W = out.shape
-    nc = np.zeros(B, np.int32)
-    for i in range(B):
-        w = int(qlens[i])
-        n = 0
-        for j in range(min(w, W)):
-            n += 1
-            t = int(out[i, j])
-            if t == eos or j >= w - 1:
-                break
-            if int(drafts[i, j]) != t:
-                break
-        nc[i] = n
-    return nc
-
-
-class VerifyHandle:
-    """In-flight VERIFY window (speculation plane): ``fetch`` resolves
-    to ``(out (B, W) int32, n_commit (B,) int32)`` in ONE batched host
-    transfer — the k-step batched readback. With device-resident accept
-    n_commit comes off the device; with host accept it is recomputed
-    here from the fetched tokens (``verify_host_ncommit``), so the
-    engine sees one resolved contract either way."""
-
-    __slots__ = ("out", "ncommit", "_drafts", "_qlens", "_eos")
-
-    def __init__(self, out, ncommit, drafts=None, qlens=None,
-                 eos: int = 2) -> None:
-        self.out = out
-        self.ncommit = ncommit
-        self._drafts = drafts
-        self._qlens = qlens
-        self._eos = eos
-
-    def fetch(self) -> tuple:
-        import jax
-
-        if self.ncommit is not None:
-            out, nc = jax.device_get((self.out, self.ncommit))
-            return np.asarray(out), np.asarray(nc)
-        out = np.asarray(self.out)
-        return out, verify_host_ncommit(out, self._drafts, self._qlens,
-                                        self._eos)
-
-
 def _is_quantized_tree(params) -> bool:
     """int8 weights? Every family's tree has the attention's output
     projection at ``layers.wo``."""
@@ -852,8 +695,6 @@ class JaxExecutor:
                  chunk_size: int = 16, prefill_batch: int = 4,
                  mixed_prefill_slices: int = 2,
                  mixed_slice_tokens: int = 64,
-                 speculation_draft_k: int = 0,
-                 speculation_device_sampling: bool = True,
                  mesh=None, telemetry_name: str = "engine0",
                  telemetry_metrics: Optional[bool] = None) -> None:
         import jax
@@ -861,8 +702,7 @@ class JaxExecutor:
         from functools import partial
 
         from llmq_tpu.models import family_of
-        from llmq_tpu.ops.sampling import (
-            position_keys, sample_token, sample_token_keyed)
+        from llmq_tpu.ops.sampling import sample_token
 
         self._jax = jax
         self._jnp = jnp
@@ -878,10 +718,8 @@ class JaxExecutor:
             kv_quantization=("int8" if cache_dtype is not None
                              and jnp.dtype(cache_dtype) == jnp.int8
                              else ""),
-            mesh=mesh is not None and mesh.size > 1,
-            speculation_draft_k=int(speculation_draft_k))
+            mesh=mesh is not None and mesh.size > 1)
         init_kv_pages = fam.init_kv_pages
-        forward_verify = fam.forward_verify
         #: Counters a forward pass of this family returns after the
         #: cache (a routed model: tokens an expert, experts touched);
         #: 0 for a family that counts nothing. The chunk programs sum
@@ -1082,18 +920,6 @@ class JaxExecutor:
                     batch_size, page_size, max_pages_per_seq,
                     pools[0].shape[3], pools[0].dtype.itemsize)
         self._key = jax.random.PRNGKey(seed)
-        #: Speculation plane (docs/performance.md "Speculative
-        #: decoding"): ``verify_draft_k`` > 0 builds the verify-window
-        #: program (static width W = draft_k + 1). The sampling base
-        #: key is FIXED (not the dispatch-ordered ``_next_key`` stream):
-        #: verify programs derive per-draw keys from (row, absolute
-        #: position) via ``position_keys``, so the temperature stream is
-        #: a function of WHAT is committed, not of how windows were cut.
-        self.verify_draft_k = (int(speculation_draft_k)
-                               if speculation_draft_k > 0 else 0)
-        self._spec_device_sampling = bool(speculation_device_sampling)
-        self._spec_key = jax.random.fold_in(jax.random.PRNGKey(seed),
-                                            0x5BEC)
 
         cfg = model_cfg
         eos = eos_id
@@ -1131,20 +957,12 @@ class JaxExecutor:
             jit_mixed = partial(jax.jit, donate_argnums=(1,),
                                 out_shardings=(_batch, _batch, _batch,
                                                _batch, _repl, kvs, None))
-            # verify (device accept) returns (out (B, W), n_commit (B,),
-            # cache); verify (host accept) returns (out (B, W), cache).
-            jit_verify = partial(jax.jit, donate_argnums=(1,),
-                                 out_shardings=(_batch, _batch, kvs))
-            jit_verify_raw = partial(jax.jit, donate_argnums=(1,),
-                                     out_shardings=(_batch, kvs))
         else:
             self._batch_shd = None
             jit_step = partial(jax.jit, donate_argnums=(1,))
             jit_decode = jit_step
             jit_chunk = jit_step
             jit_mixed = jit_step
-            jit_verify = jit_step
-            jit_verify_raw = jit_step
 
         @jit_step
         def _prefill_step(params, cache, tokens, positions, lengths,
@@ -1195,7 +1013,7 @@ class JaxExecutor:
 
             ``lax.while_loop`` instead of a scan: the program EXITS as
             soon as every row is done (EOS-latched, budget-exhausted, or
-            latched on ENTRY via ``done_in`` — how a speculative next
+            latched on ENTRY via ``done_in`` — how a carried next
             chunk keeps rows the host has since finished frozen on
             reserved page 0), so small budgets cost exactly the steps
             run — one compiled program serves every granularity from 1
@@ -1212,7 +1030,7 @@ class JaxExecutor:
             # multi-chunk generation: ``done_in``/EOS are PERSISTENT
             # (carried out: the row is finished for good), while budget
             # exhaustion is THIS-CHUNK-ONLY (the row merely pauses; the
-            # speculative next chunk resumes it from the carried
+            # carried next chunk resumes it from the carried
             # tok/pos with a fresh budget).
             frozen0 = done_in
             # 2 decode steps per loop iteration: halves the while-loop's
@@ -1340,137 +1158,11 @@ class JaxExecutor:
                         (jnp.int32(1), cache, tok, pos, frozen, out, acc))
                 return out, tok, pos, frozen, pf_first, cache, acc
 
-        _verify_chunk = None
-        if self.verify_draft_k > 0 and self._spec_device_sampling:
-            W = self.verify_draft_k + 1
-
-            @jit_verify
-            def _verify_chunk(params, cache, tokens, positions,
-                              block_tables, temperatures, drafts, qlens,
-                              key):
-                """VERIFY window with device-resident accept
-                (docs/performance.md "Speculative decoding"): up to W
-                teacher-forced decode steps — step j feeds the j-th
-                DRAFT token, not the sampled one — freezing a row the
-                step after its sample diverges from its draft (the
-                divergent sample IS the correction, already emitted) or
-                samples EOS. Decode-SHAPED steps on purpose: a
-                prefill-shaped q_len=W verify is not bitwise equal to
-                sequential decode on bf16 (measured ~3e-2 logit drift),
-                and spec-on/off byte-identity is the plane's contract.
-
-                Sampling is position-keyed (``position_keys``): the key
-                for the token at absolute index p is fold_in(fold_in(
-                base, row), p), so any window cut draws the identical
-                stream for the identical committed positions. Frozen
-                rows keep running masked (writes land on reserved page
-                0 via ``active``); their garbage samples are never
-                committed and cannot perturb live rows (per-row
-                categorical draws depend only on key + row logits).
-
-                Returns ``(out (B, W), n_commit (B,), cache)`` — the
-                engine commits ``out[i, :n_commit[i]]`` per row; ONE
-                host readback resolves the whole window.
-                """
-                B = tokens.shape[0]
-                rows = jnp.arange(B, dtype=jnp.int32)
-                out0 = jnp.full((B, W), eos, jnp.int32)
-                # Pad the draft matrix with an impossible id: the last
-                # window step has no draft to agree with, so it always
-                # freezes (its emission is the bonus/correction token).
-                drafts_pad = jnp.concatenate(
-                    [drafts, jnp.full((B, 1), -1, jnp.int32)], axis=1)
-
-                def cond(st):
-                    j, _, _, _, frozen, _, _ = st
-                    return (j < W) & jnp.any(~frozen & (j < qlens))
-
-                def body(st):
-                    j, cache, tok, pos, frozen, out, ncommit = st
-                    active = (~frozen) & (j < qlens)
-                    logits, cache, _ = forward_decode(
-                        params, cfg, tok, pos, cache, block_tables,
-                        active=active)
-                    with scope("sample"):
-                        ks = position_keys(key, rows, pos + 1)
-                        nxt = sample_token_keyed(
-                            logits, ks, temperature=temperatures,
-                            top_k=top_k, top_p=top_p)
-                        emit = jnp.where(active, nxt,
-                                         eos).astype(jnp.int32)
-                        out = jax.lax.dynamic_update_slice(
-                            out, emit[:, None], (0, j))
-                        ncommit = ncommit + active.astype(jnp.int32)
-                        nd = jax.lax.dynamic_slice_in_dim(
-                            drafts_pad, j, 1, axis=1)[:, 0]
-                        frozen = frozen | (active & ((nxt == eos)
-                                                     | (nxt != nd)))
-                        tok = jnp.where(active, nd, tok)
-                        pos = pos + active.astype(jnp.int32)
-                    return (j + 1, cache, tok, pos, frozen, out, ncommit)
-
-                frozen0 = qlens <= 0
-                with scope("decode_loop"):
-                    _, cache, _, _, _, out, ncommit = jax.lax.while_loop(
-                        cond, body,
-                        (jnp.int32(0), cache, tokens, positions, frozen0,
-                         out0, jnp.zeros(B, jnp.int32)))
-                return out, ncommit, cache
-
-        elif self.verify_draft_k > 0:
-            W = self.verify_draft_k + 1
-
-            @jit_verify_raw
-            def _verify_chunk(params, cache, tokens, positions,
-                              block_tables, temperatures, qlens, key):
-                """VERIFY window with HOST accept (``device_sampling:
-                false``): the full W-step teacher-forced window runs
-                unconditionally (``forward_verify`` — same decode-shaped
-                steps, REAL KV writes for the whole window), all W
-                positions sample at once post-loop with the same
-                position-derived keys as the device-accept program, and
-                the executor wrapper computes n_commit on host from the
-                fetched tokens. Rows past their freeze point leave a
-                STALE KV tail beyond the committed position — safe by
-                the attention contract (``seq_lens`` masks positions
-                beyond the row's length; re-advancing overwrites before
-                attending) and deliberately exercised by the rollback
-                tests. ``tokens`` is the assembled (B, W) window:
-                column 0 the last committed token, columns 1.. the
-                drafts. Committed prefixes are byte-identical to the
-                device-accept program's.
-                """
-                B = tokens.shape[0]
-                with scope("decode_loop"):  # the W steps, unrolled
-                    logits, cache = forward_verify(
-                        params, cfg, tokens, positions, qlens, cache,
-                        block_tables)
-                with scope("sample"):
-                    V = logits.shape[-1]
-                    pos_flat = (positions[:, None]
-                                + jnp.arange(W, dtype=jnp.int32)[None, :]
-                                + 1).reshape(-1)
-                    rows_flat = jnp.repeat(
-                        jnp.arange(B, dtype=jnp.int32), W)
-                    ks = position_keys(key, rows_flat, pos_flat)
-                    toks = sample_token_keyed(
-                        logits.reshape(B * W, V), ks,
-                        temperature=jnp.repeat(temperatures, W),
-                        top_k=top_k, top_p=top_p)
-                    return toks.reshape(B, W), cache
-
         self._prefill_step = _prefill_step
         self._prefill_multi = _prefill_multi
         self._decode_step = _decode_step
         self._decode_chunk = _decode_chunk
         self._mixed_chunk = _mixed_chunk
-        self._verify_chunk = _verify_chunk
-        if _verify_chunk is None:
-            # Hard off-switch: no verify program exists, and the engine
-            # sees no verify entry points at all (same hiding pattern as
-            # EchoExecutor's async attrs).
-            self.verify_chunk = None
-            self.verify_chunk_start = None
         #: AOT-compiled executables by program name (filled by warmup;
         #: call sites prefer these — the jit wrappers re-trace on first
         #: call, the executables don't).
@@ -1832,12 +1524,6 @@ class JaxExecutor:
                       # across budget/slice reconfigurations.
                       (self.mixed_prefill_slices,
                        self.mixed_slice_tokens),
-                      # Speculation geometry: W = draft_k + 1 sets the
-                      # verify program's shapes, and device- vs
-                      # host-accept lower DIFFERENT programs under the
-                      # same name — artifacts must not collide.
-                      ("speculation", self.verify_draft_k,
-                       self._spec_device_sampling),
                       jax.tree.map(leaf_ident, self.params),
                       # Cache tree identity: bf16-KV and int8-KV lower
                       # different programs — colliding keys would make
@@ -1934,19 +1620,6 @@ class JaxExecutor:
                          (p, c, bsds((B,), i32), bsds((B,), i32),
                           bsds((B, MP), i32), bsds((B,), f32), key),
                          dec_routes))
-        if self._verify_chunk is not None:
-            Wv = self.verify_draft_k + 1
-            if self._spec_device_sampling:
-                jobs.append(("verify_chunk", self._verify_chunk,
-                             (p, c, bsds((B,), i32), bsds((B,), i32),
-                              bsds((B, MP), i32), bsds((B,), f32),
-                              bsds((B, Wv - 1), i32), bsds((B,), i32),
-                              key), dec_routes))
-            else:
-                jobs.append(("verify_chunk", self._verify_chunk,
-                             (p, c, bsds((B, Wv), i32), bsds((B,), i32),
-                              bsds((B, MP), i32), bsds((B,), f32),
-                              bsds((B,), i32), key), dec_routes))
         if self._mixed_chunk is not None:
             S, T = self.mixed_prefill_slices, self.mixed_slice_tokens
             jobs.append(("mixed_chunk", self._mixed_chunk,
@@ -2128,15 +1801,6 @@ class JaxExecutor:
                 np.ones(spec.batch_size, np.int32),
                 [(0, [1], 0, zbt[0], 0.0)])
             mixed.fetch()
-        if self._verify_chunk is not None:
-            # Verify-window smoke: window size 1 per row (a pure
-            # correction step), trash drafts, every write landing on
-            # reserved page 0 through the all-zero block tables.
-            self.verify_chunk(
-                zeros_b, zeros_b, zbt, ztemp,
-                np.zeros((spec.batch_size, self.verify_draft_k),
-                         np.int32),
-                np.ones(spec.batch_size, np.int32))
         if self.chunk_size > 1:
             self.decode_chunk(zeros_b, zeros_b, zbt, ztemp,
                               np.ones(spec.batch_size, np.int32))
@@ -2427,56 +2091,6 @@ class JaxExecutor:
                      budgets: np.ndarray) -> np.ndarray:
         h = self.decode_chunk_start(tokens, positions, block_tables,
                                     temperatures, budgets)
-        return h.fetch()
-
-    def verify_chunk_start(self, tokens, positions,
-                           block_tables: np.ndarray,
-                           temperatures: np.ndarray,
-                           drafts: np.ndarray,
-                           qlens: np.ndarray) -> "VerifyHandle":
-        """Dispatch one VERIFY window (speculation plane) without a
-        host sync: ``tokens`` (B,) are the rows' last committed tokens,
-        ``drafts`` (B, draft_k) the teacher-forced proposals (garbage
-        beyond a row's drafts), ``qlens`` (B,) the per-row window sizes
-        (accepted-draft upper bound + 1; 0 skips the row). The handle's
-        single fetch resolves (out, n_commit) for every row."""
-        if self._verify_chunk is None:
-            raise RuntimeError("speculation disabled for this executor")
-        jnp = self._jnp
-        fn = self._aot.get("verify_chunk", self._verify_chunk)
-        if self._spec_device_sampling:
-            out, ncommit, self.cache = fn(
-                self.params, self.cache,
-                self._batch_arr(tokens, jnp.int32),
-                self._batch_arr(positions, jnp.int32),
-                self._batch_arr(block_tables, jnp.int32),
-                self._batch_arr(temperatures, jnp.float32),
-                self._batch_arr(drafts, jnp.int32),
-                self._batch_arr(qlens, jnp.int32),
-                self._spec_key)
-            return VerifyHandle(out, ncommit)
-        W = self.verify_draft_k + 1
-        st = self._staging
-        toks = st.take("verify.tok", (self.spec.batch_size, W), np.int32)
-        toks[:, 0] = tokens
-        toks[:, 1:] = drafts
-        out, self.cache = fn(
-            self.params, self.cache,
-            self._batch_arr(toks, jnp.int32),
-            self._batch_arr(positions, jnp.int32),
-            self._batch_arr(block_tables, jnp.int32),
-            self._batch_arr(temperatures, jnp.float32),
-            self._batch_arr(qlens, jnp.int32),
-            self._spec_key)
-        return VerifyHandle(out, None,
-                            drafts=np.array(drafts, np.int32),
-                            qlens=np.array(qlens, np.int32),
-                            eos=self.spec.eos_id)
-
-    def verify_chunk(self, tokens, positions, block_tables, temperatures,
-                     drafts, qlens) -> tuple:
-        h = self.verify_chunk_start(tokens, positions, block_tables,
-                                    temperatures, drafts, qlens)
         return h.fetch()
 
     def mixed_chunk_start(self, tokens, positions,

@@ -36,7 +36,7 @@ A family is a module of this package that defines
   adopts nothing that only pages could rebuild (``get_stats()
   ["row_state"]``);
 - the serving programs' model functions: ``forward_prefill``,
-  ``forward_decode``, ``forward_mixed``, ``forward_verify``, with
+  ``forward_decode``, ``forward_mixed``, with
   ``models/llama.py``'s signatures and returns (``forward_mixed``
   takes its slices' tokens TIGHT with ``pf_starts`` — ``ops/rows.py`` —
   and returns of each slice the logits of its last valid position,
@@ -76,9 +76,9 @@ A family is a module of this package that defines
 - ``routes(cfg, cache, *, batch, page_size, max_pages, decode,
   prefill_rows)``: which implementation each attention op of a program
   takes (``ops/attention.kernel_routes``'s form);
-- ``check_serving(cfg, *, quantization, kv_quantization, mesh,
-  speculation_draft_k)``: raises ``ValueError`` naming the setting for
-  what the family does not support.
+- ``check_serving(cfg, *, quantization, kv_quantization, mesh)``:
+  raises ``ValueError`` naming the setting for what the family does not
+  support.
 """
 
 from __future__ import annotations

@@ -59,8 +59,8 @@ Float32 residual stream and router, bf16 products, every layer
 unrolled. In a mixed step the attention runs a layer's slices and its
 decode rows apart and the feed-forward runs them TOGETHER (a routed
 layer's experts are streamed once for both), as ``deepseek_v3``'s.
-Int8 weights, an int8 cache, a mesh and speculation's verify window are
-not written: each is refused by name (``check_serving``).
+Int8 weights, an int8 cache and a mesh are not written: each is refused
+by name (``check_serving``).
 """
 
 from __future__ import annotations
@@ -236,8 +236,7 @@ def attention_window(cfg: AfmoeConfig) -> Dict[str, int]:
 
 
 def check_serving(cfg: AfmoeConfig, *, quantization: str = "",
-                  kv_quantization: str = "", mesh: bool = False,
-                  speculation_draft_k: int = 0) -> None:
+                  kv_quantization: str = "", mesh: bool = False) -> None:
     """Refuse what is not written for this family, naming the setting."""
     what = None
     if quantization:
@@ -248,9 +247,6 @@ def check_serving(cfg: AfmoeConfig, *, quantization: str = "",
     elif mesh:
         what = ("executor.mesh (no partition rules for the slabs or the "
                 "experts, no exchange between shares)")
-    elif speculation_draft_k > 0:
-        what = (f"executor.speculation.draft_k={speculation_draft_k} "
-                f"(no verify window)")
     if what:
         raise ValueError(f"model {cfg.name!r} (family afmoe) does not "
                          f"support {what}; unset it")
@@ -743,10 +739,6 @@ def forward_decode(params: Params, cfg: AfmoeConfig, tokens: jnp.ndarray,
         counts.append(st)
     out = (_head(params, cfg, h), kv_cache, row_state)
     return out + (_sum_stats(cfg, counts),) if stats else out
-
-
-def forward_verify(params, cfg: AfmoeConfig, *args, **kw):
-    check_serving(cfg, speculation_draft_k=1)
 
 
 @partial(jax.jit, static_argnames=("cfg", "stats"))

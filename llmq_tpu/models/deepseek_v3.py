@@ -53,9 +53,9 @@ is a few kilobytes a token; the matmuls' operands stay bf16.
 The attention itself — the cache's row, the absorbed decode, the
 expanded prefill, the kernels' routes, and ``q_lora_rank`` (a low-rank
 query) — is ``models/latent.py``'s, which ``models/longcat_flash.py``
-shares. Int8 weights, an int8 cache, a mesh and speculation's verify
-window are not written for this family: each is refused with an error
-that names the setting (``check_serving``).
+shares. Int8 weights, an int8 cache and a mesh are not written for this
+family: each is refused with an error that names the setting
+(``check_serving``).
 """
 
 from __future__ import annotations
@@ -205,8 +205,7 @@ def row_state_bytes_per_row(cfg) -> int:
 
 
 def check_serving(cfg: DeepseekV3Config, *, quantization: str = "",
-                  kv_quantization: str = "", mesh: bool = False,
-                  speculation_draft_k: int = 0) -> None:
+                  kv_quantization: str = "", mesh: bool = False) -> None:
     """Refuse what is not written for this family, naming the setting."""
     what = None
     if quantization:
@@ -215,9 +214,6 @@ def check_serving(cfg: DeepseekV3Config, *, quantization: str = "",
         what = f"model.kv_quantization={kv_quantization!r} (an int8 latent)"
     elif mesh:
         what = "executor.mesh (no partition rules for latents or experts)"
-    elif speculation_draft_k > 0:
-        what = (f"executor.speculation.draft_k={speculation_draft_k} "
-                f"(no verify window)")
     if what:
         raise ValueError(f"model {cfg.name!r} (family deepseek_v3) does "
                          f"not support {what}; unset it")
@@ -457,10 +453,6 @@ def forward_decode(params: Params, cfg: DeepseekV3Config, tokens, positions,
             h = h + y
     out = (_finish(params, h, cfg), {"ckv": pool})
     return out + (_sum_stats(cfg, counts),) if stats else out
-
-
-def forward_verify(params, cfg: DeepseekV3Config, *args, **kw):
-    check_serving(cfg, speculation_draft_k=1)
 
 
 @partial(jax.jit, static_argnames=("cfg", "stats"))

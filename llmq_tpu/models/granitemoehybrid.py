@@ -181,8 +181,7 @@ def serving_config(cfg: GraniteHybridConfig) -> GraniteHybridConfig:
 
 
 def check_serving(cfg: GraniteHybridConfig, *, quantization: str = "",
-                  kv_quantization: str = "", mesh: bool = False,
-                  speculation_draft_k: int = 0) -> None:
+                  kv_quantization: str = "", mesh: bool = False) -> None:
     """Refuse what is not written for this family, naming the setting."""
     what = None
     if quantization:
@@ -193,10 +192,6 @@ def check_serving(cfg: GraniteHybridConfig, *, quantization: str = "",
                 f"beside a float32 row state)")
     elif mesh:
         what = "executor.mesh (no partition rules for the row state)"
-    elif speculation_draft_k > 0:
-        what = (f"executor.speculation.draft_k={speculation_draft_k} (a "
-                f"rejected draft cannot be rolled back out of the row "
-                f"state by trimming pages)")
     if what:
         raise ValueError(f"model {cfg.name!r} (family granitemoehybrid) "
                          f"does not support {what}; unset it")
@@ -674,25 +669,6 @@ def forward_decode(params: Params, cfg: GraniteHybridConfig,
         cfg, layer, (h, kv_cache["k"], kv_cache["v"], row_state), True)
     with scope("head"):
         return _head(params, cfg, h), {"k": k_pool, "v": v_pool}, row_state
-
-
-def forward_verify(params: Params, cfg: GraniteHybridConfig,
-                   tokens: jnp.ndarray, positions: jnp.ndarray,
-                   qlens: jnp.ndarray, kv_cache: KVCache,
-                   block_tables: jnp.ndarray,
-                   row_state: Optional[RowState] = None):
-    """W teacher-forced decode steps (``models/llama.forward_verify``).
-    Serving never builds it for this family — ``check_serving`` refuses
-    speculation, because the steps past a rejected draft have already
-    moved the row state — but a caller that commits the whole window
-    may use it."""
-    outs = []
-    for j in range(tokens.shape[1]):
-        logits, kv_cache, row_state = forward_decode(
-            params, cfg, tokens[:, j], positions + j, kv_cache, block_tables,
-            active=j < qlens, row_state=row_state)
-        outs.append(logits)
-    return jnp.stack(outs, axis=1), kv_cache, row_state
 
 
 @partial(jax.jit, static_argnames=("cfg",))

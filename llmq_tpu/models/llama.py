@@ -304,7 +304,8 @@ def row_state_bytes_per_row(cfg) -> int:
     return 0
 
 
-def check_serving(cfg: LlamaConfig, **settings) -> None:
+def check_serving(cfg: LlamaConfig, *, quantization: str = "",
+                  kv_quantization: str = "", mesh: bool = False) -> None:
     """Everything the serving settings can ask for is written for this
     family."""
 
@@ -624,48 +625,6 @@ def forward_decode(
     with scope("head"):
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
         return _logits(params, h), out_cache
-
-
-def forward_verify(
-    params: Params,
-    cfg: LlamaConfig,
-    tokens: jnp.ndarray,        # (B, W) int32 — teacher-forced window inputs
-    positions: jnp.ndarray,     # (B,) int32 — absolute position of tokens[:, 0]
-    qlens: jnp.ndarray,         # (B,) int32 — valid window steps per row (0 = inactive)
-    kv_cache: KVCache,
-    block_tables: jnp.ndarray,  # (B, max_pages)
-) -> Tuple[jnp.ndarray, KVCache]:
-    """Speculation verify window, decode-shaped (docs/performance.md
-    "Speculative decoding"): run the W window inputs through W
-    TEACHER-FORCED ``forward_decode`` steps — step j feeds
-    ``tokens[:, j]`` at position ``positions + j`` regardless of what
-    step j-1 sampled. Returns (logits (B, W, V) f32 — one target
-    distribution per window step, the caller samples/accepts — and the
-    updated cache).
-
-    Decode-shaped on purpose: a q_len=W prefill-shaped slice computes
-    the SAME math with different reduction shapes and is NOT bit-stable
-    against the decode path on bf16 (measured ~3e-2 logit drift, KV
-    pools diverge) — and bit-identity with speculation off is the
-    plane's contract. Teacher-forcing keeps every committed position's
-    logits byte-equal to what the plain chunk program would have
-    produced, while still costing ONE dispatch + ONE readback for the
-    whole window.
-
-    Steps past a row's ``qlens`` run with ``active=False``: their KV
-    scatters to reserved page 0 and their logits are garbage the caller
-    must ignore. W is static (the compiled window width); the step loop
-    unrolls like the layer loop — same aliased-pool reasoning.
-    """
-    B, W = tokens.shape
-    outs = []
-    for j in range(W):
-        active_j = j < qlens
-        logits_j, kv_cache = forward_decode(
-            params, cfg, tokens[:, j], positions + j, kv_cache,
-            block_tables, active=active_j)
-        outs.append(logits_j)
-    return jnp.stack(outs, axis=1), kv_cache
 
 
 @partial(jax.jit, static_argnames=("cfg",))

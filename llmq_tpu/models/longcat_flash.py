@@ -50,9 +50,8 @@ router, bf16 matmul operands, absorbed decode through the latent
 kernel, expanded prefill under XLA, each routed layer's expert matrices
 a leaf of their own. In a mixed step every projection and feed-forward
 runs ONCE over the slices' tokens and the decode rows together; only
-the attention itself runs them apart. Int8 weights, an int8 cache, a
-mesh and speculation's verify window are not written: each is refused
-by name (``check_serving``).
+the attention itself runs them apart. Int8 weights, an int8 cache and a
+mesh are not written: each is refused by name (``check_serving``).
 """
 
 from __future__ import annotations
@@ -218,8 +217,7 @@ def row_state_bytes_per_row(cfg) -> int:
 
 
 def check_serving(cfg: LongcatFlashConfig, *, quantization: str = "",
-                  kv_quantization: str = "", mesh: bool = False,
-                  speculation_draft_k: int = 0) -> None:
+                  kv_quantization: str = "", mesh: bool = False) -> None:
     """Refuse what is not written for this family, naming the setting."""
     what = None
     if quantization:
@@ -229,9 +227,6 @@ def check_serving(cfg: LongcatFlashConfig, *, quantization: str = "",
     elif mesh:
         what = ("executor.mesh (no partition rules for latents or experts, "
                 "no exchange between shares)")
-    elif speculation_draft_k > 0:
-        what = (f"executor.speculation.draft_k={speculation_draft_k} "
-                f"(no verify window)")
     if what:
         raise ValueError(f"model {cfg.name!r} (family longcat_flash) does "
                          f"not support {what}; unset it")
@@ -567,10 +562,6 @@ def forward_decode(params: Params, cfg: LongcatFlashConfig, tokens,
         active)
     out = (_finish(params, h, cfg), {"ckv": pool[0]})
     return out + (counts,) if stats else out
-
-
-def forward_verify(params, cfg: LongcatFlashConfig, *args, **kw):
-    check_serving(cfg, speculation_draft_k=1)
 
 
 @partial(jax.jit, static_argnames=("cfg", "stats"))

@@ -218,31 +218,6 @@ def paged_decode_attention_pooled(
     return _gqa_attend(q, k, v, seq_lens, window)
 
 
-def paged_pool_window(pool: jnp.ndarray, block_table: jnp.ndarray,
-                      start: int, length: int) -> jnp.ndarray:
-    """Read ``length`` token rows at absolute positions
-    ``[start, start+length)`` of ONE sequence out of a stacked flat pool
-    (L, P, page_size, H_kv·D) via its block table. Returns
-    (L, length, H_kv·D).
-
-    This is the speculation plane's KV-truncation probe (tests and the
-    tiering extract path): after a mid-window rejection the pages past
-    ``pages_for(new_pos)`` are freed, but the KEPT tail positions
-    ``[new_pos, old_window_end)`` may still hold teacher-forced garbage
-    (host-accept mode runs the whole window with real writes). That
-    tail is safe ONLY because every attention read masks beyond
-    ``seq_lens`` (``_gqa_attend``) — this helper is how tests pin the
-    physical-layout half of that contract: committed positions'
-    KV must be byte-stable across accept/reject, while the stale tail
-    gets overwritten before the row's ``seq_lens`` ever reaches it.
-    """
-    page_size = pool.shape[2]
-    pos = start + jnp.arange(length)
-    page_of = block_table[pos // page_size]
-    slot_of = pos % page_size
-    return pool[:, page_of, slot_of]
-
-
 def pallas_mode() -> str:
     """``LLMQ_PALLAS``: ``auto`` (default — kernels on a TPU backend,
     pure JAX elsewhere), ``0`` (pure JAX everywhere) or ``interpret``

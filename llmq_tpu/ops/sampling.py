@@ -52,37 +52,3 @@ def sample_token(
     t, lf, scaled = _filter_logits(logits, temperature, top_k, top_p)
     sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(t <= 0.0, greedy(lf), sampled)
-
-
-def position_keys(base: jax.Array, rows: jnp.ndarray,
-                  positions: jnp.ndarray) -> jax.Array:
-    """Per-(row, position) PRNG keys: ``fold_in(fold_in(base, row),
-    position)``, vmapped. The speculation plane samples with these so
-    the random stream is a function of WHAT is sampled (batch row +
-    absolute sequence position), not of how steps were chunked into
-    dispatches — any draft window size then draws the identical stream
-    for the identical committed positions (docs/performance.md
-    "Speculative decoding")."""
-    def one(r, p):
-        return jax.random.fold_in(jax.random.fold_in(base, r), p)
-    return jax.vmap(one)(rows.astype(jnp.uint32),
-                         positions.astype(jnp.uint32))
-
-
-def sample_token_keyed(
-    logits: jnp.ndarray,          # (B, V)
-    keys: jax.Array,              # (B,) stacked PRNG keys (one per row)
-    temperature: jnp.ndarray | float = 1.0,   # scalar or (B,)
-    top_k: int = 0,
-    top_p: float = 1.0,
-) -> jnp.ndarray:
-    """``sample_token`` with an independent key per row. Same
-    temperature/top-k/top-p filtering; the categorical draw vmaps over
-    (key, row) pairs instead of deriving every row from one key —
-    required by position-keyed sampling, where two rows at different
-    sequence positions must draw from unrelated streams."""
-    t, lf, scaled = _filter_logits(logits, temperature, top_k, top_p)
-    sampled = jax.vmap(
-        lambda k, row: jax.random.categorical(k, row)
-    )(keys, scaled).astype(jnp.int32)
-    return jnp.where(t <= 0.0, greedy(lf), sampled)

@@ -75,7 +75,7 @@ WAVE = [
 
 REFUSAL_KEYS = {"depth", "free_slot", "urgent_pending", "cancelled",
                 "geometry", "pages", "row_ended", "nothing_to_decode",
-                "spec", "tenancy"}
+                "tenancy"}
 
 
 def drive_wave(eng, wave=WAVE, conv=None, steps_between=2, max_new=40):
@@ -236,7 +236,7 @@ class TestEchoEquivalence:
         assert all(int(k) <= 3 for k in hist)  # never past the bound
 
     def test_depth1_reconciles_every_chunk(self):
-        """depth=1 disables speculation entirely — every chunk is
+        """depth=1 disables the carried dispatch entirely — every chunk is
         reconciled before the next dispatch, streams unchanged."""
         eng, _ = make_echo_engine(pipe_cfg(depth=1))
         handles = drive_wave(eng)
@@ -521,7 +521,7 @@ class TestFullBatchRule:
         everyone = ([s for s in eng._slots]
                     + [q for _, _, q in eng._pending])
         calls = []
-        inner = eng._dispatch_speculative
+        inner = eng._dispatch_carried
 
         def watched(infl):
             before = [(q.slot, list(q.pages)) for q in everyone]
@@ -531,14 +531,14 @@ class TestFullBatchRule:
                                      for q in everyone]))
             return out
 
-        eng._dispatch_speculative = watched
+        eng._dispatch_carried = watched
         for _ in range(40):
             eng.step()
             if ("pages", True) in calls or ("pages", False) in calls:
                 break
         assert ("pages", True) in calls and ("pages", False) not in calls
         assert eng.fill_refusals["pages"] >= 1
-        eng._dispatch_speculative = inner
+        eng._dispatch_carried = inner
         eng.allocator.free(held)
         eng.run_until_idle()
         assert all(h.result.finish_reason in ("eos", "length")
@@ -572,6 +572,38 @@ class TestFullBatchRule:
         assert eng.allocator.available_by_shard() == [3, 0]
         for h in held:
             eng.allocator.free(h)
+        eng.stop()
+
+    def test_a_row_ended_inside_a_chunk_trims_into_its_own_universe(self):
+        """A row commits up to ``decode_chunk`` tokens a fetch and its
+        chunks' budgets — the carried one's too — are backed with pages
+        ahead. When its stream ends inside a chunk, the pin keeps the
+        pages of what was written and the rest go back to the free list
+        of the row's OWN universe of a dp mesh: none leaks to the
+        other one, where no row of this one could use it."""
+        eng, _ = make_echo_engine(pipe_cfg(), mixed=mixed_cfg(), slots=4,
+                                  dp=2)
+        before = eng.allocator.available_by_shard()
+        hs = [eng.submit(GenRequest(
+                  id=f"r{i}", conversation_id=f"conv{i}", max_new_tokens=60,
+                  prompt=f"row {i} " + "says so " * (2 + i)))
+              for i in range(4)]
+        eng.run_until_idle()
+        assert [h.result.finish_reason for h in hs] == ["eos"] * 4
+        assert eng.pipeline_depth_hist[2] > 0      # chunks were carried
+        ps = eng.spec.page_size
+        pinned = [0, 0]
+        for i, h in enumerate(hs):
+            kv = eng._conv_cache[f"conv{i}"]
+            # the echo's EOS fell inside a chunk: a budget ran past it
+            assert len(h.result.tokens) % 4
+            assert len(kv.pages) == eng.allocator.pages_for(kv.length, ps)
+            (shard,) = {eng.allocator.shard_of(p) for p in kv.pages}
+            pinned[shard] += len(kv.pages)
+        assert min(pinned) > 0                     # both universes used
+        assert eng.allocator.available_by_shard() == [
+            before[d] - pinned[d] for d in (0, 1)]
+        assert eng.allocator.used() == eng.allocator.pinned_pages()
         eng.stop()
 
     @pytest.mark.parametrize("clients", [6, 12])
@@ -643,7 +675,7 @@ class TestFullBatchRule:
         assert eng._inflight[0].seqs[0] is stuck
         eng._inflight[0].budgets[0] = 0    # nothing of it in flight
         stuck.pos = eng.spec.max_pages_per_seq * eng.spec.page_size
-        assert eng._dispatch_speculative(eng._inflight[-1]) is None
+        assert eng._dispatch_carried(eng._inflight[-1]) is None
         assert eng.fill_refusals["row_ended"] == 1
         eng.step()
         eng.step()

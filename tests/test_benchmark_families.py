@@ -637,6 +637,30 @@ def test_who_imports_what_in_a_family(family):
     assert any(m.startswith("llmq_tpu") for m in imports["adapter"])
 
 
+@pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
+                                    "granitemoehybrid", "afmoe"])
+def test_what_a_family_brings_to_the_program(family):
+    """The program's side of the seam (``llmq_tpu/models/__init__.py``):
+    three forward passes the serving programs are built from — no
+    fourth for a window of drafts — and a ``check_serving`` that is
+    asked about weights, cache and mesh, nothing else."""
+    import inspect
+
+    from llmq_tpu import models
+
+    assert sorted(models.FAMILIES) == sorted(
+        os.listdir(os.path.join(REPO, "benchmark", "families")))
+    mod = models.family(family)
+    for name in ("forward_prefill", "forward_decode", "forward_mixed"):
+        assert callable(getattr(mod, name)), name
+    assert not hasattr(mod, "forward_verify")
+    params = list(inspect.signature(mod.check_serving).parameters.values())
+    assert params[0].kind is inspect.Parameter.POSITIONAL_OR_KEYWORD
+    assert [(p.name, p.kind) for p in params[1:]] == [
+        (n, inspect.Parameter.KEYWORD_ONLY)
+        for n in ("quantization", "kv_quantization", "mesh")]
+
+
 def test_the_parent_process_stays_off_jax_for_the_new_cell(bench):
     p = subprocess.run(
         [sys.executable, "-c",

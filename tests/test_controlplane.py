@@ -558,7 +558,7 @@ class TestChaosRecovery:
             ctl.run_once()                       # bootstrap 2 replicas
             assert len(router.lb.endpoints()) == 2
             # A LIVE token stream on the doomed replica: the crash
-            # lands mid-stream (2 chunks speculated in flight), and
+            # lands mid-stream (2 chunks carried in flight), and
             # the monotone invariant must hold — streamed tokens are a
             # prefix of the recorded result, never replayed/extended.
             from llmq_tpu.engine.engine import GenRequest

@@ -120,6 +120,15 @@ class TestConfig:
         with pytest.raises(ValueError):
             load_config(str(p))
 
+    def test_a_removed_option_is_refused_by_name(self, tmp_path):
+        """``executor.speculation`` left with PR 44: a YAML that still
+        names it fails at load, with the key, rather than being read
+        past (docs/configuration.md)."""
+        p = tmp_path / "c.yaml"
+        p.write_text("executor:\n  speculation: {enabled: false}\n")
+        with pytest.raises(ValueError, match=r"executor\.speculation"):
+            load_config(str(p))
+
     def test_repo_canonical_config_loads(self):
         path = os.path.join(os.path.dirname(__file__), "..", "configs", "config.yaml")
         cfg = load_config(path, env=False)

@@ -577,7 +577,7 @@ def reference_greedy(cfg, params, prompt_ids, n_steps):
 class TestJaxEngine:
     def test_multi_chunk_generation_spans_chunks(self, tiny_model):
         """A generation LONGER than chunk_size must produce identical
-        tokens through the pipelined/speculative path (chunk_size=4) and
+        tokens through the pipelined/carried path (chunk_size=4) and
         the single-step path (chunk_size=1) — and run to its full length
         (r4: a carry bug latched budget-paused rows as done, truncating
         every multi-chunk generation with a phantom EOS)."""

@@ -530,7 +530,6 @@ def test_registry_serves_the_family_by_name_at_the_published_sizes():
     ("int8-weights", "model.quantization='int8'"),
     ("int8-cache", "model.kv_quantization='int8'"),
     ("mesh", "executor.mesh"),
-    ("speculation", "executor.speculation.draft_k=4"),
 ])
 def test_registry_refuses_with_an_error_that_names_the_setting(what, match):
     cfg = lf.longcat_flash_tiny(max_seq_len=64)
@@ -544,8 +543,7 @@ def test_registry_refuses_with_an_error_that_names_the_setting(what, match):
                 lambda: lf.init_params(jax.random.PRNGKey(0), cfg))
             kw = {"int8-cache": dict(cache_dtype=jnp.int8),
                   "mesh": dict(mesh=jax.sharding.Mesh(
-                      np.array(jax.devices()[:2]), ("tp",))),
-                  "speculation": dict(speculation_draft_k=4)}[what]
+                      np.array(jax.devices()[:2]), ("tp",)))}[what]
             JaxExecutor(cfg, params, batch_size=2, page_size=8,
                         num_pages=16, **kw)
 

@@ -620,8 +620,7 @@ class TestMeshExportCacheKey:
                 model_cfg=cfg, spec=ExecutorSpec(2, 16, 34, 16, 2),
                 chunk_size=2, prefill_batch=1, prefill_buckets=[16],
                 _top_k=0, _top_p=1.0, mixed_prefill_slices=0,
-                mixed_slice_tokens=0, verify_draft_k=0,
-                _spec_device_sampling=True, mesh=mesh, dp_shards=1,
+                mixed_slice_tokens=0, mesh=mesh, dp_shards=1,
                 params=placed, cache=dict(cache))
             return JaxExecutor._export_cache_key(stub)
 
@@ -947,8 +946,7 @@ def key_for(mesh_, dp_shards, cache):
         spec=ExecutorSpec(B, page_size, num_pages, mpps, 2),
         chunk_size=16, prefill_batch=4, prefill_buckets=[512],
         _top_k=0, _top_p=1.0, mixed_prefill_slices=0,
-        mixed_slice_tokens=0,
-        verify_draft_k=0, _spec_device_sampling=True, mesh=mesh_,
+        mixed_slice_tokens=0, mesh=mesh_,
         dp_shards=dp_shards, params=abs_params, cache=cache)
     return JaxExecutor._export_cache_key(stub)
 
