@@ -645,10 +645,10 @@ class MixedChunkHandle:
 
 
 def _is_quantized_tree(params) -> bool:
-    """int8 weights? Every family's tree has the attention's output
-    projection at ``layers.wo``."""
+    """int8 weights? The attention's output projection at ``layers.wo``
+    says (a tree without it stacks its mixers by kind, and has none)."""
     from llmq_tpu.ops.quant import is_quantized
-    return is_quantized(params["layers"]["wo"])
+    return is_quantized(params["layers"].get("wo"))
 
 
 def _named(fn: Callable, name: str) -> Callable:

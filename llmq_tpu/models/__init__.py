@@ -20,7 +20,9 @@ A family is a module of this package that defines
   batch row, ...)`` that a sequence carries from token to token
   whatever its length (a state-space layer's recurrent state; the
   leaves hold ``batch + 1`` rows, the last nobody's, as page 0 is: an
-  unused slice of a program names it; or a window layer's keys and
+  unused slice of a program names it — ``models/granitemoehybrid.py``
+  beside K/V pages, ``models/ling_hybrid.py`` beside a latent pool; or
+  a window layer's keys and
   values, a SLAB of pool-shaped pages a batch row: ``models/afmoe.py``,
   which also defines the optional ``bind_cache(cfg, *, page_size,
   step_tokens)``, called by the executor before ``init_row_state``, and
@@ -104,6 +106,7 @@ FAMILIES: Dict[str, str] = {
     "longcat_flash": "llmq_tpu.models.longcat_flash",
     "granitemoehybrid": "llmq_tpu.models.granitemoehybrid",
     "afmoe": "llmq_tpu.models.afmoe",
+    "ling_hybrid": "llmq_tpu.models.ling_hybrid",
 }
 
 

@@ -397,3 +397,23 @@ def latent_prefill_attention(cfg, lp: Params, l: int, q_nope, q_rope, pool,
             o = o * s_kv
         return o.astype(jnp.result_type(pool.dtype, wv.dtype)).reshape(
             B, T, -1)
+
+
+# -- a head-wise output gate (an optional leaf) ---------------------------------
+
+def head_gate(cfg, lp: Params, l: int, x, o):
+    """What a configuration may add to the attention's result before
+    ``wo``: each head's ``v_head_dim`` values times ``sigmoid(x
+    W_gate)``, one value a head (``gated_attention_proj_granularity_type``
+    "head_wise"; leaf ``w_head_gate`` (n, D, H)). ``x`` (..., D) the
+    attention's normed input, ``o`` (..., H * dv). A tree without the
+    leaf: ``o`` as it came — the program there was."""
+    if "w_head_gate" not in lp:
+        return o
+    with scope("attn_gate"):
+        gate = jax.nn.sigmoid(jnp.dot(x, lp["w_head_gate"][l])
+                              .astype(jnp.float32))
+        shape = o.shape
+        o = o.reshape(shape[:-1] + (cfg.n_heads, cfg.v_head_dim))
+        return (o.astype(jnp.float32) * gate[..., None]).astype(
+            o.dtype).reshape(shape)

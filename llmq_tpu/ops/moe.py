@@ -56,16 +56,42 @@ from jax import lax
 from llmq_tpu.utils.profiling import scope
 
 
+def _limit(sel: jnp.ndarray, n_group: int, topk_group: int) -> jnp.ndarray:
+    """``route``'s selection scores ``sel`` (N, E) = s + bias, float32,
+    LIMITED TO GROUPS (DeepSeek-V3's ``n_group`` / ``topk_group``): the
+    E experts lie in ``n_group`` groups of E / n_group neighbours, a
+    group's score is the sum of its top 2 of ``sel``, the best
+    ``topk_group`` groups are kept and the experts of every other group
+    are out of the choice (-inf). ``n_group`` 1 (``route``'s default):
+    ``sel`` as it came — the program of a router without groups, which
+    ``tests/test_kda.py`` pins."""
+    sel = sel.astype(jnp.float32)
+    if n_group == 1:
+        return sel
+    N, E = sel.shape
+    if E % n_group or not 0 < topk_group <= n_group:
+        raise ValueError(f"{E} experts in {n_group} groups, the best "
+                         f"{topk_group} kept")
+    by_group = sel.reshape(N, n_group, E // n_group)
+    score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)        # (N, G)
+    kept = jnp.zeros((N, n_group), bool).at[
+        jnp.arange(N)[:, None], lax.top_k(score, topk_group)[1]].set(True)
+    return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(N, E)
+
+
 def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
-          top_k: int, scale: float, norm_topk: bool = True,
-          scoring: str = "sigmoid") -> Tuple[jnp.ndarray, jnp.ndarray]:
+          top_k: int, scale: float, norm_topk: bool = True, n_group: int = 1,
+          topk_group: int = 1, scoring: str = "sigmoid"
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``x`` (N, D) -> (experts (N, k) int32, gates (N, k) float32).
 
     s = sigmoid(x W_r) — or, ``scoring="softmax"``, softmax over all E
     of x W_r — in float32 at the highest matmul precision (a
     bf16 pass swaps near-tied experts); the ``k`` experts are the top
-    ``k`` of ``s + bias``; the gates are the chosen ``s`` (WITHOUT the
-    bias), normalised to sum 1 where ``norm_topk``, times ``scale``."""
+    ``k`` of ``s + bias``, among the best ``topk_group`` of ``n_group``
+    groups where there are groups (``_limit``); the gates are the chosen
+    ``s`` (WITHOUT the bias), normalised to sum 1 where ``norm_topk``,
+    times ``scale``."""
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"unknown router scoring {scoring!r}")
     with scope("moe_route"):
@@ -74,7 +100,7 @@ def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
                          precision=lax.Precision.HIGHEST)
         s = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
              else jax.nn.softmax(logits, axis=-1))
-        _, experts = lax.top_k(s + bias.astype(jnp.float32), top_k)
+        _, experts = lax.top_k(_limit(s + bias, n_group, topk_group), top_k)
         g = jnp.take_along_axis(s, experts, axis=-1)
         if norm_topk:
             g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
@@ -247,3 +273,4 @@ def _routed_share(x, flat, gates, w_gate_up, w_down, live, k, lo, n_routed):
             [counts, jnp.stack([jnp.sum(counts > 0, dtype=jnp.int32),
                                 n_zero, n_away])])
         return y.astype(x.dtype), stats
+

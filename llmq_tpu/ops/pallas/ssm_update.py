@@ -100,68 +100,31 @@ def _copy_lanes(width: int) -> int:
     return LANE_BLOCK if width % LANE_BLOCK == 0 else 128
 
 
-def _kernel(layer_ref, rows_ref, live_ref, decay_ref, dtx_ref, bm_ref,
-            cm_ref, pool_in, y_ref, pool, slots, read_sem, write_sem, *,
-            blocks: int):
-    del pool_in                         # aliased: ``pool`` is the leaf
-    _, N, lanes = slots.shape
-    lyr = layer_ref[0]
-    steps = live_ref[0] * blocks        # a live row is ``blocks`` steps
+def walk(steps, block_at, slots, read_sem, write_sem, update):
+    """The walk of the in-place update kernels (this file's and
+    ``ops/pallas/kda_update.py``'s): ``steps`` blocks of the leaf, step
+    ``k``'s at ``block_at(k)`` (a view of the leaf in HBM, of a slot's
+    shape), each copied into the VMEM slot ``k % SLOTS``, handed to
+    ``update(k, slot, arrived)`` — which calls ``arrived()`` once,
+    when it needs the block, and leaves the new block in the slot —
+    and copied back to where it came from; the next ``AHEAD`` blocks
+    are on their way in meanwhile and the last one on its way out."""
 
     def copy(k, read):
-        """Step k's block of the leaf into its slot, or back."""
-        row, block = rows_ref[k // blocks], k % blocks
         slot = k % SLOTS
-        at = pool.at[lyr, row, :, pl.ds(block * lanes, lanes)]
         if read:
-            return pltpu.make_async_copy(at, slots.at[slot],
+            return pltpu.make_async_copy(block_at(k), slots.at[slot],
                                          read_sem.at[slot])
-        return pltpu.make_async_copy(slots.at[slot], at, write_sem.at[slot])
+        return pltpu.make_async_copy(slots.at[slot], block_at(k),
+                                     write_sem.at[slot])
 
-    # A row of an operand that lies whole in VMEM: Mosaic loads eight
-    # sublanes from an aligned start and no single one from a traced
-    # row, so it is picked out of its group of eight (and ``y`` put
-    # back into its group: every row is written once, over zeros).
-    def group_of(row):
-        return (pl.ds(pl.multiple_of(row // 8 * 8, 8), 8),
-                jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0) == row % 8)
-
-    def picked(ref, group, pick, at=slice(None)):
-        return jnp.sum(jnp.where(pick, ref[group, at], 0.0), axis=0,
-                       keepdims=True)
-
-    def down_sublanes(row):             # (1, N) on the lanes -> (N, 128)
-        return jnp.broadcast_to(row, (128, N)).T
-
-    y_ref[...] = jnp.zeros_like(y_ref)
     for k in range(AHEAD):
         @pl.when(k < steps)
         def _():
             copy(k, True).start()
 
     def step(k, _):
-        row, slot = rows_ref[k // blocks], k % SLOTS
-        group, pick = group_of(row)
-        bb = down_sublanes(picked(bm_ref, group, pick))
-        cb = down_sublanes(picked(cm_ref, group, pick))
-        # ``decay``, ``dt X`` and ``y`` come (rows * blocks, lanes)
-        group, pick = group_of(row * blocks + k % blocks)
-        copy(k, True).wait()
-
-        # (a loop and not 32 trips written out: every program holds the
-        # kernel once a Mamba layer, and written out it cost each of
-        # them most of a second of tracing and lowering a layer)
-        def tile(j, _):                 # 128 lanes of the block
-            at = pl.ds(pl.multiple_of(j * 128, 128), 128)
-            new = (slots[slot, :, at] * picked(decay_ref, group, pick, at)
-                   + bb * picked(dtx_ref, group, pick, at))
-            slots[slot, :, at] = new
-            y_ref[group, at] = jnp.where(
-                pick, jnp.sum(new * cb, axis=0, keepdims=True),
-                y_ref[group, at])
-            return 0
-
-        jax.lax.fori_loop(0, lanes // 128, tile, 0)
+        update(k, k % SLOTS, copy(k, True).wait)
         copy(k, False).start()
         ahead = k + AHEAD
 
@@ -178,6 +141,61 @@ def _kernel(layer_ref, rows_ref, live_ref, decay_ref, dtx_ref, bm_ref,
         @pl.when(steps >= back)
         def _():
             copy(steps - back, False).wait()
+
+
+def _kernel(layer_ref, rows_ref, live_ref, decay_ref, dtx_ref, bm_ref,
+            cm_ref, pool_in, y_ref, pool, slots, read_sem, write_sem, *,
+            blocks: int):
+    del pool_in                         # aliased: ``pool`` is the leaf
+    _, N, lanes = slots.shape
+    lyr = layer_ref[0]
+
+    def block_at(k):
+        row, block = rows_ref[k // blocks], k % blocks
+        return pool.at[lyr, row, :, pl.ds(block * lanes, lanes)]
+
+    # A row of an operand that lies whole in VMEM: Mosaic loads eight
+    # sublanes from an aligned start and no single one from a traced
+    # row, so it is picked out of its group of eight (and ``y`` put
+    # back into its group: every row is written once, over zeros).
+    def group_of(row):
+        return (pl.ds(pl.multiple_of(row // 8 * 8, 8), 8),
+                jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0) == row % 8)
+
+    def picked(ref, group, pick, at=slice(None)):
+        return jnp.sum(jnp.where(pick, ref[group, at], 0.0), axis=0,
+                       keepdims=True)
+
+    def down_sublanes(row):             # (1, N) on the lanes -> (N, 128)
+        return jnp.broadcast_to(row, (128, N)).T
+
+    def update(k, slot, arrived):
+        row = rows_ref[k // blocks]
+        group, pick = group_of(row)
+        bb = down_sublanes(picked(bm_ref, group, pick))
+        cb = down_sublanes(picked(cm_ref, group, pick))
+        # ``decay``, ``dt X`` and ``y`` come (rows * blocks, lanes)
+        group, pick = group_of(row * blocks + k % blocks)
+        arrived()
+
+        # (a loop and not 32 trips written out: every program holds the
+        # kernel once a Mamba layer, and written out it cost each of
+        # them most of a second of tracing and lowering a layer)
+        def tile(j, _):                 # 128 lanes of the block
+            at = pl.ds(pl.multiple_of(j * 128, 128), 128)
+            new = (slots[slot, :, at] * picked(decay_ref, group, pick, at)
+                   + bb * picked(dtx_ref, group, pick, at))
+            slots[slot, :, at] = new
+            y_ref[group, at] = jnp.where(
+                pick, jnp.sum(new * cb, axis=0, keepdims=True),
+                y_ref[group, at])
+            return 0
+
+        jax.lax.fori_loop(0, lanes // 128, tile, 0)
+
+    y_ref[...] = jnp.zeros_like(y_ref)
+    # a live row is ``blocks`` steps
+    walk(live_ref[0] * blocks, block_at, slots, read_sem, write_sem, update)
 
 
 # One function under ``jit``: a program calls it once a Mamba layer with

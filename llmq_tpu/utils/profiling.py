@@ -74,7 +74,7 @@ SCOPES = (
     "embed", "qkv", "kv_write", "attn", "latent_prefill_attention",
     "attn_window", "attn_full", "attn_gate", "attn_out", "mlp",
     "moe_route", "moe_experts", "moe_combine", "head",
-    "act_quant", "ssm_conv", "ssm_update", "ssm_scan",
+    "act_quant", "ssm_conv", "ssm_update", "ssm_scan", "kda_gates",
 )
 
 

@@ -591,6 +591,16 @@ def bench_latent_prefill(args, doc) -> None:
                        "max_pages": mp, "results": results}, f, indent=1)
 
 
+def _save(args, doc, results) -> None:
+    """``--out``: a bench's records, with the tree they were read in."""
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+        with open(args.out, "w", encoding="utf-8") as f:
+            json.dump({"config": doc["name"], "tree": args.tree,
+                       "results": results}, f, indent=1)
+
+
 #: family (the configuration file's ``family``, ``llmq_tpu/models``
 #: ``FAMILIES``) -> the bench of its decode kernels. The kernels a
 def bench_ssm(args, doc) -> None:
@@ -701,18 +711,100 @@ def bench_ssm(args, doc) -> None:
               f"rows' bytes at peak), xla {rec['xla_us']:,.1f} "
               f"({rec['xla_roofline_pct']:.1f} %); least {least:,.1f}; "
               f"outputs apart by {gap:.2e}", flush=True)
-    if args.out:
-        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
-                    exist_ok=True)
-        with open(args.out, "w", encoding="utf-8") as f:
-            json.dump({"config": doc["name"], "tree": args.tree,
-                       "results": results}, f, indent=1)
+    _save(args, doc, results)
+
+
+def bench_kda(args, doc) -> None:
+    """The delta rule's decode state update of one layer at the served
+    geometry (``--lens ROWS``: that many of the batch's rows decode):
+    µs a call through the in-place kernel (``ops/pallas/kda_update.py``)
+    and through XLA's fusion of ``ops/kda.kda_update``, each over the
+    stacked leaf with the pool donated, and the share of the LIVE rows'
+    state read once and written once at 819 GB/s (the yardstick of
+    ``ssm_update_roofline``). ``bench_ssm``'s procedure."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmq_tpu.ops import kda
+
+    ex = doc["server"]["executor"]
+    B, H, d = (ex["max_batch_size"], doc["num_attention_heads"],
+               doc["head_dim"])
+    L = sum(1 for l in range(doc["num_hidden_layers"])
+            if (l + 1) % doc["layer_group_size"])
+    reps = L
+    if args.rehearse:
+        os.environ["LLMQ_PALLAS"] = "interpret"
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path in interpret mode)")
+    ks = jax.random.split(jax.random.key(0), 6)
+    q = kda.l2_norm(jax.random.normal(ks[0], (B, H, d))) * d ** -0.5
+    k = kda.l2_norm(jax.random.normal(ks[1], (B, H, d)))
+    v = jax.random.normal(ks[2], (B, H, d), jnp.float32)
+    g = doc["kda_lower_bound"] * jax.nn.sigmoid(
+        2.0 * jax.random.normal(ks[3], (B, H, d)) - 2.5)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, H)))
+
+    def make(enabled):
+        @partial(jax.jit, donate_argnums=(0,))
+        def run(pool, active, q, k, v, g, beta):
+            def body(i, carry):
+                pool, acc = carry
+                o, pool = kda.kda_update_layer(
+                    pool, i % L, q, k, v * (1 + i / 64), g, beta, active,
+                    enabled=enabled)
+                return pool, acc + o
+            return jax.lax.fori_loop(
+                0, reps, body, (pool, jnp.zeros((B, H, d), jnp.float32)))
+        return run
+
+    print(f"{doc['name']}: kda update B={B} heads={H}x{d} layers={L} "
+          f"device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    n = 1 if args.rehearse else 10
+
+    def timed(enabled, active):
+        run = make(enabled)
+        pool = jnp.tile(jax.random.normal(
+            ks[5], (1, B + 1, d, H * d), jnp.float32), (L, 1, 1, 1))
+        call = (active, q, k, v, g, beta)
+        pool, o = run(pool, *call)
+        first = np.asarray(o)
+        t0 = time.perf_counter()
+        for _ in range(n):
+            pool, o = run(pool, *call)
+        jax.block_until_ready(o)
+        return (time.perf_counter() - t0) / (n * reps) * 1e6, first
+
+    results = []
+    for spec in args.lens:
+        rows = min(B, int(spec.split("x")[0]))
+        active = jnp.arange(B) < rows
+        rec = {"rows": rows}
+        rec["kernel_us"], kernel_o = timed(True, active)
+        rec["xla_us"], xla_o = timed(False, active)
+        least = rows * 2 * d * H * d * 4 / PEAK_BYTES_PER_S * 1e6
+        gap = float(np.abs(kernel_o[:rows] - xla_o[:rows]).max())
+        rec.update(least_us=least, max_abs_gap=gap,
+                   kernel_roofline_pct=100 * least / rec["kernel_us"],
+                   xla_roofline_pct=100 * least / rec["xla_us"])
+        results.append(rec)
+        print(f"  rows {rows:3d} of {B}: kernel {rec['kernel_us']:,.1f} "
+              f"us/call ({rec['kernel_roofline_pct']:.1f} % of the live "
+              f"rows' bytes at peak), xla {rec['xla_us']:,.1f} "
+              f"({rec['xla_roofline_pct']:.1f} %); least {least:,.1f}; "
+              f"outputs apart by {gap:.2e}", flush=True)
+    _save(args, doc, results)
 
 
 #: family dispatches are what this tool is about, so a new family's
 #: bench is a function here and an entry in this table.
 BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent,
-           "longcat_flash": bench_latent, "granitemoehybrid": bench_ssm}
+           "longcat_flash": bench_latent, "granitemoehybrid": bench_ssm,
+           "ling_hybrid": bench_kda}
 
 
 def main() -> None:
@@ -761,10 +853,9 @@ def main() -> None:
         sys.exit(f"{args.model_file}: no kernel bench for the family "
                  f"{doc.get('family')!r}; known: {sorted(BENCHES)}")
     if args.prefill:
-        if doc["family"] == "granitemoehybrid":
-            sys.exit("--prefill: no slice bench for granitemoehybrid (its "
-                     "attention layers run the llama kernels; its scan is "
-                     "XLA's)")
+        if doc["family"] in ("granitemoehybrid", "ling_hybrid"):
+            sys.exit(f"--prefill: no slice bench for {doc['family']} (its "
+                     f"recurrent mixer's scan is XLA's)")
         (bench_prefill if doc["family"] == "llama"
          else bench_latent_prefill)(args, doc)
     if args.lens:

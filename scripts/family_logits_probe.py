@@ -18,7 +18,10 @@ differences and the worst position, served against the reference and
 the control against the reference at the SAME positions, and the RMS
 of the reference's logits. The family must have the routed families'
 surface (``reference.JUDGED``, ``reference_forward(..., lowp=)``,
-``judge``). One process for all seeds: the programs compile once.
+``judge``). A family whose reference is routed by the served path's
+choices judges both for itself (``reference.judged_groups``: one
+verdict a group, served and each ``--control``). One process for all
+seeds: the programs compile once.
 
 ``--check`` runs, for every seed, ``benchmark/harness/child.py``
 ``check_logits`` itself, as a run of the cell does before it serves (the
@@ -57,6 +60,12 @@ def main() -> int:
                     help="run the harness's check_logits, not the readings")
     ap.add_argument("--setattr", action="append", default=[],
                     metavar="MODULE.ATTR=EXPRESSION")
+    ap.add_argument("--control", action="append", default=[],
+                    metavar="NAME[+NAME]",
+                    help="for a family that judges its groups itself "
+                         "(reference.judged_groups): what the control "
+                         "holds one precision down, of reference.LOWP "
+                         "(repeatable; default: all of it at once)")
     args = ap.parse_args()
 
     import jax
@@ -79,6 +88,8 @@ def main() -> int:
     mcfg = adapter.register(srv["model"]["name"], config)
     path = adapter.serving_path(mcfg, srv)
     served_many, _ = reference.JUDGED
+    controls = ([tuple(c.split("+")) for c in args.control]
+                or [tuple(getattr(reference, "LOWP", ()))])
     for patch in args.setattr:
         target, expression = patch.split("=", 1)
         module, attr = target.rsplit(".", 1)
@@ -119,6 +130,20 @@ def main() -> int:
             continue
         tokens = np.random.default_rng(seed).integers(
             3, mcfg.vocab_size, T, dtype=np.int32)
+        if hasattr(reference, "judged_groups"):
+            # a family that routes its reference by the served choices
+            # judges served and control for itself, group by group
+            line = {"seed": seed}
+            served = served_many(params, tokens)
+            for name, lowp in [("served", ())] + [
+                    (f"control.{'+'.join(c)}", c) for c in controls]:
+                for group, got in reference.judged_groups(
+                        params, tokens, model, served, tol, lowp):
+                    line[f"{group}.{name}"] = got
+            line["seconds"] = round(time.perf_counter() - t0, 1)
+            del params, served
+            emit(line)
+            continue
         every = np.arange(T)
         ref, margins = reference.reference_forward(params, tokens, model,
                                                    every)

@@ -54,14 +54,15 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
         assert all(k in config for k in keys), entry["name"]
         assert set(config["reduced"]) == set(entry["reduced"])
     assert families == {"llama", "deepseek_v3", "longcat_flash",
-                        "granitemoehybrid", "afmoe"}
+                        "granitemoehybrid", "afmoe", "ling_hybrid"}
 
 
 @pytest.mark.parametrize("cell", [
     "smollm2-chat-bursts", "smollm2-decode-saturated",
     "smollm2-sessions-prefix", "mistral7b-decode-saturated",
     "kanana2-decode-saturated", "longcat-decode-saturated",
-    "granite4h-decode-saturated", "trinity-longshort-saturated"])
+    "granite4h-decode-saturated", "trinity-longshort-saturated",
+    "ling3-reasoning-saturated"])
 def test_every_cell_resolves_and_reports_what_the_contract_asks(bench, cell):
     assert contract.check_names(bench) == []
     assert cell in [w["name"] for w in bench["workloads"]]
@@ -604,6 +605,231 @@ def test_the_harness_s_own_check_passes_the_window(monkeypatch, window,
         jax.clear_caches()
 
 
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+
+
+def test_the_delta_rule_configuration_is_the_catalogs_row(bench):
+    """``ling-3.0-flash-bf16-ep4.json`` holds every key of the catalog's
+    row ``Ling-3.0-flash-VL`` under the same name and value, but those
+    its ``reduced`` names; no width is among them; the cut is what the
+    program counts; and the adapter registers it, refusing a held layer
+    that clamps."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("no catalog of architectures on this machine")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["name"] == "Ling-3.0-flash-VL")
+    entry = next(c for c in bench["configs"]
+                 if c["name"] == "ling-3.0-flash-bf16-ep4")
+    with open(os.path.join(REPO, entry["file"])) as f:
+        config = json.load(f)
+    assert entry["source"] == config["source"] == row["source_url"]
+    differs = {k for k, v in row["config"].items() if config.get(k, k) != v}
+    assert differs == set(entry["reduced"]) - {"first_k_dense_replace"}
+    assert config["first_k_dense_replace"] == 2 and \
+        config["dense_layers_held"] == 1
+    assert not [k for k in entry["reduced"] if k.endswith(("_dim", "_rank"))
+                or "size" in k.replace("vocab_size", "")]
+    assert config["published"] == {k: row["config"][k]
+                                   for k in config["published"]}
+    assert len(config["assumed"]) >= 7 and set(config["reduced"]) == set(
+        entry["reduced"])
+    from llmq_tpu.models import ling_hybrid as lh
+    fdir = contract.family_dir(bench, config)
+    adapter = contract.load_family(fdir, "adapter")
+    shapes = contract.load_family(fdir, "shapes")
+    cfg = adapter.register("ling-row-check", config)
+    assert cfg.layer_types == (lh.KDA,) * 5 + (lh.LATENT, lh.KDA)
+    assert (cfg.n_held, cfg.held, cfg.n_group, cfg.topk_group) == (
+        128, (0, 128), 8, 4)
+    model = {k: config[k] for k in shapes.MODEL_KEYS}
+    assert lh.param_count_analytic(cfg) == shapes.param_count(model) \
+        == 5_231_790_016
+    assert shapes.kda_params(model) == 63_045_632
+    assert shapes.latent_params(model) == 31_965_184
+    assert lh.row_state_bytes_per_row(cfg) == shapes.state_bytes_per_row(
+        model) == 13_025_280
+    assert lh.kv_bytes_per_token(cfg) == shapes.kv_bytes_per_token(model, 2) \
+        == 1152
+    assert shapes.ssm_update_bytes(model, 128) == 128 * 6 * 2 * (2 << 20)
+    clamped = dict(config, expert_swiglu_limit_list=[0] * 6 + [4])
+    with pytest.raises(ValueError, match="expert_swiglu_limit"):
+        adapter.register("ling-clamped", clamped)
+    with pytest.raises(ValueError, match="ling_hybrid block"):
+        adapter.register("ling-lora", dict(config, no_kda_lora=False))
+
+
+def _state_not_handed_on(lh, monkeypatch):
+    """The chunked scan's state NOT written back to the row-state leaf:
+    neither the next slice nor the decode steps continue it."""
+    whole = lh.rows_write
+    monkeypatch.setattr(lh, "rows_write", lambda pool, l, rows, new, **kw: (
+        pool if pool.ndim == 4 else whole(pool, l, rows, new, **kw)))
+
+
+def _decode_state_in_bfloat16(lh, monkeypatch):
+    """The one-token update leaves its state rounded to bfloat16: a
+    decode path one precision down behind a sound scan."""
+    import jax
+    update = lh.kda_update_layer
+
+    def rounded(*a, **kw):
+        o, pool = update(*a, **kw)
+        return o, jax.lax.reduce_precision(pool, 8, 7)
+
+    monkeypatch.setattr(lh, "kda_update_layer", rounded)
+
+
+@pytest.mark.parametrize("fault, by", [
+    (_state_not_handed_on, "rms_clean 0.2"),
+    (_decode_state_in_bfloat16, "state_rel")], ids=["state-not-handed-on",
+                                                   "decode-state-bf16"])
+def test_a_broken_delta_rule_path_is_refused_by_the_check(monkeypatch, fault,
+                                                          by):
+    """``harness/child.py`` ``check_logits`` itself on the rehearsal's
+    toy of the delta-rule family: correct as served; with the fault the
+    family's ``reference_logits`` raises ``NotCorrect`` — by the
+    comparison the benchmark makes, not only by
+    ``tests/test_ling_hybrid.py``'s. The state not handed on is another
+    program (the level); a decode update that holds its state in
+    bfloat16 is a precision, which the logits' level cannot see at the
+    toy's width and the state's own distance does."""
+    import jax
+
+    import llmq_tpu.models.ling_hybrid as lh
+    from benchmark.harness import child
+    bench = contract.load_benchmark(os.path.join(
+        REPO, "benchmark", "selftest", "data", "rehearsal_ling.json"))
+    cell = contract.resolve_cell(bench, "tiny-ling-saturated")
+    config, srv = cell["config"], cell["config"]["server"]
+    adapter = contract.load_family(cell["family_dir"], "adapter")
+    reference = contract.load_family(cell["family_dir"], "reference")
+    assert config["tolerance"]["judged_tokens"] > 3 * max(
+        srv["executor"]["prefill_buckets"])
+    mcfg = adapter.register(srv["model"]["name"], config)
+    params = child.make_params(4500000123, adapter.param_builder(
+        mcfg, srv["model"]))
+    spec = {"config": config, "seed": 4500000123}
+    try:
+        jax.clear_caches()      # the family's step functions are jitted
+        path = adapter.serving_path(mcfg, srv)
+        assert child.check_logits(params, path, reference.reference_logits,
+                                  spec)["ok"]
+        fault(lh, monkeypatch)
+        jax.clear_caches()
+        path = adapter.serving_path(mcfg, srv)
+        path.ident += fault.__name__
+        with pytest.raises(reference.NotCorrect) as refused:
+            child.check_logits(params, path, reference.reference_logits,
+                               spec)
+        said = str(refused.value)
+        assert by in said, said
+        if fault is _decode_state_in_bfloat16:  # the level does not see it
+            level = float(re.search(r"differences is ([\d.]+)", said)[1])
+            assert said.startswith("decode_from_") and level < 0.2, said
+    finally:
+        reference.JUDGED = None
+        jax.clear_caches()
+
+
+def _ling_readings():
+    """What ``ling-3.0-flash-bf16-ep4``'s check read on the chip (my chip
+    runs, PR 45, calls 12-14; the configuration's ``tolerance.why`` has
+    them): for the served path and for the control one precision down,
+    the level of a group of many positions, the prefill's growth, each
+    KDA layer's state and the latent layer's rows, lowest and highest."""
+    return {
+        "served": {"level": (0.0099, 0.0116), "growth": (0.898, 1.055),
+                   "latent": (0.0096, 0.0107), "swap": (0.0, 0.0048),
+                   "state": [(0.0030, 0.0033), (0.0050, 0.0069),
+                             (0.0073, 0.0095), (0.0086, 0.0109),
+                             (0.0096, 0.0121), (0.0101, 0.0130)]},
+        "control": {"level": (0.0166, 0.0239), "growth": (1.536, 1.789),
+                    "latent": (0.0265, 0.0319),
+                    "state": [(0.0110, 0.0150), (0.0139, 0.0268),
+                              (0.0169, 0.0261), (0.0187, 0.0317),
+                              (0.0197, 0.0311), (0.0203, 0.0335)]}}
+
+
+def _judged(reference, tol, level, growth=1.0, state=None, latent=None,
+            swap=0.0, n=128):
+    import numpy as np
+    ref = np.zeros((n, 16), np.float32)
+    ramp = np.linspace(2.0 / (1 + growth), 2.0 * growth / (1 + growth), n)
+    margins = np.full((6, n), 0.05)
+    swapped = np.zeros((6, n), bool)
+    if swap:
+        margins[2, 7], swapped[2, 7] = swap, True
+    state = [lim / 2 for lim in tol["state_rel"]] if state is None else state
+    return reference.judge(ref + (level * ramp)[:, None], ref, margins,
+                           swapped, state, latent, tol)
+
+
+@pytest.mark.parametrize("limit", ["rms_clean", "growth", "state_rel",
+                                   "latent_rel", "margin_decisive", "rms"])
+def test_the_delta_rule_family_s_tolerance_sits_between_its_readings(bench,
+                                                                     limit):
+    """The judge's keys and no other; under the file's numbers the
+    served path's readings on the chip pass and the control one
+    precision down is refused by EACH of the limits that sees a
+    precision, with room on both sides of each (a sixth at the least);
+    a clear choice the served path did not make and unrelated logits
+    are refused too; a group of a mixed step's one or two positions is
+    held to the worst position, its state and its choices alone."""
+    cell = contract.resolve_cell(bench, "ling3-reasoning-saturated")
+    reference = contract.load_family(cell["family_dir"], "reference")
+    tol = cell["config"]["tolerance"]
+    assert set(tol) == {"rms", "max", "clean_quantile", "rms_clean",
+                        "growth", "state_rel", "latent_rel",
+                        "margin_decisive", "margin_eps", "min_positions",
+                        "judged_tokens", "why"}
+    ex = cell["config"]["server"]["executor"]
+    assert tol["judged_tokens"] >= 3 * max(ex["prefill_buckets"]) + 128
+    read = _ling_readings()
+    served, control = read["served"], read["control"]
+    sound = _judged(reference, tol, served["level"][1],
+                    served["growth"][1], [hi for _, hi in served["state"]],
+                    [served["latent"][1]], served["swap"][1])
+    assert sound["ok"], sound
+    room = 7 / 6
+    if limit == "rms_clean":
+        assert served["level"][1] * room < tol[limit] < \
+            control["level"][0] / room
+        got = _judged(reference, tol, control["level"][0])
+        assert not got["ok"] and got["rms_clean"] > tol[limit]
+        # one or two positions are no distribution
+        assert _judged(reference, tol, control["level"][1], n=2)["ok"]
+    elif limit == "growth":
+        assert served["growth"][1] * room < tol[limit] < \
+            control["growth"][0] / room
+        got = _judged(reference, tol, served["level"][0],
+                      control["growth"][0])
+        assert not got["ok"] and got["rms_clean"] < tol["rms_clean"]
+    elif limit == "state_rel":
+        assert len(tol[limit]) == len(served["state"]) == 6
+        for i, lim in enumerate(tol[limit]):
+            assert served["state"][i][1] * room < lim < \
+                control["state"][i][0] / room
+            one = [hi for _, hi in served["state"]]
+            one[i] = control["state"][i][0]     # that layer's state alone
+            assert not _judged(reference, tol, served["level"][0],
+                               state=one)["ok"]
+    elif limit == "latent_rel":
+        assert served["latent"][1] * room < tol[limit] < \
+            control["latent"][0] / room
+        assert not _judged(reference, tol, served["level"][0],
+                           latent=[control["latent"][0]])["ok"]
+        assert _judged(reference, tol, served["level"][0])["ok"]
+    elif limit == "margin_decisive":
+        assert 2 * served["swap"][1] < tol[limit] <= 0.02
+        got = _judged(reference, tol, served["level"][0], swap=0.03)
+        assert not got["ok"] and got["swap_margin"] == 0.03
+    else:
+        assert _judged(reference, tol, 0.3)["rms"] < tol["rms"] < 1.4
+        assert not _judged(reference, tol, 1.0)["ok"]
+        assert not _judged(reference, tol, 1.0, n=2)["ok"]
+
+
 def test_the_harness_names_no_family():
     named = re.compile(r"llama|deepseek|kanana|smollm|fused_decode|gmm|"
                        r"latent_decode|moe_grouped|llmq_tpu\.models")
@@ -619,7 +845,8 @@ def test_the_harness_names_no_family():
 
 
 @pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
-                                    "granitemoehybrid", "afmoe"])
+                                    "granitemoehybrid", "afmoe",
+                                    "ling_hybrid"])
 def test_who_imports_what_in_a_family(family):
     """``shapes.py`` is standard library alone (the parent and the
     readers import it); ``reference.py`` imports neither the program
@@ -638,7 +865,8 @@ def test_who_imports_what_in_a_family(family):
 
 
 @pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
-                                    "granitemoehybrid", "afmoe"])
+                                    "granitemoehybrid", "afmoe",
+                                    "ling_hybrid"])
 def test_what_a_family_brings_to_the_program(family):
     """The program's side of the seam (``llmq_tpu/models/__init__.py``):
     three forward passes the serving programs are built from — no

@@ -397,7 +397,8 @@ def test_native_loader_decides_staleness_from_content(monkeypatch):
     assert loader._build_if_needed()
     with open(loader._STAMP) as f:
         assert f.read() == good
-    assert os.path.getmtime(loader._SO) < so_mtime + 3600   # rebuilt
+    # rebuilt: the file's time is the build's, whatever the hour
+    assert os.path.getmtime(loader._SO) != so_mtime + 3600
     # Up to date: no rebuild (the compiler is not invoked).
     monkeypatch.setattr(loader.subprocess, "run",
                         lambda *a, **k: pytest.fail("rebuilt"))

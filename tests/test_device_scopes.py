@@ -34,6 +34,9 @@ SSM = {"ssm_conv", "ssm_update", "ssm_scan"}
 WINDOW = {"attn_window", "attn_full", "attn_gate"}
 FAMILIES = {
     "afmoe": MODULES | ROUTED | WINDOW,
+    "ling_hybrid": (MODULES | ROUTED | SSM
+                    | {"latent_prefill_attention", "attn_gate",
+                       "kda_gates"}),
     "granitemoehybrid": MODULES | SSM,
     "llama": MODULES,
     "llama-w8kv8": MODULES | {"act_quant"},
@@ -63,6 +66,11 @@ def _tiny(family):
         cfg = am.afmoe_tiny(dtype=jnp.float32, max_seq_len=128,
                             held_experts=(8, 16))
         return cfg, am.init_params(jax.random.PRNGKey(41), cfg), {}
+    if family == "ling_hybrid":
+        from llmq_tpu.models import ling_hybrid as lh
+        cfg = lh.ling_hybrid_tiny(dtype=jnp.float32, max_seq_len=128,
+                                  held_experts=(8, 16))
+        return cfg, lh.init_params(jax.random.PRNGKey(45), cfg), {}
     if family == "granitemoehybrid":
         from llmq_tpu.models import granitemoehybrid as gm
         cfg = gm.granite4h_tiny(dtype=jnp.float32, max_seq_len=128)
@@ -159,8 +167,8 @@ def test_a_name_outside_the_vocabulary_is_refused():
     # short: they are stored in every instruction's metadata
     assert max(map(len, SCOPES)) <= len("latent_prefill_attention")
     # (176 characters until the family afmoe brought its three:
-    # attn_window, attn_full, attn_gate)
-    assert sum(map(len, SCOPES)) < 210
+    # attn_window, attn_full, attn_gate; ling_hybrid one: kda_gates)
+    assert sum(map(len, SCOPES)) < 220
     assert SSM | WINDOW <= set(SCOPES)
 
 

@@ -1,12 +1,15 @@
 """A chip's share of a routed layer (``ops/moe.routed_ffn(held=...)``,
-``models/longcat_flash.py``, ``models/afmoe.py``): the router scores
+``models/longcat_flash.py``, ``models/afmoe.py``,
+``models/ling_hybrid.py``): the router scores
 every expert, a token chooses among all, this chip multiplies the pairs
 whose expert it holds, adds what a token's home chip adds (LongCat's
 zero-compute experts, afmoe's shared expert) and leaves the rest out.
 The test that TIES THE SHARE TO THE MODEL, one case a family that
 serves a share (``FAMILIES``): at a small size (LongCat: 32 experts + 16
 zero-compute ones in 4 shares of 8; afmoe: 32 experts in 16 shares of 2
-beside a shared expert), one routed layer's partial results over all
+beside a shared expert; ling_hybrid: 32 experts in 4 GROUPS of 8, the
+best 2 groups kept, in 4 shares of 8 — a share a group — beside a shared
+expert), one routed layer's partial results over all
 shares, with the home chip's part and the dense path counted once, add
 up to what the family's plain reference
 (``benchmark/families/<family>/reference.py``) gives for the UNCUT
@@ -25,6 +28,7 @@ import jax.numpy as jnp
 
 from benchmark.harness import contract
 from llmq_tpu.models import afmoe as am
+from llmq_tpu.models import ling_hybrid as lh
 from llmq_tpu.models import longcat_flash as lf
 from llmq_tpu.ops import moe
 
@@ -166,7 +170,60 @@ def _am_all_shares(fam, monkeypatch, slots):
     monkeypatch.setattr(am, "routed_ffn", routed_by_all_shares)
 
 
+# -- ling_hybrid: 4 shares of 8, a group-limited router, the shared expert -----
+
+def _lh_model_of(cfg):
+    lo, hi = cfg.held
+    return {"num_hidden_layers": cfg.n_layers, "hidden_size": cfg.dim,
+            "num_attention_heads": cfg.n_heads,
+            "layer_group_size": cfg.layer_group_size,
+            "first_k_dense_replace": cfg.first_k_dense,
+            "kv_lora_rank": cfg.kv_lora_rank, "q_lora_rank": None,
+            "qk_nope_head_dim": cfg.qk_nope_head_dim,
+            "qk_rope_head_dim": cfg.qk_rope_head_dim,
+            "kda_lower_bound": cfg.kda_lower_bound,
+            "num_experts": hi - lo, "router_experts": E,
+            "expert_share": {"chips": E // (hi - lo),
+                             "index": lo // (hi - lo)},
+            "num_experts_per_tok": cfg.n_experts_per_tok,
+            "n_group": cfg.n_group, "topk_group": cfg.topk_group,
+            "routed_scaling_factor": cfg.routed_scaling_factor,
+            "norm_topk_prob": cfg.norm_topk_prob,
+            "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta}
+
+
+def _lh_uncut():
+    """One routed layer behind a KDA mixer, 32 experts in 4 groups of 8
+    with the top 4 of the best 2 groups, all held."""
+    return lh.ling_hybrid_tiny(
+        dtype=jnp.float32, max_seq_len=64, n_layers=1, first_k_dense=0,
+        n_routed_experts=E, n_experts_per_tok=4, n_group=4, topk_group=2)
+
+
+def _lh_all_positions(fns, cfg, params, seq):
+    mp = cfg.max_seq_len // PAGE
+    cache = lh.init_kv_pages(cfg, 1 + mp, PAGE)
+    logits, _, _, st = fns.forward_prefill(
+        params, cfg, jnp.asarray(seq[None]),
+        jnp.arange(len(seq), dtype=jnp.int32)[None],
+        jnp.asarray([len(seq)], jnp.int32), cache,
+        jnp.arange(1, 1 + mp, dtype=jnp.int32)[None], stats=True)
+    return np.asarray(logits)[0], np.asarray(st)
+
+
+def _lh_all_shares(fam, monkeypatch, slots):
+    """``lh.routed_ffn`` as the SUM of the four shares' partial results
+    (``_am_all_shares``); the shared expert is ``lh._ffn``'s own, once."""
+    _am_all_shares(fam, monkeypatch, slots)
+    monkeypatch.setattr(lh, "routed_ffn", am.routed_ffn)
+
+
 FAMILIES = {
+    "ling_hybrid": SimpleNamespace(
+        name="ling_hybrid", mod=lh, shares=SHARES, held=HELD, k=4,
+        uncut=_lh_uncut, model_of=_lh_model_of, bias=0.01,
+        all_positions=_lh_all_positions, all_shares=_lh_all_shares,
+        zero_at=None, away_at=HELD + 2),
     "longcat_flash": SimpleNamespace(
         name="longcat_flash", mod=lf, shares=SHARES, held=HELD, k=K,
         uncut=_lf_uncut, model_of=_lf_model_of, bias=0.005,

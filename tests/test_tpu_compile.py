@@ -810,3 +810,120 @@ def test_the_share_s_step_fits_v5e_and_copies_no_cache(one_chip, monkeypatch,
     assert mem.alias_size_in_bytes >= held
     assert not _whole_copies(compiled, (cache, state))
     assert mem.temp_size_in_bytes < (0.5e9 if program == "decode" else 2.0e9)
+
+
+def _ling_share(one_chip, monkeypatch):
+    """``benchmark/configs/ling-3.0-flash-bf16-ep4.json`` as the
+    executor holds it: ``(family, cfg, params, cache, state, (B, S, T))``
+    as shapes on the described chip."""
+    import json
+
+    from benchmark.harness import contract
+    from llmq_tpu.models import ling_hybrid as lh
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    path = os.path.join(contract.ROOT, "benchmark", "configs",
+                        "ling-3.0-flash-bf16-ep4.json")
+    with open(path, encoding="utf-8") as f:
+        config = json.load(f)
+    adapter = contract.load_family(
+        os.path.join(contract.ROOT, "benchmark", "families", "ling_hybrid"),
+        "adapter")
+    cfg = adapter.register("ling-compile-check", config)
+    ex = config["server"]["executor"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: lh.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: lh.init_kv_pages(cfg, ex["kv_pages"], ex["page_size"])))
+    state = on_chip(jax.eval_shape(
+        lambda: lh.init_row_state(cfg, ex["max_batch_size"])))
+    S = ex["mixed_batch"]["max_slices"]
+    return lh, cfg, params, cache, state, (
+        ex["max_batch_size"], S, ex["mixed_batch"]["prefill_token_budget"]
+        // S, ex["page_size"])
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "prefill"])
+def test_the_delta_rule_share_s_step_fits_v5e_and_copies_no_cache(
+        one_chip, monkeypatch, program):
+    """One step of ``ling-3.0-flash-bf16-ep4`` as served (7 layers: 6
+    KDA and 1 latent, 128 held experts, a quarter of the vocabulary, 128
+    rows of 13 MB of row state beside the latent layer's pool): 13.2 GB
+    of arguments, the pool and BOTH row-state leaves go in and come out
+    in place — no copy of a leaf — the in-place update kernel is there
+    once a KDA layer, and the step's temporaries stay inside what is
+    left of the chip's 16.9 GB."""
+    lh, cfg, params, cache, state, (B, S, T, page) = _ling_share(
+        one_chip, monkeypatch)
+    mp = cfg.max_seq_len // page
+    assert (cfg.n_kda, cfg.n_latent, cfg.n_held) == (6, 1, 128)
+    assert state["kda"].shape == (6, B + 1, 128, 4096)
+
+    def arg(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if program == "decode":
+        def step(params, cache, state, tokens, positions, tables, active):
+            return lh.forward_decode.__wrapped__(
+                params, cfg, tokens, positions, cache, tables, active=active,
+                stats=True, row_state=state)
+        args = (arg(B), arg(B), arg(B, mp), arg(B, dtype=jnp.bool_))
+        # the update a KDA layer, the latent write and attention, two
+        # grouped products a routed layer
+        calls = 6 + 2 + 2 * 6
+    elif program == "mixed":
+        # as a mixed CHUNK runs it: the fused step, then decode steps in
+        # a loop that carries pool and state (without the barrier behind
+        # the slices' attention XLA copied the whole pool twice here, and
+        # in no program that held the mixed step alone: PR 45)
+        def step(params, cache, state, tokens, positions, tables, active,
+                 *pf):
+            dec, pf_logits, cache, state, st = lh.forward_mixed.__wrapped__(
+                params, cfg, tokens, positions, cache, tables, *pf[:5],
+                dec_active=active, stats=True, row_state=state,
+                pf_rows=pf[5])
+
+            def body(_, carry):
+                tok, pos, cache, state, acc = carry
+                logits, cache, state, st = lh.forward_decode.__wrapped__(
+                    params, cfg, tok, pos, cache, tables, active=active,
+                    stats=True, row_state=state)
+                return (jnp.argmax(logits, -1).astype(jnp.int32), pos + 1,
+                        cache, state, acc + st)
+
+            tok, _, cache, state, st = jax.lax.fori_loop(0, 3, body, (
+                jnp.argmax(dec, -1).astype(jnp.int32), positions + 1, cache,
+                state, st))
+            return tok, pf_logits, cache, state, st
+        args = (arg(B), arg(B), arg(B, mp), arg(B, dtype=jnp.bool_),
+                arg(S * T), arg(S * T), arg(S), arg(S + 1), arg(S, mp),
+                arg(S))
+        # the fused step (and a KDA layer's two copies of its slices'
+        # states) and the loop's body
+        calls = (6 * 3 + 2 + 2 * 6) + (6 + 2 + 2 * 6)
+    else:
+        def step(params, cache, state, tokens, positions, tables, lengths,
+                 rows):
+            return lh.forward_prefill.__wrapped__(
+                params, cfg, tokens, positions, lengths, cache, tables,
+                last_only=True, stats=True, row_state=state, rows=rows)
+        args = (arg(1, T), arg(1, T), arg(1, mp), arg(1), arg(1))
+        calls = 6 * 2 + 2 * 6
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, cache, state, *args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((cache, state)))
+    assert compiled.as_text().count("tpu_custom_call") == calls
+    assert 13.0e9 < mem.argument_size_in_bytes < 13.4e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    assert mem.alias_size_in_bytes >= held
+    assert not _whole_copies(compiled, (cache, state))
+    assert mem.temp_size_in_bytes < (0.5e9 if program == "decode" else 2.0e9)
