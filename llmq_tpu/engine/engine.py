@@ -46,7 +46,7 @@ import time
 import weakref
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -763,6 +763,10 @@ class InferenceEngine:
         #: the decode attention is not that kernel's): on the dispatch
         #: span while a capture is held (``_attn_counts``).
         self._attn_work = getattr(executor, "attn_work", None)
+        #: What one recurrent layer's scan kernel does for a program's
+        #: prompt chunks (``executor.scan_work``; None without one): on
+        #: the dispatch span while a capture is held.
+        self._scan_work = getattr(executor, "scan_work", None)
         self.row_state_rebuilds = 0
         self.row_state_declined = {"prefix": 0, "conversation": 0,
                                    "tiering": 0, "disagg": 0}
@@ -2655,7 +2659,8 @@ class InferenceEngine:
     def _dispatch_span(self, entry: str, *, steps: int = 0, rows: int = 0,
                        row_steps: int = 0, context_tokens: int = 0,
                        prefill_tokens: int = 0, longest: int = 0,
-                       chunk: bool = True, state_rows: int = 0):
+                       chunk: bool = True, state_rows: int = 0,
+                       slice_lens: Sequence[int] = ()):
         """``engine.dispatch``: the span around ONE executor call and
         nothing else, with the counts taken where the work is handed
         over. ``program`` is the executor's name for what runs (its
@@ -2671,9 +2676,13 @@ class InferenceEngine:
         padding included (the executor's ``slice_tokens``: the live row
         tiles of a mixed chunk, bucket x rows of a prefill program, 0
         otherwise).
-        ``pages_live`` / ``tokens_live`` (``_live_kv``) and the
-        attention kernel's schedule (``_attn_counts``) only while a
-        capture is held. The same quantities accumulate for
+        ``pages_live`` / ``tokens_live`` (``_live_kv``), the attention
+        kernel's schedule (``_attn_counts``) and, for prompt chunks of
+        ``slice_lens`` tokens in a family whose recurrent layers scan
+        them in a kernel, ``scan_chunks`` / ``scan_chunks_live`` of ONE
+        layer's call (``executor.scan_work``: the grid's steps a head
+        block and those under a slice's length, by these lengths) only
+        while a capture is held. The same quantities accumulate for
         ``get_stats()``."""
         self.device_steps += steps
         self.row_steps += row_steps
@@ -2697,6 +2706,10 @@ class InferenceEngine:
             counts["pages_live"], counts["tokens_live"] = self._live_kv()
             if chunk and rows and self._attn_work is not None:
                 counts.update(self._attn_counts())
+            work = (self._scan_work(entry, slice_lens)
+                    if self._scan_work is not None and slice_lens else None)
+            if work is not None:
+                counts["scan_chunks"], counts["scan_chunks_live"] = work
             if self._row_state_bytes:
                 # rows whose state the program updates: its decode rows
                 # and, of a prefill or a mixed chunk, its prompt chunks'
@@ -2752,15 +2765,16 @@ class InferenceEngine:
 
     def _chunk_dispatch(self, entry: str, budgets: np.ndarray,
                         context_tokens: int, prefill_tokens: int = 0,
-                        slices: int = 0):
+                        pf: Sequence[tuple] = ()):
         """``_dispatch_span`` for a chunk whose row budgets are the
-        (B,) array handed to the device (``slices``: the prompt slices
-        a mixed chunk carries)."""
+        (B,) array handed to the device (``pf``: the prompt slices a
+        mixed chunk carries, as ``_take_slices`` hands them over)."""
         return self._dispatch_span(
             entry, steps=int(budgets.max()),
             rows=int(np.count_nonzero(budgets)),
             row_steps=int(budgets.sum()), context_tokens=context_tokens,
-            prefill_tokens=prefill_tokens, state_rows=slices)
+            prefill_tokens=prefill_tokens, state_rows=len(pf),
+            slice_lens=[len(sl[1]) for sl in pf])
 
     def _prefill_dispatch(self, entry: str, chunks):
         """``_dispatch_span`` for a dedicated prefill program over
@@ -2768,7 +2782,8 @@ class InferenceEngine:
         return self._dispatch_span(
             entry, rows=len(chunks),
             prefill_tokens=sum(len(c) for c in chunks),
-            longest=max(len(c) for c in chunks), chunk=False)
+            longest=max(len(c) for c in chunks), chunk=False,
+            slice_lens=[len(c) for c in chunks])
 
     def _dispatch_carried(
             self, infl: _InflightChunk) -> Optional[_InflightChunk]:
@@ -2918,8 +2933,7 @@ class InferenceEngine:
             pf, infl_pf = self._take_slices(pf_plan, pf_budget, len(plan))
             t0 = time.perf_counter()
             with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                      prefill_tokens=packed,
-                                      slices=len(pf)):
+                                      prefill_tokens=packed, pf=pf):
                 handle = self.executor.mixed_chunk_start(
                     None, None, block_tables, temps, budgets, pf,
                     carry=infl.handle, overrides=overrides)
@@ -3623,8 +3637,7 @@ class InferenceEngine:
         t0 = time.perf_counter()
         if start_fn is not None:
             with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                      prefill_tokens=packed,
-                                      slices=len(pf)):
+                                      prefill_tokens=packed, pf=pf):
                 handle = start_fn(tokens, positions, block_tables,
                                   temps, budgets, pf)
             dispatch_s = time.perf_counter() - t_asm
@@ -3645,7 +3658,7 @@ class InferenceEngine:
             return True
         # Sync executor (echo): one blocking call, commit inline.
         with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                  prefill_tokens=packed, slices=len(pf)):
+                                  prefill_tokens=packed, pf=pf):
             out, pf_first = self.executor.mixed_chunk(
                 tokens, positions, block_tables, temps, budgets, pf)
         t_done = time.perf_counter()

@@ -1295,6 +1295,31 @@ class JaxExecutor:
             ordered=True)
         return steps, computed, full
 
+    def scan_work(self, entry: str, lengths) -> Optional[tuple]:
+        """``(chunks, chunks_live)`` of ONE recurrent layer's scan
+        kernel call in the program behind ``entry`` over prompt chunks
+        of ``lengths`` tokens: the steps of its grid a head block — the
+        program's slices (a mixed chunk's ``mixed_prefill_slices``, the
+        empty ones too; a prefill program's rows) by their width in the
+        kernel's steps — and those that start under a slice's length,
+        the others being skipped. None where the family has no such
+        kernel or the program's slices go to XLA's scan (the family's
+        ``scan_step_tokens``)."""
+        step_of = getattr(self._family, "scan_step_tokens", None)
+        if step_of is None or not lengths:
+            return None
+        if entry == "mixed_chunk":
+            S, T = self.mixed_prefill_slices, self.mixed_slice_tokens
+        elif entry in ("prefill", "prefill_multi"):
+            S = self.prefill_batch if entry == "prefill_multi" else 1
+            T = self._bucket_for(max(1, max(lengths)))
+        else:
+            return None
+        step = step_of(self.model_cfg, T)
+        if step is None:
+            return None
+        return S * (T // step), sum(-(-n // step) for n in lengths)
+
     def _rows_arg(self, rows) -> tuple:
         """The batch rows of a program's prompt chunks as its last
         operand — ``()`` for a family without row state, whose programs

@@ -78,8 +78,8 @@ from llmq_tpu.models.latent import (  # noqa: F401 (param_count: surface)
     param_count, prefill_key_blocks)
 from llmq_tpu.models.latent import prod as _prod
 from llmq_tpu.models.latent import swiglu as _mlp
-from llmq_tpu.ops.kda import (conv_step, kda_scan, kda_update_layer,
-                              l2_norm, update_route)
+from llmq_tpu.ops.kda import (conv_step, kda_scan_slices, kda_update_layer,
+                              l2_norm, scan_route, update_route)
 from llmq_tpu.ops.moe import route, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rope import rope_cos_sin
@@ -456,19 +456,43 @@ def routes(cfg: LingHybridConfig, cache: KVCache, *, batch: int,
            prefill_rows: int = 0) -> Dict[str, str]:
     """The latent layers' routes (``models/latent.routes``) and the KDA
     layers': ``ssm_update`` of a program that decodes, ``ssm_scan`` of
-    one that runs prompt tokens (plain JAX)."""
+    one that runs prompt tokens (the kernel takes slices of whole
+    64-token steps; this function is not told a program's)."""
     out = latent.routes(cfg, cache, batch=batch, page_size=page_size,
                         max_pages=max_pages, decode=decode,
                         prefill_rows=prefill_rows)
+    d, H = cfg.kda_head_dim, cfg.n_heads
+
+    def named(route, kernel):
+        use, interp = route
+        return (f"pallas{'-interpret' if interp else ''}:{kernel}"
+                if use else "xla")
+
     if prefill_rows:
-        out["ssm_scan"] = "xla"
+        step, route = _scan_route(cfg)
+        out["ssm_scan"] = named(route, f"kda_scan_pallas(slice%{step}==0)")
     if decode:
-        use, interp = update_route(cfg.kda_head_dim, cfg.n_heads,
-                                   cfg.kda_head_dim, enabled=cfg.pallas)
-        out["ssm_update"] = (
-            f"pallas{'-interpret' if interp else ''}:kda_update_pallas"
-            if use else "xla")
+        out["ssm_update"] = named(update_route(d, H, d, enabled=cfg.pallas),
+                                  "kda_update_pallas")
     return out
+
+
+def _scan_route(cfg: LingHybridConfig, T: Optional[int] = None):
+    """``(the scan kernel's step in tokens, ops/kda.scan_route of slices
+    of T tokens)``; ``T`` None: of slices of whole steps."""
+    from llmq_tpu.ops.pallas.kda_scan import CHUNK
+    d = cfg.kda_head_dim
+    return CHUNK, scan_route(d, cfg.n_heads, d, T or CHUNK, cfg.kda_chunk,
+                             enabled=cfg.pallas)
+
+
+def scan_step_tokens(cfg: LingHybridConfig, T: int) -> Optional[int]:
+    """Tokens a grid step of the KDA layers' scan kernel takes of a
+    slice of ``T`` tokens (``ops/pallas/kda_scan.CHUNK``) — None where
+    such slices go to XLA's scan (:func:`llmq_tpu.ops.kda.scan_route`).
+    What the executor's ``scan_work`` counts a program's chunks by."""
+    step, (use, _) = _scan_route(cfg, T)
+    return step if use else None
 
 
 # -- the layer ------------------------------------------------------------------
@@ -565,9 +589,9 @@ def _kda_slices(h, x, kp: Params, i: int, rs: RowState, rows, first,
     with scope("ssm_scan"):
         q, k, v = _kda_heads(y, cfg)
         before = rows_read(kda, i, rows, enabled=cfg.pallas)
-        o, st = kda_scan(jnp.where(keep, before, 0), q, k, v,
-                         g.reshape(S, T, H, -1), b.reshape(S, T, H),
-                         lengths, cfg.kda_chunk)
+        o, st = kda_scan_slices(jnp.where(keep, before, 0), q, k, v,
+                                g.reshape(S, T, H, -1), b.reshape(S, T, H),
+                                lengths, cfg.kda_chunk, enabled=cfg.pallas)
         kda = rows_write(kda, i, rows, st, enabled=cfg.pallas)
     h = _kda_out(h.reshape(S * T, -1), o.reshape(S * T, H, -1), z, kp, i,
                  cfg)

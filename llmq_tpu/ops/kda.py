@@ -1,7 +1,9 @@
 """Delta-rule linear attention with a decay a CHANNEL (Kimi Delta
 Attention, arXiv:2510.26692) in plain JAX: the one-token state update
 (decode rows) and the chunked scan (prompt slices and the prefill
-program). The convolution in front of it is ``ops/ssm.py``'s.
+program), and the routes to their kernels (``ops/pallas/kda_update.py``,
+``ops/pallas/kda_scan.py``). The convolution in front of it is
+``ops/ssm.py``'s.
 
 The recurrence, a head (``k_t``, ``q_t`` its d_k key values, ``v_t`` its
 d_v values, ``a_t = exp(g_t)`` in (0, 1)^d_k, ``b_t`` in (0, 1))::
@@ -203,6 +205,37 @@ def kda_scan(state: jnp.ndarray, q: jnp.ndarray, k: jnp.ndarray,
     return (o.reshape(S, T + pad, H, dv)[:, :T],
             jnp.moveaxis(last, 1, 2).reshape(S, dk, H * dv)
             .astype(state.dtype))
+
+
+def scan_route(d_k: int, n_heads: int, d_v: int, T: int, chunk: int, *,
+               enabled: bool = True) -> Tuple[bool, bool]:
+    """``(use the scan kernel, in interpret mode)`` for slices of ``T``
+    tokens worked in exact blocks of ``chunk``: ``ops/attention.
+    _kernel_route``'s policy, as :func:`update_route`, and the shapes
+    ``ops/pallas/kda_scan.py`` is written for."""
+    from llmq_tpu.ops.attention import _kernel_route
+    from llmq_tpu.ops.pallas.kda_scan import kda_scan_viable
+    return _kernel_route(n_heads * d_v, enabled=enabled,
+                         extra_ok=kda_scan_viable(d_k, n_heads, d_v, T,
+                                                  chunk))
+
+
+def kda_scan_slices(state: jnp.ndarray, q: jnp.ndarray, k: jnp.ndarray,
+                    v: jnp.ndarray, g: jnp.ndarray, beta: jnp.ndarray,
+                    lengths: jnp.ndarray, chunk: int, *,
+                    enabled: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`kda_scan` of a program's prompt slices: the kernel where
+    :func:`scan_route` takes them — ``chunk`` then the tokens of a block
+    it works in exact differences, inside steps of 64 —, else XLA's
+    scan. An output past its slice's length is of no use either way."""
+    S, T, H, dk = k.shape
+    use_kernel, interpret = scan_route(dk, H, v.shape[-1], T, chunk,
+                                       enabled=enabled)
+    if not use_kernel:
+        return kda_scan(state, q, k, v, g, beta, lengths, chunk)
+    from llmq_tpu.ops.pallas.kda_scan import kda_scan_pallas
+    return kda_scan_pallas(state, q, k, v, g, beta, lengths, block=chunk,
+                           interpret=interpret)
 
 
 def update_route(d_k: int, n_heads: int, d_v: int, *,

@@ -800,6 +800,135 @@ def bench_kda(args, doc) -> None:
     _save(args, doc, results)
 
 
+def bench_kda_scan(args, doc) -> None:
+    """The delta rule's scan of one layer over a mixed step's prompt
+    slices at the served geometry (``--prefill L1,L2,...``: the valid
+    tokens of each slice, the slices left over empty): µs a call through
+    the kernel (``ops/pallas/kda_scan.py``; ``--lanes N`` again at N / 128
+    heads a grid step) and through XLA's scan (``ops/kda.kda_scan``),
+    the 64-token chunks under a length of the grid's, how far the two
+    are apart at the valid positions and in the states handed on, and
+    how far each is from the update applied a token at a time in float64
+    on the host (one head of the first slice). A tree without the kernel
+    (``--tree``) times XLA's scan alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmq_tpu.ops import kda
+
+    mb = doc["server"]["executor"]["mixed_batch"]
+    S = mb["max_slices"]
+    T = mb["prefill_token_budget"] // S
+    H, d = doc["num_attention_heads"], doc["head_dim"]
+    L = sum(1 for l in range(doc["num_hidden_layers"])
+            if (l + 1) % doc["layer_group_size"])
+    block = 16                  # families/ling_hybrid/adapter.KDA_CHUNK
+    if args.rehearse:
+        os.environ["LLMQ_PALLAS"] = "interpret"
+        S, T, H, L = 2, 128, 2, 1
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path in interpret mode)")
+    ks = jax.random.split(jax.random.key(0), 6)
+    q = kda.l2_norm(jax.random.normal(ks[0], (S, T, H, d))) * d ** -0.5
+    k = kda.l2_norm(jax.random.normal(ks[1], (S, T, H, d)))
+    v = jax.random.normal(ks[2], (S, T, H, d), jnp.float32)
+    g = doc["kda_lower_bound"] * jax.nn.sigmoid(
+        2.0 * jax.random.normal(ks[3], (S, T, H, d)) - 2.5)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (S, T, H)))
+    state = jax.random.normal(ks[5], (S, d, H * d), jnp.float32)
+    kernel = getattr(kda, "kda_scan_slices", None)
+
+    def make(heads):
+        @jax.jit
+        def run(state, lengths, q, k, v, g, beta):
+            def body(i, carry):
+                st, acc = carry
+                # (nothing of a call is the loop's invariant to hoist)
+                one = jnp.where(i < 1 << 20, 1.0, 2.0)
+                call = (state, q * one, k * one, v * (1 + i / 64), g * one,
+                        beta, lengths)
+                if heads is None:
+                    o, st = kda.kda_scan(*call, block)
+                elif heads:
+                    from llmq_tpu.ops.pallas.kda_scan import kda_scan_pallas
+                    o, st = kda_scan_pallas(*call, block=block, heads=heads,
+                                            interpret=args.rehearse)
+                else:
+                    o, st = kernel(*call, block)
+                return st, acc + o
+            return jax.lax.fori_loop(0, L, body,
+                                     (state, jnp.zeros_like(v)))
+        return run
+
+    print(f"{doc['name']}: kda scan S={S} T={T} heads={H}x{d} layers={L} "
+          f"device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    n = 1 if args.rehearse else 10
+
+    def timed(heads, lengths):
+        run = make(heads)
+        call = (state, lengths, q, k, v, g, beta)
+        st, o = run(*call)
+        first = (np.asarray(o), np.asarray(st))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            st, o = run(*call)
+        jax.block_until_ready(o)
+        return (time.perf_counter() - t0) / (n * L) * 1e6, first
+
+    def reference(length, h=0):
+        """The state behind slice 0 of head ``h``, a token at a time in
+        float64; the last layer's call (``v`` scaled as ``make`` does)."""
+        f = lambda x: np.asarray(x[0, :, h], np.float64)  # noqa: E731
+        kk, gg = f(k), f(g)
+        vv = np.asarray(v[0, :, h] * (1 + (L - 1) / 64), np.float64)
+        bb = np.asarray(beta[0, :, h], np.float64)
+        st = np.asarray(state[0, :, h * d:(h + 1) * d], np.float64)
+        for t in range(length):
+            st = st * np.exp(gg[t])[:, None]
+            st = st + np.outer(kk[t] * bb[t], vv[t] - kk[t] @ st)
+        return st
+
+    results = []
+    for spec in args.prefill:
+        lens = [min(T, int(x)) for x in spec.split(",")][:S]
+        lens += [0] * (S - len(lens))
+        lengths = jnp.asarray(lens, jnp.int32)
+        valid = np.arange(T)[None] < np.asarray(lens)[:, None]
+        chunks = sum(-(-x // 64) for x in lens)
+        rec = {"lengths": lens, "chunks": S * T // 64, "chunks_live": chunks}
+        rec["xla_us"], (xla_o, xla_s) = timed(None, lengths)
+        line = (f"  lengths {lens}: {chunks} of {rec['chunks']} chunks "
+                f"live: xla {rec['xla_us']:,.1f} us/call")
+        # (every call starts from ``state``: the last one's is returned)
+        ref_s = reference(lens[0])
+
+        def against(s):
+            return float(np.abs(s[0, :, :d] - ref_s).max())
+
+        rec["xla_state_err"] = against(xla_s)
+        line += f" (state off float64 by {rec['xla_state_err']:.2e})"
+        if kernel is not None:
+            for heads in [0] + [x // 128 for x in args.lanes]:
+                us, (ker_o, ker_s) = timed(heads, lengths)
+                tag = f"kernel{heads or ''}"
+                rec[f"{tag}_us"] = us
+                rec[f"{tag}_state_err"] = against(ker_s)
+                rec[f"{tag}_gap_o"] = float(
+                    np.abs(ker_o - xla_o)[valid].max()) if chunks else 0.0
+                rec[f"{tag}_gap_state"] = float(np.abs(ker_s - xla_s).max())
+                line += (f"; {tag} {us:,.1f} (off float64 by "
+                         f"{rec[f'{tag}_state_err']:.2e}; from xla: outputs "
+                         f"{rec[f'{tag}_gap_o']:.2e}, states "
+                         f"{rec[f'{tag}_gap_state']:.2e})")
+        results.append(rec)
+        print(line, flush=True)
+    _save(args, doc, results)
+
+
 #: family dispatches are what this tool is about, so a new family's
 #: bench is a function here and an entry in this table.
 BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent,
@@ -853,11 +982,11 @@ def main() -> None:
         sys.exit(f"{args.model_file}: no kernel bench for the family "
                  f"{doc.get('family')!r}; known: {sorted(BENCHES)}")
     if args.prefill:
-        if doc["family"] in ("granitemoehybrid", "ling_hybrid"):
+        if doc["family"] == "granitemoehybrid":
             sys.exit(f"--prefill: no slice bench for {doc['family']} (its "
                      f"recurrent mixer's scan is XLA's)")
-        (bench_prefill if doc["family"] == "llama"
-         else bench_latent_prefill)(args, doc)
+        {"llama": bench_prefill, "ling_hybrid": bench_kda_scan}.get(
+            doc["family"], bench_latent_prefill)(args, doc)
     if args.lens:
         BENCHES[doc["family"]](args, doc)
     elif not args.prefill:

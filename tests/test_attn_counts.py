@@ -11,7 +11,12 @@ dispatchers go by). Held here, on the CPU, for a ``llama`` and an
 ``afmoe`` tiny configuration: the span's numbers are the schedule's at
 the contexts the engine held at that dispatch. The counts are the host's
 bookkeeping of a kernel that runs on the chip: nothing here times or
-runs it."""
+runs it.
+
+Since PR 46 the span of a program that carries prompt chunks also holds
+``scan_chunks`` / ``scan_chunks_live`` where a family's recurrent layers
+scan them in a kernel (``ling_hybrid``: ``executor.scan_work`` by the
+family's ``scan_step_tokens``), held here the same way."""
 
 import dataclasses
 
@@ -178,3 +183,99 @@ def test_a_family_with_another_kernel_counts_nothing():
     assert h.done
     assert not any("attn_steps" in (s.meta or {})
                    for s in eng._prof.snapshot())
+
+
+
+def _ling(kda_head_dim=128):
+    from llmq_tpu.models import ling_hybrid as lh
+    cfg = lh.ling_hybrid_tiny(dtype=jnp.float32, max_seq_len=256, n_layers=3,
+                              kda_head_dim=kda_head_dim, kda_chunk=16)
+    tok = ByteTokenizer()
+    ex = JaxExecutor(cfg, lh.init_params(jax.random.PRNGKey(46), cfg),
+                     batch_size=ROWS, page_size=PAGE, num_pages=160,
+                     prefill_buckets=[64, 128], eos_id=tok.eos_id,
+                     chunk_size=4, mixed_prefill_slices=2,
+                     mixed_slice_tokens=128)
+    return ex, tok
+
+
+def test_the_scan_kernel_s_chunks_are_counted_where_it_serves(monkeypatch):
+    """``executor.scan_work``: the grid's 64-token steps a head block of
+    ONE layer's call — every slice of the program, the empty ones too —
+    and those under a slice's length; nothing where the slices go to
+    XLA's scan (the kernel off, a head width that is not its) or the
+    family has no such kernel."""
+    ex, _ = _ling()
+    # on the CPU the kernel does not serve
+    assert ex.scan_work("mixed_chunk", [128, 5]) is None
+    monkeypatch.setenv("LLMQ_PALLAS", "interpret")
+    assert ex.scan_work("mixed_chunk", [128, 5]) == (4, 3)
+    assert ex.scan_work("mixed_chunk", [65]) == (4, 2)
+    assert ex.scan_work("mixed_chunk", [64]) == (4, 1)
+    assert ex.scan_work("prefill", [100]) == (2, 2)     # the 128 bucket
+    assert ex.scan_work("prefill", [7]) == (1, 1)       # the 64 bucket
+    assert ex.scan_work("decode_chunk", [7]) is None
+    assert ex.scan_work("mixed_chunk", []) is None
+    assert _ling(kda_head_dim=32)[0].scan_work("mixed_chunk", [5]) is None
+    cfg, params = _llama()
+    llama_ex = JaxExecutor(cfg, params, batch_size=ROWS, page_size=PAGE,
+                           num_pages=96, prefill_buckets=[16],
+                           eos_id=ByteTokenizer().eos_id, chunk_size=4)
+    assert llama_ex.scan_work("prefill", [5]) is None
+
+
+def test_the_dispatch_span_carries_the_scan_kernel_s_chunks(monkeypatch):
+    """While a capture is held, a program that carries prompt chunks
+    says how many steps of the scan kernel's grid one layer's call has
+    and how many run, by the engine's own bookkeeping of the slices'
+    lengths; a decode chunk says nothing, and nothing is counted with no
+    capture held."""
+    ex, tok = _ling()
+    eng = InferenceEngine(
+        ex, tok, enable_metrics=False, max_decode_steps=64,
+        mixed_batch=MixedBatchConfig(enabled=True, prefill_token_budget=256,
+                                     max_slices=2))
+    held = [True]
+    monkeypatch.setattr(engine_module, "capture_held", lambda: held[0])
+    seen = []
+    work = eng._scan_work
+
+    def recorded(entry, lens):
+        # count as the chip would: the route is asked for here alone, no
+        # program is traced inside this call
+        monkeypatch.setenv("LLMQ_PALLAS", "interpret")
+        try:
+            seen.append((entry, list(lens), work(entry, lens)))
+        finally:
+            monkeypatch.delenv("LLMQ_PALLAS")
+        return seen[-1][2]
+
+    eng._scan_work = recorded
+    prompts = ["short", "x" * 150, "y" * 70, "z" * 200]
+    handles = [eng.submit(GenRequest(id=str(i), prompt=p,
+                                     max_new_tokens=12 + 5 * i,
+                                     temperature=0.0))
+               for i, p in enumerate(prompts)]
+    eng.run_until_idle()
+    assert all(h.done for h in handles)
+    spans = [s.meta for s in eng._prof.snapshot()
+             if s.name == "engine.dispatch" and s.meta]
+    counted = [m for m in spans if "scan_chunks" in m]
+    assert len(counted) == len(seen) >= 2
+    for meta, (entry, lens, got) in zip(counted, seen):
+        assert meta["program"].startswith(entry)
+        assert sum(lens) == meta["prefill_tokens"]
+        assert (meta["scan_chunks"], meta["scan_chunks_live"]) == got
+        assert got[1] == sum(-(-n // 64) for n in lens) <= got[0]
+        if entry == "mixed_chunk":
+            assert got[0] == 2 * 128 // 64
+    assert any(e == "mixed_chunk" for e, _, _ in seen)
+    assert any(m["scan_chunks_live"] < m["scan_chunks"] for m in counted)
+    assert not any("scan_chunks" in m for m in spans
+                   if m["program"] == "decode_chunk")
+    held[0] = False
+    n = len(seen)
+    h = eng.submit(GenRequest(id="late", prompt="once more",
+                              max_new_tokens=8, temperature=0.0))
+    eng.run_until_idle()
+    assert h.done and len(seen) == n

@@ -3,9 +3,11 @@
 ops the other families share: ``ops/moe.route``'s group limit and
 ``models/latent.head_gate``.
 
-The chunked scan is held to the one-token update applied T times (the
-two disagreeing is the family's likeliest fault), down to the decay's
-floor; the in-place kernel, in interpret mode, to the update's XLA form;
+The chunked scan — XLA's and the kernel's (``ops/pallas/kda_scan.py``,
+in interpret mode) — is held to the one-token update applied T times
+(the two disagreeing is the family's likeliest fault), down to the
+decay's floor; the in-place kernel, in interpret mode, to the update's
+XLA form;
 and with its defaults ``route`` traces to the program it was before
 there were groups, as the latent attention does for a tree without a
 gate leaf: the jaxpr's digest is the one recorded at the parent commit.
@@ -22,6 +24,8 @@ import jax.numpy as jnp  # noqa: E402
 from llmq_tpu.models import deepseek_v3 as ds  # noqa: E402
 from llmq_tpu.models import latent  # noqa: E402
 from llmq_tpu.ops import kda, moe  # noqa: E402
+from llmq_tpu.ops.pallas.kda_scan import (kda_scan_heads,  # noqa: E402
+                                          kda_scan_pallas, kda_scan_viable)
 from llmq_tpu.ops.pallas.kda_update import (kda_update_pallas,  # noqa: E402
                                             kda_update_viable)
 from llmq_tpu.ops.ssm import decode_walk  # noqa: E402
@@ -47,38 +51,118 @@ def _token_by_token(state, q, k, v, g, beta, lengths):
     return jnp.stack(outs, 1), state
 
 
-@pytest.mark.parametrize("chunk", [8, 16, 64])
-def test_the_scan_is_the_update_applied_a_token_at_a_time(chunk):
-    """Two slices, one that ends in the middle of a chunk: outputs at
-    the valid positions and the state behind each slice's LAST VALID
-    token, from a state that is not zero."""
+def _kernel_scan(heads=0):
+    def scan(state, q, k, v, g, beta, lengths, chunk):
+        return kda_scan_pallas(state, q, k, v, g, beta, lengths, block=chunk,
+                               heads=heads, interpret=True)
+    return scan
+
+
+#: (scan, its chunk, (S, T, H, d_k, d_v), lengths, the state before).
+#: XLA's scan at small widths; the kernel (interpret mode) at the one
+#: width it is written for, 64-token steps around blocks of ``chunk``.
+SCANS = [
+    pytest.param(kda.kda_scan, 8, (2, 37, 2, 16, 8), [37, 21], "random",
+                 id="8"),
+    pytest.param(kda.kda_scan, 16, (2, 37, 2, 16, 8), [37, 21], "random",
+                 id="16"),
+    pytest.param(kda.kda_scan, 64, (2, 37, 2, 16, 8), [37, 21], "random",
+                 id="64"),
+    # a length inside a 16-token block, on a block's edge inside a step,
+    # on a step's edge, and a full slice
+    pytest.param(_kernel_scan(), 16, (4, 128, 2, 128, 128),
+                 [70, 80, 64, 128], "random", id="kernel-lengths"),
+    # a slice of length 0 beside a full one: its state back to the bit
+    pytest.param(_kernel_scan(), 16, (2, 128, 1, 128, 128), [0, 128],
+                 "random", id="kernel-empty-slice"),
+    # H * d_v of two head blocks, from a zero state, blocks of 8
+    pytest.param(_kernel_scan(heads=2), 8, (1, 64, 4, 128, 128), [50],
+                 "zero", id="kernel-head-blocks"),
+    pytest.param(_kernel_scan(heads=1), 32, (2, 64, 1, 128, 128), [64, 33],
+                 "random", id="kernel-blocks-of-32"),
+]
+
+
+@pytest.mark.parametrize("scan, chunk, shape, lengths, before", SCANS)
+def test_the_scan_is_the_update_applied_a_token_at_a_time(
+        scan, chunk, shape, lengths, before):
+    """Slices that end in the middle of a chunk, on its edges and not at
+    all: outputs at the valid positions and the state behind each
+    slice's LAST VALID token, from a state that is not zero; an empty
+    slice's state comes back as it went in."""
     rng = np.random.default_rng(0)
-    S, T, H, dk, dv = 2, 37, 2, 16, 8
+    S, T, H, dk, dv = shape
     ins = _inputs(rng, S, T, H, dk, dv)
     state = jnp.asarray(rng.normal(size=(S, dk, H * dv)), jnp.float32)
-    lengths = [37, 21]
+    if before == "zero":
+        state = jnp.zeros_like(state)
     want_o, want_s = _token_by_token(state, *ins, lengths)
-    got_o, got_s = kda.kda_scan(state, *ins, jnp.asarray(lengths), chunk)
+    got_o, got_s = scan(state, *ins, jnp.asarray(lengths), chunk)
     valid = (np.arange(T)[None] < np.asarray(lengths)[:, None])
+    assert np.isfinite(np.asarray(got_o)).all()
     np.testing.assert_allclose(np.asarray(got_o)[valid],
                                np.asarray(want_o)[valid], atol=2e-6)
     np.testing.assert_allclose(got_s, want_s, atol=5e-6)
+    for s, n in enumerate(lengths):
+        if n == 0:
+            assert (np.asarray(got_s[s]) == np.asarray(state[s])).all()
 
 
-def test_the_scan_at_the_decays_floor_neither_overflows_nor_underflows():
+@pytest.mark.parametrize("scan, shape", [
+    pytest.param(kda.kda_scan, (1, 256, 2, 16, 8), id="xla"),
+    pytest.param(_kernel_scan(), (1, 256, 1, 128, 128), id="kernel")])
+def test_the_scan_at_the_decays_floor_neither_overflows_nor_underflows(
+        scan, shape):
     """g = -5 every token and channel, 256 tokens: a cumulative decay
     of exp(-1280), which no float32 holds; the scan works in differences
-    inside a chunk and agrees with the update to float32."""
+    inside a chunk (the kernel: inside a block, and through a reference
+    point from block to block) and agrees with the update to float32."""
     rng = np.random.default_rng(1)
-    S, T, H, dk, dv = 1, 256, 2, 16, 8
+    S, T, H, dk, dv = shape
     ins = _inputs(rng, S, T, H, dk, dv, floor=True)
     state = jnp.asarray(rng.normal(size=(S, dk, H * dv)), jnp.float32)
     want_o, want_s = _token_by_token(state, *ins, [T])
-    got_o, got_s = kda.kda_scan(state, *ins, jnp.asarray([T]), 16)
+    got_o, got_s = scan(state, *ins, jnp.asarray([T]), 16)
     assert np.isfinite(np.asarray(got_o)).all()
     assert np.isfinite(np.asarray(got_s)).all()
     np.testing.assert_allclose(got_o, want_o, atol=2e-6)
     np.testing.assert_allclose(got_s, want_s, atol=2e-6)
+
+
+def test_the_scan_kernel_takes_the_served_shape_and_leaves_the_rest_to_xla(
+        monkeypatch):
+    """``kda_scan_viable`` on the shapes the kernel is written for, and
+    ``kda_scan_slices`` by ``_kernel_route``'s policy: XLA's scan where
+    the kernel is off or the shape is not its, the kernel's result under
+    ``LLMQ_PALLAS=interpret``."""
+    assert kda_scan_viable(128, 32, 128, 512, 16)      # the served shape
+    for dk, H, dv, T, chunk in ((16, 32, 128, 512, 16), (128, 32, 64, 512, 16),
+                                (128, 32, 128, 500, 16), (128, 32, 128, 32, 16),
+                                (128, 32, 128, 512, 12), (128, 32, 128, 512, 4),
+                                (128, 32, 128, 512, 128)):
+        assert not kda_scan_viable(dk, H, dv, T, chunk)
+    assert [kda_scan_heads(H) for H in (32, 6, 3, 1)] == [4, 2, 1, 1]
+    rng = np.random.default_rng(5)
+    S, T, H, d = 2, 64, 1, 128
+    ins = _inputs(rng, S, T, H, d, d)
+    state = jnp.asarray(rng.normal(size=(S, d, H * d)), jnp.float32)
+    lengths = jnp.asarray([64, 9])
+    monkeypatch.setenv("LLMQ_PALLAS", "0")
+    assert kda.scan_route(d, H, d, T, 16) == (False, False)
+    o_x, s_x = kda.kda_scan_slices(state, *ins, lengths, 16)
+    want_o, want_s = kda.kda_scan(state, *ins, lengths, 16)
+    assert (np.asarray(o_x) == np.asarray(want_o)).all()
+    assert (np.asarray(s_x) == np.asarray(want_s)).all()
+    monkeypatch.setenv("LLMQ_PALLAS", "interpret")
+    assert kda.scan_route(d, H, d, T, 16) == (True, True)
+    assert kda.scan_route(d, H, d, T, 16, enabled=False) == (False, False)
+    assert kda.scan_route(d, H, d, T + 8, 16) == (False, False)
+    o_k, s_k = kda.kda_scan_slices(state, *ins, lengths, 16)
+    assert (np.asarray(o_k[1, 9:]) != np.asarray(o_x[1, 9:])).any()  # kernel's
+    valid = np.arange(T)[None] < np.asarray(lengths)[:, None]
+    np.testing.assert_allclose(np.asarray(o_k)[valid],
+                               np.asarray(o_x)[valid], atol=2e-6)
+    np.testing.assert_allclose(s_k, s_x, atol=5e-6)
 
 
 def test_the_update_kernel_is_the_update_and_touches_only_the_live_rows():
@@ -228,3 +312,48 @@ def test_the_head_gate_without_its_leaf_is_nothing():
                                      jnp.bfloat16)}
     np.testing.assert_allclose(
         np.asarray(latent.head_gate(cfg, gate, 0, x, o), np.float32), 0.5)
+
+
+def test_the_prefill_program_through_the_scan_kernel_is_the_program(
+        monkeypatch):
+    """A prompt in two slices of the prefill program (64 tokens, then 36:
+    the state carried through the row-state leaf, the second slice ending
+    inside a block) at the kernel's head width: routed to the scan kernel
+    (interpret mode) the logits and the KDA layers' states are what XLA's
+    scan gives."""
+    from llmq_tpu.models import ling_hybrid as lh
+    cfg = lh.ling_hybrid_tiny(dtype=jnp.float32, max_seq_len=128, n_layers=3,
+                              kda_head_dim=128, kda_chunk=16)
+    params = lh.init_params(jax.random.PRNGKey(46), cfg)
+    seq = np.random.default_rng(46).integers(3, cfg.vocab_size, 100,
+                                             dtype=np.int32)
+    table = jnp.asarray(1 + np.arange(cfg.max_seq_len // 8)[None], jnp.int32)
+
+    def served(mode):
+        monkeypatch.setenv("LLMQ_PALLAS", mode)
+        jax.clear_caches()              # the route is read at trace time
+        assert lh.routes(cfg, None, batch=1, page_size=8, max_pages=16,
+                         prefill_rows=1)["ssm_scan"].startswith(
+            "xla" if mode == "0" else "pallas-interpret:kda_scan_pallas")
+        cache = lh.init_kv_pages(cfg, 1 + cfg.max_seq_len // 8, 8)
+        state = lh.init_row_state(cfg, 1)
+        for start, end in ((0, 64), (64, 100)):
+            toks = np.zeros((1, 64), np.int32)
+            toks[0, :end - start] = seq[start:end]
+            pos = start + np.minimum(np.arange(64, dtype=np.int32),
+                                     end - start - 1)[None]
+            logits, cache, state = lh.forward_prefill(
+                params, cfg, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray([end - start], jnp.int32), cache, table,
+                last_only=True, row_state=state,
+                rows=jnp.zeros((1,), jnp.int32))
+        return np.asarray(logits), np.asarray(state["kda"])
+
+    try:
+        want_logits, want_state = served("0")
+        got_logits, got_state = served("interpret")
+    finally:
+        jax.clear_caches()      # no interpret-mode trace for other tests
+    np.testing.assert_allclose(got_logits, want_logits, atol=1e-4)
+    np.testing.assert_allclose(got_state, want_state, atol=1e-5)
+    assert np.abs(want_state[:, 0]).max() > 0.1

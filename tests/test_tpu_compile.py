@@ -905,9 +905,9 @@ def test_the_delta_rule_share_s_step_fits_v5e_and_copies_no_cache(
         args = (arg(B), arg(B), arg(B, mp), arg(B, dtype=jnp.bool_),
                 arg(S * T), arg(S * T), arg(S), arg(S + 1), arg(S, mp),
                 arg(S))
-        # the fused step (and a KDA layer's two copies of its slices'
-        # states) and the loop's body
-        calls = (6 * 3 + 2 + 2 * 6) + (6 + 2 + 2 * 6)
+        # the fused step (a KDA layer: the scan kernel between the two
+        # copies of its slices' states, and the update) and the loop's body
+        calls = (6 * 4 + 2 + 2 * 6) + (6 + 2 + 2 * 6)
     else:
         def step(params, cache, state, tokens, positions, tables, lengths,
                  rows):
@@ -915,7 +915,7 @@ def test_the_delta_rule_share_s_step_fits_v5e_and_copies_no_cache(
                 params, cfg, tokens, positions, lengths, cache, tables,
                 last_only=True, stats=True, row_state=state, rows=rows)
         args = (arg(1, T), arg(1, T), arg(1, mp), arg(1), arg(1))
-        calls = 6 * 2 + 2 * 6
+        calls = 6 * 3 + 2 * 6
     compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
         params, cache, state, *args).compile()
     mem = compiled.memory_analysis()
@@ -927,3 +927,36 @@ def test_the_delta_rule_share_s_step_fits_v5e_and_copies_no_cache(
     assert mem.alias_size_in_bytes >= held
     assert not _whole_copies(compiled, (cache, state))
     assert mem.temp_size_in_bytes < (0.5e9 if program == "decode" else 2.0e9)
+
+
+def test_the_delta_rule_scan_kernel_compiles_for_v5e(one_chip):
+    """``ops/pallas/kda_scan.py`` at the served sizes — five slices of
+    512 tokens, 32 heads of 128 / 128, blocks of 16 inside steps of 64,
+    ``HEADS`` heads a step side by side: one kernel, the slices' states
+    aliased in and out, and its blocks and what the body spills inside
+    the VMEM the call asks for (32 MiB at most: a quarter of the
+    chip's), which the compiler would refuse otherwise."""
+    import re
+
+    from llmq_tpu.ops.pallas import kda_scan as ks
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, T, H, d = 5, 512, 32, 128
+    assert ks.kda_scan_viable(d, H, d, T, 16) and ks.kda_scan_heads(H) == 4
+
+    def step(state, q, k, v, g, beta, lengths):
+        return ks.kda_scan_pallas(state, q, k, v, g, beta, lengths, block=16)
+
+    wide = arg(S, T, H, d)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        arg(S, d, H * d), wide, wide, wide, wide, arg(S, T, H),
+        arg(S, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().alias_size_in_bytes >= S * d * H * d * 4
+    scoped = [int(n) for n in re.findall(
+        r'scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+        r'"size":"(\d+)"', text)]
+    assert scoped and max(scoped) <= 32 << 20, scoped
