@@ -6,7 +6,7 @@ component construction the reference spreads over its cmd/ binaries."""
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 from llmq_tpu.core.config import Config
 from llmq_tpu.engine.engine import InferenceEngine
@@ -15,6 +15,40 @@ from llmq_tpu.engine.tokenizer import get_tokenizer
 from llmq_tpu.utils.logging import get_logger
 
 log = get_logger("engine.builder")
+
+
+def _mixed_geometry(ex) -> Tuple[int, int]:
+    """Executor-side mixed geometry: S slice rows × T tokens (the
+    compiled program's shapes). Disabled → S = 0 → no mixed program
+    is built, and the engine keeps the exact unfused scheduling."""
+    mixed = getattr(ex, "mixed_batch", None)
+    if not getattr(mixed, "enabled", False):
+        return 0, 0
+    return int(getattr(mixed, "max_slices", 0)), int(mixed.slice_tokens)
+
+
+def executor_geometry(cfg: Config) -> dict:
+    """What ``cfg.executor`` / ``cfg.model`` say of a
+    :class:`JaxExecutor`'s programs, as its keyword arguments: batch,
+    pages, buckets, chunk, the cache's type and the mixed step's
+    geometry. One reading for ``build_engine`` and for whoever lowers a
+    configuration's programs without serving them
+    (``scripts/whole_copies.py``)."""
+    import jax.numpy as jnp
+
+    ex = cfg.executor
+    mixed_slices, mixed_slice_tokens = _mixed_geometry(ex)
+    return dict(
+        batch_size=ex.max_batch_size,
+        page_size=ex.page_size,
+        num_pages=ex.kv_pages,
+        prefill_buckets=list(ex.prefill_buckets),
+        chunk_size=ex.decode_chunk,
+        prefill_batch=ex.prefill_batch,
+        cache_dtype=(jnp.int8 if getattr(cfg.model, "kv_quantization", "")
+                     == "int8" else None),
+        mixed_prefill_slices=mixed_slices,
+        mixed_slice_tokens=mixed_slice_tokens)
 
 
 def build_engine(cfg: Config, *, name: str = "engine0",
@@ -54,11 +88,7 @@ def build_engine(cfg: Config, *, name: str = "engine0",
         mesh_shape = dict(mesh_cfg.shape)
     elif getattr(cfg.tpu, "mesh_shape", None):
         mesh_shape = dict(cfg.tpu.mesh_shape)
-    # Executor-side mixed geometry: S slice rows × T tokens (the
-    # compiled program's shapes). Disabled → S = 0 → no mixed program
-    # is built, and the engine keeps the exact unfused scheduling.
-    mixed_slices = int(getattr(mixed, "max_slices", 0)) if mixed_on else 0
-    mixed_slice_tokens = (int(mixed.slice_tokens) if mixed_on else 0)
+    mixed_slices, mixed_slice_tokens = _mixed_geometry(ex)
 
     if ex.backend == "echo":
         executor = EchoExecutor(
@@ -77,7 +107,6 @@ def build_engine(cfg: Config, *, name: str = "engine0",
             async_chunks=pipe_on)
     elif ex.backend == "jax":
         import jax
-        import jax.numpy as jnp
 
         from llmq_tpu.models import family_of, get_config
         from llmq_tpu.models.checkpoint import import_hf, load_checkpoint
@@ -153,16 +182,8 @@ def build_engine(cfg: Config, *, name: str = "engine0",
             mesh = make_mesh(mesh_shape)
         executor = JaxExecutor(
             mcfg, params,
-            batch_size=ex.max_batch_size,
-            page_size=ex.page_size,
-            num_pages=ex.kv_pages,
-            prefill_buckets=list(ex.prefill_buckets),
+            **executor_geometry(cfg),
             eos_id=tokenizer.eos_id,
-            chunk_size=ex.decode_chunk,
-            prefill_batch=ex.prefill_batch,
-            cache_dtype=(jnp.int8 if kv_quant == "int8" else None),
-            mixed_prefill_slices=mixed_slices,
-            mixed_slice_tokens=mixed_slice_tokens,
             mesh=mesh,
             telemetry_name=name,
             # Warmup runs before InferenceEngine can set the flag.

@@ -651,6 +651,83 @@ def _is_quantized_tree(params) -> bool:
     return is_quantized(params["layers"].get("wo"))
 
 
+def _transposed(leaf) -> Tuple[int, ...]:
+    """Major-to-minor order of a stacked matrix (..., in, out) that lies
+    transposed: ``in`` — the axis a product contracts — minor."""
+    n = leaf.ndim
+    return tuple(range(n - 2)) + (n - 1, n - 2)
+
+
+def _lies_transposed(leaf) -> bool:
+    """Does ``leaf`` (an array on a device, or the description of one)
+    say of itself that it lies as :func:`_transposed` has it?"""
+    layout = getattr(getattr(leaf, "format", None), "layout", None)
+    order = getattr(layout, "major_to_minor", None)
+    return order is not None and tuple(order) == _transposed(leaf)
+
+
+def lay_params(fam, params) -> Dict[str, int]:
+    """Lay the stacked leaves that ``fam``'s decode step wants
+    transposed (its ``DECODE_TRANSPOSED``, ``models/__init__.py``) in
+    that layout on the device, ONCE, and say what was laid:
+    ``{"leaves", "bytes"}``.
+
+    The PHYSICAL layout alone (``jax.experimental.layout``): a laid leaf
+    has the shape, the dtype and the values it had, so the forward
+    functions, ``x @ wq`` and every other reader of the tree stay as
+    they are, and a program lowered against the laid leaf
+    (:func:`describe`) multiplies with it where it lies instead of
+    copying the whole stack into that layout at the start of every run.
+
+    The laid leaf takes the original's place IN ``params["layers"]``:
+    the tree that was handed in stays whole and means what it meant,
+    and the original — 201 MB a leaf at SmolLM2's sizes, beside a pool
+    that fills the chip — is freed as soon as nobody else holds it,
+    not kept for the life of whoever built the tree. A leaf that is not
+    a plain array (int8 with its scales: ``ops/quant.is_quantized``), a
+    leaf that already lies so (a tree a second executor is built over)
+    and a family without the name pass through as the objects they
+    are. A DESCRIPTION of a leaf (``jax.ShapeDtypeStruct`` with its
+    sharding) is laid as a description."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+
+    laid = {"leaves": 0, "bytes": 0}
+    layers = params.get("layers", {}) if isinstance(params, dict) else {}
+    for name in getattr(fam, "DECODE_TRANSPOSED", ()):
+        leaf = layers.get(name)
+        if getattr(leaf, "sharding", None) is None or leaf.ndim < 2:
+            continue        # absent, quantized, or on no device
+        if not _lies_transposed(leaf):
+            fmt = Format(Layout(major_to_minor=_transposed(leaf)),
+                         leaf.sharding)
+            if isinstance(leaf, jax.ShapeDtypeStruct):
+                leaf = jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                            sharding=fmt)
+            else:
+                leaf = jax.device_put(leaf, fmt)
+            layers[name] = leaf
+        laid["leaves"] += 1
+        laid["bytes"] += leaf.size * leaf.dtype.itemsize
+    return laid
+
+
+def describe(tree, on=None):
+    """``tree``'s leaves as a lowering takes them
+    (``jax.ShapeDtypeStruct``): shape, dtype and where each lies — the
+    sharding ``on`` or, without one, the leaf's own (mesh path: the AOT
+    program must be partitioned exactly like the runtime arrays) and,
+    for a leaf :func:`lay_params` laid, its layout: the compiled
+    program then takes the leaf as it lies."""
+    import jax
+
+    def one(x):
+        where = on or (x.format if _lies_transposed(x)
+                       else getattr(x, "sharding", None))
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)
+    return jax.tree.map(one, tree)
+
+
 def _named(fn: Callable, name: str) -> Callable:
     """``fn`` under another ``__name__``: ``jax.jit`` names the XLA
     module it builds ``jit_<__name__>``."""
@@ -754,7 +831,21 @@ class JaxExecutor:
                 model_cfg, page_size=page_size,
                 step_tokens=max(max(prefill_buckets or [32, 128, 512]),
                                 int(mixed_slice_tokens)))
-        self.row_state = fam.init_row_state(model_cfg, batch_size)
+        #: Parameters that are only DESCRIBED (``jax.ShapeDtypeStruct``
+        #: leaves with their sharding, on a device that may itself be
+        #: described: ``scripts/whole_copies.py``,
+        #: ``tests/test_tpu_compile.py``) get a described pool and row
+        #: state beside them: such an executor lowers its programs
+        #: (``programs``) as a served one does, holds no buffer and
+        #: runs nothing.
+        lead = jax.tree.leaves(params)[0]
+
+        def held(make):
+            if isinstance(lead, jax.ShapeDtypeStruct):
+                return describe(jax.eval_shape(make), lead.sharding)
+            return make()
+        self.row_state = held(
+            lambda: fam.init_row_state(model_cfg, batch_size))
         self.row_state_bytes_per_row = (
             fam.row_state_bytes_per_row(model_cfg)
             if self.row_state is not None else 0)
@@ -858,6 +949,13 @@ class JaxExecutor:
         else:
             self._kv_shardings = None
         self.model_cfg = model_cfg
+        #: What :func:`lay_params` laid transposed on the device:
+        #: ``get_stats()["device"]["relaid"]``.
+        self.relaid = lay_params(fam, params)
+        if self.relaid["leaves"]:
+            log.info("laid %d stacked parameter leaves transposed on the "
+                     "device (%d bytes)", self.relaid["leaves"],
+                     self.relaid["bytes"])
         self.params = params
         max_pages_per_seq = max(
             1, model_cfg.max_seq_len // page_size)
@@ -898,8 +996,9 @@ class JaxExecutor:
                                       dtype=cache_dtype),
                 out_shardings=self._kv_shardings)()
         else:
-            self.cache = init_kv_pages(model_cfg, num_pages, page_size,
-                                       dtype=cache_dtype)
+            self.cache = held(
+                lambda: init_kv_pages(model_cfg, num_pages, page_size,
+                                      dtype=cache_dtype))
         #: How the fused decode kernel cuts a call of this geometry
         #: (``attn_work`` counts by it); None where the decode steps'
         #: attention is not that kernel's — a family whose pool it does
@@ -1237,6 +1336,7 @@ class JaxExecutor:
         from llmq_tpu.observability.device import device_identity
         ident = device_identity()
         return {"n_params": n_params,
+                "relaid": self.relaid,
                 "platform": ident["platform"],
                 "device_kind": ident["kind"],
                 "device_count": ident["count"],
@@ -1527,7 +1627,7 @@ class JaxExecutor:
 
         def leaf_ident(x):
             spec = getattr(getattr(x, "sharding", None), "spec", None)
-            return (x.shape, str(x.dtype), str(spec))
+            return (x.shape, str(x.dtype), str(spec), _lies_transposed(x))
 
         # Mesh identity: (axis names, axis sizes, dp page universes).
         # A single-chip artifact must MISS when the same model builds
@@ -1557,31 +1657,15 @@ class JaxExecutor:
         h.update(ident.encode())
         return h.hexdigest()[:16]
 
-    def _warmup_parallel(self) -> None:
-        """AOT-compile every program CONCURRENTLY from abstract shapes
-        and keep the executables.
-
-        ``jit.lower(...).compile()`` needs no real buffers (the donated
-        multi-GB KV pool is passed as a ShapeDtypeStruct, so no second
-        pool is ever allocated) and XLA compilation releases the GIL, so
-        the decode-chunk giant and all prefill buckets compile in
-        parallel — first-start warmup costs max(program) instead of
-        sum(programs). The compiled executables are stored in
-        ``self._aot`` and CALLED directly at runtime (the call sites
-        prefer them over the jit wrappers), so each program is traced
-        exactly once; with the persistent compilation cache
-        (parallel/mesh.enable_compilation_cache) a restart pays only
-        tracing + cache deserialization — and with the EXPORT cache
-        (``_export_cache_dir``) not even the tracing + Mosaic lowering:
-        warm restarts deserialize the lowered module per program.
-        """
-        import os
-
+    def programs(self) -> List[tuple]:
+        """Every program this executor serves with, as ``(name, the
+        jitted function, its abstract operands, its attention
+        routes)``: what the warm-up lowers and compiles, from abstract
+        shapes alone (the donated multi-GB pool is a ShapeDtypeStruct,
+        so no second pool is ever allocated). The parameters are
+        described as they LIE (:func:`describe`): a leaf
+        :func:`lay_params` laid enters the program in its layout."""
         import jax
-        from jax import export as jexport
-        from concurrent.futures import ThreadPoolExecutor
-
-        from llmq_tpu.observability.device import XLA_CACHE
 
         jnp = self._jnp
         spec = self.spec
@@ -1598,14 +1682,8 @@ class JaxExecutor:
             return jax.ShapeDtypeStruct(shape, dtype,
                                         sharding=self._batch_shd)
 
-        # Params/cache keep their shardings (mesh path: the AOT program
-        # must be partitioned exactly like the runtime arrays).
-        abstract = lambda tree: jax.tree.map(  # noqa: E731
-            lambda x: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=getattr(x, "sharding", None)),
-            tree)
-        p = abstract(self.params)
-        c = abstract(self._pool)
+        p = describe(self.params)
+        c = describe(self._pool)
         # the batch rows of a program's prompt chunks: an operand of a
         # family that keeps row state, none of the others
         rows_of = ((lambda n: (sds((n,), jnp.int32),))
@@ -1656,6 +1734,33 @@ class JaxExecutor:
                           sds((S, MP), i32), sds((S,), f32), key)
                          + rows_of(S),
                          self._routes(decode=True, prefill_rows=S)))
+        return jobs
+
+    def _warmup_parallel(self) -> None:
+        """AOT-compile every program (``programs``) CONCURRENTLY from
+        abstract shapes and keep the executables.
+
+        ``jit.lower(...).compile()`` needs no real buffers and XLA
+        compilation releases the GIL, so the decode-chunk giant and all
+        prefill buckets compile in parallel — first-start warmup costs
+        max(program) instead of sum(programs). The compiled executables are stored in
+        ``self._aot`` and CALLED directly at runtime (the call sites
+        prefer them over the jit wrappers), so each program is traced
+        exactly once; with the persistent compilation cache
+        (parallel/mesh.enable_compilation_cache) a restart pays only
+        tracing + cache deserialization — and with the EXPORT cache
+        (``_export_cache_dir``) not even the tracing + Mosaic lowering:
+        warm restarts deserialize the lowered module per program.
+        """
+        import os
+
+        import jax
+        from jax import export as jexport
+        from concurrent.futures import ThreadPoolExecutor
+
+        from llmq_tpu.observability.device import XLA_CACHE
+
+        jobs = self.programs()
 
         exp_dir = self._export_cache_dir()
         exp_key = self._export_cache_key() if exp_dir else None

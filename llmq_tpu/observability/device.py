@@ -334,6 +334,10 @@ class DeviceTelemetry:
         self.device_count = 0
         self.quant = ""
         self.n_chips = 1
+        #: Stacked parameter leaves the executor laid transposed on the
+        #: device when it took the tree, and their bytes
+        #: (``engine/executor.lay_params``); zeros where it laid none.
+        self.relaid: Dict[str, int] = {"leaves": 0, "bytes": 0}
         self.rtt_ms: Optional[float] = None
         # Compile/export-cache surface (executor warmup fills these).
         self._compile: Dict[str, Dict[str, Any]] = {}
@@ -360,8 +364,10 @@ class DeviceTelemetry:
 
     def configure_model(self, *, n_params: int = 0, device_kind: str = "",
                         platform: str = "", device_count: int = 0,
-                        quant: str = "", n_chips: int = 1) -> None:
+                        quant: str = "", n_chips: int = 1,
+                        relaid: Optional[Dict[str, int]] = None) -> None:
         self.n_params = int(n_params)
+        self.relaid = dict(relaid or {"leaves": 0, "bytes": 0})
         self.device_kind = device_kind
         self.platform = platform
         self.device_count = int(device_count)
@@ -651,6 +657,7 @@ class DeviceTelemetry:
                     "quant": self.quant or "bf16",
                     "n_chips": self.n_chips,
                 },
+                "relaid": dict(self.relaid),
                 "host_device_rtt_ms": (round(self.rtt_ms, 2)
                                        if self.rtt_ms is not None
                                        else None),

@@ -8,7 +8,10 @@ The engine replaces the reference's simulated LLM processing
 
 import threading
 
+import numpy as np
 import pytest
+
+from mixed_tight import tight_step  # noqa: F401 — a fixture
 
 from llmq_tpu.core.clock import FakeClock
 from llmq_tpu.core.types import Message, MessageStatus, Priority
@@ -897,3 +900,166 @@ class TestIncrementalPrefill:
         assert hr.result.text == "r" * 12, hr.result   # echo intact
         assert hl.done and hl.result.finish_reason in ("eos", "length")
         assert hl.result.text == "l" * 4, hl.result    # rebuilt correctly
+
+
+# -- the leaves the executor lays transposed ----------------------------------
+
+def _laid_model(**kw):
+    import jax
+
+    from llmq_tpu.models.llama import init_params, llama3_tiny
+
+    cfg = llama3_tiny(dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+                      ffn_dim=128, vocab_size=512, max_seq_len=256, **kw)
+    return cfg, init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _laid_executor(cfg, params, **kw):
+    return JaxExecutor(cfg, params, batch_size=2, page_size=8, num_pages=64,
+                       prefill_buckets=[16, 64], eos_id=ByteTokenizer().eos_id,
+                       chunk_size=4, mixed_prefill_slices=2,
+                       mixed_slice_tokens=16, **kw)
+
+
+class TestLaidParams:
+    """``llama.DECODE_TRANSPOSED``: the executor lays ``wq``, ``wk`` and
+    ``wv`` transposed on the device once, when it takes the tree
+    (``executor.lay_params``) — the physical layout alone."""
+
+    NAMES = ("wq", "wk", "wv")
+
+    def test_three_leaves_are_laid_and_counted(self):
+        from llmq_tpu.engine.executor import _lies_transposed
+
+        cfg, params = _laid_model()
+        before = {n: np.asarray(params["layers"][n], np.float32)
+                  for n in params["layers"]}
+        ex = _laid_executor(cfg, params)
+        nbytes = sum(before[n].size * 2 for n in self.NAMES)
+        assert ex.relaid == {"leaves": 3, "bytes": nbytes}
+        assert ex.telemetry_info()["relaid"] == ex.relaid
+        for name, leaf in ex.params["layers"].items():
+            assert _lies_transposed(leaf) == (name in self.NAMES), name
+            # shape, dtype and values are what they were
+            assert leaf.shape == before[name].shape
+            np.testing.assert_array_equal(np.asarray(leaf, np.float32),
+                                          before[name])
+        eng = InferenceEngine(ex, ByteTokenizer(), enable_metrics=False)
+        assert eng.get_stats()["device"]["relaid"] == ex.relaid
+
+    def test_the_tree_handed_in_stays_whole_and_holds_nothing_twice(self):
+        """The laid leaf takes the original's place in the tree that
+        was handed in (nobody keeps 3 x 201 MB beside the pool for the
+        harness's sake), and a second executor over the same tree holds
+        the same leaves: laid once."""
+        cfg, params = _laid_model()
+        first = _laid_executor(cfg, params)
+        assert first.params is params
+        laid = {n: params["layers"][n] for n in self.NAMES}
+        second = _laid_executor(cfg, params)
+        assert second.relaid == first.relaid
+        for n in self.NAMES:
+            assert second.params["layers"][n] is laid[n]
+
+    @pytest.mark.parametrize("which", ["quantized", "no-name"])
+    def test_what_is_not_asked_for_comes_back_the_same_objects(
+            self, which, monkeypatch):
+        import jax
+
+        from llmq_tpu.models import llama
+
+        if which == "quantized":
+            cfg, _ = _laid_model()
+            params = llama.init_params_quantized(jax.random.PRNGKey(0), cfg)
+        else:
+            cfg, params = _laid_model()
+            monkeypatch.delattr(llama, "DECODE_TRANSPOSED")
+        leaves = jax.tree.leaves(params)
+        ex = _laid_executor(cfg, params)
+        assert ex.relaid == {"leaves": 0, "bytes": 0}
+        assert ex.params is params
+        assert all(a is b for a, b in zip(jax.tree.leaves(ex.params),
+                                          leaves))
+
+    def test_a_described_tree_is_laid_as_a_description(self):
+        """``scripts/whole_copies.py`` and ``tests/test_tpu_compile.py``
+        hand the executor ShapeDtypeStructs: it describes its pool
+        beside them and its programs take the three leaves in their
+        layout."""
+        import jax
+
+        from llmq_tpu.engine.executor import _lies_transposed, describe
+
+        cfg, params = _laid_model()
+        ex = _laid_executor(cfg, describe(
+            params, jax.sharding.SingleDeviceSharding(jax.devices()[0])))
+        assert ex.relaid["leaves"] == 3
+        assert all(isinstance(x, jax.ShapeDtypeStruct)
+                   for x in jax.tree.leaves((ex.params, ex.cache)))
+        for name, fn, operands, _ in ex.programs():
+            got = operands[0]["layers"]
+            assert sorted(n for n in got if _lies_transposed(
+                got[n])) == sorted(self.NAMES), name
+            fn.lower(*operands)
+
+    @pytest.mark.parametrize("case", ["two-tiles-whole-full",
+                                      "four-tiles-lead-crosses-an-edge",
+                                      "all-slices-full"])
+    def test_the_logits_are_bit_equal(self, case, tight_step):
+        """``forward_prefill``, ``forward_decode`` and ``forward_mixed``
+        over the laid tree against the same over the tree as it was
+        made: the products are the same products."""
+        import jax
+
+        from llmq_tpu.engine.executor import lay_params
+        from llmq_tpu.models import llama
+        import mixed_tight
+
+        cfg, plain = _laid_model()
+        laid = jax.tree.map(lambda x: x, plain)
+        assert lay_params(llama, laid)["leaves"] == 3
+        assert laid["layers"]["wq"] is not plain["layers"]["wq"]
+        want = mixed_tight.both_ways(tight_step, llama, cfg, plain, case,
+                                     page=8)
+        got = mixed_tight.both_ways(tight_step, llama, cfg, laid, case,
+                                    page=8)
+        for a, b in zip(want, got):
+            for name in ("dec", "pf"):
+                np.testing.assert_array_equal(a[name], b[name])
+            for name in a["pages"]:
+                np.testing.assert_array_equal(a["pages"][name],
+                                              b["pages"][name])
+
+    def test_the_streams_are_those_of_the_tree_as_it_was(self, monkeypatch):
+        """A tiny engine over the laid leaves against the same engine
+        built with the family's answer patched empty: token for token,
+        through prefill, mixed and decode chunks."""
+        from llmq_tpu.core.config import MixedBatchConfig
+        from llmq_tpu.models import llama
+
+        def streams(lay):
+            if not lay:
+                monkeypatch.setattr(llama, "DECODE_TRANSPOSED", ())
+            cfg, params = _laid_model()
+            ex = _laid_executor(cfg, params)
+            assert ex.relaid["leaves"] == (3 if lay else 0)
+            eng = InferenceEngine(
+                ex, ByteTokenizer(), enable_metrics=False,
+                max_decode_steps=24,
+                mixed_batch=MixedBatchConfig(enabled=True,
+                                             prefill_token_budget=32,
+                                             max_slices=2))
+            hs = [eng.submit(GenRequest(id="a", prompt="the first runs alone",
+                                        max_new_tokens=20))]
+            for _ in range(3):
+                eng.step()
+            hs += [eng.submit(GenRequest(
+                id=f"b{i}", prompt=f"prompt {i} joins a running batch " * 2,
+                max_new_tokens=12)) for i in range(3)]
+            eng.run_until_idle()
+            assert eng.get_stats()["mixed_batch"]["steps"] > 0
+            return [h.result.tokens for h in hs]
+
+        laid = streams(True)
+        assert all(laid)
+        assert laid == streams(False)

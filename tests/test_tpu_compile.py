@@ -505,6 +505,60 @@ def test_llamas_joined_mixed_step_copies_no_pool_and_no_stacked_matrix_for_v5e(
         mem.temp_size_in_bytes, pool_bytes)
 
 
+@pytest.mark.parametrize("program", ["decode_chunk", "mixed_chunk"])
+def test_smollm2_s_chunk_programs_copy_no_stacked_matrix_as_dispatched_for_v5e(
+        one_chip, monkeypatch, program):
+    """SmolLM2's two chunk programs AS THE EXECUTOR DISPATCHES THEM —
+    the decode loop of 8 steps, and the mixed step (32 rows, two
+    256-token slices) with that loop behind it — at 4 layers, lowered
+    by a ``JaxExecutor`` built over the DESCRIPTION of the parameters
+    (its own ``lay_params`` and ``programs()``: what the warm-up
+    compiles): no stacked parameter and no pool is copied whole.
+
+    The guards above compile a mixed step ALONE and could not see this:
+    inside the decode LOOP the 32-row q, k and v products, whose
+    results are split into 64-wide heads, want their matrices with the
+    contracted axis minor; that is a transposition a layer a step, so
+    loop-invariant motion lifts it out of the ``while`` — and it
+    becomes a transposing copy of the whole stacked ``wq``, ``wk`` and
+    ``wv`` at the start of EVERY run (at full depth 3 x 201 MB, 1.89 ms
+    of a 52-107 ms chunk: the whole ``device_unscoped_share`` of the
+    three SmolLM2 cells, PERF.md PR 47). ``forward_decode`` with no
+    loop around it and ``forward_mixed`` alone hold none. The executor
+    lays the three leaves transposed once (``llama.DECODE_TRANSPOSED``)
+    and describes them so to the lowering; with the family's answer
+    empty this test finds ``[4,2048,2048]`` three times (CHANGES.md)."""
+    from llmq_tpu.engine.executor import JaxExecutor, describe
+    from llmq_tpu.models import llama
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    cfg = llama.LlamaConfig(
+        name="smollm2-4-layers", vocab_size=49152, dim=2048, n_layers=4,
+        n_heads=32, n_kv_heads=32, ffn_dim=8192, max_seq_len=4096,
+        rope_theta=130000.0, tie_embeddings=True)
+    params = describe(jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    ex = JaxExecutor(cfg, params, batch_size=32, page_size=16,
+                     num_pages=3328, prefill_buckets=[256, 1024],
+                     chunk_size=8, prefill_batch=4, mixed_prefill_slices=2,
+                     mixed_slice_tokens=256, telemetry_metrics=False)
+    (fn, operands), = [(fn, operands)
+                       for name, fn, operands, _ in ex.programs()
+                       if name == program]
+    compiled = fn.lower(*operands).compile()
+    assert "%fused_decode_attention_pallas" in compiled.as_text()
+    assert _control_flow(compiled).count("while") == 1
+    assert not _whole_copies(compiled, ex.params)
+    assert not _whole_copies(compiled, ex.cache)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(ex.cache))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert ex.relaid == {"leaves": 3, "bytes": 3 * 4 * 2048 * 2048 * 2}
+
+
 # -- the row state of a hybrid family (granite-4.0-h-micro's sizes) ------------
 
 
@@ -960,3 +1014,51 @@ def test_the_delta_rule_scan_kernel_compiles_for_v5e(one_chip):
         r'scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
         r'"size":"(\d+)"', text)]
     assert scoped and max(scoped) <= 32 << 20, scoped
+
+
+# -- scripts/whole_copies.py: what it reads out of a compiled program's text ----
+
+
+_HLO = """\
+HloModule jit_decode_chunk, is_scheduled=true
+
+%body.7 (arg: (s32[], bf16[24,2048,2048])) -> (s32[], bf16[24,2048,2048]) {
+  %copy-start.3 = (bf16[1,2048,2048]{2,1,0:T(8,128)(2,1)S(1)}, bf16[1,2048,2048]{2,1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%slice.1)
+  %copy-done.3 = bf16[3328,16,4096]{2,1,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.3)
+}
+
+ENTRY %main.9 (params__layers____wq__.1: bf16[24,2048,2048], tok: s32[32]) -> s32[32,8] {
+  %copy.321 = bf16[24,2048,2048]{1,2,0:T(8,128)(2,1)} copy(%params__layers____wq__.1), backend_config={"flag_configs":[]}
+  %transpose.2 = bf16[24,2048,2048]{2,1,0:T(8,128)(2,1)} transpose(%copy.321), dimensions={0,2,1}, metadata={op_name="jit(_decode_chunk)/decode_loop/while/body/qkv/dot_general"}
+  %copy.5 = bf16[32,2048]{1,0:T(8,128)(2,1)} copy(%fusion.1)
+  ROOT %fusion.9 = s32[32,8]{1,0} fusion(%copy.5), kind=kLoop
+}
+"""
+
+
+@pytest.mark.parametrize("name,op,inside,under", [
+    ("copy.321", "copy", "the entry computation", "(no name)"),
+    ("transpose.2", "transpose", "the entry computation",
+     "jit(_decode_chunk)/decode_loop/while/body/qkv/dot_general"),
+    ("copy-done.3", "copy-done", "body.7", "(no name)"),
+])
+def test_whole_copies_script_names_each_move_with_its_place(name, op, inside,
+                                                            under):
+    """``scripts/whole_copies.whole_moves``: a copy, a transpose and an
+    asynchronous copy's end as large as a named leaf, each with the
+    computation it stands in and its ``op_name``; a small copy and a
+    ``copy-start`` (a tuple) are not listed."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "whole_copies.py")
+    spec = importlib.util.spec_from_file_location("whole_copies", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    moves = mod.whole_moves(_HLO, {"24,2048,2048": "params['layers']['wq']",
+                                   "3328,16,4096": "pool['k']"})
+    assert [m["name"] for m in moves] == ["copy-done.3", "copy.321",
+                                          "transpose.2"]
+    (mv,) = [m for m in moves if m["name"] == name]
+    assert (mv["op"], mv["inside"], mv["under"]) == (op, inside, under)
+    assert mv["like"] == ("pool['k']" if name == "copy-done.3"
+                          else "params['layers']['wq']")
