@@ -21,7 +21,10 @@ A family is a module of this package that defines
   whatever its length (a state-space layer's recurrent state; the
   leaves hold ``batch + 1`` rows, the last nobody's, as page 0 is: an
   unused slice of a program names it — ``models/granitemoehybrid.py``
-  beside K/V pages, ``models/ling_hybrid.py`` beside a latent pool; or
+  beside K/V pages, ``models/ling_hybrid.py`` beside a latent pool,
+  both on layers that own NO pages; ``models/zaya.py`` on the very
+  layers that own K/V pages: the tail its mixer's convolutions and
+  value shift need of the token before; or
   a window layer's keys and
   values, a SLAB of pool-shaped pages a batch row: ``models/afmoe.py``,
   which also defines the optional ``bind_cache(cfg, *, page_size,
@@ -116,6 +119,7 @@ FAMILIES: Dict[str, str] = {
     "granitemoehybrid": "llmq_tpu.models.granitemoehybrid",
     "afmoe": "llmq_tpu.models.afmoe",
     "ling_hybrid": "llmq_tpu.models.ling_hybrid",
+    "zaya": "llmq_tpu.models.zaya",
 }
 
 

@@ -4,7 +4,9 @@ selection-only correction bias, the top ``k`` of ``E`` experts a token,
 and a GROUPED product that multiplies each token with its own experts
 and no others.
 
-``route`` chooses and weighs; ``routed_ffn`` sorts the (token, expert)
+``route`` scores (one product) and ``choose`` chooses and weighs — the
+one choice of every family, also of one whose scores come from a router
+network of its own (``models/zaya.py``); ``routed_ffn`` sorts the (token, expert)
 pairs by expert, gathers the tokens in that order and runs the three
 SwiGLU matrices as grouped products over the sorted rows (one product
 a group, the group being the expert's rows), then weighs and adds each
@@ -79,6 +81,25 @@ def _limit(sel: jnp.ndarray, n_group: int, topk_group: int) -> jnp.ndarray:
     return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(N, E)
 
 
+def choose(scores: jnp.ndarray, bias: jnp.ndarray, *, top_k: int,
+           scale: float, norm_topk: bool = True, n_group: int = 1,
+           topk_group: int = 1) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The choice, for every family: ``scores`` (N, E) float32 — a
+    token's score of each expert, whatever made it (``route``'s one
+    product; a family's own router network) — -> (experts (N, k) int32,
+    gates (N, k) float32). The ``k`` experts are the top ``k`` of
+    ``scores + bias``, among the best ``topk_group`` of ``n_group``
+    groups where there are groups (``_limit``); the gates are the chosen
+    ``scores`` (WITHOUT the bias), normalised to sum 1 where
+    ``norm_topk``, times ``scale``. No scope of its own: the caller's
+    ``moe_route`` covers the scores and the choice."""
+    _, experts = lax.top_k(_limit(scores + bias, n_group, topk_group), top_k)
+    g = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk:
+        g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), g * scale
+
+
 def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
           top_k: int, scale: float, norm_topk: bool = True, n_group: int = 1,
           topk_group: int = 1, scoring: str = "sigmoid"
@@ -87,11 +108,8 @@ def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
 
     s = sigmoid(x W_r) — or, ``scoring="softmax"``, softmax over all E
     of x W_r — in float32 at the highest matmul precision (a
-    bf16 pass swaps near-tied experts); the ``k`` experts are the top
-    ``k`` of ``s + bias``, among the best ``topk_group`` of ``n_group``
-    groups where there are groups (``_limit``); the gates are the chosen
-    ``s`` (WITHOUT the bias), normalised to sum 1 where ``norm_topk``,
-    times ``scale``."""
+    bf16 pass swaps near-tied experts); experts and gates are
+    :func:`choose`'s of ``s``."""
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"unknown router scoring {scoring!r}")
     with scope("moe_route"):
@@ -100,11 +118,9 @@ def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
                          precision=lax.Precision.HIGHEST)
         s = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
              else jax.nn.softmax(logits, axis=-1))
-        _, experts = lax.top_k(_limit(s + bias, n_group, topk_group), top_k)
-        g = jnp.take_along_axis(s, experts, axis=-1)
-        if norm_topk:
-            g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
-        return experts.astype(jnp.int32), g * scale
+        return choose(s, bias, top_k=top_k, scale=scale,
+                      norm_topk=norm_topk, n_group=n_group,
+                      topk_group=topk_group)
 
 
 #: megablox tiling (rows, contraction, output): the best of those

@@ -54,7 +54,7 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
         assert all(k in config for k in keys), entry["name"]
         assert set(config["reduced"]) == set(entry["reduced"])
     assert families == {"llama", "deepseek_v3", "longcat_flash",
-                        "granitemoehybrid", "afmoe", "ling_hybrid"}
+                        "granitemoehybrid", "afmoe", "ling_hybrid", "zaya"}
 
 
 @pytest.mark.parametrize("cell", [
@@ -62,7 +62,7 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
     "smollm2-sessions-prefix", "mistral7b-decode-saturated",
     "kanana2-decode-saturated", "longcat-decode-saturated",
     "granite4h-decode-saturated", "trinity-longshort-saturated",
-    "ling3-reasoning-saturated"])
+    "ling3-reasoning-saturated", "zaya1-reasoning-saturated"])
 def test_every_cell_resolves_and_reports_what_the_contract_asks(bench, cell):
     assert contract.check_names(bench) == []
     assert cell in [w["name"] for w in bench["workloads"]]
@@ -830,6 +830,224 @@ def test_the_delta_rule_family_s_tolerance_sits_between_its_readings(bench,
         assert not _judged(reference, tol, 1.0, n=2)["ok"]
 
 
+# -- the family ``zaya`` (PR 48) --------------------------------------------------
+
+
+def test_the_compressed_attention_configuration_is_the_catalogs_row(bench):
+    """``zaya1-8b-bf16-pp2.json`` holds every key of the catalog's row
+    ``ZAYA1-8B`` under the same name and value, but the three its
+    ``reduced`` names; no width is among them; the cut is what the
+    program counts; and the adapter registers it, refusing a layer of
+    another kind."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("no catalog of architectures on this machine")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f) if r["name"] == "ZAYA1-8B")
+    entry = next(c for c in bench["configs"]
+                 if c["name"] == "zaya1-8b-bf16-pp2")
+    with open(os.path.join(REPO, entry["file"])) as f:
+        config = json.load(f)
+    assert entry["source"] == config["source"] == row["source_url"]
+    differs = {k for k, v in row["config"].items() if config.get(k, k) != v}
+    assert differs == set(entry["reduced"]) == {
+        "num_hidden_layers", "layer_types", "max_position_embeddings"}
+    assert set(config["reduced"]) == set(entry["reduced"])
+    assert config["published"] == {k: row["config"][k]
+                                   for k in config["published"]}
+    assert set(config["published"]) == set(entry["reduced"])
+    assert config["layer_types"] == row["config"]["layer_types"][:20]
+    assert len(config["assumed"]) >= 10
+    assert "two pipeline stages" in config["deployment"] \
+        and "stage 0" in config["deployment"]
+    from llmq_tpu.models import zaya
+    fdir = contract.family_dir(bench, config)
+    adapter = contract.load_family(fdir, "adapter")
+    shapes = contract.load_family(fdir, "shapes")
+    cfg = adapter.register("zaya-row-check", config)
+    assert (cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
+            cfg.rotary_dim, cfg.n_experts, cfg.n_experts_per_tok,
+            cfg.router_dim, cfg.rope_theta) == (20, 8, 2, 128, 64, 16, 1,
+                                                256, 5e6)
+    model = {k: config[k] for k in shapes.MODEL_KEYS}
+    assert zaya.param_count_analytic(cfg) == shapes.param_count(model) \
+        == 4_688_805_224
+    whole = dict(model, num_hidden_layers=40,
+                 layer_types=config["published"]["layer_types"])
+    assert shapes.param_count(whole) == 8_840_475_344
+    assert zaya.kv_bytes_per_token(cfg) == shapes.kv_bytes_per_token(
+        model, 2) == 20 * 1024
+    assert zaya.row_state_bytes_per_row(cfg) == shapes.state_bytes_per_row(
+        model) == 215_040
+    assert zaya.active_param_count(cfg) == shapes.active_param_count(model)
+    sliding = dict(config, layer_types=["hybrid"] * 19 + ["hybrid_sliding"])
+    with pytest.raises(ValueError, match="hybrid_sliding"):
+        adapter.register("zaya-sliding", sliding)
+    with pytest.raises(ValueError, match="zaya block"):
+        adapter.register("zaya-three-taps", dict(config, cca_time0=3))
+
+
+def _tail_not_handed_on(zaya, monkeypatch):
+    """A prompt slice is NOT handed the tail its predecessor left (it
+    reads zeros): the convolutions and the value shift start over at
+    every slice boundary."""
+    whole = zaya.rows_read
+    monkeypatch.setattr(zaya, "rows_read", lambda pool, l, rows, **kw:
+                        0 * whole(pool, l, rows, **kw))
+
+
+def _value_shift_dropped(zaya, monkeypatch):
+    """KV head 1 carries the CURRENT token's second value projection."""
+    import jax.numpy as jnp
+
+    from llmq_tpu.ops import cca
+    monkeypatch.setattr(cca, "_values", lambda v1, now, before:
+                        jnp.concatenate([v1, now], -1))
+
+
+def test_a_broken_compressed_attention_path_is_refused_by_the_check(
+        monkeypatch):
+    """``harness/child.py`` ``check_logits`` itself on the rehearsal's
+    toy of the family: correct as served; with either fault planted the
+    family's ``reference_logits`` raises ``NotCorrect`` — by the
+    comparison the benchmark makes, and the first group judged (the
+    prefill's: its slices' last positions, too few for the level's
+    limit, and its row's tails) names a limit that saw it. A slice's
+    last position sits a whole slice behind the boundary the tail was
+    dropped at: what reaches it is what the first tokens' wrong K and V
+    do to the stream — twice the limit and more, not the whole norm
+    (the worst cached ROW is the whole norm: ``tests/test_zaya.py``)."""
+    import jax
+
+    import llmq_tpu.models.zaya as zaya
+    from benchmark.harness import child
+    bench = contract.load_benchmark(os.path.join(
+        REPO, "benchmark", "selftest", "data", "rehearsal_zaya.json"))
+    cell = contract.resolve_cell(bench, "tiny-zaya-saturated")
+    config, srv = cell["config"], cell["config"]["server"]
+    adapter = contract.load_family(cell["family_dir"], "adapter")
+    reference = contract.load_family(cell["family_dir"], "reference")
+    assert config["tolerance"]["judged_tokens"] > 3 * max(
+        srv["executor"]["prefill_buckets"])
+    mcfg = adapter.register(srv["model"]["name"], config)
+    params = child.make_params(4800000123, adapter.param_builder(
+        mcfg, srv["model"]))
+    spec = {"config": config, "seed": 4800000123}
+    try:
+        jax.clear_caches()      # the family's step functions are jitted
+        path = adapter.serving_path(mcfg, srv)
+        assert child.check_logits(params, path, reference.reference_logits,
+                                  spec)["ok"]
+        for fault in (_tail_not_handed_on, _value_shift_dropped):
+            with monkeypatch.context() as planted:
+                fault(zaya, planted)
+                jax.clear_caches()
+                path = adapter.serving_path(mcfg, srv)
+                path.ident += fault.__name__
+                with pytest.raises(reference.NotCorrect) as refused:
+                    child.check_logits(params, path,
+                                       reference.reference_logits, spec)
+            said = str(refused.value)
+            got = float(re.search(r"tail lies ([\d.]+)", said)[1])
+            assert said.startswith("prefill") and got > 2 * config[
+                "tolerance"]["tail_rel"], said
+    finally:
+        reference.JUDGED = None
+        jax.clear_caches()
+
+
+#: What ``zaya1-8b-bf16-pp2``'s check read on the chip (my chip runs,
+#: PR 48; the configuration's ``tolerance.why`` has them): for the served
+#: path and for the control one precision down (router network in
+#: bfloat16 + cache in 8 bits + tail in bfloat16), lowest and highest
+#: over the seeds and groups: the level of a group of many positions,
+#: the worst position, the worst layer's tail and cached rows, the
+#: clearest choice the served path did not make.
+READINGS_ZAYA = {
+    "served": {"level": (0.0028, 0.0052), "worst": (0.0030, 0.0064),
+               "tail": (0.0054, 0.0102), "kv": (0.0060, 0.0074),
+               "row": (0.0095, 0.0109), "swap": (0.0, 0.00088)},
+    "control": {"level": (0.0222, 0.0336), "tail": (0.0378, 0.0822),
+                "kv": (0.0475, 0.0579), "row": (0.0786, 0.0897)},
+    #: the tail NOT handed from slice to slice (call 3)
+    "fault": {"row": (1.25, 1.28)}}
+
+
+def _judged_zaya(reference, tol, level, tail=None, kv=None, swap=0.0, n=128,
+                 row=None):
+    import numpy as np
+    ref = np.zeros((n, 16), np.float32)
+    margins = np.full((20, n), 0.01)
+    swapped = np.zeros((20, n), bool)
+    if swap:
+        margins[2, 7], swapped[2, 7] = swap, True
+    tail = [tol["tail_rel"] / 2] * 20 if tail is None else tail
+    return reference.judge(ref + level, ref, margins, swapped, tail, kv, tol,
+                           row)
+
+
+@pytest.mark.parametrize("limit", ["rms_clean", "tail_rel", "kv_rel",
+                                   "kv_row", "margin_decisive", "rms"])
+def test_the_compressed_attention_family_s_tolerance_sits_between_its_readings(
+        bench, limit):
+    """The judge's keys and no other; under the file's numbers the
+    served path's readings on the chip pass and the control one
+    precision down is refused by EACH of the four limits that see a
+    precision, with room on both sides of each (a quarter at the least),
+    and a tail that was not handed on by the worst row forty times over;
+    a clear choice the served path did not make and unrelated logits
+    are refused too; a group of a slice's one or two positions is held
+    to the worst position, its tails and its choices alone."""
+    cell = contract.resolve_cell(bench, "zaya1-reasoning-saturated")
+    reference = contract.load_family(cell["family_dir"], "reference")
+    tol = cell["config"]["tolerance"]
+    assert set(tol) == {"rms", "max", "clean_quantile", "rms_clean",
+                        "tail_rel", "kv_rel", "kv_row", "margin_decisive",
+                        "margin_eps", "min_positions", "judged_tokens",
+                        "why"}
+    ex = cell["config"]["server"]["executor"]
+    assert tol["judged_tokens"] >= 3 * max(ex["prefill_buckets"]) + 128
+    served, control = READINGS_ZAYA["served"], READINGS_ZAYA["control"]
+    sound = _judged_zaya(reference, tol, served["level"][1],
+                         [served["tail"][1]] * 20, [served["kv"][1]] * 20,
+                         served["swap"][1], row=served["row"][1])
+    assert sound["ok"], sound
+    room = 5 / 4
+    if limit == "rms_clean":
+        assert served["level"][1] * room < tol[limit] < \
+            control["level"][0] / room
+        got = _judged_zaya(reference, tol, control["level"][0])
+        assert not got["ok"] and got["rms_clean"] > tol[limit]
+        # one or two positions are no distribution
+        assert _judged_zaya(reference, tol, control["level"][1], n=2)["ok"]
+    elif limit == "tail_rel":
+        assert served["tail"][1] * room < tol[limit] < \
+            control["tail"][0] / room
+        one = [served["tail"][1]] * 20
+        one[11] = control["tail"][0]            # that layer's tail alone
+        assert not _judged_zaya(reference, tol, served["level"][0],
+                                tail=one)["ok"]
+    elif limit == "kv_rel":
+        assert served["kv"][1] * room < tol[limit] < control["kv"][0] / room
+        one = [served["kv"][1]] * 20
+        one[3] = control["kv"][0]
+        assert not _judged_zaya(reference, tol, served["level"][0],
+                                kv=one)["ok"]
+        assert _judged_zaya(reference, tol, served["level"][0])["ok"]
+    elif limit == "kv_row":
+        assert served["row"][1] * 2 < tol[limit] < control["row"][0] / 2
+        assert READINGS_ZAYA["fault"]["row"][0] > 40 * tol[limit]
+        assert not _judged_zaya(reference, tol, served["level"][0],
+                                row=control["row"][0])["ok"]
+    elif limit == "margin_decisive":
+        assert 4 * served["swap"][1] < tol[limit] <= 0.005
+        got = _judged_zaya(reference, tol, served["level"][0], swap=0.01)
+        assert not got["ok"] and got["swap_margin"] == 0.01
+    else:
+        assert 10 * served["worst"][1] < tol["rms"] < 1.4
+        assert not _judged_zaya(reference, tol, 1.0)["ok"]
+        assert not _judged_zaya(reference, tol, 1.0, n=2)["ok"]
+
+
 def test_the_harness_names_no_family():
     named = re.compile(r"llama|deepseek|kanana|smollm|fused_decode|gmm|"
                        r"latent_decode|moe_grouped|llmq_tpu\.models")
@@ -846,7 +1064,7 @@ def test_the_harness_names_no_family():
 
 @pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
                                     "granitemoehybrid", "afmoe",
-                                    "ling_hybrid"])
+                                    "ling_hybrid", "zaya"])
 def test_who_imports_what_in_a_family(family):
     """``shapes.py`` is standard library alone (the parent and the
     readers import it); ``reference.py`` imports neither the program
@@ -866,7 +1084,7 @@ def test_who_imports_what_in_a_family(family):
 
 @pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
                                     "granitemoehybrid", "afmoe",
-                                    "ling_hybrid"])
+                                    "ling_hybrid", "zaya"])
 def test_what_a_family_brings_to_the_program(family):
     """The program's side of the seam (``llmq_tpu/models/__init__.py``):
     three forward passes the serving programs are built from — no

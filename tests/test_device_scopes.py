@@ -38,6 +38,7 @@ FAMILIES = {
                     | {"latent_prefill_attention", "attn_gate",
                        "kda_gates"}),
     "granitemoehybrid": MODULES | SSM,
+    "zaya": MODULES | ROUTED | {"cca_mix"},
     "llama": MODULES,
     "llama-w8kv8": MODULES | {"act_quant"},
     "deepseek_v3": MODULES | ROUTED | {"latent_prefill_attention"},
@@ -71,6 +72,10 @@ def _tiny(family):
         cfg = lh.ling_hybrid_tiny(dtype=jnp.float32, max_seq_len=128,
                                   held_experts=(8, 16))
         return cfg, lh.init_params(jax.random.PRNGKey(45), cfg), {}
+    if family == "zaya":
+        from llmq_tpu.models import zaya
+        cfg = zaya.zaya_tiny(dtype=jnp.float32, max_seq_len=128)
+        return cfg, zaya.init_params(jax.random.PRNGKey(48), cfg), {}
     if family == "granitemoehybrid":
         from llmq_tpu.models import granitemoehybrid as gm
         cfg = gm.granite4h_tiny(dtype=jnp.float32, max_seq_len=128)
@@ -167,8 +172,9 @@ def test_a_name_outside_the_vocabulary_is_refused():
     # short: they are stored in every instruction's metadata
     assert max(map(len, SCOPES)) <= len("latent_prefill_attention")
     # (176 characters until the family afmoe brought its three:
-    # attn_window, attn_full, attn_gate; ling_hybrid one: kda_gates)
-    assert sum(map(len, SCOPES)) < 220
+    # attn_window, attn_full, attn_gate; ling_hybrid one: kda_gates;
+    # zaya one: cca_mix)
+    assert sum(map(len, SCOPES)) < 230
     assert SSM | WINDOW <= set(SCOPES)
 
 

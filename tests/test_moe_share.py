@@ -406,3 +406,62 @@ def test_a_share_against_a_loop_over_its_experts(case):
         jnp.asarray(experts), jnp.asarray(gates), E, jnp.asarray(live)))
     np.testing.assert_allclose(
         zero, np.where(experts >= E, gates, 0).sum(-1) * live, rtol=1e-6)
+
+
+# -- ``route`` split into its scores and ``choose`` (PR 48) ----------------------
+
+
+def _route_before_the_split(x, w_router, bias, *, top_k, scale,
+                            norm_topk=True, n_group=1, topk_group=1,
+                            scoring="sigmoid"):
+    """``ops/moe.route`` as it stood at the parent of the PR that split
+    it, body for body: the scores and the choice in one function."""
+    from jax import lax
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    s = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
+    _, experts = lax.top_k(moe._limit(s + bias, n_group, topk_group), top_k)
+    g = jnp.take_along_axis(s, experts, axis=-1)
+    if norm_topk:
+        g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), g * scale
+
+
+#: caller -> the keywords its ``route`` call passes (``models/*.py``)
+ROUTE_CALLERS = {
+    "deepseek_v3": dict(top_k=6, scale=2.448, norm_topk=True),
+    "longcat_flash": dict(top_k=12, scale=6.0, norm_topk=False,
+                          scoring="softmax"),
+    "afmoe": dict(top_k=4, scale=2.826, norm_topk=True, scoring="sigmoid"),
+    "ling_hybrid": dict(top_k=8, scale=2.5, norm_topk=True, n_group=8,
+                        topk_group=4),
+    "kda-pinned-defaults": dict(top_k=4, scale=2.5),
+}
+
+
+@pytest.mark.parametrize("caller", sorted(ROUTE_CALLERS))
+def test_choose_behind_route_gives_its_callers_what_route_gave(caller):
+    """Experts and gates to the BIT, jitted and not, for the keywords
+    each routed family calls ``route`` with; and ``choose`` of the same
+    scores is ``route``'s choice (one choice, whoever made the scores)."""
+    kw = ROUTE_CALLERS[caller]
+    rng = np.random.default_rng(48)
+    x = jnp.asarray(rng.normal(size=(96, 64)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(64, 64)) / 8, jnp.bfloat16)
+    bias = jnp.asarray(rng.uniform(-0.02, 0.02, size=(64,)), jnp.float32)
+    for wrap in (lambda f: f, jax.jit):
+        new = wrap(lambda x, w, b: moe.route(x, w, b, **kw))(x, w, bias)
+        old = wrap(lambda x, w, b: _route_before_the_split(
+            x, w, b, **kw))(x, w, bias)
+        for got, want in zip(new, old):
+            assert got.dtype == want.dtype
+            assert (np.asarray(got) == np.asarray(want)).all()
+    from jax import lax
+    logits = jnp.dot(x, w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = (jax.nn.softmax(logits, -1) if kw.get("scoring") == "softmax"
+              else jax.nn.sigmoid(logits))
+    rest = {k: v for k, v in kw.items() if k != "scoring"}
+    for got, want in zip(moe.choose(scores, bias, **rest), new):
+        assert (np.asarray(got) == np.asarray(want)).all()

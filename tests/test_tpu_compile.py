@@ -44,6 +44,7 @@ _GEOMETRIES = {
     "smollm2-1.7b-b1024": (1024, 32, 32, 64, 16, 256, 24, 3328),
     "llama3-1b-b512": (512, 32, 8, 64, 16, 128, 16, 512),
     "llama3-8b-ps128-b512": (512, 32, 8, 128, 128, 16, 32, 264),
+    "zaya1-8b-pp2-b512": (512, 8, 2, 128, 128, 48, 20, 2048),
 }
 
 
@@ -108,6 +109,7 @@ _DECODE_GEOMETRIES = {
     "granite-4.0-h-micro": (64, 32, 8, 64, 128, 16, 4, 832, False),
     "llama3-1b": (8, 32, 8, 64, 16, 128, 16, 512, False),
     "smollm2-1.7b-one-tile": (8, 32, 32, 64, 16, 256, 24, 3328, False),
+    "zaya1-8b-pp2": (64, 8, 2, 128, 128, 48, 20, 2048, False),
 }
 
 
@@ -981,6 +983,85 @@ def test_the_delta_rule_share_s_step_fits_v5e_and_copies_no_cache(
     assert mem.alias_size_in_bytes >= held
     assert not _whole_copies(compiled, (cache, state))
     assert mem.temp_size_in_bytes < (0.5e9 if program == "decode" else 2.0e9)
+
+
+def _zaya_stage(one_chip, monkeypatch):
+    """``benchmark/configs/zaya1-8b-bf16-pp2.json`` as the executor holds
+    it: ``(family, cfg, params, cache, state, (B, T, page))`` as shapes on
+    the described chip."""
+    import json
+
+    from benchmark.harness import contract
+    from llmq_tpu.models import zaya
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    path = os.path.join(contract.ROOT, "benchmark", "configs",
+                        "zaya1-8b-bf16-pp2.json")
+    with open(path, encoding="utf-8") as f:
+        config = json.load(f)
+    adapter = contract.load_family(
+        os.path.join(contract.ROOT, "benchmark", "families", "zaya"),
+        "adapter")
+    cfg = zaya.serving_config(adapter.register("zaya-compile-check", config))
+    ex = config["server"]["executor"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: zaya.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: zaya.init_kv_pages(cfg, ex["kv_pages"], ex["page_size"])))
+    state = on_chip(jax.eval_shape(
+        lambda: zaya.init_row_state(cfg, ex["max_batch_size"])))
+    return zaya, cfg, params, cache, state, (
+        ex["max_batch_size"], max(ex["prefill_buckets"]), ex["page_size"])
+
+
+def test_the_compressed_attention_stage_s_decode_step_fits_v5e_and_copies_no_cache(
+        one_chip, monkeypatch):
+    """One decode step of ``zaya1-8b-bf16-pp2`` as served (20 layers,
+    each with K/V pages AND a tail as row state, 16 experts a layer all
+    held, the whole 262k vocabulary, 64 rows): 14.8 GB of arguments, the
+    pool and the tails go in and come out in place — no copy of a leaf —
+    the shared fused decode kernel is there once a layer at 2 KV heads
+    of 128 beside two grouped products, and the step's temporaries stay
+    inside what is left of the chip's 16.9 GB."""
+    zaya, cfg, params, cache, state, (B, _T, page) = _zaya_stage(
+        one_chip, monkeypatch)
+    mp = cfg.max_seq_len // page
+    assert (cfg.n_layers, cfg.n_experts, cfg.tail_width) == (20, 16, 2688)
+    assert state["tail"].shape == (20, B + 1, 2688)
+    routes = zaya.routes(cfg, cache, batch=B, page_size=page, max_pages=mp,
+                         decode=True, prefill_rows=1)
+    assert routes["decode_attention"].startswith("pallas:_fused_kernel")
+    assert routes["prefill_attention"] == "pallas:_prefill_attn_kernel"
+    assert routes["cca_mix"] == "xla"
+
+    def arg(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(params, cache, state, tokens, positions, tables, active):
+        return zaya.forward_decode.__wrapped__(
+            params, cfg, tokens, positions, cache, tables, active=active,
+            stats=True, row_state=state)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, cache, state, arg(B), arg(B), arg(B, mp),
+        arg(B, dtype=jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((cache, state)))
+    # the fused decode kernel and two ``gmm`` a layer
+    assert compiled.as_text().count("tpu_custom_call") == 20 + 2 * 20
+    assert 14.6e9 < mem.argument_size_in_bytes < 14.9e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    assert mem.alias_size_in_bytes >= held
+    assert not _whole_copies(compiled, (cache, state))
+    assert mem.temp_size_in_bytes < 0.5e9
 
 
 def test_the_delta_rule_scan_kernel_compiles_for_v5e(one_chip):
