@@ -651,26 +651,40 @@ def _is_quantized_tree(params) -> bool:
     return is_quantized(params["layers"].get("wo"))
 
 
-def _transposed(leaf) -> Tuple[int, ...]:
-    """Major-to-minor order of a stacked matrix (..., in, out) that lies
-    transposed: ``in`` — the axis a product contracts — minor."""
-    n = leaf.ndim
-    return tuple(range(n - 2)) + (n - 1, n - 2)
+#: The layouts a family may name for a stacked leaf (..., in, out) of
+#: its parameters (its ``DEVICE_LAYOUT``, ``models/__init__.py``), as
+#: the major-to-minor order of a leaf of ``n`` axes. ``transposed``:
+#: ``in`` — the axis a product contracts — minor. ``row_major``:
+#: ``out`` minor — what a leaf has wherever the backend does not choose
+#: otherwise, which the TPU does for a last axis that is no multiple of
+#: its 128 lanes (granite's ``in_proj``, 8,512 wide, lies transposed by
+#: default: PERF.md, PR 49).
+LAYOUTS: Dict[str, Callable[[int], Tuple[int, ...]]] = {
+    "transposed": lambda n: tuple(range(n - 2)) + (n - 1, n - 2),
+    "row_major": lambda n: tuple(range(n)),
+}
 
 
-def _lies_transposed(leaf) -> bool:
-    """Does ``leaf`` (an array on a device, or the description of one)
-    say of itself that it lies as :func:`_transposed` has it?"""
+def _order(leaf) -> Optional[Tuple[int, ...]]:
+    """The major-to-minor order ``leaf`` (an array on a device, or the
+    description of one) says of itself that it lies in; None where it
+    says nothing (a description that leaves the layout to the
+    backend)."""
     layout = getattr(getattr(leaf, "format", None), "layout", None)
     order = getattr(layout, "major_to_minor", None)
-    return order is not None and tuple(order) == _transposed(leaf)
+    return None if order is None else tuple(order)
+
+
+def _lies(leaf, how: str) -> bool:
+    """Does ``leaf`` lie as ``LAYOUTS[how]`` has it?"""
+    return _order(leaf) == LAYOUTS[how](leaf.ndim)
 
 
 def lay_params(fam, params) -> Dict[str, int]:
-    """Lay the stacked leaves that ``fam``'s decode step wants
-    transposed (its ``DECODE_TRANSPOSED``, ``models/__init__.py``) in
-    that layout on the device, ONCE, and say what was laid:
-    ``{"leaves", "bytes"}``.
+    """Lay the stacked leaves that ``fam`` names a layout for (its
+    ``DEVICE_LAYOUT``, ``models/__init__.py``: leaf -> a name of
+    :data:`LAYOUTS`) in that layout on the device, ONCE, and say what
+    was laid: ``{"leaves", "bytes"}``.
 
     The PHYSICAL layout alone (``jax.experimental.layout``): a laid leaf
     has the shape, the dtype and the values it had, so the forward
@@ -681,25 +695,27 @@ def lay_params(fam, params) -> Dict[str, int]:
 
     The laid leaf takes the original's place IN ``params["layers"]``:
     the tree that was handed in stays whole and means what it meant,
-    and the original — 201 MB a leaf at SmolLM2's sizes, beside a pool
-    that fills the chip — is freed as soon as nobody else holds it,
-    not kept for the life of whoever built the tree. A leaf that is not
-    a plain array (int8 with its scales: ``ops/quant.is_quantized``), a
-    leaf that already lies so (a tree a second executor is built over)
-    and a family without the name pass through as the objects they
-    are. A DESCRIPTION of a leaf (``jax.ShapeDtypeStruct`` with its
-    sharding) is laid as a description."""
+    and the original — 201 MB a leaf at SmolLM2's sizes, 1.26 GB at
+    granite's, beside a pool that fills the chip — is freed as soon as
+    nobody else holds it, not kept for the life of whoever built the
+    tree. A leaf that is not a plain array (int8 with its scales:
+    ``ops/quant.is_quantized``), a leaf that already lies so (a tree a
+    second executor is built over; a ``row_major`` leaf on a backend
+    whose default that is) and a family without the table pass through
+    as the objects they are. A DESCRIPTION of a leaf
+    (``jax.ShapeDtypeStruct`` with its sharding) is laid as a
+    description."""
     import jax
     from jax.experimental.layout import Format, Layout
 
     laid = {"leaves": 0, "bytes": 0}
     layers = params.get("layers", {}) if isinstance(params, dict) else {}
-    for name in getattr(fam, "DECODE_TRANSPOSED", ()):
+    for name, how in getattr(fam, "DEVICE_LAYOUT", {}).items():
         leaf = layers.get(name)
         if getattr(leaf, "sharding", None) is None or leaf.ndim < 2:
             continue        # absent, quantized, or on no device
-        if not _lies_transposed(leaf):
-            fmt = Format(Layout(major_to_minor=_transposed(leaf)),
+        if not _lies(leaf, how):
+            fmt = Format(Layout(major_to_minor=LAYOUTS[how](leaf.ndim)),
                          leaf.sharding)
             if isinstance(leaf, jax.ShapeDtypeStruct):
                 leaf = jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
@@ -712,20 +728,24 @@ def lay_params(fam, params) -> Dict[str, int]:
     return laid
 
 
-def describe(tree, on=None):
+def describe(tree, on=None, layouts=None):
     """``tree``'s leaves as a lowering takes them
     (``jax.ShapeDtypeStruct``): shape, dtype and where each lies — the
     sharding ``on`` or, without one, the leaf's own (mesh path: the AOT
     program must be partitioned exactly like the runtime arrays) and,
-    for a leaf :func:`lay_params` laid, its layout: the compiled
+    for a leaf :func:`lay_params` laid (``layouts``: the family's
+    ``DEVICE_LAYOUT``, leaf name -> layout), its layout: the compiled
     program then takes the leaf as it lies."""
     import jax
 
-    def one(x):
-        where = on or (x.format if _lies_transposed(x)
+    layouts = layouts or {}
+
+    def one(path, x):
+        how = layouts.get(getattr(path[-1], "key", None)) if path else None
+        where = on or (x.format if how and _lies(x, how)
                        else getattr(x, "sharding", None))
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)
-    return jax.tree.map(one, tree)
+    return jax.tree_util.tree_map_with_path(one, tree)
 
 
 def _named(fn: Callable, name: str) -> Callable:
@@ -949,12 +969,13 @@ class JaxExecutor:
         else:
             self._kv_shardings = None
         self.model_cfg = model_cfg
-        #: What :func:`lay_params` laid transposed on the device:
-        #: ``get_stats()["device"]["relaid"]``.
+        #: What :func:`lay_params` laid on the device in the layouts
+        #: the family names: ``get_stats()["device"]["relaid"]``.
+        self._layouts = dict(getattr(fam, "DEVICE_LAYOUT", {}))
         self.relaid = lay_params(fam, params)
         if self.relaid["leaves"]:
-            log.info("laid %d stacked parameter leaves transposed on the "
-                     "device (%d bytes)", self.relaid["leaves"],
+            log.info("laid %d stacked parameter leaves on the device as "
+                     "their family asks (%d bytes)", self.relaid["leaves"],
                      self.relaid["bytes"])
         self.params = params
         max_pages_per_seq = max(
@@ -1627,7 +1648,7 @@ class JaxExecutor:
 
         def leaf_ident(x):
             spec = getattr(getattr(x, "sharding", None), "spec", None)
-            return (x.shape, str(x.dtype), str(spec), _lies_transposed(x))
+            return (x.shape, str(x.dtype), str(spec), _order(x))
 
         # Mesh identity: (axis names, axis sizes, dp page universes).
         # A single-chip artifact must MISS when the same model builds
@@ -1682,7 +1703,7 @@ class JaxExecutor:
             return jax.ShapeDtypeStruct(shape, dtype,
                                         sharding=self._batch_shd)
 
-        p = describe(self.params)
+        p = describe(self.params, layouts=self._layouts)
         c = describe(self._pool)
         # the batch rows of a program's prompt chunks: an operand of a
         # family that keeps row state, none of the others

@@ -78,15 +78,17 @@ A family is a module of this package that defines
   hands the fused decode kernel for a row that is not active, where
   that is not ``positions + 1`` of an empty seat's position 0 (the
   executor's ``attn_work`` counts an empty seat by it);
-- optionally ``DECODE_TRANSPOSED``: names of stacked leaves of
-  ``params["layers"]`` (layer, in, out) whose matrices the decode
-  step's products want transposed ON THE DEVICE — the contracted axis
-  minor. The executor lays each such leaf so once, when it takes the
-  parameters (``engine/executor.lay_params``: the physical layout
-  alone; shape, values and every forward function stay), and lowers
-  every serving program against what lies there; a leaf that is not a
-  plain array (int8 with scales) and a family without the name are
-  left as they are;
+- optionally ``DEVICE_LAYOUT``: stacked leaf of ``params["layers"]``
+  (layer, in, out) -> the layout its matrices want ON THE DEVICE, a
+  name of ``engine/executor.LAYOUTS``: ``"transposed"`` (the contracted
+  axis minor) or ``"row_major"`` (the output axis minor: not what the
+  TPU gives a leaf whose width is no multiple of 128 lanes). The
+  executor lays each such leaf so once, when it takes the parameters
+  (``engine/executor.lay_params``: the physical layout alone; shape,
+  values and every forward function stay), and lowers every serving
+  program against what lies there; a leaf that is not a plain array
+  (int8 with scales) and a family without the table are left as they
+  are;
 - ``routes(cfg, cache, *, batch, page_size, max_pages, decode,
   prefill_rows)``: which implementation each attention op of a program
   takes (``ops/attention.kernel_routes``'s form);

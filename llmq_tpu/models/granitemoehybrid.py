@@ -382,6 +382,19 @@ def routes(cfg: GraniteHybridConfig, cache: KVCache, *, batch: int,
     return out
 
 
+#: Stacked leaves of ``params["layers"]`` -> how their matrices want to
+#: lie on the device (``models/__init__.py``). ``in_proj`` is 8,512 =
+#: 66.5 lane tiles wide, so the TPU's own choice for the leaf puts the
+#: 2,048 axis — the one every product contracts — minor, and the decode
+#: loop, which wants the output axis minor, copied all 1.26 GB across at
+#: the start of every run of ``decode_chunk`` and ``mixed_chunk`` and
+#: held a second ``in_proj`` for the length of the loop (PERF.md,
+#: PR 49). The attention layers' three lie as ``llama``'s, for its
+#: reason.
+DEVICE_LAYOUT = {"in_proj": "row_major", "wq": "transposed",
+                 "wk": "transposed", "wv": "transposed"}
+
+
 # -- forward -------------------------------------------------------------------
 
 def _run_layers(cfg: GraniteHybridConfig, layer_fn, carry, rolled: bool):
@@ -458,14 +471,24 @@ def _attn_out(h, attn, lp: Params, i, cfg: GraniteHybridConfig):
             lp["wo"][i]).astype(jnp.float32)
 
 
-def _mamba_in(h, lp: Params, l, i, cfg: GraniteHybridConfig):
-    """Mamba layer ``i``'s norm and input projection over rows
-    ``h`` (M, D): ``(z (M, I), xBC (M, C), dt (M, H_m))``."""
+def _mamba_proj(h, lp: Params, l, i, cfg: GraniteHybridConfig, lo=0,
+                hi=None):
+    """Mamba layer ``i``'s norm over rows ``h`` (M, D) and their product
+    with columns ``lo:hi`` of its input projection ``[z | xBC | dt]``
+    (the matrix lies row-major on the device, ``DEVICE_LAYOUT``, and
+    ``z`` ends at a lane tile's edge: a product reads a column block
+    where it lies)."""
     with scope("qkv"):
         hn = _normed(h, lp["attn_norm"][l], cfg)
-        zxd = jnp.dot(hn, lp["in_proj"][i])
-        I, C = cfg.mamba_inner, cfg.conv_width
-        return zxd[:, :I], zxd[:, I:I + C], zxd[:, I + C:]
+        return jnp.dot(hn, lp["in_proj"][i, :, lo:hi])
+
+
+def _mamba_in(h, lp: Params, l, i, cfg: GraniteHybridConfig):
+    """Mamba layer ``i``'s norm and input projection over rows
+    ``h`` (M, D), ONE product: ``(z (M, I), xBC (M, C), dt (M, H_m))``."""
+    zxd = _mamba_proj(h, lp, l, i, cfg)
+    I, C = cfg.mamba_inner, cfg.conv_width
+    return zxd[:, :I], zxd[:, I:I + C], zxd[:, I + C:]
 
 
 def _mamba_out(h, y, z, lp: Params, i, cfg: GraniteHybridConfig):
@@ -511,7 +534,7 @@ def _mamba_decode(h, lp: Params, l, i, rs: RowState, active, walk,
     return h, {"ssm": ssm, "conv": conv}
 
 
-def _mamba_slices(z, xbc, dt, lp: Params, i, rs: RowState, rows, first,
+def _mamba_slices(xbc, dt, lp: Params, i, rs: RowState, rows, first,
                   lengths, cfg: GraniteHybridConfig):
     """S slices of T tokens through Mamba layer ``i``'s convolution and
     scan: ``xbc`` (S, T, C), ``dt`` (S, T, H_m) on the grid; ``rows``
@@ -519,7 +542,6 @@ def _mamba_slices(z, xbc, dt, lp: Params, i, rs: RowState, rows, first,
     last row: the leaf's last, nobody's), ``first`` (S,) whether
     the slice starts its sequence (a zero state), ``lengths`` (S,).
     Returns ``(y (S, T, I) float32, row state)``."""
-    del z
     ssm, conv = rs["ssm"], rs["conv"]
     keep = ~first[:, None, None]
     with scope("ssm_conv"):
@@ -572,7 +594,7 @@ def forward_prefill(params: Params, cfg: GraniteHybridConfig,
         h, k_pool, v_pool, rs = carry
         if kind == MAMBA:
             z, xbc, dt = _mamba_in(h, lp, l, i, cfg)
-            y, rs = _mamba_slices(z, xbc.reshape(B, T, -1),
+            y, rs = _mamba_slices(xbc.reshape(B, T, -1),
                                   dt.reshape(B, T, -1), lp, i, rs, rows,
                                   first, lengths, cfg)
             h = _mamba_out(h, y.reshape(B * T, -1), z, lp, i, cfg)
@@ -690,10 +712,18 @@ def forward_mixed(params: Params, cfg: GraniteHybridConfig,
 
     What is a row's own — both norms, the mixers' projections, the
     SwiGLU — runs over the tight rows (the gated norm, the output
-    projection and the SwiGLU a live tile at a time; the input
-    projections all S*T rows, as ``llama``'s); the convolution, the
-    scan, the KV write and the attention take the (S, T) grid, a slice
-    a row. Returns ``(dec_logits (B, V), pf_logits (S, V), cache,
+    projection and the SwiGLU a live tile at a time; the attention's
+    input projections all S*T rows, as ``llama``'s); the convolution,
+    the scan, the KV write and the attention take the (S, T) grid, a
+    slice a row. A Mamba layer's input projection is multiplied ONCE,
+    in two column blocks: ``xBC | dt`` over all S*T rows before the
+    convolution, which reads it at once, and ``z`` INSIDE the live
+    tile beside the gated norm, its only reader (the tile's norm of
+    ``h`` is made again: 256 x D values). As one product whose ``z``
+    had to outlive the convolution and the scan, XLA kept no 17 MB
+    result that long and multiplied three times a layer — 105 products
+    of the projection's size where the model has 36 (PERF.md, PR 49).
+    Returns ``(dec_logits (B, V), pf_logits (S, V), cache,
     row_state)``."""
     B = dec_tokens.shape[0]
     S = pf_lengths.shape[0]
@@ -729,17 +759,22 @@ def forward_mixed(params: Params, cfg: GraniteHybridConfig,
         h_p, h_d, k_pool, v_pool, rs = carry
         with scope("slices"):
             if kind == MAMBA:
-                z, xbc, dt = _mamba_in(h_p, lp, l, i, cfg)
-                y, rs = _mamba_slices(z, to_grid(xbc), to_grid(dt), lp, i,
-                                      rs, pf_rows, first, pf_lengths, cfg)
+                # xBC | dt before the convolution, which reads it at
+                # once; z inside the tile, beside its only reader
+                xd = _mamba_proj(h_p, lp, l, i, cfg, lo=cfg.mamba_inner)
+                y, rs = _mamba_slices(to_grid(xd[:, :cfg.conv_width]),
+                                      to_grid(xd[:, cfg.conv_width:]), lp,
+                                      i, rs, pf_rows, first, pf_lengths,
+                                      cfg)
                 mixed = grid_to_rows(
                     y, pf_starts, jnp.zeros((S * T, y.shape[-1]), y.dtype))
 
-                def out(h, y, z):
+                def out(h, y):
+                    z = _mamba_proj(h, lp, l, i, cfg, hi=cfg.mamba_inner)
                     return _mlp_block(_mamba_out(h, y, z, lp, i, cfg), lp,
                                       l, cfg)
 
-                h_p = live_rows(out, n_live, tile, h_p, mixed, z)
+                h_p = live_rows(out, n_live, tile, h_p, mixed)
             else:
                 q_t, k_t, v_t = _qkv(h_p, lp, l, i, cfg)
                 with scope("kv_write"):

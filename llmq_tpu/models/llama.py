@@ -294,17 +294,18 @@ def mixed_live_rows(tokens: int, batch: int, slices: int, width: int) -> int:
     return tile_rows(tokens, row_tile(width), slices * width, lead=batch)
 
 
-#: Stacked leaves of ``params["layers"]`` whose matrices the decode
-#: step's products want TRANSPOSED on the device (``models/__init__.py``):
-#: a 32-row product whose result is split into 64-wide heads is given
-#: its matrix with the contracted axis minor; inside the chunk
+#: Stacked leaves of ``params["layers"]`` -> how their matrices want to
+#: lie on the device (``models/__init__.py``). TRANSPOSED, for the
+#: decode step's products: a 32-row product whose result is split into
+#: 64-wide heads is given its matrix with the contracted axis minor;
+#: inside the chunk
 #: programs' decode loop that would be a transposition a layer a step,
 #: so XLA lifts it out of the loop — a copy of the whole stack at the
 #: start of every run (three 201 MB copies, 1.89 ms a run of SmolLM2's
 #: ``decode_chunk`` and ``mixed_chunk``: PERF.md, PR 47). An int8 leaf
 #: with its scales asks for none and is never laid
 #: (``engine/executor.lay_params``).
-DECODE_TRANSPOSED = ("wq", "wk", "wv")
+DEVICE_LAYOUT = {"wq": "transposed", "wk": "transposed", "wv": "transposed"}
 
 
 def init_row_state(cfg, batch: int) -> None:

@@ -922,14 +922,14 @@ def _laid_executor(cfg, params, **kw):
 
 
 class TestLaidParams:
-    """``llama.DECODE_TRANSPOSED``: the executor lays ``wq``, ``wk`` and
+    """``llama.DEVICE_LAYOUT``: the executor lays ``wq``, ``wk`` and
     ``wv`` transposed on the device once, when it takes the tree
     (``executor.lay_params``) — the physical layout alone."""
 
     NAMES = ("wq", "wk", "wv")
 
     def test_three_leaves_are_laid_and_counted(self):
-        from llmq_tpu.engine.executor import _lies_transposed
+        from llmq_tpu.engine.executor import _lies
 
         cfg, params = _laid_model()
         before = {n: np.asarray(params["layers"][n], np.float32)
@@ -939,7 +939,7 @@ class TestLaidParams:
         assert ex.relaid == {"leaves": 3, "bytes": nbytes}
         assert ex.telemetry_info()["relaid"] == ex.relaid
         for name, leaf in ex.params["layers"].items():
-            assert _lies_transposed(leaf) == (name in self.NAMES), name
+            assert _lies(leaf, "transposed") == (name in self.NAMES), name
             # shape, dtype and values are what they were
             assert leaf.shape == before[name].shape
             np.testing.assert_array_equal(np.asarray(leaf, np.float32),
@@ -973,7 +973,7 @@ class TestLaidParams:
             params = llama.init_params_quantized(jax.random.PRNGKey(0), cfg)
         else:
             cfg, params = _laid_model()
-            monkeypatch.delattr(llama, "DECODE_TRANSPOSED")
+            monkeypatch.delattr(llama, "DEVICE_LAYOUT")
         leaves = jax.tree.leaves(params)
         ex = _laid_executor(cfg, params)
         assert ex.relaid == {"leaves": 0, "bytes": 0}
@@ -988,7 +988,7 @@ class TestLaidParams:
         layout."""
         import jax
 
-        from llmq_tpu.engine.executor import _lies_transposed, describe
+        from llmq_tpu.engine.executor import _lies, describe
 
         cfg, params = _laid_model()
         ex = _laid_executor(cfg, describe(
@@ -998,8 +998,8 @@ class TestLaidParams:
                    for x in jax.tree.leaves((ex.params, ex.cache)))
         for name, fn, operands, _ in ex.programs():
             got = operands[0]["layers"]
-            assert sorted(n for n in got if _lies_transposed(
-                got[n])) == sorted(self.NAMES), name
+            assert sorted(n for n in got if _lies(
+                got[n], "transposed")) == sorted(self.NAMES), name
             fn.lower(*operands)
 
     @pytest.mark.parametrize("case", ["two-tiles-whole-full",
@@ -1030,6 +1030,58 @@ class TestLaidParams:
                 np.testing.assert_array_equal(a["pages"][name],
                                               b["pages"][name])
 
+    @pytest.mark.parametrize("served,laid", [
+        ("smollm2-1.7b-bf16", {"leaves": 3, "bytes": 603_979_776}),
+        # int8 leaves with their scales are never laid
+        ("mistral-7b-v0.3-w8kv8", {"leaves": 0, "bytes": 0}),
+        # in_proj row-major, the four attention layers' wq, wk and wv
+        ("granite-4.0-h-micro-bf16", {"leaves": 4,
+                                      "bytes": 1_305_477_120}),
+        # a family that names no leaf
+        ("kanana-2-30b-a3b-bf16", {"leaves": 0, "bytes": 0}),
+    ])
+    def test_a_served_tree_s_description_is_laid_by_its_family_s_table(
+            self, served, laid):
+        """``lay_params`` over the DESCRIPTION of each benchmark
+        configuration's parameters as served: what it lays is the
+        family's ``DEVICE_LAYOUT`` and nothing else, each leaf in the
+        order its entry names, every other leaf the object it was."""
+        import json
+        import os
+
+        import jax
+
+        from benchmark.harness import contract
+        from llmq_tpu.engine.executor import (LAYOUTS, _order, describe,
+                                              lay_params)
+        from llmq_tpu.models import family_of
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               served + ".json"), encoding="utf-8") as f:
+            doc = json.load(f)
+        adapter = contract.load_family(
+            contract.family_dir(contract.load_benchmark(), doc), "adapter")
+        mcfg = adapter.register(doc["server"]["model"]["name"], doc)
+        params = describe(
+            jax.eval_shape(adapter.param_builder(mcfg,
+                                                 doc["server"]["model"]),
+                           jax.random.PRNGKey(0)),
+            jax.sharding.SingleDeviceSharding(jax.devices()[0]))
+        before = dict(params["layers"])
+        fam = family_of(mcfg)
+        assert lay_params(fam, params) == laid
+        table = getattr(fam, "DEVICE_LAYOUT", {}) if laid["leaves"] else {}
+        for name, leaf in params["layers"].items():
+            if name in table:
+                assert _order(leaf) == LAYOUTS[table[name]](leaf.ndim), name
+                assert (leaf.shape, leaf.dtype) == (before[name].shape,
+                                                    before[name].dtype)
+            else:
+                assert leaf is before[name], name
+        assert lay_params(fam, params) == laid      # and nothing twice
+        assert all(params["layers"][n] is not before[n] for n in table)
+
     def test_the_streams_are_those_of_the_tree_as_it_was(self, monkeypatch):
         """A tiny engine over the laid leaves against the same engine
         built with the family's answer patched empty: token for token,
@@ -1039,7 +1091,7 @@ class TestLaidParams:
 
         def streams(lay):
             if not lay:
-                monkeypatch.setattr(llama, "DECODE_TRANSPOSED", ())
+                monkeypatch.setattr(llama, "DEVICE_LAYOUT", {})
             cfg, params = _laid_model()
             ex = _laid_executor(cfg, params)
             assert ex.relaid["leaves"] == (3 if lay else 0)

@@ -213,6 +213,108 @@ def test_a_mixed_step_carries_the_state_from_program_to_program(tiny,
     assert worst_of < TOL
 
 
+#: case -> (batch row, length, start position) of each used slice of
+#: three, 12 wide over 8-row tiles: 36 rows, so the fifth tile is moved
+#: back to end at the last row. Rows 0 and 1 decode, row 2 holds a
+#: sequence that sits this step out.
+_MIXED_PARTS = {
+    # nothing is live: the tile loops run no trip, every slice unused
+    "no-live-slice-row": [],
+    "one-row-past-a-tile-edge": [(3, 9, 0)],
+    # the moved-back last tile computes z for rows 28..31 a second time
+    "all-rows-live": [(3, 12, 0), (4, 12, 0), (5, 12, 0)],
+    "a-start-beside-a-continuation": [(3, 6, 10), (4, 5, 0)],
+    "a-tile-edge-inside-each-slice": [(5, 7, 0), (3, 8, 10), (4, 9, 0)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MIXED_PARTS))
+def test_a_mixed_step_is_its_parts(tiny, small_tile, case):
+    """One mixed step against its parts over the same pool and row
+    state: each slice through ``forward_prefill``, then the rows
+    through ``forward_decode`` — every logit the step returns, every
+    page but page 0 and every row of both row-state leaves but nobody's.
+    The mixed step multiplies a Mamba layer's ``xBC | dt`` over all S*T
+    rows and its ``z`` inside the live tiles; the parts multiply all
+    three at once."""
+    cfg, params, seq = tiny
+    plan = _MIXED_PARTS[case]
+    B, S, T = 6, 3, 12
+    bt = block_table(cfg, B)
+    cache = gm.init_kv_pages(cfg, 1 + bt.size, PAGE)
+    state = gm.init_row_state(cfg, B)
+    rng = np.random.default_rng(sorted(_MIXED_PARTS).index(case))
+
+    def draw(n):
+        return rng.integers(3, cfg.vocab_size, n, dtype=np.int32)
+
+    def prefill(cache, state, row, toks, start, width):
+        n = len(toks)
+        padded = np.zeros((1, width), np.int32)
+        padded[0, :n] = toks
+        pos = start + np.minimum(np.arange(width, dtype=np.int32), n - 1)
+        logits, cache, state = gm.forward_prefill(
+            params, cfg, jnp.asarray(padded), jnp.asarray(pos[None]),
+            jnp.asarray([n], jnp.int32), cache, jnp.asarray(bt[row:row + 1]),
+            last_only=True, row_state=state,
+            rows=jnp.asarray([row], jnp.int32))
+        return np.asarray(logits)[0], cache, state
+
+    contexts = (5, 12, 3)               # what rows 0..2 hold
+    for row, n in enumerate(contexts):
+        _, cache, state = prefill(cache, state, row, draw(n), 0, BUCKET)
+    for row, _, start in plan:
+        if start:                       # what a continuing slice follows
+            _, cache, state = prefill(cache, state, row, draw(start), 0,
+                                      BUCKET)
+    slices = [draw(n) for _, n, _ in plan]
+    tok, pos = np.zeros(B, np.int32), np.zeros(B, np.int32)
+    tok[:3], pos[:3] = draw(3), contexts
+    active = np.arange(B) < 2
+
+    ref_cache, ref_state = jax.tree.map(jnp.copy, (cache, state))
+    ref_pf = []
+    for (row, _, start), toks in zip(plan, slices):
+        logits, ref_cache, ref_state = prefill(ref_cache, ref_state, row,
+                                               toks, start, T)
+        ref_pf.append(logits)
+    ref_dec, ref_cache, ref_state = gm.forward_decode(
+        params, cfg, jnp.asarray(tok), jnp.asarray(pos), ref_cache,
+        jnp.asarray(bt), active=jnp.asarray(active), row_state=ref_state)
+
+    g_t, g_p = np.zeros((S, T), np.int32), np.zeros((S, T), np.int32)
+    lens, pf_rows = np.ones(S, np.int32), np.full(S, B, np.int32)
+    pf_bt = np.zeros((S, bt.shape[1]), np.int32)
+    for s, ((row, n, start), toks) in enumerate(zip(plan, slices)):
+        g_t[s, :n], g_p[s, :n] = toks, start + np.arange(n)
+        lens[s], pf_rows[s], pf_bt[s] = n, row, bt[row]
+    pf_tok, pf_pos, starts = pack_grid(g_t, g_p, lens, used=len(plan))
+    assert starts[-1] == sum(n for _, n, _ in plan)
+    dec, pf, cache, state = small_tile(
+        params, cfg, jnp.asarray(tok), jnp.asarray(pos), cache,
+        jnp.asarray(bt), jnp.asarray(pf_tok), jnp.asarray(pf_pos),
+        jnp.asarray(lens), jnp.asarray(starts), jnp.asarray(pf_bt),
+        dec_active=jnp.asarray(active), row_state=state,
+        pf_rows=jnp.asarray(pf_rows))
+
+    np.testing.assert_allclose(np.asarray(dec)[active],
+                               np.asarray(ref_dec)[active], atol=TOL)
+    if plan:
+        np.testing.assert_allclose(np.asarray(pf)[:len(plan)],
+                                   np.stack(ref_pf), atol=TOL)
+    for name in cache:
+        np.testing.assert_allclose(np.asarray(cache[name][:, 1:]),
+                                   np.asarray(ref_cache[name][:, 1:]),
+                                   atol=TOL, err_msg=name)
+    for name in state:
+        np.testing.assert_allclose(np.asarray(state[name][:, :B]),
+                                   np.asarray(ref_state[name][:, :B]),
+                                   atol=TOL, err_msg=name)
+        # the row that sits out keeps what it held, to the bit
+        np.testing.assert_array_equal(np.asarray(state[name][:, 2]),
+                                      np.asarray(ref_state[name][:, 2]))
+
+
 # -- (b) the chunked scan against the recurrence -------------------------------
 
 
@@ -634,16 +736,11 @@ def test_registry_refuses_with_an_error_that_names_the_setting(what, match):
 # -- (g) loading published weights --------------------------------------------
 
 
-def test_a_published_checkpoint_is_loaded(tmp_path):
-    """``import_hf_granitemoehybrid`` on a synthetic safetensors
-    checkpoint under the public tensor names (from memory of
-    ``modeling_granitemoehybrid.py``: no network here): each leaf lands
-    where the program reads it — the attention layers' and the Mamba
-    layers' stacks by their own indices, ``input_linear`` split into
-    gate and up — and the imported model is the reference."""
+def _synthetic_checkpoint(tmp_path, cfg):
+    """A safetensors checkpoint of ``cfg``'s sizes under the public
+    tensor names (from memory of ``modeling_granitemoehybrid.py``: no
+    network here), written to ``tmp_path``; returns its tensors."""
     st = pytest.importorskip("safetensors.numpy")
-    from llmq_tpu.models.checkpoint import import_hf
-    cfg = gm.granite4h_tiny(dtype=jnp.float32, max_seq_len=128)
     rng = np.random.default_rng(0)
 
     def w(*shape, scale=0.05):
@@ -679,6 +776,19 @@ def test_a_published_checkpoint_is_loaded(tmp_path):
             t[pre + "self_attn.o_proj.weight"] = w(D, cfg.n_heads
                                                    * cfg.head_dim)
     st.save_file(t, str(tmp_path / "model.safetensors"))
+    return t
+
+
+def test_a_published_checkpoint_is_loaded(tmp_path):
+    """``import_hf_granitemoehybrid`` on a synthetic safetensors
+    checkpoint: each leaf lands where the program reads it — the
+    attention layers' and the Mamba layers' stacks by their own
+    indices, ``input_linear`` split into gate and up — and the imported
+    model is the reference."""
+    from llmq_tpu.models.checkpoint import import_hf
+    cfg = gm.granite4h_tiny(dtype=jnp.float32, max_seq_len=128)
+    t = _synthetic_checkpoint(tmp_path, cfg)
+    F = cfg.ffn_dim
     params = import_hf(str(tmp_path), cfg)
     want = jax.eval_shape(lambda: gm.init_params(jax.random.PRNGKey(0), cfg))
     assert jax.tree.map(lambda x: x.shape, params) == jax.tree.map(
@@ -698,6 +808,55 @@ def test_a_published_checkpoint_is_loaded(tmp_path):
                                             dtype=np.int32)
     served, _ = serve(cfg, params, seq, (11, 20))
     assert worst(served, cfg, params, seq) < TOL
+
+
+def test_a_laid_tree_means_what_it_meant(tmp_path):
+    """An executor over the imported checkpoint lays ``in_proj``
+    row-major and the attention layers' ``wq``, ``wk``, ``wv``
+    transposed (``gm.DEVICE_LAYOUT``) IN the tree it was handed: shapes
+    and values stay, the logits are those of the tree as it was
+    imported, and a second executor over the laid tree lays nothing
+    again."""
+    from llmq_tpu.engine.executor import _lies
+    from llmq_tpu.models.checkpoint import import_hf
+    cfg = gm.granite4h_tiny(dtype=jnp.float32, max_seq_len=128)
+    _synthetic_checkpoint(tmp_path, cfg)
+    plain = import_hf(str(tmp_path), cfg)
+    params = jax.tree.map(lambda x: x, plain)
+
+    def executor(tree):
+        return JaxExecutor(cfg, tree, batch_size=2, page_size=PAGE,
+                           num_pages=96, prefill_buckets=[16, 64], eos_id=2,
+                           chunk_size=4, mixed_prefill_slices=2,
+                           mixed_slice_tokens=8)
+
+    ex = executor(params)
+    names = sorted(gm.DEVICE_LAYOUT)
+    assert names == ["in_proj", "wk", "wq", "wv"]
+    assert ex.params is params
+    assert ex.relaid == {"leaves": 4, "bytes": sum(
+        plain["layers"][n].size * 4 for n in names)}
+    for name, leaf in params["layers"].items():
+        assert _lies(leaf, "transposed") == (name in ("wq", "wk", "wv")), name
+        assert leaf.shape == plain["layers"][name].shape
+        np.testing.assert_array_equal(np.asarray(leaf),
+                                      np.asarray(plain["layers"][name]))
+    assert _lies(params["layers"]["in_proj"], "row_major")
+    # every program's description names the four leaves' layouts
+    for name, _, operands, _ in ex.programs():
+        got = operands[0]["layers"]
+        assert sorted(n for n in got
+                      if got[n].format.layout is not None) == names, name
+    seq = np.random.default_rng(2).integers(3, cfg.vocab_size, 30,
+                                            dtype=np.int32)
+    served, _ = serve(cfg, ex.params, seq, (11, 20))
+    as_imported, _ = serve(cfg, plain, seq, (11, 20))
+    np.testing.assert_allclose(served, as_imported, atol=TOL)
+    assert worst(served, cfg, params, seq) < TOL
+    laid = dict(params["layers"])
+    second = executor(params)
+    assert second.relaid == ex.relaid
+    assert all(second.params["layers"][n] is laid[n] for n in laid)
 
 
 # -- (h) the older families' programs take no row state -----------------------

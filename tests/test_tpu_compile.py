@@ -527,7 +527,7 @@ def test_smollm2_s_chunk_programs_copy_no_stacked_matrix_as_dispatched_for_v5e(
     of a 52-107 ms chunk: the whole ``device_unscoped_share`` of the
     three SmolLM2 cells, PERF.md PR 47). ``forward_decode`` with no
     loop around it and ``forward_mixed`` alone hold none. The executor
-    lays the three leaves transposed once (``llama.DECODE_TRANSPOSED``)
+    lays the three leaves transposed once (``llama.DEVICE_LAYOUT``)
     and describes them so to the lowering; with the family's answer
     empty this test finds ``[4,2048,2048]`` three times (CHANGES.md)."""
     from llmq_tpu.engine.executor import JaxExecutor, describe
@@ -737,6 +737,128 @@ def test_the_hybrid_decode_rows_copy_no_state_for_v5e(one_chip, monkeypatch,
     assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
     assert sum("tpu_custom_call" in line and "/ssm_update/" in line
                for line in compiled.as_text().splitlines()) == 9
+
+
+def _script(name="whole_copies"):
+    """``scripts/<name>.py`` as a module."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _top_level(text):
+    """``(computation, line)`` of the instructions of a compiled
+    program that run by themselves: those of a fused computation are
+    part of the fusion that calls it (a slice there is read where it
+    lies)."""
+    import re
+    inside = ""
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
+        if head is not None:
+            inside = head[1]
+        elif "fused_computation" not in inside:
+            yield inside, line
+
+
+@pytest.mark.parametrize("periods", [
+    1, pytest.param(4, marks=pytest.mark.slow, id="4-as-served")])
+@pytest.mark.parametrize("program", ["decode_chunk", "mixed_chunk"])
+def test_granite_s_chunk_programs_read_in_proj_where_it_lies_as_dispatched_for_v5e(
+        one_chip, monkeypatch, program, periods):
+    """``granite-4.0-h-micro``'s two chunk programs AS THE EXECUTOR
+    DISPATCHES THEM — 64 rows, 16 decode steps, two 512-token slices —
+    lowered by a ``JaxExecutor`` over the DESCRIPTION of the parameters
+    (its own ``lay_params`` and ``programs()``), at one period of the
+    published layer order (9 Mamba, 1 attention; tier-1) and at all
+    four (``slow``: 90 s for the mixed step).
+
+    (i) ``in_proj`` is 8,512 = 66.5 lane tiles wide, so the TPU's own
+    layout of the leaf is ``{1,2,0}`` and both programs began with a
+    transposing copy of all of it into ``{2,1,0}`` for the decode loop
+    (1.26 GB a run at full depth, and a second ``in_proj`` resident).
+    Laid row-major by the executor (``gm.DEVICE_LAYOUT``) it enters as
+    the loop reads it: no whole move of any stacked leaf or of the
+    pool, and the attention layers' ``wq``, ``wk``, ``wv`` lie as
+    ``llama``'s.
+
+    (ii) the mixed step multiplies by ``in_proj`` ONCE a Mamba layer,
+    in two column blocks — ``xBC | dt`` over the 1,024 slice rows,
+    ``z`` inside the 256-row live tile — and once more over the decode
+    rows; nothing of a product's size or of a layer's matrix is
+    rematerialised (the parent held 105 products of ``bf16[1024,8512]``
+    at 40 layers, 69 of them ``.remat``), and no layer's matrix or
+    column block is copied out of the leaf by an instruction of its own
+    (``lp["in_proj"][i][:, :I]``, two slices, stood as a 35 MB copy a
+    tile trip at 40 layers; ``[i, :, :I]`` is read in place)."""
+    import re
+
+    from llmq_tpu.engine.executor import JaxExecutor, describe
+    from llmq_tpu.models import granitemoehybrid as gm
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    n_mamba = 9 * periods
+    types = tuple(gm.ATTENTION if i % 10 == 5 else gm.MAMBA
+                  for i in range(10 * periods))
+    cfg = gm.serving_config(gm.granite_4_0_h_micro(layer_types=types,
+                                                   max_seq_len=2048))
+    params = describe(jax.eval_shape(
+        lambda: gm.init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    ex = JaxExecutor(cfg, params, batch_size=64, page_size=128,
+                     num_pages=832, prefill_buckets=[512], chunk_size=16,
+                     prefill_batch=1, mixed_prefill_slices=2,
+                     mixed_slice_tokens=512, telemetry_metrics=False)
+    assert ex.relaid == {"leaves": 4, "bytes": 2 * periods * (
+        9 * 2048 * 8512 + 2048 * 2048 + 2 * 2048 * 512)}
+    (fn, operands), = [(fn, operands)
+                       for name, fn, operands, _ in ex.programs()
+                       if name == program]
+    text = fn.lower(*operands).compile().as_text()
+
+    script = _script()
+    big = {",".join(map(str, x.shape)): "a leaf"
+           for x in jax.tree.leaves((ex.params, ex.cache, ex.row_state))
+           if x.size > 1 << 20}
+    assert f"{n_mamba},2048,8512" in big
+    # (one period's stacks — an attention layer's matrices, nine layers'
+    # convolution windows — are small enough to be PREFETCHED whole,
+    # ``copy-done`` in and out of ``S(1)``: the served depth's are not)
+    assert not [mv for mv in script.whole_moves(text, big)
+                if mv["op"] != "copy-done" or periods > 1]
+    (enters,) = set(re.findall(
+        r"= bf16\[%d,2048,8512\](\{[^ ]*\}) parameter\(" % n_mamba, text))
+    assert enters.startswith("{2,1,0:")
+    if program == "decode_chunk":
+        return
+    # nothing as large as the smaller product's result is computed twice
+    # but the row state's in-place update (a name, not a second pass)
+    again = script.rematerialised(text, 256 * 4096)
+    assert not [r for r in again if not r.startswith((
+        f"bf16[{n_mamba},65,", "f32[2,512,4352]", "bf16[1,2048,2048]"))], again
+    products = {}
+    for _, line in _top_level(text):
+        m = re.match(r"\s*(?:ROOT )?%[\w.-]+ = \(?(?:f32\[\d+\]\S*, )?"
+                     r"bf16\[(\d+),(\d+)\]\S* (\w[\w-]*)\(", line)
+        if m is None:
+            continue
+        rows, width, op = int(m[1]), int(m[2]), m[3]
+        if rows == 2048 and width in (8512, 4096, 4416):
+            assert op in ("bitcast", "get-tuple-element", "parameter"), line
+        if "/qkv/dot_general" in line and op in ("fusion", "convolution"):
+            products[rows, width] = products.get((rows, width), 0) + 1
+    # a Mamba layer: xBC | dt of the slice rows, z of a tile, the decode
+    # rows' whole width — in the mixed step and in the one period that
+    # is the decode loop's body (an attention layer's q, k, v are others)
+    for shape, n in (((1024, 4416), n_mamba), ((256, 4096), n_mamba),
+                     ((64, 8512), n_mamba + 9)):
+        assert products.get(shape) == n, (shape, products)
+    assert (1024, 8512) not in products and (1024, 4096) not in products
 
 
 # -- afmoe: window and full attention over two caches ---------------------------
@@ -1109,6 +1231,11 @@ HloModule jit_decode_chunk, is_scheduled=true
 }
 
 ENTRY %main.9 (params__layers____wq__.1: bf16[24,2048,2048], tok: s32[32]) -> s32[32,8] {
+  %params__layers____wq__.1 = bf16[24,2048,2048]{2,1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="params[\'layers\'][\'wq\']"}
+  %tok = s32[32]{0:T(256)} parameter(1)
+  %fusion.7.remat2 = bf16[1024,8512]{0,1:T(8,128)(2,1)S(1)} fusion(%copy.5, %copy.321), kind=kOutput, calls=%fused_computation.7.clone
+  %fusion.7.remat = bf16[1024,8512]{0,1:T(8,128)(2,1)S(1)} fusion(%copy.5, %copy.321), kind=kOutput, calls=%fused_computation.7.clone
+  %fusion.8.remat = bf16[32,2048]{1,0:T(8,128)(2,1)} fusion(%copy.5), kind=kLoop, calls=%fused_computation.8
   %copy.321 = bf16[24,2048,2048]{1,2,0:T(8,128)(2,1)} copy(%params__layers____wq__.1), backend_config={"flag_configs":[]}
   %transpose.2 = bf16[24,2048,2048]{2,1,0:T(8,128)(2,1)} transpose(%copy.321), dimensions={0,2,1}, metadata={op_name="jit(_decode_chunk)/decode_loop/while/body/qkv/dot_general"}
   %copy.5 = bf16[32,2048]{1,0:T(8,128)(2,1)} copy(%fusion.1)
@@ -1129,13 +1256,7 @@ def test_whole_copies_script_names_each_move_with_its_place(name, op, inside,
     asynchronous copy's end as large as a named leaf, each with the
     computation it stands in and its ``op_name``; a small copy and a
     ``copy-start`` (a tuple) are not listed."""
-    import importlib.util
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "scripts", "whole_copies.py")
-    spec = importlib.util.spec_from_file_location("whole_copies", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    moves = mod.whole_moves(_HLO, {"24,2048,2048": "params['layers']['wq']",
+    moves = _script().whole_moves(_HLO, {"24,2048,2048": "params['layers']['wq']",
                                    "3328,16,4096": "pool['k']"})
     assert [m["name"] for m in moves] == ["copy-done.3", "copy.321",
                                           "transpose.2"]
@@ -1143,3 +1264,22 @@ def test_whole_copies_script_names_each_move_with_its_place(name, op, inside,
     assert (mv["op"], mv["inside"], mv["under"]) == (op, inside, under)
     assert mv["like"] == ("pool['k']" if name == "copy-done.3"
                           else "params['layers']['wq']")
+    # a move of a parameter says how the parameter ENTERS the program
+    assert mv["enters"] == (
+        "params__layers____wq__.1 enters as "
+        "bf16[24,2048,2048]{2,1,0:T(8,128)(2,1)}" if name == "copy.321"
+        else "")
+
+
+@pytest.mark.parametrize("least,counted", [
+    (1 << 20, {"bf16[1024,8512]{0,1:T(8,128)(2,1)S(1)}": 2}),
+    (1 << 16, {"bf16[1024,8512]{0,1:T(8,128)(2,1)S(1)}": 2,
+               "bf16[32,2048]{1,0:T(8,128)(2,1)}": 1}),
+    (1 << 24, {}),
+])
+def test_whole_copies_script_counts_what_is_computed_again(least, counted):
+    """``scripts/whole_copies.rematerialised``: the instructions with
+    ``.remat`` in their name whose result holds ``least`` elements or
+    more, counted by result (how granite's mixed step showed 69
+    products of ``bf16[1024,8512]`` beside the model's 36)."""
+    assert _script().rematerialised(_HLO, least) == counted
