@@ -1414,7 +1414,7 @@ def bench_tpu_decode(model_name: str, batch: int, steps: int,
 
     # Decode: chunked program — sampling/EOS stay on device, one host
     # round-trip per `chunk` tokens (host sync latency amortized).
-    from llmq_tpu.utils.profiling import trace
+    from llmq_tpu.utils.profiling import trace, trace_dir
     positions = np.full(batch, prompt_len, np.int32)
     tokens = toks[:, -1].copy()
     temps = np.zeros(batch, np.float32)
@@ -1426,7 +1426,7 @@ def bench_tpu_decode(model_name: str, batch: int, steps: int,
     # per-call host round-trip would otherwise be billed to the device.
     h = ex.decode_chunk_start(tokens, positions, bt, temps, budgets)
     h.fetch()     # warm
-    with trace("decode"):  # LLMQ_TRACE_DIR=… captures an xprof trace
+    with trace("decode", trace_dir()):  # LLMQ_TRACE_DIR=… an xprof trace
         # Timing window excludes profiler session start/stop and
         # trace-file writes (they can cost seconds when tracing is on).
         t0 = time.perf_counter()

@@ -1,0 +1,8 @@
+"""Of the tail's mean TTFT (``harness/waits.py``: the window's requests at
+or above its 90th percentile of TTFT, the MEAN over them), the leg from
+``first_token`` (committed on the engine thread) until the tap saw the
+first token: the completion pool. ``None`` where no request of the tail
+has every mark (a request missing one is left out of all seven legs)."""
+from benchmark.harness.waits import leg
+
+read = leg("deliver")

@@ -65,6 +65,7 @@ from llmq_tpu.observability.usage import (DEFAULT_TENANT, RequestUsage,
                                           sanitize_tenant)
 from llmq_tpu.tenancy import get_tenant_registry, weighted_token_caps
 from llmq_tpu.utils.logging import get_logger
+from llmq_tpu.utils import profiling
 from llmq_tpu.utils.profiling import SpanRecorder, capture_held
 
 log = get_logger("engine")
@@ -344,12 +345,15 @@ class _InflightChunk:
     handle.fetch() returns (decode tokens, slice first-tokens)."""
 
     __slots__ = ("handle", "seqs", "budgets", "fetch_box", "pf",
-                 "dispatch_s", "dispatched_at")
+                 "dispatch_s", "dispatched_at", "chunk")
 
     def __init__(self, handle, seqs, budgets, pf=None,
                  dispatch_s: float = 0.0,
-                 dispatched_at: float = 0.0) -> None:
+                 dispatched_at: float = 0.0, chunk: int = 0) -> None:
         self.handle = handle
+        #: The serial number of the ``engine.dispatch`` that sent it:
+        #: its ``engine.fetch`` and ``engine.commit`` carry the same.
+        self.chunk = chunk
         self.seqs = seqs          # List[Optional[_Sequence]], len B
         self.budgets = budgets    # np.ndarray (B,) int32
         self.fetch_box = None
@@ -375,27 +379,40 @@ class _CompletionPool:
     worker), so per-request token order — and tokens-before-done — are
     preserved at any worker count."""
 
-    def __init__(self, workers: int, name: str) -> None:
+    def __init__(self, workers: int, name: str,
+                 spans: SpanRecorder) -> None:
         self._qs: List[queue.Queue] = [queue.Queue()
                                        for _ in range(max(1, workers))]
         self._threads: List[threading.Thread] = []
         for i, q in enumerate(self._qs):
-            t = threading.Thread(target=self._loop, args=(q,),
-                                 name=f"completion-{i}-{name}",
-                                 daemon=True)
+            t = threading.Thread(
+                target=self._loop,
+                args=(q, spans.loop(f"completion.{i}.{name}")),
+                name=f"completion-{i}-{name}", daemon=True)
             t.start()
             self._threads.append(t)
 
-    def _loop(self, q: queue.Queue) -> None:
-        while True:
-            fn = q.get()
-            if fn is None:
-                return
-            try:
-                fn()
-            except Exception:  # noqa: BLE001 — a broken consumer must
-                # not kill the worker; the next request's jobs still run
-                log.exception("completion job failed")
+    def _loop(self, q: queue.Queue, watch: profiling.LoopWatch) -> None:
+        """One beat a job; the wait for the next job has no bound, so
+        it is a rest, not a gap. What a job was inside when it overran
+        is its ``engine.deliver`` span (a consumer's callback that
+        blocks)."""
+        watch.open()
+        try:
+            while True:
+                watch.rest()
+                fn = q.get()
+                watch.wake()
+                if fn is None:
+                    return
+                try:
+                    fn()
+                except Exception:  # noqa: BLE001 — a broken consumer
+                    # must not kill the worker; the next request's jobs
+                    # still run
+                    log.exception("completion job failed")
+        finally:
+            watch.close()
 
     def submit(self, key: str, fn) -> None:
         self._qs[hash(key) % len(self._qs)].put(fn)
@@ -521,6 +538,19 @@ class InferenceEngine:
         self._prof = getattr(executor, "spans", None)
         if self._prof is None:
             self._prof = SpanRecorder()
+        #: The engine loop's watch (utils/profiling.LoopWatch): one
+        #: beat an iteration of ``_loop``, the waits on the device as
+        #: its named waits. Opened by the loop's thread (an engine
+        #: stepped by a test or a bench keeps the waits' account
+        #: alone).
+        self._watch = self._prof.loop(f"engine.{name}")
+        #: The open ``engine.wait`` span of the idle stretch the loop
+        #: is in (None while it works), and whether its last poll was
+        #: ended by the wake event.
+        self._wait_span = None
+        self._woken = False
+        #: Serial number of the newest ``engine.dispatch``.
+        self._dispatch_serial = 0
         #: Device telemetry plane (observability/device.py): step-time
         #: decomposition, live tok/s + MFU, HBM accounting — shared by
         #: name with the executor (compile-cache side) and read live by
@@ -770,12 +800,6 @@ class InferenceEngine:
         self.row_state_rebuilds = 0
         self.row_state_declined = {"prefix": 0, "conversation": 0,
                                    "tiering": 0, "disagg": 0}
-        #: Device stall accounting (bench satellite: BENCH rate
-        #: points carry these as deltas so a poisoned latency point is
-        #: attributable): a "stall" is a device transfer that exceeded
-        #: the 5 s warning threshold in _service_while / chunk fetch.
-        self.stall_events = 0
-        self.stall_ms_total = 0.0
         #: A routed model's counters, summed over every chunk fetched
         #: (``ChunkHandle.stats``, models/deepseek_v3.py): tokens each
         #: expert received, then the experts that received any summed
@@ -1239,6 +1263,7 @@ class InferenceEngine:
         return {"recovered": recovered, "already_done": already_done}
 
     def _loop(self) -> None:
+        watch = self._watch.open()
         try:
             while not self._stop.is_set():
                 try:
@@ -1246,9 +1271,13 @@ class InferenceEngine:
                 except Exception:  # noqa: BLE001
                     log.exception("engine step failed")
                     did_work = False
+                # One beat an iteration. An iteration that only polled
+                # is judged and kept out of the median, or an idle
+                # engine would measure its poll interval.
+                load = self._load()
+                watch.beat(did_work, **load)
                 if not did_work:
-                    self._wake.wait(0.005)
-                    self._wake.clear()
+                    self._idle_poll(load)
         except BaseException:
             # A BaseException (injected chaos.EngineCrash, interpreter
             # teardown, a bug in the except path) kills this thread.
@@ -1257,6 +1286,55 @@ class InferenceEngine:
             log.exception("engine %s loop DIED — thread exiting; "
                           "supervisor recovery takes over", self.name)
             raise
+        finally:
+            self._close_wait()
+            watch.close()
+
+    def _load(self) -> Dict[str, int]:
+        """What waits and what runs, as the loop's beats and the
+        ``engine.wait`` span carry it: ``pending`` (arrived, not
+        admitted), ``active`` rows, ``inflight`` chunks. Lock-free
+        reads of three lengths and one walk over the rows."""
+        return {"pending": len(self._pending) + len(self._inbox),
+                "active": sum(1 for s in self._slots if s is not None),
+                "inflight": len(self._inflight)}
+
+    def _idle_poll(self, load: Dict[str, int]) -> None:
+        """The loop asleep: one 5 ms poll of the wake event, as the
+        watch's named wait ``idle``, under ONE ``engine.wait`` span
+        for the whole idle stretch — it opens at the first step that
+        did no work, with the load at that instant, and closes when a
+        step has work again (``_close_wait``), so an idle second is
+        one ring entry and one trace event, not two hundred. A capture
+        that begins inside a stretch splits it once, so the rest of
+        the stretch lands in the capture."""
+        span = self._wait_span
+        if span is not None and not span.annotated and capture_held():
+            self._close_wait("capture")
+            span = None
+        if span is None:
+            span = self._wait_span = self._prof.span("engine.wait", **load)
+            span.__enter__()
+        with self._watch.wait("idle"):
+            self._woken = self._wake.wait(0.005)
+        self._wake.clear()
+
+    def _close_wait(self, woke: str = "") -> None:
+        """End the idle stretch, if one is open: ``woke`` says what
+        ended it — ``arrival`` (a submit set the wake event), ``ready``
+        (the event was set with nothing in the inbox: the tiering
+        plane's ``on_ready``), ``timeout`` (a poll ran out and the next
+        step found work by itself), ``stop``, ``capture``."""
+        span = self._wait_span
+        if span is None:
+            return
+        self._wait_span = None
+        if not woke:
+            woke = ("stop" if self._stop.is_set() else
+                    "timeout" if not self._woken else
+                    "arrival" if self._inbox else "ready")
+        span.note(woke=woke)
+        span.__exit__(None, None, None)
 
     @property
     def _chunk_inflight(self) -> Optional[_InflightChunk]:
@@ -1309,6 +1387,8 @@ class InferenceEngine:
             self._expire_pins()
             self._set_gauges()
             return False
+        if self._wait_span is not None:
+            self._close_wait()
         with self._prof.span("engine.step"):
             return self._step()
 
@@ -1518,7 +1598,7 @@ class InferenceEngine:
             newly, self._inbox = self._inbox, []
         if not newly and not (self._pending and self.tier_max_wait):
             return              # no arrival, nobody to promote
-        with self._prof.span("engine.ingest", arrivals=len(newly)):
+        with self._prof.span("engine.ingest"):
             now = self._clock.now()
             for seq in newly:
                 seq.arrival = now
@@ -1599,7 +1679,7 @@ class InferenceEngine:
     def _admit(self) -> bool:
         if not self._pending:
             return False
-        with self._prof.span("engine.admit", pending=len(self._pending)):
+        with self._prof.span("engine.admit"):
             return self._admit_pending()
 
     def _admit_pending(self) -> bool:
@@ -2259,7 +2339,7 @@ class InferenceEngine:
             # the decode program (budget-bounded) instead of dedicated
             # bucket programs that would stall it for the whole bucket.
             return reaped
-        with self._prof.span("engine.prefill_advance", seqs=len(cands)):
+        with self._prof.span("engine.prefill_advance"):
             self._dispatch_prefill_buckets(cands, decode_active)
         return True
 
@@ -2293,6 +2373,9 @@ class InferenceEngine:
             chunk_len = buckets[-1] if buckets else len(seq.todo_ids)
             chunk = seq.todo_ids[:chunk_len]
             seq.todo_ids = seq.todo_ids[chunk_len:]
+            if not seq.todo_ids:
+                seq.handle.marks.setdefault("prefill_last_dispatched",
+                                            time.perf_counter())
             work.append((seq, chunk))
 
         handles: List = [None] * len(work)
@@ -2372,13 +2455,13 @@ class InferenceEngine:
             fetch = lambda: gather(handles)              # noqa: E731
         else:
             fetch = lambda: [int(np.asarray(h)) for h in handles]  # noqa: E731
-        with self._prof.span("engine.resolve", seqs=len(pending)):
+        with self._prof.span("engine.resolve"):
             # Offload the blocking transfer so arrivals keep being
             # admitted during the wait (same pattern as chunk fetches
             # — without this, resolve waits of ~chunk+RTT showed up as
             # 170-240 ms realtime queue_ms tails).
             box = self._offload_fetch(fetch, lane="resolve")
-            self._service_while(box["ev"])
+            self._service_while(box["ev"], "resolve")
             if box["err"] is not None:
                 raise box["err"]
             vals = box["out"]
@@ -2683,9 +2766,12 @@ class InferenceEngine:
         layer's call (``executor.scan_work``: the grid's steps a head
         block and those under a slice's length, by these lengths) only
         while a capture is held. The same quantities accumulate for
-        ``get_stats()``."""
+        ``get_stats()``. ``chunk`` is the dispatch's serial number:
+        the ``engine.fetch`` and ``engine.commit`` of the chunk it sent
+        carry the same, so a reader of a capture joins the three."""
         self.device_steps += steps
         self.row_steps += row_steps
+        self._dispatch_serial += 1
         name_fn = getattr(self.executor, "program_name", None)
         slice_fn = getattr(self.executor, "slice_tokens", None)
         slice_tokens = (0 if slice_fn is None else slice_fn(
@@ -2699,7 +2785,8 @@ class InferenceEngine:
             "inflight": len(self._inflight) + (1 if chunk else 0),
             "context_tokens": context_tokens,
             "prefill_tokens": prefill_tokens,
-            "slice_tokens": slice_tokens}
+            "slice_tokens": slice_tokens,
+            "chunk": self._dispatch_serial}
         if self._window and chunk and rows:
             counts.update(self._window_counts())
         if capture_held():
@@ -2954,7 +3041,8 @@ class InferenceEngine:
             self._m("decode_steps").inc()
         infl_next = _InflightChunk(handle, seqs, budgets, pf=infl_pf,
                                    dispatch_s=dispatch_s,
-                                   dispatched_at=now)
+                                   dispatched_at=now,
+                                   chunk=self._dispatch_serial)
         self._start_fetch(infl_next)
         return infl_next
 
@@ -3064,35 +3152,42 @@ class InferenceEngine:
                 box["err"] = e
             box["ev"].set()
 
-    def _service_while(self, ev: threading.Event) -> None:
+    def _service_while(self, ev: threading.Event, wait: str) -> None:
         """Service arrivals while a transfer completes: ingest +
         free-slot admission + the admitted wave's first prefill bucket
         (all non-blocking dispatches). While a chunk is in flight the
         usual guards defer shedding/preemption; with NO chunk in
         flight (resolve-only waits) the admission path MAY shed
         mid-prefill sequences — callers holding snapshots must
-        re-validate them after the wait (see _resolve_prefills)."""
-        t0 = time.perf_counter()
-        warned = False
-        while not ev.wait(0.002):
-            if self._wake.is_set():
-                self._wake.clear()
-                self._ingest()
-                if self._admit():
-                    self._advance_prefill()
-            if not warned and time.perf_counter() - t0 > 5.0:
-                # A rare multi-second device stall poisons a whole
-                # latency run — make it attributable after the fact.
-                log.warning("device transfer stalled > 5 s "
-                            "(engine %s keeps servicing arrivals)",
-                            self.name)
-                warned = True
-        if warned:
-            # Counted, not just logged: BENCH rate points carry the
-            # deltas (stall_events / stall_ms_total) so a poisoned p99
-            # is attributable in the artifact itself.
-            self.stall_events += 1
-            self.stall_ms_total += (time.perf_counter() - t0) * 1e3
+        re-validate them after the wait (see _resolve_prefills).
+
+        The whole wait is the loop watch's named wait ``wait``
+        (``fetch`` / ``resolve``): a device transfer that stalls shows
+        as the loop's overrun, ``inside`` this wait, in the one
+        ``loop_stall`` line."""
+        with self._watch.wait(wait):
+            while not ev.wait(0.002):
+                if self._wake.is_set():
+                    self._wake.clear()
+                    self._ingest()
+                    if self._admit():
+                        self._advance_prefill()
+
+    def _stalls(self) -> "tuple[int, float]":
+        """(overruns of the loop that fell inside a wait on the
+        device, their seconds): the watch's account of ``fetch`` and
+        ``resolve``."""
+        waits = [self._watch.wait(n) for n in ("fetch", "resolve")]
+        return (sum(w.stalls for w in waits),
+                sum(w.stall_s for w in waits))
+
+    @property
+    def stall_events(self) -> int:
+        return self._stalls()[0]
+
+    @property
+    def stall_ms_total(self) -> float:
+        return self._stalls()[1] * 1e3
 
     # -- completion offload (docs/performance.md "Async pipeline") ------------
 
@@ -3105,7 +3200,7 @@ class InferenceEngine:
         p = self._completion
         if p is None:
             p = self._completion = _CompletionPool(
-                self._completion_workers, self.name)
+                self._completion_workers, self.name, self._prof)
         return p
 
     def _drain_completions(self) -> bool:
@@ -3256,24 +3351,18 @@ class InferenceEngine:
         pre-reconcile admission pass)."""
         box = infl.fetch_box
         if box is None:
-            t0 = time.perf_counter()
-            with self._prof.span("engine.fetch"):
+            with self._prof.span("engine.fetch", chunk=infl.chunk), \
+                    self._watch.wait("fetch"):
                 out, device_s, readback_s, overlapped_s = \
                     self._telemetry.timed_fetch(
                         infl.handle, dispatched_at=infl.dispatched_at)
-            dt = time.perf_counter() - t0
-            if dt > 5.0:          # same stall threshold as _service_while
-                log.warning("blocking chunk fetch stalled %.1f s "
-                            "(engine %s)", dt, self.name)
-                self.stall_events += 1
-                self.stall_ms_total += dt * 1e3
         else:
-            with self._prof.span("engine.fetch"):
-                self._service_while(box["ev"])
+            with self._prof.span("engine.fetch", chunk=infl.chunk):
+                self._service_while(box["ev"], "fetch")
             if box["err"] is not None:
                 raise box["err"]
             out, device_s, readback_s, overlapped_s = box["out"]
-        with self._prof.span("engine.commit") as span:
+        with self._prof.span("engine.commit", chunk=infl.chunk) as span:
             self._commit_chunk(infl, out, device_s, readback_s,
                                overlapped_s)
             self._note_moe(getattr(infl.handle, "stats", None), span)
@@ -3518,7 +3607,8 @@ class InferenceEngine:
                 seqs[seq.slot] = seq
             infl = _InflightChunk(handle, seqs, budgets,
                                   dispatch_s=dispatch_s,
-                                  dispatched_at=now)
+                                  dispatched_at=now,
+                                  chunk=self._dispatch_serial)
             self._inflight.append(infl)
             self._note_dispatch_depth(len(self._inflight))
             self._start_fetch(infl)
@@ -3648,7 +3738,8 @@ class InferenceEngine:
                 seqs[seq.slot] = seq
             infl = _InflightChunk(handle, seqs, budgets, pf=infl_pf,
                                   dispatch_s=dispatch_s,
-                                  dispatched_at=time.perf_counter())
+                                  dispatched_at=time.perf_counter(),
+                                  chunk=self._dispatch_serial)
             self._inflight.append(infl)
             self._note_dispatch_depth(len(self._inflight))
             self._start_fetch(infl)
@@ -3741,6 +3832,11 @@ class InferenceEngine:
             seq.pos = seq.todo_pos
             seq.pf_tokens_run += len(sl)
             seq.written_ids.extend(sl)
+            if not seq.todo_ids:
+                # The FINAL slice is handed to a chunk: what is left of
+                # TTFT is that chunk's run and its reconcile.
+                seq.handle.marks.setdefault("prefill_last_dispatched",
+                                            time.perf_counter())
             infl_pf.append((seq, len(sl), not seq.todo_ids))
         if self._metrics:
             packed = sum(n for _, n, _ in infl_pf)
@@ -3957,6 +4053,7 @@ class InferenceEngine:
                   for stage in ("admitted", "kv_promote_start",
                                 "kv_promote_done", "handoff_claim_start",
                                 "handoff_claim_done", "prefill_start",
+                                "prefill_last_dispatched",
                                 "prefill_done", "first_token",
                                 "first_token_out", "preempted",
                                 "decode_done")
@@ -4162,6 +4259,12 @@ class InferenceEngine:
             "prefill_tps_ewma": (round(self.prefill_tps_ewma, 1)
                                  if self.prefill_tps_ewma else None),
             "profile": self._prof.summary(),
+            # Every long-lived loop of the process (this engine's, the
+            # workers', the completion threads'): beats, the running
+            # median gap, the longest gap and what it was inside, the
+            # overruns, the named waits (docs/observability.md "Loops
+            # and stalls").
+            "loops": profiling.loops(),
             # Device telemetry plane (docs/observability.md "Device
             # telemetry"): step decomposition, live tok/s + MFU, HBM,
             # compile-cache state.

@@ -73,11 +73,17 @@ BOOT_STAGES = ("provision", "artifact", "weights", "compile", "warmup",
 #: close "admission" (the admit→prefill-dispatch gap is still the
 #: engine's admission machinery), ``prefill_done``/``first_token``
 #: both close "prefill" (sampling the first token IS prefill work).
+#: ``prefill_last_dispatched`` (the prompt's FINAL slice handed to a
+#: chunk) is a cut INSIDE "prefill": the segment keeps its name and its
+#: sum, and ``decompose`` also says how it divides (``prefill_cut``) —
+#: the benchmark's ``ttft_tail_slices_ms`` / ``ttft_tail_reconcile_ms``
+#: by the same mark.
 _BOUNDARIES: Tuple[Tuple[str, str], ...] = (
     ("scheduled", "queue_wait"),
     ("dispatched", "dispatch"),
     ("admitted", "admission"),
     ("prefill_start", "admission"),
+    ("prefill_last_dispatched", "prefill"),
     ("prefill_done", "prefill"),
     ("first_token", "prefill"),
     ("decode_done", "decode"),
@@ -92,6 +98,7 @@ _PHASE_AFTER = {
     "dispatched": "admission",
     "admitted": "prefill",
     "prefill_start": "prefill",
+    "prefill_last_dispatched": "prefill",
     "prefill_done": "decode",
     "first_token": "decode",
     "decode_done": "completion",
@@ -111,7 +118,14 @@ def decompose(tl: Timeline) -> Optional[Dict[str, Any]]:
          "total_s": float,                 # == sum(segments) exactly
          "dominant": str,                  # argmax segment
          "priority": str, "endpoint": str,
-         "outcome": "completed"|"failed"|"cancelled"}
+         "outcome": "completed"|"failed"|"cancelled",
+         "prefill_cut": {"slices_s", "reconcile_s"}}  # with the mark
+
+    ``prefill_cut`` divides the span from ``prefill_start`` to the
+    first token at ``prefill_last_dispatched``: the slices taking their
+    turns in chunks, then the final chunk's run and reconcile. It is a
+    reading of the "prefill" segment, not a segment: nothing is added
+    to the sum.
 
     Conservation is by construction: the base intervals tile
     ``[min event ts, max terminal ts]`` and sub-span carving moves
@@ -193,7 +207,7 @@ def decompose(tl: Timeline) -> Optional[Dict[str, Any]]:
                 segments["decode_stall"] = stall
     total = t_end - t0
     dominant = max(segments, key=segments.get) if segments else "completion"
-    return {
+    out = {
         "segments": segments,
         "total_s": total,
         "dominant": dominant,
@@ -201,6 +215,11 @@ def decompose(tl: Timeline) -> Optional[Dict[str, Any]]:
         "endpoint": tl.label("endpoint", tl.label("engine", "local")),
         "outcome": outcome,
     }
+    a, cut = ts.get("prefill_start"), ts.get("prefill_last_dispatched")
+    b = ts.get("first_token", ts.get("prefill_done"))
+    if a is not None and cut is not None and b is not None and a <= cut <= b:
+        out["prefill_cut"] = {"slices_s": cut - a, "reconcile_s": b - cut}
+    return out
 
 
 class CriticalPathAnalyzer:

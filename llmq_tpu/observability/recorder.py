@@ -59,7 +59,8 @@ TERMINAL_STAGES = ("completed", "failed", "cancelled")
 STAGE_ORDER = ("enqueued", "received", "scheduled", "dispatched",
                "admitted", "kv_promote_start", "handoff_claim_start",
                "kv_promote_done", "handoff_claim_done",
-               "prefill_start", "prefill_done", "first_token",
+               "prefill_start", "prefill_last_dispatched", "prefill_done",
+               "first_token",
                "first_token_out", "preempted", "kv_publish", "decode_done",
                "failover", "retry_scheduled", "completed", "failed",
                "cancelled")

@@ -44,6 +44,7 @@ from llmq_tpu.queueing.delayed_queue import DelayedQueue
 from llmq_tpu.queueing.queue_manager import QueueManager
 from llmq_tpu.utils.logging import (bind_log_context, get_logger,
                                     reset_log_context)
+from llmq_tpu.utils.profiling import SpanRecorder
 
 log = get_logger("worker")
 
@@ -310,6 +311,13 @@ class Worker:
         self.on_permanent_failure = on_permanent_failure
         self.stats = WorkerStats()
         self._sem = threading.Semaphore(self.wconfig.max_concurrent)
+        #: The dispatch loop's watch (utils/profiling.LoopWatch): one
+        #: beat a tick, the wait for a concurrency slot as its named
+        #: wait ``sem`` — a loop that blocks there while every pool
+        #: thread waits on a handle stops taking messages, and the
+        #: ``loop_stall`` line says so. (The worker opens no span: its
+        #: recorder's ring stays empty.)
+        self._watch = SpanRecorder(capacity=0).loop(f"worker.{name}")
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._pool: Optional[_DispatchPool] = None
@@ -364,11 +372,18 @@ class Worker:
     # -- processing (worker.go:109-159) --------------------------------------
 
     def _process_loop(self) -> None:
-        while not self._stop.wait(self.wconfig.process_interval):
-            try:
-                self.process_batch()
-            except Exception:  # noqa: BLE001
-                log.exception("worker %s batch failed", self.name)
+        watch = self._watch.open()
+        try:
+            while not self._stop.wait(self.wconfig.process_interval):
+                popped = 0
+                try:
+                    popped = self.process_batch()
+                except Exception:  # noqa: BLE001
+                    log.exception("worker %s batch failed", self.name)
+                watch.beat(popped=popped,
+                           sem_free=getattr(self._sem, "_value", -1))
+        finally:
+            watch.close()
 
     def process_batch(self) -> int:
         """Pop up to max_batch_size in priority order and dispatch.
@@ -379,7 +394,8 @@ class Worker:
             self.delayed_queue.run_due_once()
         batch = self.manager.drain_in_priority_order(self.wconfig.max_batch_size)
         for msg in batch:
-            self._sem.acquire()
+            with self._watch.wait("sem"):
+                self._sem.acquire()
             pool = self._pool
             if pool is not None:
                 try:
