@@ -157,8 +157,11 @@ def routes(cfg, cache, *, batch: int, page_size: int, max_pages: int,
            decode: bool = False, prefill_rows: int = 0) -> Dict[str, str]:
     """Which implementation each attention op of one serving program
     takes (``ops/attention.kernel_routes``'s form): the latent decode
-    kernel with its plan, and XLA for prefill."""
-    from llmq_tpu.ops.pallas.latent_decode import pages_per_chunk
+    kernels with their plans (the rows of its page a decode row's write
+    moves: its sublane tile, or the page where that is no whole tiles),
+    and XLA for prefill."""
+    from llmq_tpu.ops.pallas.latent_decode import (
+        pages_per_chunk, write_rows)
     out: Dict[str, str] = {}
     if prefill_rows:
         out["prefill_write"] = out["prefill_attention"] = "xla"
@@ -166,8 +169,12 @@ def routes(cfg, cache, *, batch: int, page_size: int, max_pages: int,
         use, interp = _route(cfg, page_size)
         tag = f"pallas{'-interpret' if interp else ''}:"
         chunk = pages_per_chunk(page_size, max_pages) * page_size
-        out["decode_write"] = (tag + "_latent_write_kernel" if use
-                               else "xla")
+        out["decode_write"] = "xla"
+        if use:
+            rows = write_rows(cache["ckv"])
+            out["decode_write"] = (
+                f"{tag}_latent_write_kernel("
+                f"{'tile' if rows < page_size else 'page'}_rows={rows})")
         out["decode_attention"] = (
             f"{tag}_latent_decode_kernel(rows=1,chunk_tokens={chunk})"
             if use else "xla")

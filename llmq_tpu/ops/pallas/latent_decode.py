@@ -25,11 +25,21 @@ logits are SELECTED to -1e30 and the rows of a chunk past the
 sequence's end are zeroed before the second product (0 x NaN of stale
 scratch is NaN).
 
-``latent_write_pallas`` — ``kv_write.py``'s row kernel for one pool:
-read the row's page, select the new row into its slot, write the page
-back, double-buffered over the rows, the pool aliased in place. Live
-rows target distinct pages (the decode invariant); rows that are not
-live all target reserved page 0.
+``latent_write_pallas`` — one new row a sequence, the pool aliased in
+place. A row moves the SUBLANE TILE of its page that holds its slot
+``s`` — rows ``[s // R * R, s // R * R + R)``, ``R`` the pool dtype's
+rows a packed tile (``tile_rows``: 16 of bf16), the smallest aligned
+piece a DMA moves: 20 KB each way at W = 640 where the whole page is
+164 KB — read into a scratch slot, the new row selected in, written
+back. The rows go round a ring of ``2 * WRITE_AHEAD`` slots: a read is
+started ``WRITE_AHEAD`` rows before its merge and a write-back is
+waited for when its slot is next read into, so a DMA's latency is paid
+once a call and not once a row. A pool whose ``page_size`` is no
+multiple of ``R`` moves the whole page instead (``write_rows``; no
+served configuration is such a pool). Live rows target distinct pages
+(the decode invariant); rows that are not live all target reserved
+page 0, which is nobody's: two of them in one tile may lose each
+other's bytes there.
 """
 
 from __future__ import annotations
@@ -46,47 +56,76 @@ NEG = -1e30
 #: outweigh its fixed cost, small enough that two slots of it are a
 #: megabyte of VMEM at W = 640.
 CHUNK_TOKENS = 512
+#: Reads the decode write keeps in flight ahead of the row it merges
+#: (twice as many scratch slots: a slot's write-back has that many rows
+#: to land before the slot is read into again). The best of 1 .. 64
+#: tried on the chip at 128 and at 64 rows (PERF.md §6, PR 51).
+WRITE_AHEAD = 16
 
 
 def pages_per_chunk(page_size: int, max_pages: int) -> int:
     return max(1, min(max_pages, CHUNK_TOKENS // page_size))
 
 
+def tile_rows(dtype) -> int:
+    """Rows of one packed sublane tile: eight sublanes of 32 bits (16
+    rows of bf16), the smallest aligned piece of a page a DMA moves."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def write_rows(pool) -> int:
+    """Rows of its page a decode row's write moves: the sublane tile
+    that holds the new row where the page is whole tiles, else the
+    page."""
+    page_size, R = pool.shape[2], tile_rows(pool.dtype)
+    return R if page_size % R == 0 else page_size
+
+
 def _latent_write_kernel(page_of_ref, slot_of_ref, layer_ref, new_ref,
-                         pool_hbm, pool_out, page, sem, *, n_rows: int,
-                         page_size: int):
+                         pool_hbm, pool_out, piece, sem, *, n_rows: int,
+                         rows: int, ahead: int):
+    """Row ``i`` reads the ``rows`` rows of its page around its slot
+    into scratch slot ``i % (2 * ahead)``, selects the new row in, and
+    writes them back. ``ahead`` reads are in flight ahead of the row
+    being merged, and a write-back is waited for when its scratch slot
+    is next read into, ``ahead`` rows later (at the end, what is still
+    on its way)."""
     lyr = layer_ref[0]
-    n_pad = new_ref.shape[0]
+    slots = 2 * ahead
 
-    def fetch(i, slot):
-        @pl.when(i < n_rows)
-        def _():
-            pltpu.make_async_copy(pool_hbm.at[lyr, page_of_ref[i]],
-                                  page.at[slot], sem.at[slot]).start()
+    def copy(i, back: bool):
+        """Row ``i``'s piece of its page into its scratch slot, or
+        ``back`` to the pool."""
+        k = jax.lax.rem(i, slots)
+        lo = pl.multiple_of(slot_of_ref[i] // rows * rows, rows)
+        there = (pool_out if back else pool_hbm).at[
+            lyr, page_of_ref[i], pl.ds(lo, rows)]
+        src, dst = (piece.at[k], there) if back else (there, piece.at[k])
+        return pltpu.make_async_copy(src, dst, sem.at[int(back), k])
 
-    fetch(0, 0)
+    for i in range(min(ahead, n_rows)):
+        copy(i, False).start()
 
     def body(i, _):
-        slot = jax.lax.rem(i, 2)
-        fetch(i + 1, 1 - slot)
-        p, s = page_of_ref[i], slot_of_ref[i]
-        pltpu.make_async_copy(pool_hbm.at[lyr, p], page.at[slot],
-                              sem.at[slot]).wait()
-        # Row i of the (N_pad, W) block by a masked sum (no dynamic
-        # sublane indexing), selected into slot s of the page.
-        rows = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)
-        row = jnp.sum(new_ref[...].astype(jnp.float32)
-                      * (rows == i).astype(jnp.float32), axis=0,
-                      keepdims=True).astype(page.dtype)
-        sl = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
-        page[slot] = jnp.where(sl != s, page[slot], row)
-        out = pltpu.make_async_copy(page.at[slot], pool_out.at[lyr, p],
-                                    sem.at[slot])
-        out.start()
-        out.wait()
+        @pl.when(i + ahead < n_rows)
+        def _():
+            @pl.when(i >= ahead)
+            def _():
+                copy(i - ahead, True).wait()
+            copy(i + ahead, False).start()
+
+        copy(i, False).wait()
+        k = jax.lax.rem(i, slots)
+        row = new_ref[pl.ds(i, 1), :].astype(piece.dtype)
+        at = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        piece[k] = jnp.where(at == jax.lax.rem(slot_of_ref[i], rows), row,
+                             piece[k])
+        copy(i, True).start()
         return 0
 
     jax.lax.fori_loop(0, n_rows, body, 0)
+    for i in range(max(0, n_rows - slots), n_rows):
+        copy(i, True).wait()
 
 
 def latent_write_pallas(pool: jnp.ndarray, new: jnp.ndarray,
@@ -94,24 +133,31 @@ def latent_write_pallas(pool: jnp.ndarray, new: jnp.ndarray,
                         layer: jnp.ndarray | int = 0, *,
                         interpret: bool = False) -> jnp.ndarray:
     """Write ``new`` (N, W), one row a sequence, into layer ``layer`` of
-    ``pool`` (L, P, page_size, W) at ``(page_of, slot_of)``, in place.
-    Live rows must target distinct pages."""
+    ``pool`` (L, P, page_size, W) at ``(page_of, slot_of)``, in place:
+    what ``pool.at[layer, page_of, slot_of].set(new)`` stores, by moving
+    ``write_rows(pool)`` rows of each page. Live rows must target
+    distinct pages; page 0 is nobody's (rows aimed at one tile of it may
+    lose one another's bytes)."""
     _L, _P, page_size, W = pool.shape
     N = new.shape[0]
     if W % 128 or page_size % 8:
         raise ValueError(f"latent pool needs W % 128 == 0 and "
                          f"page_size % 8 == 0, got {W}, {page_size}")
+    rows = write_rows(pool)
     n_pad = -(-N // 8) * 8
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(1,),
         in_specs=[pl.BlockSpec((n_pad, W), lambda c, *_: (0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[pltpu.VMEM((2, page_size, W), pool.dtype),
-                        pltpu.SemaphoreType.DMA((2,))])
+        scratch_shapes=[pltpu.VMEM((2 * WRITE_AHEAD, rows, W), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2 * WRITE_AHEAD))])
+    # The new rows as float32 (every pool dtype's values are exact
+    # there): a row is then ONE dynamic sublane load, which a packed
+    # dtype does not have.
     return pl.pallas_call(
-        functools.partial(_latent_write_kernel, n_rows=N,
-                          page_size=page_size),
+        functools.partial(_latent_write_kernel, n_rows=N, rows=rows,
+                          ahead=WRITE_AHEAD),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={4: 0},
@@ -120,7 +166,8 @@ def latent_write_pallas(pool: jnp.ndarray, new: jnp.ndarray,
         interpret=interpret,
     )(page_of.astype(jnp.int32), slot_of.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.pad(new, ((0, n_pad - N), (0, 0))).astype(pool.dtype), pool)
+      jnp.pad(new.astype(pool.dtype).astype(jnp.float32),
+              ((0, n_pad - N), (0, 0))), pool)
 
 
 def _latent_decode_kernel(bt_ref, lens_ref, layer_ref, q_ref, pool_hbm,

@@ -194,14 +194,22 @@ def bench_latent(args, doc) -> None:
         tokens = int(seq.sum())
         least = tokens * (rank + dr) * 2 / PEAK_BYTES_PER_S * 1e6
         pool_least = tokens * W * 2 / PEAK_BYTES_PER_S * 1e6
+        # Rows of its page a row's write moves, in and out (a tree from
+        # before the plan moves the page).
+        moved = getattr(ld, "write_rows", lambda pool: ps)(pool)
+        write_least = 2 * B * moved * W * 2 / PEAK_BYTES_PER_S * 1e6
         results.append({"lens": spec, "rows": rows, "tokens": tokens,
                         "us_per_call": us, "write_us_per_call": write_us,
+                        "write_rows_moved": moved,
+                        "write_least_us": write_least,
                         "latent_least_us": least,
                         "latent_roofline_pct": 100 * least / us,
                         "pool_roofline_pct": 100 * pool_least / us,
                         "finite": finite})
         print(f"  lens {spec}: attention {us:,.1f} us/call, write "
-              f"{write_us:,.1f}; latents at peak {least:,.1f} us = "
+              f"{write_us:,.1f} ({B} rows x {moved} of {ps} rows of a "
+              f"page in and out: {write_least:,.1f} us at peak); "
+              f"latents at peak {least:,.1f} us = "
               f"{100 * least / us:.1f} % (the pool's padded rows: "
               f"{100 * pool_least / us:.1f} %)  finite={finite}",
               flush=True)

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 from llmq_tpu.ops import moe
 from llmq_tpu.ops.pallas.latent_decode import (
-    latent_decode_attention_pallas, latent_write_pallas, pages_per_chunk)
+    WRITE_AHEAD, latent_decode_attention_pallas, latent_write_pallas,
+    pages_per_chunk, tile_rows, write_rows)
 
 L, PS, W, RANK, H = 2, 16, 256, 128, 4
 MP = 40                     # 640 tokens a row: more than one 512-token chunk
@@ -105,6 +106,114 @@ def test_latent_write_kernel(n_rows):
         want[1, page_of[i], slot_of[i]] = new[i]
     assert np.array_equal(out[:, 1:], want[:, 1:])      # page 0 is trash
     assert np.array_equal(out[0], pool[0])
+
+
+R = tile_rows(np.float32)    # rows of a sublane tile: two a page here
+
+#: name -> (slots of the rows, rows that are not live, layer, dtype,
+#: page size). A row that is not live aims at page 0, each at a tile of
+#: its own (two in one tile of nobody's page may lose each other: the
+#: case after these).
+WRITE_CASES = {
+    "tile-first-row": ([0], [], 0, np.float32, PS),
+    "tile-last-row": ([R - 1], [], 0, np.float32, PS),
+    "next-tile-first-row": ([R], [], 0, np.float32, PS),
+    "page-last-row": ([PS - 1], [], 0, np.float32, PS),
+    "both-sides-of-a-tile-edge": ([R - 1, R, 0, PS - 1], [], 0,
+                                  np.float32, PS),
+    "five-rows": ([3, R, 1, PS - 1, R - 1], [], 0, np.float32, PS),
+    "thirteen-rows-layer-1": (list(range(13)), [], 1, np.float32, PS),
+    "dead-rows-beside-live": ([0, 3, R - 1, R + 1, PS - 1, 5, R],
+                              [1, 6], 0, np.float32, PS),
+    "all-dead-but-one": ([R, 2, R + 2], [1, 2], 1, np.float32, PS),
+    "more-rows-than-scratch-slots": (
+        [(7 * i) % PS for i in range(4 * WRITE_AHEAD + 5)], [9], 1,
+        np.float32, PS),
+    "bf16-sixteen-row-tiles": ([0, 15, 16, 31, 17, 8], [5], 1,
+                               jnp.bfloat16, 32),
+    "bf16-page-of-no-whole-tile": ([0, 15, 16, 23, 7], [], 1,
+                                   jnp.bfloat16, 24),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WRITE_CASES))
+def test_latent_write_kernel_holds_the_whole_pool(case):
+    """The WHOLE pool after the write is bit for bit what ``pool.at[l,
+    page_of, slot_of].set(new)`` stores: the new row in its slot, every
+    other row of its tile and of its page, every other page and every
+    other layer as they were."""
+    slots, dead, layer, dtype, ps = WRITE_CASES[case]
+    n = len(slots)
+    rng = np.random.default_rng(n + layer)
+    P = n + 3
+    pool = jnp.asarray(rng.standard_normal((L, P, ps, W)), dtype)
+    new = jnp.asarray(rng.standard_normal((n, W)), dtype)
+    page_of = 1 + rng.permutation(P - 1)[:n]
+    page_of[dead] = 0
+    slot_of = np.asarray(slots)
+    tiles = slot_of[dead] // tile_rows(dtype)
+    assert len(set(tiles)) == len(tiles)
+    assert write_rows(pool) == (ps if case.endswith("no-whole-tile")
+                                else tile_rows(dtype))
+    out = latent_write_pallas(pool, new, jnp.asarray(page_of),
+                              jnp.asarray(slot_of), layer, interpret=True)
+    want = pool.at[layer, page_of, slot_of].set(new)
+    assert out.dtype == pool.dtype
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(want, np.float32))
+    assert not np.array_equal(np.asarray(out, np.float32),
+                              np.asarray(pool, np.float32))
+
+
+def test_latent_write_kernel_page_0_is_nobody_s():
+    """Rows that are not live may share a tile of page 0: each of its
+    rows then holds what it held or a row aimed at it, and nothing else
+    of the pool moves."""
+    rng = np.random.default_rng(7)
+    n, P = 12, 8
+    pool = rng.standard_normal((L, P, PS, W)).astype(np.float32)
+    new = rng.standard_normal((n, W)).astype(np.float32)
+    page_of = np.zeros(n, np.int32)
+    page_of[[2, 9]] = [5, 3]
+    slot_of = rng.integers(0, PS, n)
+    out = np.asarray(latent_write_pallas(
+        jnp.asarray(pool), jnp.asarray(new), jnp.asarray(page_of),
+        jnp.asarray(slot_of), 1, interpret=True))
+    want = pool.copy()
+    for i in (2, 9):
+        want[1, page_of[i], slot_of[i]] = new[i]
+    assert np.array_equal(out[:, 1:], want[:, 1:])
+    assert np.array_equal(out[0], pool[0])
+    for s in range(PS):
+        aimed = [new[i] for i in range(n) if page_of[i] == 0
+                 and slot_of[i] == s]
+        assert any(np.array_equal(out[1, 0, s], r)
+                   for r in [pool[1, 0, s]] + aimed)
+
+
+@pytest.mark.parametrize("dtype,ps,plan", [
+    (np.float32, 16, "tile_rows=8"), (jnp.bfloat16, 128, "tile_rows=16"),
+    (jnp.bfloat16, 24, "page_rows=24")],
+    ids=["float32", "bf16-served-page", "bf16-no-whole-tile"])
+def test_the_routes_line_names_the_write_s_plan(monkeypatch, dtype, ps, plan):
+    """What says the tile move is in force: ``decode_write`` of the line
+    the engine logs at start names the rows of its page a row's write
+    moves, read off the pool's dtype and page size."""
+    from types import SimpleNamespace
+
+    from llmq_tpu.models import latent
+
+    class Dims(latent.LatentDims, SimpleNamespace):
+        pass
+
+    cfg = Dims(kv_lora_rank=RANK, qk_rope_head_dim=64)
+    pool = {"ckv": jnp.zeros((L, 2, ps, cfg.latent_width), dtype)}
+    geometry = dict(batch=4, page_size=ps, max_pages=4, decode=True)
+    monkeypatch.setenv("LLMQ_PALLAS", "interpret")
+    assert latent.routes(cfg, pool, **geometry)["decode_write"] == (
+        f"pallas-interpret:_latent_write_kernel({plan})")
+    monkeypatch.setenv("LLMQ_PALLAS", "0")
+    assert latent.routes(cfg, None, **geometry)["decode_write"] == "xla"
 
 
 @pytest.mark.parametrize("rows", [3, 130])

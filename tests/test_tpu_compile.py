@@ -259,6 +259,40 @@ def test_latent_kernels_compile_for_v5e(one_chip, kernel, served):
     assert len(text) < 100_000
 
 
+@pytest.mark.parametrize("served", sorted(_LATENT))
+def test_the_latent_write_holds_tiles_not_pages_for_v5e(one_chip, served):
+    """The decode write at the served geometries keeps scratch slots of
+    ONE sublane tile each (16 rows x 640 of bf16) and no ``(2,
+    page_size, W)`` scratch is left: the VMEM the compiled call asks
+    for is its tiles', and the pool is written where it lies."""
+    import re
+
+    from llmq_tpu.ops.pallas.latent_decode import (
+        WRITE_AHEAD, latent_write_pallas, tile_rows)
+
+    g = _LATENT[served]
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg((g["L"], g["P"], g["ps"], g["W"]), jnp.bfloat16),
+            arg((g["B"], g["W"]), jnp.bfloat16), arg((g["B"],)),
+            arg((g["B"],)), arg(()))
+    R, slots = tile_rows(jnp.bfloat16), 2 * WRITE_AHEAD
+    assert R == 16
+    kernel = str(jax.make_jaxpr(latent_write_pallas)(*args))
+    assert f"bf16[{slots},{R},{g['W']}]" in kernel
+    assert f"bf16[2,{g['ps']},{g['W']}]" not in kernel
+    compiled = jax.jit(latent_write_pallas,
+                       donate_argnums=(0,)).lower(*args).compile()
+    call, = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    scoped, = re.findall(
+        r'"used_scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', call)
+    assert int(scoped) == slots * R * g["W"] * 2
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 @pytest.mark.parametrize("rows", [384, 6528],
                          ids=["64-decode-rows", "1088-mixed-tokens"])
 def test_grouped_product_compiles_for_v5e(one_chip, rows):
