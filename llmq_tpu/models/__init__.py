@@ -21,8 +21,9 @@ A family is a module of this package that defines
   whatever its length (a state-space layer's recurrent state; the
   leaves hold ``batch + 1`` rows, the last nobody's, as page 0 is: an
   unused slice of a program names it — ``models/granitemoehybrid.py``
+  (Mamba-2 state) and ``models/solar_open2.py`` (delta-rule state)
   beside K/V pages, ``models/ling_hybrid.py`` beside a latent pool,
-  both on layers that own NO pages; ``models/zaya.py`` on the very
+  all three on layers that own NO pages; ``models/zaya.py`` on the very
   layers that own K/V pages: the tail its mixer's convolutions and
   value shift need of the token before; or
   a window layer's keys and
@@ -122,6 +123,7 @@ FAMILIES: Dict[str, str] = {
     "afmoe": "llmq_tpu.models.afmoe",
     "ling_hybrid": "llmq_tpu.models.ling_hybrid",
     "zaya": "llmq_tpu.models.zaya",
+    "solar_open2": "llmq_tpu.models.solar_open2",
 }
 
 

@@ -79,10 +79,11 @@ from llmq_tpu.ops.attention import (decode_order,
                                     dispatch_prefill_attention,
                                     kernel_routes, paged_decode_step,
                                     paged_kv_write_prefill, rows_by_place)
-from llmq_tpu.ops.moe import route, routed_ffn
+from llmq_tpu.ops.moe import route, routed_ffn, share_counts
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rope import apply_rope, rope_cos_sin
 from llmq_tpu.ops.rows import grid_positions, rows_to_grid
+from llmq_tpu.ops.ssm import own_rows
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -537,10 +538,7 @@ def _ffn(params: Params, cfg: AfmoeConfig, l: int, h, live):
         f, st = routed_ffn(y, experts, gates, m["we_gate_up"][i],
                            m["we_down"][i], live, held=cfg.held,
                            n_routed=cfg.n_routed_experts)
-        if st.shape[0] == cfg.n_held + 1:       # all held: none is away
-            st = jnp.concatenate([st, jnp.zeros((1,), jnp.int32)])
-        else:                                   # (load, touched, zero, away)
-            st = jnp.concatenate([st[:cfg.n_held + 1], st[cfg.n_held + 2:]])
+        st = share_counts(st, cfg.n_held)
         with scope("mlp"):    # the shared expert, beside the routed ones
             f = f + _mlp(y, m["ws_gate"][i], m["ws_up"][i], m["ws_down"][i])
     with scope("mlp"):
@@ -571,10 +569,8 @@ def _own_rows(cfg: AfmoeConfig, batch: int, kv_cache: KVCache, row_state,
     """A caller without row state (a test, a plain prefill) gets a zero
     one of its batch's size, row ``b`` for sequence ``b``; the slabs and
     the pool are cut in the same pages."""
-    if row_state is None:
-        row_state = init_row_state(cfg, batch)
-    if rows is None:
-        rows = jnp.arange(batch, dtype=jnp.int32)
+    row_state, rows = own_rows(partial(init_row_state, cfg), batch,
+                               row_state, rows)
     if kv_cache["k"].shape[2] != row_state["wk"].shape[2]:
         raise ValueError(
             f"model {cfg.name!r}: pages of {kv_cache['k'].shape[2]} tokens "

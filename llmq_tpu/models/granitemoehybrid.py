@@ -66,9 +66,9 @@ from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rows import (grid_positions, grid_to_rows, live_rows,
                                row_tile, rows_to_grid, tile_rows,
                                worth_a_loop)
-from llmq_tpu.ops.ssm import (conv_slices, conv_step, decode_walk, rows_read,
-                              rows_write, ssm_scan, ssm_update_layer,
-                              update_route)
+from llmq_tpu.ops.ssm import (conv_slices, conv_step, decode_walk, own_rows,
+                              rows_read, rows_write, ssm_scan,
+                              ssm_update_layer, update_route)
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -559,17 +559,6 @@ def _mamba_slices(xbc, dt, lp: Params, i, rs: RowState, rows, first,
     return y.reshape(y.shape[:2] + (-1,)), {"ssm": ssm, "conv": conv}
 
 
-def _own_rows(cfg: GraniteHybridConfig, batch: int, row_state, rows):
-    """A caller without row state (a test, the reference comparison's
-    plain prefill) gets a zero one of its batch's size, row ``b`` for
-    sequence ``b``."""
-    if row_state is None:
-        row_state = init_row_state(cfg, batch)
-    if rows is None:
-        rows = jnp.arange(batch, dtype=jnp.int32)
-    return row_state, rows
-
-
 @partial(jax.jit, static_argnames=("cfg", "last_only"))
 def forward_prefill(params: Params, cfg: GraniteHybridConfig,
                     tokens: jnp.ndarray, positions: jnp.ndarray,
@@ -583,7 +572,8 @@ def forward_prefill(params: Params, cfg: GraniteHybridConfig,
     state; any other continues what its row holds. Returns ``(logits,
     cache, row_state)``."""
     B, T = tokens.shape
-    row_state, rows = _own_rows(cfg, B, row_state, rows)
+    row_state, rows = own_rows(partial(init_row_state, cfg), B, row_state,
+                               rows)
     h = _embed(params, cfg, tokens).reshape(B * T, -1)
     valid = jnp.arange(T)[None, :] < lengths[:, None]
     seq_lens = jnp.max(jnp.where(valid, positions, -1), axis=1) + 1
@@ -675,7 +665,8 @@ def forward_decode(params: Params, cfg: GraniteHybridConfig,
     is not ``active`` leaves its state as it found it. Returns
     ``(logits (B, V), cache, row_state)``."""
     B = tokens.shape[0]
-    row_state, _ = _own_rows(cfg, B, row_state, None)
+    row_state, _ = own_rows(partial(init_row_state, cfg), B, row_state,
+                            None)
     live = jnp.ones((B,), bool) if active is None else active
     h = _embed(params, cfg, tokens)
     geom = _decode_geometry(positions, block_tables, kv_cache, active, cfg)
@@ -728,7 +719,8 @@ def forward_mixed(params: Params, cfg: GraniteHybridConfig,
     B = dec_tokens.shape[0]
     S = pf_lengths.shape[0]
     T = pf_tokens.shape[0] // S
-    row_state, _ = _own_rows(cfg, B, row_state, None)
+    row_state, _ = own_rows(partial(init_row_state, cfg), B, row_state,
+                            None)
     if pf_rows is None:
         pf_rows = jnp.full((S,), B, jnp.int32)
     live = jnp.ones((B,), bool) if dec_active is None else dec_active

@@ -80,11 +80,12 @@ from llmq_tpu.models.latent import prod as _prod
 from llmq_tpu.models.latent import swiglu as _mlp
 from llmq_tpu.ops.kda import (conv_step, kda_scan_slices, kda_update_layer,
                               l2_norm, scan_route, update_route)
-from llmq_tpu.ops.moe import route, routed_ffn
+from llmq_tpu.ops.moe import (pass_extras, route, routed_ffn,
+                              share_counts)
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rope import rope_cos_sin
 from llmq_tpu.ops.rows import grid_positions, rows_to_grid
-from llmq_tpu.ops.ssm import (conv_slices, decode_walk, rows_read,
+from llmq_tpu.ops.ssm import (conv_slices, decode_walk, own_rows, rows_read,
                               rows_write)
 from llmq_tpu.utils.profiling import scope
 
@@ -646,44 +647,10 @@ def _ffn(params: Params, cfg: LingHybridConfig, l: int, h, live):
     y, st = routed_ffn(x, experts, gates, m["we_gate_up"][i],
                        m["we_down"][i], live, held=cfg.held,
                        n_routed=cfg.n_routed_experts)
-    if st.shape[0] == cfg.n_held + 1:           # all held: none is away
-        st = jnp.concatenate([st, jnp.zeros((1,), jnp.int32)])
-    else:                                       # (load, touched, zero, away)
-        st = jnp.concatenate([st[:cfg.n_held + 1], st[cfg.n_held + 2:]])
+    st = share_counts(st, cfg.n_held)
     with scope("mlp"):        # the shared expert, beside the routed ones
         return h + y + _mlp(x, m["ws_gate"][i], m["ws_up"][i],
                             m["ws_down"][i]), st, experts
-
-
-def _extras(cfg: LingHybridConfig, per_layer, stats: bool, chosen: bool):
-    """What a forward function returns after its cache and row state,
-    from its layers' ``(stats, experts)``: with ``stats`` one pass's
-    counters (``step_stats_size``: the routed layers' counts summed,
-    then how many routed layers ran); with ``chosen`` the experts each
-    routed layer chose for each row of the stream ``(routed layers, N,
-    k)`` int32 — what the benchmark's reference is routed by, so that a
-    near-tie that falls the other way in bfloat16 does not hide what
-    the precision does (``benchmark/families/ling_hybrid``)."""
-    got = [(st, ex) for st, ex in per_layer if st is not None]
-    out = ()
-    if stats:
-        total = sum((st for st, _ in got),
-                    jnp.zeros((cfg.n_held + 2,), jnp.int32))
-        out += (jnp.concatenate(
-            [total, jnp.full((1,), len(got), jnp.int32)]),)
-    if chosen:
-        out += (jnp.stack([ex for _, ex in got]),)
-    return out
-
-
-def _own_rows(cfg: LingHybridConfig, batch: int, row_state, rows):
-    """A caller without row state (a test, a plain prefill) gets a zero
-    one of its batch's size, row ``b`` for sequence ``b``."""
-    if row_state is None:
-        row_state = init_row_state(cfg, batch)
-    if rows is None:
-        rows = jnp.arange(batch, dtype=jnp.int32)
-    return row_state, rows
 
 
 def _rope(cfg: LingHybridConfig, positions):
@@ -708,9 +675,10 @@ def forward_prefill(params: Params, cfg: LingHybridConfig,
     state; any other continues what its row holds. Returns ``(logits,
     cache, row_state)``, and after them the routed layers' counts with
     ``stats`` and their choices (rows in (B, T) order) with ``chosen``
-    (``_extras``)."""
+    (``ops/moe.pass_extras``)."""
     B, T = tokens.shape
-    row_state, rows = _own_rows(cfg, B, row_state, rows)
+    row_state, rows = own_rows(partial(init_row_state, cfg), B, row_state,
+                               rows)
     h = _embed(params, tokens)
     rope = _rope(cfg, positions)
     valid = jnp.arange(T)[None, :] < lengths[:, None]
@@ -736,7 +704,7 @@ def forward_prefill(params: Params, cfg: LingHybridConfig,
         with scope("head"):
             h = h[jnp.arange(B), lengths - 1]
     out = (_head(params, cfg, h), {"ckv": pool}, row_state)
-    return out + _extras(cfg, counts, stats, chosen)
+    return out + pass_extras(counts, cfg.n_held + 2, stats, chosen)
 
 
 @partial(jax.jit, static_argnames=("cfg", "stats", "chosen"))
@@ -752,9 +720,10 @@ def forward_decode(params: Params, cfg: LingHybridConfig,
     updates row ``b`` of ``row_state``. A row that is not active leaves
     its state as it found it, writes to page 0, attends to nothing and
     is routed to no expert; its logits mean nothing. Returns ``(logits
-    (B, V), cache, row_state)``, and ``_extras`` after them."""
+    (B, V), cache, row_state)``, and ``pass_extras`` after them."""
     B = tokens.shape[0]
-    row_state, _ = _own_rows(cfg, B, row_state, None)
+    row_state, _ = own_rows(partial(init_row_state, cfg), B, row_state,
+                            None)
     live = jnp.ones((B,), bool) if active is None else active
     pool = kv_cache["ckv"]
     h = _embed(params, tokens)
@@ -776,7 +745,7 @@ def forward_decode(params: Params, cfg: LingHybridConfig,
         h, *took = _ffn(params, cfg, l, h, active)
         counts.append(took)
     out = (_head(params, cfg, h), {"ckv": pool}, row_state)
-    return out + _extras(cfg, counts, stats, chosen)
+    return out + pass_extras(counts, cfg.n_held + 2, stats, chosen)
 
 
 @partial(jax.jit, static_argnames=("cfg", "stats", "chosen"))
@@ -801,12 +770,13 @@ def forward_mixed(params: Params, cfg: LingHybridConfig,
     (``mixed_live_rows``); the feed-forward runs slices and decode rows
     together, so a routed layer's experts are streamed once for both.
     Returns ``(dec_logits (B, V), pf_logits (S, V), cache, row_state)``
-    and ``_extras`` after them (``chosen``: the slices' S * T grid rows,
+    and ``pass_extras`` after them (``chosen``: the slices' S * T grid rows,
     then the B decode rows)."""
     B = dec_tokens.shape[0]
     S = pf_lengths.shape[0]
     T = pf_tokens.shape[0] // S
-    row_state, _ = _own_rows(cfg, B, row_state, None)
+    row_state, _ = own_rows(partial(init_row_state, cfg), B, row_state,
+                            None)
     if pf_rows is None:
         pf_rows = jnp.full((S,), B, jnp.int32)
     live_d = jnp.ones((B,), bool) if dec_active is None else dec_active
@@ -868,4 +838,4 @@ def forward_mixed(params: Params, cfg: LingHybridConfig,
     with scope("decode_rows"):
         dec_logits = _head(params, cfg, h_d)
     out = (dec_logits, pf_logits, {"ckv": pool}, row_state)
-    return out + _extras(cfg, counts, stats, chosen)
+    return out + pass_extras(counts, cfg.n_held + 2, stats, chosen)

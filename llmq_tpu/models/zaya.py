@@ -73,11 +73,11 @@ from llmq_tpu.ops.attention import (dispatch_prefill_attention,
                                     kernel_routes, paged_decode_step,
                                     paged_kv_write_prefill)
 from llmq_tpu.ops.cca import cca_slices, cca_step, tail_width
-from llmq_tpu.ops.moe import choose, routed_ffn
+from llmq_tpu.ops.moe import choose, pass_extras, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rope import apply_rope, rope_cos_sin
 from llmq_tpu.ops.rows import grid_positions, rows_to_grid
-from llmq_tpu.ops.ssm import rows_read, rows_write
+from llmq_tpu.ops.ssm import own_rows, rows_read, rows_write
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -535,34 +535,6 @@ def _ffn(params: Params, cfg: ZayaConfig, l: int, h, r, live):
         return _merge(h, y, lp["res_mlp"][l]), r, st, experts
 
 
-def _extras(cfg: ZayaConfig, per_layer, stats: bool, chosen: bool):
-    """What a forward function returns after its cache and row state,
-    from its layers' ``(stats, experts)``
-    (``models/ling_hybrid._extras``): with ``stats`` one pass's counters
-    (``step_stats_size``); with ``chosen`` the experts each layer chose
-    for each row of the stream ``(layers, N, k)`` int32 — what the
-    benchmark's reference is routed by."""
-    out = ()
-    if stats:
-        total = sum((st for st, _ in per_layer),
-                    jnp.zeros((cfg.n_experts + 1,), jnp.int32))
-        out += (jnp.concatenate(
-            [total, jnp.full((1,), len(per_layer), jnp.int32)]),)
-    if chosen:
-        out += (jnp.stack([ex for _, ex in per_layer]),)
-    return out
-
-
-def _own_rows(cfg: ZayaConfig, batch: int, row_state, rows):
-    """A caller without row state (a test, a plain prefill) gets a zero
-    one of its batch's size, row ``b`` for sequence ``b``."""
-    if row_state is None:
-        row_state = init_row_state(cfg, batch)
-    if rows is None:
-        rows = jnp.arange(batch, dtype=jnp.int32)
-    return row_state, rows
-
-
 def _no_carry(cfg: ZayaConfig, n: int) -> jnp.ndarray:
     return jnp.zeros((n, cfg.router_dim), jnp.float32)
 
@@ -581,9 +553,10 @@ def forward_prefill(params: Params, cfg: ZayaConfig, tokens: jnp.ndarray,
     of zeros; any other continues what its row holds. Returns ``(logits,
     cache, row_state)``, and after them the routed layers' counts with
     ``stats`` and their choices (rows in (B, T) order) with ``chosen``
-    (``_extras``)."""
+    (``ops/moe.pass_extras``)."""
     B, T = tokens.shape
-    row_state, rows = _own_rows(cfg, B, row_state, rows)
+    row_state, rows = own_rows(partial(init_row_state, cfg), B, row_state,
+                               rows)
     h = _embed(params, tokens)
     rope = _rope(cfg, positions)
     valid = jnp.arange(T)[None, :] < lengths[:, None]
@@ -613,7 +586,7 @@ def forward_prefill(params: Params, cfg: ZayaConfig, tokens: jnp.ndarray,
         with scope("head"):
             h = h[jnp.arange(B), lengths - 1]
     out = (_head(params, cfg, h), {"k": k_pool, "v": v_pool}, row_state)
-    return out + _extras(cfg, counts, stats, chosen)
+    return out + pass_extras(counts, cfg.n_experts + 1, stats, chosen)
 
 
 @partial(jax.jit, static_argnames=("cfg", "stats", "chosen"))
@@ -630,9 +603,10 @@ def forward_decode(params: Params, cfg: ZayaConfig, tokens: jnp.ndarray,
     its tail as it found it, writes to page 0 and is routed to no
     expert; its carry is computed and thrown away like the rest of it,
     and its logits mean nothing. Returns ``(logits (B, V), cache,
-    row_state)``, and ``_extras`` after them."""
+    row_state)``, and ``pass_extras`` after them."""
     B = tokens.shape[0]
-    row_state, _ = _own_rows(cfg, B, row_state, None)
+    row_state, _ = own_rows(partial(init_row_state, cfg), B, row_state,
+                            None)
     live = jnp.ones((B,), bool) if active is None else active
     h = _embed(params, tokens)
     rope = _rope(cfg, positions)
@@ -647,7 +621,7 @@ def forward_decode(params: Params, cfg: ZayaConfig, tokens: jnp.ndarray,
         h, r, *took = _ffn(params, cfg, l, h, r, active)
         counts.append(took)
     out = (_head(params, cfg, h), {"k": k_pool, "v": v_pool}, row_state)
-    return out + _extras(cfg, counts, stats, chosen)
+    return out + pass_extras(counts, cfg.n_experts + 1, stats, chosen)
 
 
 @partial(jax.jit, static_argnames=("cfg", "stats", "chosen"))
@@ -671,12 +645,13 @@ def forward_mixed(params: Params, cfg: ZayaConfig, dec_tokens: jnp.ndarray,
     (``mixed_live_rows``); the routed sublayer — its router network and
     its carry with it — runs slices and decode rows together, so the
     experts are streamed once for both. Returns ``(dec_logits (B, V),
-    pf_logits (S, V), cache, row_state)`` and ``_extras`` after them
+    pf_logits (S, V), cache, row_state)`` and ``pass_extras`` after them
     (``chosen``: the slices' S * T grid rows, then the B decode rows)."""
     B = dec_tokens.shape[0]
     S = pf_lengths.shape[0]
     T = pf_tokens.shape[0] // S
-    row_state, _ = _own_rows(cfg, B, row_state, None)
+    row_state, _ = own_rows(partial(init_row_state, cfg), B, row_state,
+                            None)
     if pf_rows is None:
         pf_rows = jnp.full((S,), B, jnp.int32)
     live_d = jnp.ones((B,), bool) if dec_active is None else dec_active
@@ -736,4 +711,4 @@ def forward_mixed(params: Params, cfg: ZayaConfig, dec_tokens: jnp.ndarray,
     with scope("decode_rows"):
         dec_logits = _head(params, cfg, h_d)
     out = (dec_logits, pf_logits, {"k": k_pool, "v": v_pool}, row_state)
-    return out + _extras(cfg, counts, stats, chosen)
+    return out + pass_extras(counts, cfg.n_experts + 1, stats, chosen)

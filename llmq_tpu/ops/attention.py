@@ -862,3 +862,28 @@ def dispatch_prefill_attention_q8(q, pools, block_tables, positions,
     v_hist = _dequant_window(v_pool, vs_pool, layer, block_tables, D)
     return blockwise_prefill_attention(q, k_hist, v_hist, positions,
                                        seq_lens)
+
+
+def decode_geometry(positions, block_tables, active, pools, head_dim: int,
+                    *, enabled: bool = True):
+    """What the paged GQA layers of a family whose hidden rows STAY BY
+    BATCH ROW (its other layers keep state by row) need of a decode
+    step's rows, with no slabs beside the pool: ``(block_tables,
+    page_of, slot_of, seq_lens, order)`` — ``page_of`` 0 for a row that
+    is not active (it writes to page 0), ``seq_lens`` 0 for it (it
+    attends to nothing), and the ``order`` the attention kernel wants
+    its rows in (:func:`decode_order`, made here ONCE for the step's
+    layers, with the table, ``page_of`` and ``seq_lens`` laid out by
+    it; None where the kernel does not serve). ``pools``: the layers'
+    ``(k, v)`` pools ``(L, P, page_size, ...)``."""
+    B = positions.shape[0]
+    ps = pools[0].shape[2]
+    live = jnp.ones((B,), bool) if active is None else active
+    page_of = jnp.where(
+        live, block_tables[jnp.arange(B), positions // ps], 0)
+    seq_lens = jnp.where(live, positions + 1, 0)
+    order = decode_order(seq_lens, pools, block_tables.shape[1], head_dim,
+                         enabled=enabled)
+    seq_lens, block_tables, page_of = rows_by_place(order, seq_lens,
+                                                    block_tables, page_of)
+    return block_tables, page_of, positions % ps, seq_lens, order

@@ -5,11 +5,27 @@ program), and the routes to their kernels (``ops/pallas/kda_update.py``,
 ``ops/pallas/kda_scan.py``). The convolution in front of it is
 ``ops/ssm.py``'s.
 
-The recurrence, a head (``k_t``, ``q_t`` its d_k key values, ``v_t`` its
-d_v values, ``a_t = exp(g_t)`` in (0, 1)^d_k, ``b_t`` in (0, 1))::
+The recurrence, a head (``k_t``, ``q_t`` its d_k key values at unit
+length, ``v_t`` its d_v values, ``a_t = exp(g_t)`` in (0, 1]^d_k, ``b_t``
+in (0, 2))::
 
     S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T   # (d_k, d_v)
     o_t = S_t^T q_t
+
+**The decay and beta are DATA here**: the update, the scan and both
+kernels take ``g_t <= 0`` and ``b_t`` as they come and know nothing of
+how a family makes them. Two forms are served:
+
+- the BOUNDED decay (Ling-3.0-flash's safe gate, ``models/ling_hybrid.
+  py``): ``g = lower * sigmoid(exp(A_log)_h (x W_f + b_f))`` in
+  (``lower``, 0) = (-5, 0) a token, full-rank ``W_f``, ``b = sigmoid(x
+  W_b)`` in (0, 1);
+- the published Kimi form (:func:`kimi_decay`, :func:`low_rank`;
+  ``models/solar_open2.py``): ``g = -exp(A_log)_h softplus(W_f^up
+  W_f^down x + dt_bias)``, UNBOUNDED below, through a low-rank pair, and
+  ``b = 2 sigmoid(x W_b)`` in (0, 2) (arXiv:2411.12537: ``I - b k k^T``
+  then has an eigenvalue in (-1, 1), a NEGATIVE one for b > 1; still a
+  contraction, so the state stays bounded).
 
 which, with ``u_t = k_t^T (a_t * S_{t-1})`` (what the decayed state
 answers to the key), is ``S_t = a_t * S_{t-1} + b_t k_t (v_t - u_t)^T``:
@@ -34,7 +50,16 @@ a product that feeds the state asks for ``Precision.HIGHEST``.
 ``G_i`` the log-decays summed from the chunk's start, token ``i`` reads
 token ``j <= i`` through ``exp(G_i - G_j)``, a DIFFERENCE a channel,
 always in (0, 1] — ``exp(G_i) / exp(G_j)`` underflows float32 within 18
-tokens at the decay's floor of -5 a token.
+tokens at a decay of -5 a token (the bounded form's floor), and within
+one at the decays the unbounded form reaches. Every factor the scan
+does form — ``exp(G_i)`` from a chunk's (the kernel: a block's) start,
+``exp(G_C - G_i)`` to its end — is of a sum that only falls, so it lies
+in (0, 1] whatever the decay, and one that underflows to 0 is of a term
+whose true value underflows as well. The chunk's unit-lower solve is
+exact for any ``b``; with ``b`` up to 2 its inverse's entries grow with
+the keys' overlap (``|k_i . k_j| b``), not with the decay
+(``tests/test_kda.py`` holds both scans to the update a token at a time
+with b in (0, 2) and a decay that underflows a block's factor).
 """
 
 from __future__ import annotations
@@ -55,6 +80,36 @@ L2_EPS = 1e-6
 def l2_norm(x: jnp.ndarray) -> jnp.ndarray:
     """``x`` (..., d) float32 at unit length over its last axis."""
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+def low_rank(x: jnp.ndarray, down: jnp.ndarray, up: jnp.ndarray, *,
+             exact: bool = False) -> jnp.ndarray:
+    """``(x W_down) W_up`` of a low-rank pair, ``x`` (M, D), ``down``
+    (D, r), ``up`` (r, W): two small products, float32 out. ``exact``:
+    the second at ``Precision.HIGHEST`` over the float32 intermediate
+    (the decay's pair: the r values a token would else be rounded to
+    bfloat16 on the way in, and the log-decay carries a logit's error on
+    from token to token — ``models/ling_hybrid._kda_in`` has what a
+    rounded logit cost)."""
+    f32 = jnp.float32
+    mid = jnp.dot(x, down, preferred_element_type=f32)
+    if exact:
+        return jnp.dot(mid, up.astype(f32), precision=lax.Precision.HIGHEST)
+    return jnp.dot(mid.astype(x.dtype), up, preferred_element_type=f32)
+
+
+def kimi_decay(f: jnp.ndarray, a_log: jnp.ndarray,
+               dt_bias: jnp.ndarray) -> jnp.ndarray:
+    """Kimi Linear's log-decay a channel, unbounded below: ``g =
+    -exp(A_log)_h softplus(f + dt_bias)`` for ``f`` (M, H * d) float32
+    (the low-rank pair's product, heads side by side on the lanes as
+    the state's), ``a_log`` (H,), ``dt_bias`` (H * d,). Returns
+    (M, H * d) float32, < 0 — FLAT, as it came: on the TPU a reshape to
+    (M, H, d) moves the rows off the sublanes, a copy of the whole array
+    each way (``models/solar_open2._unit`` has the same)."""
+    f32 = jnp.float32
+    a = jnp.repeat(jnp.exp(a_log.astype(f32)), f.shape[-1] // a_log.shape[0])
+    return -a * jax.nn.softplus(f.astype(f32) + dt_bias.astype(f32))
 
 
 def conv_step(pool: jnp.ndarray, layer, x: jnp.ndarray, w: jnp.ndarray,
@@ -90,8 +145,9 @@ def kda_update(state: jnp.ndarray, q: jnp.ndarray, k: jnp.ndarray,
                active: Optional[jnp.ndarray] = None
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One token a row. ``state`` (B, d_k, H*d_v); ``q``, ``k``, ``g``
-    (B, H, d_k) — ``g`` the log-decay, at most 0; ``v`` (B, H, d_v);
-    ``beta`` (B, H). Returns ``(o (B, H, d_v) float32, the new state in
+    (B, H, d_k) — ``g`` the log-decay, at most 0, of either form;
+    ``v`` (B, H, d_v); ``beta`` (B, H), in (0, 2). Returns ``(o (B, H,
+    d_v) float32, the new state in
     ``state.dtype``)``; a row that is not ``active`` keeps its state
     (its ``o`` is of no use). Elementwise products and sums over d_k,
     all float32: no matrix product, so nothing is rounded on the way."""

@@ -290,3 +290,37 @@ def _routed_share(x, flat, gates, w_gate_up, w_down, live, k, lo, n_routed):
                                 n_zero, n_away])])
         return y.astype(x.dtype), stats
 
+
+
+def share_counts(st: jnp.ndarray, n_held: int) -> jnp.ndarray:
+    """``routed_ffn``'s counters of one layer as a family's
+    ``step_stats_layout`` has them, ``(n_held + 2,)``: the tokens each
+    held expert received, the held experts that received any, the slots
+    whose expert is held elsewhere. All experts held (``routed_ffn``'s
+    plain form, ``(n_held + 1,)``): none is away; a share (``(n_held +
+    3,)``): without its count of zero-compute slots, which a family
+    with none does not report."""
+    if st.shape[0] == n_held + 1:
+        return jnp.concatenate([st, jnp.zeros((1,), jnp.int32)])
+    return jnp.concatenate([st[:n_held + 1], st[n_held + 2:]])
+
+
+def pass_extras(per_layer, width: int, stats: bool, chosen: bool) -> tuple:
+    """What a routed family's forward function returns after its cache
+    and row state, from its layers' ``(stats, experts)`` (``(None,
+    None)`` for a dense layer): with ``stats`` one pass's counters —
+    the routed layers' counts ``(width,)`` summed, then how many routed
+    layers ran; with ``chosen`` the experts each routed layer chose for
+    each row of the stream ``(routed layers, N, k)`` int32 — what the
+    benchmark's reference is routed by, so that a near-tie that falls
+    the other way in bfloat16 does not hide what the precision does
+    (``benchmark/families/ling_hybrid``)."""
+    got = [(st, ex) for st, ex in per_layer if st is not None]
+    out: tuple = ()
+    if stats:
+        total = sum((st for st, _ in got), jnp.zeros((width,), jnp.int32))
+        out += (jnp.concatenate(
+            [total, jnp.full((1,), len(got), jnp.int32)]),)
+    if chosen:
+        out += (jnp.stack([ex for _, ex in got]),)
+    return out

@@ -249,3 +249,15 @@ def rows_write(pool: jnp.ndarray, layer, rows: jnp.ndarray,
         pool = lax.dynamic_update_slice(
             pool, new[s][None, None].astype(pool.dtype), at)
     return pool
+
+
+def own_rows(init, batch: int, row_state, rows):
+    """``(row_state, rows)`` for a forward function of a row-state
+    family: a caller without row state (a test, a plain prefill) gets a
+    zero one of its batch's size (``init(batch)``), row ``b`` for
+    sequence ``b``."""
+    if row_state is None:
+        row_state = init(batch)
+    if rows is None:
+        rows = jnp.arange(batch, dtype=jnp.int32)
+    return row_state, rows

@@ -722,6 +722,34 @@ def bench_ssm(args, doc) -> None:
     _save(args, doc, results)
 
 
+def _kda_geometry(doc):
+    """``(heads, head_dim, KDA layers held, draw)`` of a configuration
+    with delta-rule layers, by its family's keys; ``draw(key_g, key_b,
+    shape)`` the log-decay and beta as the family makes them:
+    ``ling_hybrid`` the bounded decay and beta in (0, 1), ``solar_open2``
+    Kimi's unbounded softplus form and beta in (0, 2)."""
+    import jax
+
+    if doc["family"] == "solar_open2":
+        lin = doc["linear_attn_config"]
+        n = doc["num_hidden_layers"]
+        L = n - sum(1 for l in doc["gqa_layers"] if l < n)
+
+        def draw(kg, kb, shape):
+            return (-2.0 * jax.nn.softplus(
+                        3.0 * jax.random.normal(kg, shape) - 3.0),
+                    2.0 * jax.nn.sigmoid(jax.random.normal(kb, shape[:-1])))
+        return lin["num_heads"], lin["head_dim"], L, draw
+    L = sum(1 for l in range(doc["num_hidden_layers"])
+            if (l + 1) % doc["layer_group_size"])
+
+    def draw(kg, kb, shape):
+        return (doc["kda_lower_bound"] * jax.nn.sigmoid(
+                    2.0 * jax.random.normal(kg, shape) - 2.5),
+                jax.nn.sigmoid(jax.random.normal(kb, shape[:-1])))
+    return doc["num_attention_heads"], doc["head_dim"], L, draw
+
+
 def bench_kda(args, doc) -> None:
     """The delta rule's decode state update of one layer at the served
     geometry (``--lens ROWS``: that many of the batch's rows decode):
@@ -737,10 +765,8 @@ def bench_kda(args, doc) -> None:
     from llmq_tpu.ops import kda
 
     ex = doc["server"]["executor"]
-    B, H, d = (ex["max_batch_size"], doc["num_attention_heads"],
-               doc["head_dim"])
-    L = sum(1 for l in range(doc["num_hidden_layers"])
-            if (l + 1) % doc["layer_group_size"])
+    B = ex["max_batch_size"]
+    H, d, L, draw = _kda_geometry(doc)
     reps = L
     if args.rehearse:
         os.environ["LLMQ_PALLAS"] = "interpret"
@@ -751,9 +777,7 @@ def bench_kda(args, doc) -> None:
     q = kda.l2_norm(jax.random.normal(ks[0], (B, H, d))) * d ** -0.5
     k = kda.l2_norm(jax.random.normal(ks[1], (B, H, d)))
     v = jax.random.normal(ks[2], (B, H, d), jnp.float32)
-    g = doc["kda_lower_bound"] * jax.nn.sigmoid(
-        2.0 * jax.random.normal(ks[3], (B, H, d)) - 2.5)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, H)))
+    g, beta = draw(ks[3], ks[4], (B, H, d))
 
     def make(enabled):
         @partial(jax.jit, donate_argnums=(0,))
@@ -828,10 +852,8 @@ def bench_kda_scan(args, doc) -> None:
     mb = doc["server"]["executor"]["mixed_batch"]
     S = mb["max_slices"]
     T = mb["prefill_token_budget"] // S
-    H, d = doc["num_attention_heads"], doc["head_dim"]
-    L = sum(1 for l in range(doc["num_hidden_layers"])
-            if (l + 1) % doc["layer_group_size"])
-    block = 16                  # families/ling_hybrid/adapter.KDA_CHUNK
+    H, d, L, draw = _kda_geometry(doc)
+    block = 16                  # the families' adapter.KDA_CHUNK
     if args.rehearse:
         os.environ["LLMQ_PALLAS"] = "interpret"
         S, T, H, L = 2, 128, 2, 1
@@ -842,9 +864,7 @@ def bench_kda_scan(args, doc) -> None:
     q = kda.l2_norm(jax.random.normal(ks[0], (S, T, H, d))) * d ** -0.5
     k = kda.l2_norm(jax.random.normal(ks[1], (S, T, H, d)))
     v = jax.random.normal(ks[2], (S, T, H, d), jnp.float32)
-    g = doc["kda_lower_bound"] * jax.nn.sigmoid(
-        2.0 * jax.random.normal(ks[3], (S, T, H, d)) - 2.5)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (S, T, H)))
+    g, beta = draw(ks[3], ks[4], (S, T, H, d))
     state = jax.random.normal(ks[5], (S, d, H * d), jnp.float32)
     kernel = getattr(kda, "kda_scan_slices", None)
 
@@ -941,7 +961,7 @@ def bench_kda_scan(args, doc) -> None:
 #: bench is a function here and an entry in this table.
 BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent,
            "longcat_flash": bench_latent, "granitemoehybrid": bench_ssm,
-           "ling_hybrid": bench_kda}
+           "ling_hybrid": bench_kda, "solar_open2": bench_kda}
 
 
 def main() -> None:
@@ -993,7 +1013,8 @@ def main() -> None:
         if doc["family"] == "granitemoehybrid":
             sys.exit(f"--prefill: no slice bench for {doc['family']} (its "
                      f"recurrent mixer's scan is XLA's)")
-        {"llama": bench_prefill, "ling_hybrid": bench_kda_scan}.get(
+        {"llama": bench_prefill, "ling_hybrid": bench_kda_scan,
+         "solar_open2": bench_kda_scan}.get(
             doc["family"], bench_latent_prefill)(args, doc)
     if args.lens:
         BENCHES[doc["family"]](args, doc)

@@ -83,6 +83,44 @@ def pytest_sessionfinish(session, exitstatus):
         session.exitstatus = 3
 
 
+#: Memory mappings of a worker process past which the programs JAX has
+#: compiled are dropped (``vm.max_map_count`` is 65,530 here; one test of
+#: a routed family's engine adds some 4,000).
+MAPPINGS_HIGH_WATER = 40_000
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled_programs_before_the_mappings_run_out():
+    """After a test, if the process holds more than
+    ``MAPPINGS_HIGH_WATER`` memory mappings: drop every compiled program
+    JAX keeps (``jax.clear_caches``) and collect what held them. Why: a
+    compiled CPU program is MAPPINGS of the worker process, a worker
+    runs file after file and keeps all of them, and past
+    ``vm.max_map_count`` the next compile's ``mmap`` fails inside LLVM
+    and the worker dies with a segmentation fault, in whatever test
+    comes next (met in PR 52: a new file of 27 tests left ~40,000
+    behind, and ``tests/test_afmoe.py`` on the same worker went down
+    at the same test — twice with no guard, and once more with the
+    release scoped to the new file alone: what fills a worker is the sum
+    of the files it was dealt, so the guard is the suite's; released, a
+    worker is back to some 700). Under the mark nothing happens: one
+    read of ``/proc/self/maps``, 2-3 ms."""
+    yield
+    import sys
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            mappings = sum(1 for _ in f)
+    except OSError:
+        return
+    if mappings > MAPPINGS_HIGH_WATER:
+        import gc
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture
 def fake_clock() -> FakeClock:
     return FakeClock()

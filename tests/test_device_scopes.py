@@ -38,6 +38,8 @@ FAMILIES = {
                     | {"latent_prefill_attention", "attn_gate",
                        "kda_gates"}),
     "granitemoehybrid": MODULES | SSM,
+    "solar_open2": (MODULES | ROUTED | SSM
+                    | {"attn_full", "attn_gate", "kda_gates"}),
     "zaya": MODULES | ROUTED | {"cca_mix"},
     "llama": MODULES,
     "llama-w8kv8": MODULES | {"act_quant"},
@@ -72,6 +74,12 @@ def _tiny(family):
         cfg = lh.ling_hybrid_tiny(dtype=jnp.float32, max_seq_len=128,
                                   held_experts=(8, 16))
         return cfg, lh.init_params(jax.random.PRNGKey(45), cfg), {}
+    if family == "solar_open2":
+        from llmq_tpu.models import solar_open2 as so
+        cfg = so.solar_open2_tiny(dtype=jnp.float32, max_seq_len=128,
+                                  held_experts=(8, 16), n_layers=4,
+                                  gqa_layers=(0,))
+        return cfg, so.init_params(jax.random.PRNGKey(52), cfg), {}
     if family == "zaya":
         from llmq_tpu.models import zaya
         cfg = zaya.zaya_tiny(dtype=jnp.float32, max_seq_len=128)

@@ -26,7 +26,8 @@ from llmq_tpu.models import latent  # noqa: E402
 from llmq_tpu.ops import kda, moe  # noqa: E402
 from llmq_tpu.ops.pallas.kda_scan import (kda_scan_heads,  # noqa: E402
                                           kda_scan_pallas, kda_scan_viable)
-from llmq_tpu.ops.pallas.kda_update import (kda_update_pallas,  # noqa: E402
+from llmq_tpu.ops.pallas.kda_update import (head_blocks,  # noqa: E402
+                                            kda_update_pallas,
                                             kda_update_viable)
 from llmq_tpu.ops.ssm import decode_walk  # noqa: E402
 
@@ -193,6 +194,125 @@ def test_the_update_kernel_is_the_update_and_touches_only_the_live_rows():
                                     *decode_walk(none), interpret=True)
     assert (np.asarray(pool_0) == np.asarray(pool)).all()
     assert (np.asarray(o_0) == 0).all()
+
+
+def _kimi_inputs(rng, S, T, H, dk, dv, strong=False):
+    """``_inputs`` with the decay and beta as the published Kimi form
+    makes them (``ops/kda.kimi_decay``; beta = 2 sigmoid): a log-decay
+    unbounded below — ``strong``: -30 to -60 a token in half the
+    channels, so that a block's factor ``exp(G)`` underflows float32
+    within two tokens, and about -0.01 in the rest, which remember —
+    and beta on both sides of 1."""
+    f32 = jnp.float32
+    q, k, v, _, _ = _inputs(rng, S, T, H, dk, dv)
+    a_log = jnp.asarray(rng.uniform(np.log(0.5), np.log(4.0), (H,)), f32)
+    bias = rng.uniform(-8.0, 2.0, (H, dk))
+    if strong:
+        bias = np.where(rng.random((H, dk)) < 0.5, 30.0, -6.0)
+    g = kda.kimi_decay(jnp.asarray(rng.normal(size=(S * T, H * dk)), f32),
+                       a_log, jnp.asarray(bias, f32).reshape(-1)
+                       ).reshape(S, T, H, dk)
+    beta = 2 * jax.nn.sigmoid(jnp.asarray(1.5 * rng.normal(size=(S, T, H)),
+                                          f32))
+    assert float(beta.max()) > 1.5 and float(beta.min()) < 0.5
+    assert not strong or float(g.min()) < -30
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("strong", [False, True],
+                         ids=["spans-its-range", "underflows-a-block"])
+@pytest.mark.parametrize("scan, shape, lengths", [
+    pytest.param(kda.kda_scan, (2, 70, 2, 16, 8), [70, 37], id="xla"),
+    pytest.param(_kernel_scan(), (2, 128, 2, 128, 128), [128, 70],
+                 id="kernel")])
+def test_the_scan_with_beta_up_to_two_and_an_unbounded_decay(
+        scan, shape, lengths, strong):
+    """The published Kimi form (``models/solar_open2.py``): beta in
+    (0, 2), so ``I - beta k k^T`` has a NEGATIVE eigenvalue for beta > 1,
+    and a decay with no floor. Both reach the scan as data; its
+    unit-lower solve is exact for any beta, and every factor it forms is
+    of a sum that only falls — one that underflows is of a term whose
+    true value underflows too. Held to the update a token at a time."""
+    rng = np.random.default_rng(11)
+    S, T, H, dk, dv = shape
+    ins = _kimi_inputs(rng, S, T, H, dk, dv, strong)
+    state = jnp.asarray(rng.normal(size=(S, dk, H * dv)), jnp.float32)
+    want_o, want_s = _token_by_token(state, *ins, lengths)
+    got_o, got_s = scan(state, *ins, jnp.asarray(lengths), 16)
+    valid = (np.arange(T)[None] < np.asarray(lengths)[:, None])
+    assert np.isfinite(np.asarray(got_o)).all()
+    assert np.isfinite(np.asarray(got_s)).all()
+    np.testing.assert_allclose(np.asarray(got_o)[valid],
+                               np.asarray(want_o)[valid], atol=4e-6)
+    np.testing.assert_allclose(got_s, want_s, atol=1e-5)
+
+
+def test_the_low_rank_pair_and_the_kimi_decay():
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.normal(size=(5, 32)), jnp.float32)
+    down = jnp.asarray(rng.normal(size=(32, 4)), jnp.float32)
+    up = jnp.asarray(rng.normal(size=(4, 24)), jnp.float32)
+    want = np.asarray(x) @ np.asarray(down) @ np.asarray(up)
+    for exact in (False, True):
+        np.testing.assert_allclose(kda.low_rank(x, down, up, exact=exact),
+                                   want, rtol=1e-5, atol=1e-5)
+    # bfloat16 operands: the exact form keeps the float32 intermediate
+    xb, db, ub = (y.astype(jnp.bfloat16) for y in (x, down, up))
+    mid = np.asarray(xb, np.float32) @ np.asarray(db, np.float32)
+    np.testing.assert_allclose(
+        kda.low_rank(xb, db, ub, exact=True),
+        mid @ np.asarray(ub, np.float32), rtol=1e-5, atol=1e-5)
+    f = jnp.asarray(rng.normal(size=(5, 3, 8)), jnp.float32)
+    a_log = jnp.asarray([0.0, 1.0, -1.0])
+    bias = jnp.asarray(rng.normal(size=(3, 8)), jnp.float32)
+    # heads side by side on the lanes, in and out
+    g = kda.kimi_decay(f.reshape(5, 24), a_log, bias.reshape(24))
+    assert g.shape == (5, 24)
+    np.testing.assert_allclose(
+        g.reshape(5, 3, 8), -np.exp(np.asarray(a_log))[:, None] * np.log1p(
+            np.exp(np.asarray(f) + np.asarray(bias))), rtol=1e-5)
+    assert float(g.max()) < 0
+
+
+@pytest.mark.parametrize("H, blocks", [(64, 2), (32, 1)])
+def test_the_update_kernel_walks_a_row_in_head_blocks(H, blocks):
+    """Interpret mode at a short d_k: 64 heads are two blocks of 32 a
+    live row (the packed columns a block, a block's lanes of every
+    sublane of the row), 32 heads one block — the whole row, the call
+    the 32-head family ran before there were blocks. Either way the live
+    rows' outputs and states are ``kda_update``'s with beta up to 2 and
+    an unbounded decay, and nothing else is moved."""
+    rng = np.random.default_rng(13)
+    B, dk, dv, L = 4, 8, 128, 2
+    assert head_blocks(H) == blocks
+    assert kda_update_viable(dk, H, dv)
+    pool = jnp.asarray(rng.normal(size=(L, B + 1, dk, H * dv)), jnp.float32)
+    q, k, v, g, beta = (x[:, 0] for x in _kimi_inputs(rng, B, 1, H, dk, dv))
+    active = jnp.asarray([True, False, True, True])
+    o_k, pool_k = kda_update_pallas(pool, 1, q, k, v, g, beta,
+                                    *decode_walk(active), interpret=True)
+    o_x, new = kda.kda_update(pool[1, :B], q, k, v, g, beta, active)
+    live = np.asarray(active)
+    np.testing.assert_allclose(np.asarray(o_k)[live], np.asarray(o_x)[live],
+                               atol=2e-6)
+    np.testing.assert_allclose(pool_k[1, :B], new, atol=2e-6)
+    assert (np.asarray(o_k)[~live] == 0).all()
+    for l, rows in ((0, slice(None)), (1, [1, 4])):
+        assert (np.asarray(pool_k[l, rows]) == np.asarray(pool[l, rows])).all()
+
+
+def test_the_update_kernel_s_head_block_rule():
+    """``kda_update_viable``: whole rows up to 32 heads (their four
+    columns a head fit 128 lanes), whole blocks of 32 above, three
+    blocks within the walk's 8 MiB."""
+    assert kda_update_viable(128, 64, 128)        # two 2 MiB blocks a row
+    assert kda_update_viable(128, 32, 128)        # one: the row
+    assert kda_update_viable(128, 96, 128) and head_blocks(96) == 3
+    assert [head_blocks(h) for h in (1, 2, 31, 32, 33, 48, 64)] == [
+        1, 1, 1, 1, 0, 0, 2]
+    assert not kda_update_viable(128, 48, 128)    # no whole blocks
+    assert not kda_update_viable(256, 64, 128)    # three blocks of 4 MiB
+    assert not kda_update_viable(128, 64, 64)
 
 
 def test_the_update_layer_routes_to_the_kernel_under_interpret(monkeypatch):

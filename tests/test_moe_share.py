@@ -1,6 +1,6 @@
 """A chip's share of a routed layer (``ops/moe.routed_ffn(held=...)``,
 ``models/longcat_flash.py``, ``models/afmoe.py``,
-``models/ling_hybrid.py``): the router scores
+``models/ling_hybrid.py``, ``models/solar_open2.py``): the router scores
 every expert, a token chooses among all, this chip multiplies the pairs
 whose expert it holds, adds what a token's home chip adds (LongCat's
 zero-compute experts, afmoe's shared expert) and leaves the rest out.
@@ -9,7 +9,8 @@ serves a share (``FAMILIES``): at a small size (LongCat: 32 experts + 16
 zero-compute ones in 4 shares of 8; afmoe: 32 experts in 16 shares of 2
 beside a shared expert; ling_hybrid: 32 experts in 4 GROUPS of 8, the
 best 2 groups kept, in 4 shares of 8 — a share a group — beside a shared
-expert), one routed layer's partial results over all
+expert; solar_open2: 32 experts in EIGHT shares of 4, no groups, beside
+a shared expert counted once), one routed layer's partial results over all
 shares, with the home chip's part and the dense path counted once, add
 up to what the family's plain reference
 (``benchmark/families/<family>/reference.py``) gives for the UNCUT
@@ -30,6 +31,7 @@ from benchmark.harness import contract
 from llmq_tpu.models import afmoe as am
 from llmq_tpu.models import ling_hybrid as lh
 from llmq_tpu.models import longcat_flash as lf
+from llmq_tpu.models import solar_open2 as so
 from llmq_tpu.ops import moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -218,7 +220,59 @@ def _lh_all_shares(fam, monkeypatch, slots):
     monkeypatch.setattr(lh, "routed_ffn", am.routed_ffn)
 
 
+# -- solar_open2: 8 shares of 4, no groups, the shared expert once ---------------
+
+def _so_model_of(cfg):
+    lo, hi = cfg.held
+    return {"num_hidden_layers": cfg.n_layers, "hidden_size": cfg.dim,
+            "num_attention_heads": cfg.n_heads,
+            "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+            "gqa_layers": list(cfg.gqa_layers),
+            "linear_attn_config": {
+                "short_conv_kernel_size": cfg.kda_conv,
+                "head_dim": cfg.kda_head_dim, "num_heads": cfg.kda_heads,
+                "num_kv_heads": None},
+            "n_routed_experts": hi - lo, "router_experts": E,
+            "expert_share": {"chips": E // (hi - lo),
+                             "index": lo // (hi - lo)},
+            "num_experts_per_tok": cfg.n_experts_per_tok,
+            "routed_scaling_factor": cfg.routed_scaling_factor,
+            "norm_topk_prob": cfg.norm_topk_prob,
+            "rms_norm_eps": cfg.norm_eps, "vocab_size": cfg.vocab_size}
+
+
+def _so_uncut():
+    """One routed layer behind a KDA mixer, 32 experts with 4 a token,
+    all held (the deployment's eight shares are of 4 each here)."""
+    return so.solar_open2_tiny(
+        dtype=jnp.float32, max_seq_len=64, n_layers=1, gqa_layers=(),
+        n_routed_experts=E, n_experts_per_tok=4)
+
+
+def _so_all_positions(fns, cfg, params, seq):
+    mp = cfg.max_seq_len // PAGE
+    cache = so.init_kv_pages(cfg, 1 + mp, PAGE)
+    logits, _, _, st = fns.forward_prefill(
+        params, cfg, jnp.asarray(seq[None]),
+        jnp.arange(len(seq), dtype=jnp.int32)[None],
+        jnp.asarray([len(seq)], jnp.int32), cache,
+        jnp.arange(1, 1 + mp, dtype=jnp.int32)[None], stats=True)
+    return np.asarray(logits)[0], np.asarray(st)
+
+
+def _so_all_shares(fam, monkeypatch, slots):
+    """``so.routed_ffn`` as the SUM of the eight shares' partial results
+    (``_am_all_shares``); the shared expert is ``so._ffn``'s own, once."""
+    _am_all_shares(fam, monkeypatch, slots)
+    monkeypatch.setattr(so, "routed_ffn", am.routed_ffn)
+
+
 FAMILIES = {
+    "solar_open2": SimpleNamespace(
+        name="solar_open2", mod=so, shares=8, held=E // 8, k=4,
+        uncut=_so_uncut, model_of=_so_model_of, bias=0.01,
+        all_positions=_so_all_positions, all_shares=_so_all_shares,
+        zero_at=None, away_at=E // 8 + 2),
     "ling_hybrid": SimpleNamespace(
         name="ling_hybrid", mod=lh, shares=SHARES, held=HELD, k=4,
         uncut=_lh_uncut, model_of=_lh_model_of, bias=0.01,
@@ -465,3 +519,66 @@ def test_choose_behind_route_gives_its_callers_what_route_gave(caller):
     rest = {k: v for k, v in kw.items() if k != "scoring"}
     for got, want in zip(moe.choose(scores, bias, **rest), new):
         assert (np.asarray(got) == np.asarray(want)).all()
+
+
+# -- what the routed families share of a pass's counters (PR 52) ---------------
+
+@pytest.mark.parametrize("form", ["all-held", "a-share"])
+def test_a_layer_s_counters_in_the_layout_the_families_state(form):
+    """``routed_ffn`` counts (load, touched) with every expert held and
+    (load, touched, zero, away) for a share: a family's
+    ``step_stats_layout`` has (load, touched, away) in both."""
+    load = jnp.asarray([3, 0, 2, 1], jnp.int32)
+    st = (jnp.concatenate([load, jnp.asarray([3], jnp.int32)])
+          if form == "all-held" else
+          jnp.concatenate([load, jnp.asarray([3, 7, 5], jnp.int32)]))
+    got = np.asarray(moe.share_counts(st, 4))
+    assert got.tolist() == [3, 0, 2, 1, 3, 0 if form == "all-held" else 5]
+
+
+@pytest.mark.parametrize("flags", [(False, False), (True, False),
+                                   (False, True), (True, True)])
+def test_a_pass_s_extras_skip_the_dense_layers(flags):
+    stats, chosen = flags
+    st = jnp.asarray([1, 2, 2, 0], jnp.int32)
+    ex = jnp.zeros((5, 2), jnp.int32)
+    out = moe.pass_extras([(None, None), (st, ex), (st, ex + 1)], 4, stats,
+                          chosen)
+    assert len(out) == stats + chosen
+    if stats:
+        assert np.asarray(out[0]).tolist() == [2, 4, 4, 0, 2]
+    if chosen:
+        assert out[-1].shape == (2, 5, 2) and int(out[-1][1].min()) == 1
+
+
+def test_a_caller_without_row_state_gets_a_zero_one_of_its_batch():
+    from llmq_tpu.ops.ssm import own_rows
+    made = []
+
+    def init(batch):
+        made.append(batch)
+        return {"s": jnp.zeros((batch + 1, 2))}
+
+    state, rows = own_rows(init, 3, None, None)
+    assert made == [3] and np.asarray(rows).tolist() == [0, 1, 2]
+    mine, given = {"s": jnp.ones((4, 2))}, jnp.asarray([2, 0], jnp.int32)
+    state, rows = own_rows(init, 2, mine, given)
+    assert state is mine and rows is given and made == [3]
+
+
+def test_the_decode_geometry_of_rows_that_stay_by_batch_row():
+    """A row that is not active writes to page 0 and attends to nothing;
+    off the TPU no order is made and the rows stay as they came."""
+    from llmq_tpu.ops.attention import decode_geometry
+    pools = (jnp.zeros((1, 9, 8, 16)),) * 2
+    tables = jnp.asarray([[1, 2], [3, 4], [5, 6]], jnp.int32)
+    positions = jnp.asarray([9, 3, 15], jnp.int32)
+    active = jnp.asarray([True, False, True])
+    bts, page_of, slot_of, seq_lens, order = decode_geometry(
+        positions, tables, active, pools, 16)
+    assert order is None and bts is tables
+    assert np.asarray(page_of).tolist() == [2, 0, 6]
+    assert np.asarray(slot_of).tolist() == [1, 3, 7]
+    assert np.asarray(seq_lens).tolist() == [10, 0, 16]
+    *_, seq_lens, _ = decode_geometry(positions, tables, None, pools, 16)
+    assert np.asarray(seq_lens).tolist() == [10, 4, 16]

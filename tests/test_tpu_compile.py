@@ -1141,6 +1141,147 @@ def test_the_delta_rule_share_s_step_fits_v5e_and_copies_no_cache(
     assert mem.temp_size_in_bytes < (0.5e9 if program == "decode" else 2.0e9)
 
 
+def _solar_share(one_chip, monkeypatch):
+    """``benchmark/configs/solar-open2-250b-bf16-ep8.json`` as the
+    executor holds it (``_ling_share``'s form)."""
+    import json
+
+    from benchmark.harness import contract
+    from llmq_tpu.models import solar_open2 as so
+    from llmq_tpu.ops import attention
+
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("LLMQ_PALLAS", raising=False)
+    path = os.path.join(contract.ROOT, "benchmark", "configs",
+                        "solar-open2-250b-bf16-ep8.json")
+    with open(path, encoding="utf-8") as f:
+        config = json.load(f)
+    adapter = contract.load_family(
+        os.path.join(contract.ROOT, "benchmark", "families", "solar_open2"),
+        "adapter")
+    cfg = so.serving_config(adapter.register("solar-compile-check", config))
+    ex = config["server"]["executor"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: so.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: so.init_kv_pages(cfg, ex["kv_pages"], ex["page_size"])))
+    state = on_chip(jax.eval_shape(
+        lambda: so.init_row_state(cfg, ex["max_batch_size"])))
+    S = ex["mixed_batch"]["max_slices"]
+    return so, cfg, params, cache, state, (
+        ex["max_batch_size"], S, ex["mixed_batch"]["prefill_token_budget"]
+        // S, ex["page_size"])
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "prefill"])
+def test_the_kimi_form_share_s_step_fits_v5e_and_copies_no_cache(
+        one_chip, monkeypatch, program):
+    """One step of ``solar-open2-250b-bf16-ep8`` as served (4 layers: 1
+    gated GQA and 3 KDA of 64 heads, 40 held experts, an eighth of the
+    vocabulary, 32 rows of 13 MB of row state beside 8,704 K/V pages of
+    272 a row): 11.6 GB of arguments, the pool's two leaves and BOTH
+    row-state leaves go in and come out in place — no copy of a leaf —,
+    the in-place update kernel is there once a KDA layer (64 heads: two
+    head blocks a row, which the kernel refused before it walked in
+    blocks), and the step's temporaries stay inside what is left of the
+    chip's 16.9 GB."""
+    so, cfg, params, cache, state, (B, S, T, page) = _solar_share(
+        one_chip, monkeypatch)
+    mp = cfg.max_seq_len // page
+    assert (cfg.n_kda, cfg.n_gqa, cfg.n_held, mp) == (3, 1, 40, 272)
+    assert state["kda"].shape == (3, B + 1, 128, 8192)
+    assert so.routes(cfg, cache, batch=B, page_size=page, max_pages=mp,
+                     decode=True, prefill_rows=S)["ssm_update"] == \
+        "pallas:kda_update_pallas(head_blocks=2)"
+    assert so.scan_step_tokens(cfg, T) == 64
+
+    def arg(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, cache, state, tok, pos, tables, active):
+        return so.forward_decode.__wrapped__(
+            params, cfg, tok, pos, cache, tables, active=active, stats=True,
+            row_state=state)
+
+    if program == "decode":
+        step = decode
+        args = (arg(B), arg(B), arg(B, mp), arg(B, dtype=jnp.bool_))
+    elif program == "mixed":
+        # as a mixed CHUNK runs it: the fused step, then decode steps in
+        # a loop that carries pool and state
+        def step(params, cache, state, tokens, positions, tables, active,
+                 *pf):
+            dec, pf_logits, cache, state, st = so.forward_mixed.__wrapped__(
+                params, cfg, tokens, positions, cache, tables, *pf[:5],
+                dec_active=active, stats=True, row_state=state,
+                pf_rows=pf[5])
+
+            def body(_, carry):
+                tok, pos, cache, state, acc = carry
+                logits, cache, state, st = decode(params, cache, state, tok,
+                                                  pos, tables, active)
+                return (jnp.argmax(logits, -1).astype(jnp.int32), pos + 1,
+                        cache, state, acc + st)
+
+            tok, _, cache, state, st = jax.lax.fori_loop(0, 3, body, (
+                jnp.argmax(dec, -1).astype(jnp.int32), positions + 1, cache,
+                state, st))
+            return tok, pf_logits, cache, state, st
+        args = (arg(B), arg(B), arg(B, mp), arg(B, dtype=jnp.bool_),
+                arg(S * T), arg(S * T), arg(S), arg(S + 1), arg(S, mp),
+                arg(S))
+    else:
+        def step(params, cache, state, tokens, positions, tables, lengths,
+                 rows):
+            return so.forward_prefill.__wrapped__(
+                params, cfg, tokens, positions, lengths, cache, tables,
+                last_only=True, stats=True, row_state=state, rows=rows)
+        args = (arg(1, T), arg(1, T), arg(1, mp), arg(1), arg(1))
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, cache, state, *args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((cache, state)))
+    text = compiled.as_text()
+    assert text.count("kda_update") >= 3 or program == "prefill"
+    assert 11.5e9 < mem.argument_size_in_bytes < 11.7e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    assert mem.alias_size_in_bytes >= held
+    assert not _whole_copies(compiled, (cache, state))
+    # (the mixed step's sixteen 512-token slices: 0.28 MB a grid row)
+    assert mem.temp_size_in_bytes < (0.3e9 if program != "mixed" else 2.6e9)
+
+
+def test_the_update_kernel_in_head_blocks_compiles_for_v5e(one_chip):
+    """``ops/pallas/kda_update.py`` at 64 heads of 128 x 128 and 32
+    rows: ONE kernel, the leaf aliased in and out, three 2 MiB slots
+    (a block of 32 heads) where three whole 4 MiB rows would pass the
+    walk's 8 MiB."""
+    from llmq_tpu.ops.pallas import kda_update as ku
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, B, H, d = 3, 32, 64, 128
+    assert ku.kda_update_viable(d, H, d) and ku.head_blocks(H) == 2
+
+    def step(pool, q, k, v, g, beta, rows, n_live):
+        return ku.kda_update_pallas(pool, 1, q, k, v, g, beta, rows, n_live)
+
+    wide = arg(B, H, d)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        arg(L, B + 1, d, H * d), wide, wide, wide, wide, arg(B, H),
+        arg(B, dtype=jnp.int32), arg(dtype=jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        L * (B + 1) * d * H * d * 4
+
+
 def _zaya_stage(one_chip, monkeypatch):
     """``benchmark/configs/zaya1-8b-bf16-pp2.json`` as the executor holds
     it: ``(family, cfg, params, cache, state, (B, T, page))`` as shapes on
