@@ -61,8 +61,9 @@ The residual stream is float32 (the router reads the float32 normed
 activations, ``models/deepseek_v3.py`` has why), products take bf16, the
 recurrence and what feeds its decay are float32. One period of four
 layers is held where this is served, so every program unrolls its
-layers, and the mixed step puts its slices back onto the (S, T) grid at
-the door (``models/deepseek_v3.py``).
+layers. The mixed step's slice rows lie TIGHT (``ops/rows.py``): what is
+a row's own runs over the tiles that hold a token, and what needs a
+slice a row takes the (S, T) grid (``forward_mixed``).
 """
 
 from __future__ import annotations
@@ -88,7 +89,8 @@ from llmq_tpu.ops.kda import (L2_EPS, conv_step, kda_scan_slices,
 from llmq_tpu.ops.moe import (pass_extras, route, routed_ffn,
                               share_counts)
 from llmq_tpu.ops.norms import rms_norm
-from llmq_tpu.ops.rows import grid_positions, rows_to_grid
+from llmq_tpu.ops.rows import (grid_positions, grid_to_rows, live_rows,
+                               row_tile, rows_to_grid, tile_rows)
 from llmq_tpu.ops.ssm import (conv_slices, decode_walk, own_rows,
                               rows_read, rows_write)
 from llmq_tpu.utils.profiling import scope
@@ -260,9 +262,9 @@ def step_stats_size(cfg: SolarOpen2Config) -> int:
 
 
 def mixed_live_rows(tokens: int, batch: int, slices: int, width: int) -> int:
-    """Every row of the slices' grid, whatever ``tokens`` is
-    (``models/deepseek_v3.mixed_live_rows``)."""
-    return slices * width
+    """As ``models/granitemoehybrid.mixed_live_rows``: the slice rows'
+    live tiles (the decode rows go through products of their own)."""
+    return tile_rows(tokens, row_tile(width), slices * width)
 
 
 def mixed_key_blocks(seq_lens, T: int, page_size: int, max_pages: int):
@@ -544,10 +546,10 @@ def _unit(x, cfg: SolarOpen2Config) -> jnp.ndarray:
     return x * _over_lanes(lax.rsqrt(_head_sums(x * x, cfg) + L2_EPS), cfg)
 
 
-def _kda_in(x, kp: Params, i: int, cfg: SolarOpen2Config):
-    """KDA layer ``i``'s products over the normed rows ``x`` (M, D):
-    ``(qkv (M, 3 H d) before the convolution, g (M, H d) the log-decay,
-    b (M, H) in (0, 2), z (M, H d) the output gate's logits)``, heads
+def _kda_proj(x, kp: Params, i: int, cfg: SolarOpen2Config):
+    """KDA layer ``i``'s products over the normed rows ``x`` (M, D) that
+    the convolution and the scan read: ``(qkv (M, 3 H d) before the
+    convolution, g (M, H d) the log-decay, b (M, H) in (0, 2))``, heads
     side by side on the lanes."""
     with scope("qkv"):
         qkv = jnp.dot(x, kp["wqkv"][i])
@@ -558,22 +560,40 @@ def _kda_in(x, kp: Params, i: int, cfg: SolarOpen2Config):
                        kp["a_log"][i], kp["dt_bias"][i])
         b = 2.0 * jax.nn.sigmoid(jnp.dot(
             x, kp["wb"][i], preferred_element_type=jnp.float32))
-        return qkv, g, b, low_rank(x, kp["wg_a"][i], kp["wg_b"][i])
+        return qkv, g, b
+
+
+def _kda_gate(x, kp: Params, i: int, cfg: SolarOpen2Config):
+    """The output gate's logits ``z`` (M, H d) float32 of the normed
+    rows ``x``: :func:`_kda_out` alone reads them."""
+    with scope("kda_gates"):
+        return low_rank(x, kp["wg_a"][i], kp["wg_b"][i])
+
+
+def _kda_in(x, kp: Params, i: int, cfg: SolarOpen2Config):
+    """``(qkv, g, b, z)``: :func:`_kda_proj` and :func:`_kda_gate` of
+    the same rows."""
+    return _kda_proj(x, kp, i, cfg) + (_kda_gate(x, kp, i, cfg),)
+
+
+def _kda_unit(y, cfg: SolarOpen2Config):
+    """The convolved channels ``y`` (..., 3 H d) float32 as ``(q, k, v)``
+    (..., H d) each: q and k at unit length a head, q times 1/sqrt(d).
+    Computed on the FLAT rows, heads side by side on the lanes
+    (:func:`_head_sums` has why)."""
+    W, d = cfg.kda_width, cfg.kda_head_dim
+    lead, y = y.shape[:-1], y.reshape(-1, 3 * W)
+    return tuple(x.reshape(lead + (W,)) for x in (
+        _unit(y[:, :W], cfg) * d ** -0.5, _unit(y[:, W:2 * W], cfg),
+        y[:, 2 * W:]))
 
 
 def _kda_heads(y, g, cfg: SolarOpen2Config):
-    """The convolved channels ``y`` (..., 3 H d) float32 and the
-    log-decay ``g`` (M, H d) as ``(q, k, v, g (..., H, d))``: q and k at
-    unit length a head, q times 1/sqrt(d). Everything is computed on the
-    flat rows; the heads' axis is split last, where the update and the
-    scan (which lays it flat again) take it."""
-    W, d = cfg.kda_width, cfg.kda_head_dim
-    lead = y.shape[:-1]
-    y = y.reshape(-1, 3 * W)
-    q = _unit(y[:, :W], cfg) * d ** -0.5
-    k = _unit(y[:, W:2 * W], cfg)
-    return tuple(x.reshape(lead + (cfg.kda_heads, d))
-                 for x in (q, k, y[:, 2 * W:], g))
+    """:func:`_kda_unit` of ``y`` (..., 3 H d) and the log-decay ``g``
+    (M, H d) as ``(q, k, v, g (..., H, d))``: the heads' axis is split
+    last, where the update takes it."""
+    return tuple(x.reshape(y.shape[:-1] + (cfg.kda_heads, cfg.kda_head_dim))
+                 for x in _kda_unit(y, cfg) + (g,))
 
 
 def _kda_out(h, o, z, kp: Params, i: int, cfg: SolarOpen2Config):
@@ -610,44 +630,99 @@ def _kda_decode(h, x, kp: Params, i: int, rs: RowState, active, walk,
             {"kda": kda, "conv": conv})
 
 
-def _kda_slices(h, x, kp: Params, i: int, rs: RowState, rows, first,
-                lengths, cfg: SolarOpen2Config):
-    """S slices of T tokens through KDA layer ``i``: ``h``, ``x``
-    (S, T, D); ``rows`` (S,) the batch row each slice's sequence owns
-    (one past the batch's last: nobody's), ``first`` (S,) whether the
-    slice starts its sequence (a zero state), ``lengths`` (S,)."""
-    S, T, H = x.shape[0], x.shape[1], cfg.kda_heads
-    qkv, g, b, z = _kda_in(x.reshape(S * T, -1), kp, i, cfg)
+def _kda_scan(qkv, g, b, kp: Params, i: int, rs: RowState, rows, first,
+              lengths, cfg: SolarOpen2Config, tight=None):
+    """S slices of T tokens through KDA layer ``i``'s convolution and
+    scan: ``qkv`` (S, T, 3 H d), ``g`` (S, T, H d), ``b`` (S, T, H) on
+    the grid (:func:`_kda_proj`'s); ``rows`` (S,) the batch row each
+    slice's sequence owns (one past the batch's last: nobody's),
+    ``first`` (S,) whether the slice starts its sequence (a zero state),
+    ``lengths`` (S,). Returns ``(o (S, T, H d) float32, row state)``.
+
+    ``tight=(starts, used)``: the three lie TIGHT, (S T, ...), slice
+    ``s`` from row ``starts[s]`` (``ops/rows.py``), and the first
+    ``used`` slices are in use (a traced scalar). The convolution and
+    the unit norms then run over those, a slice at a time
+    (``ops/rows.live_rows`` with a slice as its tile): each trip cuts
+    its slice's T rows out of the tight buffers where they lie — that
+    IS the move onto the grid, and an unused slice is neither moved nor
+    computed (its q, k, v, g and b are zero) —, and one slice's
+    (T, 3 H d) float32 stands at a time where all S stood (805 MB at
+    16 x 512). The scan kernel skips what lies past a slice's length by
+    itself."""
+    S, H = lengths.shape[0], cfg.kda_heads
+    T = qkv.shape[0] // S if tight else qkv.shape[1]
     kda, conv = rs["kda"], rs["conv"]
     keep = ~first[:, None, None]
+
+    def conv_unit(win, x, n):
+        with scope("ssm_conv"):
+            y, win = conv_slices(win, x, n, kp["conv_w"][i], _conv_bias(cfg))
+        with scope("ssm_scan"):
+            return _kda_unit(y, cfg) + (win,)
+
     with scope("ssm_conv"):
-        win = rows_read(conv, i, rows).reshape(S, cfg.kda_conv - 1, -1)
-        y, win = conv_slices(jnp.where(keep, win, 0), qkv.reshape(S, T, -1),
-                             lengths, kp["conv_w"][i], _conv_bias(cfg))
+        win = jnp.where(keep, rows_read(conv, i, rows).reshape(
+            S, cfg.kda_conv - 1, -1), 0)
+    if tight:
+        starts, used = tight
+
+        def some_slices(win, n, at):    # (one a trip; all S where S <= 2)
+            qkv_s, g_s, b_s = (jnp.stack([
+                lax.dynamic_slice_in_dim(x, at[j], T)
+                for j in range(at.shape[0])]) for x in (qkv, g, b))
+            return conv_unit(win, qkv_s, n) + (g_s, b_s)
+
+        q, k, v, win, g, b = live_rows(some_slices, used, 1, win, lengths,
+                                       starts[:S])
+    else:
+        q, k, v, win = conv_unit(win, qkv, lengths)
+    with scope("ssm_conv"):
         conv = rows_write(conv, i, rows, win.reshape(S, -1))
     with scope("ssm_scan"):
-        q, k, v, g = _kda_heads(y, g.reshape(S, T, -1), cfg)
         before = rows_read(kda, i, rows, enabled=cfg.pallas)
-        o, st = kda_scan_slices(jnp.where(keep, before, 0), q, k, v, g,
-                                b.reshape(S, T, H), lengths, cfg.kda_chunk,
-                                enabled=cfg.pallas)
+        o, st = kda_scan_slices(
+            jnp.where(keep, before, 0),
+            *(x.reshape(S, T, H, -1) for x in (q, k, v, g)), b, lengths,
+            cfg.kda_chunk, enabled=cfg.pallas)
         kda = rows_write(kda, i, rows, st, enabled=cfg.pallas)
+    return o.reshape(S, T, -1), {"kda": kda, "conv": conv}
+
+
+def _kda_slices(h, x, kp: Params, i: int, rs: RowState, rows, first,
+                lengths, cfg: SolarOpen2Config):
+    """S slices of T tokens through KDA layer ``i``, all of them on the
+    grid: ``h``, ``x`` (S, T, D); the rest as :func:`_kda_scan`."""
+    S, T = x.shape[:2]
+    qkv, g, b, z = _kda_in(x.reshape(S * T, -1), kp, i, cfg)
+    o, rs = _kda_scan(qkv.reshape(S, T, -1), g.reshape(S, T, -1),
+                      b.reshape(S, T, -1), kp, i, rs, rows, first, lengths,
+                      cfg)
     h = _kda_out(h.reshape(S * T, -1), o.reshape(z.shape), z, kp, i, cfg)
-    return h.reshape(S, T, -1), {"kda": kda, "conv": conv}
+    return h.reshape(S, T, -1), rs
+
+
+def _qkv(x, gp: Params, i: int, cfg: SolarOpen2Config):
+    """GQA layer ``i``'s q (..., H * hd), k and v (..., G * hd) of the
+    normalised rows ``x`` (..., D), FLAT. No rotary embedding and no
+    q/k norm: the keys go to the pages as the product left them."""
+    with scope("qkv"):
+        return tuple(jnp.dot(x, gp[w][i]) for w in ("wq", "wk", "wv"))
+
+
+def _gate(x, gp: Params, i: int, cfg: SolarOpen2Config):
+    """GQA layer ``i``'s gate (..., H * hd) of the same rows:
+    :func:`_attn_close` alone reads it."""
+    with scope("attn_gate"):
+        return jnp.dot(x, gp["wg"][i])
 
 
 def _qkvg(x, gp: Params, i: int, cfg: SolarOpen2Config):
-    """GQA layer ``i``'s q, k, v and gate of the normalised rows ``x``
-    (..., D): q (..., H, hd), k, v (..., G, hd), gate (..., H * hd). No
-    rotary embedding and no q/k norm: the keys go to the pages as the
-    product left them."""
-    with scope("qkv"):
-        q, k, v = (jnp.dot(x, gp[w][i]).reshape(x.shape[:-1]
-                                                + (-1, cfg.head_dim))
-                   for w in ("wq", "wk", "wv"))
-    with scope("attn_gate"):
-        gate = jnp.dot(x, gp["wg"][i])
-    return q, k, v, gate
+    """:func:`_qkv` split into heads — q (..., H, hd), k, v (..., G,
+    hd) — and :func:`_gate`."""
+    q, k, v = (y.reshape(x.shape[:-1] + (-1, cfg.head_dim))
+               for y in _qkv(x, gp, i, cfg))
+    return q, k, v, _gate(x, gp, i, cfg)
 
 
 def _attn_close(h, attn, gate, gp: Params, i: int, cfg: SolarOpen2Config):
@@ -660,11 +735,10 @@ def _attn_close(h, attn, gate, gp: Params, i: int, cfg: SolarOpen2Config):
         return h + jnp.dot(a, gp["wo"][i]).astype(jnp.float32)
 
 
-def _gqa_slices(h, x, gp: Params, i: int, kv_cache: KVCache, tables,
-                positions, lengths, seq_lens, cfg: SolarOpen2Config):
-    """S slices through GQA layer ``i`` (its slice of the stacked leaves
-    and its layer of the pool): written, then attended."""
-    q, k, v, gate = _qkvg(x, gp, i, cfg)
+def _gqa_attend(q, k, v, i: int, kv_cache: KVCache, tables, positions,
+                lengths, seq_lens, cfg: SolarOpen2Config):
+    """S slices through GQA layer ``i``'s layer of the pool: k, v (S, T,
+    G, hd) written, then q (S, T, H, hd) attended."""
     layer = jnp.asarray(i, jnp.int32)
     with scope("kv_write"):
         k_pool, v_pool = paged_kv_write_prefill(
@@ -674,7 +748,7 @@ def _gqa_slices(h, x, gp: Params, i: int, kv_cache: KVCache, tables,
         attn = dispatch_prefill_attention(
             q, k_pool, v_pool, tables, positions, seq_lens, layer,
             enabled=cfg.pallas, multi_ok=cfg.pallas_batched_prefill)
-    return attn, gate, {"k": k_pool, "v": v_pool}
+    return attn, {"k": k_pool, "v": v_pool}
 
 
 def _gqa_decode(h, x, gp: Params, i: int, kv_cache: KVCache, geom,
@@ -690,11 +764,11 @@ def _gqa_decode(h, x, gp: Params, i: int, kv_cache: KVCache, geom,
             {"k": k_pool, "v": v_pool})
 
 
-def _ffn(params: Params, cfg: SolarOpen2Config, l: int, h, live):
-    """Layer ``l``'s routed feed-forward over the stream's rows h
-    (N, D). Returns (h', stats, experts): ``ops/moe.routed_ffn``'s
-    counts as ``step_stats_layout`` has them (without ``runs``), and the
-    experts ``ops/moe.route`` chose for each row (N, k)."""
+def _ffn_in(params: Params, cfg: SolarOpen2Config, l: int, h):
+    """What layer ``l``'s feed-forward reads of the stream's rows h
+    (M, D), each row its own: ``(x the normed rows in the products'
+    type, experts, gates (M, k) — ``ops/moe.route``'s of the float32
+    normed rows)``."""
     with scope("mlp"):
         xf = rms_norm(h, params["layers"]["mlp_norm"][l], cfg.norm_eps)
         x = xf.astype(cfg.dtype)
@@ -703,13 +777,37 @@ def _ffn(params: Params, cfg: SolarOpen2Config, l: int, h, live):
         xf, m["router"][l], m["router_bias"][l],
         top_k=cfg.n_experts_per_tok, scale=cfg.routed_scaling_factor,
         norm_topk=cfg.norm_topk_prob, scoring="sigmoid")
+    return x, experts, gates
+
+
+def _shared(params: Params, cfg: SolarOpen2Config, l: int, x):
+    """Layer ``l``'s shared expert over the normed rows ``x``."""
+    m = params["moe"]
+    with scope("mlp"):
+        return _mlp(x, m["ws_gate"][l], m["ws_up"][l], m["ws_down"][l])
+
+
+def _routed(params: Params, cfg: SolarOpen2Config, l: int, x, experts, gates,
+            live):
+    """Layer ``l``'s held experts over :func:`_ffn_in`'s rows: ``(y,
+    ops/moe.routed_ffn's counts as step_stats_layout has them, without
+    runs)``."""
+    m = params["moe"]
     y, st = routed_ffn(x, experts, gates, m["we_gate_up"][l],
                        m["we_down"][l], live, held=cfg.held,
                        n_routed=cfg.n_routed_experts)
-    st = share_counts(st, cfg.n_held)
-    with scope("mlp"):        # the shared expert, beside the routed ones
-        return h + y + _mlp(x, m["ws_gate"][l], m["ws_up"][l],
-                            m["ws_down"][l]), st, experts
+    return y, share_counts(st, cfg.n_held)
+
+
+def _ffn(params: Params, cfg: SolarOpen2Config, l: int, h, live):
+    """Layer ``l``'s routed feed-forward over the stream's rows h
+    (N, D). Returns (h', stats, experts): :func:`_routed`'s counts and
+    the experts ``ops/moe.route`` chose for each row (N, k)."""
+    x, experts, gates = _ffn_in(params, cfg, l, h)
+    y, st = _routed(params, cfg, l, x, experts, gates, live)
+    shared = _shared(params, cfg, l, x)       # beside the routed ones
+    with scope("mlp"):
+        return h + y + shared, st, experts
 
 
 # -- forward ------------------------------------------------------------------
@@ -746,9 +844,9 @@ def forward_prefill(params: Params, cfg: SolarOpen2Config,
             h, row_state = _kda_slices(h, x, params["kda"], i, row_state,
                                        rows, first, lengths, cfg)
         else:
-            attn, gate, kv_cache = _gqa_slices(
-                h, x, params["gqa"], i, kv_cache, block_tables, positions,
-                lengths, seq_lens, cfg)
+            q, k, v, gate = _qkvg(x, params["gqa"], i, cfg)
+            attn, kv_cache = _gqa_attend(q, k, v, i, kv_cache, block_tables,
+                                         positions, lengths, seq_lens, cfg)
             h = _attn_close(h, attn, gate, params["gqa"], i, cfg)
         h, *took = _ffn(params, cfg, l, h.reshape(B * T, -1),
                         valid.reshape(-1))
@@ -818,24 +916,37 @@ def forward_mixed(params: Params, cfg: SolarOpen2Config,
     ``row_state`` and ``pf_rows`` (S,): the batch row each slice's
     sequence owns; an unused slice names one past the last row. A slice
     is never one of the step's active decode rows, so the two halves of
-    a layer touch different rows of the state and different pages. The
-    slices go back onto the (S, T) grid at the door
-    (``mixed_live_rows``); the feed-forward runs slices and decode rows
-    together, so a routed layer's experts are streamed once for both.
+    a layer touch different rows of the state and different pages.
+
+    What is a row's own runs over the tight rows a live tile at a time
+    (``ops/rows.live_rows``, ``mixed_live_rows``), in two tiles a
+    layer: the norm and the products the mixer reads (``_kda_proj``,
+    ``_qkv``) in front of it; behind it the output gate — made beside
+    its only reader, of the tile's norm made again, so that nothing of
+    a gate's size outlives the convolution and the scan
+    (``models/granitemoehybrid.forward_mixed``) —, the mixer's close
+    and what the feed-forward reads of a row (its norm, the router,
+    the shared expert). The convolution, the scan, the K/V write and
+    the attention take the (S, T) grid, a slice a row (``_kda_scan``
+    over the slices in use). The routed experts take slices and decode
+    rows together, so a layer's experts are streamed once for both; the
+    decode rows' shared expert is a product of their own. A row past
+    ``pf_starts[S]`` holds no token and is routed nowhere (an unused
+    slice's position, the first dead row, is not counted).
     Returns ``(dec_logits (B, V), pf_logits (S, V), cache, row_state)``
-    and ``pass_extras`` after them (``chosen``: the slices' S * T grid
+    and ``pass_extras`` after them (``chosen``: the slices' S * T GRID
     rows, then the B decode rows)."""
     B = dec_tokens.shape[0]
     S = pf_lengths.shape[0]
-    T = pf_tokens.shape[0] // S
+    N = pf_tokens.shape[0]
+    T = N // S
     row_state, _ = own_rows(partial(init_row_state, cfg), B, row_state,
                             None)
     if pf_rows is None:
         pf_rows = jnp.full((S,), B, jnp.int32)
     live_d = jnp.ones((B,), bool) if dec_active is None else dec_active
-    pf_tokens = rows_to_grid(pf_tokens, pf_starts, T)
-    pf_positions, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
-                                               pf_starts, T)
+    n_live = pf_starts[S]
+    used = jnp.sum(pf_starts[:S] < n_live)      # they come first
     with scope("decode_rows"):
         h_d = _embed(params, dec_tokens)
         geom = decode_geometry(
@@ -844,47 +955,91 @@ def forward_mixed(params: Params, cfg: SolarOpen2Config,
         walk = decode_walk(live_d)
     with scope("slices"):
         h_p = _embed(params, pf_tokens)
-        pf_valid = jnp.arange(T)[None, :] < pf_lengths[:, None]
-        first = pf_positions[:, 0] == 0
-    live = jnp.concatenate([pf_valid.reshape(-1), live_d])
+        grid_pos, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
+                                               pf_starts, T)
+        first = grid_pos[:, 0] == 0
+    live = jnp.concatenate([jnp.arange(N) < n_live, live_d])
     lp, counts = params["layers"], []
+
+    def tiles(fn, *rows):
+        return live_rows(fn, n_live, row_tile(T), *rows)
+
+    def to_grid(x):
+        return rows_to_grid(x, pf_starts, T)
+
+    def to_rows(x):
+        return grid_to_rows(x, pf_starts,
+                            jnp.zeros((N,) + x.shape[2:], x.dtype))
+
     for l, kind in enumerate(cfg.layer_types):
         i = cfg.kind_index(l)
-        with scope("slices"):
+        mp = params[kind]          # (a kind is named as its leaves' group)
+
+        def normed(h):
             with scope("qkv"):
-                x = _normed(h_p, lp["attn_norm"][l], cfg)
+                return _normed(h, lp["attn_norm"][l], cfg)
+
+        with scope("slices"):
             if kind == KDA:
-                h_p, row_state = _kda_slices(
-                    h_p, x, params["kda"], i, row_state, pf_rows, first,
-                    pf_lengths, cfg)
+                qkv, g, b = tiles(
+                    lambda h: _kda_proj(normed(h), mp, i, cfg), h_p)
+                mixed, row_state = _kda_scan(
+                    qkv, g, b, mp, i, row_state, pf_rows, first, pf_lengths,
+                    cfg, tight=(pf_starts, used))
+
+                def close(h, o):
+                    z = _kda_gate(normed(h), mp, i, cfg)
+                    return _kda_out(h, o, z, mp, i, cfg)
             else:
-                attn, gate, kv_cache = _gqa_slices(
-                    h_p, x, params["gqa"], i, kv_cache, pf_block_tables,
-                    pf_positions, pf_lengths, pf_seq_lens, cfg)
+                q, k, v = (to_grid(x).reshape(S, T, -1, cfg.head_dim)
+                           for x in tiles(
+                               lambda h: _qkv(normed(h), mp, i, cfg), h_p))
+                mixed, kv_cache = _gqa_attend(
+                    q, k, v, i, kv_cache, pf_block_tables, grid_pos,
+                    pf_lengths, pf_seq_lens, cfg)
                 # The decode rows' write takes the pools in place: only
                 # once the slices' attention has read them, or XLA copies
                 # a whole pool to keep both (models/granitemoehybrid).
-                attn, kv_cache = jax.lax.optimization_barrier(
-                    (attn, kv_cache))
-                h_p = _attn_close(h_p, attn, gate, params["gqa"], i, cfg)
+                mixed, kv_cache = jax.lax.optimization_barrier(
+                    (mixed, kv_cache))
+                mixed = mixed.reshape(S, T, -1)
+
+                def close(h, attn):
+                    gate = _gate(normed(h), mp, i, cfg)
+                    return _attn_close(h, attn, gate, mp, i, cfg)
+
+            def behind(h, mixed):
+                h = close(h, mixed)
+                x, experts, gates = _ffn_in(params, cfg, l, h)
+                return h, x, experts, gates, _shared(params, cfg, l, x)
+
+            h_p, x_p, took_p, gates_p, shared_p = tiles(behind, h_p,
+                                                        to_rows(mixed))
         with scope("decode_rows"):
-            with scope("qkv"):
-                x = _normed(h_d, lp["attn_norm"][l], cfg)
+            x = normed(h_d)
             if kind == KDA:
-                h_d, row_state = _kda_decode(h_d, x, params["kda"], i,
-                                             row_state, live_d, walk, cfg)
+                h_d, row_state = _kda_decode(h_d, x, mp, i, row_state,
+                                             live_d, walk, cfg)
             else:
-                h_d, kv_cache = _gqa_decode(h_d, x, params["gqa"], i,
-                                            kv_cache, geom, cfg)
-        # The feed-forward takes both kinds of row side by side (its
-        # matrices are streamed once): no row kind on its scopes.
-        h, *took = _ffn(params, cfg, l,
-                        jnp.concatenate([h_p.reshape(S * T, -1), h_d]), live)
-        h_p, h_d = h[:S * T].reshape(S, T, -1), h[S * T:]
-        counts.append(took)
+                h_d, kv_cache = _gqa_decode(h_d, x, mp, i, kv_cache, geom,
+                                            cfg)
+            x_d, took_d, gates_d = _ffn_in(params, cfg, l, h_d)
+            shared_d = _shared(params, cfg, l, x_d)
+        # The held experts take both kinds of row side by side (their
+        # matrices are streamed once): no row kind on their scopes.
+        y, st = _routed(params, cfg, l, jnp.concatenate([x_p, x_d]),
+                        jnp.concatenate([took_p, took_d]),
+                        jnp.concatenate([gates_p, gates_d]), live)
+        with scope("mlp"):
+            h_p, h_d = h_p + y[:N] + shared_p, h_d + y[N:] + shared_d
+        if chosen:                 # the choices leave in grid order
+            with scope("slices"):
+                took_p = to_grid(took_p).reshape(N, -1)
+            took_p = jnp.concatenate([took_p, took_d])
+        counts.append((st, took_p))
     with scope("slices"):
         with scope("head"):
-            h_p = h_p[jnp.arange(S), pf_lengths - 1]
+            h_p = h_p[pf_starts[:S] + pf_lengths - 1]
         pf_logits = _head(params, cfg, h_p)
     with scope("decode_rows"):
         dec_logits = _head(params, cfg, h_d)

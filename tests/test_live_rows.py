@@ -102,6 +102,28 @@ def test_the_rows_behind_the_lead_alone_decide_whether_rows_loop(
                                          width) == ran - lead
 
 
+@pytest.mark.parametrize("tokens", [0, 1, 511, 512, 4100, 8192])
+def test_solar_open2_counts_its_slice_rows_live_tiles(tokens):
+    """``solar_open2.mixed_live_rows`` at the served shape (sixteen
+    512-token slices, 32 decode rows that go through products of their
+    own, so none leads): ``tile_rows``' rule, which is the trip count of
+    the loop ``forward_mixed`` runs its row-wise products in
+    (``live_rows`` over the S x T tight rows, no lead) times the tile —
+    never under the prompt's tokens, under a tile over them, and never
+    the 8,192 grid rows unless they all hold a token."""
+    from llmq_tpu.models import solar_open2
+    S, T, B = 16, 512, 32
+    tile = rows.row_tile(T)
+    assert tile == 256 and rows.worth_a_loop(S * T, tile)
+    got = solar_open2.mixed_live_rows(tokens, B, S, T)
+    assert got == rows.tile_rows(tokens, tile, S * T) == -(-tokens // tile) * tile
+    assert tokens <= got < tokens + tile and got <= S * T
+    run = jax.jit(lambda x, n: rows.live_rows(lambda t: t + 1, n, tile, x))
+    out = np.asarray(run(jnp.zeros((S * T, 1), jnp.float32),
+                         jnp.int32(tokens)))
+    assert int(out.sum()) == got                 # rows that were computed
+
+
 def test_each_result_keeps_the_type_fn_gives_it():
     """The loop's buffers take ``fn``'s own result types — a bfloat16
     product beside the float32 residual it was added to, as a latent

@@ -750,3 +750,27 @@ def test_a_mixed_chunk_counts_its_live_tiles_rows(family, tokens, batch,
     assert EchoExecutor.ROW_TILE == rows.ROW_TILE
     assert echo.slice_tokens("mixed_chunk", tokens) == (
         family_module("llama").mixed_live_rows(tokens, batch, slices, width))
+
+
+@pytest.mark.parametrize("family", ["granitemoehybrid", "solar_open2"])
+@pytest.mark.parametrize("tokens,batch,slices,width", _PLANS + [
+    (1, 32, 16, 512), (4100, 32, 16, 512), (8192, 32, 16, 512)])
+def test_a_mixed_chunk_counts_its_slice_rows_tiles_where_no_row_leads(
+        family, tokens, batch, slices, width):
+    """The two row-state families run their decode rows through
+    products of their own, so no row leads the slices' tight rows: a
+    mixed chunk's ``slice_tokens`` is the live tiles' rows of the S x T
+    buffer alone — ``live_rows``' trip count times the tile, whatever
+    the batch — and every row where the rows are two tiles or fewer."""
+    from llmq_tpu.models import family as family_module
+    from llmq_tpu.ops import rows
+    fam = family_module(family)
+    tile = rows.row_tile(width)
+    got = fam.mixed_live_rows(tokens, batch, slices, width)
+    assert got == fam.mixed_live_rows(tokens, 0, slices, width)
+    assert tokens <= got <= slices * width
+    if not rows.worth_a_loop(slices * width, tile):
+        assert got == slices * width
+    else:
+        assert got == min(-(-tokens // tile) * tile, slices * width)
+        assert got < tokens + tile

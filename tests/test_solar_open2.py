@@ -258,7 +258,7 @@ def test_a_mixed_step_with_a_live_and_an_idle_slice(tiny):
                       [row])
         assert got["ok"], got
     c = _counts(cfg, st)
-    live = 29 + 2 + 1          # and the idle slice's one trash token
+    live = 29 + 2              # (the idle slice's trash token is no row's)
     assert c["runs"] == cfg.n_layers == 4
     assert (c["load"].sum() + c["away_slots"]
             == live * cfg.n_experts_per_tok * c["runs"])
@@ -377,6 +377,163 @@ def test_position_zero_starts_from_a_zero_state(tiny):
                                    0, 25, 1)
     got = verdict(cfg, params, seq[:25], logits[None], [24])
     assert got["ok"], got
+
+
+# -- ``tests/mixed_tight.py``'s cases, with row state ---------------------------
+
+
+@pytest.fixture
+def tight(monkeypatch):
+    """``tight(as_written)`` -> the three forward functions, the mixed
+    step under a jit of this module's own that is traced (at its first
+    call, inside the test) with ``mixed_tight.TILE``-row tiles in slices
+    ``WIDTH`` wide, so that a tile's edge falls inside a slice; no other
+    test meets a program traced with the small tile. ``as_written``: all
+    three compiled to round where their source rounds
+    (``mixed_tight._forward`` has why)."""
+    import mixed_tight as mt
+    monkeypatch.setattr(mt.rows, "ROW_TILE", mt.TILE)
+
+    def fns(as_written):
+        if as_written not in _TIGHT:
+            opts = ({"xla_allow_excess_precision": False} if as_written
+                    else None)
+            _TIGHT[as_written] = SimpleNamespace(**{
+                name: (getattr(so, name) if not as_written
+                       and name != "forward_mixed" else jax.jit(
+                    getattr(so, name).__wrapped__, compiler_options=opts,
+                    static_argnames=("cfg", "stats", "chosen") + also))
+                for name, also in (("forward_prefill", ("last_only",)),
+                                   ("forward_decode", ()),
+                                   ("forward_mixed", ()))})
+        return _TIGHT[as_written]
+    return fns
+
+
+_TIGHT = {}
+
+
+def _both_ways(fns, cfg, params, case):
+    """The case's plan apart (each slice through ``forward_prefill``,
+    then the rows' ``forward_decode``) and together (one
+    ``forward_mixed`` over tight slices), over the same pool and row
+    state: ``{"dec", "pf", "pages", "state", "took"}`` each — ``took``
+    the experts chosen for the used slices' tokens, in the plan's order,
+    then for the active decode rows. The decode rows own batch rows
+    0 .. B - 1, slice ``s`` row B + s; the last decode row is not
+    active."""
+    import mixed_tight as mt
+    S, T, plan = mt.shape_of(case)
+    B = len(mt.DECODE)
+    rng = np.random.default_rng(sorted(mt.CASES).index(case))
+    mp = cfg.max_seq_len // PAGE
+    bts = block_table(cfg, B + S)
+    cache, state = new_cache(cfg, B + S)
+
+    def draw(n):
+        return rng.integers(3, cfg.vocab_size, n, dtype=np.int32)
+
+    def one(cache, state, row, toks, start, width=T):
+        n = len(toks)
+        padded = np.zeros((1, width), np.int32)
+        padded[0, :n] = toks
+        pos = start + np.minimum(np.arange(width, dtype=np.int32), n - 1)
+        logits, cache, state, took = fns.forward_prefill(
+            params, cfg, jnp.asarray(padded), jnp.asarray(pos[None]),
+            jnp.asarray([n], jnp.int32), cache, jnp.asarray(bts[row][None]),
+            last_only=True, row_state=state,
+            rows=jnp.asarray([row], jnp.int32), chosen=True)
+        return np.asarray(logits)[0], cache, state, np.asarray(took)[:, :n]
+
+    for b, n in enumerate(mt.DECODE):
+        _, cache, state, _ = one(cache, state, b, draw(n), 0)
+    for s, (_, start) in enumerate(plan):
+        if start:
+            _, cache, state, _ = one(cache, state, B + s, draw(start), 0)
+    slices = [draw(n) for n, _ in plan]
+    dec_tok, dec_pos = draw(B), np.asarray(mt.DECODE, np.int32)
+    active = np.arange(B) < B - 1
+
+    def result(dec, pf, cache, state, took):
+        return {"dec": np.asarray(dec)[active],
+                "pf": np.asarray(pf)[:len(plan)],
+                "pages": {x: np.asarray(cache[x][:, 1:], np.float32)
+                          for x in cache},
+                "state": {x: np.asarray(v[:, :B + S], np.float32)
+                          for x, v in state.items()},
+                "took": np.concatenate(took, axis=1)}
+
+    ref_c, ref_s = jax.tree.map(jnp.copy, (cache, state))
+    ref_pf, ref_took = [], []
+    for s, (toks, (_, start)) in enumerate(zip(slices, plan)):
+        logits, ref_c, ref_s, took = one(ref_c, ref_s, B + s, toks, start)
+        ref_pf.append(logits)
+        ref_took.append(took)
+    ref_dec, ref_c, ref_s, took = fns.forward_decode(
+        params, cfg, jnp.asarray(dec_tok), jnp.asarray(dec_pos), ref_c,
+        jnp.asarray(bts[:B]), active=jnp.asarray(active), row_state=ref_s,
+        chosen=True)
+    parts = result(ref_dec, np.stack(ref_pf), ref_c, ref_s,
+                   ref_took + [np.asarray(took)[:, active]])
+
+    g_t, g_p = np.zeros((S, T), np.int32), np.zeros((S, T), np.int32)
+    lens, rows = np.ones((S,), np.int32), np.full((S,), B + S, np.int32)
+    pf_bt = np.zeros((S, mp), np.int32)
+    for s, (toks, (n, start)) in enumerate(zip(slices, plan)):
+        g_t[s, :n], g_p[s, :n] = toks, start + np.arange(n)
+        lens[s], rows[s], pf_bt[s] = n, B + s, bts[B + s]
+    pf_tok, pf_pos, starts = pack_grid(g_t, g_p, lens, used=len(plan))
+    bt_dec = np.zeros((B + S, mp), np.int32)
+    bt_dec[:B] = bts[:B]
+    tok, pos = np.zeros(B + S, np.int32), np.zeros(B + S, np.int32)
+    tok[:B], pos[:B] = dec_tok, dec_pos
+    live = np.zeros(B + S, bool)
+    live[:B] = active
+    dec, pf, cache, state, took = fns.forward_mixed(
+        params, cfg, jnp.asarray(tok), jnp.asarray(pos), cache,
+        jnp.asarray(bt_dec), jnp.asarray(pf_tok), jnp.asarray(pf_pos),
+        jnp.asarray(lens), jnp.asarray(starts), jnp.asarray(pf_bt),
+        dec_active=jnp.asarray(live), row_state=state,
+        pf_rows=jnp.asarray(rows), chosen=True)
+    took = np.asarray(took)
+    assert took.shape == (cfg.n_layers, S * T + B + S, cfg.n_experts_per_tok)
+    # the slices' S * T GRID rows, then the decode rows
+    in_grid = [took[:, s * T:s * T + n] for s, (n, _) in enumerate(plan)]
+    return parts, result(np.asarray(dec)[:B], pf, cache, state,
+                         in_grid + [took[:, S * T:S * T + B][:, active]])
+
+
+@pytest.mark.parametrize("served", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(__import__("mixed_tight").CASES))
+def test_the_mixed_step_over_tight_slices_computes_what_the_parts_do(
+        tiny, tight, case, served):
+    """``tests/mixed_tight.py``'s ``CASES`` — slices full, ending on and
+    beside a tile's edge, unused, of one token, continuing a context —
+    through ``forward_mixed`` (rows in live tiles of 8, the convolution
+    a slice in use at a time) against ``forward_prefill`` +
+    ``forward_decode`` over the same pool AND row state: the logits, the
+    K/V pages, both row-state leaves of every row, and the chosen
+    experts in GRID order. In float32, and in bfloat16 as served with
+    all three programs compiled to round where their source rounds:
+    there the two ways read 0.0 apart in every case, state and choices
+    included — no row that holds a token is computed differently. (Left
+    to keep a bfloat16 chain wide inside a fusion, XLA's CPU backend
+    does so in the whole-grid parts and not in a loop's body, and the
+    logits read 0.04-0.35 apart through 0-11 swapped experts.)"""
+    cfg, params, _ = tiny
+    atol = 2e-5
+    if served:
+        cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
+        params = so.init_params(jax.random.PRNGKey(53), cfg)
+        atol = 1e-3
+    parts, mixed = _both_ways(tight(served), cfg, params, case)
+    for r in (parts, mixed):
+        r.update(r.pop("pages"), **r.pop("state"))
+    assert set(parts) == {"dec", "pf", "k", "v", "kda", "conv", "took"}
+    for name in set(parts) - {"took"}:
+        assert np.abs(parts[name] - mixed[name]).max() <= atol, name
+    np.testing.assert_array_equal(np.sort(mixed["took"]),
+                                  np.sort(parts["took"]))
 
 
 # -- the broken paths, each of which the comparison refuses -------------------
