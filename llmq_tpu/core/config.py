@@ -813,8 +813,19 @@ class PrefixCacheConfig:
     max_cached_pages: int = 0
     #: "lru" (default) or "fifo" — which zero-ref leaf goes first.
     eviction: str = "lru"
+    #: Tail slots on the device, for a model family whose row state a
+    #: window of K/V rebuilds (docs/prefix_cache.md "Tails"): each holds
+    #: what lets ONE cached page boundary be adopted by such a family
+    #: (its size is the family's: ``get_stats()["row_state"]``). 0 (the
+    #: default): none — a prefix hit for a row-state family is declined
+    #: and counted, as before there were tails. Ignored by a family
+    #: whose pages are its whole cache.
+    row_tail_slots: int = 0
 
     def __post_init__(self) -> None:
+        if self.row_tail_slots < 0:
+            raise ValueError(
+                f"prefix_cache.row_tail_slots {self.row_tail_slots} < 0")
         if self.eviction not in VALID_PREFIX_EVICTION:
             raise ValueError(
                 f"unknown prefix-cache eviction policy {self.eviction!r}; "

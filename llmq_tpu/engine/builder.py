@@ -48,7 +48,10 @@ def executor_geometry(cfg: Config) -> dict:
         cache_dtype=(jnp.int8 if getattr(cfg.model, "kv_quantization", "")
                      == "int8" else None),
         mixed_prefill_slices=mixed_slices,
-        mixed_slice_tokens=mixed_slice_tokens)
+        mixed_slice_tokens=mixed_slice_tokens,
+        row_tail_slots=(int(getattr(ex.prefix_cache, "row_tail_slots", 0))
+                        if getattr(ex.prefix_cache, "enabled", False)
+                        else 0))
 
 
 def build_engine(cfg: Config, *, name: str = "engine0",

@@ -247,7 +247,8 @@ class _Sequence:
                  "todo_ids", "todo_pos", "todo_rebuild", "todo_resume",
                  "first_handle", "eff_prio", "arrival", "prefix_match",
                  "reuse_counted", "mixed_pending", "pf_tokens_run",
-                 "usage", "pending_emit", "served_tier", "cp_decode_s")
+                 "usage", "pending_emit", "served_tier", "cp_decode_s",
+                 "tails")
 
     def __init__(self, req: GenRequest, handle: GenHandle, order: int,
                  max_pages: int) -> None:
@@ -296,6 +297,11 @@ class _Sequence:
         #: ``pages``) and one lock per matched node — unlocked whenever
         #: the pages leave the sequence (finish, shed, un-match).
         self.prefix_match = None
+        #: Row tails this sequence took on its way (``[(end token, tail
+        #: slot)]``, docs/prefix_cache.md "Tails"): each waits for the
+        #: radix node that ends there, which exists once the stream is
+        #: published; what is left when the sequence ends is freed.
+        self.tails: List = []
         #: Hit/miss counted for this REQUEST (first admission only —
         #: a shed-and-rebuilt sequence must not re-count its reuse).
         self.reuse_counted = False
@@ -619,13 +625,27 @@ class InferenceEngine:
         #: ``prefix_cache`` accepts a core.config.PrefixCacheConfig or
         #: anything with the same fields.
         self._prefix_cache = None
+        self._row_tail = None
         if prefix_cache is not None and getattr(prefix_cache, "enabled",
                                                 False):
             from llmq_tpu.prefixcache import PrefixCache
+            #: What rebuilds a row's state at a page boundary, for a
+            #: family that says so and an executor that holds tail
+            #: slots (``executor.row_tail``), else None: a prefix hit
+            #: for a row-state family is then declined.
+            self._row_tail = getattr(executor, "row_tail", None)
+            tail_kw = {}
+            if self._row_tail is not None:
+                page_bytes = sum(
+                    int(np.prod(shape)) * np.dtype(dt).itemsize
+                    for shape, dt in executor.kv_page_spec())
+                tail_kw = {"tail_slots": self._row_tail["slots"],
+                           "tail_cost_pages": -(-self._row_tail["bytes"]
+                                                // max(1, page_bytes))}
             self._prefix_cache = PrefixCache(
                 self.allocator, self.spec.page_size,
                 max_pages=int(getattr(prefix_cache, "max_cached_pages", 0)),
-                policy=getattr(prefix_cache, "eviction", "lru"))
+                policy=getattr(prefix_cache, "eviction", "lru"), **tail_kw)
         #: Admission-level reuse counters (engine-local so benches with
         #: prometheus disabled can still read them): an admission that
         #: starts from cached KV — a pinned conversation or a radix
@@ -800,6 +820,16 @@ class InferenceEngine:
         self.row_state_rebuilds = 0
         self.row_state_declined = {"prefix": 0, "conversation": 0,
                                    "tiering": 0, "disagg": 0}
+        #: Prefix hits ADOPTED by a family whose tails rebuild its rows
+        #: (``_row_tail``): adoptions, tails copied out of rows, and of
+        #: the tokens the radix walk matched those it gave up because
+        #: the deepest tail lay before the deepest matched block.
+        self.row_tail_counts = {"adopted": 0, "tails_taken": 0,
+                                "matched_tokens": 0, "match_cut_tokens": 0}
+        #: (seq, position before, position after) of prompt slices
+        #: handed to a chunk that is not dispatched yet: their stride
+        #: boundaries' tails are taken once it is (``_take_due_tails``).
+        self._tails_due: List = []
         #: A routed model's counters, summed over every chunk fetched
         #: (``ChunkHandle.stats``, models/deepseek_v3.py): tokens each
         #: expert received, then the experts that received any summed
@@ -1816,6 +1846,8 @@ class InferenceEngine:
         if seq.pages:
             self.allocator.free(seq.pages)
             seq.pages = []
+        if seq.tails:
+            self._drop_tails(seq)
         seq.block_table[:] = 0
         seq.pos = 0
         seq.cached_len = 0
@@ -1832,6 +1864,73 @@ class InferenceEngine:
         if seq.prefilled or seq.written_ids:
             seq.rebuild = True
         seq.prefilled = False
+
+    # -- row tails (docs/prefix_cache.md "Tails") ------------------------------
+
+    def _tail_room(self, written: int, end: int) -> bool:
+        """Whether a row that has written ``written`` tokens — and may
+        write a pipeline's worth of decode steps more before a copy
+        dispatched now runs — still holds the tail before ``end``."""
+        overrun = (int(getattr(self.executor, "chunk_size", 1))
+                   * self._pipe_depth)
+        return (self.spec.page_size <= end <= written
+                and written - end + overrun
+                <= self._row_tail["slack_tokens"])
+
+    def _take_tail(self, seq: _Sequence, row: int, end: int) -> None:
+        """Copy the tail before token ``end`` out of batch row ``row``
+        (which holds ``seq``'s stream up to ``seq.pos``) into a slot
+        that waits on ``seq`` for its radix node. Not where the tree
+        has that node's tail already, nor without a slot to take."""
+        if (not self._tail_room(seq.pos, end)
+                or self._prefix_cache.has_tail(seq.written_ids, end)):
+            return
+        slot = self._prefix_cache.take_tail_slot()
+        if slot is None:
+            return
+        self.executor.export_row_tail(row, end // self.spec.page_size, slot)
+        seq.tails.append((end, slot))
+        self.row_tail_counts["tails_taken"] += 1
+
+    def _take_stride_tail(self, seq: _Sequence, before: int,
+                          after: int) -> None:
+        """``seq``'s prefill has been DISPATCHED from ``before`` to
+        ``after``: take the tail of the last multiple of the family's
+        stride it passed (a shared context ends inside a prompt, and
+        its blocks are published only when the sequence ends)."""
+        if self._row_tail is None or seq.slot is None:
+            return
+        stride = self._row_tail["stride"]
+        end = after // stride * stride
+        if end > before:
+            self._take_tail(seq, seq.slot, end)
+
+    def _take_due_tails(self) -> None:
+        """The slices handed to the chunk that was just dispatched
+        (``_take_slices``)."""
+        due, self._tails_due = self._tails_due, []
+        for seq, before, after in due:
+            self._take_stride_tail(seq, before, after)
+
+    def _publish_tails(self, seq: _Sequence, row: Optional[int]) -> None:
+        """``seq``'s stream was just published (``insert``): take the
+        tail at its page-aligned end out of ``row`` (the next turn's
+        stream is this one plus what was typed) and hang every tail the
+        sequence holds on its node."""
+        if self._row_tail is None:
+            return
+        ps = self.spec.page_size
+        end = min(len(seq.written_ids) // ps, len(seq.pages)) * ps
+        if row is not None:
+            self._take_tail(seq, row, end)
+        for at, slot in seq.tails:
+            self._prefix_cache.attach_tail(seq.written_ids, at, slot)
+        seq.tails = []
+
+    def _drop_tails(self, seq: _Sequence) -> None:
+        for _, slot in seq.tails:
+            self._prefix_cache.free_tail_slot(slot)
+        seq.tails = []
 
     def _unmatch(self, seq: _Sequence) -> None:
         """Undo a radix match that could not complete admission: unlock
@@ -2148,7 +2247,12 @@ class InferenceEngine:
                         seq.carry = [kv.pending]
             if not seq.prompt_ids:
                 text = req.prompt
-                if seq.cached_len == 0 and req.history_text:
+                # (a declined pin put the remembered stream into
+                # ``carry``: the carry IS the history, exact to the
+                # token, as on the recompute path — ``history_text``
+                # beside it would prefill the history twice)
+                if (seq.cached_len == 0 and req.history_text
+                        and not seq.carry):
                     text = req.history_text + req.prompt
                 ids = self.tokenizer.encode(text)
                 seq.prompt_ids = ids or [self.tokenizer.bos_id]
@@ -2221,9 +2325,18 @@ class InferenceEngine:
             match_seed: Optional[List[int]] = None
             if (self._prefix_cache is not None and start_pos == 0
                     and not seq.pages and len(ids) > 1):
-                m = self._prefix_cache.match(ids)
-                if m.nodes and self._row_state_bytes:
+                tailed = self._row_tail is not None
+                m = self._prefix_cache.match(ids, need_tail=tailed)
+                if tailed:
+                    self.row_tail_counts["matched_tokens"] += (
+                        m.length + m.cut_tokens)
+                    self.row_tail_counts["match_cut_tokens"] += m.cut_tokens
+                if m.nodes and self._row_state_bytes and not tailed:
+                    # Pages without what rebuilds the row's state: the
+                    # match is given back whole (its node pins and its
+                    # page references) and counted.
                     self._prefix_cache.unlock(m)
+                    self.allocator.free(m.pages)
                     self.row_state_declined["prefix"] += 1
                 elif m.nodes:
                     n_m = len(m.pages)
@@ -2260,6 +2373,14 @@ class InferenceEngine:
                     return False
                 seq.block_table[have:have + need] = pages
                 seq.pages.extend(pages)
+            if match_seed is not None and self._row_tail is not None:
+                # The admission stands: the matched node's tail goes
+                # into this row's state at the match's end, and the
+                # prefill dispatched after it continues from there.
+                self.executor.import_row_tail(
+                    seq.prefix_match.tail, slot,
+                    seq.prefix_match.length // self.spec.page_size)
+                self.row_tail_counts["adopted"] += 1
 
             # Incremental prefill: the sequence takes its slot NOW but
             # runs at most one prefill bucket per engine step
@@ -2431,6 +2552,8 @@ class InferenceEngine:
             seq.pos = seq.todo_pos
             seq.pf_tokens_run += len(chunk)
             seq.written_ids.extend(chunk)
+            self._take_stride_tail(seq, seq.todo_pos - len(chunk),
+                                   seq.todo_pos)
             if seq.todo_ids:
                 continue                    # more buckets next step
             if handle is not None:
@@ -3758,6 +3881,7 @@ class InferenceEngine:
         self._note_prefill_dispatch(
             packed, t_done - t0,
             decode_active=bool(active), fused=True)
+        self._take_due_tails()
         self.steps += 1
         self.mixed_steps += 1
         self.mixed_prefill_tokens_total += packed
@@ -3832,6 +3956,9 @@ class InferenceEngine:
             seq.pos = seq.todo_pos
             seq.pf_tokens_run += len(sl)
             seq.written_ids.extend(sl)
+            if self._row_tail is not None:
+                self._tails_due.append((seq, seq.todo_pos - len(sl),
+                                        seq.todo_pos))
             if not seq.todo_ids:
                 # The FINAL slice is handed to a chunk: what is left of
                 # TTFT is that chunk's run and its reconcile.
@@ -3855,6 +3982,7 @@ class InferenceEngine:
         self._note_prefill_dispatch(packed, host_seconds,
                                     decode_active=decode_active,
                                     fused=True)
+        self._take_due_tails()
         _prefetch(getattr(handle, "out", None))
         _prefetch(getattr(handle, "pf_first", None))
         for seq, _, _ in infl_pf:
@@ -3925,6 +4053,7 @@ class InferenceEngine:
             # "completion" segment.
             seq.handle.marks.setdefault("decode_done",
                                         time.perf_counter())
+        row = seq.slot          # its state is the sequence's until reseated
         if seq.slot is not None:
             self.executor.release_slot(seq.slot)
             self._slots[seq.slot] = None
@@ -3981,6 +4110,7 @@ class InferenceEngine:
                     if publish:
                         self._prefix_cache.insert(seq.written_ids,
                                                   list(seq.pages))
+                        self._publish_tails(seq, row)
                     self._conv_cache[conv] = _ConvKV(
                         pages=list(seq.pages),
                         block_table=seq.block_table.copy(),
@@ -4005,6 +4135,7 @@ class InferenceEngine:
             seq.pages = []
         elif publish and seq.pages:
             self._prefix_cache.insert(seq.written_ids, list(seq.pages))
+            self._publish_tails(seq, row)
         if handle_rec is not None and self._state_manager is not None:
             # Outside self._mu: the state manager's lock is ABOVE the
             # engine's in the ordering (its eviction hooks call back in).
@@ -4104,6 +4235,8 @@ class InferenceEngine:
         if seq.pages:
             self.allocator.free(seq.pages)
             seq.pages = []
+        if seq.tails:
+            self._drop_tails(seq)
         conv = seq.req.conversation_id
         if conv:
             with self._mu:
@@ -4279,6 +4412,18 @@ class InferenceEngine:
                 "bytes": self._row_state_bytes * self.spec.batch_size,
                 "rebuilds": self.row_state_rebuilds,
                 "declined": dict(self.row_state_declined)}
+            if self._row_tail is not None:
+                # Tails (docs/prefix_cache.md): prefix hits adopted,
+                # tails copied out of rows, the pool's slots and those
+                # that hold one (on a node, or waiting for theirs), and
+                # of the tokens the radix walk matched those given up
+                # to the tails' grain.
+                out["row_state"].update(
+                    self.row_tail_counts,
+                    tail_slots=self._row_tail["slots"],
+                    tail_slots_live=self._prefix_cache.tail_slots_in_use,
+                    tail_bytes=self._row_tail["bytes"],
+                    tail_stride=self._row_tail["stride"])
         if self._pipe_cfg is not None:
             # Async pipeline (docs/performance.md): occupancy histogram
             # (chunks dispatched at each in-flight depth) + the
@@ -4343,5 +4488,13 @@ class InferenceEngine:
                 round(self.prefix_hits / total, 4) if total else 0.0)
             pc["cached_prefill_tokens"] = self.cached_prefill_tokens_total
             pc["shared_pages"] = self.allocator.shared_pages()
+            if self._row_state_bytes:
+                # A family with row state: the matches declined, and
+                # with tails what ``row_state`` says of them.
+                pc["declined"] = self.row_state_declined["prefix"]
+                if self._row_tail is not None:
+                    pc.update(
+                        self.row_tail_counts,
+                        tail_slots_live=self._prefix_cache.tail_slots_in_use)
             out["prefix_cache"] = pc
         return out

@@ -791,7 +791,7 @@ class JaxExecutor:
                  cache_dtype=None, seed: int = 0,
                  chunk_size: int = 16, prefill_batch: int = 4,
                  mixed_prefill_slices: int = 2,
-                 mixed_slice_tokens: int = 64,
+                 mixed_slice_tokens: int = 64, row_tail_slots: int = 0,
                  mesh=None, telemetry_name: str = "engine0",
                  telemetry_metrics: Optional[bool] = None) -> None:
         import jax
@@ -1343,9 +1343,9 @@ class JaxExecutor:
         #: their staging buffers can be rewritten.
         self._staging = HostStaging(ring=max(8, batch_size + 4))
         self._staging_fence_counts: Dict[str, int] = {}
-        #: Lazily-built donated scatter program for the tiered-KV
-        #: plane's promotions (import_kv_pages) — one compile total.
-        self._kv_inject = None
+        #: Lazily-built donated scatter program of the tiered-KV plane's
+        self._kv_inject = None      # promotions: one compile in all
+        self._init_row_tails(fam, model_cfg, int(row_tail_slots), held)
 
     def telemetry_info(self) -> Dict:
         """Model identity for the MFU estimator — shared with the
@@ -1499,7 +1499,7 @@ class JaxExecutor:
 
             add(self.params, "weights_bytes")
             add(self.cache, "kv_pool_bytes")
-            add(self.row_state, "row_state_bytes")
+            add((self.row_state, self.row_tails), "row_state_bytes")
             self._hbm_static = per
         chips = []
         for dev in jax.local_devices():
@@ -1936,6 +1936,12 @@ class JaxExecutor:
                 # the eager per-row split of its result.
                 self.prefill_multi_async([([1] * n, 0, bt[0], 0.0, 0)])
             prev = b
+        if self.row_tail is not None:
+            # The two tail programs, at boundary 0: every page of the
+            # tail lies before the sequence's start, so both copies
+            # move page 0 (nobody's).
+            self.export_row_tail(0, 0, 0)
+            self.import_row_tail(0, 0, 0)
         # Reset pool: warmup wrote garbage KV into page 0 only (block
         # table all-zero), which is never read — nothing to clean.
         zeros_b = np.zeros(spec.batch_size, np.int32)
@@ -2409,3 +2415,59 @@ class JaxExecutor:
 
     def resume(self, slot: int, tokens: List[int], start_pos: int) -> None:
         pass  # block tables carry everything
+
+    # -- row tails (docs/prefix_cache.md "Tails") ------------------------------
+    # (at the END of the class: the serving programs above keep their
+    # lines, and with them their place in XLA's cache)
+
+    def _init_row_tails(self, fam, model_cfg, slots: int, held) -> None:
+        """Tails (``models/__init__.py``: ``row_tail``): a pool of
+        ``slots`` copies of what rebuilds a row's state at a page
+        boundary, for a family that says what does, and the two programs
+        that move one between a batch row and a slot. ``self.row_tail``:
+        the family's ``{"pages", "stride", "slack_tokens", "bytes"}``
+        and ``"slots"``; None where the family has none, no slot was
+        asked for or a mesh serves — the engine then declines a prefix
+        hit as before there were any."""
+        self.row_tail = self.row_tails = None
+        tail_fn = getattr(fam, "row_tail", None)
+        sharded = self.mesh is not None and self.mesh.size > 1
+        if slots <= 0 or self.row_state is None or tail_fn is None or sharded:
+            return
+        self.row_tail = dict(tail_fn(model_cfg), slots=slots)
+        self.row_tails = held(lambda: fam.init_row_tails(model_cfg, slots))
+
+        def row_tail_export(state, tails, row, end_page, slot):
+            with scope("row_tail"), scope("export"):
+                return fam.export_row_tail(model_cfg, state, tails, row,
+                                           end_page, slot)
+
+        def row_tail_import(state, tails, slot, row, end_page):
+            with scope("row_tail"), scope("import"):
+                return fam.import_row_tail(model_cfg, state, tails, slot,
+                                           row, end_page)
+
+        self._tail_export = self._jax.jit(row_tail_export,
+                                          donate_argnums=(1,))
+        self._tail_import = self._jax.jit(row_tail_import,
+                                          donate_argnums=(0,))
+
+    def export_row_tail(self, row: int, end_page: int, slot: int) -> None:
+        """DISPATCH the copy of batch row ``row``'s tail before page
+        boundary ``end_page`` into tail ``slot``. Engine-thread only, no
+        host sync: the device stream is FIFO, so the copy reads what
+        every program dispatched before it wrote and nothing later."""
+        i32 = np.int32
+        self.row_tails = self._tail_export(
+            self.row_state, self.row_tails, i32(row), i32(end_page),
+            i32(slot))
+
+    def import_row_tail(self, slot: int, row: int, end_page: int) -> None:
+        """DISPATCH the copy of tail ``slot`` into batch row ``row``'s
+        state at page boundary ``end_page``: a prefill of that row
+        dispatched after it continues from ``end_page * page_size``.
+        REBINDS ``self.row_state`` (donated, in place)."""
+        i32 = np.int32
+        self.row_state = self._tail_import(
+            self.row_state, self.row_tails, i32(slot), i32(row),
+            i32(end_page))

@@ -27,8 +27,9 @@ A family is a module of this package that defines
   layers that own K/V pages: the tail its mixer's convolutions and
   value shift need of the token before; or
   a window layer's keys and
-  values, a SLAB of pool-shaped pages a batch row: ``models/afmoe.py``,
-  which also defines the optional ``bind_cache(cfg, *, page_size,
+  values, a SLAB of pool-shaped pages a batch row: ``models/afmoe.py``
+  and, over the same slabs, ``models/mellum.py``,
+  which also define the optional ``bind_cache(cfg, *, page_size,
   step_tokens)``, called by the executor before ``init_row_state``, and
   ``attention_window(cfg)``, what the engine counts such a cache by), or
   ``None`` for a family whose pages are its whole cache, and
@@ -40,7 +41,23 @@ A family is a module of this package that defines
   leaves the state of a decode row that is not active as it found it.
   The executor carries and donates it beside the pool; the engine
   adopts nothing that only pages could rebuild (``get_stats()
-  ["row_state"]``);
+  ["row_state"]``: ``declined``) — unless the family says what DOES
+  rebuild a row at a page boundary E. Optionally ``row_tail(cfg)``
+  (``{"pages", "stride", "slack_tokens", "bytes"}``: a TAIL is that
+  many pages of row state before E; one is taken at every multiple of
+  ``stride`` on the way through a prefill and where a stream is
+  published; a row that has written at most ``slack_tokens`` past E
+  still holds it), ``init_row_tails(cfg, slots)`` (a pool of tails,
+  leaves indexed ``(layer, slot * pages + page, ...)``) and the two
+  programs' bodies ``export_row_tail(cfg, row_state, tails, row,
+  end_page, slot) -> tails`` and ``import_row_tail(cfg, row_state,
+  tails, slot, row, end_page) -> row_state``: a row that imports the
+  tail of E continues at E as if it had prefilled the prefix. With them
+  (and ``executor.prefix_cache.row_tail_slots`` > 0) the prefix cache
+  adopts a hit for the family (``models/afmoe.py``, ``models/mellum.py``:
+  W tokens of a window layer's K and V; ``docs/prefix_cache.md``
+  "Tails"); a family whose state is a recurrence has none and goes on
+  declining;
 - the serving programs' model functions: ``forward_prefill``,
   ``forward_decode``, ``forward_mixed``, with
   ``models/llama.py``'s signatures and returns (``forward_mixed``
@@ -124,6 +141,7 @@ FAMILIES: Dict[str, str] = {
     "ling_hybrid": "llmq_tpu.models.ling_hybrid",
     "zaya": "llmq_tpu.models.zaya",
     "solar_open2": "llmq_tpu.models.solar_open2",
+    "mellum": "llmq_tpu.models.mellum",
 }
 
 

@@ -51,9 +51,9 @@ as page 0 of the pool is: where a row that does not decode, a slice
 that is not used and a slice's padding leave their token. The host
 allocates
 nothing for a sliding layer, and the pages alone no longer rebuild a
-sequence: the engine adopts no cached prefix, pinned conversation,
-tiering promotion or hand-over for this family (``get_stats()
-["row_state"]``).
+sequence: the engine adopts no pinned conversation, promotion or
+hand-over for this family (``get_stats()["row_state"]``); a cached
+PREFIX it can, by a TAIL (``row_tail``, at the end of this file).
 
 Float32 residual stream and router, bf16 products, every layer
 unrolled. In a mixed step the attention runs a layer's slices and its
@@ -817,3 +817,95 @@ def forward_mixed(params: Params, cfg: AfmoeConfig, dec_tokens: jnp.ndarray,
         dec_logits = _head(params, cfg, h_d)
     out = (dec_logits, pf_logits, kv_cache, row_state)
     return out + (_sum_stats(cfg, counts),) if stats else out
+
+
+# -- tails: what rebuilds a row at a page boundary -----------------------------
+#
+# W tokens of K and V rebuild a row: the last ``ceil(W / page_size)`` slab
+# pages before a page boundary E are post-rotary at absolute positions,
+# so copied into another row's ring at the same positions they are what
+# that row would have written had it prefilled the same prefix. That is
+# how the prefix cache adopts a hit for a window family
+# (``docs/prefix_cache.md`` "Tails"; ``models/mellum.py`` imports these).
+# (At the END of the file: the lines above keep their numbers, and with
+# them the serving programs their place in XLA's cache.)
+
+#: A tail is taken at every multiple of the stride on the way through
+#: a prefill: the largest power-of-two multiple of the page size that
+#: divides this many tokens and is at most two windows.
+TAIL_STRIDE_DIVIDES = 2048
+
+
+def row_tail(cfg) -> Dict[str, int]:
+    """What rebuilds a row's state at a page boundary E, for the engine
+    and the prefix cache (``models/__init__.py``): ``pages`` slab pages
+    a sliding layer (the window in whole pages), ``stride`` (the prompt
+    positions a tail is taken at on the way through a prefill),
+    ``slack_tokens`` (how far past E a row may have written and its
+    slab still hold the tail: the ring's pages beyond the tail's) and
+    ``bytes`` of one tail."""
+    _bound(cfg)
+    ps = cfg.page_size
+    W = cfg.sliding_window
+    pages, stride = -(-W // ps), ps
+    while TAIL_STRIDE_DIVIDES % (2 * stride) == 0 and stride <= W:
+        stride *= 2
+    return {"pages": pages, "stride": stride,
+            "slack_tokens": (cfg.slab_pages - pages) * ps,
+            "bytes": (2 * cfg.n_sliding * pages * ps * cfg.n_kv_heads
+                      * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize)}
+
+
+def init_row_tails(cfg, slots: int) -> RowState:
+    """The pool of ``slots`` tails, the slabs' layout: ``wk`` / ``wv``
+    ``(L_w, slots * pages, page_size, H_kv * head_dim)``, tail ``s`` the
+    pages ``s * pages .. s * pages + pages - 1``."""
+    shape = (cfg.n_sliding, slots * row_tail(cfg)["pages"], cfg.page_size,
+             cfg.n_kv_heads * cfg.head_dim)
+    return {"wk": jnp.zeros(shape, cfg.dtype),
+            "wv": jnp.zeros(shape, cfg.dtype)}
+
+
+def _tail_slab_pages(cfg, row, end_page):
+    """The slab pages of batch row ``row`` that hold the position pages
+    ``end_page - pages .. end_page - 1``; page 0 (nobody's) for one
+    before the sequence's start."""
+    n, t = cfg.slab_pages, row_tail(cfg)["pages"]
+    j = end_page - t + jnp.arange(t, dtype=jnp.int32)
+    return jnp.where(j >= 0, 1 + row * n + j % n, 0)
+
+
+def _move_pages(dst, src, dst_pages, src_pages):
+    """``dst`` (L, P, page, width) with its pages ``dst_pages[t]`` set
+    to ``src``'s pages ``src_pages[t]``, a page at a time in place (a
+    gather of a few pages out of a pool-sized leaf makes XLA copy the
+    leaf in halves: 0.5 GB where 19 MB move)."""
+    for d, s in zip(dst_pages, src_pages):
+        page = jax.lax.dynamic_slice_in_dim(src, s, 1, axis=1)
+        dst = jax.lax.dynamic_update_slice_in_dim(dst, page, d, axis=1)
+    return dst
+
+
+def export_row_tail(cfg, row_state: RowState, tails: RowState, row,
+                    end_page, slot) -> RowState:
+    """``tails`` with tail ``slot`` holding batch row ``row``'s K and V
+    of the ``pages`` position pages before ``end_page`` in every sliding
+    layer (``row``, ``end_page``, ``slot``: int32 scalars)."""
+    t = row_tail(cfg)["pages"]
+    src = _tail_slab_pages(cfg, row, end_page)
+    return {k: _move_pages(tails[k], row_state[k],
+                           [slot * t + i for i in range(t)],
+                           [src[i] for i in range(t)]) for k in tails}
+
+
+def import_row_tail(cfg, row_state: RowState, tails: RowState, slot, row,
+                    end_page) -> RowState:
+    """``row_state`` with tail ``slot`` in batch row ``row``'s ring at
+    the position pages before ``end_page``: the row then continues at
+    ``end_page * page_size`` as if it had prefilled the prefix."""
+    t = row_tail(cfg)["pages"]
+    dst = _tail_slab_pages(cfg, row, end_page)
+    return {k: _move_pages(row_state[k], tails[k],
+                           [dst[i] for i in range(t)],
+                           [slot * t + i for i in range(t)])
+            for k in row_state}

@@ -39,3 +39,50 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     out1 = x1 * c - x2 * s
     out2 = x2 * c + x1 * s
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
+
+
+def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> jnp.ndarray:
+    """YaRN's frequency of each of the ``head_dim // 2`` rotary pairs
+    (Peng et al., arXiv 2309.00071, the "NTK-by-parts" interpolation as
+    Hugging Face's ``_compute_yarn_parameters`` has it): pair ``i``'s
+    plain frequency ``f_i = theta^(-2i / head_dim)`` divided by
+    ``factor`` where the pair turns fewer than ``beta_slow`` times in
+    ``original_max_position`` positions, kept where it turns more than
+    ``beta_fast`` times, and blended linearly between those two pairs::
+
+        pair(b) = head_dim ln(original / (2 pi b)) / (2 ln theta)
+        lo, hi  = floor(pair(beta_fast)), ceil(pair(beta_slow)), in [0, half-1]
+        gamma_i = clip((i - lo) / (hi - lo), 0, 1)
+        f'_i    = f_i / factor * gamma_i + f_i * (1 - gamma_i)
+
+    A model whose layers differ in kind may rotate each kind by a table
+    of its own (``models/mellum.py``: this one on its full layers).
+    """
+    import math
+
+    half = head_dim // 2
+    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+
+    def pair(turns: float) -> float:
+        return (head_dim * math.log(original_max_position
+                                    / (turns * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    lo = max(math.floor(pair(beta_fast)), 0)
+    hi = min(math.ceil(pair(beta_slow)), half - 1)
+    gamma = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo)
+                     / max(hi - lo, 1e-3), 0.0, 1.0)
+    return freqs / factor * gamma + freqs * (1.0 - gamma)
+
+
+def rope_cos_sin_scaled(positions: jnp.ndarray, inv_freq: jnp.ndarray,
+                        attention_factor: float = 1.0
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`rope_cos_sin` over a frequency table of the caller's
+    (``yarn_inv_freq``), cos and sin multiplied by ``attention_factor``
+    (YaRN scales q and k alike, so the scores by its square)."""
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    return (jnp.cos(angles) * attention_factor,
+            jnp.sin(angles) * attention_factor)

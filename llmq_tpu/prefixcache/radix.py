@@ -38,6 +38,18 @@ Design:
   hook. Shared ancestors (another conversation's live prefix, or any
   locked node) survive.
 
+- **Tails** (``tail_slots`` > 0; ``docs/prefix_cache.md`` "Tails"). For
+  a model family that keeps ROW STATE beside its pages, pages alone
+  rebuild nothing — but for a window family the last W tokens' K and V
+  before a block boundary do. A node may carry a TAIL: the id of a slot
+  in the executor's tail pool that holds that state at the node's end.
+  ``match(ids, need_tail=True)`` ends at the deepest matched node that
+  has one (``PrefixMatch.tail``; what it gave up is ``cut_tokens``).
+  The tree owns the slot ids (``take_tail_slot`` hands one out, the
+  least recently used tail's when none is free; ``attach_tail`` hangs
+  it on a node); a tail goes with its node, and each counts
+  ``tail_cost_pages`` against ``max_pages``.
+
 The int8-KV path needs nothing special here: per-page quantization
 scales live in pools indexed by the same page id as the KV they scale
 (models/llama.init_kv_pages), so sharing a page id shares its scale
@@ -60,7 +72,7 @@ log = get_logger("prefixcache")
 
 class RadixNode:
     __slots__ = ("key", "page", "parent", "children", "lock_ref",
-                 "last_used", "created")
+                 "last_used", "created", "tail", "tail_used")
 
     def __init__(self, key: Optional[Tuple[int, ...]], page: int,
                  parent: Optional["RadixNode"], now: float,
@@ -75,6 +87,12 @@ class RadixNode:
         self.lock_ref = 0
         self.last_used = now
         self.created = seq_no
+        #: The tail slot that rebuilds a row's state at this node's end,
+        #: or None; ``tail_used``: when it was taken or last adopted
+        #: (what the tails' own LRU goes by: a walk through the node on
+        #: the way to a deeper tail does not count).
+        self.tail: Optional[int] = None
+        self.tail_used = 0.0
 
 
 @dataclass
@@ -87,6 +105,11 @@ class PrefixMatch:
     length: int                      # tokens covered (page-aligned)
     pages: List[int] = field(default_factory=list)
     nodes: List[RadixNode] = field(default_factory=list)
+    #: ``match(need_tail=True)``: the tail slot at ``length`` (None with
+    #: an empty match), and the tokens of the walk given up because the
+    #: deepest tail lay before the deepest matched block.
+    tail: Optional[int] = None
+    cut_tokens: int = 0
 
 
 class PrefixCache:
@@ -95,7 +118,8 @@ class PrefixCache:
 
     def __init__(self, allocator: PageAllocator, page_size: int, *,
                  max_pages: int = 0, policy: str = "lru",
-                 clock=None) -> None:
+                 clock=None, tail_slots: int = 0,
+                 tail_cost_pages: int = 0) -> None:
         if policy not in EVICTION_POLICIES:
             raise ValueError(
                 f"unknown prefix-cache eviction policy {policy!r}; "
@@ -109,6 +133,14 @@ class PrefixCache:
         self._now = clock if clock is not None else time.monotonic
         self._root = RadixNode(None, 0, None, 0.0, 0)
         self._pages = 0                  # nodes (== pages) in the tree
+        #: Tails: the slot ids not on a node nor lent out, the nodes
+        #: that carry one, and what one counts against ``max_pages``.
+        self.tail_slots = int(tail_slots)
+        self.tail_cost_pages = int(tail_cost_pages)
+        self._free_tails: List[int] = list(range(self.tail_slots))[::-1]
+        self._tailed: Dict[int, RadixNode] = {}
+        self.tails_attached = 0
+        self.tails_evicted = 0
         self._seq = 0                    # insertion order for fifo
         self._mu = threading.RLock()
         #: Demotion seam (llmq_tpu/tiering/, docs/tiering.md): when an
@@ -130,13 +162,18 @@ class PrefixCache:
 
     # -- lookup --------------------------------------------------------------
 
-    def match(self, ids: List[int]) -> PrefixMatch:
+    def match(self, ids: List[int], need_tail: bool = False) -> PrefixMatch:
         """Longest page-aligned cached prefix of ``ids``, capped at
         ``len(ids) - 1`` tokens — at least one token is always left for
         the caller to prefill (sampling the first output token needs
         live logits). Matched pages are retained in the allocator and
         their nodes lock-pinned; the caller owns both until
-        :meth:`unlock` (nodes) and its own page free (pages)."""
+        :meth:`unlock` (nodes) and its own page free (pages).
+
+        ``need_tail`` (a family with row state): the match is cut back
+        to the deepest matched node that carries a tail — ``m.tail`` its
+        slot, ``m.cut_tokens`` what the cut gave up; with no tail on the
+        path the match is empty (a miss) and all of the walk is cut."""
         ps = self.page_size
         n_blocks = max(0, (len(ids) - 1) // ps)
         m = PrefixMatch(0)
@@ -151,6 +188,15 @@ class PrefixCache:
                 node = child
                 m.nodes.append(node)
                 m.pages.append(node.page)
+            if need_tail:
+                walked = len(m.nodes)
+                while m.nodes and m.nodes[-1].tail is None:
+                    m.nodes.pop()
+                    m.pages.pop()
+                m.cut_tokens = (walked - len(m.nodes)) * ps
+                if m.nodes:
+                    m.tail = m.nodes[-1].tail
+                    m.nodes[-1].tail_used = now
             if not m.nodes:
                 self.misses += 1
                 return m
@@ -194,6 +240,88 @@ class PrefixCache:
                     nd.lock_ref -= 1
                 nd.last_used = now
 
+    # -- tails ---------------------------------------------------------------
+
+    def _walk(self, ids: List[int], end: int) -> Optional[RadixNode]:
+        """The node that ends at token ``end`` (a multiple of the page
+        size) on the path of ``ids``, or None. Caller holds the lock."""
+        ps = self.page_size
+        node = self._root
+        for b in range(end // ps):
+            node = node.children.get(tuple(ids[b * ps:(b + 1) * ps]))
+            if node is None:
+                return None
+        return node if end > 0 else None
+
+    def has_tail(self, ids: List[int], end: int) -> bool:
+        """Whether the node that ends at token ``end`` of ``ids`` is in
+        the tree with a tail (read-only: nothing retained or locked)."""
+        with self._mu:
+            node = self._walk(ids, end)
+            return node is not None and node.tail is not None
+
+    def take_tail_slot(self) -> Optional[int]:
+        """A slot id to copy a tail into: a free one, else the slot of
+        the least recently used tail in the tree (its node keeps its
+        page and loses the tail). The caller owns the id until
+        :meth:`attach_tail` or :meth:`free_tail_slot`. None with no
+        slots configured."""
+        with self._mu:
+            if self._free_tails:
+                return self._free_tails.pop()
+            if not self._tailed:
+                return None
+            slot, node = min(self._tailed.items(),
+                             key=lambda kv: kv[1].tail_used)
+            node.tail = None
+            del self._tailed[slot]
+            self.tails_evicted += 1
+            return slot
+
+    def free_tail_slot(self, slot: int) -> None:
+        with self._mu:
+            self._free_tails.append(slot)
+
+    def attach_tail(self, ids: List[int], end: int, slot: int) -> bool:
+        """Hang tail ``slot`` on the node that ends at token ``end`` of
+        ``ids``. False — and the slot is free again — where that node is
+        not in the tree or already carries a tail."""
+        with self._mu:
+            node = self._walk(ids, end)
+            if node is None or node.tail is not None:
+                self._free_tails.append(slot)
+                return False
+            node.tail = slot
+            node.tail_used = self._now()
+            self._tailed[slot] = node
+            self.tails_attached += 1
+            self._trim_locked()
+            return True
+
+    def _held(self) -> int:
+        """What the tree holds against ``max_pages``: its pages, and
+        ``tail_cost_pages`` for every tail."""
+        return self._pages + len(self._tailed) * self.tail_cost_pages
+
+    def _trim_locked(self) -> None:
+        """Evict down to ``max_pages`` (0: bounded by the pool alone)."""
+        if self.max_pages > 0 and self._held() > self.max_pages:
+            self._evict_locked(target_nodes=self._held() - self.max_pages)
+
+    def _drop_tail(self, node: RadixNode) -> None:
+        """A node leaves the tree: its tail goes with it."""
+        if node.tail is not None:
+            self._tailed.pop(node.tail, None)
+            self._free_tails.append(node.tail)
+            node.tail = None
+
+    @property
+    def tail_slots_in_use(self) -> int:
+        """Slots that hold a tail: on a node, or lent out and waiting
+        for theirs."""
+        with self._mu:
+            return self.tail_slots - len(self._free_tails)
+
     # -- publication ---------------------------------------------------------
 
     def insert(self, ids: List[int], pages: List[int]) -> int:
@@ -228,8 +356,7 @@ class PrefixCache:
                     child.last_used = now
                 node = child
             self.inserted_pages += added
-            if self.max_pages > 0 and self._pages > self.max_pages:
-                self._evict_locked(target_nodes=self._pages - self.max_pages)
+            self._trim_locked()
         return added
 
     # -- eviction ------------------------------------------------------------
@@ -318,7 +445,10 @@ class PrefixCache:
             del victim.parent.children[victim.key]
             self.allocator.free([victim.page])
             self._pages -= 1
-            removed += 1
+            # (a tail counts as ``tail_cost_pages`` nodes gone)
+            removed += 1 + (self.tail_cost_pages
+                            if victim.tail is not None else 0)
+            self._drop_tail(victim)
             self.evicted_pages += 1
             if last_holder:
                 pool_freed += 1
@@ -359,6 +489,7 @@ class PrefixCache:
                 assert nd.parent is not None
                 del nd.parent.children[nd.key]
                 self.allocator.free([nd.page])
+                self._drop_tail(nd)
                 self._pages -= 1
                 self.evicted_pages += 1
                 removed += 1
@@ -395,4 +526,9 @@ class PrefixCache:
                 "cached_tokens_served": self.cached_tokens_served,
                 "inserted_pages": self.inserted_pages,
                 "evicted_pages": self.evicted_pages,
+                **({"tail_slots": self.tail_slots,
+                    "tails": len(self._tailed),
+                    "tails_attached": self.tails_attached,
+                    "tails_evicted": self.tails_evicted}
+                   if self.tail_slots else {}),
             }

@@ -62,10 +62,11 @@ def main() -> int:
                     metavar="MODULE.ATTR=EXPRESSION")
     ap.add_argument("--control", action="append", default=[],
                     metavar="NAME[+NAME]",
-                    help="for a family that judges its groups itself "
-                         "(reference.judged_groups): what the control "
-                         "holds one precision down, of reference.LOWP "
-                         "(repeatable; default: all of it at once)")
+                    help="what the control holds one precision down "
+                         "(repeatable; default: all of it at once): of "
+                         "reference.LOWP for a family that judges its "
+                         "groups itself (reference.judged_groups), else "
+                         "the value of reference_forward's lowp")
     args = ap.parse_args()
 
     import jax
@@ -147,9 +148,15 @@ def main() -> int:
         every = np.arange(T)
         ref, margins = reference.reference_forward(params, tokens, model,
                                                    every)
-        low, _ = reference.reference_forward(params, tokens, model, every,
-                                             lowp=True)
-        ref, low, margins = map(np.asarray, (ref, low, margins))
+        # the control: all of it at once (``lowp=True``), or with
+        # ``--control NAME`` the part a family's reference takes by name
+        # (``lowp="router"``)
+        lows = {("control." + "+".join(c) if args.control else "control"):
+                np.asarray(reference.reference_forward(
+                    params, tokens, model, every,
+                    lowp="+".join(c) if args.control else True)[0])
+                for c in (controls if args.control else [()])}
+        ref, margins = map(np.asarray, (ref, margins))
         line = {"seed": seed,
                 "reference_rms": float(np.sqrt((ref * ref).mean()))}
         for group, (at, served) in served_many(params, tokens).items():
@@ -157,8 +164,8 @@ def main() -> int:
             # a family whose limits follow the context takes the positions
             by = (at,) if "at" in inspect.signature(
                 reference.judge).parameters else ()
-            for name, got in (("served", np.asarray(served)),
-                              ("control", low[at])):
+            for name, got in [("served", np.asarray(served))] + [
+                    (name, low[at]) for name, low in lows.items()]:
                 j = reference.judge(got, ref[at], margins[at], tol, *by)
                 rms = np.sqrt(((got - ref[at]) ** 2).mean(-1))
                 line[f"{group}.{name}"] = {

@@ -408,14 +408,15 @@ def test_the_kernels_serve_both_kinds_of_layer(tiny, monkeypatch):
 # -- through the executor and the engine --------------------------------------
 
 
-def make_engine(tiny, batch=2, **kw):
+def make_engine(tiny, batch=2, slots=0, **kw):
     cfg, params, _ = tiny
     tok = ByteTokenizer()
     ex = JaxExecutor(dataclasses.replace(cfg, page_size=0, slab_pages=0),
                      params, batch_size=batch, page_size=PAGE,
                      num_pages=96, prefill_buckets=[16, 32],
                      eos_id=tok.eos_id, chunk_size=4,
-                     mixed_prefill_slices=2, mixed_slice_tokens=8)
+                     mixed_prefill_slices=2, mixed_slice_tokens=8,
+                     row_tail_slots=slots)
     return InferenceEngine(
         ex, tok, enable_metrics=False, max_decode_steps=64,
         mixed_batch=MixedBatchConfig(enabled=True, prefill_token_budget=16,
@@ -481,7 +482,14 @@ def test_a_window_layer_s_cache_is_bounded_whatever_the_context(tiny):
     assert again.tokens == got.tokens and len(got.tokens) == 40
 
 
-def test_a_prefix_match_is_declined_and_counted(tiny):
+def test_a_prefix_match_is_adopted_where_there_are_tail_slots(tiny):
+    """The same two prompts twice. Without tail slots (the default) the
+    match is declined and counted, as it was before there were tails;
+    with them the second prompt adopts the first one's blocks up to the
+    deepest tail (the stride boundary at 32 of the 48 shared tokens:
+    the window's last 24 tokens of K and V copied into its ring) and
+    gives the tokens it gives cold (``tests/test_row_tails.py`` has the
+    wrapped ring, the next turn and the broken import)."""
     shared = "the same forty-odd characters of system prompt: "
     plain, _ = make_engine(tiny)
     want = generate(plain, "b", shared + "second question")
@@ -490,6 +498,19 @@ def test_a_prefix_match_is_declined_and_counted(tiny):
     second = generate(eng, "b", shared + "second question")
     assert second.cached_tokens == 0 and second.tokens == want.tokens
     assert eng.get_stats()["row_state"]["declined"]["prefix"] == 1
+    assert "adopted" not in eng.get_stats()["row_state"]
+    eng, ex = make_engine(
+        tiny, slots=4,
+        prefix_cache=PrefixCacheConfig(enabled=True, row_tail_slots=4))
+    assert ex.row_tail == {"pages": 3, "stride": 32, "slack_tokens": 40,
+                           "bytes": 6 * 3 * PAGE * 128 * 4 * 2, "slots": 4}
+    assert ex.row_tails["wk"].shape == (6, 4 * 3, PAGE, 128)
+    generate(eng, "a", shared + "first question")
+    second = generate(eng, "b", shared + "second question")
+    stats = eng.get_stats()["row_state"]
+    assert second.cached_tokens == 32 and second.tokens == want.tokens
+    assert stats["declined"]["prefix"] == 0 and stats["adopted"] == 1
+    assert (stats["matched_tokens"], stats["match_cut_tokens"]) == (48, 16)
 
 
 # -- the registry --------------------------------------------------------------

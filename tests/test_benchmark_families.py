@@ -55,7 +55,7 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
         assert set(config["reduced"]) == set(entry["reduced"])
     assert families == {"llama", "deepseek_v3", "longcat_flash",
                         "granitemoehybrid", "afmoe", "ling_hybrid", "zaya",
-                        "solar_open2"}
+                        "solar_open2", "mellum"}
 
 
 @pytest.mark.parametrize("cell", [
@@ -64,7 +64,7 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
     "kanana2-decode-saturated", "longcat-decode-saturated",
     "granite4h-decode-saturated", "trinity-longshort-saturated",
     "ling3-reasoning-saturated", "zaya1-reasoning-saturated",
-    "solar2-longdoc-saturated"])
+    "solar2-longdoc-saturated", "mellum2-completion-sessions"])
 def test_every_cell_resolves_and_reports_what_the_contract_asks(bench, cell):
     assert contract.check_names(bench) == []
     assert cell in [w["name"] for w in bench["workloads"]]
@@ -90,6 +90,7 @@ def test_no_cell_is_left_out_of_the_cases_above(bench):
     ("longcat-flash-chat-bf16-ep32", 2, 9_216),
     ("granite-4.0-h-micro-bf16", 2, 8_192),
     ("trinity-large-preview-bf16-ep16", 2, 4_096),
+    ("mellum2-12b-a2.5b-bf16", 2, 6_144),
 ])
 def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
                                                     kv_itemsize, per_token):
@@ -1306,6 +1307,202 @@ def test_a_broken_kimi_form_path_is_refused_by_the_check(monkeypatch, fault):
         jax.clear_caches()
 
 
+# -- mellum: window and YaRN-scaled full attention by layer type ----------------
+
+
+def _mellum(bench):
+    cell = contract.resolve_cell(bench, "mellum2-completion-sessions")
+    return cell, contract.load_family(cell["family_dir"], "shapes")
+
+
+def test_the_window_tail_family_s_shapes_on_the_published_sizes(bench):
+    """``mellum``: a pipeline stage held whole — every expert, the whole
+    vocabulary. The arithmetic of the cut as the configuration's
+    ``deployment`` states it; the page pool holds the FULL layers' K and
+    V alone; a tail is the window of every sliding layer's K and V."""
+    cell, shapes = _mellum(bench)
+    held = cell["config"]["model"]
+    assert shapes.layer_kinds(held) == (9, 3)
+    assert shapes.attn_params(held) == 21_233_664
+    assert shapes.expert_params(held) == 6_193_152
+    assert shapes.param_count(held) == 5_465_959_680
+    assert "5,465,959,680 parameters" in cell["config"]["deployment"]
+    whole = dict(held, num_hidden_layers=28)
+    assert round(shapes.param_count(whole) / 1e9, 2) == 12.15
+    assert round(shapes.active_param_count(whole) / 1e9, 2) == 2.44
+    assert shapes.kv_bytes_per_token(held, 2) == 6_144
+    assert shapes.row_tail_bytes(held, 2, 128) == 18_874_368
+    assert round(shapes.experts_touched(held, 16), 1) == 56.4
+    # the program's count is the same model
+    from llmq_tpu.models import get_config, mellum
+    import dataclasses
+    cfg = get_config("mellum2-12b-a2.5b")
+    cut = dataclasses.replace(cfg, layer_types=cfg.layer_types[:12])
+    assert mellum.param_count_analytic(cut) == shapes.param_count(held)
+    assert mellum.active_param_count(cut) == shapes.active_param_count(held)
+    W, most = 1_024, held["max_position_embeddings"]
+    ctx = 16 * 9_000
+    least = shapes.decode_attn_bytes(held, 2, 16, ctx)
+    assert least == 2_048 * ctx * (3 + 9 * W / most)
+    exact = 2_048 * ctx * 3 + shapes.attn_window_bytes(held, 2, 16 * W)
+    assert least < exact < 2_048 * ctx * 12
+    step = shapes.decode_step_bytes(held, 2, 2, 16, ctx)
+    assert 9.0e9 < step < 10.5e9            # the experts' stream
+
+
+def test_the_window_tail_configuration_is_the_catalog_s_row(bench):
+    """``mellum2-12b-a2.5b-bf16``: every key of the catalog's ``config``
+    under the same key with the same value but the two cuts, each with
+    its published value beside it; no width, no expert and no row of the
+    vocabulary among them; what is assumed and what is left out stated."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    entry, doc = next(x for x in _configs(bench)
+                      if x[0]["name"] == "mellum2-12b-a2.5b-bf16")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == entry["source"])
+    assert row["name"] == "Mellum2-12B-A2.5B-Instruct"
+    differs = {k for k, v in row["config"].items() if doc.get(k) != v}
+    assert differs == set(entry["reduced"]) == {
+        "num_hidden_layers", "max_position_embeddings"}
+    assert doc["published"] == {k: row["config"][k] for k in differs}
+    # three whole periods, the first twelve of the published layers
+    assert doc["num_hidden_layers"] == 12
+    assert doc["layer_types"][:12].count("full_attention") == 3
+    assert doc["mlp_layer_types"][:12] == ["sparse"] * 12
+    assert doc["rope_parameters"] == row["config"]["rope_parameters"]
+    assert doc["rope_parameters"]["full_attention"]["attention_factor"] == \
+        1.2772588722239782
+    assert len(doc["assumed"]) == 1 and doc["qk_norm"] is True
+    assert "qk_norm" in doc["assumed"][0]
+    assert any("MTP" in d for d in doc["departures"])
+    assert any("intermediate_size" in d for d in doc["departures"])
+    assert "pipeline stages" in doc["deployment"]
+    ex = doc["server"]["executor"]
+    assert ex["page_size"] == 128 and ex["prefill_buckets"] == [512]
+    assert ex["prefix_cache"]["row_tail_slots"] > 0
+    assert doc["server"]["model"]["max_seq_len"] == 32_768
+    assert set(doc["server_why"]) >= {"max_batch_size", "kv_pages",
+                                      "prefix_cache", "memory"}
+    # the program's registry holds the same model
+    from llmq_tpu.models import get_config
+    cfg = get_config("mellum2-12b-a2.5b")
+    assert (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (
+        row["config"]["hidden_size"], row["config"]["num_attention_heads"],
+        row["config"]["num_key_value_heads"], row["config"]["head_dim"])
+    assert list(cfg.layer_types) == row["config"]["layer_types"]
+    assert (cfg.moe_ffn_dim, cfg.n_routed_experts, cfg.n_experts_per_tok,
+            cfg.sliding_window, cfg.vocab_size) == (896, 64, 8, 1_024, 98_304)
+    full = row["config"]["rope_parameters"]["full_attention"]
+    assert (cfg.rope_full.factor, cfg.rope_full.original_max_position,
+            cfg.rope_full.attention_factor) == (
+        full["factor"], full["original_max_position_embeddings"],
+        full["attention_factor"])
+
+
+def test_the_window_tail_family_s_tolerance_sits_between_its_readings(bench):
+    """The judge's keys and no other; under its numbers the served
+    path's readings on the chip pass (medians up to 0.043, at most 0.49
+    of the control's), the control itself is refused by the RATIO even
+    where its level passes the absolute limit, and so is a position of
+    unrelated logits. The family's own judged sequence passes the
+    window and the ring, and leaves the adopted row a boundary beyond
+    both."""
+    import numpy as np
+    cell, _ = _mellum(bench)
+    reference = contract.load_family(cell["family_dir"], "reference")
+    adapter = contract.load_family(cell["family_dir"], "adapter")
+    judge, tol = reference.judge, cell["config"]["tolerance"]
+    assert set(tol) == {"rms", "max", "clean_quantile", "rms_clean",
+                        "control_ratio", "margin_eps", "min_positions",
+                        "judged_tokens", "why"}
+    ex = cell["config"]["server"]["executor"]
+    bucket = min(ex["prefill_buckets"])
+    assert bucket // 3 + 3 < tol["min_positions"] <= bucket - 5 + 3
+    W = cell["config"]["model"]["sliding_window"]
+    ring = 13 * ex["page_size"]
+    assert ring + bucket + 128 <= tol["judged_tokens"] <= \
+        cell["config"]["server"]["model"]["max_seq_len"]
+    starts = adapter.judged_starts(tol["judged_tokens"], 128, W)
+    assert starts[0] == tol["judged_tokens"] - 128 and W - 6 in starts
+    ref = np.zeros((128, 16), np.float32)
+    margins = np.full(128, 1e-3)
+    served = ref + np.linspace(0.02, 0.066, 128)[:, None]       # median 0.043
+    control = ref + np.linspace(0.06, 0.115, 128)[:, None]      # median 0.088
+    got = judge(served, ref, margins, tol, control)
+    assert got["ok"] and 0.48 < got["ratio"] < 0.5, got
+    # the control against itself: ratio 1, whatever its level
+    low = ref + np.linspace(0.04, 0.07, 128)[:, None]           # median 0.055
+    got = judge(low, ref, margins, tol, low)
+    assert not got["ok"] and got["rms_clean"] < tol["rms_clean"]
+    assert got["ratio"] == 1.0
+    # without a control the absolute limits alone: the level passes
+    assert judge(low, ref, margins, tol)["ok"]
+    unrelated = served.copy()
+    unrelated[3] = 1.4
+    assert not judge(unrelated, ref, margins, tol, control)["ok"]
+    high = ref + np.full((128, 1), 0.07, np.float32)
+    assert not judge(high, ref, margins, tol, 4 * high)["ok"]    # rms_clean
+    # the sequence is the seed's: the harness's prompt decides it
+    a = reference.judged_sequence(np.arange(5, 515), 64, 98_304)
+    b = reference.judged_sequence(np.arange(6, 516), 64, 98_304)
+    assert (a == reference.judged_sequence(np.arange(5, 515), 64, 98_304)
+            ).all() and (a != b).any() and a.min() >= 3 and a.max() < 98_304
+
+
+def _tail_not_imported(ml, monkeypatch):
+    monkeypatch.setattr(
+        ml, "import_row_tail",
+        lambda cfg, state, tails, slot, row, end_page: state)
+
+
+@pytest.mark.parametrize("fault", [None, _tail_not_imported])
+def test_the_harness_s_own_check_judges_the_adopted_path(monkeypatch, fault):
+    """``harness/child.py`` ``check_logits`` itself on the rehearsal's
+    toy ``mellum``: the family's ``reference_logits`` judges, among its
+    groups, the ADOPTED path — a tail exported from one batch row where
+    its prefill passed a page boundary, imported into another row's
+    ring, the rest prefilled there and decoded — over the harness's
+    prompt and over a sequence of its own that passes the window and
+    the ring. With the import left out the served path is NOT correct
+    by the comparison the benchmark makes (on the chip: the family's
+    README)."""
+    import jax
+
+    import llmq_tpu.models.mellum as ml
+    from benchmark.harness import child
+    bench = contract.load_benchmark(os.path.join(
+        REPO, "benchmark", "selftest", "data", "rehearsal_mellum.json"))
+    cell = contract.resolve_cell(bench, "tiny-mellum-completion")
+    config, srv = cell["config"], cell["config"]["server"]
+    adapter = contract.load_family(cell["family_dir"], "adapter")
+    reference = contract.load_family(cell["family_dir"], "reference")
+    W = config["model"]["sliding_window"]
+    ring = 7 * srv["executor"]["page_size"]
+    assert config["tolerance"]["judged_tokens"] > ring + W
+    mcfg = adapter.register(srv["model"]["name"], config)
+    params = child.make_params(3400000123, adapter.param_builder(
+        mcfg, srv["model"]))
+    if fault is not None:
+        fault(ml, monkeypatch)
+    jax.clear_caches()
+    try:
+        path = adapter.serving_path(mcfg, srv)
+        path.ident += getattr(fault, "__name__", "whole")
+        spec = {"config": config, "seed": 3400000123}
+        if fault is not None:
+            with pytest.raises(reference.NotCorrect, match="adopted"):
+                child.check_logits(params, path, reference.reference_logits,
+                                   spec)
+        else:
+            assert child.check_logits(
+                params, path, reference.reference_logits, spec)["ok"]
+    finally:
+        reference.JUDGED = None
+        jax.clear_caches()
+
+
 def test_the_harness_names_no_family():
     named = re.compile(r"llama|deepseek|kanana|smollm|fused_decode|gmm|"
                        r"latent_decode|moe_grouped|llmq_tpu\.models")
@@ -1322,7 +1519,8 @@ def test_the_harness_names_no_family():
 
 @pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
                                     "granitemoehybrid", "afmoe",
-                                    "ling_hybrid", "zaya", "solar_open2"])
+                                    "ling_hybrid", "zaya", "solar_open2",
+                                    "mellum"])
 def test_who_imports_what_in_a_family(family):
     """``shapes.py`` is standard library alone (the parent and the
     readers import it); ``reference.py`` imports neither the program
@@ -1342,7 +1540,8 @@ def test_who_imports_what_in_a_family(family):
 
 @pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
                                     "granitemoehybrid", "afmoe",
-                                    "ling_hybrid", "zaya", "solar_open2"])
+                                    "ling_hybrid", "zaya", "solar_open2",
+                                    "mellum"])
 def test_what_a_family_brings_to_the_program(family):
     """The program's side of the seam (``llmq_tpu/models/__init__.py``):
     three forward passes the serving programs are built from — no

@@ -34,6 +34,7 @@ SSM = {"ssm_conv", "ssm_update", "ssm_scan"}
 WINDOW = {"attn_window", "attn_full", "attn_gate"}
 FAMILIES = {
     "afmoe": MODULES | ROUTED | WINDOW,
+    "mellum": MODULES | ROUTED | {"attn_window", "attn_full"},
     "ling_hybrid": (MODULES | ROUTED | SSM
                     | {"latent_prefill_attention", "attn_gate",
                        "kda_gates"}),
@@ -69,6 +70,10 @@ def _tiny(family):
         cfg = am.afmoe_tiny(dtype=jnp.float32, max_seq_len=128,
                             held_experts=(8, 16))
         return cfg, am.init_params(jax.random.PRNGKey(41), cfg), {}
+    if family == "mellum":
+        from llmq_tpu.models import mellum as ml
+        cfg = ml.mellum_tiny(dtype=jnp.float32, max_seq_len=128)
+        return cfg, ml.init_params(jax.random.PRNGKey(54), cfg), {}
     if family == "ling_hybrid":
         from llmq_tpu.models import ling_hybrid as lh
         cfg = lh.ling_hybrid_tiny(dtype=jnp.float32, max_seq_len=128,
@@ -181,8 +186,10 @@ def test_a_name_outside_the_vocabulary_is_refused():
     assert max(map(len, SCOPES)) <= len("latent_prefill_attention")
     # (176 characters until the family afmoe brought its three:
     # attn_window, attn_full, attn_gate; ling_hybrid one: kda_gates;
-    # zaya one: cca_mix)
-    assert sum(map(len, SCOPES)) < 230
+    # zaya one: cca_mix; the window families' two tail programs three
+    # that no serving program's instruction carries: row_tail, export,
+    # import)
+    assert sum(map(len, SCOPES)) < 250
     assert SSM | WINDOW <= set(SCOPES)
 
 

@@ -198,6 +198,98 @@ class TestRadixTree:
         assert pc.pages == 0 and alloc.available() == alloc.total
 
 
+class TestTails:
+    """A node may carry a TAIL — a slot of the executor's tail pool that
+    rebuilds a row-state family's row at the node's end
+    (docs/prefix_cache.md "Tails")."""
+
+    def tree(self, **kw):
+        """Three blocks of one stream published, a tail at the end of
+        the second."""
+        alloc, pc = make_cache(tail_slots=2, **kw)
+        ids = list(range(12))
+        pages = seq_pages(alloc, 3)
+        pc.insert(ids, pages)
+        slot = pc.take_tail_slot()
+        assert pc.attach_tail(ids, 8, slot)
+        return alloc, pc, ids, pages, slot
+
+    def test_a_match_is_cut_to_the_deepest_tail(self):
+        alloc, pc, ids, pages, slot = self.tree()
+        m = pc.match(ids + [99], need_tail=True)
+        # three blocks match; the tail hangs at the end of the second
+        assert (m.length, m.tail, m.cut_tokens) == (8, slot, 4)
+        assert m.pages == pages[:2] and len(m.nodes) == 2
+        assert alloc.refcount(pages[2]) == 2      # the cut block: not retained
+        # without the need the same walk goes all the way
+        assert pc.match(ids + [99]).length == 12
+
+    def test_no_tail_on_the_path_is_a_miss_and_all_of_it_is_cut(self):
+        alloc, pc = make_cache(tail_slots=2)
+        ids = list(range(12))
+        pages = seq_pages(alloc, 3)
+        pc.insert(ids, pages)
+        m = pc.match(ids + [99], need_tail=True)
+        assert (m.length, m.tail, m.cut_tokens, m.nodes) == (0, None, 12, [])
+        assert pc.misses == 1 and pc.hits == 0
+        assert all(alloc.refcount(p) == 2 for p in pages)    # nothing held
+
+    def test_unlock_gives_everything_back(self):
+        alloc, pc, ids, pages, _ = self.tree()
+        m = pc.match(ids + [99], need_tail=True)
+        assert pc.evict_pages(10) == 0             # its nodes are pinned
+        pc.unlock(m)
+        alloc.free(m.pages)
+        alloc.free(pages)
+        assert pc.evict_pages(10) == 3 and pc.pages == 0
+        assert alloc.available() == alloc.total
+        assert pc.tail_slots_in_use == 0           # the tail went with its node
+
+    def test_a_tail_goes_with_its_node(self):
+        alloc, pc, ids, pages, slot = self.tree()
+        alloc.free(pages)
+        assert pc.tail_slots_in_use == 1
+        assert pc.invalidate(ids) == 3
+        assert pc.tail_slots_in_use == 0 and not pc.has_tail(ids, 8)
+        # the slot is handed out again
+        assert sorted([pc.take_tail_slot(), pc.take_tail_slot()]) == [0, 1]
+
+    def test_a_tail_counts_against_the_budget(self):
+        """``max_pages`` 6 with a tail worth 3 pages: three blocks and a
+        tail fit (3 + 3); one more block evicts by LRU until they do —
+        and the tailed node's eviction gives back its tail's share."""
+        alloc, pc, ids, pages, _ = self.tree(max_pages=6, tail_cost_pages=3)
+        alloc.free(pages)
+        assert pc.pages == 3 and pc._held() == 6
+        other = seq_pages(alloc, 1)
+        pc.insert([50, 51, 52, 53], other)
+        alloc.free(other)
+        assert pc._held() <= 6
+        # a tail that does not fit evicts for itself
+        slot = pc.take_tail_slot()
+        assert pc.attach_tail([50, 51, 52, 53], 4, slot)
+        assert pc._held() <= 6 and pc.has_tail([50, 51, 52, 53], 4)
+
+    def test_the_slots_are_the_tree_s_to_lend(self):
+        alloc, pc, ids, pages, slot = self.tree()
+        assert pc.has_tail(ids, 8) and not pc.has_tail(ids, 4)
+        # a node that has one keeps it; the offered slot is free again
+        second = pc.take_tail_slot()
+        assert second != slot and not pc.attach_tail(ids, 8, second)
+        assert not pc.attach_tail(ids + [1, 2, 3, 4], 16, pc.take_tail_slot())
+        assert pc.tail_slots_in_use == 1
+        # both slots out: the least recently used tail gives up its own
+        assert pc.attach_tail(ids, 4, pc.take_tail_slot())
+        pc.unlock(pc.match(ids + [99], need_tail=True))      # uses the one at 8
+        assert pc.take_tail_slot() is not None
+        assert pc.has_tail(ids, 8) and not pc.has_tail(ids, 4)
+        assert pc.get_stats()["tails_evicted"] == 1
+        # no slots configured: none to lend, and the stats say nothing
+        _, plain = make_cache()
+        assert plain.take_tail_slot() is None
+        assert "tail_slots" not in plain.get_stats()
+
+
 # -- engine integration (echo executor: page accounting) -----------------------
 
 

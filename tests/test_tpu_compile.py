@@ -1478,3 +1478,43 @@ def test_whole_copies_script_counts_what_is_computed_again(least, counted):
     more, counted by result (how granite's mixed step showed 69
     products of ``bf16[1024,8512]`` beside the model's 36)."""
     assert _script().rematerialised(_HLO, least) == counted
+
+
+@pytest.mark.parametrize("which", ["export", "import"])
+def test_the_tail_programs_move_pages_in_place_for_v5e(one_chip, which):
+    """The two tail programs of a window family at Mellum 2's served
+    sizes (9 sliding layers, 32 rows' slabs of 13 pages, 64 tail slots
+    of 8 pages): each moves its eight pages a layer where they lie — the
+    donated leaf comes back aliased, no temporary of a leaf's size. (A
+    gather of the eight pages out of the slabs made XLA copy each 0.49 GB
+    leaf in halves: 0.49 GB of temporaries where 19 MB move.)"""
+    import numpy as np
+
+    from llmq_tpu.models import get_config, mellum
+
+    cfg = get_config("mellum2-12b-a2.5b", max_seq_len=32768)
+    cfg = mellum.bind_cache(mellum.serving_config(cfg), page_size=128,
+                            step_tokens=512)
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    state = described(jax.eval_shape(lambda: mellum.init_row_state(cfg, 32)))
+    tails = described(jax.eval_shape(lambda: mellum.init_row_tails(cfg, 64)))
+    i = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
+    if which == "export":
+        fn = jax.jit(partial(mellum.export_row_tail, cfg),
+                     donate_argnums=(1,))
+        moved = tails
+    else:
+        fn = jax.jit(partial(mellum.import_row_tail, cfg),
+                     donate_argnums=(0,))
+        moved = state
+    compiled = fn.lower(state, tails, i, i, i).compile()
+    mem = compiled.memory_analysis()
+    leaf = min(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert mem.temp_size_in_bytes < leaf // 100
+    assert mem.alias_size_in_bytes == sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(moved))
+    assert not _whole_copies(compiled, (state, tails))
