@@ -38,10 +38,19 @@ layer by its own table, so a page or a tail is valid exactly where its
 tokens stood.
 
 Float32 residual stream and router, bf16 products, every layer
-unrolled; the mixed step runs a layer's slices and decode rows apart in
-the attention and TOGETHER in the feed-forward (the experts are streamed
-once). Int8 weights, an int8 cache and a mesh are not written: each is
-refused by name (``check_serving``).
+unrolled. **The mixed step runs the rows that hold a token**
+(``forward_mixed``, ``ops/rows.py``): ONE stream of B + S T rows, the
+B decode rows leading and the slices' tokens TIGHT behind them, from
+the door to the head. What is a row's own — the two norms, q / k / v
+with the per-head norms and the rotation, ``wo`` and the residual, the
+router — runs over the live prefix a tile of rows at a time
+(``live_rows``: as many tiles as hold a token, read on the device), and
+the routed experts multiply the live (token, expert) pairs alone, a
+block of sorted pairs at a time (``ops/moe.routed_ffn(n_live=...)``:
+no array of N k rows). Only the K/V write and the two attentions see
+the (S, T) grid and take the two kinds of row apart.
+``mixed_live_rows`` says what ran. Int8 weights, an int8 cache and a
+mesh are not written: each is refused by name (``check_serving``).
 """
 
 from __future__ import annotations
@@ -57,15 +66,16 @@ from llmq_tpu.models import afmoe
 from llmq_tpu.models.afmoe import (  # noqa: F401 — the family surface
     FULL, IDLE_ROW_CONTEXT, SLIDING, attention_window, bind_cache,
     export_row_tail, import_row_tail, init_kv_pages, init_row_state,
-    init_row_tails, kv_bytes_per_token, mixed_live_rows, routes,
-    row_state_bytes_per_row, row_tail)
+    init_row_tails, kv_bytes_per_token, routes, row_state_bytes_per_row,
+    row_tail)
 from llmq_tpu.models.latent import draw_groups
 from llmq_tpu.models.latent import prod as _prod
 from llmq_tpu.ops.moe import pass_extras, route, routed_ffn
 from llmq_tpu.ops.norms import rms_norm
 from llmq_tpu.ops.rope import (apply_rope, rope_cos_sin,
                                rope_cos_sin_scaled, yarn_inv_freq)
-from llmq_tpu.ops.rows import grid_positions, rows_to_grid
+from llmq_tpu.ops.rows import (grid_positions, grid_to_rows, live_rows,
+                               row_tile, rows_to_grid, tile_rows)
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -213,6 +223,14 @@ def step_stats_size(cfg: MellumConfig) -> int:
     return cfg.n_routed_experts + 2
 
 
+def mixed_live_rows(tokens: int, batch: int, slices: int, width: int) -> int:
+    """Rows ``forward_mixed``'s row-wise work runs for ``tokens`` prompt
+    tokens (``models/__init__.py``): the live tiles' rows, less the
+    ``batch`` decode rows that lead them
+    (``models/llama.mixed_live_rows``)."""
+    return tile_rows(tokens, row_tile(width), slices * width, lead=batch)
+
+
 # -- parameters ---------------------------------------------------------------
 
 def param_shapes(cfg: MellumConfig) -> Dict[str, Dict[str, tuple]]:
@@ -353,22 +371,38 @@ def _attn_close(h, attn, lp: Params, l: int, cfg: MellumConfig):
         return h + jnp.dot(a, lp["wo"][l]).astype(jnp.float32)
 
 
-def _ffn(params: Params, cfg: MellumConfig, l: int, h, live):
-    """Layer ``l``'s routed feed-forward over the stream's rows h
-    (N, D). Returns (h', stats, experts (N, k))."""
-    lp, m = params["layers"], params["moe"]
+def _ffn_in(lp: Params, cfg: MellumConfig, l: int, h):
+    """What layer ``l``'s experts read of the rows h (N, D): the normed
+    rows (N, D) in ``cfg.dtype``, and the router's choice of the
+    float32 ones, experts (N, k) and gates (N, k)."""
     with scope("mlp"):
         yf = rms_norm(h, lp["mlp_norm"][l], cfg.norm_eps)
         y = yf.astype(cfg.dtype)
-    experts, gates = route(
+    return (y,) + route(
         yf, lp["router"][l],
         jnp.zeros((cfg.n_routed_experts,), jnp.float32),
         top_k=cfg.n_experts_per_tok, scale=1.0, norm_topk=cfg.route_norm,
         scoring="softmax")
+
+
+def _ffn_out(params: Params, l: int, h, y, experts, gates, live,
+             n_live=None):
+    """h plus layer ``l``'s experts' weighted results for the normed
+    rows y (``n_live``: ``ops/moe.routed_ffn``'s). Returns (h',
+    stats)."""
+    m = params["moe"]
     f, st = routed_ffn(y, experts, gates, m["we_gate_up"][l],
-                       m["we_down"][l], live)
+                       m["we_down"][l], live, n_live=n_live)
     with scope("mlp"):
-        return h + f.astype(jnp.float32), st, experts
+        return h + f.astype(jnp.float32), st
+
+
+def _ffn(params: Params, cfg: MellumConfig, l: int, h, live):
+    """Layer ``l``'s routed feed-forward over the stream's rows h
+    (N, D). Returns (h', stats, experts (N, k))."""
+    y, experts, gates = _ffn_in(params["layers"], cfg, l, h)
+    h, st = _ffn_out(params, l, h, y, experts, gates, live)
+    return h, st, experts
 
 
 def _extras(cfg: MellumConfig, per_layer, stats: bool) -> tuple:
@@ -455,63 +489,89 @@ def forward_mixed(params: Params, cfg: MellumConfig, dec_tokens: jnp.ndarray,
                   stats: bool = False,
                   row_state: Optional[RowState] = None,
                   pf_rows: Optional[jnp.ndarray] = None):
-    """The fused mixed step (``models/afmoe.forward_mixed``'s contract
-    and order: the slices go back onto the (S, T) grid at the door, a
-    layer's slices write and attend before its decode rows do, the
-    feed-forward runs both together). Returns ``(dec_logits (B, V),
-    pf_logits (S, V), cache, row_state [, counts])``."""
+    """The fused mixed step (``models/llama.forward_mixed``'s contract
+    and layout of the rows, ``models/afmoe.forward_mixed``'s caches and
+    order). ONE stream h (B + S T, D): the B decode rows LEAD, the
+    slices' tokens lie TIGHT behind them as they were handed over
+    (slice ``s`` the ``pf_lengths[s]`` rows from ``B + pf_starts[s]``),
+    and the first ``B + pf_starts[S]`` rows are all that hold a token.
+    A layer's FRONT (the attention's norm, q / k / v, the per-head
+    norms, the rotation by the layer kind's table, made once for the
+    joined positions) and its CLOSE (``wo``, the residual, the
+    feed-forward's norm, the router's float32 scores and choice) run
+    over that prefix a tile of rows at a time (``ops/rows.live_rows``),
+    and the experts over the live (token, expert) pairs
+    (``routed_ffn(n_live=...)``). Between front and close the slices'
+    q, k, v go to the (S, T) grid, are written and attend there; the
+    barrier; then the decode rows' — the first B — write in place and
+    attend; the two outputs are laid back into one buffer. A row past
+    the live prefix is never read by anyone: of a slice only its last
+    valid row goes through the head, and a dead row is routed nowhere.
+    Returns ``(dec_logits (B, V), pf_logits (S, V), cache, row_state
+    [, counts])``."""
     B = dec_tokens.shape[0]
     S = pf_lengths.shape[0]
-    T = pf_tokens.shape[0] // S
+    N = pf_tokens.shape[0]
+    T = N // S
+    tile = row_tile(T)
     row_state, _ = afmoe._own_rows(cfg, B, kv_cache, row_state, None)
     if pf_rows is None:
         pf_rows = jnp.full((S,), B, jnp.int32)
-    pf_tokens = rows_to_grid(pf_tokens, pf_starts, T)
-    pf_positions, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
-                                               pf_starts, T)
+    n_live = B + pf_starts[S]
+    grid_pos, pf_seq_lens = grid_positions(pf_positions, pf_lengths,
+                                           pf_starts, T)
     with scope("decode_rows"):
         h_d = _embed(params, cfg, dec_tokens)
-        rope_d = rope_tables(cfg, dec_positions)
         geom = afmoe._decode_geometry(cfg, dec_positions, dec_block_tables,
                                       dec_active, kv_cache, row_state)
     with scope("slices"):
         h_p = _embed(params, cfg, pf_tokens)
-        rope_p = rope_tables(cfg, pf_positions)
-        pf_valid = jnp.arange(T)[None, :] < pf_lengths[:, None]
         pf_tables = {FULL: pf_block_tables,
                      SLIDING: afmoe._slab_table(pf_rows, cfg, row_state,
                                                 pf_block_tables.shape[1])}
-    live = jnp.concatenate([pf_valid.reshape(-1), geom[4]])
+    h = jnp.concatenate([h_d, h_p])                         # (B + N, D)
+    rope = rope_tables(cfg, jnp.concatenate([dec_positions, pf_positions]))
+    live = jnp.concatenate([geom[4], jnp.ones((N,), bool)])
     lp, per_layer = params["layers"], []
-    for l in range(cfg.n_layers):
-        with scope("slices"):
+    for l, kind in enumerate(cfg.layer_types):
+        def front(h, cos, sin, l=l, kind=kind):
             with scope("qkv"):
-                x = _normed(h_p, lp["attn_norm"][l], cfg)
-            q, k, v = _qkv(x, lp, l, rope_p, cfg)
-            attn, kv_cache, row_state = afmoe._prefill_attn(
-                cfg, l, q, k, v, kv_cache, row_state, pf_tables,
-                pf_positions, pf_lengths, pf_seq_lens)
+                x = _normed(h, lp["attn_norm"][l], cfg)
+            return _qkv(x, lp, l, {kind: (cos, sin)}, cfg)
+
+        def close(h, attn, l=l):
+            h = _attn_close(h, attn, lp, l, cfg)
+            return (h,) + _ffn_in(lp, cfg, l, h)
+
+        q, k, v = live_rows(front, n_live, tile, h, *rope[kind], lead=B)
+        with scope("slices"):
+            q_p, k_p, v_p = (rows_to_grid(x, pf_starts, T, lead=B)
+                             for x in (q, k, v))
+            attn_p, kv_cache, row_state = afmoe._prefill_attn(
+                cfg, l, q_p, k_p, v_p, kv_cache, row_state, pf_tables,
+                grid_pos, pf_lengths, pf_seq_lens)
             # The decode rows' write takes the pools in place: only
             # once the slices' attention has read them (models/afmoe).
-            attn, kv_cache, row_state = jax.lax.optimization_barrier(
-                (attn, kv_cache, row_state))
-            h_p = _attn_close(h_p, attn, lp, l, cfg)
+            attn_p, kv_cache, row_state = jax.lax.optimization_barrier(
+                (attn_p, kv_cache, row_state))
         with scope("decode_rows"):
-            with scope("qkv"):
-                x = _normed(h_d, lp["attn_norm"][l], cfg)
-            q, k, v = _qkv(x, lp, l, rope_d, cfg)
-            attn, kv_cache, row_state = afmoe._decode_attn(
-                cfg, l, q, k, v, kv_cache, row_state, geom)
-            h_d = _attn_close(h_d, attn, lp, l, cfg)
-        h, st, ex = _ffn(params, cfg, l,
-                         jnp.concatenate([h_p.reshape(S * T, -1), h_d]), live)
-        h_p, h_d = h[:S * T].reshape(S, T, -1), h[S * T:]
-        per_layer.append((st, ex))
+            attn_d, kv_cache, row_state = afmoe._decode_attn(
+                cfg, l, q[:B], k[:B], v[:B], kv_cache, row_state, geom)
+        with scope("slices"):
+            attn = grid_to_rows(
+                attn_p, pf_starts,
+                jnp.concatenate([attn_d, jnp.zeros((N,) + attn_d.shape[1:],
+                                                   attn_d.dtype)]), lead=B)
+        h, y, experts, gates = live_rows(close, n_live, tile, h, attn,
+                                         lead=B)
+        h, st = _ffn_out(params, l, h, y, experts, gates, live,
+                         n_live=n_live)
+        per_layer.append((st, experts))
     with scope("slices"):
         with scope("head"):
-            h_p = h_p[jnp.arange(S), pf_lengths - 1]
+            h_p = h[B + pf_starts[:S] + pf_lengths - 1]
         pf_logits = _head(params, cfg, h_p)
     with scope("decode_rows"):
-        dec_logits = _head(params, cfg, h_d)
+        dec_logits = _head(params, cfg, h[:B])
     return ((dec_logits, pf_logits, kv_cache, row_state)
             + _extras(cfg, per_layer, stats))

@@ -34,10 +34,10 @@ Nothing here stands in for the other chips or for the exchange with
 them. **Zero-compute experts** (``n_routed``): a chosen index at or
 above it is an identity expert, which adds ``g_e x`` and enters no
 group (``identity_gate`` gives the token's summed weight of them; the
-token's home chip adds it). With a share, the held pairs are a small
-and varying part of the ``N k`` chosen ones, so they are multiplied a
-BLOCK of sorted pairs at a time, as many blocks as there are held pairs
-(``_held_blocks``), and no array of ``N k`` rows is ever made.
+token's home chip adds it). A share's held pairs, and the live pairs
+of a tight mixed step (``n_live``), are a small and varying part of the
+``N k`` chosen ones: they are multiplied a BLOCK of sorted pairs at a
+time (``_held_blocks``) and no array of ``N k`` rows is ever made.
 
 ``stats`` of a call: tokens each (held) expert received and how many
 experts received any — with a share or zero-compute experts also the
@@ -170,17 +170,17 @@ def identity_gate(experts: jnp.ndarray, gates: jnp.ndarray, n_routed: int,
         return g if live is None else jnp.where(live, g, 0.0)
 
 
-def _held_blocks(x, key, gates, w_gate_up, w_down, counts, k):
+def _held_blocks(x, key, gates, w_gate_up, w_down, counts, k, blk=None):
     """The held pairs' weighted SwiGLU results summed by token, (N, D)
     float32. ``key`` (N k,): each pair's group among the held experts,
     or ``E_held`` for a pair that is multiplied with nothing; ``counts``
     (E_held,) the groups' sizes. The pairs are sorted by group and
-    taken ``HELD_BLOCK`` at a time while held pairs are left: a block's
+    taken ``blk`` (``HELD_BLOCK``) at a time while any are left: a block's
     groups are the groups' overlaps with its rows, its tokens' rows are
     gathered, multiplied and added to their tokens."""
     N, D = x.shape
     F = w_gate_up.shape[-1] // 2
-    M, blk = key.shape[0], HELD_BLOCK
+    M, blk = key.shape[0], blk or HELD_BLOCK
     with scope("moe_route"):
         order = jnp.pad(jnp.argsort(key, stable=True), (0, -M % blk))
         ends = jnp.cumsum(counts)
@@ -215,7 +215,7 @@ def routed_ffn(x: jnp.ndarray, experts: jnp.ndarray, gates: jnp.ndarray,
                w_gate_up: jnp.ndarray, w_down: jnp.ndarray,
                live: Optional[jnp.ndarray] = None, *,
                held: Optional[Tuple[int, int]] = None,
-               n_routed: Optional[int] = None
+               n_routed: Optional[int] = None, n_live=None
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """sum_i gates_i SwiGLU_{experts_i}(x) for every token of ``x``
     (N, D). ``w_gate_up`` (E, D, 2F) holds each expert's gate and up
@@ -233,8 +233,8 @@ def routed_ffn(x: jnp.ndarray, experts: jnp.ndarray, gates: jnp.ndarray,
     (``identity_gate``). With either, stats is (E_held + 3,): the
     tokens each held expert received, the held experts that received
     any, the slots that chose a zero-compute expert, the slots whose
-    expert is held elsewhere. All experts held and none zero-compute
-    is the call without either, to the bit."""
+    expert is held elsewhere. All held, none zero-compute: the call
+    without either, to the bit. ``n_live``: see ``_routed_live``."""
     N, k = experts.shape
     E, _, F2 = w_gate_up.shape
     F = F2 // 2
@@ -245,10 +245,10 @@ def routed_ffn(x: jnp.ndarray, experts: jnp.ndarray, gates: jnp.ndarray,
     if (lo, hi) != (0, E) or n_routed not in (None, E):
         return _routed_share(x, flat, gates, w_gate_up, w_down, live, k,
                              lo, n_routed)
+    if n_live is not None:
+        return _routed_live(x, flat, gates, w_gate_up, w_down, live, n_live)
     with scope("moe_route"):
-        if live is not None:
-            # Sorted behind the last group: outside every group, so no
-            # product touches those rows (they are zeroed below).
+        if live is not None:     # (dead rows sort behind every group)
             flat = jnp.where(jnp.repeat(live, k), flat, E)
         order = jnp.argsort(flat, stable=True)
         counts = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
@@ -290,6 +290,39 @@ def _routed_share(x, flat, gates, w_gate_up, w_down, live, k, lo, n_routed):
                                 n_zero, n_away])])
         return y.astype(x.dtype), stats
 
+
+#: Sorted pairs a block of ``_routed_live`` multiplies. A tight mixed
+#: step of Mellum 2 brings ~4,000 live pairs to 64 experts: a block
+#: reads again the one expert it shares with the block before it.
+LIVE_BLOCK = 2048
+
+
+def _routed_live(x, flat, gates, w_gate_up, w_down, live, n_live):
+    """``routed_ffn`` where the caller says how many rows, LYING FIRST,
+    can be live (``n_live``, a traced scalar: a tight mixed step's
+    decode rows and the slices' tokens behind them, ``ops/rows.py``; a
+    row at or past it is dead whatever ``live`` says): the pairs that
+    are multiplied are then the first ``sum(counts)`` of the sorted
+    order, and the gather of their rows, the two grouped products, the
+    activation between them, the weighing and the sum by token run over
+    those alone, ``LIVE_BLOCK`` sorted pairs at a time while live pairs
+    are left (``_held_blocks``). The sort keeps its static length. The
+    sum of a token's ``k`` results is float32 as in the plain form, in
+    the sorted order instead of the slots'. Same ``stats``."""
+    N, k = gates.shape
+    E = w_gate_up.shape[0]
+    with scope("moe_route"):
+        alive = jnp.arange(N) < n_live
+        if live is not None:
+            alive = alive & live
+        key = jnp.where(jnp.repeat(alive, k), flat, E)
+        counts = jnp.zeros((E,), jnp.int32).at[key].add(1, mode="drop")
+    y = _held_blocks(x, key, gates, w_gate_up, w_down, counts, k,
+                     min(LIVE_BLOCK, -(-N * k // 128) * 128))
+    with scope("moe_combine"):
+        stats = jnp.concatenate(
+            [counts, jnp.sum(counts > 0, dtype=jnp.int32)[None]])
+        return y.astype(x.dtype), stats
 
 
 def share_counts(st: jnp.ndarray, n_held: int) -> jnp.ndarray:

@@ -6,7 +6,12 @@ family ``deepseek_v3``, the latent decode kernel and the latent write
 (``ops/pallas/latent_decode.py``), each timed apart; or, for the family
 ``granitemoehybrid``, the Mamba-2 decode state update in place
 (``ops/pallas/ssm_update.py``) beside XLA's fusion of the same
-(``--lens ROWS``: that many of the batch's rows decode).
+(``--lens ROWS``: that many of the batch's rows decode); or, for the
+family ``mellum``, one layer's routed experts over a mixed step's rows
+(``--lens ROWS``: that many of the batch + token budget rows hold a
+token, lying first): ``ops/moe.routed_ffn``'s plain form beside the
+form that is told the count (``n_live``), at each ``--block`` of sorted
+pairs.
 
 Heads, page size, block table width, batch, pool and the int8 kernel
 come from a served configuration (``--model-file
@@ -959,7 +964,90 @@ def bench_kda_scan(args, doc) -> None:
 
 #: family dispatches are what this tool is about, so a new family's
 #: bench is a function here and an entry in this table.
-BENCHES = {"llama": bench_fused, "deepseek_v3": bench_latent,
+def bench_routed(args, doc) -> None:
+    """``ops/moe.routed_ffn`` of one layer at the served widths over the
+    rows of a mixed step (``max_batch_size`` + ``prefill_token_budget``
+    of them, ``--lens ROWS`` live and lying first): µs a call of the
+    plain form (a ``live`` mask: every array N k rows long) and of the
+    form that is told the count (``n_live``: a block of sorted pairs at
+    a time while live pairs are left), the latter at each ``--block``
+    (``moe.LIVE_BLOCK`` where none is given), and how far the two
+    results lie apart. Four layers' experts, each a leaf of its own,
+    are multiplied in turn, so that every call reads its matrices from
+    memory."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmq_tpu.ops import moe
+
+    ex = doc["server"]["executor"]
+    N = ex["max_batch_size"] + ex["mixed_batch"]["prefill_token_budget"]
+    D, F = doc["hidden_size"], doc["moe_intermediate_size"]
+    E, k, L = doc["num_experts"], doc["num_experts_per_tok"], 4
+    if args.rehearse:
+        os.environ["LLMQ_PALLAS"] = "interpret"
+        N, D, F, L = 160, 256, 128, 2
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path in interpret mode)")
+    ks = jax.random.split(jax.random.key(0), 3 + 2 * L)
+    x = jax.random.normal(ks[0], (N, D), jnp.bfloat16)
+    experts, gates = moe.route(
+        x, jax.random.normal(ks[1], (D, E), jnp.float32) / D ** 0.5,
+        jnp.zeros((E,), jnp.float32), top_k=k, scale=1.0, scoring="softmax")
+    w_in = [jax.random.normal(ks[3 + i], (E, D, 2 * F), jnp.bfloat16)
+            / D ** 0.5 for i in range(L)]
+    w_out = [jax.random.normal(ks[3 + L + i], (E, F, D), jnp.bfloat16)
+             / F ** 0.5 for i in range(L)]
+
+    def make(told):
+        @jax.jit
+        def run(x, experts, gates, w_in, w_out, rows):
+            live = jnp.arange(N) < rows
+            y = jnp.zeros((N, D), jnp.float32)
+            for a, b in zip(w_in, w_out):
+                y = y + moe.routed_ffn(
+                    x, experts, gates, a, b, live,
+                    **({"n_live": rows} if told else {}))[0]
+            return y
+        return run
+
+    print(f"{doc['name']}: routed experts N={N} D={D} F={F} E={E} k={k} "
+          f"layers={L} device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    n = 1 if args.rehearse else 10
+
+    def timed(told, rows):
+        run = make(told)
+        call = (x, experts, gates, w_in, w_out, jnp.int32(rows))
+        first = np.asarray(run(*call))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            y = run(*call)
+        jax.block_until_ready(y)
+        return (time.perf_counter() - t0) / (n * L) * 1e6, first
+
+    results = []
+    for spec in args.lens:
+        rows = min(N, int(spec))
+        rec = {"rows": rows, "of": N, "pairs": rows * k}
+        rec["plain_us"], plain = timed(False, rows)
+        line = f"  {rows:5d} rows live of {N}: plain {rec['plain_us']:,.1f}"
+        for block in args.block or [moe.LIVE_BLOCK]:
+            moe.LIVE_BLOCK = block
+            us, got = timed(True, rows)
+            gap = float(np.abs(got - plain).max())
+            rec[f"live_us_block_{block}"] = us
+            rec["max_abs_gap"] = max(gap, rec.get("max_abs_gap", 0.0))
+            line += f"; told, blocks of {block}: {us:,.1f} (apart {gap:.1e})"
+        results.append(rec)
+        print(line + " us/call", flush=True)
+    _save(args, doc, results)
+
+
+BENCHES = {"llama": bench_fused, "mellum": bench_routed, "deepseek_v3": bench_latent,
            "longcat_flash": bench_latent, "granitemoehybrid": bench_ssm,
            "ling_hybrid": bench_kda, "solar_open2": bench_kda}
 
@@ -996,6 +1084,8 @@ def main() -> None:
     ap.add_argument("--lanes", action="append", type=int, default=[],
                     help="granitemoehybrid: time the update kernel at "
                          "this many lanes a step of its walk as well")
+    ap.add_argument("--block", action="append", type=int, default=[],
+                    help="mellum: sorted pairs a block of the told form")
     ap.add_argument("--out", default="")
     ap.add_argument("--rehearse", action="store_true",
                     help="off the chip: interpret mode, 2 layers, 2 "

@@ -45,11 +45,20 @@ shows at 4 layers as at 24; a small stack is also PREFETCHED whole,
 ``copy-done`` into ``S(1)``, where the served one is not). The TPU
 library is loaded by this process: run it alone
 (``docs/observability.md``).
+
+``--lowered`` compiles nothing and prints a digest of each program's
+LOWERED text instead (StableHLO with its Mosaic payloads, which carry
+their call sites' file paths and line numbers): what XLA's cache key is
+made from. Two trees unpacked IN TURN AT THE SAME PATH that print the
+same digests give the same programs — how PR 55 showed that an edit of
+``ops/moe.py`` left the six other routed cells' programs where they
+were (a second digest, with every location, moves with any line).
 """
 
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import math
 import os
@@ -180,6 +189,9 @@ def main(argv: List[str] | None = None) -> int:
                     help="cut the model to this many layers (0: as served)")
     ap.add_argument("--program", action="append", default=[],
                     help="only this program (repeatable; default: all)")
+    ap.add_argument("--lowered", action="store_true",
+                    help="print a digest of each program's lowered text "
+                         "and compile nothing")
     ap.add_argument("--keep", default="",
                     help="write each program's optimised text into this "
                          "directory (<program>.hlo.txt)")
@@ -245,6 +257,10 @@ def main(argv: List[str] | None = None) -> int:
         if args.program and name not in args.program:
             continue
         t0 = time.perf_counter()
+        if args.lowered:
+            lowered = fn.lower(*operands).as_text()
+            print(f"{name}: {hashlib.md5(lowered.encode()).hexdigest()}")
+            continue
         text = fn.lower(*operands).compile().as_text()
         if args.keep:
             with open(os.path.join(args.keep, name + ".hlo.txt"), "w",
@@ -267,7 +283,8 @@ def main(argv: List[str] | None = None) -> int:
               f"{sum(again.values())}")
         for result, n in sorted(again.items()):
             print(f"    {n} x {result}")
-    print(f"{total} in all")
+    if not args.lowered:
+        print(f"{total} in all")
     return 0
 
 

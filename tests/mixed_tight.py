@@ -1,10 +1,14 @@
 """One mixed step whose slices lie TIGHT (``ops/rows.py``) against
 ``forward_prefill`` of each slice and ``forward_decode`` of the rows
 over the same pool: the decode rows' logits, each slice's last
-position's, and every page written. What the four families' test files
+position's, and every page written. What the five families' test files
 share (``test_mixed_batch.py``, ``test_mistral_w8kv8.py``,
-``test_deepseek_v3.py``, ``test_longcat_flash.py``): each runs
-``CASES`` as ONE parametrised test over its own tiny model.
+``test_deepseek_v3.py``, ``test_longcat_flash.py``, ``test_mellum.py``):
+each runs ``CASES`` as ONE parametrised test over its own tiny model. A
+family that keeps ROW STATE beside its pages (``init_row_state`` gives
+one: ``models/mellum``'s slabs) has it carried through both ways — the
+decode rows own batch rows 0 .. B - 1, slice ``s`` row B + s — and
+compared leaf by leaf with the pages.
 
 The step is traced with ``TILE``-row tiles in slices ``WIDTH`` wide, so
 that a tile's edge falls inside a slice; its own ``jax.jit``, so that
@@ -59,6 +63,13 @@ JOINED = {
     "four-tiles-full-last-tile-moved-back": (2, 2 * TILE,
                                              [(16, 0), (16, 0)]),
 }
+#: for a family whose rows keep a window in a ring (``models/mellum``
+#: at a window of 4 in slabs of 24 tokens): the first slice's 16 tokens
+#: behind 10 cached ones pass the window and wrap the ring
+RING = {
+    "past-the-window-round-the-ring": (2, 2 * TILE,
+                                       [(16, HISTORY), (7, 0)]),
+}
 
 _STATIC = {"forward_mixed": ("cfg",), "forward_decode": ("cfg",),
            "forward_prefill": ("cfg", "last_only")}
@@ -97,73 +108,97 @@ def tight_step(monkeypatch):
 
 
 def shape_of(case):
-    """``(slices, width, plan)`` of a case of ``CASES`` or ``JOINED``."""
+    """``(slices, width, plan)`` of a case of ``CASES``, ``JOINED`` or
+    ``RING``."""
     if case in CASES:
         return SLICES, WIDTH, CASES[case]
-    return JOINED[case]
+    return {**JOINED, **RING}[case]
 
 
-def _prefill(forward_prefill, cfg, params, cache, bt, toks, start,
-             width=WIDTH):
+def _prefill(forward_prefill, cfg, params, held, bt, toks, start,
+             width=WIDTH, row=None):
     """One slice alone: ``toks`` at ``start``.. through
     ``forward_prefill`` in a bucket ``width`` wide (never under
-    ``WIDTH``: the history's rows are up to 12 tokens)."""
+    ``WIDTH``: the history's rows are up to 12 tokens). ``held``: (the
+    pool, the row state or None); with a state the slice is batch row
+    ``row``'s."""
     n = len(toks)
     padded = np.zeros((1, width), np.int32)
     padded[0, :n] = toks
     pos = start + np.minimum(np.arange(width, dtype=np.int32), n - 1)[None]
-    logits, cache = forward_prefill(
+    cache, state = held
+    logits, *held = forward_prefill(
         params, cfg, jnp.asarray(padded), jnp.asarray(pos),
         jnp.asarray([n], jnp.int32), cache, jnp.asarray(bt[None]),
-        last_only=True)
-    return np.asarray(logits)[0], cache
+        last_only=True, **_state_args(state, rows=[row]))
+    return np.asarray(logits)[0], _held(held)
+
+
+def _state_args(state, **rows):
+    """What a row-state family's forward function takes besides."""
+    if state is None:
+        return {}
+    return {"row_state": state,
+            **{k: jnp.asarray(v, jnp.int32) for k, v in rows.items()}}
+
+
+def _held(out):
+    """``(pool, row state or None)`` of a forward function's returns
+    behind its logits."""
+    return (out[0], out[1] if len(out) > 1 else None)
 
 
 def both_ways(step, fam, cfg, params, case, *, page, cache_dtype=None,
               as_written=False):
     """The case's plan apart and together: ``(parts, mixed)``, each
     ``{"dec": the active rows' logits, "pf": the used slices' last
-    logits, "pages": {pool: all but page 0}}``; ``step=None`` leaves
-    the mixed step out."""
+    logits, "pages": {pool or row-state leaf: all but page 0}}``;
+    ``step=None`` leaves the mixed step out."""
     S, T, plan = shape_of(case)
-    rng = np.random.default_rng([*sorted(CASES), *sorted(JOINED)].index(case))
+    rng = np.random.default_rng(
+        [*sorted(CASES), *sorted(JOINED), *sorted(RING)].index(case))
     B = len(DECODE)
     mp = cfg.max_seq_len // page
     bts = (1 + np.arange((B + S) * mp).reshape(B + S, mp)).astype(np.int32)
     forward_prefill = _forward(fam, "forward_prefill", as_written)
-    cache = fam.init_kv_pages(cfg, 1 + (B + S) * mp, page,
-                              dtype=cache_dtype)
+    held = (fam.init_kv_pages(cfg, 1 + (B + S) * mp, page,
+                              dtype=cache_dtype),
+            fam.init_row_state(cfg, B + S))
 
     def draw(n):
         return rng.integers(3, cfg.vocab_size, n, dtype=np.int32)
 
-    def result(dec, pf, pool):          # page 0 is everyone's trash
+    def result(dec, pf, held):          # page 0 is everyone's trash
+        pool, state = held
         return {"dec": np.asarray(dec)[active],
                 "pf": np.asarray(pf)[:len(plan)],
                 "pages": {name: np.asarray(
-                    pool[name][:, 1:].astype(jnp.float32)) for name in pool}}
+                    leaf[:, 1:].astype(jnp.float32))
+                    for name, leaf in {**pool, **(state or {})}.items()}}
 
     for b, n in enumerate(DECODE):      # what the decode rows attend to
-        _, cache = _prefill(forward_prefill, cfg, params, cache, bts[b], draw(n), 0)
+        _, held = _prefill(forward_prefill, cfg, params, held, bts[b],
+                           draw(n), 0, row=b)
     for s, (_, start) in enumerate(plan):
         if start:                       # what a continuing slice attends to
-            _, cache = _prefill(forward_prefill, cfg, params, cache, bts[B + s],
-                                draw(start), 0)
+            _, held = _prefill(forward_prefill, cfg, params, held,
+                               bts[B + s], draw(start), 0, row=B + s)
     slices = [draw(n) for n, _ in plan]
     dec_tok, dec_pos = draw(B), np.asarray(DECODE, np.int32)
     active = np.arange(B) < B - 1
 
     # apart: each slice through forward_prefill, then the rows' step
-    ref = jax.tree.map(jnp.copy, cache)
+    ref = jax.tree.map(jnp.copy, held)
     ref_pf = []
     for s, (toks, (_, start)) in enumerate(zip(slices, plan)):
-        logits, ref = _prefill(forward_prefill, cfg, params, ref, bts[B + s], toks,
-                               start, T)
+        logits, ref = _prefill(forward_prefill, cfg, params, ref,
+                               bts[B + s], toks, start, T, row=B + s)
         ref_pf.append(logits)
-    ref_dec, ref = _forward(fam, "forward_decode", as_written)(
-        params, cfg, jnp.asarray(dec_tok), jnp.asarray(dec_pos), ref,
-        jnp.asarray(bts[:B]), active=jnp.asarray(active))
-    parts = result(ref_dec, np.stack(ref_pf), ref)
+    ref_dec, *ref = _forward(fam, "forward_decode", as_written)(
+        params, cfg, jnp.asarray(dec_tok), jnp.asarray(dec_pos), ref[0],
+        jnp.asarray(bts[:B]), active=jnp.asarray(active),
+        **_state_args(ref[1]))
+    parts = result(ref_dec, np.stack(ref_pf), _held(ref))
     if step is None:
         return parts, None
 
@@ -177,14 +212,17 @@ def both_ways(step, fam, cfg, params, case, *, page, cache_dtype=None,
     tok, pos, starts = rows.pack_grid(grid[0], grid[1], lens,
                                       used=len(plan))
     assert starts[-1] == sum(n for n, _ in plan)
-    dec, pf, got = step(fam, as_written)(
-        params, cfg, jnp.asarray(dec_tok), jnp.asarray(dec_pos), cache,
+    # (an unused slice names one past the last batch row)
+    pf_rows = [B + s if s < len(plan) else B + S for s in range(S)]
+    dec, pf, *got = step(fam, as_written)(
+        params, cfg, jnp.asarray(dec_tok), jnp.asarray(dec_pos), held[0],
         jnp.asarray(bts[:B]), jnp.asarray(tok), jnp.asarray(pos),
         jnp.asarray(lens), jnp.asarray(starts), jnp.asarray(pf_bts),
-        dec_active=jnp.asarray(active))
+        dec_active=jnp.asarray(active),
+        **_state_args(held[1], pf_rows=pf_rows))
     assert pf.shape == (S, parts["pf"].shape[-1])
     assert np.isfinite(np.asarray(pf)).all()
-    return parts, result(dec, pf, got)
+    return parts, result(dec, pf, _held(got))
 
 
 def check(step, fam, cfg, params, case, *, page, cache_dtype=None,

@@ -35,6 +35,8 @@ from llmq_tpu.models import mellum as ml
 from llmq_tpu.ops import moe
 from llmq_tpu.ops.rope import rope_cos_sin, yarn_inv_freq
 from llmq_tpu.ops.rows import pack_grid
+from mixed_tight import (CASES, JOINED, RING, check,  # noqa: F401
+                         check_served, tight_step)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILY = os.path.join(REPO, "benchmark", "families", "mellum")
@@ -200,6 +202,40 @@ def test_the_mixed_step_is_the_same_model(tiny):
     served = np.stack([np.asarray(pf_logits)[0]] + rest)
     got = held(served, cfg, params, seq, list(range(63, len(seq))))
     assert got["ok"], got
+
+
+@pytest.mark.parametrize("served", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", [*CASES, *JOINED, *RING])
+def test_the_tight_mixed_step_computes_what_the_parts_do(tight_step, case,
+                                                         served):
+    """``forward_mixed`` — one stream, the decode rows leading the
+    slices' tight tokens, front and close in live tiles of 8 rows, the
+    experts over the live pairs — against ``forward_prefill`` of each
+    slice + ``forward_decode`` of the rows over the same pool AND slabs
+    (``tests/mixed_tight.py``): the logits, the full layers' pages and
+    the sliding layers' slabs. A tile's edge inside a slice, an unused
+    slice, one token, a continuation behind cached history, all slices
+    full with the last tile moved back, a decode row that is not
+    active; at a window of 4 tokens in slabs of 24, so every case
+    passes the window and ``RING``'s wraps the ring; both kinds of
+    layer. In float32, and in bfloat16 as served with the programs
+    compiled to round where their source rounds
+    (``mixed_tight._forward``): there the two ways read 0.0 apart in
+    ten of the twelve cases and one bfloat16 step (0.008-0.03) in two —
+    the live pairs' results are added to their token in the sorted
+    order, not the slots'. (Left to keep a bfloat16 chain wide inside a
+    fusion, XLA's CPU backend does so differently in a loop's body: the
+    logits then read 0.03-0.55 apart through swapped experts.)"""
+    cfg = ml.bind_cache(
+        ml.mellum_tiny(dtype=jnp.bfloat16 if served else jnp.float32,
+                       max_seq_len=64, sliding_window=4),
+        page_size=4, step_tokens=16)
+    assert ml.attention_window(cfg)["slab_tokens"] == 24
+    params = ml.init_params(jax.random.PRNGKey(55), cfg)
+    if served:
+        return check_served(tight_step, ml, cfg, params, case, page=4,
+                            atol=6e-2, pages_atol=6e-2, as_written=True)
+    check(tight_step, ml, cfg, params, case, page=4)
 
 
 def test_the_adopted_path_gives_the_cold_path_s_logits(tiny):

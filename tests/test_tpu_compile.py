@@ -1518,3 +1518,100 @@ def test_the_tail_programs_move_pages_in_place_for_v5e(one_chip, which):
     assert mem.alias_size_in_bytes == sum(
         x.size * x.dtype.itemsize for x in jax.tree.leaves(moved))
     assert not _whole_copies(compiled, (state, tails))
+
+
+# -- mellum: the mixed chunk over the rows that hold a token ---------------------
+
+
+@pytest.fixture(scope="module")
+def mellum_served(one_chip):
+    """``mellum2-12b-a2.5b-bf16``'s executor as the cell builds it
+    (``scripts/whole_copies.py``'s way: the configuration file through
+    the family's adapter and the server block through the builder's
+    ``executor_geometry`` — 32 rows, four slices of 512, 2,048 pages,
+    64 tail slots — over the DESCRIPTION of the parameters), with its
+    ``mixed_chunk`` compiled for the described chip."""
+    import json
+    import tempfile
+
+    from benchmark.harness import contract
+    from llmq_tpu.core.config import load_config
+    from llmq_tpu.engine.builder import executor_geometry
+    from llmq_tpu.engine.executor import JaxExecutor, describe
+    from llmq_tpu.models import mellum
+    from llmq_tpu.ops import attention
+
+    with open(os.path.join(contract.ROOT, "benchmark", "configs",
+                           "mellum2-12b-a2.5b-bf16.json")) as f:
+        doc = json.load(f)
+    adapter = contract.load_family(
+        os.path.join(contract.ROOT, "benchmark", "families", "mellum"),
+        "adapter")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention.jax, "default_backend", lambda: "tpu")
+        patch.delenv("LLMQ_PALLAS", raising=False)
+        name = "mellum-compile-check"
+        mcfg = adapter.register(name, doc)
+        patch.delitem(mellum.MODEL_CONFIGS, name)
+        with tempfile.NamedTemporaryFile("w", suffix=".json") as f:
+            json.dump(doc["server"], f)              # JSON is YAML
+            f.flush()
+            geometry = executor_geometry(load_config(f.name, env=False))
+        params = describe(jax.eval_shape(
+            adapter.param_builder(mcfg, doc["server"]["model"]),
+            jax.random.PRNGKey(0)), one_chip)
+        ex = JaxExecutor(mcfg, params, **geometry, telemetry_metrics=False)
+        (fn, operands), = [(fn, operands)
+                           for name, fn, operands, _ in ex.programs()
+                           if name == "mixed_chunk"]
+        yield ex, fn.lower(*operands).compile()
+
+
+def test_mellum_s_mixed_chunk_holds_no_grid_of_pairs_for_v5e(mellum_served):
+    """Mellum 2's ``mixed_chunk`` AS DISPATCHED at the served shape (12
+    layers, 32 decode rows leading four tight slices of 512, 2,048
+    pages beside 32 rows' slabs, 64 tail slots), since its mixed step
+    runs the rows that hold a token (PR 55): the page pool and both
+    slab leaves go in and come out in place and none is copied; no
+    array of 16,640 (token, expert) pairs stands anywhere (the sort's
+    keys alone keep that length: the parent held 768 such arrays up to
+    2,304 wide, 153 MB the float32 one); three loops a layer are there
+    (front, close, the blocks of live pairs); the only stacked leaves
+    copied whole are ``wq``, ``wk`` and ``wv``, once each, transposed
+    for the decode LOOP's 32-row products as in the parent's program
+    (the front's head split inside its loop asks for the same layout:
+    no copy more); and the temporaries, 0.37 GB, stay under the
+    parent's 0.95 GB — the cell runs at 92 % of the chip."""
+    import re
+    ex, compiled = mellum_served
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert (ex.spec.batch_size, ex.mixed_prefill_slices,
+            ex.mixed_slice_tokens, ex.spec.num_pages) == (32, 4, 512, 2048)
+    held = (ex.cache, ex.row_state)
+    assert not _whole_copies(compiled, held)
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(held))
+    assert not re.search(r"\[16640,\d\d+\]", text)
+    assert _control_flow(compiled).count("while") >= 3 * 12
+    assert sorted(_whole_copies(compiled, ex.params)) == [
+        "[12,2304,4096]", "[12,2304,512]", "[12,2304,512]"]
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("tokens,rows", [
+    (0, 224), (1, 224), (224, 224), (490, 736), (2016, 2016), (2048, 2048)])
+def test_mellum_s_mixed_chunk_reports_the_rows_it_ran(mellum_served, tokens,
+                                                      rows):
+    """``mixed_slice_live_share``'s denominator
+    (``executor.slice_tokens`` -> the ``engine.dispatch`` span's
+    ``slice_tokens``) is the family's own rule, which is the loop's
+    trip count (``ops/rows.tile_rows`` with the 32 decode rows leading):
+    256-row tiles over 32 + ``tokens`` rows, less the 32 — one tile
+    even for no token at all, and never more than the 2,048 slice
+    rows."""
+    from llmq_tpu.models import mellum
+    from llmq_tpu.ops.rows import tile_rows
+    ex, _ = mellum_served
+    assert ex.slice_tokens("mixed_chunk", tokens) == rows
+    assert mellum.mixed_live_rows(tokens, 32, 4, 512) == rows
+    assert tile_rows(tokens, 256, 2048, lead=32) == rows
