@@ -693,7 +693,7 @@ class InferenceEngine:
             if self._pipe_cfg is not None else 0)
         self._completion: Optional[_CompletionPool] = None
         #: Dispatched-but-unfetched chunks, oldest first (pipelined
-        #: path). See _decode_once / _dispatch_carried / step().
+        #: path). See _emit_chunk / _dispatch_carried / step().
         self._inflight: "deque[_InflightChunk]" = deque()
         #: Chunks dispatched at each pipeline occupancy (depth AFTER
         #: the dispatch) — the bench's depth histogram. Keys are
@@ -1468,10 +1468,9 @@ class InferenceEngine:
                 with self._prof.span("engine.fill") as fill:
                     dispatched = 0
                     while True:
-                        nxt = self._dispatch_carried(self._inflight[-1])
-                        if nxt is None:
+                        if self._dispatch_carried(
+                                self._inflight[-1]) is None:
                             break
-                        self._inflight.append(nxt)
                         dispatched += 1
                         if not self._can_fill():
                             break
@@ -1593,16 +1592,27 @@ class InferenceEngine:
 
     def _assemble(self) -> bool:
         """Host assembly of the next chunk from reconciled state
-        (``engine.assemble``: eligibility, ``_budget_chunk_rows``, the
-        staging buffers) and its dispatch (``engine.dispatch`` inside,
-        the executor call alone). Nothing seated: no span."""
+        (``engine.assemble``: a fresh plan — eligibility,
+        ``_budget_chunk_rows`` — then ``_emit_chunk``'s staging) and
+        its dispatch (``engine.dispatch`` inside, the executor call
+        alone). Nothing seated: no span."""
         if not any(s is not None for s in self._slots):
             self._set_gauges()
             return False
         with self._prof.span("engine.assemble"):
-            if self._mixed_applicable():
-                return self._mixed_once()
-            return self._decode_once()
+            plan = self._plan_mixed() if self._mixed_applicable() else None
+            if plan is None:
+                # No slice to run — or none left of a mixed plan (every
+                # candidate shed or cancelled while its rows were
+                # budgeted: rare), which is planned AGAIN as a decode
+                # chunk, joining rows and all: the second budgeting pass
+                # is idempotent (pages already ensured, need <= 0).
+                plan = self._plan_decode()
+            if plan is None:
+                self._set_gauges()
+                return False
+            self._emit_chunk(*plan)
+            return True
 
     def run_until_idle(self, max_steps: int = 100000) -> None:
         for _ in range(max_steps):
@@ -2973,19 +2983,6 @@ class InferenceEngine:
         acc["cache_reserved_tokens"] += out["window_reserved"]
         return out
 
-    def _chunk_dispatch(self, entry: str, budgets: np.ndarray,
-                        context_tokens: int, prefill_tokens: int = 0,
-                        pf: Sequence[tuple] = ()):
-        """``_dispatch_span`` for a chunk whose row budgets are the
-        (B,) array handed to the device (``pf``: the prompt slices a
-        mixed chunk carries, as ``_take_slices`` hands them over)."""
-        return self._dispatch_span(
-            entry, steps=int(budgets.max()),
-            rows=int(np.count_nonzero(budgets)),
-            row_steps=int(budgets.sum()), context_tokens=context_tokens,
-            prefill_tokens=prefill_tokens, state_rows=len(pf),
-            slice_lens=[len(sl[1]) for sl in pf])
-
     def _prefill_dispatch(self, entry: str, chunks):
         """``_dispatch_span`` for a dedicated prefill program over
         ``chunks`` (one prompt chunk a row)."""
@@ -2997,10 +2994,11 @@ class InferenceEngine:
 
     def _dispatch_carried(
             self, infl: _InflightChunk) -> Optional[_InflightChunk]:
-        """Dispatch the next chunk from the in-flight chunk's
-        device-carried end state, BEFORE its tokens are fetched.
-        Returns None, with the reason counted (``fill_refusals``), when
-        that isn't possible: the caller reconciles instead.
+        """Plan the next chunk from the in-flight chunk's
+        device-carried end state, BEFORE its tokens are fetched, and
+        send it (``_emit_chunk``). Returns None, with the reason counted
+        (``fill_refusals``), when that isn't possible: the caller
+        reconciles instead.
 
         **Decode rows.** Budgets use conservative upper bounds (as if
         every chunk in flight consumes its full budget on every row):
@@ -3023,7 +3021,7 @@ class InferenceEngine:
 
         **Prefill slices**, under a full batch: the prompt slices of
         seated mid-prefill sequences ride along as a carried MIXED
-        chunk, packed as ``_mixed_once`` packs them (their pages were
+        chunk, packed as ``_plan_mixed`` packs them (their pages were
         granted at admission). One sequence's consecutive slices may
         ride consecutive chunks in flight: the device queue is FIFO.
 
@@ -3036,8 +3034,7 @@ class InferenceEngine:
         decides what an admission is worth."""
         B = self.spec.batch_size
         full = all(s is not None for s in self._slots)
-        chunk = max(1, getattr(self.executor, "chunk_size", 1))
-        chunk = min(chunk, self._admission_cap())
+        chunk = self._chunk_steps()
         capacity = self.spec.max_pages_per_seq * self.spec.page_size
         plan = []   # (seq, slot, budget, pages_needed)
         ctx = 0     # context tokens the rows attend to (upper bound)
@@ -3068,7 +3065,7 @@ class InferenceEngine:
                 pos_upper + b, self.spec.page_size) - len(seq.pages)
             plan.append((seq, slot, b, max(0, need)))
             ctx += pos_upper
-        # Joining rows: same eligibility as _decode_once's join path
+        # Joining rows: same eligibility as _plan_decode's join path
         # (final prefill dispatched, not a rebuild/resume), minus rows
         # already snapshotted into ANY in-flight chunk.
         join_plan = []   # (seq, slot, budget, pages_needed)
@@ -3116,12 +3113,7 @@ class InferenceEngine:
                     full and self._evict_unheld(n, d)):
                 self._refuse_fill("pages")   # would shed → reconcile
                 return None
-        t_asm = time.perf_counter()   # step decomposition: dispatch leg
-        budgets = np.zeros(B, np.int32)   # read again at process time
-        block_tables = self._staging.take(
-            "chunk.bt", (B, self.spec.max_pages_per_seq), np.int32)
-        temps = self._staging.take("chunk.temp", (B,), np.float32)
-        for seq, slot, b, need in plan + join_plan:
+        for seq, slot, _, need in plan + join_plan:
             if need > 0:
                 pages = self.allocator.alloc(
                     need, shard=self._slot_shard(slot))
@@ -3129,45 +3121,11 @@ class InferenceEngine:
                 seq.block_table[len(seq.pages):len(seq.pages) + need] = pages
                 seq.pages.extend(pages)
                 self._usage_pages(seq)
-            budgets[slot] = b
-            block_tables[slot] = seq.block_table
-            temps[slot] = seq.req.temperature
-        overrides = [(slot, first_of[slot], seq.pos)
-                     for seq, slot, _, _ in join_plan]
-        seqs = list(infl.seqs)
-        for seq, slot, _, _ in join_plan:
-            seqs[slot] = seq
-        infl_pf = None
-        if pf_plan:
-            packed = sum(len(sl) for _, sl in pf_plan)
-            pf, infl_pf = self._take_slices(pf_plan, pf_budget, len(plan))
-            t0 = time.perf_counter()
-            with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                      prefill_tokens=packed, pf=pf):
-                handle = self.executor.mixed_chunk_start(
-                    None, None, block_tables, temps, budgets, pf,
-                    carry=infl.handle, overrides=overrides)
-            self._mixed_dispatched(handle, infl_pf, packed,
-                                   time.perf_counter() - t0, bool(plan))
-        else:
-            with self._chunk_dispatch("decode_chunk", budgets, ctx):
-                handle = self.executor.decode_chunk_start(
-                    None, None, block_tables, temps, budgets,
-                    carry=infl.handle, overrides=overrides)
-            _prefetch(getattr(handle, "out", None))
-        now = time.perf_counter()
-        dispatch_s = now - t_asm
-        self.steps += 1
-        self._note_dispatch_depth(len(self._inflight) + 1)
-        # (caller appends the chunk after return)
-        if self._metrics:
-            self._m("decode_steps").inc()
-        infl_next = _InflightChunk(handle, seqs, budgets, pf=infl_pf,
-                                   dispatch_s=dispatch_s,
-                                   dispatched_at=now,
-                                   chunk=self._dispatch_serial)
-        self._start_fetch(infl_next)
-        return infl_next
+        return self._emit_chunk(
+            [row[:3] for row in plan + join_plan],
+            [(slot, first_of[slot], seq.pos)
+             for seq, slot, _, _ in join_plan],
+            ctx, pf_plan, pf_budget, carry=infl)
 
     def _final_slice_first(self, seq: _Sequence):
         """For a sequence whose FINAL prefill slice rides a mixed chunk
@@ -3584,12 +3542,11 @@ class InferenceEngine:
         self._set_gauges()
 
     def _budget_chunk_rows(self, chunk: int, rows) -> Dict[int, int]:
-        """Shared eligibility + budgeting for chunk assembly
-        (_decode_once AND _mixed_once — the two must stay in lockstep
-        or the mixed path's token-equivalence contract breaks): reap
-        cancelled/length rows, back each survivor's budget with pages
-        (preempt-with-release when the pool can't), and return
-        seq.order → budget."""
+        """Eligibility + budgeting of a host-assembled chunk's rows —
+        both fresh plans call it (``_plan_decode``, ``_plan_mixed``)
+        and neither stages anything: reap cancelled/length rows, back
+        each survivor's budget with pages (preempt-with-release when
+        the pool can't), and return seq.order → budget."""
         budgets_by_order: Dict[int, int] = {}
         for seq in rows:
             if seq.slot is None:
@@ -3653,12 +3610,18 @@ class InferenceEngine:
                 if b > 1:
                     budgets_by_order[s.order] = max(1, int(b * scale))
 
-    def _decode_once(self) -> bool:
-        B = self.spec.batch_size
-        chunk = max(1, getattr(self.executor, "chunk_size", 1))
-        chunk = min(chunk, self._admission_cap())
-        start_fn = (getattr(self.executor, "decode_chunk_start", None)
-                    if chunk > 1 else None)
+    def _chunk_steps(self) -> int:
+        """The most steps a row may take in the next chunk: the
+        executor's chunk size under the admission cap."""
+        return min(max(1, getattr(self.executor, "chunk_size", 1)),
+                   self._admission_cap())
+
+    def _plan_decode(self) -> Optional[tuple]:
+        """The plan of a host-assembled DECODE chunk — ``(rows,
+        overrides, context_tokens)`` for ``_emit_chunk`` — or None with
+        no row to step: the prefilled rows and the joining ones,
+        budgets exact through ``_budget_chunk_rows``."""
+        chunk = self._chunk_steps()
         active = [s for s in self._slots
                   if s is not None and s.prefilled]
         # Same-step decode JOIN: a sequence whose final prefill chunk is
@@ -3672,16 +3635,14 @@ class InferenceEngine:
         # later owner rewrites before reading). Rebuild-resume rows are
         # excluded — their replayed first sample is discarded by design.
         joining = []
-        if start_fn is not None:
+        if chunk > 1 and getattr(self.executor, "decode_chunk_start",
+                                 None) is not None:
             joining = [s for s in self._slots
                        if s is not None and not s.prefilled
                        and s.first_handle is not None
                        and not s.todo_ids and s.todo_resume is None
                        and not s.todo_rebuild
                        and not s.handle.cancelled]
-        if not active and not joining:
-            self._set_gauges()
-            return False
         budgets_by_order = self._budget_chunk_rows(chunk,
                                                    list(active) + joining)
         active = [s for s in self._slots
@@ -3690,95 +3651,17 @@ class InferenceEngine:
                    if s.slot is not None and s.first_handle is not None
                    and s.order in budgets_by_order]
         if not active and not joining:
-            self._set_gauges()
-            return False
+            return None
+        return ([(s, s.slot, budgets_by_order.get(s.order, 1))
+                 for s in active + joining],
+                [(s.slot, s.first_handle, s.pos) for s in joining],
+                sum(s.pos for s in active + joining))
 
-        t_asm = time.perf_counter()   # step decomposition: dispatch leg
-        st = self._staging            # per-dispatch alloc churn killer
-        tokens = st.take("chunk.tok", (B,), np.int32)
-        positions = st.take("chunk.pos", (B,), np.int32)
-        block_tables = st.take("chunk.bt",
-                               (B, self.spec.max_pages_per_seq), np.int32)
-        temps = st.take("chunk.temp", (B,), np.float32)
-        budgets = np.zeros(B, np.int32)   # read again at process time
-        overrides = []
-        ctx = 0
-        for seq in active + joining:
-            i = seq.slot
-            # Joining rows' input token is a device scalar (their
-            # prefill's sample); the host placeholder is overridden.
-            if seq.prefilled:
-                tokens[i] = seq.last_token
-            else:
-                overrides.append((i, seq.first_handle, seq.pos))
-            positions[i] = seq.pos
-            ctx += seq.pos
-            block_tables[i] = seq.block_table
-            temps[i] = seq.req.temperature
-            budgets[i] = budgets_by_order.get(seq.order, 1)
-        if start_fn is not None:
-            # Pipelined: dispatch only — tokens are fetched on the NEXT
-            # step (possibly after the next chunk is already running).
-            with self._chunk_dispatch("decode_chunk", budgets, ctx):
-                handle = start_fn(tokens, positions, block_tables, temps,
-                                  budgets, overrides=overrides)
-            now = time.perf_counter()
-            dispatch_s = now - t_asm
-            _prefetch(getattr(handle, "out", None))
-            seqs = [None] * B
-            for seq in active + joining:
-                seqs[seq.slot] = seq
-            infl = _InflightChunk(handle, seqs, budgets,
-                                  dispatch_s=dispatch_s,
-                                  dispatched_at=now,
-                                  chunk=self._dispatch_serial)
-            self._inflight.append(infl)
-            self._note_dispatch_depth(len(self._inflight))
-            self._start_fetch(infl)
-            self.steps += 1
-            if self._metrics:
-                self._m("decode_steps").inc()
-            return True
-        t_call = time.perf_counter()
-        if chunk > 1 and hasattr(self.executor, "decode_chunk"):
-            with self._chunk_dispatch("decode_chunk", budgets, ctx):
-                out = self.executor.decode_chunk(tokens, positions,
-                                                 block_tables, temps,
-                                                 budgets)
-        else:
-            with self._dispatch_span("decode", steps=1, rows=len(active),
-                                     row_steps=len(active),
-                                     context_tokens=ctx):
-                out = self.executor.decode(tokens, positions, block_tables,
-                                           temps)[:, None]
-        t_done = time.perf_counter()
-        out = np.asarray(out)        # readback fence (no-op for echo)
-        t_rb = time.perf_counter()
-        self.steps += 1
-        if self._metrics:
-            self._m("decode_steps").inc()
-        if self._usage.enabled or self._cp.enabled:
-            parts = [(seq, max(1, int(budgets[seq.slot])), False)
-                     for seq in active if seq.slot is not None]
-            if self._usage.enabled:
-                self._charge_step(t_done - t_call, parts)
-            if self._cp.enabled:
-                self._cp_decode_share(
-                    (t_done - t_call) + (t_rb - t_done), parts,
-                    [(seq, w) for seq, w, _ in parts])
-        tok0 = self.tokens_generated_total
-        for seq in active:
-            self._commit_row(seq, out[seq.slot], int(budgets[seq.slot]))
-            self._flush_emits(seq)
-        self._telemetry.note_step(t_call - t_asm, t_done - t_call,
-                                  t_rb - t_done,
-                                  self.tokens_generated_total - tok0)
-        self._set_gauges()
-        return True
-
-    def _mixed_once(self) -> bool:
-        """Dispatch ONE mixed iteration: the active decode rows' chunk
-        plus up to ``mixed_batch.prefill_token_budget`` tokens of
+    def _plan_mixed(self) -> Optional[tuple]:
+        """The plan of a host-assembled MIXED chunk — ``(rows, None,
+        context_tokens, pf_plan, pf_budget)`` for ``_emit_chunk`` — or
+        None when the packing came back empty: the active decode rows'
+        chunk plus up to ``mixed_batch.prefill_token_budget`` tokens of
         pending prefill slices, fused into a single device program
         (executor ``mixed_chunk_start`` / ``mixed_chunk``). This
         replaces the "prefill program, then decode chunk" serialization
@@ -3788,23 +3671,21 @@ class InferenceEngine:
         streams are identical to the unfused path — slices write the
         same KV at the same positions, the final slice samples the same
         first token, decode rows never read another sequence's pages.
-        """
-        B = self.spec.batch_size
-        chunk = max(1, getattr(self.executor, "chunk_size", 1))
-        chunk = min(chunk, self._admission_cap())
 
-        # Decode rows: same eligibility/budgeting as _decode_once (no
-        # join rows — mixed iterations reconcile every cycle, so there
-        # is never an unresolved first_handle to join here).
-        budgets_by_order = self._budget_chunk_rows(
-            chunk, [s for s in self._slots
-                    if s is not None and s.prefilled])
+        No joining rows: a sequence with an unresolved ``first_handle``
+        beside a mixed chunk is left for the next one.
+        """
         active = [s for s in self._slots
                   if s is not None and s.prefilled]
-
+        budgets_by_order = self._budget_chunk_rows(self._chunk_steps(),
+                                                   active)
+        active = [s for s in self._slots
+                  if s is not None and s.prefilled]
         # Prefill slices, most urgent first — packed AFTER decode
         # budgeting (its page allocation may shed a mid-prefill victim;
-        # the pack must see the post-shed state).
+        # the pack must see the post-shed state: packing BEFORE it
+        # would reintroduce the stale-slice bug, a shed victim's
+        # todo_ids fold into its rebuild stream).
         cands = [s for s in self._slots
                  if s is not None and not s.prefilled and s.todo_ids
                  and s.first_handle is None and not s.mixed_pending]
@@ -3812,109 +3693,127 @@ class InferenceEngine:
             if s.handle.cancelled:
                 self._finish_active(s, "cancelled")
                 cands.remove(s)
-        pf_plan, budget = self._pack_slices(cands)
-        packed = sum(len(sl) for _, sl in pf_plan)
+        pf_plan, pf_budget = self._pack_slices(cands)
         if not pf_plan:
             # Every candidate was shed/cancelled DURING decode
             # budgeting (a page-pressure race — _mixed_applicable
-            # guaranteed one existed at entry): fall back to a plain
-            # chunk. _decode_once re-runs the budgeting pass, which is
-            # idempotent (pages already ensured, need <= 0) and rare
-            # enough that sharing budgets across the two paths isn't
-            # worth the coupling; packing BEFORE budgeting instead
-            # would reintroduce the stale-slice bug (a shed victim's
-            # todo_ids fold into its rebuild stream).
-            return self._decode_once()
+            # guaranteed one existed at entry): ``_assemble`` plans a
+            # plain chunk instead.
+            return None
+        return ([(s, s.slot, budgets_by_order.get(s.order, 1))
+                 for s in active], None, sum(s.pos for s in active),
+                pf_plan, pf_budget)
 
+    def _emit_chunk(self, rows, overrides, ctx: int, pf_plan=(),
+                    pf_budget: int = 0,
+                    carry: Optional[_InflightChunk] = None
+                    ) -> _InflightChunk:
+        """Send the chunk a plan describes: the ONE way a decode or a
+        mixed chunk reaches the executor, whoever planned it
+        (``_plan_decode``, ``_plan_mixed``, ``_dispatch_carried``).
+        ``rows``: ``(seq, slot, budget)`` of every row that steps, the
+        joining rows last; ``overrides``: their lanes (None: the call
+        names none); ``ctx``: the context tokens the rows attend to;
+        ``pf_plan`` / ``pf_budget``: ``_pack_slices``' answer, handed
+        over here — with slices the chunk is a ``mixed_chunk``;
+        ``carry``: the chunk in flight this one starts from — tokens
+        and positions stay on the device, and its row snapshot is this
+        one's with the joining rows laid over it.
+
+        Pipelined (the executor has the ``*_start`` call; a chunk from
+        the host needs ``chunk_size`` > 1 too) the chunk is appended to
+        ``_inflight`` HERE and its fetch started. Otherwise the one
+        SERIAL arm: the blocking call, then ``_commit_chunk``, as for
+        a fetched one."""
+        B = self.spec.batch_size
         t_asm = time.perf_counter()   # step decomposition: dispatch leg
         st = self._staging            # per-dispatch alloc churn killer
-        tokens = st.take("chunk.tok", (B,), np.int32)
-        positions = st.take("chunk.pos", (B,), np.int32)
+        tokens = positions = None
+        if carry is None:
+            tokens = st.take("chunk.tok", (B,), np.int32)
+            positions = st.take("chunk.pos", (B,), np.int32)
         block_tables = st.take("chunk.bt",
                                (B, self.spec.max_pages_per_seq), np.int32)
         temps = st.take("chunk.temp", (B,), np.float32)
         budgets = np.zeros(B, np.int32)   # read again at process time
-        ctx = 0
-        for seq in active:
-            i = seq.slot
-            tokens[i] = seq.last_token
-            positions[i] = seq.pos
-            ctx += seq.pos
-            block_tables[i] = seq.block_table
-            temps[i] = seq.req.temperature
-            budgets[i] = budgets_by_order.get(seq.order, 1)
-
-        pf, infl_pf = self._take_slices(pf_plan, budget, len(active))
-
-        start_fn = getattr(self.executor, "mixed_chunk_start", None)
-        t0 = time.perf_counter()
-        if start_fn is not None:
-            with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                      prefill_tokens=packed, pf=pf):
-                handle = start_fn(tokens, positions, block_tables,
-                                  temps, budgets, pf)
-            dispatch_s = time.perf_counter() - t_asm
-            self._mixed_dispatched(handle, infl_pf, packed,
-                                   time.perf_counter() - t0, bool(active))
-            seqs = [None] * B
-            for seq in active:
-                seqs[seq.slot] = seq
-            infl = _InflightChunk(handle, seqs, budgets, pf=infl_pf,
-                                  dispatch_s=dispatch_s,
-                                  dispatched_at=time.perf_counter(),
-                                  chunk=self._dispatch_serial)
+        seqs = [None] * B if carry is None else list(carry.seqs)
+        for seq, slot, budget in rows:
+            if carry is None:
+                # A joining row's input token is a device scalar (its
+                # prefill's sample): the host placeholder is overridden.
+                if seq.prefilled:
+                    tokens[slot] = seq.last_token
+                positions[slot] = seq.pos
+            block_tables[slot] = seq.block_table
+            temps[slot] = seq.req.temperature
+            budgets[slot] = budget
+            seqs[slot] = seq
+        decoding = len(rows) - len(overrides or ())
+        ex = self.executor
+        chunked = getattr(ex, "chunk_size", 1) > 1
+        pf, infl_pf, packed = (), None, 0
+        if pf_plan:
+            packed = sum(len(sl) for _, sl in pf_plan)
+            pf, infl_pf = self._take_slices(pf_plan, pf_budget, decoding)
+            entry = "mixed_chunk"
+            pipelined = getattr(ex, "mixed_chunk_start", None) is not None
+        else:
+            pipelined = (getattr(ex, "decode_chunk_start", None) is not None
+                         and (chunked or carry is not None))
+            entry = ("decode_chunk" if pipelined or (
+                chunked and hasattr(ex, "decode_chunk")) else "decode")
+        arrays = (tokens, positions, block_tables, temps, budgets)
+        lanes = {} if overrides is None else {"overrides": overrides}
+        if carry is not None:
+            lanes["carry"] = carry.handle
+        handle = out = None
+        t_call = time.perf_counter()
+        with self._dispatch_span(
+                entry, steps=int(budgets.max()),
+                rows=int(np.count_nonzero(budgets)),
+                row_steps=int(budgets.sum()), context_tokens=ctx,
+                prefill_tokens=packed, state_rows=len(pf),
+                slice_lens=[len(sl[1]) for sl in pf]):
+            if pipelined and pf_plan:
+                handle = ex.mixed_chunk_start(*arrays, pf, **lanes)
+            elif pipelined:
+                handle = ex.decode_chunk_start(*arrays, **lanes)
+            elif pf_plan:           # the serial arm: one blocking call
+                out = ex.mixed_chunk(*arrays, pf)
+            elif entry == "decode":
+                out = ex.decode(*arrays[:4])[:, None]
+            else:
+                out = ex.decode_chunk(*arrays)
+        now = time.perf_counter()   # handed to the device queue, or done
+        if pf_plan:
+            self._mixed_dispatched(handle, infl_pf, packed, now - t_call,
+                                   decoding > 0)
+        else:
+            _prefetch(getattr(handle, "out", None))
+        infl = _InflightChunk(
+            handle, seqs, budgets, pf=infl_pf,
+            dispatch_s=(now if pipelined else t_call) - t_asm,
+            dispatched_at=now, chunk=self._dispatch_serial)
+        self.steps += 1
+        if self._metrics:
+            self._m("decode_steps").inc()
+        if pipelined:   # its tokens are fetched on a LATER step
             self._inflight.append(infl)
             self._note_dispatch_depth(len(self._inflight))
             self._start_fetch(infl)
-            self.steps += 1
-            if self._metrics:
-                self._m("decode_steps").inc()
-            return True
-        # Sync executor (echo): one blocking call, commit inline.
-        with self._chunk_dispatch("mixed_chunk", budgets, ctx,
-                                  prefill_tokens=packed, pf=pf):
-            out, pf_first = self.executor.mixed_chunk(
-                tokens, positions, block_tables, temps, budgets, pf)
+            return infl
         t_done = time.perf_counter()
-        out = np.asarray(out)        # readback fence (no-op for echo)
-        t_rb = time.perf_counter()
-        self._note_prefill_dispatch(
-            packed, t_done - t0,
-            decode_active=bool(active), fused=True)
-        self._take_due_tails()
-        self.steps += 1
-        self.mixed_steps += 1
-        self.mixed_prefill_tokens_total += packed
-        if self._metrics:
-            self._m("decode_steps").inc()
-        if self._usage.enabled or self._cp.enabled:
-            decode_parts = [(seq, max(1, int(budgets[seq.slot])), False)
-                            for seq in active if seq.slot is not None]
-            parts = decode_parts + [(seq, n_tok, seq.todo_rebuild)
-                                    for seq, n_tok, _final in infl_pf]
-            if self._usage.enabled:
-                self._charge_step(t_done - t0, parts)
-            if self._cp.enabled:
-                self._cp_decode_share(
-                    (t_done - t0) + (t_rb - t_done), parts,
-                    [(seq, w) for seq, w, _ in decode_parts])
-        tok0 = self.tokens_generated_total
-        for seq in active:
-            if seq.slot is not None:
-                self._commit_row(seq, out[seq.slot],
-                                 int(budgets[seq.slot]))
-                self._flush_emits(seq)
-        self._finish_mixed_prefills(infl_pf, pf_first)
-        self._telemetry.note_step(t0 - t_asm, t_done - t0, t_rb - t_done,
-                                  self.tokens_generated_total - tok0)
-        self._set_gauges()
-        return True
+        out = ((np.asarray(out[0]), out[1]) if pf_plan
+               else np.asarray(out))     # readback fence (no-op for echo)
+        self._commit_chunk(infl, out, now - t_call,
+                           time.perf_counter() - t_done, 0.0)
+        return infl
 
     def _pack_slices(self, cands) -> "tuple[list, int]":
         """``([(seq, token_ids)], budget)``: the mid-prefill sequences
         ``cands``, most urgent first, packed into the compiled mixed
         program's slice grid under the token budget — the one packing
-        of a host-assembled (``_mixed_once``) and of a carried
+        of a host-assembled (``_plan_mixed``) and of a carried
         (``_dispatch_carried``) mixed chunk."""
         S = int(getattr(self.executor, "mixed_prefill_slices", 0))
         T = int(getattr(self.executor, "mixed_slice_tokens", 0))
@@ -3976,9 +3875,10 @@ class InferenceEngine:
     def _mixed_dispatched(self, handle, infl_pf, packed: int,
                           host_seconds: float,
                           decode_active: bool) -> None:
-        """Accounting of one mixed chunk handed to an async executor:
-        the stall estimate, the transfers queued behind the program,
-        the slices' in-flight latch, the counters."""
+        """Accounting of one mixed chunk handed to the executor
+        (``handle`` None: it ran in the call): the stall estimate, the
+        transfers queued behind the program, the slices' in-flight
+        latch (``_finish_mixed_prefills`` clears it), the counters."""
         self._note_prefill_dispatch(packed, host_seconds,
                                     decode_active=decode_active,
                                     fused=True)

@@ -679,6 +679,7 @@ class TestFullBatchRule:
         assert eng.fill_refusals["row_ended"] == 1
         eng.step()
         eng.step()
+        eng._drain_completions()   # ``done`` is the completion thread's
         assert stuck.slot is None and stuck.handle.done
         assert stuck.handle.result.finish_reason == "length"
         eng.run_until_idle()
@@ -859,51 +860,66 @@ class TestOverlapTelemetry:
                 in exp)
         eng.stop()
 
-    def test_timed_fetch_overlap_attribution(self):
+    def test_timed_fetch_overlap_attribution(self, monkeypatch):
         """Unit pin for the serial-attribution math: two chunks whose
         spans overlap split into novel device time + overlapped time;
-        without dispatched_at the old serial split is exact."""
-        import time as _t
-
+        without dispatched_at the old serial split is exact. On a clock
+        the test advances — a wait IS its length, whatever the worker's
+        load — so the attribution's identities and order are exact:
+        overlapped + novel = span, B hidden behind A, C with nothing to
+        overlap reads 0.0."""
+        from llmq_tpu.observability import device
         from llmq_tpu.observability.device import DeviceTelemetry
 
+        class Clock:
+            now = 100.0
+
+            def perf_counter(self):
+                return self.now
+
+            time = perf_counter
+
+        clock = Clock()
+        monkeypatch.setattr(device, "time", clock)
         tel = DeviceTelemetry("tf-unit", metrics=False)
-
-        class H:
-            def __init__(self, delay):
-                self.delay = delay
-
-            def fetch(self):
-                return np.zeros(1)
 
         class Out:
             def __init__(self, delay):
                 self.delay = delay
 
             def block_until_ready(self):
-                _t.sleep(self.delay)
+                clock.now += self.delay
+
+        class H:
+            def __init__(self, delay):
+                self.out = Out(delay)
+
+            def fetch(self):
+                clock.now += 0.002          # the readback
+                return np.zeros(1)
+
+        def near(x):
+            return pytest.approx(x, abs=1e-9)
 
         # Chunk A: dispatched now, 20ms compute.
-        h = H(0.0)
-        h.out = Out(0.02)
-        t_dispatch = _t.perf_counter()
-        _, dev_a, _, ov_a = tel.timed_fetch(h, dispatched_at=t_dispatch)
-        assert dev_a == pytest.approx(0.02, abs=0.01)
-        assert ov_a < 0.005
-        # Chunk B: dispatched BEFORE chunk A finished (span overlaps
-        # the attributed window) — the overlap is attributed, not
-        # double-counted as device time.
-        h2 = H(0.0)
-        h2.out = Out(0.001)
+        t_dispatch = clock.now
+        _, dev_a, rb_a, ov_a = tel.timed_fetch(H(0.02),
+                                               dispatched_at=t_dispatch)
+        assert (dev_a, rb_a, ov_a) == (near(0.02), near(0.002), 0.0)
+        # Chunk B: dispatched BEFORE chunk A finished (its span of 18 ms
+        # overlaps the attributed window) — the overlap is attributed,
+        # not double-counted as device time: only the 1 ms past A's
+        # window is novel.
+        t_b = clock.now
         _, dev_b, _, ov_b = tel.timed_fetch(
-            h2, dispatched_at=t_dispatch + 0.005)
-        assert ov_b > 0.005            # hidden behind chunk A's window
-        assert dev_b <= 0.01
+            H(0.001), dispatched_at=t_dispatch + 0.005)
+        span_b = t_b + 0.001 - (t_dispatch + 0.005)
+        assert dev_b == near(0.001) and ov_b == near(0.017)
+        assert dev_b + ov_b == near(span_b)
+        assert ov_b > dev_b            # hidden behind chunk A's window
         # No dispatched_at → exact old behavior: wait is device time.
-        h3 = H(0.0)
-        h3.out = Out(0.003)
-        _, dev_c, _, ov_c = tel.timed_fetch(h3)
-        assert dev_c == pytest.approx(0.003, abs=0.003)
+        _, dev_c, _, ov_c = tel.timed_fetch(H(0.003))
+        assert dev_c == near(0.003)
         assert ov_c == 0.0
 
 

@@ -2,10 +2,11 @@
 
 The open-loop chat cell meets the engine with arrivals while a row is
 free, bursts over a full batch, rows that end inside a chunk, long
-prompts sliced under decode rows, cancellations and a trickle — each
-with carried chunks in flight. A seeded plan of submissions and
-cancellations, keyed by the STEP they land before (no wall clock), is
-served twice: by the engine under test (``async_pipeline`` depth 2,
+prompts sliced under decode rows, cancellations, a trickle and arrivals
+INSIDE a step, while the loop waits on a fetch — each with carried
+chunks in flight. A seeded plan of submissions and cancellations, keyed
+by the STEP they land before or inside (no wall clock), is served
+twice: by the engine under test (``async_pipeline`` depth 2,
 mixed batching on or off, prefix cache on or off) and by a serial one
 (``async_pipeline.enabled=false``, ``mixed_batch.enabled=false``).
 Every request's token stream must be equal, none lost, none delivered
@@ -47,17 +48,20 @@ LOW, NORMAL, HIGH, REALTIME = (Priority.LOW, Priority.NORMAL,
 
 
 class Plan:
-    """Submissions and cancellations by the step they land before."""
+    """Submissions and cancellations by the step they land before
+    (``events``) or INSIDE (``inside``: while that step waits on a
+    transfer, if it does, and right after it otherwise)."""
 
     def __init__(self, seed: int, shared: bool) -> None:
         self.rng = random.Random(seed)
         self.shared = shared
         self.events = {}        # step -> [("submit", GenRequest) | ("cancel", id)]
+        self.inside = {}        # the same, for the step's first wait
         self.ids = []
         self.cancelled = set()
 
     def submit(self, step: int, rid: str, tokens: int, new: int,
-               prio: Priority = NORMAL) -> None:
+               prio: Priority = NORMAL, inside: bool = False) -> None:
         """A prompt of exactly ``tokens`` bytes (the byte tokenizer: one
         token each) that starts with its id, behind ``SHARED`` where the
         case runs the prefix cache: what is left to prefill, and what
@@ -66,37 +70,58 @@ class Plan:
                                     for _ in range(tokens))
         prompt = (SHARED if self.shared else "") + body[:tokens]
         self.ids.append(rid)
-        self.events.setdefault(step, []).append(("submit", GenRequest(
+        at = self.inside if inside else self.events
+        at.setdefault(step, []).append(("submit", GenRequest(
             id=rid, prompt=prompt, priority=prio, max_new_tokens=new)))
 
-    def cancel(self, step: int, rid: str) -> None:
+    def cancel(self, step: int, rid: str, inside: bool = False) -> None:
         self.cancelled.add(rid)
-        self.events.setdefault(step, []).append(("cancel", rid))
+        at = self.inside if inside else self.events
+        at.setdefault(step, []).append(("cancel", rid))
 
     @property
     def steps(self) -> int:
-        return max(self.events) + 1
+        return max(list(self.events) + list(self.inside)) + 1
+
+
+class _HeldOnce:
+    """A transfer's event that reads as not yet set the first time it
+    is asked: ``_service_while`` then runs its body once — as it does
+    when the transfer really is slower than an arrival — before it
+    waits on the transfer itself."""
+
+    def __init__(self, ev) -> None:
+        self.ev, self.held = ev, True
+
+    def wait(self, timeout=None) -> bool:
+        if self.held:
+            self.held = False
+            return False
+        return self.ev.wait(timeout)
 
 
 def serve(eng, plan: Plan):
     """Drive ``plan`` step by step; returns ``(handles, streamed,
     seen)``: ``streamed[id]`` the tokens delivered through ``on_token``,
     ``seen[k]`` the engine as step ``k``'s events met it — free rows,
-    rows decoding, chunks in flight, requests done."""
+    rows decoding, chunks in flight, requests done — and, where the
+    plan has events INSIDE step ``k``, ``seated_inside``: the requests
+    seated by the wait they landed in (None where nothing landed in a
+    wait: the plan had nothing for it, or the step waited on nothing,
+    as a serial engine's never does, and they landed after it).
+
+    Events inside a step land in its first ``_service_while``: the
+    engine's is wrapped HERE, for this plan, to submit them and then
+    delegate with the transfer's event held once, so that the wait
+    services them whatever the transfer's speed."""
     if plan.shared:
         eng.submit(GenRequest(id="seed", prompt=SHARED + "seed",
                               max_new_tokens=2))
         eng.run_until_idle()
     handles, streamed, seen = {}, {}, []
-    for k in range(plan.steps):
-        eng._drain_completions()
-        seen.append({
-            "free": sum(s is None for s in eng._slots),
-            "decoding": sum(s is not None and s.prefilled
-                            for s in eng._slots),
-            "inflight": len(eng._inflight),
-            "done": {r for r, h in handles.items() if h.done}})
-        for ev in plan.events.get(k, ()):
+
+    def land(events) -> None:
+        for ev in events:
             if ev[0] == "submit":
                 req = ev[1]
                 streamed[req.id] = []
@@ -104,13 +129,45 @@ def serve(eng, plan: Plan):
                     req, on_token=streamed[req.id].append)
             else:
                 handles[ev[1]].cancel()
-        eng.step()
+
+    due = []                    # this step's inside events, until landed
+    service = eng._service_while
+
+    def service_landing(ev, wait):
+        if not due:
+            return service(ev, wait)
+        events = due[:]
+        due.clear()
+        land(events)
+        service(_HeldOnce(ev), wait)
+        seen[-1]["seated_inside"] = {
+            s.req.id for s in eng._slots if s is not None} & {
+            e[1].id for e in events if e[0] == "submit"}
+
+    eng._service_while = service_landing
+    try:
+        for k in range(plan.steps):
+            eng._drain_completions()
+            seen.append({
+                "free": sum(s is None for s in eng._slots),
+                "decoding": sum(s is not None and s.prefilled
+                                for s in eng._slots),
+                "inflight": len(eng._inflight),
+                "done": {r for r, h in handles.items() if h.done},
+                "seated_inside": None})
+            land(plan.events.get(k, ()))
+            due[:] = plan.inside.get(k, ())
+            eng.step()
+            land(due)           # the step waited on nothing
+            due.clear()
+    finally:
+        del eng._service_while  # the instance's: the class's is back
     eng.run_until_idle()
     eng._drain_completions()
     return handles, streamed, seen
 
 
-# -- the six shapes ------------------------------------------------------------
+# -- the seven shapes ----------------------------------------------------------
 #
 # Each builds its plan and returns ``(plan, check)``; ``check(seen, eng,
 # d0, mixed)`` holds the engine under test to the shape's own condition
@@ -214,9 +271,39 @@ def trickle(seed, shared):
     return p, check
 
 
+def arrival_inside_a_step(seed, shared):
+    """Arrivals that land while a step waits on its fetch: two into
+    free rows (one of them long: sliced), one onto a full batch, and a
+    cancellation — ``_service_while`` ingests, seats and starts the
+    prefill with chunks in flight."""
+    p = Plan(seed, shared)
+    p.submit(0, "a0", 48, 48)
+    p.submit(0, "a1", 44, 46)
+    p.submit(8, "in0", 27, 20, inside=True)
+    p.submit(8, "in1", 5 * SLICE + 3, 14, HIGH, inside=True)
+    p.submit(9, "in2", 30, 16, REALTIME, inside=True)
+    p.cancel(10, "a1", inside=True)
+    p.submit(14, "in3", 26, 12, LOW, inside=True)
+
+    def check(seen, eng, d0, mixed):
+        assert seen[8]["decoding"] == 2 and seen[8]["free"] == 2
+        assert seen[8]["inflight"] == 1
+        # both were seated by the wait they landed in, mid-step
+        assert seen[8]["seated_inside"] == {"in0", "in1"}
+        assert seen[9]["free"] == 0
+        # the third landed in a wait too, onto a full batch: not seated
+        assert seen[9]["inflight"] >= 1
+        assert seen[9]["seated_inside"] == set()
+        assert seen[10]["seated_inside"] == set()      # the cancel's wait
+        assert seen[14]["seated_inside"] is not None
+        assert carried(eng, d0)
+    return p, check
+
+
 SHAPES = {f.__name__: f for f in (
     burst_into_free_rows, burst_over_full_batch, finish_then_join,
-    long_prompt_sliced, cancel_mid_burst, trickle)}
+    long_prompt_sliced, cancel_mid_burst, trickle,
+    arrival_inside_a_step)}
 
 
 def compare(plan, got, ref, eng, refusals0):
@@ -281,7 +368,7 @@ def test_echo_streams_equal_the_serial_engine(shape, mixed, prefix):
 @pytest.fixture(scope="module")
 def jax_pair(tiny_model_f32):  # noqa: F811
     """The engine under test and the serial one on the tiny float32
-    model, compiled once for the six shapes: an engine is idle again
+    model, compiled once for the seven shapes: an engine is idle again
     after a plan (``compare`` holds it to that), so the next one finds
     it as a new one would."""
     eng = make_jax_engine(tiny_model_f32, pipe_cfg(depth=2), slots=SLOTS,

@@ -471,7 +471,12 @@ class TestTieringDegraded:
 
         fk = FakeClock()
         inner = ScriptedStore()
-        store = wrap_store(inner, _rcfg(retries=0), clock=fk)
+        # The breaker is what gates here, on the fake clock: the wall
+        # deadline of an op is one no stalled worker misses (a miss is
+        # a timeout, not the scripted fault, and holds ``degraded`` on
+        # a rung the fake clock does not end).
+        store = wrap_store(inner, _rcfg(retries=0, op_timeout_s=5.0),
+                           clock=fk)
         plane = KVTieringPlane(KVTieringConfig(enabled=True), "p", _Exec())
         plane.store = store
         assert "tiering" in store.resilience_stats()["consumers"]
@@ -994,10 +999,16 @@ class TestStoreBlackoutAcceptance:
                 out[c] = [h.result.tokens]
             fclock.advance(6.0)
             eng.step()
-            assert wait_until(
-                lambda: sum(plane.counts().values()) == 2)
-            # j0 demoted first → spilled to the store tier when j1's
-            # demotion claimed the single host slot.
+
+            def spilled():
+                """Both demoted, and the first spilled to the store
+                tier when the second claimed the single host slot (on
+                the plane's worker: the tier, not the entries alone,
+                is what the asserts below rest on)."""
+                return plane.counts() == {"host": 1, "store": 1,
+                                          "recompute": 0}
+
+            assert wait_until(spilled, timeout=30.0)
             assert store.totals["ops"] > 0
 
             _arm(72, {"point": "store.*", "kind": "error", "times": 200})
@@ -1012,17 +1023,26 @@ class TestStoreBlackoutAcceptance:
                 assert (out[c][0], out[c][1]) == base[c], c
 
             chaos.configure(None)
-            assert wait_until(lambda: not store.degraded, timeout=5.0)
-            store.load("j0")        # probe success fires the recovery
+
+            def recovered():
+                """A probe that succeeds fires the recovery; one shed
+                (the breaker's window, or the timeout rung a stalled
+                worker set) or late is asked again."""
+                try:
+                    store.load("j0")
+                except Exception:  # noqa: BLE001 — shed / deadline
+                    return False
+                return not store.degraded
+
+            assert wait_until(recovered, timeout=30.0, step=0.02)
             assert wait_until(lambda: sm.replay_pending() == 0,
-                              timeout=5.0)
+                              timeout=30.0)
 
             # Store tier resumes: demote again against the healthy
             # store, and the next promote comes back as a STORE hit.
             fclock.advance(6.0)
             eng.step()
-            assert wait_until(
-                lambda: sum(plane.counts().values()) == 2)
+            assert wait_until(spilled, timeout=30.0)
             for c, (p1, _) in prompts.items():
                 _turn(eng, sm, checker, f"{c}.t3", c, p1,
                       budget_s=60.0)
