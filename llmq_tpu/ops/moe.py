@@ -21,7 +21,12 @@ trace, two calls a routed layer) and ``jax.lax.ragged_dot``
 elsewhere, by the policy every kernel of this repo follows
 (``ops/attention._kernel_route``: ``LLMQ_PALLAS``). Both were measured on the chip at this model's
 widths (PERF.md §6, PR 31): both read only the experts a batch
-touches, and the kernel is the faster by a quarter at 64 rows.
+touches, and the kernel is the faster by a quarter at 64 rows. The
+kernel's tiles are cut to the matrices it multiplies: ``gmm_tiling``, a
+function of the call's shapes and the VMEM budget alone, whose
+docstring states the rule (PERF.md §6, PR 57: a block that overhangs
+its matrix is multiplied in full, and a contraction tile that does not
+divide it is masked at every visit).
 
 **A chip's share of the experts** (``routed_ffn(..., held=(lo, hi))``):
 the router still scores every expert and a token still chooses its
@@ -49,13 +54,17 @@ slots that went to zero-compute experts and to experts held elsewhere
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from llmq_tpu.utils.logging import get_logger
 from llmq_tpu.utils.profiling import scope
+
+log = get_logger("ops.moe")
 
 
 def _limit(sel: jnp.ndarray, n_group: int, topk_group: int) -> jnp.ndarray:
@@ -123,9 +132,77 @@ def route(x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray, *,
                       topk_group=topk_group)
 
 
-#: megablox tiling (rows, contraction, output): the best of those
-#: tried at 2,048 x 1,536 and 768 x 2,048 for 384 and 6,528 rows.
-GMM_TILING = (128, 768, 2048)
+#: Scoped VMEM a Mosaic kernel is compiled with on a v5e where its call
+#: names no limit, as megablox's does not.
+VMEM_SCOPED = 16 * 2 ** 20
+#: What the rule lets the kernel's own buffers take of it; the rest is
+#: the compiler's (its scoped use is ``gmm_tile_bytes`` give or take
+#: 1 MB at the served widths: ``tests/test_tpu_compile.py``).
+VMEM_BUDGET = 12 * 2 ** 20
+
+
+def gmm_tile_bytes(tm: int, tk: int, tn: int, itemsize: int = 2) -> int:
+    """VMEM the grouped product holds at these tiles: the weight block,
+    the rows and the output, each twice (the pipeline fetches the next
+    while this one is multiplied), and the float32 accumulator."""
+    return 2 * itemsize * (tk * tn + tm * tk + tm * tn) + 4 * tm * tn
+
+
+def _tiles_of(d: int) -> Tuple[int, ...]:
+    """The tiles a dimension may be cut in: the multiples of 128 lanes
+    that divide it; the whole of a dimension that is no such multiple
+    (a tiny model's: a block as wide as its array is legal)."""
+    return tuple(t for t in range(128, d + 1, 128) if d % t == 0) or (d,)
+
+
+@functools.lru_cache(maxsize=None)
+def gmm_tiling(m: int, K: int, N: int, itemsize: int = 2
+               ) -> Tuple[int, int, int]:
+    """The grouped product's tiles (rows, contraction, output) for ``m``
+    sorted rows times ``(E, K, N)`` matrices of ``itemsize`` bytes an
+    element: megablox's ``tiling=``, asked at trace time and logged
+    once a distinct shape.
+
+    The kernel's grid is (output tiles, visits, contraction tiles), a
+    visit being one overlap of a row tile with an expert's rows, and
+    every grid step multiplies a FULL ``(tm, tk) x (tk, tn)`` block
+    whatever part of it lies inside the matrix; where ``tk`` does not
+    divide ``K`` the last contraction step also masks both blocks in
+    float32 on the vector units. So:
+
+    * ``tk`` and ``tn`` DIVIDE ``K`` and ``N`` (``_tiles_of``): no block
+      overhangs its matrix and no step is masked — the work is the
+      matrix's, and a visit stays bound by the expert's bytes;
+    * ONE contraction step (``tk`` = ``K``) wherever an output tile of
+      it fits ``VMEM_BUDGET`` (``gmm_tile_bytes``): the weight block's
+      index is then (expert, 0, output tile), which the pipeline does
+      not fetch again when the next row tile belongs to the same
+      expert, the rows' block stays while one row tile is visited, and
+      no partial sum goes through the accumulator twice. Measured at
+      every served shape it is as fast as any other exact tiling at a
+      decode step's rows and 9-45 % faster where an expert has more
+      rows than a tile, down to an output tile of 256;
+    * then the largest weight block that fits (the fewest grid steps a
+      visit), the wider output tile among equals;
+    * ``tm`` is 128: a visit then does 128 flop a weight byte against
+      the chip's 240 (197 Tflop/s / 819 GB/s) and stays bound by the
+      bytes; at 256 rows it is bound by the MXU before any padding,
+      and a decode step's few rows an expert save no visit for it
+      (measured: slower at every row count but a full grid, equal
+      there).
+
+    ``m`` does not enter the choice (the measurements: PERF.md section
+    6, PR 57); it is megablox's signature and the log's key."""
+    tm = 128
+    fits = [(tk, tn) for tk in _tiles_of(K) for tn in _tiles_of(N)
+            if gmm_tile_bytes(tm, tk, tn, itemsize) <= VMEM_BUDGET]
+    if not fits:
+        raise ValueError(f"no tiles of {K} x {N} fit {VMEM_BUDGET} bytes")
+    tk, tn = max(fits, key=lambda t: (t[0] == K, t[0] * t[1], t[1]))
+    log.info("gmm tiling m=%d K=%d N=%d: tiles (%d, %d, %d), %d k-steps, "
+             "%d n-steps, padded/true work %.3f", m, K, N, tm, tk, tn,
+             K // tk, N // tn, (m + -m % tm) / m)
+    return tm, tk, tn
 
 
 def moe_grouped_matmul_pallas(xs: jnp.ndarray, w: jnp.ndarray,
@@ -136,9 +213,9 @@ def moe_grouped_matmul_pallas(xs: jnp.ndarray, w: jnp.ndarray,
     come out undefined."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     m = xs.shape[0]
-    pad = -m % GMM_TILING[0]
-    out = gmm(jnp.pad(xs, ((0, pad), (0, 0))), w, counts,
-              preferred_element_type=xs.dtype, tiling=GMM_TILING,
+    tiles = gmm_tiling(m, *w.shape[1:], w.dtype.itemsize)
+    out = gmm(jnp.pad(xs, ((0, -m % tiles[0]), (0, 0))), w, counts,
+              preferred_element_type=xs.dtype, tiling=tiles,
               interpret=interpret)
     return out[:m]
 

@@ -7,11 +7,17 @@ family ``deepseek_v3``, the latent decode kernel and the latent write
 ``granitemoehybrid``, the Mamba-2 decode state update in place
 (``ops/pallas/ssm_update.py``) beside XLA's fusion of the same
 (``--lens ROWS``: that many of the batch's rows decode); or, for the
-family ``mellum``, one layer's routed experts over a mixed step's rows
+family ``mellum`` and with ``--routed`` for any family with routed
+experts, one layer's routed experts over a mixed step's rows
 (``--lens ROWS``: that many of the batch + token budget rows hold a
 token, lying first): ``ops/moe.routed_ffn``'s plain form beside the
 form that is told the count (``n_live``), at each ``--block`` of sorted
-pairs.
+pairs, with the tiles ``ops/moe.gmm_tiling`` chose for the two grouped
+products; ``--tiling TM,TK,TN`` (repeatable) times the two products
+ALONE (the megablox kernel over the sorted pairs of ROWS tokens, the
+gate-up and the down product apart) at those tiles beside the rule's:
+us a call, the (row tile, expert) visits, us a visit and the share of
+the visited experts' bytes.
 
 Heads, page size, block table width, batch, pool and the int8 kernel
 come from a served configuration (``--model-file
@@ -962,6 +968,113 @@ def bench_kda_scan(args, doc) -> None:
     _save(args, doc, results)
 
 
+def routed_widths(doc):
+    """(hidden, expert width, experts held, experts a token, experts the
+    router scores) of a configuration with routed experts, under the
+    names its family's file gives them."""
+    def first(*names):
+        for name in names:
+            if doc.get(name):
+                return doc[name]
+        sys.exit(f"{doc.get('name')}: no routed experts (none of {names})")
+    E = first("n_routed_experts", "num_experts", "num_local_experts")
+    return (doc["hidden_size"],
+            first("moe_intermediate_size", "expert_ffn_hidden_size"), E,
+            first("num_experts_per_tok", "moe_topk"),
+            doc.get("router_experts", E) + doc.get("zero_expert_num", 0))
+
+
+def _rule(moe, m, K, N):
+    """The tiles the tree's ``ops/moe.py`` gives the product: its rule's,
+    or a parent's constant."""
+    rule = getattr(moe, "gmm_tiling", None)
+    return rule(m, K, N) if rule else moe.GMM_TILING
+
+
+def bench_tiles(args, doc) -> None:
+    """The two grouped products of one routed layer ALONE at the served
+    widths, over the sorted pairs that ``--lens ROWS`` tokens send to
+    the held experts (each token the top ``k`` of random scores over
+    the router's experts): the megablox kernel at each ``--tiling`` and
+    at the rule's tiles (``ops/moe.gmm_tiling``; a parent's constant).
+    Four layers' matrices, each a leaf of its own, are multiplied in
+    turn so that every call reads its matrices from memory."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from llmq_tpu.ops import moe
+
+    D, F, E, k, R = routed_widths(doc)
+    L, n, interpret = 4, 10, False
+    if args.rehearse:
+        D, F, E, R, L, n, interpret = 256, 128, min(E, 8), min(R, 8), 2, 1, True
+    elif jax.default_backend() != "tpu":
+        sys.exit("no TPU here: a time from this host is no device "
+                 "number (--rehearse runs the path in interpret mode)")
+    ks = jax.random.split(jax.random.key(0), 2 + 2 * L)
+    w = {"gate-up": [jax.random.normal(ks[2 + i], (E, D, 2 * F), jnp.bfloat16)
+                     / D ** 0.5 for i in range(L)],
+         "down": [jax.random.normal(ks[2 + L + i], (E, F, D), jnp.bfloat16)
+                  / F ** 0.5 for i in range(L)]}
+    print(f"{doc['name']}: grouped products alone D={D} F={F} held={E} of "
+          f"{R} k={k} layers={L} device={jax.devices()[0].device_kind}"
+          f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
+          flush=True)
+    given = [tuple(int(v) for v in t.split(",")) for t in args.tiling]
+    rng = np.random.default_rng(0)
+    results = []
+    for spec in args.lens:
+        rows = int(spec)
+        chosen = np.argsort(rng.random((rows, R)), axis=1)[:, :k]
+        counts = np.bincount(chosen[chosen < E], minlength=E).astype(np.int32)
+        pairs = int(counts.sum())
+        ends = np.cumsum(counts)
+        groups = [(e - c, e) for c, e in zip(counts, ends) if c]
+        for name, (K, N) in (("gate-up", (D, 2 * F)), ("down", (F, D))):
+            xs = jax.random.normal(ks[0], (pairs, K), jnp.bfloat16)
+            seen = []
+            for tiles in [_rule(moe, pairs, K, N)] + given:
+                tm = tiles[0]
+                if tiles in seen or (args.rehearse and (
+                        tiles[1] > K or tiles[2] > N)):
+                    continue
+                seen.append(tiles)
+                lhs = jnp.pad(xs, ((0, -pairs % tm), (0, 0)))
+
+                @jax.jit
+                def run(lhs, ws, counts, tiles=tiles):
+                    return sum(gmm(lhs, a, counts, tiling=tiles,
+                                   preferred_element_type=lhs.dtype,
+                                   interpret=interpret) for a in ws)
+
+                visits = int(sum(-(-e // tm) - s // tm for s, e in groups))
+                try:
+                    jax.block_until_ready(run(lhs, w[name], counts))
+                except Exception as e:  # a tile the compiler refuses
+                    print(f"  {rows:5d} rows {name:7s} {tiles}: refused: "
+                          f"{str(e).splitlines()[0][:200]}", flush=True)
+                    continue
+                t0 = time.perf_counter()
+                for _ in range(n):
+                    y = run(lhs, w[name], counts)
+                jax.block_until_ready(y)
+                us = (time.perf_counter() - t0) / (n * L) * 1e6
+                least = len(groups) * K * N * 2 / PEAK_BYTES_PER_S
+                rec = {"rows": rows, "pairs": pairs, "product": name,
+                       "K": K, "N": N, "tiling": list(tiles), "us": us,
+                       "visits": visits, "us_per_visit": us / max(visits, 1),
+                       "bytes_share": least * 1e6 / us}
+                results.append(rec)
+                print(f"  {rows:5d} rows {pairs:6d} pairs {name:7s} "
+                      f"{K}x{N} {str(tiles):18s}: {us:9,.1f} us/call, "
+                      f"{visits:4d} visits, {rec['us_per_visit']:6.2f} "
+                      f"us/visit, {rec['bytes_share']:6.1%} of the visited "
+                      f"experts' bytes", flush=True)
+    _save(args, doc, results)
+
+
 #: family dispatches are what this tool is about, so a new family's
 #: bench is a function here and an entry in this table.
 def bench_routed(args, doc) -> None:
@@ -972,8 +1085,11 @@ def bench_routed(args, doc) -> None:
     form that is told the count (``n_live``: a block of sorted pairs at
     a time while live pairs are left), the latter at each ``--block``
     (``moe.LIVE_BLOCK`` where none is given), and how far the two
-    results lie apart. Four layers' experts, each a leaf of its own,
-    are multiplied in turn, so that every call reads its matrices from
+    results lie apart. A file that holds a SHARE of the experts its
+    router scores (``router_experts``) runs the share form (``held``,
+    ``n_routed``: blocks of ``moe.HELD_BLOCK`` held pairs), which no
+    count is told. Four layers' experts, each a leaf of its own, are
+    multiplied in turn, so that every call reads its matrices from
     memory."""
     import jax
     import jax.numpy as jnp
@@ -983,8 +1099,10 @@ def bench_routed(args, doc) -> None:
 
     ex = doc["server"]["executor"]
     N = ex["max_batch_size"] + ex["mixed_batch"]["prefill_token_budget"]
-    D, F = doc["hidden_size"], doc["moe_intermediate_size"]
-    E, k, L = doc["num_experts"], doc["num_experts_per_tok"], 4
+    D, F, E, k, R = routed_widths(doc)
+    share = ({"held": (0, E), "n_routed": doc.get("router_experts", E)}
+             if R != E else {})
+    L = 4
     if args.rehearse:
         os.environ["LLMQ_PALLAS"] = "interpret"
         N, D, F, L = 160, 256, 128, 2
@@ -994,8 +1112,8 @@ def bench_routed(args, doc) -> None:
     ks = jax.random.split(jax.random.key(0), 3 + 2 * L)
     x = jax.random.normal(ks[0], (N, D), jnp.bfloat16)
     experts, gates = moe.route(
-        x, jax.random.normal(ks[1], (D, E), jnp.float32) / D ** 0.5,
-        jnp.zeros((E,), jnp.float32), top_k=k, scale=1.0, scoring="softmax")
+        x, jax.random.normal(ks[1], (D, R), jnp.float32) / D ** 0.5,
+        jnp.zeros((R,), jnp.float32), top_k=k, scale=1.0, scoring="softmax")
     w_in = [jax.random.normal(ks[3 + i], (E, D, 2 * F), jnp.bfloat16)
             / D ** 0.5 for i in range(L)]
     w_out = [jax.random.normal(ks[3 + L + i], (E, F, D), jnp.bfloat16)
@@ -1009,14 +1127,18 @@ def bench_routed(args, doc) -> None:
             for a, b in zip(w_in, w_out):
                 y = y + moe.routed_ffn(
                     x, experts, gates, a, b, live,
-                    **({"n_live": rows} if told else {}))[0]
+                    **({"n_live": rows} if told else share))[0]
             return y
         return run
 
-    print(f"{doc['name']}: routed experts N={N} D={D} F={F} E={E} k={k} "
-          f"layers={L} device={jax.devices()[0].device_kind}"
+    print(f"{doc['name']}: routed experts N={N} D={D} F={F} E={E} of {R} "
+          f"k={k} layers={L} device={jax.devices()[0].device_kind}"
           f"{' REHEARSAL: times mean nothing' if args.rehearse else ''}",
           flush=True)
+    for m in ([moe.HELD_BLOCK] if share else sorted(
+            {N * k, min(moe.LIVE_BLOCK, -(-N * k // 128) * 128)})):
+        print(f"  tiles at {m} pairs: gate-up {_rule(moe, m, D, 2 * F)}, "
+              f"down {_rule(moe, m, F, D)}", flush=True)
     n = 1 if args.rehearse else 10
 
     def timed(told, rows):
@@ -1035,7 +1157,7 @@ def bench_routed(args, doc) -> None:
         rec = {"rows": rows, "of": N, "pairs": rows * k}
         rec["plain_us"], plain = timed(False, rows)
         line = f"  {rows:5d} rows live of {N}: plain {rec['plain_us']:,.1f}"
-        for block in args.block or [moe.LIVE_BLOCK]:
+        for block in [] if share else args.block or [moe.LIVE_BLOCK]:
             moe.LIVE_BLOCK = block
             us, got = timed(True, rows)
             gap = float(np.abs(got - plain).max())
@@ -1085,7 +1207,14 @@ def main() -> None:
                     help="granitemoehybrid: time the update kernel at "
                          "this many lanes a step of its walk as well")
     ap.add_argument("--block", action="append", type=int, default=[],
-                    help="mellum: sorted pairs a block of the told form")
+                    help="routed: sorted pairs a block of the told form")
+    ap.add_argument("--routed", action="store_true",
+                    help="the routed experts' bench at the file's widths "
+                         "whatever its family")
+    ap.add_argument("--tiling", action="append", default=[],
+                    metavar="TM,TK,TN",
+                    help="routed: time the two grouped products alone at "
+                         "these tiles beside the rule's (repeatable)")
     ap.add_argument("--out", default="")
     ap.add_argument("--rehearse", action="store_true",
                     help="off the chip: interpret mode, 2 layers, 2 "
@@ -1096,6 +1225,9 @@ def main() -> None:
         doc = json.load(f)
     if args.fused:
         doc["family"] = "llama"
+    if args.routed or args.tiling:
+        routed_widths(doc)         # exits where the file has no experts
+        doc["family"] = "mellum"
     if doc.get("family") not in BENCHES:
         sys.exit(f"{args.model_file}: no kernel bench for the family "
                  f"{doc.get('family')!r}; known: {sorted(BENCHES)}")
@@ -1106,7 +1238,9 @@ def main() -> None:
         {"llama": bench_prefill, "ling_hybrid": bench_kda_scan,
          "solar_open2": bench_kda_scan}.get(
             doc["family"], bench_latent_prefill)(args, doc)
-    if args.lens:
+    if args.lens and args.tiling:
+        bench_tiles(args, doc)
+    elif args.lens:
         BENCHES[doc["family"]](args, doc)
     elif not args.prefill:
         ap.error("give --lens or --prefill")

@@ -293,27 +293,46 @@ def test_the_latent_write_holds_tiles_not_pages_for_v5e(one_chip, served):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
-@pytest.mark.parametrize("rows", [384, 6528],
-                         ids=["64-decode-rows", "1088-mixed-tokens"])
-def test_grouped_product_compiles_for_v5e(one_chip, rows):
-    """The routed layers' grouped product at this model's widths (128
+@pytest.mark.parametrize("E,D,F,rows", [
+    (128, 2048, 768, 384), (128, 2048, 768, 6528),
+    (64, 2304, 896, 256), (64, 2304, 896, 2048)],
+    ids=["64-decode-rows", "1088-mixed-tokens",
+         "mellum-32-decode-rows", "mellum-live-block"])
+def test_grouped_product_compiles_for_v5e(one_chip, E, D, F, rows):
+    """The routed layers' grouped product at Kanana's widths (128
     experts, 2,048 -> 2 x 768 and 768 -> 2,048) for a decode step's
-    pairs and a mixed step's: two Mosaic calls, and the 0.8 GB expert
-    leaf is not copied (temporaries stay a few megabytes)."""
-    from llmq_tpu.ops.moe import moe_grouped_matmul_pallas
+    pairs and a mixed step's, and at Mellum's (64 experts, 2,304 -> 2 x
+    896 -> 2,304) for a decode step's pairs and a block of live ones:
+    two Mosaic calls at the tiles ``moe.gmm_tiling`` cuts to the
+    matrices (a tile that does not fit VMEM fails HERE, without a
+    chip), each within 1 MiB above what the rule counted for its tiles
+    and inside the scoped VMEM, and the expert leaves are not copied
+    (temporaries stay a few megabytes)."""
+    import re
+
+    from llmq_tpu.ops import moe
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def ffn(xs, w_gu, w_d, counts):
-        gu = moe_grouped_matmul_pallas(xs, w_gu, counts)
-        return moe_grouped_matmul_pallas(gu[:, :768] * gu[:, 768:], w_d,
-                                         counts)
+        gu = moe.moe_grouped_matmul_pallas(xs, w_gu, counts)
+        return moe.moe_grouped_matmul_pallas(gu[:, :F] * gu[:, F:], w_d,
+                                             counts)
 
     compiled = jax.jit(ffn).lower(
-        arg((rows, 2048)), arg((128, 2048, 1536)), arg((128, 768, 2048)),
-        arg((128,), jnp.int32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 2
+        arg((rows, D)), arg((E, D, 2 * F)), arg((E, F, D)),
+        arg((E,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    scoped = [int(size) for line in text.splitlines()
+              if "tpu_custom_call" in line for size in re.findall(
+                  r'"used_scoped_memory_configs":\[\{[^]]*"size":"(\d+)"',
+                  line)]
+    assert len(scoped) == 2 and max(scoped) < moe.VMEM_SCOPED
+    counted = [moe.gmm_tile_bytes(*moe.gmm_tiling(rows, K, N))
+               for K, N in ((D, 2 * F), (F, D))]
+    assert all(s <= c + 2 ** 20 for s, c in zip(scoped, counted))
     assert compiled.memory_analysis().temp_size_in_bytes < 100e6
 
 
