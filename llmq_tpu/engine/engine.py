@@ -2920,6 +2920,10 @@ class InferenceEngine:
             "prefill_tokens": prefill_tokens,
             "slice_tokens": slice_tokens,
             "chunk": self._dispatch_serial}
+        hc_fn = getattr(self.executor, "hc_rows_live", None)
+        hc_rows = hc_fn(prefill_tokens) if chunk and hc_fn else None
+        if hc_rows is not None:      # rows the mixed step's sites run
+            counts["hc_rows_live"] = hc_rows
         if self._window and chunk and rows:
             counts.update(self._window_counts())
         if capture_held():
@@ -3468,6 +3472,8 @@ class InferenceEngine:
                       moe_zero_slots=c["zero_slots"],
                       moe_away_slots=c["away_slots"],
                       moe_load="n" + "_".join(map(str, c["load"].tolist())))
+            if "hc_row_sum_err" in c:
+                span.note(hc_row_sum_err=c["hc_row_sum_err"])
 
     def _note_key_blocks(self, key_blocks, span) -> None:
         """Fold a mixed chunk's prefill key blocks (``(visited, the
@@ -3478,10 +3484,13 @@ class InferenceEngine:
         block tables' windows the prefill attention ran over."""
         if key_blocks is None:
             return
-        self._key_blocks += np.asarray(key_blocks, np.int64)
+        self._key_blocks += np.asarray(key_blocks[:2], np.int64)
         if capture_held():
             span.note(pf_key_blocks=int(key_blocks[0]),
                       pf_table_blocks=int(key_blocks[1]))
+            if len(key_blocks) > 2:     # the slices' LIVE keys and pairs
+                span.note(pf_live_keys=int(key_blocks[2]),
+                          pf_live_pairs=int(key_blocks[3]))
 
     def _moe_counts(self, st: np.ndarray) -> Dict[str, Any]:
         """A family's step counters by name, by the layout the family
@@ -3493,6 +3502,8 @@ class InferenceEngine:
         out: Dict[str, Any] = {"load": st[first:end]}
         for name in ("touched", "runs", "zero_slots", "away_slots"):
             out[name] = int(st[layout[name]]) if name in layout else 0
+        if "hc_row_sum_err" in layout:     # a family of several streams
+            out["hc_row_sum_err"] = int(st[layout["hc_row_sum_err"]])
         return out
 
     def _commit_chunk(self, infl: _InflightChunk, out, device_s: float,
@@ -4369,6 +4380,11 @@ class InferenceEngine:
                 "zero_slots": c["zero_slots"],
                 "away_slots": c["away_slots"],
             }
+            if "hc_row_sum_err" in c:
+                # The sites' worst |row sum - 1| x 1e6 a pass, summed
+                # over the steps run (``layer_runs`` / the routed layers
+                # held of them): 0 would be exact.
+                out["moe"]["hc_row_sum_err_sum"] = c["hc_row_sum_err"]
         if self._key_blocks[1]:
             # Key blocks the mixed steps' prefill attention ran over and
             # those their slices' block tables held (one attention's,

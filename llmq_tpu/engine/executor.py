@@ -2073,6 +2073,17 @@ class JaxExecutor:
             return self._bucket_for(max(1, tokens))
         return 0
 
+    def hc_rows_live(self, tokens: int) -> Optional[int]:
+        """Rows the hyper-connection sites of a mixed chunk's MIXED STEP
+        run for ``tokens`` prompt tokens (the family's ``hc_rows_live``:
+        its decode rows and the live row tiles behind them); None for a
+        family whose residual is one stream."""
+        fn = getattr(self._family, "hc_rows_live", None)
+        if fn is None or not self.mixed_prefill_slices:
+            return None
+        return fn(tokens, self.spec.batch_size, self.mixed_prefill_slices,
+                  self.mixed_slice_tokens)
+
     def _prefill_chunk(self, chunk: List[int], start_pos: int, bt,
                        temperature: float, slot: Optional[int] = None):
         """Launch ONE bucketed prefill program (no host sync): pads the
@@ -2328,9 +2339,16 @@ class JaxExecutor:
             # the slices' contexts as the program reads them
             # (``ops/rows.grid_positions``; an empty slot is one trash
             # token at the first dead row's position 0: a context of 1)
-            key_blocks = self._mixed_key_blocks(
+            first = pf_poss[pf_starts[:len(pf)]].astype(np.int64)
+            n_new = pf_lens[:len(pf)].astype(np.int64)
+            # ... and, behind (visited, the table holds), what its LIVE
+            # work is: the keys its used slices' contexts hold and the
+            # (query, visible key) pairs of their tokens
+            key_blocks = tuple(self._mixed_key_blocks(
                 pf_poss[pf_starts[:S]] + pf_lens, T, self.spec.page_size,
-                self.spec.max_pages_per_seq)
+                self.spec.max_pages_per_seq)) + (
+                int((first + n_new).sum()),
+                int((n_new * first + n_new * (n_new + 1) // 2).sum()))
         return MixedChunkHandle(out, tok, pos, done, pf_first, stats,
                                 key_blocks)
 

@@ -92,6 +92,13 @@ A family is a module of this package that defines
   by the rule the program runs by (``get_stats()["mixed_key_blocks"]``);
   a family without it (a prefill kernel that follows the context by
   itself) counts nothing;
+- optionally ``hc_rows_live(tokens, batch, slices, width)``: for a family
+  whose residual is several streams mixed at every sub-layer
+  (``models/xing.py``, ``ops/hyper.py``), the rows its mixed STEP's
+  sites run — the decode rows and ``mixed_live_rows`` behind them
+  (``engine.dispatch``'s ``hc_rows_live``); such a family's
+  ``step_stats_layout`` may name ``"hc_row_sum_err"``, the worst ``|row
+  sum - 1|`` of a pass's mixing matrices x 1e6;
 - optionally ``IDLE_ROW_CONTEXT``: the ``seq_len`` its decode step
   hands the fused decode kernel for a row that is not active, where
   that is not ``positions + 1`` of an empty seat's position 0 (the
@@ -142,6 +149,7 @@ FAMILIES: Dict[str, str] = {
     "zaya": "llmq_tpu.models.zaya",
     "solar_open2": "llmq_tpu.models.solar_open2",
     "mellum": "llmq_tpu.models.mellum",
+    "xing": "llmq_tpu.models.xing",
 }
 
 

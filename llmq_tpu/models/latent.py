@@ -1,6 +1,7 @@
 """Multi-head LATENT attention over a latent page pool: what the
 families that have it share (``models/deepseek_v3.py``,
-``models/longcat_flash.py``). The equations and the pool's layout are
+``models/longcat_flash.py``, ``models/ling_hybrid.py``,
+``models/xing.py``). The equations and the pool's layout are
 in ``models/deepseek_v3.py``'s docstring; here they are written once.
 
 An attention is addressed by ONE index ``l``: its slice of the stacked
@@ -8,7 +9,7 @@ attention leaves ``lp`` AND its layer of the pool. A family with one
 attention a layer passes the layer; one with two a layer stacks both
 and its pool's leading axis is twice its layers.
 
-Two things a configuration may add to DeepSeek-V3's attention, both
+Three things a configuration may add to DeepSeek-V3's attention, all
 written here and nowhere else:
 
 - ``q_lora_rank``: a low-rank query, c_q = RMSNorm(x W_qa), q = c_q
@@ -18,7 +19,14 @@ written here and nowhere else:
   kv_lora_rank) (the RoPE key is not). s_kv never touches the cached
   row: it is folded into the query's latent part and into the output,
   in float32, so the pool holds the same ``[c | k^rope | 0]`` row
-  whatever the scales.
+  whatever the scales;
+- ``rope_scaling`` (``ops/rope.YarnScaling``, DeepSeek-V3's reading of
+  a ``yarn`` block): the rotary lanes turn by YaRN's frequencies
+  (``rope_table``) and a score's scale is no longer ``qk_head_dim **
+  -0.5`` alone: ``LatentDims.softmax_scale`` is the ONE place that
+  computes it, read by the absorbed decode (folded into its query; the
+  kernel takes the query pre-scaled) and by the expanded prefill alike,
+  so the temperature cannot reach one and miss the other.
 """
 
 from __future__ import annotations
@@ -29,7 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from llmq_tpu.ops.norms import rms_norm
-from llmq_tpu.ops.rope import apply_rope
+from llmq_tpu.ops.rope import (apply_rope, rope_cos_sin, rope_cos_sin_scaled,
+                               yarn_inv_freq, yarn_mscale)
 from llmq_tpu.utils.profiling import scope
 
 Params = Dict[str, Any]
@@ -41,7 +50,9 @@ class LatentDims:
     (``dim``, ``n_heads``, ``kv_lora_rank``, ``q_lora_rank``,
     ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
     ``mla_scale_q_lora``, ``mla_scale_kv_lora``, ``norm_eps``,
-    ``dtype``): a mixin for the families' frozen dataclasses."""
+    ``dtype``, and where it has them ``rope_theta`` and
+    ``rope_scaling``, an ``ops/rope.YarnScaling``): a mixin for the
+    families' frozen dataclasses."""
 
     @property
     def qk_head_dim(self) -> int:
@@ -52,6 +63,18 @@ class LatentDims:
         """Lanes of one cached row: the latent and the RoPE key, rounded
         up to whole 128-lane tiles."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        """What a score is multiplied by before the softmax, computed
+        HERE and nowhere else (the absorbed decode folds it into its
+        query, the expanded prefill multiplies its scores):
+        ``qk_head_dim ** -0.5``, times YaRN's temperature squared where
+        the configuration scales its positions (DeepSeek-V3's reading
+        of ``rope_scaling``: 2.00474 at factor 64)."""
+        y = getattr(self, "rope_scaling", None)
+        m = 1.0 if y is None else yarn_mscale(y.factor, y.mscale_all_dim)
+        return self.qk_head_dim ** -0.5 * m * m
 
     @property
     def q_scale(self) -> float:
@@ -196,6 +219,22 @@ def _jit_latent(name: str):
 
 # -- attention ----------------------------------------------------------------
 
+def rope_table(cfg, positions):
+    """(cos, sin) of the ``qk_rope_head_dim`` rotary lanes at
+    ``positions`` (..., T): the plain table, or YaRN's frequencies
+    where the configuration has a ``rope_scaling`` (cos and sin times
+    its attention factor, 1 where ``mscale`` equals ``mscale_all_dim``:
+    the temperature is then all in ``softmax_scale``)."""
+    y = getattr(cfg, "rope_scaling", None)
+    if y is None:
+        return rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    inv = yarn_inv_freq(
+        cfg.qk_rope_head_dim, cfg.rope_theta, factor=y.factor,
+        original_max_position=y.original_max_position,
+        beta_fast=y.beta_fast, beta_slow=y.beta_slow)
+    return rope_cos_sin_scaled(positions, inv, y.attention_factor)
+
+
 def qkv(cfg, lp: Params, l: int, x, cos, sin):
     """x (..., T, D) normed -> q_nope (..., T, H, dn), q_rope
     (..., T, H, dr) rotated, row (..., T, W): the cache's row. Both
@@ -253,7 +292,7 @@ def latent_decode_attention(cfg, lp: Params, l: int, q_nope, q_rope, row,
         pad = jnp.zeros((B, cfg.n_heads,
                          cfg.latent_width - r - cfg.qk_rope_head_dim),
                         q_lat.dtype)
-        scale, s_kv = cfg.qk_head_dim ** -0.5, cfg.kv_scale
+        scale, s_kv = cfg.softmax_scale, cfg.kv_scale
         if s_kv != 1.0:
             q_lat = q_lat.astype(jnp.float32) * s_kv
             q_rope, pad = (q_rope.astype(jnp.float32),
@@ -362,7 +401,7 @@ def latent_prefill_attention(cfg, lp: Params, l: int, q_nope, q_rope, pool,
     kb = bp * ps
     block_tables = jnp.pad(block_tables, ((0, 0), (0, -mp % bp)))
     wk, wv = wkv_b(cfg, lp, l)
-    scale, s_kv = cfg.qk_head_dim ** -0.5, cfg.kv_scale
+    scale, s_kv = cfg.softmax_scale, cfg.kv_scale
 
     def block(j, carry):
         m, z, acc = carry
@@ -404,6 +443,34 @@ def latent_prefill_attention(cfg, lp: Params, l: int, q_nope, q_rope, pool,
             o = o * s_kv
         return o.astype(jnp.result_type(pool.dtype, wv.dtype)).reshape(
             B, T, -1)
+
+
+def latent_prefill_attention_each(cfg, lp: Params, l: int, q_nope, q_rope,
+                                  pool, block_tables, positions, seq_lens):
+    """``latent_prefill_attention`` ONE ROW AT A TIME (a ``lax.map`` over
+    the B rows): each row then runs exactly its own key blocks, where
+    side by side every row runs the LONGEST context's, and a block's
+    float32 scores are a B-th. What a family whose mixed step holds many
+    slices calls (``models/xing.py``; ``models/longcat_flash.py`` has
+    the same map in ``_prefill_attend``, bound to its module's own name
+    of the attention, which ``tests/test_latent_prefill.py`` swaps);
+    ``key_blocks_each`` counts by this rule."""
+    def one(s):
+        q_n, q_r, bt, pos, n = s
+        return latent_prefill_attention(cfg, lp, l, q_n[None], q_r[None],
+                                        pool, bt[None], pos[None],
+                                        n[None])[0]
+    return jax.lax.map(one, (q_nope, q_rope, block_tables, positions,
+                             seq_lens))
+
+
+def key_blocks_each(seq_lens, T: int, page_size: int, max_pages: int):
+    """A family's ``mixed_key_blocks`` (``models/__init__.py``) where the
+    slices attend one at a time: (the blocks visited, those the tables
+    hold), each slice its own."""
+    each = [prefill_key_blocks(seq_lens[i:i + 1], T, page_size, max_pages)
+            for i in range(len(seq_lens))]
+    return sum(int(v) for _, v, _ in each), sum(t for _, _, t in each)
 
 
 # -- a head-wise output gate (an optional leaf) ---------------------------------

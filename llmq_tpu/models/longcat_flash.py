@@ -67,7 +67,7 @@ from llmq_tpu.models.latent import (  # noqa: F401
     LatentDims, attn_norm_count, attn_norm_leaves, attn_param_shapes,
     decode_geometry, draw_groups, init_latent_pool,
     latent_decode_attention, latent_prefill_attention, latent_write_prefill,
-    param_count, prefill_key_blocks, qkv, routes)
+    key_blocks_each, param_count, prefill_key_blocks, qkv, routes)
 from llmq_tpu.models.latent import prod as _prod
 from llmq_tpu.models.latent import swiglu as _mlp
 from llmq_tpu.ops.moe import identity_gate, route, routed_ffn
@@ -201,9 +201,7 @@ def mixed_key_blocks(seq_lens, T: int, page_size: int, max_pages: int):
     the executor's empty slot is one trash token: 1), and those their
     block tables hold (``models/__init__.py``). The slices attend one
     at a time (``_prefill_attend``): each runs its own blocks."""
-    each = [prefill_key_blocks(seq_lens[i:i + 1], T, page_size, max_pages)
-            for i in range(len(seq_lens))]
-    return sum(int(v) for _, v, _ in each), sum(t for _, _, t in each)
+    return key_blocks_each(seq_lens, T, page_size, max_pages)
 
 
 def init_row_state(cfg, batch: int) -> None:

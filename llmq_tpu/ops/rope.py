@@ -8,6 +8,8 @@ checkpoint import)."""
 
 from __future__ import annotations
 
+import math
+from dataclasses import dataclass
 from typing import Tuple
 
 import jax.numpy as jnp
@@ -60,8 +62,6 @@ def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,
     A model whose layers differ in kind may rotate each kind by a table
     of its own (``models/mellum.py``: this one on its full layers).
     """
-    import math
-
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
@@ -86,3 +86,31 @@ def rope_cos_sin_scaled(positions: jnp.ndarray, inv_freq: jnp.ndarray,
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
     return (jnp.cos(angles) * attention_factor,
             jnp.sin(angles) * attention_factor)
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """A ``rope_scaling`` block of ``type: yarn`` in DeepSeek-V3's
+    reading (``modeling_deepseek_v3.py``): the rotary pairs turn by
+    :func:`yarn_inv_freq`, cos and sin are multiplied by
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)`` (1 where the two keys are equal), and the softmax
+    scale by ``yarn_mscale(factor, mscale_all_dim) ** 2``
+    (``models/latent.LatentDims.softmax_scale``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def attention_factor(self) -> float:
+        return (yarn_mscale(self.factor, self.mscale)
+                / yarn_mscale(self.factor, self.mscale_all_dim))
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's temperature: ``0.1 mscale ln(factor) + 1`` (1.41589 at
+    factor 64), 1 without scaling."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0

@@ -79,7 +79,8 @@ SCOPES = (
     "attn_window", "attn_full", "attn_gate", "attn_out", "mlp",
     "moe_route", "moe_experts", "moe_combine", "head",
     "act_quant", "ssm_conv", "ssm_update", "ssm_scan", "kda_gates",
-    "cca_mix", "row_tail", "export", "import",
+    "cca_mix", "hc_mix", "hc_project", "hc_apply",
+    "row_tail", "export", "import",
 )
 
 

@@ -55,7 +55,7 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
         assert set(config["reduced"]) == set(entry["reduced"])
     assert families == {"llama", "deepseek_v3", "longcat_flash",
                         "granitemoehybrid", "afmoe", "ling_hybrid", "zaya",
-                        "solar_open2", "mellum"}
+                        "solar_open2", "mellum", "xing"}
 
 
 @pytest.mark.parametrize("cell", [
@@ -64,7 +64,8 @@ def test_every_configuration_names_a_family_with_the_whole_surface(
     "kanana2-decode-saturated", "longcat-decode-saturated",
     "granite4h-decode-saturated", "trinity-longshort-saturated",
     "ling3-reasoning-saturated", "zaya1-reasoning-saturated",
-    "solar2-longdoc-saturated", "mellum2-completion-sessions"])
+    "solar2-longdoc-saturated", "mellum2-completion-sessions",
+    "xing4-longdoc-saturated"])
 def test_every_cell_resolves_and_reports_what_the_contract_asks(bench, cell):
     assert contract.check_names(bench) == []
     assert cell in [w["name"] for w in bench["workloads"]]
@@ -91,6 +92,7 @@ def test_no_cell_is_left_out_of_the_cases_above(bench):
     ("granite-4.0-h-micro-bf16", 2, 8_192),
     ("trinity-large-preview-bf16-ep16", 2, 4_096),
     ("mellum2-12b-a2.5b-bf16", 2, 6_144),
+    ("xing4.0-29b-a4b-bf16-pp7", 2, 6_912),
 ])
 def test_cache_bytes_a_token_on_the_published_sizes(bench, config,
                                                     kv_itemsize, per_token):
@@ -1503,6 +1505,126 @@ def test_the_harness_s_own_check_judges_the_adopted_path(monkeypatch, fault):
         jax.clear_caches()
 
 
+def _xing(bench):
+    cell = contract.resolve_cell(bench, "xing4-longdoc-saturated")
+    return cell, contract.load_family(cell["family_dir"], "shapes")
+
+
+def test_the_stream_family_s_shapes_on_the_published_sizes(bench):
+    """``xing``: a pipeline stage held whole — every expert, the whole
+    vocabulary, the dense layer 0 and five routed layers, float32 sites.
+    The issue's arithmetic, and the program's count the same model."""
+    cell, shapes = _xing(bench)
+    held = cell["config"]["model"]
+    assert shapes.site_params(held) == 344_091
+    assert shapes.param_count(held) == 4_792_669_828
+    whole = dict(held, num_hidden_layers=40, dense_layers_held=2)
+    assert round(shapes.param_count(whole) / 1e9, 1) == 29.5
+    assert round(shapes.active_param_count(whole) / 1e9, 1) == 4.4
+    assert shapes.kv_bytes_per_token(whole, 2) == 40 * 1_152
+    from llmq_tpu.models import get_config, xing
+    import dataclasses
+    cfg = get_config("xing4.0-29b-a4b")
+    assert xing.param_count_analytic(cfg) == shapes.param_count(whole)
+    cut = dataclasses.replace(cfg, n_layers=6, first_k_dense=1)
+    assert xing.param_count_analytic(cut) == shapes.param_count(held)
+    assert xing.active_param_count(cut) == shapes.active_param_count(held)
+    assert xing.weight_bytes(cut) == shapes.weight_bytes(held, 2)
+    # a decode step's least bytes: the weights it touches, the latents
+    # of 32 rows at 14k, and the sites' streams (nothing beside them)
+    step = shapes.decode_step_bytes(held, 2, 2, 32, 32 * 14_000)
+    assert 10.5e9 < step < 11.5e9
+    assert shapes.hc_mix_bytes(held, 32) < 0.01 * step
+    assert shapes.decode_attn_bytes(held, 2, 32, 1e3) == 6_912e3
+
+
+def test_the_stream_configuration_is_the_catalog_s_row(bench):
+    """``xing4.0-29b-a4b-bf16-pp7``: every key of the catalog's
+    ``config`` under the same key with the same value but the two cuts,
+    each with its published value beside it; no width, no expert and no
+    row of the vocabulary among them; what is assumed and what is left
+    out stated, every assumed reading with its test."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    entry, doc = next(x for x in _configs(bench)
+                      if x[0]["name"] == "xing4.0-29b-a4b-bf16-pp7")
+    with open(CATALOG) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == entry["source"])
+    assert row["name"] == "Xing4.0-29B-A4B"
+    differs = {k for k, v in row["config"].items() if doc.get(k) != v}
+    assert differs == set(entry["reduced"]) == set(doc["reduced"]) == {
+        "num_hidden_layers", "max_position_embeddings"}
+    assert doc["published"] == {k: row["config"][k] for k in differs}
+    assert (doc["num_hidden_layers"], doc["dense_layers_held"],
+            doc["first_k_dense_replace"]) == (6, 1, 2)
+    assert doc["rope_scaling"] == row["config"]["rope_scaling"]
+    assert (doc["hc_mult"], doc["hc_sinkhorn_iters"], doc["hc_eps"]) == (
+        4, 20, 1e-6)
+    assert doc["num_nextn_predict_layers"] == 1
+    assert len(doc["left_out"]) == 1
+    assert "num_nextn_predict_layers" in doc["left_out"][0]
+    assert sum("tests/test_xing.py" in a or "[" in a
+               for a in doc["assumed"]) >= 4
+    assert "seven pipeline stages" in doc["deployment"]
+    ex = doc["server"]["executor"]
+    assert (ex["max_batch_size"], ex["page_size"],
+            ex["prefill_buckets"]) == (32, 128, [512])
+    assert doc["server"]["model"]["max_seq_len"] == 34_816
+    assert set(doc["server_why"]) >= {"max_batch_size", "kv_pages",
+                                      "mixed_batch", "memory"}
+    # the program's registry holds the same model
+    from llmq_tpu.models import get_config
+    cfg = get_config("xing4.0-29b-a4b")
+    c = row["config"]
+    assert (cfg.dim, cfg.n_layers, cfg.n_heads, cfg.q_lora_rank,
+            cfg.kv_lora_rank, cfg.ffn_dim, cfg.moe_ffn_dim,
+            cfg.n_routed_experts, cfg.n_experts_per_tok, cfg.vocab_size,
+            cfg.first_k_dense, cfg.hc_mult, cfg.hc_sinkhorn_iters) == (
+        c["hidden_size"], c["num_hidden_layers"], c["num_attention_heads"],
+        c["q_lora_rank"], c["kv_lora_rank"], c["intermediate_size"],
+        c["moe_intermediate_size"], c["n_routed_experts"],
+        c["num_experts_per_tok"], c["vocab_size"],
+        c["first_k_dense_replace"], c["hc_mult"], c["hc_sinkhorn_iters"])
+    rs = c["rope_scaling"]
+    assert (cfg.rope_scaling.factor, cfg.rope_scaling.original_max_position,
+            cfg.rope_scaling.mscale_all_dim) == (
+        rs["factor"], rs["original_max_position_embeddings"],
+        rs["mscale_all_dim"])
+
+
+def test_the_stream_family_s_tolerance_sits_between_its_readings(bench):
+    """``tolerance`` holds the harness's two keys, ``judge``'s and
+    ``judged_tokens``, no other; the judged sequence passes the YaRN
+    table's original positions and decodes at 16k; under its numbers the
+    served path's readings pass and the control's are refused by
+    ``rms_clean``, with room on both sides (``why`` has the readings)."""
+    import numpy as np
+    cell, _ = _xing(bench)
+    judge = contract.load_family(cell["family_dir"], "reference").judge
+    tol = cell["config"]["tolerance"]
+    assert set(tol) == {"rms", "max", "clean_quantile", "rms_clean",
+                        "margin_eps", "judged_tokens", "why"}
+    adapter = contract.load_family(cell["family_dir"], "adapter")
+    starts, steps = adapter.judged_plan(tol["judged_tokens"], 512, 4)
+    assert starts[0] >= 16_384 and min(starts) < 4_096 < starts[4]
+    assert starts[0] + steps + adapter.JUDGED_STEPS == tol["judged_tokens"]
+    # the largest the serving path read in any group of any seed, and
+    # the least the control did (my chip runs, PR 58; ``why`` has them)
+    served, control = 0.0054, 0.0441
+    assert "0.0048-0.0054" in tol["why"] and "0.0441-0.0463" in tol["why"]
+    assert 2.5 * served <= tol["rms_clean"] <= control / 2.5
+    ref = np.zeros((40, 16), np.float32)
+    margins = np.full(40, 0.001)
+    at = lambda v: ref + np.where(np.arange(40) < 10, v,  # noqa: E731
+                                  np.linspace(3 * v, 0.9, 40))[:, None]
+    assert judge(at(served), ref, margins, tol)["ok"]
+    assert not judge(at(control), ref, margins, tol)["ok"]
+    unrelated = at(served)
+    unrelated[3] = 1.41
+    assert not judge(unrelated, ref, margins, tol)["ok"]
+
+
 def test_the_harness_names_no_family():
     named = re.compile(r"llama|deepseek|kanana|smollm|fused_decode|gmm|"
                        r"latent_decode|moe_grouped|llmq_tpu\.models")
@@ -1520,7 +1642,7 @@ def test_the_harness_names_no_family():
 @pytest.mark.parametrize("family", ["llama", "deepseek_v3", "longcat_flash",
                                     "granitemoehybrid", "afmoe",
                                     "ling_hybrid", "zaya", "solar_open2",
-                                    "mellum"])
+                                    "mellum", "xing"])
 def test_who_imports_what_in_a_family(family):
     """``shapes.py`` is standard library alone (the parent and the
     readers import it); ``reference.py`` imports neither the program

@@ -32,6 +32,8 @@ SSM = {"ssm_conv", "ssm_update", "ssm_scan"}
 #: a family with two kinds of attention layer: each kind's call, and a
 #: gated attention's gate
 WINDOW = {"attn_window", "attn_full", "attn_gate"}
+#: a residual of several streams: the sites' two halves
+HYPER = {"hc_mix", "hc_project", "hc_apply"}
 FAMILIES = {
     "afmoe": MODULES | ROUTED | WINDOW,
     "mellum": MODULES | ROUTED | {"attn_window", "attn_full"},
@@ -46,6 +48,8 @@ FAMILIES = {
     "llama-w8kv8": MODULES | {"act_quant"},
     "deepseek_v3": MODULES | ROUTED | {"latent_prefill_attention"},
     "longcat_flash": MODULES | ROUTED | {"latent_prefill_attention"},
+    "xing": (MODULES | ROUTED | HYPER
+             | {"latent_prefill_attention", "attn_full"}),
 }
 PROGRAMS = ("prefill_multi_b16", "decode_chunk", "mixed_chunk")
 
@@ -89,6 +93,10 @@ def _tiny(family):
         from llmq_tpu.models import zaya
         cfg = zaya.zaya_tiny(dtype=jnp.float32, max_seq_len=128)
         return cfg, zaya.init_params(jax.random.PRNGKey(48), cfg), {}
+    if family == "xing":
+        from llmq_tpu.models import xing
+        cfg = xing.xing_tiny(dtype=jnp.float32, max_seq_len=128, n_layers=2)
+        return cfg, xing.init_params(jax.random.PRNGKey(58), cfg), {}
     if family == "granitemoehybrid":
         from llmq_tpu.models import granitemoehybrid as gm
         cfg = gm.granite4h_tiny(dtype=jnp.float32, max_seq_len=128)
@@ -188,9 +196,9 @@ def test_a_name_outside_the_vocabulary_is_refused():
     # attn_window, attn_full, attn_gate; ling_hybrid one: kda_gates;
     # zaya one: cca_mix; the window families' two tail programs three
     # that no serving program's instruction carries: row_tail, export,
-    # import)
-    assert sum(map(len, SCOPES)) < 250
-    assert SSM | WINDOW <= set(SCOPES)
+    # import; xing three: hc_mix, hc_project, hc_apply)
+    assert sum(map(len, SCOPES)) < 275
+    assert SSM | WINDOW | HYPER <= set(SCOPES)
 
 
 def test_no_scope_string_outside_the_vocabulary():
@@ -254,10 +262,12 @@ def test_every_module_of_the_family_is_named(compiled):
         lacks = want - got - {"kv_write"}
         if prog == "decode_chunk":
             lacks -= {"latent_prefill_attention", "ssm_scan"}
+            if name == "xing":      # ``attn_full`` names its PREFILL
+                lacks -= {"attn_full"}      # attention alone
         if prog.startswith("prefill"):
             lacks -= {"ssm_update"}
         assert not lacks, (prog, sorted(lacks))
-        others = (ROUTED | SSM | WINDOW
+        others = (ROUTED | SSM | WINDOW | HYPER
                   | {"act_quant", "latent_prefill_attention"}) - want
         assert not (got & others), (prog, sorted(got & others))
     assert "kv_write" in {c for p in _paths(text["mixed_chunk"])

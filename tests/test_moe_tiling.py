@@ -37,9 +37,13 @@ def _products():
 PRODUCTS = _products()
 
 
-def test_the_benchmark_serves_seven_routed_configurations():
-    assert len(PRODUCTS) == 14
+def test_the_benchmark_serves_eight_routed_configurations():
+    assert len(PRODUCTS) == 16
     assert ("mellum2-12b-a2.5b-bf16", "down", 896, 2304) in PRODUCTS
+    # the eighth (PR 58): experts of 3,584 x 2,048 and 1,024 x 3,584
+    assert ("xing4.0-29b-a4b-bf16-pp7", "gate-up", 3584, 2048) in PRODUCTS
+    assert moe.gmm_tiling(2048, 3584, 2048) == (128, 3584, 512)
+    assert moe.gmm_tiling(128, 1024, 3584) == (128, 1024, 1792)
 
 
 @pytest.mark.parametrize("m", [128, 256, 2048, 6528])
