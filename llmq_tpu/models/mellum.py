@@ -46,9 +46,9 @@ with the per-head norms and the rotation, ``wo`` and the residual, the
 router — runs over the live prefix a tile of rows at a time
 (``live_rows``: as many tiles as hold a token, read on the device), and
 the routed experts multiply the live (token, expert) pairs alone, a
-block of sorted pairs at a time (``ops/moe.routed_ffn(n_live=...)``:
-no array of N k rows). Only the K/V write and the two attentions see
-the (S, T) grid and take the two kinds of row apart.
+block of sorted pairs at a time (``ops/moe.routed_ffn(n_live=...)``: ONE
+array of N k rows, in bfloat16, written and gathered only where pairs are
+live). Only the K/V write and the two attentions see the (S, T) grid.
 ``mixed_live_rows`` says what ran. Int8 weights, an int8 cache and a
 mesh are not written: each is refused by name (``check_serving``).
 """

@@ -29,10 +29,10 @@ and the slices' tokens behind them, a layer's row-wise work in two
 layer before closes, the attention site opens, norm, q and the cache's
 row; CLOSE: ``wo``, the attention site closes, the feed-forward site
 opens, norm, router and the shared expert), the routed experts over the
-live (token, expert) pairs, and only the cache's write and the two
-attentions on the grid — the slices' attention one slice at a time, each
-over its own context's key blocks
-(``latent.latent_prefill_attention_each``); the PREFILL attention's call
+live pairs (``ops/moe.routed_ffn(n_live=...)``: held as sorted, a token
+gathering its k), and only the cache's write and the two attentions on
+the grid — the slices' attention a slice at a time, each over its own key
+blocks (``latent_prefill_attention_each``); the PREFILL attention's call
 stands under the scope ``attn_full``, the name the families with two
 kinds of layer give the attention that sees its whole context, so what
 reads theirs deep in a document reads this one. ``mixed_live_rows``

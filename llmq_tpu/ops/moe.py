@@ -41,8 +41,8 @@ above it is an identity expert, which adds ``g_e x`` and enters no
 group (``identity_gate`` gives the token's summed weight of them; the
 token's home chip adds it). A share's held pairs, and the live pairs
 of a tight mixed step (``n_live``), are a small and varying part of the
-``N k`` chosen ones: they are multiplied a BLOCK of sorted pairs at a
-time (``_held_blocks``) and no array of ``N k`` rows is ever made.
+``N k`` chosen ones: multiplied a BLOCK of sorted pairs at a time, then
+scatter-added by token (``_held_blocks``) or gathered (``_routed_live``).
 
 ``stats`` of a call: tokens each (held) expert received and how many
 experts received any — with a share or zero-compute experts also the
@@ -247,7 +247,7 @@ def identity_gate(experts: jnp.ndarray, gates: jnp.ndarray, n_routed: int,
         return g if live is None else jnp.where(live, g, 0.0)
 
 
-def _held_blocks(x, key, gates, w_gate_up, w_down, counts, k, blk=None):
+def _held_blocks(x, key, gates, w_gate_up, w_down, counts, k):
     """The held pairs' weighted SwiGLU results summed by token, (N, D)
     float32. ``key`` (N k,): each pair's group among the held experts,
     or ``E_held`` for a pair that is multiplied with nothing; ``counts``
@@ -257,7 +257,7 @@ def _held_blocks(x, key, gates, w_gate_up, w_down, counts, k, blk=None):
     gathered, multiplied and added to their tokens."""
     N, D = x.shape
     F = w_gate_up.shape[-1] // 2
-    M, blk = key.shape[0], blk or HELD_BLOCK
+    M, blk = key.shape[0], HELD_BLOCK
     with scope("moe_route"):
         order = jnp.pad(jnp.argsort(key, stable=True), (0, -M % blk))
         ends = jnp.cumsum(counts)
@@ -372,34 +372,88 @@ def _routed_share(x, flat, gates, w_gate_up, w_down, live, k, lo, n_routed):
 #: step of Mellum 2 brings ~4,000 live pairs to 64 experts: a block
 #: reads again the one expert it shares with the block before it.
 LIVE_BLOCK = 2048
+#: Tokens a tile of ``_routed_live``'s sum by token reads back at once
+#: (``k`` held rows each): of 128, 256, 512 and 1,024 the fastest at
+#: Xing's and Mellum's widths, full and part full, by 0.5-2.5 % of a
+#: routed layer over 256 (PERF.md section 6, PR 59).
+LIVE_TILE = 128
 
 
 def _routed_live(x, flat, gates, w_gate_up, w_down, live, n_live):
     """``routed_ffn`` where the caller says how many rows, LYING FIRST,
     can be live (``n_live``, a traced scalar: a tight mixed step's
     decode rows and the slices' tokens behind them, ``ops/rows.py``; a
-    row at or past it is dead whatever ``live`` says): the pairs that
-    are multiplied are then the first ``sum(counts)`` of the sorted
-    order, and the gather of their rows, the two grouped products, the
-    activation between them, the weighing and the sum by token run over
-    those alone, ``LIVE_BLOCK`` sorted pairs at a time while live pairs
-    are left (``_held_blocks``). The sort keeps its static length. The
-    sum of a token's ``k`` results is float32 as in the plain form, in
-    the sorted order instead of the slots'. Same ``stats``."""
+    row at or past it is dead whatever ``live`` says). Every expert is
+    held, so the pairs that are multiplied are the first
+    ``sum(counts)`` of the sorted order and a live token has exactly
+    ``k`` of them, at places known before any product runs. Two loops:
+
+    * over ``LIVE_BLOCK`` sorted pairs at a time while live pairs are
+      left (``_held_blocks``'s gather and products): a block WRITES its
+      results, in ``x.dtype`` as the grouped product returns them, to
+      its rows of ONE ``(N k up to a whole block, D)`` array in the
+      sorted order — a contiguous slice, no index a row. Rows at or
+      past ``sum(counts)`` hold whatever lay there;
+    * over ``LIVE_TILE`` tokens at a time while the tile's first token
+      is under ``n_live``: a token GATHERS its ``k`` rows back by the
+      inverse of the sort, weighs them in float32 and adds slot 0
+      first, then 1 ... k - 1 — the plain form's sum to the bit on the
+      CPU (XLA's TPU reduction over that form's middle axis adds in
+      another order: one bfloat16 step apart). A pair that is not live
+      weighs an exact 0 and is selected away, whatever its row holds.
+
+    The sort keeps its static length; no row is scatter-added. What
+    the sum needs beyond the products — the array, the inverse, the
+    second loop — stands under ``moe_combine``. Same ``stats``."""
     N, k = gates.shape
-    E = w_gate_up.shape[0]
+    D, (E, _, F2) = x.shape[-1], w_gate_up.shape
+    F, M = F2 // 2, N * k
+    blk, tile = min(LIVE_BLOCK, -(-M // 128) * 128), min(LIVE_TILE, N)
     with scope("moe_route"):
         alive = jnp.arange(N) < n_live
         if live is not None:
             alive = alive & live
         key = jnp.where(jnp.repeat(alive, k), flat, E)
         counts = jnp.zeros((E,), jnp.int32).at[key].add(1, mode="drop")
-    y = _held_blocks(x, key, gates, w_gate_up, w_down, counts, k,
-                     min(LIVE_BLOCK, -(-N * k // 128) * 128))
+        place = jnp.argsort(key, stable=True)
+        order = jnp.pad(place, (0, -M % blk))
+        ends = jnp.cumsum(counts)
+        starts, n_held = ends - counts, ends[-1]
+
+    def block(b, held):
+        with scope("moe_route"):
+            lo = b * blk
+            size = (jnp.clip(ends, lo, lo + blk)
+                    - jnp.clip(starts, lo, lo + blk))
+            xs = x[lax.dynamic_slice(order, (lo,), (blk,)) // k]
+        with scope("moe_experts"):
+            gu = _grouped(xs, w_gate_up, size)
+            a = (jax.nn.silu(gu[:, :F].astype(jnp.float32)).astype(x.dtype)
+                 * gu[:, F:])
+            ys = _grouped(a, w_down, size)
+        with scope("moe_combine"):
+            return lax.dynamic_update_slice(held, ys, (lo, 0))
+
+    def tokens(i, y):
+        at = jnp.minimum(i * tile, N - tile)
+        w = lax.dynamic_slice_in_dim(weight, at, tile, 1)[..., None]
+        rows = held[lax.dynamic_slice_in_dim(inv, at, tile, 1)]  # (k, tile, D)
+        rows = jnp.where(w != 0, rows.astype(jnp.float32) * w, 0.0)
+        return lax.dynamic_update_slice_in_dim(       # slot 0 first, then 1 …
+            y, functools.reduce(jnp.add, rows).astype(x.dtype), at, 0)
+
     with scope("moe_combine"):
+        held = jnp.zeros((order.shape[0], D), x.dtype)
+    held = lax.fori_loop(0, (n_held + blk - 1) // blk, block, held)
+    with scope("moe_combine"):
+        # (slot-major, (k, N): a tile's sum adds k whole (tile, D) slabs)
+        inv = jnp.argsort(place).reshape(N, k).T   # where pair (t, j) lies
+        weight = jnp.where(alive, gates.T, 0.0)
+        y = lax.fori_loop(0, (n_live + tile - 1) // tile, tokens,
+                          jnp.zeros((N, D), x.dtype))
         stats = jnp.concatenate(
             [counts, jnp.sum(counts > 0, dtype=jnp.int32)[None]])
-        return y.astype(x.dtype), stats
+        return y, stats
 
 
 def share_counts(st: jnp.ndarray, n_held: int) -> jnp.ndarray:

@@ -12,8 +12,8 @@ experts, one layer's routed experts over a mixed step's rows
 (``--lens ROWS``: that many of the batch + token budget rows hold a
 token, lying first): ``ops/moe.routed_ffn``'s plain form beside the
 form that is told the count (``n_live``), at each ``--block`` of sorted
-pairs, with the tiles ``ops/moe.gmm_tiling`` chose for the two grouped
-products; ``--tiling TM,TK,TN`` (repeatable) times the two products
+pairs and ``--tile`` of tokens, with the tiles ``ops/moe.gmm_tiling``
+chose for the two grouped products; ``--tiling TM,TK,TN`` (repeatable) times the two products
 ALONE (the megablox kernel over the sorted pairs of ROWS tokens, the
 gate-up and the down product apart) at those tiles beside the rule's:
 us a call, the (row tile, expert) visits, us a visit and the share of
@@ -1083,9 +1083,11 @@ def bench_routed(args, doc) -> None:
     of them, ``--lens ROWS`` live and lying first): µs a call of the
     plain form (a ``live`` mask: every array N k rows long) and of the
     form that is told the count (``n_live``: a block of sorted pairs at
-    a time while live pairs are left), the latter at each ``--block``
-    (``moe.LIVE_BLOCK`` where none is given), and how far the two
-    results lie apart. A file that holds a SHARE of the experts its
+    a time while live pairs are left, then a tile of tokens at a time
+    gathering each token's results), the latter at each ``--block`` and
+    ``--tile`` (``moe.LIVE_BLOCK`` and ``moe.LIVE_TILE`` where none is
+    given), and how far the two results lie apart. A file that holds a
+    SHARE of the experts its
     router scores (``router_experts``) runs the share form (``held``,
     ``n_routed``: blocks of ``moe.HELD_BLOCK`` held pairs), which no
     count is told. Four layers' experts, each a leaf of its own, are
@@ -1151,19 +1153,27 @@ def bench_routed(args, doc) -> None:
         jax.block_until_ready(y)
         return (time.perf_counter() - t0) / (n * L) * 1e6, first
 
+    # (block, tile) of each told run: none for a share; no tile (0) for
+    # a tree from before PR 59, whose sum by token scatter-adds
+    tiles = (args.tile or [moe.LIVE_TILE]) if hasattr(moe, "LIVE_TILE") else [0]
+    told = [] if share else [(b, t) for b in args.block or [moe.LIVE_BLOCK]
+                             for t in tiles]
     results = []
     for spec in args.lens:
         rows = min(N, int(spec))
         rec = {"rows": rows, "of": N, "pairs": rows * k}
         rec["plain_us"], plain = timed(False, rows)
         line = f"  {rows:5d} rows live of {N}: plain {rec['plain_us']:,.1f}"
-        for block in [] if share else args.block or [moe.LIVE_BLOCK]:
-            moe.LIVE_BLOCK = block
+        for block, tile in told:
+            moe.LIVE_BLOCK, key, of = block, f"block_{block}", f"{block}"
+            if tile:
+                moe.LIVE_TILE = tile
+                key, of = key + f"_tile_{tile}", of + f", tiles of {tile}"
             us, got = timed(True, rows)
             gap = float(np.abs(got - plain).max())
-            rec[f"live_us_block_{block}"] = us
+            rec["live_us_" + key] = us
             rec["max_abs_gap"] = max(gap, rec.get("max_abs_gap", 0.0))
-            line += f"; told, blocks of {block}: {us:,.1f} (apart {gap:.1e})"
+            line += f"; told, blocks of {of}: {us:,.1f} (apart {gap:.1e})"
         results.append(rec)
         print(line + " us/call", flush=True)
     _save(args, doc, results)
@@ -1208,6 +1218,9 @@ def main() -> None:
                          "this many lanes a step of its walk as well")
     ap.add_argument("--block", action="append", type=int, default=[],
                     help="routed: sorted pairs a block of the told form")
+    ap.add_argument("--tile", action="append", type=int, default=[],
+                    help="routed: tokens a tile of the told form's sum "
+                         "by token")
     ap.add_argument("--routed", action="store_true",
                     help="the routed experts' bench at the file's widths "
                          "whatever its family")

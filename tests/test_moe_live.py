@@ -1,7 +1,9 @@
 """``ops/moe.routed_ffn(n_live=...)``: the routed experts of a stream
-whose live rows LIE FIRST (a tight mixed step's: ``models/mellum.py``),
-multiplied a block of sorted pairs at a time while live pairs are left
-— against the plain form over the same rows, which makes every array
+whose live rows LIE FIRST (a tight mixed step's: ``models/mellum.py``,
+``models/xing.py``), multiplied a block of sorted pairs at a time while
+live pairs are left and summed by token a tile of tokens at a time
+while live tokens are left, each token GATHERING its ``k`` results —
+against the plain form over the same rows, which makes every array
 ``N k`` rows long; and, WITHOUT the count, ``routed_ffn``'s two forms
 held to the equations they traced before the count existed (the six
 cells that share ``ops/moe.py`` and pass none keep their programs).
@@ -17,7 +19,7 @@ from jax import lax
 from llmq_tpu.ops import moe
 from llmq_tpu.utils.profiling import scope
 
-N, D, F, E, K, BLOCK = 40, 32, 16, 8, 4, 32
+N, D, F, E, K, BLOCK, TILE = 40, 32, 16, 8, 4, 32, 16
 #: the decode rows that lead, the last of them not active
 LEAD = 4
 #: case -> (rows that can be live, experts the router may choose)
@@ -44,47 +46,115 @@ def _layer(dtype, n_experts=E, seed=55):
             jnp.asarray(w_gu, dtype), jnp.asarray(w_d, dtype))
 
 
+def _both_forms(case_rows, n_experts, dtype):
+    """(told, plain, mask, stats of both) over ``case_rows`` rows that
+    can be live, the decode row ``LEAD - 1`` not active."""
+    x, experts, gates, w_gu, w_d = _layer(dtype, n_experts)
+    active = jnp.arange(N) != LEAD - 1          # what ``live`` says
+    mask = active & (jnp.arange(N) < case_rows)
+    plain, plain_stats = jax.jit(moe.routed_ffn)(x, experts, gates, w_gu,
+                                                 w_d, mask)
+    told, told_stats = jax.jit(moe.routed_ffn)(
+        x, experts, gates, w_gu, w_d, active, n_live=jnp.int32(case_rows))
+    assert told.dtype == plain.dtype == dtype and told.shape == (N, D)
+    np.testing.assert_array_equal(np.asarray(told_stats),
+                                  np.asarray(plain_stats))
+    return (np.asarray(told, np.float32), np.asarray(plain, np.float32),
+            np.asarray(mask), np.asarray(plain_stats))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("case", sorted(LIVE_CASES))
 def test_the_told_form_is_the_plain_form_on_live_rows_and_zero_on_dead(
         monkeypatch, case, dtype):
-    """Blocks of 32 sorted pairs over 160: a live row's result is the
-    plain form's (float32: to rounding — a token's ``k`` results are
-    added in the sorted order, not the slots'; bfloat16: to one step of
-    the result), a dead row's — past the count, or a decode row that is
+    """Blocks of 32 sorted pairs over 160, tiles of 16 tokens over 40:
+    a live row's result is the plain form's TO THE BIT, in float32 and
+    in bfloat16 (the grouped product of a block's rows gives each row
+    what the product of all rows gives it — ``ragged_dot`` here — the
+    results are held in the type they come in, and a token's ``k`` are
+    weighed in float32 and added in the slots' order as the plain form
+    adds them), a dead row's — past the count, or a decode row that is
     not active — is exactly zero, and ``stats`` are equal."""
     monkeypatch.setattr(moe, "LIVE_BLOCK", BLOCK)
+    monkeypatch.setattr(moe, "LIVE_TILE", TILE)
     n_live, n_experts = LIVE_CASES[case]
-    x, experts, gates, w_gu, w_d = _layer(dtype, n_experts)
-    active = jnp.arange(N) != LEAD - 1          # what ``live`` says
-    mask = active & (jnp.arange(N) < n_live)
-    plain, plain_stats = jax.jit(moe.routed_ffn)(x, experts, gates, w_gu,
-                                                 w_d, mask)
-    told, told_stats = jax.jit(moe.routed_ffn)(
-        x, experts, gates, w_gu, w_d, active, n_live=jnp.int32(n_live))
-    assert told.dtype == plain.dtype == dtype and told.shape == (N, D)
-    np.testing.assert_array_equal(np.asarray(told_stats),
-                                  np.asarray(plain_stats))
-    pairs = int(np.asarray(plain_stats)[:E].sum())
+    told, plain, mask, stats = _both_forms(n_live, n_experts, dtype)
+    pairs = int(stats[:E].sum())
     assert pairs == int(mask.sum()) * K
     if case == "pairs-end-on-a-block-s-edge":
         assert pairs == 2 * BLOCK
     if case == "an-expert-with-no-token":
-        assert np.asarray(plain_stats)[E - 1] == 0
-    told, plain = (np.asarray(a, np.float32) for a in (told, plain))
-    assert not told[~np.asarray(mask)].any()
-    assert not pairs or np.abs(plain[np.asarray(mask)]).max() > 0.1
-    np.testing.assert_allclose(told, plain,
-                               atol=2e-6 if dtype == jnp.float32 else 2e-2)
+        assert stats[E - 1] == 0
+    assert not told[~mask].any()
+    assert not pairs or np.abs(plain[mask]).max() > 0.1
+    np.testing.assert_array_equal(told, plain)
     # without a mask the count alone says what is live
+    x, experts, gates, w_gu, w_d = _layer(dtype, n_experts)
     alone, _ = jax.jit(moe.routed_ffn)(x, experts, gates, w_gu, w_d,
                                        n_live=jnp.int32(n_live))
     rows = np.arange(N) < n_live
     assert not np.asarray(alone, np.float32)[~rows].any()
-    np.testing.assert_array_equal(
-        np.asarray(alone, np.float32)[np.asarray(mask)],
-        told[np.asarray(mask)])
+    np.testing.assert_array_equal(np.asarray(alone, np.float32)[mask],
+                                  told[mask])
+
+
+#: rows that can be live, against tiles of ``TILE`` tokens: none, the
+#: decode rows alone, one under / on / one over a tile's edge, a last
+#: tile that is moved back to end at N (40 is no multiple of 16), all
+TILE_EDGES = (0, LEAD, TILE - 1, TILE, TILE + 1, 2 * TILE + 1, N - 1, N)
+
+
+@pytest.mark.parametrize("n_live", TILE_EDGES)
+def test_the_sum_by_token_reads_the_live_tiles_and_no_dead_pair(
+        monkeypatch, n_live):
+    """The second loop's edges. The grouped product is made to return
+    NaN in every row behind its last group (the kernel's "undefined"),
+    and the held array is therefore NaN wherever a dead pair points
+    inside a written block: a dead row — past the count, in a tile that
+    is not read or in one that is, or the decode row that is not active
+    inside the first tile — still comes out EXACTLY zero, and a live
+    one equals the plain form to the bit."""
+    monkeypatch.setattr(moe, "LIVE_BLOCK", BLOCK)
+    monkeypatch.setattr(moe, "LIVE_TILE", TILE)
+    grouped = moe._grouped
+
+    def undefined_behind(xs, w, counts):
+        rows = jnp.arange(xs.shape[0])[:, None] < jnp.sum(counts)
+        return jnp.where(rows, grouped(xs, w, counts), jnp.nan)
+
+    monkeypatch.setattr(moe, "_grouped", undefined_behind)
+    told, plain, mask, _ = _both_forms(n_live, E, jnp.float32)
+    assert mask.sum() == max(0, n_live - (n_live >= LEAD))
+    assert np.isfinite(told).all() and not told[~mask].any()
+    np.testing.assert_array_equal(told[mask], plain[mask])
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_the_told_form_scatter_adds_no_row_of_results():
+    """Of the told form's program, loops included: the experts'
+    histogram (int32, one number a pair) is its only scatter; no
+    float array is scattered into, added or set — each token gathers.
+    The share form, for contrast, scatter-adds (N, D) float32 a block."""
+    x, experts, gates, w_gu, w_d = _layer(jnp.bfloat16)
+
+    def scatters(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: moe.routed_ffn(*a, **kw))(
+            x, experts, gates, w_gu, w_d)
+        return sorted((e.primitive.name, str(e.outvars[0].aval.dtype),
+                       e.outvars[0].aval.shape)
+                      for e in _equations(jaxpr.jaxpr)
+                      if e.primitive.name.startswith("scatter"))
+
+    assert scatters(n_live=jnp.int32(N)) == [("scatter-add", "int32", (E,))]
+    assert ("scatter-add", "float32", (N, D)) in scatters(held=(0, E),
+                                                          n_routed=E + 1)
 
 
 # -- without the count: today's two programs -------------------------------------

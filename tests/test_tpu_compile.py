@@ -1591,16 +1591,19 @@ def test_mellum_s_mixed_chunk_holds_no_grid_of_pairs_for_v5e(mellum_served):
     layers, 32 decode rows leading four tight slices of 512, 2,048
     pages beside 32 rows' slabs, 64 tail slots), since its mixed step
     runs the rows that hold a token (PR 55): the page pool and both
-    slab leaves go in and come out in place and none is copied; no
-    array of 16,640 (token, expert) pairs stands anywhere (the sort's
-    keys alone keep that length: the parent held 768 such arrays up to
-    2,304 wide, 153 MB the float32 one); three loops a layer are there
-    (front, close, the blocks of live pairs); the only stacked leaves
-    copied whole are ``wq``, ``wk`` and ``wv``, once each, transposed
-    for the decode LOOP's 32-row products as in the parent's program
-    (the front's head split inside its loop asks for the same layout:
-    no copy more); and the temporaries, 0.37 GB, stay under the
-    parent's 0.95 GB — the cell runs at 92 % of the chip."""
+    slab leaves go in and come out in place and none is copied; of the
+    16,640 (token, expert) pairs, nine blocks of 2,048, ONE array
+    stands, the experts' results in bfloat16 as sorted (85 MB a layer,
+    written and gathered where pairs are live: PR 59) and none in
+    float32 (PR 55's parent held 768 such arrays up to 2,304 wide, 153
+    MB the float32 one); four loops a layer are there (front, close,
+    the blocks of live pairs, the tiles of live tokens); the only
+    stacked leaves copied whole are ``wq``, ``wk`` and ``wv``, once
+    each, transposed for the decode LOOP's 32-row products as in the
+    parent's program (the front's head split inside its loop asks for
+    the same layout: no copy more); and the temporaries, 0.43 GB (0.37
+    before the one array), stay under PR 55's parent's 0.95 GB — the
+    cell runs at 92 % of the chip."""
     import re
     ex, compiled = mellum_served
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -1611,7 +1614,9 @@ def test_mellum_s_mixed_chunk_holds_no_grid_of_pairs_for_v5e(mellum_served):
     assert mem.alias_size_in_bytes >= sum(
         x.size * x.dtype.itemsize for x in jax.tree.leaves(held))
     assert not re.search(r"\[16640,\d\d+\]", text)
-    assert _control_flow(compiled).count("while") >= 3 * 12
+    assert set(re.findall(r"\w+\[18432,\d\d+\]", text)) == {
+        "bf16[18432,2304]"}
+    assert _control_flow(compiled).count("while") >= 4 * 12
     assert sorted(_whole_copies(compiled, ex.params)) == [
         "[12,2304,4096]", "[12,2304,512]", "[12,2304,512]"]
     assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
